@@ -1,0 +1,1272 @@
+#!/usr/bin/env python3
+"""Chip smoke: the serving path, end to end, on one TPU chip.
+
+    python chip_smoke.py [--seed N]          # one chip (what the driver runs)
+    python chip_smoke.py --four-chips        # the two multi-chip paths only
+
+Drives the system through the entry points a user calls, at llama3-8b's
+published widths with random int8 weights from ``--seed``:
+
+1. ``device``   — what JAX finds; anything but a TPU ends the run.
+2. ``serve``    — ``python -m generativeaiexamples_tpu.engine.server
+   --model llama3-8b --embedder arctic`` (32 layers, int8 weights, int8
+   KV) answers concurrent streaming chat completions with ~1,500-token
+   prompts and an embeddings batch; then the chain server
+   (``python -m generativeaiexamples_tpu.server``, placed on the CPU by
+   an explicit ``JAX_PLATFORMS=cpu`` as its container is) takes a
+   document and streams a ``/generate`` with the knowledge base on.
+3. ``retrieval`` — the ``tpu`` vector store over 262,144 x 1024 rows
+   against a numpy top-k; ``vector_store.name=auto`` picks a TPU store.
+4. ``optin``    — ``kv_layout=paged`` and ``matmul_kernel=pallas_w8a8``
+   each decode greedy streams through the ``Scheduler`` (8 layers) and
+   match their reference streams token for token (paged against
+   contiguous inside one page, the W8A8 kernel against its XLA twin);
+   the paged kernel's walk over up to eight pages is held to its XLA
+   twin at tolerance, and streams of two to four pages run beside it.
+
+The parent imports no JAX: the chip belongs to one process at a time, so
+each phase is a child (or the pair engine + chain server) that has
+exited before the next starts.  Every phase prints one JSON line; any
+failure, or a device that is not the expected one, exits non-zero.  The
+last line is ``{"ok": true, "device": {...}}`` and nothing else.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import json
+import os
+import re
+import shutil
+import signal
+import socket
+import subprocess
+import sys
+import tempfile
+import threading
+import time
+import urllib.error
+import urllib.request
+import uuid
+
+ROOT = os.path.dirname(os.path.abspath(__file__))
+PACKAGE = "generativeaiexamples_tpu"
+
+
+class SmokeFailure(Exception):
+    """A phase did not do what it must; the message says which and why."""
+
+
+@dataclasses.dataclass(frozen=True)
+class Sizes:
+    """Everything that differs between the chip run and its rehearsal."""
+
+    model: str = "llama3-8b"
+    embedder: str = "arctic"
+    embed_dim: int = 1024
+    max_batch: int = 32
+    max_len: int = 2048
+    # Byte tokenizer, and the chat template adds 100 tokens: 1,500-token
+    # prompts (chunked prefill), and one of 220 that prefills cold in the
+    # s=256 bucket — the flash kernel's shape under default chunking.
+    prompt_chars: int = 1400
+    short_prompt_chars: int = 120
+    new_tokens: int = 96
+    concurrent: int = 4
+    embed_batch: int = 8
+    corpus_rows: int = 262_144
+    queries: int = 32
+    top_k: int = 4
+    optin_layers: int = 8
+    optin_model: str = "llama3-8b"
+    kv_heads: int = 8  # the preset's own; the tiny rehearsal needs 4 to split
+    start_timeout_s: float = 600.0
+    request_timeout_s: float = 600.0
+
+
+FULL = Sizes()
+# The CPU rehearsal of the control flow (tests/test_chip_smoke.py).
+TINY = Sizes(
+    model="llama-tiny",
+    embedder="tiny",
+    embed_dim=64,
+    max_batch=4,
+    max_len=256,
+    prompt_chars=150,
+    short_prompt_chars=40,
+    new_tokens=8,
+    concurrent=2,
+    embed_batch=4,
+    corpus_rows=2048,
+    queries=8,
+    optin_layers=2,
+    optin_model="llama-tiny",
+    kv_heads=4,
+    start_timeout_s=240.0,
+    request_timeout_s=240.0,
+)
+
+
+def emit(line: dict) -> None:
+    print(json.dumps(line), flush=True)
+
+
+# --------------------------------------------------------------------------
+# Parent-side plumbing: children, HTTP, SSE (stdlib only — no JAX here).
+# --------------------------------------------------------------------------
+
+
+def _free_port() -> int:
+    with socket.socket() as s:
+        s.bind(("127.0.0.1", 0))
+        return s.getsockname()[1]
+
+
+def _child_env(**extra: str) -> dict:
+    env = dict(os.environ)
+    env["PYTHONPATH"] = ROOT + os.pathsep + env.get("PYTHONPATH", "")
+    env.setdefault("HF_HUB_OFFLINE", "1")
+    env.setdefault("TRANSFORMERS_OFFLINE", "1")
+    env["LOGLEVEL"] = "INFO"  # the phases read the servers' logs
+    env.update(extra)
+    return env
+
+
+class Server:
+    """A child server process with its log in a file; always stopped."""
+
+    def __init__(self, name: str, argv: list, env: dict, logdir: str) -> None:
+        self.name = name
+        self.log_path = os.path.join(logdir, f"{name}.log")
+        self._log = open(self.log_path, "wb")
+        self.proc = subprocess.Popen(
+            [sys.executable, *argv],
+            cwd=ROOT,
+            env=env,
+            stdout=self._log,
+            stderr=subprocess.STDOUT,
+        )
+
+    def log_tail(self, n: int = 2000) -> str:
+        with open(self.log_path, "rb") as f:
+            return f.read()[-n:].decode(errors="replace")
+
+    def log_has(self, needle: str) -> bool:
+        with open(self.log_path, "rb") as f:
+            return needle.encode() in f.read()
+
+    def log_count(self, pattern: str) -> int:
+        """The last number ``pattern`` captured in the log, or 0."""
+        with open(self.log_path, "rb") as f:
+            found = re.findall(pattern, f.read().decode(errors="replace"))
+        return int(found[-1]) if found else 0
+
+    def wait_healthy(self, url: str, timeout: float) -> dict:
+        deadline = time.monotonic() + timeout
+        while time.monotonic() < deadline:
+            if self.proc.poll() is not None:
+                raise SmokeFailure(
+                    f"{self.name} exited with code {self.proc.returncode} "
+                    f"before it served: {self.log_tail()}"
+                )
+            try:
+                return _http_json("GET", url, timeout=5.0)
+            except (urllib.error.URLError, OSError, ValueError):
+                time.sleep(1.0)
+        raise SmokeFailure(
+            f"{self.name} not healthy after {timeout:.0f}s: {self.log_tail()}"
+        )
+
+    def stop(self) -> None:
+        if self.proc.poll() is None:
+            self.proc.send_signal(signal.SIGTERM)
+            try:
+                self.proc.wait(timeout=60)
+            except subprocess.TimeoutExpired:
+                self.proc.kill()
+                self.proc.wait(timeout=30)
+        self._log.close()
+
+
+def _http_json(method: str, url: str, body=None, timeout: float = 60.0):
+    data = None if body is None else json.dumps(body).encode()
+    req = urllib.request.Request(
+        url,
+        data=data,
+        method=method,
+        headers={"Content-Type": "application/json"},
+    )
+    with urllib.request.urlopen(req, timeout=timeout) as resp:
+        return json.loads(resp.read().decode())
+
+
+def _http_text(url: str, timeout: float = 30.0) -> str:
+    with urllib.request.urlopen(url, timeout=timeout) as resp:
+        return resp.read().decode()
+
+
+def _sse(url: str, body: dict, timeout: float) -> tuple[list, bool]:
+    """POST and read a server-sent-event stream: (JSON events, saw [DONE])."""
+    req = urllib.request.Request(
+        url,
+        data=json.dumps(body).encode(),
+        method="POST",
+        headers={"Content-Type": "application/json"},
+    )
+    events, done = [], False
+    with urllib.request.urlopen(req, timeout=timeout) as resp:
+        for raw in resp:
+            line = raw.decode(errors="replace").strip()
+            if not line.startswith("data:"):
+                continue
+            payload = line[5:].strip()
+            if payload == "[DONE]":
+                done = True
+                break
+            events.append(json.loads(payload))
+    return events, done
+
+
+def _upload(url: str, filename: str, text: str, timeout: float) -> dict:
+    boundary = uuid.uuid4().hex
+    body = (
+        f"--{boundary}\r\nContent-Disposition: form-data; name=\"file\"; "
+        f"filename=\"{filename}\"\r\nContent-Type: text/plain\r\n\r\n"
+        f"{text}\r\n--{boundary}--\r\n"
+    ).encode()
+    req = urllib.request.Request(
+        url,
+        data=body,
+        method="POST",
+        headers={"Content-Type": f"multipart/form-data; boundary={boundary}"},
+    )
+    with urllib.request.urlopen(req, timeout=timeout) as resp:
+        return json.loads(resp.read().decode() or "{}")
+
+
+def _metric(metrics: str, name: str) -> float:
+    for line in metrics.splitlines():
+        if line.startswith(name + " "):
+            return float(line.split()[1])
+    raise SmokeFailure(f"engine /metrics has no {name}")
+
+
+def _engine_counts(engine_url: str) -> tuple[int, int]:
+    """(generation requests, tokens) the engine has served so far."""
+    metrics = _http_text(engine_url + "/metrics")
+    return (
+        int(_metric(metrics, "engine_requests_total")),
+        int(_metric(metrics, "engine_tokens_total")),
+    )
+
+
+def _words(seed: int, n_chars: int, salt: int) -> str:
+    """Deterministic English-like filler of about ``n_chars`` bytes."""
+    import random
+
+    rng = random.Random(seed * 1000 + salt)
+    vocab = (
+        "the chip serves a model from memory while retrieval finds "
+        "passages about tensor cores pages caches queues latency "
+        "throughput batches tokens prompts answers documents vectors"
+    ).split()
+    out, size = [], 0
+    while size < n_chars:
+        w = rng.choice(vocab)
+        out.append(w)
+        size += len(w) + 1
+    return " ".join(out)[:n_chars]
+
+
+def _run_child(name: str, seed: int, sizes: Sizes, timeout: float) -> list:
+    """Run ``chip_smoke.py --child <name>`` to its end; its JSON lines."""
+    proc = subprocess.run(
+        [
+            sys.executable,
+            os.path.abspath(__file__),
+            "--child",
+            name,
+            "--seed",
+            str(seed),
+            "--sizes",
+            json.dumps(dataclasses.asdict(sizes)),
+        ],
+        cwd=ROOT,
+        env=_child_env(),
+        capture_output=True,
+        text=True,
+        timeout=timeout,
+    )
+    lines = []
+    for ln in proc.stdout.splitlines():
+        if ln.startswith("{"):
+            lines.append(json.loads(ln))
+    if proc.returncode != 0:
+        raise SmokeFailure(
+            f"phase {name} exited with code {proc.returncode}: "
+            f"{proc.stderr[-3000:]}"
+        )
+    return lines
+
+
+def child_phase(
+    name: str,
+    seed: int,
+    sizes: Sizes,
+    expect: str,
+    timeout: float,
+    count: int | None = None,
+) -> dict:
+    """A phase that is one child: print its lines, hold each to the
+    expected device (and device count); returns the device."""
+    device = None
+    for line in _run_child(name, seed, sizes, timeout):
+        emit(line)
+        device = line["device"]
+        if device["platform"] != expect or count not in (None, device["count"]):
+            raise SmokeFailure(
+                f"{line['phase']} ran on {device}, not {count or 1} x {expect}"
+            )
+    if device is None:
+        raise SmokeFailure(f"phase {name} printed nothing")
+    return device
+
+
+# --------------------------------------------------------------------------
+# Phase: device.
+# --------------------------------------------------------------------------
+
+
+def phase_device(seed: int, sizes: Sizes, expect: str) -> dict:
+    (line,) = _run_child("device", seed, sizes, timeout=300)
+    device = line["device"]
+    if device["platform"] != expect:
+        raise SmokeFailure(
+            f"no {expect} device: JAX found platform "
+            f"{device['platform']!r} ({device['kind']} x{device['count']})"
+        )
+    emit(line)
+    return device
+
+
+def child_device(seed: int, sizes: Sizes) -> None:
+    from generativeaiexamples_tpu.utils.jax_runtime import device_report
+
+    emit({"phase": "device", "device": device_report()})
+
+
+# --------------------------------------------------------------------------
+# Phase: serve — engine server, then the chain server against it.
+# --------------------------------------------------------------------------
+
+
+def phase_serve(seed: int, sizes: Sizes, expect: str) -> None:
+    logdir = tempfile.mkdtemp(prefix="chip-smoke-")
+    port = _free_port()
+    engine_url = f"http://127.0.0.1:{port}"
+    t0 = time.monotonic()
+    engine = Server(
+        "engine",
+        [
+            "-m", f"{PACKAGE}.engine.server",
+            "--host", "127.0.0.1", "--port", str(port),
+            "--model", sizes.model,
+            "--embedder", sizes.embedder,
+            "--weight-dtype", "int8", "--kv-dtype", "int8",
+            "--max-batch", str(sizes.max_batch),
+            "--max-len", str(sizes.max_len),
+            "--seed", str(seed),
+        ],
+        _child_env(),
+        logdir,
+    )
+    chain = None
+    try:
+        health = engine.wait_healthy(
+            engine_url + "/health", sizes.start_timeout_s
+        )
+        start_s = time.monotonic() - t0
+        device = health["runtime"]["device"]
+        if device["platform"] != expect:
+            raise SmokeFailure(f"engine serves from {device}, not {expect}")
+        tokens0 = _metric(
+            _http_text(engine_url + "/metrics"), "engine_tokens_total"
+        )
+
+        # Concurrent streaming chat completions: long prompts (chunked
+        # prefill) plus one short one (a cold prefill at s=256).
+        prompts = [
+            _words(seed, sizes.prompt_chars, i)
+            for i in range(sizes.concurrent)
+        ] + [_words(seed, sizes.short_prompt_chars, 99)]
+        finishes: list = [None] * len(prompts)
+        errors: list = []
+
+        def chat(i: int) -> None:
+            try:
+                events, done = _sse(
+                    engine_url + "/v1/chat/completions",
+                    {
+                        "model": sizes.model,
+                        "messages": [{"role": "user", "content": prompts[i]}],
+                        "stream": True,
+                        "max_tokens": sizes.new_tokens,
+                        "temperature": 0.2,
+                    },
+                    sizes.request_timeout_s,
+                )
+                reasons = [
+                    c["finish_reason"]
+                    for e in events
+                    for c in e.get("choices", [])
+                    if c.get("finish_reason")
+                ]
+                finishes[i] = (reasons[-1] if reasons else None, done)
+            except Exception as e:  # noqa: BLE001 — reported below
+                errors.append(f"request {i}: {type(e).__name__}: {e}")
+
+        t_req = time.monotonic()
+        threads = [
+            threading.Thread(target=chat, args=(i,)) for i in range(len(prompts))
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(sizes.request_timeout_s + 30)
+        chat_s = time.monotonic() - t_req
+        if errors or any(t.is_alive() for t in threads):
+            raise SmokeFailure(f"chat completions failed: {errors or 'hung'}")
+        for i, fin in enumerate(finishes):
+            if fin is None or fin[0] not in ("length", "stop") or not fin[1]:
+                raise SmokeFailure(
+                    f"chat completion {i} finished {fin}, not length/stop "
+                    "then [DONE]"
+                )
+
+        texts = [
+            _words(seed, 300, 200 + i) for i in range(sizes.embed_batch)
+        ]
+        emb = _http_json(
+            "POST",
+            engine_url + "/v1/embeddings",
+            {"model": sizes.embedder, "input": texts},
+            timeout=sizes.request_timeout_s,
+        )
+        vectors = [d["embedding"] for d in emb["data"]]
+        if len(vectors) != len(texts) or any(
+            len(v) != sizes.embed_dim or not all(x == x for x in v)
+            for v in vectors
+        ):
+            raise SmokeFailure("embeddings: wrong shape or non-finite values")
+
+        metrics = _http_text(engine_url + "/metrics")
+        tokens = _metric(metrics, "engine_tokens_total") - tokens0
+        if tokens < len(prompts) * sizes.new_tokens * 0.9:
+            raise SmokeFailure(
+                f"engine returned {tokens:.0f} tokens for "
+                f"{len(prompts)} x {sizes.new_tokens} asked"
+            )
+        runtime = _http_json("GET", engine_url + "/health")["runtime"]
+        paths = runtime["kernel_paths"]
+        emit(
+            {
+                "phase": "serve.engine",
+                "entry": f"python -m {PACKAGE}.engine.server",
+                "model": sizes.model,
+                "weights": "int8",
+                "kv": "int8",
+                "max_batch": sizes.max_batch,
+                "max_len": sizes.max_len,
+                "device": runtime["device"],
+                "start_s": round(start_s, 1),
+                "chat_requests": len(prompts),
+                "chat_finish": [f[0] for f in finishes],
+                "chat_s": round(chat_s, 1),
+                "tokens_returned": int(tokens),
+                "embeddings": [len(vectors), sizes.embed_dim],
+                "compile": runtime["compile"],
+                "kernel_paths": paths,
+                "peak_bytes_in_use": runtime["peak_bytes_in_use"],
+            }
+        )
+
+        if expect == "tpu":
+            for site in ("decode_attention", "prefill_attention"):
+                if "pallas" not in {
+                    v for k, v in paths.items() if k.startswith(site)
+                }:
+                    raise SmokeFailure(
+                        f"{site} never took its Pallas kernel: {paths}"
+                    )
+
+        # The RAG path: chain server on the CPU, engine on the chip —
+        # the layout of deploy/compose/rag-app-base.yaml.
+        cport = _free_port()
+        chain_url = f"http://127.0.0.1:{cport}"
+        chain = Server(
+            "chain",
+            ["-m", f"{PACKAGE}.server", "--host", "127.0.0.1",
+             "--port", str(cport)],
+            _child_env(
+                JAX_PLATFORMS="cpu",
+                APP_LLM_MODELENGINE="openai",
+                APP_LLM_SERVERURL=engine_url,
+                APP_LLM_MODELNAME=sizes.model,
+                APP_EMBEDDINGS_MODELENGINE="openai",
+                APP_EMBEDDINGS_SERVERURL=engine_url,
+                APP_EMBEDDINGS_DIMENSIONS=str(sizes.embed_dim),
+                APP_VECTORSTORE_NAME="tpu",
+                # Two ~500-character chunks: with the byte tokenizer the
+                # chain's prompt is then ~1,500 tokens and fits max_len
+                # with room for the answer (four would be clipped).
+                APP_RETRIEVER_TOPK="2",
+                APP_RETRIEVER_SCORETHRESHOLD="0.0",
+                # Uploads stay under this run's own directory (the
+                # server's default is a fixed path outside the checkout).
+                GAIE_UPLOAD_DIR=os.path.join(logdir, "uploads"),
+            ),
+            logdir,
+        )
+        chain.wait_healthy(chain_url + "/health", sizes.start_timeout_s)
+        doc = "\n\n".join(_words(seed, 900, 300 + i) for i in range(4))
+        _upload(
+            chain_url + "/documents", "smoke.txt", doc,
+            sizes.request_timeout_s,
+        )
+        question = _words(seed, 80, 300)
+        found = _http_json(
+            "POST",
+            chain_url + "/search",
+            {"query": question, "top_k": 4},
+            timeout=sizes.request_timeout_s,
+        )
+        n_chunks = len(found.get("chunks", []))
+        if n_chunks < 1:
+            raise SmokeFailure(f"/search found no chunk: {found}")
+        before = _engine_counts(engine_url)
+        answer_tokens = min(sizes.new_tokens, 64)
+        events, _ = _sse(
+            chain_url + "/generate",
+            {
+                "messages": [{"role": "user", "content": question}],
+                "use_knowledge_base": True,
+                "max_tokens": answer_tokens,
+            },
+            sizes.request_timeout_s,
+        )
+        # The chain's sentinel is a last ChainResponse whose choice has
+        # finish_reason "[DONE]"; the error idiom is the same chunk with
+        # an empty id and the error as its content.
+        degraded = sorted({d for e in events for d in e.get("degraded") or []})
+        last = events[-1] if events else {}
+        done = any(
+            c.get("finish_reason") == "[DONE]" for c in last.get("choices", [])
+        )
+        if not done or not last.get("id") or degraded:
+            raise SmokeFailure(
+                f"/generate: [DONE]={done}, degraded={degraded}, last "
+                f"chunk {last}: {chain.log_tail()}"
+            )
+        retrieved = chain.log_count(r"retrieved (\d+) chunks")
+        if retrieved < 1:
+            raise SmokeFailure("/generate answered without retrieved context")
+        # The answer must be the engine's: random weights emit ids far
+        # outside the byte tokenizer's 0..255, which detokenize to empty
+        # text, so the stream may carry no content chunk — the engine's
+        # own counters say whether it generated for the chain's request,
+        # and all it was asked for (a prompt that overflows max_len is
+        # clipped to its tail and leaves room for nine tokens).
+        deadline = time.monotonic() + 10.0  # counters flush per tick
+        while True:
+            asked, generated = (
+                now - was
+                for now, was in zip(_engine_counts(engine_url), before)
+            )
+            if generated >= answer_tokens or time.monotonic() > deadline:
+                break
+            time.sleep(0.2)
+        if asked != 1 or generated != answer_tokens:
+            raise SmokeFailure(
+                f"/generate reached [DONE] but the engine served {asked} "
+                f"chat completion(s) and {generated} of {answer_tokens} "
+                "token(s) for it"
+            )
+        if engine.log_has("scheduler tick failed"):
+            raise SmokeFailure(
+                f"engine logged a failed scheduler tick: {engine.log_tail(4000)}"
+            )
+        emit(
+            {
+                "phase": "serve.chain",
+                "entry": f"python -m {PACKAGE}.server",
+                "placement": "JAX_PLATFORMS=cpu (explicit); engine on the chip",
+                "document_chars": len(doc),
+                "search_chunks": n_chunks,
+                "generate_context_chunks": retrieved,
+                "generate_events": len(events),
+                "generate_text_chars": sum(
+                    len(c.get("message", {}).get("content") or "")
+                    for e in events
+                    for c in e.get("choices", [])
+                ),
+                "engine_requests_for_generate": asked,
+                "engine_tokens_for_generate": generated,
+                "generate_done": done,
+                "degraded": degraded,
+            }
+        )
+    finally:
+        if chain is not None:
+            chain.stop()
+        engine.stop()
+        shutil.rmtree(logdir, ignore_errors=True)
+
+
+# --------------------------------------------------------------------------
+# Phase: retrieval on the chip.
+# --------------------------------------------------------------------------
+
+
+def child_retrieval(seed: int, sizes: Sizes) -> None:
+    import numpy as np
+
+    from generativeaiexamples_tpu.core.configuration import get_config
+    from generativeaiexamples_tpu.retrieval.base import Chunk
+    from generativeaiexamples_tpu.retrieval.factory import get_vector_store
+    from generativeaiexamples_tpu.utils.jax_runtime import (
+        enable_compile_cache,
+        runtime_report,
+    )
+
+    enable_compile_cache()
+    dim, n, k = sizes.embed_dim, sizes.corpus_rows, sizes.top_k
+    rng = np.random.default_rng(seed)
+    corpus = rng.standard_normal((n, dim), dtype=np.float32)
+    corpus /= np.linalg.norm(corpus, axis=1, keepdims=True)
+    queries = rng.standard_normal((sizes.queries, dim), dtype=np.float32)
+    queries /= np.linalg.norm(queries, axis=1, keepdims=True)
+
+    cfg = get_config()
+    store = get_vector_store(cfg, dimensions=dim, overrides={"backend": "tpu"})
+    t0 = time.monotonic()
+    store.add([Chunk(text=str(i), source="smoke") for i in range(n)], corpus)
+    hits = store.search_batch(queries, k)  # first call: load + compile
+    load_s = time.monotonic() - t0
+    t0 = time.monotonic()
+    hits = store.search_batch(queries, k)
+    search_s = time.monotonic() - t0
+    got = np.array([[int(h.chunk.text) for h in row] for row in hits])
+    # The reference scores the rows the store holds: it keeps them (and
+    # casts queries) in bfloat16 and accumulates in f32.
+    import ml_dtypes
+
+    def held(x):
+        return x.astype(ml_dtypes.bfloat16).astype(np.float32)
+
+    want = np.argsort(-(held(queries) @ held(corpus).T), axis=1)[:, :k]
+    if got.shape != want.shape or not (got == want).all():
+        raise SmokeFailure(
+            f"tpu store ids differ from the numpy top-{k}: "
+            f"{int((got != want).sum())} of {want.size}"
+        )
+    auto = get_vector_store(cfg, dimensions=dim, overrides={"backend": "auto"})
+    report = runtime_report()
+    on_tpu = report["device"]["platform"] == "tpu"
+    if on_tpu and not type(auto).__name__.startswith("TPU"):
+        raise SmokeFailure(
+            f"vector_store.name=auto resolved to {type(auto).__name__} "
+            "on a TPU host"
+        )
+    emit(
+        {
+            "phase": "retrieval",
+            "store": type(store).__name__,
+            "corpus": [n, dim],
+            "queries": sizes.queries,
+            "top_k": k,
+            "ids_equal_numpy": True,
+            "reference": "numpy f32 top-k over the bfloat16 rows the store holds",
+            "auto_resolves_to": type(auto).__name__,
+            "load_and_first_search_s": round(load_s, 2),
+            "second_search_s": round(search_s, 4),
+            "device": report["device"],
+            "compile": report["compile"],
+            "peak_bytes_in_use": report["peak_bytes_in_use"],
+        }
+    )
+
+
+# --------------------------------------------------------------------------
+# Phase: the two opt-in paths through the Scheduler.
+# --------------------------------------------------------------------------
+
+
+def _greedy(scheduler, prompts: list, max_tokens: int, timeout: float) -> list:
+    """Greedy streams for ``prompts`` through a running scheduler/pool."""
+    import queue
+
+    from generativeaiexamples_tpu.engine.sampler import SamplingParams
+    from generativeaiexamples_tpu.engine.scheduler import Request
+
+    streams: list = [[] for _ in prompts]
+    done: "queue.Queue[tuple]" = queue.Queue()
+    for i, prompt in enumerate(prompts):
+        ok = scheduler.submit(
+            Request(
+                token_ids=list(prompt),
+                sampling=SamplingParams(temperature=0.0, max_tokens=max_tokens),
+                on_token=streams[i].append,
+                on_done=lambda reason, i=i: done.put((i, reason)),
+            )
+        )
+        if not ok:
+            raise SmokeFailure("scheduler refused a request")
+    for _ in prompts:
+        i, reason = done.get(timeout=timeout)
+        if reason not in ("length", "stop"):
+            raise SmokeFailure(f"request {i} finished with reason {reason!r}")
+    return streams
+
+
+def _optin_cfg(sizes: Sizes):
+    import dataclasses as dc
+
+    from generativeaiexamples_tpu.models import llama
+
+    return dc.replace(
+        llama.PRESETS[sizes.optin_model](),
+        n_layers=sizes.optin_layers,
+        kv_dtype="int8",
+        max_seq_len=256,
+    )
+
+
+def _prompts(seed: int, vocab: int, lengths: list) -> list:
+    import numpy as np
+
+    rng = np.random.default_rng(seed + 7)
+    return [rng.integers(0, vocab, (n,)).tolist() for n in lengths]
+
+
+def _taken(prefix: str) -> dict:
+    from generativeaiexamples_tpu.ops.dispatch import TAKEN
+
+    return {k: v for k, v in sorted(TAKEN.items()) if k.startswith(prefix)}
+
+
+def _require_pallas(what: str, paths: dict, on_tpu: bool) -> None:
+    if on_tpu and (not paths or set(paths.values()) != {"pallas"}):
+        raise SmokeFailure(
+            f"{what} was asked for and is not the path that ran: {paths}"
+        )
+
+
+def _paged_kernel_vs_twin(cfg, seed: int, page: int, on_tpu: bool) -> dict:
+    """The paged decode kernel against its XLA twin on one layer of a
+    random pool: eight ragged rows of one to eight pages behind a
+    shuffled page table, with and without the append buffer.  The pair
+    is gated at tolerance, as in tests/test_paged_kv.py: the kernel
+    normalizes its online softmax in page order, the twin once over
+    the gathered window (docs/kernels.md)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from generativeaiexamples_tpu.ops import decode_attention as da
+
+    layers, rows, slot_pages, chunk = 2, 8, 8, 8
+    kh, nq, hd = cfg.n_kv_heads, cfg.n_heads, cfg.head_dim
+    lengths = [1, page - 1, page, page + 1, 3 * page + 8, 4 * page + 1,
+               7 * page, 8 * page]
+    rng = np.random.default_rng(seed + 11)
+    # Pool page 0 is the pinned garbage page; the rows own the rest in
+    # shuffled order, so consecutive logical pages are far apart.
+    table = 1 + rng.permutation(rows * slot_pages).reshape(rows, slot_pages)
+    slots = -(-(rows * slot_pages + 1) * page // 128) * 128
+    keys = jax.random.split(jax.random.PRNGKey(seed + 11), 9)
+
+    def values(key, shape):
+        return jax.random.randint(key, shape, -127, 128, jnp.int8)
+
+    def scales(key, shape):
+        x = jnp.abs(jax.random.normal(key, shape, jnp.float32))
+        return (x * 0.02 + 0.01).astype(jnp.bfloat16)
+
+    pool = (
+        values(keys[0], (layers, kh, slots, hd)),
+        values(keys[1], (layers, kh, slots, hd)),
+        scales(keys[2], (layers, kh, slots)),
+        scales(keys[3], (layers, kh, slots)),
+    )
+    append = (
+        values(keys[4], (layers, kh, rows, chunk, hd)),
+        values(keys[5], (layers, kh, rows, chunk, hd)),
+        scales(keys[6], (layers, kh, rows, chunk)),
+        scales(keys[7], (layers, kh, rows, chunk)),
+        jnp.int32(5),
+    )
+    q = jax.random.normal(keys[8], (rows, nq, hd), jnp.bfloat16)
+    rest = (
+        jnp.int32(1),
+        jnp.asarray(lengths, jnp.int32),
+        jnp.asarray(table, jnp.int32),
+    )
+    took = da.use_paged_kernel(
+        s=1, kv_int8=True, page_tokens=page, n_q=nq, n_kv=kh,
+        head_dim=hd, append_width=chunk,
+    )
+    if on_tpu and not took:
+        raise SmokeFailure("use_paged_kernel refuses llama3-8b's geometry")
+    out = {
+        "rows_pages": [-(-n // page) for n in lengths],
+        "tolerance": "0.02 x the row's max|out|",
+        "max_abs_diff_over_row_max": {},
+    }
+    for name, ab in (("no_append", None), ("append_5_of_8", append)):
+        ref = np.asarray(
+            da.paged_decode_gqa_attention_xla(
+                q, *pool, *rest, ab,
+                window=slot_pages * page, page_tokens=page,
+            ),
+            np.float32,
+        )
+        if not np.isfinite(ref).all():
+            raise SmokeFailure(f"paged XLA twin ({name}): non-finite output")
+        if not took:  # the CPU rehearsal: the gate sends it to the twin
+            out["max_abs_diff_over_row_max"][name] = None
+            continue
+        got = np.asarray(
+            da.paged_decode_gqa_attention(
+                q, *pool, *rest, ab, page_tokens=page
+            ),
+            np.float32,
+        )
+        # Held row by row: two or three bfloat16 steps of the row's
+        # largest output, so a long row cannot hide behind a short one.
+        err = np.abs(got - ref).max(axis=(1, 2))
+        top = np.abs(ref).max(axis=(1, 2))
+        out["max_abs_diff_over_row_max"][name] = round(
+            float((err / top).max()), 5
+        )
+        if not (err <= 0.02 * top).all():
+            raise SmokeFailure(
+                f"paged kernel ({name}) differs from its XLA twin: max "
+                f"|d| per row {err.round(4).tolist()} against max |out| "
+                f"{top.round(4).tolist()} for {out['rows_pages']} pages"
+            )
+    return out
+
+
+def _agree(a: list, b: list) -> list:
+    """Per stream, how many leading tokens two runs have in common."""
+    return [
+        next((i for i, (x, y) in enumerate(zip(s, t)) if x != y), len(s))
+        for s, t in zip(a, b)
+    ]
+
+
+def child_optin(seed: int, sizes: Sizes) -> None:
+    import jax
+
+    from generativeaiexamples_tpu.engine.decode import (
+        init_random_int8_params,
+        prepare_params,
+    )
+    from generativeaiexamples_tpu.engine.scheduler import Scheduler
+    from generativeaiexamples_tpu.ops.dispatch import TAKEN
+    from generativeaiexamples_tpu.utils.jax_runtime import (
+        enable_compile_cache,
+        runtime_report,
+    )
+
+    enable_compile_cache()
+    cfg = _optin_cfg(sizes)
+    on_tpu = jax.default_backend() == "tpu"
+    raw = init_random_int8_params(cfg, jax.random.PRNGKey(seed))
+    packed = prepare_params(cfg, raw, None, pack=True)
+    # The kernel alone first, then through the Scheduler with two sets
+    # of streams.  Short: prompt + new tokens stay inside one 64-token
+    # page and one 64-slot window, where the paged and the contiguous
+    # layout fold the softmax over the same tiles, so their tokens must
+    # be equal.  Long: two to four pages per row, ragged, two of them
+    # crossing into a new page while they decode, so the kernel walks
+    # real page tables, alternates its buffer slots and prefetches page
+    # i+1 under page i.  Across tilings the kernels (and a kernel and
+    # its twin) agree to tolerance, not bitwise (docs/kernels.md), and
+    # greedy streams of a random model amplify the last bit: the long
+    # streams' agreement is printed, the tolerance is held above.
+    page = 64
+    t0 = time.monotonic()
+    kernel_vs_twin = _paged_kernel_vs_twin(cfg, seed, page, on_tpu)
+    short = _prompts(seed, cfg.vocab_size, [40, 33, 24, 17])
+    long_ = _prompts(seed + 3, cfg.vocab_size, [185, 170, 120])
+    new_tokens = 16
+    kw = dict(max_batch=16, max_len=256, decode_chunk_size=8, seed=seed)
+    note = (
+        f"{sizes.optin_model} widths, depth cut to {cfg.n_layers} layers "
+        "to save compile time"
+    )
+
+    def run(params, sets, **extra) -> list:
+        """One scheduler, one greedy pass per prompt set, in order."""
+        sched = Scheduler(cfg, params, **kw, **extra)
+        sched.start()
+        try:
+            return [_greedy(sched, ps, new_tokens, 600.0) for ps in sets]
+        finally:
+            sched.stop()
+
+    contiguous = run(packed, (short, long_))
+    TAKEN.clear()
+    paged = run(packed, (short, long_), kv_layout="paged", kv_page_size=page)
+    paged_paths = _taken("paged_decode_attention")
+    os.environ["GAIE_DISABLE_PAGED_KERNEL"] = "1"
+    try:
+        TAKEN.clear()
+        (twin,) = run(packed, (long_,), kv_layout="paged", kv_page_size=page)
+        twin_paths = _taken("paged_decode_attention")
+    finally:
+        del os.environ["GAIE_DISABLE_PAGED_KERNEL"]
+    if paged[0] != contiguous[0]:
+        raise SmokeFailure(
+            f"kv_layout=paged stream differs from contiguous: "
+            f"{paged[0]} vs {contiguous[0]}"
+        )
+    _require_pallas("the paged decode kernel", paged_paths, on_tpu)
+    if on_tpu and set(twin_paths.values()) != {"xla"}:
+        raise SmokeFailure(f"the paged kernel's twin run took {twin_paths}")
+    report = runtime_report()
+    emit(
+        {
+            "phase": "optin.paged",
+            "config": note,
+            "page_tokens": page,
+            "kernel_vs_xla_twin": kernel_vs_twin,
+            "streams": len(short),
+            "tokens_each": new_tokens,
+            "matches_contiguous": True,
+            "long_streams_pages": [
+                [-(-len(p) // page), -(-(len(p) + new_tokens) // page)]
+                for p in long_
+            ],
+            "long_streams_tokens_equal_xla_twin": _agree(paged[1], twin),
+            "long_streams_tokens_equal_contiguous": _agree(
+                paged[1], contiguous[1]
+            ),
+            "kernel_paths": paged_paths,
+            "seconds": round(time.monotonic() - t0, 1),
+            "device": report["device"],
+            "compile": report["compile"],
+        }
+    )
+
+    # W8A8: the kernel against its XLA twin on the same blocked params
+    # (the twin is what GAIE_DISABLE_QMM_KERNEL selects at trace time).
+    t0 = time.monotonic()
+    os.environ["GAIE_DISABLE_QMM_KERNEL"] = "1"
+    try:
+        (twin,) = run(packed, (short,), matmul_kernel="pallas_w8a8")
+    finally:
+        del os.environ["GAIE_DISABLE_QMM_KERNEL"]
+    TAKEN.clear()
+    (fused,) = run(packed, (short,), matmul_kernel="pallas_w8a8")
+    decode_paths = {
+        k: v for k, v in _taken("q_matmul").items() if k.endswith("m=32")
+    }
+    if fused != twin:
+        raise SmokeFailure(
+            f"matmul_kernel=pallas_w8a8 stream differs from its XLA "
+            f"twin: {fused} vs {twin}"
+        )
+    _require_pallas("the W8A8 kernel", decode_paths, on_tpu)
+    report = runtime_report()
+    emit(
+        {
+            "phase": "optin.w8a8",
+            "config": note,
+            "streams": len(short),
+            "tokens_each": new_tokens,
+            "matches_xla_twin": True,
+            "kernel_paths": _taken("q_matmul"),
+            "seconds": round(time.monotonic() - t0, 1),
+            "device": report["device"],
+            "compile": report["compile"],
+            "peak_bytes_in_use": report["peak_bytes_in_use"],
+        }
+    )
+
+
+# --------------------------------------------------------------------------
+# --four-chips: the tensor-parallel mesh and the replica pool.
+# --------------------------------------------------------------------------
+
+
+def _shard_summary(tree) -> dict:
+    """Per leaf: global shape, one device's shard shape, devices."""
+    import jax
+
+    out = {}
+    for path, leaf in jax.tree_util.tree_flatten_with_path(tree)[0]:
+        out[jax.tree_util.keystr(path)] = {
+            "shape": list(leaf.shape),
+            "shard": list(leaf.addressable_shards[0].data.shape),
+            "devices": len(leaf.sharding.device_set),
+        }
+    return out
+
+
+def child_four(seed: int, sizes: Sizes) -> None:
+    import dataclasses as dc
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from generativeaiexamples_tpu.engine.replica import EnginePool
+    from generativeaiexamples_tpu.engine.scheduler import Scheduler
+    from generativeaiexamples_tpu.models import llama
+    from generativeaiexamples_tpu.ops.dispatch import TAKEN
+    from generativeaiexamples_tpu.parallel.mesh import (
+        MeshSpec,
+        make_mesh,
+        replica_device_slices,
+    )
+    from generativeaiexamples_tpu.utils.jax_runtime import (
+        enable_compile_cache,
+        runtime_report,
+    )
+
+    enable_compile_cache()
+    devices = jax.devices()
+    if len(devices) != 4:
+        raise SmokeFailure(f"--four-chips needs 4 devices, JAX has {len(devices)}")
+    on_tpu = devices[0].platform == "tpu"
+    prompts_len = [40, 33, 24, 17]
+    new_tokens = 16
+    kw = dict(max_batch=16, max_len=256, decode_chunk_size=8, seed=seed)
+
+    # (a) Tensor parallel: bf16, depth cut so the unsharded copy fits one
+    # chip beside its sharded twin.
+    cfg = dc.replace(
+        llama.PRESETS[sizes.optin_model](),
+        n_layers=sizes.optin_layers,
+        n_kv_heads=sizes.kv_heads,
+        max_seq_len=256,
+    )
+    prompts = _prompts(seed, cfg.vocab_size, prompts_len)
+    params = llama.init_params(cfg, jax.random.PRNGKey(seed))
+    t0 = time.monotonic()
+    mesh = make_mesh(MeshSpec(data=1, tensor=4))
+    single = Scheduler(cfg, params, **kw)
+    tp = Scheduler(cfg, params, mesh=mesh, **kw)
+    shards = _shard_summary(
+        {"params": tp.params["layers"], "kv": tp._cache}
+    )
+    whole = [k for k, v in shards.items() if v["devices"] != 4]
+    split = {
+        k: v for k, v in shards.items() if v["shard"] != v["shape"]
+    }
+    for name in ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"):
+        if not any(name in k for k in split):
+            raise SmokeFailure(f"{name} is not split across the mesh: {shards}")
+    if whole or not all("kv" not in k or k in split for k in shards):
+        raise SmokeFailure(f"leaves not on all four chips or KV whole: {shards}")
+    streams = {}
+    for label, sched in (("single", single), ("tp4", tp)):
+        sched.start()
+        try:
+            streams[label] = _greedy(sched, prompts, new_tokens, 900.0)
+        finally:
+            sched.stop()
+
+    # Logits of one forward pass, sharded against whole: the stated
+    # tolerance for bf16 sums taken in a different order.
+    toks = jnp.asarray(np.array([prompts[0][:16]], np.int32))
+    pos = jnp.arange(16, dtype=jnp.int32)[None]
+
+    def logits(p, m):
+        hidden, _ = llama.forward(p, cfg, toks, pos, mesh=m)
+        return llama.logits(p, hidden).astype(jnp.float32)
+
+    lg_one = np.asarray(jax.jit(lambda p: logits(p, None))(single.params))
+    lg_tp = np.asarray(jax.jit(lambda p: logits(p, mesh))(tp.params))
+    err = float(np.abs(lg_one - lg_tp).max())
+    scale = float(np.abs(lg_one).max())
+    tol = 0.05
+    same_tokens = streams["single"] == streams["tp4"]
+    if not same_tokens and err > tol * scale:
+        raise SmokeFailure(
+            f"tensor=4 differs from one device: tokens differ and max "
+            f"|dlogit| {err:.4f} > {tol} x {scale:.4f}"
+        )
+    report = runtime_report()
+    emit(
+        {
+            "phase": "four.tensor_parallel",
+            "config": f"{sizes.optin_model} widths, bf16, "
+            f"{cfg.n_layers} layers, MeshSpec(tensor=4)",
+            "same_greedy_tokens": same_tokens,
+            "first_tokens_equal": [
+                a[0] == b[0]
+                for a, b in zip(streams["single"], streams["tp4"])
+            ],
+            "logits_max_abs_diff": round(err, 5),
+            "logits_max_abs": round(scale, 4),
+            "tolerance": f"{tol} x max|logit|",
+            "sharded_leaves": {
+                k: f"{v['shape']} -> {v['shard']} x{v['devices']}"
+                for k, v in split.items()
+            },
+            "seconds": round(time.monotonic() - t0, 1),
+            "device": report["device"],
+        }
+    )
+    del single, tp, params
+
+    # (b) Four one-device replicas behind the router, int8 + both opt-in
+    # kernels' host path (W8A8) and the default decode kernel.
+    t0 = time.monotonic()
+    cfg8 = dc.replace(cfg, kv_dtype="int8")
+    rkw = dict(kw, quantize=True, matmul_kernel="pallas_w8a8")
+    # Each replica first answers ``probe`` alone (so the paths it takes
+    # can be read off), then the router spreads ``prompts``: no replica
+    # sees a prompt twice, which would be served from its prefix cache
+    # (int8 KV read back, not the cold path's numerics).
+    (probe,) = _prompts(seed + 1, cfg.vocab_size, [29])
+    reference_sched = Scheduler(cfg8, None, **rkw)
+    reference_sched.start()
+    try:
+        reference = _greedy(reference_sched, prompts, new_tokens, 900.0)
+        (probe_ref,) = _greedy(reference_sched, [probe], new_tokens, 900.0)
+    finally:
+        reference_sched.stop()
+    del reference_sched
+    meshes = [
+        make_mesh(MeshSpec(data=1, tensor=1), devices=sl)
+        for sl in replica_device_slices(4)
+    ]
+    replicas = [Scheduler(cfg8, None, mesh=m, **rkw) for m in meshes]
+    placement = []
+    for i, (rep, m) in enumerate(zip(replicas, meshes)):
+        want = set(m.devices.flat)
+        leaves = jax.tree.leaves((rep.params, rep._cache))
+        if any(leaf.devices() != want for leaf in leaves):
+            raise SmokeFailure(
+                f"replica {i}: params or cache not on its own device {want}"
+            )
+        placement.append(str(next(iter(want))))
+    pool = EnginePool(replicas, policy="round_robin")
+    pool.start()
+    try:
+        per_replica = []
+        for i, rep in enumerate(replicas):
+            TAKEN.clear()
+            (got,) = _greedy(rep, [probe], new_tokens, 900.0)
+            paths = _taken("")
+            if got != probe_ref:
+                raise SmokeFailure(
+                    f"replica {i} stream differs from the single "
+                    f"scheduler: {got} vs {probe_ref}"
+                )
+            decode = {
+                k: v for k, v in paths.items()
+                if k.startswith("decode_attention")
+                or (k.startswith("q_matmul") and k.endswith("m=32"))
+            }
+            _require_pallas(f"replica {i}'s kernels", decode, on_tpu)
+            per_replica.append(decode)
+        routed = _greedy(pool, prompts, new_tokens, 900.0)
+        if routed != reference:
+            raise SmokeFailure(
+                f"routed streams differ from the single scheduler: "
+                f"{routed} vs {reference}"
+            )
+        served = [
+            int(s.get("requests_total", 0))
+            for s in (r.stats.snapshot() for r in replicas)
+        ]
+    finally:
+        pool.stop()
+    report = runtime_report()
+    emit(
+        {
+            "phase": "four.replicas",
+            "config": f"{sizes.optin_model} widths, int8 weights + int8 KV, "
+            f"{cfg.n_layers} layers, 4 one-device replicas, round_robin",
+            "replica_devices": placement,
+            "replica_requests": served,
+            "same_greedy_tokens_as_single": True,
+            "kernel_paths_per_replica": per_replica,
+            "seconds": round(time.monotonic() - t0, 1),
+            "device": report["device"],
+        }
+    )
+
+
+# --------------------------------------------------------------------------
+# Entry.
+# --------------------------------------------------------------------------
+
+CHILDREN = {
+    "device": child_device,
+    "retrieval": child_retrieval,
+    "optin": child_optin,
+    "four": child_four,
+}
+
+
+def run(
+    seed: int,
+    sizes: Sizes = FULL,
+    expect: str = "tpu",
+    four_chips: bool = False,
+) -> dict:
+    """Run the phases; returns the device for the last line.  ``sizes``
+    and ``expect`` exist for the CPU rehearsal in the tests — the command
+    line always runs FULL on a TPU."""
+    if not os.path.isdir(os.path.join(ROOT, PACKAGE)):
+        raise SmokeFailure(
+            f"{ROOT} holds no {PACKAGE}/: chip_smoke.py runs from the "
+            "root of a checkout"
+        )
+    if four_chips:
+        return child_phase("four", seed, sizes, expect, 3000, count=4)
+    device = phase_device(seed, sizes, expect)
+    phase_serve(seed, sizes, expect)
+    child_phase("retrieval", seed, sizes, expect, 900)
+    child_phase("optin", seed, sizes, expect, 1200)
+    return device
+
+
+def main(argv=None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n")[0])
+    parser.add_argument("--seed", type=int, default=0)
+    parser.add_argument(
+        "--four-chips",
+        action="store_true",
+        help="run only the tensor-parallel mesh and the replica pool, "
+        "in one process that holds four chips",
+    )
+    parser.add_argument("--child", choices=sorted(CHILDREN), help=argparse.SUPPRESS)
+    parser.add_argument("--sizes", help=argparse.SUPPRESS)
+    args = parser.parse_args(argv)
+    if args.child:
+        sizes = Sizes(**json.loads(args.sizes)) if args.sizes else FULL
+        try:
+            CHILDREN[args.child](args.seed, sizes)
+        except SmokeFailure as e:
+            print(f"chip_smoke {args.child}: {e}", file=sys.stderr)
+            return 1
+        return 0
+    try:
+        device = run(args.seed, four_chips=args.four_chips)
+    except SmokeFailure as e:
+        print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
+        return 1
+    emit({"ok": True, "device": device})
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

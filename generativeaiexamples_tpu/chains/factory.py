@@ -30,9 +30,13 @@ def get_chat_llm():
     if engine == "openai":
         from generativeaiexamples_tpu.chains.llm import OpenAIChatLLM
 
-        base = cfg.llm.server_url or "http://localhost:8000/v1"
+        # Same normalisation as the embeddings client: the compose
+        # files give the engine's root (http://engine:8000).
+        base = (cfg.llm.server_url or "http://localhost:8000").rstrip("/")
         if not base.startswith("http"):
-            base = f"http://{base}/v1"
+            base = f"http://{base}"
+        if not base.endswith("/v1"):
+            base = f"{base}/v1"
         return OpenAIChatLLM(base_url=base, model=cfg.llm.model_name)
     if engine == "tpu":
         from generativeaiexamples_tpu.chains.llm import TPUChatLLM

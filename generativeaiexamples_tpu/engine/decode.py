@@ -38,8 +38,11 @@ def prepare_params(
     quantize: bool = False,
     pack: bool = False,
     matmul_kernel: Optional[str] = None,
+    seed: int = 0,
 ):
     """Init (if needed), mesh-shard, and optionally quantize/pack params.
+
+    ``params=None`` means random weights from ``seed`` (no checkpoint).
 
     ``quantize`` converts every projection to weight-only int8
     (``ops.quant``) — halves decode HBM traffic and fits full-depth
@@ -53,8 +56,11 @@ def prepare_params(
     layout; ``"pallas_w8a8"`` pre-blocks the int8 projections ONCE here
     into the ``(NB, K, BN)`` tile layout the streaming W8A8 Pallas kernel
     DMAs from HBM (``ops.qmm``).  Blocking applies after packing so the
-    fused wqkv / w_gu leaves stream as single kernel calls, and only for
-    single-chip serving (the blocked layout is not mesh-sharded).
+    fused wqkv / w_gu leaves stream as single kernel calls, and only
+    where one device holds the params (no mesh, or a one-device mesh
+    such as a replica's slice): the blocked layout is not mesh-sharded.
+    Asking for it where it cannot apply — float projections, a
+    multi-device mesh — is an error, not a silent stay on XLA.
     """
     if matmul_kernel not in (None, "xla", "pallas_w8a8"):
         raise ValueError(
@@ -67,10 +73,10 @@ def prepare_params(
             # first (16 GB for llama3-8b) would not fit HBM alongside the
             # quantized copy.
             logger.info("initializing random int8 llama params (%s)", cfg)
-            params = init_random_int8_params(cfg, jax.random.PRNGKey(0))
+            params = init_random_int8_params(cfg, jax.random.PRNGKey(seed))
         else:
             logger.info("initializing random llama params (%s)", cfg)
-            params = llama.init_params(cfg, jax.random.PRNGKey(0))
+            params = llama.init_params(cfg, jax.random.PRNGKey(seed))
     elif quantize:
         from generativeaiexamples_tpu.ops.quant import quantize_llama_params
 
@@ -106,12 +112,27 @@ def prepare_params(
         params = shard_pytree(params, specs, mesh)
     if pack and (mesh is None or mesh.shape.get("tensor", 1) == 1):
         params = llama.pack_for_serving(params)
-    if matmul_kernel == "pallas_w8a8" and mesh is None:
+    if matmul_kernel == "pallas_w8a8":
         from generativeaiexamples_tpu.engine.weights import (
             preblock_llama_params,
         )
+        from generativeaiexamples_tpu.ops.dispatch import one_device
+        from generativeaiexamples_tpu.ops.qmm import BlockedQuantizedMatrix
 
+        if not one_device(mesh):
+            raise ValueError(
+                "matmul_kernel='pallas_w8a8' needs the params on one "
+                f"device; the mesh has {mesh.size}"
+            )
         params = preblock_llama_params(params)
+        if not any(
+            isinstance(leaf, BlockedQuantizedMatrix)
+            for leaf in params["layers"].values()
+        ):
+            raise ValueError(
+                "matmul_kernel='pallas_w8a8' needs int8 projections: pass "
+                "quantize=True or pre-quantized params"
+            )
     return params
 
 
@@ -291,16 +312,8 @@ def pin_default_layout(cache):
     silently fails and the multi-GB cache is double-buffered — measured as
     the difference between llama3-8b 2k-context batch 96 fitting a 16 GB
     chip or OOM.  Single-device only (with a mesh, layouts ride sharding).
-
-    Layout pinning is a TPU HBM/donation optimization, not a semantics
-    change: on JAX versions without ``with_layout_constraint`` (it landed
-    after 0.4.37) the cache is returned unpinned — correct everywhere,
-    and only TPU donation efficiency is at stake.
     """
-    try:
-        from jax.experimental.layout import Layout, with_layout_constraint
-    except ImportError:
-        return cache
+    from jax.experimental.layout import Layout, with_layout_constraint
 
     return tuple(
         with_layout_constraint(
@@ -317,9 +330,8 @@ def make_decode_chunk_fn(cfg: llama.LlamaConfig, mesh, max_len: int):
     top_k, n_steps, kv_bucket=None)`` with the cache donated and
     ``n_steps``/``kv_bucket`` static (bucketed by callers).  Returns
     ``(cache, toks)`` with toks shaped (n_steps, batch).  One host
-    round-trip per chunk instead of per token — on remote/tunneled TPU
-    backends a device→host sync costs orders of magnitude more than a
-    decode step.  ``kv_bucket`` caps the cache prefix attention reads
+    round-trip per chunk instead of per token: a device→host sync costs
+    more than a decode step.  ``kv_bucket`` caps the cache prefix attention reads
     (callers pass a power-of-two ≥ every position the chunk will write),
     so per-step KV traffic follows the live length, not max_len.
 

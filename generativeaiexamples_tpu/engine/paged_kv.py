@@ -138,7 +138,10 @@ class PagedKVPool:
         self.total_pages = max(int(total_pages or 0), floor)
 
         kv_heads = cfg.n_kv_heads if cfg.n_kv_heads else cfg.n_heads
-        p = self.total_pages * self.page_tokens
+        # The token axis pads to whole 128-lane tiles: the paged kernel
+        # copies scales a tile at a time, so the last page's tile must
+        # exist.  Padding slots belong to no page and are never mapped.
+        p = -(-self.total_pages * self.page_tokens // 128) * 128
         self.leaves = (
             jnp.zeros(
                 (cfg.n_layers, kv_heads, p, cfg.head_dim), jnp.int8

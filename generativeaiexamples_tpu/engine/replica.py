@@ -118,6 +118,9 @@ class Replica:
         self.low_since: Optional[float] = None
         self.ok_since: Optional[float] = None
         self.probation_since: Optional[float] = None
+        # (ttft_sum, ttft_count) at the last TSDB feed: the scorer's
+        # TTFT series carries what was served since, not a lifetime mean.
+        self.ttft_seen: tuple[float, int] = (0.0, 0)
 
     def started(self) -> bool:
         return self.scheduler._thread is not None
@@ -340,18 +343,18 @@ class EnginePool:
             # Detached replicas are excluded: their series were dropped
             # at detach time and must not resurrect.
             states = [
-                (r.idx, r.state, r.score, r.scheduler)
+                (r.idx, r.state, r.score, r.scheduler, r)
                 for r in self.replicas
                 if r.state != DETACHED
             ]
             size = sum(
-                1 for _, state, _, _ in states
+                1 for _, state, *_ in states
                 if state in (HEALTHY, PROBATION)
             )
             desired = self.desired_replicas
         db.record("engine.pool_size", size)
         db.record("engine.pool_desired", desired)
-        for idx, state, score, scheduler in states:
+        for idx, state, score, scheduler, replica in states:
             healthy = 1.0 if state in (HEALTHY, PROBATION) else 0.0
             db.record(f"engine.replica.{idx}.healthy", healthy)
             db.record(f"engine.replica.{idx}.score", score)
@@ -375,11 +378,20 @@ class EnginePool:
                 getattr(stats, "tick_ms_norm_ewma", 0.0)
                 or stats.tick_ms_ewma,
             )
-            if ttft_count:
+            # TTFT of the requests served since the last pass.  A
+            # lifetime mean never forgets: a replica ejected after a
+            # brownout serves nothing, so its mean would stay high
+            # against peers whose means keep falling, and the scorer's
+            # window could never let it back in.
+            seen_sum, seen_count = replica.ttft_seen
+            if ttft_count > seen_count:
                 db.record(
                     f"engine.replica.{idx}.ttft_ms",
-                    ttft_sum / ttft_count * 1000.0,
+                    (ttft_sum - seen_sum)
+                    / (ttft_count - seen_count)
+                    * 1000.0,
                 )
+            replica.ttft_seen = (ttft_sum, ttft_count)
 
     # -- request surface (Scheduler-compatible) ---------------------------
 

@@ -656,6 +656,9 @@ async def handle_health(request: web.Request) -> web.Response:
     states_fn = getattr(engine, "replica_states", None)
     if callable(states_fn):
         body["replicas"] = states_fn()
+    from generativeaiexamples_tpu.utils.jax_runtime import runtime_report
+
+    body["runtime"] = runtime_report()
     return web.json_response(body, status=200 if ok else 503)
 
 
@@ -1129,6 +1132,29 @@ def main() -> None:
         "the $GAIE_WEIGHTS_DIR lookup for --draft-model)",
     )
     parser.add_argument(
+        "--seed",
+        type=int,
+        default=0,
+        help="seed of the random weights served when no checkpoint is "
+        "provisioned, and of the sampler",
+    )
+    parser.add_argument(
+        "--weight-dtype",
+        default="bfloat16",
+        choices=["bfloat16", "int8"],
+        help="projection weights: 'int8' quantizes them per output "
+        "channel at load and packs qkv and gate/up (halves decode HBM "
+        "traffic; what fits full-depth llama3-8b on one 16 GB chip).",
+    )
+    parser.add_argument(
+        "--kv-dtype",
+        default="",
+        choices=["", "bfloat16", "int8"],
+        help="KV cache storage: 'int8' (per-token-per-head scales) "
+        "halves cache HBM and is what the Pallas decode-attention "
+        "kernel reads. Empty keeps the model preset's (bfloat16).",
+    )
+    parser.add_argument(
         "--matmul-kernel",
         default=os.environ.get("GAIE_MATMUL_KERNEL", ""),
         choices=["", "xla", "pallas_w8a8"],
@@ -1213,14 +1239,16 @@ def main() -> None:
             "random-initialized weights",
             args.model,
         )
-    import jax
+    from generativeaiexamples_tpu.utils.jax_runtime import (
+        enable_compile_cache,
+        require_accelerator,
+    )
 
-    # Some images pin a TPU plugin platform at import time; honor an
-    # explicit JAX_PLATFORMS env override (e.g. cpu smoke tests) anyway.
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
-    n_devices = len(jax.devices())
-    platform = jax.devices()[0].platform
+    device = require_accelerator("engine server")
+    n_devices, platform = device["count"], device["platform"]
+    logger.info(
+        "devices: %s; compile cache: %s", device, enable_compile_cache()
+    )
     from generativeaiexamples_tpu.core.configuration import get_config
 
     # Config-file fallbacks ([llm] section) for deployments that prefer
@@ -1246,6 +1274,8 @@ def main() -> None:
     kv_page_size = args.kv_page_size or int(
         getattr(llm_cfg, "kv_page_size", 0) or 64
     )
+    if args.kv_dtype:
+        cfg = dataclasses.replace(cfg, kv_dtype=args.kv_dtype)
     if kv_layout == "paged" and cfg.kv_dtype != "int8":
         # The paged pool stores int8 pages + per-page scales; model
         # presets default to bf16 KV, so selecting paged implies int8.
@@ -1290,12 +1320,14 @@ def main() -> None:
             mesh=mesh,
             max_batch=args.max_batch,
             max_len=args.max_len,
+            seed=args.seed,
             draft_cfg=draft_cfg,
             draft_params=draft_params,
             gamma=gamma,
             spec_mode="ngram" if spec_ngram else None,
             prefix_cache=args.prefix_cache,
             prefill_chunk_tokens=args.prefill_chunk_tokens or None,
+            quantize=args.weight_dtype == "int8",
             matmul_kernel=matmul_kernel,
             kv_layout=kv_layout,
             kv_page_size=kv_page_size,

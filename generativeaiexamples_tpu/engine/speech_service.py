@@ -357,10 +357,13 @@ def main() -> None:
     parser.add_argument("-v", "--verbose", action="count", default=None)
     args = parser.parse_args()
     configure_logging(args.verbose)
-    import jax
+    from generativeaiexamples_tpu.utils.jax_runtime import (
+        enable_compile_cache,
+        require_accelerator,
+    )
 
-    if os.environ.get("JAX_PLATFORMS"):
-        jax.config.update("jax_platforms", os.environ["JAX_PLATFORMS"])
+    require_accelerator("speech service")
+    enable_compile_cache()
     engine = (
         SpeechEngine(speech.asr_tiny(), speech.tts_tiny(), w2v2_dir=args.w2v2_dir)
         if args.tiny

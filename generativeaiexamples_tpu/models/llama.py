@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from generativeaiexamples_tpu.ops.attention import attention
+from generativeaiexamples_tpu.ops.dispatch import record
 from generativeaiexamples_tpu.ops.quant import q_dot
 from generativeaiexamples_tpu.ops.rope import apply_rope
 from generativeaiexamples_tpu.parallel.mesh import logical_to_partition
@@ -930,11 +931,15 @@ def forward(
                 n_q=n_q, n_kv=n_kv, head_dim=hd,
                 append_width=append_cache[0][0].shape[3], mesh=mesh,
             )
+            _site = f"paged_decode_attention b={b} page={page_tokens}"
         else:
             _append_kernel = s == 1 and use_decode_kernel(
                 s=s, kv_int8=kv_int8, batch=b, window=window,
                 n_q=n_q, n_kv=n_kv, head_dim=hd, mesh=mesh,
             )
+            _site = f"decode_attention b={b} w={window}"
+        if s == 1:
+            record(_site, _append_kernel)
         ab_in, append_step = append_cache
         if s > 1 and ab_in[0].shape[3] != s:
             raise ValueError(

@@ -19,6 +19,8 @@ from typing import Optional
 
 import jax.numpy as jnp
 
+from generativeaiexamples_tpu.ops.dispatch import record
+
 _NEG_INF = -1e30
 
 
@@ -137,6 +139,9 @@ def attention(
         return sequence_parallel_attention(
             q, k, v, q_positions, kv_lengths, mesh=mesh, strategy="ring"
         )
-    if fa.use_flash(q.shape[1], q.shape[3], mesh=mesh):
+    if record(
+        f"prefill_attention b={q.shape[0]} s={q.shape[1]} t={k.shape[1]}",
+        fa.use_flash(q.shape[1], q.shape[3], mesh=mesh),
+    ):
         return fa.flash_gqa_attention(q, k, v, q_positions, kv_lengths)
     return gqa_attention(q, k, v, q_positions, kv_lengths)

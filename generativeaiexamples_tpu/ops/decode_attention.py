@@ -36,15 +36,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x exposes the TPU compiler-params struct as TPUCompilerParams;
-# newer releases renamed it to CompilerParams.  Resolve whichever exists.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
-
-# The paged kernel shares the W8A8 streaming kernel's VMEM ceiling (and
-# compat shims): both manually double-buffer HBM-resident operands, so
-# one budget constant keeps the accounting honest across kernels.
+# The paged kernel shares the W8A8 streaming kernel's VMEM ceiling: both
+# manually double-buffer HBM-resident operands, so one budget constant
+# keeps the accounting honest across kernels.
+from generativeaiexamples_tpu.ops.dispatch import one_device, platform_of
 from generativeaiexamples_tpu.ops.qmm import _VMEM_BUDGET_BYTES
 
 _NEG_INF = -1e30
@@ -255,25 +250,19 @@ def use_decode_kernel(
     if s != 1 or not kv_int8:
         return False
     if not _interpret_mode():
-        backend = backend or jax.default_backend()
-        if backend != "tpu":
-            return False
-        if mesh is not None:
-            if mesh.size > 1:
-                return False
-        elif jax.device_count() > 1:
+        if (backend or platform_of(mesh)) != "tpu" or not one_device(mesh):
             return False
     return (
         batch % 16 == 0
         # Exact-tiling gate, mirroring the wrapper's tile pick: a window
-        # at or under one tile runs as a single window-deep tile — legal
-        # whenever the int8 sublane quantum (32) divides it — and larger
-        # windows must split into whole 256- or 128-deep tiles (the
-        # dense 3*2^k buckets 384, 768, ... tile at 128).  The former
-        # ``window % 128 == 0`` test silently dropped the small pow2
-        # buckets 32 and 64 — reachable from any short-context decode —
-        # to the scatter path; tests/test_paged_kv.py pins the gate
-        # against the wrapper for every reachable bucket.
+        # at or under one tile runs as a single tile (the wrapper widens
+        # the small pow2 buckets 32 and 64 — reachable from any
+        # short-context decode — to a whole 128-lane tile or the cache's
+        # length), and larger windows must split into whole 256- or
+        # 128-deep tiles (the dense 3*2^k buckets 384, 768, ... tile at
+        # 128).  tests/test_paged_kv.py pins the gate against the wrapper
+        # for every reachable bucket; tests/test_chip_compile.py asks the
+        # v5e compiler about a 64-slot window of a 256-slot cache.
         and (
             (window <= BLOCK_T and window % 32 == 0)
             or window % 128 == 0
@@ -323,12 +312,7 @@ def use_append_buffer(
         return False
     if os.environ.get("GAIE_FORCE_APPEND_BUFFER"):
         return True
-    backend = backend or jax.default_backend()
-    if backend != "tpu":
-        return False
-    if mesh is not None:
-        return mesh.size == 1
-    return jax.device_count() == 1
+    return (backend or platform_of(mesh)) == "tpu" and one_device(mesh)
 
 
 def _slice_layer_window(buf, li, w):
@@ -557,8 +541,10 @@ def paged_decode_gqa_attention_xla(
     are flat (L, KH, P, HD) int8 pool values (P = total_pages *
     page_tokens) with (L, KH, P) scales, and ``page_table`` (B,
     n_slot_pages) int32 maps each row's logical pages to pool pages.
-    The reference/fallback for :func:`paged_decode_gqa_attention` —
-    bit-identical to it AND to the contiguous twin on matching content.
+    The reference/fallback for :func:`paged_decode_gqa_attention`:
+    bit-identical to the contiguous twin on matching content, and equal
+    to the kernel to float tolerance (the kernel normalizes its online
+    softmax in page order, this twin once over the gathered window).
     """
     if append is not None:
         k_ab, v_ab, ks_ab, vs_ab, count = append
@@ -727,6 +713,13 @@ def decode_gqa_attention(
     b, n_q, hd = q.shape
     n_kv = k8.shape[1]
     g = n_q // n_kv
+    # A KV block's slot dim must be whole 128-lane tiles or the cache's
+    # whole length (the bf16 scale blocks carry it minor-most), so a
+    # narrower window widens to the next tile: the extra slots lie
+    # beyond every row's kv_length and mask to exact zeros.
+    cache_len = k8.shape[3]
+    if window % 128 and window < cache_len:
+        window = min(-(-window // 128) * 128, cache_len)
     if window <= BLOCK_T:
         bt = window
     elif window % BLOCK_T == 0:
@@ -811,7 +804,7 @@ def decode_gqa_attention(
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, n_kv, g, hd), q.dtype),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -826,14 +819,17 @@ def _paged_interpret_mode() -> bool:
     return bool(os.environ.get("GAIE_PAGED_KERNEL_INTERPRET"))
 
 
-def _paged_kernel_vmem_bytes(page_tokens: int, g: int, hd: int, c: int) -> int:
-    """VMEM the paged kernel holds live per program: the double-buffered
-    page (int8 k/v + bf16 scales), the query/output blocks, the append
-    blocks, and the online-softmax scratch."""
-    return (
+def _paged_kernel_vmem_bytes(
+    page_tokens: int, n_kv: int, g: int, hd: int, c: int
+) -> int:
+    """VMEM the paged kernel holds live per program (one batch row, all
+    ``n_kv`` heads): the double-buffered page (int8 k/v + bf16 scale
+    tiles), the query/output blocks, the append blocks, and the
+    online-softmax scratch."""
+    return n_kv * (
         2 * 2 * page_tokens * hd  # k/v page double buffers (int8)
-        + 2 * 2 * page_tokens * 2  # k/v scale double buffers (bf16)
-        + 2 * g * hd * 2  # q block + output block (<=bf16... f32 worst)
+        + 2 * 2 * max(page_tokens, 128) * 2  # k/v scale tiles (bf16)
+        + 2 * g * hd * 4  # q block + output block (f32 worst case)
         + c * (2 * hd + 4)  # append block values (int8 x2) + scales
         + (2 * 128 + hd) * g * 4  # m/l/acc f32 scratch
     )
@@ -881,18 +877,15 @@ def use_paged_kernel(
     ):
         return False
     if (
-        _paged_kernel_vmem_bytes(page_tokens, g, head_dim, append_width)
+        _paged_kernel_vmem_bytes(
+            page_tokens, n_kv, g, head_dim, append_width
+        )
         > _VMEM_BUDGET_BYTES
     ):
         return False
     if _paged_interpret_mode():
         return True
-    backend = backend or jax.default_backend()
-    if backend != "tpu":
-        return False
-    if mesh is not None:
-        return mesh.size == 1
-    return jax.device_count() == 1
+    return (backend or platform_of(mesh)) == "tpu" and one_device(mesh)
 
 
 def _paged_decode_kernel(
@@ -900,29 +893,33 @@ def _paged_decode_kernel(
     abn_ref,  # scalar prefetch: (1,) int32 valid append-buffer slots
     tab_ref,  # scalar prefetch: (B, n_slot_pages) int32 page table
     len_ref,  # scalar prefetch: (B,) int32 valid kv prefix per row
-    q_ref,  # (1, 1, G, HD)
+    q_ref,  # (1, KH, G, HD)
     k_hbm,  # (L, KH, P, HD) int8 — stays in HBM (pl.ANY)
     v_hbm,  # (L, KH, P, HD) int8 — stays in HBM
     ks_hbm,  # (L, KH, P) bf16 — stays in HBM
     vs_hbm,  # (L, KH, P) bf16 — stays in HBM
-    # with has_ab: kab, vab (1, 1, 1, C, HD) int8; ksab, vsab
-    # (1, 1, 1, C) bf16 — the decode chunk's append buffer (VMEM).
+    # with has_ab: kab, vab (1, KH, 1, C, HD) int8; ksab, vsab
+    # (1, KH, 1, 1, C) bf16 — the decode chunk's append buffer (VMEM).
     *rest,
     page_tokens: int,
     scale: float,
     has_ab: bool,
 ):
-    """Page-table-walking decode attention for one (row, kv-head) lane.
+    """Page-table-walking decode attention for one batch row.
 
-    Each program owns one batch row × one KV head: it reads the row's
-    valid length, walks ``ceil(len / page_tokens)`` page-table entries,
-    and ``make_async_copy``-streams each page's int8 k/v (+ bf16 scales)
-    out of the HBM-resident pool into a ping-pong VMEM buffer — page
-    ``i+1`` prefetches while page ``i`` runs the online-softmax update.
-    No window slice, no pow2 padding: a ragged batch reads exactly the
-    pages it owns.  The trailing partial page masks to the row length,
-    and the append buffer folds after the page walk — the same
-    ``_online_update`` math as the contiguous kernel.
+    Each program owns one batch row across ALL its KV heads: it reads
+    the row's valid length, walks ``ceil(len / page_tokens)`` page-table
+    entries, and ``make_async_copy``-streams each page's int8 k/v
+    (+ bf16 scales) for every head out of the HBM-resident pool into a
+    ping-pong VMEM buffer — page ``i+1`` prefetches while page ``i``
+    runs the online-softmax update with the heads as its batch dim.
+    Taking every head per copy is what keeps the scale DMA legal: the
+    (L, KH, P) scale planes tile their (KH, P) dims, so a one-head
+    slice is not a whole tile and Mosaic refuses it.  No window slice,
+    no pow2 padding: a ragged batch reads exactly the pages it owns.
+    The trailing partial page masks to the row length, and the append
+    buffer folds after the page walk — the same ``_online_update`` math
+    as the contiguous kernel.
     """
     if has_ab:
         kab_ref, vab_ref, ksab_ref, vsab_ref = rest[:4]
@@ -930,8 +927,7 @@ def _paged_decode_kernel(
     o_ref = rest[0]
     kbuf, vbuf, ksbuf, vsbuf, sem, m_ref, l_ref, acc_ref = rest[1:]
     bi = pl.program_id(0)
-    hi = pl.program_id(1)
-    g = q_ref.shape[2]
+    kh, g = q_ref.shape[1], q_ref.shape[2]
     pt = page_tokens
     li = li_ref[0]
     length = len_ref[bi]
@@ -941,37 +937,46 @@ def _paged_decode_kernel(
     l_ref[:] = jnp.zeros_like(l_ref)
     acc_ref[:] = jnp.zeros_like(acc_ref)
 
+    # Scale copies take whole 128-lane tiles (the HBM planes tile their
+    # token axis by 128): a page narrower than that rides in with its
+    # tile-mates and ``page_scales`` selects its lanes afterwards.
+    lanes = ksbuf.shape[2]
+    sub = lanes // pt
+
     def page_dma(slot, p):
-        base = tab_ref[bi, p] * pt
-        return (
+        page = tab_ref[bi, p]
+        base = pl.multiple_of(page * pt, pt)
+        sbase = pl.multiple_of(page // sub * lanes, lanes)
+        return tuple(
             pltpu.make_async_copy(
-                k_hbm.at[li, hi, pl.ds(base, pt)],
-                kbuf.at[slot],
-                sem.at[slot, 0],
-            ),
-            pltpu.make_async_copy(
-                v_hbm.at[li, hi, pl.ds(base, pt)],
-                vbuf.at[slot],
-                sem.at[slot, 1],
-            ),
-            pltpu.make_async_copy(
-                ks_hbm.at[li, hi, pl.ds(base, pt)],
-                ksbuf.at[slot],
-                sem.at[slot, 2],
-            ),
-            pltpu.make_async_copy(
-                vs_hbm.at[li, hi, pl.ds(base, pt)],
-                vsbuf.at[slot],
-                sem.at[slot, 3],
-            ),
+                hbm.at[li, :, pl.ds(start, size)],
+                buf.at[slot],
+                sem.at[slot, j],
+            )
+            for j, (hbm, buf, start, size) in enumerate(
+                (
+                    (k_hbm, kbuf, base, pt),
+                    (v_hbm, vbuf, base, pt),
+                    (ks_hbm, ksbuf, sbase, lanes),
+                    (vs_hbm, vsbuf, sbase, lanes),
+                )
+            )
         )
+
+    def page_scales(buf, slot, p):
+        tile = buf[slot].astype(jnp.float32)  # (KH, lanes)
+        out = tile[:, :pt]
+        which = tab_ref[bi, p] % sub
+        for j in range(1, sub):
+            out = jnp.where(which == j, tile[:, j * pt : (j + 1) * pt], out)
+        return out
 
     @pl.when(n_pages > 0)
     def _first():
         for cp in page_dma(0, 0):
             cp.start()
 
-    q = q_ref[0, 0][None]  # (1, G, HD)
+    q = q_ref[0]  # (KH, G, HD)
 
     def body(i, _):
         slot = i % 2
@@ -984,15 +989,15 @@ def _paged_decode_kernel(
         for cp in page_dma(slot, i):
             cp.wait()
         t_idx = (
-            jax.lax.broadcasted_iota(jnp.int32, (1, g, pt), 2) + i * pt
+            jax.lax.broadcasted_iota(jnp.int32, (kh, g, pt), 2) + i * pt
         )
         mask = t_idx < length
         _online_update(
             q,
-            kbuf[slot][None],
-            vbuf[slot][None],
-            ksbuf[slot][None].astype(jnp.float32),
-            vsbuf[slot][None].astype(jnp.float32),
+            kbuf[slot],
+            vbuf[slot],
+            page_scales(ksbuf, slot, i),
+            page_scales(vsbuf, slot, i),
             mask,
             m_ref,
             l_ref,
@@ -1005,14 +1010,14 @@ def _paged_decode_kernel(
 
     if has_ab:
         c = kab_ref.shape[3]
-        j_idx = jax.lax.broadcasted_iota(jnp.int32, (1, g, c), 2)
+        j_idx = jax.lax.broadcasted_iota(jnp.int32, (kh, g, c), 2)
         ab_mask = j_idx < abn_ref[0]
         _online_update(
             q,
-            kab_ref[0, 0],
-            vab_ref[0, 0],
-            ksab_ref[0, 0].astype(jnp.float32),
-            vsab_ref[0, 0].astype(jnp.float32),
+            kab_ref[0, :, 0],
+            vab_ref[0, :, 0],
+            ksab_ref[0, :, 0, 0].astype(jnp.float32),
+            vsab_ref[0, :, 0, 0].astype(jnp.float32),
             ab_mask,
             m_ref,
             l_ref,
@@ -1021,7 +1026,7 @@ def _paged_decode_kernel(
         )
 
     denom = jnp.maximum(l_ref[:, :1], 1e-30)
-    o_ref[0, 0] = (acc_ref[:] / denom).astype(o_ref.dtype)
+    o_ref[0] = (acc_ref[:] / denom).reshape(kh, g, -1).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -1059,9 +1064,11 @@ def paged_decode_gqa_attention(
       page_tokens: static tokens per page (multiple of 128 on TPU).
 
     Returns:
-      (B, n_q_heads, HD) in q's dtype — bit-identical to
-      :func:`paged_decode_gqa_attention_xla` (the gate
-      tests/test_paged_kv.py enforces in interpret mode).
+      (B, n_q_heads, HD) in q's dtype — equal to
+      :func:`paged_decode_gqa_attention_xla` to float tolerance (the
+      gate tests/test_paged_kv.py holds in interpret mode and
+      ``chip_smoke.py`` on the chip), not bitwise: the online softmax
+      normalizes in page order.
     """
     if interpret is None:
         interpret = _paged_interpret_mode()
@@ -1069,41 +1076,44 @@ def paged_decode_gqa_attention(
     n_kv = k8.shape[1]
     g = n_q // n_kv
     has_ab = append is not None
-    grid = (b, n_kv)
+    scale_lanes = max(page_tokens, 128)
+    if k8.shape[2] % scale_lanes:
+        # The last page's scale tile would read past the plane (and
+        # interpret mode would clamp the read onto the wrong lanes).
+        raise ValueError(
+            f"paged pool token axis {k8.shape[2]} must be a multiple of "
+            f"{scale_lanes} (PagedKVPool pads its leaves to it)"
+        )
+
+    def row_map(*tail):
+        return lambda bi, li, abn, tab, lens: (bi,) + tail
+
+    def ab_map(*tail):
+        return lambda bi, li, abn, tab, lens: (li[0], 0, bi) + tail
 
     in_specs = [
-        pl.BlockSpec(
-            (1, 1, g, hd),
-            lambda bi, hi, li, abn, tab, lens: (bi, hi, 0, 0),
-        ),
-        pl.BlockSpec(memory_space=pltpu.ANY),  # k pool stays in HBM
-        pl.BlockSpec(memory_space=pltpu.ANY),  # v pool stays in HBM
-        pl.BlockSpec(memory_space=pltpu.ANY),  # k scales stay in HBM
-        pl.BlockSpec(memory_space=pltpu.ANY),  # v scales stay in HBM
+        pl.BlockSpec((1, n_kv, g, hd), row_map(0, 0, 0)),
+        pl.BlockSpec(memory_space=pl.ANY),  # k pool stays in HBM
+        pl.BlockSpec(memory_space=pl.ANY),  # v pool stays in HBM
+        pl.BlockSpec(memory_space=pl.ANY),  # k scales stay in HBM
+        pl.BlockSpec(memory_space=pl.ANY),  # v scales stay in HBM
     ]
     operands = [q.reshape(b, n_kv, g, hd), k8, v8, ks, vs]
     if has_ab:
         k_ab, v_ab, ks_ab, vs_ab, count = append
         c = k_ab.shape[3]
         in_specs += [
-            pl.BlockSpec(
-                (1, 1, 1, c, hd),
-                lambda bi, hi, li, abn, tab, lens: (li[0], hi, bi, 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, 1, c, hd),
-                lambda bi, hi, li, abn, tab, lens: (li[0], hi, bi, 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, 1, c),
-                lambda bi, hi, li, abn, tab, lens: (li[0], hi, bi, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, 1, c),
-                lambda bi, hi, li, abn, tab, lens: (li[0], hi, bi, 0),
-            ),
+            pl.BlockSpec((1, n_kv, 1, c, hd), ab_map(0, 0)),
+            pl.BlockSpec((1, n_kv, 1, c, hd), ab_map(0, 0)),
+            # Scales ride as (L, KH, B, 1, C): one row's (1, C) block
+            # then equals the array's last two dims, which the TPU
+            # lowering requires of a block that is not (8, 128)-aligned.
+            pl.BlockSpec((1, n_kv, 1, 1, c), ab_map(0, 0)),
+            pl.BlockSpec((1, n_kv, 1, 1, c), ab_map(0, 0)),
         ]
-        operands += [k_ab, v_ab, ks_ab, vs_ab]
+        operands += [
+            k_ab, v_ab, ks_ab[:, :, :, None], vs_ab[:, :, :, None]
+        ]
         abn = jnp.asarray(count, jnp.int32).reshape(1)
     else:
         abn = jnp.zeros((1,), jnp.int32)
@@ -1117,26 +1127,23 @@ def paged_decode_gqa_attention(
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=grid,
+            grid=(b,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (1, 1, g, hd),
-                lambda bi, hi, li, abn, tab, lens: (bi, hi, 0, 0),
-            ),
+            out_specs=pl.BlockSpec((1, n_kv, g, hd), row_map(0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((2, page_tokens, hd), jnp.int8),
-                pltpu.VMEM((2, page_tokens, hd), jnp.int8),
-                pltpu.VMEM((2, page_tokens), ks.dtype),
-                pltpu.VMEM((2, page_tokens), vs.dtype),
+                pltpu.VMEM((2, n_kv, page_tokens, hd), jnp.int8),
+                pltpu.VMEM((2, n_kv, page_tokens, hd), jnp.int8),
+                pltpu.VMEM((2, n_kv, scale_lanes), ks.dtype),
+                pltpu.VMEM((2, n_kv, scale_lanes), vs.dtype),
                 pltpu.SemaphoreType.DMA((2, 4)),
-                pltpu.VMEM((g, 128), jnp.float32),
-                pltpu.VMEM((g, 128), jnp.float32),
-                pltpu.VMEM((g, hd), jnp.float32),
+                pltpu.VMEM((n_kv * g, 128), jnp.float32),
+                pltpu.VMEM((n_kv * g, 128), jnp.float32),
+                pltpu.VMEM((n_kv * g, hd), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, n_kv, g, hd), q.dtype),
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel"),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",),
             vmem_limit_bytes=_VMEM_BUDGET_BYTES,
         ),
         interpret=interpret,

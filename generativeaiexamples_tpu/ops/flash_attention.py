@@ -33,6 +33,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from generativeaiexamples_tpu.ops.dispatch import one_device, platform_of
+
 _NEG_INF = -1e30
 
 
@@ -259,14 +261,8 @@ def use_flash(
     gather/replicate of the KV cache (a shard_map wrapping is the planned
     path to sharded flash).
     """
-    backend = backend or jax.default_backend()
-    if mesh is not None:
-        if mesh.size > 1:
-            return False
-    elif jax.device_count() > 1:
-        # No mesh threaded: fail safe — the caller may be inside a sharded
-        # jit we can't see, where the non-partitionable pallas_call would
-        # force a KV gather/replicate.
+    backend = backend or platform_of(mesh)
+    if not one_device(mesh):
         return False
     # s >= 256: at s == 128 the (batch, heads, 1, 1) grid degenerates to
     # thousands of tiny programs and per-program dispatch overhead dominates

@@ -34,11 +34,11 @@ expression ``(acc.astype(f32) * a_scale) * w_scale``.  Greedy decode
 through the serving scheduler is therefore bit-identical with the
 kernel on or off — the property tests/test_qmm.py gates.
 
-Dispatch mirrors ``ops.decode_attention``: the kernel runs on a single
-TPU chip (or anywhere under ``GAIE_QMM_INTERPRET=1`` for hermetic CPU
-tests), subject to a VMEM budget; everything else — multi-chip meshes,
-prefill-sized row counts, CPU — falls back to the XLA twin, which is
-also the reference implementation.  ``GAIE_DISABLE_QMM_KERNEL=1``
+Dispatch mirrors ``ops.decode_attention``: the kernel runs on a TPU
+device (or anywhere under ``GAIE_QMM_INTERPRET=1`` for hermetic CPU
+tests), subject to a VMEM budget; everything else — prefill-sized row
+counts, CPU — takes the XLA twin, which is also the reference
+implementation.  ``GAIE_DISABLE_QMM_KERNEL=1``
 forces the twin everywhere (A/B harness for bench.py --fused).
 """
 
@@ -53,11 +53,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax renamed TPUCompilerParams -> CompilerParams after 0.4.x; support
-# both so interpret-mode CPU tests and TPU builds run on either.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
+from generativeaiexamples_tpu.ops.dispatch import record
 
 # Column-block width of a weight tile.  256 keeps the double buffer at
 # 2*K*BN = 2 MB for K=4096 while each DMA stays a single dense ~1 MB
@@ -65,11 +61,13 @@ _CompilerParams = getattr(pltpu, "CompilerParams", None) or getattr(
 # of 128 (MXU lane width).
 DEFAULT_BLOCK_N = 256
 
-# VMEM ceiling for kernel dispatch: scratch (2*K*BN int8) + operands +
-# the narrow output must fit or the remote compile fails with a
-# "scoped vmem" overflow (PERF_NOTES round-19); 14 MB measured safe of
-# the ~16 MB/core.
-_VMEM_BUDGET_BYTES = 14 * 1024 * 1024
+# VMEM ceiling for kernel dispatch, handed to Mosaic as the kernel's
+# scoped limit: what :func:`_kernel_vmem_bytes` counts must fit or the
+# compile fails with a "scoped vmem" overflow.  A v5e core has 128 MiB
+# of VMEM and the compiler's default scoped limit is 16 MiB; 32 MiB
+# admits all four llama3-8b projections up to M = 320 rows
+# (tests/test_chip_compile.py asks the v5e compiler).
+_VMEM_BUDGET_BYTES = 32 * 1024 * 1024
 
 # Host-side blocking-event counter: every ``block_matrix`` call (one
 # per projection per model load) increments it, and NOTHING on the
@@ -270,7 +268,7 @@ def _qmm_pallas(xq, a_scale, tiles, w_scale, out_dtype, interpret):
             pl.BlockSpec(memory_space=pltpu.VMEM),  # xq (M, K_pad)
             pl.BlockSpec(memory_space=pltpu.VMEM),  # w_scale (NB, 1, BN)
             pl.BlockSpec(memory_space=pltpu.VMEM),  # a_scale (M, 1)
-            pl.BlockSpec(memory_space=pltpu.ANY),  # tiles stay in HBM
+            pl.BlockSpec(memory_space=pl.ANY),  # tiles stay in HBM
         ],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((m, nb * bn), out_dtype),
@@ -278,7 +276,7 @@ def _qmm_pallas(xq, a_scale, tiles, w_scale, out_dtype, interpret):
             pltpu.VMEM((2, k_pad, bn), jnp.int8),
             pltpu.SemaphoreType.DMA((2,)),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             # Operand VMEM + the double buffer, with headroom for
             # Mosaic's own temporaries.
             vmem_limit_bytes=_VMEM_BUDGET_BYTES,
@@ -288,12 +286,19 @@ def _qmm_pallas(xq, a_scale, tiles, w_scale, out_dtype, interpret):
 
 
 def _kernel_vmem_bytes(m_pad, k_pad, n_pad, bn, out_itemsize) -> int:
+    """Scoped VMEM Mosaic allocates for :func:`_qmm_pallas`, fitted to
+    the least ``vmem_limit_bytes`` the v5e compiler accepts (bisected at
+    llama3-8b widths, M = 32..512; the estimate stays within 0.3 MiB
+    above it)."""
     return (
         2 * k_pad * bn  # double-buffered weight tile (int8)
-        + m_pad * k_pad  # int8 activations
+        # int8 activations, twice: the operand block plus the copy the
+        # loop-invariant ``xq_ref[:]`` load is hoisted into.
+        + 2 * m_pad * k_pad
         + m_pad * n_pad * out_itemsize  # narrow output
         + n_pad * 4  # blocked weight scales
-        + m_pad * 4  # per-token activation scales
+        + m_pad * 128 * 4  # per-token activation scales, lane-padded
+        + 256 * 1024  # accumulator / fold temporaries
     )
 
 
@@ -302,10 +307,12 @@ def use_qmm_kernel(
 ) -> bool:
     """Dispatch predicate for the W8A8 streaming kernel.
 
-    Single-chip TPU (pre-blocking already restricts to mesh-free
-    serving) within the VMEM budget; interpret mode forces the kernel
-    on CPU for tests.  Everything else — prefill-sized M, multi-chip,
-    CPU — takes the XLA twin, which is bit-identical by construction.
+    TPU within the VMEM budget; interpret mode forces the kernel on CPU
+    for tests.  Blocked weights only exist on one device
+    (``engine.decode.prepare_params`` blocks for no other placement),
+    so only the platform is asked here.  Everything else —
+    prefill-sized M, CPU — takes the XLA twin, which is bit-identical
+    by construction.
     """
     if os.environ.get("GAIE_DISABLE_QMM_KERNEL"):
         return False
@@ -316,12 +323,12 @@ def use_qmm_kernel(
         return False
     if _interpret_mode():
         return True
-    if jax.default_backend() != "tpu":
-        return False
-    return jax.device_count() == 1
+    return jax.default_backend() == "tpu"
 
 
-def q_matmul(x: jnp.ndarray, w: BlockedQuantizedMatrix) -> jnp.ndarray:
+def q_matmul(
+    x: jnp.ndarray, w: BlockedQuantizedMatrix, name: str | None = None
+) -> jnp.ndarray:
     """``x @ w`` in W8A8: quantize activations per token, int8 dot,
     fold scales into the narrow output.
 
@@ -345,12 +352,15 @@ def q_matmul(x: jnp.ndarray, w: BlockedQuantizedMatrix) -> jnp.ndarray:
         a_scale = jnp.pad(
             a_scale, ((0, m_pad - m), (0, 0)), constant_values=1.0
         )
-    if use_qmm_kernel(
-        m_pad=m_pad,
-        k_pad=k_pad,
-        n_pad=nb * bn,
-        bn=bn,
-        out_itemsize=jnp.dtype(x.dtype).itemsize,
+    if record(
+        f"q_matmul {name or f'{k}x{w.n}'} m={m_pad}",
+        use_qmm_kernel(
+            m_pad=m_pad,
+            k_pad=k_pad,
+            n_pad=nb * bn,
+            bn=bn,
+            out_itemsize=jnp.dtype(x.dtype).itemsize,
+        ),
     ):
         out = _qmm_pallas(
             xq, a_scale, w.tiles, w.scale, x.dtype, _interpret_mode()

@@ -125,7 +125,7 @@ def q_dot(x: jnp.ndarray, w: Any, name: Optional[str] = None) -> jnp.ndarray:
         _validate_q_dot(x, w, name)
         from generativeaiexamples_tpu.ops.qmm import q_matmul
 
-        return q_matmul(x, w)
+        return q_matmul(x, w, name)
     if isinstance(w, QuantizedMatrix):
         _validate_q_dot(x, w, name)
         out = jnp.einsum(

@@ -1,1 +1,1 @@
-"""Perf scripts + the round-long TPU backend watcher (tpu_watch)."""
+"""Perf probes and one-off bench scripts; chip_smoke.py at the repo root is the chip check."""

@@ -7,6 +7,9 @@ are shrunk to seconds; numbers are not asserted, only the contract
 (phases complete, expected keys present, sane types).
 """
 
+import json
+import os
+
 import numpy as np
 import pytest
 
@@ -451,6 +454,11 @@ def test_bench_slo_phase(monkeypatch):
     monkeypatch.setattr(bench, "OBS_DIM", 32)
     monkeypatch.setattr(bench, "SLO_OVERHEAD_ITERS", 8)
     monkeypatch.setattr(bench, "SLO_DRILL_REQUESTS", 16)
+    from generativeaiexamples_tpu.obs.tsdb import get_tsdb
+
+    # Earlier suites in this worker may have served real requests into
+    # the global tsdb: only what the phase itself adds is a leak.
+    before = set(get_tsdb().names())
     out = bench.bench_slo()
     for key in (
         "slo_raw_p50_ms",
@@ -478,15 +486,13 @@ def test_bench_slo_phase(monkeypatch):
     assert out["slo_transitions"] >= 2
     # Phase-local state must not leak into the process-wide singletons.
     from generativeaiexamples_tpu.obs.slo import get_slo_engine
-    from generativeaiexamples_tpu.obs.tsdb import get_tsdb
     from generativeaiexamples_tpu.resilience.faults import get_fault_injector
 
-    # (earlier suites may have ticked real schedulers into the global
-    # tsdb — only the phase's own series prefixes must be absent)
     leaked = [
         n
         for n in get_tsdb().names()
-        if n.startswith("slo.") or n.startswith("chain.")
+        if n not in before
+        and (n.startswith("slo.") or n.startswith("chain."))
     ]
     assert leaked == []
     assert get_slo_engine().evaluate(force=True)["fast_burn_firing"] is False
@@ -618,8 +624,9 @@ def test_bench_gray_phase(monkeypatch):
     monkeypatch.setattr(bench, "GRAY_BRIDGE_REQS", 4)
     monkeypatch.setattr(bench, "GRAY_MEASURED_REQS", 12)
     monkeypatch.setattr(bench, "GRAY_OVERHEAD_ITERS", 4)
-    monkeypatch.setattr(bench, "GRAY_EJECT_TIMEOUT_S", 30.0)
-    monkeypatch.setattr(bench, "GRAY_RECOVER_TIMEOUT_S", 45.0)
+    # The eject and recover waits are waits on a condition: they keep
+    # bench.py's own deadlines (45 s / 90 s), which a loaded six-worker
+    # host needs and a quiet one never reaches.
     out = bench.bench_gray()
     for key in (
         "gray_ejected",
@@ -661,8 +668,8 @@ def test_bench_fused_phase(monkeypatch):
     """The fused-W8A8 phase's glue must run at tiny smoke scale on CPU:
     microbench keys, kernel-vs-twin tile bit-identity (interpret mode),
     and the tile-once loading contract.  The full phase (decode parity +
-    spec on/off through the scheduler) is exercised in tests/test_qmm.py
-    and on hardware by the tpu_watch ``fused`` job."""
+    spec on/off through the scheduler) is exercised in tests/test_qmm.py;
+    on the chip, chip_smoke.py decodes through the kernel."""
     monkeypatch.setenv("GAIE_FUSED_TINY", "1")
     monkeypatch.setenv("GAIE_FUSED_SMOKE", "1")
     out = bench.bench_fused()
@@ -727,8 +734,8 @@ def test_bench_paged_phase(monkeypatch):
     graft) actually holding.  The throughput gate keys must exist but
     their thresholds are asserted only on captures — one-rep CPU smoke
     timings are noise.  The full parity matrix lives in
-    tests/test_paged_kv.py; hardware numbers land via the tpu_watch
-    ``paged`` job."""
+    tests/test_paged_kv.py; on the chip, chip_smoke.py decodes through
+    the paged kernel."""
     monkeypatch.setenv("GAIE_PAGED_SMOKE", "1")
     out = bench.bench_paged()
     for key in (
@@ -769,3 +776,32 @@ def test_bench_paged_phase(monkeypatch):
     # geometry, so they are deterministic even at one-rep smoke scale.
     assert out["paged_attn_traffic_ratio_skewed"] >= 1.3
     assert out["paged_attn_traffic_ratio_uniform"] >= 1.0
+
+
+def test_bench_long4k_glue():
+    """perf/bench_long4k.py runs end to end at tiny scale: the one-shot
+    chip run must not die on Python-level glue."""
+    import subprocess
+    import sys
+
+    env = {
+        k: v
+        for k, v in os.environ.items()
+        # Hermeticity: ambient bench/engine knobs (BENCH_B=128 etc.)
+        # must not scale the "tiny" run up.
+        if not k.startswith(("BENCH_", "GAIE_"))
+    }
+    env.update({"JAX_PLATFORMS": "cpu", "GAIE_LONG4K_TINY": "1"})
+    proc = subprocess.run(
+        [sys.executable, os.path.join("perf", "bench_long4k.py")],
+        capture_output=True,
+        text=True,
+        timeout=600,
+        env=env,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    result = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert len(result["windows"]) == 3
+    for w in result["windows"]:
+        assert w["decode_tps"] > 0 and w["prefill_batch_ms"] > 0

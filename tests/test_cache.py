@@ -415,6 +415,14 @@ class TestAnswerAttachment:
 
 
 class TestMetricsExport:
+    @pytest.fixture(autouse=True)
+    def _as_at_process_start(self):
+        # "From zero" is the state of a new process: drop the cache
+        # singleton and counters an earlier test file left in this worker.
+        from generativeaiexamples_tpu.chains.factory import reset_factories
+
+        reset_factories()
+
     def test_all_series_export_from_zero(self):
         text = "\n".join(cache_metrics_lines())
         assert 'rag_cache_hits_total{tier="exact"} 0' in text

@@ -1,0 +1,181 @@
+"""The serving path's Pallas kernels, asked of the v5e compiler.
+
+Interpret mode cannot see what Mosaic refuses — a block shape off the
+(8, 128) tiling, a dynamic index on a packed sublane, more scoped VMEM
+than the limit — so every kernel of the main path is compiled here at
+llama3-8b widths for a chip that is described, not attached
+(``on-chip-measurement`` guide, section 2).  Nothing runs; a compile
+that passes is not a chip run.
+
+All of it lives in this one file: the worker that is handed the file is
+the only process that loads the TPU's library, and it does so inside the
+module fixture — never while a module is imported.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from generativeaiexamples_tpu.ops import decode_attention as da
+from generativeaiexamples_tpu.ops import flash_attention as fa
+from generativeaiexamples_tpu.ops import qmm
+
+# llama3-8b: 32 layers, 32 q / 8 kv heads, head_dim 128.
+L, KH, NQ, HD = 32, 8, 32, 128
+PROJECTIONS = {
+    "wqkv": (4096, 6144),
+    "wo": (4096, 4096),
+    "w_gu": (4096, 28672),
+    "w_down": (14336, 4096),
+}
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # noqa: BLE001 — any failure to describe skips
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # A compile for a described chip is written to the persistent cache
+    # but cannot be read back without the chip: keep it off here.
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    # conftest forces "highest" matmul precision for the CPU's sake; the
+    # chip runs the default, and Mosaic refuses an fp32-precision bf16 dot.
+    precision_was = jax.config.jax_default_matmul_precision
+    jax.config.update("jax_default_matmul_precision", None)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_default_matmul_precision", precision_was)
+    jax.config.update("jax_enable_compilation_cache", cache_was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *shapes):
+    """Compile for the described chip; the kernel must be in the program."""
+    compiled = jax.jit(fn).lower(*shapes).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    return compiled
+
+
+def _spec(sharding):
+    return lambda shape, dtype: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=sharding
+    )
+
+
+@pytest.mark.parametrize(
+    "batch,window,cache_len,chunk",
+    [
+        (320, 256, 256, 64),
+        (48, 2048, 2048, 8),
+        # A short context in a longer cache: the first chip run's
+        # scheduler tick died here (a 64-wide bf16 scale block).
+        (16, 64, 256, 8),
+    ],
+)
+def test_decode_kernel_compiles(one_chip, batch, window, cache_len, chunk):
+    S = _spec(one_chip)
+    cache = S((L, KH, batch, cache_len, HD), jnp.int8)
+    scales = S((L, KH, batch, cache_len), jnp.bfloat16)
+    ab = S((L, KH, batch, chunk, HD), jnp.int8)
+    ab_scales = S((L, KH, batch, chunk), jnp.bfloat16)
+
+    def attn(q, k, v, ks, vs, li, lens, kab, vab, ksab, vsab, count):
+        return da.decode_gqa_attention(
+            q, k, v, ks, vs, li, lens,
+            append=(kab, vab, ksab, vsab, count),
+            window=window, interpret=False,
+        )
+
+    _compile(
+        attn,
+        S((batch, NQ, HD), jnp.bfloat16), cache, cache, scales, scales,
+        S((), jnp.int32), S((batch,), jnp.int32),
+        ab, ab, ab_scales, ab_scales, S((), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("batch,s,t", [(8, 1536, 1536), (16, 256, 2048)])
+def test_flash_kernel_compiles(one_chip, batch, s, t):
+    S = _spec(one_chip)
+
+    def attn(q, k, v, pos, lens):
+        return fa.flash_gqa_attention(q, k, v, pos, lens, interpret=False)
+
+    _compile(
+        attn,
+        S((batch, s, NQ, HD), jnp.bfloat16),
+        S((batch, t, KH, HD), jnp.bfloat16),
+        S((batch, t, KH, HD), jnp.bfloat16),
+        S((batch, s), jnp.int32),
+        S((batch,), jnp.int32),
+    )
+
+
+@pytest.mark.parametrize("m", [32, 320])
+@pytest.mark.parametrize("name", sorted(PROJECTIONS))
+def test_w8a8_kernel_compiles_where_the_gate_admits(one_chip, name, m):
+    """What ``use_qmm_kernel`` admits is what Mosaic accepts: at decode
+    batches up to 320 rows that is all four projections."""
+    S = _spec(one_chip)
+    k, n = PROJECTIONS[name]
+    bn = qmm.DEFAULT_BLOCK_N
+    assert (
+        qmm._kernel_vmem_bytes(m, k, n, bn, 2) <= qmm._VMEM_BUDGET_BYTES
+    ), "the gate sends this shape to the XLA twin"
+
+    def matmul(xq, a_scale, tiles, w_scale):
+        return qmm._qmm_pallas(
+            xq, a_scale, tiles, w_scale, jnp.bfloat16, False
+        )
+
+    _compile(
+        matmul,
+        S((m, k), jnp.int8),
+        S((m, 1), jnp.float32),
+        S((n // bn, k, bn), jnp.int8),
+        S((n // bn, 1, bn), jnp.float32),
+    )
+
+
+@pytest.mark.parametrize("chunk", [0, 8])
+@pytest.mark.parametrize("page_tokens", [64, 128])
+def test_paged_kernel_compiles(one_chip, page_tokens, chunk):
+    S = _spec(one_chip)
+    batch, max_len = 64, 2048
+    n_slot_pages = max_len // page_tokens
+    assert da.use_paged_kernel(
+        s=1, kv_int8=True, page_tokens=page_tokens, n_q=NQ, n_kv=KH,
+        head_dim=HD, append_width=chunk, backend="tpu",
+    )
+    slots = -(-(batch * n_slot_pages + 1) * page_tokens // 128) * 128
+    pool = S((L, KH, slots, HD), jnp.int8)
+    scales = S((L, KH, slots), jnp.bfloat16)
+    args = [
+        S((batch, NQ, HD), jnp.bfloat16), pool, pool, scales, scales,
+        S((), jnp.int32), S((batch,), jnp.int32),
+        S((batch, n_slot_pages), jnp.int32),
+    ]
+    if chunk:
+        ab = S((L, KH, batch, chunk, HD), jnp.int8)
+        ab_scales = S((L, KH, batch, chunk), jnp.bfloat16)
+        args += [ab, ab, ab_scales, ab_scales, S((), jnp.int32)]
+
+    def attn(q, k, v, ks, vs, li, lens, table, *append):
+        return da.paged_decode_gqa_attention(
+            q, k, v, ks, vs, li, lens, table,
+            append=append or None,
+            page_tokens=page_tokens, interpret=False,
+        )
+
+    _compile(attn, *args)

@@ -1,0 +1,197 @@
+"""chip_smoke.py's control flow rehearsed on the CPU, and the rules that
+keep a run without a chip from passing for one: the smoke, the bench
+and the engine server each refuse, by exit code, to stand in for the
+TPU; the compile cache goes where it is told.
+
+Only the rehearsals pass ``expect="cpu"`` — an argument of
+``chip_smoke.run``, not a command-line option: the script as the driver
+runs it always expects a TPU.
+"""
+
+import json
+import os
+import shutil
+import subprocess
+import sys
+import tempfile
+
+import jax
+import pytest
+
+import chip_smoke
+from generativeaiexamples_tpu.utils import jax_runtime
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _phases(capsys) -> dict:
+    lines = [
+        json.loads(ln)
+        for ln in capsys.readouterr().out.splitlines()
+        if ln.startswith("{")
+    ]
+    return {ln["phase"]: ln for ln in lines}
+
+
+def test_smoke_rehearsal_on_cpu(capsys, monkeypatch, tmp_path):
+    """Every phase of the one-chip run at tiny sizes: engine server and
+    chain server as child processes, retrieval and the opt-in paths as
+    children that exit before the next starts."""
+    # One device, as on the one-chip machine (conftest's eight virtual
+    # devices would have the server build a tensor=8 mesh).
+    monkeypatch.delenv("XLA_FLAGS", raising=False)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "cache"))
+    # The servers' logs and the chain server's uploads go under one
+    # directory of the run's own, in TMPDIR, and leave with the phase.
+    scratch = tmp_path / "tmp"
+    scratch.mkdir()
+    monkeypatch.setenv("TMPDIR", str(scratch))
+    monkeypatch.setattr(tempfile, "tempdir", None)
+    started = {}
+
+    class Recorded(chip_smoke.Server):
+        def __init__(self, name, argv, env, logdir):
+            started[name] = (env, logdir)
+            super().__init__(name, argv, env, logdir)
+
+    monkeypatch.setattr(chip_smoke, "Server", Recorded)
+    device = chip_smoke.run(0, chip_smoke.TINY, expect="cpu")
+    assert device["platform"] == "cpu"
+    env, logdir = started["chain"]
+    assert env["GAIE_UPLOAD_DIR"] == os.path.join(logdir, "uploads")
+    assert os.path.dirname(logdir) == str(scratch)
+    assert not [d for d in os.listdir(scratch) if d.startswith("chip-smoke-")]
+    phases = _phases(capsys)
+    assert list(phases) == [
+        "device", "serve.engine", "serve.chain", "retrieval",
+        "optin.paged", "optin.w8a8",
+    ]
+    engine = phases["serve.engine"]
+    assert set(engine["chat_finish"]) == {"length"}
+    assert engine["tokens_returned"] == 3 * chip_smoke.TINY.new_tokens
+    chain = phases["serve.chain"]
+    assert chain["generate_context_chunks"] >= 1
+    assert chain["engine_requests_for_generate"] == 1
+    assert chain["engine_tokens_for_generate"] == chip_smoke.TINY.new_tokens
+    assert phases["retrieval"]["ids_equal_numpy"]
+    paged = phases["optin.paged"]
+    assert paged["matches_contiguous"]
+    # Rows of two to four pages, two of which cross into a new page
+    # while they decode; on the CPU every run reads through the XLA
+    # twins, which are bit-identical, so the streams agree to the end.
+    assert paged["long_streams_pages"] == [[3, 4], [3, 3], [2, 3]]
+    assert paged["long_streams_tokens_equal_xla_twin"] == [16, 16, 16]
+    assert paged["long_streams_tokens_equal_contiguous"] == [16, 16, 16]
+    assert paged["kernel_vs_xla_twin"]["rows_pages"] == [1, 1, 1, 2, 4, 5, 7, 8]
+    assert phases["optin.w8a8"]["matches_xla_twin"]
+
+
+def test_four_chip_rehearsal_on_virtual_devices(
+    capsys, monkeypatch, tmp_path
+):
+    monkeypatch.setenv(
+        "XLA_FLAGS", "--xla_force_host_platform_device_count=4"
+    )
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    device = chip_smoke.run(
+        0, chip_smoke.TINY, expect="cpu", four_chips=True
+    )
+    assert device["count"] == 4
+    phases = _phases(capsys)
+    assert list(phases) == ["four.tensor_parallel", "four.replicas"]
+    assert len(set(phases["four.replicas"]["replica_devices"])) == 4
+    assert any(
+        "x4" in v
+        for v in phases["four.tensor_parallel"]["sharded_leaves"].values()
+    )
+
+
+def _run(argv, **env):
+    full = {k: v for k, v in os.environ.items() if k != "JAX_PLATFORMS"}
+    full.update(env)
+    return subprocess.run(
+        [sys.executable, *argv],
+        cwd=REPO,
+        env=full,
+        capture_output=True,
+        text=True,
+        timeout=300,
+    )
+
+
+def test_smoke_without_a_chip_fails_and_prints_no_result():
+    proc = _run(["chip_smoke.py"], JAX_PLATFORMS="cpu")
+    assert proc.returncode != 0
+    assert "no tpu device" in proc.stderr and "'cpu'" in proc.stderr
+    assert proc.stdout.strip() == ""
+
+
+def test_smoke_outside_a_checkout_fails(tmp_path):
+    shutil.copy(os.path.join(REPO, "chip_smoke.py"), tmp_path)
+    proc = subprocess.run(
+        [sys.executable, "chip_smoke.py"],
+        cwd=tmp_path,
+        env={k: v for k, v in os.environ.items() if k != "PYTHONPATH"},
+        capture_output=True,
+        text=True,
+        timeout=120,
+    )
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout
+
+
+def test_bench_without_a_chip_fails_and_names_the_device(tmp_path):
+    full = str(tmp_path / "bench_full.json")
+    proc = _run(
+        ["bench.py"], JAX_PLATFORMS="cpu", GAIE_BENCH_RESULT_PATH=full
+    )
+    assert proc.returncode != 0
+    headline = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert "no TPU" in headline["error"] and "'cpu'" in headline["error"]
+    assert headline["platform"] == "cpu" and headline["value"] == 0.0
+    assert "live" not in headline
+
+
+def test_engine_server_refuses_to_start_without_a_tpu():
+    """No ``JAX_PLATFORMS=cpu`` in its environment and no TPU to find:
+    the server says so and exits instead of serving from the host."""
+    proc = _run(
+        ["-m", "generativeaiexamples_tpu.engine.server",
+         "--model", "llama-tiny", "--port", "0"]
+    )
+    assert proc.returncode != 0
+    assert "no TPU found" in proc.stderr
+
+
+def test_require_accelerator_accepts_an_explicit_cpu(monkeypatch):
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert jax_runtime.require_accelerator("test")["platform"] == "cpu"
+    monkeypatch.delenv("JAX_PLATFORMS")
+    with pytest.raises(SystemExit, match="no TPU found"):
+        jax_runtime.require_accelerator("test")
+
+
+@pytest.fixture
+def restore_cache_dir():
+    was = jax.config.jax_compilation_cache_dir
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+
+
+def test_compile_cache_follows_the_environment(
+    monkeypatch, tmp_path, restore_cache_dir
+):
+    """Placed from outside: the helper sets no directory of its own."""
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    was = jax.config.jax_compilation_cache_dir
+    assert jax_runtime.enable_compile_cache() == str(tmp_path)
+    assert jax.config.jax_compilation_cache_dir == was
+
+
+def test_compile_cache_defaults_to_the_checkout(
+    monkeypatch, restore_cache_dir
+):
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = os.path.join(REPO, ".jax_cache")
+    assert jax_runtime.enable_compile_cache() == want
+    assert jax.config.jax_compilation_cache_dir == want

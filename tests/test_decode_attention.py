@@ -302,8 +302,7 @@ def test_block_b_env_override_validated(monkeypatch):
 
 
 def test_use_decode_kernel_gating():
-    # A 1-device mesh stands in for the single-chip serving case (the
-    # bare-device_count probe sees the 8-device virtual CPU platform).
+    # A 1-device mesh is a replica's slice of a multi-chip host.
     mesh1 = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("data",))
     common = dict(
         s=1, kv_int8=True, batch=320, window=256, n_q=32, n_kv=8,
@@ -321,5 +320,9 @@ def test_use_decode_kernel_gating():
     assert use_decode_kernel(backend="tpu", **{**common, "window": 64})
     assert use_decode_kernel(backend="tpu", **{**common, "window": 32})
     assert not use_decode_kernel(backend="tpu", **{**common, "window": 16})
-    # Multi-device meshes and ambient multi-device platforms fall back.
-    assert not use_decode_kernel(backend="tpu", **{**common, "mesh": None})
+    # The gate reads the mesh, not the process: no mesh means the
+    # default device, whatever else the host holds (8 virtual devices
+    # here); only a mesh that spans devices falls back.
+    assert use_decode_kernel(backend="tpu", **{**common, "mesh": None})
+    mesh2 = jax.sharding.Mesh(np.asarray(jax.devices()[:2]), ("data",))
+    assert not use_decode_kernel(backend="tpu", **{**common, "mesh": mesh2})

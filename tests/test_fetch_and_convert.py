@@ -1,4 +1,4 @@
-"""Real-checkpoint path rehearsal (VERDICT r4 #6): HF snapshot ->
+"""Real-checkpoint path rehearsal: HF snapshot ->
 converter -> orbax shards -> engine boot, against a locally GENERATED
 mid-size HF-format checkpoint (~127M params, not tiny) — so the day real
 weights are reachable, serving them is a config change (reference

@@ -103,9 +103,9 @@ def test_use_flash_dispatch_predicate():
     assert not use_flash(512, 128, backend="cpu", mesh=one)  # hermetic
     assert use_flash(512, 128, backend="tpu", mesh=one)
 
-    # Multi-device meshes stay on the partitionable XLA path, and so does
-    # the no-mesh case in a multi-device process (fail-safe default).
+    # Multi-device meshes stay on the partitionable XLA path; no mesh
+    # means the default device, whatever else the process can see.
     mesh = make_mesh()  # all local (virtual CPU) devices
-    if mesh.size > 1:
-        assert not use_flash(512, 128, backend="tpu", mesh=mesh)
-        assert not use_flash(512, 128, backend="tpu", mesh=None)
+    assert mesh.size > 1
+    assert not use_flash(512, 128, backend="tpu", mesh=mesh)
+    assert use_flash(512, 128, backend="tpu", mesh=None)

@@ -475,7 +475,6 @@ def fleet(monkeypatch, tmp_path):
     )
     cfg = llama.llama_tiny(dtype="float32", max_seq_len=1024)
     sched = Scheduler(cfg, max_batch=2, max_len=1024, decode_chunk_size=8)
-    sched.start()
     loop = asyncio.new_event_loop()
     engine = _start(
         loop, create_engine_app(sched, ByteTokenizer(), model_name="llama-tiny")
@@ -485,6 +484,11 @@ def fleet(monkeypatch, tmp_path):
     from generativeaiexamples_tpu.chains.factory import reset_factories
 
     reset_factories()
+    # Only now: the tick thread's first pass through its fault point
+    # loads the config, and a load that began before the URL was set and
+    # ended after the cache was cleared would leave the chain a config
+    # without it (the failure under six workers: "Connection refused").
+    sched.start()
     from generativeaiexamples_tpu.server.app import create_app
 
     chain = _start(loop, create_app())
@@ -508,11 +512,31 @@ def test_generate_request_id_spans_chain_and_engine(fleet):
         assert resp.status == 200
         req_id = resp.headers["X-Request-Id"]
         await resp.read()
-        chain_debug = await (await chain.get("/debug/requests")).json()
-        engine_debug = await (await engine.get("/debug/requests")).json()
-        series = await (
-            await engine.get("/debug/timeseries?series=engine.*")
-        ).json()
+
+        def recorded(debug, route):
+            return any(
+                r["route"] == route and r["request_id"] == req_id
+                for r in debug["requests"]
+            )
+
+        # Both flight recorders and the tick loop's TSDB are fed from
+        # other threads after the response is out: wait until all three
+        # show the request, not for a time that a loaded host outruns.
+        deadline = time.monotonic() + 60.0
+        while True:
+            chain_debug = await (await chain.get("/debug/requests")).json()
+            engine_debug = await (await engine.get("/debug/requests")).json()
+            series = await (
+                await engine.get("/debug/timeseries?series=engine.*")
+            ).json()
+            ticks = series["series"].get("engine.tick_ms", {"points": []})
+            if (
+                recorded(chain_debug, "/generate")
+                and recorded(engine_debug, "/v1/chat/completions")
+                and sum(p[1] for p in ticks["points"]) > 0
+            ) or time.monotonic() > deadline:
+                break
+            await asyncio.sleep(0.05)
         return req_id, chain_debug, engine_debug, series
 
     req_id, chain_debug, engine_debug, series = loop.run_until_complete(go())

@@ -211,11 +211,17 @@ def test_prepare_params_xla_path_untouched():
         prepare_params(CFG, packed, None, matmul_kernel="mxu9000")
 
 
-def test_preblock_skips_float_params():
-    """Float (unquantized) params stay on the XLA path — blocking only
-    applies to int8 serving weights."""
-    params = prepare_params(CFG, None, None, matmul_kernel="pallas_w8a8")
-    assert _blocked_leaf_names(params) == []
+def test_pallas_w8a8_refuses_float_params():
+    """Blocking only applies to int8 serving weights: asking for the
+    kernel over float projections is an error, not a silent stay on the
+    XLA path — and ``quantize=True`` is what makes it apply."""
+    with pytest.raises(ValueError, match="needs int8 projections"):
+        prepare_params(CFG, None, None, matmul_kernel="pallas_w8a8")
+    params = prepare_params(
+        CFG, None, None, quantize=True, pack=True,
+        matmul_kernel="pallas_w8a8",
+    )
+    assert _blocked_leaf_names(params) == ["w_down", "w_gu", "wo", "wqkv"]
 
 
 # ---------------------------------------------------------------------------
@@ -365,8 +371,8 @@ def test_bench_fused_full_phase(monkeypatch):
     round-19 contract keys plus the mechanism gates the CPU capture is
     responsible for — greedy bit-identity kernel-vs-twin through the
     generator, tile-once loading, and a clean spec on/off sub-phase.
-    (The cheap glue smoke lives in test_bench_glue.py; TPU GB/s numbers
-    are the tpu_watch ``fused`` job's business.)"""
+    (The cheap glue smoke lives in test_bench_glue.py; GB/s on the chip
+    has not been measured.)"""
     import bench
 
     monkeypatch.setenv("GAIE_FUSED_TINY", "1")

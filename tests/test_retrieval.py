@@ -479,7 +479,7 @@ class TestReranker:
 
 class TestAutoBackendSelection:
     """``auto`` picks the platform's fastest adaptive store with the
-    measured exact-vs-IVF crossover (VERDICT r4 #5; the reference
+    measured exact-vs-IVF crossover (the reference
     hardwires Milvus GPU_IVF_FLAT, ``common/utils.py:198-203``)."""
 
     def _auto_store(self, monkeypatch, dim=64, extra_env=()):
@@ -530,9 +530,9 @@ class TestAutoBackendSelection:
         # store stays exact until the extrapolated ~4M break-even.
         assert store.min_train_size == 4_000_000
 
-    def test_platform_detection_avoids_backend_init(self):
-        """On an initialized runtime _platform reports the LIVE backend
-        (cpu here), not the environment's plugin name."""
+    def test_platform_is_the_live_backend(self):
+        """_platform reports JAX's live backend (cpu here) and nothing
+        inferred from the environment."""
         from generativeaiexamples_tpu.retrieval import factory
 
         assert factory._platform() == "cpu"

@@ -481,8 +481,8 @@ class TestRejectionSampling:
 class TestTrainedPairAcceptance:
     """A target/draft pair TRAINED on the same structured corpus reaches
     non-floor acceptance for sampled requests through the scheduler —
-    the hermetic stand-in for a production llama 8B/1B pair (VERDICT r4
-    #3b); random-weight pairs can only measure the overhead floor."""
+    the hermetic stand-in for a production llama 8B/1B pair;
+    random-weight pairs can only measure the overhead floor."""
 
     @pytest.fixture(scope="class")
     def trained_pair(self):

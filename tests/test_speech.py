@@ -371,8 +371,8 @@ class TestWav2Vec2:
 
 
 class TestTrainedSpeechLoop:
-    """Trained weights BOTH ways through the real service surfaces
-    (VERDICT r4 #4).  Two trained recognizers cover the two ASR
+    """Trained weights BOTH ways through the real service surfaces.
+    Two trained recognizers cover the two ASR
     architectures: the mel-feature CONFORMER (shift-robust — trained
     with per-step random time shifts, it transcribes tone-coded speech
     at any offset and through the vocoder channel) drives streaming,
