@@ -212,6 +212,7 @@ def prepare_paged_pool(
     )
 
 
+@jax.named_scope("kv_write")
 def _flush_append_buffer(cache, ab, starts, max_len: int):
     """Write the chunk's append buffer into the big cache, one scatter per
     leaf.
@@ -265,6 +266,7 @@ def _flush_append_buffer(cache, ab, starts, max_len: int):
     return tuple(flush_leaf(bg, sm) for bg, sm in zip(cache, ab))
 
 
+@jax.named_scope("kv_write")
 def _flush_append_buffer_paged(
     leaves, ab, starts, table, max_len: int, page_tokens: int
 ):

@@ -73,7 +73,12 @@ from generativeaiexamples_tpu.engine.health import (
     ReplicaScorer,
 )
 from generativeaiexamples_tpu.engine.router import ReplicaView, Router
-from generativeaiexamples_tpu.engine.scheduler import Request, Scheduler
+from generativeaiexamples_tpu.engine.scheduler import (
+    STARVED_PHASES,
+    TICK_PHASES,
+    Request,
+    Scheduler,
+)
 
 logger = get_logger(__name__)
 
@@ -1118,6 +1123,7 @@ class EnginePool:
         "requests_total",
         "tokens_total",
         "tick_count",
+        "busy_ticks",
         "prefill_rows",
         "decode_chunks",
         "active_slots",
@@ -1132,6 +1138,21 @@ class EnginePool:
         "spec_accepted",
         "spec_fallbacks",
         "ttft_count",
+        # Tick-phase seconds, starved-device seconds and the request
+        # lifecycle sums: each replica has its own tick thread, so the
+        # pool's totals are thread-seconds, not wall time.
+        *(f"tick_phase_{p}_s" for p in TICK_PHASES),
+        "device_starved_s",
+        *(f"device_starved_{p}_s" for p in STARVED_PHASES),
+        "queue_wait_s_sum",
+        "queue_wait_count",
+        "warm_s_sum",
+        "warm_count",
+        "prompt_tokens_admitted",
+        "prompts_clipped",
+        "prompt_tokens_clipped",
+        "prefill_tokens_dispatched",
+        "prefill_tokens_padded",
         # Paged-KV pool gauges/counters sum across replicas: each
         # replica owns a disjoint page pool, so pool-wide capacity and
         # pressure are the sums (all zero under the contiguous layout).
@@ -1156,8 +1177,6 @@ class EnginePool:
             readmissions = self.readmissions_total
             session_evictions = self.router.session_evictions_total
         agg: dict = {k: 0 for k in self._SUM_KEYS}
-        agg["prefill_s"] = 0.0
-        agg["decode_s"] = 0.0
         ttft_weighted = 0.0
         tick_ewma_max = 0.0
         tick_norm_max = 0.0
@@ -1175,8 +1194,6 @@ class EnginePool:
             replicas.append(snap)
             for k in self._SUM_KEYS:
                 agg[k] += snap.get(k, 0)
-            agg["prefill_s"] += snap["prefill_s"]
-            agg["decode_s"] += snap["decode_s"]
             ttft_weighted += snap["ttft_avg_ms"] * snap.get("ttft_count", 0)
             accept_weighted += snap.get(
                 "spec_acceptance_ewma", 0.0

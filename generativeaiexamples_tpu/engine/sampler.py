@@ -151,6 +151,7 @@ def prob_of(
     return jnp.sum(jnp.where(match, cand_probs, 0.0), axis=-1)
 
 
+@jax.named_scope("sample")
 def sample(
     logits: jnp.ndarray,
     key: jax.Array,

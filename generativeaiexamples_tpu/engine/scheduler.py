@@ -79,6 +79,10 @@ class Request:
     # parked tokens prefills only the new suffix (see _admit_hit).
     session_id: str = ""
     submitted_at: float = 0.0
+    # Stamped by the scheduler where a slot is claimed for the request
+    # (same perf_counter clock as submitted_at / first_token_at):
+    # submit -> claim is queue wait, claim -> first token is prefill.
+    claimed_at: Optional[float] = None
     first_token_at: Optional[float] = None
     # Set by the HTTP front for short non-streaming requests: the pool
     # may fire a duplicate copy to a second replica if this one is slow
@@ -111,6 +115,124 @@ class _Slot:
     # draft+verify tokens every round.  Reset to 1.0 (optimistic) at
     # every claim so a fresh request starts at full lookahead.
     accept_ewma: float = 1.0
+
+
+# What the tick thread can be doing; it is in exactly one at any instant.
+#   idle         blocked on the empty queue: no work anywhere
+#   plan         host-only bookkeeping: page eviction, slot scans, prefix
+#                lookups, prompt clipping, slot claims, lane state
+#   dispatch     from the first host-to-device array of a program to the
+#                return of its jitted call
+#   wait_device  blocked fetching a result
+#   emit         token callbacks, finishing and parking slots
+#   telemetry    _note_tick and the tick record
+TICK_PHASES = ("idle", "plan", "dispatch", "wait_device", "emit", "telemetry")
+# The phases starved-device time is split by: there is no work to give
+# in ``idle`` and the device is by definition busy in ``wait_device``.
+STARVED_PHASES = ("plan", "dispatch", "emit", "telemetry")
+# One record a busy tick (Scheduler.tick_records, GET /debug/ticks).
+TICK_RECORD_FIELDS = (
+    ("tick", "t_start", "wall_start")
+    + tuple(f"{p}_s" for p in TICK_PHASES)
+    + ("starved_s", "prefill_chunks", "admitted", "decode_lanes",
+       "kv_bucket", "tokens", "queued")
+)
+
+
+class _TickClock:
+    """The tick thread's phase clock.
+
+    ``enter(phase)`` reads the clock once, adds the elapsed time to the
+    phase that ends and starts the next, so ``Stats.tick_phase_s``
+    partitions the thread's wall time.  Each phase is also a
+    ``jax.profiler.TraceAnnotation("tick/<phase>")`` on the thread's line
+    of the profiler's host plane — the same clock as the device plane —
+    which costs a check of an atomic while no trace runs.
+
+    Starved-device time: once the fetch of the last-enqueued program has
+    returned (``fetched``), nothing is queued on the device until the
+    next jitted call returns (``dispatched``); the elapsed time of every
+    phase in between goes to ``Stats.device_starved_s`` too (``idle``
+    excepted).  It is a lower bound of the device's idle time: a tick
+    whose last program is never fetched (a warming chunk with no decode
+    chunk behind it) opens no interval, a gap between two programs of one
+    tick is not seen at all, and a fetch that returns a first token while
+    the graft dispatched behind its prefill still runs opens the interval
+    that much early.
+    """
+
+    def __init__(self, stats: "Stats") -> None:
+        self._stats = stats
+        self._span: Optional[jax.profiler.TraceAnnotation] = None
+        self._seq = 0  # dispatch sites returned from so far
+
+    def _lap(self) -> None:
+        """Book the time since the last lap to the running phase (under
+        the lock ``Stats.snapshot`` reads it with)."""
+        st = self._stats
+        now = time.perf_counter()
+        with st.lock:
+            if st.tick_phase is not None:
+                dt = now - st.tick_phase_since
+                st.tick_phase_s[st.tick_phase] += dt
+                if st.device_starved and st.tick_phase in st.device_starved_s:
+                    st.device_starved_s[st.tick_phase] += dt
+            st.tick_phase_since = now
+
+    def enter(self, phase: str, **facts) -> None:
+        """End the current phase and start ``phase``; ``facts`` (a
+        dispatch's program and shapes) ride on the trace annotation."""
+        self._lap()
+        self._stats.tick_phase = phase
+        self.end_span()
+        self._span = jax.profiler.TraceAnnotation(_SPAN_NAMES[phase], **facts)
+        self._span.__enter__()
+
+    def end_span(self) -> None:
+        """Close the open annotation (the phase itself runs on), so that
+        it nests inside the loop's step annotation."""
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            self._span = None
+
+    def start(self, phase: str) -> None:
+        """The tick thread starts: the time since the last one stopped
+        belongs to no phase."""
+        self._stats.tick_phase = None
+        self._stats.device_starved = False
+        self.enter(phase)
+
+    def stop(self) -> None:
+        """The tick thread ends: no phase runs on."""
+        self._lap()
+        self._stats.tick_phase = None
+        self.end_span()
+
+    def dispatched(self) -> int:
+        """A dispatch site's jitted calls have returned: the device has
+        work.  Returns the ticket its finalizer hands to ``fetched``."""
+        self._lap()
+        self._stats.device_starved = False
+        self._seq += 1
+        return self._seq
+
+    def fetched(self, ticket: int) -> None:
+        """A result was fetched; if nothing was dispatched behind it the
+        device is starved from here on."""
+        if ticket == self._seq:
+            self._lap()
+            self._stats.device_starved = True
+
+    def sums(self) -> tuple:
+        """(phase sums..., starved) up to now, for a tick's record."""
+        self._lap()
+        st = self._stats
+        return tuple(st.tick_phase_s.values()) + (
+            sum(st.device_starved_s.values()),
+        )
+
+
+_SPAN_NAMES = {p: f"tick/{p}" for p in TICK_PHASES}
 
 
 class Stats:
@@ -157,19 +279,45 @@ class Stats:
         self.spec_acceptance_ewma = 0.0
         self.spec_gamma = 0
         self.spec_fallbacks = 0
-        # Tick-phase wall-time accounting: where a serving tick actually
-        # goes (batched admission prefill vs the decode chunk).  Each
-        # counter spans its phase's dispatch -> fetch-complete interval;
-        # in the PIPELINED tick those intervals overlap by design, so
-        # prefill_s + decode_s can exceed wall time (a negative
-        # "wall - prefill_s - decode_s" reads as "fully overlapped", not
-        # as an accounting bug).  Only in the synchronous path does the
-        # difference equal host-side scheduling overhead.
+        # tick_count counts every pass of the tick loop, idle polls
+        # included; busy_ticks only those that dispatched or fetched a
+        # program.
         self.tick_count = 0
-        self.prefill_s = 0.0
+        self.busy_ticks = 0
         self.prefill_rows = 0
-        self.decode_s = 0.0
         self.decode_chunks = 0
+        # Exclusive tick phases (see _TickClock): the tick thread is in
+        # exactly one phase at any instant, so the six sums partition its
+        # wall time.  tick_phase is the phase it is in now (None while no
+        # tick thread runs) and tick_phase_since when it entered it:
+        # snapshot() adds the running phase's time, so the sums are exact
+        # at any instant and not only at a phase change.
+        self.tick_phase_s = dict.fromkeys(TICK_PHASES, 0.0)
+        self.tick_phase: Optional[str] = None
+        self.tick_phase_since = 0.0
+        # Host time during which nothing was queued on the device, by the
+        # phase the tick thread was in (_TickClock says what opens and
+        # closes an interval; device_starved is whether one is open);
+        # snapshot() sums the parts into device_starved_s.
+        self.device_starved_s = dict.fromkeys(STARVED_PHASES, 0.0)
+        self.device_starved = False
+        # Request lifecycle: submit -> slot claim (queue wait, counted at
+        # the claim) and claim -> first token fetched (counted at the
+        # first token); the two add up to ttft_sum request by request.
+        self.queue_wait_s_sum = 0.0
+        self.queue_wait_count = 0
+        self.warm_s_sum = 0.0
+        self.warm_count = 0
+        # Prompt tokens of claimed requests (after clipping), and what
+        # _clip_prompt cut off prompts over the admissible bound.
+        self.prompt_tokens_admitted = 0
+        self.prompts_clipped = 0
+        self.prompt_tokens_clipped = 0
+        # Real prompt tokens handed to the prefill programs, counted at
+        # the dispatch, and the padding that rode along (bucketed shape
+        # minus real tokens, padded batch rows included).
+        self.prefill_tokens_dispatched = 0
+        self.prefill_tokens_padded = 0
         # EWMA of tick wall time, updated lock-free from the tick loop
         # (single-writer; readers tolerate a torn-in-time value).  The
         # 429 Retry-After hint derives queue-drain time from it without
@@ -210,14 +358,33 @@ class Stats:
 
     def snapshot(self) -> dict:
         with self.lock:
+            phase_s = dict(self.tick_phase_s)
+            starved_s = dict(self.device_starved_s)
+            if self.tick_phase is not None:
+                running = time.perf_counter() - self.tick_phase_since
+                phase_s[self.tick_phase] += running
+                if self.device_starved and self.tick_phase in starved_s:
+                    starved_s[self.tick_phase] += running
             return {
                 "requests_total": self.requests_total,
                 "tokens_total": self.tokens_total,
                 "tick_count": self.tick_count,
-                "prefill_s": round(self.prefill_s, 3),
+                "busy_ticks": self.busy_ticks,
                 "prefill_rows": self.prefill_rows,
-                "decode_s": round(self.decode_s, 3),
                 "decode_chunks": self.decode_chunks,
+                # Unrounded: a reader takes deltas over a few seconds.
+                **{f"tick_phase_{p}_s": v for p, v in phase_s.items()},
+                "device_starved_s": sum(starved_s.values()),
+                **{f"device_starved_{p}_s": v for p, v in starved_s.items()},
+                "queue_wait_s_sum": self.queue_wait_s_sum,
+                "queue_wait_count": self.queue_wait_count,
+                "warm_s_sum": self.warm_s_sum,
+                "warm_count": self.warm_count,
+                "prompt_tokens_admitted": self.prompt_tokens_admitted,
+                "prompts_clipped": self.prompts_clipped,
+                "prompt_tokens_clipped": self.prompt_tokens_clipped,
+                "prefill_tokens_dispatched": self.prefill_tokens_dispatched,
+                "prefill_tokens_padded": self.prefill_tokens_padded,
                 "ttft_avg_ms": (
                     self.ttft_sum / self.ttft_count * 1000 if self.ttft_count else 0.0
                 ),
@@ -584,6 +751,18 @@ class Scheduler:
         # Scheduler-thread only; _note_tick reads them after each tick.
         self._tick_tokens = 0
         self._tick_decoded = 0
+        # The rest of a busy tick's record (``_ticks``, newest last):
+        # warming chunks dispatched, requests claimed, the decode chunk's
+        # attention window, and whether the tick touched the device.
+        self._tick_chunks = 0
+        self._tick_admitted = 0
+        self._tick_kv_bucket = 0
+        self._tick_busy = False
+        self._tick_no = 0
+        self._ticks: "collections.deque[tuple]" = collections.deque(
+            maxlen=4096
+        )
+        self._clock = _TickClock(self.stats)
         self._pending: "queue.Queue[Request]" = queue.Queue()
         # Requests popped but not yet placeable (all slots busy) wait here,
         # at the FRONT, so admission stays FIFO under overload.  Scheduler-
@@ -623,6 +802,7 @@ class Scheduler:
             return small, tok
 
         @functools.partial(jax.jit, donate_argnums=(0,))
+        @jax.named_scope("kv_write")
         def _graft_rows(big, small, rows, slots):
             """Copy prefilled KV rows of the small cache into their slots
             of the big cache — one scatter per leaf for the whole
@@ -677,18 +857,20 @@ class Scheduler:
                 mesh=mesh_arg,
                 kv_bucket=kv_bucket,
             )
-            cache = tuple(
-                jax.lax.dynamic_update_slice(
-                    bg, r, (0, 0, slot) + (0,) * (bg.ndim - 3)
+            with jax.named_scope("kv_write"):
+                cache = tuple(
+                    jax.lax.dynamic_update_slice(
+                        bg, r, (0, 0, slot) + (0,) * (bg.ndim - 3)
+                    )
+                    for bg, r in zip(cache, row)
                 )
-                for bg, r in zip(cache, row)
-            )
             last = hidden[0, jnp.maximum(suffix_len - 1, 0)]
             lg = llama.logits(params, last[None, None, :])[:, 0]
             tok = sample(lg, key, temp, top_p, top_k)
             return cache, tok
 
         @functools.partial(jax.jit, donate_argnums=(0,), static_argnums=(3,))
+        @jax.named_scope("kv_write")
         def _graft_prefix(cache, src, dst, n):
             """Copy the first ``n`` cache rows of slot ``src`` into slot
             ``dst`` — the shared-prefix cache hit's device op.
@@ -723,6 +905,7 @@ class Scheduler:
             pages_len_arg = self.max_len
 
             @functools.partial(jax.jit, donate_argnums=(0,))
+            @jax.named_scope("kv_write")
             def _graft_rows_paged(big, small, rows, phys):
                 """Paged twin of ``_graft_rows``: cold-prefilled rows of
                 the small contiguous cache scatter to the PHYSICAL pool
@@ -824,12 +1007,13 @@ class Scheduler:
                     mesh=mesh_arg,
                     kv_bucket=kv_bucket,
                 )
-                return tuple(
-                    jax.lax.dynamic_update_slice(
-                        bg, r, (0, 0, slot) + (0,) * (bg.ndim - 3)
+                with jax.named_scope("kv_write"):
+                    return tuple(
+                        jax.lax.dynamic_update_slice(
+                            bg, r, (0, 0, slot) + (0,) * (bg.ndim - 3)
+                        )
+                        for bg, r in zip(cache, row)
                     )
-                    for bg, r in zip(cache, row)
-                )
 
             self._prefill_draft_suffix = _prefill_draft_suffix
 
@@ -887,6 +1071,18 @@ class Scheduler:
             or not self._running
             or self._thread.is_alive()
         )
+
+    def tick_records(self, limit: int = 100) -> list[dict]:
+        """The newest ``limit`` busy ticks, oldest first: tick number,
+        start (perf_counter and wall clock), seconds in each phase,
+        starved seconds, warming chunks dispatched, requests claimed,
+        decode lanes and their attention window, tokens emitted and the
+        queue depth at the tick's end.  What a stall is read from."""
+        if limit <= 0:
+            return []
+        return [
+            dict(zip(TICK_RECORD_FIELDS, r)) for r in list(self._ticks)[-limit:]
+        ]
 
     # -- internals ---------------------------------------------------------
 
@@ -1040,8 +1236,38 @@ class Scheduler:
         TAIL — recency matters for chat/RAG prompts).  The bound keeps
         prompt KV clear of the append-buffer flush-clip zone a pipelined
         tick can garbage-write for lanes admitted the same tick."""
-        if len(req.token_ids) >= self._admit_limit:
+        n = len(req.token_ids)
+        if n >= self._admit_limit:
             req.token_ids = req.token_ids[-(self._admit_limit - 1) :]
+            with self.stats.lock:
+                self.stats.prompts_clipped += 1
+                self.stats.prompt_tokens_clipped += n - len(req.token_ids)
+            logger.warning(
+                "request %s: prompt of %d tokens clipped to its last %d",
+                req.id or "<no id>", n, len(req.token_ids),
+            )
+
+    def _note_claim(self, reqs: Sequence[Request]) -> None:
+        """A slot was claimed for each of ``reqs``: stamp it and count
+        its queue wait.  Every admission path claims through
+        ``_admit_dispatch``, ``_admit_hit`` or ``_claim_warm_cold``."""
+        now = time.perf_counter()
+        self._tick_admitted += len(reqs)
+        with self.stats.lock:
+            for req in reqs:
+                req.claimed_at = now
+                self.stats.queue_wait_s_sum += now - req.submitted_at
+                self.stats.queue_wait_count += 1
+                self.stats.prompt_tokens_admitted += len(req.token_ids)
+
+    def _note_first_token(self, req: Request) -> None:
+        """``req``'s first token was fetched: TTFT and its prefill part.
+        Caller holds the stats lock."""
+        self.stats.requests_total += 1
+        self.stats.ttft_sum += req.first_token_at - req.submitted_at
+        self.stats.ttft_count += 1
+        self.stats.warm_s_sum += req.first_token_at - req.claimed_at
+        self.stats.warm_count += 1
 
     def _finish(self, slot_idx: int, reason: str) -> None:
         # Publish deferred token counts before on_done fires: a caller
@@ -1162,13 +1388,16 @@ class Scheduler:
         and the batch's first tokens are fetchable ~RTT+prefill into the tick —
         ahead of the decode chunk — which keeps the decode chunk off
         every request's TTFT critical path."""
-        t_admit0 = time.perf_counter()
         plens = []
         for req in reqs:
             self._clip_prompt(req)
             plens.append(len(req.token_ids))
+        self._note_claim(reqs)
         pb = bucket_size(len(reqs), minimum=min(4, self.max_batch))
         s = min(bucket_size(max(plens), dense=True), self.max_len)
+        with self.stats.lock:
+            self.stats.prefill_tokens_dispatched += sum(plens)
+            self.stats.prefill_tokens_padded += pb * s - sum(plens)
         tokens = np.zeros((pb, s), dtype=np.int32)
         lengths = np.zeros((pb,), dtype=np.int32)
         temp = np.zeros((pb,), dtype=np.float32)
@@ -1180,6 +1409,10 @@ class Scheduler:
             temp[r] = req.sampling.temperature
             top_p[r] = req.sampling.top_p
             top_k[r] = req.sampling.top_k
+        self._clock.enter(
+            "dispatch", program="_prefill_some", tokens=sum(plens),
+            rows=pb, bucket=s,
+        )
         small, tok = self._prefill_some(
             self.params,
             jnp.asarray(tokens),
@@ -1240,6 +1473,8 @@ class Scheduler:
             self._dcache = self._graft_rows(
                 self._dcache, dsmall, jnp.asarray(rows), jnp.asarray(slots_arr)
             )
+        ticket = self._clock.dispatched()
+        self._clock.enter("plan")
         for r, (req, slot_idx) in enumerate(zip(reqs, slot_idxs)):
             slot = self._slots[slot_idx]
             slot.request = req
@@ -1247,31 +1482,31 @@ class Scheduler:
             slot.emitted = 0
             slot.history = list(req.token_ids)
             slot.accept_ewma = 1.0
-        return reqs, slot_idxs, tok, t_admit0
+        return reqs, slot_idxs, tok, ticket
 
     def _admit_finalize(
         self,
         reqs: Sequence[Request],
         slot_idxs: Sequence[int],
         tok,
-        t_admit0: float,
+        ticket: int,
     ) -> None:
         """Fetch a dispatched admission batch's first tokens and emit them."""
+        self._clock.enter("wait_device")
         tok_host = np.asarray(tok)
+        self._clock.fetched(ticket)
+        self._clock.enter("emit")
         now = time.perf_counter()
         for r, (req, slot_idx) in enumerate(zip(reqs, slot_idxs)):
             req.first_token_at = now
             with self.stats.lock:
                 self.stats.queued -= 1
-                self.stats.requests_total += 1
-                self.stats.ttft_sum += req.first_token_at - req.submitted_at
-                self.stats.ttft_count += 1
+                self._note_first_token(req)
             observe_stage(
                 "llm_ttft", (req.first_token_at - req.submitted_at) * 1000.0
             )
             self._handle_token(slot_idx, int(tok_host[r]))
         with self.stats.lock:
-            self.stats.prefill_s += time.perf_counter() - t_admit0
             self.stats.prefill_rows += len(reqs)
 
     # Minimum shared-prefix length for the suffix-prefill path; below this
@@ -1338,7 +1573,6 @@ class Scheduler:
         """Dispatch a suffix prefill into ``slot_idx`` (whose cache rows
         already hold KV for ``common`` prompt tokens) without blocking;
         claims the slot.  Returns args for :meth:`_suffix_finalize`."""
-        t0 = time.perf_counter()
         plen = len(req.token_ids)
         suffix = req.token_ids[common:]
         s = min(bucket_size(len(suffix), minimum=16, dense=True), self.max_len)
@@ -1346,6 +1580,7 @@ class Scheduler:
         tokens[0, : len(suffix)] = suffix
         kv_bucket = bucket_size(common + s, maximum=self.max_len, dense=True)
         sp = req.sampling
+        self._prefill_suffix_begin(len(suffix), s, kv_bucket)
         sampling_dev = (
             jnp.asarray([sp.temperature], dtype=jnp.float32),
             jnp.asarray([sp.top_p], dtype=jnp.float32),
@@ -1404,6 +1639,8 @@ class Scheduler:
             row = np.zeros((self.max_len,), np.int32)
             row[:plen] = req.token_ids
             self._dhist = self._dhist.at[slot_idx].set(jnp.asarray(row))
+        ticket = self._clock.dispatched()
+        self._clock.enter("plan")
         slot = self._slots[slot_idx]
         slot.request = req
         slot.length = plen
@@ -1411,17 +1648,28 @@ class Scheduler:
         slot.history = list(req.token_ids)
         slot.warm_pos = None
         slot.accept_ewma = 1.0
-        return req, slot_idx, tok, t0
+        return req, slot_idx, tok, ticket
 
-    def _suffix_finalize(self, req, slot_idx, tok, t0) -> None:
+    def _prefill_suffix_begin(self, n: int, s: int, kv_bucket: int) -> None:
+        """Count a ``_prefill_suffix`` dispatch of ``n`` real tokens in a
+        bucket of ``s`` and enter the dispatch phase."""
+        with self.stats.lock:
+            self.stats.prefill_tokens_dispatched += n
+            self.stats.prefill_tokens_padded += s - n
+        self._clock.enter(
+            "dispatch", program="_prefill_suffix", tokens=n, bucket=s,
+            kv_bucket=kv_bucket,
+        )
+
+    def _suffix_finalize(self, req, slot_idx, tok, ticket) -> None:
         """Fetch a suffix prefill's first token and emit it."""
+        self._clock.enter("wait_device")
         tok_host = int(np.asarray(tok)[0])
+        self._clock.fetched(ticket)
+        self._clock.enter("emit")
         req.first_token_at = time.perf_counter()
         with self.stats.lock:
-            self.stats.requests_total += 1
-            self.stats.ttft_sum += req.first_token_at - req.submitted_at
-            self.stats.ttft_count += 1
-            self.stats.prefill_s += req.first_token_at - t0
+            self._note_first_token(req)
             self.stats.prefill_rows += 1
         observe_stage(
             "llm_ttft", (req.first_token_at - req.submitted_at) * 1000.0
@@ -1443,6 +1691,7 @@ class Scheduler:
         chunk in a later tick)."""
         plen = len(req.token_ids)
         common = min(common, plen - 1, self._admit_limit - 2)
+        self._note_claim([req])
         with self.stats.lock:
             self.stats.queued -= 1
             if shared:
@@ -1516,6 +1765,10 @@ class Scheduler:
             dtok = np.zeros((1, s), dtype=np.int32)
             dtok[0, :common] = req.token_ids[:common]
             kv_bucket = bucket_size(s, maximum=self.max_len, dense=True)
+            self._clock.enter(
+                "dispatch", program="_prefill_draft_suffix", tokens=common,
+                bucket=s,
+            )
             self._dcache = self._prefill_draft_suffix(
                 self.draft_params,
                 self._dcache,
@@ -1525,6 +1778,8 @@ class Scheduler:
                 jnp.int32(slot_idx),
                 kv_bucket,
             )
+            self._clock.dispatched()
+            self._clock.enter("plan")
         return True, self._admit_hit(req, slot_idx, common, shared=shared)
 
     def _admit_pages_ok(
@@ -1592,6 +1847,7 @@ class Scheduler:
         n = min(
             bucket_size(common, minimum=16, dense=True), self.max_len
         )
+        self._clock.enter("dispatch", program="_graft_prefix", rows=n)
         self._cache = self._graft_prefix(
             self._cache, jnp.int32(src), jnp.int32(dst), n
         )
@@ -1604,6 +1860,8 @@ class Scheduler:
             self._dcache = self._graft_prefix(
                 self._dcache, jnp.int32(src), jnp.int32(dst), n
             )
+        self._clock.dispatched()
+        self._clock.enter("plan")
         self._prefix_index.touch(src)
 
     def _claim_warm(self, req: Request, slot_idx: int, start: int) -> None:
@@ -1630,6 +1888,7 @@ class Scheduler:
 
     def _claim_warm_cold(self, req: Request, slot_idx: int) -> None:
         """Cold chunked admission: claim + account (no cached prefix)."""
+        self._note_claim([req])
         with self.stats.lock:
             self.stats.queued -= 1
         self._claim_warm(req, slot_idx, 0)
@@ -1650,9 +1909,10 @@ class Scheduler:
         if req is None or slot.warm_pos is None:
             return None, 0
         if req.id and self._is_cancelled(req.id):
+            self._clock.enter("emit")
             self._finish(slot_idx, "cancelled")
+            self._clock.enter("plan")
             return None, 0
-        t0 = time.perf_counter()
         pos = slot.warm_pos
         plen = slot.length
         n = min(self.prefill_chunk_tokens, plen - pos)
@@ -1662,6 +1922,7 @@ class Scheduler:
         tokens[0, :n] = chunk
         kv_bucket = bucket_size(pos + s, maximum=self.max_len, dense=True)
         sp = req.sampling
+        self._prefill_suffix_begin(n, s, kv_bucket)
         sampling_dev = (
             jnp.asarray([sp.temperature], dtype=jnp.float32),
             jnp.asarray([sp.top_p], dtype=jnp.float32),
@@ -1712,6 +1973,9 @@ class Scheduler:
                 jnp.int32(slot_idx),
                 kv_bucket,
             )
+        ticket = self._clock.dispatched()
+        self._clock.enter("plan")
+        self._tick_chunks += 1
         with self.stats.lock:
             self.stats.prefill_chunks += 1
         if pos + n < plen:
@@ -1719,7 +1983,7 @@ class Scheduler:
             return None, n
         # Final chunk: prefill complete — the slot joins decode next tick.
         slot.warm_pos = None
-        return lambda: self._suffix_finalize(req, slot_idx, tok, t0), n
+        return lambda: self._suffix_finalize(req, slot_idx, tok, ticket), n
 
     def _handle_token(self, slot_idx: int, tid: int) -> None:
         """Process one sampled token for a slot; may finish the slot."""
@@ -1760,67 +2024,99 @@ class Scheduler:
             self.max_batch,
             self.decode_chunk_size,
         )
+        clock = self._clock
+        clock.start("plan")
         while self._running:
-            tick_t0 = time.perf_counter()
-            # Gray-failure chaos hook: `replica:latency=ms,index=i`
-            # slows exactly this scheduler's ticks.  Inside the timed
-            # region so the injected latency lands in tick_ms and the
-            # brownout scorer can see the straggler it creates.
-            inject_replica(self.replica_index)
-            try:
-                self._tick()
-            except Exception:
-                # A failing request must not take the serving loop down:
-                # fail every in-flight request, keep serving new ones.
-                logger.exception("scheduler tick failed; failing active slots")
-                # Every slot with a live request — warming (mid chunked
-                # prefill) included: a warming slot left behind would hold
-                # its slot forever with no tick ever advancing it.
-                for i, s in enumerate(self._slots):
-                    if s.request is not None:
-                        self._finish(i, "error")
-                # A fault mid-step can leave the donated cache deleted;
-                # reallocate so the next tick starts from clean buffers.
-                # Parked prefix caches died with the old buffers — unpark
-                # them all, or the next prefix hit would suffix-prefill on
-                # zeroed KV and stream silently wrong tokens.
-                for i, s in enumerate(self._slots):
-                    if s.cached:
-                        self._unpark(i)
-                if self._pool is not None:
-                    # Parked page segments die with the pool: clear the
-                    # index and session maps IN THE SAME recovery as the
-                    # pool's full wipe (refcounts, free list, tables,
-                    # fresh zero leaves — the old ones may have been
-                    # donated away by the faulted dispatch), or a later
-                    # hit would reference recycled pages.
-                    self._prefix_index.clear()
-                    self._session_segs.clear()
-                    self._seg_sessions.clear()
-                    self._pool.reset_all()
-                    self._cache = self._pool.leaves
-                else:
-                    from generativeaiexamples_tpu.engine.decode import (
-                        prepare_cache,
-                    )
-
-                    self._cache = prepare_cache(
-                        self.cfg, self.max_batch, self.max_len, self.mesh
-                    )
-                if self.draft_cfg is not None:
-                    self._dcache = prepare_cache(
-                        self.draft_cfg, self.max_batch, self.max_len,
-                        self.mesh,
-                    )
-                if self._dhist is not None:
-                    # The n-gram history is donated through the chunk the
-                    # same way the caches are — a fault mid-step can
-                    # leave it deleted too.
-                    self._dhist = jnp.zeros(
-                        (self.max_batch, self.max_len), jnp.int32
-                    )
-            self._note_tick((time.perf_counter() - tick_t0) * 1000.0)
+            self._tick_no += 1
+            # One step on the tick thread's line of the profiler's host
+            # plane; the phase annotations nest inside it.
+            with jax.profiler.StepTraceAnnotation(
+                "tick", step_num=self._tick_no
+            ):
+                clock.enter("plan")
+                self._run_tick()
+                clock.end_span()
+        clock.stop()
         logger.info("scheduler stopped")
+
+    def _run_tick(self) -> None:
+        """One pass of the tick loop: the tick, recovery if it raised,
+        telemetry, and the tick's record if it touched the device."""
+        clock = self._clock
+        tick_t0 = time.perf_counter()
+        wall_t0 = time.time()
+        before = clock.sums()
+        # Gray-failure chaos hook: `replica:latency=ms,index=i`
+        # slows exactly this scheduler's ticks.  Inside the timed
+        # region so the injected latency lands in tick_ms and the
+        # brownout scorer can see the straggler it creates.
+        inject_replica(self.replica_index)
+        try:
+            self._tick()
+        except Exception:
+            clock.enter("emit")
+            # A failing request must not take the serving loop down:
+            # fail every in-flight request, keep serving new ones.
+            logger.exception("scheduler tick failed; failing active slots")
+            # Every slot with a live request — warming (mid chunked
+            # prefill) included: a warming slot left behind would hold
+            # its slot forever with no tick ever advancing it.
+            for i, s in enumerate(self._slots):
+                if s.request is not None:
+                    self._finish(i, "error")
+            # A fault mid-step can leave the donated cache deleted;
+            # reallocate so the next tick starts from clean buffers.
+            # Parked prefix caches died with the old buffers — unpark
+            # them all, or the next prefix hit would suffix-prefill on
+            # zeroed KV and stream silently wrong tokens.
+            for i, s in enumerate(self._slots):
+                if s.cached:
+                    self._unpark(i)
+            if self._pool is not None:
+                # Parked page segments die with the pool: clear the
+                # index and session maps IN THE SAME recovery as the
+                # pool's full wipe (refcounts, free list, tables,
+                # fresh zero leaves — the old ones may have been
+                # donated away by the faulted dispatch), or a later
+                # hit would reference recycled pages.
+                self._prefix_index.clear()
+                self._session_segs.clear()
+                self._seg_sessions.clear()
+                self._pool.reset_all()
+                self._cache = self._pool.leaves
+            else:
+                from generativeaiexamples_tpu.engine.decode import (
+                    prepare_cache,
+                )
+
+                self._cache = prepare_cache(
+                    self.cfg, self.max_batch, self.max_len, self.mesh
+                )
+            if self.draft_cfg is not None:
+                self._dcache = prepare_cache(
+                    self.draft_cfg, self.max_batch, self.max_len,
+                    self.mesh,
+                )
+            if self._dhist is not None:
+                # The n-gram history is donated through the chunk the
+                # same way the caches are — a fault mid-step can
+                # leave it deleted too.
+                self._dhist = jnp.zeros(
+                    (self.max_batch, self.max_len), jnp.int32
+                )
+        clock.enter("telemetry")
+        self._note_tick((time.perf_counter() - tick_t0) * 1000.0)
+        if self._tick_busy:
+            spent = tuple(
+                b - a for a, b in zip(before, clock.sums())
+            )
+            self._ticks.append(
+                (self._tick_no, tick_t0, wall_t0) + spent + (
+                    self._tick_chunks, self._tick_admitted,
+                    self._tick_decoded, self._tick_kv_bucket,
+                    self._tick_tokens, self.stats.queued,
+                )
+            )
 
     # Snapshot counters mirrored into the TSDB as per-interval deltas, so
     # /debug/timeseries shows their history (rates at read time) instead
@@ -1958,6 +2254,10 @@ class Scheduler:
         progressed = False
         self._tick_tokens = 0
         self._tick_decoded = 0
+        self._tick_chunks = 0
+        self._tick_admitted = 0
+        self._tick_kv_bucket = 0
+        self._tick_busy = False
         if self._pool is not None:
             self._kv_pages_reserved = 0
         if self._pool is not None and (
@@ -2201,16 +2501,25 @@ class Scheduler:
             # whose latency the budget would protect.
             req = self._next_pending()
             if req is None:
+                self._clock.enter("idle")
                 try:
                     req = self._pending.get(timeout=0.05)
                 except queue.Empty:
                     return
+                finally:
+                    self._clock.enter("plan")
             if self._drop_if_cancelled(req):
                 return
-            if not self._admit_request_now(req):
+            if self._admit_request_now(req):
+                progressed = True
+            else:
                 # Every slot parked/busy and none reclaimable this tick:
                 # keep the request waiting at the front, not dropped.
                 self._backlog.appendleft(req)
+        if progressed:
+            self._tick_busy = True
+            with self.stats.lock:
+                self.stats.busy_ticks += 1
 
     def _admit_request_now(self, req: Request) -> bool:
         """Idle-path admission: route one request through the same
@@ -2390,7 +2699,6 @@ class Scheduler:
         ``_verify_and_emit`` holds them to one garbage token per round
         whose writes land only in the tail flush zone that
         ``_admit_limit`` keeps clear of live KV."""
-        t_dec0 = time.perf_counter()
         lengths, temp, top_p, top_k, max_active = self._lane_state()
         snap = np.zeros((self.max_batch,), dtype=bool)
         snap[active] = True
@@ -2402,6 +2710,11 @@ class Scheduler:
         rounds = max(1, -(-self.decode_chunk_size // (g + 1)))
         kv_bucket = bucket_size(
             max_active + rounds * (g + 1) + 1, maximum=self.max_len
+        )
+        self._tick_kv_bucket = kv_bucket
+        self._clock.enter(
+            "dispatch", program="spec_chunk", lanes=len(active),
+            kv_bucket=kv_bucket, gamma=g,
         )
         table = None
         if self._pool is not None:
@@ -2489,16 +2802,21 @@ class Scheduler:
                     kv_bucket,
                 )
                 self._cache = tcache
-        return outs, n_emits, active, g, t_dec0
+        ticket = self._clock.dispatched()
+        self._clock.enter("plan")
+        return outs, n_emits, active, g, ticket
 
-    def _spec_finalize(self, outs, n_emits, active, gamma_used, t_dec0):
+    def _spec_finalize(self, outs, n_emits, active, gamma_used, ticket):
         """Fetch a dispatched speculative chunk and emit its tokens.
 
         Only lanes in the dispatch snapshot update ``_cur_tok`` — lanes
         admitted behind the dispatch keep the first token their prefill
         wrote (same masked-update contract as ``_decode_finalize``)."""
+        self._clock.enter("wait_device")
         outs_h = np.asarray(outs)
         n_h = np.asarray(n_emits)
+        self._clock.fetched(ticket)
+        self._clock.enter("emit")
         last = outs_h[
             -1, np.arange(self.max_batch), np.maximum(n_h[-1] - 1, 0)
         ]
@@ -2516,7 +2834,6 @@ class Scheduler:
                 if slot.request is not None and slot.warm_pos is None:
                     self._pool.trim(i, slot.length + slot.emitted)
         with self.stats.lock:
-            self.stats.decode_s += time.perf_counter() - t_dec0
             self.stats.decode_chunks += 1
 
     def _consume_spec_outs(
@@ -2594,9 +2911,10 @@ class Scheduler:
         after that snapshot still hold a device-future first token, so
         this chunk must neither read their ``_cur_tok`` nor emit their
         lanes."""
-        t_dec0 = time.perf_counter()
         lengths, temp, top_p, top_k, max_active = self._lane_state()
-        if active is not None:
+        if active is None:
+            active = self._active()
+        else:
             # Lanes outside the emission snapshot (freshly admitted this
             # tick, emitted still 0) would garbage-write at length-1 —
             # INSIDE the prompt KV the graft just landed.  Pin their
@@ -2615,11 +2933,16 @@ class Scheduler:
             max_active + self.decode_chunk_size + 1,
             maximum=self.max_len,
         )
+        self._tick_kv_bucket = kv_bucket
+        self._clock.enter(
+            "dispatch", program="decode_chunk", lanes=len(active),
+            kv_bucket=kv_bucket,
+        )
         if self._pool is not None:
             # Pages for the chunk's write range per live lane; inactive
             # and pinned lanes write the garbage page through their
             # unowned tail entries, so they need nothing here.
-            for i in active if active is not None else self._active():
+            for i in active:
                 slot = self._slots[i]
                 live = slot.length + slot.emitted
                 self._pool.make_writable(
@@ -2656,16 +2979,21 @@ class Scheduler:
                 kv_bucket,
             )
             self._cache = cache
-        return toks, self._active() if active is None else active, t_dec0
+        ticket = self._clock.dispatched()
+        self._clock.enter("plan")
+        return toks, active, ticket
 
-    def _decode_finalize(self, toks, active: list[int], t_dec0: float) -> None:
+    def _decode_finalize(self, toks, active: list[int], ticket: int) -> None:
         """Fetch a dispatched decode chunk's tokens and emit them.
 
         ``active`` is the slot set snapshotted at dispatch: slots admitted
         after the dispatch (pipelined tick) were not decoded by this chunk
         and must keep the first token their prefill just wrote into
         ``_cur_tok`` — hence the masked update rather than a full copy."""
+        self._clock.enter("wait_device")
         toks_host = np.asarray(toks)  # (chunk, b)
+        self._clock.fetched(ticket)
+        self._clock.enter("emit")
         if active:
             self._cur_tok[active] = toks_host[-1][active]
         for row in toks_host:
@@ -2674,5 +3002,4 @@ class Scheduler:
                     self._handle_token(i, int(row[i]))
         self._flush_tokens()
         with self.stats.lock:
-            self.stats.decode_s += time.perf_counter() - t_dec0
             self.stats.decode_chunks += 1

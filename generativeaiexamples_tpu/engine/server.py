@@ -35,7 +35,12 @@ from aiohttp import web
 
 from generativeaiexamples_tpu.core.logging import get_logger
 from generativeaiexamples_tpu.engine.sampler import SamplingParams
-from generativeaiexamples_tpu.engine.scheduler import Request, Scheduler
+from generativeaiexamples_tpu.engine.scheduler import (
+    STARVED_PHASES,
+    TICK_PHASES,
+    Request,
+    Scheduler,
+)
 
 # Profiler endpoints live in ``obs/profiler.py`` so the chain server can
 # register the same handlers; these re-exports keep this module's
@@ -90,6 +95,27 @@ def _decode_stream(tokenizer):
         return tokenizer.decode([tid])
 
     return piece
+
+
+def _add_lifecycle_stages(request: web.Request, req: "Request") -> None:
+    """Where a finished generation's time went, from the scheduler's
+    stamps on ``req``: ``queue_wait`` (submit to slot claim), ``prefill``
+    (claim to first token fetched) and ``decode`` (first token to now) on
+    the request's trace — one ``add_stage`` each, at the end, not per
+    token.  A request that never reached a stamp ends at the last one."""
+    trace = request.get(TRACE_KEY)
+    if trace is None:
+        return
+    marks = [
+        ("queue_wait", req.submitted_at),
+        ("prefill", req.claimed_at),
+        ("decode", req.first_token_at),
+    ]
+    ends = [t for _, t in marks[1:]] + [time.perf_counter()]
+    for (stage, start), end in zip(marks, ends):
+        if start is None or end is None:
+            break
+        trace.add_stage(stage, (end - start) * 1000.0, start=start)
 
 
 async def _stream_generation(
@@ -153,6 +179,7 @@ async def _stream_generation(
         # Client disconnects release the slot too.
         if not completed:
             scheduler.cancel(req.id)
+        _add_lifecycle_stages(request, req)
     await resp.write_eof()
     return resp
 
@@ -288,6 +315,7 @@ async def handle_chat_completions(request: web.Request) -> web.StreamResponse:
     text, n_tokens, finish = await _aggregate_generation(
         bridge, piece, stop, scheduler, req.id
     )
+    _add_lifecycle_stages(request, req)
     if finish == "error":
         return _retryable_error_response()
     return web.json_response(
@@ -465,6 +493,7 @@ async def handle_completions(request: web.Request) -> web.StreamResponse:
     text, n_tokens, finish = await _aggregate_generation(
         bridge, piece, stop, scheduler, req.id
     )
+    _add_lifecycle_stages(request, req)
     if finish == "error":
         return _retryable_error_response()
     return web.json_response(
@@ -728,6 +757,40 @@ async def handle_metrics(request: web.Request) -> web.Response:
         "# TYPE engine_kv_page_evictions_total counter",
         f"engine_kv_page_evictions_total {snap.get('kv_page_evictions', 0)}",
     ]
+    # Where the tick thread's time goes (exclusive phases: the six sum
+    # to its wall time), how long it left the device with nothing
+    # queued, and the request lifecycle.  ``.get`` keeps engine stubs
+    # without these keys exporting zeros.
+    lines.append("# TYPE engine_tick_phase_seconds_total counter")
+    lines += [
+        f'engine_tick_phase_seconds_total{{phase="{p}"}} '
+        f"{snap.get(f'tick_phase_{p}_s', 0.0):.6f}"
+        for p in TICK_PHASES
+    ]
+    lines.append("# TYPE engine_device_starved_seconds_total counter")
+    lines += [
+        f'engine_device_starved_seconds_total{{phase="{p}"}} '
+        f"{snap.get(f'device_starved_{p}_s', 0.0):.6f}"
+        for p in STARVED_PHASES
+    ]
+    for name, key, fmt in (
+        ("engine_busy_ticks_total", "busy_ticks", "d"),
+        ("engine_queue_wait_seconds_total", "queue_wait_s_sum", ".6f"),
+        ("engine_queue_wait_count_total", "queue_wait_count", "d"),
+        ("engine_warm_seconds_total", "warm_s_sum", ".6f"),
+        ("engine_warm_count_total", "warm_count", "d"),
+        ("engine_prompt_tokens_admitted_total", "prompt_tokens_admitted", "d"),
+        ("engine_prompts_clipped_total", "prompts_clipped", "d"),
+        ("engine_prompt_tokens_clipped_total", "prompt_tokens_clipped", "d"),
+        (
+            "engine_prefill_tokens_dispatched_total",
+            "prefill_tokens_dispatched",
+            "d",
+        ),
+        ("engine_prefill_tokens_padded_total", "prefill_tokens_padded", "d"),
+    ):
+        lines.append(f"# TYPE {name} counter")
+        lines.append(f"{name} {format(snap.get(key, 0), fmt)}")
     # Which serving matmul path is live (info-style gauge: every known
     # value exported, the active one carrying 1) — deployments can alert
     # on the fused kernel silently falling back to XLA.  From zero:
@@ -933,6 +996,34 @@ async def handle_admin_scale(request: web.Request) -> web.Response:
     return web.json_response(result)
 
 
+async def handle_debug_ticks(request: web.Request) -> web.Response:
+    """``GET /debug/ticks?limit=N``: the newest N busy ticks of the
+    scheduler (``Scheduler.tick_records``), oldest first; for a replica
+    pool, of each replica.  What a stall is read from: which phase the
+    tick thread was in, and for how long."""
+    try:
+        limit = int(request.query.get("limit", "100"))
+    except ValueError:
+        return web.json_response(
+            {"detail": "limit must be an integer"}, status=422
+        )
+    engine = request.app[SCHED_KEY]
+    if hasattr(engine, "replicas"):
+        return web.json_response(
+            {
+                "replicas": [
+                    {
+                        "replica": rep.idx,
+                        "ticks": rep.scheduler.tick_records(limit),
+                    }
+                    for rep in engine.replicas
+                ]
+            }
+        )
+    ticks = engine.tick_records(limit)
+    return web.json_response({"ticks": ticks, "count": len(ticks)})
+
+
 def create_engine_app(
     scheduler,
     tokenizer,
@@ -969,6 +1060,7 @@ def create_engine_app(
     app.router.add_post("/admin/drain", handle_admin_drain)
     app.router.add_post("/admin/scale", handle_admin_scale)
     app.router.add_get("/debug/requests", handle_debug_requests)
+    app.router.add_get("/debug/ticks", handle_debug_ticks)
     app.router.add_get("/debug/timeseries", handle_debug_timeseries)
     if enable_profiler:
         app.router.add_post("/debug/profiler/start", handle_profiler_start)

@@ -588,6 +588,7 @@ def embed(params: Params, tokens: jnp.ndarray, dtype) -> jnp.ndarray:
     return jnp.take(table, tokens, axis=0).astype(dtype)
 
 
+@jax.named_scope("kv_write")
 def _quantize_kv(x: jnp.ndarray) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Per-(token, head) symmetric int8: x (b, s, n_kv, hd) -> (q8, scale).
 
@@ -650,54 +651,58 @@ def _moe_mlp(
         cap = max(8, int(cfg.expert_capacity_factor * s * k / E + 0.999))
         cap = min(cap, s)
 
-    router_logits = q_dot(h, lp["router"], "router").astype(jnp.float32)  # (b, s, E)
-    probs = jax.nn.softmax(router_logits, axis=-1)
-    gate_w, gate_idx = jax.lax.top_k(probs, k)  # (b, s, k)
-    gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("layer/moe/router"):
+        router_logits = q_dot(h, lp["router"], "router").astype(
+            jnp.float32
+        )  # (b, s, E)
+        probs = jax.nn.softmax(router_logits, axis=-1)
+        gate_w, gate_idx = jax.lax.top_k(probs, k)  # (b, s, k)
+        gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
 
-    onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)  # (b, s, k, E)
-    onehot = onehot * valid[:, :, None, None]  # pads never claim capacity
-    # Load-balancing aux: fraction of routed choices per expert × mean
-    # router probability per expert (valid tokens only), scaled so uniform
-    # routing gives 1.
-    valid_row = jnp.maximum(valid.sum(axis=1), 1.0)  # (b,)
-    frac = onehot.sum(axis=(1, 2)) / (valid_row * k)[:, None]  # (b, E)
-    mean_prob = (probs * valid[:, :, None]).sum(axis=1) / valid_row[:, None]
-    aux_loss = (E * (frac * mean_prob).sum(-1)).mean()
+        onehot = jax.nn.one_hot(gate_idx, E, dtype=jnp.float32)  # (b, s, k, E)
+        onehot = onehot * valid[:, :, None, None]  # pads never claim capacity
+        # Load-balancing aux: fraction of routed choices per expert × mean
+        # router probability per expert (valid tokens only), scaled so uniform
+        # routing gives 1.
+        valid_row = jnp.maximum(valid.sum(axis=1), 1.0)  # (b,)
+        frac = onehot.sum(axis=(1, 2)) / (valid_row * k)[:, None]  # (b, E)
+        mean_prob = (probs * valid[:, :, None]).sum(axis=1) / valid_row[:, None]
+        aux_loss = (E * (frac * mean_prob).sum(-1)).mean()
 
-    flat = onehot.reshape(b, s * k, E)
-    # Position of each (token, choice) within its expert's buffer: count of
-    # earlier assignments to the same expert.
-    pos = jnp.einsum(
-        "bte,bte->bt", jnp.cumsum(flat, axis=1) - flat, flat
-    ).astype(jnp.int32)
-    keep = (pos < cap).astype(jnp.float32)
-    pos_oh = jax.nn.one_hot(pos, cap, dtype=jnp.float32) * keep[..., None]
-    # dispatch[b, t, e, c] = 1 iff choice t routes to expert e at slot c;
-    # summing out the choice axis is lossless (pairs are unique) and
-    # yields the canonical (b, s, E, cap) GShard tensors.
-    disp_k = (flat[:, :, :, None] * pos_oh[:, :, None, :]).reshape(
-        b, s, k, E, cap
-    )
-    combine = (disp_k * gate_w[..., None, None]).sum(axis=2).astype(h.dtype)
-    disp = disp_k.sum(axis=2).astype(h.dtype)  # (b, s, E, cap)
-
-    x_e = jnp.einsum("bsec,bsd->becd", disp, h)  # (b, E, cap, d)
-    if mesh is not None:
-        from jax.sharding import NamedSharding
-
-        x_e = jax.lax.with_sharding_constraint(
-            x_e, NamedSharding(mesh, P("data", "expert", None, None))
+        flat = onehot.reshape(b, s * k, E)
+        # Position of each (token, choice) within its expert's buffer: count of
+        # earlier assignments to the same expert.
+        pos = jnp.einsum(
+            "bte,bte->bt", jnp.cumsum(flat, axis=1) - flat, flat
+        ).astype(jnp.int32)
+        keep = (pos < cap).astype(jnp.float32)
+        pos_oh = jax.nn.one_hot(pos, cap, dtype=jnp.float32) * keep[..., None]
+        # dispatch[b, t, e, c] = 1 iff choice t routes to expert e at slot c;
+        # summing out the choice axis is lossless (pairs are unique) and
+        # yields the canonical (b, s, E, cap) GShard tensors.
+        disp_k = (flat[:, :, :, None] * pos_oh[:, :, None, :]).reshape(
+            b, s, k, E, cap
         )
-    gated = jax.nn.silu(
-        jnp.einsum("becd,edf->becf", x_e, lp["w_gate_e"],
-                   preferred_element_type=jnp.float32).astype(h.dtype)
-    ) * jnp.einsum("becd,edf->becf", x_e, lp["w_up_e"],
-                   preferred_element_type=jnp.float32).astype(h.dtype)
-    y = jnp.einsum("becf,efd->becd", gated, lp["w_down_e"],
-                   preferred_element_type=jnp.float32).astype(h.dtype)
-    out = jnp.einsum("bsec,becd->bsd", combine, y)
-    out = out.reshape(b_orig, s_orig + pad, d)
+        combine = (disp_k * gate_w[..., None, None]).sum(axis=2).astype(h.dtype)
+        disp = disp_k.sum(axis=2).astype(h.dtype)  # (b, s, E, cap)
+
+    with jax.named_scope("layer/moe/experts"):
+        x_e = jnp.einsum("bsec,bsd->becd", disp, h)  # (b, E, cap, d)
+        if mesh is not None:
+            from jax.sharding import NamedSharding
+
+            x_e = jax.lax.with_sharding_constraint(
+                x_e, NamedSharding(mesh, P("data", "expert", None, None))
+            )
+        gated = jax.nn.silu(
+            jnp.einsum("becd,edf->becf", x_e, lp["w_gate_e"],
+                       preferred_element_type=jnp.float32).astype(h.dtype)
+        ) * jnp.einsum("becd,edf->becf", x_e, lp["w_up_e"],
+                       preferred_element_type=jnp.float32).astype(h.dtype)
+        y = jnp.einsum("becf,efd->becd", gated, lp["w_down_e"],
+                       preferred_element_type=jnp.float32).astype(h.dtype)
+        out = jnp.einsum("bsec,becd->bsd", combine, y)
+        out = out.reshape(b_orig, s_orig + pad, d)
     return out[:, :s_orig], aux_loss
 
 
@@ -740,30 +745,39 @@ def dense_layer(
             )
         n_q //= tp
         n_kv //= tp
-    h = block_norm(x, cfg, lp, "attn_norm")
-    q = _badd(q_dot(h, lp["wq"], "wq"), lp, "bq").reshape(b, s, n_q, hd)
-    k = _badd(q_dot(h, lp["wk"], "wk"), lp, "bk").reshape(b, s, n_kv, hd)
-    v = _badd(q_dot(h, lp["wv"], "wv"), lp, "bv").reshape(b, s, n_kv, hd)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
-    attn = attention(q, k, v, positions, kv_lengths, mesh=mesh)
-    attn_out = _badd(
-        q_dot(attn.reshape(b, s, n_q * hd), lp["wo"], "wo"), lp, "bo"
-    )
-    if tp_axis is not None:
-        attn_out = jax.lax.psum(attn_out, tp_axis)
-    x = _shard_activations(x + attn_out, mesh)
-    h = block_norm(x, cfg, lp, "mlp_norm")
-    if "w_gate" in lp:
-        gated = cfg.act_fn(
-            _badd(q_dot(h, lp["w_gate"], "w_gate"), lp, "b_gate")
-        ) * _badd(q_dot(h, lp["w_up"], "w_up"), lp, "b_up")
-    else:  # plain MLP: up -> act -> down
-        gated = cfg.act_fn(_badd(q_dot(h, lp["w_up"], "w_up"), lp, "b_up"))
-    mlp_out = _badd(q_dot(gated, lp["w_down"], "w_down"), lp, "b_down")
-    if tp_axis is not None:
-        mlp_out = jax.lax.psum(mlp_out, tp_axis)
-    return _shard_activations(x + mlp_out, mesh)
+    with jax.named_scope("layer/norm"):
+        h = block_norm(x, cfg, lp, "attn_norm")
+    with jax.named_scope("layer/qkv"):
+        q = _badd(q_dot(h, lp["wq"], "wq"), lp, "bq").reshape(b, s, n_q, hd)
+        k = _badd(q_dot(h, lp["wk"], "wk"), lp, "bk").reshape(b, s, n_kv, hd)
+        v = _badd(q_dot(h, lp["wv"], "wv"), lp, "bv").reshape(b, s, n_kv, hd)
+    with jax.named_scope("layer/rope"):
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+    with jax.named_scope("layer/attn"):
+        attn = attention(q, k, v, positions, kv_lengths, mesh=mesh)
+    with jax.named_scope("layer/wo"):
+        attn_out = _badd(
+            q_dot(attn.reshape(b, s, n_q * hd), lp["wo"], "wo"), lp, "bo"
+        )
+        if tp_axis is not None:
+            attn_out = jax.lax.psum(attn_out, tp_axis)
+        x = _shard_activations(x + attn_out, mesh)
+    with jax.named_scope("layer/norm"):
+        h = block_norm(x, cfg, lp, "mlp_norm")
+    with jax.named_scope("layer/mlp"):
+        if "w_gate" in lp:
+            gated = cfg.act_fn(
+                _badd(q_dot(h, lp["w_gate"], "w_gate"), lp, "b_gate")
+            ) * _badd(q_dot(h, lp["w_up"], "w_up"), lp, "b_up")
+        else:  # plain MLP: up -> act -> down
+            gated = cfg.act_fn(
+                _badd(q_dot(h, lp["w_up"], "w_up"), lp, "b_up")
+            )
+        mlp_out = _badd(q_dot(gated, lp["w_down"], "w_down"), lp, "b_down")
+        if tp_axis is not None:
+            mlp_out = jax.lax.psum(mlp_out, tp_axis)
+        return _shard_activations(x + mlp_out, mesh)
 
 
 def _shard_activations(x: jnp.ndarray, mesh) -> jnp.ndarray:
@@ -856,15 +870,16 @@ def forward(
     into pool pages).
     """
     b, s = tokens.shape
-    if embeds is not None:
-        x = embeds.astype(cfg.compute_dtype)
-    else:
-        x = embed(params, tokens, cfg.compute_dtype)
-    if cfg.scale_embeddings:
-        # Gemma: inputs scale by sqrt(d_model) in the activation dtype
-        # (HF applies the normalizer to inputs_embeds from any source).
-        x = x * jnp.asarray(cfg.d_model**0.5, x.dtype)
-    x = _shard_activations(x, mesh)
+    with jax.named_scope("embed"):
+        if embeds is not None:
+            x = embeds.astype(cfg.compute_dtype)
+        else:
+            x = embed(params, tokens, cfg.compute_dtype)
+        if cfg.scale_embeddings:
+            # Gemma: inputs scale by sqrt(d_model) in the activation dtype
+            # (HF applies the normalizer to inputs_embeds from any source).
+            x = x * jnp.asarray(cfg.d_model**0.5, x.dtype)
+        x = _shard_activations(x, mesh)
 
     n_q, n_kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     paged = page_table is not None
@@ -986,18 +1001,29 @@ def forward(
                 carry_x, lp, cfg, positions, kv_lengths, mesh
             )
             return (carry_x, kv, ab, li + 1, aux), None
-        h = block_norm(carry_x, cfg, lp, "attn_norm")
-        if "wqkv" in lp:
-            qkv = q_dot(h, lp["wqkv"], "wqkv")
-            q = qkv[..., : n_q * hd].reshape(b, s, n_q, hd)
-            k = qkv[..., n_q * hd : (n_q + n_kv) * hd].reshape(b, s, n_kv, hd)
-            v = qkv[..., (n_q + n_kv) * hd :].reshape(b, s, n_kv, hd)
-        else:
-            q = _badd(q_dot(h, lp["wq"], "wq"), lp, "bq").reshape(b, s, n_q, hd)
-            k = _badd(q_dot(h, lp["wk"], "wk"), lp, "bk").reshape(b, s, n_kv, hd)
-            v = _badd(q_dot(h, lp["wv"], "wv"), lp, "bv").reshape(b, s, n_kv, hd)
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        with jax.named_scope("layer/norm"):
+            h = block_norm(carry_x, cfg, lp, "attn_norm")
+        with jax.named_scope("layer/qkv"):
+            if "wqkv" in lp:
+                qkv = q_dot(h, lp["wqkv"], "wqkv")
+                q = qkv[..., : n_q * hd].reshape(b, s, n_q, hd)
+                k = qkv[..., n_q * hd : (n_q + n_kv) * hd].reshape(
+                    b, s, n_kv, hd
+                )
+                v = qkv[..., (n_q + n_kv) * hd :].reshape(b, s, n_kv, hd)
+            else:
+                q = _badd(q_dot(h, lp["wq"], "wq"), lp, "bq").reshape(
+                    b, s, n_q, hd
+                )
+                k = _badd(q_dot(h, lp["wk"], "wk"), lp, "bk").reshape(
+                    b, s, n_kv, hd
+                )
+                v = _badd(q_dot(h, lp["wv"], "wv"), lp, "bv").reshape(
+                    b, s, n_kv, hd
+                )
+        with jax.named_scope("layer/rope"):
+            q = apply_rope(q, positions, cfg.rope_theta)
+            k = apply_rope(k, positions, cfg.rope_theta)
 
         def slice_layer(buf):
             """Layer ``li``'s KV window: (KH, b, window, ...) from the
@@ -1017,229 +1043,252 @@ def forward(
 
         def write_cold(buf, fresh, r0):
             """Contiguous rows [r0, r0+b) x slots [0, s) of layer li."""
-            fresh_t = jnp.transpose(
-                fresh, (2, 0, 1) + tuple(range(3, fresh.ndim))
-            )[None]
-            return jax.lax.dynamic_update_slice(
-                buf, fresh_t, (li, 0, r0) + (0,) * (buf.ndim - 3)
-            )
-
-        if kv is not None and kv_int8 and ab is not None:
-            # Append-buffer decode: fresh KV goes to ab slot
-            # ``append_step`` (a contiguous dynamic_update_slice — no
-            # scatter touches the big cache in this executable), and the
-            # kernel attends over cache[0:kv_lengths) + ab[0:step].
-            k8, ks = _quantize_kv(k)
-            v8, vs = _quantize_kv(v)
-            step = jnp.asarray(append_step, jnp.int32)
-
-            def write_ab(buf, fresh):
+            with jax.named_scope("kv_write"):
                 fresh_t = jnp.transpose(
                     fresh, (2, 0, 1) + tuple(range(3, fresh.ndim))
                 )[None]
                 return jax.lax.dynamic_update_slice(
-                    buf, fresh_t, (li, 0, 0, step) + (0,) * (buf.ndim - 4)
+                    buf, fresh_t, (li, 0, r0) + (0,) * (buf.ndim - 3)
                 )
 
-            ab = (
-                write_ab(ab[0], k8),
-                write_ab(ab[1], v8),
-                write_ab(ab[2], ks),
-                write_ab(ab[3], vs),
-            )
-            if s == 1 and paged:
-                if _append_kernel:
-                    attn = paged_decode_gqa_attention(
+        def write_at(buf, fresh, *index):
+            """Scatter ``fresh`` to layer li's (row, position) index."""
+            with jax.named_scope("kv_write"):
+                return buf.at[(li, slice(None)) + index].set(fresh)
+
+        # KV writes inside carry their own scope: layer/attn/kv_write.
+        with jax.named_scope("layer/attn"):
+            if kv is not None and kv_int8 and ab is not None:
+                # Append-buffer decode: fresh KV goes to ab slot
+                # ``append_step`` (a contiguous dynamic_update_slice — no
+                # scatter touches the big cache in this executable), and the
+                # kernel attends over cache[0:kv_lengths) + ab[0:step].
+                k8, ks = _quantize_kv(k)
+                v8, vs = _quantize_kv(v)
+                step = jnp.asarray(append_step, jnp.int32)
+
+                def write_ab(buf, fresh):
+                    with jax.named_scope("kv_write"):
+                        fresh_t = jnp.transpose(
+                            fresh, (2, 0, 1) + tuple(range(3, fresh.ndim))
+                        )[None]
+                        return jax.lax.dynamic_update_slice(
+                            buf,
+                            fresh_t,
+                            (li, 0, 0, step) + (0,) * (buf.ndim - 4),
+                        )
+
+                ab = (
+                    write_ab(ab[0], k8),
+                    write_ab(ab[1], v8),
+                    write_ab(ab[2], ks),
+                    write_ab(ab[3], vs),
+                )
+                if s == 1 and paged:
+                    if _append_kernel:
+                        attn = paged_decode_gqa_attention(
+                            q[:, 0],
+                            kv[0], kv[1], kv[2], kv[3],
+                            li,
+                            kv_lengths,
+                            page_table,
+                            append=(ab[0], ab[1], ab[2], ab[3], step + 1),
+                            page_tokens=page_tokens,
+                        )[:, None]
+                    else:
+                        attn = paged_decode_gqa_attention_xla(
+                            q[:, 0],
+                            kv[0], kv[1], kv[2], kv[3],
+                            li,
+                            kv_lengths,
+                            page_table,
+                            append=(ab[0], ab[1], ab[2], ab[3], step + 1),
+                            window=window,
+                            page_tokens=page_tokens,
+                        )[:, None]
+                elif s == 1:
+                    _decode_attn = (
+                        decode_gqa_attention if _append_kernel
+                        else decode_gqa_attention_xla
+                    )
+                    attn = _decode_attn(
                         q[:, 0],
-                        kv[0], kv[1], kv[2], kv[3],
+                        kv[0],
+                        kv[1],
+                        kv[2],
+                        kv[3],
                         li,
                         kv_lengths,
-                        page_table,
-                        append=(ab[0], ab[1], ab[2], ab[3], step + 1),
-                        page_tokens=page_tokens,
-                    )[:, None]
-                else:
-                    attn = paged_decode_gqa_attention_xla(
-                        q[:, 0],
-                        kv[0], kv[1], kv[2], kv[3],
-                        li,
-                        kv_lengths,
-                        page_table,
                         append=(ab[0], ab[1], ab[2], ab[3], step + 1),
                         window=window,
-                        page_tokens=page_tokens,
                     )[:, None]
-            elif s == 1:
-                _decode_attn = (
-                    decode_gqa_attention if _append_kernel
-                    else decode_gqa_attention_xla
-                )
-                attn = _decode_attn(
-                    q[:, 0],
-                    kv[0],
-                    kv[1],
-                    kv[2],
-                    kv[3],
-                    li,
-                    kv_lengths,
-                    append=(ab[0], ab[1], ab[2], ab[3], step + 1),
-                    window=window,
-                )[:, None]
-            elif paged:  # paged speculative-verify block
-                attn = paged_verify_gqa_attention_xla(
-                    q,
-                    kv[0], kv[1], kv[2], kv[3],
-                    li,
-                    kv_lengths,
-                    page_table,
-                    (ab[0], ab[1], ab[2], ab[3]),
-                    window=window,
-                    page_tokens=page_tokens,
-                )
-            else:  # speculative-verify block over cache + causal buffer
-                attn = verify_gqa_attention_xla(
-                    q,
-                    kv[0],
-                    kv[1],
-                    kv[2],
-                    kv[3],
-                    li,
-                    kv_lengths,
-                    (ab[0], ab[1], ab[2], ab[3]),
-                    window=window,
-                )
-        elif kv is not None and kv_int8 and paged:
-            # Paged warm mode: scatter fresh KV through the page table
-            # into the flat pool, then attend over the table-gathered
-            # logical window — the SAME ``attention`` call as the
-            # contiguous slice path, so greedy decode is bit-identical
-            # across layouts (masked window slots zero out exactly).
-            k8, ks = _quantize_kv(k)
-            v8, vs = _quantize_kv(v)
-            kv = (
-                kv[0].at[li, :, _phys_pos].set(k8),
-                kv[1].at[li, :, _phys_pos].set(v8),
-                kv[2].at[li, :, _phys_pos].set(ks),
-                kv[3].at[li, :, _phys_pos].set(vs),
-            )
-
-            def gather_layer(buf):
-                """Layer ``li``'s logical KV window gathered through the
-                page table: (KH, b, window, ...) -> the (b, window, KH,
-                ...) shape gqa_attention expects."""
-                sl = jax.lax.dynamic_slice(
-                    buf,
-                    (li,) + (0,) * (buf.ndim - 1),
-                    (1,) + buf.shape[1:],
-                )[0]
-                gat = sl[:, _page_flat]
-                perm = (1, 2, 0) + tuple(range(3, gat.ndim))
-                return jnp.transpose(gat, perm)
-
-            attn = attention(
-                q,
-                gather_layer(kv[0]),
-                gather_layer(kv[1]),
-                positions,
-                kv_lengths,
-                mesh=mesh,
-                k_scale=gather_layer(kv[2]),
-                v_scale=gather_layer(kv[3]),
-            )
-        elif kv is not None and kv_int8:
-            k8, ks = _quantize_kv(k)
-            v8, vs = _quantize_kv(v)
-            if s > 1 and cold_prefill:
-                # Cold prefill writes positions 0..s-1 contiguously (the
-                # cold_prefill contract: positions == arange(s) per row), so
-                # a dynamic_update_slice replaces the general gather/scatter
-                # — profiled ~4x cheaper per layer at b=192 s=128.
-                r0 = jnp.asarray(row_offset, jnp.int32)
+                elif paged:  # paged speculative-verify block
+                    attn = paged_verify_gqa_attention_xla(
+                        q,
+                        kv[0], kv[1], kv[2], kv[3],
+                        li,
+                        kv_lengths,
+                        page_table,
+                        (ab[0], ab[1], ab[2], ab[3]),
+                        window=window,
+                        page_tokens=page_tokens,
+                    )
+                else:  # speculative-verify block over cache + causal buffer
+                    attn = verify_gqa_attention_xla(
+                        q,
+                        kv[0],
+                        kv[1],
+                        kv[2],
+                        kv[3],
+                        li,
+                        kv_lengths,
+                        (ab[0], ab[1], ab[2], ab[3]),
+                        window=window,
+                    )
+            elif kv is not None and kv_int8 and paged:
+                # Paged warm mode: scatter fresh KV through the page table
+                # into the flat pool, then attend over the table-gathered
+                # logical window — the SAME ``attention`` call as the
+                # contiguous slice path, so greedy decode is bit-identical
+                # across layouts (masked window slots zero out exactly).
+                k8, ks = _quantize_kv(k)
+                v8, vs = _quantize_kv(v)
                 kv = (
-                    write_cold(kv[0], k8, r0),
-                    write_cold(kv[1], v8, r0),
-                    write_cold(kv[2], ks, r0),
-                    write_cold(kv[3], vs, r0),
+                    write_at(kv[0], k8, _phys_pos),
+                    write_at(kv[1], v8, _phys_pos),
+                    write_at(kv[2], ks, _phys_pos),
+                    write_at(kv[3], vs, _phys_pos),
                 )
-            else:
-                bidx = jnp.arange(b, dtype=jnp.int32)[:, None]
-                kv = (
-                    kv[0].at[li, :, bidx, positions].set(k8),
-                    kv[1].at[li, :, bidx, positions].set(v8),
-                    kv[2].at[li, :, bidx, positions].set(ks),
-                    kv[3].at[li, :, bidx, positions].set(vs),
-                )
-            if s > 1 and cold_prefill:
-                # Cold prefill: attend over the fresh bf16 k/v (exact — no
-                # quantization error on the prompt pass).  Only valid when
-                # the caller guarantees the cache holds nothing visible to
-                # these queries; warm multi-token calls (chunked prefill,
-                # speculative verify) must read the cache below.
-                attn = attention(q, k, v, positions, kv_lengths, mesh=mesh)
-            else:
-                # NOTE: the Pallas kernel is deliberately NOT used here
-                # even when shapes allow it — this branch scatters into
-                # the big cache in the same executable, and the scatter's
-                # preferred (KH-minor) layout conflicts with the kernel's
-                # required default layout, costing 5 GB of entry copies
-                # (measured).  The kernel path is the append-buffer
-                # protocol above, where the big cache is read-only.
+
+                def gather_layer(buf):
+                    """Layer ``li``'s logical KV window gathered through the
+                    page table: (KH, b, window, ...) -> the (b, window, KH,
+                    ...) shape gqa_attention expects."""
+                    sl = jax.lax.dynamic_slice(
+                        buf,
+                        (li,) + (0,) * (buf.ndim - 1),
+                        (1,) + buf.shape[1:],
+                    )[0]
+                    gat = sl[:, _page_flat]
+                    perm = (1, 2, 0) + tuple(range(3, gat.ndim))
+                    return jnp.transpose(gat, perm)
+
                 attn = attention(
                     q,
-                    slice_layer(kv[0]),
-                    slice_layer(kv[1]),
+                    gather_layer(kv[0]),
+                    gather_layer(kv[1]),
                     positions,
                     kv_lengths,
                     mesh=mesh,
-                    k_scale=slice_layer(kv[2]),
-                    v_scale=slice_layer(kv[3]),
+                    k_scale=gather_layer(kv[2]),
+                    v_scale=gather_layer(kv[3]),
                 )
-        elif kv is not None:
-            if s > 1 and cold_prefill:
-                r0 = jnp.asarray(row_offset, jnp.int32)
-                kv = (
-                    write_cold(kv[0], k, r0),
-                    write_cold(kv[1], v, r0),
-                )
-                # Cold prefill: attend over the fresh k/v — nothing in the
-                # cache is visible to these queries, and the written rows
-                # may live at a row_offset while slice_layer always reads
-                # rows [0, b).
-                attn = attention(q, k, v, positions, kv_lengths, mesh=mesh)
+            elif kv is not None and kv_int8:
+                k8, ks = _quantize_kv(k)
+                v8, vs = _quantize_kv(v)
+                if s > 1 and cold_prefill:
+                    # Cold prefill writes positions 0..s-1 contiguously (the
+                    # cold_prefill contract: positions == arange(s) per row), so
+                    # a dynamic_update_slice replaces the general gather/scatter
+                    # — profiled ~4x cheaper per layer at b=192 s=128.
+                    r0 = jnp.asarray(row_offset, jnp.int32)
+                    kv = (
+                        write_cold(kv[0], k8, r0),
+                        write_cold(kv[1], v8, r0),
+                        write_cold(kv[2], ks, r0),
+                        write_cold(kv[3], vs, r0),
+                    )
+                else:
+                    bidx = jnp.arange(b, dtype=jnp.int32)[:, None]
+                    kv = (
+                        write_at(kv[0], k8, bidx, positions),
+                        write_at(kv[1], v8, bidx, positions),
+                        write_at(kv[2], ks, bidx, positions),
+                        write_at(kv[3], vs, bidx, positions),
+                    )
+                if s > 1 and cold_prefill:
+                    # Cold prefill: attend over the fresh bf16 k/v (exact — no
+                    # quantization error on the prompt pass).  Only valid when
+                    # the caller guarantees the cache holds nothing visible to
+                    # these queries; warm multi-token calls (chunked prefill,
+                    # speculative verify) must read the cache below.
+                    attn = attention(q, k, v, positions, kv_lengths, mesh=mesh)
+                else:
+                    # NOTE: the Pallas kernel is deliberately NOT used here
+                    # even when shapes allow it — this branch scatters into
+                    # the big cache in the same executable, and the scatter's
+                    # preferred (KH-minor) layout conflicts with the kernel's
+                    # required default layout, costing 5 GB of entry copies
+                    # (measured).  The kernel path is the append-buffer
+                    # protocol above, where the big cache is read-only.
+                    attn = attention(
+                        q,
+                        slice_layer(kv[0]),
+                        slice_layer(kv[1]),
+                        positions,
+                        kv_lengths,
+                        mesh=mesh,
+                        k_scale=slice_layer(kv[2]),
+                        v_scale=slice_layer(kv[3]),
+                    )
+            elif kv is not None:
+                if s > 1 and cold_prefill:
+                    r0 = jnp.asarray(row_offset, jnp.int32)
+                    kv = (
+                        write_cold(kv[0], k, r0),
+                        write_cold(kv[1], v, r0),
+                    )
+                    # Cold prefill: attend over the fresh k/v — nothing in the
+                    # cache is visible to these queries, and the written rows
+                    # may live at a row_offset while slice_layer always reads
+                    # rows [0, b).
+                    attn = attention(q, k, v, positions, kv_lengths, mesh=mesh)
+                else:
+                    bidx = jnp.arange(b, dtype=jnp.int32)[:, None]
+                    kv = (
+                        write_at(kv[0], k, bidx, positions),
+                        write_at(kv[1], v, bidx, positions),
+                    )
+                    attn = attention(
+                        q, slice_layer(kv[0]), slice_layer(kv[1]),
+                        positions, kv_lengths, mesh=mesh,
+                    )
             else:
-                bidx = jnp.arange(b, dtype=jnp.int32)[:, None]
-                kv = (
-                    kv[0].at[li, :, bidx, positions].set(k),
-                    kv[1].at[li, :, bidx, positions].set(v),
-                )
-                attn = attention(
-                    q, slice_layer(kv[0]), slice_layer(kv[1]),
-                    positions, kv_lengths, mesh=mesh,
-                )
-        else:
-            attn = attention(q, k, v, positions, kv_lengths, mesh=mesh)
-        attn_out = _badd(
-            q_dot(attn.reshape(b, s, n_q * hd), lp["wo"], "wo"), lp, "bo"
-        )
-        carry_x = _shard_activations(carry_x + attn_out, mesh)
+                attn = attention(q, k, v, positions, kv_lengths, mesh=mesh)
+        with jax.named_scope("layer/wo"):
+            attn_out = _badd(
+                q_dot(attn.reshape(b, s, n_q * hd), lp["wo"], "wo"), lp, "bo"
+            )
+            carry_x = _shard_activations(carry_x + attn_out, mesh)
 
-        h = block_norm(carry_x, cfg, lp, "mlp_norm")
+        with jax.named_scope("layer/norm"):
+            h = block_norm(carry_x, cfg, lp, "mlp_norm")
         if "router" in lp:
+            # _moe_mlp scopes itself: layer/moe/router, layer/moe/experts.
             mlp_out, layer_aux = _moe_mlp(h, lp, cfg, mesh)
-            aux = aux + layer_aux
-        elif "w_gu" in lp:
-            gu = q_dot(h, lp["w_gu"], "w_gu")
-            gated = cfg.act_fn(gu[..., : cfg.d_ff]) * gu[..., cfg.d_ff :]
-            mlp_out = q_dot(gated, lp["w_down"], "w_down")
-        elif "w_gate" in lp:
-            gated = cfg.act_fn(
-                _badd(q_dot(h, lp["w_gate"], "w_gate"), lp, "b_gate")
-            ) * _badd(q_dot(h, lp["w_up"], "w_up"), lp, "b_up")
-            mlp_out = _badd(q_dot(gated, lp["w_down"], "w_down"), lp, "b_down")
-        else:  # plain MLP: up -> act -> down
-            gated = cfg.act_fn(_badd(q_dot(h, lp["w_up"], "w_up"), lp, "b_up"))
-            mlp_out = _badd(q_dot(gated, lp["w_down"], "w_down"), lp, "b_down")
-        carry_x = _shard_activations(carry_x + mlp_out, mesh)
+            with jax.named_scope("layer/moe/experts"):
+                carry_x = _shard_activations(carry_x + mlp_out, mesh)
+            return (carry_x, kv, ab, li + 1, aux + layer_aux), None
+        with jax.named_scope("layer/mlp"):
+            if "w_gu" in lp:
+                gu = q_dot(h, lp["w_gu"], "w_gu")
+                gated = cfg.act_fn(gu[..., : cfg.d_ff]) * gu[..., cfg.d_ff :]
+                mlp_out = q_dot(gated, lp["w_down"], "w_down")
+            elif "w_gate" in lp:
+                gated = cfg.act_fn(
+                    _badd(q_dot(h, lp["w_gate"], "w_gate"), lp, "b_gate")
+                ) * _badd(q_dot(h, lp["w_up"], "w_up"), lp, "b_up")
+                mlp_out = _badd(
+                    q_dot(gated, lp["w_down"], "w_down"), lp, "b_down"
+                )
+            else:  # plain MLP: up -> act -> down
+                gated = cfg.act_fn(
+                    _badd(q_dot(h, lp["w_up"], "w_up"), lp, "b_up")
+                )
+                mlp_out = _badd(
+                    q_dot(gated, lp["w_down"], "w_down"), lp, "b_down"
+                )
+            carry_x = _shard_activations(carry_x + mlp_out, mesh)
         return (carry_x, kv, ab, li + 1, aux), None
 
     layer_fn = jax.checkpoint(layer) if (remat and cfg.remat) else layer
@@ -1262,7 +1311,8 @@ def forward(
         params["layers"],
     )
 
-    x = apply_final_norm(x, cfg, params)
+    with jax.named_scope("final_norm"):
+        x = apply_final_norm(x, cfg, params)
     if append_cache is not None:
         return x, cache_out, ab_out
     if return_aux:
@@ -1270,6 +1320,7 @@ def forward(
     return x, cache_out
 
 
+@jax.named_scope("lm_head")
 def logits(params: Params, hidden: jnp.ndarray) -> jnp.ndarray:
     """Project hidden states to vocab logits, accumulating in f32.
 

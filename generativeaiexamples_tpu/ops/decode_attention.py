@@ -808,6 +808,7 @@ def decode_gqa_attention(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="decode_gqa_attention",
     )(jnp.asarray(layer, jnp.int32).reshape(1), abn, *operands)
     return out.reshape(b, n_q, hd)
 
@@ -1147,6 +1148,7 @@ def paged_decode_gqa_attention(
             vmem_limit_bytes=_VMEM_BUDGET_BYTES,
         ),
         interpret=interpret,
+        name="paged_decode_gqa_attention",
     )(
         jnp.asarray(layer, jnp.int32).reshape(1),
         abn,

@@ -234,6 +234,7 @@ def flash_gqa_attention(
             transcendentals=b * n_q * s_pad * t_pad // 2,
         ),
         interpret=interpret,
+        name="flash_gqa_attention",
     )(
         q_positions.astype(jnp.int32).reshape(b, 1, s_pad),
         kv_lengths.astype(jnp.int32).reshape(b, 1, 1),

@@ -282,6 +282,7 @@ def _qmm_pallas(xq, a_scale, tiles, w_scale, out_dtype, interpret):
             vmem_limit_bytes=_VMEM_BUDGET_BYTES,
         ),
         interpret=interpret,
+        name="qmm_w8a8",
     )(xq, w_scale, a_scale, tiles)
 
 

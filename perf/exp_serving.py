@@ -2,7 +2,7 @@
 
 Runs a single Poisson phase against the continuous-batching scheduler
 with every knob on the command line, and prints one JSON line that
-includes the tick-phase breakdown (prefill_s / decode_s / host overhead)
+includes the tick-phase breakdown (the scheduler's exclusive phase sums)
 so tuning decisions are driven by where the tick time actually goes.
 
     python perf/exp_serving.py --slots 320 --chunk 12 --max-queue 32 \
@@ -154,8 +154,11 @@ def main() -> None:
     sched.stop()
 
     ticks = snap1["tick_count"] - snap0["tick_count"]
-    prefill_s = snap1["prefill_s"] - snap0["prefill_s"]
-    decode_s = snap1["decode_s"] - snap0["decode_s"]
+    phase_ms = {
+        k[len("tick_phase_"):-2]: (snap1[k] - snap0[k]) * 1000
+        for k in snap1
+        if k.startswith("tick_phase_")
+    }
     out = {
         "slots": args.slots,
         "chunk": args.chunk,
@@ -170,11 +173,10 @@ def main() -> None:
         "mean_active_slots": round(float(np.mean(occupancy)), 1),
         "ticks": ticks,
         "tick_ms": round(wall / max(ticks, 1) * 1000, 1),
-        "prefill_ms_per_tick": round(prefill_s / max(ticks, 1) * 1000, 1),
-        "decode_ms_per_tick": round(decode_s / max(ticks, 1) * 1000, 1),
-        "host_ms_per_tick": round(
-            (wall - prefill_s - decode_s) / max(ticks, 1) * 1000, 1
-        ),
+        **{
+            f"{phase}_ms_per_tick": round(ms / max(ticks, 1), 1)
+            for phase, ms in phase_ms.items()
+        },
         "prefill_rows": snap1["prefill_rows"] - snap0["prefill_rows"],
         "decode_chunks": snap1["decode_chunks"] - snap0["decode_chunks"],
     }

@@ -1,0 +1,310 @@
+"""The scheduler's own account of its time: exclusive tick phases, the
+starved-device counter, request-lifecycle counters, the tick record and
+the request stages on the engine's front.  CPU, tiny model, no profiler:
+the annotations are no-ops while no trace runs."""
+
+import asyncio
+import logging
+import queue
+import time
+
+import pytest
+from aiohttp.test_utils import TestClient, TestServer
+
+from generativeaiexamples_tpu.engine.replica import EnginePool
+from generativeaiexamples_tpu.engine.sampler import SamplingParams
+from generativeaiexamples_tpu.engine.scheduler import (
+    STARVED_PHASES,
+    TICK_PHASES,
+    TICK_RECORD_FIELDS,
+    Request,
+    Scheduler,
+)
+from generativeaiexamples_tpu.engine.tokenizer import ByteTokenizer
+from generativeaiexamples_tpu.models import llama
+from generativeaiexamples_tpu.utils.buckets import bucket_size
+
+CFG = llama.llama_tiny(dtype="float32", max_seq_len=128)
+
+
+def _run(scheduler, prompts, max_tokens=5, timeout=120):
+    """Submit ``prompts`` together and wait for every one to finish."""
+    done: "queue.Queue[str]" = queue.Queue()
+    for i, prompt in enumerate(prompts):
+        ok = scheduler.submit(
+            Request(
+                token_ids=list(prompt),
+                sampling=SamplingParams(temperature=0.0, max_tokens=max_tokens),
+                on_token=lambda t: None,
+                on_done=done.put,
+                id=f"r{i}",
+            )
+        )
+        assert ok
+    return [done.get(timeout=timeout) for _ in prompts]
+
+
+def _delta(after, before, keys):
+    return {k: after[k] - before[k] for k in keys}
+
+
+@pytest.fixture
+def running():
+    """A started scheduler; ``make(**kw)`` builds it, teardown stops it."""
+    made = []
+
+    def make(**kw):
+        base = dict(max_batch=4, max_len=128, decode_chunk_size=4)
+        base.update(kw)
+        s = Scheduler(CFG, **base)
+        s.start()
+        made.append(s)
+        return s
+
+    yield make
+    for s in made:
+        s.stop()
+
+
+def test_phase_sums_partition_the_tick_threads_time(running):
+    s = running(prefill_chunk_tokens=8)
+    _run(s, [[1, 2, 3]])  # compile outside the measured burst
+    keys = [f"tick_phase_{p}_s" for p in TICK_PHASES]
+    t0, before = time.perf_counter(), s.stats.snapshot()
+    _run(s, [[i + 1] * (6 + 5 * i) for i in range(6)], max_tokens=9)
+    time.sleep(0.12)  # two idle polls, so that idle is in the sums too
+    t1, after = time.perf_counter(), s.stats.snapshot()
+    spent = _delta(after, before, keys)
+    assert all(v >= 0.0 for v in spent.values())
+    assert sum(spent.values()) == pytest.approx(t1 - t0, rel=0.02)
+    for phase in ("idle", "plan", "dispatch", "wait_device", "emit", "telemetry"):
+        assert spent[f"tick_phase_{phase}_s"] > 0.0, phase
+    assert after["busy_ticks"] - before["busy_ticks"] >= 3
+    assert after["busy_ticks"] < after["tick_count"]  # idle polls are not busy
+
+
+def test_starved_parts_add_up_to_the_whole_exactly(running):
+    s = running()
+    _run(s, [[1, 2, 3], [4, 5, 6, 7]], max_tokens=12)
+    snap = s.stats.snapshot()
+    parts = [snap[f"device_starved_{p}_s"] for p in STARVED_PHASES]
+    assert snap["device_starved_s"] == sum(parts)
+    # Between the fetch of one decode chunk and the next dispatch the
+    # thread emits, feeds telemetry and plans: all three are in there.
+    assert all(v > 0.0 for p, v in zip(STARVED_PHASES, parts) if p != "dispatch")
+    busy = sum(snap[f"tick_phase_{p}_s"] for p in STARVED_PHASES)
+    assert 0.0 < snap["device_starved_s"] <= busy
+
+
+def test_lifecycle_counts_follow_admissions_and_first_tokens(running):
+    s = running(max_batch=2)  # four requests on two slots: two must wait
+    before = s.stats.snapshot()
+    prompts = [[i + 1] * 5 for i in range(4)]
+    assert _run(s, prompts, max_tokens=6) == ["length"] * 4
+    after = s.stats.snapshot()
+    d = _delta(
+        after, before,
+        ["queue_wait_count", "warm_count", "ttft_count", "requests_total",
+         "queue_wait_s_sum", "warm_s_sum", "prompt_tokens_admitted"],
+    )
+    assert d["queue_wait_count"] == d["requests_total"] == 4
+    assert d["warm_count"] == d["ttft_count"] == 4
+    assert d["prompt_tokens_admitted"] == sum(len(p) for p in prompts)
+    assert d["queue_wait_s_sum"] > 0.0 and d["warm_s_sum"] > 0.0
+    # Submit -> claim -> first token: the two parts are the whole TTFT.
+    ttft_s = (
+        after["ttft_avg_ms"] * after["ttft_count"]
+        - before["ttft_avg_ms"] * before["ttft_count"]
+    ) / 1000.0
+    assert d["queue_wait_s_sum"] + d["warm_s_sum"] == pytest.approx(ttft_s, rel=1e-6)
+
+
+@pytest.mark.parametrize("path", ["cold", "chunked", "suffix"])
+def test_prefill_tokens_dispatched_is_prompt_less_reuse(running, path):
+    """Every prompt token is either handed to a prefill program or
+    supplied by the prefix cache, on each admission path."""
+    chunk = 8 if path == "chunked" else None
+    s = running(
+        prefill_chunk_tokens=chunk,
+        prefix_cache="shared" if path == "suffix" else "off",
+    )
+    prompt = [3 + (i % 11) for i in range(40)]
+    if path == "suffix":
+        _run(s, [prompt], max_tokens=4)  # parks the prompt's KV
+        prompt = prompt + [7, 8, 9, 10, 11]  # the replay extends it
+    before = s.stats.snapshot()
+    _run(s, [prompt], max_tokens=4)
+    d = _delta(
+        s.stats.snapshot(), before,
+        ["prefill_tokens_dispatched", "prefill_tokens_padded",
+         "prefix_tokens_reused", "prefill_chunks"],
+    )
+    assert d["prefill_tokens_dispatched"] == len(prompt) - d["prefix_tokens_reused"]
+    if path == "cold":
+        rows = bucket_size(1, minimum=4)
+        width = bucket_size(len(prompt), dense=True)
+        assert d["prefix_tokens_reused"] == 0 and d["prefill_chunks"] == 0
+        assert d["prefill_tokens_padded"] == rows * width - len(prompt)
+    elif path == "chunked":
+        assert d["prefix_tokens_reused"] == 0 and d["prefill_chunks"] == 5
+        assert d["prefill_tokens_padded"] == 5 * (16 - 8)  # 8 tokens in a bucket of 16
+    else:
+        assert d["prefix_tokens_reused"] >= 32  # MIN_PREFIX
+        new = len(prompt) - d["prefix_tokens_reused"]
+        assert d["prefill_tokens_padded"] == bucket_size(new, minimum=16, dense=True) - new
+
+
+def test_clipped_prompt_is_counted_and_logged_once(running):
+    s = running(max_len=64)
+    limit = s._admit_limit
+    prompt = [1 + (i % 50) for i in range(limit + 20)]
+    records: list[logging.LogRecord] = []
+    handler = logging.Handler()
+    handler.emit = records.append
+    sched_logger = logging.getLogger("generativeaiexamples_tpu.engine.scheduler")
+    sched_logger.addHandler(handler)
+    try:
+        before = s.stats.snapshot()
+        _run(s, [prompt, [1, 2, 3]], max_tokens=2)
+        d = _delta(
+            s.stats.snapshot(), before,
+            ["prompts_clipped", "prompt_tokens_clipped", "prompt_tokens_admitted"],
+        )
+    finally:
+        sched_logger.removeHandler(handler)
+    assert d["prompts_clipped"] == 1
+    assert d["prompt_tokens_clipped"] == len(prompt) - (limit - 1)
+    assert d["prompt_tokens_admitted"] == (limit - 1) + 3
+    clipped = [r.getMessage() for r in records if "clipped" in r.getMessage()]
+    assert len(clipped) == 1
+    assert "r0" in clipped[0] and str(len(prompt)) in clipped[0]
+    assert str(limit - 1) in clipped[0]
+
+
+def test_old_overlapping_sums_are_gone_from_scheduler_and_pool():
+    scheds = [
+        Scheduler(CFG, max_batch=2, max_len=128, decode_chunk_size=4)
+        for _ in range(2)
+    ]
+    pool = EnginePool(scheds, policy="least_loaded", health_interval=None)
+    pool.start()
+    try:
+        _run(pool, [[1, 2, 3], [4, 5, 6], [7, 8, 9]], max_tokens=3)
+        agg = pool.snapshot()
+    finally:
+        pool.stop()
+    for snap in [scheds[0].stats.snapshot(), agg, *agg["replicas"]]:
+        assert "prefill_s" not in snap and "decode_s" not in snap
+    # The pool sums the new counters like the others.
+    for key in ("busy_ticks", "queue_wait_count", "warm_count",
+                "prefill_tokens_dispatched", "tick_phase_dispatch_s",
+                "device_starved_s"):
+        assert agg[key] == pytest.approx(sum(r[key] for r in agg["replicas"]))
+    assert agg["queue_wait_count"] == agg["warm_count"] == 3
+    assert agg["prefill_tokens_dispatched"] == 9
+
+
+@pytest.fixture
+def engine_client():
+    from generativeaiexamples_tpu.engine.server import create_engine_app
+
+    scheduler = Scheduler(CFG, max_batch=2, max_len=128, decode_chunk_size=4)
+    scheduler.start()
+    app = create_engine_app(scheduler, ByteTokenizer(), model_name="llama-tiny")
+    loop = asyncio.new_event_loop()
+    client = TestClient(TestServer(app), loop=loop)
+    loop.run_until_complete(client.start_server())
+    yield client, loop
+    loop.run_until_complete(client.close())
+    loop.close()
+    scheduler.stop()
+
+
+def _complete(client, loop, request_id="", **body):
+    async def go():
+        resp = await client.post(
+            "/v1/completions",
+            json={"prompt": "hello there", "temperature": 0.0, **body},
+            headers={"X-Request-Id": request_id} if request_id else {},
+        )
+        assert resp.status == 200
+        await resp.read()
+
+    loop.run_until_complete(go())
+
+
+def _get_json(client, loop, path):
+    async def go():
+        resp = await client.get(path)
+        return resp.status, await resp.json()
+
+    return loop.run_until_complete(go())
+
+
+def test_debug_ticks_returns_the_newest_tick_records(engine_client):
+    client, loop = engine_client
+    _complete(client, loop, max_tokens=24)
+    status, body = _get_json(client, loop, "/debug/ticks?limit=4")
+    assert status == 200 and body["count"] == len(body["ticks"]) == 4
+    ticks = body["ticks"]
+    assert all(set(t) == set(TICK_RECORD_FIELDS) for t in ticks)
+    numbers = [t["tick"] for t in ticks]
+    assert numbers == sorted(set(numbers))  # rising, none twice
+    starts = [t["t_start"] for t in ticks]
+    assert starts == sorted(starts)
+    for t in ticks:
+        assert all(t[f"{p}_s"] >= 0.0 for p in TICK_PHASES)
+        assert t["starved_s"] >= 0.0
+        # Only the idle path's admission waits on the queue first.
+        assert t["idle_s"] == 0.0 or t["admitted"]
+        assert t["decode_lanes"] in (0, 1) and t["queued"] >= 0
+    assert sum(t["tokens"] for t in ticks) > 0
+    assert any(t["kv_bucket"] > 0 for t in ticks)
+    status, everything = _get_json(client, loop, "/debug/ticks")
+    assert sum(t["admitted"] for t in everything["ticks"]) == 1
+    status, _ = _get_json(client, loop, "/debug/ticks?limit=x")
+    assert status == 422
+
+
+@pytest.mark.parametrize("stream", [False, True])
+def test_engine_request_record_has_lifecycle_stages(engine_client, stream):
+    client, loop = engine_client
+    request_id = f"tick-tracing-{int(stream)}"
+    _complete(client, loop, request_id=request_id, max_tokens=8, stream=stream)
+    status, body = _get_json(client, loop, "/debug/requests")
+    assert status == 200
+    record = next(r for r in body["requests"] if r["request_id"] == request_id)
+    assert record["route"] == "/v1/completions"
+    stages = {s["stage"]: s for s in record["stages"]}
+    assert list(stages) == ["queue_wait", "prefill", "decode"]
+    assert all(s["duration_ms"] >= 0.0 for s in stages.values())
+    # One after the other, and inside the request's total.
+    assert stages["queue_wait"]["start_ms"] <= stages["prefill"]["start_ms"]
+    assert stages["prefill"]["start_ms"] <= stages["decode"]["start_ms"]
+    assert sum(s["duration_ms"] for s in stages.values()) <= record["total_ms"] + 1.0
+
+
+def test_metrics_export_the_new_counters(engine_client):
+    from generativeaiexamples_tpu.obs.exposition import parse_exposition
+
+    client, loop = engine_client
+    _complete(client, loop, max_tokens=6)
+
+    async def go():
+        resp = await client.get("/metrics")
+        return await resp.text()
+
+    exp = parse_exposition(loop.run_until_complete(go()))
+    assert exp.types["engine_tick_phase_seconds_total"] == "counter"
+    for phase in TICK_PHASES:
+        assert exp.value("engine_tick_phase_seconds_total", phase=phase) >= 0.0
+    assert exp.value("engine_tick_phase_seconds_total", phase="dispatch") > 0.0
+    for phase in STARVED_PHASES:
+        assert exp.value("engine_device_starved_seconds_total", phase=phase) >= 0.0
+    assert exp.value("engine_busy_ticks_total") >= 2
+    assert exp.value("engine_queue_wait_count_total") == 1
+    assert exp.value("engine_warm_count_total") == 1
+    assert exp.value("engine_prompt_tokens_admitted_total") == len("hello there") + 1
+    assert exp.value("engine_prefill_tokens_dispatched_total") == len("hello there") + 1
+    assert exp.types["engine_prompts_clipped_total"] == "counter"
+    assert exp.value("engine_prompts_clipped_total") == 0
