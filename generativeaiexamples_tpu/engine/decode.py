@@ -329,13 +329,23 @@ def make_decode_chunk_fn(cfg: llama.LlamaConfig, mesh, max_len: int):
     """Compiled multi-step decode: ``lax.scan`` of forward+sample.
 
     Signature: ``fn(params, cache, tokens, lengths, key, temp, top_p,
-    top_k, n_steps, kv_bucket=None)`` with the cache donated and
-    ``n_steps``/``kv_bucket`` static (bucketed by callers).  Returns
+    top_k, n_steps, kv_bucket=None, live=None)`` with the cache donated
+    and ``n_steps``/``kv_bucket`` static (bucketed by callers).  Returns
     ``(cache, toks)`` with toks shaped (n_steps, batch).  One host
     round-trip per chunk instead of per token: a device→host sync costs
     more than a decode step.  ``kv_bucket`` caps the cache prefix attention reads
-    (callers pass a power-of-two ≥ every position the chunk will write),
-    so per-step KV traffic follows the live length, not max_len.
+    (callers pass a power-of-two ≥ every position the chunk will write);
+    the XLA twin slices that window, while the Pallas kernel reads each
+    row's own ``ceil(length / block)`` blocks and no longer the window.
+
+    ``live`` (batch,) bool says which rows decode.  On the append-buffer
+    path a row that does not (a parked prefix, a warming or empty slot,
+    a slot admitted after the tick's snapshot) attends with
+    ``kv_lengths`` 0: nothing of its cache is read, it folds the append
+    buffer alone, so its tokens are finite and — as before — never
+    emitted.  Its write positions, append-buffer slots and the flush are
+    those of ``lengths``, exactly as without ``live``.  ``None`` attends
+    every row over its length.
 
     Two equivalent implementations, chosen at trace time:
 
@@ -368,6 +378,7 @@ def make_decode_chunk_fn(cfg: llama.LlamaConfig, mesh, max_len: int):
         top_k,
         n_steps,
         kv_bucket=None,
+        live=None,
     ):
         window = min(kv_bucket, max_len) if kv_bucket else max_len
         kv_int8 = len(cache) == 4
@@ -385,6 +396,11 @@ def make_decode_chunk_fn(cfg: llama.LlamaConfig, mesh, max_len: int):
             # Valid big-cache slots per row: the current token's write
             # position (its KV lives in the append buffer this chunk).
             lengths0 = jnp.minimum(lengths, max_len - 1)
+            # What attention reads of each row: nothing for a row that
+            # does not decode.  Positions and the flush keep lengths0.
+            attended = (
+                lengths0 if live is None else jnp.where(live, lengths0, 0)
+            )
             ab_shape = (
                 cfg.n_layers, cfg.n_kv_heads, b, n_steps, cfg.head_dim
             )
@@ -407,7 +423,7 @@ def make_decode_chunk_fn(cfg: llama.LlamaConfig, mesh, max_len: int):
                     tok[:, None],
                     positions,
                     cache,
-                    lengths0,
+                    attended,
                     mesh=mesh,
                     kv_bucket=kv_bucket,
                     append_cache=(ab, step),
@@ -452,7 +468,7 @@ def make_decode_chunk_fn(cfg: llama.LlamaConfig, mesh, max_len: int):
 
     def decode_chunk_checked(
         params, cache, tokens, lengths, key, temp, top_p, top_k,
-        n_steps, kv_bucket=None,
+        n_steps, kv_bucket=None, live=None,
     ):
         """Debug-mode contract guard wrapping the compiled step.
 
@@ -483,7 +499,7 @@ def make_decode_chunk_fn(cfg: llama.LlamaConfig, mesh, max_len: int):
                     )
         return decode_chunk(
             params, cache, tokens, lengths, key, temp, top_p, top_k,
-            n_steps, kv_bucket,
+            n_steps, kv_bucket, live,
         )
 
     return decode_chunk_checked

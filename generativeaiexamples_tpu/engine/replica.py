@@ -1153,6 +1153,8 @@ class EnginePool:
         "prompt_tokens_clipped",
         "prefill_tokens_dispatched",
         "prefill_tokens_padded",
+        "decode_kv_tokens_read",
+        "decode_kv_tokens_dense",
         # Paged-KV pool gauges/counters sum across replicas: each
         # replica owns a disjoint page pool, so pool-wide capacity and
         # pressure are the sums (all zero under the contiguous layout).

@@ -55,7 +55,10 @@ from generativeaiexamples_tpu.engine.prefix_cache import PrefixCacheIndex
 from generativeaiexamples_tpu.obs.metrics import observe_stage
 from generativeaiexamples_tpu.engine.sampler import SamplingParams, sample
 from generativeaiexamples_tpu.models import llama
-from generativeaiexamples_tpu.ops.decode_attention import flush_clip_start
+from generativeaiexamples_tpu.ops.decode_attention import (
+    flush_clip_start,
+    kv_tokens_read,
+)
 from generativeaiexamples_tpu.resilience.faults import (
     FaultInjected,
     inject,
@@ -318,6 +321,12 @@ class Stats:
         # minus real tokens, padded batch rows included).
         self.prefill_tokens_dispatched = 0
         self.prefill_tokens_padded = 0
+        # KV positions a contiguous decode chunk's attention reads a
+        # step, counted at the dispatch: each decoding row's length in
+        # whole kernel blocks, beside what a dense walk of the window
+        # would read for every slot (max_batch x kv_bucket).
+        self.decode_kv_tokens_read = 0
+        self.decode_kv_tokens_dense = 0
         # EWMA of tick wall time, updated lock-free from the tick loop
         # (single-writer; readers tolerate a torn-in-time value).  The
         # 429 Retry-After hint derives queue-drain time from it without
@@ -385,6 +394,8 @@ class Stats:
                 "prompt_tokens_clipped": self.prompt_tokens_clipped,
                 "prefill_tokens_dispatched": self.prefill_tokens_dispatched,
                 "prefill_tokens_padded": self.prefill_tokens_padded,
+                "decode_kv_tokens_read": self.decode_kv_tokens_read,
+                "decode_kv_tokens_dense": self.decode_kv_tokens_dense,
                 "ttft_avg_ms": (
                     self.ttft_sum / self.ttft_count * 1000 if self.ttft_count else 0.0
                 ),
@@ -2912,17 +2923,20 @@ class Scheduler:
         this chunk must neither read their ``_cur_tok`` nor emit their
         lanes."""
         lengths, temp, top_p, top_k, max_active = self._lane_state()
-        if active is None:
+        pinned = active is not None
+        if not pinned:
             active = self._active()
-        else:
+        # The rows that decode: the only ones whose cache attention
+        # reads (decode_chunk's ``live``), and the only ones emitted.
+        snap = np.zeros((self.max_batch,), dtype=bool)
+        snap[active] = True
+        if pinned:
             # Lanes outside the emission snapshot (freshly admitted this
             # tick, emitted still 0) would garbage-write at length-1 —
             # INSIDE the prompt KV the graft just landed.  Pin their
             # write positions to the cache tail instead: any row that
             # eventually reaches those positions rewrites them with its
             # own K/V before its attention mask exposes them.
-            snap = np.zeros((self.max_batch,), dtype=bool)
-            snap[active] = True
             lengths = np.where(snap, lengths, self.max_len - 1)
         # Attention window: smallest power-of-two bucket covering every
         # position this chunk can write for a LIVE sequence — per-step KV
@@ -2966,19 +2980,28 @@ class Scheduler:
             )
             self._set_cache(cache)
         else:
+            lengths = np.minimum(lengths, self.max_len - 1)
             cache, toks = self._decode_chunk(
                 self.params,
                 self._cache,
                 jnp.asarray(self._cur_tok),
-                jnp.asarray(np.minimum(lengths, self.max_len - 1)),
+                jnp.asarray(lengths),
                 self._next_key(),
                 jnp.asarray(temp),
                 jnp.asarray(top_p),
                 jnp.asarray(top_k),
                 self.decode_chunk_size,
                 kv_bucket,
+                jnp.asarray(snap),
             )
             self._cache = cache
+            with self.stats.lock:
+                self.stats.decode_kv_tokens_read += kv_tokens_read(
+                    lengths[snap], self.max_len, kv_bucket
+                )
+                self.stats.decode_kv_tokens_dense += (
+                    self.max_batch * kv_bucket
+                )
         ticket = self._clock.dispatched()
         self._clock.enter("plan")
         return toks, active, ticket

@@ -788,6 +788,8 @@ async def handle_metrics(request: web.Request) -> web.Response:
             "d",
         ),
         ("engine_prefill_tokens_padded_total", "prefill_tokens_padded", "d"),
+        ("engine_decode_kv_tokens_read_total", "decode_kv_tokens_read", "d"),
+        ("engine_decode_kv_tokens_dense_total", "decode_kv_tokens_dense", "d"),
     ):
         lines.append(f"# TYPE {name} counter")
         lines.append(f"{name} {format(snap.get(key, 0), fmt)}")

@@ -950,7 +950,8 @@ def forward(
         else:
             _append_kernel = s == 1 and use_decode_kernel(
                 s=s, kv_int8=kv_int8, batch=b, window=window,
-                n_q=n_q, n_kv=n_kv, head_dim=hd, mesh=mesh,
+                n_q=n_q, n_kv=n_kv, head_dim=hd, cache_len=t,
+                append_width=append_cache[0][0].shape[3], mesh=mesh,
             )
             _site = f"decode_attention b={b} w={window}"
         if s == 1:

@@ -7,12 +7,14 @@ TensorRT-LLM (consumed by the reference via the NIM container,
 Why a kernel: the XLA decode path must ``dynamic_slice`` each layer's KV
 window out of the stacked cache before the attention einsums, and XLA
 materializes that slice in HBM — measured at 4.3 ms of the 26.6 ms decode
-step (b=192, window 256; PERF_NOTES.md).  This kernel DMAs (block_b,
-block_t) KV tiles straight out of the full ``(L, KH, B, T, HD)`` cache —
-the layer index rides in as a scalar-prefetch operand used by the
-BlockSpec index maps — so the window streams once at HBM bandwidth with no
-intermediate copy.  Probe (b=320, window 256): 11.2 ms vs 16.5 ms for the
-slice+einsum XLA path per 32-layer step.
+step (b=192, window 256; PERF_NOTES.md).  The kernel copies KV blocks
+straight out of the full ``(L, KH, B, T, HD)`` cache, which stays in HBM
+— the layer index rides in as a scalar-prefetch operand — so there is no
+intermediate copy, and it copies only what is live: a program takes 16
+batch rows and walks each row's own ``ceil(kv_length / block)`` blocks,
+none for a row of length 0.  (Until PR 25 a dense grid read the whole window for
+every row and the lengths only masked; on the v5e that was 2.0 s of a
+10 s serving window of which a third was live — PERF.md.)
 
 Semantics match :func:`ops.attention.gqa_attention` specialized to s == 1:
 key slot ``t`` is visible iff ``t < kv_length[b]`` (the decode caller's
@@ -69,35 +71,42 @@ def _interpret_mode() -> bool:
     (tests/conftest.py's virtual-device platform)."""
     return bool(os.environ.get("GAIE_DECODE_KERNEL_INTERPRET"))
 
-def _pick_block_b(batch: int) -> int:
-    """Batch rows per program.
+# KV slots per block of a row's walk: one DMA and one online-softmax
+# update.  Measured on the v5e (PERF.md, PR 25): at 256 the update's
+# fixed cost shows (a full batch is 34 % slower than a dense grid), at
+# 1,024 a ragged batch reads too far past its rows; 512 is within 9 % of
+# the dense grid on full rows and faster on everything shorter.
+BLOCK_T = 512
 
-    64 measured fastest inside the serving decode scan at b=320 (the
-    layer scan already pipelines across kernel calls, so fewer/bigger
-    programs win); smaller powers keep small batches legal.  Must be a
-    multiple of 16 — it is the second-to-minor dim of the bf16 scale
-    blocks.
-    """
-    env = os.environ.get("GAIE_DECODE_KERNEL_BB")
-    if env:
-        bb = int(env)
-        if bb % 16 != 0 or batch % bb != 0:
-            # A non-dividing override would silently drop trailing batch
-            # rows (grid = batch // bb) and return wrong attention for
-            # them — refuse instead.
-            raise ValueError(
-                f"GAIE_DECODE_KERNEL_BB={bb} must be a multiple of 16 "
-                f"that divides batch {batch}"
-            )
-        return bb
-    for bb in (64, 32, 16):
-        if batch % bb == 0:
-            return bb
-    return 16
+# Rows whose scales, queries, append slots and outputs ride in one VMEM
+# block: the second-to-minor tile of the bf16 scale planes.
+_ROW_GROUP = 16
 
 
-# KV slots per program; multiple of 128 (minor dim of the scale blocks).
-BLOCK_T = 256
+def _block_t(cache_len: int, window: int) -> int:
+    """Slots per block for a cache of ``cache_len`` slots under a window
+    of ``window``: the largest of BLOCK_T, 256 and 128 that divides the
+    cache, so a row's last block never reads past it, and is no wider
+    than the window (a short context costs one small block a row); a
+    cache shorter than 128 is one block."""
+    for bt in (BLOCK_T, 256, 128):
+        if cache_len % bt == 0 and bt <= max(window, 128):
+            return bt
+    return cache_len
+
+
+def kv_tokens_read(kv_lengths, cache_len: int, window: int) -> int:
+    """Cache positions one call of the kernel reads for rows of these
+    (host) lengths: each row's length in whole blocks."""
+    bt = _block_t(cache_len, window)
+    return sum(-(-int(n) // bt) * bt for n in kv_lengths)
+
+
+def _scale_width(window: int, cache_len: int) -> int:
+    """Slots of the scale planes a program holds: ``window`` widened to
+    whole blocks (never past the cache, which whole blocks tile)."""
+    bt = _block_t(cache_len, window)
+    return min(-(-window // bt) * bt, cache_len)
 
 
 def _online_update(
@@ -144,87 +153,218 @@ def _online_update(
     l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
 
+def _fold_append_group(
+    q_ref, kab_ref, vab_ref, ksab_ref, vsab_ref, count,
+    m_ref, l_ref, acc_ref, scale,
+):
+    """Start the group's 16 online-softmax states from the append
+    buffer: ``_online_update``'s step from the empty state, a KV head at
+    a time with the rows as the batch dim, so the fold costs one short
+    chain a head and group where it cost one a row.  State refs are
+    (16, KH*G, 128 | HD), a row's rows ordered (head, group)."""
+    rows, kh, g = q_ref.shape[0], q_ref.shape[1], q_ref.shape[2]
+    c = kab_ref.shape[3]
+    mask = jax.lax.broadcasted_iota(jnp.int32, (rows, g, c), 2) < count
+    for h in range(kh):
+        q = q_ref[:, h]  # (16, G, HD)
+        s = jax.lax.dot_general(
+            q,
+            kab_ref[0, h].astype(q.dtype),
+            dimension_numbers=(((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )
+        s = s * scale
+        s = s * ksab_ref[0, h].astype(jnp.float32)[:, None, :]
+        s = jnp.where(mask, s, _NEG_INF)
+        m = jnp.max(s, axis=-1, keepdims=True)
+        p = jnp.exp(s - m) * mask
+        pv = (p * vsab_ref[0, h].astype(jnp.float32)[:, None, :]).astype(
+            q.dtype
+        )
+        acc = jax.lax.dot_general(
+            pv,
+            vab_ref[0, h].astype(q.dtype),
+            dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32,
+        )  # (16, G, HD)
+        head = slice(h * g, (h + 1) * g)
+        m_ref[:, head, :] = jnp.broadcast_to(m, (rows, g, m_ref.shape[2]))
+        l_ref[:, head, :] = jnp.broadcast_to(
+            jnp.sum(p, axis=-1, keepdims=True), (rows, g, l_ref.shape[2])
+        )
+        acc_ref[:, head, :] = acc
+
+
 def _decode_kernel(
     li_ref,  # scalar prefetch: (1,) int32 layer index
     abn_ref,  # scalar prefetch: (1,) int32 valid append-buffer slots
-    len_ref,  # (BB, 1) int32 valid kv prefix per row
-    q_ref,  # (BB, 1, G, HD)
-    k_ref,  # (1, 1, BB, BT, HD) int8
-    v_ref,  # (1, 1, BB, BT, HD) int8
-    ks_ref,  # (1, 1, BB, BT) bf16
-    vs_ref,  # (1, 1, BB, BT) bf16
-    # with has_ab: kab, vab (1, 1, BB, C, HD) int8; ksab, vsab
-    # (1, 1, BB, C) bf16 — the decode chunk's append buffer.
+    len_ref,  # scalar prefetch: (B,) int32 valid kv prefix per row
+    q_ref,  # (16, KH, G, HD) — the program's group of 16 rows
+    k_hbm,  # (L, KH, B, T, HD) int8 — stays in HBM (pl.ANY)
+    v_hbm,  # (L, KH, B, T, HD) int8 — stays in HBM
+    ks_ref,  # (1, KH, 16, W) bf16 — the group's scale planes
+    vs_ref,  # (1, KH, 16, W) bf16
+    # with has_ab: kab, vab (1, KH, 16, C, HD) int8; ksab, vsab
+    # (1, KH, 16, C) bf16 — the group's rows of the append buffer.
     *rest,
     block_t: int,
     scale: float,
     has_ab: bool,
 ):
+    """Decode attention for a group of 16 batch rows: a walk over each
+    row's own blocks of the contiguous cache.
+
+    A row is walked across ALL its KV heads (they are the batch dim of
+    ``_online_update``): ``ceil(len / block_t)`` blocks, none for a row
+    of length 0, each block's int8 k/v ``make_async_copy``-streamed out
+    of the HBM-resident cache into a ping-pong VMEM buffer — block
+    ``i + 1`` is fetched while block ``i`` computes, and during a row's
+    last block the first block of the NEXT row that has any (of this
+    group or a later one), so only the call's very first copy is
+    exposed.  The grid runs in order on one core, so the buffer slot and
+    the "my first block is already on its way" flag ride from row to row
+    and group to group in SMEM.  The trailing partial block masks to the
+    row's length.
+
+    What a one-row copy cannot bring (the bf16 scale planes tile
+    ``(B, T)`` by (16, 128), so one row is not a whole tile) comes in
+    through BlockSpecs for the group: scales, and with them queries,
+    append slots and the output block.  The append buffer folds first,
+    for all 16 rows at once (:func:`_fold_append_group`), and the
+    outputs are normalized and stored once at the end, so a row that
+    reads nothing costs a few scalar operations.
+    """
     if has_ab:
         kab_ref, vab_ref, ksab_ref, vsab_ref = rest[:4]
-        o_ref, m_ref, l_ref, acc_ref = rest[4:]
-    else:
-        o_ref, m_ref, l_ref, acc_ref = rest
-    ti = pl.program_id(2)
-    n_t = pl.num_programs(2)
-    bb, g = q_ref.shape[0], q_ref.shape[2]
+        rest = rest[4:]
+    o_ref, kbuf, vbuf, sem, state, m_ref, l_ref, acc_ref = rest
+    rows, kh, g = q_ref.shape[0], q_ref.shape[1], q_ref.shape[2]
+    first_row = pl.program_id(0) * rows
+    n_rows = pl.num_programs(0) * rows
+    bt = block_t
+    li = li_ref[0]
+    width = ks_ref.shape[3]
 
-    @pl.when(ti == 0)
-    def _init():
+    def n_blocks(row):
+        return (jnp.minimum(len_ref[row], width) + bt - 1) // bt
+
+    def block_dma(slot, row, i):
+        start = pl.multiple_of(i * bt, bt)
+        return tuple(
+            pltpu.make_async_copy(
+                hbm.at[li, :, row, pl.ds(start, bt)],
+                buf.at[slot],
+                sem.at[slot, j],
+            )
+            for j, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf)))
+        )
+
+    # state[0]: buffer slot of the next block to compute; state[1]: 1 if
+    # an earlier row already started the next walked row's first copy.
+    @pl.when(first_row == 0)
+    def _reset():
+        state[0] = 0
+        state[1] = 0
+
+    if has_ab:
+        _fold_append_group(
+            q_ref, kab_ref, vab_ref, ksab_ref, vsab_ref, abn_ref[0],
+            m_ref, l_ref, acc_ref, scale,
+        )
+    else:
         m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[:] = jnp.zeros_like(l_ref)
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    q = q_ref[:, 0]  # (BB, G, HD)
-    lens = len_ref[:, 0]  # (BB,)
+    def walk_row(r, _):
+        b = first_row + r
+        length = jnp.minimum(len_ref[b], width)
+        n = (length + bt - 1) // bt
+        slot0 = state[0]
 
-    t_idx = (
-        jax.lax.broadcasted_iota(jnp.int32, (bb, g, block_t), 2)
-        + ti * block_t
-    )
-    mask = t_idx < lens[:, None, None]
-    _online_update(
-        q,
-        k_ref[0, 0],
-        v_ref[0, 0],
-        ks_ref[0, 0].astype(jnp.float32),
-        vs_ref[0, 0].astype(jnp.float32),
-        mask,
-        m_ref,
-        l_ref,
-        acc_ref,
-        scale,
-    )
+        @pl.when((n > 0) & (state[1] == 0))
+        def _first():
+            for cp in block_dma(slot0, b, 0):
+                cp.start()
 
-    # The append buffer folds into the LAST cache grid step (an extra
-    # grid step would double the program count — measured +50% kernel
-    # time; its blocks have constant index maps, so they are DMA'd once).
-    if has_ab:
+        def row_of_group(tile):
+            """Row ``r`` of a (KH, 16, n) group tile, as f32 (KH, n): a
+            masked sum, because a packed sublane takes no dynamic
+            index."""
+            tile = tile.astype(jnp.float32)
+            which = jax.lax.broadcasted_iota(jnp.int32, tile.shape, 1)
+            return jnp.sum(jnp.where(which == r, tile, 0.0), axis=1)
 
-        @pl.when(ti == n_t - 1)
-        def _ab_tile():
-            c = kab_ref.shape[3]
-            j_idx = jax.lax.broadcasted_iota(jnp.int32, (bb, g, c), 2)
-            ab_mask = j_idx < abn_ref[0]
+        def body(i, _):
+            slot = (slot0 + i) % 2
+
+            @pl.when(i + 1 < n)
+            def _prefetch():
+                for cp in block_dma(1 - slot, b, i + 1):
+                    cp.start()
+
+            @pl.when(i + 1 == n)
+            def _prefetch_next_row():
+                nxt = jax.lax.while_loop(
+                    lambda j: (j < n_rows)
+                    & (n_blocks(jnp.minimum(j, n_rows - 1)) == 0),
+                    lambda j: j + 1,
+                    b + 1,
+                )
+
+                @pl.when(nxt < n_rows)
+                def _start():
+                    for cp in block_dma(1 - slot, nxt, 0):
+                        cp.start()
+
+                state[0] = 1 - slot
+                state[1] = (nxt < n_rows).astype(jnp.int32)
+
+            for cp in block_dma(slot, b, i):
+                cp.wait()
+            start = pl.multiple_of(i * bt, bt)
+            t_idx = (
+                jax.lax.broadcasted_iota(jnp.int32, (kh, g, bt), 2) + i * bt
+            )
             _online_update(
-                q,
-                kab_ref[0, 0],
-                vab_ref[0, 0],
-                ksab_ref[0, 0].astype(jnp.float32),
-                vsab_ref[0, 0].astype(jnp.float32),
-                ab_mask,
-                m_ref,
-                l_ref,
-                acc_ref,
+                q_ref[r],
+                kbuf[slot],
+                vbuf[slot],
+                row_of_group(ks_ref[0, :, :, pl.ds(start, bt)]),
+                row_of_group(vs_ref[0, :, :, pl.ds(start, bt)]),
+                t_idx < length,
+                m_ref.at[r],
+                l_ref.at[r],
+                acc_ref.at[r],
                 scale,
             )
+            return 0
 
-    @pl.when(ti == n_t - 1)
-    def _finalize():
-        denom = jnp.maximum(l_ref[:, :1], 1e-30)
-        o_ref[:, 0] = (
-            (acc_ref[:] / denom).reshape(bb, g, -1).astype(o_ref.dtype)
-        )
+        jax.lax.fori_loop(0, n, body, 0)
+        return 0
+
+    jax.lax.fori_loop(0, rows, walk_row, 0)
+
+    denom = jnp.maximum(l_ref[:, :, :1], 1e-30)
+    o_ref[:] = (
+        (acc_ref[:] / denom).reshape(rows, kh, g, -1).astype(o_ref.dtype)
+    )
+
+
+def _decode_kernel_vmem_bytes(
+    block_t: int, width: int, n_kv: int, g: int, hd: int, c: int
+) -> int:
+    """VMEM the contiguous kernel holds: the ping-pong k/v blocks, and
+    for the row's group of 16 the double-buffered scale planes, append
+    slots, queries and outputs, plus the online-softmax scratch."""
+    rows = _ROW_GROUP
+    return n_kv * (
+        2 * 2 * block_t * hd  # k/v block double buffers (int8)
+        + 2 * 2 * rows * width * 2  # k/v scale planes (bf16) x 2 buffers
+        + 2 * 2 * rows * c * (hd + 2)  # append values + scales x 2 buffers
+        + 2 * 2 * rows * g * hd * 4  # q + output blocks (f32 worst case)
+        + rows * (2 * 128 + hd) * g * 4  # m/l/acc f32 scratch
+    )
 
 
 def use_decode_kernel(
@@ -236,6 +376,8 @@ def use_decode_kernel(
     n_q: int,
     n_kv: int,
     head_dim: int,
+    cache_len: int | None = None,
+    append_width: int = 0,
     mesh=None,
     backend=None,
 ) -> bool:
@@ -244,6 +386,9 @@ def use_decode_kernel(
     Single-token decode on a single TPU chip with an int8 cache and
     MXU/tile-aligned shapes; everything else falls back to the XLA path
     (which is also the reference implementation for tests).
+    ``cache_len`` is the cache's slot count (``window`` when the caller
+    does not say): the kernel walks the cache itself in blocks that must
+    tile it, and ``window`` only sizes the scale planes it holds.
     """
     if os.environ.get("GAIE_DISABLE_DECODE_KERNEL"):
         return False
@@ -252,24 +397,26 @@ def use_decode_kernel(
     if not _interpret_mode():
         if (backend or platform_of(mesh)) != "tpu" or not one_device(mesh):
             return False
+    t = cache_len or window
+    g = n_q // max(n_kv, 1)
     return (
-        batch % 16 == 0
-        # Exact-tiling gate, mirroring the wrapper's tile pick: a window
-        # at or under one tile runs as a single tile (the wrapper widens
-        # the small pow2 buckets 32 and 64 — reachable from any
-        # short-context decode — to a whole 128-lane tile or the cache's
-        # length), and larger windows must split into whole 256- or
-        # 128-deep tiles (the dense 3*2^k buckets 384, 768, ... tile at
-        # 128).  tests/test_paged_kv.py pins the gate against the wrapper
-        # for every reachable bucket; tests/test_chip_compile.py asks the
+        batch % _ROW_GROUP == 0
+        # Exact-tiling gate, mirroring ``_block_t``: a cache of whole
+        # 128-slot tiles walks in 512-, 256- or 128-slot blocks, and a
+        # shorter one (the small pow2 buckets 32 and 64 — reachable from
+        # any short-context decode) is a single block, whole int8
+        # sublane tiles deep.  tests/test_paged_kv.py pins the gate for
+        # every reachable bucket; tests/test_chip_compile.py asks the
         # v5e compiler about a 64-slot window of a 256-slot cache.
-        and (
-            (window <= BLOCK_T and window % 32 == 0)
-            or window % 128 == 0
-        )
+        and (t % 128 == 0 or (t <= 256 and t % 32 == 0))
         and head_dim % 128 == 0
         and n_q % n_kv == 0
-        and n_q // n_kv <= 16
+        and g <= 16
+        and _decode_kernel_vmem_bytes(
+            _block_t(t, window), _scale_width(window, t), n_kv, g,
+            head_dim, append_width,
+        )
+        <= _VMEM_BUDGET_BYTES
     )
 
 
@@ -690,20 +837,25 @@ def decode_gqa_attention(
     Args:
       q: (B, n_q_heads, HD) — the single decode token's queries, rope
         already applied.
-      k8, v8: (L, KH, B, T, HD) int8 stacked cache values.
+      k8, v8: (L, KH, B, T, HD) int8 stacked cache values; they stay in
+        HBM and the kernel copies the blocks each row owns.
       ks, vs: (L, KH, B, T) bf16 dequant scales.
       layer: int32 scalar — which layer's cache to read.
       kv_lengths: (B,) int32 — cache slots [0, kv_lengths[b]) are
-        attended.
+        attended, and they are what is read: row ``b`` costs
+        ``ceil(kv_lengths[b] / block)`` block copies.  0 means the row
+        reads nothing from the cache: it attends the append buffer
+        alone (exact zeros without one), which is what the decode chunk
+        asks for rows that do not decode.
       append: optional ``(k_ab, v_ab, ks_ab, vs_ab, count)`` — the decode
         chunk's append buffer holding this chunk's fresh KV: values
         (L, KH, B, C, HD) int8, scales (L, KH, B, C) bf16, ``count`` an
-        int32 scalar of valid slots (slot j holds the token at absolute
-        position kv_lengths[b] + j; all rows share the count).  Processed
-        as one extra grid step whose blocks are fetched once per program
-        (their index map is constant, so Pallas skips the re-DMA).
-      window: static; attention reads cache slots [0, window).  Caller
-        guarantees every valid slot (kv_lengths max) is <= window.
+        int32 scalar of valid slots (all rows share the count).  Folded
+        before the rows' walks, 16 rows at a time.
+      window: static; the caller guarantees ``kv_lengths <= window``.
+        It no longer bounds the kernel's reads of k/v (the lengths do);
+        it sizes the scale planes a program holds for its 16 rows, and
+        a length beyond it is clipped to it.
 
     Returns:
       (B, n_q_heads, HD) in q's dtype.
@@ -713,69 +865,34 @@ def decode_gqa_attention(
     b, n_q, hd = q.shape
     n_kv = k8.shape[1]
     g = n_q // n_kv
-    # A KV block's slot dim must be whole 128-lane tiles or the cache's
-    # whole length (the bf16 scale blocks carry it minor-most), so a
-    # narrower window widens to the next tile: the extra slots lie
-    # beyond every row's kv_length and mask to exact zeros.
     cache_len = k8.shape[3]
-    if window % 128 and window < cache_len:
-        window = min(-(-window // 128) * 128, cache_len)
-    if window <= BLOCK_T:
-        bt = window
-    elif window % BLOCK_T == 0:
-        bt = BLOCK_T
-    else:
-        bt = 128  # dense 3*2^k windows (384, 768, ...) tile at 128
-    n_cache = window // bt
+    bt = _block_t(cache_len, window)
+    width = _scale_width(window, cache_len)
     has_ab = append is not None
-    bb = _pick_block_b(b)
-    grid = (b // bb, n_kv, n_cache)
+    rows = _ROW_GROUP
 
-    def cache_val_map(bi, hi, ti, li, abn):
-        return (li[0], hi, bi, ti, 0)
+    def group_map(*tail):
+        return lambda gi, li, abn, lens: (gi,) + tail
 
-    def cache_scale_map(bi, hi, ti, li, abn):
-        return (li[0], hi, bi, ti)
+    def layer_group_map(*tail):
+        return lambda gi, li, abn, lens: (li[0], 0, gi) + tail
 
     in_specs = [
-        pl.BlockSpec((bb, 1), lambda bi, hi, ti, li, abn: (bi, 0)),
-        pl.BlockSpec(
-            (bb, 1, g, hd),
-            lambda bi, hi, ti, li, abn: (bi, hi, 0, 0),
-        ),
-        pl.BlockSpec((1, 1, bb, bt, hd), cache_val_map),
-        pl.BlockSpec((1, 1, bb, bt, hd), cache_val_map),
-        pl.BlockSpec((1, 1, bb, bt), cache_scale_map),
-        pl.BlockSpec((1, 1, bb, bt), cache_scale_map),
+        pl.BlockSpec((rows, n_kv, g, hd), group_map(0, 0, 0)),
+        pl.BlockSpec(memory_space=pl.ANY),  # k cache stays in HBM
+        pl.BlockSpec(memory_space=pl.ANY),  # v cache stays in HBM
+        pl.BlockSpec((1, n_kv, rows, width), layer_group_map(0)),
+        pl.BlockSpec((1, n_kv, rows, width), layer_group_map(0)),
     ]
-    operands = [
-        kv_lengths.astype(jnp.int32).reshape(b, 1),
-        q.reshape(b, n_kv, g, hd),
-        k8,
-        v8,
-        ks,
-        vs,
-    ]
+    operands = [q.reshape(b, n_kv, g, hd), k8, v8, ks, vs]
     if has_ab:
         k_ab, v_ab, ks_ab, vs_ab, count = append
         c = k_ab.shape[3]
         in_specs += [
-            pl.BlockSpec(
-                (1, 1, bb, c, hd),
-                lambda bi, hi, ti, li, abn: (li[0], hi, bi, 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, bb, c, hd),
-                lambda bi, hi, ti, li, abn: (li[0], hi, bi, 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, bb, c),
-                lambda bi, hi, ti, li, abn: (li[0], hi, bi, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, bb, c),
-                lambda bi, hi, ti, li, abn: (li[0], hi, bi, 0),
-            ),
+            pl.BlockSpec((1, n_kv, rows, c, hd), layer_group_map(0, 0)),
+            pl.BlockSpec((1, n_kv, rows, c, hd), layer_group_map(0, 0)),
+            pl.BlockSpec((1, n_kv, rows, c), layer_group_map(0)),
+            pl.BlockSpec((1, n_kv, rows, c), layer_group_map(0)),
         ]
         operands += [k_ab, v_ab, ks_ab, vs_ab]
         abn = jnp.asarray(count, jnp.int32).reshape(1)
@@ -790,26 +907,37 @@ def decode_gqa_attention(
             has_ab=has_ab,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
+            num_scalar_prefetch=3,
+            grid=(b // rows,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec(
-                (bb, 1, g, hd),
-                lambda bi, hi, ti, li, abn: (bi, hi, 0, 0),
+                (rows, n_kv, g, hd), group_map(0, 0, 0)
             ),
             scratch_shapes=[
-                pltpu.VMEM((bb * g, 128), jnp.float32),
-                pltpu.VMEM((bb * g, 128), jnp.float32),
-                pltpu.VMEM((bb * g, hd), jnp.float32),
+                pltpu.VMEM((2, n_kv, bt, hd), jnp.int8),
+                pltpu.VMEM((2, n_kv, bt, hd), jnp.int8),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((2,), jnp.int32),
+                pltpu.VMEM((rows, n_kv * g, 128), jnp.float32),
+                pltpu.VMEM((rows, n_kv * g, 128), jnp.float32),
+                pltpu.VMEM((rows, n_kv * g, hd), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((b, n_kv, g, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            # In order on one core: the buffer slot and the next row's
+            # first copy ride from one program to the next.
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_BUDGET_BYTES,
         ),
         interpret=interpret,
         name="decode_gqa_attention",
-    )(jnp.asarray(layer, jnp.int32).reshape(1), abn, *operands)
+    )(
+        jnp.asarray(layer, jnp.int32).reshape(1),
+        abn,
+        kv_lengths.astype(jnp.int32),
+        *operands,
+    )
     return out.reshape(b, n_q, hd)
 
 

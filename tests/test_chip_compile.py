@@ -81,6 +81,12 @@ def _spec(sharding):
         # A short context in a longer cache: the first chip run's
         # scheduler tick died here (a 64-wide bf16 scale block).
         (16, 64, 256, 8),
+        # The row walk's other block sizes (lengths are run-time values,
+        # ragged or not: what the compiler sees of them is the block):
+        # 128 in a cache that whole 256-slot blocks do not tile, and 128
+        # under a window no wider, in the serving cache.
+        (32, 384, 384, 8),
+        (32, 128, 2048, 8),
     ],
 )
 def test_decode_kernel_compiles(one_chip, batch, window, cache_len, chunk):

@@ -286,19 +286,128 @@ def test_flush_clip_boundary_confines_damage_to_tail_zone():
             )
 
 
-def test_block_b_env_override_validated(monkeypatch):
-    """A BB override that doesn't divide batch must refuse, not silently
-    truncate the grid (dropping trailing rows)."""
-    from generativeaiexamples_tpu.ops.decode_attention import _pick_block_b
+# A cache of three 512-slot blocks and two row groups of 16, so a walk
+# takes 0 to 3 turns, the ping-pong slot is carried from row to row and
+# group to group in either parity, and a walked row follows empty ones.
+WALK_B, WALK_T = 32, 1536
+_RAGGED = [1400, 3, 513, 0, 1023, 1024, 1025, 64, 1536, 1, 511, 512, 600, 0, 0, 1280] * 2
+WALK_LENGTHS = {
+    # every row another number of blocks, some rows empty
+    "ragged": _RAGGED,
+    # empty rows first, last, and a whole group of 16
+    "zero-rows": [0] * 17 + [600, 0, 1536, 0, 0, 5, 0, 0, 0, 1025, 0, 0, 0, 0, 0],
+    # every row ends inside a block
+    "mid-block": [1 + (37 * i) % 511 + 512 * (i % 3) for i in range(WALK_B)],
+    # every row is exactly one block
+    "one-block": [512] * WALK_B,
+    # every row fills the cache
+    "full": [WALK_T] * WALK_B,
+}
 
-    monkeypatch.setenv("GAIE_DECODE_KERNEL_BB", "48")
-    with pytest.raises(ValueError):
-        _pick_block_b(320)  # 320 % 48 != 0
-    monkeypatch.setenv("GAIE_DECODE_KERNEL_BB", "20")
-    with pytest.raises(ValueError):
-        _pick_block_b(320)  # not a multiple of 16
-    monkeypatch.setenv("GAIE_DECODE_KERNEL_BB", "32")
-    assert _pick_block_b(320) == 32
+
+@pytest.fixture(scope="module")
+def walk_inputs():
+    kk = jax.random.split(jax.random.PRNGKey(25), 8)
+    shape = (L, KH, WALK_B, WALK_T, HD)
+    c = 8
+
+    def scales(key, n):
+        return (
+            jnp.abs(jax.random.normal(key, shape[:3] + (n,), jnp.float32)) * 0.02
+            + 0.01
+        ).astype(jnp.bfloat16)
+
+    cache = (
+        jax.random.randint(kk[0], shape, -127, 128, jnp.int8),
+        jax.random.randint(kk[1], shape, -127, 128, jnp.int8),
+        scales(kk[2], WALK_T),
+        scales(kk[3], WALK_T),
+    )
+    append = (
+        jax.random.randint(kk[4], shape[:3] + (c, HD), -127, 128, jnp.int8),
+        jax.random.randint(kk[5], shape[:3] + (c, HD), -127, 128, jnp.int8),
+        scales(kk[6], c),
+        scales(kk[7], c),
+        jnp.int32(5),
+    )
+    q = jax.random.normal(kk[7], (WALK_B, QH, HD), jnp.float32)
+    return q, cache, append
+
+
+@pytest.mark.parametrize("layer", [0, 2])
+@pytest.mark.parametrize("with_append", [False, True], ids=["cache", "append"])
+@pytest.mark.parametrize("case", sorted(WALK_LENGTHS))
+def test_row_walk_matches_xla_twin(walk_inputs, case, with_append, layer):
+    """The kernel walks each row's own blocks — none for a row of length
+    0, the last masked to the length — and equals the XLA twin, which
+    slices the whole window and masks."""
+    from generativeaiexamples_tpu.ops.decode_attention import (
+        decode_gqa_attention_xla,
+    )
+
+    q, cache, append = walk_inputs
+    lengths = jnp.asarray(WALK_LENGTHS[case], jnp.int32)
+    kw = dict(append=append if with_append else None, window=WALK_T)
+    want = decode_gqa_attention_xla(
+        q, *cache, jnp.int32(layer), lengths, **kw
+    )
+    got = decode_gqa_attention(
+        q, *cache, jnp.int32(layer), lengths, interpret=True, **kw
+    )
+    g, w = np.asarray(got), np.asarray(want)
+    np.testing.assert_allclose(g, w, rtol=1e-3, atol=1e-4)
+    if not with_append:
+        # A row of length 0 reads nothing and is exactly zero.
+        empty = np.asarray(lengths) == 0
+        np.testing.assert_array_equal(g[empty], np.zeros_like(g[empty]))
+
+
+def test_decode_chunk_dead_rows_change_nothing_that_is_kept(monkeypatch):
+    """``live`` only takes the dead rows' cache out of attention: the
+    live rows' tokens are those of a chunk that attends every row, and
+    the flush lands where it did — the cache is bit-identical on the live
+    rows and, on the dead ones, everywhere but the tail zone their
+    (never emitted) garbage is written to."""
+    from generativeaiexamples_tpu.engine.decode import make_decode_chunk_fn
+    from generativeaiexamples_tpu.models import llama
+    from generativeaiexamples_tpu.ops.decode_attention import flush_clip_start
+
+    monkeypatch.setenv("GAIE_DECODE_KERNEL_INTERPRET", "1")
+    cfg = _append_cfg()
+    b, plen, max_len, steps = 16, 8, 128, 4
+    key = jax.random.PRNGKey(3)
+    params = llama.init_params(cfg, key)
+    tokens = jax.random.randint(key, (b, plen), 0, cfg.vocab_size)
+    positions = jnp.broadcast_to(jnp.arange(plen), (b, plen))
+    cache = llama.init_kv_cache(cfg, b, max_len)
+    _, cache = llama.forward(
+        params, cfg, tokens, positions, cache,
+        jnp.full((b,), plen, jnp.int32), cold_prefill=True,
+    )
+    live = np.zeros((b,), bool)
+    live[[0, 3, 4, 9, 15]] = True
+    # Dead rows sit where the scheduler pins them: the cache's last slot.
+    lengths = jnp.asarray(np.where(live, plen, max_len - 1), jnp.int32)
+    decode_chunk = make_decode_chunk_fn(cfg, None, max_len)
+    args = (
+        tokens[:, -1], lengths, key, jnp.zeros((b,), jnp.float32),
+        jnp.ones((b,), jnp.float32), jnp.zeros((b,), jnp.int32), steps, 32,
+    )
+    copy = lambda c: tuple(jnp.array(leaf) for leaf in c)  # the cache is donated
+    cache_all, toks_all = decode_chunk(params, copy(cache), *args)
+    cache_live, toks_live = decode_chunk(
+        params, copy(cache), *args, jnp.asarray(live)
+    )
+    np.testing.assert_array_equal(
+        np.asarray(toks_live)[:, live], np.asarray(toks_all)[:, live]
+    )
+    zone = flush_clip_start(max_len, steps)
+    for got, want, before in zip(cache_live, cache_all, cache):
+        g, w, b0 = (np.asarray(x).astype(np.float32) for x in (got, want, before))
+        np.testing.assert_array_equal(g[:, :, live], w[:, :, live])
+        np.testing.assert_array_equal(g[:, :, ~live, :zone], w[:, :, ~live, :zone])
+        # ... and the live rows did write their chunk: [plen, plen + steps).
+        assert (g[:, :, live, plen : plen + steps] != b0[:, :, live, plen : plen + steps]).any()
 
 
 def test_use_decode_kernel_gating():

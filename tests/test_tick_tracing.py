@@ -154,6 +154,48 @@ def test_prefill_tokens_dispatched_is_prompt_less_reuse(running, path):
         assert d["prefill_tokens_padded"] == bucket_size(new, minimum=16, dense=True) - new
 
 
+def test_decode_kv_counters_count_the_snapshots_rows_in_blocks():
+    """One decode dispatch with a known snapshot: the decoding rows'
+    lengths in whole kernel blocks beside max_batch x kv_bucket, and the
+    chunk is told which rows decode while every row keeps its write
+    position."""
+    import numpy as np
+
+    from generativeaiexamples_tpu.ops.decode_attention import kv_tokens_read
+
+    s = Scheduler(CFG, max_batch=8, max_len=1024, decode_chunk_size=4)
+    req = Request(
+        token_ids=[1], sampling=SamplingParams(temperature=0.0, max_tokens=4),
+        on_token=lambda t: None, on_done=lambda r: None,
+    )
+    # Slots 0, 2, 5 decode (next write positions 300, 256, 40); slot 1 is
+    # warming, 3 parked, 4 admitted after the snapshot, 6 and 7 empty.
+    for i, (length, emitted) in {0: (300, 1), 2: (250, 7), 5: (40, 1), 4: (90, 1)}.items():
+        s._slots[i].request, s._slots[i].length, s._slots[i].emitted = req, length, emitted
+    s._slots[1].request, s._slots[1].warm_pos = req, 16
+    s._slots[3].cached = True
+    seen = {}
+
+    def chunk(params, cache, tokens, lengths, key, temp, top_p, top_k, n, kv_bucket, live):
+        seen.update(lengths=np.asarray(lengths), live=np.asarray(live), kv_bucket=kv_bucket)
+        return cache, np.zeros((n, 8), np.int32)
+
+    s._decode_chunk = chunk
+    before = s.stats.snapshot()
+    s._decode_dispatch([0, 2, 5])
+    d = _delta(s.stats.snapshot(), before, ["decode_kv_tokens_read", "decode_kv_tokens_dense"])
+    assert seen["live"].tolist() == [True, False, True, False, False, True, False, False]
+    assert seen["lengths"].tolist() == [300, 1023, 256, 1023, 1023, 40, 1023, 1023]
+    assert seen["kv_bucket"] == bucket_size(300 + 4 + 1, maximum=1024) == 512
+    # Blocks of 512 in a cache of 1,024: each of the three rows reads one.
+    assert d["decode_kv_tokens_read"] == 1536 == kv_tokens_read([300, 256, 40], 1024, 512)
+    assert kv_tokens_read([513, 1023, 0], 1024, 1024) == 2048
+    # A block is no wider than the window, down to 128.
+    assert kv_tokens_read([100, 40, 0], 1024, 128) == 256
+    assert kv_tokens_read([200, 40, 0], 1024, 256) == 512
+    assert d["decode_kv_tokens_dense"] == 8 * 512
+
+
 def test_clipped_prompt_is_counted_and_logged_once(running):
     s = running(max_len=64)
     limit = s._admit_limit
@@ -308,3 +350,6 @@ def test_metrics_export_the_new_counters(engine_client):
     assert exp.value("engine_prefill_tokens_dispatched_total") == len("hello there") + 1
     assert exp.types["engine_prompts_clipped_total"] == "counter"
     assert exp.value("engine_prompts_clipped_total") == 0
+    assert exp.types["engine_decode_kv_tokens_read_total"] == "counter"
+    assert exp.value("engine_decode_kv_tokens_read_total") > 0
+    assert exp.value("engine_decode_kv_tokens_dense_total") > 0
