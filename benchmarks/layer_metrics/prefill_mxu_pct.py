@@ -1,9 +1,9 @@
 """Share of the bf16 matrix peak the prefill programs reach: operations
-the prefilled tokens need (model_math.prefill_flops: the experts a token
-uses, not those the dispatch computes) over the prefill modules' device
+the prefilled tokens need (``prefill_flops`` of the configuration's
+architecture module, ``ctx["arch"]``: the experts a token uses, not those
+the dispatch computes) over the prefill modules' device
 time x the chip's peak (peaks.json)."""
 
-import model_math
 from metrics_lib import prefilled_in_trace
 from reduce_trace import modules_matching
 
@@ -17,5 +17,5 @@ def read(ctx):
     tokens, pairs = prefilled_in_trace(ctx)
     if not m["dev_s"] or not tokens:
         return None
-    need = model_math.prefill_flops(ctx["model"], tokens, pairs)
+    need = ctx["arch"].prefill_flops(ctx["model"], tokens, pairs)
     return 100.0 * need / (m["dev_s"] * ctx["peaks"]["bf16_flops"])

@@ -5,13 +5,33 @@ result, or None without ``--trace 1``), ``trace_window`` ((start, end)
 seconds from the window's start), ``counters`` (scheduler counter deltas
 over the window), ``trace_counters`` (the same between the traced
 window's two markers, or None), ``records`` (the load generator's per-request
-records), ``model`` and ``engine`` (the configuration), ``peaks`` (this
+records), ``model`` and ``engine`` (the configuration), ``arch`` (its
+architecture module: ``arch/llama.py`` says what it exports), ``peaks`` (this
 device's row of ``peaks.json``), ``window_s``.
 """
 
 from __future__ import annotations
 
+import importlib.util
 import statistics
+from pathlib import Path
+
+
+def load_module(path: Path):
+    """A Python file that is named by data (a metric's or an
+    architecture's name, which may hold ``.`` or ``-``), as a module."""
+    name = f"{path.parent.name}_{path.stem}".replace(".", "_").replace("-", "_")
+    spec = importlib.util.spec_from_file_location(name, path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def load_reader(name: str):
+    """``layer_metrics/<name>.py`` -> its ``read`` function.  A reader
+    that gives an existing reading under a second name (for cells that
+    judge another end-to-end metric) takes the first one's through this."""
+    return load_module(Path(__file__).resolve().parent / "layer_metrics" / f"{name}.py").read
 
 
 def percentile(values: list, q: float) -> float:

@@ -6,10 +6,33 @@ one-hot MoE dispatch computes all experts for every token; only the
 experts a token uses count here, so that waste shows as a low share.
 
 ``model`` is a configuration file's top level (the public config.json
-keys); ``engine`` its engine block.
+keys); ``engine`` its engine block.  These are the counts of the
+llama-shaped architecture (``arch/llama.py`` exports them); another
+architecture brings its own in its own module.
 """
 
 from __future__ import annotations
+
+# The key under which each family's config.json gives its expert count.
+EXPERT_COUNT_KEYS = ("num_local_experts", "num_experts", "n_routed_experts")
+DTYPE_BYTES = {"int8": 1, "bfloat16": 2, "float16": 2, "float32": 4}
+
+
+def n_experts(model: dict) -> int:
+    """Routed experts a layer holds; 0 for a dense model."""
+    for key in EXPERT_COUNT_KEYS:
+        if key in model:
+            return int(model[key])
+    return 0
+
+
+def head_dim(model: dict) -> int:
+    """The ``head_dim`` key, or hidden size / heads where a family's
+    config.json leaves it out."""
+    return int(
+        model.get("head_dim")
+        or int(model["hidden_size"]) // int(model["num_attention_heads"])
+    )
 
 
 def _dims(model: dict) -> dict:
@@ -18,10 +41,10 @@ def _dims(model: dict) -> dict:
         "D": int(model["hidden_size"]),
         "H": int(model["num_attention_heads"]),
         "KV": int(model["num_key_value_heads"]),
-        "HD": int(model["head_dim"]),
+        "HD": head_dim(model),
         "F": int(model["intermediate_size"]),
         "V": int(model["vocab_size"]),
-        "E": int(model.get("num_local_experts", 0)),
+        "E": n_experts(model),
         "K": int(model.get("num_experts_per_tok", 0)),
     }
 
@@ -46,8 +69,11 @@ def weight_bytes(model: dict, engine: dict) -> int:
     d = _dims(model)
     lp = layer_params(model)
     w = 1 if engine["weight_dtype"] == "int8" else 2
-    # ops.quant.QUANT_TARGETS does not cover the expert weights: bf16.
-    mlp_w = 2 if d["E"] > 1 else w
+    # The experts are served in ``engine.expert_weight_dtype``; absent, in
+    # bf16, which is what ops.quant.QUANT_TARGETS leaves them in today.
+    mlp_w = w
+    if d["E"] > 1:
+        mlp_w = DTYPE_BYTES[engine.get("expert_weight_dtype", "bfloat16")]
     per_layer = lp["attn"] * w + lp["mlp"] * mlp_w + lp["router"] * 2
     return d["L"] * per_layer + d["D"] * d["V"] * w
 

@@ -24,18 +24,29 @@ import jax.numpy as jnp
 F32 = jnp.float32
 
 
-def _pick(layers, name, layer) -> jnp.ndarray:
-    """Layer ``layer`` of a stacked weight leaf, as float32: a plain
-    array, or int8 x per-channel scale.  Sliced inside the jitted layer,
-    so that no second copy of a whole leaf is ever made."""
-    w = layers[name]
-
-    def one(a):
-        return jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False)
-
+def _dense(w, one=lambda a: a) -> jnp.ndarray:
+    """A weight leaf as float32: a plain array, int8 x per-channel scale,
+    or the W8A8 path's blocked int8 tiles (``ops/qmm.py``: column blocks
+    ``(NB, K_pad, BN)``, zero-padded), which the control run serves.
+    ``one`` picks the part of each array that is wanted."""
+    if hasattr(w, "tiles"):
+        t = one(w.tiles).astype(F32) * one(w.scale).astype(F32)
+        return jnp.moveaxis(t, -3, -2).reshape(*t.shape[:-3], t.shape[-2], -1)[
+            ..., : w.k, : w.n
+        ]
     if hasattr(w, "q") and hasattr(w, "scale"):
         return one(w.q).astype(F32) * one(w.scale).astype(F32)
     return one(w).astype(F32)
+
+
+def _pick(layers, name, layer) -> jnp.ndarray:
+    """Layer ``layer`` of a stacked weight leaf, as float32.  Sliced
+    inside the jitted layer, so that no second copy of a whole leaf is
+    ever made."""
+    return _dense(
+        layers[name],
+        lambda a: jax.lax.dynamic_index_in_dim(a, layer, 0, keepdims=False),
+    )
 
 
 def _rms_norm(x, gain, eps):
@@ -89,7 +100,8 @@ def _moe_mlp(h, layers, layer, dims):
     model; one expert (0.7 GB) does."""
     probs = jax.nn.softmax(h @ _pick(layers, "router", layer), axis=-1)  # (s, E)
     top_w, top_i = jax.lax.top_k(probs, dims["K"])
-    top_w = top_w / top_w.sum(axis=-1, keepdims=True)
+    if dims["norm_topk"]:
+        top_w = top_w / top_w.sum(axis=-1, keepdims=True)
 
     def weights(name, e):
         w = layers[name]  # (layers, experts, rows, cols): leading axes only
@@ -119,14 +131,12 @@ def _layer(x, layers, layer, dims_t):
 
 @functools.partial(jax.jit, static_argnames=("eps",))
 def _head(x_last, final_norm, lm_head, eps):
-    if hasattr(lm_head, "q"):
-        head = lm_head.q.astype(F32) * lm_head.scale.astype(F32)
-    else:
-        head = lm_head.astype(F32)
-    return _rms_norm(x_last, final_norm.astype(F32), eps) @ head
+    return _rms_norm(x_last, final_norm.astype(F32), eps) @ _dense(lm_head)
 
 
-def last_logits(params, cfg, tokens, pad_to: int = 0) -> jnp.ndarray:
+def last_logits(
+    params, cfg, tokens, pad_to: int = 0, norm_topk: bool = True
+) -> jnp.ndarray:
     """Float32 logits at the last position of one prompt.
 
     ``params`` is the serving pytree (``models.llama`` layout, layers
@@ -135,12 +145,16 @@ def last_logits(params, cfg, tokens, pad_to: int = 0) -> jnp.ndarray:
     right to that length, so that one compiled program serves prompts of
     every length up to it: attention is causal and everything else acts
     on one position, so no position before the pad sees it.
+    ``norm_topk`` False leaves the top-k routing weights as the softmax
+    over all experts gave them (families whose ``norm_topk_prob`` is
+    false); an architecture module of such a family passes it.
     """
     dims_t = tuple(
         sorted(
             {
                 "H": cfg.n_heads, "KV": cfg.n_kv_heads, "HD": cfg.head_dim,
                 "E": cfg.n_experts, "K": cfg.n_experts_per_tok,
+                "norm_topk": bool(norm_topk),
                 "theta": float(cfg.rope_theta), "eps": float(cfg.norm_eps),
             }.items()
         )

@@ -4,8 +4,9 @@
 
 One process holds the chip: it builds the engine exactly as
 ``engine.server.main()`` does (``Scheduler`` + ``create_engine_app``;
-only the preset lookup is bypassed, the configuration comes from
-``configs/<name>.json``), serves the app on 127.0.0.1, warms up every
+only the preset lookup is bypassed: the configuration comes from
+``configs/<name>.json`` through its architecture module, ``arch/``),
+serves the app on 127.0.0.1, warms up every
 program shape the traffic mix uses, then lets ``loadgen.py`` — a child
 that never imports JAX — drive the HTTP front for ``--seconds``.  The
 last line of standard output is the result; everything else goes on
@@ -24,7 +25,7 @@ PROCESS_START = time.monotonic()
 
 import argparse
 import asyncio
-import importlib.util
+import gc
 import json
 import logging
 import os
@@ -41,6 +42,7 @@ sys.path.insert(0, str(HERE))
 sys.path.insert(0, str(REPO))
 
 import metrics_lib  # noqa: E402
+from metrics_lib import load_reader  # noqa: E402
 import reduce_trace  # noqa: E402
 import traffic  # noqa: E402
 from tokenizer import BenchTokenizer, piece_ids  # noqa: E402
@@ -48,6 +50,10 @@ from tokenizer import BenchTokenizer, piece_ids  # noqa: E402
 # Long enough that the requests and module executions cut by its two
 # edges are few beside those inside (prefills last 1-1.5 s, a tick 0.2 s).
 TRACE_SECONDS = 10.0
+# The control of the reference check: the program's own path in the nearest
+# precision below the one the configurations state (bf16 activations ->
+# int8, per token, in every projection), switched on by ``--control``.
+CONTROL_ENGINE = {"matmul_kernel": "pallas_w8a8"}
 
 
 def log(msg: str, **fields) -> None:
@@ -89,41 +95,21 @@ def load_cell(name: str) -> dict:
     }
 
 
-def llama_config(model: dict, engine: dict):
-    """The public config.json keys -> the program's ``LlamaConfig``."""
-    from generativeaiexamples_tpu.models.llama import LlamaConfig
-
-    experts = int(model.get("num_local_experts", 0))
-    return LlamaConfig(
-        vocab_size=int(model["vocab_size"]),
-        d_model=int(model["hidden_size"]),
-        n_layers=int(model["num_hidden_layers"]),
-        n_heads=int(model["num_attention_heads"]),
-        n_kv_heads=int(model["num_key_value_heads"]),
-        head_dim=int(model["head_dim"]),
-        d_ff=int(model["intermediate_size"]),
-        rope_theta=float(model["rope_theta"]),
-        norm_eps=float(model["rms_norm_eps"]),
-        max_seq_len=int(engine["max_len"]),
-        dtype=str(model.get("torch_dtype", "bfloat16")),
-        kv_dtype=str(engine["kv_dtype"]),
-        n_experts=experts,
-        n_experts_per_tok=int(model.get("num_experts_per_tok", 2)),
-        # Serving routes droplessly, as main() sets it for every MoE preset.
-        moe_dropless=experts > 1,
-        hidden_act=str(model.get("hidden_act", "silu")),
-    )
+ARCH_EXPORTS = ("llama_config", "last_logits", "decode_step_bytes", "prefill_flops")
 
 
-def load_reader(name: str):
-    """``layer_metrics/<name>.py`` -> its ``read`` function."""
-    path = HERE / "layer_metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        "layer_metric_" + name.replace(".", "_").replace("-", "_"), path
-    )
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module.read
+def load_arch(config: dict):
+    """``arch/<name>.py``, named by the configuration's ``"arch"`` key
+    (absent: ``llama``): the mapping to the program's model
+    configuration, the plain reference and the roofline counts."""
+    path = HERE / "arch" / f"{config.get('arch', 'llama')}.py"
+    if not path.exists():
+        fail(f"{path.relative_to(REPO)} does not exist", 2)
+    module = metrics_lib.load_module(path)
+    missing = [f for f in ARCH_EXPORTS if not callable(getattr(module, f, None))]
+    if missing:
+        fail(f"{path.relative_to(REPO)} lacks {missing}", 2)
+    return module
 
 
 # -- the server -------------------------------------------------------------
@@ -188,6 +174,29 @@ class TickFailures(logging.Handler):
     def emit(self, record: logging.LogRecord) -> None:
         if "tick failed" in record.getMessage():
             self.count += 1
+
+
+class GcPauses:
+    """Python's garbage collections inside the window, by generation:
+    the server shares this process, and a collection holds the tick
+    thread and the front alike.  Logged, never part of a metric."""
+
+    def __init__(self) -> None:
+        self.pauses: list[tuple[int, float]] = []
+        self._since = 0.0
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._since = time.perf_counter()
+        else:
+            self.pauses.append((info["generation"], time.perf_counter() - self._since))
+
+    def summary(self) -> dict:
+        by_gen = {g: [s for gen, s in self.pauses if gen == g] for g in (0, 1, 2)}
+        return {
+            f"gen{g}": {"count": len(v), "total_s": sum(v), "longest_s": max(v, default=0.0)}
+            for g, v in by_gen.items()
+        }
 
 
 def watch_compiles() -> dict:
@@ -258,16 +267,15 @@ def http_json(port: int, path: str, body: dict, timeout: float = 600.0) -> dict:
         return json.loads(resp.read())
 
 
-def reference_check(scheduler, cfg, port, prompts, ref_cfg, pad_to) -> dict:
-    """The server's first greedy token against the float32 reference.
+def reference_check(arch, scheduler, cfg, port, prompts, ref_cfg, pad_to) -> dict:
+    """The server's first greedy token against the float32 reference of
+    the configuration's architecture module.
 
     A prompt agrees when the served token's reference logit lies within
     ``tolerance`` x max |logit| of the reference maximum; at least
     ``min_within`` of the prompts must agree (the configuration's file
     says why not all)."""
     import numpy as np
-
-    import reference
 
     rows = []
     started = time.monotonic()
@@ -279,7 +287,7 @@ def reference_check(scheduler, cfg, port, prompts, ref_cfg, pad_to) -> dict:
         served = piece_ids(out["choices"][0]["text"])
         if len(served) != 1:
             return {"ok": False, "why": f"served {len(served)} tokens for max_tokens 1"}
-        logits = np.asarray(reference.last_logits(scheduler.params, cfg, prompt, pad_to))
+        logits = np.asarray(arch.last_logits(scheduler.params, cfg, prompt, pad_to))
         rows.append(
             {
                 "gap": float(logits.max() - logits[served[0]]) / float(np.abs(logits).max()),
@@ -297,6 +305,25 @@ def reference_check(scheduler, cfg, port, prompts, ref_cfg, pad_to) -> dict:
         "seconds": time.monotonic() - started,
         "prompts": rows,
     }
+
+
+def longest_ticks(scheduler, t0: float, window_s: float, n: int = 3) -> list:
+    """The window's ``n`` longest busy ticks, seconds by phase, from the
+    scheduler's own tick record (what ``GET /debug/ticks`` serves; its
+    ``t_start`` is on the clock ``t0`` is on).  A stall is read from
+    these: which phase held the tick thread, and for how long."""
+    phases = ("plan_s", "dispatch_s", "wait_device_s", "emit_s", "telemetry_s")
+    t0 += time.perf_counter() - time.monotonic()  # the record's clock
+    ticks = [
+        r for r in scheduler.tick_records(4096)  # its whole ring
+        if t0 <= r["t_start"] < t0 + window_s
+    ]
+    ticks.sort(key=lambda r: sum(r[p] for p in phases))
+    return [
+        {"at_s": r["t_start"] - t0, "total_s": sum(r[p] for p in phases),
+         **{k: r[k] for k in phases + ("prefill_chunks", "admitted", "decode_lanes", "kv_bucket")}}
+        for r in reversed(ticks[-n:])
+    ]
 
 
 def counters(scheduler) -> dict:
@@ -331,6 +358,11 @@ def main(argv=None) -> int:
         help="override the open loop's rate (the sweep uses this; a cell's "
         "runs do not)",
     )
+    parser.add_argument(
+        "--control", action="store_true",
+        help="serve through the control path (below); the reference check has "
+        "to fail it; never a measurement",
+    )
     args = parser.parse_args(argv)
     spec = load_cell(args.workload)
     model = dict(spec["config"])
@@ -339,6 +371,8 @@ def main(argv=None) -> int:
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
         model.update(model["rehearse"]["model"])
         engine.update(model["rehearse"]["engine"])
+    if args.control:
+        engine.update(CONTROL_ENGINE)
 
     import jax
 
@@ -379,7 +413,8 @@ def main(argv=None) -> int:
     out_dir = HERE / "out" / args.workload
     out_dir.mkdir(parents=True, exist_ok=True)
 
-    cfg = llama_config(model, engine)
+    arch = load_arch(model)
+    cfg = arch.llama_config(model, engine)
     weight_seed = (args.seed ^ (args.seed >> 31)) & 0x7FFFFFFF
     t = time.monotonic()
     scheduler = Scheduler(
@@ -395,7 +430,8 @@ def main(argv=None) -> int:
         matmul_kernel=str(engine["matmul_kernel"]),
         kv_layout=str(engine["kv_layout"]),
     )
-    log("scheduler built", seconds=time.monotonic() - t, cache_dir=cache_dir)
+    log("scheduler built", seconds=time.monotonic() - t, cache_dir=cache_dir,
+        matmul_kernel=scheduler.matmul_kernel)
     app = create_engine_app(
         scheduler, BenchTokenizer(cfg.vocab_size), None, None,
         model_name=spec["cell"]["config"], enable_profiler=False,
@@ -443,6 +479,8 @@ def main(argv=None) -> int:
     logging.getLogger("jax").addHandler(compiled)
     jax.config.update("jax_log_compiles", True)
     child_env = {k: v for k, v in os.environ.items() if k != "BENCH_RUN"}
+    gc_pauses = GcPauses()
+    gc.callbacks.append(gc_pauses)
     child = subprocess.Popen(
         [sys.executable, str(HERE / "loadgen.py"), str(plan_path), str(records_path)],
         stdout=subprocess.PIPE, text=True, env=child_env,
@@ -478,6 +516,8 @@ def main(argv=None) -> int:
             child.kill()
             child.wait()
     after = counters(scheduler)
+    gc.callbacks.remove(gc_pauses)
+    slow_ticks = longest_ticks(scheduler, t0, float(args.seconds))
     compiles_in_window = compiles["requests"] - compiles_before
     jax.config.update("jax_log_compiles", False)
     if rc != 0:
@@ -510,7 +550,7 @@ def main(argv=None) -> int:
     )
     try:
         ref = reference_check(
-            scheduler, cfg, server.port,
+            arch, scheduler, cfg, server.port,
             traffic.reference_prompts(mix, args.seed, vocab, int(model["reference"]["prompts"])),
             model["reference"], int(mix["reference_len"][1]),
         )
@@ -525,7 +565,8 @@ def main(argv=None) -> int:
         "supply_lasted": not gen["supply_exhausted"],
     }
     correct = all(checks.values())
-    log("checks", checks=checks, compiles_in_window=compiles_in_window,
+    log("checks", checks=checks, arch=Path(arch.__file__).stem,
+        compiles_in_window=compiles_in_window,
         compiled_in_window=compiled.names,
         reference_check=ref, kernel_paths=report["kernel_paths"],
         finishes=sorted({str(r["finish"]) for r in records}))
@@ -572,6 +613,7 @@ def main(argv=None) -> int:
         "records": records,
         "model": model,
         "engine": engine,
+        "arch": arch,
         "peaks": peaks,
         "window_s": window_s,
     }
@@ -606,7 +648,8 @@ def main(argv=None) -> int:
         ttft_samples=len(ttft), gap_samples=len(gaps), end_to_end=e2e_values,
         counters=ctx["counters"], loadgen=gen, rate_rps=rate if is_open else None,
         t0_wall=time.time() - (time.monotonic() - t0),
-        backlog_end=after["queued"], active_end=after["active_slots"],
+        backlog_end=after["queued"], active_end=after["active_slots"], longest_ticks=slow_ticks,
+        gc_in_window=gc_pauses.summary(),
         memory=jax.local_devices()[0].memory_stats())
 
     device["memory_peak_bytes"] = report["peak_bytes_in_use"]
@@ -626,6 +669,8 @@ def main(argv=None) -> int:
         }
     if args.rehearse:
         result["rehearsal"] = "tiny sizes on the CPU: not a measurement"
+    if args.control:
+        result["control"] = "served through the control path: not a measurement"
 
     server.stop()
     scheduler.stop()

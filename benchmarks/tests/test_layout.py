@@ -7,6 +7,7 @@ from pathlib import Path
 
 import pytest
 
+import run
 import traffic
 
 BENCH = Path(__file__).resolve().parents[1]
@@ -38,6 +39,16 @@ def test_every_cell_finds_its_files(cell):
     assert "setup_s" in names and len(names) >= 2
     moved = {m["moves"] for m in SPEC["per_layer"] if cell["name"] in m.get("workloads", [cell["name"]])}
     assert moved and moved <= names  # what a reader moves is reported in this cell
+
+
+@pytest.mark.parametrize("config", SPEC["configs"], ids=lambda c: c["name"])
+def test_every_configuration_finds_its_architecture_module(config):
+    model = json.loads((BENCH.parent / config["file"]).read_text())
+    arch = run.load_arch(model)  # exits, naming the file or the function that is missing
+    assert Path(arch.__file__) == BENCH / "arch" / f"{model.get('arch', 'llama')}.py"
+    assert all(callable(getattr(arch, f)) for f in run.ARCH_EXPORTS)
+    assert arch.decode_step_bytes(model, model["engine"], 0) > 0
+    assert arch.prefill_flops(model, 1, 1) > 0
 
 
 def test_peaks_name_their_source():

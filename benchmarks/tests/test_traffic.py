@@ -5,7 +5,7 @@ import pytest
 
 import traffic
 
-MIXES = ["rag-open", "rag-closed"]
+MIXES = ["rag-open", "rag-closed", "chat-closed"]
 VOCAB = 32768
 
 
@@ -65,6 +65,22 @@ def test_rag_shares_what_it_says():
     assert 0.1 < repeats / 240 < 0.3  # about a fifth ask again
     first = [p[0] for p in shapes["picks"]]
     assert first.count(0) / 240 > 0.1  # Zipf: the top chunk leads often
+
+
+def test_chat_shares_a_line_too_short_to_hit():
+    mix = traffic.load_mix("chat-closed")
+    assert mix["arrivals"] == {"loop": "closed", "clients": 48}
+    assert mix["prefix_tokens"] == 24 and "docs" not in mix  # under MIN_PREFIX 32
+    reqs = traffic.generate(mix, 2**31 + 5, VOCAB, 512)
+    assert len({tuple(r["prompt"][:24]) for r in reqs}) == 1
+    assert len({tuple(r["prompt"][:25]) for r in reqs}) > 1  # and nothing after it
+    lens = sorted(len(r["prompt"]) - 24 for r in reqs)
+    outs = sorted(r["max_tokens"] for r in reqs)
+    assert 80 < lens[256] < 115 and 160 < outs[256] < 230  # medians 96 and 192
+    # What the warm-up plan leaves out: no request reaches the window of 2,048.
+    assert max(len(r["prompt"]) + r["max_tokens"] for r in reqs) + 9 <= 1024
+    lo, hi = mix["reference_len"]
+    assert lo <= 256 < hi <= 288  # the reference check meets both admission paths
 
 
 def test_open_loop_rate():
