@@ -3,6 +3,7 @@
 
     python chip_smoke.py [--seed N]          # one chip (what the driver runs)
     python chip_smoke.py --four-chips        # the two multi-chip paths only
+    python chip_smoke.py --hybrid [--control w8a8_mlp]   # phase 5 only
 
 Drives the system through the entry points a user calls, at llama3-8b's
 published widths with random int8 weights from ``--seed``:
@@ -24,6 +25,16 @@ published widths with random int8 weights from ``--seed``:
    the paged kernel's walk over up to eight pages is held to its XLA
    twin at tolerance, and streams of two to four pages run beside it.
 
+5. ``hybrid`` (``--hybrid``; not part of the default run) — the layer-kind
+   model (``models/hybrid.py``: KDA beside MLA, a share of the experts)
+   at ling-3.0-flash-vl-l7e128's published widths, in process on the
+   step programs' own calls: logits of a two-chunk prefill and then 64
+   positions decoded through the state, against the plain float32
+   reference's full forward over the same tokens.  ``--control
+   w8a8_mlp`` computes the reference's MLP products as W8A8 matmuls, the
+   nearest precision below the configuration's, and has to fail the
+   comparison.
+
 The parent imports no JAX: the chip belongs to one process at a time, so
 each phase is a child (or the pair engine + chain server) that has
 exited before the next starts.  Every phase prints one JSON line; any
@@ -34,6 +45,7 @@ last line is ``{"ok": true, "device": {...}}`` and nothing else.
 from __future__ import annotations
 
 import argparse
+import functools
 import dataclasses
 import json
 import os
@@ -80,6 +92,10 @@ class Sizes:
     top_k: int = 4
     optin_layers: int = 8
     optin_model: str = "llama3-8b"
+    hybrid_model: str = "ling-3.0-flash-vl-l7e128"
+    # Two prefill chunks (a whole one and a padded one), then decoding.
+    hybrid_chunks: tuple = (256, 128)
+    hybrid_decode: int = 64
     kv_heads: int = 8  # the preset's own; the tiny rehearsal needs 4 to split
     start_timeout_s: float = 600.0
     request_timeout_s: float = 600.0
@@ -102,6 +118,9 @@ TINY = Sizes(
     queries=8,
     optin_layers=2,
     optin_model="llama-tiny",
+    hybrid_model="ling-tiny",
+    hybrid_chunks=(32, 16),
+    hybrid_decode=8,
     kv_heads=4,
     start_timeout_s=240.0,
     request_timeout_s=240.0,
@@ -303,6 +322,8 @@ def _run_child(name: str, seed: int, sizes: Sizes, timeout: float) -> list:
         if ln.startswith("{"):
             lines.append(json.loads(ln))
     if proc.returncode != 0:
+        for line in lines:  # what it read before it failed
+            emit(line)
         raise SmokeFailure(
             f"phase {name} exited with code {proc.returncode}: "
             f"{proc.stderr[-3000:]}"
@@ -1208,7 +1229,139 @@ def child_four(seed: int, sizes: Sizes) -> None:
 # Entry.
 # --------------------------------------------------------------------------
 
+# Limits of the hybrid phase's comparison: quantiles over positions of
+# each position's error as a share of its reference logits' root mean
+# square.  PERF.md section 6 (PR 27) has the readings they lie between:
+# the served precision (bf16 weights and activations, float32 state)
+# below them, the control (the reference's MLP products as W8A8 matmuls)
+# above the lowest tenth and the median.  The ninth tenth reads expert
+# flips in sound runs and control alike; its limit is there for a fault
+# in some of the positions, such as state lost between chunks or steps.
+HYBRID_QUANTILE_LIMITS = {"p10": 0.025, "p50": 0.1, "p90": 0.4}
+HYBRID_LIMITS = {
+    f"{part}_{q}_share": limit
+    for part in ("prefill", "decode") for q, limit in HYBRID_QUANTILE_LIMITS.items()
+}
+
+
+def child_hybrid(seed: int, sizes: Sizes, control: str = "") -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from generativeaiexamples_tpu.engine.serving_models import serving_model
+    from generativeaiexamples_tpu.models import hybrid, hybrid_reference
+    from generativeaiexamples_tpu.utils.jax_runtime import (
+        device_report,
+        enable_compile_cache,
+        runtime_report,
+    )
+
+    enable_compile_cache()
+    t0 = time.monotonic()
+    cfg = hybrid.PRESETS[sizes.hybrid_model]()
+    model = serving_model(cfg, None, sizes.max_len)
+    params = model.prepare_params(None, quantize=False, matmul_kernel="xla", seed=seed)
+    state = model.init_state(2, sizes.max_len)
+    first, second = sizes.hybrid_chunks
+    n_prompt, n_all = first + second - 7, first + second - 7 + sizes.hybrid_decode
+    tokens = np.random.RandomState(seed).randint(1, cfg.vocab_size, size=n_all).astype(np.int32)
+
+    @functools.partial(jax.jit, donate_argnums=(1,), static_argnums=(5,))
+    def prefill(params, cache, toks, start, n, kv_bucket):
+        cache, hidden, _ = model.prefill_row(params, cache, toks, start, n, jnp.int32(1), kv_bucket)
+        return cache, model.logits(params, hidden)[0]
+
+    @functools.partial(jax.jit, donate_argnums=(1,))
+    def step(params, cache, tok, pos):
+        """One teacher-forced decode step of slot 1 (slot 0 does not
+        decode), returning its logits: decode_chunk's own body."""
+        hidden, cache, _ = hybrid.forward(
+            params, cfg, jnp.stack([tok, tok])[:, None], jnp.stack([pos, pos]),
+            jnp.asarray([0, 1], jnp.int32), cache, window=sizes.max_len,
+        )
+        return cache, model.logits(params, hidden)[1, 0]
+
+    got = []
+    padded = np.zeros((1, first), np.int32)
+    padded[0, : n_prompt - first] = tokens[first:n_prompt]
+    for toks, start, n in ((tokens[None, :first], 0, first), (padded, first, n_prompt - first)):
+        state, lg = prefill(params, state, jnp.asarray(toks), jnp.int32(start), jnp.int32(n), sizes.max_len)
+        got.append(np.asarray(lg[:n], np.float32))
+    for pos in range(n_prompt, n_all):
+        state, lg = step(params, state, jnp.int32(tokens[pos]), jnp.int32(pos))
+        got.append(np.asarray(lg, np.float32)[None])
+    got = np.concatenate(got)
+    served_s = time.monotonic() - t0
+    # The reference's full forward, in blocks of positions through the
+    # head so that a float32 (positions, vocabulary) block fits.
+    if control == "w8a8_mlp":
+        # The control: the reference computed in the nearest precision
+        # below the configuration's — every MLP product (dense, shared
+        # and routed experts) as a W8A8 matmul would compute it: int8
+        # weights, one scale an output channel, and int8 activations, one
+        # scale a token (what ``benchmarks/run.py --control`` serves the
+        # llama-shaped models through).  It has to leave the limits.
+        def int8(a, axis):
+            scale = jnp.max(jnp.abs(a), axis=axis, keepdims=True) / 127.0 + 1e-30
+            return jnp.round(a / scale) * scale
+
+        def w8a8_swiglu(h, w_gu, w_down):
+            gu = int8(h, -1) @ int8(w_gu.astype(jnp.float32), 0)
+            half = gu.shape[-1] // 2
+            act = jax.nn.silu(gu[:, :half]) * gu[:, half:]
+            return int8(act, -1) @ int8(w_down.astype(jnp.float32), 0)
+
+        hybrid_reference._swiglu = w8a8_swiglu  # this process runs nothing else
+        jax.clear_caches()  # a layer traced before this would keep the plain one
+    x = hybrid_reference.hidden_states(params, cfg, tokens)
+    want = np.concatenate([
+        np.asarray(hybrid_reference._head(
+            x[i : i + 128], params["final_norm"], params["lm_head"], float(cfg.norm_eps)))
+        for i in range(0, n_all, 128)
+    ])
+
+    # Each position's error as a share of its own logits' root mean
+    # square.  A token whose eighth and ninth expert scores lie within
+    # rounding takes another expert, and that moves its logits, and
+    # through the state those of later positions, by far more than the
+    # precision does: the lowest tenth over positions reads the
+    # arithmetic, the median and the ninth tenth read those flips too.
+    share = np.sqrt(((got - want) ** 2).mean(-1)) / np.sqrt((want**2).mean(-1))
+
+    def quantiles(lo, hi):
+        return [float(np.quantile(share[lo:hi], q)) for q in (0.1, 0.5, 0.9)]
+
+    readings = {
+        f"{part}_{q}_share": value
+        for part, span in (("prefill", (0, n_prompt)), ("decode", (n_prompt, n_all)))
+        for q, value in zip(HYBRID_QUANTILE_LIMITS, quantiles(*span))
+    }
+    worst = float(np.abs(got - want).max() / np.abs(want).max())
+    agree = int((got.argmax(-1) == want.argmax(-1)).sum())
+    failed = {k: v for k, v in readings.items() if not v <= HYBRID_LIMITS[k]}
+    report = runtime_report()
+    emit(
+        {
+            "phase": "hybrid", "model": sizes.hybrid_model, "control": control or None,
+            "positions": {"prefill": n_prompt, "decode": sizes.hybrid_decode},
+            **readings, "limits": HYBRID_LIMITS, "within_limits": not failed,
+            "first_chunk_p50": quantiles(0, first)[1],
+            "worst_abs_gap_share": worst, "argmax_agree": agree, "of": n_all,
+            "served_s": served_s, "reference_s": time.monotonic() - t0 - served_s,
+            "compile": report["compile"], "peak_bytes_in_use": report["peak_bytes_in_use"],
+            "kernel_paths": _taken("moe_experts"), "device": device_report(),
+        }
+    )
+    if control and not failed:
+        raise SmokeFailure(f"the control {control!r} stayed inside every limit: {readings}")
+    if not control and failed:
+        raise SmokeFailure(f"logits left the reference: {failed} (limits {HYBRID_LIMITS})")
+
+
 CHILDREN = {
+    "hybrid": child_hybrid,
+    "hybrid_w8a8_mlp": functools.partial(child_hybrid, control="w8a8_mlp"),
     "device": child_device,
     "retrieval": child_retrieval,
     "optin": child_optin,
@@ -1221,6 +1374,7 @@ def run(
     sizes: Sizes = FULL,
     expect: str = "tpu",
     four_chips: bool = False,
+    hybrid: str | None = None,
 ) -> dict:
     """Run the phases; returns the device for the last line.  ``sizes``
     and ``expect`` exist for the CPU rehearsal in the tests — the command
@@ -1232,6 +1386,8 @@ def run(
         )
     if four_chips:
         return child_phase("four", seed, sizes, expect, 3000, count=4)
+    if hybrid is not None:
+        return child_phase("hybrid" + ("_" + hybrid if hybrid else ""), seed, sizes, expect, 3000)
     device = phase_device(seed, sizes, expect)
     phase_serve(seed, sizes, expect)
     child_phase("retrieval", seed, sizes, expect, 900)
@@ -1248,6 +1404,15 @@ def main(argv=None) -> int:
         help="run only the tensor-parallel mesh and the replica pool, "
         "in one process that holds four chips",
     )
+    parser.add_argument(
+        "--hybrid",
+        action="store_true",
+        help="run only the layer-kind model's logits against its reference",
+    )
+    parser.add_argument(
+        "--control", choices=["w8a8_mlp"], default="",
+        help="with --hybrid: run the comparison's control, which has to fail it",
+    )
     parser.add_argument("--child", choices=sorted(CHILDREN), help=argparse.SUPPRESS)
     parser.add_argument("--sizes", help=argparse.SUPPRESS)
     args = parser.parse_args(argv)
@@ -1260,7 +1425,10 @@ def main(argv=None) -> int:
             return 1
         return 0
     try:
-        device = run(args.seed, four_chips=args.four_chips)
+        device = run(
+            args.seed, four_chips=args.four_chips,
+            hybrid=args.control if args.hybrid else None,
+        )
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)
         return 1

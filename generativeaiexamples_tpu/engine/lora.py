@@ -61,6 +61,12 @@ def init_lora_params(
     cfg: llama.LlamaConfig, lora: LoRAConfig, key: jax.Array
 ) -> dict:
     """A ~ N(0, 0.02), B = 0 (so the adapted model starts at the base)."""
+    if not isinstance(cfg, llama.LlamaConfig):
+        raise ValueError(
+            f"LoRA is not served for {type(cfg).__name__}: the adapters' "
+            "targets are the llama family's stacked projection leaves, and a "
+            "model of layer kinds has per-layer leaves of other names"
+        )
     if cfg.n_experts > 1:
         bad = [t for t in lora.targets if t in _DENSE_MLP_TARGETS]
         if bad:

@@ -41,6 +41,7 @@ cap).
 
 from __future__ import annotations
 
+import array
 from typing import Iterator, Optional, Sequence
 
 
@@ -266,3 +267,69 @@ class PrefixCacheIndex:
     def touch(self, seg_id: int) -> None:
         self._clock += 1
         self._used[seg_id] = self._clock
+
+
+class StateSnapshots:
+    """Snapshots of a slot's recurrent state, keyed by the tokens the
+    state has seen, least-recently-used first out under a byte budget.
+
+    The other half of a prefix hit for a model whose state cannot be cut
+    at a token (``engine.serving_models``: ``cut_anywhere`` False): parked
+    rows can be grafted at any depth, the recurrent state only at a depth
+    where chunked prefill saved it — every ``every`` tokens.  The key is
+    the token prefix itself (as bytes, so no collision), which makes a
+    snapshot independent of the slot that made it: two prompts that share
+    their first ``every`` tokens share the snapshot, and it outlives the
+    slot's reclaim until the budget pushes it out.  Values are opaque
+    (device arrays); each counts ``bytes_each``.
+    """
+
+    def __init__(self, every: int, bytes_each: int, budget_bytes: int) -> None:
+        self.every = int(every)
+        self.bytes_each = int(bytes_each)
+        self.capacity = max(0, int(budget_bytes) // max(1, self.bytes_each))
+        self._items: dict[bytes, object] = {}  # insertion order = recency
+
+    @staticmethod
+    def key(token_ids: Sequence[int], depth: int) -> bytes:
+        return array.array("i", token_ids[:depth]).tobytes()
+
+    @property
+    def bytes(self) -> int:
+        return len(self._items) * self.bytes_each
+
+    def __len__(self) -> int:
+        return len(self._items)
+
+    def __contains__(self, key: bytes) -> bool:
+        return key in self._items
+
+    def deepest(self, token_ids: Sequence[int], upto: int) -> int:
+        """The deepest saved depth <= ``upto`` along ``token_ids``; 0 if
+        none."""
+        depth = (min(upto, len(token_ids)) // self.every) * self.every
+        while depth > 0 and self.key(token_ids, depth) not in self._items:
+            depth -= self.every
+        return depth
+
+    def get(self, key: bytes):
+        """The snapshot under ``key``, now the most recently used."""
+        value = self._items.pop(key)
+        self._items[key] = value
+        return value
+
+    def put(self, key: bytes, value) -> int:
+        """Save ``value``; returns how many older snapshots the budget
+        pushed out."""
+        if self.capacity == 0:
+            return 0
+        self._items.pop(key, None)
+        self._items[key] = value
+        evicted = 0
+        while len(self._items) > self.capacity:
+            self._items.pop(next(iter(self._items)))
+            evicted += 1
+        return evicted
+
+    def clear(self) -> None:
+        self._items.clear()

@@ -1130,6 +1130,11 @@ class EnginePool:
         "queued",
         "prefix_hits",
         "prefix_tokens_reused",
+        "prefix_tokens_matched",
+        "state_snapshots_saved",
+        "state_snapshots_restored",
+        "state_snapshots_evicted",
+        "state_snapshot_bytes",
         "shared_prefix_hits",
         "prefill_chunks",
         "spec_rounds",

@@ -51,7 +51,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from generativeaiexamples_tpu.core.logging import get_logger
-from generativeaiexamples_tpu.engine.prefix_cache import PrefixCacheIndex
+from generativeaiexamples_tpu.engine.prefix_cache import (
+    PrefixCacheIndex,
+    StateSnapshots,
+)
 from generativeaiexamples_tpu.obs.metrics import observe_stage
 from generativeaiexamples_tpu.engine.sampler import SamplingParams, sample
 from generativeaiexamples_tpu.models import llama
@@ -91,6 +94,9 @@ class Request:
     # may fire a duplicate copy to a second replica if this one is slow
     # (first response wins; see EnginePool hedging).
     hedgeable: bool = False
+    # Prompt tokens the prefix index matched at the last lookup; what was
+    # reused of them may be less (Stats.prefix_tokens_matched).
+    prefix_matched: int = 0
 
 
 @dataclasses.dataclass
@@ -133,6 +139,10 @@ TICK_PHASES = ("idle", "plan", "dispatch", "wait_device", "emit", "telemetry")
 # The phases starved-device time is split by: there is no work to give
 # in ``idle`` and the device is by definition busy in ``wait_device``.
 STARVED_PHASES = ("plan", "dispatch", "emit", "telemetry")
+# Device bytes the snapshots of recurrent state may hold (StateSnapshots),
+# beside the slots: a tenth of a 16 GB chip, about 120 snapshots of six
+# KDA layers at the published widths.
+STATE_SNAPSHOT_BUDGET = 1_600_000_000
 # One record a busy tick (Scheduler.tick_records, GET /debug/ticks).
 TICK_RECORD_FIELDS = (
     ("tick", "t_start", "wall_start")
@@ -252,6 +262,20 @@ class Stats:
         self.rejected_total = 0
         self.prefix_hits = 0
         self.prefix_tokens_reused = 0
+        # Prompt tokens the prefix index matched for admitted requests;
+        # reused is less where a hit was cut back to a state snapshot's
+        # boundary (models whose state cannot be cut at a token).
+        self.prefix_tokens_matched = 0
+        # Snapshots of recurrent state (engine.prefix_cache.StateSnapshots):
+        # saved at prefill-chunk boundaries, restored by prefix hits,
+        # pushed out by their byte budget; bytes held now (a gauge).
+        self.state_snapshots_saved = 0
+        self.state_snapshots_restored = 0
+        self.state_snapshots_evicted = 0
+        self.state_snapshot_bytes = 0
+        # Counters the model's step programs return beside their tokens
+        # (serving_models: ``counter_names``); empty for a model with none.
+        self.model_counters: dict[str, int] = {}
         # Cross-request shared-prefix cache hits (content match through
         # the radix index; session matches count under prefix_hits) and
         # chunked-prefill chunk dispatches.  prefix_tokens_reused pools
@@ -405,6 +429,12 @@ class Stats:
                 "rejected_total": self.rejected_total,
                 "prefix_hits": self.prefix_hits,
                 "prefix_tokens_reused": self.prefix_tokens_reused,
+                "prefix_tokens_matched": self.prefix_tokens_matched,
+                "state_snapshots_saved": self.state_snapshots_saved,
+                "state_snapshots_restored": self.state_snapshots_restored,
+                "state_snapshots_evicted": self.state_snapshots_evicted,
+                "state_snapshot_bytes": self.state_snapshot_bytes,
+                **self.model_counters,
                 "shared_prefix_hits": self.shared_prefix_hits,
                 "prefill_chunks": self.prefill_chunks,
                 "spec_rounds": self.spec_rounds,
@@ -511,17 +541,25 @@ class Scheduler:
         self.stats = Stats()
         self._key = jax.random.PRNGKey(seed)
         from generativeaiexamples_tpu.engine.decode import (
-            make_decode_chunk_fn,
             prepare_cache,
             prepare_params,
         )
+        from generativeaiexamples_tpu.engine.serving_models import (
+            serving_model,
+        )
 
+        # The model behind the step programs (engine/serving_models.py):
+        # everything below that touches parameters or slot state goes
+        # through it.  It refuses, with the reason, what it does not serve.
+        self.model = model = serving_model(cfg, mesh, self.max_len)
+        model.check_supported(
+            kv_layout=kv_layout, draft_cfg=draft_cfg, spec_mode=spec_mode
+        )
         # ``quantize`` is the int8-weights serving configuration: float
         # (or absent, hence random) params become int8 projections with
         # qkv and gate/up packed — what bench.py hands over pre-built.
-        self.params = prepare_params(
-            cfg, params, mesh, quantize=quantize, pack=quantize,
-            matmul_kernel=matmul_kernel, seed=seed,
+        self.params = model.prepare_params(
+            params, quantize=quantize, matmul_kernel=matmul_kernel, seed=seed
         )
         # The layout the params are in (prepare_params refuses a
         # pallas_w8a8 request it cannot honour; params may also arrive
@@ -602,8 +640,8 @@ class Scheduler:
             # the same free list.
             self._kv_pages_reserved = 0
         else:
-            self._cache = prepare_cache(cfg, max_batch, self.max_len, mesh)
-            self._decode_chunk = make_decode_chunk_fn(cfg, mesh, self.max_len)
+            self._cache = model.init_state(max_batch, self.max_len)
+            self._decode_chunk = model.make_decode_chunk()
         # Speculative decoding (TRT-LLM draft-model parity, SURVEY.md
         # §2.8): a draft config turns every decode chunk into speculation
         # rounds — draft proposes gamma tokens, target verifies in one
@@ -726,6 +764,22 @@ class Scheduler:
         if prefill_chunk_tokens is not None and prefill_chunk_tokens <= 0:
             prefill_chunk_tokens = None
         self.prefill_chunk_tokens = prefill_chunk_tokens
+        # A model whose state cannot be cut at a token is reused only from
+        # where chunked prefill saved it: at every chunk boundary, under a
+        # byte budget of its own (StateSnapshots).  Without chunking there
+        # is no boundary, and every prefix hit is cut back to nothing.
+        self._snapshots: Optional[StateSnapshots] = None
+        if not model.cut_anywhere:
+            self._snapshots = StateSnapshots(
+                prefill_chunk_tokens or self.max_len,
+                model.snapshot_bytes,
+                STATE_SNAPSHOT_BUDGET if prefill_chunk_tokens else 0,
+            )
+        self.stats.model_counters = dict.fromkeys(model.counter_names, 0)
+        # Counters the step programs return beside their tokens, not yet
+        # fetched: drained once ready, after a token fetch, so that they
+        # cost no synchronisation of their own.
+        self._aux_pending: list = []
         # Pipelined ticks dispatch the decode chunk in the same tick as
         # admissions, pinning not-yet-decoding lanes to max_len - 1 —
         # whose append-buffer flush garbage-writes [max_len - w, max_len)
@@ -800,17 +854,12 @@ class Scheduler:
             dominates at b == 1), so all waiting requests prefill together
             and then graft row-by-row into their slots.
             """
-            b, s = tokens.shape
-            small = llama.init_kv_cache(cfg, b, s)
-            positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
-            hidden, small = llama.forward(
-                params, cfg, tokens, positions, small, lengths, mesh=mesh_arg,
-                cold_prefill=True,
-            )
+            b = tokens.shape[0]
+            hidden, small, aux = model.prefill_cold(params, tokens, lengths)
             last = hidden[jnp.arange(b), jnp.maximum(lengths - 1, 0)]
-            lg = llama.logits(params, last[:, None, :])[:, 0]
+            lg = model.logits(params, last[:, None, :])[:, 0]
             tok = sample(lg, key, temp, top_p, top_k)
-            return small, tok
+            return small, tok, aux
 
         @functools.partial(jax.jit, donate_argnums=(0,))
         @jax.named_scope("kv_write")
@@ -822,15 +871,8 @@ class Scheduler:
 
             ``rows``/``slots`` are equal-length int32 vectors, padded by
             the caller with duplicates of index 0 (duplicate scatters of
-            the same source row are harmless).  Works leaf-wise over the
-            head-major (L, KH, B, T, ...) cache tuple (2 leaves for bf16
-            KV, 4 for int8 KV): rows/slots index axis 2, the slot axis."""
-            out = []
-            for bg, sm in zip(big, small):
-                s = sm.shape[3]
-                gathered = jnp.take(sm, rows, axis=2)  # (L, KH, k, s, ...)
-                out.append(bg.at[:, :, slots, :s].set(gathered))
-            return tuple(out)
+            the same source row are harmless)."""
+            return model.graft_rows(big, small, rows, slots)
 
         @functools.partial(
             jax.jit, donate_argnums=(1,), static_argnums=(8,)
@@ -848,37 +890,13 @@ class Scheduler:
             back the slot's cached prefix via the warm (non-cold) path.
             """
             temp, top_p, top_k = sampling
-            s = tokens.shape[1]
-            row = tuple(
-                jax.lax.dynamic_slice(
-                    bg,
-                    (0, 0, slot) + (0,) * (bg.ndim - 3),
-                    bg.shape[:2] + (1,) + bg.shape[3:],
-                )
-                for bg in cache
+            cache, hidden, aux = model.prefill_row(
+                params, cache, tokens, start, suffix_len, slot, kv_bucket
             )
-            positions = start + jnp.arange(s, dtype=jnp.int32)[None, :]
-            hidden, row = llama.forward(
-                params,
-                cfg,
-                tokens,
-                positions,
-                row,
-                jnp.reshape(start + suffix_len, (1,)),
-                mesh=mesh_arg,
-                kv_bucket=kv_bucket,
-            )
-            with jax.named_scope("kv_write"):
-                cache = tuple(
-                    jax.lax.dynamic_update_slice(
-                        bg, r, (0, 0, slot) + (0,) * (bg.ndim - 3)
-                    )
-                    for bg, r in zip(cache, row)
-                )
             last = hidden[0, jnp.maximum(suffix_len - 1, 0)]
-            lg = llama.logits(params, last[None, None, :])[:, 0]
+            lg = model.logits(params, last[None, None, :])[:, 0]
             tok = sample(lg, key, temp, top_p, top_k)
-            return cache, tok
+            return cache, tok, aux
 
         @functools.partial(jax.jit, donate_argnums=(0,), static_argnums=(3,))
         @jax.named_scope("kv_write")
@@ -890,21 +908,17 @@ class Scheduler:
             beyond the actual common prefix is harmless — positions past
             the destination's live length are rewritten by its own
             suffix prefill/decode before any attention mask exposes
-            them.  Leaf-generic over the head-major cache tuple like
-            ``_graft_rows`` (2 bf16 leaves or 4 int8+scale leaves)."""
-            out = []
-            for bg in cache:
-                rows = jax.lax.dynamic_slice(
-                    bg,
-                    (0, 0, src, 0) + (0,) * (bg.ndim - 4),
-                    bg.shape[:2] + (1, min(n, bg.shape[3])) + bg.shape[4:],
-                )
-                out.append(
-                    jax.lax.dynamic_update_slice(
-                        bg, rows, (0, 0, dst, 0) + (0,) * (bg.ndim - 4)
-                    )
-                )
-            return tuple(out)
+            them."""
+            return model.graft_prefix(cache, src, dst, n)
+
+        if self._snapshots is not None:
+            self._save_state = jax.jit(
+                jax.named_scope("state_snapshot")(model.save_state)
+            )
+            self._restore_state = jax.jit(
+                jax.named_scope("state_snapshot")(model.restore_state),
+                donate_argnums=(0,),
+            )
 
         self._prefill_some = _prefill_some
         self._prefill_suffix = _prefill_suffix
@@ -1101,6 +1115,66 @@ class Scheduler:
         self._key, sub = jax.random.split(self._key)
         return sub
 
+    def _note_aux(self, aux=None) -> None:
+        """Keep a step program's counters until they can be fetched
+        without waiting (``_drain_aux``)."""
+        if aux is not None:
+            self._aux_pending.append(aux)
+
+    def _drain_aux(self) -> None:
+        """Add up the counters of every step program that has finished.
+        Called right after a token fetch: the device runs its programs
+        in order, so whatever was dispatched before the fetched one is
+        ready and nothing here waits."""
+        if not self._aux_pending:
+            return
+        ready, waiting = [], []
+        for a in self._aux_pending:
+            (ready if a.is_ready() else waiting).append(a)
+        if not ready:
+            return
+        self._aux_pending = waiting
+        total = np.sum([np.asarray(a, dtype=np.int64) for a in ready], axis=0)
+        with self.stats.lock:
+            for name, n in zip(self.model.counter_names, total):
+                self.stats.model_counters[name] += int(n)
+
+    def _state_depth(self, req: Request, common: int) -> int:
+        """How much of a ``common``-token prefix match can be reused: all
+        of it where the state can be cut at any token, else up to the
+        deepest boundary at which a snapshot of the state is held."""
+        req.prefix_matched = common
+        if self._snapshots is None:
+            return common
+        return self._snapshots.deepest(req.token_ids, common)
+
+    def _save_boundary(self, slot: _Slot, slot_idx: int, depth: int) -> None:
+        """After a prefill chunk that ended at ``depth``: keep the slot's
+        recurrent state if ``depth`` is a snapshot boundary."""
+        snaps = self._snapshots
+        if snaps is None or depth % snaps.every or not snaps.capacity:
+            return
+        key = snaps.key(slot.history, depth)
+        if key in snaps:
+            snaps.get(key)  # fresh again
+            return
+        evicted = snaps.put(key, self._save_state(self._cache, jnp.int32(slot_idx)))
+        with self.stats.lock:
+            self.stats.state_snapshots_saved += 1
+            self.stats.state_snapshots_evicted += evicted
+            self.stats.state_snapshot_bytes = snaps.bytes
+
+    def _restore_boundary(self, req: Request, slot_idx: int, depth: int) -> None:
+        """Before a prefix hit's suffix runs: put the state saved at
+        ``depth`` tokens of ``req``'s prompt into the slot."""
+        snaps = self._snapshots
+        if snaps is None or depth <= 0:
+            return
+        snap = snaps.get(snaps.key(req.token_ids, depth))
+        self._cache = self._restore_state(self._cache, jnp.int32(slot_idx), snap)
+        with self.stats.lock:
+            self.stats.state_snapshots_restored += 1
+
     def _set_cache(self, leaves) -> None:
         """Install updated cache buffers; in paged mode the pool owns
         the leaves (its COW copies replace them too), so keep the two
@@ -1270,6 +1344,7 @@ class Scheduler:
                 self.stats.queue_wait_s_sum += now - req.submitted_at
                 self.stats.queue_wait_count += 1
                 self.stats.prompt_tokens_admitted += len(req.token_ids)
+                self.stats.prefix_tokens_matched += req.prefix_matched
 
     def _note_first_token(self, req: Request) -> None:
         """``req``'s first token was fetched: TTFT and its prefill part.
@@ -1424,7 +1499,7 @@ class Scheduler:
             "dispatch", program="_prefill_some", tokens=sum(plens),
             rows=pb, bucket=s,
         )
-        small, tok = self._prefill_some(
+        small, tok, aux = self._prefill_some(
             self.params,
             jnp.asarray(tokens),
             jnp.asarray(lengths),
@@ -1433,6 +1508,7 @@ class Scheduler:
             jnp.asarray(top_p),
             jnp.asarray(top_k),
         )
+        self._note_aux(aux)
         k = len(reqs)
         kb = bucket_size(k, minimum=min(4, pb))
         rows = np.zeros((kb,), dtype=np.int32)
@@ -1507,6 +1583,7 @@ class Scheduler:
         tok_host = np.asarray(tok)
         self._clock.fetched(ticket)
         self._clock.enter("emit")
+        self._drain_aux()
         now = time.perf_counter()
         for r, (req, slot_idx) in enumerate(zip(reqs, slot_idxs)):
             req.first_token_at = now
@@ -1530,6 +1607,7 @@ class Scheduler:
         (contiguous) or a page-owning segment (paged) — whose cached
         history is a long-enough prefix of the new prompt; returns
         (slot_or_seg, prefix_len) or (-1, 0)."""
+        req.prefix_matched = 0
         if not req.session_id:
             return -1, 0
         if self._pool is not None:
@@ -1543,6 +1621,7 @@ class Scheduler:
                 if a != b:
                     break
                 n += 1
+            n = self._state_depth(req, n)
             if n >= self.MIN_PREFIX:
                 return seg, n
             return -1, 0
@@ -1553,6 +1632,7 @@ class Scheduler:
                     if a != b:
                         break
                     n += 1
+                n = self._state_depth(req, n)
                 if n >= self.MIN_PREFIX:
                     return i, n
                 return -1, 0
@@ -1568,6 +1648,8 @@ class Scheduler:
         if seg is None:
             return -1, 0
         common = min(common, len(req.token_ids) - 1)
+        if common >= self.MIN_PREFIX:
+            common = self._state_depth(req, common)
         if common < self.MIN_PREFIX:
             return -1, 0
         if self._pool is None:
@@ -1616,7 +1698,7 @@ class Scheduler:
             )
             self._set_cache(cache)
         else:
-            cache, tok = self._prefill_suffix(
+            cache, tok, aux = self._prefill_suffix(
                 self.params,
                 self._cache,
                 jnp.asarray(tokens),
@@ -1628,6 +1710,7 @@ class Scheduler:
                 kv_bucket,
             )
             self._cache = cache
+            self._note_aux(aux)
         if self.draft_cfg is not None:
             # Draft-side twin: the draft cache row must cover the same
             # [0, plen) window as the target's before the next spec round
@@ -1678,6 +1761,7 @@ class Scheduler:
         tok_host = int(np.asarray(tok)[0])
         self._clock.fetched(ticket)
         self._clock.enter("emit")
+        self._drain_aux()
         req.first_token_at = time.perf_counter()
         with self.stats.lock:
             self._note_first_token(req)
@@ -1702,6 +1786,8 @@ class Scheduler:
         chunk in a later tick)."""
         plen = len(req.token_ids)
         common = min(common, plen - 1, self._admit_limit - 2)
+        if self._snapshots is not None:
+            common = self._snapshots.deepest(req.token_ids, common)
         self._note_claim([req])
         with self.stats.lock:
             self.stats.queued -= 1
@@ -1711,6 +1797,7 @@ class Scheduler:
                 self.stats.prefix_hits += 1
             self.stats.prefix_tokens_reused += common
         self._unpark(slot_idx)  # consumed: off the index, cached cleared
+        self._restore_boundary(req, slot_idx, common)
         if (
             self.prefill_chunk_tokens
             and plen - common > self.prefill_chunk_tokens
@@ -1959,7 +2046,7 @@ class Scheduler:
             )
             self._set_cache(cache)
         else:
-            cache, tok = self._prefill_suffix(
+            cache, tok, aux = self._prefill_suffix(
                 self.params,
                 self._cache,
                 jnp.asarray(tokens),
@@ -1971,6 +2058,8 @@ class Scheduler:
                 kv_bucket,
             )
             self._cache = cache
+            self._note_aux(aux)
+            self._save_boundary(slot, slot_idx, pos + n)
         if self.draft_cfg is not None:
             # Same chunk through the draft: both caches advance their
             # warm frontier together, so whenever the slot joins decode
@@ -2096,14 +2185,19 @@ class Scheduler:
                 self._pool.reset_all()
                 self._cache = self._pool.leaves
             else:
+                self._cache = self.model.init_state(
+                    self.max_batch, self.max_len
+                )
+            self._aux_pending.clear()
+            if self._snapshots is not None:
+                self._snapshots.clear()
+                with self.stats.lock:
+                    self.stats.state_snapshot_bytes = 0
+            if self.draft_cfg is not None:
                 from generativeaiexamples_tpu.engine.decode import (
                     prepare_cache,
                 )
 
-                self._cache = prepare_cache(
-                    self.cfg, self.max_batch, self.max_len, self.mesh
-                )
-            if self.draft_cfg is not None:
                 self._dcache = prepare_cache(
                     self.draft_cfg, self.max_batch, self.max_len,
                     self.mesh,
@@ -2981,7 +3075,7 @@ class Scheduler:
             self._set_cache(cache)
         else:
             lengths = np.minimum(lengths, self.max_len - 1)
-            cache, toks = self._decode_chunk(
+            cache, toks, *aux = self._decode_chunk(
                 self.params,
                 self._cache,
                 jnp.asarray(self._cur_tok),
@@ -2995,6 +3089,7 @@ class Scheduler:
                 jnp.asarray(snap),
             )
             self._cache = cache
+            self._note_aux(*aux)
             with self.stats.lock:
                 self.stats.decode_kv_tokens_read += kv_tokens_read(
                     lengths[snap], self.max_len, kv_bucket
@@ -3017,6 +3112,7 @@ class Scheduler:
         toks_host = np.asarray(toks)  # (chunk, b)
         self._clock.fetched(ticket)
         self._clock.enter("emit")
+        self._drain_aux()
         if active:
             self._cur_tok[active] = toks_host[-1][active]
         for row in toks_host:

@@ -790,9 +790,22 @@ async def handle_metrics(request: web.Request) -> web.Response:
         ("engine_prefill_tokens_padded_total", "prefill_tokens_padded", "d"),
         ("engine_decode_kv_tokens_read_total", "decode_kv_tokens_read", "d"),
         ("engine_decode_kv_tokens_dense_total", "decode_kv_tokens_dense", "d"),
+        ("engine_prefix_tokens_matched_total", "prefix_tokens_matched", "d"),
+        ("engine_state_snapshots_saved_total", "state_snapshots_saved", "d"),
+        ("engine_state_snapshots_restored_total", "state_snapshots_restored", "d"),
+        ("engine_state_snapshots_evicted_total", "state_snapshots_evicted", "d"),
+        # What the model's step programs count (an expert model's routing:
+        # ops.moe.COUNTERS); none for a model that returns none.
+        *(
+            (f"engine_{key}_total", key, "d")
+            for key in sorted(snap)
+            if key.startswith("moe_")
+        ),
     ):
         lines.append(f"# TYPE {name} counter")
         lines.append(f"{name} {format(snap.get(key, 0), fmt)}")
+    lines.append("# TYPE engine_state_snapshot_bytes gauge")
+    lines.append(f"engine_state_snapshot_bytes {snap.get('state_snapshot_bytes', 0)}")
     # Which serving matmul path is live (info-style gauge: every known
     # value exported, the active one carrying 1) — deployments can alert
     # on the fused kernel silently falling back to XLA.  From zero:
@@ -1311,8 +1324,11 @@ def main() -> None:
         os.environ["GAIE_EXACT_SAMPLING"] = "1"
 
     preset = resolve_model_preset(args.model)
-    cfg = llama.PRESETS[preset]()
-    if cfg.n_experts > 1:
+    from generativeaiexamples_tpu.models import hybrid
+
+    cfg = (hybrid.PRESETS.get(preset) or llama.PRESETS[preset])()
+    is_llama = isinstance(cfg, llama.LlamaConfig)
+    if is_llama and cfg.n_experts > 1:
         # Serving decodes must match reference (dropless) MoE routing
         # token-for-token; training keeps capacity-factor dropping, so the
         # flag lives here rather than in the shared geometry preset.
@@ -1324,6 +1340,11 @@ def main() -> None:
 
     params = None
     ckpt_dir = weights_dir_for(args.model)
+    if ckpt_dir and not is_llama:
+        raise SystemExit(
+            f"no checkpoint loader for {preset}: its layer kinds are served "
+            "with random weights only"
+        )
     if ckpt_dir:
         logger.info("loading weights from %s", ckpt_dir)
         params = load_hf_causal_lm(cfg, ckpt_dir)

@@ -1,0 +1,320 @@
+"""What the scheduler asks of a model: one small interface, two servers.
+
+``Scheduler`` builds its step programs (``_prefill_some``,
+``_prefill_suffix``, ``_graft_rows``, ``_graft_prefix``, ``decode_chunk``)
+from these calls and knows nothing else about the model:
+
+``prepare_params``   random or given parameters, as they are served
+``init_state``       the slots' state (``max_batch`` rows of ``max_len``)
+``prefill_cold``     a batch of whole prompts into fresh state
+``prefill_row``      a run of one slot's prompt from a given position
+``graft_rows``       rows of a cold batch's state into their slots
+``graft_prefix``     the first n token rows of one slot into another
+``save_state`` / ``restore_state``   a snapshot of what cannot be cut at a
+                     token: a slot's recurrent state, as of its last token
+``logits``           hidden states -> vocabulary logits
+``make_decode_chunk``   the compiled multi-step decode
+
+``cut_anywhere`` says whether a prefix of the state can be taken at any
+token (K/V rows can; a recurrent state exists only where it was saved, so
+the scheduler keeps snapshots at prefill-chunk boundaries and cuts a
+prefix hit back to the deepest one).  ``counter_names`` names the int32
+counters each step program returns beside its tokens (``aux``; None for a
+model that has none).
+
+``LlamaServing`` is ``models/llama.py`` exactly as the scheduler used to
+call it, so the programs of every llama-shaped configuration compile as
+they did; ``HybridServing`` serves ``models/hybrid.py``'s layer kinds.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+
+from generativeaiexamples_tpu.engine.sampler import sample
+from generativeaiexamples_tpu.models import hybrid, llama
+from generativeaiexamples_tpu.ops import moe
+
+
+def serving_model(cfg, mesh, max_len: int):
+    """The server of ``cfg``'s kind."""
+    if isinstance(cfg, hybrid.HybridConfig):
+        return HybridServing(cfg, mesh, max_len)
+    return LlamaServing(cfg, mesh, max_len)
+
+
+class LlamaServing:
+    cut_anywhere = True
+    counter_names: tuple = ()
+    snapshot_bytes = 0
+
+    def __init__(self, cfg: llama.LlamaConfig, mesh, max_len: int) -> None:
+        self.cfg, self.mesh, self.max_len = cfg, mesh, max_len
+
+    def check_supported(self, **_) -> None:
+        """Every option of the scheduler serves this model."""
+
+    def prepare_params(self, params, *, quantize, matmul_kernel, seed):
+        from generativeaiexamples_tpu.engine.decode import prepare_params
+
+        return prepare_params(
+            self.cfg, params, self.mesh, quantize=quantize, pack=quantize,
+            matmul_kernel=matmul_kernel, seed=seed,
+        )
+
+    def init_state(self, batch: int, max_len: int):
+        from generativeaiexamples_tpu.engine.decode import prepare_cache
+
+        return prepare_cache(self.cfg, batch, max_len, self.mesh)
+
+    def prefill_cold(self, params, tokens, lengths):
+        b, s = tokens.shape
+        small = llama.init_kv_cache(self.cfg, b, s)
+        positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
+        hidden, small = llama.forward(
+            params, self.cfg, tokens, positions, small, lengths, mesh=self.mesh,
+            cold_prefill=True,
+        )
+        return hidden, small, None
+
+    def graft_rows(self, big, small, rows, slots):
+        """One scatter per leaf for the whole admission batch, leaf-wise
+        over the head-major (L, KH, B, T, ...) cache tuple (2 leaves for
+        bf16 KV, 4 for int8 KV): rows/slots index axis 2, the slot axis."""
+        out = []
+        for bg, sm in zip(big, small):
+            s = sm.shape[3]
+            gathered = jnp.take(sm, rows, axis=2)  # (L, KH, k, s, ...)
+            out.append(bg.at[:, :, slots, :s].set(gathered))
+        return tuple(out)
+
+    def prefill_row(self, params, cache, tokens, start, suffix_len, slot, kv_bucket):
+        s = tokens.shape[1]
+        row = tuple(
+            jax.lax.dynamic_slice(
+                bg,
+                (0, 0, slot) + (0,) * (bg.ndim - 3),
+                bg.shape[:2] + (1,) + bg.shape[3:],
+            )
+            for bg in cache
+        )
+        positions = start + jnp.arange(s, dtype=jnp.int32)[None, :]
+        hidden, row = llama.forward(
+            params,
+            self.cfg,
+            tokens,
+            positions,
+            row,
+            jnp.reshape(start + suffix_len, (1,)),
+            mesh=self.mesh,
+            kv_bucket=kv_bucket,
+        )
+        with jax.named_scope("kv_write"):
+            cache = tuple(
+                jax.lax.dynamic_update_slice(
+                    bg, r, (0, 0, slot) + (0,) * (bg.ndim - 3)
+                )
+                for bg, r in zip(cache, row)
+            )
+        return cache, hidden, None
+
+    def graft_prefix(self, cache, src, dst, n: int):
+        """Leaf-generic over the head-major cache tuple like
+        ``graft_rows`` (2 bf16 leaves or 4 int8+scale leaves)."""
+        out = []
+        for bg in cache:
+            rows = jax.lax.dynamic_slice(
+                bg,
+                (0, 0, src, 0) + (0,) * (bg.ndim - 4),
+                bg.shape[:2] + (1, min(n, bg.shape[3])) + bg.shape[4:],
+            )
+            out.append(
+                jax.lax.dynamic_update_slice(
+                    bg, rows, (0, 0, dst, 0) + (0,) * (bg.ndim - 4)
+                )
+            )
+        return tuple(out)
+
+    def logits(self, params, hidden):
+        return llama.logits(params, hidden)
+
+    def make_decode_chunk(self):
+        from generativeaiexamples_tpu.engine.decode import make_decode_chunk_fn
+
+        return make_decode_chunk_fn(self.cfg, self.mesh, self.max_len)
+
+
+class HybridServing:
+    """``models/hybrid.py`` behind the same calls.  The state is a tuple
+    of one dict a layer (``hybrid.init_state``), slot axis first."""
+
+    cut_anywhere = False
+    counter_names = tuple(f"moe_{n}" for n in moe.COUNTERS)
+
+    def __init__(self, cfg: hybrid.HybridConfig, mesh, max_len: int) -> None:
+        self.cfg, self.mesh, self.max_len = cfg, mesh, max_len
+        self.snapshot_bytes = cfg.snapshot_bytes()
+
+    def check_supported(
+        self, *, kv_layout="contiguous", draft_cfg=None, spec_mode=None, **_
+    ) -> None:
+        """What is not served for a model with recurrent state, refused
+        with the reason."""
+        if draft_cfg is not None or spec_mode is not None:
+            raise ValueError(
+                "speculative decoding is not served for a model with "
+                "recurrent state: a rejected draft would need the state "
+                "rolled back, and no step keeps the state it started from"
+            )
+        if kv_layout != "contiguous":
+            raise ValueError(
+                "the paged layout is not served for this model: its pages "
+                "hold K/V rows of one shape, not latent rows beside a "
+                "fixed recurrent state"
+            )
+        if self.mesh is not None and self.mesh.size > 1:
+            raise ValueError(
+                "one device holds this model's share: the exchange between "
+                "expert shares is not implemented"
+            )
+        self.cfg.state_dtype  # refuses int8 state
+
+    def prepare_params(self, params, *, quantize, matmul_kernel, seed):
+        if quantize or matmul_kernel not in (None, "xla"):
+            raise ValueError(
+                "int8 weights are not served for this model: "
+                "ops.quant.QUANT_TARGETS covers neither the experts nor the "
+                "KDA and MLA projections"
+            )
+        if params is None:
+            key = jax.random.PRNGKey(seed)
+            params = hybrid.balance_router_biases(
+                hybrid.init_params(self.cfg, key), self.cfg, jax.random.fold_in(key, 1)
+            )
+        return params
+
+    def init_state(self, batch: int, max_len: int):
+        return hybrid.init_state(self.cfg, batch, max_len)
+
+    def prefill_cold(self, params, tokens, lengths):
+        b, s = tokens.shape
+        return hybrid.forward(
+            params, self.cfg, tokens, jnp.zeros((b,), jnp.int32), lengths,
+            hybrid.init_state(self.cfg, b, s), window=s, mesh=self.mesh,
+        )
+
+    def graft_rows(self, big, small, rows, slots):
+        out = []
+        for bg, sm in zip(big, small):
+            layer = {}
+            for name, leaf in bg.items():
+                picked = jnp.take(sm[name], rows, axis=0)
+                if name == "latent":
+                    layer[name] = leaf.at[slots, : picked.shape[1]].set(picked)
+                else:
+                    layer[name] = leaf.at[slots].set(picked)
+            out.append(layer)
+        return tuple(out)
+
+    def prefill_row(self, params, cache, tokens, start, suffix_len, slot, kv_bucket):
+        def take(leaf):
+            return jax.lax.dynamic_slice_in_dim(leaf, slot, 1, axis=0)
+
+        row = jax.tree.map(take, cache)
+        # A prompt that starts here starts from nothing, whatever the
+        # slot's last occupant left (K/V rows can be stale; a state cannot).
+        row = tuple(
+            {n: (leaf if n == "latent" else jnp.where(start == 0, 0, leaf))
+             for n, leaf in layer.items()}
+            for layer in row
+        )
+        hidden, row, aux = hybrid.forward(
+            params, self.cfg, tokens, jnp.reshape(start, (1,)),
+            jnp.reshape(suffix_len, (1,)), row, window=kv_bucket, mesh=self.mesh,
+        )
+        with jax.named_scope("kv_write"):
+            cache = jax.tree.map(
+                lambda bg, r: jax.lax.dynamic_update_slice_in_dim(bg, r, slot, axis=0),
+                cache, row,
+            )
+        return cache, hidden, aux
+
+    def graft_prefix(self, cache, src, dst, n: int):
+        """The first ``n`` latent rows; the recurrent state comes from a
+        snapshot (``restore_state``), since the source's has moved on."""
+        out = []
+        for layer in cache:
+            if "latent" in layer:
+                lat = layer["latent"]
+                rows = jax.lax.dynamic_slice(
+                    lat, (src, 0, 0), (1, min(n, lat.shape[1]), lat.shape[2])
+                )
+                layer = {"latent": jax.lax.dynamic_update_slice(lat, rows, (dst, 0, 0))}
+            out.append(layer)
+        return tuple(out)
+
+    def save_state(self, cache, slot):
+        """One slot's recurrent state, a copy a KDA layer."""
+        return tuple(
+            {n: jax.lax.dynamic_index_in_dim(leaf, slot, 0, keepdims=False)
+             for n, leaf in layer.items()}
+            for layer in cache if "latent" not in layer
+        )
+
+    def restore_state(self, cache, slot, snap):
+        snaps = iter(snap)
+        out = []
+        for layer in cache:
+            if "latent" not in layer:
+                saved = next(snaps)
+                layer = {
+                    n: jax.lax.dynamic_update_index_in_dim(leaf, saved[n], slot, 0)
+                    for n, leaf in layer.items()
+                }
+            out.append(layer)
+        return tuple(out)
+
+    def logits(self, params, hidden):
+        return hybrid.logits(params, self.cfg, hidden)
+
+    def make_decode_chunk(self):
+        cfg, mesh, max_len = self.cfg, self.mesh, self.max_len
+
+        @functools.partial(jax.jit, donate_argnums=(1,), static_argnums=(8, 9))
+        def decode_chunk(
+            params, cache, tokens, lengths, key, temp, top_p, top_k,
+            n_steps, kv_bucket=None, live=None,
+        ):
+            """The signature of ``engine.decode``'s chunk, and a third
+            result: the counters summed over the steps.  A row that does
+            not decode (``live`` False) writes no latent row and leaves its
+            state as it was; its tokens are finite and never emitted."""
+            window = min(kv_bucket, max_len) if kv_bucket else max_len
+            b = tokens.shape[0]
+            counts = (
+                jnp.ones((b,), jnp.int32) if live is None else live.astype(jnp.int32)
+            )
+
+            def body(carry, step):
+                cache, tok, key, aux = carry
+                key, sub = jax.random.split(key)
+                start = jnp.minimum(lengths + step, max_len - 1)
+                hidden, cache, c = hybrid.forward(
+                    params, cfg, tok[:, None], start, counts, cache,
+                    window=window, mesh=mesh,
+                )
+                lg = hybrid.logits(params, cfg, hidden)[:, 0]
+                tok = sample(lg, sub, temp, top_p, top_k)
+                return (cache, tok, key, aux + c), tok
+
+            (cache, _, _, aux), toks = jax.lax.scan(
+                body,
+                (cache, tokens, key, jnp.zeros((hybrid.N_COUNTERS,), jnp.int32)),
+                jnp.arange(n_steps, dtype=jnp.int32),
+            )
+            return cache, toks, aux
+
+        return decode_chunk

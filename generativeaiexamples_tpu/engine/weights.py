@@ -73,6 +73,9 @@ def preblock_llama_params(params, *, block_n: Optional[int] = None):
 def resolve_model_preset(model_name: str) -> str:
     """Map a model name (HF id or NIM-style) to an engine preset."""
     name = model_name.lower()
+    if name.startswith("ling") or "/ling" in name:
+        # models/hybrid.py's presets (layer kinds), not llama's.
+        return "ling-tiny" if "tiny" in name else "ling-3.0-flash-vl-l7e128"
     if "mixtral" in name or "8x7b" in name:
         return "mixtral-8x7b"
     if "gemma" in name:

@@ -1,0 +1,171 @@
+"""A share of a wide expert layer: DeepSeek-V3's ``noaux_tc`` routing over
+all the published experts, and the products of the experts held here.
+
+The layer is told which experts it holds (``offset``, ``held``).  It
+routes every token over all ``E`` router outputs, computes what its own
+experts give for the choices that land on them, and leaves out what the
+absent experts would add: on one chip the layer runs without its
+exchange, and the partial result is what goes on (the four shares add up
+to the whole: ``tests/test_hybrid_model.py``).
+
+Dispatch is sorted, not one-hot: the local choices are ordered by
+expert, their token rows gathered, and the three products run as grouped
+matrix multiplications over the experts that received a row —
+``jax.experimental.pallas.ops.tpu.megablox.gmm`` on the TPU, which
+skips experts with no row, so a decode step streams the experts it
+touches and not all that are held.  Off the TPU (tier-1 tests, the
+rehearsal) a dense product over the experts held stands in.
+"""
+
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+
+from generativeaiexamples_tpu.ops.dispatch import (
+    one_device,
+    platform_of,
+    record,
+)
+
+F32 = jnp.float32
+# Rows of the sorted choices are padded to a multiple of this (gmm's row
+# tile); padding rows belong to no group and are never computed.
+ROW_TILE = 128
+# What the counters vector holds, in order (``models.hybrid`` sums it
+# over layers and steps; ``Stats`` exports each under ``moe_<name>``).
+COUNTERS = ("choices_routed", "choices_local", "experts_touched", "expert_rows_max")
+
+
+def select(sel, *, k, n_group, topk_group):
+    """Group-limited top-k over selection scores ``sel`` (n, E): a group's
+    score is the sum of its two largest, the best ``topk_group`` groups
+    are kept and the ``k`` largest inside them taken.  Returns (n, k)
+    int32.  Ties go to the lower index (``lax.top_k``)."""
+    n, E = sel.shape
+    grouped = sel.reshape(n, n_group, E // n_group)
+    group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)  # (n, n_group)
+    _, keep = jax.lax.top_k(group_score, topk_group)
+    kept = jnp.zeros((n, n_group), bool).at[jnp.arange(n)[:, None], keep].set(True)
+    masked = jnp.where(kept[:, :, None], grouped, -jnp.inf).reshape(n, E)
+    return jax.lax.top_k(masked, k)[1].astype(jnp.int32)
+
+
+def scores(x, w_router):
+    """Sigmoid routing scores in float32: (n, D) x (D, E) -> (n, E)."""
+    return jax.nn.sigmoid(
+        jnp.dot(x.astype(F32), w_router.astype(F32),
+                precision=jax.lax.Precision.HIGHEST)
+    )
+
+
+@jax.named_scope("layer/moe/router")
+def route(x, w_router, bias, *, k, n_group, topk_group, norm_topk, scale):
+    """Sigmoid scores, group-limited top-k on ``score + bias``, weights
+    from the scores alone.
+
+    x: (n, D); w_router: (D, E); bias: (E,) float32.  Returns (idx (n, k)
+    int32 over all E, weights (n, k) float32)."""
+    s = scores(x, w_router)
+    idx = select(s + bias.astype(F32), k=k, n_group=n_group, topk_group=topk_group)
+    w = jnp.take_along_axis(s, idx, axis=-1)
+    if norm_topk:
+        w = w / (w.sum(-1, keepdims=True) + 1e-20)
+    return idx, w * scale
+
+
+def balanced_bias(x, w_router, *, k, n_group, topk_group, iters: int = 75):
+    """The selection bias that evens the experts' load on the tokens
+    ``x`` (n, D): DeepSeek-V3's aux-loss-free balancing run to its fixed
+    point on a sample — each round the bias of an expert over the mean
+    load goes down a step and of one under it up, the step decaying from
+    0.03 to 6e-5.  This is what ``moe_router_enable_expert_bias`` leaves
+    in a trained checkpoint; random weights with an arbitrary bias load
+    the experts, and so each chip's share, unevenly by a few percent that
+    change with the seed."""
+    s = scores(x, w_router)
+    E = s.shape[1]
+
+    def step(i, bias):
+        idx = select(s + bias, k=k, n_group=n_group, topk_group=topk_group)
+        load = jnp.zeros((E,), F32).at[idx.reshape(-1)].add(1.0)
+        return bias - 0.03 * 0.92**i * jnp.sign(load - load.mean())
+
+    return jax.lax.fori_loop(0, iters, step, jnp.zeros((E,), F32))
+
+
+def use_gmm(mesh) -> bool:
+    if os.environ.get("GAIE_MOE_KERNEL_INTERPRET") == "1":
+        return True
+    return platform_of(mesh) == "tpu" and one_device(mesh)
+
+
+def _grouped(xs, w, group_sizes, pallas: bool, tiling):
+    """(m, a) x (held, a, b) by row groups -> (m, b)."""
+    if pallas:
+        from jax.experimental.pallas.ops.tpu.megablox import gmm
+
+        return gmm(
+            xs, w, group_sizes, preferred_element_type=xs.dtype, tiling=tiling,
+            interpret=platform_of(None) != "tpu",
+        )
+    # Dense stand-in: every row through every expert held, the row's own
+    # group picked out.  Tiny sizes only.
+    held = w.shape[0]
+    ends = jnp.cumsum(group_sizes)
+    group = jnp.searchsorted(ends, jnp.arange(xs.shape[0]), side="right")
+    every = jnp.einsum("ma,eab->emb", xs, w, preferred_element_type=F32)
+    pick = jax.nn.one_hot(group, held, dtype=F32)  # rows past the groups: zero
+    return jnp.einsum("emb,me->mb", every, pick).astype(xs.dtype)
+
+
+def _tiling(a: int, b: int) -> tuple[int, int, int]:
+    """gmm tiles for an (m, a) x (a, b) product: whole rows of 128, the
+    widest column tiles that divide the sizes and fit VMEM."""
+    def pick(x, options):
+        return next((t for t in options if x % t == 0), 128)
+
+    return ROW_TILE, pick(a, (512, 256, 128)), pick(b, (768, 512, 256, 128))
+
+
+def expert_mlp(x, idx, weights, valid, lp, *, offset: int, held: int, mesh=None):
+    """What the experts held give: ``sum_i w_i E_i(x)`` over the choices
+    ``i`` with ``offset <= idx_i < offset + held``.
+
+    x: (n, D); idx, weights: (n, k); valid: (n,) bool (a padded position
+    routes nowhere); lp: ``w_gu_e`` (held, D, 2F) gate and up side by
+    side, ``w_down_e`` (held, F, D).  Returns (y (n, D), counters (4,)
+    int32 in the order of ``COUNTERS``)."""
+    n, d = x.shape
+    k = idx.shape[1]
+    f = lp["w_down_e"].shape[1]
+    pallas = record(f"moe_experts n={n} held={held}", use_gmm(mesh))
+    with jax.named_scope("layer/moe/dispatch"):
+        local = (idx >= offset) & (idx < offset + held) & valid[:, None]
+        group = jnp.where(local, idx - offset, held).reshape(-1)  # (n k,)
+        m = -(-n * k // ROW_TILE) * ROW_TILE
+        group = jnp.pad(group, (0, m - n * k), constant_values=held)
+        order = jnp.argsort(group, stable=True)  # local choices first, by expert
+        starts = jnp.searchsorted(
+            group[order], jnp.arange(held + 1, dtype=group.dtype), side="left"
+        ).astype(jnp.int32)
+        sizes = starts[1:] - starts[:-1]  # rows of each expert held
+        n_local = starts[held]
+        xs = x[jnp.minimum(order // k, n - 1)]
+    with jax.named_scope("layer/moe/experts"):
+        h = _grouped(xs, lp["w_gu_e"], sizes, pallas, _tiling(d, 2 * f))
+        act = (jax.nn.silu(h[:, :f].astype(F32)) * h[:, f:].astype(F32)).astype(x.dtype)
+        ys = _grouped(act, lp["w_down_e"], sizes, pallas, _tiling(f, d))
+    with jax.named_scope("layer/moe/combine"):
+        # Back to (token, choice) order by a gather.  Rows past the local
+        # choices were never computed: zero, not whatever the buffer held.
+        back = jnp.argsort(order)[: n * k]
+        per_choice = ys[back].reshape(n, k, d).astype(F32)
+        w_local = jnp.where(local, weights, 0.0)
+        y = jnp.where(local[..., None], per_choice * w_local[..., None], 0.0).sum(1)
+    counters = jnp.stack(
+        [valid.sum().astype(jnp.int32) * k, n_local, (sizes > 0).sum(), sizes.max()]
+    ).astype(jnp.int32)
+    return y.astype(x.dtype), counters

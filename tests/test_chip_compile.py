@@ -185,3 +185,37 @@ def test_paged_kernel_compiles(one_chip, page_tokens, chunk):
         )
 
     _compile(attn, *args)
+
+
+@pytest.mark.parametrize("tokens", [32, 256])
+def test_grouped_expert_products_compile_at_ling_widths(one_chip, tokens, monkeypatch):
+    """``ops/moe.py``'s sorted dispatch at ling-3.0-flash-vl-l7e128's
+    widths: 128 experts held of 512, hidden 2,560, expert width 768, 8 a
+    token; a decode step's 32 rows and a prefill chunk's 256.  The
+    grouped products are megablox's ``gmm``; this holds the tilings
+    ``ops.moe._tiling`` picks to what Mosaic accepts."""
+    from generativeaiexamples_tpu.ops import moe
+
+    # The gate asks the default backend, which is the CPU here.
+    monkeypatch.setattr(moe, "platform_of", lambda mesh: "tpu")
+    spec = _spec(one_chip)
+    D, F, E, held, k = 2560, 768, 512, 128, 8
+
+    def layer(x, w_router, bias, w_gu_e, w_down_e, valid):
+        idx, w = moe.route(
+            x, w_router, bias, k=k, n_group=8, topk_group=4, norm_topk=True, scale=2.5
+        )
+        return moe.expert_mlp(
+            x, idx, w, valid, {"w_gu_e": w_gu_e, "w_down_e": w_down_e},
+            offset=0, held=held,
+        )
+
+    _compile(
+        layer,
+        spec((tokens, D), jnp.bfloat16),
+        spec((D, E), jnp.bfloat16),
+        spec((E,), jnp.float32),
+        spec((held, D, 2 * F), jnp.bfloat16),
+        spec((held, F, D), jnp.bfloat16),
+        spec((tokens,), jnp.bool_),
+    )

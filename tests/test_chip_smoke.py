@@ -195,3 +195,34 @@ def test_compile_cache_defaults_to_the_checkout(
     want = os.path.join(REPO, ".jax_cache")
     assert jax_runtime.enable_compile_cache() == want
     assert jax.config.jax_compilation_cache_dir == want
+
+
+def test_hybrid_phase_rehearsal_and_its_control(capsys):
+    """``--hybrid`` at the tiny size, in process: the served logits stay
+    inside the limits (float32 here, far inside), and the control — the
+    reference with W8A8 MLP products — leaves them."""
+    chip_smoke.child_hybrid(0, chip_smoke.TINY)
+    sound = _phases(capsys)["hybrid"]
+    assert sound["within_limits"] and sound["control"] is None
+    assert sound["positions"] == {"prefill": 41, "decode": 8}
+    assert sound["prefill_p10_share"] < 1e-4 and sound["decode_p10_share"] < 1e-4
+    assert sound["prefill_p90_share"] < 1e-3 and sound["decode_p90_share"] < 1e-3
+    assert sorted(sound["limits"]) == sorted(
+        f"{part}_{q}_share" for part in ("prefill", "decode") for q in ("p10", "p50", "p90"))
+    # The limits are set for the chip's size and precision (bf16
+    # activations read 0.012); at this size an expert is a small part of a
+    # layer, so the control is only held to read far above the sound run,
+    # and the phase to say so if it stayed inside the limits.
+    from generativeaiexamples_tpu.models import hybrid_reference as chip_smoke_reference
+
+    plain = chip_smoke_reference._swiglu  # the control replaces it for its process
+    try:
+        chip_smoke.child_hybrid(0, chip_smoke.TINY, control="w8a8_mlp")
+    except chip_smoke.SmokeFailure as e:
+        assert "stayed inside" in str(e)
+    finally:
+        chip_smoke_reference._swiglu = plain
+        jax.clear_caches()
+    control = _phases(capsys)["hybrid"]
+    assert control["control"] == "w8a8_mlp"
+    assert control["decode_p10_share"] > 20 * sound["decode_p10_share"]

@@ -1,0 +1,193 @@
+"""The serving-path forms of the new mechanisms against their plain
+forms: chunk-wise KDA against the token-by-token recurrence, absorbed MLA
+against expanded, group-limited routing against a brute-force choice,
+sorted expert dispatch against every expert computed whole."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from generativeaiexamples_tpu.models import hybrid_reference as ref
+from generativeaiexamples_tpu.ops import kda, mla, moe
+
+F32 = jnp.float32
+
+
+def _kda_inputs(seed, b, s, H, K, gate):
+    r = np.random.RandomState(seed)
+    q = kda.l2_normalize(jnp.asarray(r.randn(b, s, H, K), F32)) * K**-0.5
+    k = kda.l2_normalize(jnp.asarray(r.randn(b, s, H, K), F32))
+    v = jnp.asarray(r.randn(b, s, H, K), F32)
+    # The safe gate's range is (-5, 0): "near -5" forgets the state in a
+    # token, "near 0" keeps it over the whole run.
+    lo, hi = {"near_floor": (-4.999, -4.5), "near_zero": (-1e-3, -1e-6),
+              "mixed": (-4.999, -1e-6)}[gate]
+    g = jnp.asarray(r.uniform(lo, hi, size=(b, s, H, K)), F32)
+    beta = jnp.asarray(r.uniform(0.05, 0.95, size=(b, s, H)), F32)
+    S0 = jnp.asarray(r.randn(b, H, K, K) * 0.1, F32)
+    return q, k, v, g, beta, S0
+
+
+def _token_by_token(q, k, v, g, beta, S0):
+    def step(S, x):
+        o, S = kda.kda_step(*x, S)
+        return S, o
+
+    xs = tuple(jnp.moveaxis(a, 1, 0) for a in (q, k, v, g, beta))
+    S, o = jax.lax.scan(step, S0, xs)
+    return jnp.moveaxis(o, 0, 1), S
+
+
+@pytest.mark.parametrize("gate", ["near_floor", "near_zero", "mixed"])
+def test_kda_chunkwise_matches_the_token_recurrence(gate):
+    q, k, v, g, beta, S0 = _kda_inputs(3, 2, 80, 3, 16, gate)
+    want_o, want_S = _token_by_token(q, k, v, g, beta, S0)
+    got_o, got_S = kda.kda_chunked(q, k, v, g, beta, S0)
+    # float32 at the highest precision on both sides; the chunk form
+    # multiplies by exp(+-G) with |G| <= 80 and solves a 16 x 16 unit
+    # triangular system, which costs a few ulps of the largest term.
+    np.testing.assert_allclose(got_o, want_o, rtol=2e-4, atol=2e-5)
+    np.testing.assert_allclose(got_S, want_S, rtol=2e-4, atol=2e-5)
+
+
+def test_kda_a_token_with_beta_and_gate_zero_leaves_state_bit_equal():
+    q, k, v, g, beta, S0 = _kda_inputs(4, 2, 32, 2, 16, "mixed")
+    off = jnp.zeros_like(beta)
+    _, S = kda.kda_chunked(q, k, v, g * 0.0, off, S0)
+    assert np.array_equal(np.asarray(S), np.asarray(S0))
+    _, S = kda.kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0] * 0.0, off[:, 0], S0)
+    assert np.array_equal(np.asarray(S), np.asarray(S0))
+
+
+def test_kda_step_is_the_references_token():
+    """The decode step against the plain reference's own recurrence."""
+    q, k, v, g, beta, _ = _kda_inputs(5, 1, 24, 2, 16, "mixed")
+
+    def token(S, x):
+        q_t, k_t, v_t, g_t, b_t = x
+        S = S * jnp.exp(g_t)[:, :, None]
+        S = S + b_t[:, None, None] * k_t[:, :, None] * (
+            v_t - jnp.einsum("hkv,hk->hv", S, k_t))[:, None, :]
+        return S, jnp.einsum("hkv,hk->hv", S, q_t)
+
+    _, want = jax.lax.scan(token, jnp.zeros((2, 16, 16)), (q[0], k[0], v[0], g[0], beta[0]))
+    got, _ = _token_by_token(q, k, v, g, beta, jnp.zeros((1, 2, 16, 16)))
+    np.testing.assert_allclose(got[0], want, rtol=1e-5, atol=1e-6)
+
+
+def test_mla_absorbed_matches_expanded():
+    r = np.random.RandomState(0)
+    b, s, T, H, rank, nope, rope, vd = 2, 5, 40, 4, 32, 16, 8, 16
+    q_nope = jnp.asarray(r.randn(b, s, H, nope), F32)
+    q_rope = jnp.asarray(r.randn(b, s, H, rope), F32)
+    latent = jnp.asarray(r.randn(b, T, rank + rope), F32)
+    w_kvb = jnp.asarray(r.randn(rank, H * (nope + vd)) * rank**-0.5, F32)
+    q_pos = jnp.asarray([[30, 31, 32, 33, 34], [3, 4, 5, 6, 7]], jnp.int32)
+    kw = dict(rank=rank, nope=nope, v_dim=vd)
+    a = mla.attend_expanded(q_nope, q_rope, latent, w_kvb, q_pos, **kw)
+    c = mla.attend_absorbed(q_nope, q_rope, latent, w_kvb, q_pos, **kw)
+    # The same sums in another order, float32.
+    np.testing.assert_allclose(a, c, rtol=1e-4, atol=1e-5)
+    # Causal: rows past a query's position change nothing.
+    latent2 = latent.at[:, 36:].set(99.0)
+    a2 = mla.attend_expanded(q_nope, q_rope, latent2, w_kvb, q_pos, **kw)
+    np.testing.assert_allclose(a, a2, rtol=1e-6)
+
+
+def test_rope_interleaved_rotates_pairs_by_position():
+    x = jnp.asarray(np.random.RandomState(1).randn(1, 3, 2, 8), F32)
+    pos = jnp.asarray([[0, 5, 9]], jnp.int32)
+    y = mla.rope_interleaved(x, pos, 6e6)
+    np.testing.assert_allclose(y[:, 0], x[:, 0], atol=1e-6)  # position 0: identity
+    np.testing.assert_allclose(
+        jnp.linalg.norm(y, axis=-1), jnp.linalg.norm(x, axis=-1), rtol=1e-5)
+    want = ref._rope_pairs(jnp.broadcast_to(x[0, 1:2], (6, 2, 8)), 6e6)[5]
+    np.testing.assert_allclose(y[0, 1], want, rtol=1e-5, atol=1e-6)
+
+
+DIMS = {"E": 32, "G": 8, "topk_group": 4, "k": 4, "norm_topk": True, "scale": 2.5}
+
+
+def _route(x, w, bias):
+    return moe.route(x, w, bias, k=4, n_group=8, topk_group=4, norm_topk=True, scale=2.5)
+
+
+@pytest.mark.parametrize("ties", [False, True])
+def test_group_limited_choice_matches_brute_force(ties):
+    r = np.random.RandomState(7)
+    n, D, E = 64, 16, 32
+    x = jnp.asarray(r.randn(n, D), F32)
+    w = jnp.asarray(r.randn(D, E), F32)
+    bias = jnp.asarray(r.randn(E) * 0.3, F32)
+    if ties:
+        # Equal scores everywhere: every group and every expert ties, so
+        # the rule (the lower index wins) decides the whole choice.
+        w, bias = jnp.zeros_like(w), jnp.zeros_like(bias)
+    idx, weights = _route(x, w, bias)
+    dense = ref.routing(x, {"router": w, "router_bias": bias}, DIMS)  # (n, E)
+    got = np.zeros((n, E), np.float32)
+    np.put_along_axis(got, np.asarray(idx), np.asarray(weights), axis=1)
+    np.testing.assert_allclose(got, dense, rtol=1e-5, atol=1e-7)
+    if ties:
+        assert np.asarray(idx)[0].tolist() == [0, 1, 2, 3]
+    # Group limit: a token's experts lie in at most topk_group groups.
+    assert max(len(set(row // 4)) for row in np.asarray(idx)) <= 4
+    np.testing.assert_allclose(weights.sum(-1), 2.5, rtol=1e-5)
+
+
+def _experts(seed, held, D, F):
+    r = np.random.RandomState(seed)
+    return {
+        "w_gu_e": jnp.asarray(r.randn(held, D, 2 * F) * D**-0.5, F32),
+        "w_down_e": jnp.asarray(r.randn(held, F, D) * F**-0.5, F32),
+    }
+
+
+@pytest.mark.parametrize("kernel", ["dense_stand_in", "gmm_interpret"])
+def test_sorted_dispatch_matches_every_expert_computed_whole(kernel, monkeypatch):
+    if kernel == "gmm_interpret":
+        monkeypatch.setenv("GAIE_MOE_KERNEL_INTERPRET", "1")
+    r = np.random.RandomState(11)
+    n, D, F, held, offset = 48, 128, 128, 8, 8
+    x = jnp.asarray(r.randn(n, D), F32)
+    lp = _experts(2, held, D, F)
+    idx = jnp.asarray(np.stack([r.choice(32, 4, replace=False) for _ in range(n)]), jnp.int32)
+    w = jnp.asarray(r.uniform(0.1, 1.0, size=(n, 4)), F32)
+    valid = jnp.asarray(np.arange(n) < 40)
+    y, counters = moe.expert_mlp(x, idx, w, valid, lp, offset=offset, held=held)
+    want = np.zeros((n, D), np.float32)
+    rows = np.zeros(held, int)
+    for t in range(40):
+        for e, wt in zip(np.asarray(idx)[t], np.asarray(w)[t]):
+            if offset <= e < offset + held:
+                gu = x[t] @ lp["w_gu_e"][e - offset]
+                want[t] += wt * np.asarray((jax.nn.silu(gu[:F]) * gu[F:]) @ lp["w_down_e"][e - offset])
+                rows[e - offset] += 1
+    np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-5)
+    assert not np.asarray(y)[40:].any()  # a padded position routes nowhere
+    assert counters.tolist() == [40 * 4, rows.sum(), (rows > 0).sum(), rows.max()]
+
+
+def test_balanced_bias_evens_the_experts_load():
+    """Aux-loss-free balancing to its fixed point on a sample: with the
+    bias, fresh tokens of the same distribution load the experts far more
+    evenly than without, and the choice stays group-limited."""
+    r = np.random.RandomState(3)
+    D, E = 32, 32
+    w = jnp.asarray(r.randn(D, E) * D**-0.5, F32)
+    shift = jnp.asarray(r.randn(D) * 0.5, F32)  # a common direction: some experts run hot
+
+    def tokens(seed, n):
+        return jnp.asarray(np.random.RandomState(seed).randn(n, D), F32) + shift
+
+    bias = moe.balanced_bias(tokens(0, 4096), w, k=4, n_group=8, topk_group=4)
+
+    def worst_load(b):
+        idx, _ = moe.route(tokens(1, 4096), w, b, k=4, n_group=8, topk_group=4,
+                           norm_topk=True, scale=2.5)
+        load = np.bincount(np.asarray(idx).ravel(), minlength=E)
+        return load.max() / load.mean()
+
+    assert worst_load(jnp.zeros((E,), F32)) > 1.5
+    assert worst_load(bias) < 1.25  # 512 rows an expert: the fullest of 32 reads ~1.1 by chance

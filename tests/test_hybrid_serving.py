@@ -1,0 +1,193 @@
+"""The layer-kind model on the normal serving path at the tiny size:
+``Scheduler`` + ``create_engine_app`` built as ``engine.server`` builds
+them, a streamed ``/v1/completions`` request, a cold batch, a chunked
+prompt and a prefix hit restored from a state snapshot, each held to the
+plain reference's logits.
+
+The path returns tokens, so a greedy token is held to the reference's
+logits: the reference logit of the served token must lie within ``GAP`` of
+the reference maximum.  Both sides are float32 at the highest precision
+(conftest.py) and agree to 2e-4 in every logit (test_hybrid_model.py), so
+a served token that is not the reference's argmax is a near-tie; 1e-3 is
+five times that agreement, and a wrong state or a missed restore moves
+logits by 1e-2 and more.
+"""
+
+import asyncio
+import json
+import threading
+
+import numpy as np
+import pytest
+from aiohttp.test_utils import TestClient, TestServer
+
+from generativeaiexamples_tpu.engine.prefix_cache import StateSnapshots
+from generativeaiexamples_tpu.engine.sampler import SamplingParams
+from generativeaiexamples_tpu.engine.scheduler import Request, Scheduler
+from generativeaiexamples_tpu.engine.weights import resolve_model_preset
+from generativeaiexamples_tpu.models import hybrid
+from generativeaiexamples_tpu.models import hybrid_reference as ref
+
+GAP = 1e-3
+CHUNK = 32
+
+
+@pytest.fixture(scope="module")
+def scheduler():
+    # What engine.server.main() builds for --model ling-tiny.
+    cfg = hybrid.PRESETS[resolve_model_preset("ling-tiny")]()
+    s = Scheduler(
+        cfg, None, max_batch=4, max_len=256, decode_chunk_size=4, seed=3,
+        prefill_chunk_tokens=CHUNK, prefix_cache="shared",
+    )
+    s.start()
+    yield s
+    s.stop()
+
+
+def _generate(scheduler, prompts, n=6):
+    """Submit while the tick loop is stopped, so that the prompts are
+    admitted together; returns each one's tokens."""
+    scheduler.stop()
+    outs = [[] for _ in prompts]
+    done = [threading.Event() for _ in prompts]
+    for i, p in enumerate(prompts):
+        assert scheduler.submit(Request(
+            token_ids=list(p),
+            sampling=SamplingParams(temperature=0.0, top_p=1.0, max_tokens=n),
+            on_token=outs[i].append, on_done=lambda _r, i=i: done[i].set(),
+            eos_id=None, id=f"t{i}-{len(p)}",
+        ))
+    scheduler.start()
+    assert all(ev.wait(300) for ev in done)
+    return outs
+
+
+def _worst_gap(scheduler, prompt, out):
+    """Largest (reference max - reference logit of the served token) over
+    the positions: prefill's first token, then decoding through the state."""
+    seq, worst = list(prompt), 0.0
+    for tok in out:
+        # Padded to one length: one compiled reference for every position.
+        lg = np.asarray(ref.last_logits(scheduler.params, scheduler.cfg, seq, pad_to=128))
+        worst = max(worst, float(lg.max() - lg[tok]))
+        seq.append(tok)
+    return worst
+
+
+def _prompt(seed, n):
+    return np.random.RandomState(seed).randint(0, 512, size=n).tolist()
+
+
+def test_a_cold_batch_of_unequal_prompts(scheduler):
+    prompts = [_prompt(1, 20), _prompt(2, 31), _prompt(3, 9)]  # all <= one chunk
+    before = scheduler.stats.snapshot()
+    outs = _generate(scheduler, prompts)
+    after = scheduler.stats.snapshot()
+    assert after["prefill_rows"] - before["prefill_rows"] == 3
+    assert after["prefill_chunks"] == before["prefill_chunks"]  # one batched program
+    for p, o in zip(prompts, outs):
+        assert len(o) == 6 and _worst_gap(scheduler, p, o) <= GAP
+
+
+def test_a_chunked_prompt_and_a_hit_restored_from_its_snapshot(scheduler):
+    first = _prompt(4, 100)  # chunks of 32: snapshots at 32, 64, 96
+    before = scheduler.stats.snapshot()
+    (out,) = _generate(scheduler, [first])
+    mid = scheduler.stats.snapshot()
+    assert mid["prefill_chunks"] - before["prefill_chunks"] == 4
+    assert mid["state_snapshots_saved"] - before["state_snapshots_saved"] == 3
+    assert mid["state_snapshot_bytes"] == len(scheduler._snapshots) * scheduler.cfg.snapshot_bytes()
+    assert _worst_gap(scheduler, first, out) <= GAP
+    # Shares 70 tokens: rows could be grafted to 70, the state exists at 64.
+    again = first[:70] + _prompt(5, 25)
+    (hit,) = _generate(scheduler, [again])
+    after = scheduler.stats.snapshot()
+    assert after["shared_prefix_hits"] - mid["shared_prefix_hits"] == 1
+    assert after["state_snapshots_restored"] - mid["state_snapshots_restored"] == 1
+    assert after["prefix_tokens_matched"] - mid["prefix_tokens_matched"] == 70
+    assert after["prefix_tokens_reused"] - mid["prefix_tokens_reused"] == 64
+    # Against a cold prefill of the same prompt: the reference's forward.
+    assert _worst_gap(scheduler, again, hit) <= GAP
+    # The step programs' counters came out with the tokens.
+    routed = after["moe_choices_routed"] - mid["moe_choices_routed"]
+    assert routed % (scheduler.cfg.n_experts_per_tok * 6) == 0 and routed > 0
+    assert 0 < after["moe_choices_local"] < after["moe_choices_routed"]
+    assert after["moe_experts_touched"] > 0 and after["moe_expert_rows_max"] > 0
+
+
+def test_a_slots_next_occupant_starts_from_nothing(scheduler):
+    """Chunked cold prefill into a slot whose last occupant left state."""
+    for seed in (6, 7, 8, 9, 10):  # more prompts than slots: every slot is reused
+        p = _prompt(seed, 70)
+        (o,) = _generate(scheduler, [p], n=3)
+        assert _worst_gap(scheduler, p, o) <= GAP
+
+
+def test_streamed_completion_through_the_http_front(scheduler):
+    from generativeaiexamples_tpu.engine.server import create_engine_app
+    from generativeaiexamples_tpu.engine.tokenizer import ByteTokenizer
+
+    app = create_engine_app(scheduler, ByteTokenizer(), model_name="ling-tiny")
+    loop = asyncio.new_event_loop()
+    client = TestClient(TestServer(app), loop=loop)
+    loop.run_until_complete(client.start_server())
+    prompt = [int(t) for t in np.random.RandomState(11).randint(3, 250, size=40)]
+
+    async def go():
+        resp = await client.post("/v1/completions", json={
+            "prompt": prompt, "max_tokens": 5, "temperature": 0.0, "stream": True})
+        assert resp.status == 200
+        body = (await resp.read()).decode()
+        metrics = await (await client.get("/metrics")).text()
+        return body, metrics
+
+    try:
+        body, metrics = loop.run_until_complete(go())
+    finally:
+        loop.run_until_complete(client.close())
+        loop.close()
+    events = [json.loads(l[6:]) for l in body.splitlines()
+              if l.startswith("data: ") and l != "data: [DONE]"]
+    assert events and events[-1]["choices"][0]["finish_reason"] == "length"
+    for name in ("engine_moe_choices_routed_total", "engine_moe_experts_touched_total",
+                 "engine_prefix_tokens_matched_total", "engine_state_snapshots_saved_total",
+                 "engine_state_snapshot_bytes"):
+        assert f"\n{name} " in metrics, name
+
+
+def test_state_snapshots_keep_a_byte_budget_and_cut_to_the_deepest_boundary():
+    snaps = StateSnapshots(every=4, bytes_each=10, budget_bytes=35)  # room for 3
+    toks = list(range(100, 120))
+    assert snaps.deepest(toks, 19) == 0
+    for depth in (4, 8, 12):
+        assert snaps.put(snaps.key(toks, depth), f"s{depth}") == 0
+    assert snaps.bytes == 30 and len(snaps) == 3
+    assert snaps.deepest(toks, 11) == 8 and snaps.deepest(toks, 12) == 12
+    assert snaps.deepest(toks[:6] + [0] * 10, 16) == 4  # diverges after 6 tokens
+    assert snaps.get(snaps.key(toks, 4)) == "s4"  # now the freshest
+    assert snaps.put(snaps.key(toks, 16), "s16") == 1  # pushes out the oldest: 8
+    assert snaps.deepest(toks, 11) == 4 and snaps.deepest(toks, 19) == 16
+    none = StateSnapshots(every=4, bytes_each=10, budget_bytes=0)
+    assert none.put(none.key(toks, 4), "x") == 0 and len(none) == 0
+
+
+def test_a_llama_scheduler_reports_the_new_counters_as_zero_or_equal():
+    """matched == reused where the state can be cut at any token, no
+    snapshot is ever taken, and no model counter appears."""
+    from generativeaiexamples_tpu.models import llama
+
+    s = Scheduler(llama.PRESETS["llama-tiny"](), None, max_batch=2, max_len=128,
+                  decode_chunk_size=4, prefill_chunk_tokens=32, prefix_cache="shared")
+    assert s._snapshots is None and s.model.cut_anywhere
+    s.start()
+    try:
+        base = _prompt(12, 60)
+        _generate(s, [[t % 250 for t in base]], n=2)
+        _generate(s, [[t % 250 for t in base[:50]] + [7] * 20], n=2)
+    finally:
+        s.stop()
+    snap = s.stats.snapshot()
+    assert snap["prefix_tokens_matched"] == snap["prefix_tokens_reused"] == 50
+    assert snap["state_snapshots_saved"] == snap["state_snapshot_bytes"] == 0
+    assert not any(k.startswith("moe_") for k in snap)
