@@ -1137,6 +1137,7 @@ class EnginePool:
         "state_snapshot_bytes",
         "shared_prefix_hits",
         "prefill_chunks",
+        "prefill_chunks_ahead",
         "spec_rounds",
         "spec_tokens",
         "spec_proposed",

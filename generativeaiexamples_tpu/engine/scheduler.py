@@ -117,6 +117,15 @@ class _Slot:
     # warming; while set, the slot owns a request but is excluded from
     # decode (its lanes pin to the tail garbage zone like parked slots).
     warm_pos: Optional[int] = None
+    # Chunked prefill, a tick ahead: tokens of the chunk that the last
+    # tick dispatched behind its decode chunk on this tick's account
+    # (0 = none; this tick's phase 1 then books it and dispatches
+    # nothing), and, where that chunk was the prompt's last, the
+    # finalizer that fetches its first token.  While ``first_token`` is
+    # set the token is a device future and the slot is in no decode
+    # snapshot (``_active``), although ``warm_pos`` is already None.
+    ahead_tokens: int = 0
+    first_token: Optional[Callable[[], None]] = None
     # Speculative decoding: EWMA of this request's observed per-round
     # acceptance rate (accepted drafts / gamma).  Drives the adaptive
     # lookahead — a request whose drafts keep getting rejected decays
@@ -171,7 +180,13 @@ class _TickClock:
     chunk behind it) opens no interval, a gap between two programs of one
     tick is not seen at all, and a fetch that returns a first token while
     the graft dispatched behind its prefill still runs opens the interval
-    that much early.
+    that much early.  Since the next tick's warming chunks are dispatched
+    behind the decode chunk it is blind wherever that happens: the chunks
+    are the last programs enqueued and are never fetched, so the fetch of
+    the decode chunk's tokens opens nothing, and a device that runs out of
+    those chunks before the next tick's first dispatch idles unseen.  What
+    it still times is a tick with no continuing warming slot (the host's
+    whole gap, as before); the device's idle share is the trace's to say.
     """
 
     def __init__(self, stats: "Stats") -> None:
@@ -282,6 +297,9 @@ class Stats:
         # BOTH hit kinds — it measures prefill FLOPs avoided either way.
         self.shared_prefix_hits = 0
         self.prefill_chunks = 0
+        # Of those, chunks dispatched behind a decode chunk and before
+        # the host blocked on its tokens (``_advance_warm(ahead=True)``).
+        self.prefill_chunks_ahead = 0
         # Speculative decoding: rounds = live speculating (slot, round)
         # pairs run, tokens = tokens emitted by those rounds.  Acceptance
         # rate is derivable as (tokens/rounds - 1) / gamma.  Greedy slots
@@ -437,6 +455,7 @@ class Stats:
                 **self.model_counters,
                 "shared_prefix_hits": self.shared_prefix_hits,
                 "prefill_chunks": self.prefill_chunks,
+                "prefill_chunks_ahead": self.prefill_chunks_ahead,
                 "spec_rounds": self.spec_rounds,
                 "spec_tokens": self.spec_tokens,
                 "spec_proposed": self.spec_proposed,
@@ -780,6 +799,9 @@ class Scheduler:
         # fetched: drained once ready, after a token fetch, so that they
         # cost no synchronisation of their own.
         self._aux_pending: list = []
+        # Token futures of the chunks the last tick sent ahead, in the
+        # device's order (``_tick`` waits for the first of several).
+        self._ahead_toks: list = []
         # Pipelined ticks dispatch the decode chunk in the same tick as
         # admissions, pinning not-yet-decoding lanes to max_len - 1 —
         # whose append-buffer flush garbage-writes [max_len - w, max_len)
@@ -1301,19 +1323,26 @@ class Scheduler:
             self._pool.release(pages)
 
     def _active(self) -> list[int]:
-        """Slots decoding this tick: live request, prefill complete."""
+        """Slots decoding this tick: live request, prefill complete and
+        its first token on the host (a final chunk dispatched a tick
+        ahead leaves it a device future until ``first_token`` has run)."""
         return [
             i
             for i, s in enumerate(self._slots)
-            if s.request is not None and s.warm_pos is None
+            if s.request is not None
+            and s.warm_pos is None
+            and s.first_token is None
         ]
 
     def _warming(self) -> list[int]:
-        """Slots mid chunked-prefill (live request, KV still building)."""
+        """Slots phase 1 has a chunk to book for: mid chunked-prefill
+        (live request, KV still building), or the prompt's last chunk
+        went out a tick ahead and is still to be booked."""
         return [
             i
             for i, s in enumerate(self._slots)
-            if s.request is not None and s.warm_pos is not None
+            if s.request is not None
+            and (s.warm_pos is not None or s.ahead_tokens)
         ]
 
     def _clip_prompt(self, req: Request) -> None:
@@ -1362,6 +1391,10 @@ class Scheduler:
         slot = self._slots[slot_idx]
         req = slot.request
         slot.request = None
+        # A chunk dispatched ahead for this request wrote only this
+        # slot's rows; nothing of it is booked or fetched any more.
+        slot.ahead_tokens = 0
+        slot.first_token = None
         if (
             req is not None
             and reason in ("stop", "length")
@@ -1992,7 +2025,7 @@ class Scheduler:
         self._claim_warm(req, slot_idx, 0)
 
     def _advance_warm(
-        self, slot_idx: int
+        self, slot_idx: int, ahead: bool = False
     ) -> tuple[Optional[Callable[[], None]], int]:
         """Dispatch one prefill chunk for a warming slot.
 
@@ -2001,15 +2034,37 @@ class Scheduler:
         returns a finalize callable that fetches the prompt's first
         token; the pipelined tick runs it after the decode dispatch so
         the chunk rides the device stream ahead of the decode like every
-        other admission.  Returns (finalize_or_None, chunk_tokens)."""
+        other admission.  Returns (finalize_or_None, chunk_tokens).
+
+        ``ahead``: the tick calls this behind its decode dispatch, before
+        it blocks on anything, for the chunk the NEXT tick's phase 1
+        would send — its tokens were known when the last chunk went out,
+        so the device runs it while the host emits and plans.  The slot
+        keeps the chunk's size and finalizer (``ahead_tokens``,
+        ``first_token``) and this call returns (None, 0); the next tick's
+        phase-1 call dispatches nothing and returns them, so the chunk is
+        charged to that tick's budget and its first token joins where it
+        would have.  Every counter is counted here, at the dispatch."""
         slot = self._slots[slot_idx]
         req = slot.request
-        if req is None or slot.warm_pos is None:
+        if req is None or (slot.warm_pos is None and not slot.ahead_tokens):
             return None, 0
         if req.id and self._is_cancelled(req.id):
             self._clock.enter("emit")
             self._finish(slot_idx, "cancelled")
             self._clock.enter("plan")
+            return None, 0
+        if slot.ahead_tokens:
+            # This tick's chunk is on the device already.
+            fin, n = slot.first_token, slot.ahead_tokens
+            slot.first_token, slot.ahead_tokens = None, 0
+            return fin, n
+        if ahead and self._pool is not None:
+            # Refused: a paged chunk takes its pages at dispatch, and the
+            # rows that finish in the fetch it would overtake have not
+            # returned theirs, nor has the next tick's low-water eviction
+            # run — it could find the free list empty where the next
+            # tick's chunk would not.
             return None, 0
         pos = slot.warm_pos
         plen = slot.length
@@ -2078,12 +2133,20 @@ class Scheduler:
         self._tick_chunks += 1
         with self.stats.lock:
             self.stats.prefill_chunks += 1
+            self.stats.prefill_chunks_ahead += ahead
+        fin = None
         if pos + n < plen:
             slot.warm_pos = pos + n
-            return None, n
-        # Final chunk: prefill complete — the slot joins decode next tick.
-        slot.warm_pos = None
-        return lambda: self._suffix_finalize(req, slot_idx, tok, ticket), n
+        else:
+            # Final chunk: prefill complete — the slot joins decode in
+            # the tick after the one its first token is fetched in.
+            slot.warm_pos = None
+            fin = lambda: self._suffix_finalize(req, slot_idx, tok, ticket)
+        if ahead:
+            slot.ahead_tokens, slot.first_token = n, fin
+            self._ahead_toks.append(tok)
+            return None, 0
+        return fin, n
 
     def _handle_token(self, slot_idx: int, tid: int) -> None:
         """Process one sampled token for a slot; may finish the slot."""
@@ -2189,6 +2252,7 @@ class Scheduler:
                     self.max_batch, self.max_len
                 )
             self._aux_pending.clear()
+            self._ahead_toks.clear()
             if self._snapshots is not None:
                 self._snapshots.clear()
                 with self.stats.lock:
@@ -2406,11 +2470,26 @@ class Scheduler:
         # BEFORE new admissions: they already own slots, and their
         # per-tick chunk is what bounds running lanes' latency to one
         # prefill chunk + one decode chunk during a long cold admission.
+        # A slot whose chunk the last tick sent ahead (below) is only
+        # booked here: its chunk leads this tick's programs on the device
+        # as it would have, dispatched before the host's gap, not after.
         for i in self._warming():
             fin, n = self._advance_warm(i)
             budget -= n
             settle(fin)
             progressed = True
+        # The admissions below are read where they used to be: phase 1's
+        # dispatches took 20-30 ms between the last tick's emit and this
+        # poll, long enough for a client that sends its next request when
+        # its reply ends to be in the queue; with nothing left to
+        # dispatch the poll would come at once and that request would
+        # wait a whole tick.  So where several chunks went ahead, wait
+        # for the first: the device has the others to run meanwhile.
+        sent, self._ahead_toks = self._ahead_toks, []
+        if len(sent) > 1:
+            self._clock.enter("wait_device")
+            sent[0].block_until_ready()
+            self._clock.enter("plan")
         # Phase 2 — admit pending requests into free slots (batched
         # prefill phase).  Keep draining in ADMIT_CAP-sized prefill
         # batches until slots, the queue, or this tick's token budget run
@@ -2594,6 +2673,13 @@ class Scheduler:
             self._tick_decoded = len(decode_active)
             decode_pending = self._dispatch_decode_phase(decode_active)
             progressed = True
+            # The next tick's phase 1, now: a continuing slot's next
+            # chunk depends on nothing the finalizers below fetch, so it
+            # goes out behind the decode chunk and the device has work
+            # while the host emits this tick's tokens, books the tick and
+            # plans the next.  Same programs in the same order.
+            for i in self._warming():
+                self._advance_warm(i, ahead=True)
         for fin in admits:
             fin()
         if decode_pending is not None:

@@ -718,6 +718,10 @@ async def handle_metrics(request: web.Request) -> web.Response:
         f"engine_shared_prefix_hits_total {snap['shared_prefix_hits']}",
         "# TYPE engine_prefill_chunks_total counter",
         f"engine_prefill_chunks_total {snap['prefill_chunks']}",
+        # Of those, dispatched behind a decode chunk before the host
+        # blocked on its tokens.
+        "# TYPE engine_prefill_chunks_ahead_total counter",
+        f"engine_prefill_chunks_ahead_total {snap.get('prefill_chunks_ahead', 0)}",
         "# TYPE engine_spec_rounds_total counter",
         f"engine_spec_rounds_total {snap['spec_rounds']}",
         "# TYPE engine_spec_tokens_total counter",

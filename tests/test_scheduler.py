@@ -826,10 +826,12 @@ class TestChunkedPrefill:
             prefix_cache="off", prefill_chunk_tokens=8,
         )
         events: list[str] = []
-        orig_advance = sched._advance_warm
+        # The step program, not _advance_warm: a chunk sent a tick ahead
+        # is booked by a second call that dispatches nothing.
+        orig_chunk = sched._prefill_suffix
         orig_decode = sched._decode_dispatch
-        sched._advance_warm = lambda i: (
-            events.append("chunk"), orig_advance(i)
+        sched._prefill_suffix = lambda *a, **k: (
+            events.append("chunk"), orig_chunk(*a, **k)
         )[1]
         sched._decode_dispatch = lambda *a, **k: (
             events.append("decode"), orig_decode(*a, **k)
