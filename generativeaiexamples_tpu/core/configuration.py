@@ -495,8 +495,8 @@ class ObservabilityConfig:
     """Per-request telemetry (see ``docs/observability.md``).
 
     Defaults ON: the stage histograms and the ``/debug/requests`` flight
-    recorder are the production postmortem surface, and ``bench_obs``
-    gates their clean-path overhead at <= 3%.
+    recorder are the production postmortem surface.  What they cost a
+    request has not been measured on a serving host.
     """
 
     enabled: bool = configfield(

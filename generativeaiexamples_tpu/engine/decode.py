@@ -137,7 +137,8 @@ def prepare_params(
 
 
 def init_random_int8_params(cfg: llama.LlamaConfig, key: jax.Array):
-    """Random serving params with projections born int8 (bench/tests).
+    """Random serving params with projections born int8: what
+    ``benchmarks/run.py``, ``chip_smoke.py`` and the tests serve.
 
     Quantizes leaf-by-leaf under jit so peak HBM never holds a full bf16
     copy of the model next to the int8 one.
@@ -151,8 +152,9 @@ def init_random_int8_params(cfg: llama.LlamaConfig, key: jax.Array):
     )
 
     params = llama.init_params(dataclasses.replace(cfg, n_layers=1), key)
-    # Broadcast the single random layer to full depth in int8 (bench-only
-    # weights: values are random either way, but shapes/dtypes are real).
+    # Broadcast the single random layer to full depth in int8 (weights for
+    # measuring and testing: values are random either way, but
+    # shapes/dtypes are real).
     quant1 = jax.jit(quantize_matrix)
     layers = {}
     for name, leaf in params["layers"].items():

@@ -576,7 +576,7 @@ class Scheduler:
         )
         # ``quantize`` is the int8-weights serving configuration: float
         # (or absent, hence random) params become int8 projections with
-        # qkv and gate/up packed — what bench.py hands over pre-built.
+        # qkv and gate/up packed — what ``chip_smoke.py`` hands over pre-built.
         self.params = model.prepare_params(
             params, quantize=quantize, matmul_kernel=matmul_kernel, seed=seed
         )
@@ -2664,8 +2664,9 @@ class Scheduler:
             budget -= batch_tokens
             progressed = True
 
-        # Published occupancy includes this tick's admissions (bench.py
-        # samples this) — the DECODE snapshot stays pre-admission.
+        # Published occupancy includes this tick's admissions (``/metrics``
+        # and the benchmark's window log read it) — the DECODE snapshot
+        # stays pre-admission.
         with self.stats.lock:
             self.stats.active_slots = len(self._active())
         decode_pending = None

@@ -4,9 +4,8 @@ TRT-LLM ships draft-model speculative decoding inside its serving engine
 (reference consumes it via the NIM container, SURVEY.md §2.8;
 ``deploy/compose/docker-compose-nim-ms.yaml:2-22`` is the engine that owns
 this class of optimization); this is the TPU-native equivalent wired into
-the continuous-batching scheduler rather than the offline
-``SpeculativeGenerator`` (``engine/speculative.py``), whose greedy
-acceptance rule and cache invariants it shares.
+the continuous-batching scheduler; it is the repo's one speculative
+engine.
 
 One **speculation round** per live slot:
 
@@ -56,8 +55,8 @@ serving configuration), the verify pass uses the append-buffer protocol
 verify_gqa_attention_xla``), and one windowed flush per round lands it —
 so the big cache is never scattered into inside the executable and the
 spec path shares the plain decode path's memory/layout profile at
-serving batch (the scatter-layout copy failure mode of PERF_NOTES.md
-round-3 cannot occur).  On CPU/bf16 the warm multi-token scatter path
+serving batch (the scatter layout's entry copies, which ran out of
+memory on the chip in 2026-07, cannot occur).  On CPU/bf16 the warm multi-token scatter path
 remains the semantics oracle; both are bit-identity tested.
 """
 

@@ -6,8 +6,8 @@ TensorRT-LLM (consumed by the reference via the NIM container,
 
 Why a kernel: the XLA decode path must ``dynamic_slice`` each layer's KV
 window out of the stacked cache before the attention einsums, and XLA
-materializes that slice in HBM — measured at 4.3 ms of the 26.6 ms decode
-step (b=192, window 256; PERF_NOTES.md).  The kernel copies KV blocks
+materializes that slice in HBM (4.3 ms of a 26.6 ms decode step at b=192,
+window 256, in a 2026-07 run that is in no ledger).  The kernel copies KV blocks
 straight out of the full ``(L, KH, B, T, HD)`` cache, which stays in HBM
 — the layer index rides in as a scalar-prefetch operand — so there is no
 intermediate copy, and it copies only what is live: a program takes 16
@@ -439,7 +439,8 @@ def use_append_buffer(
     append protocol: the alternative — per-token scatters into the big
     head-major cache — prefers a KH-minor layout that conflicts with
     every other executable touching the cache, and the resulting entry
-    copies OOM at serving batch (PERF_NOTES.md round-3 caveat).  When
+    copies OOM at serving batch (seen on the chip in 2026-07, before the
+    ledger).  When
     :func:`use_decode_kernel` also holds, attention runs in the Pallas
     kernel; otherwise :func:`decode_gqa_attention_xla` computes the same
     contract with einsums — slower (it materializes the per-layer KV

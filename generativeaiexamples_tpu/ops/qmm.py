@@ -1,11 +1,12 @@
 """W8A8 Pallas quantized matmul: int8 weight streaming on the MXU.
 
-The decode step's weight matmuls are the dominant remaining serving
-bottleneck (PERF_NOTES.md "The dominant remaining bottleneck"): XLA's
-s8-operand convolution emitter reads the int8 weights at ~460 GB/s
-effective against a ~910 GB/s raw HBM stream.  The one probe that beat
-it — a manual-DMA Pallas kernel with a native int8×int8 MXU dot — is
-productionized here:
+The decode step's weight matmuls are most of a serving step.  This
+kernel was written on the theory that XLA's s8-operand emitter read the
+int8 weights at about half the HBM stream; the ledger has since put
+XLA's stream at 97 % of the v5e's 819 GB/s (PERF.md section 5), the
+kernel has never been timed in a cell, and ROADMAP.md Design 2 decides
+whether it stays.  A manual-DMA Pallas kernel with a native int8×int8
+MXU dot:
 
 * **Per-token dynamic activation quantization** (symmetric int8,
   ``quantize_activations``) happens in plain jnp OUTSIDE the kernel so
@@ -39,7 +40,7 @@ device (or anywhere under ``GAIE_QMM_INTERPRET=1`` for hermetic CPU
 tests), subject to a VMEM budget; everything else — prefill-sized row
 counts, CPU — takes the XLA twin, which is also the reference
 implementation.  ``GAIE_DISABLE_QMM_KERNEL=1``
-forces the twin everywhere (A/B harness for bench.py --fused).
+forces the twin everywhere (the tests' A/B switch).
 """
 
 from __future__ import annotations

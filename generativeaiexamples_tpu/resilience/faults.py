@@ -6,7 +6,7 @@ path.  Production code calls :func:`inject` at each site; with no
 faults installed that is one module-level boolean check, so the hooks
 cost nothing in normal operation.
 
-Standard sites (the names ``bench_chaos`` and the docs use):
+Standard sites (the names the tests and the docs use):
 
   =============  =====================================================
   ``embedder``   query/document embedding (Retriever embed stage and
@@ -36,7 +36,7 @@ reproducible.
 The ``replica`` site additionally takes ``index``: with
 ``replica:latency=200,index=1`` only the scheduler whose pool index is
 1 sleeps per tick — a deterministic slow-but-alive straggler for
-``bench.py --gray`` (its tick counter keeps advancing, so the binary
+``tests/test_gray.py`` (its tick counter keeps advancing, so the binary
 stall detector never fires; only the PR 13 brownout scoring sees it).
 """
 

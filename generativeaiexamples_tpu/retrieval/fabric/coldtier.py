@@ -8,8 +8,8 @@ plus the original f32 rows.  Searching a cold partition is two-stage:
   1. **Stage 1 — host ADC scan.**  A per-subspace lookup table turns the
      query into ``pq_m`` gathers over the code matrix: ``rows * pq_m``
      bytes of host traffic instead of ``rows * dim * 4`` of HBM traffic
-     (pq_m=16 over dim=64 f32 is a 16x byte cut — the ≤0.15x gate in
-     ``bench.py --shard``).
+     (pq_m=16 over dim=64 f32 is a 16x byte cut — the <=0.15x bound
+     ``tests/test_fabric.py`` holds).
   2. **Stage 2 — exact rescore.**  The stage-1 survivors' f32 rows are
      prefetched to the accelerator with ``jax.device_put`` (dispatch is
      async, so the transfer overlaps the remaining shards' stage-1

@@ -12,7 +12,8 @@ Three families:
     store is unsharded);
   * ``rag_coldtier_*`` — host-RAM tier movement: promotions/demotions,
     async prefetch traffic, resident host bytes, and the per-query
-    host/HBM scan-byte split behind the ≤0.15x bench gate;
+    host/HBM scan-byte split (``tests/test_fabric.py`` holds the host
+    share to <= 0.15x);
   * ``rag_collection_*`` — tenancy: collection count, lifecycle
     counters, quota rejections.
 

@@ -544,7 +544,8 @@ class ShardedVectorStore(VectorStore):
     def scanned_bytes_split(self, top_k: int) -> dict:
         """Per-query scan traffic by tier: HBM bytes (hot shards' scans
         plus cold shards' prefetched rescore rows) vs host bytes (cold
-        code scans).  The host/HBM split ``bench.py --shard`` gates on."""
+        code scans).  ``tests/test_fabric.py`` holds a demoted shard's host
+        share to <= 0.15x of its rows' full-width bytes."""
         k_shard = self.shard_k(top_k)
         hbm = host = 0
         with self._lock:

@@ -875,9 +875,10 @@ class TPUVectorStore(VectorStore):
         """Analytic HBM bytes one query's search reads: the
         corpus-proportional scan (compressed codes or the full-width
         buffer), the gathered rescore rows, the always-exact tail, and
-        the validity masks.  The number ``bench_quant`` turns into
-        effective GB/s — and the whole point of quantized scoring: int8
-        cuts it ~2x, PQ by ~2*dim/pq_m."""
+        the validity masks.  Bytes over a search's time is its effective
+        bandwidth, and cutting them is the whole point of quantized
+        scoring: int8 ~2x, PQ ~2*dim/pq_m
+        (``tests/test_retrieval.py::TestQuantized``)."""
         with self._lock:
             if self._device_buf is None:
                 if self._dirty and int(self._valid.sum()):
@@ -1037,8 +1038,8 @@ class TPUIVFVectorStore(TPUVectorStore):
          matmul → masked ``lax.top_k``.
 
     HBM read traffic per query drops from capacity·dim (exact) to
-    nprobe·bucket_cap·dim — the crossover where clustering beats the
-    exact matmul is measured by ``perf/bench_retrieval_sweep.py``.
+    nprobe·bucket_cap·dim — where clustering beats the exact matmul on
+    the chip has not been measured (``retrieval/factory.py``).
     Small corpora (< min_train_size) fall back to the exact path; recall
     follows cluster structure (probe all lists → exact by construction,
     tested).

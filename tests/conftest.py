@@ -2,7 +2,7 @@
 
 This mirrors the survey's test strategy (SURVEY.md §4): pjit/sharding logic
 is validated hermetically on a virtual multi-device CPU platform; real-TPU
-runs happen only in bench.py.
+runs happen only in ``benchmarks/run.py`` and ``chip_smoke.py``.
 """
 
 import os

@@ -324,6 +324,83 @@ class TestAutoscalerDecisions:
         assert pool.calls == [3]
 
 
+class TestClosedLoop:
+    def test_load_step_pages_scales_out_recovers_and_sheds_low_classes(self):
+        """The three controllers closed over each other, for real, on a
+        simulated clock: a 4x load step on a one-replica pool (10
+        requests/s a replica) fires the fast-burn page within a minute,
+        the autoscaler grows the pool and shrinks it after, the page
+        clears, the last five minutes' p95 is inside the latency SLO,
+        and every shed request was batch or ingest."""
+        from generativeaiexamples_tpu.core.configuration import SLOConfig
+        from generativeaiexamples_tpu.obs.slo import SloEngine
+
+        db, rec = Tsdb(), _Recorder()
+        slo = SloEngine(
+            SLOConfig(latency_p95_ms="/generate=2500", evaluation_period_s=0.0),
+            tsdb=db,
+            recorder=rec,
+        )
+        admission = AdmissionController(
+            AdmissionConfig(
+                weights="interactive=70,batch=20,ingest=10",
+                rates="batch=3,ingest=2",  # ~1.5x their baseline share
+                burst_s=2.0,
+                max_inflight=0,
+            ),
+            recorder=rec,
+            tsdb=db,
+        )
+        pool = _StubPool(1)
+        scaler = _scaler(
+            pool, db=db, slo=slo, rec=rec, max_replicas=4, down_checks=3
+        )
+        mix = (("interactive", 0.60), ("batch", 0.25), ("ingest", 0.15))
+        t0, t_step, t_back, t_end = 1e6, 1e6 + 600, 1e6 + 900, 1e6 + 1500
+        queue, carry = [], dict.fromkeys(CLASSES, 0.0)
+        arrived, served = dict(carry), dict(carry)
+        fired_at, late, directions = None, [], []
+        t = t0
+        while t < t_end:
+            rps = 8 * (4 if t_step <= t < t_back else 1)
+            for cls, share in mix:
+                carry[cls] += rps * share
+                n, carry[cls] = int(carry[cls]), carry[cls] % 1
+                for _ in range(n):
+                    arrived[cls] += 1
+                    if admission.try_admit(cls, now=t, route="/generate").admitted:
+                        queue.append((cls, t))
+                    else:  # a deliberate 429: fast, and no error
+                        slo.note_request("/generate", 1.0, ts=t)
+            for cls, t_in in queue[: pool.size * 10]:
+                ms = (t - t_in) * 1000.0 + 100.0
+                slo.note_request("/generate", ms, ts=t)
+                admission.release(cls, duration_ms=ms)
+                served[cls] += 1
+                if t >= t_end - 300:
+                    late.append(ms)
+            del queue[: pool.size * 10]
+            db.record("engine.queued", float(len(queue)), ts=t)
+            if fired_at is None and t >= t_step:
+                if slo.evaluate(now=t, force=True)["fast_burn_firing"]:
+                    fired_at = t
+            event = scaler.tick(now=t)
+            if event is not None:
+                directions.append(event["direction"])
+            t += 1.0
+
+        assert fired_at is not None and fired_at - t_step <= 60
+        assert "up" in directions and "down" in directions
+        assert max(pool.calls) >= 2
+        assert any((e.get("attrs") or {}).get("autoscale") for e in rec.records)
+        assert not slo.evaluate(now=t_end, force=True)["fast_burn_firing"]
+        assert 0 < sorted(late)[int(len(late) * 0.95)] <= 2500.0
+        assert served["interactive"] / arrived["interactive"] >= 0.99
+        shed = admission.snapshot()["shed_total"]
+        assert shed.get("interactive", 0) == 0
+        assert shed.get("batch", 0) + shed.get("ingest", 0) > 0
+
+
 class TestPoolMetricsLines:
     def test_three_shapes(self):
         doc = "\n".join(pool_metrics_lines(None))

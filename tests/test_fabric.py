@@ -284,6 +284,69 @@ def test_cold_tier_byte_split_and_capacity():
         fab.close()
 
 
+def _clustered(rows, n_queries, dim=DIM, seed=37):
+    """Topics of ~64 rows: structure PQ codebooks can learn (iid rows
+    would make the recall figure say nothing)."""
+    rng = np.random.default_rng(seed)
+    centers = rng.standard_normal((rows // 64, dim)).astype(np.float32) * 3
+    vecs = centers[rng.integers(0, len(centers), size=rows)]
+    vecs = vecs + rng.standard_normal((rows, dim)).astype(np.float32)
+    vecs /= np.linalg.norm(vecs, axis=1, keepdims=True)
+    queries = centers[rng.integers(0, len(centers), size=n_queries)]
+    queries = queries + 0.3 * rng.standard_normal(
+        (n_queries, dim)
+    ).astype(np.float32)
+    queries /= np.linalg.norm(queries, axis=1, keepdims=True)
+    chunks = [Chunk(text=f"r{i}", source="corpus") for i in range(rows)]
+    return chunks, vecs, queries
+
+
+@pytest.mark.parametrize("mode", ["int8", "pq_cold"])
+def test_compressed_fabric_recall_where_the_rescore_sees_a_fraction(mode):
+    """At 2048 rows a shard the stage-2 rescore sees a few dozen
+    candidates, not every row (the tests above make it see all): the
+    merged top-10 still holds >= 0.95 of the exact scan's, and a demoted
+    shard's host scan reads <= 0.15x the bytes its rows cost full-width."""
+    from generativeaiexamples_tpu.retrieval.tpu import TPUVectorStore
+
+    chunks, vecs, queries = _clustered(4096, 16)
+    single = MemoryVectorStore(DIM)
+    single.add(chunks, vecs)
+    if mode == "int8":
+        fab = ShardedVectorStore(
+            DIM,
+            num_shards=2,
+            shard_factory=lambda i: TPUVectorStore(
+                DIM, dtype="float32", quantization="int8",
+                rescore_multiplier=4,
+            ),
+        )
+        fab.add(chunks, vecs)
+    else:
+        fab = ShardedVectorStore(
+            DIM, num_shards=2, hot_shard_budget=1, pq_m=8
+        )
+        fab.add(chunks, vecs)
+        fab.rebalance()
+    try:
+        found = sum(
+            len(
+                set(_ids(fab.search(q.tolist(), top_k=10)))
+                & set(_ids(single.search(q.tolist(), top_k=10)))
+            )
+            for q in queries
+        )
+        assert found / (len(queries) * 10) >= 0.95
+        if mode == "pq_cold":
+            (cold,) = fab.cold_shards()
+            cold_rows = fab._shards[cold].cold.rows()
+            assert fab.shard_k(10) * fab.rescore_multiplier < cold_rows
+            host = fab.scanned_bytes_split(10)["host"]
+            assert host <= 0.15 * cold_rows * DIM * 4
+    finally:
+        fab.close()
+
+
 def test_ewma_rebalance_promotes_hot_demotes_cold():
     chunks, vecs = _corpus(300, seed=8)
     fab = ShardedVectorStore(

@@ -505,6 +505,93 @@ class TestReplicaFaultSite:
 # -- scheduler integration -------------------------------------------------
 
 
+class _ClockedScorer(ReplicaScorer):
+    """The real scorer, reading its window at the test's clock."""
+
+    now = 0.0
+
+    def score_all(self, indices, now=None):
+        return super().score_all(indices, now=self.now)
+
+
+class TestBrownoutLoop:
+    def test_slow_replica_is_ejected_and_readmitted_and_no_request_lost(self):
+        """Series -> scorer -> state machine -> routing, closed over three
+        live replicas on a simulated clock: the tick series of replica 0
+        read ten times its peers', it is scored down and, after the
+        dwell, ejected; requests then complete on the other two; with
+        its series back to the peers' it returns through probation to
+        healthy.  Every request ends once, with all its tokens.  (That
+        an injected latency reaches the series is
+        `TestSchedulerTickInjection`; here the series are written by
+        hand, two a second, so no wall clock decides who is slow.)"""
+        pool = _pool(
+            3,
+            health_cfg=_cfg(
+                window_s=3.0,
+                score_smoothing=0.6,
+                eject_after_s=1.0,
+                readmit_after_s=1.0,
+                probation_s=1.0,
+            ),
+        )
+        pool.scorer = _ClockedScorer(pool.health_cfg, pool.tsdb)
+        clock = [1000.0]
+
+        def step(slow_ms):
+            clock[0] += 0.5
+            for i in range(3):
+                pool.tsdb.record(
+                    f"engine.replica.{i}.tick_ms",
+                    slow_ms if i == 0 else 20.0,
+                    ts=clock[0],
+                )
+            pool.scorer.now = clock[0]
+            pool.check_replicas(now=clock[0])
+
+        def ask(i):
+            req, tokens, done = _request([1 + i % 7, 2, 3, 4], f"brown-{i}")
+            assert pool.submit(req)
+            assert done.get(timeout=120) == "length"
+            assert len(tokens) == 3 and done.empty()
+
+        def states():
+            return [r.state for r in pool.replicas]
+
+        pool.start()
+        try:
+            for i in range(6):
+                ask(i)
+            for _ in range(4):
+                step(20.0)
+            assert states() == [HEALTHY] * 3
+
+            step(200.0)  # scored down, but not yet for a second
+            assert states() == [HEALTHY] * 3
+            for _ in range(5):
+                step(200.0)
+            assert states() == [EJECTED, HEALTHY, HEALTHY]
+            assert pool.replicas[0].score < 0.5
+            assert pool.replicas[1].score == pool.replicas[2].score == 1.0
+            assert [v.idx for v in pool._views_locked()] == [1, 2]
+            served = pool.replicas[0].scheduler.stats.requests_total
+            for i in range(6, 12):
+                ask(i)
+            assert pool.replicas[0].scheduler.stats.requests_total == served
+
+            seen = []
+            for _ in range(16):  # 3 s for the window to forget, then dwell
+                step(20.0)
+                if pool.replicas[0].state not in seen:
+                    seen.append(pool.replicas[0].state)
+            assert seen == [EJECTED, PROBATION, HEALTHY]
+            assert pool.ejections_total == pool.readmissions_total == 1
+            ask(12)
+            assert not pool._placements
+        finally:
+            pool.stop()
+
+
 class TestSchedulerTickInjection:
     def teardown_method(self):
         reset_faults()

@@ -364,42 +364,3 @@ def test_scheduler_factory_replicas_get_blocked_layout(int8_packed_params):
 def test_scheduler_reports_xla_for_unblocked_params():
     sched = Scheduler(CFG, max_batch=2, max_len=128)
     assert sched.matmul_kernel == "xla"
-
-
-def test_bench_fused_full_phase(monkeypatch):
-    """The full ``bench.py --fused`` phase at tiny scale on CPU: the
-    round-19 contract keys plus the mechanism gates the CPU capture is
-    responsible for — greedy bit-identity kernel-vs-twin through the
-    generator, tile-once loading, and a clean spec on/off sub-phase.
-    (The cheap glue smoke lives in test_bench_glue.py; GB/s on the chip
-    has not been measured.)"""
-    import bench
-
-    monkeypatch.setenv("GAIE_FUSED_TINY", "1")
-    monkeypatch.delenv("GAIE_FUSED_SMOKE", raising=False)
-    out = bench.bench_fused()
-    for key in (
-        "fused_platform",
-        "fused_tile_mkn",
-        "fused_kernel_gbps",
-        "fused_xla_gbps",
-        "fused_kernel_engaged",
-        "fused_tile_bit_identical",
-        "fused_decode_tokens_per_sec",
-        "fused_twin_tokens_per_sec",
-        "fused_baseline_tokens_per_sec",
-        "fused_vs_xla_speedup",
-        "fused_greedy_bit_identical",
-        "fused_block_events_per_load",
-        "fused_block_events_flat",
-        "fused_spec_off_tokens_per_sec",
-        "fused_spec_on_tokens_per_sec",
-        "fused_spec_speedup",
-    ):
-        assert key in out, key
-    assert out["fused_tile_bit_identical"] is True
-    assert out["fused_greedy_bit_identical"] is True
-    assert out["fused_block_events_per_load"] == 4
-    assert out["fused_block_events_flat"] is True
-    assert out["fused_decode_tokens_per_sec"] > 0
-    assert "fused_spec_error" not in out

@@ -492,6 +492,47 @@ def test_expired_deadline_rejects_before_any_stage():
         retriever.retrieve_many(["q"], deadline=Deadline(time.monotonic() - 1))
 
 
+@pytest.mark.parametrize("attempts, lost", [(3, 0), (1, 2)])
+def test_concurrent_clients_under_faults_lose_only_what_no_retry_covers(
+    attempts, lost
+):
+    """Eight clients at once, the embedder failing twice and the reranker
+    down for good.  With three attempts a stage no request is lost and
+    every one is served in vector order, marked ``rerank``; with one
+    attempt exactly the two faulted requests fail.  A request that got its
+    hits got all of them either way."""
+    retriever = _make_retriever(
+        reranker=_IdentityReranker(),
+        embed_retry=RetryPolicy(max_attempts=attempts, base_ms=1, name="embed"),
+    )
+    get_fault_injector().configure(
+        "embedder:error=1.0,count=2;reranker:error=1.0"
+    )
+    outcomes: list = []
+
+    def client():
+        for _ in range(6):
+            try:
+                with deadline_scope(Deadline.after_ms(30_000)), \
+                        degrade_scope() as log:
+                    hits = retriever.retrieve("q")
+                outcomes.append((len(hits), log.stages()))
+            except FaultInjected:
+                outcomes.append(None)
+
+    threads = [threading.Thread(target=client) for _ in range(8)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=60)
+    assert len(outcomes) == 48
+    assert outcomes.count(None) == lost
+    assert all(o == (4, ["rerank"]) for o in outcomes if o is not None)
+    assert resilience_snapshot()["retries_total"] == (2 if attempts > 1 else 0)
+    reset_faults()
+    assert get_fault_injector().active_sites() == []
+
+
 # -- MicroBatcher: deadline expiry + crash guard -----------------------------
 
 

@@ -725,6 +725,47 @@ class TestBatchedRetrieval:
         assert emb.batched_calls == 1
         assert emb.single_calls == 0
 
+    def test_concurrent_clients_through_the_batcher_get_their_own_hits(self):
+        """The chain layer's shape: clients call a ``MicroBatcher`` over
+        ``retrieve_many`` on the device store.  Every client gets the hits
+        a lone ``retrieve`` returns for its query, none comes back empty,
+        and the sixteen requests share fewer dispatches than requests."""
+        import threading
+
+        from generativeaiexamples_tpu.engine.microbatch import MicroBatcher
+
+        emb = HashEmbedder(dimensions=DIM)
+        store = TPUVectorStore(DIM, dtype="float32", max_query_batch=16)
+        texts = self._corpus(emb, store, n=48)
+        r = Retriever(store=store, embedder=emb, top_k=3, score_threshold=-1.0)
+        queries = texts[:16]
+        alone = [[h.chunk.text for h in r.retrieve(q)] for q in queries]
+        batcher = MicroBatcher(
+            lambda qs: r.retrieve_many(qs, top_k=3),
+            max_batch=16,
+            max_wait_ms=200.0,
+        )
+        got: dict = {}
+
+        def client(i):
+            got[i] = batcher.call(queries[i])
+
+        try:
+            threads = [
+                threading.Thread(target=client, args=(i,)) for i in range(16)
+            ]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+            snap = batcher.stats.snapshot()
+        finally:
+            batcher.close()
+        assert all(alone) and [
+            [h.chunk.text for h in got[i]] for i in range(16)
+        ] == alone
+        assert snap["requests_total"] == 16 and snap["batches_total"] < 16
+
     def test_tpu_embedder_embed_queries_matches_embed_query(self):
         emb = TPUEmbedder(bert.bert_tiny(), batch_size=4)
         texts = ["alpha", "beta gamma", "delta epsilon zeta", "eta", "theta"]
@@ -830,6 +871,27 @@ class TestIncrementalSync:
         store.delete_source("new")
         assert store.search(vecs[0], 1)[0].chunk.text == "t0"
         assert store._device_buf is buf0  # delete flipped masks only
+
+    @pytest.mark.parametrize("incremental", [True, False])
+    def test_rows_appended_after_a_sync_are_found_by_the_next_search(
+        self, incremental
+    ):
+        """Time-to-searchable is one search: the first query after an
+        append of N rows to a synced corpus of M >> N finds a new row,
+        through the tail sync and through a full rebuild alike."""
+        vecs, _ = _clustered(1088)
+        store = TPUVectorStore(DIM, dtype="float32", incremental=incremental)
+        store.add(
+            [Chunk(text=f"r{i}", source="base") for i in range(1024)],
+            vecs[:1024],
+        )
+        assert store.search(vecs[0], 10)  # M sits exactly at capacity
+        store.add(
+            [Chunk(text=f"n{i}", source="new") for i in range(64)],
+            vecs[1024:],
+        )
+        assert store.search(vecs[1024], 10)[0].chunk.text == "n0"
+        assert len(store) == 1088
 
     def test_tail_overflow_compacts(self, monkeypatch):
         """Appends beyond the tail capacity fold into a rebuilt main

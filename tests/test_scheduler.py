@@ -919,7 +919,7 @@ class TestPipelinedTickBounds:
 
     def test_pipelined_active_slots_counts_same_tick_admissions(self):
         """stats.active_slots must include lanes admitted THIS tick, as
-        the sync tick reports (bench.py samples it for occupancy)."""
+        the sync tick reports (``/metrics`` and the benchmark's log read it)."""
         sched = Scheduler(
             CFG, max_batch=4, max_len=128, decode_chunk_size=4,
             prefix_cache="off",

@@ -9,10 +9,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from generativeaiexamples_tpu.engine.generator import LlamaGenerator
 from generativeaiexamples_tpu.engine.sampler import SamplingParams
 from generativeaiexamples_tpu.engine.scheduler import Scheduler
-from generativeaiexamples_tpu.engine.speculative import SpeculativeGenerator
 from generativeaiexamples_tpu.models import llama
 
 TARGET_CFG = llama.llama_tiny(dtype="float32", max_seq_len=128)
@@ -22,76 +20,6 @@ DRAFT_CFG = llama.llama_tiny(
 )
 
 PROMPTS = [[3, 1, 4, 1, 5], [9, 2, 6], [5, 3, 5, 8, 9, 7, 9]]
-
-
-def _reference(target_params, prompts, max_tokens):
-    gen = LlamaGenerator(
-        TARGET_CFG, target_params, max_batch=len(prompts), max_len=128
-    )
-    return [
-        r.token_ids
-        for r in gen.generate(
-            prompts, SamplingParams(temperature=0.0, max_tokens=max_tokens)
-        )
-    ]
-
-
-class TestSpeculativeExactness:
-    def test_weak_draft_matches_target_greedy(self):
-        """A draft with different (random) weights mostly disagrees with
-        the target — acceptance is low, output must still be identical."""
-        tparams = llama.init_params(TARGET_CFG, jax.random.PRNGKey(0))
-        dparams = llama.init_params(DRAFT_CFG, jax.random.PRNGKey(99))
-        spec = SpeculativeGenerator(
-            TARGET_CFG, DRAFT_CFG, tparams, dparams,
-            max_batch=len(PROMPTS), max_len=128, gamma=4,
-        )
-        got = spec.generate(PROMPTS, max_tokens=12)
-        want = _reference(tparams, PROMPTS, 12)
-        assert got == want
-        assert spec.stats["rounds"] >= 1
-
-    def test_self_draft_accepts_everything(self):
-        """Draft == target always agrees: every round must emit the full
-        gamma+1 tokens, and output still equals plain greedy decoding."""
-        tparams = llama.init_params(TARGET_CFG, jax.random.PRNGKey(1))
-        spec = SpeculativeGenerator(
-            TARGET_CFG, TARGET_CFG, tparams, tparams,
-            max_batch=1, max_len=128, gamma=4, pack=False,
-        )
-        got = spec.generate([PROMPTS[0]], max_tokens=15)
-        want = _reference(tparams, [PROMPTS[0]], 15)
-        assert got == want
-        # 1 prefill token + ceil(14 / (gamma+1)) rounds = 3 rounds.
-        assert spec.stats["rounds"] <= 3
-
-    def test_eos_stops_mid_round(self):
-        tparams = llama.init_params(TARGET_CFG, jax.random.PRNGKey(2))
-        ref = _reference(tparams, [PROMPTS[0]], 12)[0]
-        eos = ref[5]  # force a stop inside the stream
-        gen = LlamaGenerator(TARGET_CFG, tparams, max_batch=1, max_len=128)
-        want = [
-            r.token_ids
-            for r in gen.generate(
-                [PROMPTS[0]],
-                SamplingParams(temperature=0.0, max_tokens=12),
-                eos_id=eos,
-            )
-        ]
-        dparams = llama.init_params(DRAFT_CFG, jax.random.PRNGKey(98))
-        spec = SpeculativeGenerator(
-            TARGET_CFG, DRAFT_CFG, tparams, dparams,
-            max_batch=1, max_len=128, gamma=3,
-        )
-        got = spec.generate([PROMPTS[0]], max_tokens=12, eos_id=eos)
-        assert got == want
-
-    def test_vocab_mismatch_rejected(self):
-        with pytest.raises(ValueError):
-            SpeculativeGenerator(
-                TARGET_CFG,
-                llama.llama_tiny(vocab_size=77),
-            )
 
 
 class TestSchedulerSpeculation:
