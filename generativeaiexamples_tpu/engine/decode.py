@@ -327,11 +327,23 @@ def pin_default_layout(cache):
     )
 
 
+def carry_tokens(tokens, carried, carry):
+    """A chunk's input tokens: the host's ``tokens``, and for the rows
+    ``carry`` (batch,) bool marks the last row of ``carried``, the
+    (n_steps, batch) tokens of the chunk before this one (with what an
+    admission's graft landed there since), which never left the device.
+    ``None`` for either takes the host's for every row."""
+    if carried is None or carry is None:
+        return tokens
+    return jnp.where(carry, carried[-1], tokens)
+
+
 def make_decode_chunk_fn(cfg: llama.LlamaConfig, mesh, max_len: int):
     """Compiled multi-step decode: ``lax.scan`` of forward+sample.
 
     Signature: ``fn(params, cache, tokens, lengths, key, temp, top_p,
-    top_k, n_steps, kv_bucket=None, live=None)`` with the cache donated
+    top_k, n_steps, kv_bucket=None, live=None, carried=None, carry=None)``
+    (the last two: :func:`carry_tokens`) with the cache donated
     and ``n_steps``/``kv_bucket`` static (bucketed by callers).  Returns
     ``(cache, toks)`` with toks shaped (n_steps, batch).  One host
     round-trip per chunk instead of per token: a device→host sync costs
@@ -381,7 +393,10 @@ def make_decode_chunk_fn(cfg: llama.LlamaConfig, mesh, max_len: int):
         n_steps,
         kv_bucket=None,
         live=None,
+        carried=None,
+        carry=None,
     ):
+        tokens = carry_tokens(tokens, carried, carry)
         window = min(kv_bucket, max_len) if kv_bucket else max_len
         kv_int8 = len(cache) == 4
         b = cache[0].shape[2]
@@ -470,7 +485,7 @@ def make_decode_chunk_fn(cfg: llama.LlamaConfig, mesh, max_len: int):
 
     def decode_chunk_checked(
         params, cache, tokens, lengths, key, temp, top_p, top_k,
-        n_steps, kv_bucket=None, live=None,
+        n_steps, kv_bucket=None, live=None, carried=None, carry=None,
     ):
         """Debug-mode contract guard wrapping the compiled step.
 
@@ -501,7 +516,7 @@ def make_decode_chunk_fn(cfg: llama.LlamaConfig, mesh, max_len: int):
                     )
         return decode_chunk(
             params, cache, tokens, lengths, key, temp, top_p, top_k,
-            n_steps, kv_bucket, live,
+            n_steps, kv_bucket, live, carried, carry,
         )
 
     return decode_chunk_checked
@@ -513,7 +528,8 @@ def make_paged_decode_chunk_fn(
     """Paged twin of :func:`make_decode_chunk_fn`.
 
     Signature: ``fn(params, leaves, table, tokens, lengths, key, temp,
-    top_p, top_k, n_steps, kv_bucket=None)`` — the pool leaves are
+    top_p, top_k, n_steps, kv_bucket=None, carried=None, carry=None)``
+    (the last two as the contiguous chunk's) — the pool leaves are
     donated, the device page table rides alongside (NOT donated: the
     host owns it), and ``max_len`` is the LOGICAL per-slot capacity the
     table maps.  Branch structure mirrors the contiguous chunk exactly
@@ -540,7 +556,10 @@ def make_paged_decode_chunk_fn(
         top_k,
         n_steps,
         kv_bucket=None,
+        carried=None,
+        carry=None,
     ):
+        tokens = carry_tokens(tokens, carried, carry)
         window = min(kv_bucket, max_len) if kv_bucket else max_len
         b = tokens.shape[0]
         if use_append_buffer(
@@ -629,7 +648,7 @@ def make_paged_decode_chunk_fn(
 
     def paged_decode_chunk_checked(
         params, leaves, table, tokens, lengths, key, temp, top_p,
-        top_k, n_steps, kv_bucket=None,
+        top_k, n_steps, kv_bucket=None, carried=None, carry=None,
     ):
         """Same kv_bucket contract guard as the contiguous wrapper."""
         if kv_bucket is not None:
@@ -647,7 +666,7 @@ def make_paged_decode_chunk_fn(
                     )
         return paged_decode_chunk(
             params, leaves, table, tokens, lengths, key, temp, top_p,
-            top_k, n_steps, kv_bucket,
+            top_k, n_steps, kv_bucket, carried, carry,
         )
 
     return paged_decode_chunk_checked

@@ -1138,6 +1138,8 @@ class EnginePool:
         "shared_prefix_hits",
         "prefill_chunks",
         "prefill_chunks_ahead",
+        "decode_chunks_ahead",
+        "decode_tokens_dropped",
         "spec_rounds",
         "spec_tokens",
         "spec_proposed",

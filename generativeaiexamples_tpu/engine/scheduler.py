@@ -126,6 +126,17 @@ class _Slot:
     # snapshot (``_active``), although ``warm_pos`` is already None.
     ahead_tokens: int = 0
     first_token: Optional[Callable[[], None]] = None
+    # Tokens of this request that are on the device and not fetched yet:
+    # its first token from the dispatch of its last prefill program to
+    # that token's fetch, and ``decode_chunk_size`` for every decode chunk
+    # dispatched with the row live until that chunk's fetch.  The row's
+    # next write position is ``length + emitted + unfetched - 1``.
+    # ``on_device``: the newest of them is the slot's entry in the last row
+    # of ``Scheduler._carried``, where the next decode chunk can read it
+    # without the host (the last decode chunk computed it, or an
+    # admission's graft landed it there since).
+    unfetched: int = 0
+    on_device: bool = False
     # Speculative decoding: EWMA of this request's observed per-round
     # acceptance rate (accepted drafts / gamma).  Drives the adaptive
     # lookahead — a request whose drafts keep getting rejected decays
@@ -157,7 +168,7 @@ TICK_RECORD_FIELDS = (
     ("tick", "t_start", "wall_start")
     + tuple(f"{p}_s" for p in TICK_PHASES)
     + ("starved_s", "prefill_chunks", "admitted", "decode_lanes",
-       "kv_bucket", "tokens", "queued")
+       "kv_bucket", "tokens", "queued", "decode_ahead")
 )
 
 
@@ -187,6 +198,9 @@ class _TickClock:
     those chunks before the next tick's first dispatch idles unseen.  What
     it still times is a tick with no continuing warming slot (the host's
     whole gap, as before); the device's idle share is the trace's to say.
+    A decode chunk sent ahead (a full house) is another matter: it IS
+    fetched, a tick later, so the fetch of the chunk before it opens no
+    interval because work is queued behind it, and that is true.
     """
 
     def __init__(self, stats: "Stats") -> None:
@@ -300,6 +314,13 @@ class Stats:
         # Of those, chunks dispatched behind a decode chunk and before
         # the host blocked on its tokens (``_advance_warm(ahead=True)``).
         self.prefill_chunks_ahead = 0
+        # Decode chunks dispatched while the one before was still
+        # unfetched (a full house: ``Scheduler._goes_ahead``), counted at
+        # their fetch like ``decode_chunks``; and the tokens a chunk
+        # computed for a row whose request had ended before the chunk's
+        # fetch (it stopped on EOS or was cancelled in the chunk before).
+        self.decode_chunks_ahead = 0
+        self.decode_tokens_dropped = 0
         # Speculative decoding: rounds = live speculating (slot, round)
         # pairs run, tokens = tokens emitted by those rounds.  Acceptance
         # rate is derivable as (tokens/rounds - 1) / gamma.  Greedy slots
@@ -456,6 +477,8 @@ class Stats:
                 "shared_prefix_hits": self.shared_prefix_hits,
                 "prefill_chunks": self.prefill_chunks,
                 "prefill_chunks_ahead": self.prefill_chunks_ahead,
+                "decode_chunks_ahead": self.decode_chunks_ahead,
+                "decode_tokens_dropped": self.decode_tokens_dropped,
                 "spec_rounds": self.spec_rounds,
                 "spec_tokens": self.spec_tokens,
                 "spec_proposed": self.spec_proposed,
@@ -802,6 +825,17 @@ class Scheduler:
         # Token futures of the chunks the last tick sent ahead, in the
         # device's order (``_tick`` waits for the first of several).
         self._ahead_toks: list = []
+        # The decode chunk dispatched ahead, while the one before it was
+        # unfetched (``_goes_ahead``): ``_decode_finalize``'s arguments and
+        # the chunk's kv_bucket (for the record of the tick that fetches
+        # it), kept for the next tick; None between ticks otherwise.
+        self._flight: Optional[tuple] = None
+        # The newest decode chunk's tokens, (decode_chunk_size, max_batch)
+        # on the device: the next chunk reads the rows that go on in its
+        # last row (``decode.carry_tokens``), and a cold admission's graft
+        # lands its first tokens there (``_graft_rows``).  Never donated:
+        # the chunk's finalizer fetches the same buffer.
+        self._carried = self._no_tokens()
         # Pipelined ticks dispatch the decode chunk in the same tick as
         # admissions, pinning not-yet-decoding lanes to max_len - 1 —
         # whose append-buffer flush garbage-writes [max_len - w, max_len)
@@ -844,6 +878,7 @@ class Scheduler:
         self._tick_chunks = 0
         self._tick_admitted = 0
         self._tick_kv_bucket = 0
+        self._tick_ahead = 0
         self._tick_busy = False
         self._tick_no = 0
         self._ticks: "collections.deque[tuple]" = collections.deque(
@@ -885,7 +920,7 @@ class Scheduler:
 
         @functools.partial(jax.jit, donate_argnums=(0,))
         @jax.named_scope("kv_write")
-        def _graft_rows(big, small, rows, slots):
+        def _graft_rows(big, small, rows, slots, carried=None, first=None):
             """Copy prefilled KV rows of the small cache into their slots
             of the big cache — one scatter per leaf for the whole
             admission batch (per-row dispatches were a measurable slice of
@@ -893,8 +928,16 @@ class Scheduler:
 
             ``rows``/``slots`` are equal-length int32 vectors, padded by
             the caller with duplicates of index 0 (duplicate scatters of
-            the same source row are harmless)."""
-            return model.graft_rows(big, small, rows, slots)
+            the same source row are harmless).
+
+            ``carried``/``first``: the batch's sampled first tokens land
+            in their slots' entries of ``carried``'s last row, where a
+            decode chunk dispatched behind this graft reads them
+            (``Scheduler._carried``); returned second, not donated."""
+            big = model.graft_rows(big, small, rows, slots)
+            if carried is None:
+                return big
+            return big, carried.at[-1, slots].set(first[rows])
 
         @functools.partial(
             jax.jit, donate_argnums=(1,), static_argnums=(8,)
@@ -1132,6 +1175,12 @@ class Scheduler:
         ]
 
     # -- internals ---------------------------------------------------------
+
+    def _no_tokens(self) -> jax.Array:
+        """``_carried`` before any chunk has run (an upload, no program)."""
+        return jax.device_put(
+            np.zeros((self.decode_chunk_size, self.max_batch), np.int32)
+        )
 
     def _next_key(self) -> jax.Array:
         self._key, sub = jax.random.split(self._key)
@@ -1395,6 +1444,10 @@ class Scheduler:
         # slot's rows; nothing of it is booked or fetched any more.
         slot.ahead_tokens = 0
         slot.first_token = None
+        # Nor of a decode chunk that is still on the device with this row
+        # live: its finalizer finds another request here, or none, and
+        # drops the row's tokens (``_decode_finalize``).
+        slot.unfetched, slot.on_device = 0, False
         if (
             req is not None
             and reason in ("stop", "length")
@@ -1570,8 +1623,9 @@ class Scheduler:
                 )
             )
         else:
-            self._cache = self._graft_rows(
-                self._cache, small, jnp.asarray(rows), jnp.asarray(slots_arr)
+            self._cache, self._carried = self._graft_rows(
+                self._cache, small, jnp.asarray(rows), jnp.asarray(slots_arr),
+                self._carried, tok,
             )
         if self._dhist is not None:
             # Scatter the admitted prompts into the device history.  The
@@ -1602,6 +1656,8 @@ class Scheduler:
             slot.emitted = 0
             slot.history = list(req.token_ids)
             slot.accept_ewma = 1.0
+            slot.unfetched = 1
+            slot.on_device = self._pool is None
         return reqs, slot_idxs, tok, ticket
 
     def _admit_finalize(
@@ -1626,7 +1682,7 @@ class Scheduler:
             observe_stage(
                 "llm_ttft", (req.first_token_at - req.submitted_at) * 1000.0
             )
-            self._handle_token(slot_idx, int(tok_host[r]))
+            self._first_token(slot_idx, req, int(tok_host[r]))
         with self.stats.lock:
             self.stats.prefill_rows += len(reqs)
 
@@ -1775,6 +1831,7 @@ class Scheduler:
         slot.history = list(req.token_ids)
         slot.warm_pos = None
         slot.accept_ewma = 1.0
+        slot.unfetched = 1
         return req, slot_idx, tok, ticket
 
     def _prefill_suffix_begin(self, n: int, s: int, kv_bucket: int) -> None:
@@ -1802,7 +1859,7 @@ class Scheduler:
         observe_stage(
             "llm_ttft", (req.first_token_at - req.submitted_at) * 1000.0
         )
-        self._handle_token(slot_idx, tok_host)
+        self._first_token(slot_idx, req, tok_host)
 
     def _admit_hit(
         self, req: Request, slot_idx: int, common: int, *, shared: bool
@@ -2141,12 +2198,22 @@ class Scheduler:
             # Final chunk: prefill complete — the slot joins decode in
             # the tick after the one its first token is fetched in.
             slot.warm_pos = None
+            slot.unfetched = 1
             fin = lambda: self._suffix_finalize(req, slot_idx, tok, ticket)
         if ahead:
             slot.ahead_tokens, slot.first_token = n, fin
             self._ahead_toks.append(tok)
             return None, 0
         return fin, n
+
+    def _first_token(self, slot_idx: int, req: Request, tid: int) -> None:
+        """A prompt's first token has been fetched: it is on the device
+        no longer.  ``req`` still holds the slot (no tick ends a request
+        between a prefill's dispatch and its fetch but by failing)."""
+        slot = self._slots[slot_idx]
+        if slot.request is req:
+            slot.unfetched -= 1
+            self._handle_token(slot_idx, tid)
 
     def _handle_token(self, slot_idx: int, tid: int) -> None:
         """Process one sampled token for a slot; may finish the slot."""
@@ -2253,6 +2320,10 @@ class Scheduler:
                 )
             self._aux_pending.clear()
             self._ahead_toks.clear()
+            # A decode chunk sent ahead dies with the buffers it wrote,
+            # and no row's token is on the device any more.
+            self._flight = None
+            self._carried = self._no_tokens()
             if self._snapshots is not None:
                 self._snapshots.clear()
                 with self.stats.lock:
@@ -2284,6 +2355,7 @@ class Scheduler:
                     self._tick_chunks, self._tick_admitted,
                     self._tick_decoded, self._tick_kv_bucket,
                     self._tick_tokens, self.stats.queued,
+                    self._tick_ahead,
                 )
             )
 
@@ -2426,6 +2498,7 @@ class Scheduler:
         self._tick_chunks = 0
         self._tick_admitted = 0
         self._tick_kv_bucket = 0
+        self._tick_ahead = 0
         self._tick_busy = False
         if self._pool is not None:
             self._kv_pages_reserved = 0
@@ -2447,7 +2520,9 @@ class Scheduler:
         #
         # Newly admitted slots join decode at the NEXT tick (this tick's
         # chunk keeps the pre-admission active snapshot: their host-side
-        # _cur_tok is still a device future when the chunk is dispatched).
+        # _cur_tok is still a device future when the chunk is dispatched);
+        # with a full house a cold batch's rows join the chunk dispatched
+        # behind them, reading that future on the device (``_carried``).
         # The chunk's shape-stable garbage writes into those lanes are
         # harmless BECAUSE admissions are length-bounded: non-snapshot
         # lanes pin to max_len - 1, whose append-buffer flush clips into
@@ -2485,8 +2560,10 @@ class Scheduler:
         # dispatch the poll would come at once and that request would
         # wait a whole tick.  So where several chunks went ahead, wait
         # for the first: the device has the others to run meanwhile.
+        # (Not with a decode chunk in flight from the last tick: the house
+        # was full then, and its tokens are the next thing to fetch.)
         sent, self._ahead_toks = self._ahead_toks, []
-        if len(sent) > 1:
+        if len(sent) > 1 and self._flight is None:
             self._clock.enter("wait_device")
             sent[0].block_until_ready()
             self._clock.enter("plan")
@@ -2669,11 +2746,33 @@ class Scheduler:
         # stays pre-admission.
         with self.stats.lock:
             self.stats.active_slots = len(self._active())
+        # A full house decodes without a pause: the chunk the last tick
+        # sent ahead is on the device, and the next goes out behind it
+        # before the host blocks on anything, with its rows' input tokens
+        # read where the chunk in flight leaves them.
+        ahead = self._goes_ahead()
+        flight, self._flight = self._flight, None
+        in_flight = flight is not None
         decode_pending = None
-        if decode_active:
-            self._tick_decoded = len(decode_active)
-            decode_pending = self._dispatch_decode_phase(decode_active)
+        if in_flight:
+            pending, self._tick_kv_bucket = flight
+            finalize = functools.partial(self._decode_finalize, ahead=True)
+            decode_pending, lanes = (finalize, pending), pending[1]
+        else:
+            lanes = self._decode_lanes() if ahead else decode_active
+            if lanes:
+                decode_pending = self._dispatch_decode_phase(lanes)
+        if decode_pending is not None:
             progressed = True
+            # The tick's record describes the chunk it fetches: its
+            # lanes, its window, its tokens, whether it had gone ahead.
+            self._tick_decoded, self._tick_ahead = len(lanes), int(in_flight)
+            fetched_bucket = self._tick_kv_bucket
+            lanes = self._decode_lanes() if ahead else None
+            if lanes:
+                pending = self._decode_dispatch(lanes)
+                self._flight = pending, self._tick_kv_bucket
+                self._tick_kv_bucket = fetched_bucket
             # The next tick's phase 1, now: a continuing slot's next
             # chunk depends on nothing the finalizers below fetch, so it
             # goes out behind the decode chunk and the device has work
@@ -2681,11 +2780,14 @@ class Scheduler:
             # plans the next.  Same programs in the same order.
             for i in self._warming():
                 self._advance_warm(i, ahead=True)
-        for fin in admits:
-            fin()
+        # Fetches follow the device's order: a chunk that was in flight
+        # when this tick began ran before this tick's admissions.
+        fetch = []
         if decode_pending is not None:
             finalize, pending = decode_pending
-            finalize(*pending)
+            fetch = [lambda: finalize(*pending)]
+        for fin in fetch + admits if in_flight else admits + fetch:
+            fin()
         if not progressed:
             # Idle: block briefly on the queue (backlogged requests first).
             # This path deliberately bypasses ADMIT_TOKEN_BUDGET — it only
@@ -2778,6 +2880,44 @@ class Scheduler:
             return True
         self._admit_many([req], [free[0]])
         return True
+
+    def _goes_ahead(self) -> bool:
+        """Whether this tick dispatches its decode chunk AND the next
+        before it fetches anything: after its admissions every slot holds
+        a live request, decoding or warming.  While a slot is free the
+        next arrival's prefill should lead the device's queue, not wait
+        behind a decode chunk; with a full house nothing can be admitted
+        before a row ends anyway.  Plain decoding over the contiguous
+        cache only: a speculative round's acceptance counts and a paged
+        chunk's pages are the host's to know before the next dispatch."""
+        return (
+            self._pool is None
+            and self.draft_cfg is None
+            and self.spec_mode != "ngram"
+            and all(s.request is not None for s in self._slots)
+        )
+
+    def _decode_lanes(self) -> list[int]:
+        """A full house's rows for the next decode chunk: every row whose
+        newest token the host has (as ``_active``'s) or the device holds
+        for it (``_Slot.on_device``: the chunk in flight computes it, or
+        this tick's admission landed it), left out if the tokens in
+        flight already bring it to its end: no lane computes a token
+        that the host knows will be thrown away.  A row that may stop on
+        EOS or be cancelled is taken to go on (``_decode_finalize``)."""
+        lanes = []
+        for i, s in enumerate(self._slots):
+            if s.request is None or s.warm_pos is not None:
+                continue
+            if s.unfetched and not s.on_device:
+                continue
+            done = s.emitted + s.unfetched
+            if (
+                done < s.request.sampling.max_tokens
+                and s.length + done < self.effective_max_len
+            ):
+                lanes.append(i)
+        return lanes
 
     def _lane_state(self) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, int]:
         """Per-slot decode-chunk inputs shared by the plain and speculative
@@ -3094,7 +3234,9 @@ class Scheduler:
                 )
         self._flush_tokens()
 
-    def _decode_dispatch(self, active: Optional[list[int]] = None) -> tuple:
+    def _decode_dispatch(
+        self, active: Optional[list[int]] = None
+    ) -> tuple:
         """Dispatch one plain decode chunk asynchronously; the host does
         not block until :meth:`_decode_finalize` fetches the tokens.
 
@@ -3102,7 +3244,10 @@ class Scheduler:
         BEFORE this tick's admissions (pipelined tick): rows admitted
         after that snapshot still hold a device-future first token, so
         this chunk must neither read their ``_cur_tok`` nor emit their
-        lanes."""
+        lanes.  A full house's snapshot (``_decode_lanes``) also holds
+        rows with tokens still on the device (``_Slot.unfetched``): each
+        writes that many positions further on, and reads its input token
+        in ``_carried`` instead of ``_cur_tok``."""
         lengths, temp, top_p, top_k, max_active = self._lane_state()
         pinned = active is not None
         if not pinned:
@@ -3111,6 +3256,13 @@ class Scheduler:
         # reads (decode_chunk's ``live``), and the only ones emitted.
         snap = np.zeros((self.max_batch,), dtype=bool)
         snap[active] = True
+        carry = np.zeros((self.max_batch,), dtype=bool)
+        for i in active:
+            n = self._slots[i].unfetched
+            if n:
+                carry[i] = True
+                lengths[i] += n
+                max_active = max(max_active, int(lengths[i]))
         if pinned:
             # Lanes outside the emission snapshot (freshly admitted this
             # tick, emitted still 0) would garbage-write at length-1 —
@@ -3158,6 +3310,8 @@ class Scheduler:
                 jnp.asarray(top_k),
                 self.decode_chunk_size,
                 kv_bucket,
+                self._carried,
+                jnp.asarray(carry),
             )
             self._set_cache(cache)
         else:
@@ -3174,6 +3328,8 @@ class Scheduler:
                 self.decode_chunk_size,
                 kv_bucket,
                 jnp.asarray(snap),
+                self._carried,
+                jnp.asarray(carry),
             )
             self._cache = cache
             self._note_aux(*aux)
@@ -3186,26 +3342,51 @@ class Scheduler:
                 )
         ticket = self._clock.dispatched()
         self._clock.enter("plan")
-        return toks, active, ticket
+        # The chunk's last tokens stay where the next chunk can read them;
+        # a row that sat this chunk out has nothing there any more.
+        self._carried = toks
+        for i, s in enumerate(self._slots):
+            s.on_device = bool(snap[i])
+            if s.on_device:
+                s.unfetched += self.decode_chunk_size
+        lanes = [(i, self._slots[i].request) for i in active]
+        return toks, lanes, ticket
 
-    def _decode_finalize(self, toks, active: list[int], ticket: int) -> None:
+    def _decode_finalize(
+        self, toks, lanes: list, ticket: int, ahead: bool = False
+    ) -> None:
         """Fetch a dispatched decode chunk's tokens and emit them.
 
-        ``active`` is the slot set snapshotted at dispatch: slots admitted
-        after the dispatch (pipelined tick) were not decoded by this chunk
-        and must keep the first token their prefill just wrote into
-        ``_cur_tok`` — hence the masked update rather than a full copy."""
+        ``lanes`` is the snapshot taken at dispatch, each slot with the
+        request it held then: slots admitted after the dispatch
+        (pipelined tick) were not decoded by this chunk, and a slot whose
+        request ended while the chunk was in flight (it stopped on EOS or
+        was cancelled in the chunk before: a full house dispatches a
+        chunk before it has fetched the last) may hold another by now.
+        Such a row's tokens are dropped and counted; what the chunk wrote
+        for it lies beyond the history that was kept, in the slot's own
+        rows, before any later admission's writes on the device.
+        ``ahead``: the chunk was dispatched while the one before it was
+        unfetched (``_tick`` says so of the chunk it kept in flight)."""
         self._clock.enter("wait_device")
         toks_host = np.asarray(toks)  # (chunk, b)
         self._clock.fetched(ticket)
         self._clock.enter("emit")
         self._drain_aux()
-        if active:
-            self._cur_tok[active] = toks_host[-1][active]
+        mine = []
+        for i, req in lanes:
+            slot = self._slots[i]
+            if slot.request is req and req is not None:
+                slot.unfetched -= self.decode_chunk_size
+                mine.append((i, req))
         for row in toks_host:
-            for i in active:
-                if self._slots[i].request is not None:
+            for i, req in mine:
+                if self._slots[i].request is req:
                     self._handle_token(i, int(row[i]))
         self._flush_tokens()
         with self.stats.lock:
             self.stats.decode_chunks += 1
+            self.stats.decode_chunks_ahead += ahead
+            self.stats.decode_tokens_dropped += (
+                (len(lanes) - len(mine)) * len(toks_host)
+            )

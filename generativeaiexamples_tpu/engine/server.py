@@ -722,6 +722,10 @@ async def handle_metrics(request: web.Request) -> web.Response:
         # blocked on its tokens.
         "# TYPE engine_prefill_chunks_ahead_total counter",
         f"engine_prefill_chunks_ahead_total {snap.get('prefill_chunks_ahead', 0)}",
+        "# TYPE engine_decode_chunks_ahead_total counter",
+        f"engine_decode_chunks_ahead_total {snap.get('decode_chunks_ahead', 0)}",
+        "# TYPE engine_decode_tokens_dropped_total counter",
+        f"engine_decode_tokens_dropped_total {snap.get('decode_tokens_dropped', 0)}",
         "# TYPE engine_spec_rounds_total counter",
         f"engine_spec_rounds_total {snap['spec_rounds']}",
         "# TYPE engine_spec_tokens_total counter",

@@ -34,6 +34,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from generativeaiexamples_tpu.engine.decode import carry_tokens
 from generativeaiexamples_tpu.engine.sampler import sample
 from generativeaiexamples_tpu.models import hybrid, llama
 from generativeaiexamples_tpu.ops import moe
@@ -286,12 +287,13 @@ class HybridServing:
         @functools.partial(jax.jit, donate_argnums=(1,), static_argnums=(8, 9))
         def decode_chunk(
             params, cache, tokens, lengths, key, temp, top_p, top_k,
-            n_steps, kv_bucket=None, live=None,
+            n_steps, kv_bucket=None, live=None, carried=None, carry=None,
         ):
             """The signature of ``engine.decode``'s chunk, and a third
             result: the counters summed over the steps.  A row that does
             not decode (``live`` False) writes no latent row and leaves its
             state as it was; its tokens are finite and never emitted."""
+            tokens = carry_tokens(tokens, carried, carry)
             window = min(kv_bucket, max_len) if kv_bucket else max_len
             b = tokens.shape[0]
             counts = (

@@ -1,10 +1,13 @@
 """Phase 1 of tick N+1 is dispatched in tick N, behind the decode chunk
-and before the host blocks on its tokens.
+and before the host blocks on its tokens; and with a full house decode
+chunk N+1 is, too.
 
 The ticks are driven from the test thread (``_run_tick``), one at a time,
 with a spy on the step programs and on the two fetches, at the tiny size
-of both model kinds: a runner decodes in slot 0 while three prompts of
-4, 5 and 4 chunks warm beside it.
+of both model kinds.  The warming cases: a runner decodes in slot 0 while
+three prompts of 4, 5 and 4 chunks warm beside it, with a fifth slot left
+free, so that no decode chunk goes ahead.  The decode cases (the second
+half): four slots, all taken.
 """
 
 import numpy as np
@@ -16,6 +19,7 @@ from generativeaiexamples_tpu.engine.weights import resolve_model_preset
 from generativeaiexamples_tpu.models import hybrid, llama
 
 CHUNK = 8
+STEPS = 4  # of a decode chunk
 LENGTHS = (30, 40, 27)  # 4, 5 and 4 chunks of 8
 
 
@@ -29,16 +33,21 @@ class Driven:
     ("fetch",) for the decode chunk's tokens and ("first", slot) for a
     prompt's first token, each with the tick's number in front."""
 
-    def __init__(self, kind):
+    def __init__(self, kind, max_batch=5, kv_dtype="bfloat16", **options):
         if kind == "llama":
-            cfg = llama.llama_tiny(dtype="float32", max_seq_len=128)
+            cfg = llama.llama_tiny(
+                dtype="float32", max_seq_len=128, kv_dtype=kv_dtype
+            )
         else:
             cfg = hybrid.PRESETS[resolve_model_preset("ling-tiny")]()
         self.s = s = Scheduler(
-            cfg, None, max_batch=4, max_len=128, decode_chunk_size=4,
-            seed=5, prefill_chunk_tokens=CHUNK, prefix_cache="off",
+            cfg, None, max_batch=max_batch, max_len=128, decode_chunk_size=STEPS,
+            seed=5, prefill_chunk_tokens=CHUNK,
+            **{"prefix_cache": "off", **options},
         )
         self.events = []
+        self.chunks = []  # the plain decode chunks' tokens, as dispatched
+        self.buckets = []  # and their kv_buckets
         self.tick = 0
         chunk, decode = s._prefill_suffix, s._decode_dispatch
         fetch, first = s._decode_finalize, s._suffix_finalize
@@ -49,16 +58,26 @@ class Driven:
 
         def spy_decode(active=None):
             self.events.append((self.tick, "decode", tuple(active)))
-            return decode(active)
+            out = decode(active)
+            self.chunks.append(out[0])
+            self.buckets.append(s._tick_kv_bucket)
+            return out
 
-        def spy_fetch(*a):
-            self.events.append((self.tick, "fetch"))
-            return fetch(*a)
+        def spy_fetch(toks, *a, **k):
+            # Which chunk, by the order of dispatch (plain chunks only).
+            n = [i for i, c in enumerate(self.chunks) if c is toks]
+            self.events.append((self.tick, "fetch", *n))
+            return fetch(toks, *a, **k)
 
         def spy_first(req, slot, *rest):
             self.events.append((self.tick, "first", slot))
             return first(req, slot, *rest)
 
+        # The step programs themselves, for their caches' sizes.
+        self.programs = {
+            "decode_chunk": s._decode_chunk, "_prefill_some": s._prefill_some,
+            "_graft_rows": s._graft_rows, "_prefill_suffix": chunk,
+        }
         s._prefill_suffix, s._decode_dispatch = spy_chunk, spy_decode
         s._decode_finalize, s._suffix_finalize = spy_fetch, spy_first
         s._clock.start("plan")
@@ -67,14 +86,27 @@ class Driven:
         self.tick += 1
         self.s._run_tick()
 
-    def submit(self, prompt, n, rid):
+    def submit(self, prompt, n, rid, eos_id=None):
         out, done = [], []
         assert self.s.submit(Request(
             token_ids=list(prompt),
             sampling=SamplingParams(temperature=0.0, max_tokens=n),
-            on_token=out.append, on_done=done.append, id=rid,
+            on_token=out.append, on_done=done.append, id=rid, eos_id=eos_id,
         ))
         return out, done
+
+    def slot_of(self, rid):
+        return next(
+            i for i, sl in enumerate(self.s._slots)
+            if sl.request is not None and sl.request.id == rid
+        )
+
+    def alone(self, prompt, n, rid="alone"):
+        """The greedy stream of one request with the slots to itself."""
+        out, done = self.submit(prompt, n, rid)
+        self.drain()
+        assert done == ["length"] and len(out) == n
+        return out
 
     def drain(self, limit=200):
         """Tick until no slot holds a request and nothing is queued."""
@@ -125,6 +157,8 @@ def fresh(driven):
     with driven.s._cancel_lock:
         driven.s._cancelled.clear()
     driven.events.clear()
+    driven.chunks.clear()
+    driven.buckets.clear()
     return driven
 
 
@@ -146,6 +180,7 @@ def test_next_chunks_go_out_behind_the_decode_chunk_and_before_its_fetch(fresh):
         ev = [e[1:] for e in d.events if e[0] == t]
         kinds = [e[0] for e in ev]
         assert kinds.count("decode") == 1 and kinds.count("fetch") == 1
+        assert d.s._flight is None  # a slot is free: no decode chunk ahead
         dec, fet = kinds.index("decode"), kinds.index("fetch")
         # Behind the decode chunk and before its tokens are fetched: the
         # next chunk of every slot that is still warming, in slot order,
@@ -292,3 +327,275 @@ def test_no_decode_chunk_nothing_ahead(fresh):
     assert after["prefill_chunks_ahead"] == before["prefill_chunks_ahead"]
     d.drain()
     assert done == ["length"]
+
+
+# -- decode chunks ahead: a full house ------------------------------------
+#
+# Four slots, all taken: chunk N+1 is dispatched before chunk N's tokens
+# are fetched, its rows' input tokens never leave the device, and a cold
+# admission's first token reaches the chunk dispatched behind it there.
+
+SHORT = (3, 5, 7, 4, 6, 5)  # under a chunk: batched cold admission
+
+
+@pytest.fixture(scope="module", params=["llama", "hybrid"])
+def house(request):
+    return Driven(request.param, max_batch=4)
+
+
+@pytest.fixture
+def full(house):
+    """Empty slots, no chunk in flight, only this test's events."""
+    house.drain()
+    assert house.s._flight is None
+    with house.s._cancel_lock:
+        house.s._cancelled.clear()
+    house.events.clear()
+    house.chunks.clear()
+    house.buckets.clear()
+    return house
+
+
+def _fill(d, tokens=(40, 40, 40, 40), base=40, prompts=None):
+    """Submit one short prompt a slot and run the tick that admits all
+    four, dispatches two chunks and fetches the first; returns (prompts,
+    outs, dones)."""
+    prompts = prompts or [_prompt(base + i, n) for i, n in enumerate(SHORT[:4])]
+    subs = [d.submit(p, n, f"h{i}") for i, (p, n) in enumerate(zip(prompts, tokens))]
+    d.run_tick()
+    return prompts, [o for o, _ in subs], [x for _, x in subs]
+
+
+def _counters(d):
+    snap = d.s.stats.snapshot()
+    return snap["decode_chunks"], snap["decode_chunks_ahead"], snap["decode_tokens_dropped"]
+
+
+def test_a_full_house_streams_what_each_request_streams_alone(full):
+    """Rows that end at different steps, a queue that refills the slots
+    (cold batches and a chunked prompt), chunks ahead all the while."""
+    d = full
+    before = _counters(d)
+    lengths = SHORT + (27, 4)
+    tokens = (9, 14, 21, 30, 12, 17, 11, 1)
+    prompts = [_prompt(60 + i, n) for i, n in enumerate(lengths)]
+    subs = [d.submit(p, n, f"f{i}") for i, (p, n) in enumerate(zip(prompts, tokens))]
+    d.drain()
+    chunks, ahead, dropped = (b - a for a, b in zip(before, _counters(d)))
+    assert ahead > chunks // 2 and dropped == 0
+    for i, (out, done) in enumerate(subs):
+        assert done == ["length"] and len(out) == tokens[i]
+    for i, (p, n) in enumerate(zip(prompts, tokens)):
+        assert d.alone(p, n) == subs[i][0], i
+
+
+def test_chunk_n_plus_1_goes_out_before_chunk_n_is_fetched(full):
+    d = full
+    _fill(d)
+    t0 = d.tick  # admits all four, dispatches chunks 0 and 1, fetches 0
+    for _ in range(4):
+        d.run_tick()
+    for t in range(t0, d.tick + 1):
+        ev = [e[1:] for e in d.events if e[0] == t and e[1] in ("decode", "fetch")]
+        n = t - t0 + 1  # the chunk this tick sends ahead
+        want = [("decode", (0, 1, 2, 3)), ("fetch", n - 1)]
+        assert ev == ([("decode", (0, 1, 2, 3))] if t == t0 else []) + want
+        # The record describes the chunk fetched: chunk 0 had not gone
+        # ahead; the window is the one that chunk was dispatched with.
+        rec = d.s.tick_records(d.tick - t + 1)[0]
+        assert rec["decode_ahead"] == (t > t0)
+        assert rec["kv_bucket"] == d.buckets[n - 1], (t, d.buckets)
+    assert d.s._flight is not None
+    assert len(set(d.buckets)) > 1  # the window grew meanwhile
+    # Four tokens a chunk and the first: nothing waited for the host.
+    assert sorted(len(sl.history) for sl in d.s._slots) == sorted(
+        n + 1 + STEPS * (d.tick - t0 + 1) for n in SHORT[:4]
+    )
+
+
+def test_a_chunk_goes_ahead_only_with_every_slot_taken(full):
+    """Three of four slots decode: each tick fetches the chunk it sent."""
+    d = full
+    subs = [d.submit(_prompt(70 + i, n), 14, f"t{i}") for i, n in enumerate(SHORT[:3])]
+    before = _counters(d)
+    for _ in range(4):
+        d.run_tick()
+        assert d.s._flight is None
+        ev = [e[1:] for e in d.events if e[0] == d.tick and e[1] in ("decode", "fetch")]
+        if ev:
+            assert [e[0] for e in ev] == ["decode", "fetch"]
+            assert ev[1][1] == len(d.chunks) - 1
+        assert d.s.tick_records(1)[0]["decode_ahead"] == 0
+    d.drain()
+    chunks, ahead, dropped = (b - a for a, b in zip(before, _counters(d)))
+    assert chunks >= 4 and ahead == 0 and dropped == 0
+    assert all(done == ["length"] and len(out) == 14 for out, done in subs)
+
+
+def test_a_row_that_ends_by_length_is_absent_from_the_next_chunk(full):
+    d = full
+    before = _counters(d)
+    # Row 1 ends after its first token and ten more: in its third chunk.
+    prompts, outs, dones = _fill(d, tokens=(40, 11, 40, 40))
+    waiting, waited = d.submit(_prompt(80, 5), 6, "next")
+    row = d.slot_of("h1")
+    while not dones[1]:
+        d.run_tick()
+    with_row = [e for e in d.events if e[1] == "decode" and row in e[2]]
+    assert len(with_row) == 3 == -(-(11 - 1) // STEPS)
+    # The chunk dispatched before its last tokens were fetched left it out.
+    last = max(e[0] for e in with_row)
+    assert [e[2] for e in d.events if e[0] == last + 1 and e[1] == "decode"] == [
+        tuple(i for i in range(4) if i != row)
+    ]
+    assert _counters(d)[2] == before[2]  # no lane computed a token for nobody
+    for i in (0, 2, 3):
+        d.s.cancel(f"h{i}")
+    d.drain()
+    assert waited == ["length"] and outs[1] == d.alone(prompts[1], 11)
+
+
+def _stops_inside(d):
+    """A prompt, its greedy stream alone, and a step k of it (past the
+    first decode chunk) whose token occurs there first: as ``eos_id`` it
+    stops the request at step k."""
+    for seed in range(90, 120):
+        p = _prompt(seed, 6)
+        out = d.alone(p, 14, f"probe{seed}")
+        for k in range(STEPS + 2, 12):
+            if out[k] not in out[:k]:
+                return p, out, k
+    raise AssertionError("no prompt's stream has a token that is new at a late step")
+
+
+@pytest.mark.parametrize("how", ["eos", "cancel"])
+def test_a_row_that_stops_inside_n_has_n_plus_1_dropped(full, how):
+    d = full
+    prompt, alone, k = _stops_inside(d)
+    d.events.clear()
+    d.chunks.clear()
+    d.buckets.clear()
+    before = _counters(d)
+    others = [_prompt(85 + i, n) for i, n in enumerate(SHORT[1:4])]
+    subs = [d.submit(p, 40, f"o{i}") for i, p in enumerate(others)]
+    out, done = d.submit(prompt, 40, "stopper", eos_id=alone[k] if how == "eos" else None)
+    heir_prompt = _prompt(99, 6)
+    heir, heir_done = d.submit(heir_prompt, 9, "heir")
+    d.run_tick()
+    slot = d.slot_of("stopper")
+    if how == "eos":
+        while not done:
+            d.run_tick()
+        assert done == ["stop"] and out == alone[:k]
+    else:
+        d.run_tick()
+        d.s.cancel("stopper")
+        d.run_tick()
+        assert done == ["cancelled"] and out == alone[: len(out)]
+    stopped = d.tick
+    # The chunk in flight when it stopped had the row live: all of that
+    # row's tokens are dropped, and the slot's next request sees none.
+    in_flight = [e for e in d.events if e[0] == stopped and e[1] == "decode"][-1]
+    assert slot in in_flight[2]
+    while not heir_done:
+        d.run_tick()
+    assert _counters(d)[2] - before[2] == STEPS
+    assert d.s._slots[slot].request is None or d.s._slots[slot].request.id != "stopper"
+    for i in range(3):
+        d.s.cancel(f"o{i}")
+    d.drain()
+    assert heir_done == ["length"] and heir == d.alone(heir_prompt, 9)
+    assert [e[2] for e in d.events if e[1] == "first"] == []  # cold batches all
+
+
+def test_a_cold_admission_decodes_in_the_chunk_dispatched_behind_it(full):
+    """Its first token reaches that chunk on the device: the second
+    token follows one chunk after the first, as with a free slot."""
+    d = full
+    _, outs, dones = _fill(d, tokens=(40, 6, 40, 40))
+    late, late_done = d.submit(_prompt(81, 5), 20, "late")
+    row = d.slot_of("h1")
+    while not dones[1]:
+        d.run_tick()
+    assert late == []
+    d.run_tick()  # the freed slot is taken: prefill, graft, chunk ahead
+    assert d.slot_of("late") == row and len(late) == 1
+    assert row in [e for e in d.events if e[0] == d.tick and e[1] == "decode"][-1][2]
+    d.run_tick()
+    assert len(late) == 1 + STEPS
+    for i in (0, 2, 3):
+        d.s.cancel(f"h{i}")
+    d.drain()
+    assert late_done == ["length"] and late == d.alone(_prompt(81, 5), 20)
+
+
+def test_a_tick_that_raises_with_a_chunk_in_flight_recovers(full):
+    d = full
+    prompts, outs, dones = _fill(d)
+    d.run_tick()
+    assert d.s._flight is not None
+
+    def boom(*a, **k):
+        raise RuntimeError("injected")
+
+    spy, d.s._decode_finalize = d.s._decode_finalize, boom
+    d.run_tick()
+    d.s._decode_finalize = spy
+    assert dones == [["error"]] * 4 and d.s._flight is None
+    assert all(sl.request is None and not sl.unfetched for sl in d.s._slots)
+    # The same requests again: the streams they have alone.
+    _, again, dones = _fill(d, tokens=(9, 9, 9, 9), prompts=prompts)
+    d.drain()
+    assert dones == [["length"]] * 4
+    for p, out in zip(prompts, again):
+        assert out == d.alone(p, 9)
+
+
+def test_an_ahead_tick_runs_no_program_a_plain_tick_has_not(full):
+    """Three rows at a time reach every attention window and both batch
+    buckets; a full house of the same requests then adds no entry to any
+    step program's cache: chunks go ahead inside a measured window whose
+    warm-up never filled the house."""
+    d = full
+    programs = d.programs
+    lengths, tokens = (3, 5, 7, 27, 6), (100, 30, 12, 9, 6)
+    prompts = [_prompt(130 + i, n) for i, n in enumerate(lengths)]
+
+    def serve(width):
+        for lo in range(0, len(prompts), width):
+            for p, n in zip(prompts[lo : lo + width], tokens[lo : lo + width]):
+                d.submit(p, n, "w")
+            d.run_tick()
+        d.drain()
+
+    serve(3)
+    serve(3)  # the second pass finds ``_carried`` a program's result
+    sizes = {k: f._cache_size() for k, f in programs.items()}
+    before = _counters(d)
+    serve(5)
+    assert _counters(d)[1] > before[1]
+    assert {k: f._cache_size() for k, f in programs.items()} == sizes
+
+
+@pytest.mark.parametrize("options", [
+    dict(spec_mode="ngram", gamma=2),
+    dict(kv_layout="paged", kv_page_size=16, kv_dtype="int8"),
+], ids=["speculative", "paged"])
+def test_a_speculative_scheduler_and_the_paged_layout_send_nothing_ahead(options):
+    d = Driven("llama", max_batch=4, **options)
+    lengths, tokens = SHORT, (9, 14, 21, 30, 12, 17)
+    subs = [
+        d.submit(_prompt(60 + i, n), t, f"s{i}")
+        for i, (n, t) in enumerate(zip(lengths, tokens))
+    ]
+    full_house = 0
+    for _ in range(200):
+        d.run_tick()
+        assert d.s._flight is None
+        full_house += all(sl.request is not None for sl in d.s._slots)
+        if all(done for _, done in subs):
+            break
+    assert full_house >= 3
+    chunks, ahead, dropped = _counters(d)
+    assert ahead == 0 and dropped == 0
+    assert [len(out) for out, _ in subs] == list(tokens)

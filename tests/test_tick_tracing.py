@@ -176,8 +176,10 @@ def test_decode_kv_counters_count_the_snapshots_rows_in_blocks():
     s._slots[3].cached = True
     seen = {}
 
-    def chunk(params, cache, tokens, lengths, key, temp, top_p, top_k, n, kv_bucket, live):
-        seen.update(lengths=np.asarray(lengths), live=np.asarray(live), kv_bucket=kv_bucket)
+    def chunk(params, cache, tokens, lengths, key, temp, top_p, top_k, n, kv_bucket, live,
+              carried, carry):
+        seen.update(lengths=np.asarray(lengths), live=np.asarray(live), kv_bucket=kv_bucket,
+                    carry=np.asarray(carry))
         return cache, np.zeros((n, 8), np.int32)
 
     s._decode_chunk = chunk
@@ -185,6 +187,7 @@ def test_decode_kv_counters_count_the_snapshots_rows_in_blocks():
     s._decode_dispatch([0, 2, 5])
     d = _delta(s.stats.snapshot(), before, ["decode_kv_tokens_read", "decode_kv_tokens_dense"])
     assert seen["live"].tolist() == [True, False, True, False, False, True, False, False]
+    assert not seen["carry"].any()  # every row's newest token is the host's
     assert seen["lengths"].tolist() == [300, 1023, 256, 1023, 1023, 40, 1023, 1023]
     assert seen["kv_bucket"] == bucket_size(300 + 4 + 1, maximum=1024) == 512
     # Blocks of 512 in a cache of 1,024: each of the three rows reads one.
