@@ -3,7 +3,7 @@
 
     python chip_smoke.py [--seed N]          # one chip (what the driver runs)
     python chip_smoke.py --four-chips        # the two multi-chip paths only
-    python chip_smoke.py --hybrid [--control w8a8_mlp]   # phase 5 only
+    python chip_smoke.py --hybrid [--model ling|mellum] [--control NAME]   # phase 5 only
 
 Drives the system through the entry points a user calls, at llama3-8b's
 published widths with random int8 weights from ``--seed``:
@@ -25,15 +25,20 @@ published widths with random int8 weights from ``--seed``:
    the paged kernel's walk over up to eight pages is held to its XLA
    twin at tolerance, and streams of two to four pages run beside it.
 
-5. ``hybrid`` (``--hybrid``; not part of the default run) — the layer-kind
-   model (``models/hybrid.py``: KDA beside MLA, a share of the experts)
-   at ling-3.0-flash-vl-l7e128's published widths, in process on the
-   step programs' own calls: logits of a two-chunk prefill and then 64
+5. ``hybrid`` (``--hybrid``; not part of the default run) — a layer-kind
+   model (``models/hybrid.py``) at its published widths, in process on
+   the step programs' own calls: logits of a chunked prefill and then 64
    positions decoded through the state, against the plain float32
-   reference's full forward over the same tokens.  ``--control
-   w8a8_mlp`` computes the reference's MLP products as W8A8 matmuls, the
-   nearest precision below the configuration's, and has to fail the
-   comparison.
+   reference's full forward over the same tokens.  ``--model ling`` (the
+   default): ling-3.0-flash-vl-l7e128, KDA beside MLA and a share of the
+   experts, a prompt of two chunks.  ``--model mellum``:
+   mellum2-12b-a2.5b-l12, window layers beside full ones, a prompt of
+   1,300 tokens, so that the rings have wrapped and YaRN has passed a
+   chunk.  A ``--control`` has to fail the comparison: ``w8a8_mlp``
+   computes the reference's MLP products as W8A8 matmuls, the nearest
+   precision below the configuration's; for ``mellum`` also ``no_window``
+   (the program's window layers attend to everything) and ``no_yarn``
+   (its full layers take the plain frequencies and factor 1).
 
 The parent imports no JAX: the chip belongs to one process at a time, so
 each phase is a child (or the pair engine + chain server) that has
@@ -96,6 +101,10 @@ class Sizes:
     # Two prefill chunks (a whole one and a padded one), then decoding.
     hybrid_chunks: tuple = (256, 128)
     hybrid_decode: int = 64
+    # ``--model mellum``: chunks of ``hybrid_chunks[0]`` over a prompt
+    # longer than the window (the last one padded), then decoding.
+    mellum_model: str = "mellum2-12b-a2.5b-l12"
+    mellum_prompt: int = 1300
     kv_heads: int = 8  # the preset's own; the tiny rehearsal needs 4 to split
     start_timeout_s: float = 600.0
     request_timeout_s: float = 600.0
@@ -121,6 +130,8 @@ TINY = Sizes(
     hybrid_model="ling-tiny",
     hybrid_chunks=(32, 16),
     hybrid_decode=8,
+    mellum_model="mellum-tiny",
+    mellum_prompt=75,
     kv_heads=4,
     start_timeout_s=240.0,
     request_timeout_s=240.0,
@@ -1231,26 +1242,35 @@ def child_four(seed: int, sizes: Sizes) -> None:
 
 # Limits of the hybrid phase's comparison: quantiles over positions of
 # each position's error as a share of its reference logits' root mean
-# square.  PERF.md section 6 (PR 27) has the readings they lie between:
-# the served precision (bf16 weights and activations, float32 state)
-# below them, the control (the reference's MLP products as W8A8 matmuls)
-# above the lowest tenth and the median.  The ninth tenth reads expert
-# flips in sound runs and control alike; its limit is there for a fault
-# in some of the positions, such as state lost between chunks or steps.
-HYBRID_QUANTILE_LIMITS = {"p10": 0.025, "p50": 0.1, "p90": 0.4}
-HYBRID_LIMITS = {
-    f"{part}_{q}_share": limit
-    for part in ("prefill", "decode") for q, limit in HYBRID_QUANTILE_LIMITS.items()
+# square.  PERF.md section 6 (PR 27 for ling, PR 31 for mellum) has the
+# readings they lie between: the served precision (bf16 weights and
+# activations, float32 recurrent state) below them, the controls above
+# at least one.  The ninth tenth reads expert flips in sound runs and in
+# the precision control alike; its limit is there for a fault in some of
+# the positions, such as state lost between chunks or steps.
+HYBRID_QUANTILE_LIMITS = {
+    "ling": {"p10": 0.025, "p50": 0.1, "p90": 0.4},
+    "mellum": {"p10": 0.0175, "p50": 0.06, "p90": 0.15},
 }
+HYBRID_CONTROLS = {"ling": ("w8a8_mlp",), "mellum": ("w8a8_mlp", "no_window", "no_yarn")}
 
 
-def child_hybrid(seed: int, sizes: Sizes, control: str = "") -> None:
+def hybrid_limits(model: str) -> dict:
+    return {
+        f"{part}_{q}_share": limit
+        for part in ("prefill", "decode") for q, limit in HYBRID_QUANTILE_LIMITS[model].items()
+    }
+
+
+def child_hybrid(seed: int, sizes: Sizes, control: str = "", model: str = "ling") -> None:
+    import importlib
+
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from generativeaiexamples_tpu.engine.serving_models import serving_model
-    from generativeaiexamples_tpu.models import hybrid, hybrid_reference
+    from generativeaiexamples_tpu.models import hybrid
     from generativeaiexamples_tpu.utils.jax_runtime import (
         device_report,
         enable_compile_cache,
@@ -1259,33 +1279,46 @@ def child_hybrid(seed: int, sizes: Sizes, control: str = "") -> None:
 
     enable_compile_cache()
     t0 = time.monotonic()
-    cfg = hybrid.PRESETS[sizes.hybrid_model]()
-    model = serving_model(cfg, None, sizes.max_len)
-    params = model.prepare_params(None, quantize=False, matmul_kernel="xla", seed=seed)
-    state = model.init_state(2, sizes.max_len)
     first, second = sizes.hybrid_chunks
-    n_prompt, n_all = first + second - 7, first + second - 7 + sizes.hybrid_decode
+    if model == "ling":
+        preset, n_prompt = sizes.hybrid_model, first + second - 7
+        reference = importlib.import_module(f"{PACKAGE}.models.hybrid_reference")
+    else:
+        preset, n_prompt = sizes.mellum_model, sizes.mellum_prompt
+        reference = importlib.import_module(f"{PACKAGE}.models.mellum_reference")
+    n_all = n_prompt + sizes.hybrid_decode
+    cfg = hybrid.PRESETS[preset]()  # what the reference computes
+    served = cfg  # what the program computes: a control may differ
+    if control == "no_window":
+        served = dataclasses.replace(cfg, sliding_window=sizes.max_len)
+    elif control == "no_yarn":
+        served = dataclasses.replace(cfg, rope_full=cfg.rope_window)
+    limits = hybrid_limits(model)
+    server = serving_model(served, None, sizes.max_len)
+    params = server.prepare_params(None, quantize=False, matmul_kernel="xla", seed=seed)
+    state = server.init_state(2, sizes.max_len)
     tokens = np.random.RandomState(seed).randint(1, cfg.vocab_size, size=n_all).astype(np.int32)
 
     @functools.partial(jax.jit, donate_argnums=(1,), static_argnums=(5,))
     def prefill(params, cache, toks, start, n, kv_bucket):
-        cache, hidden, _ = model.prefill_row(params, cache, toks, start, n, jnp.int32(1), kv_bucket)
-        return cache, model.logits(params, hidden)[0]
+        cache, hidden, _ = server.prefill_row(params, cache, toks, start, n, jnp.int32(1), kv_bucket)
+        return cache, server.logits(params, hidden)[0]
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def step(params, cache, tok, pos):
         """One teacher-forced decode step of slot 1 (slot 0 does not
         decode), returning its logits: decode_chunk's own body."""
         hidden, cache, _ = hybrid.forward(
-            params, cfg, jnp.stack([tok, tok])[:, None], jnp.stack([pos, pos]),
+            params, served, jnp.stack([tok, tok])[:, None], jnp.stack([pos, pos]),
             jnp.asarray([0, 1], jnp.int32), cache, window=sizes.max_len,
         )
-        return cache, model.logits(params, hidden)[1, 0]
+        return cache, server.logits(params, hidden)[1, 0]
 
     got = []
-    padded = np.zeros((1, first), np.int32)
-    padded[0, : n_prompt - first] = tokens[first:n_prompt]
-    for toks, start, n in ((tokens[None, :first], 0, first), (padded, first, n_prompt - first)):
+    for start in range(0, n_prompt, first):
+        n = min(first, n_prompt - start)
+        toks = np.zeros((1, first), np.int32)
+        toks[0, :n] = tokens[start : start + n]
         state, lg = prefill(params, state, jnp.asarray(toks), jnp.int32(start), jnp.int32(n), sizes.max_len)
         got.append(np.asarray(lg[:n], np.float32))
     for pos in range(n_prompt, n_all):
@@ -1312,11 +1345,11 @@ def child_hybrid(seed: int, sizes: Sizes, control: str = "") -> None:
             act = jax.nn.silu(gu[:, :half]) * gu[:, half:]
             return int8(act, -1) @ int8(w_down.astype(jnp.float32), 0)
 
-        hybrid_reference._swiglu = w8a8_swiglu  # this process runs nothing else
+        reference._swiglu = w8a8_swiglu  # this process runs nothing else
         jax.clear_caches()  # a layer traced before this would keep the plain one
-    x = hybrid_reference.hidden_states(params, cfg, tokens)
+    x = reference.hidden_states(params, cfg, tokens)
     want = np.concatenate([
-        np.asarray(hybrid_reference._head(
+        np.asarray(reference._head(
             x[i : i + 128], params["final_norm"], params["lm_head"], float(cfg.norm_eps)))
         for i in range(0, n_all, 128)
     ])
@@ -1335,33 +1368,37 @@ def child_hybrid(seed: int, sizes: Sizes, control: str = "") -> None:
     readings = {
         f"{part}_{q}_share": value
         for part, span in (("prefill", (0, n_prompt)), ("decode", (n_prompt, n_all)))
-        for q, value in zip(HYBRID_QUANTILE_LIMITS, quantiles(*span))
+        for q, value in zip(HYBRID_QUANTILE_LIMITS[model], quantiles(*span))
     }
     worst = float(np.abs(got - want).max() / np.abs(want).max())
     agree = int((got.argmax(-1) == want.argmax(-1)).sum())
-    failed = {k: v for k, v in readings.items() if not v <= HYBRID_LIMITS[k]}
+    failed = {k: v for k, v in readings.items() if not v <= limits[k]}
     report = runtime_report()
     emit(
         {
-            "phase": "hybrid", "model": sizes.hybrid_model, "control": control or None,
+            "phase": "hybrid", "model": preset, "control": control or None,
             "positions": {"prefill": n_prompt, "decode": sizes.hybrid_decode},
-            **readings, "limits": HYBRID_LIMITS, "within_limits": not failed,
+            **readings, "limits": limits, "within_limits": not failed,
             "first_chunk_p50": quantiles(0, first)[1],
             "worst_abs_gap_share": worst, "argmax_agree": agree, "of": n_all,
             "served_s": served_s, "reference_s": time.monotonic() - t0 - served_s,
             "compile": report["compile"], "peak_bytes_in_use": report["peak_bytes_in_use"],
-            "kernel_paths": _taken("moe_experts"), "device": device_report(),
+            "kernel_paths": {**_taken("moe_experts"), **_taken("attn_")},
+            "device": device_report(),
         }
     )
     if control and not failed:
         raise SmokeFailure(f"the control {control!r} stayed inside every limit: {readings}")
     if not control and failed:
-        raise SmokeFailure(f"logits left the reference: {failed} (limits {HYBRID_LIMITS})")
+        raise SmokeFailure(f"logits left the reference: {failed} (limits {limits})")
 
 
 CHILDREN = {
-    "hybrid": child_hybrid,
-    "hybrid_w8a8_mlp": functools.partial(child_hybrid, control="w8a8_mlp"),
+    **{
+        f"hybrid_{model}" + (f"_{control}" if control else ""):
+            functools.partial(child_hybrid, control=control, model=model)
+        for model, controls in HYBRID_CONTROLS.items() for control in ("", *controls)
+    },
     "device": child_device,
     "retrieval": child_retrieval,
     "optin": child_optin,
@@ -1374,7 +1411,7 @@ def run(
     sizes: Sizes = FULL,
     expect: str = "tpu",
     four_chips: bool = False,
-    hybrid: str | None = None,
+    hybrid: tuple | None = None,
 ) -> dict:
     """Run the phases; returns the device for the last line.  ``sizes``
     and ``expect`` exist for the CPU rehearsal in the tests — the command
@@ -1387,7 +1424,11 @@ def run(
     if four_chips:
         return child_phase("four", seed, sizes, expect, 3000, count=4)
     if hybrid is not None:
-        return child_phase("hybrid" + ("_" + hybrid if hybrid else ""), seed, sizes, expect, 3000)
+        model, control = hybrid
+        if control and control not in HYBRID_CONTROLS[model]:
+            raise SmokeFailure(f"--model {model} has no control {control!r}: {HYBRID_CONTROLS[model]}")
+        name = f"hybrid_{model}" + (f"_{control}" if control else "")
+        return child_phase(name, seed, sizes, expect, 3000)
     device = phase_device(seed, sizes, expect)
     phase_serve(seed, sizes, expect)
     child_phase("retrieval", seed, sizes, expect, 900)
@@ -1410,8 +1451,12 @@ def main(argv=None) -> int:
         help="run only the layer-kind model's logits against its reference",
     )
     parser.add_argument(
-        "--control", choices=["w8a8_mlp"], default="",
-        help="with --hybrid: run the comparison's control, which has to fail it",
+        "--model", choices=sorted(HYBRID_CONTROLS), default="ling",
+        help="with --hybrid: which layer-kind model",
+    )
+    parser.add_argument(
+        "--control", choices=sorted({c for cs in HYBRID_CONTROLS.values() for c in cs}), default="",
+        help="with --hybrid: run one of the comparison's controls, which has to fail it",
     )
     parser.add_argument("--child", choices=sorted(CHILDREN), help=argparse.SUPPRESS)
     parser.add_argument("--sizes", help=argparse.SUPPRESS)
@@ -1427,7 +1472,7 @@ def main(argv=None) -> int:
     try:
         device = run(
             args.seed, four_chips=args.four_chips,
-            hybrid=args.control if args.hybrid else None,
+            hybrid=(args.model, args.control) if args.hybrid else None,
         )
     except SmokeFailure as e:
         print(f"chip_smoke: FAILED: {e}", file=sys.stderr)

@@ -305,6 +305,12 @@ class Stats:
         # Counters the model's step programs return beside their tokens
         # (serving_models: ``counter_names``); empty for a model with none.
         self.model_counters: dict[str, int] = {}
+        # Bytes of the slots' state by kind (gauges, fixed when the state
+        # is made): rows that grow with the tokens, and window layers'
+        # rings, which do not grow with ``max_len``.  Zero for a model
+        # that does not say (``serving_models``: ``state_bytes``).
+        self.state_bytes_full = 0
+        self.state_bytes_window = 0
         # Cross-request shared-prefix cache hits (content match through
         # the radix index; session matches count under prefix_hits) and
         # chunked-prefill chunk dispatches.  prefix_tokens_reused pools
@@ -473,6 +479,8 @@ class Stats:
                 "state_snapshots_restored": self.state_snapshots_restored,
                 "state_snapshots_evicted": self.state_snapshots_evicted,
                 "state_snapshot_bytes": self.state_snapshot_bytes,
+                "state_bytes_full": self.state_bytes_full,
+                "state_bytes_window": self.state_bytes_window,
                 **self.model_counters,
                 "shared_prefix_hits": self.shared_prefix_hits,
                 "prefill_chunks": self.prefill_chunks,
@@ -818,6 +826,10 @@ class Scheduler:
                 STATE_SNAPSHOT_BUDGET if prefill_chunk_tokens else 0,
             )
         self.stats.model_counters = dict.fromkeys(model.counter_names, 0)
+        if hasattr(model, "state_bytes"):
+            by_kind = model.state_bytes(max_batch)
+            self.stats.state_bytes_full = by_kind["full"]
+            self.stats.state_bytes_window = by_kind["window"]
         # Counters the step programs return beside their tokens, not yet
         # fetched: drained once ready, after a token fetch, so that they
         # cost no synchronisation of their own.

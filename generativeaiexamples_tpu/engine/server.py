@@ -803,17 +803,19 @@ async def handle_metrics(request: web.Request) -> web.Response:
         ("engine_state_snapshots_restored_total", "state_snapshots_restored", "d"),
         ("engine_state_snapshots_evicted_total", "state_snapshots_evicted", "d"),
         # What the model's step programs count (an expert model's routing:
-        # ops.moe.COUNTERS); none for a model that returns none.
+        # ops.moe.COUNTERS; the rows its attention layers read, by kind:
+        # models.hybrid.ATTN_COUNTERS); none for a model that returns none.
         *(
             (f"engine_{key}_total", key, "d")
             for key in sorted(snap)
-            if key.startswith("moe_")
+            if key.startswith(("moe_", "attn_rows_"))
         ),
     ):
         lines.append(f"# TYPE {name} counter")
         lines.append(f"{name} {format(snap.get(key, 0), fmt)}")
-    lines.append("# TYPE engine_state_snapshot_bytes gauge")
-    lines.append(f"engine_state_snapshot_bytes {snap.get('state_snapshot_bytes', 0)}")
+    for key in ("state_snapshot_bytes", "state_bytes_full", "state_bytes_window"):
+        lines.append(f"# TYPE engine_{key} gauge")
+        lines.append(f"engine_{key} {snap.get(key, 0)}")
     # Which serving matmul path is live (info-style gauge: every known
     # value exported, the active one carrying 1) — deployments can alert
     # on the fused kernel silently falling back to XLA.  From zero:

@@ -150,36 +150,52 @@ class LlamaServing:
 
 class HybridServing:
     """``models/hybrid.py`` behind the same calls.  The state is a tuple
-    of one dict a layer (``hybrid.init_state``), slot axis first."""
+    of one dict a layer (``hybrid.init_state``), slot axis first.  Its
+    leaves are of two sorts (``hybrid.ROW_LEAVES``): rows, one a position
+    (latent rows, a full layer's K/V), which a graft copies up to any
+    token; and state as of the last token (a KDA layer's ``S`` and
+    ``conv``, a window layer's ring), which a prefix hit takes from a
+    snapshot saved at a prefill-chunk boundary."""
 
     cut_anywhere = False
-    counter_names = tuple(f"moe_{n}" for n in moe.COUNTERS)
 
     def __init__(self, cfg: hybrid.HybridConfig, mesh, max_len: int) -> None:
         self.cfg, self.mesh, self.max_len = cfg, mesh, max_len
-        self.snapshot_bytes = cfg.snapshot_bytes()
+        self.snapshot_bytes = cfg.snapshot_bytes(max_len)
+        # ``forward``'s counters; the rows its attention layers read are
+        # counted apart for decode steps and for prefill chunks.
+        self.counter_names = tuple(f"moe_{n}" for n in moe.COUNTERS)
+        if cfg.has_attn_counters:
+            self.counter_names += tuple(
+                f"attn_rows_{n}_{phase}"
+                for phase in ("decode", "prefill") for n in hybrid.ATTN_COUNTERS
+            )
 
     def check_supported(
         self, *, kv_layout="contiguous", draft_cfg=None, spec_mode=None, **_
     ) -> None:
-        """What is not served for a model with recurrent state, refused
-        with the reason."""
+        """What is not served for a model whose state cannot be cut at a
+        token, refused with the reason."""
         if draft_cfg is not None or spec_mode is not None:
             raise ValueError(
                 "speculative decoding is not served for a model with "
-                "recurrent state: a rejected draft would need the state "
-                "rolled back, and no step keeps the state it started from"
+                "recurrent state or a window ring: a rejected draft would "
+                "need the state rolled back, and no step keeps the state it "
+                "started from (a ring has overwritten the rows it would "
+                "return to)"
             )
         if kv_layout != "contiguous":
             raise ValueError(
                 "the paged layout is not served for this model: its pages "
                 "hold K/V rows of one shape, not latent rows beside a "
-                "fixed recurrent state"
+                "fixed recurrent state, nor a window layer's ring whose "
+                "pages would be released behind the window"
             )
         if self.mesh is not None and self.mesh.size > 1:
             raise ValueError(
-                "one device holds this model's share: the exchange between "
-                "expert shares is not implemented"
+                "one device holds this model's share: neither the exchange "
+                "between expert shares nor a sharding of the layer kinds' "
+                "state is implemented"
             )
         self.cfg.state_dtype  # refuses int8 state
 
@@ -188,7 +204,7 @@ class HybridServing:
             raise ValueError(
                 "int8 weights are not served for this model: "
                 "ops.quant.QUANT_TARGETS covers neither the experts nor the "
-                "KDA and MLA projections"
+                "KDA, MLA and fused GQA projections of models/hybrid.py"
             )
         if params is None:
             key = jax.random.PRNGKey(seed)
@@ -200,20 +216,40 @@ class HybridServing:
     def init_state(self, batch: int, max_len: int):
         return hybrid.init_state(self.cfg, batch, max_len)
 
+    def state_bytes(self, batch: int) -> dict:
+        """Bytes of ``batch`` slots' state by kind (``hybrid.state_bytes``)."""
+        return hybrid.state_bytes(self.cfg, batch, self.max_len)
+
+    def _aux(self, counters, decode: bool):
+        """``forward``'s counters under ``counter_names``: the attention
+        rows go to the decode or to the prefill entries."""
+        if not self.cfg.has_attn_counters:
+            return counters
+        n = len(moe.COUNTERS)
+        rows, none = counters[n:], jnp.zeros_like(counters[n:])
+        return jnp.concatenate(
+            [counters[:n], *((rows, none) if decode else (none, rows))]
+        )
+
     def prefill_cold(self, params, tokens, lengths):
         b, s = tokens.shape
-        return hybrid.forward(
+        hidden, state, counters = hybrid.forward(
             params, self.cfg, tokens, jnp.zeros((b,), jnp.int32), lengths,
             hybrid.init_state(self.cfg, b, s), window=s, mesh=self.mesh,
         )
+        return hidden, state, self._aux(counters, decode=False)
 
     def graft_rows(self, big, small, rows, slots):
+        """Rows of a cold batch into their slots: a leaf of rows (a ring
+        too, which a cold batch fills from row 0) up to the batch's
+        length, the rest whole."""
+        rows_of = hybrid.ROW_LEAVES + hybrid.RING_LEAVES
         out = []
         for bg, sm in zip(big, small):
             layer = {}
             for name, leaf in bg.items():
                 picked = jnp.take(sm[name], rows, axis=0)
-                if name == "latent":
+                if name in rows_of:
                     layer[name] = leaf.at[slots, : picked.shape[1]].set(picked)
                 else:
                     layer[name] = leaf.at[slots].set(picked)
@@ -224,15 +260,17 @@ class HybridServing:
         def take(leaf):
             return jax.lax.dynamic_slice_in_dim(leaf, slot, 1, axis=0)
 
+        rows_of = hybrid.ROW_LEAVES + hybrid.RING_LEAVES
         row = jax.tree.map(take, cache)
         # A prompt that starts here starts from nothing, whatever the
-        # slot's last occupant left (K/V rows can be stale; a state cannot).
+        # slot's last occupant left (rows can be stale, and a ring's are
+        # masked by position; a recurrent state cannot be).
         row = tuple(
-            {n: (leaf if n == "latent" else jnp.where(start == 0, 0, leaf))
+            {n: (leaf if n in rows_of else jnp.where(start == 0, 0, leaf))
              for n, leaf in layer.items()}
             for layer in row
         )
-        hidden, row, aux = hybrid.forward(
+        hidden, row, counters = hybrid.forward(
             params, self.cfg, tokens, jnp.reshape(start, (1,)),
             jnp.reshape(suffix_len, (1,)), row, window=kv_bucket, mesh=self.mesh,
         )
@@ -241,35 +279,42 @@ class HybridServing:
                 lambda bg, r: jax.lax.dynamic_update_slice_in_dim(bg, r, slot, axis=0),
                 cache, row,
             )
-        return cache, hidden, aux
+        return cache, hidden, self._aux(counters, decode=False)
 
     def graft_prefix(self, cache, src, dst, n: int):
-        """The first ``n`` latent rows; the recurrent state comes from a
-        snapshot (``restore_state``), since the source's has moved on."""
+        """The first ``n`` rows of what holds a row a position; what exists
+        only as of the last token comes from a snapshot
+        (``restore_state``), since the source's has moved on."""
         out = []
         for layer in cache:
-            if "latent" in layer:
-                lat = layer["latent"]
+            grafted = dict(layer)
+            for name in (n for n in layer if n in hybrid.ROW_LEAVES):
+                leaf = layer[name]
                 rows = jax.lax.dynamic_slice(
-                    lat, (src, 0, 0), (1, min(n, lat.shape[1]), lat.shape[2])
+                    leaf, (src, 0, 0), (1, min(n, leaf.shape[1]), leaf.shape[2])
                 )
-                layer = {"latent": jax.lax.dynamic_update_slice(lat, rows, (dst, 0, 0))}
-            out.append(layer)
+                grafted[name] = jax.lax.dynamic_update_slice(leaf, rows, (dst, 0, 0))
+            out.append(grafted)
         return tuple(out)
 
+    @staticmethod
+    def _as_of_last_token(layer) -> bool:
+        return not any(n in hybrid.ROW_LEAVES for n in layer)
+
     def save_state(self, cache, slot):
-        """One slot's recurrent state, a copy a KDA layer."""
+        """One slot's state as of its last token: a copy of every KDA
+        layer's state and every window layer's ring."""
         return tuple(
             {n: jax.lax.dynamic_index_in_dim(leaf, slot, 0, keepdims=False)
              for n, leaf in layer.items()}
-            for layer in cache if "latent" not in layer
+            for layer in cache if self._as_of_last_token(layer)
         )
 
     def restore_state(self, cache, slot, snap):
         snaps = iter(snap)
         out = []
         for layer in cache:
-            if "latent" not in layer:
+            if self._as_of_last_token(layer):
                 saved = next(snaps)
                 layer = {
                     n: jax.lax.dynamic_update_index_in_dim(leaf, saved[n], slot, 0)
@@ -282,7 +327,7 @@ class HybridServing:
         return hybrid.logits(params, self.cfg, hidden)
 
     def make_decode_chunk(self):
-        cfg, mesh, max_len = self.cfg, self.mesh, self.max_len
+        cfg, max_len, step_logits = self.cfg, self.max_len, self.decode_step
 
         @functools.partial(jax.jit, donate_argnums=(1,), static_argnums=(8, 9))
         def decode_chunk(
@@ -303,20 +348,28 @@ class HybridServing:
             def body(carry, step):
                 cache, tok, key, aux = carry
                 key, sub = jax.random.split(key)
-                start = jnp.minimum(lengths + step, max_len - 1)
-                hidden, cache, c = hybrid.forward(
-                    params, cfg, tok[:, None], start, counts, cache,
-                    window=window, mesh=mesh,
+                cache, lg, c = step_logits(
+                    params, cache, tok, lengths + step, counts, window
                 )
-                lg = hybrid.logits(params, cfg, hidden)[:, 0]
                 tok = sample(lg, sub, temp, top_p, top_k)
                 return (cache, tok, key, aux + c), tok
 
             (cache, _, _, aux), toks = jax.lax.scan(
                 body,
-                (cache, tokens, key, jnp.zeros((hybrid.N_COUNTERS,), jnp.int32)),
+                (cache, tokens, key, jnp.zeros((cfg.n_counters,), jnp.int32)),
                 jnp.arange(n_steps, dtype=jnp.int32),
             )
-            return cache, toks, aux
+            return cache, toks, self._aux(aux, decode=True)
 
         return decode_chunk
+
+    def decode_step(self, params, cache, tokens, lengths, counts, window: int):
+        """One decode step over every slot, as ``decode_chunk`` scans it:
+        ``tokens`` (b,) at positions ``lengths``, of which ``counts`` (0 or
+        1 a row) count.  Returns (state, logits (b, V) float32, counters)."""
+        start = jnp.minimum(lengths, self.max_len - 1)
+        hidden, cache, c = hybrid.forward(
+            params, self.cfg, tokens[:, None], start, counts, cache,
+            window=window, mesh=self.mesh,
+        )
+        return cache, hybrid.logits(params, self.cfg, hidden)[:, 0], c

@@ -1,15 +1,21 @@
-"""A decoder made of layer kinds: each layer names its mixer (``kda`` or
-``mla``) and its MLP (``dense`` or ``experts``), owns the parameters of
-those kinds and keeps the state of its mixer's kind.
+"""A decoder made of layer kinds: each layer names its mixer (``kda``,
+``mla``, ``full`` or ``window``) and its MLP (``dense`` or ``experts``),
+owns the parameters of those kinds and keeps the state of its mixer's
+kind.
 
-This is the model definition of the ``bailing_hybrid`` family
-(Ling-3.0-flash and its -VL sibling's language model): KDA linear
-attention (``ops/kda.py``) beside a latent-attention layer every
-``layer_group_size`` layers (``ops/mla.py``), a leading dense SwiGLU
-layer and then sigmoid-routed experts with a shared one (``ops/moe.py``),
-of which this process may hold a share.  A new architecture is a new
-layer kind here, not another flag on ``LlamaConfig``; ``models/llama.py``
-keeps serving the configurations it serves.
+Two families are defined here.  ``bailing_hybrid`` (Ling-3.0-flash and
+its -VL sibling's language model): KDA linear attention (``ops/kda.py``)
+beside a latent-attention layer every ``layer_group_size`` layers
+(``ops/mla.py``), a leading dense SwiGLU layer and then sigmoid-routed
+experts with a shared one (``ops/moe.py``), of which this process may
+hold a share.  ``mellum`` (Mellum2-12B-A2.5B): grouped-query attention
+in two kinds (``ops/gqa.py``), ``window`` layers that see the last
+``sliding_window`` positions beside a ``full`` layer every fourth, each
+kind with its own rotary frequencies (YaRN on the full layers,
+``ops/rope.py``), and softmax-routed experts with neither selection bias
+nor shared expert.  A new architecture is a new layer kind here, not
+another flag on ``LlamaConfig``; ``models/llama.py`` keeps serving the
+configurations it serves.
 
 One ``forward`` serves the three ways the engine calls a model: a cold
 batch into fresh state, a chunk of one slot's prompt, and one decode
@@ -25,29 +31,52 @@ State of a slot, by the layer's mixer:
   exists only as of the last token it has seen.
 * ``mla``: ``latent`` (T, kv_lora_rank + rope) — rows that grow with the
   tokens and can be cut at any length.
+* ``full``: ``k``, ``v`` (T, KH * head) — rows like the latent ones.
+* ``window``: ``ring_k``, ``ring_v`` (R, KH * head), ``R`` =
+  ``sliding_window`` whatever the length: position ``p`` lives in row
+  ``p % R``.  Like a recurrent state it exists only as of the last token
+  written, so a prefix hit takes it from a snapshot.
 
-What is read from the family's convention and not from a key of the
-public config is listed in ``benchmarks/configs/ling-3.0-flash-vl-l7e128.json``
-under ``assumed``; the plain reference is ``models/hybrid_reference.py``.
+``ROW_LEAVES`` names the leaves that hold a row a position; every other
+leaf is state as of the last token.  ``HybridConfig`` holds what every
+family has and the KDA and MLA sizes; ``GqaConfig`` adds the GQA sizes,
+the rotary parameters of each kind and the routing options.
+
+What is read from a family's convention and not from a key of the
+public config is listed under ``assumed`` in
+``benchmarks/configs/ling-3.0-flash-vl-l7e128.json`` and
+``benchmarks/configs/mellum2-12b-a2.5b-l12.json``; the plain references
+are ``models/hybrid_reference.py`` and ``models/mellum_reference.py``.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Mapping
+from typing import Any, ClassVar, Mapping
 
 import jax
 import jax.numpy as jnp
 
 from generativeaiexamples_tpu.models.llama import rms_norm
-from generativeaiexamples_tpu.ops import kda, mla, moe
+from generativeaiexamples_tpu.ops import gqa, kda, mla, moe
+from generativeaiexamples_tpu.ops.dispatch import record
+from generativeaiexamples_tpu.ops.rope import RopeSpec, apply_rope_spec, rope_spec
 
 Params = Mapping[str, Any]
 F32 = jnp.float32
-MIXERS = ("kda", "mla")
+MIXERS = ("kda", "mla", "full", "window")
 MLPS = ("dense", "experts")
-N_COUNTERS = len(moe.COUNTERS)
+# State leaves that hold one row a position, which can be cut at any
+# token (the others exist only as of the last token written), and those
+# that are rings of rows.  Rows run along axis 1 (slot axis 0).
+ROW_LEAVES = ("latent", "k", "v")
+RING_LEAVES = ("ring_k", "ring_v")
+GQA_LEAVES = {"full": ("k", "v"), "window": RING_LEAVES}  # a GQA mixer's K and V
+# Rows of K (and as many of V) the attention layers read from the slots'
+# state, by kind, and what the window layers would have read as full
+# layers; a model with such layers returns them after ``moe.COUNTERS``.
+ATTN_COUNTERS = ("read_window", "read_full", "dense_window")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -82,9 +111,19 @@ class HybridConfig:
     norm_eps: float = 1e-6
     max_seq_len: int = 2048
     dtype: str = "bfloat16"
-    # The latent rows and the convolution tails; the KDA state is float32
-    # whatever this says.  int8 is refused (``state_dtype``).
+    # The latent and K/V rows and the convolution tails; the KDA state is
+    # float32 whatever this says.  int8 is refused (``state_dtype``).
     kv_dtype: str = "bfloat16"
+    # What ``GqaConfig`` makes fields of.  Constants here, so that a
+    # configuration without GQA layers is the fields it always was (its
+    # ``dataclasses.asdict`` is held to golden values by the benchmark).
+    score_function: ClassVar[str] = "sigmoid"
+    router_bias: ClassVar[bool] = True
+    n_kv_heads: ClassVar[int] = 0
+    attn_head_dim: ClassVar[int] = 128
+    sliding_window: ClassVar[int] = 0
+    rope_full: ClassVar[RopeSpec | None] = None
+    rope_window: ClassVar[RopeSpec | None] = None
 
     def __post_init__(self) -> None:
         for mixer, mlp in self.layer_kinds:
@@ -94,10 +133,34 @@ class HybridConfig:
             raise ValueError("n_group must divide n_experts")
         if not 0 <= self.expert_offset <= self.n_experts - self.experts_held:
             raise ValueError("the experts held lie outside the router's outputs")
+        if self.score_function not in moe.SCORE_FUNCTIONS:
+            raise ValueError(f"unknown score_function {self.score_function!r}")
+        if self.layers_of("full") or self.layers_of("window"):
+            if self.n_kv_heads < 1 or self.n_heads % self.n_kv_heads:
+                raise ValueError("n_kv_heads must divide n_heads")
+            if self.rope_full is None or self.rope_window is None:
+                raise ValueError("a GQA layer kind needs its rotary parameters")
+            if self.layers_of("window") and self.sliding_window < 1:
+                raise ValueError("a window layer needs sliding_window")
 
     @property
     def n_layers(self) -> int:
         return len(self.layer_kinds)
+
+    @property
+    def has_attn_counters(self) -> bool:
+        return bool(self.layers_of("full") or self.layers_of("window"))
+
+    @property
+    def n_counters(self) -> int:
+        """Entries of ``forward``'s counters: ``moe.COUNTERS`` and, with
+        GQA layers, ``ATTN_COUNTERS`` after them."""
+        return len(moe.COUNTERS) + len(ATTN_COUNTERS) * self.has_attn_counters
+
+    def ring_rows(self, max_len: int) -> int:
+        """Rows of a window layer's ring: the window, whatever the length
+        (a state shorter than the window holds every position)."""
+        return min(self.sliding_window, max_len)
 
     @property
     def latent_width(self) -> int:
@@ -120,13 +183,36 @@ class HybridConfig:
     def layers_of(self, mixer: str) -> list[int]:
         return [i for i, (m, _) in enumerate(self.layer_kinds) if m == mixer]
 
-    def snapshot_bytes(self) -> int:
-        """Bytes of one slot's recurrent state over the KDA layers."""
+    def snapshot_bytes(self, max_len: int | None = None) -> int:
+        """Bytes of what one slot keeps only as of its last token: the
+        recurrent state of the KDA layers and the rings of the window
+        layers (of a state ``max_len`` long; absent: ``max_seq_len``)."""
         h, k = self.n_heads, self.kda_head_dim
-        per_layer = h * k * k * 4 + (self.conv_kernel - 1) * self.conv_channels * (
-            self.state_dtype.itemsize
+        item = self.state_dtype.itemsize
+        kda_layer = h * k * k * 4 + (self.conv_kernel - 1) * self.conv_channels * item
+        ring = self.ring_rows(self.max_seq_len if max_len is None else max_len)
+        window_layer = 2 * self.n_kv_heads * ring * self.attn_head_dim * item
+        return (
+            len(self.layers_of("kda")) * kda_layer
+            + len(self.layers_of("window")) * window_layer
         )
-        return len(self.layers_of("kda")) * per_layer
+
+
+@dataclasses.dataclass(frozen=True)
+class GqaConfig(HybridConfig):
+    """A configuration with ``full`` or ``window`` layers: the GQA sizes,
+    each kind's rotary parameters, and the routing of a family that is
+    not ``bailing_hybrid``'s."""
+
+    # Routing: ``sigmoid`` scores with a selection bias, or ``softmax``
+    # over all outputs with none; ``n_group`` 1 is the plain top-k.
+    score_function: str = "sigmoid"
+    router_bias: bool = True
+    n_kv_heads: int = 0
+    attn_head_dim: int = 128
+    sliding_window: int = 0
+    rope_full: RopeSpec | None = None
+    rope_window: RopeSpec | None = None
 
 
 def from_hf_config(
@@ -136,8 +222,9 @@ def from_hf_config(
     expert_offset: int = 0,
     kv_dtype: str = "bfloat16",
 ) -> HybridConfig:
-    """The public ``config.json`` keys of the ``bailing_hybrid`` family ->
-    ``HybridConfig``.
+    """The public ``config.json`` keys -> ``HybridConfig``, by
+    ``model_type``: ``mellum`` (:func:`_from_mellum`), else the
+    ``bailing_hybrid`` family, of which the rest speaks.
 
     ``num_experts`` counts the experts held (the chip's share);
     ``num_experts_published`` (absent: the same) the router's outputs.
@@ -146,6 +233,8 @@ def from_hf_config(
     ``i`` is MLA where ``(i + 1) % layer_group_size == 0`` and KDA
     otherwise; the first ``first_k_dense_replace`` layers kept are dense.
     """
+    if model.get("model_type") == "mellum":
+        return _from_mellum(model, max_len=max_len, kv_dtype=kv_dtype)
     period = int(model["layer_group_size"])
     first = int(model.get("first_layer", 0))
     dense = int(model["first_k_dense_replace"])
@@ -190,6 +279,63 @@ def from_hf_config(
     )
 
 
+def _from_mellum(model: Mapping[str, Any], *, max_len: int, kv_dtype: str) -> GqaConfig:
+    """``model_type: mellum``: ``layer_types`` names each layer's mixer
+    (``sliding_attention`` -> ``window``, ``full_attention`` -> ``full``),
+    ``mlp_layer_types`` its MLP, ``rope_parameters`` the rotary section of
+    each kind.  A cut in depth keeps the first ``num_hidden_layers``
+    entries of both lists.  Every expert is held (``num_experts`` are the
+    router's outputs); routing is the softmax over them, plain top-k."""
+    n = int(model["num_hidden_layers"])
+    mixers = {"sliding_attention": "window", "full_attention": "full"}
+    layer_types, mlp_types = list(model["layer_types"]), list(model["mlp_layer_types"])
+    if len(layer_types) < n or len(mlp_types) < n:
+        raise ValueError("layer_types and mlp_layer_types name fewer layers than num_hidden_layers")
+    kinds = []
+    for mixer, mlp in zip(layer_types[:n], mlp_types[:n]):
+        if mixer not in mixers:
+            raise ValueError(f"layer type {mixer!r} is not served")
+        if mlp != "sparse":
+            raise ValueError(
+                f"mlp_layer_types entry {mlp!r} is not served: this family's "
+                "layers are all 'sparse' (experts, no shared one); a 'dense' "
+                "SwiGLU of intermediate_size has no layer of the published "
+                "model to be checked against"
+            )
+        kinds.append((mixers[mixer], "experts"))
+    if model.get("attention_bias") or model.get("hidden_act", "silu") != "silu":
+        raise ValueError("attention biases and activations other than silu are not served")
+    rope = model["rope_parameters"]
+    experts = int(model["num_experts"])
+    return GqaConfig(
+        vocab_size=int(model["vocab_size"]),
+        d_model=int(model["hidden_size"]),
+        layer_kinds=tuple(kinds),
+        n_heads=int(model["num_attention_heads"]),
+        n_kv_heads=int(model["num_key_value_heads"]),
+        attn_head_dim=int(model["head_dim"]),
+        sliding_window=int(model["sliding_window"]),
+        rope_full=rope_spec(rope["full_attention"]),
+        rope_window=rope_spec(rope["sliding_attention"]),
+        d_ff=int(model["intermediate_size"]),
+        moe_d_ff=int(model["moe_intermediate_size"]),
+        shared_d_ff=0,
+        n_experts=experts,
+        experts_held=experts,
+        n_experts_per_tok=int(model["num_experts_per_tok"]),
+        n_group=1,
+        topk_group=1,
+        routed_scaling=1.0,
+        norm_topk=bool(model["norm_topk_prob"]),
+        score_function="softmax",
+        router_bias=False,
+        norm_eps=float(model["rms_norm_eps"]),
+        max_seq_len=int(max_len),
+        dtype=str(model.get("torch_dtype", "bfloat16")),
+        kv_dtype=kv_dtype,
+    )
+
+
 # -- parameters ---------------------------------------------------------------
 
 
@@ -215,6 +361,12 @@ def _layer_shapes(cfg: HybridConfig, mixer: str, mlp: str) -> dict:
             o_norm=((K,), 1.0),
             w_o=((H * K, D), H * K),
         )
+    elif mixer in ("full", "window"):
+        hd, KH = cfg.attn_head_dim, cfg.n_kv_heads
+        shapes.update(
+            w_qkv=((D, (H + 2 * KH) * hd), D),  # q heads, then k, then v
+            w_o=((H * hd, D), H * hd),
+        )
     else:
         qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
         shapes.update(
@@ -232,14 +384,12 @@ def _layer_shapes(cfg: HybridConfig, mixer: str, mlp: str) -> dict:
         shapes.update(w_gu=((D, 2 * cfg.d_ff), D), w_down=((cfg.d_ff, D), cfg.d_ff))
     else:
         F, Fs, E = cfg.moe_d_ff, cfg.shared_d_ff, cfg.experts_held
-        shapes.update(
-            router=((D, cfg.n_experts), D),
-            router_bias=((cfg.n_experts,), 0.0),
-            w_gu_e=((E, D, 2 * F), D),
-            w_down_e=((E, F, D), F),
-            w_gu_s=((D, 2 * Fs), D),
-            w_down_s=((Fs, D), Fs),
-        )
+        shapes.update(router=((D, cfg.n_experts), D))
+        if cfg.router_bias:
+            shapes.update(router_bias=((cfg.n_experts,), 0.0))
+        shapes.update(w_gu_e=((E, D, 2 * F), D), w_down_e=((E, F, D), F))
+        if Fs:
+            shapes.update(w_gu_s=((D, 2 * Fs), D), w_down_s=((Fs, D), Fs))
     return shapes
 
 
@@ -283,7 +433,7 @@ def balance_router_biases(params: Params, cfg: HybridConfig, key: jax.Array) -> 
     (``ops.moe.balanced_bias``) before they go on through it.  Random
     weights stand in for a checkpoint whose
     ``moe_router_enable_expert_bias`` training has done this."""
-    if not any(mlp == "experts" for _, mlp in cfg.layer_kinds):
+    if not cfg.router_bias or not any(mlp == "experts" for _, mlp in cfg.layer_kinds):
         return params
     rows = max(4, cfg.n_experts // 8)
     biases = iter(_balanced_biases(
@@ -306,7 +456,7 @@ def _balanced_biases(params, cfg: HybridConfig, tokens):
     x = params["embed"][tokens]
     out = []
     for (mixer, mlp), lp, st in zip(cfg.layer_kinds, params["layers"], init_state(cfg, b, s)):
-        x, _ = _mix(x, lp, st, mixer, pos, valid, n_valid, cfg, s)
+        x, _, _ = _mix(x, lp, st, mixer, pos, valid, n_valid, cfg, s)
         if mlp == "experts":
             h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
             bias = moe.balanced_bias(
@@ -328,7 +478,11 @@ def init_state(cfg: HybridConfig, batch: int, max_len: int) -> tuple:
     sd = cfg.state_dtype
     out = []
     for mixer, _ in cfg.layer_kinds:
-        if mixer == "kda":
+        if mixer in ("full", "window"):
+            rows = max_len if mixer == "full" else cfg.ring_rows(max_len)
+            shape = (batch, rows, cfg.n_kv_heads * cfg.attn_head_dim)
+            out.append({n: jnp.zeros(shape, sd) for n in GQA_LEAVES[mixer]})
+        elif mixer == "kda":
             out.append(
                 {
                     "S": jnp.zeros((batch, H, K, K), F32),
@@ -340,6 +494,18 @@ def init_state(cfg: HybridConfig, batch: int, max_len: int) -> tuple:
         else:
             out.append({"latent": jnp.zeros((batch, max_len, cfg.latent_width), sd)})
     return tuple(out)
+
+
+def state_bytes(cfg: HybridConfig, batch: int, max_len: int) -> dict[str, int]:
+    """Bytes of the slots' state by kind: ``full`` (rows that grow with
+    the tokens: latent, K/V), ``window`` (rings: the same at any
+    ``max_len`` over the window) and ``recurrent``."""
+    out = {"full": 0, "window": 0, "recurrent": 0}
+    shapes = jax.eval_shape(lambda: init_state(cfg, batch, max_len))
+    for (mixer, _), layer in zip(cfg.layer_kinds, shapes):
+        kind = {"kda": "recurrent", "window": "window"}.get(mixer, "full")
+        out[kind] += sum(leaf.size * leaf.dtype.itemsize for leaf in layer.values())
+    return out
 
 
 # -- layers ---------------------------------------------------------------------
@@ -406,6 +572,52 @@ def _mla_mixer(h, lp, st, pos, valid, cfg: HybridConfig, window: int):
     return out, {"latent": latent}
 
 
+def _gqa_mixer(h, lp, st, mixer, pos, valid, n_valid, cfg: HybridConfig, window: int):
+    """A ``full`` or ``window`` layer.  Returns (output, state, counters
+    in the order of ``ATTN_COUNTERS``): a full layer writes its rows and
+    attends over the first ``window`` of them; a window layer attends
+    over its ring as it was and over its own new rows, then writes."""
+    b, s, _ = h.shape
+    H, KH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.attn_head_dim
+    scope = f"layer/attn_{mixer}"
+    spec = cfg.rope_full if mixer == "full" else cfg.rope_window
+    with jax.named_scope(f"{scope}/qkv"):
+        qkv = jnp.dot(h, lp["w_qkv"]).reshape(b, s, H + 2 * KH, hd)
+        q, k, v = qkv[:, :, :H], qkv[:, :, H : H + KH], qkv[:, :, H + KH :]
+    with jax.named_scope(f"{scope}/rope"):
+        q, k = apply_rope_spec(q, pos, spec), apply_rope_spec(k, pos, spec)
+        k, v = k.reshape(b, s, KH * hd), v.reshape(b, s, KH * hd)  # a state row
+    names = GQA_LEAVES[mixer]
+    old_k, old_v = (st[n] for n in names)
+    rows = old_k.shape[1]
+    record(f"attn_{mixer} b={b} s={s} t={window if mixer == 'full' else rows}", False)
+
+    def write(at):
+        # A token that does not count is written nowhere (``at`` == rows).
+        with jax.named_scope(f"{scope}/kv_write"):
+            slots = jnp.arange(b)[:, None]
+            return (
+                old_k.at[slots, at].set(k.astype(old_k.dtype), mode="drop"),
+                old_v.at[slots, at].set(v.astype(old_v.dtype), mode="drop"),
+            )
+
+    if mixer == "full":
+        new_k, new_v = write(jnp.where(valid, pos, rows))
+        with jax.named_scope(f"{scope}/attend"):
+            o = gqa.attend_rows(q, new_k[:, :window], new_v[:, :window], pos, n_kv=KH)
+        read = (0, b * min(window, rows), 0)
+    else:
+        with jax.named_scope(f"{scope}/attend"):
+            o = gqa.attend_ring(
+                q, k, v, old_k, old_v, pos, n_kv=KH, window=cfg.sliding_window
+            )
+        new_k, new_v = write(gqa.ring_slots(pos, valid, n_valid, rows))
+        read = (b * rows, 0, b * window)
+    with jax.named_scope(f"{scope}/wo"):
+        out = jnp.dot(o.reshape(b, s, H * hd).astype(h.dtype), lp["w_o"])
+    return out, dict(zip(names, (new_k, new_v))), jnp.array(read, jnp.int32)
+
+
 def _swiglu(h, w_gu, w_down):
     gu = jnp.dot(h, w_gu)
     half = gu.shape[-1] // 2
@@ -417,27 +629,33 @@ def _expert_layer(h, lp, valid, cfg: HybridConfig, mesh):
     b, s, d = h.shape
     x = h.reshape(b * s, d)
     idx, w = moe.route(
-        x, lp["router"], lp["router_bias"], k=cfg.n_experts_per_tok,
+        x, lp["router"], lp.get("router_bias"), k=cfg.n_experts_per_tok,
         n_group=cfg.n_group, topk_group=cfg.topk_group,
         norm_topk=cfg.norm_topk, scale=cfg.routed_scaling,
+        score=cfg.score_function,
     )
     y, counters = moe.expert_mlp(
         x, idx, w, valid.reshape(-1), lp,
         offset=cfg.expert_offset, held=cfg.experts_held, mesh=mesh,
     )
-    with jax.named_scope("layer/moe/shared"):
-        y = y + _swiglu(x, lp["w_gu_s"], lp["w_down_s"])
+    if cfg.shared_d_ff:
+        with jax.named_scope("layer/moe/shared"):
+            y = y + _swiglu(x, lp["w_gu_s"], lp["w_down_s"])
     return y.reshape(b, s, d), counters
 
 
 def _mix(x, lp, st, mixer, pos, valid, n_valid, cfg: HybridConfig, window: int):
-    """The mixer's half of a layer: (x + mixer(norm(x)), new state)."""
+    """The mixer's half of a layer: (x + mixer(norm(x)), new state, a GQA
+    layer's ``ATTN_COUNTERS`` or 0)."""
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
+    read = 0
     if mixer == "kda":
         y, st = _kda_mixer(h, lp, st, valid, n_valid, cfg)
-    else:
+    elif mixer == "mla":
         y, st = _mla_mixer(h, lp, st, pos, valid, cfg, window)
-    return x + y, st
+    else:
+        y, st, read = _gqa_mixer(h, lp, st, mixer, pos, valid, n_valid, cfg, window)
+    return x + y, st, read
 
 
 def _mlp(x, lp, mlp, valid, cfg: HybridConfig, mesh):
@@ -463,20 +681,26 @@ def forward(
 ):
     """tokens (b, s) at positions ``start[b] + [0, s)``, of which the first
     ``n_valid[b]`` count; ``state`` is these rows' state; MLA layers
-    attend over the first ``window`` latent rows.  Returns (hidden
-    (b, s, D), state, counters (N_COUNTERS,) int32 summed over layers)."""
+    and full layers attend over the first ``window`` rows.  Returns
+    (hidden (b, s, D), state, counters (cfg.n_counters,) int32
+    summed over layers)."""
     b, s = tokens.shape
     x = params["embed"][tokens]
     steps = jnp.arange(s, dtype=jnp.int32)[None, :]
     pos = start[:, None].astype(jnp.int32) + steps
     valid = steps < n_valid[:, None]
-    counters = jnp.zeros((N_COUNTERS,), jnp.int32)
+    counters = jnp.zeros((len(moe.COUNTERS),), jnp.int32)
+    read = jnp.zeros((len(ATTN_COUNTERS),), jnp.int32) if cfg.has_attn_counters else None
     out_state = []
     for (mixer, mlp), lp, st in zip(cfg.layer_kinds, params["layers"], state):
-        x, st = _mix(x, lp, st, mixer, pos, valid, n_valid.astype(jnp.int32), cfg, window)
+        x, st, r = _mix(x, lp, st, mixer, pos, valid, n_valid.astype(jnp.int32), cfg, window)
         x, c = _mlp(x, lp, mlp, valid, cfg, mesh)
         counters = counters + c
+        if cfg.has_attn_counters:
+            read = read + r
         out_state.append(st)
+    if cfg.has_attn_counters:
+        counters = jnp.concatenate([counters, read])
     return x, tuple(out_state), counters
 
 
@@ -525,6 +749,50 @@ LING_TINY = {
 }
 
 
+# JetBrains/Mellum2-12B-A2.5B-Instruct's config.json: every key that gives
+# the model its shape (``intermediate_size`` is of a 'dense' MLP, which no
+# published layer is).
+_MELLUM_PERIOD = ["sliding_attention"] * 3 + ["full_attention"]
+MELLUM2_12B = {
+    "model_type": "mellum", "num_hidden_layers": 28, "hidden_size": 2304,
+    "intermediate_size": 7168, "moe_intermediate_size": 896,
+    "num_attention_heads": 32, "num_key_value_heads": 4, "head_dim": 128,
+    "attention_bias": False, "hidden_act": "silu",
+    "layer_types": _MELLUM_PERIOD * 7, "mlp_layer_types": ["sparse"] * 28,
+    "num_experts": 64, "num_experts_per_tok": 8, "norm_topk_prob": True,
+    "sliding_window": 1024, "max_position_embeddings": 131072,
+    "rope_parameters": {
+        "full_attention": {
+            "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+            "original_max_position_embeddings": 8192, "beta_fast": 32,
+            "beta_slow": 1, "attention_factor": 1.2772588722239782,
+        },
+        "sliding_attention": {"rope_type": "default", "rope_theta": 500000},
+    },
+    "rms_norm_eps": 1e-06, "vocab_size": 98304, "tie_word_embeddings": False,
+}
+# One chip of a three-chip pipeline of whole layers: published layers
+# 0-11, three whole periods, every expert and the whole vocabulary.
+MELLUM_L12_CUT = {"num_hidden_layers": 12}
+# Every ratio at sizes a CPU test runs: a period of 4, two periods deep, a
+# window of 16 (prompts are several windows long), YaRN over an original
+# context of 32 (so positions past it are reached), 3 of 8 experts a token.
+MELLUM_TINY = {
+    **MELLUM2_12B, "num_hidden_layers": 8, "hidden_size": 64,
+    "moe_intermediate_size": 32, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "head_dim": 16, "num_experts": 8,
+    "num_experts_per_tok": 3, "sliding_window": 16, "vocab_size": 512,
+    "rope_parameters": {
+        "full_attention": {
+            **MELLUM2_12B["rope_parameters"]["full_attention"],
+            "original_max_position_embeddings": 32,
+        },
+        "sliding_attention": MELLUM2_12B["rope_parameters"]["sliding_attention"],
+    },
+    "torch_dtype": "float32",
+}
+
+
 def ling_flash_vl_l7e128() -> HybridConfig:
     return from_hf_config({**LING_FLASH_VL, **LING_L7E128_CUT}, max_len=2048)
 
@@ -533,7 +801,17 @@ def ling_tiny() -> HybridConfig:
     return from_hf_config(LING_TINY, max_len=256, kv_dtype="float32")
 
 
+def mellum2_12b_l12() -> HybridConfig:
+    return from_hf_config({**MELLUM2_12B, **MELLUM_L12_CUT}, max_len=8192)
+
+
+def mellum_tiny() -> HybridConfig:
+    return from_hf_config(MELLUM_TINY, max_len=256, kv_dtype="float32")
+
+
 PRESETS = {
     "ling-3.0-flash-vl-l7e128": ling_flash_vl_l7e128,
     "ling-tiny": ling_tiny,
+    "mellum2-12b-a2.5b-l12": mellum2_12b_l12,
+    "mellum-tiny": mellum_tiny,
 }

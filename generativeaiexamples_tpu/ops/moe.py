@@ -1,5 +1,7 @@
-"""A share of a wide expert layer: DeepSeek-V3's ``noaux_tc`` routing over
-all the published experts, and the products of the experts held here.
+"""A share of a wide expert layer: routing over all the published experts
+(DeepSeek-V3's ``noaux_tc``: sigmoid scores, a selection bias, a group
+limit; or the plain softmax top-k of a router with none of the three),
+and the products of the experts held here, which may be all of them.
 
 The layer is told which experts it holds (``offset``, ``held``).  It
 routes every token over all ``E`` router outputs, computes what its own
@@ -43,8 +45,11 @@ def select(sel, *, k, n_group, topk_group):
     """Group-limited top-k over selection scores ``sel`` (n, E): a group's
     score is the sum of its two largest, the best ``topk_group`` groups
     are kept and the ``k`` largest inside them taken.  Returns (n, k)
-    int32.  Ties go to the lower index (``lax.top_k``)."""
+    int32.  Ties go to the lower index (``lax.top_k``).  One group is no
+    limit: the plain top-k over all of ``sel``."""
     n, E = sel.shape
+    if n_group == 1:
+        return jax.lax.top_k(sel, k)[1].astype(jnp.int32)
     grouped = sel.reshape(n, n_group, E // n_group)
     group_score = jax.lax.top_k(grouped, 2)[0].sum(-1)  # (n, n_group)
     _, keep = jax.lax.top_k(group_score, topk_group)
@@ -53,23 +58,30 @@ def select(sel, *, k, n_group, topk_group):
     return jax.lax.top_k(masked, k)[1].astype(jnp.int32)
 
 
-def scores(x, w_router):
-    """Sigmoid routing scores in float32: (n, D) x (D, E) -> (n, E)."""
-    return jax.nn.sigmoid(
+SCORE_FUNCTIONS = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}
+
+
+def scores(x, w_router, score: str = "sigmoid"):
+    """Routing scores in float32: (n, D) x (D, E) -> (n, E); each
+    output's sigmoid, or the softmax over all E."""
+    return SCORE_FUNCTIONS[score](
         jnp.dot(x.astype(F32), w_router.astype(F32),
                 precision=jax.lax.Precision.HIGHEST)
     )
 
 
 @jax.named_scope("layer/moe/router")
-def route(x, w_router, bias, *, k, n_group, topk_group, norm_topk, scale):
-    """Sigmoid scores, group-limited top-k on ``score + bias``, weights
-    from the scores alone.
+def route(x, w_router, bias, *, k, n_group, topk_group, norm_topk, scale,
+          score: str = "sigmoid"):
+    """Scores (``score``: sigmoid or softmax), group-limited top-k on
+    ``score + bias``, weights from the scores alone.  ``bias`` None and
+    ``n_group`` 1 give the plain top-k of a router with neither.
 
-    x: (n, D); w_router: (D, E); bias: (E,) float32.  Returns (idx (n, k)
-    int32 over all E, weights (n, k) float32)."""
-    s = scores(x, w_router)
-    idx = select(s + bias.astype(F32), k=k, n_group=n_group, topk_group=topk_group)
+    x: (n, D); w_router: (D, E); bias: (E,) float32 or None.  Returns
+    (idx (n, k) int32 over all E, weights (n, k) float32)."""
+    s = scores(x, w_router, score)
+    sel = s if bias is None else s + bias.astype(F32)
+    idx = select(sel, k=k, n_group=n_group, topk_group=topk_group)
     w = jnp.take_along_axis(s, idx, axis=-1)
     if norm_topk:
         w = w / (w.sum(-1, keepdims=True) + 1e-20)
@@ -123,11 +135,21 @@ def _grouped(xs, w, group_sizes, pallas: bool, tiling):
 
 def _tiling(a: int, b: int) -> tuple[int, int, int]:
     """gmm tiles for an (m, a) x (a, b) product: whole rows of 128, the
-    widest column tiles that divide the sizes and fit VMEM."""
+    widest column tiles that divide the sizes and fit VMEM.  A width that
+    is 7 or 9 times 128 (896, 2,304) divides by none of the powers of
+    two: it takes 896 or 1,152, since a tile of 128 or 256 costs one grid
+    step per 64-128 KB of weights and the steps, not the stream, then set
+    the time (the v5e: 1.8 ms for a product of 64 experts of 2,304 x
+    1,792 in 4,977 steps of (256, 256), against 0.65 ms to stream them;
+    PERF.md section 6, PR 31)."""
     def pick(x, options):
         return next((t for t in options if x % t == 0), 128)
 
-    return ROW_TILE, pick(a, (512, 256, 128)), pick(b, (768, 512, 256, 128))
+    return (
+        ROW_TILE,
+        pick(a, (1152, 896, 512, 256, 128)),
+        pick(b, (896, 768, 512, 256, 128)),
+    )
 
 
 def expert_mlp(x, idx, weights, valid, lp, *, offset: int, held: int, mesh=None):

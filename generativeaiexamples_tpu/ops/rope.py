@@ -6,7 +6,13 @@ weights need no permutation at load time.
 
 from __future__ import annotations
 
+import dataclasses
+import functools
+import math
+from typing import Any, Mapping
+
 import jax.numpy as jnp
+import numpy as np
 
 
 def rope_frequencies(head_dim: int, theta: float) -> jnp.ndarray:
@@ -35,8 +41,100 @@ def apply_rope(
     # b=192 prefill profile (~2.4 ms/layer of pure data formatting).
     cos = jnp.cos(angles)[:, :, None, :].astype(x.dtype)  # (b, s, 1, hd/2)
     sin = jnp.sin(angles)[:, :, None, :].astype(x.dtype)
+    return _rotate_half(x, cos, sin).astype(x.dtype)
+
+
+def _rotate_half(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndarray:
+    """(x1, x2) -> (x1 cos - x2 sin, x2 cos + x1 sin) over x's halves."""
     x1, x2 = jnp.split(x, 2, axis=-1)
-    rotated = jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+
+
+# -- per-layer-kind parameters ----------------------------------------------------
+
+
+@dataclasses.dataclass(frozen=True)
+class RopeSpec:
+    """One section of a config's ``rope_parameters``: the plain
+    frequencies (``rope_type`` ``default``) or YaRN's."""
+
+    theta: float
+    rope_type: str = "default"
+    factor: float = 1.0
+    original_max: int = 0  # original_max_position_embeddings
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
+    truncate: bool = True
+
+    def __post_init__(self) -> None:
+        if self.rope_type not in ("default", "yarn"):
+            raise ValueError(
+                f"rope_type {self.rope_type!r} is not served: only 'default' "
+                "and 'yarn' frequencies are implemented (ops/rope.py)"
+            )
+
+
+def rope_spec(section: Mapping[str, Any]) -> RopeSpec:
+    """A ``rope_parameters`` section of a public config -> ``RopeSpec``."""
+    kind = str(section.get("rope_type", "default"))
+    spec = RopeSpec(theta=float(section["rope_theta"]), rope_type=kind)
+    if kind != "yarn":
+        return spec
+    factor = float(section["factor"])
+    return dataclasses.replace(
+        spec,
+        factor=factor,
+        original_max=int(section["original_max_position_embeddings"]),
+        beta_fast=float(section.get("beta_fast", 32.0)),
+        beta_slow=float(section.get("beta_slow", 1.0)),
+        # transformers' default where the config gives none.
+        attention_factor=float(
+            section.get("attention_factor") or 0.1 * math.log(factor) + 1.0
+        ),
+        truncate=bool(section.get("truncate", True)),
     )
-    return rotated.astype(x.dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def spec_frequencies(spec: RopeSpec, head_dim: int) -> np.ndarray:
+    """Inverse frequencies of ``spec``, (head_dim // 2,) float32, computed
+    once a configuration (the cache) and on the host.
+
+    YaRN as in ``transformers``' ``_compute_yarn_parameters``: pair ``i``
+    turns ``dim(r)`` times over the original context where
+    ``dim(r) = head_dim ln(original_max / (2 pi r)) / (2 ln theta)``;
+    pairs below ``low = dim(beta_fast)`` keep the plain frequency, pairs
+    above ``high = dim(beta_slow)`` are divided by ``factor``, and a
+    linear ramp joins them."""
+    half = head_dim // 2
+    plain = spec.theta ** (-np.arange(0, head_dim, 2, dtype=np.float64) / head_dim)
+    if spec.rope_type == "default":
+        return plain.astype(np.float32)
+
+    def dim_of(rotations: float) -> float:
+        return (
+            head_dim
+            * math.log(spec.original_max / (rotations * 2.0 * math.pi))
+            / (2.0 * math.log(spec.theta))
+        )
+
+    low, high = dim_of(spec.beta_fast), dim_of(spec.beta_slow)
+    if spec.truncate:
+        low, high = math.floor(low), math.ceil(high)
+    low, high = max(low, 0), min(high, head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low) / (high - low), 0.0, 1.0)
+    return ((1.0 - ramp) * plain + ramp * plain / spec.factor).astype(np.float32)
+
+
+def apply_rope_spec(x: jnp.ndarray, positions: jnp.ndarray, spec: RopeSpec) -> jnp.ndarray:
+    """:func:`apply_rope` with the frequencies of ``spec``; cos and sin
+    are multiplied by its ``attention_factor`` (so q.k grows by its
+    square)."""
+    inv_freq = jnp.asarray(spec_frequencies(spec, x.shape[-1]))
+    angles = positions.astype(jnp.float32)[..., None] * inv_freq
+    cos = (jnp.cos(angles) * spec.attention_factor)[:, :, None, :].astype(x.dtype)
+    sin = (jnp.sin(angles) * spec.attention_factor)[:, :, None, :].astype(x.dtype)
+    return _rotate_half(x, cos, sin)

@@ -187,23 +187,38 @@ def test_paged_kernel_compiles(one_chip, page_tokens, chunk):
     _compile(attn, *args)
 
 
+# (hidden, expert width, router outputs, experts held, group limit, score,
+# the tiles ``ops.moe._tiling`` must pick for gate-up and for down)
+EXPERT_WIDTHS = {
+    "ling": (2560, 768, 512, 128, (8, 4), "sigmoid", (128, 512, 768), (128, 256, 512)),
+    "mellum": (2304, 896, 64, 64, (1, 1), "softmax", (128, 1152, 896), (128, 896, 768)),
+}
+
+
+@pytest.mark.parametrize("family", sorted(EXPERT_WIDTHS))
 @pytest.mark.parametrize("tokens", [32, 256])
-def test_grouped_expert_products_compile_at_ling_widths(one_chip, tokens, monkeypatch):
-    """``ops/moe.py``'s sorted dispatch at ling-3.0-flash-vl-l7e128's
-    widths: 128 experts held of 512, hidden 2,560, expert width 768, 8 a
-    token; a decode step's 32 rows and a prefill chunk's 256.  The
-    grouped products are megablox's ``gmm``; this holds the tilings
-    ``ops.moe._tiling`` picks to what Mosaic accepts."""
+def test_grouped_expert_products_compile_at_ling_widths(one_chip, tokens, family, monkeypatch):
+    """``ops/moe.py``'s sorted dispatch at the published widths of the two
+    expert families served: ling-3.0-flash-vl-l7e128 (128 experts held of
+    512, hidden 2,560, expert width 768, sigmoid scores, 4 of 8 groups)
+    and mellum2-12b-a2.5b-l12 (all 64, hidden 2,304, width 896 = 7 x 128,
+    softmax, no groups), 8 a token; a decode step's 32 rows and a prefill
+    chunk's 256.  The grouped products are megablox's ``gmm``; this holds
+    the tilings ``ops.moe._tiling`` picks to what Mosaic accepts, and
+    Ling's to what they were."""
     from generativeaiexamples_tpu.ops import moe
 
     # The gate asks the default backend, which is the CPU here.
     monkeypatch.setattr(moe, "platform_of", lambda mesh: "tpu")
     spec = _spec(one_chip)
-    D, F, E, held, k = 2560, 768, 512, 128, 8
+    D, F, E, held, (n_group, topk_group), score, gate_up, down = EXPERT_WIDTHS[family]
+    k = 8
+    assert moe._tiling(D, 2 * F) == gate_up and moe._tiling(F, D) == down
 
     def layer(x, w_router, bias, w_gu_e, w_down_e, valid):
         idx, w = moe.route(
-            x, w_router, bias, k=k, n_group=8, topk_group=4, norm_topk=True, scale=2.5
+            x, w_router, bias if family == "ling" else None, k=k, n_group=n_group,
+            topk_group=topk_group, norm_topk=True, scale=2.5, score=score,
         )
         return moe.expert_mlp(
             x, idx, w, valid, {"w_gu_e": w_gu_e, "w_down_e": w_down_e},

@@ -226,3 +226,32 @@ def test_hybrid_phase_rehearsal_and_its_control(capsys):
     control = _phases(capsys)["hybrid"]
     assert control["control"] == "w8a8_mlp"
     assert control["decode_p10_share"] > 20 * sound["decode_p10_share"]
+
+
+@pytest.mark.parametrize("control", ["", "no_window", "no_yarn"])
+def test_hybrid_phase_rehearsal_of_the_window_model_and_its_controls(control, capsys):
+    """``--hybrid --model mellum`` at the tiny size, in process: a prompt of
+    75 tokens under a window of 16 (the rings wrap four times), then 8
+    decode steps.  The served logits stay far inside the limits; with the
+    program's window layers attending to everything, or its full layers
+    on the plain frequencies, they leave them."""
+    chip_smoke.child_hybrid(0, chip_smoke.TINY, control=control, model="mellum")
+    line = _phases(capsys)["hybrid"]
+    assert line["model"] == "mellum-tiny" and line["control"] == (control or None)
+    assert line["positions"] == {"prefill": 75, "decode": 8}
+    assert any(site.startswith("attn_window") for site in line["kernel_paths"])
+    if not control:
+        assert line["within_limits"]
+        assert line["prefill_p90_share"] < 1e-3 and line["decode_p90_share"] < 1e-3
+    else:
+        assert not line["within_limits"]
+        assert line["decode_p10_share"] > line["limits"]["decode_p10_share"]
+
+
+def test_hybrid_phase_names_a_child_for_every_model_and_control():
+    assert sorted(n for n in chip_smoke.CHILDREN if n.startswith("hybrid")) == [
+        "hybrid_ling", "hybrid_ling_w8a8_mlp", "hybrid_mellum", "hybrid_mellum_no_window",
+        "hybrid_mellum_no_yarn", "hybrid_mellum_w8a8_mlp",
+    ]
+    with pytest.raises(chip_smoke.SmokeFailure, match="has no control 'no_yarn'"):
+        chip_smoke.run(0, chip_smoke.TINY, expect="cpu", hybrid=("ling", "no_yarn"))

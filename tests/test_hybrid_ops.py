@@ -1,7 +1,7 @@
 """The serving-path forms of the new mechanisms against their plain
 forms: chunk-wise KDA against the token-by-token recurrence, absorbed MLA
-against expanded, group-limited routing against a brute-force choice,
-sorted expert dispatch against every expert computed whole."""
+against expanded, group-limited sigmoid routing and plain softmax routing
+against a brute-force choice, sorted expert dispatch against every expert computed whole."""
 
 import jax
 import jax.numpy as jnp
@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 
 from generativeaiexamples_tpu.models import hybrid_reference as ref
+from generativeaiexamples_tpu.models import mellum_reference as mellum_ref
 from generativeaiexamples_tpu.ops import kda, mla, moe
 
 F32 = jnp.float32
@@ -107,33 +108,55 @@ def test_rope_interleaved_rotates_pairs_by_position():
 
 
 DIMS = {"E": 32, "G": 8, "topk_group": 4, "k": 4, "norm_topk": True, "scale": 2.5}
-
-
-def _route(x, w, bias):
-    return moe.route(x, w, bias, k=4, n_group=8, topk_group=4, norm_topk=True, scale=2.5)
+# The two routers served: sigmoid scores, a selection bias and a group
+# limit (``hybrid_reference.routing``); softmax over all outputs, neither
+# bias nor groups, weights renormalised to one (``mellum_reference.routing``).
+ROUTERS = {
+    "sigmoid_grouped": dict(
+        route=dict(n_group=8, topk_group=4, scale=2.5), bias=True, total=2.5,
+        brute=lambda x, w, bias: ref.routing(x, {"router": w, "router_bias": bias}, DIMS),
+    ),
+    "softmax_plain": dict(
+        route=dict(n_group=1, topk_group=1, scale=1.0, score="softmax"), bias=False, total=1.0,
+        brute=lambda x, w, bias: mellum_ref.routing(x, {"router": w}, {"k": 4, "norm_topk": True}),
+    ),
+}
 
 
 @pytest.mark.parametrize("ties", [False, True])
-def test_group_limited_choice_matches_brute_force(ties):
+@pytest.mark.parametrize("router", sorted(ROUTERS))
+def test_group_limited_choice_matches_brute_force(router, ties):
+    kind = ROUTERS[router]
     r = np.random.RandomState(7)
     n, D, E = 64, 16, 32
     x = jnp.asarray(r.randn(n, D), F32)
     w = jnp.asarray(r.randn(D, E), F32)
-    bias = jnp.asarray(r.randn(E) * 0.3, F32)
+    bias = jnp.asarray(r.randn(E) * 0.3, F32) if kind["bias"] else None
     if ties:
         # Equal scores everywhere: every group and every expert ties, so
         # the rule (the lower index wins) decides the whole choice.
-        w, bias = jnp.zeros_like(w), jnp.zeros_like(bias)
-    idx, weights = _route(x, w, bias)
-    dense = ref.routing(x, {"router": w, "router_bias": bias}, DIMS)  # (n, E)
+        w, bias = jnp.zeros_like(w), (jnp.zeros((E,), F32) if kind["bias"] else None)
+    idx, weights = moe.route(x, w, bias, k=4, norm_topk=True, **kind["route"])
+    dense = kind["brute"](x, w, bias)  # (n, E)
     got = np.zeros((n, E), np.float32)
     np.put_along_axis(got, np.asarray(idx), np.asarray(weights), axis=1)
     np.testing.assert_allclose(got, dense, rtol=1e-5, atol=1e-7)
     if ties:
         assert np.asarray(idx)[0].tolist() == [0, 1, 2, 3]
-    # Group limit: a token's experts lie in at most topk_group groups.
-    assert max(len(set(row // 4)) for row in np.asarray(idx)) <= 4
-    np.testing.assert_allclose(weights.sum(-1), 2.5, rtol=1e-5)
+    if kind["route"]["n_group"] > 1:
+        # Group limit: a token's experts lie in at most topk_group groups.
+        assert max(len(set(row // 4)) for row in np.asarray(idx)) <= 4
+    np.testing.assert_allclose(weights.sum(-1), kind["total"], rtol=1e-5)
+
+
+def test_softmax_weights_without_renormalising_are_the_probabilities():
+    r = np.random.RandomState(8)
+    x, w = jnp.asarray(r.randn(16, 8), F32), jnp.asarray(r.randn(8, 32), F32)
+    idx, weights = moe.route(x, w, None, k=4, n_group=1, topk_group=1, norm_topk=False,
+                             scale=1.0, score="softmax")
+    p = jax.nn.softmax(x @ w, axis=-1)
+    np.testing.assert_allclose(weights, jnp.take_along_axis(p, idx, axis=-1), rtol=1e-5)
+    assert float(weights.sum(-1).max()) < 1.0
 
 
 def _experts(seed, held, D, F):

@@ -191,3 +191,102 @@ def test_a_llama_scheduler_reports_the_new_counters_as_zero_or_equal():
     assert snap["prefix_tokens_matched"] == snap["prefix_tokens_reused"] == 50
     assert snap["state_snapshots_saved"] == snap["state_snapshot_bytes"] == 0
     assert not any(k.startswith("moe_") for k in snap)
+
+
+# -- the ``mellum`` family: window rings beside full rows ---------------------------
+
+
+@pytest.fixture(scope="module")
+def mellum():
+    # What engine.server.main() builds for --model mellum-tiny.
+    cfg = hybrid.PRESETS[resolve_model_preset("mellum-tiny")]()
+    s = Scheduler(
+        cfg, None, max_batch=4, max_len=256, decode_chunk_size=4, seed=5,
+        prefill_chunk_tokens=CHUNK, prefix_cache="shared",
+    )
+    s.start()
+    yield s
+    s.stop()
+
+
+def _mellum_gap(scheduler, prompt, out, pad_to=192):
+    """``_worst_gap`` against ``mellum_reference``: the whole (padded)
+    sequence at once, one compiled reference; every layer is causal, so no
+    position before the pad sees it."""
+    from generativeaiexamples_tpu.models import mellum_reference
+
+    seq = list(prompt) + list(out)
+    lg = np.asarray(mellum_reference.all_logits(
+        scheduler.params, scheduler.cfg, seq + [0] * (pad_to - len(seq))))
+    rows = lg[len(prompt) - 1 : len(seq) - 1]
+    return float((rows.max(-1) - rows[np.arange(len(out)), out]).max())
+
+
+def test_mellum_cold_prompts_a_snapshot_hit_and_a_prompt_over_half_of_max_len(mellum):
+    """A window of 16 under chunks of 32: every prompt wraps its rings.
+    Greedy tokens equal the reference's (to a near-tie) for a cold batch,
+    a chunked prompt, a prefix hit cut back to a snapshot, and a prompt of
+    150 tokens in slots of 256."""
+    cfg = mellum.cfg
+    assert mellum._snapshots.bytes_each == cfg.snapshot_bytes(256) == 6 * 2 * 2 * 16 * 16 * 4
+    snap0 = mellum.stats.snapshot()
+    assert snap0["state_bytes_window"] == 4 * cfg.snapshot_bytes(256)
+    assert snap0["state_bytes_full"] == 4 * 2 * 2 * 2 * 256 * 16 * 4
+    cold = [_prompt(21, 20), _prompt(22, 31)]
+    for p, o in zip(cold, _generate(mellum, cold)):
+        assert len(o) == 6 and _mellum_gap(mellum, p, o) <= GAP
+    first = _prompt(23, 100)  # chunks of 32: snapshots at 32, 64, 96
+    before = mellum.stats.snapshot()
+    (out,) = _generate(mellum, [first])
+    mid = mellum.stats.snapshot()
+    assert mid["prefill_chunks"] - before["prefill_chunks"] == 4
+    assert mid["state_snapshots_saved"] - before["state_snapshots_saved"] == 3
+    assert _mellum_gap(mellum, first, out) <= GAP
+    again = first[:70] + _prompt(24, 25)  # rows match to 70, the rings exist at 64
+    (hit,) = _generate(mellum, [again])
+    after = mellum.stats.snapshot()
+    assert after["shared_prefix_hits"] - mid["shared_prefix_hits"] == 1
+    assert after["state_snapshots_restored"] - mid["state_snapshots_restored"] == 1
+    assert after["prefix_tokens_matched"] - mid["prefix_tokens_matched"] == 70
+    assert after["prefix_tokens_reused"] - mid["prefix_tokens_reused"] == 64
+    assert _mellum_gap(mellum, again, hit) <= GAP
+    long = _prompt(25, 150)
+    (o,) = _generate(mellum, [long])
+    assert len(o) == 6 and _mellum_gap(mellum, long, o) <= GAP
+    # The rows the attention layers read, by kind and phase, came out with
+    # the tokens: a window layer reads its ring where a full one reads the
+    # bucket, so it reads less than it would as a full layer.
+    end = mellum.stats.snapshot()
+    for phase in ("decode", "prefill"):
+        read, dense = (end[f"attn_rows_{n}_window_{phase}"] for n in ("read", "dense"))
+        assert 0 < read < dense and end[f"attn_rows_read_full_{phase}"] * 3 == dense
+    assert end["moe_choices_local"] == end["moe_choices_routed"] > 0  # every expert is here
+
+
+def test_mellum_next_occupants_start_from_nothing_and_metrics_are_exported(mellum):
+    for seed in (26, 27, 28, 29, 30):  # more prompts than slots: every slot is reused
+        p = _prompt(seed, 70)
+        (o,) = _generate(mellum, [p], n=3)
+        assert _mellum_gap(mellum, p, o) <= GAP
+    from generativeaiexamples_tpu.engine.server import create_engine_app
+    from generativeaiexamples_tpu.engine.tokenizer import ByteTokenizer
+
+    app = create_engine_app(mellum, ByteTokenizer(), model_name="mellum-tiny")
+    loop = asyncio.new_event_loop()
+    client = TestClient(TestServer(app), loop=loop)
+    loop.run_until_complete(client.start_server())
+
+    async def go():
+        return await (await client.get("/metrics")).text()
+
+    try:
+        metrics = loop.run_until_complete(go())
+    finally:
+        loop.run_until_complete(client.close())
+        loop.close()
+    for name in ("engine_attn_rows_read_window_decode_total", "engine_attn_rows_read_full_decode_total",
+                 "engine_attn_rows_dense_window_decode_total", "engine_attn_rows_read_window_prefill_total",
+                 "engine_attn_rows_read_full_prefill_total", "engine_attn_rows_dense_window_prefill_total",
+                 "engine_state_bytes_full", "engine_state_bytes_window", "engine_state_snapshot_bytes",
+                 "engine_moe_experts_touched_total"):
+        assert f"\n{name} " in metrics, name
