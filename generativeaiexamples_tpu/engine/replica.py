@@ -1138,6 +1138,7 @@ class EnginePool:
         "shared_prefix_hits",
         "prefill_chunks",
         "prefill_chunks_ahead",
+        "prefill_chunk_programs",
         "decode_chunks_ahead",
         "decode_tokens_dropped",
         "spec_rounds",

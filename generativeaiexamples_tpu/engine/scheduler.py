@@ -146,6 +146,16 @@ class _Slot:
     accept_ewma: float = 1.0
 
 
+@dataclasses.dataclass(frozen=True)
+class _Chunk:
+    """A warming slot's next prefill chunk, about to be dispatched: ``n``
+    tokens of its prompt from position ``pos``."""
+
+    slot_idx: int
+    pos: int
+    n: int
+
+
 # What the tick thread can be doing; it is in exactly one at any instant.
 #   idle         blocked on the empty queue: no work anywhere
 #   plan         host-only bookkeeping: page eviction, slot scans, prefix
@@ -168,7 +178,8 @@ TICK_RECORD_FIELDS = (
     ("tick", "t_start", "wall_start")
     + tuple(f"{p}_s" for p in TICK_PHASES)
     + ("starved_s", "prefill_chunks", "admitted", "decode_lanes",
-       "kv_bucket", "tokens", "queued", "decode_ahead")
+       "kv_bucket", "tokens", "queued", "decode_ahead",
+       "prefill_chunk_programs")
 )
 
 
@@ -277,6 +288,31 @@ class _TickClock:
 _SPAN_NAMES = {p: f"tick/{p}" for p in TICK_PHASES}
 
 
+def make_prefill_suffix_rows(model):
+    """The step program for the prefill chunks of several slots at once,
+    for a serving model that has ``prefill_rows`` (``Scheduler`` compiles
+    it for every group size and window it will dispatch;
+    ``tests/test_chip_compile.py`` for the described chip)."""
+
+    @functools.partial(jax.jit, donate_argnums=(1,), static_argnums=(8,))
+    def _prefill_suffix_rows(
+        params, cache, tokens, start, suffix_len, slots, key, sampling, window
+    ):
+        """``_prefill_suffix`` for the chunks of several slots in one
+        program: tokens (B, s) of slots ``slots`` from positions
+        ``start``, each row's first ``suffix_len`` counting; a row of
+        length 0 is padding.  One sampled token a row."""
+        temp, top_p, top_k = sampling
+        cache, hidden, aux = model.prefill_rows(
+            params, cache, tokens, start, suffix_len, slots, window
+        )
+        last = hidden[jnp.arange(tokens.shape[0]), jnp.maximum(suffix_len - 1, 0)]
+        lg = model.logits(params, last[:, None, :])[:, 0]
+        return cache, sample(lg, key, temp, top_p, top_k), aux
+
+    return _prefill_suffix_rows
+
+
 class Stats:
     """Served-token counters surfaced by /metrics."""
 
@@ -318,8 +354,13 @@ class Stats:
         self.shared_prefix_hits = 0
         self.prefill_chunks = 0
         # Of those, chunks dispatched behind a decode chunk and before
-        # the host blocked on its tokens (``_advance_warm(ahead=True)``).
+        # the host blocked on its tokens (``_advance_warming(ahead=True)``).
         self.prefill_chunks_ahead = 0
+        # Programs dispatched for those chunks: a chunk alone is a program
+        # of one, the chunks of several slots may share one
+        # (``Scheduler._send_chunks``), so chunks / programs is the mean
+        # number of chunks a weight pass served.
+        self.prefill_chunk_programs = 0
         # Decode chunks dispatched while the one before was still
         # unfetched (a full house: ``Scheduler._goes_ahead``), counted at
         # their fetch like ``decode_chunks``; and the tokens a chunk
@@ -485,6 +526,7 @@ class Stats:
                 "shared_prefix_hits": self.shared_prefix_hits,
                 "prefill_chunks": self.prefill_chunks,
                 "prefill_chunks_ahead": self.prefill_chunks_ahead,
+                "prefill_chunk_programs": self.prefill_chunk_programs,
                 "decode_chunks_ahead": self.decode_chunks_ahead,
                 "decode_tokens_dropped": self.decode_tokens_dropped,
                 "spec_rounds": self.spec_rounds,
@@ -834,8 +876,8 @@ class Scheduler:
         # fetched: drained once ready, after a token fetch, so that they
         # cost no synchronisation of their own.
         self._aux_pending: list = []
-        # Token futures of the chunks the last tick sent ahead, in the
-        # device's order (``_tick`` waits for the first of several).
+        # Token futures of the chunk programs the last tick sent ahead, in
+        # the device's order (``_tick`` waits for the first of several).
         self._ahead_toks: list = []
         # The decode chunk dispatched ahead, while the one before it was
         # unfetched (``_goes_ahead``): ``_decode_finalize``'s arguments and
@@ -888,6 +930,7 @@ class Scheduler:
         # warming chunks dispatched, requests claimed, the decode chunk's
         # attention window, and whether the tick touched the device.
         self._tick_chunks = 0
+        self._tick_chunk_programs = 0
         self._tick_admitted = 0
         self._tick_kv_bucket = 0
         self._tick_ahead = 0
@@ -999,8 +1042,22 @@ class Scheduler:
 
         self._prefill_some = _prefill_some
         self._prefill_suffix = _prefill_suffix
+        self._prefill_suffix_rows = make_prefill_suffix_rows(model)
         self._graft_rows = _graft_rows
         self._graft_prefix = _graft_prefix
+        # The chunks that one tick sends for several warming slots go out
+        # as one program where the model says its chunk is a weight stream
+        # that more token rows could share (``chunks_per_program``): the
+        # largest group, 1 where every chunk goes alone through
+        # ``_prefill_suffix``.  The chunk programs of such a model are a
+        # closed family, compiled here, so that no traffic can ask for one
+        # inside a request.
+        self._chunk_rows, self._chunk_windows = 1, ()
+        self._chunk_programs: dict[tuple[int, int], Callable] = {}
+        if prefill_chunk_tokens and self._pool is None and draft_cfg is None:
+            self._compile_chunk_programs(
+                model.chunks_per_program(prefill_chunk_tokens)
+            )
 
         if self._pool is not None:
             page_tokens_arg = self.kv_page_size
@@ -1846,21 +1903,26 @@ class Scheduler:
         slot.unfetched = 1
         return req, slot_idx, tok, ticket
 
-    def _prefill_suffix_begin(self, n: int, s: int, kv_bucket: int) -> None:
-        """Count a ``_prefill_suffix`` dispatch of ``n`` real tokens in a
-        bucket of ``s`` and enter the dispatch phase."""
+    def _prefill_suffix_begin(
+        self, n: int, s: int, kv_bucket: int, rows: int = 1,
+        program: str = "_prefill_suffix",
+    ) -> None:
+        """Count a ``_prefill_suffix`` dispatch of ``n`` real tokens in
+        ``rows`` buckets of ``s`` (a group's padding rows are padding too)
+        and enter the dispatch phase."""
         with self.stats.lock:
             self.stats.prefill_tokens_dispatched += n
-            self.stats.prefill_tokens_padded += s - n
+            self.stats.prefill_tokens_padded += rows * s - n
         self._clock.enter(
-            "dispatch", program="_prefill_suffix", tokens=n, bucket=s,
-            kv_bucket=kv_bucket,
+            "dispatch", program=program, tokens=n, bucket=s,
+            kv_bucket=kv_bucket, rows=rows,
         )
 
-    def _suffix_finalize(self, req, slot_idx, tok, ticket) -> None:
-        """Fetch a suffix prefill's first token and emit it."""
+    def _suffix_finalize(self, req, slot_idx, tok, ticket, row=0) -> None:
+        """Fetch a suffix prefill's first token (``row`` of its program's)
+        and emit it."""
         self._clock.enter("wait_device")
-        tok_host = int(np.asarray(tok)[0])
+        tok_host = int(np.asarray(tok)[row])
         self._clock.fetched(ticket)
         self._clock.enter("emit")
         self._drain_aux()
@@ -2094,26 +2156,50 @@ class Scheduler:
         self._claim_warm(req, slot_idx, 0)
 
     def _advance_warm(
-        self, slot_idx: int, ahead: bool = False
+        self, slot_idx: int
     ) -> tuple[Optional[Callable[[], None]], int]:
-        """Dispatch one prefill chunk for a warming slot.
+        """Dispatch a prompt's first chunk for the slot its admission has
+        just claimed (:meth:`_advance_warming` for one slot)."""
+        (out,) = self._advance_warming([slot_idx])
+        return out
+
+    def _advance_warming(
+        self, slot_idxs: Sequence[int], ahead: bool = False
+    ) -> list[tuple[Optional[Callable[[], None]], int]]:
+        """Dispatch one prefill chunk for each warming slot of
+        ``slot_idxs``; the chunks of several slots go out as one program
+        where the model's chunk is a weight stream that their token rows
+        can share (:meth:`_chunk_groups`).
 
         Intermediate chunks need no host sync at all — the sampled token
         future is dropped and the cache future flows on.  The FINAL chunk
         returns a finalize callable that fetches the prompt's first
         token; the pipelined tick runs it after the decode dispatch so
         the chunk rides the device stream ahead of the decode like every
-        other admission.  Returns (finalize_or_None, chunk_tokens).
+        other admission.  Returns (finalize_or_None, chunk_tokens) a
+        slot, in the device's order.
 
         ``ahead``: the tick calls this behind its decode dispatch, before
-        it blocks on anything, for the chunk the NEXT tick's phase 1
-        would send — its tokens were known when the last chunk went out,
-        so the device runs it while the host emits and plans.  The slot
-        keeps the chunk's size and finalizer (``ahead_tokens``,
-        ``first_token``) and this call returns (None, 0); the next tick's
-        phase-1 call dispatches nothing and returns them, so the chunk is
+        it blocks on anything, for the chunks the NEXT tick's phase 1
+        would send — their tokens were known when the last chunks went
+        out, so the device runs them while the host emits and plans.  A
+        slot keeps its chunk's size and finalizer (``ahead_tokens``,
+        ``first_token``) and gets (None, 0) here; the next tick's phase-1
+        call dispatches nothing for it and returns them, so the chunk is
         charged to that tick's budget and its first token joins where it
         would have.  Every counter is counted here, at the dispatch."""
+        out, chunks = [], []
+        for i in slot_idxs:
+            nxt = self._next_chunk(i, ahead)
+            (chunks if isinstance(nxt, _Chunk) else out).append(nxt)
+        for group in self._chunk_groups(chunks):
+            out.extend(self._send_chunks(group, ahead))
+        return out
+
+    def _next_chunk(self, slot_idx: int, ahead: bool):
+        """A warming slot's part of this call: the ``_Chunk`` to dispatch
+        for it, or what it gets with nothing dispatched, as
+        (finalize_or_None, chunk_tokens)."""
         slot = self._slots[slot_idx]
         req = slot.request
         if req is None or (slot.warm_pos is None and not slot.ahead_tokens):
@@ -2136,14 +2222,123 @@ class Scheduler:
             # tick's chunk would not.
             return None, 0
         pos = slot.warm_pos
-        plen = slot.length
-        n = min(self.prefill_chunk_tokens, plen - pos)
-        chunk = slot.history[pos : pos + n]
+        return _Chunk(
+            slot_idx, pos, min(self.prefill_chunk_tokens, slot.length - pos)
+        )
+
+    def _chunk_window(self, need: int) -> int:
+        """The attention window of a group's program whose widest row
+        reaches ``need`` rows (the widest there is, if it pads past it)."""
+        return next((w for w in self._chunk_windows if w >= need), self.max_len)
+
+    def _compile_chunk_programs(self, most: int) -> None:
+        """Build the family of chunk programs: ``_prefill_suffix_rows``
+        for 1, 2, 4, ... up to ``most`` rows (a group between two sizes
+        is padded) at windows that double from eight chunks up to
+        ``max_len``: far coarser than ``_prefill_suffix``'s dense
+        ``kv_bucket``s, since a row pays a wider window in its attention
+        alone and every window is a program to build for each size.  Each
+        is lowered against the shapes its dispatch will have and compiled
+        now (the persistent cache serves a second run).  Every chunk,
+        grouped or alone, is dispatched through these executables, so
+        nothing the traffic does can compile one later: a lone chunk
+        through ``_prefill_suffix`` would lean on a warm-up to have sent
+        its position alone, which a warm-up whose chunks went out in
+        groups has not."""
+        most = min(most, self.max_batch)
+        if most < 2:
+            return
+        sizes = [1 << k for k in range(most.bit_length())]
+        s = self._chunk_bucket()
+        windows = [min(8 * self.prefill_chunk_tokens, self.max_len)]
+        while windows[-1] < self.max_len:
+            windows.append(min(2 * windows[-1], self.max_len))
+        t0 = time.perf_counter()
+        for rows in sizes:
+            ints = jax.ShapeDtypeStruct((rows,), jnp.int32)
+            floats = jax.ShapeDtypeStruct((rows,), jnp.float32)
+            for window in windows:
+                self._chunk_programs[rows, window] = self._prefill_suffix_rows.lower(
+                    self.params, self._cache,
+                    jax.ShapeDtypeStruct((rows, s), jnp.int32), ints, ints, ints,
+                    self._key, (floats, floats, ints), window,
+                ).compile()
+        self._chunk_rows, self._chunk_windows = sizes[-1], tuple(windows)
+        logger.info(
+            "chunk programs: %s rows x windows %s compiled in %.1f s",
+            sizes, windows, time.perf_counter() - t0,
+        )
+
+    def _chunk_bucket(self) -> int:
+        """Token columns of a group's program: a whole chunk's bucket (a
+        prompt's shorter last chunk is padded to it)."""
+        return min(
+            bucket_size(self.prefill_chunk_tokens, minimum=16, dense=True),
+            self.max_len,
+        )
+
+    def _chunk_groups(self, chunks: list[_Chunk]) -> list[list[_Chunk]]:
+        """Partition one tick's chunks into programs: alone where the
+        model's chunk shares nothing (``_chunk_rows`` 1), else in groups
+        of at most ``_chunk_rows``, rows of alike reach together so that
+        a short prompt seldom pays a long one's window."""
+        most = self._chunk_rows
+        chunks = sorted(chunks, key=lambda c: c.pos) if most > 1 else chunks
+        return [chunks[i : i + most] for i in range(0, len(chunks), most)]
+
+    def _send_chunks(
+        self, group: list[_Chunk], ahead: bool
+    ) -> list[tuple[Optional[Callable[[], None]], int]]:
+        """Dispatch one program for ``group`` (of the family where the
+        model has one, else the lone chunk's ``_prefill_suffix``) and book
+        each of its rows."""
+        if self._chunk_programs:
+            tok = self._dispatch_chunk_rows(group)
+        else:
+            (lone,) = group
+            tok = self._dispatch_chunk(lone)
+        ticket = self._clock.dispatched()
+        self._clock.enter("plan")
+        self._tick_chunks += len(group)
+        self._tick_chunk_programs += 1
+        with self.stats.lock:
+            self.stats.prefill_chunks += len(group)
+            self.stats.prefill_chunks_ahead += len(group) * ahead
+            self.stats.prefill_chunk_programs += 1
+        if ahead:
+            self._ahead_toks.append(tok)
+        out = []
+        for row, c in enumerate(group):
+            slot = self._slots[c.slot_idx]
+            fin = None
+            if c.pos + c.n < slot.length:
+                slot.warm_pos = c.pos + c.n
+            else:
+                # Final chunk: prefill complete — the slot joins decode in
+                # the tick after the one its first token is fetched in.
+                slot.warm_pos = None
+                slot.unfetched = 1
+                fin = functools.partial(
+                    self._suffix_finalize, slot.request, c.slot_idx, tok,
+                    ticket, row,
+                )
+            if ahead:
+                slot.ahead_tokens, slot.first_token = c.n, fin
+                out.append((None, 0))
+            else:
+                out.append((fin, c.n))
+        return out
+
+    def _dispatch_chunk(self, c: _Chunk):
+        """One slot's chunk as a program of its own; returns the token
+        future (1,)."""
+        slot_idx, pos, n = c.slot_idx, c.pos, c.n
+        slot = self._slots[slot_idx]
         s = min(bucket_size(n, minimum=16, dense=True), self.max_len)
         tokens = np.zeros((1, s), dtype=np.int32)
-        tokens[0, :n] = chunk
+        tokens[0, :n] = slot.history[pos : pos + n]
         kv_bucket = bucket_size(pos + s, maximum=self.max_len, dense=True)
-        sp = req.sampling
+        sp = slot.request.sampling
         self._prefill_suffix_begin(n, s, kv_bucket)
         sampling_dev = (
             jnp.asarray([sp.temperature], dtype=jnp.float32),
@@ -2197,26 +2392,39 @@ class Scheduler:
                 jnp.int32(slot_idx),
                 kv_bucket,
             )
-        ticket = self._clock.dispatched()
-        self._clock.enter("plan")
-        self._tick_chunks += 1
-        with self.stats.lock:
-            self.stats.prefill_chunks += 1
-            self.stats.prefill_chunks_ahead += ahead
-        fin = None
-        if pos + n < plen:
-            slot.warm_pos = pos + n
-        else:
-            # Final chunk: prefill complete — the slot joins decode in
-            # the tick after the one its first token is fetched in.
-            slot.warm_pos = None
-            slot.unfetched = 1
-            fin = lambda: self._suffix_finalize(req, slot_idx, tok, ticket)
-        if ahead:
-            slot.ahead_tokens, slot.first_token = n, fin
-            self._ahead_toks.append(tok)
-            return None, 0
-        return fin, n
+        return tok
+
+    def _dispatch_chunk_rows(self, group: list[_Chunk]):
+        """The chunks of one or several slots as one program of the family
+        (:meth:`_compile_chunk_programs`): the group padded to the next
+        size with rows of no tokens, which write to no slot, over the
+        window its widest row needs.  Returns the token future, one
+        entry a row."""
+        rows = bucket_size(len(group), minimum=1)
+        s = self._chunk_bucket()
+        tokens = np.zeros((rows, s), np.int32)
+        start, lens, slots = (np.zeros((rows,), np.int32) for _ in range(3))
+        temp, top_p = np.zeros((rows,), np.float32), np.ones((rows,), np.float32)
+        top_k = np.zeros((rows,), np.int32)
+        for r, c in enumerate(group):
+            slot = self._slots[c.slot_idx]
+            tokens[r, : c.n] = slot.history[c.pos : c.pos + c.n]
+            start[r], lens[r], slots[r] = c.pos, c.n, c.slot_idx
+            sp = slot.request.sampling
+            temp[r], top_p[r], top_k[r] = sp.temperature, sp.top_p, sp.top_k
+        window = self._chunk_window(max(c.pos for c in group) + s)
+        self._prefill_suffix_begin(
+            int(lens.sum()), s, window, rows=rows, program="_prefill_suffix_rows"
+        )
+        cache, tok, aux = self._chunk_programs[rows, window](
+            self.params, self._cache, *map(jnp.asarray, (tokens, start, lens, slots)),
+            self._next_key(), tuple(map(jnp.asarray, (temp, top_p, top_k))),
+        )
+        self._cache = cache
+        self._note_aux(aux)
+        for c in group:
+            self._save_boundary(self._slots[c.slot_idx], c.slot_idx, c.pos + c.n)
+        return tok
 
     def _first_token(self, slot_idx: int, req: Request, tid: int) -> None:
         """A prompt's first token has been fetched: it is on the device
@@ -2367,7 +2575,7 @@ class Scheduler:
                     self._tick_chunks, self._tick_admitted,
                     self._tick_decoded, self._tick_kv_bucket,
                     self._tick_tokens, self.stats.queued,
-                    self._tick_ahead,
+                    self._tick_ahead, self._tick_chunk_programs,
                 )
             )
 
@@ -2508,6 +2716,7 @@ class Scheduler:
         self._tick_tokens = 0
         self._tick_decoded = 0
         self._tick_chunks = 0
+        self._tick_chunk_programs = 0
         self._tick_admitted = 0
         self._tick_kv_bucket = 0
         self._tick_ahead = 0
@@ -2560,18 +2769,20 @@ class Scheduler:
         # A slot whose chunk the last tick sent ahead (below) is only
         # booked here: its chunk leads this tick's programs on the device
         # as it would have, dispatched before the host's gap, not after.
-        for i in self._warming():
-            fin, n = self._advance_warm(i)
+        warming = self._warming()
+        progressed = bool(warming)
+        for fin, n in self._advance_warming(warming):
             budget -= n
             settle(fin)
-            progressed = True
         # The admissions below are read where they used to be: phase 1's
         # dispatches took 20-30 ms between the last tick's emit and this
         # poll, long enough for a client that sends its next request when
         # its reply ends to be in the queue; with nothing left to
         # dispatch the poll would come at once and that request would
-        # wait a whole tick.  So where several chunks went ahead, wait
-        # for the first: the device has the others to run meanwhile.
+        # wait a whole tick.  So where several chunk programs went ahead,
+        # wait for the first: the device has the others to run meanwhile.
+        # (Chunks that share one program leave no first to wait for: the
+        # poll then comes at once, as it does behind a single chunk.)
         # (Not with a decode chunk in flight from the last tick: the house
         # was full then, and its tokens are the next thing to fetch.)
         sent, self._ahead_toks = self._ahead_toks, []
@@ -2790,8 +3001,7 @@ class Scheduler:
             # goes out behind the decode chunk and the device has work
             # while the host emits this tick's tokens, books the tick and
             # plans the next.  Same programs in the same order.
-            for i in self._warming():
-                self._advance_warm(i, ahead=True)
+            self._advance_warming(self._warming(), ahead=True)
         # Fetches follow the device's order: a chunk that was in flight
         # when this tick began ran before this tick's admissions.
         fetch = []

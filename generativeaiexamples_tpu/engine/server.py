@@ -722,6 +722,10 @@ async def handle_metrics(request: web.Request) -> web.Response:
         # blocked on its tokens.
         "# TYPE engine_prefill_chunks_ahead_total counter",
         f"engine_prefill_chunks_ahead_total {snap.get('prefill_chunks_ahead', 0)}",
+        # Programs dispatched for those chunks (one may carry the chunks
+        # of several slots): chunks / programs = chunks a weight pass.
+        "# TYPE engine_prefill_chunk_programs_total counter",
+        f"engine_prefill_chunk_programs_total {snap.get('prefill_chunk_programs', 0)}",
         "# TYPE engine_decode_chunks_ahead_total counter",
         f"engine_decode_chunks_ahead_total {snap.get('decode_chunks_ahead', 0)}",
         "# TYPE engine_decode_tokens_dropped_total counter",

@@ -8,6 +8,9 @@ from these calls and knows nothing else about the model:
 ``init_state``       the slots' state (``max_batch`` rows of ``max_len``)
 ``prefill_cold``     a batch of whole prompts into fresh state
 ``prefill_row``      a run of one slot's prompt from a given position
+``chunks_per_program``   how many slots' prefill chunks may share one
+                     program (1: ``prefill_row`` is all there is), and then
+``prefill_rows``     runs of several slots' prompts, each from its position
 ``graft_rows``       rows of a cold batch's state into their slots
 ``graft_prefix``     the first n token rows of one slot into another
 ``save_state`` / ``restore_state``   a snapshot of what cannot be cut at a
@@ -57,6 +60,12 @@ class LlamaServing:
 
     def check_supported(self, **_) -> None:
         """Every option of the scheduler serves this model."""
+
+    def chunks_per_program(self, chunk_tokens: int) -> int:
+        """Every weight matrix multiplies every token of a chunk (a dense
+        projection; the one-hot expert dispatch of ``llama._moe_mlp``), so
+        one chunk is all the rows its weight pass has use for."""
+        return 1
 
     def prepare_params(self, params, *, quantize, matmul_kernel, seed):
         from generativeaiexamples_tpu.engine.decode import prepare_params
@@ -278,6 +287,66 @@ class HybridServing:
             cache = jax.tree.map(
                 lambda bg, r: jax.lax.dynamic_update_slice_in_dim(bg, r, slot, axis=0),
                 cache, row,
+            )
+        return cache, hidden, self._aux(counters, decode=False)
+
+    # The most chunks one program takes, whatever the rule below allows.
+    MAX_CHUNKS_PER_PROGRAM = 8
+
+    def chunks_per_program(self, chunk_tokens: int) -> int:
+        """How many slots' chunks may go through the model as one program:
+        as many as keep the rows one expert's matrices see within the
+        grouped product's row tile (``moe.ROW_TILE``), below which a chunk
+        pays for the whole expert stream whatever its rows.  A chunk
+        brings an expert ``chunk_tokens x n_experts_per_tok / n_experts``
+        rows (a share held here sees its share of the choices): 32 at the
+        64 experts of Mellum's cut, 4 at Ling's 512.  A model with no
+        expert layer is a dense one: 1."""
+        cfg = self.cfg
+        if not any(mlp == "experts" for _, mlp in cfg.layer_kinds):
+            return 1
+        rows = -(-chunk_tokens * cfg.n_experts_per_tok // cfg.n_experts)
+        return max(1, min(self.MAX_CHUNKS_PER_PROGRAM, moe.ROW_TILE // rows))
+
+    def prefill_rows(self, params, cache, tokens, start, suffix_len, slots, window):
+        """``prefill_row`` for the chunks of several slots at once: tokens
+        (B, s) of slots ``slots`` (B,) from positions ``start`` (B,), of
+        which the first ``suffix_len`` (B,) count, over one static
+        ``window`` of rows.  Each layer's weights pass once for all B x s
+        token rows; a row gets what it gets alone.  A pad row
+        (``suffix_len`` 0) routes to no expert and writes to no slot,
+        whatever ``slots`` says of it.  Returns (cache, hidden (B, s, D),
+        counters)."""
+        live = suffix_len > 0
+
+        def per_row(x, like):
+            return x.reshape(x.shape + (1,) * (like.ndim - 1))
+
+        def take(name, leaf):
+            if name in hybrid.ROW_LEAVES:
+                return leaf[slots, :window]
+            row = leaf[slots]
+            if name in hybrid.RING_LEAVES:
+                return row
+            # As ``prefill_row``: a prompt that starts here starts from nothing.
+            return jnp.where(per_row(start == 0, row), 0, row)
+
+        rows = tuple({n: take(n, leaf) for n, leaf in layer.items()} for layer in cache)
+        hidden, rows, counters = hybrid.forward(
+            params, self.cfg, tokens, start, suffix_len, rows, window=window,
+            mesh=self.mesh, rows_apart=True,
+        )
+        # A pad row's slot is past the last: its write is dropped.
+        dest = jnp.where(live, slots, jax.tree.leaves(cache)[0].shape[0])
+        with jax.named_scope("kv_write"):
+            cache = tuple(
+                {
+                    n: (
+                        leaf.at[dest, :window] if n in hybrid.ROW_LEAVES else leaf.at[dest]
+                    ).set(row[n], mode="drop")
+                    for n, leaf in layer.items()
+                }
+                for layer, row in zip(cache, rows)
             )
         return cache, hidden, self._aux(counters, decode=False)
 

@@ -511,6 +511,28 @@ def state_bytes(cfg: HybridConfig, batch: int, max_len: int) -> dict[str, int]:
 # -- layers ---------------------------------------------------------------------
 
 
+def _attend(attend, n_valid, apart: bool, *rows):
+    """``attend(*rows)`` over the leading (row) axis of every operand: all
+    rows in one product, or, ``apart``, a row at a time.  XLA materialises
+    a layer's float32 scores (heads x queries x window: 268 MB a row for
+    32 heads, a chunk of 256 and 8,192 rows), so the chunks of several
+    slots in one program attend one after the other; a row with nothing
+    that counts (a group's padding) is passed over."""
+    if not apart:
+        return attend(*rows)
+    shape = jax.eval_shape(attend, *rows)
+
+    def one(args):
+        n, *row = args
+        return jax.lax.cond(
+            n > 0,
+            lambda: attend(*(r[None] for r in row))[0],
+            lambda: jnp.zeros(shape.shape[1:], shape.dtype),
+        )
+
+    return jax.lax.map(one, (n_valid, *rows))
+
+
 def _kda_mixer(h, lp, st, valid, n_valid, cfg: HybridConfig):
     b, s, _ = h.shape
     H, K = cfg.n_heads, cfg.kda_head_dim
@@ -543,7 +565,7 @@ def _kda_mixer(h, lp, st, valid, n_valid, cfg: HybridConfig):
     return out, {"S": S, "conv": tail.astype(st["conv"].dtype)}
 
 
-def _mla_mixer(h, lp, st, pos, valid, cfg: HybridConfig, window: int):
+def _mla_mixer(h, lp, st, pos, valid, n_valid, cfg: HybridConfig, window: int, apart: bool):
     b, s, _ = h.shape
     H, rank = cfg.n_heads, cfg.kv_lora_rank
     nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
@@ -560,10 +582,13 @@ def _mla_mixer(h, lp, st, pos, valid, cfg: HybridConfig, window: int):
         # A token that does not count is written nowhere.
         at = jnp.where(valid, pos, T)
         latent = st["latent"].at[jnp.arange(b)[:, None], at].set(new, mode="drop")
-    attend = mla.attend_absorbed if s == 1 else mla.attend_expanded
-    o = attend(
-        q_nope, q_rope, latent[:, :window], lp["w_kvb"], pos,
-        rank=rank, nope=nope, v_dim=vd,
+    attend = functools.partial(
+        mla.attend_absorbed if s == 1 else mla.attend_expanded,
+        w_kvb=lp["w_kvb"], rank=rank, nope=nope, v_dim=vd,
+    )
+    o = _attend(
+        lambda qn, qr, lat, p: attend(qn, qr, lat, q_pos=p),
+        n_valid, apart, q_nope, q_rope, latent[:, :window], pos,
     )
     with jax.named_scope("layer/mla/wo"):
         gate = jax.nn.sigmoid(jnp.dot(h, lp["w_gate"], preferred_element_type=F32))
@@ -572,7 +597,9 @@ def _mla_mixer(h, lp, st, pos, valid, cfg: HybridConfig, window: int):
     return out, {"latent": latent}
 
 
-def _gqa_mixer(h, lp, st, mixer, pos, valid, n_valid, cfg: HybridConfig, window: int):
+def _gqa_mixer(
+    h, lp, st, mixer, pos, valid, n_valid, cfg: HybridConfig, window: int, apart: bool
+):
     """A ``full`` or ``window`` layer.  Returns (output, state, counters
     in the order of ``ATTN_COUNTERS``): a full layer writes its rows and
     attends over the first ``window`` of them; a window layer attends
@@ -604,7 +631,10 @@ def _gqa_mixer(h, lp, st, mixer, pos, valid, n_valid, cfg: HybridConfig, window:
     if mixer == "full":
         new_k, new_v = write(jnp.where(valid, pos, rows))
         with jax.named_scope(f"{scope}/attend"):
-            o = gqa.attend_rows(q, new_k[:, :window], new_v[:, :window], pos, n_kv=KH)
+            o = _attend(
+                functools.partial(gqa.attend_rows, n_kv=KH), n_valid, apart,
+                q, new_k[:, :window], new_v[:, :window], pos,
+            )
         read = (0, b * min(window, rows), 0)
     else:
         with jax.named_scope(f"{scope}/attend"):
@@ -644,7 +674,10 @@ def _expert_layer(h, lp, valid, cfg: HybridConfig, mesh):
     return y.reshape(b, s, d), counters
 
 
-def _mix(x, lp, st, mixer, pos, valid, n_valid, cfg: HybridConfig, window: int):
+def _mix(
+    x, lp, st, mixer, pos, valid, n_valid, cfg: HybridConfig, window: int,
+    apart: bool = False,
+):
     """The mixer's half of a layer: (x + mixer(norm(x)), new state, a GQA
     layer's ``ATTN_COUNTERS`` or 0)."""
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
@@ -652,9 +685,11 @@ def _mix(x, lp, st, mixer, pos, valid, n_valid, cfg: HybridConfig, window: int):
     if mixer == "kda":
         y, st = _kda_mixer(h, lp, st, valid, n_valid, cfg)
     elif mixer == "mla":
-        y, st = _mla_mixer(h, lp, st, pos, valid, cfg, window)
+        y, st = _mla_mixer(h, lp, st, pos, valid, n_valid, cfg, window, apart)
     else:
-        y, st, read = _gqa_mixer(h, lp, st, mixer, pos, valid, n_valid, cfg, window)
+        y, st, read = _gqa_mixer(
+            h, lp, st, mixer, pos, valid, n_valid, cfg, window, apart
+        )
     return x + y, st, read
 
 
@@ -678,12 +713,14 @@ def forward(
     *,
     window: int,
     mesh=None,
+    rows_apart: bool = False,
 ):
     """tokens (b, s) at positions ``start[b] + [0, s)``, of which the first
     ``n_valid[b]`` count; ``state`` is these rows' state; MLA layers
-    and full layers attend over the first ``window`` rows.  Returns
-    (hidden (b, s, D), state, counters (cfg.n_counters,) int32
-    summed over layers)."""
+    and full layers attend over the first ``window`` rows, ``rows_apart``
+    a row at a time (``_attend``: the same numbers, one row's scores in
+    memory).  Returns (hidden (b, s, D), state, counters
+    (cfg.n_counters,) int32 summed over layers)."""
     b, s = tokens.shape
     x = params["embed"][tokens]
     steps = jnp.arange(s, dtype=jnp.int32)[None, :]
@@ -693,7 +730,10 @@ def forward(
     read = jnp.zeros((len(ATTN_COUNTERS),), jnp.int32) if cfg.has_attn_counters else None
     out_state = []
     for (mixer, mlp), lp, st in zip(cfg.layer_kinds, params["layers"], state):
-        x, st, r = _mix(x, lp, st, mixer, pos, valid, n_valid.astype(jnp.int32), cfg, window)
+        x, st, r = _mix(
+            x, lp, st, mixer, pos, valid, n_valid.astype(jnp.int32), cfg, window,
+            rows_apart,
+        )
         x, c = _mlp(x, lp, mlp, valid, cfg, mesh)
         counters = counters + c
         if cfg.has_attn_counters:

@@ -234,3 +234,61 @@ def test_grouped_expert_products_compile_at_ling_widths(one_chip, tokens, family
         spec((held, F, D), jnp.bfloat16),
         spec((tokens,), jnp.bool_),
     )
+
+
+# (configuration under benchmarks/configs/, rows of the largest group its
+# rule allows, the widest window of its slots)
+GROUP_PROGRAMS = {
+    "mellum": ("mellum2-12b-a2.5b-l12", 4, 8192),
+    "ling": ("ling-3.0-flash-vl-l7e128", 8, 2048),
+}
+# What Mellum's cell has to spare beside its weights, slots and snapshots
+# (peak 15.19 of the 16.91 GB the build sees, less the reference check's
+# blocks: PERF.md section 4).
+SPARE_BYTES = 1_400_000_000
+
+
+@pytest.mark.parametrize("family", sorted(GROUP_PROGRAMS))
+def test_the_chunks_of_several_slots_compile_as_one_program(one_chip, family, monkeypatch):
+    """``_prefill_suffix_rows`` (the scheduler's program for the prefill
+    chunks of several slots) at the published widths, for the largest
+    group and the widest window each cell's family holds, against the
+    cell's own slot state (32 slots of 8,192 and of 2,048 rows): the
+    grouped products are in it, and its temporaries (a full layer's
+    float32 scores are 268 MB a row at 8,192, which is why the rows
+    attend one after the other) stay under what Mellum's cell has to
+    spare."""
+    import json
+    from pathlib import Path
+
+    from generativeaiexamples_tpu.engine.scheduler import make_prefill_suffix_rows
+    from generativeaiexamples_tpu.engine.serving_models import HybridServing
+    from generativeaiexamples_tpu.models import hybrid
+    from generativeaiexamples_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "platform_of", lambda mesh: "tpu")
+    config, rows, window = GROUP_PROGRAMS[family]
+    configs = Path(__file__).resolve().parents[1] / "benchmarks" / "configs"
+    model = json.loads((configs / f"{config}.json").read_text())
+    engine = model["engine"]
+    max_len, chunk = int(engine["max_len"]), int(engine["prefill_chunk_tokens"])
+    cfg = hybrid.from_hf_config(model, max_len=max_len, kv_dtype=engine["kv_dtype"])
+    serving = HybridServing(cfg, None, max_len)
+    assert serving.chunks_per_program(chunk) == rows and window == max_len
+
+    def described(make):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            jax.eval_shape(make),
+        )
+
+    spec = _spec(one_chip)
+    ints, floats = spec((rows,), jnp.int32), spec((rows,), jnp.float32)
+    compiled = make_prefill_suffix_rows(serving).lower(
+        described(lambda: hybrid.init_params(cfg, jax.random.PRNGKey(0))),
+        described(lambda: hybrid.init_state(cfg, int(engine["max_batch"]), max_len)),
+        spec((rows, chunk), jnp.int32), ints, ints, ints,
+        spec((2,), jnp.uint32), (floats, floats, ints), window,
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert compiled.memory_analysis().temp_size_in_bytes < SPARE_BYTES

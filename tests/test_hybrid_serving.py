@@ -152,7 +152,7 @@ def test_streamed_completion_through_the_http_front(scheduler):
     assert events and events[-1]["choices"][0]["finish_reason"] == "length"
     for name in ("engine_moe_choices_routed_total", "engine_moe_experts_touched_total",
                  "engine_prefix_tokens_matched_total", "engine_state_snapshots_saved_total",
-                 "engine_state_snapshot_bytes"):
+                 "engine_state_snapshot_bytes", "engine_prefill_chunk_programs_total"):
         assert f"\n{name} " in metrics, name
 
 
@@ -290,3 +290,135 @@ def test_mellum_next_occupants_start_from_nothing_and_metrics_are_exported(mellu
                  "engine_state_bytes_full", "engine_state_bytes_window", "engine_state_snapshot_bytes",
                  "engine_moe_experts_touched_total"):
         assert f"\n{name} " in metrics, name
+
+
+# -- the chunks of several slots in one program ---------------------------------------
+
+
+@pytest.mark.parametrize("family", ["ling", "mellum"])
+def test_prompts_that_warm_side_by_side_are_held_to_the_reference(family, request):
+    """Three chunked prompts admitted together: from their second chunk on
+    a tick sends their chunks as one program (three rows padded to four,
+    then two), and each greedy stream is the reference's."""
+    s = request.getfixturevalue("scheduler" if family == "ling" else "mellum")
+    prompts = [_prompt(50 + i, n) for i, n in enumerate((100, 120, 70))]
+    before = s.stats.snapshot()
+    outs = _generate(s, prompts, n=4)
+    after = s.stats.snapshot()
+    chunks = after["prefill_chunks"] - before["prefill_chunks"]
+    assert chunks == 4 + 4 + 3
+    # Alone: each prompt's first chunk and the longest's fourth; together: two of three, one of two.
+    assert after["prefill_chunk_programs"] - before["prefill_chunk_programs"] < chunks
+    gap = _worst_gap if family == "ling" else _mellum_gap
+    for p, o in zip(prompts, outs):
+        assert len(o) == 4 and gap(s, p, o) <= GAP
+
+
+# -- the chunks of several slots in one program: ``prefill_rows`` ---------------------
+
+SLOTS, MAX_LEN, WINDOW, S = 6, 128, 64, 8
+# (slot, tokens of the prompt before this chunk, tokens of the chunk that
+# count): a prompt's first chunk into a slot whose last occupant left
+# state, a chunk far past the window layers' ring of 16, and a prompt's
+# last chunk, shorter than the bucket of 8.
+ROWS = ((4, 0, 8), (1, 40, 8), (3, 19, 5))
+
+
+@pytest.fixture(scope="module", params=["ling-tiny", "mellum-tiny"])
+def rows_case(request):
+    """A serving model, its parameters, and slots whose state is what
+    ``prefill_row`` left of each row's prompt so far; every slot that is
+    no row's holds noise, which no call may touch."""
+    import jax
+    import jax.numpy as jnp
+
+    from generativeaiexamples_tpu.engine.serving_models import HybridServing
+
+    cfg = hybrid.PRESETS[resolve_model_preset(request.param)]()
+    model = HybridServing(cfg, None, MAX_LEN)
+    params = model.prepare_params(None, quantize=False, matmul_kernel=None, seed=7)
+    keys = iter(jax.random.split(jax.random.PRNGKey(11), 64))
+    state = jax.tree.map(
+        lambda x: jax.random.normal(next(keys), x.shape, jnp.float32).astype(x.dtype),
+        model.init_state(SLOTS, MAX_LEN),
+    )
+    prompts = {slot: _prompt(40 + slot, before + n) for slot, before, n in ROWS}
+    one = jax.jit(model.prefill_row, static_argnums=(6,))
+    for slot, before, _ in ROWS:
+        if before:
+            toks = jnp.zeros((1, 64), jnp.int32).at[0, :before].set(jnp.asarray(prompts[slot][:before]))
+            state, _, _ = one(
+                params, state, toks, jnp.int32(0), jnp.int32(before), jnp.int32(slot), 64
+            )
+    return model, params, state, prompts, one
+
+
+def _chunk_of(prompts, slot, before, n):
+    return np.pad(prompts[slot][before : before + n], (0, S - n))
+
+
+@pytest.mark.parametrize("n_rows", [2, 3])
+def test_rows_of_a_group_get_what_each_gets_alone(rows_case, n_rows):
+    """2 rows, and 3 padded to 4, at different starts and lengths over one
+    shared window: the hidden states and the state ``prefill_row`` gives
+    each row alone; every other slot is as it was, the slot that the pad
+    row names too."""
+    import jax
+    import jax.numpy as jnp
+
+    model, params, state, prompts, one = rows_case
+    rows = ROWS[:n_rows]
+    alone, want = state, {}
+    for slot, before, n in rows:
+        alone, hidden, _ = one(
+            params, alone, jnp.asarray(_chunk_of(prompts, slot, before, n))[None],
+            jnp.int32(before), jnp.int32(n), jnp.int32(slot), WINDOW,
+        )
+        want[slot] = np.asarray(hidden[0, :n])
+    pad = [(2, 7, 0)] * (-n_rows % 2)  # names slot 2, counts no token
+    tokens = np.stack([_chunk_of(prompts, *r) if r[2] else np.zeros(S, int) for r in (*rows, *pad)])
+    slots, start, lens = (jnp.asarray(c, jnp.int32) for c in zip(*rows, *pad))
+    together, hidden, counters = jax.jit(model.prefill_rows, static_argnums=(6,))(
+        params, state, jnp.asarray(tokens, jnp.int32), start, lens, slots, WINDOW
+    )
+    for r, (slot, _, n) in enumerate(rows):
+        np.testing.assert_allclose(np.asarray(hidden[r, :n]), want[slot], rtol=2e-5, atol=2e-5)
+    touched = {slot for slot, _, _ in rows}
+    for got, each, was in zip(*(jax.tree.leaves(t) for t in (together, alone, state))):
+        np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(each, np.float32), rtol=2e-5, atol=2e-5)
+        others = [i for i in range(SLOTS) if i not in touched]
+        np.testing.assert_array_equal(np.asarray(got)[others], np.asarray(was)[others])
+    # A pad row routes to no expert: the choices counted are the rows'.
+    assert int(counters[0]) == sum(n for _, _, n in rows) * model.cfg.n_experts_per_tok * sum(
+        mlp == "experts" for _, mlp in model.cfg.layer_kinds)
+
+
+@pytest.mark.parametrize("config, chunks", [
+    ("mellum2-12b-a2.5b-l12", 4), ("ling-3.0-flash-vl-l7e128", 8),
+    ("mistral-7b", 1), ("mixtral-8x7b-l4", 1),
+])
+def test_how_many_chunks_share_a_program_follows_from_the_rows_an_expert_sees(config, chunks):
+    """256 tokens x 8 choices over 64 experts are 32 rows an expert: 4
+    chunks fill ``gmm``'s row tile of 128; over 512 experts they are 4
+    rows: the cap of 8; a dense projection and Mixtral's one-hot
+    dispatch see every token: a chunk goes alone."""
+    import importlib
+    from pathlib import Path
+
+    from generativeaiexamples_tpu.engine.serving_models import serving_model
+
+    bench = Path(__file__).resolve().parents[1] / "benchmarks"
+    model = json.loads((bench / "configs" / f"{config}.json").read_text())
+    spec = importlib.util.spec_from_file_location(
+        "arch", bench / "arch" / f"{model.get('arch', 'llama')}.py")
+    import sys
+    sys.path.append(str(bench))
+    try:
+        arch = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(arch)
+        cfg = arch.llama_config(model, model["engine"])
+    finally:
+        sys.path.remove(str(bench))
+    engine = model["engine"]
+    serving = serving_model(cfg, None, int(engine["max_len"]))
+    assert serving.chunks_per_program(int(engine["prefill_chunk_tokens"])) == chunks

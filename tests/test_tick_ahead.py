@@ -46,6 +46,7 @@ class Driven:
             **{"prefix_cache": "off", **options},
         )
         self.events = []
+        self.groups = []  # (tick, live rows, rows, window) of each group program
         self.chunks = []  # the plain decode chunks' tokens, as dispatched
         self.buckets = []  # and their kv_buckets
         self.tick = 0
@@ -55,6 +56,17 @@ class Driven:
         def spy_chunk(params, cache, tokens, pos, n, slot, *rest):
             self.events.append((self.tick, "chunk", int(slot), int(pos)))
             return chunk(params, cache, tokens, pos, n, slot, *rest)
+
+        def spy_rows(program, rows, window):
+            # The chunks of several slots in one program: a "chunk" event
+            # for each row that counts, in the rows' order.
+            def call(params, cache, tokens, start, lens, slots, *rest):
+                live = np.asarray(lens) > 0
+                for sl, pos in zip(np.asarray(slots)[live], np.asarray(start)[live]):
+                    self.events.append((self.tick, "chunk", int(sl), int(pos)))
+                self.groups.append((self.tick, int(live.sum()), rows, window))
+                return program(params, cache, tokens, start, lens, slots, *rest)
+            return call
 
         def spy_decode(active=None):
             self.events.append((self.tick, "decode", tuple(active)))
@@ -79,6 +91,7 @@ class Driven:
             "_graft_rows": s._graft_rows, "_prefill_suffix": chunk,
         }
         s._prefill_suffix, s._decode_dispatch = spy_chunk, spy_decode
+        s._chunk_programs = {k: spy_rows(p, *k) for k, p in s._chunk_programs.items()}
         s._decode_finalize, s._suffix_finalize = spy_fetch, spy_first
         s._clock.start("plan")
 
@@ -157,6 +170,7 @@ def fresh(driven):
     with driven.s._cancel_lock:
         driven.s._cancelled.clear()
     driven.events.clear()
+    driven.groups.clear()
     driven.chunks.clear()
     driven.buckets.clear()
     return driven
@@ -241,7 +255,8 @@ def test_nothing_is_left_behind_a_chunk_that_was_ahead(fresh, how):
     t0, _, outs, dones, runner_done = d.three_beside_a_runner()
     d.run_tick()  # t0: first chunks, decode, second chunks ahead
     ahead = [i for i, sl in enumerate(d.s._slots) if sl.ahead_tokens]
-    assert len(ahead) == len(d.s._ahead_toks) == 3
+    # One token future a program: the hybrid model's three chunks share one.
+    assert len(ahead) == 3 and len(d.s._ahead_toks) == (1 if d.s._chunk_rows > 1 else 3)
     if how == "cancel":
         for i in range(3):
             d.s.cancel(f"p{i}")
@@ -327,6 +342,68 @@ def test_no_decode_chunk_nothing_ahead(fresh):
     assert after["prefill_chunks_ahead"] == before["prefill_chunks_ahead"]
     d.drain()
     assert done == ["length"]
+
+
+def test_one_ticks_chunks_share_a_program_where_a_chunk_is_a_weight_stream(fresh):
+    """The hybrid model's experts see a few rows a chunk, so the chunks
+    that one tick sends go out as one program (three rows padded to four);
+    llama's projections see every token of a chunk, so each goes alone, as
+    ever.  Either way a chunk is a chunk: the counters, the snapshots and
+    the streams are what the other tests hold them to."""
+    d = fresh
+    t0, *_ = d.three_beside_a_runner(n=3)
+    before, seen = d.s.stats.snapshot(), len(d.s.tick_records(4096))
+    for _ in range(8):
+        d.run_tick()
+    after, records = d.s.stats.snapshot(), d.s.tick_records(4096)[seen:]
+    chunks = after["prefill_chunks"] - before["prefill_chunks"]
+    programs = after["prefill_chunk_programs"] - before["prefill_chunk_programs"]
+    assert chunks == sum(map(_chunks, LENGTHS)) == 13
+    # The tick's record counts both where they are dispatched.
+    assert sum(r["prefill_chunks"] for r in records) == chunks
+    assert sum(r["prefill_chunk_programs"] for r in records) == programs
+    if d.s._chunk_rows == 1:
+        assert programs == chunks and not d.groups and not d.s._chunk_programs
+        assert d.s._prefill_suffix_rows._cache_size() == 0
+        return
+    # A prompt's first chunk is its admission's, alone; then the three
+    # slots' next chunks together, tick by tick, until two prompts have
+    # ended; the fifth chunk of the longest goes alone again.
+    assert programs == 3 + 3 + 1
+    assert [g[:3] for g in d.groups if g[1] > 1] == [(t0 + k, 3, 4) for k in range(3)]
+    # Alone too a chunk is a program of the family, of one row.
+    assert [g[1:3] for g in d.groups if g[1] == 1] == [(1, 1)] * 4
+    assert [r["prefill_chunk_programs"] for r in records[:4]] == [3 + 1, 1, 1, 1]
+    assert [r["prefill_chunks"] for r in records[:4]] == [3 + 3, 3, 3, 1]
+
+
+def test_every_group_program_is_compiled_when_the_scheduler_is_built(fresh):
+    """1, 2 and 4 rows (five slots) over the windows 64 and 128: the
+    family is closed, every chunk, alone or in a group, reaches the device
+    through its compiled executables, and neither the jitted function
+    behind them nor ``_prefill_suffix`` is ever called for one, so a chunk
+    can compile nothing inside a request."""
+    d = fresh
+    if d.s._chunk_rows == 1:  # llama: no family, nothing to compile
+        assert not d.s._chunk_programs and not d.s._chunk_windows
+        return
+    assert d.s._chunk_rows == 4 and d.s._chunk_windows == (64, 128)
+    assert set(d.s._chunk_programs) == {(r, w) for r in (1, 2, 4) for w in (64, 128)}
+    lone = d.programs["_prefill_suffix"]._cache_size()
+    d.start_runner()
+    subs = [d.submit(_prompt(70 + i, n), 2, f"g{i}") for i, n in enumerate((100, 90))]
+    for _ in range(16):
+        d.run_tick()
+    assert all(done == ["length"] for _, done in subs)
+    subs = [d.submit(_prompt(80 + i, n), 2, f"h{i}") for i, n in enumerate((30, 20, 25, 28))]
+    for _ in range(8):
+        d.run_tick()
+    assert all(done == ["length"] for _, done in subs)
+    # Two long prompts side by side pass 64 rows; four short ones fill a group.
+    assert {(rows, window) for *_, rows, window in d.groups} >= {
+        (1, 64), (2, 64), (2, 128), (4, 64)}
+    assert d.s._prefill_suffix_rows._cache_size() == 0
+    assert d.programs["_prefill_suffix"]._cache_size() == lone
 
 
 # -- decode chunks ahead: a full house ------------------------------------
