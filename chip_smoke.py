@@ -3,7 +3,7 @@
 
     python chip_smoke.py [--seed N]          # one chip (what the driver runs)
     python chip_smoke.py --four-chips        # the two multi-chip paths only
-    python chip_smoke.py --hybrid [--model ling|mellum] [--control NAME]   # phase 5 only
+    python chip_smoke.py --hybrid [--model ling|mellum|exaone_moe] [--control NAME]   # phase 5 only
 
 Drives the system through the entry points a user calls, at llama3-8b's
 published widths with random int8 weights from ``--seed``:
@@ -39,6 +39,14 @@ published widths with random int8 weights from ``--seed``:
    precision below the configuration's; for ``mellum`` also ``no_window``
    (the program's window layers attend to everything) and ``no_yarn``
    (its full layers take the plain frequencies and factor 1).
+   ``--model exaone_moe``: k-exaone-236b-a23b-l5e16, whose decode step
+   verifies the draft of its own prediction module: a prompt of 800
+   tokens in chunks of 256, its last 32 positions through the verify step
+   on true and on wrong drafts, and the module's logits, by the
+   benchmark's own comparison (``benchmarks/arch/exaone_moe.py``) against
+   the configuration's limits; controls ``no_qk_norm``, ``rope_on_full``,
+   ``no_window``, ``stale_reject`` (a rejected draft's position counted as
+   written) and ``w8a8_mlp``.
 
 The parent imports no JAX: the chip belongs to one process at a time, so
 each phase is a child (or the pair engine + chain server) that has
@@ -105,6 +113,13 @@ class Sizes:
     # longer than the window (the last one padded), then decoding.
     mellum_model: str = "mellum2-12b-a2.5b-l12"
     mellum_prompt: int = 1300
+    # ``--model exaone_moe``: a prompt longer than five windows (128) in
+    # chunks of ``exaone_chunk``, its last ``exaone_verify`` positions
+    # through the verify step on true and on wrong drafts.
+    exaone_model: str = "k-exaone-236b-a23b-l5e16"
+    exaone_prompt: int = 800
+    exaone_chunk: int = 256
+    exaone_verify: int = 32
     kv_heads: int = 8  # the preset's own; the tiny rehearsal needs 4 to split
     start_timeout_s: float = 600.0
     request_timeout_s: float = 600.0
@@ -132,6 +147,10 @@ TINY = Sizes(
     hybrid_decode=8,
     mellum_model="mellum-tiny",
     mellum_prompt=75,
+    exaone_model="exaone_moe-tiny",
+    exaone_prompt=60,
+    exaone_chunk=16,
+    exaone_verify=8,
     kv_heads=4,
     start_timeout_s=240.0,
     request_timeout_s=240.0,
@@ -1252,7 +1271,16 @@ HYBRID_QUANTILE_LIMITS = {
     "ling": {"p10": 0.025, "p50": 0.1, "p90": 0.4},
     "mellum": {"p10": 0.0175, "p50": 0.06, "p90": 0.15},
 }
-HYBRID_CONTROLS = {"ling": ("w8a8_mlp",), "mellum": ("w8a8_mlp", "no_window", "no_yarn")}
+HYBRID_CONTROLS = {
+    "ling": ("w8a8_mlp",),
+    "mellum": ("w8a8_mlp", "no_window", "no_yarn"),
+    "exaone_moe": ("w8a8_mlp", "no_qk_norm", "rope_on_full", "no_window", "stale_reject"),
+}
+# ``--model exaone_moe`` is held to the limits of its benchmark
+# configuration (``reference.logit_share_limits``; PERF.md section 6,
+# PR 33, has the readings they lie between), by the comparison that
+# decides its cell's ``correct`` (``benchmarks/arch/exaone_moe.py``).
+EXAONE_CONFIG = "benchmarks/configs/k-exaone-236b-a23b-l5e16.json"
 
 
 def hybrid_limits(model: str) -> dict:
@@ -1262,7 +1290,108 @@ def hybrid_limits(model: str) -> dict:
     }
 
 
+def _w8a8_swiglu():
+    """The nearest precision below the configurations': every MLP product
+    (dense, shared and routed experts) as a W8A8 matmul would compute it:
+    int8 weights, one scale an output channel, and int8 activations, one
+    scale a token (what ``benchmarks/run.py --control`` serves the
+    llama-shaped models through)."""
+    import jax
+    import jax.numpy as jnp
+
+    def int8(a, axis):
+        scale = jnp.max(jnp.abs(a), axis=axis, keepdims=True) / 127.0 + 1e-30
+        return jnp.round(a / scale) * scale
+
+    def w8a8_swiglu(h, w_gu, w_down):
+        gu = int8(h, -1) @ int8(w_gu.astype(jnp.float32), 0)
+        half = gu.shape[-1] // 2
+        act = jax.nn.silu(gu[:, :half]) * gu[:, half:]
+        return int8(act, -1) @ int8(w_down.astype(jnp.float32), 0)
+
+    return w8a8_swiglu
+
+
+def child_exaone(seed: int, sizes: Sizes, control: str = "") -> None:
+    """``--hybrid --model exaone_moe``: the serving model's chunked
+    prefill and its verify step (on true drafts, on wrong drafts, and the
+    prediction module's logits) against the float32 reference, by the
+    benchmark's own comparison.  A control changes what the program
+    computes (``no_qk_norm``, ``rope_on_full``: the full layers rotated
+    like the window ones, ``no_window``), how the check steps after a
+    rejected draft (``stale_reject``: its position counted as written) or
+    the reference's precision (``w8a8_mlp``); each has to leave a limit."""
+    import importlib.util
+
+    import jax
+    import numpy as np
+
+    from generativeaiexamples_tpu.engine.serving_models import serving_model
+    from generativeaiexamples_tpu.models import hybrid
+    from generativeaiexamples_tpu.utils.jax_runtime import (
+        device_report,
+        enable_compile_cache,
+        runtime_report,
+    )
+
+    enable_compile_cache()
+    t0 = time.monotonic()
+    bench = os.path.join(os.path.dirname(os.path.abspath(__file__)), "benchmarks")
+    sys.path.insert(0, bench)
+    try:
+        spec = importlib.util.spec_from_file_location(
+            "arch_exaone_moe", os.path.join(bench, "arch", "exaone_moe.py"))
+        arch = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(arch)
+    finally:
+        sys.path.remove(bench)
+    with open(os.path.join(os.path.dirname(bench), EXAONE_CONFIG)) as f:
+        limits = json.load(f)["reference"]["logit_share_limits"]
+    arch._CHECK.update(limits=limits, verify=sizes.exaone_verify, chunk=sizes.exaone_chunk)
+    cfg = hybrid.PRESETS[sizes.exaone_model]()  # what the reference computes
+    served = {
+        "no_qk_norm": dataclasses.replace(cfg, qk_norm=False),
+        "rope_on_full": dataclasses.replace(cfg, rope_full=cfg.rope_window),
+        "no_window": dataclasses.replace(cfg, sliding_window=sizes.max_len),
+    }.get(control, cfg)
+    pad_to = sizes.exaone_prompt
+    params = serving_model(cfg, None, pad_to).prepare_params(
+        None, quantize=False, matmul_kernel="xla", seed=seed)
+    tokens = np.random.RandomState(seed).randint(1, cfg.vocab_size, size=pad_to).astype(np.int32)
+    plain = arch.exaone_moe_reference._swiglu
+    if control == "w8a8_mlp":
+        arch.exaone_moe_reference._swiglu = _w8a8_swiglu()
+        jax.clear_caches()  # a layer traced before this would keep the plain one
+    try:
+        shares, _ = arch.logit_shares(
+            params, cfg, tokens, pad_to, served=served, stale_reject=control == "stale_reject")
+    finally:
+        if control == "w8a8_mlp":  # a caller in this process gets the plain one back
+            arch.exaone_moe_reference._swiglu = plain
+            jax.clear_caches()
+    readings = arch.share_quantiles(shares)
+    failed = {k: v for k, v in readings.items() if not v <= limits[k]}
+    report = runtime_report()
+    emit(
+        {
+            "phase": "hybrid", "model": sizes.exaone_model, "control": control or None,
+            "positions": {k: int(len(v)) for k, v in shares.items()},
+            **readings, "limits": limits, "within_limits": not failed,
+            "seconds": time.monotonic() - t0,
+            "compile": report["compile"], "peak_bytes_in_use": report["peak_bytes_in_use"],
+            "kernel_paths": {**_taken("moe_experts"), **_taken("attn_"), **_taken("mtp_")},
+            "device": device_report(),
+        }
+    )
+    if control and not failed:
+        raise SmokeFailure(f"the control {control!r} stayed inside every limit: {readings}")
+    if not control and failed:
+        raise SmokeFailure(f"logits left the reference: {failed} (limits {limits})")
+
+
 def child_hybrid(seed: int, sizes: Sizes, control: str = "", model: str = "ling") -> None:
+    if model == "exaone_moe":
+        return child_exaone(seed, sizes, control)
     import importlib
 
     import jax
@@ -1330,22 +1459,9 @@ def child_hybrid(seed: int, sizes: Sizes, control: str = "", model: str = "ling"
     # head so that a float32 (positions, vocabulary) block fits.
     if control == "w8a8_mlp":
         # The control: the reference computed in the nearest precision
-        # below the configuration's — every MLP product (dense, shared
-        # and routed experts) as a W8A8 matmul would compute it: int8
-        # weights, one scale an output channel, and int8 activations, one
-        # scale a token (what ``benchmarks/run.py --control`` serves the
-        # llama-shaped models through).  It has to leave the limits.
-        def int8(a, axis):
-            scale = jnp.max(jnp.abs(a), axis=axis, keepdims=True) / 127.0 + 1e-30
-            return jnp.round(a / scale) * scale
-
-        def w8a8_swiglu(h, w_gu, w_down):
-            gu = int8(h, -1) @ int8(w_gu.astype(jnp.float32), 0)
-            half = gu.shape[-1] // 2
-            act = jax.nn.silu(gu[:, :half]) * gu[:, half:]
-            return int8(act, -1) @ int8(w_down.astype(jnp.float32), 0)
-
-        reference._swiglu = w8a8_swiglu  # this process runs nothing else
+        # below the configuration's (``_w8a8_swiglu``).  It has to leave
+        # the limits.
+        reference._swiglu = _w8a8_swiglu()  # this process runs nothing else
         jax.clear_caches()  # a layer traced before this would keep the plain one
     x = reference.hidden_states(params, cfg, tokens)
     want = np.concatenate([

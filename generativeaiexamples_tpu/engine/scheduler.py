@@ -137,6 +137,12 @@ class _Slot:
     # admission's graft landed it there since).
     unfetched: int = 0
     on_device: bool = False
+    # Decode chunks that are on the device, unfetched, with this row live
+    # (0, or 1 while a full house goes ahead).  Where every step verifies
+    # the model's own draft such a chunk advances the row by one or two a
+    # step: until its fetch the row's length is the device's to know
+    # (``Scheduler._carried_len``) and ``unfetched`` counts the fewest.
+    chunks_out: int = 0
     # Speculative decoding: EWMA of this request's observed per-round
     # acceptance rate (accepted drafts / gamma).  Drives the adaptive
     # lookahead — a request whose drafts keep getting rejected decays
@@ -347,6 +353,7 @@ class Stats:
         # that does not say (``serving_models``: ``state_bytes``).
         self.state_bytes_full = 0
         self.state_bytes_window = 0
+        self.state_bytes_draft = 0  # a prediction module's rows
         # Cross-request shared-prefix cache hits (content match through
         # the radix index; session matches count under prefix_hits) and
         # chunked-prefill chunk dispatches.  prefix_tokens_reused pools
@@ -522,6 +529,7 @@ class Stats:
                 "state_snapshot_bytes": self.state_snapshot_bytes,
                 "state_bytes_full": self.state_bytes_full,
                 "state_bytes_window": self.state_bytes_window,
+                "state_bytes_draft": self.state_bytes_draft,
                 **self.model_counters,
                 "shared_prefix_hits": self.shared_prefix_hits,
                 "prefill_chunks": self.prefill_chunks,
@@ -644,6 +652,9 @@ class Scheduler:
         # everything below that touches parameters or slot state goes
         # through it.  It refuses, with the reason, what it does not serve.
         self.model = model = serving_model(cfg, mesh, self.max_len)
+        # Positions a decode step writes a row: two where every step
+        # verifies the model's own draft (``serving_models``: ``draft``).
+        self._step_width = 2 if model.draft else 1
         model.check_supported(
             kv_layout=kv_layout, draft_cfg=draft_cfg, spec_mode=spec_mode
         )
@@ -872,6 +883,7 @@ class Scheduler:
             by_kind = model.state_bytes(max_batch)
             self.stats.state_bytes_full = by_kind["full"]
             self.stats.state_bytes_window = by_kind["window"]
+            self.stats.state_bytes_draft = by_kind.get("draft", 0)
         # Counters the step programs return beside their tokens, not yet
         # fetched: drained once ready, after a token fetch, so that they
         # cost no synchronisation of their own.
@@ -890,6 +902,9 @@ class Scheduler:
         # lands its first tokens there (``_graft_rows``).  Never donated:
         # the chunk's finalizer fetches the same buffer.
         self._carried = self._no_tokens()
+        # A drafting model's newest chunk also leaves each row's length
+        # (max_batch,) there: a step advanced it by one or two.
+        self._carried_len = self._no_lengths()
         # Pipelined ticks dispatch the decode chunk in the same tick as
         # admissions, pinning not-yet-decoding lanes to max_len - 1 —
         # whose append-buffer flush garbage-writes [max_len - w, max_len)
@@ -1246,10 +1261,18 @@ class Scheduler:
     # -- internals ---------------------------------------------------------
 
     def _no_tokens(self) -> jax.Array:
-        """``_carried`` before any chunk has run (an upload, no program)."""
-        return jax.device_put(
-            np.zeros((self.decode_chunk_size, self.max_batch), np.int32)
-        )
+        """``_carried`` before any chunk has run (an upload, no program):
+        the shape a chunk leaves, which for a drafting model is each
+        row's newest token alone."""
+        steps = 1 if self.model.draft else self.decode_chunk_size
+        return jax.device_put(np.zeros((steps, self.max_batch), np.int32))
+
+    def _no_lengths(self) -> Optional[jax.Array]:
+        """``_carried_len`` before any chunk has run; a model that does
+        not draft has none (the host knows every length)."""
+        if not self.model.draft:
+            return None
+        return jax.device_put(np.zeros((self.max_batch,), np.int32))
 
     def _next_key(self) -> jax.Array:
         self._key, sub = jax.random.split(self._key)
@@ -1516,7 +1539,7 @@ class Scheduler:
         # Nor of a decode chunk that is still on the device with this row
         # live: its finalizer finds another request here, or none, and
         # drops the row's tokens (``_decode_finalize``).
-        slot.unfetched, slot.on_device = 0, False
+        slot.unfetched, slot.on_device, slot.chunks_out = 0, False, 0
         if (
             req is not None
             and reason in ("stop", "length")
@@ -2544,6 +2567,7 @@ class Scheduler:
             # and no row's token is on the device any more.
             self._flight = None
             self._carried = self._no_tokens()
+            self._carried_len = self._no_lengths()
             if self._snapshots is not None:
                 self._snapshots.clear()
                 with self.stats.lock:
@@ -3109,9 +3133,12 @@ class Scheduler:
         a live request, decoding or warming.  While a slot is free the
         next arrival's prefill should lead the device's queue, not wait
         behind a decode chunk; with a full house nothing can be admitted
-        before a row ends anyway.  Plain decoding over the contiguous
-        cache only: a speculative round's acceptance counts and a paged
-        chunk's pages are the host's to know before the next dispatch."""
+        before a row ends anyway.  Decoding over the contiguous cache
+        without a draft model or n-gram drafts only: such a round's
+        acceptance counts and a paged chunk's pages are the host's to
+        know before the next dispatch.  (A chunk that verifies the
+        model's own draft leaves its rows' lengths on the device beside
+        their tokens, ``_carried_len``, and goes ahead as a plain one.)"""
         return (
             self._pool is None
             and self.draft_cfg is None
@@ -3126,7 +3153,9 @@ class Scheduler:
         this tick's admission landed it), left out if the tokens in
         flight already bring it to its end: no lane computes a token
         that the host knows will be thrown away.  A row that may stop on
-        EOS or be cancelled is taken to go on (``_decode_finalize``)."""
+        EOS or be cancelled is taken to go on (``_decode_finalize``), and
+        so is one that the drafts kept in the chunk in flight may have
+        brought to its end: ``unfetched`` counts one token a step."""
         lanes = []
         for i, s in enumerate(self._slots):
             if s.request is None or s.warm_pos is not None:
@@ -3456,6 +3485,38 @@ class Scheduler:
                 )
         self._flush_tokens()
 
+    def _emit_verified(self, toks: np.ndarray, n_emits: np.ndarray, mine: list) -> None:
+        """Emit a chunk whose steps verified the model's own draft: step
+        ``r`` gave row ``i`` its first ``n_emits[r, i]`` of ``toks[r, i]``
+        (two where the stack agreed with the draft).  A row may end on
+        the first of two (``max_tokens``, ``max_len``, EOS): its second,
+        and its later steps, are dropped with it.  Feeds the ``spec_*``
+        stats as a speculative round does: one draft a greedy row a
+        step."""
+        rounds = accepted = tokens = 0
+        for row, counts in zip(toks, n_emits):
+            for i, req in mine:
+                if self._slots[i].request is not req:
+                    continue
+                n = int(counts[i])
+                drafted = req.sampling.temperature <= 0.0
+                rounds += drafted
+                accepted += drafted and n > 1
+                for j in range(n):
+                    self._handle_token(i, int(row[i, j]))
+                    tokens += drafted
+                    if self._slots[i].request is not req:
+                        break
+        with self.stats.lock:
+            self.stats.spec_rounds += rounds
+            self.stats.spec_proposed += rounds
+            self.stats.spec_accepted += accepted
+            self.stats.spec_tokens += tokens
+            if rounds:
+                self.stats.spec_acceptance_ewma += 0.2 * (
+                    accepted / rounds - self.stats.spec_acceptance_ewma
+                )
+
     def _decode_dispatch(
         self, active: Optional[list[int]] = None
     ) -> tuple:
@@ -3479,12 +3540,19 @@ class Scheduler:
         snap = np.zeros((self.max_batch,), dtype=bool)
         snap[active] = True
         carry = np.zeros((self.max_batch,), dtype=bool)
+        carry_len = np.zeros((self.max_batch,), dtype=bool)
         for i in active:
             n = self._slots[i].unfetched
             if n:
                 carry[i] = True
                 lengths[i] += n
-                max_active = max(max_active, int(lengths[i]))
+                # What a drafting model's chunk in flight may have added
+                # to the fewest: the row's length is read on the device,
+                # and the window covers the most it can be.
+                adrift = self._slots[i].chunks_out and self.model.draft
+                carry_len[i] = bool(adrift)
+                most = int(lengths[i]) + (self.decode_chunk_size if adrift else 0)
+                max_active = max(max_active, most)
         if pinned:
             # Lanes outside the emission snapshot (freshly admitted this
             # tick, emitted still 0) would garbage-write at length-1 —
@@ -3498,8 +3566,9 @@ class Scheduler:
         # reads then track the longest live sequence instead of always
         # paying max_len.  (Garbage writes by inactive lanes may land
         # beyond the window; writes are not gated by kv_bucket.)
+        # (A step that verifies the model's own draft writes two.)
         kv_bucket = bucket_size(
-            max_active + self.decode_chunk_size + 1,
+            max_active + self._step_width * self.decode_chunk_size + 1,
             maximum=self.max_len,
         )
         self._tick_kv_bucket = kv_bucket
@@ -3552,8 +3621,18 @@ class Scheduler:
                 jnp.asarray(snap),
                 self._carried,
                 jnp.asarray(carry),
+                *(
+                    (self._carried_len, jnp.asarray(carry_len))
+                    if self.model.draft else ()
+                ),
             )
             self._cache = cache
+            if self.model.draft:
+                # Tokens (steps, b, 2), how many of the two count, and
+                # what the chunk leaves for the next: each row's newest
+                # token (wherever its counts put it) and its length.
+                n_emits, (self._carried, self._carried_len), *aux = aux
+                toks = toks, n_emits
             self._note_aux(*aux)
             with self.stats.lock:
                 self.stats.decode_kv_tokens_read += kv_tokens_read(
@@ -3566,11 +3645,13 @@ class Scheduler:
         self._clock.enter("plan")
         # The chunk's last tokens stay where the next chunk can read them;
         # a row that sat this chunk out has nothing there any more.
-        self._carried = toks
+        if not self.model.draft:
+            self._carried = toks
         for i, s in enumerate(self._slots):
             s.on_device = bool(snap[i])
             if s.on_device:
                 s.unfetched += self.decode_chunk_size
+                s.chunks_out += 1
         lanes = [(i, self._slots[i].request) for i in active]
         return toks, lanes, ticket
 
@@ -3591,7 +3672,11 @@ class Scheduler:
         ``ahead``: the chunk was dispatched while the one before it was
         unfetched (``_tick`` says so of the chunk it kept in flight)."""
         self._clock.enter("wait_device")
-        toks_host = np.asarray(toks)  # (chunk, b)
+        n_host = None
+        if self.model.draft:
+            toks, n_emits = toks
+            n_host = np.asarray(n_emits)  # (chunk, b): 1 or 2 a live row
+        toks_host = np.asarray(toks)  # (chunk, b), or (chunk, b, 2)
         self._clock.fetched(ticket)
         self._clock.enter("emit")
         self._drain_aux()
@@ -3600,11 +3685,15 @@ class Scheduler:
             slot = self._slots[i]
             if slot.request is req and req is not None:
                 slot.unfetched -= self.decode_chunk_size
+                slot.chunks_out -= 1
                 mine.append((i, req))
-        for row in toks_host:
-            for i, req in mine:
-                if self._slots[i].request is req:
-                    self._handle_token(i, int(row[i]))
+        if n_host is None:
+            for row in toks_host:
+                for i, req in mine:
+                    if self._slots[i].request is req:
+                        self._handle_token(i, int(row[i]))
+        else:
+            self._emit_verified(toks_host, n_host, mine)
         self._flush_tokens()
         with self.stats.lock:
             self.stats.decode_chunks += 1

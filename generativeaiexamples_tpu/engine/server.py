@@ -808,16 +808,20 @@ async def handle_metrics(request: web.Request) -> web.Response:
         ("engine_state_snapshots_evicted_total", "state_snapshots_evicted", "d"),
         # What the model's step programs count (an expert model's routing:
         # ops.moe.COUNTERS; the rows its attention layers read, by kind:
-        # models.hybrid.ATTN_COUNTERS); none for a model that returns none.
+        # models.hybrid.ATTN_COUNTERS; a drafting model's verify steps:
+        # serving_models.HybridServing.DRAFT_COUNTERS); none for a model
+        # that returns none.
         *(
             (f"engine_{key}_total", key, "d")
             for key in sorted(snap)
-            if key.startswith(("moe_", "attn_rows_"))
+            if key.startswith(("moe_", "attn_rows_", "draft_", "verify_", "decode_tokens_emitted"))
         ),
     ):
         lines.append(f"# TYPE {name} counter")
         lines.append(f"{name} {format(snap.get(key, 0), fmt)}")
-    for key in ("state_snapshot_bytes", "state_bytes_full", "state_bytes_window"):
+    for key in (
+        "state_snapshot_bytes", "state_bytes_full", "state_bytes_window", "state_bytes_draft",
+    ):
         lines.append(f"# TYPE engine_{key} gauge")
         lines.append(f"engine_{key} {snap.get(key, 0)}")
     # Which serving matmul path is live (info-style gauge: every known

@@ -25,6 +25,14 @@ prefix hit back to the deepest one).  ``counter_names`` names the int32
 counters each step program returns beside its tokens (``aux``; None for a
 model that has none).
 
+A model that drafts its own decode step (``draft`` ``"mtp"``: a
+prediction module behind the stack, ``HybridServing``) returns from its
+decode chunk tokens ``(n_steps, b, 2)`` and, after them, how many of the
+two each row emitted in each step ``(n_steps, b)``: 1, or 2 where the
+stack agreed with the draft; then what the chunk behind it may read
+without the host, each row's newest token ``(1, b)`` and its length
+``(b,)`` (``carried_len`` / ``carry_len`` beside ``carried`` / ``carry``).
+
 ``LlamaServing`` is ``models/llama.py`` exactly as the scheduler used to
 call it, so the programs of every llama-shaped configuration compile as
 they did; ``HybridServing`` serves ``models/hybrid.py``'s layer kinds.
@@ -43,6 +51,13 @@ from generativeaiexamples_tpu.models import hybrid, llama
 from generativeaiexamples_tpu.ops import moe
 
 
+def _last_counted(x, n):
+    """x (b, s, ...) at each row's last position that counts: ``n - 1``
+    (position 0 of a row with none, whose caller keeps what it had)."""
+    at = jnp.maximum(n - 1, 0).reshape((-1,) + (1,) * (x.ndim - 1))
+    return jnp.take_along_axis(x, at, axis=1)[:, 0]
+
+
 def serving_model(cfg, mesh, max_len: int):
     """The server of ``cfg``'s kind."""
     if isinstance(cfg, hybrid.HybridConfig):
@@ -54,6 +69,7 @@ class LlamaServing:
     cut_anywhere = True
     counter_names: tuple = ()
     snapshot_bytes = 0
+    draft = ""  # draft models and n-gram drafts are the scheduler's own
 
     def __init__(self, cfg: llama.LlamaConfig, mesh, max_len: int) -> None:
         self.cfg, self.mesh, self.max_len = cfg, mesh, max_len
@@ -164,12 +180,40 @@ class HybridServing:
     (latent rows, a full layer's K/V), which a graft copies up to any
     token; and state as of the last token (a KDA layer's ``S`` and
     ``conv``, a window layer's ring), which a prefix hit takes from a
-    snapshot saved at a prefill-chunk boundary."""
+    snapshot saved at a prefill-chunk boundary.
+
+    A model that holds a prediction module (``cfg.draft`` ``"mtp"``) is
+    served with it as the draft of every decode step.  The module's state
+    lies behind the stack's: its block's K/V rows, and ``h_last``, the
+    stack's output at the row's last position.  Between programs the
+    module's rows are filled up to the position BEFORE the last (that one
+    needs the token after it): a prefill call runs the module one
+    position behind the stack, a decode chunk first catches it up with
+    its input token (which gives the first draft), and every verify step
+    runs it over the positions the step accepted.
+
+    The rule for a rejected draft's rows: the step has written the draft's
+    position ``p + 1`` in every layer of the stack; the row's length
+    advances by one only, so a full layer's row there lies past the
+    length (masked, as a pad row is) until the next step writes the true
+    token over it, and a window layer's ring row ``(p + 1) % R`` is taken
+    by the ring's own rule to hold position ``p + 1 - R``, which no
+    query from ``p + 1`` on may see; the module writes accepted
+    positions only."""
 
     cut_anywhere = False
+    # Counters of a drafting model's decode chunk, after ``forward``'s:
+    # drafts a greedy row offered and the stack agreed with, positions the
+    # stack computed in decode steps, tokens emitted, and rows of full
+    # layers that a rejection left to be written again.
+    DRAFT_COUNTERS = (
+        "draft_proposed", "draft_accepted", "verify_positions",
+        "decode_tokens_emitted", "draft_rows_rewritten",
+    )
 
     def __init__(self, cfg: hybrid.HybridConfig, mesh, max_len: int) -> None:
         self.cfg, self.mesh, self.max_len = cfg, mesh, max_len
+        self.draft = cfg.draft
         self.snapshot_bytes = cfg.snapshot_bytes(max_len)
         # ``forward``'s counters; the rows its attention layers read are
         # counted apart for decode steps and for prefill chunks.
@@ -179,19 +223,29 @@ class HybridServing:
                 f"attn_rows_{n}_{phase}"
                 for phase in ("decode", "prefill") for n in hybrid.ATTN_COUNTERS
             )
+        if self.draft:
+            self.counter_names += self.DRAFT_COUNTERS
 
     def check_supported(
         self, *, kv_layout="contiguous", draft_cfg=None, spec_mode=None, **_
     ) -> None:
         """What is not served for a model whose state cannot be cut at a
         token, refused with the reason."""
-        if draft_cfg is not None or spec_mode is not None:
+        drafted = draft_cfg is not None or spec_mode is not None
+        if (drafted or self.draft) and self.cfg.layers_of("kda"):
             raise ValueError(
-                "speculative decoding is not served for a model with "
-                "recurrent state or a window ring: a rejected draft would "
-                "need the state rolled back, and no step keeps the state it "
-                "started from (a ring has overwritten the rows it would "
-                "return to)"
+                "speculative decoding is not served over KDA state: a "
+                "rejected draft would need the recurrent state rolled back, "
+                "and no step keeps the state it started from (ops/kda.py has "
+                "no rollback)"
+            )
+        if drafted:
+            raise ValueError(
+                "a draft model and n-gram drafts are not served for a model "
+                "of layer kinds: engine/spec_decode.py verifies over "
+                "models/llama.py's K/V cache alone; the one draft served "
+                "here is the model's own prediction module (draft 'mtp'), "
+                "whose rejected position a ring masks by its own rule"
             )
         if kv_layout != "contiguous":
             raise ValueError(
@@ -229,16 +283,45 @@ class HybridServing:
         """Bytes of ``batch`` slots' state by kind (``hybrid.state_bytes``)."""
         return hybrid.state_bytes(self.cfg, batch, self.max_len)
 
-    def _aux(self, counters, decode: bool):
+    def _aux(self, counters, decode: bool, drafted=None):
         """``forward``'s counters under ``counter_names``: the attention
-        rows go to the decode or to the prefill entries."""
+        rows go to the decode or to the prefill entries; a drafting
+        model's ``DRAFT_COUNTERS`` (``drafted``; a prefill has none)
+        come last."""
         if not self.cfg.has_attn_counters:
             return counters
         n = len(moe.COUNTERS)
         rows, none = counters[n:], jnp.zeros_like(counters[n:])
-        return jnp.concatenate(
-            [counters[:n], *((rows, none) if decode else (none, rows))]
+        parts = [counters[:n], *((rows, none) if decode else (none, rows))]
+        if self.draft:
+            parts.append(
+                jnp.zeros((len(self.DRAFT_COUNTERS),), jnp.int32)
+                if drafted is None else drafted
+            )
+        return jnp.concatenate(parts)
+
+    def _module_behind(self, params, hidden, tokens, start, n_valid, state, window,
+                       apart: bool = False):
+        """A prefill call's part for the prediction module, one position
+        behind the stack: the call's tokens (b, s) from ``start`` are the
+        tokens that follow positions ``start - 1 + [0, s)``, whose stack
+        outputs are ``h_last`` and then ``hidden`` (b, s, D) shifted by
+        one; a prompt's position -1 does not count.  Returns (state with
+        the module's rows written and ``h_last`` moved to the call's last
+        position that counts, the module's counters)."""
+        L = self.cfg.n_layers
+        rows, last = state[L], state[L + 1]["h_last"]
+        steps = jnp.arange(tokens.shape[1], dtype=jnp.int32)[None, :]
+        n_valid = n_valid.astype(jnp.int32)
+        pos = start[:, None].astype(jnp.int32) + steps - 1
+        valid = (steps < n_valid[:, None]) & (pos >= 0)
+        behind = jnp.concatenate([last[:, None].astype(hidden.dtype), hidden[:, :-1]], axis=1)
+        _, rows, counters = hybrid.mtp_forward(
+            params, self.cfg, behind, tokens, pos, valid, rows, window=window,
+            mesh=self.mesh, rows_apart=apart,
         )
+        last = jnp.where((n_valid > 0)[:, None], _last_counted(hidden, n_valid).astype(last.dtype), last)
+        return state[:L] + (rows, {"h_last": last}), counters
 
     def prefill_cold(self, params, tokens, lengths):
         b, s = tokens.shape
@@ -246,6 +329,11 @@ class HybridServing:
             params, self.cfg, tokens, jnp.zeros((b,), jnp.int32), lengths,
             hybrid.init_state(self.cfg, b, s), window=s, mesh=self.mesh,
         )
+        if self.draft:
+            state, c = self._module_behind(
+                params, hidden, tokens, jnp.zeros((b,), jnp.int32), lengths, state, s
+            )
+            counters = counters + c
         return hidden, state, self._aux(counters, decode=False)
 
     def graft_rows(self, big, small, rows, slots):
@@ -283,6 +371,12 @@ class HybridServing:
             params, self.cfg, tokens, jnp.reshape(start, (1,)),
             jnp.reshape(suffix_len, (1,)), row, window=kv_bucket, mesh=self.mesh,
         )
+        if self.draft:
+            row, c = self._module_behind(
+                params, hidden, tokens, jnp.reshape(start, (1,)),
+                jnp.reshape(suffix_len, (1,)), row, kv_bucket,
+            )
+            counters = counters + c
         with jax.named_scope("kv_write"):
             cache = jax.tree.map(
                 lambda bg, r: jax.lax.dynamic_update_slice_in_dim(bg, r, slot, axis=0),
@@ -336,6 +430,11 @@ class HybridServing:
             params, self.cfg, tokens, start, suffix_len, rows, window=window,
             mesh=self.mesh, rows_apart=True,
         )
+        if self.draft:
+            rows, c = self._module_behind(
+                params, hidden, tokens, start, suffix_len, rows, window, apart=True
+            )
+            counters = counters + c
         # A pad row's slot is past the last: its write is dropped.
         dest = jnp.where(live, slots, jax.tree.leaves(cache)[0].shape[0])
         with jax.named_scope("kv_write"):
@@ -396,6 +495,8 @@ class HybridServing:
         return hybrid.logits(params, self.cfg, hidden)
 
     def make_decode_chunk(self):
+        if self.draft:
+            return self._make_verify_chunk()
         cfg, max_len, step_logits = self.cfg, self.max_len, self.decode_step
 
         @functools.partial(jax.jit, donate_argnums=(1,), static_argnums=(8, 9))
@@ -442,3 +543,148 @@ class HybridServing:
             window=window, mesh=self.mesh,
         )
         return cache, hybrid.logits(params, self.cfg, hidden)[:, 0], c
+
+    # -- a decode step that verifies the model's own draft ----------------------
+
+    def _step_start(self, lengths):
+        """Where a verify step's two positions start: the row's length,
+        held where both fit (a row past its end decodes on until its
+        chunk ends; what it writes there is never read)."""
+        return jnp.minimum(lengths, self.max_len - 2).astype(jnp.int32)
+
+    def draft_from_last(self, params, cache, tokens, lengths, counts, window: int):
+        """Catch the prediction module up with a row's input token:
+        ``tokens`` (b,) are the tokens at positions ``lengths`` (not yet
+        through the stack), so the module's position ``lengths - 1`` has
+        its next token, with ``h_last`` for the stack's output there.
+        Returns (state with that row of the module written, the module's
+        logits (b, V) float32: its draft of the token at ``lengths + 1``,
+        counters).  A row with ``counts`` 0 writes nothing."""
+        cfg, L = self.cfg, self.cfg.n_layers
+        pos = self._step_start(lengths)[:, None] - 1
+        valid = (counts > 0)[:, None] & (pos >= 0)
+        hidden = cache[L + 1]["h_last"][:, None].astype(jnp.dtype(cfg.dtype))
+        x, rows, counters = hybrid.mtp_forward(
+            params, cfg, hidden, tokens[:, None], pos, valid, cache[L],
+            window=window, mesh=self.mesh,
+        )
+        cache = cache[:L] + (rows, cache[L + 1])
+        return cache, hybrid.mtp_logits(params, cfg, x)[:, 0], counters
+
+    def verify_stack(self, params, cache, tokens, drafts, lengths, counts, window: int):
+        """The stack over ``[token, draft]`` (b, 2) at positions
+        ``lengths``, ``lengths + 1``; a row with ``counts`` 0 writes
+        nothing.  Returns (state, hidden (b, 2, D), logits (b, 2, V)
+        float32, counters): the logits at the first position are the next
+        token's whatever the draft was, those at the second are the token
+        after a draft that was right."""
+        hidden, cache, c = hybrid.forward(
+            params, self.cfg, jnp.stack([tokens, drafts], axis=1), self._step_start(lengths),
+            2 * (counts > 0).astype(jnp.int32), cache, window=window, mesh=self.mesh,
+        )
+        return cache, hidden, hybrid.logits(params, self.cfg, hidden), c
+
+    def verify_module(self, params, cache, hidden, next_tokens, lengths, n_emit, window: int):
+        """The prediction module over the positions a verify step accepted:
+        ``hidden`` (b, 2, D) the stack's at ``lengths``, ``lengths + 1``,
+        ``next_tokens`` (b, 2) the tokens that follow them, of which the
+        first ``n_emit`` (0, 1 or 2 a row) count.  Returns (state with
+        those rows of the module written and ``h_last`` moved to the last
+        of them, the module's logits (b, V) float32 at that position: the
+        next step's draft, counters)."""
+        cfg, L = self.cfg, self.cfg.n_layers
+        steps = jnp.arange(2, dtype=jnp.int32)[None, :]
+        pos = self._step_start(lengths)[:, None] + steps
+        valid = steps < n_emit[:, None]
+        x, rows, counters = hybrid.mtp_forward(
+            params, cfg, hidden, next_tokens, pos, valid, cache[L],
+            window=window, mesh=self.mesh,
+        )
+        last = cache[L + 1]["h_last"]
+        last = jnp.where((n_emit > 0)[:, None], _last_counted(hidden, n_emit).astype(last.dtype), last)
+        cache = cache[:L] + (rows, {"h_last": last})
+        return cache, hybrid.mtp_logits(params, cfg, _last_counted(x, n_emit)), counters
+
+    def _make_verify_chunk(self):
+        cfg, max_len = self.cfg, self.max_len
+        n_full = len(cfg.layers_of("full"))
+
+        @functools.partial(jax.jit, donate_argnums=(1,), static_argnums=(8, 9))
+        def decode_chunk(
+            params, cache, tokens, lengths, key, temp, top_p, top_k,
+            n_steps, kv_bucket=None, live=None, carried=None, carry=None,
+            carried_len=None, carry_len=None,
+        ):
+            """The plain chunk's signature; every step verifies the
+            prediction module's draft and emits one or two tokens a row.
+            Returns (state, tokens (n_steps, b, 2), how many of the two a
+            row emitted (n_steps, b), what the next chunk may read without
+            the host: each row's newest token (1, b) and its length (b,),
+            counters).  A greedy row keeps its
+            draft where the stack's own choice is the same token, so its
+            tokens are the plain chunk's; a sampled row emits one token a
+            step, drawn by the plain sampler from the first position's
+            logits.  A row that does not decode (``live`` False) emits
+            nothing and writes nothing.  ``carried_len`` / ``carry_len``:
+            as ``carried`` / ``carry`` for the tokens, the lengths the
+            chunk before this one left for the rows ``carry_len`` marks
+            (how far its drafts took a row only the device knows until
+            that chunk is fetched)."""
+            tokens = carry_tokens(tokens, carried, carry)
+            if carried_len is not None and carry_len is not None:
+                lengths = jnp.where(carry_len, carried_len, lengths)
+            window = min(kv_bucket, max_len) if kv_bucket else max_len
+            b = tokens.shape[0]
+            on = jnp.ones((b,), bool) if live is None else live
+            counts = on.astype(jnp.int32)
+            greedy = on & (temp <= 0.0)
+            cache, lg, c0 = self.draft_from_last(
+                params, cache, tokens, lengths, counts, window
+            )
+            draft = jnp.argmax(lg, axis=-1).astype(jnp.int32)
+
+            def body(carry, _):
+                cache, tok, draft, lens, key, aux, drafted = carry
+                # The plain chunk's keys: a sampled row's token is the
+                # plain sampler's draw from the same logits.
+                key, sub = jax.random.split(key)
+                cache, hidden, lg, c = self.verify_stack(
+                    params, cache, tok, draft, lens, counts, window
+                )
+                with jax.named_scope("verify/accept"):
+                    t1 = sample(lg[:, 0], sub, temp, top_p, top_k)
+                    # Only a greedy row keeps a draft, and its next token
+                    # is then the second position's largest logit.
+                    t2 = jnp.argmax(lg[:, 1], axis=-1).astype(jnp.int32)
+                    accept = greedy & (draft == t1)
+                    n_emit = counts + accept.astype(jnp.int32)
+                with jax.named_scope("verify/emit"):
+                    out = jnp.stack([t1, t2], axis=1)
+                    nxt = jnp.where(accept, t2, t1)
+                cache, mlg, cm = self.verify_module(
+                    params, cache, hidden, out, lens, n_emit, window
+                )
+                proposed = greedy.sum().astype(jnp.int32)
+                accepted = accept.sum().astype(jnp.int32)
+                drafted = drafted + jnp.stack([
+                    proposed, accepted, 2 * counts.sum(), n_emit.sum(),
+                    n_full * (counts.sum() - accepted),
+                ]).astype(jnp.int32)
+                draft = jnp.argmax(mlg, axis=-1).astype(jnp.int32)
+                carry = (cache, nxt, draft, lens + n_emit, key, aux + c + cm, drafted)
+                return carry, (out, n_emit)
+
+            init = (
+                cache, tokens, draft, lengths.astype(jnp.int32), key,
+                c0,
+                jnp.zeros((len(self.DRAFT_COUNTERS),), jnp.int32),
+            )
+            (cache, tok, _, lens, _, aux, drafted), (toks, n_emits) = jax.lax.scan(
+                body, init, None, length=n_steps
+            )
+            return (
+                cache, toks, n_emits, (tok[None], lens),
+                self._aux(aux, decode=True, drafted=drafted),
+            )
+
+        return decode_chunk

@@ -78,6 +78,8 @@ def resolve_model_preset(model_name: str) -> str:
         return "ling-tiny" if "tiny" in name else "ling-3.0-flash-vl-l7e128"
     if name.startswith("mellum") or "/mellum" in name:
         return "mellum-tiny" if "tiny" in name else "mellum2-12b-a2.5b-l12"
+    if "exaone" in name:
+        return "exaone_moe-tiny" if "tiny" in name else "k-exaone-236b-a23b-l5e16"
     if "mixtral" in name or "8x7b" in name:
         return "mixtral-8x7b"
     if "gemma" in name:

@@ -13,9 +13,15 @@ in two kinds (``ops/gqa.py``), ``window`` layers that see the last
 ``sliding_window`` positions beside a ``full`` layer every fourth, each
 kind with its own rotary frequencies (YaRN on the full layers,
 ``ops/rope.py``), and softmax-routed experts with neither selection bias
-nor shared expert.  A new architecture is a new layer kind here, not
-another flag on ``LlamaConfig``; ``models/llama.py`` keeps serving the
-configurations it serves.
+nor shared expert.  ``exaone_moe`` (K-EXAONE-236B-A23B): the same two GQA
+kinds with an RMSNorm over each query and key head (QK-norm), rotary
+frequencies on the window layers and none at all on the full ones, a
+leading dense layer, sigmoid-routed experts with a shared one of which
+this process may hold a share, and one multi-token-prediction module
+after the stack (:func:`mtp_forward`), which the engine serves as the
+draft of its decode step.  A new architecture is a new layer kind here,
+not another flag on ``LlamaConfig``; ``models/llama.py`` keeps serving
+the configurations it serves.
 
 One ``forward`` serves the three ways the engine calls a model: a cold
 batch into fresh state, a chunk of one slot's prompt, and one decode
@@ -37,6 +43,11 @@ State of a slot, by the layer's mixer:
   ``p % R``.  Like a recurrent state it exists only as of the last token
   written, so a prefix hit takes it from a snapshot.
 
+A prediction module adds two entries behind the stack's: its block's
+``k``, ``v`` rows (a ``full`` layer's) and ``h_last`` (D,), the stack's
+output at the last position it has seen, which the module needs with the
+token after it; like a recurrent state it exists only as of that token.
+
 ``ROW_LEAVES`` names the leaves that hold a row a position; every other
 leaf is state as of the last token.  ``HybridConfig`` holds what every
 family has and the KDA and MLA sizes; ``GqaConfig`` adds the GQA sizes,
@@ -44,9 +55,11 @@ the rotary parameters of each kind and the routing options.
 
 What is read from a family's convention and not from a key of the
 public config is listed under ``assumed`` in
-``benchmarks/configs/ling-3.0-flash-vl-l7e128.json`` and
-``benchmarks/configs/mellum2-12b-a2.5b-l12.json``; the plain references
-are ``models/hybrid_reference.py`` and ``models/mellum_reference.py``.
+``benchmarks/configs/ling-3.0-flash-vl-l7e128.json``,
+``benchmarks/configs/mellum2-12b-a2.5b-l12.json`` and
+``benchmarks/configs/k-exaone-236b-a23b-l5e16.json``; the plain references
+are ``models/hybrid_reference.py``, ``models/mellum_reference.py`` and
+``models/exaone_moe_reference.py``.
 """
 
 from __future__ import annotations
@@ -61,12 +74,14 @@ import jax.numpy as jnp
 from generativeaiexamples_tpu.models.llama import rms_norm
 from generativeaiexamples_tpu.ops import gqa, kda, mla, moe
 from generativeaiexamples_tpu.ops.dispatch import record
-from generativeaiexamples_tpu.ops.rope import RopeSpec, apply_rope_spec, rope_spec
+from generativeaiexamples_tpu.ops.rope import NO_ROPE, RopeSpec, apply_rope_spec, rope_spec
 
 Params = Mapping[str, Any]
 F32 = jnp.float32
 MIXERS = ("kda", "mla", "full", "window")
 MLPS = ("dense", "experts")
+# The block of a prediction module: a full GQA layer with experts.
+MTP_KIND = ("full", "experts")
 # State leaves that hold one row a position, which can be cut at any
 # token (the others exist only as of the last token written), and those
 # that are rings of rows.  Rows run along axis 1 (slot axis 0).
@@ -124,6 +139,8 @@ class HybridConfig:
     sliding_window: ClassVar[int] = 0
     rope_full: ClassVar[RopeSpec | None] = None
     rope_window: ClassVar[RopeSpec | None] = None
+    qk_norm: ClassVar[bool] = False
+    mtp_layers: ClassVar[int] = 0
 
     def __post_init__(self) -> None:
         for mixer, mlp in self.layer_kinds:
@@ -142,6 +159,13 @@ class HybridConfig:
                 raise ValueError("a GQA layer kind needs its rotary parameters")
             if self.layers_of("window") and self.sliding_window < 1:
                 raise ValueError("a window layer needs sliding_window")
+        if self.mtp_layers not in (0, 1):
+            raise ValueError(
+                "more than one prediction module is not served: the decode "
+                "step verifies one draft a row"
+            )
+        if self.mtp_layers and self.n_kv_heads < 1:
+            raise ValueError("a prediction module's block is a full GQA layer")
 
     @property
     def n_layers(self) -> int:
@@ -150,6 +174,12 @@ class HybridConfig:
     @property
     def has_attn_counters(self) -> bool:
         return bool(self.layers_of("full") or self.layers_of("window"))
+
+    @property
+    def draft(self) -> str:
+        """What drafts the decode step: ``mtp``, the model's own
+        prediction module, where one is held; else nothing."""
+        return "mtp" if self.mtp_layers else ""
 
     @property
     def n_counters(self) -> int:
@@ -195,6 +225,7 @@ class HybridConfig:
         return (
             len(self.layers_of("kda")) * kda_layer
             + len(self.layers_of("window")) * window_layer
+            + self.mtp_layers * self.d_model * jnp.dtype(self.dtype).itemsize
         )
 
 
@@ -213,6 +244,12 @@ class GqaConfig(HybridConfig):
     sliding_window: int = 0
     rope_full: RopeSpec | None = None
     rope_window: RopeSpec | None = None
+    # An RMSNorm over each query and key head, before the rotation.
+    qk_norm: bool = False
+    # Prediction modules held behind the stack (0 or 1): a full GQA layer
+    # with experts, fed the next token's embedding beside the stack's
+    # output; held, it drafts every decode step (``draft``).
+    mtp_layers: int = 0
 
 
 def from_hf_config(
@@ -221,10 +258,14 @@ def from_hf_config(
     max_len: int,
     expert_offset: int = 0,
     kv_dtype: str = "bfloat16",
+    draft: str = "",
 ) -> HybridConfig:
     """The public ``config.json`` keys -> ``HybridConfig``, by
-    ``model_type``: ``mellum`` (:func:`_from_mellum`), else the
-    ``bailing_hybrid`` family, of which the rest speaks.
+    ``model_type``: ``mellum`` (:func:`_from_mellum`), ``exaone_moe``
+    (:func:`_from_exaone`), else the ``bailing_hybrid`` family, of which
+    the rest speaks.  ``draft`` ``mtp`` holds the model's own prediction
+    module and serves it as the decode step's draft; a family without one
+    refuses it.
 
     ``num_experts`` counts the experts held (the chip's share);
     ``num_experts_published`` (absent: the same) the router's outputs.
@@ -233,6 +274,16 @@ def from_hf_config(
     ``i`` is MLA where ``(i + 1) % layer_group_size == 0`` and KDA
     otherwise; the first ``first_k_dense_replace`` layers kept are dense.
     """
+    if model.get("model_type") == "exaone_moe":
+        return _from_exaone(
+            model, max_len=max_len, expert_offset=expert_offset,
+            kv_dtype=kv_dtype, draft=draft,
+        )
+    if draft:
+        raise ValueError(
+            f"draft {draft!r} is not served for model_type "
+            f"{model.get('model_type')!r}: it has no prediction module here"
+        )
     if model.get("model_type") == "mellum":
         return _from_mellum(model, max_len=max_len, kv_dtype=kv_dtype)
     period = int(model["layer_group_size"])
@@ -336,6 +387,94 @@ def _from_mellum(model: Mapping[str, Any], *, max_len: int, kv_dtype: str) -> Gq
     )
 
 
+def _from_exaone(
+    model: Mapping[str, Any], *, max_len: int, expert_offset: int, kv_dtype: str,
+    draft: str,
+) -> GqaConfig:
+    """``model_type: exaone_moe``: ``layer_types`` and ``mlp_layer_types``
+    name each layer's mixer and MLP (``dense`` for the first
+    ``first_k_dense_replace``, then ``sparse``); a cut in depth keeps
+    their first ``num_hidden_layers`` entries.  Every GQA layer has
+    QK-norm; the one ``rope_parameters`` section rotates the window
+    layers, the full layers are not rotated.  Routing is sigmoid scores
+    with a selection bias, ``n_group`` groups, renormalised and scaled;
+    ``num_experts`` counts the experts held of ``num_experts_published``
+    router outputs (absent: the same), as in ``bailing_hybrid``.  With
+    ``draft`` ``mtp`` the one prediction module is held; without, it is
+    left out."""
+    n = int(model["num_hidden_layers"])
+    mixers = {"sliding_attention": "window", "full_attention": "full"}
+    mlps = {"dense": "dense", "sparse": "experts"}
+    layer_types, mlp_types = list(model["layer_types"]), list(model["mlp_layer_types"])
+    if len(layer_types) < n or len(mlp_types) < n:
+        raise ValueError("layer_types and mlp_layer_types name fewer layers than num_hidden_layers")
+    for kind, known in ((layer_types[:n], mixers), (mlp_types[:n], mlps)):
+        unknown = sorted(set(kind) - set(known))
+        if unknown:
+            raise ValueError(f"layer types {unknown} are not served")
+    dense = int(model["first_k_dense_replace"])
+    if [m == "dense" for m in mlp_types[:n]] != [j < dense for j in range(n)]:
+        raise ValueError("mlp_layer_types and first_k_dense_replace disagree")
+    rope = model["rope_parameters"]
+    if str(rope.get("rope_type", "default")) != "default":
+        raise ValueError(
+            f"rope_type {rope.get('rope_type')!r} is not served for exaone_moe: "
+            "its window layers take the plain frequencies"
+        )
+    if model.get("scoring_func", "sigmoid") != "sigmoid":
+        raise ValueError(
+            "scoring_func other than sigmoid is not served for exaone_moe "
+            "(a softmax router has no groups: n_group > 1 with softmax is refused)"
+        )
+    if model.get("hidden_act", "silu") != "silu":
+        raise ValueError("activations other than silu are not served")
+    modules = int(model.get("num_nextn_predict_layers", 0))
+    if draft not in ("", "mtp"):
+        raise ValueError(f"draft {draft!r} is not served: only the model's own module ('mtp')")
+    if draft:
+        if modules != 1:
+            raise ValueError(
+                f"num_nextn_predict_layers {modules} is not served: the decode "
+                "step verifies the draft of exactly one prediction module"
+            )
+        if list(model.get("mtp_layer_types", ["full_attention"])) != ["full_attention"]:
+            raise ValueError(
+                f"mtp_layer_types {model.get('mtp_layer_types')} is not served: "
+                "the module's block is a full_attention layer"
+            )
+    held = int(model["num_experts"])
+    return GqaConfig(
+        vocab_size=int(model["vocab_size"]),
+        d_model=int(model["hidden_size"]),
+        layer_kinds=tuple(
+            (mixers[a], mlps[m]) for a, m in zip(layer_types[:n], mlp_types[:n])
+        ),
+        n_heads=int(model["num_attention_heads"]),
+        n_kv_heads=int(model["num_key_value_heads"]),
+        attn_head_dim=int(model["head_dim"]),
+        sliding_window=int(model["sliding_window"]),
+        rope_full=NO_ROPE,
+        rope_window=rope_spec(rope),
+        qk_norm=True,
+        mtp_layers=1 if draft else 0,
+        d_ff=int(model["intermediate_size"]),
+        moe_d_ff=int(model["moe_intermediate_size"]),
+        shared_d_ff=int(model["moe_intermediate_size"]) * int(model["num_shared_experts"]),
+        n_experts=int(model.get("num_experts_published", held)),
+        experts_held=held,
+        expert_offset=int(expert_offset),
+        n_experts_per_tok=int(model["num_experts_per_tok"]),
+        n_group=int(model["n_group"]),
+        topk_group=int(model["topk_group"]),
+        routed_scaling=float(model["routed_scaling_factor"]),
+        norm_topk=bool(model["norm_topk_prob"]),
+        norm_eps=float(model["rms_norm_eps"]),
+        max_seq_len=int(max_len),
+        dtype=str(model.get("torch_dtype", "bfloat16")),
+        kv_dtype=kv_dtype,
+    )
+
+
 # -- parameters ---------------------------------------------------------------
 
 
@@ -367,6 +506,8 @@ def _layer_shapes(cfg: HybridConfig, mixer: str, mlp: str) -> dict:
             w_qkv=((D, (H + 2 * KH) * hd), D),  # q heads, then k, then v
             w_o=((H * hd, D), H * hd),
         )
+        if cfg.qk_norm:
+            shapes.update(q_norm=((hd,), 1.0), k_norm=((hd,), 1.0))
     else:
         qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
         shapes.update(
@@ -410,18 +551,31 @@ def init_params(cfg: HybridConfig, key: jax.Array) -> Params:
             return jnp.full(shape, init, dtype)
         return _normal(next(keys), float(init) ** -0.5, shape, dtype)
 
-    layers = tuple(
-        {n: leaf(n, s, i) for n, (s, i) in _layer_shapes(cfg, *kind).items()}
-        for kind in cfg.layer_kinds
-    )
-    return {
+    def layer(kind):
+        return {n: leaf(n, s, i) for n, (s, i) in _layer_shapes(cfg, *kind).items()}
+
+    params = {
+        "layers": tuple(layer(kind) for kind in cfg.layer_kinds),
         "embed": _normal(next(keys), 1.0, (cfg.vocab_size, cfg.d_model), dtype),
-        "layers": layers,
         "final_norm": jnp.ones((cfg.d_model,), dtype),
         "lm_head": _normal(
             next(keys), cfg.d_model**-0.5, (cfg.d_model, cfg.vocab_size), dtype
         ),
     }
+    if cfg.mtp_layers:
+        # A key stream of its own: the stack's parameters are the same
+        # with the module held and without.
+        D = cfg.d_model
+        keys = iter(jax.random.split(jax.random.fold_in(key, 0x6D7470), 40))
+        params["mtp"] = {
+            "enorm": jnp.ones((D,), dtype),
+            "hnorm": jnp.ones((D,), dtype),
+            # [embedding of the next token ; the stack's output] -> D
+            "eh_proj": _normal(next(keys), (2 * D) ** -0.5, (2 * D, D), dtype),
+            "layer": layer(MTP_KIND),
+            "final_norm": jnp.ones((D,), dtype),
+        }
+    return params
 
 
 def balance_router_biases(params: Params, cfg: HybridConfig, key: jax.Array) -> Params:
@@ -443,19 +597,28 @@ def balance_router_biases(params: Params, cfg: HybridConfig, key: jax.Array) -> 
         {**lp, "router_bias": next(biases)} if "router_bias" in lp else lp
         for lp in params["layers"]
     )
-    return {**params, "layers": layers}
+    params = {**params, "layers": layers}
+    if cfg.mtp_layers:
+        mtp = params["mtp"]
+        params["mtp"] = {**mtp, "layer": {**mtp["layer"], "router_bias": next(biases)}}
+    return params
 
 
 @functools.partial(jax.jit, static_argnames=("cfg",))
 def _balanced_biases(params, cfg: HybridConfig, tokens):
     """``forward`` over whole rows from nothing, with each expert layer's
-    bias balanced on that layer's inputs before they pass through it."""
+    bias balanced on that layer's inputs before they pass through it; a
+    prediction module's last, on what the stack hands it (the token that
+    follows each position is the row's next; the last position's wraps,
+    which random tokens do not notice)."""
     b, s = tokens.shape
     pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     valid, n_valid = jnp.ones((b, s), bool), jnp.full((b,), s, jnp.int32)
+    state = init_state(cfg, b, s)
     x = params["embed"][tokens]
     out = []
-    for (mixer, mlp), lp, st in zip(cfg.layer_kinds, params["layers"], init_state(cfg, b, s)):
+
+    def through(x, lp, st, mixer, mlp):
         x, _, _ = _mix(x, lp, st, mixer, pos, valid, n_valid, cfg, s)
         if mlp == "experts":
             h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
@@ -465,7 +628,13 @@ def _balanced_biases(params, cfg: HybridConfig, tokens):
             )
             out.append(bias)
             lp = {**lp, "router_bias": bias}
-        x, _ = _mlp(x, lp, mlp, valid, cfg, None)
+        return _mlp(x, lp, mlp, valid, cfg, None)[0]
+
+    for (mixer, mlp), lp, st in zip(cfg.layer_kinds, params["layers"], state):
+        x = through(x, lp, st, mixer, mlp)
+    if cfg.mtp_layers:
+        u = _mtp_input(params, cfg, x, jnp.roll(tokens, -1, axis=1))
+        through(u, params["mtp"]["layer"], state[cfg.n_layers], *MTP_KIND)
     return out
 
 
@@ -473,7 +642,8 @@ def _balanced_biases(params, cfg: HybridConfig, tokens):
 
 
 def init_state(cfg: HybridConfig, batch: int, max_len: int) -> tuple:
-    """Zero state for ``batch`` rows: one dict a layer, of its mixer's kind."""
+    """Zero state for ``batch`` rows: one dict a layer, of its mixer's
+    kind; behind them a prediction module's rows and ``h_last``."""
     H, K = cfg.n_heads, cfg.kda_head_dim
     sd = cfg.state_dtype
     out = []
@@ -493,17 +663,28 @@ def init_state(cfg: HybridConfig, batch: int, max_len: int) -> tuple:
             )
         else:
             out.append({"latent": jnp.zeros((batch, max_len, cfg.latent_width), sd)})
+    if cfg.mtp_layers:
+        shape = (batch, max_len, cfg.n_kv_heads * cfg.attn_head_dim)
+        out.append({n: jnp.zeros(shape, sd) for n in GQA_LEAVES["full"]})
+        out.append({"h_last": jnp.zeros((batch, cfg.d_model), jnp.dtype(cfg.dtype))})
     return tuple(out)
 
 
 def state_bytes(cfg: HybridConfig, batch: int, max_len: int) -> dict[str, int]:
     """Bytes of the slots' state by kind: ``full`` (rows that grow with
     the tokens: latent, K/V), ``window`` (rings: the same at any
-    ``max_len`` over the window) and ``recurrent``."""
+    ``max_len`` over the window), ``recurrent`` and, where a prediction
+    module is held, ``draft`` (its rows and ``h_last``)."""
     out = {"full": 0, "window": 0, "recurrent": 0}
+    if cfg.mtp_layers:
+        out["draft"] = 0
     shapes = jax.eval_shape(lambda: init_state(cfg, batch, max_len))
-    for (mixer, _), layer in zip(cfg.layer_kinds, shapes):
-        kind = {"kda": "recurrent", "window": "window"}.get(mixer, "full")
+    kinds = [
+        {"kda": "recurrent", "window": "window"}.get(mixer, "full")
+        for mixer, _ in cfg.layer_kinds
+    ]
+    kinds += ["draft"] * (len(shapes) - len(kinds))
+    for kind, layer in zip(kinds, shapes):
         out[kind] += sum(leaf.size * leaf.dtype.itemsize for leaf in layer.values())
     return out
 
@@ -598,12 +779,15 @@ def _mla_mixer(h, lp, st, pos, valid, n_valid, cfg: HybridConfig, window: int, a
 
 
 def _gqa_mixer(
-    h, lp, st, mixer, pos, valid, n_valid, cfg: HybridConfig, window: int, apart: bool
+    h, lp, st, mixer, pos, valid, n_valid, cfg: HybridConfig, window: int, apart: bool,
+    site: str = "",
 ):
     """A ``full`` or ``window`` layer.  Returns (output, state, counters
     in the order of ``ATTN_COUNTERS``): a full layer writes its rows and
     attends over the first ``window`` of them; a window layer attends
-    over its ring as it was and over its own new rows, then writes."""
+    over its ring as it was and over its own new rows, then writes.
+    ``site`` prefixes the layer's ``kernel_paths`` entry (a prediction
+    module's block)."""
     b, s, _ = h.shape
     H, KH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.attn_head_dim
     scope = f"layer/attn_{mixer}"
@@ -611,13 +795,17 @@ def _gqa_mixer(
     with jax.named_scope(f"{scope}/qkv"):
         qkv = jnp.dot(h, lp["w_qkv"]).reshape(b, s, H + 2 * KH, hd)
         q, k, v = qkv[:, :, :H], qkv[:, :, H : H + KH], qkv[:, :, H + KH :]
+    if cfg.qk_norm:
+        with jax.named_scope(f"{scope}/qk_norm"):
+            q = rms_norm(q, lp["q_norm"], cfg.norm_eps)
+            k = rms_norm(k, lp["k_norm"], cfg.norm_eps)
     with jax.named_scope(f"{scope}/rope"):
         q, k = apply_rope_spec(q, pos, spec), apply_rope_spec(k, pos, spec)
         k, v = k.reshape(b, s, KH * hd), v.reshape(b, s, KH * hd)  # a state row
     names = GQA_LEAVES[mixer]
     old_k, old_v = (st[n] for n in names)
     rows = old_k.shape[1]
-    record(f"attn_{mixer} b={b} s={s} t={window if mixer == 'full' else rows}", False)
+    record(f"{site}attn_{mixer} b={b} s={s} t={window if mixer == 'full' else rows}", False)
 
     def write(at):
         # A token that does not count is written nowhere (``at`` == rows).
@@ -676,7 +864,7 @@ def _expert_layer(h, lp, valid, cfg: HybridConfig, mesh):
 
 def _mix(
     x, lp, st, mixer, pos, valid, n_valid, cfg: HybridConfig, window: int,
-    apart: bool = False,
+    apart: bool = False, site: str = "",
 ):
     """The mixer's half of a layer: (x + mixer(norm(x)), new state, a GQA
     layer's ``ATTN_COUNTERS`` or 0)."""
@@ -688,7 +876,7 @@ def _mix(
         y, st = _mla_mixer(h, lp, st, pos, valid, n_valid, cfg, window, apart)
     else:
         y, st, read = _gqa_mixer(
-            h, lp, st, mixer, pos, valid, n_valid, cfg, window, apart
+            h, lp, st, mixer, pos, valid, n_valid, cfg, window, apart, site
         )
     return x + y, st, read
 
@@ -720,7 +908,8 @@ def forward(
     and full layers attend over the first ``window`` rows, ``rows_apart``
     a row at a time (``_attend``: the same numbers, one row's scores in
     memory).  Returns (hidden (b, s, D), state, counters
-    (cfg.n_counters,) int32 summed over layers)."""
+    (cfg.n_counters,) int32 summed over layers).  The hidden states are
+    the stack's output before its final norm."""
     b, s = tokens.shape
     x = params["embed"][tokens]
     steps = jnp.arange(s, dtype=jnp.int32)[None, :]
@@ -741,7 +930,9 @@ def forward(
         out_state.append(st)
     if cfg.has_attn_counters:
         counters = jnp.concatenate([counters, read])
-    return x, tuple(out_state), counters
+    # A prediction module's state lies behind the stack's and is its own
+    # to move (``mtp_forward``).
+    return x, tuple(out_state) + tuple(state[cfg.n_layers :]), counters
 
 
 def logits(params: Params, cfg: HybridConfig, hidden: jnp.ndarray) -> jnp.ndarray:
@@ -750,6 +941,62 @@ def logits(params: Params, cfg: HybridConfig, hidden: jnp.ndarray) -> jnp.ndarra
     return jnp.einsum(
         "...d,dv->...v", h, params["lm_head"], preferred_element_type=F32
     )
+
+
+# -- the prediction module ---------------------------------------------------------
+
+
+def _mtp_input(params: Params, cfg: HybridConfig, hidden, next_tokens):
+    """``W_eh [RMSNorm_e(Emb(x_{t+1})) ; RMSNorm_h(hbar_t)]``: ``hidden``
+    (b, s, D) the stack's output before its final norm, ``hbar`` after."""
+    mp = params["mtp"]
+    with jax.named_scope("mtp/embed"):
+        e = rms_norm(params["embed"][next_tokens], mp["enorm"], cfg.norm_eps)
+    with jax.named_scope("mtp/eh_proj"):
+        hbar = rms_norm(hidden, params["final_norm"], cfg.norm_eps)
+        both = jnp.concatenate([e, rms_norm(hbar, mp["hnorm"], cfg.norm_eps)], axis=-1)
+        return jnp.dot(both.astype(hidden.dtype), mp["eh_proj"])
+
+
+def mtp_forward(
+    params: Params,
+    cfg: HybridConfig,
+    hidden: jnp.ndarray,
+    next_tokens: jnp.ndarray,
+    pos: jnp.ndarray,
+    valid: jnp.ndarray,
+    rows: Mapping[str, jnp.ndarray],
+    *,
+    window: int,
+    mesh=None,
+    rows_apart: bool = False,
+):
+    """The prediction module at positions ``pos`` (b, s): ``hidden`` the
+    stack's output there (``forward``'s), ``next_tokens`` the token that
+    follows each, ``valid`` (b, s) which of them count (a position that
+    does not writes no row and routes nowhere; ``pos`` may be -1 there),
+    ``rows`` its block's K/V rows.  Returns (the module's hidden (b, s, D),
+    whose :func:`mtp_logits` at ``t`` predict token ``t + 2``; rows;
+    counters as ``forward``'s)."""
+    lp = params["mtp"]["layer"]
+    n_valid = valid.sum(-1).astype(jnp.int32)
+    u = _mtp_input(params, cfg, hidden, next_tokens)
+    with jax.named_scope("mtp/block"):
+        x, rows, read = _mix(
+            u, lp, rows, MTP_KIND[0], pos, valid, n_valid, cfg, window, rows_apart,
+            site="mtp_",
+        )
+        x, counters = _mlp(x, lp, MTP_KIND[1], valid, cfg, mesh)
+    return x, rows, jnp.concatenate([counters, read])
+
+
+def mtp_logits(params: Params, cfg: HybridConfig, hidden: jnp.ndarray) -> jnp.ndarray:
+    """The module's own final norm, then the stack's head (shared)."""
+    with jax.named_scope("mtp/head"):
+        h = rms_norm(hidden, params["mtp"]["final_norm"], cfg.norm_eps)
+        return jnp.einsum(
+            "...d,dv->...v", h, params["lm_head"], preferred_element_type=F32
+        )
 
 
 # -- presets --------------------------------------------------------------------
@@ -833,6 +1080,42 @@ MELLUM_TINY = {
 }
 
 
+# LGAI-EXAONE/K-EXAONE-236B-A23B's config.json: every key that gives the
+# model its shape.
+K_EXAONE_236B = {
+    "model_type": "exaone_moe", "num_hidden_layers": 48, "hidden_size": 6144,
+    "intermediate_size": 18432, "moe_intermediate_size": 2048,
+    "num_attention_heads": 64, "num_key_value_heads": 8, "head_dim": 128,
+    "hidden_act": "silu", "first_k_dense_replace": 1,
+    "layer_types": _MELLUM_PERIOD * 12,
+    "mlp_layer_types": ["dense"] + ["sparse"] * 47,
+    "num_experts": 128, "num_experts_per_tok": 8, "num_shared_experts": 1,
+    "scoring_func": "sigmoid", "n_group": 1, "topk_group": 1,
+    "norm_topk_prob": True, "routed_scaling_factor": 2.5,
+    "sliding_window": 128, "max_position_embeddings": 262144,
+    "rope_parameters": {"rope_type": "default", "rope_theta": 1000000},
+    "num_nextn_predict_layers": 1, "mtp_layer_types": ["full_attention"],
+    "rms_norm_eps": 1e-05, "vocab_size": 153600, "tie_word_embeddings": False,
+}
+# Rank 0's share of an eight-chip layer group: published layers 0-4
+# (window + dense, window, window, full, window) and the prediction
+# module, 16 of the 128 experts, an eighth of the vocabulary.
+K_EXAONE_L5E16_CUT = {
+    "num_hidden_layers": 5, "num_experts": 16, "num_experts_published": 128,
+    "vocab_size": 19200,
+}
+# Every ratio at sizes a CPU test runs: a period of 4 after a dense first
+# layer (two periods deep), a window of 8 (shorter than any chunk), 4 of
+# 16 experts held and 2 a token, one prediction module.
+EXAONE_TINY = {
+    **K_EXAONE_236B, "num_hidden_layers": 8, "hidden_size": 64,
+    "intermediate_size": 128, "moe_intermediate_size": 32,
+    "num_attention_heads": 4, "num_key_value_heads": 2, "head_dim": 16,
+    "num_experts": 4, "num_experts_published": 16, "num_experts_per_tok": 2,
+    "sliding_window": 8, "vocab_size": 512, "torch_dtype": "float32",
+}
+
+
 def ling_flash_vl_l7e128() -> HybridConfig:
     return from_hf_config({**LING_FLASH_VL, **LING_L7E128_CUT}, max_len=2048)
 
@@ -849,9 +1132,21 @@ def mellum_tiny() -> HybridConfig:
     return from_hf_config(MELLUM_TINY, max_len=256, kv_dtype="float32")
 
 
+def k_exaone_236b_l5e16() -> HybridConfig:
+    return from_hf_config(
+        {**K_EXAONE_236B, **K_EXAONE_L5E16_CUT}, max_len=8192, draft="mtp"
+    )
+
+
+def exaone_tiny() -> HybridConfig:
+    return from_hf_config(EXAONE_TINY, max_len=256, kv_dtype="float32", draft="mtp")
+
+
 PRESETS = {
     "ling-3.0-flash-vl-l7e128": ling_flash_vl_l7e128,
     "ling-tiny": ling_tiny,
     "mellum2-12b-a2.5b-l12": mellum2_12b_l12,
     "mellum-tiny": mellum_tiny,
+    "k-exaone-236b-a23b-l5e16": k_exaone_236b_l5e16,
+    "exaone_moe-tiny": exaone_tiny,
 }

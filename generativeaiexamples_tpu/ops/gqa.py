@@ -35,6 +35,9 @@ import jax.numpy as jnp
 
 F32 = jnp.float32
 _NEG = -1e30
+# Queries a row up to which a call is a decode step (one token, or a
+# token and its draft) and reads the state rows as they lie (``_qk``).
+_STEP_QUERIES = 2
 
 
 def _grouped(q, n_kv: int):
@@ -55,14 +58,16 @@ def _qk(q, keys, n_kv: int):
     KV head's lanes of the rows and multiplies its own query heads with
     them.  A decode step (s == 1, every slot) would have each of those
     slices copied out of the rows first (the v5e compiler materialises
-    them: 24 copies of 67 MB a step at 32 slots x 8,192), so it widens the
+    them: 24 copies of 67 MB a step at 32 slots x 8,192; a step that
+    verifies a draft has two queries a row and is the same case), so it
+    widens the
     queries instead: a query head's values sit in its KV head's lanes of a
     ``KH * D`` vector, zeros elsewhere, and one product reads the rows
     once as they lie.  KH times the multiplications, which a decode step
     does not notice; the same numbers."""
     b, s, h, d = q.shape
     qg = _grouped(q, n_kv)
-    if s == 1:
+    if s <= _STEP_QUERIES:
         eye = jnp.eye(n_kv, dtype=q.dtype)
         wide = (qg[:, :, :, :, None, :] * eye[None, None, :, None, :, None]).reshape(
             b, s, h, n_kv * d
@@ -80,7 +85,7 @@ def _pv(probs, values, n_kv: int):
     b, h, s, t = probs.shape
     d, g = values.shape[-1] // n_kv, h // n_kv
     probs = probs.astype(values.dtype)
-    if s == 1:
+    if s <= _STEP_QUERIES:
         wide = jnp.einsum("bhst,btc->bshc", probs, values).reshape(b, s, n_kv, g, n_kv, d)
         own = jnp.eye(n_kv, dtype=wide.dtype)  # a head keeps its KV head's lanes
         return jnp.einsum("bsngmd,nm->bsngd", wide, own).reshape(b, s, h, d)

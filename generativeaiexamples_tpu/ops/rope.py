@@ -56,7 +56,9 @@ def _rotate_half(x: jnp.ndarray, cos: jnp.ndarray, sin: jnp.ndarray) -> jnp.ndar
 @dataclasses.dataclass(frozen=True)
 class RopeSpec:
     """One section of a config's ``rope_parameters``: the plain
-    frequencies (``rope_type`` ``default``) or YaRN's."""
+    frequencies (``rope_type`` ``default``) or YaRN's; ``none`` is a layer
+    kind that is not rotated at all (position-free: its scores carry no
+    position, the causal mask alone orders it)."""
 
     theta: float
     rope_type: str = "default"
@@ -68,11 +70,15 @@ class RopeSpec:
     truncate: bool = True
 
     def __post_init__(self) -> None:
-        if self.rope_type not in ("default", "yarn"):
+        if self.rope_type not in ("default", "yarn", "none"):
             raise ValueError(
                 f"rope_type {self.rope_type!r} is not served: only 'default' "
                 "and 'yarn' frequencies are implemented (ops/rope.py)"
             )
+
+
+# A layer kind that is not rotated.
+NO_ROPE = RopeSpec(theta=1.0, rope_type="none")
 
 
 def rope_spec(section: Mapping[str, Any]) -> RopeSpec:
@@ -132,7 +138,9 @@ def spec_frequencies(spec: RopeSpec, head_dim: int) -> np.ndarray:
 def apply_rope_spec(x: jnp.ndarray, positions: jnp.ndarray, spec: RopeSpec) -> jnp.ndarray:
     """:func:`apply_rope` with the frequencies of ``spec``; cos and sin
     are multiplied by its ``attention_factor`` (so q.k grows by its
-    square)."""
+    square).  A ``none`` spec leaves ``x`` as it is."""
+    if spec.rope_type == "none":
+        return x
     inv_freq = jnp.asarray(spec_frequencies(spec, x.shape[-1]))
     angles = positions.astype(jnp.float32)[..., None] * inv_freq
     cos = (jnp.cos(angles) * spec.attention_factor)[:, :, None, :].astype(x.dtype)

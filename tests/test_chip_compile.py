@@ -192,17 +192,20 @@ def test_paged_kernel_compiles(one_chip, page_tokens, chunk):
 EXPERT_WIDTHS = {
     "ling": (2560, 768, 512, 128, (8, 4), "sigmoid", (128, 512, 768), (128, 256, 512)),
     "mellum": (2304, 896, 64, 64, (1, 1), "softmax", (128, 1152, 896), (128, 896, 768)),
+    "exaone": (6144, 2048, 128, 16, (1, 1), "sigmoid", (128, 512, 512), (128, 512, 768)),
 }
 
 
 @pytest.mark.parametrize("family", sorted(EXPERT_WIDTHS))
 @pytest.mark.parametrize("tokens", [32, 256])
 def test_grouped_expert_products_compile_at_ling_widths(one_chip, tokens, family, monkeypatch):
-    """``ops/moe.py``'s sorted dispatch at the published widths of the two
+    """``ops/moe.py``'s sorted dispatch at the published widths of the
     expert families served: ling-3.0-flash-vl-l7e128 (128 experts held of
-    512, hidden 2,560, expert width 768, sigmoid scores, 4 of 8 groups)
-    and mellum2-12b-a2.5b-l12 (all 64, hidden 2,304, width 896 = 7 x 128,
-    softmax, no groups), 8 a token; a decode step's 32 rows and a prefill
+    512, hidden 2,560, expert width 768, sigmoid scores, 4 of 8 groups),
+    mellum2-12b-a2.5b-l12 (all 64, hidden 2,304, width 896 = 7 x 128,
+    softmax, no groups) and k-exaone-236b-a23b-l5e16 (16 held of 128,
+    hidden 6,144, width 2,048, sigmoid scores with a bias, one group),
+    8 a token; a decode step's 32 rows and a prefill
     chunk's 256.  The grouped products are megablox's ``gmm``; this holds
     the tilings ``ops.moe._tiling`` picks to what Mosaic accepts, and
     Ling's to what they were."""
@@ -217,7 +220,7 @@ def test_grouped_expert_products_compile_at_ling_widths(one_chip, tokens, family
 
     def layer(x, w_router, bias, w_gu_e, w_down_e, valid):
         idx, w = moe.route(
-            x, w_router, bias if family == "ling" else None, k=k, n_group=n_group,
+            x, w_router, bias if score == "sigmoid" else None, k=k, n_group=n_group,
             topk_group=topk_group, norm_topk=True, scale=2.5, score=score,
         )
         return moe.expert_mlp(
@@ -241,11 +244,15 @@ def test_grouped_expert_products_compile_at_ling_widths(one_chip, tokens, family
 GROUP_PROGRAMS = {
     "mellum": ("mellum2-12b-a2.5b-l12", 4, 8192),
     "ling": ("ling-3.0-flash-vl-l7e128", 8, 2048),
+    "exaone": ("k-exaone-236b-a23b-l5e16", 8, 8192),
 }
 # What Mellum's cell has to spare beside its weights, slots and snapshots
 # (peak 15.19 of the 16.91 GB the build sees, less the reference check's
-# blocks: PERF.md section 4).
+# blocks: PERF.md section 4).  K-EXAONE's cut holds 9.09 GB of weights and
+# 2.2 GB of state: 5 GB to spare, of which its group of 8 rows of 6,144
+# (a full layer's scores of 64 heads are 537 MB a row) may take half.
 SPARE_BYTES = 1_400_000_000
+SPARE_BY_FAMILY = {"exaone": 2_500_000_000}
 
 
 @pytest.mark.parametrize("family", sorted(GROUP_PROGRAMS))
@@ -272,7 +279,9 @@ def test_the_chunks_of_several_slots_compile_as_one_program(one_chip, family, mo
     model = json.loads((configs / f"{config}.json").read_text())
     engine = model["engine"]
     max_len, chunk = int(engine["max_len"]), int(engine["prefill_chunk_tokens"])
-    cfg = hybrid.from_hf_config(model, max_len=max_len, kv_dtype=engine["kv_dtype"])
+    cfg = hybrid.from_hf_config(
+        model, max_len=max_len, kv_dtype=engine["kv_dtype"], draft=engine.get("draft", ""),
+    )
     serving = HybridServing(cfg, None, max_len)
     assert serving.chunks_per_program(chunk) == rows and window == max_len
 
@@ -291,4 +300,55 @@ def test_the_chunks_of_several_slots_compile_as_one_program(one_chip, family, mo
         spec((2,), jnp.uint32), (floats, floats, ints), window,
     ).compile()
     assert "tpu_custom_call" in compiled.as_text()
-    assert compiled.memory_analysis().temp_size_in_bytes < SPARE_BYTES
+    spare = SPARE_BY_FAMILY.get(family, SPARE_BYTES)
+    assert compiled.memory_analysis().temp_size_in_bytes < spare
+
+
+def test_the_verify_chunk_compiles_at_the_published_widths(one_chip, monkeypatch):
+    """The decode chunk of a model that drafts its own step
+    (``HybridServing._make_verify_chunk``: the prediction module's
+    catch-up, then 8 steps of the stack over [token, draft], acceptance,
+    the module over the accepted positions) for k-exaone-236b-a23b-l5e16's
+    32 slots of 8,192 at the widest decode window: the grouped products
+    are in it, it returns tokens (8, 32, 2) with a count a row and each
+    row's newest token and length for the chunk behind it, and its
+    temporaries stay under what the cell has to spare beside 9.09 GB of
+    weights and 2.2 GB of state."""
+    import json
+    from pathlib import Path
+
+    from generativeaiexamples_tpu.engine.serving_models import HybridServing
+    from generativeaiexamples_tpu.models import hybrid
+    from generativeaiexamples_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "platform_of", lambda mesh: "tpu")
+    configs = Path(__file__).resolve().parents[1] / "benchmarks" / "configs"
+    model = json.loads((configs / "k-exaone-236b-a23b-l5e16.json").read_text())
+    engine = model["engine"]
+    max_len, b, steps = int(engine["max_len"]), int(engine["max_batch"]), int(engine["decode_chunk_size"])
+    cfg = hybrid.from_hf_config(model, max_len=max_len, kv_dtype=engine["kv_dtype"], draft=engine["draft"])
+    assert cfg.draft == "mtp" and cfg.qk_norm and cfg.rope_full.rope_type == "none"
+    serving = HybridServing(cfg, None, max_len)
+
+    def described(make):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            jax.eval_shape(make),
+        )
+
+    spec = _spec(one_chip)
+    ints, floats = spec((b,), jnp.int32), spec((b,), jnp.float32)
+    compiled = serving.make_decode_chunk().lower(
+        described(lambda: hybrid.init_params(cfg, jax.random.PRNGKey(0))),
+        described(lambda: hybrid.init_state(cfg, b, max_len)),
+        ints, ints, spec((2,), jnp.uint32), floats, floats, ints, steps, max_len,
+        spec((b,), jnp.bool_), spec((1, b), jnp.int32), spec((b,), jnp.bool_),
+        ints, spec((b,), jnp.bool_),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    _, toks, counts, (newest, lengths), aux = compiled.out_info
+    assert toks.shape == (steps, b, 2) and counts.shape == (steps, b)
+    assert newest.shape == (1, b) and lengths.shape == (b,)
+    assert aux.shape == (len(serving.counter_names),)
+    print("verify chunk temporaries", compiled.memory_analysis().temp_size_in_bytes)
+    assert compiled.memory_analysis().temp_size_in_bytes < SPARE_BY_FAMILY["exaone"]

@@ -248,8 +248,46 @@ def test_hybrid_phase_rehearsal_of_the_window_model_and_its_controls(control, ca
         assert line["decode_p10_share"] > line["limits"]["decode_p10_share"]
 
 
+EXAONE_TINY_LIMITS = {"p10": 1e-3, "p50": 1e-3, "p90": 1e-3, "accept_p50": 1e-3,
+                      "reject_p50": 1e-3, "module_p50": 1e-3}
+
+
+@pytest.mark.parametrize(
+    "control", ["", "no_qk_norm", "rope_on_full", "no_window", "stale_reject", "w8a8_mlp"])
+def test_hybrid_phase_rehearsal_of_the_drafting_model_and_its_controls(control, capsys, monkeypatch, tmp_path):
+    """``--hybrid --model exaone_moe`` at the tiny size, in process: a
+    prompt of 60 tokens under a window of 8 (seven and a half windows:
+    every ring wraps), chunks of 16, its last 8 positions through the
+    verify step on true and on wrong drafts, by the benchmark's own
+    comparison.  The limits are the configuration's, set for the chip's
+    size and precision; float32 at this size reads 1e-6, so the rehearsal
+    holds it to limits of its own, which the sound run is far inside and
+    each control leaves."""
+    config = json.loads(open(os.path.join(REPO, chip_smoke.EXAONE_CONFIG)).read())
+    config["reference"]["logit_share_limits"] = EXAONE_TINY_LIMITS
+    tiny = tmp_path / "config.json"
+    tiny.write_text(json.dumps(config))
+    monkeypatch.setattr(chip_smoke, "EXAONE_CONFIG", str(tiny))
+    # A sound run inside the limits and a control outside them both return.
+    chip_smoke.child_hybrid(0, chip_smoke.TINY, control=control, model="exaone_moe")
+    line = _phases(capsys)["hybrid"]
+    assert line["model"] == "exaone_moe-tiny" and line["control"] == (control or None)
+    assert line["positions"] == {"prefill": 52, "accept": 8, "reject": 7, "module": 12}
+    assert any(site.startswith("attn_window") for site in line["kernel_paths"])
+    assert any(site.startswith("mtp_attn_full") for site in line["kernel_paths"])
+    assert line["within_limits"] == (not control)
+    if control == "stale_reject":  # the reject branch alone sees it
+        assert line["reject_p50"] > 0.1 > line["accept_p50"]
+    elif control == "w8a8_mlp":  # the precision: every part moves, by little
+        assert 1e-3 < line["p10"] < 0.2
+    elif control:
+        assert line["p50"] > 0.05
+
+
 def test_hybrid_phase_names_a_child_for_every_model_and_control():
     assert sorted(n for n in chip_smoke.CHILDREN if n.startswith("hybrid")) == [
+        "hybrid_exaone_moe", "hybrid_exaone_moe_no_qk_norm", "hybrid_exaone_moe_no_window",
+        "hybrid_exaone_moe_rope_on_full", "hybrid_exaone_moe_stale_reject", "hybrid_exaone_moe_w8a8_mlp",
         "hybrid_ling", "hybrid_ling_w8a8_mlp", "hybrid_mellum", "hybrid_mellum_no_window",
         "hybrid_mellum_no_yarn", "hybrid_mellum_w8a8_mlp",
     ]

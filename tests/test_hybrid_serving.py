@@ -422,3 +422,122 @@ def test_how_many_chunks_share_a_program_follows_from_the_rows_an_expert_sees(co
     engine = model["engine"]
     serving = serving_model(cfg, None, int(engine["max_len"]))
     assert serving.chunks_per_program(int(engine["prefill_chunk_tokens"])) == chunks
+
+
+# -- the ``exaone_moe`` family: the model's own prediction module drafts every step ------
+
+
+@pytest.fixture(scope="module")
+def exaone():
+    # What engine.server.main() builds for --model exaone_moe-tiny: the draft on.
+    cfg = hybrid.PRESETS[resolve_model_preset("exaone_moe-tiny")]()
+    assert cfg.draft == "mtp"
+    s = Scheduler(
+        cfg, None, max_batch=4, max_len=256, decode_chunk_size=4, seed=5,
+        prefill_chunk_tokens=CHUNK, prefix_cache="shared",
+    )
+    s.start()
+    yield s
+    s.stop()
+
+
+def _exaone_gap(scheduler, prompt, out, pad_to=192):
+    """``_mellum_gap`` against ``exaone_moe_reference``'s stack."""
+    from generativeaiexamples_tpu.models import exaone_moe_reference
+
+    seq = list(prompt) + list(out)
+    x = exaone_moe_reference.hidden_states(
+        scheduler.params, scheduler.cfg, seq + [0] * (pad_to - len(seq)))
+    lg = np.asarray(exaone_moe_reference.head(scheduler.params, scheduler.cfg, x))
+    rows = lg[len(prompt) - 1 : len(seq) - 1]
+    return float((rows.max(-1) - rows[np.arange(len(out)), out]).max())
+
+
+def test_exaone_with_the_draft_on_cold_prompts_prefix_hits_and_a_full_house(exaone):
+    """A window of 8 under chunks of 32 (four windows a chunk), every
+    decode step a verify step: greedy tokens equal the reference's (to a
+    near-tie) for a cold batch, a chunked prompt, a prefix hit cut back
+    to a snapshot (rings and the module's ``h_last``), and a full house of
+    four rows decoding side by side; the ``spec_*`` stats count one draft
+    a row a step."""
+    cfg = exaone.cfg
+    snap0 = exaone.stats.snapshot()
+    assert exaone._snapshots.bytes_each == cfg.snapshot_bytes(256) == 6 * 2 * 8 * 2 * 16 * 4 + 64 * 4
+    assert snap0["state_bytes_draft"] == 4 * (2 * 256 * 2 * 16 * 4 + 64 * 4)
+    assert snap0["state_bytes_window"] == 4 * 6 * 2 * 8 * 2 * 16 * 4
+    assert not exaone._goes_ahead()  # an empty house: an arrival's prefill would lead the queue
+    cold = [_prompt(21, 20), _prompt(22, 31)]
+    for p, o in zip(cold, _generate(exaone, cold)):
+        assert len(o) == 6 and _exaone_gap(exaone, p, o) <= GAP
+    first = _prompt(23, 100)  # chunks of 32: snapshots at 32, 64, 96
+    before = exaone.stats.snapshot()
+    (out,) = _generate(exaone, [first])
+    mid = exaone.stats.snapshot()
+    assert mid["prefill_chunks"] - before["prefill_chunks"] == 4
+    assert mid["state_snapshots_saved"] - before["state_snapshots_saved"] == 3
+    assert _exaone_gap(exaone, first, out) <= GAP
+    again = first[:70] + _prompt(24, 25)  # rows match to 70, the state exists at 64
+    (hit,) = _generate(exaone, [again], n=9)
+    after = exaone.stats.snapshot()
+    assert after["shared_prefix_hits"] - mid["shared_prefix_hits"] == 1
+    assert after["state_snapshots_restored"] - mid["state_snapshots_restored"] == 1
+    assert after["prefix_tokens_reused"] - mid["prefix_tokens_reused"] == 64
+    assert len(hit) == 9 and _exaone_gap(exaone, again, hit) <= GAP
+    # A full house: four rows of unequal lengths decode together.
+    house = [_prompt(30 + i, n) for i, n in enumerate((40, 75, 12, 120))]
+    start = exaone.stats.snapshot()
+    for p, o in zip(house, _generate(exaone, house, n=10)):
+        assert len(o) == 10 and _exaone_gap(exaone, p, o) <= GAP
+    end = exaone.stats.snapshot()
+    # One draft a row a step; a row's first token comes from its prefill.
+    assert end["spec_proposed"] == end["spec_rounds"] > 0
+    tokens = end["spec_tokens"] - start["spec_tokens"]
+    assert tokens == 4 * 9 == (end["spec_rounds"] - start["spec_rounds"]) + (
+        end["spec_accepted"] - start["spec_accepted"])
+    # The device's own count: two positions a row a step, the module's
+    # rows as one more full layer.
+    assert end["verify_positions"] == 2 * end["draft_proposed"] > 0
+    assert end["draft_proposed"] >= end["spec_proposed"]  # a step past a row's end counts there
+    assert end["decode_tokens_emitted"] == end["draft_proposed"] + end["draft_accepted"]
+    assert end["draft_rows_rewritten"] == 2 * (end["draft_proposed"] - end["draft_accepted"])
+    # Every slot holds a request: a chunk goes out before the one in front is fetched.
+    assert end["decode_chunks_ahead"] > start["decode_chunks_ahead"]
+    read, dense = end["attn_rows_read_window_decode"], end["attn_rows_dense_window_decode"]
+    # A chunk of 4 steps: the 2 full layers and the module's read the bucket
+    # in every step, the module's once more to catch up; 6 window layers.
+    assert 0 < read < dense and end["attn_rows_read_full_decode"] * 6 * 4 == dense * (3 * 4 + 1)
+
+
+def test_exaone_metrics_export_the_drafts_counters(exaone):
+    from generativeaiexamples_tpu.engine.server import create_engine_app
+    from generativeaiexamples_tpu.engine.tokenizer import ByteTokenizer
+
+    (o,) = _generate(exaone, [_prompt(41, 50)], n=5)
+    assert len(o) == 5
+    app = create_engine_app(exaone, ByteTokenizer(), model_name="exaone_moe-tiny")
+    loop = asyncio.new_event_loop()
+    client = TestClient(TestServer(app), loop=loop)
+    loop.run_until_complete(client.start_server())
+
+    async def go():
+        return await (await client.get("/metrics")).text()
+
+    try:
+        metrics = loop.run_until_complete(go())
+    finally:
+        loop.run_until_complete(client.close())
+        loop.close()
+    for name in ("engine_draft_proposed_total", "engine_draft_accepted_total",
+                 "engine_verify_positions_total", "engine_decode_tokens_emitted_total",
+                 "engine_draft_rows_rewritten_total", "engine_state_bytes_draft",
+                 "engine_state_bytes_window", "engine_attn_rows_read_window_decode_total"):
+        assert f"\n{name} " in metrics, name
+    paths = runtime_kernel_paths()
+    assert any(site.startswith("mtp_attn_full") for site in paths)
+    assert any(site.startswith("attn_window") for site in paths)
+
+
+def runtime_kernel_paths() -> dict:
+    from generativeaiexamples_tpu.utils.jax_runtime import runtime_report
+
+    return runtime_report()["kernel_paths"]
