@@ -72,7 +72,7 @@ import jax
 import jax.numpy as jnp
 
 from generativeaiexamples_tpu.models.llama import rms_norm
-from generativeaiexamples_tpu.ops import gqa, kda, mla, moe
+from generativeaiexamples_tpu.ops import gqa, gqa_decode, kda, mla, moe
 from generativeaiexamples_tpu.ops.dispatch import record
 from generativeaiexamples_tpu.ops.rope import NO_ROPE, RopeSpec, apply_rope_spec, rope_spec
 
@@ -89,9 +89,11 @@ ROW_LEAVES = ("latent", "k", "v")
 RING_LEAVES = ("ring_k", "ring_v")
 GQA_LEAVES = {"full": ("k", "v"), "window": RING_LEAVES}  # a GQA mixer's K and V
 # Rows of K (and as many of V) the attention layers read from the slots'
-# state, by kind, and what the window layers would have read as full
-# layers; a model with such layers returns them after ``moe.COUNTERS``.
-ATTN_COUNTERS = ("read_window", "read_full", "dense_window")
+# state, by kind; what the window layers would have read as full layers,
+# and what the full layers read where every slot's window is read whole
+# (a decode step's row walk reads less: ``ops/gqa_decode.py``).  A model
+# with such layers returns them after ``moe.COUNTERS``.
+ATTN_COUNTERS = ("read_window", "read_full", "dense_window", "dense_full")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -780,14 +782,15 @@ def _mla_mixer(h, lp, st, pos, valid, n_valid, cfg: HybridConfig, window: int, a
 
 def _gqa_mixer(
     h, lp, st, mixer, pos, valid, n_valid, cfg: HybridConfig, window: int, apart: bool,
-    site: str = "",
+    site: str = "", mesh=None,
 ):
     """A ``full`` or ``window`` layer.  Returns (output, state, counters
     in the order of ``ATTN_COUNTERS``): a full layer writes its rows and
-    attends over the first ``window`` of them; a window layer attends
-    over its ring as it was and over its own new rows, then writes.
-    ``site`` prefixes the layer's ``kernel_paths`` entry (a prediction
-    module's block)."""
+    attends over the first ``window`` of them (a decode step walks the
+    rows each slot holds, where ``gqa_decode.use_row_walk`` admits it);
+    a window layer attends over its ring as it was and over its own new
+    rows, then writes.  ``site`` prefixes the layer's ``kernel_paths``
+    entry (a prediction module's block)."""
     b, s, _ = h.shape
     H, KH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.attn_head_dim
     scope = f"layer/attn_{mixer}"
@@ -805,7 +808,14 @@ def _gqa_mixer(
     names = GQA_LEAVES[mixer]
     old_k, old_v = (st[n] for n in names)
     rows = old_k.shape[1]
-    record(f"{site}attn_{mixer} b={b} s={s} t={window if mixer == 'full' else rows}", False)
+    span = min(window, rows)  # of a full layer's rows, those a call may see
+    walk = record(
+        f"{site}attn_{mixer} b={b} s={s} t={window if mixer == 'full' else rows}",
+        mixer == "full" and gqa_decode.use_row_walk(
+            s=s, q_dtype=q.dtype, rows_dtype=old_k.dtype, width=KH * hd, head_dim=hd, rows=rows,
+            window=span, batch=b, n_q=H, mesh=mesh, apart=apart,
+        ),
+    )
 
     def write(at):
         # A token that does not count is written nowhere (``at`` == rows).
@@ -818,22 +828,30 @@ def _gqa_mixer(
 
     if mixer == "full":
         new_k, new_v = write(jnp.where(valid, pos, rows))
+        read_full = b * span
         with jax.named_scope(f"{scope}/attend"):
-            o = _attend(
-                functools.partial(gqa.attend_rows, n_kv=KH), n_valid, apart,
-                q, new_k[:, :window], new_v[:, :window], pos,
-            )
-        read = (0, b * min(window, rows), 0)
+            if walk:
+                lengths = gqa_decode.walk_lengths(pos, n_valid, span)
+                o = gqa_decode.attend_rows_walk(
+                    q, new_k, new_v, pos, lengths, n_kv=KH, window=span
+                )
+                read_full = gqa_decode.rows_walked(lengths, rows, span)
+            else:
+                o = _attend(
+                    functools.partial(gqa.attend_rows, n_kv=KH), n_valid, apart,
+                    q, new_k[:, :window], new_v[:, :window], pos,
+                )
+        read = (0, read_full, 0, b * span)
     else:
         with jax.named_scope(f"{scope}/attend"):
             o = gqa.attend_ring(
                 q, k, v, old_k, old_v, pos, n_kv=KH, window=cfg.sliding_window
             )
         new_k, new_v = write(gqa.ring_slots(pos, valid, n_valid, rows))
-        read = (b * rows, 0, b * window)
+        read = (b * rows, 0, b * window, 0)
     with jax.named_scope(f"{scope}/wo"):
         out = jnp.dot(o.reshape(b, s, H * hd).astype(h.dtype), lp["w_o"])
-    return out, dict(zip(names, (new_k, new_v))), jnp.array(read, jnp.int32)
+    return out, dict(zip(names, (new_k, new_v))), jnp.stack(read).astype(jnp.int32)
 
 
 def _swiglu(h, w_gu, w_down):
@@ -864,7 +882,7 @@ def _expert_layer(h, lp, valid, cfg: HybridConfig, mesh):
 
 def _mix(
     x, lp, st, mixer, pos, valid, n_valid, cfg: HybridConfig, window: int,
-    apart: bool = False, site: str = "",
+    apart: bool = False, site: str = "", mesh=None,
 ):
     """The mixer's half of a layer: (x + mixer(norm(x)), new state, a GQA
     layer's ``ATTN_COUNTERS`` or 0)."""
@@ -876,7 +894,7 @@ def _mix(
         y, st = _mla_mixer(h, lp, st, pos, valid, n_valid, cfg, window, apart)
     else:
         y, st, read = _gqa_mixer(
-            h, lp, st, mixer, pos, valid, n_valid, cfg, window, apart, site
+            h, lp, st, mixer, pos, valid, n_valid, cfg, window, apart, site, mesh
         )
     return x + y, st, read
 
@@ -921,7 +939,7 @@ def forward(
     for (mixer, mlp), lp, st in zip(cfg.layer_kinds, params["layers"], state):
         x, st, r = _mix(
             x, lp, st, mixer, pos, valid, n_valid.astype(jnp.int32), cfg, window,
-            rows_apart,
+            rows_apart, mesh=mesh,
         )
         x, c = _mlp(x, lp, mlp, valid, cfg, mesh)
         counters = counters + c
@@ -984,7 +1002,7 @@ def mtp_forward(
     with jax.named_scope("mtp/block"):
         x, rows, read = _mix(
             u, lp, rows, MTP_KIND[0], pos, valid, n_valid, cfg, window, rows_apart,
-            site="mtp_",
+            site="mtp_", mesh=mesh,
         )
         x, counters = _mlp(x, lp, MTP_KIND[1], valid, cfg, mesh)
     return x, rows, jnp.concatenate([counters, read])

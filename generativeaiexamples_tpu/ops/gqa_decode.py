@@ -1,0 +1,290 @@
+"""Pallas TPU decode attention over a full GQA layer's state rows
+(``models/hybrid.py``'s ``full`` mixer): a walk over the rows a slot
+holds, where :func:`ops.gqa.attend_rows` reads the rows it could hold.
+
+A decode step (one token a row, or a token and its draft) attends over
+``(b, T, KH * D)`` rows of which a slot of length ``n`` holds ``n``.
+XLA's form reads every slot's first ``window`` rows and masks: at 32
+slots x 8,192 that is 2 x 537 MB a layer a step for rows that hold a
+third of it, with float32 scores written and read back (PERF.md, PR 34).
+The kernel leaves K and V in HBM and walks each row's own
+``ceil(length / block)`` blocks with double-buffered copies, none for a
+row of length 0, keeping a float32 online softmax; a KV head is a
+lane-aligned slice of the block in VMEM, so nothing is copied out of the
+rows and the queries are not widened.
+
+Layout contract: ``k_rows``, ``v_rows`` ``(b, T, KH * D)``, row ``t`` the
+keys of position ``t``, **written before they are read** (the mixer
+writes the step's rows, then attends: no append buffer).  Key ``t`` is
+visible to query ``i`` iff ``t <= q_pos[b, i]`` and ``t < window``; a row
+whose length is 0 reads nothing and yields exact zeros.  ``window`` is a
+static upper bound of the walk, not what is read.
+
+:func:`ops.decode_attention.decode_gqa_attention` is the model (block
+choice, the copy that runs ahead from row to row, the VMEM budget, the
+interpret hook); this one has no int8 scales, no append buffer and no
+layer index.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from generativeaiexamples_tpu.ops.decode_attention import _ROW_GROUP, _block_t, _interpret_mode
+from generativeaiexamples_tpu.ops.dispatch import one_device, platform_of
+from generativeaiexamples_tpu.ops.gqa import _NEG, _STEP_QUERIES
+from generativeaiexamples_tpu.ops.qmm import _VMEM_BUDGET_BYTES
+
+F32 = jnp.float32
+_STAT_LANES = 128  # lanes of a running maximum or sum (one lane tile)
+
+
+def _row_group(b: int) -> int:
+    """Rows whose queries and outputs ride in one VMEM block: the llama
+    kernel's 16 where they divide the batch."""
+    return next(r for r in (_ROW_GROUP, 8, 4, 2, 1) if b % r == 0)
+
+
+def walk_lengths(q_pos, n_valid, window: int):
+    """(b,) int32 rows each slot's walk covers: up to its last query's
+    position, at most ``window``; 0 for a row with nothing that counts."""
+    n = jnp.minimum(q_pos[:, -1].astype(jnp.int32) + 1, window)
+    return jnp.where(n_valid > 0, n, 0)
+
+
+def rows_walked(lengths, rows: int, window: int):
+    """Rows of K (and as many of V) one call copies for walks of these
+    lengths: each in whole blocks."""
+    bt = _block_t(rows, window)
+    return jnp.sum((lengths + bt - 1) // bt * bt).astype(jnp.int32)
+
+
+def _walk_vmem_bytes(block_t: int, width: int, group: int, heads: int, d: int) -> int:
+    """VMEM the kernel holds over bf16 rows: the ping-pong K and V blocks,
+    the group's double-buffered queries and outputs, the online-softmax
+    scratch."""
+    return (
+        2 * 2 * block_t * width * 2
+        + 2 * 2 * group * heads * d * 2
+        + heads * (2 * _STAT_LANES + d) * 4
+    )
+
+
+def use_row_walk(
+    *, s: int, q_dtype, rows_dtype, width: int, head_dim: int, rows: int, window: int,
+    batch: int, n_q: int, mesh=None, apart: bool = False,
+) -> bool:
+    """The gate, from what a traced step can observe: a decode step
+    (``s <= _STEP_QUERIES`` queries a row, every row in one call) of bf16
+    queries over bf16 rows of whole lane tiles and whole blocks, on one
+    TPU device.  Everything else is :func:`ops.gqa.attend_rows`'."""
+    if s > _STEP_QUERIES or apart:
+        return False
+    if not jnp.dtype(q_dtype) == jnp.dtype(rows_dtype) == jnp.bfloat16:
+        return False
+    if not _interpret_mode() and (platform_of(mesh) != "tpu" or not one_device(mesh)):
+        return False
+    bt = _block_t(rows, window)
+    return (
+        width % 128 == 0
+        and head_dim % 128 == 0
+        and rows % bt == 0
+        and bt % 16 == 0  # whole bf16 sublane tiles
+        and _walk_vmem_bytes(bt, width, _row_group(batch), s * n_q, head_dim)
+        <= _VMEM_BUDGET_BYTES
+    )
+
+
+def _walk_kernel(
+    len_ref,  # scalar prefetch: (B,) int32 rows each slot's walk covers
+    pos_ref,  # scalar prefetch: (B * s,) int32 the queries' positions
+    q_ref,  # (rows, KH, s * G, D): the program's group of rows
+    k_hbm,  # (B, T, KH * D): stays in HBM (pl.ANY)
+    v_hbm,  # (B, T, KH * D): stays in HBM
+    o_ref,  # (rows, KH, s * G, D)
+    kbuf,  # (2, block_t, KH * D) VMEM
+    vbuf,
+    sem,  # DMA (2 slots, K and V)
+    state,  # SMEM (2,)
+    m_ref,  # (KH, s * G, 128) float32
+    l_ref,
+    acc_ref,  # (KH, s * G, D) float32
+    *,
+    block_t: int,
+    s: int,
+    scale: float,
+):
+    """A group of rows, each walked over its own blocks across all its KV
+    heads.  Block ``i + 1`` is fetched while block ``i`` computes, and
+    during a row's last block the first block of the next row that has
+    any (of this group or a later one): the grid runs in order on one
+    core, so the buffer slot and the "my first block is on its way" flag
+    ride from row to row and group to group in SMEM, and only the call's
+    first copy is exposed."""
+    rows, kh, sg, d = q_ref.shape
+    g = sg // s
+    bt = block_t
+    first_row = pl.program_id(0) * rows
+    n_rows = pl.num_programs(0) * rows
+
+    def n_blocks(row):
+        return (len_ref[row] + bt - 1) // bt
+
+    def block_dma(slot, row, i):
+        start = pl.multiple_of(i * bt, bt)
+        return tuple(
+            pltpu.make_async_copy(
+                hbm.at[row, pl.ds(start, bt)], buf.at[slot], sem.at[slot, j]
+            )
+            for j, (hbm, buf) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf)))
+        )
+
+    # state[0]: buffer slot of the next block to compute; state[1]: 1 if
+    # an earlier row already started the next walked row's first copy.
+    @pl.when(first_row == 0)
+    def _reset():
+        state[0] = 0
+        state[1] = 0
+
+    def walk_row(r, _):
+        b = first_row + r
+        length = len_ref[b]
+        n = n_blocks(b)
+        slot0 = state[0]
+
+        @pl.when((n > 0) & (state[1] == 0))
+        def _first():
+            for cp in block_dma(slot0, b, 0):
+                cp.start()
+
+        m_ref[:] = jnp.full_like(m_ref, _NEG)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        # The last key each of a head's s * G query rows may see: query
+        # ``i`` of the step owns rows [i * G, (i + 1) * G).
+        query = jax.lax.broadcasted_iota(jnp.int32, (sg, bt), 0) // g
+        last = jnp.full((sg, bt), pos_ref[b * s], jnp.int32)
+        for i in range(1, s):
+            last = jnp.where(query == i, pos_ref[b * s + i], last)
+        last = jnp.minimum(last, length - 1)
+
+        def body(i, _):
+            slot = (slot0 + i) % 2
+
+            @pl.when(i + 1 < n)
+            def _prefetch():
+                for cp in block_dma(1 - slot, b, i + 1):
+                    cp.start()
+
+            @pl.when(i + 1 == n)
+            def _prefetch_next_row():
+                nxt = jax.lax.while_loop(
+                    lambda j: (j < n_rows) & (n_blocks(jnp.minimum(j, n_rows - 1)) == 0),
+                    lambda j: j + 1,
+                    b + 1,
+                )
+
+                @pl.when(nxt < n_rows)
+                def _start():
+                    for cp in block_dma(1 - slot, nxt, 0):
+                        cp.start()
+
+                state[0] = 1 - slot
+                state[1] = (nxt < n_rows).astype(jnp.int32)
+
+            for cp in block_dma(slot, b, i):
+                cp.wait()
+            t_idx = jax.lax.broadcasted_iota(jnp.int32, (sg, bt), 1) + i * bt
+            mask = t_idx <= last
+            for h in range(kh):
+                lanes = pl.ds(h * d, d)
+                k = kbuf[slot, :, lanes]  # (bt, D)
+                v = vbuf[slot, :, lanes]
+                sc = jax.lax.dot_general(
+                    q_ref[r, h], k, (((1,), (1,)), ((), ())), preferred_element_type=F32
+                ) * scale
+                sc = jnp.where(mask, sc, _NEG)
+                m_prev, l_prev = m_ref[h, :, :1], l_ref[h, :, :1]
+                m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
+                alpha = jnp.exp(m_prev - m_new)
+                # The multiplicative mask keeps a query that sees nothing
+                # of this block (or of any) at exact zeros.
+                p = jnp.exp(sc - m_new) * mask
+                l_new = l_prev * alpha + jnp.sum(p, axis=-1, keepdims=True)
+                pv = jax.lax.dot_general(
+                    p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=F32
+                )
+                acc_ref[h] = acc_ref[h] * alpha + pv
+                m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+                l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+            return 0
+
+        jax.lax.fori_loop(0, n, body, 0)
+        o_ref[r] = (acc_ref[:] / jnp.maximum(l_ref[:, :, :1], 1e-30)).astype(o_ref.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, rows, walk_row, 0)
+
+
+def attend_rows_walk(q, k_rows, v_rows, q_pos, lengths, *, n_kv: int, window: int, interpret=None):
+    """:func:`ops.gqa.attend_rows` over the first ``window`` rows, for a
+    decode step: q (b, s, H, D) rotated, ``s <= 2``; k_rows, v_rows
+    (b, T, KH * D) whole (not cut to the window); q_pos (b, s);
+    ``lengths`` (b,) from :func:`walk_lengths`.  Returns (b, s, H, D) in
+    q's dtype."""
+    if interpret is None:
+        interpret = _interpret_mode()
+    bt = _block_t(k_rows.shape[1], window)
+    return _walk(q, k_rows, v_rows, q_pos, lengths, n_kv=n_kv, block_t=bt, interpret=interpret)
+
+
+# A function of its own under ``jit``: the window enters through the
+# block alone, so the layers of a step and the decode chunks of every
+# window share one trace of the kernel, and a program lowers it once a
+# shape (tracing it anew for each cost K-EXAONE's cell 13 s of set-up).
+@functools.partial(jax.jit, static_argnames=("n_kv", "block_t", "interpret"))
+def _walk(q, k_rows, v_rows, q_pos, lengths, *, n_kv: int, block_t: int, interpret: bool):
+    b, s, h, d = q.shape
+    g = h // n_kv
+    width = k_rows.shape[2]
+    rows = _row_group(b)
+    # A KV head's queries side by side: (b, KH, s * G, D).
+    qh = q.reshape(b, s, n_kv, g, d).transpose(0, 2, 1, 3, 4).reshape(b, n_kv, s * g, d)
+    group = pl.BlockSpec((rows, n_kv, s * g, d), lambda gi, lens, pos: (gi, 0, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(_walk_kernel, block_t=block_t, s=s, scale=d**-0.5),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b // rows,),
+            in_specs=[
+                group,
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=group,
+            scratch_shapes=[
+                pltpu.VMEM((2, block_t, width), k_rows.dtype),
+                pltpu.VMEM((2, block_t, width), v_rows.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((2,), jnp.int32),
+                pltpu.VMEM((n_kv, s * g, _STAT_LANES), F32),
+                pltpu.VMEM((n_kv, s * g, _STAT_LANES), F32),
+                pltpu.VMEM((n_kv, s * g, d), F32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            # In order on one core: the buffer slot and the next row's
+            # first copy ride from one program to the next.
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=_VMEM_BUDGET_BYTES,
+        ),
+        interpret=interpret,
+        name="gqa_rows_decode_attention",
+    )(lengths.astype(jnp.int32), q_pos.astype(jnp.int32).reshape(b * s), qh, k_rows, v_rows)
+    return out.reshape(b, n_kv, s, g, d).transpose(0, 2, 1, 3, 4).reshape(b, s, h, d)

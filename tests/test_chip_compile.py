@@ -13,6 +13,7 @@ module fixture — never while a module is imported.
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -108,6 +109,40 @@ def test_decode_kernel_compiles(one_chip, batch, window, cache_len, chunk):
         S((batch, NQ, HD), jnp.bfloat16), cache, cache, scales, scales,
         S((), jnp.int32), S((batch,), jnp.int32),
         ab, ab, ab_scales, ab_scales, S((), jnp.int32),
+    )
+
+
+# The layer-kind models' full GQA layers (``ops/gqa_decode.py``): 32 slots
+# of 8,192 rows; K-EXAONE's rows of 8 KV heads under a step that verifies
+# a draft, Mellum's of 4 under a plain one, at the narrowest and the
+# widest decode window (blocks of 512 either way).
+@pytest.mark.parametrize("window", [512, 8192])
+@pytest.mark.parametrize("s,n_q,n_kv", [(2, 64, 8), (1, 32, 4)], ids=["k-exaone", "mellum"])
+def test_row_walk_kernel_compiles_at_both_cells_widths(one_chip, s, n_q, n_kv, window, monkeypatch):
+    from generativeaiexamples_tpu.ops import gqa_decode
+
+    S = _spec(one_chip)
+    batch, rows = 32, 8192
+    row = S((batch, rows, n_kv * HD), jnp.bfloat16)
+    monkeypatch.setattr(gqa_decode, "platform_of", lambda mesh: "tpu")
+    assert gqa_decode.use_row_walk(
+        s=s, q_dtype=jnp.bfloat16, rows_dtype=row.dtype, width=n_kv * HD, head_dim=HD,
+        rows=rows, window=window, batch=batch, n_q=n_q,
+    )
+    block = da._block_t(rows, window)
+    held = gqa_decode._walk_vmem_bytes(block, n_kv * HD, 16, s * n_q, HD)
+    assert block == 512 and held <= qmm._VMEM_BUDGET_BYTES // 4
+
+    def attn(q, k, v, pos, lens):
+        return gqa_decode.attend_rows_walk(
+            q, k, v, pos, lens, n_kv=n_kv, window=window, interpret=False
+        )
+
+    # The scoped limit handed to Mosaic is the shared budget: a kernel
+    # that held more would be refused here.
+    _compile(
+        attn, S((batch, s, n_q, HD), jnp.bfloat16), row, row,
+        S((batch, s), jnp.int32), S((batch,), jnp.int32),
     )
 
 
@@ -310,8 +345,9 @@ def test_the_verify_chunk_compiles_at_the_published_widths(one_chip, monkeypatch
     catch-up, then 8 steps of the stack over [token, draft], acceptance,
     the module over the accepted positions) for k-exaone-236b-a23b-l5e16's
     32 slots of 8,192 at the widest decode window: the grouped products
-    are in it, it returns tokens (8, 32, 2) with a count a row and each
-    row's newest token and length for the chunk behind it, and its
+    and the full layers' row walk are in it, it returns tokens (8, 32, 2)
+    with a count a row and each row's newest token and length for the
+    chunk behind it, and its
     temporaries stay under what the cell has to spare beside 9.09 GB of
     weights and 2.2 GB of state."""
     import json
@@ -319,9 +355,10 @@ def test_the_verify_chunk_compiles_at_the_published_widths(one_chip, monkeypatch
 
     from generativeaiexamples_tpu.engine.serving_models import HybridServing
     from generativeaiexamples_tpu.models import hybrid
-    from generativeaiexamples_tpu.ops import moe
+    from generativeaiexamples_tpu.ops import gqa_decode, moe
 
     monkeypatch.setattr(moe, "platform_of", lambda mesh: "tpu")
+    monkeypatch.setattr(gqa_decode, "platform_of", lambda mesh: "tpu")
     configs = Path(__file__).resolve().parents[1] / "benchmarks" / "configs"
     model = json.loads((configs / "k-exaone-236b-a23b-l5e16.json").read_text())
     engine = model["engine"]
@@ -345,7 +382,13 @@ def test_the_verify_chunk_compiles_at_the_published_widths(one_chip, monkeypatch
         spec((b,), jnp.bool_), spec((1, b), jnp.int32), spec((b,), jnp.bool_),
         ints, spec((b,), jnp.bool_),
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # The full layers' attention is the row walk (the stack's and the
+    # module's, with one query a row in the catch-up), and the scatter that
+    # writes a step's rows feeds it in place: no K or V leaf is copied.
+    assert text.count("gqa_rows_decode_attention") >= 3
+    assert not re.search(r"= bf16\[32,8192,1024\]\S* copy\(", text)
     _, toks, counts, (newest, lengths), aux = compiled.out_info
     assert toks.shape == (steps, b, 2) and counts.shape == (steps, b)
     assert newest.shape == (1, b) and lengths.shape == (b,)

@@ -203,7 +203,7 @@ def test_a_whole_sequence_and_the_modules_logits_match_the_reference(model, para
     np.testing.assert_allclose(state[L]["k"][0, : N - 1], rows["k"][0, : N - 1], atol=1e-5)
     np.testing.assert_allclose(state[L + 1]["h_last"][0], hidden[0, -1], atol=0)
     # Rows read: 6 window layers x their ring, (2 full layers + the module) x N.
-    assert counters.tolist()[4:10] == [0, 0, 0, 6 * W, 3 * N, 6 * N]
+    assert counters.tolist()[4:12] == [0, 0, 0, 0, 6 * W, 3 * N, 6 * N, 3 * N]
 
 
 # -- (b) chunks, then the verify step -------------------------------------------------
@@ -492,7 +492,7 @@ def test_what_is_not_served_is_refused_with_the_reason(model):
     with pytest.raises(ValueError, match="KDA state.*rolled back"):
         serving_model(kda, None, T).check_supported()
     assert model.counter_names[-5:] == HybridServing.DRAFT_COUNTERS
-    assert serving_model(PLAIN, None, T).counter_names[-1] == "attn_rows_dense_window_prefill"
+    assert serving_model(PLAIN, None, T).counter_names[-1] == "attn_rows_dense_full_prefill"
 
 
 # -- (c) through the scheduler: rows that end inside a step ------------------------------------------

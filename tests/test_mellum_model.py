@@ -96,8 +96,9 @@ def test_a_whole_sequence_of_five_windows_matches_the_reference(params, tokens, 
         got, _, counters = _forward(params, tokens[:1, :n], [0], [n], hybrid.init_state(CFG, 1, T), T)
         np.testing.assert_allclose(got[0], want[0][:n], atol=ATOL)
     # Rows of K read from the state: 6 window layers x their ring, 2 full
-    # layers x the window asked for; and the window layers as full ones.
-    assert counters.tolist()[len(hybrid.moe.COUNTERS):] == [6 * W, 2 * T, 6 * T]
+    # layers x the window asked for; the window layers as full ones; and
+    # the full layers with every window read whole (as XLA's path does).
+    assert counters.tolist()[len(hybrid.moe.COUNTERS):] == [6 * W, 2 * T, 6 * T, 2 * T]
 
 
 def test_chunks_that_do_not_divide_the_window_then_two_windows_of_decode(params, tokens, want):
@@ -260,7 +261,9 @@ def test_what_is_not_served_is_refused_with_the_reason():
     # No selection bias to balance: the parameters come back as they were.
     p = model.prepare_params(None, quantize=False, matmul_kernel="xla", seed=0)
     assert hybrid.balance_router_biases(p, CFG, jax.random.PRNGKey(1)) is p
-    assert model.counter_names[-6:] == (
-        "attn_rows_read_window_decode", "attn_rows_read_full_decode", "attn_rows_dense_window_decode",
-        "attn_rows_read_window_prefill", "attn_rows_read_full_prefill", "attn_rows_dense_window_prefill",
+    assert model.counter_names[-8:] == (
+        "attn_rows_read_window_decode", "attn_rows_read_full_decode",
+        "attn_rows_dense_window_decode", "attn_rows_dense_full_decode",
+        "attn_rows_read_window_prefill", "attn_rows_read_full_prefill",
+        "attn_rows_dense_window_prefill", "attn_rows_dense_full_prefill",
     )
