@@ -1164,6 +1164,13 @@ class EnginePool:
         "prefill_tokens_padded",
         "decode_kv_tokens_read",
         "decode_kv_tokens_dense",
+        # Executables the replicas' tick threads asked JAX for.
+        "executables_requested",
+        "executables_hit",
+        "executables_missed",
+        "executable_trace_s",
+        "executable_lower_s",
+        "executable_backend_s",
         # Paged-KV pool gauges/counters sum across replicas: each
         # replica owns a disjoint page pool, so pool-wide capacity and
         # pressure are the sums (all zero under the contiguous layout).

@@ -68,6 +68,7 @@ from generativeaiexamples_tpu.resilience.faults import (
     inject_replica,
 )
 from generativeaiexamples_tpu.utils.buckets import bucket_size
+from generativeaiexamples_tpu.utils.jax_runtime import EXECUTABLES
 
 logger = get_logger(__name__)
 
@@ -185,7 +186,7 @@ TICK_RECORD_FIELDS = (
     + tuple(f"{p}_s" for p in TICK_PHASES)
     + ("starved_s", "prefill_chunks", "admitted", "decode_lanes",
        "kv_bucket", "tokens", "queued", "decode_ahead",
-       "prefill_chunk_programs")
+       "prefill_chunk_programs", "executables")
 )
 
 
@@ -224,6 +225,9 @@ class _TickClock:
         self._stats = stats
         self._span: Optional[jax.profiler.TraceAnnotation] = None
         self._seq = 0  # dispatch sites returned from so far
+        # What the running phase was entered with: an executable that JAX
+        # makes inside it is recorded with them (``Scheduler._asked``).
+        self.facts: dict = {}
 
     def _lap(self) -> None:
         """Book the time since the last lap to the running phase (under
@@ -243,6 +247,7 @@ class _TickClock:
         dispatch's program and shapes) ride on the trace annotation."""
         self._lap()
         self._stats.tick_phase = phase
+        self.facts = facts
         self.end_span()
         self._span = jax.profiler.TraceAnnotation(_SPAN_NAMES[phase], **facts)
         self._span.__enter__()
@@ -459,11 +464,20 @@ class Stats:
         # raw wall time of a tick that emitted 3x the tokens would read
         # "3x slower" when the engine is actually 3x FASTER per token.
         # Non-speculative ticks emit at most the baseline, so there
-        # norm == raw and nothing changes.  tick_tokens_ewma is the
-        # companion emitted-tokens-per-tick average (tokens/sec ==
-        # tick_tokens_ewma / tick_ms_ewma to first order).
+        # norm == raw and nothing changes.
         self.tick_ms_norm_ewma = 0.0
-        self.tick_tokens_ewma = 0.0
+        # Executables this scheduler's tick thread asked JAX for (a step
+        # program's first call at a new shape; ``utils.jax_runtime``'s
+        # record has each by name): all of them, those the persistent
+        # cache had and those it was asked for in vain (the rest ran with
+        # no cache), and their seconds by stage.  After warm-up every one
+        # is a request that waited for a compile.
+        self.executables_requested = 0
+        self.executables_hit = 0
+        self.executables_missed = 0
+        self.executable_trace_s = 0.0
+        self.executable_lower_s = 0.0
+        self.executable_backend_s = 0.0
         # Paged KV pool gauges (zero when kv_layout="contiguous"): total
         # pool pages, current free-list depth, pages held by parked
         # radix segments, and pages shared by more than one owner
@@ -546,7 +560,12 @@ class Stats:
                 "spec_fallbacks": self.spec_fallbacks,
                 "tick_ms_ewma": round(self.tick_ms_ewma, 3),
                 "tick_ms_norm_ewma": round(self.tick_ms_norm_ewma, 3),
-                "tick_tokens_ewma": round(self.tick_tokens_ewma, 3),
+                "executables_requested": self.executables_requested,
+                "executables_hit": self.executables_hit,
+                "executables_missed": self.executables_missed,
+                "executable_trace_s": self.executable_trace_s,
+                "executable_lower_s": self.executable_lower_s,
+                "executable_backend_s": self.executable_backend_s,
                 "kv_pages_total": self.kv_pages_total,
                 "kv_pages_free": self.kv_pages_free,
                 "kv_pages_parked": self.kv_pages_parked,
@@ -568,9 +587,23 @@ class Stats:
             }
 
 
+def _as_build(init):
+    """``Scheduler.__init__`` as a build of the executable record: what it
+    compiles reads ``asked_by`` ``build``, and its seconds go to
+    ``runtime_report()["setup"]`` whether it returns or raises."""
+
+    @functools.wraps(init)
+    def timed(self, *args, **kwargs) -> None:
+        with EXECUTABLES.building():
+            init(self, *args, **kwargs)
+
+    return timed
+
+
 class Scheduler:
     """Continuous batching over a fixed-slot KV cache."""
 
+    @_as_build
     def __init__(
         self,
         cfg: llama.LlamaConfig,
@@ -600,6 +633,10 @@ class Scheduler:
         kv_pool_pages: Optional[int] = None,
         kv_page_low_water: Optional[int] = None,
     ) -> None:
+        # Set-up in three stages (``setup/params``, ``setup/state``,
+        # ``setup/programs``: spans, and gauges of ``runtime_report()``):
+        # the parameters made, quantized and placed.
+        EXECUTABLES.enter_stage("params")
         self.cfg = cfg
         self.mesh = mesh
         # Pool index when owned by an EnginePool (tags the `replica`
@@ -680,6 +717,8 @@ class Scheduler:
             )
             else "xla"
         )
+        # The slots' state, a draft's beside it, and the host's books.
+        EXECUTABLES.enter_stage("state")
         # Paged KV cache (opt-in): the target cache becomes a page pool
         # (``engine.paged_kv``) — fixed-size int8 pages, per-slot page
         # tables, refcounted free list.  Grafts turn into host table
@@ -949,6 +988,7 @@ class Scheduler:
         self._tick_admitted = 0
         self._tick_kv_bucket = 0
         self._tick_ahead = 0
+        self._tick_executables = 0
         self._tick_busy = False
         self._tick_no = 0
         self._ticks: "collections.deque[tuple]" = collections.deque(
@@ -967,6 +1007,9 @@ class Scheduler:
         self._tsdb_feed_interval_s = 0.25
         self._last_tsdb_feed = 0.0
         self._tsdb_prev: dict = {}
+        # The step programs: closures that compile at their first call,
+        # and the family compiled here (``_compile_chunk_programs``).
+        EXECUTABLES.enter_stage("programs")
         mesh_arg = mesh
         max_len = self.max_len
 
@@ -1250,8 +1293,10 @@ class Scheduler:
         """The newest ``limit`` busy ticks, oldest first: tick number,
         start (perf_counter and wall clock), seconds in each phase,
         starved seconds, warming chunks dispatched, requests claimed,
-        decode lanes and their attention window, tokens emitted and the
-        queue depth at the tick's end.  What a stall is read from."""
+        decode lanes and their attention window, tokens emitted, the
+        queue depth at the tick's end and, last, the executables the tick
+        asked JAX for (``utils.jax_runtime.EXECUTABLES`` has each by
+        name).  What a stall is read from."""
         if limit <= 0:
             return []
         return [
@@ -2499,18 +2544,45 @@ class Scheduler:
         )
         clock = self._clock
         clock.start("plan")
-        while self._running:
-            self._tick_no += 1
-            # One step on the tick thread's line of the profiler's host
-            # plane; the phase annotations nest inside it.
-            with jax.profiler.StepTraceAnnotation(
-                "tick", step_num=self._tick_no
-            ):
-                clock.enter("plan")
-                self._run_tick()
-                clock.end_span()
+        with EXECUTABLES.asking("tick", self._asked, self._note_executable):
+            while self._running:
+                self._tick_no += 1
+                # One step on the tick thread's line of the profiler's
+                # host plane; the phase annotations nest inside it.
+                with jax.profiler.StepTraceAnnotation(
+                    "tick", step_num=self._tick_no
+                ):
+                    clock.enter("plan")
+                    self._run_tick()
+                    clock.end_span()
         clock.stop()
         logger.info("scheduler stopped")
+
+    def _asked(self) -> dict:
+        """What the executable record notes of one this thread asked for:
+        the tick, its phase, the facts of the running dispatch and, in a
+        pool, the replica."""
+        asked = {
+            "tick": self._tick_no,
+            "phase": self.stats.tick_phase,
+            **self._clock.facts,
+        }
+        if self.replica_index is not None:
+            asked["replica"] = self.replica_index
+        return asked
+
+    def _note_executable(self, entry: dict) -> None:
+        """The tick thread asked JAX for an executable (``entry`` of
+        ``utils.jax_runtime.EXECUTABLES``): count it, and mark the tick."""
+        self._tick_executables += 1
+        stats = self.stats
+        with stats.lock:
+            stats.executables_requested += 1
+            stats.executables_hit += entry["cache"] == "hit"
+            stats.executables_missed += entry["cache"] == "miss"
+            stats.executable_trace_s += entry["trace_s"]
+            stats.executable_lower_s += entry["lower_s"]
+            stats.executable_backend_s += entry["backend_s"]
 
     def _run_tick(self) -> None:
         """One pass of the tick loop: the tick, recovery if it raised,
@@ -2600,6 +2672,7 @@ class Scheduler:
                     self._tick_decoded, self._tick_kv_bucket,
                     self._tick_tokens, self.stats.queued,
                     self._tick_ahead, self._tick_chunk_programs,
+                    self._tick_executables,
                 )
             )
 
@@ -2651,9 +2724,6 @@ class Scheduler:
                 norm_ms = dt_ms * baseline / emitted
             stats.tick_ms_norm_ewma += 0.1 * (
                 norm_ms - stats.tick_ms_norm_ewma
-            )
-            stats.tick_tokens_ewma += 0.1 * (
-                emitted - stats.tick_tokens_ewma
             )
             db = get_tsdb()
             db.record("engine.tick_ms", norm_ms)
@@ -2744,6 +2814,7 @@ class Scheduler:
         self._tick_admitted = 0
         self._tick_kv_bucket = 0
         self._tick_ahead = 0
+        self._tick_executables = 0
         self._tick_busy = False
         if self._pool is not None:
             self._kv_pages_reserved = 0

@@ -785,6 +785,25 @@ async def handle_metrics(request: web.Request) -> web.Response:
         f"{snap.get(f'device_starved_{p}_s', 0.0):.6f}"
         for p in STARVED_PHASES
     ]
+    # Executables the tick thread asked JAX for, by what the persistent
+    # cache said, and their seconds by stage: after warm-up each is a
+    # request that waited for a compile (GET /debug/executables names it).
+    requested = snap.get("executables_requested", 0)
+    hit = snap.get("executables_hit", 0)
+    missed = snap.get("executables_missed", 0)
+    lines.append("# TYPE engine_executables_total counter")
+    lines += [
+        f'engine_executables_total{{cache="{cache}"}} {n}'
+        for cache, n in (
+            ("hit", hit), ("miss", missed), ("off", requested - hit - missed),
+        )
+    ]
+    lines.append("# TYPE engine_executable_seconds_total counter")
+    lines += [
+        f'engine_executable_seconds_total{{stage="{stage}"}} '
+        f"{snap.get(f'executable_{stage}_s', 0.0):.6f}"
+        for stage in ("trace", "lower", "backend")
+    ]
     for name, key, fmt in (
         ("engine_busy_ticks_total", "busy_ticks", "d"),
         ("engine_queue_wait_seconds_total", "queue_wait_s_sum", ".6f"),
@@ -1029,14 +1048,22 @@ async def handle_admin_scale(request: web.Request) -> web.Response:
     return web.json_response(result)
 
 
+def _query_limit(request: web.Request) -> Optional[int]:
+    """``?limit=N`` of a debug endpoint (100 without one); None where it
+    is no integer."""
+    try:
+        return int(request.query.get("limit", "100"))
+    except ValueError:
+        return None
+
+
 async def handle_debug_ticks(request: web.Request) -> web.Response:
     """``GET /debug/ticks?limit=N``: the newest N busy ticks of the
     scheduler (``Scheduler.tick_records``), oldest first; for a replica
     pool, of each replica.  What a stall is read from: which phase the
     tick thread was in, and for how long."""
-    try:
-        limit = int(request.query.get("limit", "100"))
-    except ValueError:
+    limit = _query_limit(request)
+    if limit is None:
         return web.json_response(
             {"detail": "limit must be an integer"}, status=422
         )
@@ -1055,6 +1082,44 @@ async def handle_debug_ticks(request: web.Request) -> web.Response:
         )
     ticks = engine.tick_records(limit)
     return web.json_response({"ticks": ticks, "count": len(ticks)})
+
+
+async def handle_debug_executables(request: web.Request) -> web.Response:
+    """``GET /debug/executables?limit=N``: the newest N executables this
+    process asked JAX for (``utils.jax_runtime.EXECUTABLES``), oldest
+    first: which program, found in the cache or built, what each stage
+    cost, who asked and, from a tick thread, in which tick and with what
+    shapes.  The record is the process's; for a replica pool each
+    replica gets the entries its own tick thread asked for."""
+    from generativeaiexamples_tpu.utils.jax_runtime import EXECUTABLES
+
+    limit = _query_limit(request)
+    if limit is None:
+        return web.json_response(
+            {"detail": "limit must be an integer"}, status=422
+        )
+    engine = request.app[SCHED_KEY]
+    record = EXECUTABLES.report()
+    if hasattr(engine, "replicas"):
+        entries = EXECUTABLES.newest()
+
+        def asked_by(idx: int) -> list:
+            mine = [e for e in entries if e.get("replica") == idx]
+            return mine[-limit:] if limit > 0 else []
+
+        return web.json_response(
+            {
+                **record,
+                "replicas": [
+                    {"replica": rep.idx, "entries": asked_by(rep.idx)}
+                    for rep in engine.replicas
+                ],
+            }
+        )
+    entries = EXECUTABLES.newest(limit)
+    return web.json_response(
+        {**record, "entries": entries, "count": len(entries)}
+    )
 
 
 def create_engine_app(
@@ -1094,6 +1159,7 @@ def create_engine_app(
     app.router.add_post("/admin/scale", handle_admin_scale)
     app.router.add_get("/debug/requests", handle_debug_requests)
     app.router.add_get("/debug/ticks", handle_debug_ticks)
+    app.router.add_get("/debug/executables", handle_debug_executables)
     app.router.add_get("/debug/timeseries", handle_debug_timeseries)
     if enable_profiler:
         app.router.add_post("/debug/profiler/start", handle_profiler_start)

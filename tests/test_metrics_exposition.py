@@ -287,6 +287,25 @@ def test_engine_matmul_kernel_gauge_tracks_fused_path():
         assert exp.value("engine_matmul_kernel", kernel="xla") == 0
 
 
+@pytest.mark.parametrize(
+    "family, label, values",
+    [
+        ("engine_executables_total", "cache", ("hit", "miss", "off")),
+        ("engine_executable_seconds_total", "stage", ("trace", "lower", "backend")),
+    ],
+)
+def test_engine_server_executable_families_export_from_zero(
+    family, label, values
+):
+    """What the tick thread asked JAX for (``docs/observability.md``,
+    "Set-up and executables"): both families are there, typed, with
+    every label at zero, on an engine whose snapshot predates the keys."""
+    exp = parse_exposition(_scrape_engine_metrics())
+    assert exp.types[family] == "counter"
+    for value in values:
+        assert exp.value(family, **{label: value}) == 0
+
+
 def test_engine_server_metrics_fleet_families_export_from_zero(
     monkeypatch, tmp_path
 ):
