@@ -33,9 +33,12 @@ stack agreed with the draft; then what the chunk behind it may read
 without the host, each row's newest token ``(1, b)`` and its length
 ``(b,)`` (``carried_len`` / ``carry_len`` beside ``carried`` / ``carry``).
 
-``LlamaServing`` is ``models/llama.py`` exactly as the scheduler used to
-call it, so the programs of every llama-shaped configuration compile as
-they did; ``HybridServing`` serves ``models/hybrid.py``'s layer kinds.
+``LlamaServing`` is ``models/llama.py`` as the scheduler used to call it,
+so the programs of every dense llama-shaped configuration compile as they
+did; a llama-shaped model with experts (Mixtral) has ``prefill_rows``
+besides, whose experts dispatch sorted by expert where every other program
+of the model keeps ``llama._moe_mlp``'s one-hot dispatch.  ``HybridServing``
+serves ``models/hybrid.py``'s layer kinds.
 """
 
 from __future__ import annotations
@@ -49,6 +52,7 @@ from generativeaiexamples_tpu.engine.decode import carry_tokens
 from generativeaiexamples_tpu.engine.sampler import sample
 from generativeaiexamples_tpu.models import hybrid, llama
 from generativeaiexamples_tpu.ops import moe
+from generativeaiexamples_tpu.ops.dispatch import one_device
 
 
 def _last_counted(x, n):
@@ -56,6 +60,22 @@ def _last_counted(x, n):
     (position 0 of a row with none, whose caller keeps what it had)."""
     at = jnp.maximum(n - 1, 0).reshape((-1,) + (1,) * (x.ndim - 1))
     return jnp.take_along_axis(x, at, axis=1)[:, 0]
+
+
+# The most chunks one program takes, whatever the rule below allows.
+MAX_CHUNKS_PER_PROGRAM = 8
+
+
+def chunks_sharing_experts(chunk_tokens: int, n_experts: int, k: int) -> int:
+    """How many slots' chunks may go through a model with experts as one
+    program: as many as keep the rows one expert's matrices see within the
+    grouped product's row tile (``moe.ROW_TILE``), below which a chunk
+    pays for the whole expert stream whatever its rows.  A chunk brings an
+    expert ``chunk_tokens x k / n_experts`` rows (a share held here sees
+    its share of the choices): 64 at Mixtral's 2 of 8, 32 at the 64
+    experts of Mellum's cut, 4 at Ling's 512."""
+    rows = -(-chunk_tokens * k // n_experts)
+    return max(1, min(MAX_CHUNKS_PER_PROGRAM, moe.ROW_TILE // rows))
 
 
 def serving_model(cfg, mesh, max_len: int):
@@ -78,10 +98,18 @@ class LlamaServing:
         """Every option of the scheduler serves this model."""
 
     def chunks_per_program(self, chunk_tokens: int) -> int:
-        """Every weight matrix multiplies every token of a chunk (a dense
-        projection; the one-hot expert dispatch of ``llama._moe_mlp``), so
-        one chunk is all the rows its weight pass has use for."""
-        return 1
+        """A dense model's every weight matrix multiplies every token of a
+        chunk, so one chunk is all the rows its weight pass has use for: 1.
+        A model with experts sends its chunks through ``prefill_rows``,
+        whose sorted dispatch gives an expert only the rows that chose it
+        (``chunks_sharing_experts``: 2 at Mixtral's widths).  Experts
+        spread over a mesh keep the one-hot dispatch, which shards."""
+        cfg = self.cfg
+        if cfg.n_experts <= 1 or not one_device(self.mesh):
+            return 1
+        return chunks_sharing_experts(
+            chunk_tokens, cfg.n_experts, cfg.n_experts_per_tok
+        )
 
     def prepare_params(self, params, *, quantize, matmul_kernel, seed):
         from generativeaiexamples_tpu.engine.decode import prepare_params
@@ -146,6 +174,54 @@ class LlamaServing:
                 for bg, r in zip(cache, row)
             )
         return cache, hidden, None
+
+    def prefill_rows(self, params, cache, tokens, start, suffix_len, slots, window):
+        """``prefill_row`` for the chunks of several slots at once (the
+        contract of ``HybridServing.prefill_rows``): tokens (B, s) of slots
+        ``slots`` (B,) from positions ``start`` (B,), of which the first
+        ``suffix_len`` (B,) count, over one static ``window`` of rows.
+        The experts dispatch sorted (``llama._moe_mlp_sorted``), so each
+        expert's matrices pass once for the rows of all B chunks that
+        chose it; a row gets what it gets alone.  A pad row (``suffix_len``
+        0) routes to no expert and writes to no slot, whatever ``slots``
+        says of it.  Returns (cache, hidden (B, s, D), None)."""
+        B, s = tokens.shape
+        steps = jnp.arange(s, dtype=jnp.int32)[None, :]
+
+        def at(bg, r):
+            return (0, 0, slots[r]) + (0,) * (bg.ndim - 3)
+
+        def row_shape(bg):
+            return bg.shape[:2] + (1, window) + bg.shape[4:]
+
+        # A slice a row: a gather by ``slots`` reads the whole leaf.
+        rows = tuple(
+            jnp.concatenate(
+                [jax.lax.dynamic_slice(bg, at(bg, r), row_shape(bg)) for r in range(B)],
+                axis=2,
+            )
+            for bg in cache
+        )
+        hidden, rows = llama.forward(
+            params,
+            self.cfg,
+            tokens,
+            start[:, None] + steps,
+            rows,
+            start + suffix_len,
+            mesh=self.mesh,
+            chunk_valid=steps < suffix_len[:, None],
+        )
+        with jax.named_scope("kv_write"):
+            out = []
+            for bg, new in zip(cache, rows):
+                for r in range(B):
+                    # A pad row writes back what its slot holds.
+                    held = jax.lax.dynamic_slice(bg, at(bg, r), row_shape(bg))
+                    row = jnp.where(suffix_len[r] > 0, new[:, :, r : r + 1], held)
+                    bg = jax.lax.dynamic_update_slice(bg, row, at(bg, r))
+                out.append(bg)
+        return tuple(out), hidden, None
 
     def graft_prefix(self, cache, src, dst, n: int):
         """Leaf-generic over the head-major cache tuple like
@@ -384,23 +460,16 @@ class HybridServing:
             )
         return cache, hidden, self._aux(counters, decode=False)
 
-    # The most chunks one program takes, whatever the rule below allows.
-    MAX_CHUNKS_PER_PROGRAM = 8
-
     def chunks_per_program(self, chunk_tokens: int) -> int:
-        """How many slots' chunks may go through the model as one program:
-        as many as keep the rows one expert's matrices see within the
-        grouped product's row tile (``moe.ROW_TILE``), below which a chunk
-        pays for the whole expert stream whatever its rows.  A chunk
-        brings an expert ``chunk_tokens x n_experts_per_tok / n_experts``
-        rows (a share held here sees its share of the choices): 32 at the
-        64 experts of Mellum's cut, 4 at Ling's 512.  A model with no
-        expert layer is a dense one: 1."""
+        """``chunks_sharing_experts`` at the model's widths: 4 for Mellum's
+        cut, 8 (the cap) for Ling's.  A model with no expert layer is a
+        dense one: 1."""
         cfg = self.cfg
         if not any(mlp == "experts" for _, mlp in cfg.layer_kinds):
             return 1
-        rows = -(-chunk_tokens * cfg.n_experts_per_tok // cfg.n_experts)
-        return max(1, min(self.MAX_CHUNKS_PER_PROGRAM, moe.ROW_TILE // rows))
+        return chunks_sharing_experts(
+            chunk_tokens, cfg.n_experts, cfg.n_experts_per_tok
+        )
 
     def prefill_rows(self, params, cache, tokens, start, suffix_len, slots, window):
         """``prefill_row`` for the chunks of several slots at once: tokens

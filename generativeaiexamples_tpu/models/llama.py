@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from generativeaiexamples_tpu.ops import moe
 from generativeaiexamples_tpu.ops.attention import attention
 from generativeaiexamples_tpu.ops.dispatch import record
 from generativeaiexamples_tpu.ops.quant import q_dot
@@ -706,6 +707,47 @@ def _moe_mlp(
     return out[:, :s_orig], aux_loss
 
 
+# The expert leaves of a layer's parameters, stacked (L, E, ...) like the rest.
+EXPERT_LEAVES = ("w_gate_e", "w_up_e", "w_down_e")
+
+
+def _moe_mlp_sorted(
+    h: jnp.ndarray, lp: Mapping, experts: Mapping, li, valid: jnp.ndarray,
+    cfg: LlamaConfig, mesh,
+) -> jnp.ndarray:
+    """The expert MLP of a serving prefill chunk: ``_moe_mlp``'s router,
+    then the choices ordered by expert and the three products over the
+    rows each expert received (``ops.moe.stacked_expert_mlp``: grouped
+    products, dropless by construction), so a chunk multiplies its ``k``
+    choices a token and not every expert's capacity, and the rows of
+    several chunks share one pass over the experts.
+
+    ``experts`` holds ``EXPERT_LEAVES`` of ALL layers viewed as (L*E, ...):
+    the layer loop hands a grouped product the whole stack and the product
+    reads layer ``li``'s E groups, because a slice of the stack handed to
+    a kernel would be copied first, 2.8 GB a layer at Mixtral's widths.
+    ``valid`` (b, s): a position that does not count routes nowhere.
+    h: (b, s, d)."""
+    b, s, d = h.shape
+    E, k = cfg.n_experts, cfg.n_experts_per_tok
+    with jax.named_scope("layer/moe/router"):
+        router_logits = q_dot(h, lp["router"], "router").astype(jnp.float32)
+        probs = jax.nn.softmax(router_logits, axis=-1)
+        gate_w, gate_idx = jax.lax.top_k(probs, k)  # (b, s, k)
+        gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
+    out = moe.stacked_expert_mlp(
+        h.reshape(b * s, d),
+        gate_idx.astype(jnp.int32).reshape(b * s, k),
+        gate_w.reshape(b * s, k),
+        valid.reshape(b * s),
+        experts,
+        first=li * E,
+        n_experts=E,
+        mesh=mesh,
+    )
+    return out.reshape(b, s, d)
+
+
 def dense_layer(
     x: jnp.ndarray,
     lp: Mapping,
@@ -810,6 +852,7 @@ def forward(
     page_table: Optional[jnp.ndarray] = None,
     page_tokens: int = 0,
     pages_len: int = 0,
+    chunk_valid: Optional[jnp.ndarray] = None,
 ):
     """Run the transformer body.
 
@@ -868,6 +911,12 @@ def forward(
     int8 KV and is incompatible with ``cold_prefill`` (cold prefill
     stages into a small contiguous cache; the scheduler grafts rows
     into pool pages).
+
+    ``chunk_valid`` (b, s) bool — the prefill chunks of several slots as
+    one program (``LlamaServing.prefill_rows``): which positions count.
+    Given, a model with experts dispatches them sorted by expert
+    (:func:`_moe_mlp_sorted`) and not one-hot, and a position that does
+    not count routes to no expert; a dense model takes no notice.
     """
     b, s = tokens.shape
     with jax.named_scope("embed"):
@@ -986,6 +1035,15 @@ def forward(
                 page_table[:, _w_idx // _pt] * _pt + _w_idx % _pt
             )  # (b, window)
 
+    layers, experts = params["layers"], None
+    if chunk_valid is not None and "router" in layers:
+        # The expert stacks stay out of the scan's per-layer slices: the
+        # loop closes over them whole (see _moe_mlp_sorted).
+        experts = {
+            n: layers[n].reshape((-1,) + layers[n].shape[2:]) for n in EXPERT_LEAVES
+        }
+        layers = {n: w for n, w in layers.items() if n not in experts}
+
     def layer(carry, lp):
         # Serving: the full stacked (L, KH, b, t, ...) cache rides in the
         # scan CARRY and is updated in place by scatter.  Carrying it (vs
@@ -1041,6 +1099,21 @@ def forward(
             )[0]
             perm = (1, 2, 0) + tuple(range(3, sl.ndim))
             return jnp.transpose(sl, perm)
+
+        def attend_rows(q, k, v, **scales):
+            """``attention`` over cache rows; the chunks of several slots
+            (``chunk_valid``) attend one after the other, as each does
+            alone: XLA's program for two rows' float32 scores at once
+            takes five times what two programs of one row take (PERF.md
+            section 6, PR 37)."""
+            if chunk_valid is None or b == 1:
+                return attention(q, k, v, positions, kv_lengths, mesh=mesh, **scales)
+
+            def one(row):
+                q, k, v, pos, n, scales = jax.tree.map(lambda x: x[None], row)
+                return attention(q, k, v, pos, n, mesh=mesh, **scales)[0]
+
+            return jax.lax.map(one, (q, k, v, positions, kv_lengths, scales))
 
         def write_cold(buf, fresh, r0):
             """Contiguous rows [r0, r0+b) x slots [0, s) of layer li."""
@@ -1222,13 +1295,10 @@ def forward(
                     # required default layout, costing 5 GB of entry copies
                     # (measured).  The kernel path is the append-buffer
                     # protocol above, where the big cache is read-only.
-                    attn = attention(
+                    attn = attend_rows(
                         q,
                         slice_layer(kv[0]),
                         slice_layer(kv[1]),
-                        positions,
-                        kv_lengths,
-                        mesh=mesh,
                         k_scale=slice_layer(kv[2]),
                         v_scale=slice_layer(kv[3]),
                     )
@@ -1250,10 +1320,7 @@ def forward(
                         write_at(kv[0], k, bidx, positions),
                         write_at(kv[1], v, bidx, positions),
                     )
-                    attn = attention(
-                        q, slice_layer(kv[0]), slice_layer(kv[1]),
-                        positions, kv_lengths, mesh=mesh,
-                    )
+                    attn = attend_rows(q, slice_layer(kv[0]), slice_layer(kv[1]))
             else:
                 attn = attention(q, k, v, positions, kv_lengths, mesh=mesh)
         with jax.named_scope("layer/wo"):
@@ -1265,8 +1332,12 @@ def forward(
         with jax.named_scope("layer/norm"):
             h = block_norm(carry_x, cfg, lp, "mlp_norm")
         if "router" in lp:
-            # _moe_mlp scopes itself: layer/moe/router, layer/moe/experts.
-            mlp_out, layer_aux = _moe_mlp(h, lp, cfg, mesh)
+            # Both scope themselves: layer/moe/router, layer/moe/experts.
+            if experts is not None:
+                mlp_out = _moe_mlp_sorted(h, lp, experts, li, chunk_valid, cfg, mesh)
+                layer_aux = 0.0
+            else:
+                mlp_out, layer_aux = _moe_mlp(h, lp, cfg, mesh)
             with jax.named_scope("layer/moe/experts"):
                 carry_x = _shard_activations(carry_x + mlp_out, mesh)
             return (carry_x, kv, ab, li + 1, aux + layer_aux), None
@@ -1309,7 +1380,7 @@ def forward(
     (x, cache_out, ab_out, _, aux_total), _ = jax.lax.scan(
         layer_fn,
         (x, cache, ab_in, jnp.int32(0), jnp.float32(0.0)),
-        params["layers"],
+        layers,
     )
 
     with jax.named_scope("final_norm"):

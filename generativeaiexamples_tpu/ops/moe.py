@@ -17,6 +17,11 @@ matrix multiplications over the experts that received a row —
 skips experts with no row, so a decode step streams the experts it
 touches and not all that are held.  Off the TPU (tier-1 tests, the
 rehearsal) a dense product over the experts held stands in.
+
+``stacked_expert_mlp`` is the same dispatch for a few wide experts that
+lie in a stack of several layers' (Mixtral's 8 of 4,096 x 14,336 under
+``models/llama.py``'s scan): each expert's rows start at a row tile of
+their own.
 """
 
 from __future__ import annotations
@@ -133,6 +138,11 @@ def _grouped(xs, w, group_sizes, pallas: bool, tiling):
     return jnp.einsum("emb,me->mb", every, pick).astype(xs.dtype)
 
 
+def _tile_of(x: int, options) -> int:
+    """The first of ``options`` that divides ``x``, else 128."""
+    return next((t for t in options if x % t == 0), 128)
+
+
 def _tiling(a: int, b: int) -> tuple[int, int, int]:
     """gmm tiles for an (m, a) x (a, b) product: whole rows of 128, the
     widest column tiles that divide the sizes and fit VMEM.  A width that
@@ -142,13 +152,10 @@ def _tiling(a: int, b: int) -> tuple[int, int, int]:
     the time (the v5e: 1.8 ms for a product of 64 experts of 2,304 x
     1,792 in 4,977 steps of (256, 256), against 0.65 ms to stream them;
     PERF.md section 6, PR 31)."""
-    def pick(x, options):
-        return next((t for t in options if x % t == 0), 128)
-
     return (
         ROW_TILE,
-        pick(a, (1152, 896, 512, 256, 128)),
-        pick(b, (896, 768, 512, 256, 128)),
+        _tile_of(a, (1152, 896, 512, 256, 128)),
+        _tile_of(b, (896, 768, 512, 256, 128)),
     )
 
 
@@ -191,3 +198,86 @@ def expert_mlp(x, idx, weights, valid, lp, *, offset: int, held: int, mesh=None)
         [valid.sum().astype(jnp.int32) * k, n_local, (sizes > 0).sum(), sizes.max()]
     ).astype(jnp.int32)
     return y.astype(x.dtype), counters
+
+
+def _row_tile(choices: int, n_experts: int) -> int:
+    """Rows of ``gmm``'s row tile where each expert's rows start at a tile
+    of their own: twice what an expert receives on average, in whole
+    tiles of 128 and at most 256.  Nearly every expert then fits one tile,
+    so its matrices stream once, and a tile of 256 rows multiplies for
+    about as long as its weights stream on the v5e; 512 rows multiply for
+    twice as long (PERF.md section 6, PR 37: the three products of a layer
+    of Mixtral's take 4.5 / 5.0 / 9.4 ms at 128 / 256 / 512 for one chunk,
+    7.6 / 5.1 / 9.6 for two)."""
+    return min(2, max(1, -(-2 * choices // (n_experts * ROW_TILE)))) * ROW_TILE
+
+
+def _wide_tiling(a: int, b: int, tm: int) -> tuple[int, int, int]:
+    """gmm tiles for a few experts of thousands of columns: a grid step's
+    weight tile is 3.7 MB (two of them, the row tile and the accumulator
+    are 12 MB of VMEM at 256 rows), not ``_tiling``'s 0.9 MB, so the
+    steps' fixed cost is a smaller share of the stream: Mixtral's gate
+    product 1.99 -> 1.59 ms, its down product 2.07 -> 1.67 (PERF.md
+    section 6, PR 37)."""
+    return (
+        tm,
+        _tile_of(a, (1792, 1024, 512, 256, 128)),
+        _tile_of(b, (1792, 1024, 896, 512, 256, 128)),
+    )
+
+
+def stacked_expert_mlp(x, idx, weights, valid, stack, *, first, n_experts: int, mesh=None):
+    """``sum_i w_i E_i(x)`` over a token's ``k`` choices, for experts that
+    are groups ``first .. first + n_experts`` of a stack of G: the expert
+    leaves of several layers viewed as (G, ...), so that the layer loop
+    hands the grouped products the stack whole and no slice of it is
+    copied (every group outside the layer's own is empty, and an empty
+    group is skipped).  Dropless by construction.
+
+    The choices are ordered by expert and each expert's rows start at a
+    row tile of their own (``_row_tile``): a tile that two experts share
+    is a visit, and a stream of its weight tiles, for each of them.
+
+    x: (n, D); idx, weights: (n, k), idx in [0, n_experts); valid: (n,)
+    bool (a padded position routes nowhere); stack: ``w_gate_e``,
+    ``w_up_e`` (G, D, F), ``w_down_e`` (G, F, D); first: int32 scalar,
+    traced.  Returns y (n, D)."""
+    n, d = x.shape
+    k = idx.shape[1]
+    G, f = stack["w_down_e"].shape[:2]
+    pallas = record(f"moe_experts n={n} held={G}", use_gmm(mesh))
+    tm = _row_tile(n * k, n_experts)
+    # Rows enough for any routing: each expert pads its last tile.
+    m = (n * k + n_experts * (tm - 1)) // tm * tm
+    with jax.named_scope("layer/moe/dispatch"):
+        expert = jnp.where(valid[:, None], idx, n_experts).reshape(-1)  # (n k,)
+        order = jnp.argsort(expert, stable=True)  # valid choices first, by expert
+        by_expert = expert[order]
+        starts = jnp.searchsorted(
+            by_expert, jnp.arange(n_experts + 1, dtype=expert.dtype), side="left"
+        ).astype(jnp.int32)
+        sizes = -(-(starts[1:] - starts[:-1]) // tm) * tm  # whole tiles
+        tile_starts = jnp.cumsum(sizes) - sizes
+        e = jnp.minimum(by_expert, n_experts - 1)
+        # Where each sorted choice lies; a choice that does not count: past the end.
+        row = jnp.where(
+            by_expert < n_experts,
+            tile_starts[e] + jnp.arange(n * k, dtype=jnp.int32) - starts[e],
+            m,
+        )
+        xs = x[jnp.zeros((m,), jnp.int32).at[row].set(order // k, mode="drop")]
+        group_sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((G,), jnp.int32), sizes, (first,)
+        )
+    with jax.named_scope("layer/moe/experts"):
+        gate = _grouped(xs, stack["w_gate_e"], group_sizes, pallas, _wide_tiling(d, f, tm))
+        up = _grouped(xs, stack["w_up_e"], group_sizes, pallas, _wide_tiling(d, f, tm))
+        act = (jax.nn.silu(gate.astype(F32)) * up.astype(F32)).astype(x.dtype)
+        ys = _grouped(act, stack["w_down_e"], group_sizes, pallas, _wide_tiling(f, d, tm))
+    with jax.named_scope("layer/moe/combine"):
+        # Back to (token, choice) order by a gather.  A row past the tiles
+        # that hold a choice was never computed: zero, not what the buffer held.
+        at = jnp.zeros((n * k,), jnp.int32).at[order].set(jnp.minimum(row, m - 1))
+        per_choice = ys[at].reshape(n, k, d).astype(F32)
+        y = jnp.where(valid[:, None, None], per_choice * weights[..., None], 0.0).sum(1)
+    return y.astype(x.dtype)

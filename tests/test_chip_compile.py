@@ -339,6 +339,70 @@ def test_the_chunks_of_several_slots_compile_as_one_program(one_chip, family, mo
     assert compiled.memory_analysis().temp_size_in_bytes < spare
 
 
+@pytest.mark.parametrize("rows", [1, 2])
+def test_mixtrals_chunk_program_holds_no_copy_of_an_expert_leaf(one_chip, rows, monkeypatch):
+    """``_prefill_suffix_rows`` of ``LlamaServing`` at mixtral-8x7b-l4's
+    published widths (4 layers of 8 experts 4,096 x 14,336 in bf16, int8
+    dense projections and K/V, 32 slots of 2,048), the two programs its
+    family holds (1 and 2 rows x window 2,048): the sorted dispatch's
+    grouped products lower through Mosaic, three a layer; and the layer
+    loop hands them the expert stacks whole (a bitcast of the parameter,
+    (4, 8, ...) viewed as (32, ...)): no copy, slice or fusion result has
+    an expert leaf's size or a layer's share of it, 2.8 GB that a chunk
+    of ~17 ms cannot pay for."""
+    import json
+    import sys
+    from pathlib import Path
+
+    from generativeaiexamples_tpu.engine.scheduler import make_prefill_suffix_rows
+    from generativeaiexamples_tpu.engine.serving_models import LlamaServing
+    from generativeaiexamples_tpu.models import llama
+    from generativeaiexamples_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "platform_of", lambda mesh: "tpu")
+    configs = Path(__file__).resolve().parents[1] / "benchmarks" / "configs"
+    model = json.loads((configs / "mixtral-8x7b-l4.json").read_text())
+    engine = model["engine"]
+    max_len, chunk = int(engine["max_len"]), int(engine["prefill_chunk_tokens"])
+    cfg = llama.LlamaConfig(
+        vocab_size=model["vocab_size"], d_model=model["hidden_size"],
+        n_layers=model["num_hidden_layers"], n_heads=model["num_attention_heads"],
+        n_kv_heads=model["num_key_value_heads"], head_dim=model["head_dim"],
+        d_ff=model["intermediate_size"], rope_theta=model["rope_theta"],
+        norm_eps=model["rms_norm_eps"], max_seq_len=max_len, dtype="bfloat16",
+        kv_dtype=engine["kv_dtype"], n_experts=model["num_local_experts"],
+        n_experts_per_tok=model["num_experts_per_tok"], moe_dropless=True,
+    )
+    serving = LlamaServing(cfg, None, max_len)
+    assert serving.chunks_per_program(chunk) == 2
+
+    def described(make):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            jax.eval_shape(make),
+        )
+
+    spec = _spec(one_chip)
+    ints, floats = spec((rows,), jnp.int32), spec((rows,), jnp.float32)
+    compiled = make_prefill_suffix_rows(serving).lower(
+        described(lambda: serving.prepare_params(
+            None, quantize=True, matmul_kernel=engine["matmul_kernel"], seed=0)),
+        described(lambda: serving.init_state(int(engine["max_batch"]), max_len)),
+        spec((rows, chunk), jnp.int32), ints, ints, ints,
+        spec((2,), jnp.uint32), (floats, floats, ints), max_len,
+    ).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"", text)) == 3
+    # Whatever yields an expert-sized buffer is the parameter itself, its
+    # way into the layer loop, or a view of it.
+    L, E, D, F = cfg.n_layers, cfg.n_experts, cfg.d_model, cfg.d_ff
+    sized = re.findall(
+        rf"= bf16\[(?:{L},{E}|{L * E}|{E}|1,{E}),(?:{D},{F}|{F},{D})\]\S* ([\w-]+)\(", text
+    )
+    assert sized and set(sized) <= {"parameter", "get-tuple-element", "bitcast"}, sized
+    assert compiled.memory_analysis().temp_size_in_bytes < 600_000_000
+
+
 def test_the_verify_chunk_compiles_at_the_published_widths(one_chip, monkeypatch):
     """The decode chunk of a model that drafts its own step
     (``HybridServing._make_verify_chunk``: the prediction module's

@@ -395,13 +395,13 @@ def test_rows_of_a_group_get_what_each_gets_alone(rows_case, n_rows):
 
 @pytest.mark.parametrize("config, chunks", [
     ("mellum2-12b-a2.5b-l12", 4), ("ling-3.0-flash-vl-l7e128", 8),
-    ("mistral-7b", 1), ("mixtral-8x7b-l4", 1),
+    ("mistral-7b", 1), ("mixtral-8x7b-l4", 2),
 ])
 def test_how_many_chunks_share_a_program_follows_from_the_rows_an_expert_sees(config, chunks):
     """256 tokens x 8 choices over 64 experts are 32 rows an expert: 4
     chunks fill ``gmm``'s row tile of 128; over 512 experts they are 4
-    rows: the cap of 8; a dense projection and Mixtral's one-hot
-    dispatch see every token: a chunk goes alone."""
+    rows: the cap of 8; Mixtral's 2 choices over 8 experts are 64 rows:
+    2 chunks; a dense projection sees every token: a chunk goes alone."""
     import importlib
     from pathlib import Path
 
