@@ -3,7 +3,7 @@
 
     python chip_smoke.py [--seed N]          # one chip (what the driver runs)
     python chip_smoke.py --four-chips        # the two multi-chip paths only
-    python chip_smoke.py --hybrid [--model ling|mellum|exaone_moe] [--control NAME]   # phase 5 only
+    python chip_smoke.py --hybrid [--model ling|mellum|exaone_moe|mistral4] [--control NAME]   # phase 5 only
 
 Drives the system through the entry points a user calls, at llama3-8b's
 published widths with random int8 weights from ``--seed``:
@@ -46,7 +46,17 @@ published widths with random int8 weights from ``--seed``:
    benchmark's own comparison (``benchmarks/arch/exaone_moe.py``) against
    the configuration's limits; controls ``no_qk_norm``, ``rope_on_full``,
    ``no_window``, ``stale_reject`` (a rejected draft's position counted as
-   written) and ``w8a8_mlp``.
+   written) and ``w8a8_mlp``.  ``--model mistral4``:
+   mistral-small-4-119b-l6e32, latent attention in every layer: a prompt
+   of 8,448 tokens (past ``original_max_position_embeddings``, so both
+   values of the query's position scale and both regimes of YaRN's blend
+   are read) in chunks of 256 through the chunk program the scheduler
+   runs (``prefill_rows``, the slots' 32,768 rows in place), its last 16
+   positions through the absorbed decode step, by the benchmark's own
+   comparison (``benchmarks/arch/mistral4.py``) against the
+   configuration's limits; controls ``no_attn_scale`` (``a(p)`` = 1),
+   ``plain_rope`` (YaRN off), ``no_mscale`` (``m`` = 1), ``no_q_norm``
+   (the query's latent not normed) and ``w8a8_mlp``.
 
 The parent imports no JAX: the chip belongs to one process at a time, so
 each phase is a child (or the pair engine + chain server) that has
@@ -120,6 +130,13 @@ class Sizes:
     exaone_prompt: int = 800
     exaone_chunk: int = 256
     exaone_verify: int = 32
+    # ``--model mistral4``: a prompt past the original context (8,192) in
+    # chunks of ``mistral4_chunk``, its last ``mistral4_decode`` positions
+    # through the decode step.
+    mistral4_model: str = "mistral-small-4-119b-l6e32"
+    mistral4_prompt: int = 8448
+    mistral4_chunk: int = 256
+    mistral4_decode: int = 16
     kv_heads: int = 8  # the preset's own; the tiny rehearsal needs 4 to split
     start_timeout_s: float = 600.0
     request_timeout_s: float = 600.0
@@ -151,6 +168,10 @@ TINY = Sizes(
     exaone_prompt=60,
     exaone_chunk=16,
     exaone_verify=8,
+    mistral4_model="mistral4-tiny",
+    mistral4_prompt=75,
+    mistral4_chunk=16,
+    mistral4_decode=8,
     kv_heads=4,
     start_timeout_s=240.0,
     request_timeout_s=240.0,
@@ -1275,12 +1296,16 @@ HYBRID_CONTROLS = {
     "ling": ("w8a8_mlp",),
     "mellum": ("w8a8_mlp", "no_window", "no_yarn"),
     "exaone_moe": ("w8a8_mlp", "no_qk_norm", "rope_on_full", "no_window", "stale_reject"),
+    "mistral4": ("w8a8_mlp", "no_attn_scale", "plain_rope", "no_mscale", "no_q_norm"),
 }
 # ``--model exaone_moe`` is held to the limits of its benchmark
 # configuration (``reference.logit_share_limits``; PERF.md section 6,
 # PR 33, has the readings they lie between), by the comparison that
 # decides its cell's ``correct`` (``benchmarks/arch/exaone_moe.py``).
 EXAONE_CONFIG = "benchmarks/configs/k-exaone-236b-a23b-l5e16.json"
+# ``--model mistral4`` likewise (``benchmarks/arch/mistral4.py``; PERF.md
+# section 6, PR 38).
+MISTRAL4_CONFIG = "benchmarks/configs/mistral-small-4-119b-l6e32.json"
 
 
 def hybrid_limits(model: str) -> dict:
@@ -1312,6 +1337,23 @@ def _w8a8_swiglu():
     return w8a8_swiglu
 
 
+def _bench_arch(name: str):
+    """``benchmarks/arch/<name>.py`` as the harness loads it, with
+    ``benchmarks/`` on the path while it imports its reference."""
+    import importlib.util
+
+    bench = os.path.join(ROOT, "benchmarks")
+    sys.path.insert(0, bench)
+    try:
+        spec = importlib.util.spec_from_file_location(
+            f"arch_{name}", os.path.join(bench, "arch", f"{name}.py"))
+        arch = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(arch)
+    finally:
+        sys.path.remove(bench)
+    return arch
+
+
 def child_exaone(seed: int, sizes: Sizes, control: str = "") -> None:
     """``--hybrid --model exaone_moe``: the serving model's chunked
     prefill and its verify step (on true drafts, on wrong drafts, and the
@@ -1321,8 +1363,6 @@ def child_exaone(seed: int, sizes: Sizes, control: str = "") -> None:
     like the window ones, ``no_window``), how the check steps after a
     rejected draft (``stale_reject``: its position counted as written) or
     the reference's precision (``w8a8_mlp``); each has to leave a limit."""
-    import importlib.util
-
     import jax
     import numpy as np
 
@@ -1336,16 +1376,8 @@ def child_exaone(seed: int, sizes: Sizes, control: str = "") -> None:
 
     enable_compile_cache()
     t0 = time.monotonic()
-    bench = os.path.join(os.path.dirname(os.path.abspath(__file__)), "benchmarks")
-    sys.path.insert(0, bench)
-    try:
-        spec = importlib.util.spec_from_file_location(
-            "arch_exaone_moe", os.path.join(bench, "arch", "exaone_moe.py"))
-        arch = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(arch)
-    finally:
-        sys.path.remove(bench)
-    with open(os.path.join(os.path.dirname(bench), EXAONE_CONFIG)) as f:
+    arch = _bench_arch("exaone_moe")
+    with open(os.path.join(ROOT, EXAONE_CONFIG)) as f:
         limits = json.load(f)["reference"]["logit_share_limits"]
     arch._CHECK.update(limits=limits, verify=sizes.exaone_verify, chunk=sizes.exaone_chunk)
     cfg = hybrid.PRESETS[sizes.exaone_model]()  # what the reference computes
@@ -1389,9 +1421,89 @@ def child_exaone(seed: int, sizes: Sizes, control: str = "") -> None:
         raise SmokeFailure(f"logits left the reference: {failed} (limits {limits})")
 
 
+def child_mistral4(seed: int, sizes: Sizes, control: str = "") -> None:
+    """``--hybrid --model mistral4``: the serving model's chunk program
+    (``prefill_rows`` over the whole slot: attention in blocks over the
+    latent rows, read and written in place in a state of 32,768 rows a
+    slot) and its decode step over that state (the absorbed form, a
+    row's blocks up to its length) against the float32 reference, by the
+    benchmark's own comparison.  A control changes what the program computes
+    (``no_attn_scale``: the query's position scale left out,
+    ``plain_rope``: the plain frequencies for YaRN's, ``no_mscale``: the
+    softmax scale without ``m^2``) or what the reference computes
+    (``no_q_norm``: the query's latent not normed, ``w8a8_mlp``: its MLP
+    products in the nearest precision below); each has to leave a limit."""
+    import jax
+    import numpy as np
+
+    from generativeaiexamples_tpu.engine.serving_models import serving_model
+    from generativeaiexamples_tpu.models import hybrid
+    from generativeaiexamples_tpu.ops.rope import RopeSpec
+    from generativeaiexamples_tpu.utils.jax_runtime import (
+        device_report,
+        enable_compile_cache,
+        runtime_report,
+    )
+
+    enable_compile_cache()
+    t0 = time.monotonic()
+    arch = _bench_arch("mistral4")
+    with open(os.path.join(ROOT, MISTRAL4_CONFIG)) as f:
+        limits = json.load(f)["reference"]["logit_share_limits"]
+    arch._CHECK.update(limits=limits, decode=sizes.mistral4_decode, chunk=sizes.mistral4_chunk)
+    cfg = hybrid.PRESETS[sizes.mistral4_model]()  # what the reference computes
+    spec = cfg.rope_latent
+    served = {
+        "no_attn_scale": dataclasses.replace(cfg, attn_scale_beta=0.0),
+        "plain_rope": dataclasses.replace(
+            cfg, rope_latent=RopeSpec(theta=spec.theta, original_max=spec.original_max)),
+        "no_mscale": dataclasses.replace(cfg, softmax_mscale=1.0),
+    }.get(control, cfg)
+    pad_to = sizes.mistral4_prompt
+    params = serving_model(cfg, None, pad_to).prepare_params(
+        None, quantize=False, matmul_kernel="xla", seed=seed)
+    tokens = np.random.RandomState(seed).randint(1, cfg.vocab_size, size=pad_to).astype(np.int32)
+    reference = arch.mistral4_reference
+    patched = {
+        "w8a8_mlp": ("_swiglu", _w8a8_swiglu()),
+        "no_q_norm": ("_q_norm", lambda x, gain, eps: x * gain.astype(x.dtype)),
+    }.get(control)
+    if patched:
+        plain = getattr(reference, patched[0])
+        setattr(reference, *patched)
+        jax.clear_caches()  # a layer traced before this would keep the plain one
+    try:
+        share, _ = arch.logit_shares(params, cfg, tokens, pad_to, served=served)
+    finally:
+        if patched:  # a caller in this process gets the plain one back
+            setattr(reference, patched[0], plain)
+            jax.clear_caches()
+    readings = arch.share_quantiles(share, sizes.mistral4_decode)
+    failed = {k: v for k, v in readings.items() if not v <= limits[k]}
+    report = runtime_report()
+    emit(
+        {
+            "phase": "hybrid", "model": sizes.mistral4_model, "control": control or None,
+            "positions": {"prefill": int(len(share)) - sizes.mistral4_decode,
+                          "decode": sizes.mistral4_decode},
+            **readings, "limits": limits, "within_limits": not failed,
+            "seconds": time.monotonic() - t0,
+            "compile": report["compile"], "peak_bytes_in_use": report["peak_bytes_in_use"],
+            "kernel_paths": {**_taken("moe_experts"), **_taken("attn_latent")},
+            "device": device_report(),
+        }
+    )
+    if control and not failed:
+        raise SmokeFailure(f"the control {control!r} stayed inside every limit: {readings}")
+    if not control and failed:
+        raise SmokeFailure(f"logits left the reference: {failed} (limits {limits})")
+
+
 def child_hybrid(seed: int, sizes: Sizes, control: str = "", model: str = "ling") -> None:
     if model == "exaone_moe":
         return child_exaone(seed, sizes, control)
+    if model == "mistral4":
+        return child_mistral4(seed, sizes, control)
     import importlib
 
     import jax

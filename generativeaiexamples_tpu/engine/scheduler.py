@@ -2318,9 +2318,7 @@ class Scheduler:
             return
         sizes = [1 << k for k in range(most.bit_length())]
         s = self._chunk_bucket()
-        windows = [min(8 * self.prefill_chunk_tokens, self.max_len)]
-        while windows[-1] < self.max_len:
-            windows.append(min(2 * windows[-1], self.max_len))
+        windows = list(self.model.chunk_windows(self.prefill_chunk_tokens))
         t0 = time.perf_counter()
         for rows in sizes:
             ints = jax.ShapeDtypeStruct((rows,), jnp.int32)

@@ -11,6 +11,7 @@ from these calls and knows nothing else about the model:
 ``chunks_per_program``   how many slots' prefill chunks may share one
                      program (1: ``prefill_row`` is all there is), and then
 ``prefill_rows``     runs of several slots' prompts, each from its position
+``chunk_windows``    the windows those programs are compiled for
 ``graft_rows``       rows of a cold batch's state into their slots
 ``graft_prefix``     the first n token rows of one slot into another
 ``save_state`` / ``restore_state``   a snapshot of what cannot be cut at a
@@ -78,6 +79,15 @@ def chunks_sharing_experts(chunk_tokens: int, n_experts: int, k: int) -> int:
     return max(1, min(MAX_CHUNKS_PER_PROGRAM, moe.ROW_TILE // rows))
 
 
+def doubling_windows(chunk_tokens: int, max_len: int) -> tuple[int, ...]:
+    """The windows a program over the slots' first ``window`` rows is
+    compiled for: eight chunks, then doubling up to the slot's length."""
+    windows = [min(8 * chunk_tokens, max_len)]
+    while windows[-1] < max_len:
+        windows.append(min(2 * windows[-1], max_len))
+    return tuple(windows)
+
+
 def serving_model(cfg, mesh, max_len: int):
     """The server of ``cfg``'s kind."""
     if isinstance(cfg, hybrid.HybridConfig):
@@ -110,6 +120,9 @@ class LlamaServing:
         return chunks_sharing_experts(
             chunk_tokens, cfg.n_experts, cfg.n_experts_per_tok
         )
+
+    def chunk_windows(self, chunk_tokens: int) -> tuple[int, ...]:
+        return doubling_windows(chunk_tokens, self.max_len)
 
     def prepare_params(self, params, *, quantize, matmul_kernel, seed):
         from generativeaiexamples_tpu.engine.decode import prepare_params
@@ -256,7 +269,10 @@ class HybridServing:
     (latent rows, a full layer's K/V), which a graft copies up to any
     token; and state as of the last token (a KDA layer's ``S`` and
     ``conv``, a window layer's ring), which a prefix hit takes from a
-    snapshot saved at a prefill-chunk boundary.
+    snapshot saved at a prefill-chunk boundary.  A model whose every leaf
+    is of the first sort (``cfg.rows_only``: latent attention in every
+    layer) says ``cut_anywhere``: its prefix hits are cut at any row, as a
+    llama model's are, and no snapshot is ever saved for it.
 
     A model that holds a prediction module (``cfg.draft`` ``"mtp"``) is
     served with it as the draft of every decode step.  The module's state
@@ -277,7 +293,6 @@ class HybridServing:
     query from ``p + 1`` on may see; the module writes accepted
     positions only."""
 
-    cut_anywhere = False
     # Counters of a drafting model's decode chunk, after ``forward``'s:
     # drafts a greedy row offered and the stack agreed with, positions the
     # stack computed in decode steps, tokens emitted, and rows of full
@@ -290,15 +305,17 @@ class HybridServing:
     def __init__(self, cfg: hybrid.HybridConfig, mesh, max_len: int) -> None:
         self.cfg, self.mesh, self.max_len = cfg, mesh, max_len
         self.draft = cfg.draft
+        self.cut_anywhere = cfg.rows_only
+        # Latent rows alone, attended in blocks: the chunk programs read
+        # and write the slots' state in place (``_prefill_rows_in_place``).
+        self.rows_in_place = cfg.rows_only and bool(cfg.latent_block)
         self.snapshot_bytes = cfg.snapshot_bytes(max_len)
         # ``forward``'s counters; the rows its attention layers read are
         # counted apart for decode steps and for prefill chunks.
-        self.counter_names = tuple(f"moe_{n}" for n in moe.COUNTERS)
-        if cfg.has_attn_counters:
-            self.counter_names += tuple(
-                f"attn_rows_{n}_{phase}"
-                for phase in ("decode", "prefill") for n in hybrid.ATTN_COUNTERS
-            )
+        self.counter_names = tuple(f"moe_{n}" for n in moe.COUNTERS) + tuple(
+            f"attn_rows_{n}_{phase}"
+            for phase in ("decode", "prefill") for n in cfg.row_counters
+        )
         if self.draft:
             self.counter_names += self.DRAFT_COUNTERS
 
@@ -364,7 +381,7 @@ class HybridServing:
         rows go to the decode or to the prefill entries; a drafting
         model's ``DRAFT_COUNTERS`` (``drafted``; a prefill has none)
         come last."""
-        if not self.cfg.has_attn_counters:
+        if not self.cfg.row_counters:
             return counters
         n = len(moe.COUNTERS)
         rows, none = counters[n:], jnp.zeros_like(counters[n:])
@@ -480,6 +497,10 @@ class HybridServing:
         (``suffix_len`` 0) routes to no expert and writes to no slot,
         whatever ``slots`` says of it.  Returns (cache, hidden (B, s, D),
         counters)."""
+        if self.rows_in_place:
+            return self._prefill_rows_in_place(
+                params, cache, tokens, start, suffix_len, slots, window
+            )
         live = suffix_len > 0
 
         def per_row(x, like):
@@ -517,6 +538,31 @@ class HybridServing:
                 for layer, row in zip(cache, rows)
             )
         return cache, hidden, self._aux(counters, decode=False)
+
+    def _prefill_rows_in_place(self, params, cache, tokens, start, suffix_len, slots, window):
+        """``prefill_rows`` for a model whose state is latent rows alone
+        and whose chunks attend in blocks: every layer is handed the
+        slots' whole state and which slot each row is, writes a chunk's
+        rows where they belong and reads a row's blocks from there, so no
+        window of the state is gathered or written back (at 8 rows of
+        32,768 that copy is 0.2 GB a layer each way).  A pad row writes
+        nothing (none of its tokens counts) and reads nothing."""
+        rows = tuple({**layer, "slot": slots} for layer in cache)
+        hidden, rows, counters = hybrid.forward(
+            params, self.cfg, tokens, start, suffix_len, rows, window=window,
+            mesh=self.mesh, rows_apart=True,
+        )
+        cache = tuple({"latent": layer["latent"]} for layer in rows)
+        return cache, hidden, self._aux(counters, decode=False)
+
+    def chunk_windows(self, chunk_tokens: int) -> tuple[int, ...]:
+        """One window, the whole slot, for a model whose chunk programs
+        read a row's blocks in place up to its length (a wider window
+        costs such a program nothing, and every window is a program to
+        build for each size); else the doubling family."""
+        if self.rows_in_place:
+            return (self.max_len,)
+        return doubling_windows(chunk_tokens, self.max_len)
 
     def graft_prefix(self, cache, src, dst, n: int):
         """The first ``n`` rows of what holds a row a position; what exists

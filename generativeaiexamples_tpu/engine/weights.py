@@ -80,6 +80,8 @@ def resolve_model_preset(model_name: str) -> str:
         return "mellum-tiny" if "tiny" in name else "mellum2-12b-a2.5b-l12"
     if "exaone" in name:
         return "exaone_moe-tiny" if "tiny" in name else "k-exaone-236b-a23b-l5e16"
+    if "mistral-small-4" in name or name.startswith("mistral4"):
+        return "mistral4-tiny" if "tiny" in name else "mistral-small-4-119b-l6e32"
     if "mixtral" in name or "8x7b" in name:
         return "mixtral-8x7b"
     if "gemma" in name:

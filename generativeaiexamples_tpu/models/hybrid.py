@@ -3,7 +3,7 @@
 owns the parameters of those kinds and keeps the state of its mixer's
 kind.
 
-Two families are defined here.  ``bailing_hybrid`` (Ling-3.0-flash and
+Four families are defined here.  ``bailing_hybrid`` (Ling-3.0-flash and
 its -VL sibling's language model): KDA linear attention (``ops/kda.py``)
 beside a latent-attention layer every ``layer_group_size`` layers
 (``ops/mla.py``), a leading dense SwiGLU layer and then sigmoid-routed
@@ -19,9 +19,18 @@ frequencies on the window layers and none at all on the full ones, a
 leading dense layer, sigmoid-routed experts with a shared one of which
 this process may hold a share, and one multi-token-prediction module
 after the stack (:func:`mtp_forward`), which the engine serves as the
-draft of its decode step.  A new architecture is a new layer kind here,
-not another flag on ``LlamaConfig``; ``models/llama.py`` keeps serving
-the configurations it serves.
+draft of its decode step.  ``mistral4`` (Mistral-Small-4-119B-2603):
+latent attention as the mixer of EVERY layer, so a slot's state is latent
+rows and nothing else; the query goes through a low-rank pair
+(``W_qa`` -> RMSNorm -> ``W_qb``), the latent's rotary part takes YaRN
+frequencies, the query is scaled by a factor that grows with its position
+(``llama_4_scaling_beta``), the output has no gate, and every layer has
+softmax-routed experts with a shared one, of which this process may hold
+a share.  The ``mla`` kind serves Ling's form and this one from one
+function; what differs is data on the configuration (``LatentConfig``).
+A new architecture is a new layer kind here, not another flag on
+``LlamaConfig``; ``models/llama.py`` keeps serving the configurations it
+serves.
 
 One ``forward`` serves the three ways the engine calls a model: a cold
 batch into fresh state, a chunk of one slot's prompt, and one decode
@@ -51,15 +60,17 @@ token after it; like a recurrent state it exists only as of that token.
 ``ROW_LEAVES`` names the leaves that hold a row a position; every other
 leaf is state as of the last token.  ``HybridConfig`` holds what every
 family has and the KDA and MLA sizes; ``GqaConfig`` adds the GQA sizes,
-the rotary parameters of each kind and the routing options.
+the rotary parameters of each kind and the routing options;
+``LatentConfig`` adds what the ``mistral4`` family's latent layer has.
 
 What is read from a family's convention and not from a key of the
 public config is listed under ``assumed`` in
 ``benchmarks/configs/ling-3.0-flash-vl-l7e128.json``,
-``benchmarks/configs/mellum2-12b-a2.5b-l12.json`` and
-``benchmarks/configs/k-exaone-236b-a23b-l5e16.json``; the plain references
-are ``models/hybrid_reference.py``, ``models/mellum_reference.py`` and
-``models/exaone_moe_reference.py``.
+``benchmarks/configs/mellum2-12b-a2.5b-l12.json``,
+``benchmarks/configs/k-exaone-236b-a23b-l5e16.json`` and
+``benchmarks/configs/mistral-small-4-119b-l6e32.json``; the plain
+references are ``models/hybrid_reference.py``, ``models/mellum_reference.py``,
+``models/exaone_moe_reference.py`` and ``models/mistral4_reference.py``.
 """
 
 from __future__ import annotations
@@ -74,7 +85,9 @@ import jax.numpy as jnp
 from generativeaiexamples_tpu.models.llama import rms_norm
 from generativeaiexamples_tpu.ops import gqa, gqa_decode, kda, mla, moe
 from generativeaiexamples_tpu.ops.dispatch import record
-from generativeaiexamples_tpu.ops.rope import NO_ROPE, RopeSpec, apply_rope_spec, rope_spec
+from generativeaiexamples_tpu.ops.rope import (
+    NO_ROPE, RopeSpec, apply_rope_spec, rope_spec, yarn_mscale,
+)
 
 Params = Mapping[str, Any]
 F32 = jnp.float32
@@ -94,6 +107,10 @@ GQA_LEAVES = {"full": ("k", "v"), "window": RING_LEAVES}  # a GQA mixer's K and 
 # (a decode step's row walk reads less: ``ops/gqa_decode.py``).  A model
 # with such layers returns them after ``moe.COUNTERS``.
 ATTN_COUNTERS = ("read_window", "read_full", "dense_window", "dense_full")
+# Rows of the latent cache the MLA layers read, and what they would have
+# read with every row's whole window (``kv_bucket``) read.  A
+# ``LatentConfig`` model returns them after ``moe.COUNTERS``.
+LATENT_COUNTERS = ("read_latent", "dense_latent")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -143,6 +160,13 @@ class HybridConfig:
     rope_window: ClassVar[RopeSpec | None] = None
     qk_norm: ClassVar[bool] = False
     mtp_layers: ClassVar[int] = 0
+    # What ``LatentConfig`` makes fields of: this is Ling's latent layer.
+    q_lora_rank: ClassVar[int] = 0
+    rope_latent: ClassVar[RopeSpec | None] = None
+    attn_scale_beta: ClassVar[float] = 0.0
+    softmax_mscale: ClassVar[float] = 1.0
+    mla_out_gate: ClassVar[bool] = True
+    latent_block: ClassVar[int] = 0
 
     def __post_init__(self) -> None:
         for mixer, mlp in self.layer_kinds:
@@ -178,6 +202,22 @@ class HybridConfig:
         return bool(self.layers_of("full") or self.layers_of("window"))
 
     @property
+    def row_counters(self) -> tuple[str, ...]:
+        """Names of the counters of rows read that ``forward`` returns
+        after ``moe.COUNTERS``: a model with GQA layers ``ATTN_COUNTERS``,
+        a ``LatentConfig`` model ``LATENT_COUNTERS``, Ling's none."""
+        return ATTN_COUNTERS if self.has_attn_counters else ()
+
+    @property
+    def rows_only(self) -> bool:
+        """True where every leaf of a slot's state holds a row a position
+        (latent rows, a full layer's K/V): the state can then be cut at
+        any token, and a prefix hit needs no snapshot."""
+        return not (
+            self.layers_of("kda") or self.layers_of("window") or self.mtp_layers
+        )
+
+    @property
     def draft(self) -> str:
         """What drafts the decode step: ``mtp``, the model's own
         prediction module, where one is held; else nothing."""
@@ -185,9 +225,9 @@ class HybridConfig:
 
     @property
     def n_counters(self) -> int:
-        """Entries of ``forward``'s counters: ``moe.COUNTERS`` and, with
-        GQA layers, ``ATTN_COUNTERS`` after them."""
-        return len(moe.COUNTERS) + len(ATTN_COUNTERS) * self.has_attn_counters
+        """Entries of ``forward``'s counters: ``moe.COUNTERS`` and
+        ``row_counters`` after them."""
+        return len(moe.COUNTERS) + len(self.row_counters)
 
     def ring_rows(self, max_len: int) -> int:
         """Rows of a window layer's ring: the window, whatever the length
@@ -254,6 +294,67 @@ class GqaConfig(HybridConfig):
     mtp_layers: int = 0
 
 
+@dataclasses.dataclass(frozen=True)
+class LatentConfig(HybridConfig):
+    """A configuration whose ``mla`` layers are the ``mistral4`` family's:
+    what differs from Ling's latent layer, and the routing options."""
+
+    score_function: str = "sigmoid"
+    router_bias: bool = True
+    # The query as ``W_qa`` (D, q_lora_rank) -> RMSNorm -> ``W_qb``; 0 is
+    # one full-rank ``w_q``.
+    q_lora_rank: int = 0
+    # The rotation of the latent's rope key and the queries' rope part
+    # (``None``: the plain frequencies of ``rope_theta``).
+    rope_latent: RopeSpec | None = None
+    # The rotated query times ``1 + beta ln(1 + floor(p / original_max))``
+    # (``rope_latent``'s original context); 0 is no such scale.
+    attn_scale_beta: float = 0.0
+    # The softmax scale is (nope + rope)^-1/2 times this squared.
+    softmax_mscale: float = 1.0
+    # A sigmoid gate a head on the attention's output (Ling's).
+    mla_out_gate: bool = True
+    # Prefill attends a block of this many latent rows at a time
+    # (``mla.attend_blocks``).  At the mistral4 family's sizes a block's
+    # float32 scores are 32 heads x 256 queries x 1,024 keys = 33.6 MB,
+    # its expansion 12.6 MB; a chunk program of 8 rows at position 12,288
+    # takes 11.8 ms a layer on a v5e, 12.6 with blocks of 512, 13.8 with
+    # blocks of 2,048.
+    latent_block: int = 1024
+    # A decode step walks each decoding row's blocks of this many latent
+    # rows up to its length (``mla.attend_absorbed_blocks``), one row
+    # after the other, and reads nothing of a slot that does not decode.
+    # One layer's attention of a step over 16 slots of 32,768 rows on a
+    # v5e: 0.31 ms with 4 rows of 14,000 decoding (what the family's cell
+    # holds) and 1.04 ms with all 16, where one product over every slot's
+    # whole window takes 1.13 ms whoever decodes; blocks of 1,024 read
+    # 0.38 and 1.29 ms, of 4,096 0.30 and 1.02.
+    latent_decode_block: int = 2048
+
+    def __post_init__(self) -> None:
+        super().__post_init__()
+        if self.latent_block < 1 or self.latent_decode_block < 1:
+            raise ValueError(
+                "a LatentConfig attends in blocks (latent_block, latent_decode_block "
+                ">= 1): its rows are stored in whole lanes, which the whole-window "
+                "forms do not read"
+            )
+
+    @property
+    def row_counters(self) -> tuple[str, ...]:
+        return LATENT_COUNTERS
+
+    @property
+    def latent_width(self) -> int:
+        """A latent row as stored: the latent and the rope key, filled up
+        with zero columns to whole lanes of 128.  The chip's default
+        layout of a leaf whose rows are no multiple of 128 wide puts the
+        POSITIONS minor, and every step program then copies the whole
+        state in and out to get at a row (at 16 slots of 32,768 rows of
+        320: 2.5 GB of temporaries and 4 GB of traffic a program)."""
+        return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+
 def from_hf_config(
     model: Mapping[str, Any],
     *,
@@ -264,8 +365,8 @@ def from_hf_config(
 ) -> HybridConfig:
     """The public ``config.json`` keys -> ``HybridConfig``, by
     ``model_type``: ``mellum`` (:func:`_from_mellum`), ``exaone_moe``
-    (:func:`_from_exaone`), else the ``bailing_hybrid`` family, of which
-    the rest speaks.  ``draft`` ``mtp`` holds the model's own prediction
+    (:func:`_from_exaone`), ``mistral4`` (:func:`_from_mistral4`), else the
+    ``bailing_hybrid`` family, of which the rest speaks.  ``draft`` ``mtp`` holds the model's own prediction
     module and serves it as the decode step's draft; a family without one
     refuses it.
 
@@ -288,6 +389,10 @@ def from_hf_config(
         )
     if model.get("model_type") == "mellum":
         return _from_mellum(model, max_len=max_len, kv_dtype=kv_dtype)
+    if model.get("model_type") == "mistral4":
+        return _from_mistral4(
+            model, max_len=max_len, expert_offset=expert_offset, kv_dtype=kv_dtype
+        )
     period = int(model["layer_group_size"])
     first = int(model.get("first_layer", 0))
     dense = int(model["first_k_dense_replace"])
@@ -477,6 +582,73 @@ def _from_exaone(
     )
 
 
+def _from_mistral4(
+    model: Mapping[str, Any], *, max_len: int, expert_offset: int, kv_dtype: str
+) -> LatentConfig:
+    """``model_type: mistral4``: every layer an ``mla`` mixer with a
+    low-rank query and experts with a shared one (``first_k_dense_replace``
+    0); ``rope_parameters`` is YaRN over the latent's rotary part, with
+    ``mscale_all_dim``'s term squared in the softmax scale and
+    ``llama_4_scaling_beta`` the query's position scale.
+    ``n_routed_experts`` counts the experts held of
+    ``num_experts_published`` router outputs (absent: the same); routing
+    is the softmax over them all, plain top-k, renormalised."""
+    if int(model.get("first_k_dense_replace", 0)):
+        raise ValueError("a leading dense layer is not served for mistral4")
+    if not model.get("rope_interleave", True):
+        raise ValueError("only the interleaved rotation is served for mistral4")
+    if model.get("scoring_func", "softmax") != "softmax":
+        raise ValueError("scoring_func other than softmax is not served for mistral4")
+    if int(model.get("n_group", 1)) != 1 or int(model.get("topk_group", 1)) != 1:
+        raise ValueError("routing groups are not served for mistral4 (softmax, one group)")
+    if model.get("attention_bias") or model.get("mlp_bias"):
+        raise ValueError("attention and MLP biases are not served")
+    if model.get("hidden_act", "silu") != "silu":
+        raise ValueError("activations other than silu are not served")
+    if model.get("sliding_window"):
+        raise ValueError("a sliding window is not served for mistral4")
+    if not model.get("q_lora_rank"):
+        raise ValueError("mistral4 is served with a low-rank query (q_lora_rank)")
+    rope = dict(model["rope_parameters"])
+    spec = rope_spec(rope)
+    if spec.rope_type != "yarn":
+        raise ValueError("mistral4 is served with YaRN frequencies on the latent's rotary part")
+    held = int(model["n_routed_experts"])
+    return LatentConfig(
+        vocab_size=int(model["vocab_size"]),
+        d_model=int(model["hidden_size"]),
+        layer_kinds=(("mla", "experts"),) * int(model["num_hidden_layers"]),
+        n_heads=int(model["num_attention_heads"]),
+        kv_lora_rank=int(model["kv_lora_rank"]),
+        qk_nope_head_dim=int(model["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(model["qk_rope_head_dim"]),
+        v_head_dim=int(model["v_head_dim"]),
+        rope_theta=float(rope["rope_theta"]),
+        q_lora_rank=int(model["q_lora_rank"]),
+        rope_latent=spec,
+        attn_scale_beta=float(rope.get("llama_4_scaling_beta", 0.0)),
+        softmax_mscale=yarn_mscale(spec.factor, float(rope.get("mscale_all_dim", 0.0))),
+        mla_out_gate=False,
+        d_ff=int(model["intermediate_size"]),
+        moe_d_ff=int(model["moe_intermediate_size"]),
+        shared_d_ff=int(model["moe_intermediate_size"]) * int(model["n_shared_experts"]),
+        n_experts=int(model.get("num_experts_published", held)),
+        experts_held=held,
+        expert_offset=int(expert_offset),
+        n_experts_per_tok=int(model["num_experts_per_tok"]),
+        n_group=1,
+        topk_group=1,
+        routed_scaling=float(model["routed_scaling_factor"]),
+        norm_topk=bool(model["norm_topk_prob"]),
+        score_function="softmax",
+        router_bias=False,
+        norm_eps=float(model["rms_norm_eps"]),
+        max_seq_len=int(max_len),
+        dtype=str(model.get("torch_dtype", "bfloat16")),
+        kv_dtype=kv_dtype,
+    )
+
+
 # -- parameters ---------------------------------------------------------------
 
 
@@ -512,17 +684,25 @@ def _layer_shapes(cfg: HybridConfig, mixer: str, mlp: str) -> dict:
             shapes.update(q_norm=((hd,), 1.0), k_norm=((hd,), 1.0))
     else:
         qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
+        if cfg.q_lora_rank:
+            shapes.update(
+                w_qa=((D, cfg.q_lora_rank), D),
+                q_norm=((cfg.q_lora_rank,), 1.0),
+                w_qb=((cfg.q_lora_rank, H * qk), cfg.q_lora_rank),
+            )
+        else:
+            shapes.update(w_q=((D, H * qk), D))
         shapes.update(
-            w_q=((D, H * qk), D),
-            w_kva=((D, cfg.latent_width), D),
+            w_kva=((D, cfg.kv_lora_rank + cfg.qk_rope_head_dim), D),
             kv_norm=((cfg.kv_lora_rank,), 1.0),
             w_kvb=(
                 (cfg.kv_lora_rank, H * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
                 cfg.kv_lora_rank,
             ),
-            w_gate=((D, H), D),
-            w_o=((H * cfg.v_head_dim, D), H * cfg.v_head_dim),
         )
+        if cfg.mla_out_gate:
+            shapes.update(w_gate=((D, H), D))
+        shapes.update(w_o=((H * cfg.v_head_dim, D), H * cfg.v_head_dim))
     if mlp == "dense":
         shapes.update(w_gu=((D, 2 * cfg.d_ff), D), w_down=((cfg.d_ff, D), cfg.d_ff))
     else:
@@ -749,35 +929,98 @@ def _kda_mixer(h, lp, st, valid, n_valid, cfg: HybridConfig):
 
 
 def _mla_mixer(h, lp, st, pos, valid, n_valid, cfg: HybridConfig, window: int, apart: bool):
+    """An ``mla`` layer, Ling's form or the mistral4 family's by what the
+    configuration says (a low-rank query, YaRN on the rotary part, a query
+    scale by position, no output gate, prefill in blocks).  Returns
+    (output, state, the rows read in the order of ``LATENT_COUNTERS``).
+
+    ``st`` may carry ``slot`` (b,) beside ``latent``: the latent rows are
+    then a state of many slots, of which row ``i`` of this call is slot
+    ``slot[i]`` (absent: slot ``i``); the new rows are written there and
+    the block forms read a row's blocks from there, a row at a time, so
+    that no window of it is ever copied (a chunk program of a model whose
+    state is rows alone: ``HybridServing.prefill_rows``)."""
     b, s, _ = h.shape
     H, rank = cfg.n_heads, cfg.kv_lora_rank
     nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    with jax.named_scope("layer/mla/q"):
-        q = jnp.dot(h, lp["w_q"]).reshape(b, s, H, nope + rope)
+    spec = cfg.rope_latent
+    with jax.named_scope("layer/mla/q_lora" if cfg.q_lora_rank else "layer/mla/q"):
+        if cfg.q_lora_rank:
+            c_q = rms_norm(jnp.dot(h, lp["w_qa"]), lp["q_norm"], cfg.norm_eps)
+            q = jnp.dot(c_q, lp["w_qb"]).reshape(b, s, H, nope + rope)
+        else:
+            q = jnp.dot(h, lp["w_q"]).reshape(b, s, H, nope + rope)
         q_nope = q[..., :nope]
-        q_rope = mla.rope_interleaved(q[..., nope:], pos, cfg.rope_theta)
+        q_rope = mla.rope_interleaved(q[..., nope:], pos, cfg.rope_theta, spec)
+        if cfg.attn_scale_beta:
+            a = mla.position_scale(pos, cfg.attn_scale_beta, spec.original_max)
+            a = a[:, :, None, None]
+            q_nope = (q_nope.astype(F32) * a).astype(q.dtype)
+            q_rope = (q_rope.astype(F32) * a).astype(q.dtype)
     with jax.named_scope("layer/mla/kv"):
         ckr = jnp.dot(h, lp["w_kva"])
         c = rms_norm(ckr[..., :rank], lp["kv_norm"], cfg.norm_eps)
-        k_rope = mla.rope_interleaved(ckr[..., rank:], pos, cfg.rope_theta)
-        new = jnp.concatenate([c, k_rope], axis=-1).astype(st["latent"].dtype)
-        T = st["latent"].shape[1]
+        k_rope = mla.rope_interleaved(ckr[..., rank:], pos, cfg.rope_theta, spec)
+        T, width = st["latent"].shape[1:]
+        spare = jnp.zeros((b, s, width - rank - rope), c.dtype)  # none in Ling's rows
+        new = jnp.concatenate([c, k_rope, spare], axis=-1).astype(st["latent"].dtype)
         # A token that does not count is written nowhere.
         at = jnp.where(valid, pos, T)
-        latent = st["latent"].at[jnp.arange(b)[:, None], at].set(new, mode="drop")
-    attend = functools.partial(
-        mla.attend_absorbed if s == 1 else mla.attend_expanded,
-        w_kvb=lp["w_kvb"], rank=rank, nope=nope, v_dim=vd,
-    )
-    o = _attend(
-        lambda qn, qr, lat, p: attend(qn, qr, lat, q_pos=p),
-        n_valid, apart, q_nope, q_rope, latent[:, :window], pos,
-    )
+        slot = st.get("slot")
+        mine = jnp.arange(b) if slot is None else slot
+        latent = st["latent"].at[mine[:, None], at].set(new, mode="drop")
+    span = min(window, T)
+    sizes = dict(w_kvb=lp["w_kvb"], rank=rank, nope=nope, v_dim=vd)
+    if cfg.softmax_mscale != 1.0:
+        sizes["scale"] = (nope + rope) ** -0.5 * cfg.softmax_mscale**2
+    read = b * span
+
+    def in_place(attend, block, lengths):
+        """A row at a time, its whole blocks up to ``lengths`` read from
+        its slot of the state in place; a row that holds nothing (a
+        group's padding, a slot that does not decode) is passed over."""
+
+        def one(row):
+            qn, qr, sl, p, n = (x[None] for x in row)
+            return jax.lax.cond(
+                n[0] > 0,
+                lambda: attend(
+                    qn, qr, latent, q_pos=p, lengths=n, block=block, slot=sl,
+                    window=span, **sizes
+                )[0],
+                lambda: jnp.zeros((s, H, vd), q.dtype),
+            )
+
+        return jax.lax.map(one, (q_nope, q_rope, mine, pos, lengths))
+
+    if cfg.latent_block and s > 1:
+        record(f"attn_latent b={b} s={s} t={span}", False)
+        # Rows each row holds once its tokens are written; a row with
+        # nothing that counts reads nothing.
+        lengths = jnp.where(n_valid > 0, pos[:, 0] + n_valid, 0)
+        read = mla.rows_in_blocks(lengths, span, cfg.latent_block).sum()
+        o = in_place(mla.attend_blocks, cfg.latent_block, lengths)
+    elif cfg.latent_block:
+        record(f"attn_latent_decode b={b} t={span}", False)
+        # A decode step: a row that does not decode reads nothing.
+        lengths = jnp.where(n_valid > 0, pos[:, 0] + 1, 0)
+        read = mla.rows_in_blocks(lengths, span, cfg.latent_decode_block).sum()
+        o = in_place(mla.attend_absorbed_blocks, cfg.latent_decode_block, lengths)
+    else:
+        attend = functools.partial(
+            mla.attend_absorbed if s == 1 else mla.attend_expanded, **sizes
+        )
+        o = _attend(
+            lambda qn, qr, lat, p: attend(qn, qr, lat, q_pos=p),
+            n_valid, apart, q_nope, q_rope, latent[:, :window], pos,
+        )
     with jax.named_scope("layer/mla/wo"):
-        gate = jax.nn.sigmoid(jnp.dot(h, lp["w_gate"], preferred_element_type=F32))
-        o = (o.astype(F32) * gate[..., None]).astype(h.dtype)
+        if cfg.mla_out_gate:
+            gate = jax.nn.sigmoid(jnp.dot(h, lp["w_gate"], preferred_element_type=F32))
+            o = (o.astype(F32) * gate[..., None]).astype(h.dtype)
         out = jnp.dot(o.reshape(b, s, H * vd), lp["w_o"])
-    return out, {"latent": latent}
+    new_state = {"latent": latent} if slot is None else {"latent": latent, "slot": slot}
+    return out, new_state, jnp.stack([read, b * span]).astype(jnp.int32)
 
 
 def _gqa_mixer(
@@ -884,14 +1127,16 @@ def _mix(
     x, lp, st, mixer, pos, valid, n_valid, cfg: HybridConfig, window: int,
     apart: bool = False, site: str = "", mesh=None,
 ):
-    """The mixer's half of a layer: (x + mixer(norm(x)), new state, a GQA
-    layer's ``ATTN_COUNTERS`` or 0)."""
+    """The mixer's half of a layer: (x + mixer(norm(x)), new state, the
+    rows the layer read in the order of ``cfg.row_counters``, or 0)."""
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     read = 0
     if mixer == "kda":
         y, st = _kda_mixer(h, lp, st, valid, n_valid, cfg)
     elif mixer == "mla":
-        y, st = _mla_mixer(h, lp, st, pos, valid, n_valid, cfg, window, apart)
+        y, st, rows = _mla_mixer(h, lp, st, pos, valid, n_valid, cfg, window, apart)
+        if cfg.row_counters:
+            read = rows
     else:
         y, st, read = _gqa_mixer(
             h, lp, st, mixer, pos, valid, n_valid, cfg, window, apart, site, mesh
@@ -934,7 +1179,7 @@ def forward(
     pos = start[:, None].astype(jnp.int32) + steps
     valid = steps < n_valid[:, None]
     counters = jnp.zeros((len(moe.COUNTERS),), jnp.int32)
-    read = jnp.zeros((len(ATTN_COUNTERS),), jnp.int32) if cfg.has_attn_counters else None
+    read = jnp.zeros((len(cfg.row_counters),), jnp.int32)
     out_state = []
     for (mixer, mlp), lp, st in zip(cfg.layer_kinds, params["layers"], state):
         x, st, r = _mix(
@@ -943,11 +1188,9 @@ def forward(
         )
         x, c = _mlp(x, lp, mlp, valid, cfg, mesh)
         counters = counters + c
-        if cfg.has_attn_counters:
-            read = read + r
+        read = read + r
         out_state.append(st)
-    if cfg.has_attn_counters:
-        counters = jnp.concatenate([counters, read])
+    counters = jnp.concatenate([counters, read])
     # A prediction module's state lies behind the stack's and is its own
     # to move (``mtp_forward``).
     return x, tuple(out_state) + tuple(state[cfg.n_layers :]), counters
@@ -1134,6 +1377,52 @@ EXAONE_TINY = {
 }
 
 
+# mistralai/Mistral-Small-4-119B-2603's config.json: every key that gives
+# the language model its shape (``intermediate_size`` is a dense MLP's,
+# which no layer is: ``first_k_dense_replace`` 0; the vision tower has no
+# key there and is not modelled).
+MISTRAL_SMALL_4 = {
+    "model_type": "mistral4", "num_hidden_layers": 36, "hidden_size": 4096,
+    "intermediate_size": 12288, "moe_intermediate_size": 2048,
+    "first_k_dense_replace": 0, "num_attention_heads": 32,
+    "num_key_value_heads": 32, "head_dim": 128, "q_lora_rank": 1024,
+    "kv_lora_rank": 256, "qk_head_dim": 128, "qk_nope_head_dim": 64,
+    "qk_rope_head_dim": 64, "v_head_dim": 128, "rope_interleave": True,
+    "attention_bias": False, "mlp_bias": False, "hidden_act": "silu",
+    "n_routed_experts": 128, "n_shared_experts": 1, "num_experts_per_tok": 4,
+    "n_group": 1, "topk_group": 1, "norm_topk_prob": True,
+    "routed_scaling_factor": 1, "sliding_window": None,
+    "max_position_embeddings": 1048576,
+    "rope_parameters": {
+        "rope_type": "yarn", "type": "yarn", "rope_theta": 10000, "factor": 128,
+        "original_max_position_embeddings": 8192, "beta_fast": 32, "beta_slow": 1,
+        "mscale": 1, "mscale_all_dim": 1, "llama_4_scaling_beta": 0.1,
+    },
+    "rms_norm_eps": 1e-06, "vocab_size": 131072, "tie_word_embeddings": False,
+}
+# Rank 0's share of a four-chip layer group: published layers 0-5, 32 of
+# the 128 experts (the first 32), a quarter of the vocabulary.
+MISTRAL4_L6E32_CUT = {
+    "num_hidden_layers": 6, "n_routed_experts": 32, "num_experts_published": 128,
+    "vocab_size": 32768,
+}
+# Every ratio at sizes a CPU test runs: 8 of 32 experts held and 2 a
+# token, a query rank under the hidden size, YaRN and the query's
+# position scale past an original context of 32 (prompts cross it).
+MISTRAL4_TINY = {
+    **MISTRAL_SMALL_4, "num_hidden_layers": 3, "hidden_size": 64,
+    "moe_intermediate_size": 32, "num_attention_heads": 4, "q_lora_rank": 24,
+    "kv_lora_rank": 16, "qk_nope_head_dim": 8, "qk_rope_head_dim": 8,
+    "qk_head_dim": 16, "v_head_dim": 16, "head_dim": 16,
+    "n_routed_experts": 8, "num_experts_published": 32, "num_experts_per_tok": 2,
+    "vocab_size": 512, "torch_dtype": "float32",
+    "rope_parameters": {
+        **MISTRAL_SMALL_4["rope_parameters"], "factor": 8,
+        "original_max_position_embeddings": 32,
+    },
+}
+
+
 def ling_flash_vl_l7e128() -> HybridConfig:
     return from_hf_config({**LING_FLASH_VL, **LING_L7E128_CUT}, max_len=2048)
 
@@ -1160,6 +1449,18 @@ def exaone_tiny() -> HybridConfig:
     return from_hf_config(EXAONE_TINY, max_len=256, kv_dtype="float32", draft="mtp")
 
 
+def mistral_small_4_l6e32() -> HybridConfig:
+    return from_hf_config({**MISTRAL_SMALL_4, **MISTRAL4_L6E32_CUT}, max_len=32768)
+
+
+def mistral4_tiny() -> HybridConfig:
+    # Blocks of 16 latent rows: shorter than the windows the tests use.
+    return dataclasses.replace(
+        from_hf_config(MISTRAL4_TINY, max_len=256, kv_dtype="float32"),
+        latent_block=16, latent_decode_block=16,
+    )
+
+
 PRESETS = {
     "ling-3.0-flash-vl-l7e128": ling_flash_vl_l7e128,
     "ling-tiny": ling_tiny,
@@ -1167,4 +1468,6 @@ PRESETS = {
     "mellum-tiny": mellum_tiny,
     "k-exaone-236b-a23b-l5e16": k_exaone_236b_l5e16,
     "exaone_moe-tiny": exaone_tiny,
+    "mistral-small-4-119b-l6e32": mistral_small_4_l6e32,
+    "mistral4-tiny": mistral4_tiny,
 }

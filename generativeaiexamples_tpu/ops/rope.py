@@ -94,12 +94,29 @@ def rope_spec(section: Mapping[str, Any]) -> RopeSpec:
         original_max=int(section["original_max_position_embeddings"]),
         beta_fast=float(section.get("beta_fast", 32.0)),
         beta_slow=float(section.get("beta_slow", 1.0)),
-        # transformers' default where the config gives none.
-        attention_factor=float(
-            section.get("attention_factor") or 0.1 * math.log(factor) + 1.0
-        ),
+        attention_factor=_yarn_attention_factor(section, factor),
         truncate=bool(section.get("truncate", True)),
     )
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's magnitude term: ``0.1 mscale ln(factor) + 1`` (1 at or
+    under a factor of 1)."""
+    return 1.0 if factor <= 1.0 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def _yarn_attention_factor(section: Mapping[str, Any], factor: float) -> float:
+    """What cos and sin are multiplied by, as ``transformers`` infers it:
+    the section's own ``attention_factor``; else, where it gives both
+    ``mscale`` and ``mscale_all_dim`` (the latent-attention lineage, whose
+    softmax scale carries the ``mscale_all_dim`` term squared instead),
+    the ratio of their terms; else ``0.1 ln(factor) + 1``."""
+    if section.get("attention_factor"):
+        return float(section["attention_factor"])
+    mscale, all_dim = section.get("mscale"), section.get("mscale_all_dim")
+    if mscale and all_dim:
+        return yarn_mscale(factor, float(mscale)) / yarn_mscale(factor, float(all_dim))
+    return yarn_mscale(factor)
 
 
 @functools.lru_cache(maxsize=None)

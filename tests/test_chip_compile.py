@@ -280,6 +280,7 @@ GROUP_PROGRAMS = {
     "mellum": ("mellum2-12b-a2.5b-l12", 4, 8192),
     "ling": ("ling-3.0-flash-vl-l7e128", 8, 2048),
     "exaone": ("k-exaone-236b-a23b-l5e16", 8, 8192),
+    "mistral4": ("mistral-small-4-119b-l6e32", 8, 32768),
 }
 # What Mellum's cell has to spare beside its weights, slots and snapshots
 # (peak 15.19 of the 16.91 GB the build sees, less the reference check's
@@ -287,7 +288,12 @@ GROUP_PROGRAMS = {
 # 2.2 GB of state: 5 GB to spare, of which its group of 8 rows of 6,144
 # (a full layer's scores of 64 heads are 537 MB a row) may take half.
 SPARE_BYTES = 1_400_000_000
-SPARE_BY_FAMILY = {"exaone": 2_500_000_000}
+# Mistral-Small-4's cut holds 10.85 GB of weights and 2.42 GB of latent
+# rows: 3.6 GB to spare.  Its group of 8 rows reads each row's blocks from
+# the state in place (no window is gathered), a block of 1,024 keys at a
+# time: the largest temporaries are a block's float32 scores (32 heads x
+# 256 x 1,024: 33.6 MB) and the experts' combine.
+SPARE_BY_FAMILY = {"exaone": 2_500_000_000, "mistral4": 400_000_000}
 
 
 @pytest.mark.parametrize("family", sorted(GROUP_PROGRAMS))
@@ -319,6 +325,8 @@ def test_the_chunks_of_several_slots_compile_as_one_program(one_chip, family, mo
     )
     serving = HybridServing(cfg, None, max_len)
     assert serving.chunks_per_program(chunk) == rows and window == max_len
+    if family == "mistral4":
+        assert serving.chunk_windows(chunk) == (max_len,)  # the one window it is built for
 
     def described(make):
         return jax.tree.map(
@@ -334,9 +342,26 @@ def test_the_chunks_of_several_slots_compile_as_one_program(one_chip, family, mo
         spec((rows, chunk), jnp.int32), ints, ints, ints,
         spec((2,), jnp.uint32), (floats, floats, ints), window,
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
     spare = SPARE_BY_FAMILY.get(family, SPARE_BYTES)
     assert compiled.memory_analysis().temp_size_in_bytes < spare
+    if family == "mistral4":
+        _no_window_sized_temporaries(text, slots=int(engine["max_batch"]), rows=rows, window=window)
+
+
+def _no_window_sized_temporaries(text: str, *, slots: int, rows: int, window: int) -> None:
+    """Nothing of a window's size is made in a program of the latent
+    family: no scores of heads x queries x window, no expansion of a
+    window through ``W_kvb`` (window x heads x 192), and the slots' state
+    (slots, window, 384) is a parameter, scattered into and handed on, but
+    never copied, nor is a group's window of it gathered."""
+    H, s, width = 32, 256, 384
+    assert not re.search(rf"(?:f32|bf16)\[(?:\d+,)?{H},{s},{window}\]", text)
+    assert not re.search(rf"bf16\[(?:\d+,)?{window},{H},(?:192|64|128)\]", text)
+    assert not re.search(rf"bf16\[(?:\d+,)?{window},{H * 192}\]", text)
+    assert not re.search(rf"= bf16\[{slots},{window},{width}\]\S* copy\(", text)
+    assert not re.search(rf"= bf16\[{rows},{window},{width}\]", text)
 
 
 @pytest.mark.parametrize("rows", [1, 2])
@@ -459,3 +484,53 @@ def test_the_verify_chunk_compiles_at_the_published_widths(one_chip, monkeypatch
     assert aux.shape == (len(serving.counter_names),)
     print("verify chunk temporaries", compiled.memory_analysis().temp_size_in_bytes)
     assert compiled.memory_analysis().temp_size_in_bytes < SPARE_BY_FAMILY["exaone"]
+
+
+def test_the_latent_models_decode_chunk_keeps_its_state_in_place(one_chip, monkeypatch):
+    """The decode chunk of mistral-small-4-119b-l6e32 (8 absorbed steps
+    over 16 slots) at the widest decode window, 32,768: the slots' latent
+    rows go in and come out in the layout they are stored in.  A row of
+    320 columns is no whole number of lanes, the chip's default layout of
+    such a leaf puts the POSITIONS minor, and the program then copied every
+    layer's 0.34 GB in and out (2.5 GB of temporaries); rows of 384
+    columns keep the layout the steps work in, and the whole-row
+    contraction never cuts a row into its latent and its rope key."""
+    import json
+    from pathlib import Path
+
+    from generativeaiexamples_tpu.engine.serving_models import HybridServing
+    from generativeaiexamples_tpu.models import hybrid
+    from generativeaiexamples_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "platform_of", lambda mesh: "tpu")
+    configs = Path(__file__).resolve().parents[1] / "benchmarks" / "configs"
+    model = json.loads((configs / "mistral-small-4-119b-l6e32.json").read_text())
+    engine = model["engine"]
+    max_len, b, steps = int(engine["max_len"]), int(engine["max_batch"]), int(engine["decode_chunk_size"])
+    cfg = hybrid.from_hf_config(model, max_len=max_len, kv_dtype=engine["kv_dtype"])
+    assert cfg.latent_width == 384 and cfg.latent_width % 128 == 0
+    serving = HybridServing(cfg, None, max_len)
+
+    def described(make):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            jax.eval_shape(make),
+        )
+
+    spec = _spec(one_chip)
+    ints, floats = spec((b,), jnp.int32), spec((b,), jnp.float32)
+    compiled = serving.make_decode_chunk().lower(
+        described(lambda: hybrid.init_params(cfg, jax.random.PRNGKey(0))),
+        described(lambda: hybrid.init_state(cfg, b, max_len)),
+        ints, ints, spec((2,), jnp.uint32), floats, floats, ints, steps, max_len,
+        spec((b,), jnp.bool_),
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text  # the grouped expert products
+    assert not re.search(rf"= bf16\[{b},{max_len},384\]\S* copy\(", text)
+    assert not re.search(rf"= bf16\[{b},{max_len},(?:256|64|320)\]", text)  # no row cut in two
+    _, toks, aux = compiled.out_info
+    assert toks.shape == (steps, b) and aux.shape == (len(serving.counter_names),)
+    print("latent decode chunk temporaries", compiled.memory_analysis().temp_size_in_bytes)
+    assert compiled.memory_analysis().temp_size_in_bytes < 400_000_000
+

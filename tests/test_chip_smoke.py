@@ -284,12 +284,52 @@ def test_hybrid_phase_rehearsal_of_the_drafting_model_and_its_controls(control, 
         assert line["p50"] > 0.05
 
 
+MISTRAL4_TINY_LIMITS = {"p10": 1e-3, "p50": 1e-3, "p90": 1e-3, "decode_p50": 1e-3}
+
+
+@pytest.mark.parametrize(
+    "control", ["", "no_attn_scale", "plain_rope", "no_mscale", "no_q_norm", "w8a8_mlp"])
+def test_hybrid_phase_rehearsal_of_the_latent_model_and_its_controls(control, capsys, monkeypatch, tmp_path):
+    """``--hybrid --model mistral4`` at the tiny size, in process: a prompt
+    of 75 tokens over an original context of 32 (``a(p)`` takes three
+    values, YaRN's ramp is crossed), chunks of 16 through the chunk
+    programs' in-place block attention (the last padded), its last 8
+    positions through the absorbed decode step over the slots' state, by
+    the benchmark's own comparison.  The limits are the
+    configuration's, set for the chip's size and precision; float32 at
+    this size reads 1e-6, so the rehearsal holds it to limits of its own,
+    which the sound run is far inside and each control leaves."""
+    config = json.loads(open(os.path.join(REPO, chip_smoke.MISTRAL4_CONFIG)).read())
+    config["reference"]["logit_share_limits"] = MISTRAL4_TINY_LIMITS
+    tiny = tmp_path / "config.json"
+    tiny.write_text(json.dumps(config))
+    monkeypatch.setattr(chip_smoke, "MISTRAL4_CONFIG", str(tiny))
+    # A sound run inside the limits and a control outside them both return.
+    chip_smoke.child_hybrid(0, chip_smoke.TINY, control=control, model="mistral4")
+    line = _phases(capsys)["hybrid"]
+    assert line["model"] == "mistral4-tiny" and line["control"] == (control or None)
+    assert line["positions"] == {"prefill": 67, "decode": 8}
+    # The shapes of the scheduler's programs: a chunk beside a pad row over
+    # the whole slot (256 rows here), a decode step over both slots.
+    assert "attn_latent b=2 s=16 t=256" in line["kernel_paths"]
+    assert "attn_latent_decode b=2 t=256" in line["kernel_paths"]
+    assert line["within_limits"] == (not control)
+    if control == "no_attn_scale":  # a(p) is 1 below the original context: the lowest tenth is sound
+        assert line["p10"] < 1e-4 and line["p50"] > 1e-2 and line["decode_p50"] > 1e-2
+    elif control == "w8a8_mlp":  # the precision: every position moves, by little
+        assert 1e-3 < line["p10"] < 0.2
+    elif control:
+        assert line["p50"] > 0.05
+
+
 def test_hybrid_phase_names_a_child_for_every_model_and_control():
     assert sorted(n for n in chip_smoke.CHILDREN if n.startswith("hybrid")) == [
         "hybrid_exaone_moe", "hybrid_exaone_moe_no_qk_norm", "hybrid_exaone_moe_no_window",
         "hybrid_exaone_moe_rope_on_full", "hybrid_exaone_moe_stale_reject", "hybrid_exaone_moe_w8a8_mlp",
         "hybrid_ling", "hybrid_ling_w8a8_mlp", "hybrid_mellum", "hybrid_mellum_no_window",
         "hybrid_mellum_no_yarn", "hybrid_mellum_w8a8_mlp",
+        "hybrid_mistral4", "hybrid_mistral4_no_attn_scale", "hybrid_mistral4_no_mscale",
+        "hybrid_mistral4_no_q_norm", "hybrid_mistral4_plain_rope", "hybrid_mistral4_w8a8_mlp",
     ]
     with pytest.raises(chip_smoke.SmokeFailure, match="has no control 'no_yarn'"):
         chip_smoke.run(0, chip_smoke.TINY, expect="cpu", hybrid=("ling", "no_yarn"))

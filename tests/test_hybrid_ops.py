@@ -214,3 +214,116 @@ def test_balanced_bias_evens_the_experts_load():
 
     assert worst_load(jnp.zeros((E,), F32)) > 1.5
     assert worst_load(bias) < 1.25  # 512 rows an expert: the fullest of 32 reads ~1.1 by chance
+
+
+# -- the latent path of the ``mistral4`` family ----------------------------------
+
+
+@pytest.mark.parametrize("block", [8, 16, 48, 64, 4096])
+def test_mla_blocks_with_an_online_softmax_match_the_whole_window(block):
+    """``attend_blocks`` over blocks that divide the window of 96 (a size
+    that does not is cut to their common divisor) against
+    ``attend_expanded``: the same sums a block at a time."""
+    r = np.random.RandomState(2)
+    b, s, T, H, rank, nope, rope, vd = 2, 12, 96, 3, 32, 16, 8, 16
+    q_nope = jnp.asarray(r.randn(b, s, H, nope), F32)
+    q_rope = jnp.asarray(r.randn(b, s, H, rope), F32)
+    latent = jnp.asarray(r.randn(b, T, rank + rope), F32)
+    w_kvb = jnp.asarray(r.randn(rank, H * (nope + vd)) * rank**-0.5, F32)
+    q_pos = jnp.asarray([70, 5])[:, None] + jnp.arange(s)[None, :]
+    kw = dict(rank=rank, nope=nope, v_dim=vd)
+    want = mla.attend_expanded(q_nope, q_rope, latent, w_kvb, q_pos, **kw)
+    lengths = q_pos[:, -1] + 1
+    got = mla.attend_blocks(q_nope, q_rope, latent, w_kvb, q_pos, lengths, block=block, **kw)
+    np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-5)
+    # Only the whole blocks up to the longest row are read: rows past
+    # them may hold anything.
+    cut = int(mla.rows_in_blocks(lengths, T, block).max())
+    assert cut == min(-(-82 // np.gcd(T, block)) * np.gcd(T, block), T)
+    spoiled = latent.at[:, cut:].set(jnp.nan)
+    got2 = mla.attend_blocks(q_nope, q_rope, spoiled, w_kvb, q_pos, lengths, block=block, **kw)
+    np.testing.assert_array_equal(np.asarray(got2), np.asarray(got))
+    # A row that holds nothing reads nothing and gives zeros, not 0 / 0.
+    none = mla.attend_blocks(q_nope, q_rope, latent, w_kvb, q_pos, jnp.zeros((b,), jnp.int32), block=block, **kw)
+    assert not np.asarray(none).any()
+
+
+def test_rope_interleaved_takes_yarn_frequencies_from_a_spec():
+    from generativeaiexamples_tpu.models import mistral4_reference
+    from generativeaiexamples_tpu.ops.rope import RopeSpec
+
+    spec = RopeSpec(theta=10000.0, rope_type="yarn", factor=8.0, original_max=32, attention_factor=1.0)
+    x = jnp.asarray(np.random.RandomState(4).randn(1, 90, 2, 8), F32)
+    pos = jnp.arange(90, dtype=jnp.int32)[None]
+    y = mla.rope_interleaved(x, pos, 10000.0, spec)
+    inv = mistral4_reference.yarn_frequencies(8, 10000.0, 8.0, 32, 32.0, 1.0)
+    np.testing.assert_allclose(y[0], mistral4_reference._rope_pairs(x[0], inv), rtol=1e-5, atol=1e-5)
+    # Past the original context the blend departs from the plain frequencies.
+    plain = mla.rope_interleaved(x, pos, 10000.0)
+    assert float(jnp.abs(y - plain)[0, 40:].max()) > 0.1
+    # An attention factor multiplies cos and sin.
+    scaled = mla.rope_interleaved(x, pos, 10000.0, RopeSpec(**{**spec.__dict__, "attention_factor": 1.5}))
+    np.testing.assert_allclose(scaled, 1.5 * y, rtol=1e-5, atol=1e-6)
+
+
+def _lings_mixer_as_it_was(h, lp, st, pos, valid, n_valid, cfg, window, apart):
+    """``models/hybrid.py::_mla_mixer`` as of the commit before the
+    ``mistral4`` family, operation for operation."""
+    import functools
+
+    from generativeaiexamples_tpu.models import hybrid
+    from generativeaiexamples_tpu.models.llama import rms_norm
+
+    b, s, _ = h.shape
+    H, rank = cfg.n_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    q = jnp.dot(h, lp["w_q"]).reshape(b, s, H, nope + rope)
+    q_nope = q[..., :nope]
+    q_rope = mla.rope_interleaved(q[..., nope:], pos, cfg.rope_theta)
+    ckr = jnp.dot(h, lp["w_kva"])
+    c = rms_norm(ckr[..., :rank], lp["kv_norm"], cfg.norm_eps)
+    k_rope = mla.rope_interleaved(ckr[..., rank:], pos, cfg.rope_theta)
+    new = jnp.concatenate([c, k_rope], axis=-1).astype(st["latent"].dtype)
+    T = st["latent"].shape[1]
+    at = jnp.where(valid, pos, T)
+    latent = st["latent"].at[jnp.arange(b)[:, None], at].set(new, mode="drop")
+    attend = functools.partial(
+        mla.attend_absorbed if s == 1 else mla.attend_expanded,
+        w_kvb=lp["w_kvb"], rank=rank, nope=nope, v_dim=vd,
+    )
+    o = hybrid._attend(
+        lambda qn, qr, lat, p: attend(qn, qr, lat, q_pos=p),
+        n_valid, apart, q_nope, q_rope, latent[:, :window], pos,
+    )
+    gate = jax.nn.sigmoid(jnp.dot(h, lp["w_gate"], preferred_element_type=F32))
+    o = (o.astype(F32) * gate[..., None]).astype(h.dtype)
+    out = jnp.dot(o.reshape(b, s, H * vd), lp["w_o"])
+    return out, {"latent": latent}
+
+
+@pytest.mark.parametrize("s, apart", [(24, False), (24, True), (1, False)], ids=["chunk", "rows_apart", "decode"])
+def test_lings_latent_layer_is_bit_for_bit_what_it_was(s, apart):
+    """The ``mla`` kind serves Ling's form and the ``mistral4`` family's
+    from one function; for Ling's configuration it computes what it
+    computed, to the last bit (operation by operation, outside ``jit``),
+    and its parameters and counters are the ones it had."""
+    from generativeaiexamples_tpu.models import hybrid
+
+    cfg = hybrid.PRESETS["ling-tiny"]()
+    layer = cfg.layers_of("mla")[0]
+    lp = hybrid.init_params(cfg, jax.random.PRNGKey(0))["layers"][layer]
+    assert list(lp)[:8] == ["attn_norm", "mlp_norm", "w_q", "w_kva", "kv_norm", "w_kvb", "w_gate", "w_o"]
+    assert cfg.row_counters == () and cfg.n_counters == len(moe.COUNTERS) and not cfg.rows_only
+    r = np.random.RandomState(5)
+    b, T = 3, 64
+    h = jnp.asarray(r.randn(b, s, cfg.d_model), F32)
+    st = {"latent": jnp.asarray(r.randn(b, T, cfg.latent_width), F32)}
+    start = jnp.asarray([30, 0, 7], jnp.int32)
+    n_valid = jnp.asarray([s, max(s - 5, 1), 0 if apart else 1], jnp.int32)
+    steps = jnp.arange(s, dtype=jnp.int32)[None, :]
+    pos, valid = start[:, None] + steps, steps < n_valid[:, None]
+    want, want_st = _lings_mixer_as_it_was(h, lp, st, pos, valid, n_valid, cfg, T, apart)
+    got, got_st, read = hybrid._mla_mixer(h, lp, st, pos, valid, n_valid, cfg, T, apart)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(got_st["latent"]), np.asarray(want_st["latent"]))
+    assert set(got_st) == {"latent"}

@@ -395,7 +395,7 @@ def test_rows_of_a_group_get_what_each_gets_alone(rows_case, n_rows):
 
 @pytest.mark.parametrize("config, chunks", [
     ("mellum2-12b-a2.5b-l12", 4), ("ling-3.0-flash-vl-l7e128", 8),
-    ("mistral-7b", 1), ("mixtral-8x7b-l4", 2),
+    ("mistral-7b", 1), ("mixtral-8x7b-l4", 2), ("mistral-small-4-119b-l6e32", 8),
 ])
 def test_how_many_chunks_share_a_program_follows_from_the_rows_an_expert_sees(config, chunks):
     """256 tokens x 8 choices over 64 experts are 32 rows an expert: 4
@@ -541,3 +541,128 @@ def runtime_kernel_paths() -> dict:
     from generativeaiexamples_tpu.utils.jax_runtime import runtime_report
 
     return runtime_report()["kernel_paths"]
+
+
+# -- the ``mistral4`` family: latent rows are the whole state ------------------------------
+
+
+@pytest.fixture(scope="module")
+def mistral4():
+    # What engine.server.main() builds for --model mistral4-tiny.
+    cfg = hybrid.PRESETS[resolve_model_preset("mistral4-tiny")]()
+    s = Scheduler(
+        cfg, None, max_batch=4, max_len=256, decode_chunk_size=4, seed=7,
+        prefill_chunk_tokens=CHUNK, prefix_cache="shared",
+    )
+    s.start()
+    yield s
+    s.stop()
+
+
+def _mistral4_gap(scheduler, prompt, out, pad_to=192):
+    """``_mellum_gap`` against ``mistral4_reference``."""
+    from generativeaiexamples_tpu.models import mistral4_reference
+
+    seq = list(prompt) + list(out)
+    lg = np.asarray(mistral4_reference.all_logits(
+        scheduler.params, scheduler.cfg, seq + [0] * (pad_to - len(seq))))
+    rows = lg[len(prompt) - 1 : len(seq) - 1]
+    return float((rows.max(-1) - rows[np.arange(len(out)), out]).max())
+
+
+def test_mistral4_is_served_with_no_snapshot_and_its_hits_are_cut_at_any_row(mistral4):
+    """A model whose state is rows alone takes the path the llama models'
+    hits take: no ``StateSnapshots``, a hit cut at row 70 of chunks of 32
+    (no multiple of the chunk), its suffix prefilled from there; one
+    window for the chunk programs of every size."""
+    assert mistral4.model.cut_anywhere and mistral4._snapshots is None
+    assert mistral4._chunk_rows == 4 and mistral4._chunk_windows == (256,)
+    snap0 = mistral4.stats.snapshot()
+    assert snap0["state_bytes_full"] == 4 * 3 * 256 * 128 * 4 and snap0["state_bytes_window"] == 0
+    cold = [_prompt(41, 20), _prompt(42, 31)]  # within a chunk: one cold batch
+    for p, o in zip(cold, _generate(mistral4, cold)):
+        assert len(o) == 6 and _mistral4_gap(mistral4, p, o) <= GAP
+    first = _prompt(43, 100)  # past the tiny original context of 32 three times
+    before = mistral4.stats.snapshot()
+    (out,) = _generate(mistral4, [first])
+    mid = mistral4.stats.snapshot()
+    assert mid["prefill_chunks"] - before["prefill_chunks"] == 4
+    assert _mistral4_gap(mistral4, first, out) <= GAP
+    again = first[:70] + _prompt(44, 45)
+    (hit,) = _generate(mistral4, [again])
+    after = mistral4.stats.snapshot()
+    assert after["shared_prefix_hits"] - mid["shared_prefix_hits"] == 1
+    assert after["prefix_tokens_matched"] - mid["prefix_tokens_matched"] == 70
+    assert after["prefix_tokens_reused"] - mid["prefix_tokens_reused"] == 70  # not 64
+    assert after["prefill_chunks"] - mid["prefill_chunks"] == 2  # 45 tokens from row 70
+    assert _mistral4_gap(mistral4, again, hit) <= GAP
+    for key in ("state_snapshots_saved", "state_snapshots_restored", "state_snapshots_evicted",
+                "state_snapshot_bytes"):
+        assert after[key] == 0, key
+    # The rows the latent layers read came out with the tokens: a chunk
+    # and a decode step read whole blocks up to what a row holds, and a
+    # slot that does not decode is not read at all.
+    for phase in ("prefill", "decode"):
+        assert 0 < after[f"attn_rows_read_latent_{phase}"] < after[f"attn_rows_dense_latent_{phase}"]
+    assert 0 < after["moe_choices_local"] < after["moe_choices_routed"]  # 8 of 32 experts
+
+
+def test_mistral4_prompts_warm_side_by_side_and_metrics_are_exported(mistral4):
+    prompts = [_prompt(45, 120), _prompt(46, 90), _prompt(47, 60), _prompt(48, 150)]
+    before = mistral4.stats.snapshot()
+    outs = _generate(mistral4, prompts, n=4)
+    after = mistral4.stats.snapshot()
+    for p, o in zip(prompts, outs):
+        assert _mistral4_gap(mistral4, p, o) <= GAP
+    chunks = after["prefill_chunks"] - before["prefill_chunks"]
+    programs = after["prefill_chunk_programs"] - before["prefill_chunk_programs"]
+    assert chunks == 4 + 3 + 2 + 5 and programs < chunks  # some went out together
+    for seed in (49, 50, 51):  # every slot is reused: stale rows past a length are never read
+        p = _prompt(seed, 70)
+        (o,) = _generate(mistral4, [p], n=3)
+        assert _mistral4_gap(mistral4, p, o) <= GAP
+    from generativeaiexamples_tpu.engine.server import create_engine_app
+    from generativeaiexamples_tpu.engine.tokenizer import ByteTokenizer
+    from generativeaiexamples_tpu.utils.jax_runtime import runtime_report
+
+    app = create_engine_app(mistral4, ByteTokenizer(), model_name="mistral4-tiny")
+    loop = asyncio.new_event_loop()
+    client = TestClient(TestServer(app), loop=loop)
+    loop.run_until_complete(client.start_server())
+
+    async def go():
+        return await (await client.get("/metrics")).text()
+
+    try:
+        metrics = loop.run_until_complete(go())
+    finally:
+        loop.run_until_complete(client.close())
+        loop.close()
+    for name in ("engine_attn_rows_read_latent_decode_total", "engine_attn_rows_dense_latent_decode_total",
+                 "engine_attn_rows_read_latent_prefill_total", "engine_attn_rows_dense_latent_prefill_total",
+                 "engine_state_bytes_full", "engine_moe_experts_touched_total"):
+        assert f"\n{name} " in metrics, name
+    assert "engine_attn_rows_read_full_decode_total" not in metrics  # the GQA kinds' four are theirs
+    paths = runtime_report()["kernel_paths"]
+    assert any(site.startswith("attn_latent b=") for site in paths)
+    assert any(site.startswith("attn_latent_decode b=4") for site in paths)
+    ticks = mistral4.tick_records(64)
+    assert ticks and all("kv_bucket" in r for r in ticks)
+
+
+def test_check_supported_keeps_refusing_what_it_refuses_for_a_rows_only_model():
+    from generativeaiexamples_tpu.engine.serving_models import serving_model
+
+    model = serving_model(hybrid.PRESETS["mistral4-tiny"](), None, 128)
+    model.check_supported()
+    with pytest.raises(ValueError, match="paged layout"):
+        model.check_supported(kv_layout="paged")
+    with pytest.raises(ValueError, match="draft model and n-gram"):
+        model.check_supported(spec_mode="ngram")
+    with pytest.raises(ValueError, match="int8 weights"):
+        model.prepare_params(None, quantize=True, matmul_kernel="xla", seed=0)
+    with pytest.raises(ValueError, match="int8 state"):
+        serving_model(hybrid.from_hf_config(hybrid.MISTRAL4_TINY, max_len=128, kv_dtype="int8"), None, 128)
+    # The three families with state as of the last token keep their snapshots.
+    for preset in ("ling-tiny", "mellum-tiny", "exaone_moe-tiny"):
+        assert not serving_model(hybrid.PRESETS[preset](), None, 128).cut_anywhere, preset
