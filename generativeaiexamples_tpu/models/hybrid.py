@@ -111,6 +111,12 @@ ATTN_COUNTERS = ("read_window", "read_full", "dense_window", "dense_full")
 # read with every row's whole window (``kv_bucket``) read.  A
 # ``LatentConfig`` model returns them after ``moe.COUNTERS``.
 LATENT_COUNTERS = ("read_latent", "dense_latent")
+# Slots whose recurrent state the KDA layers' decode steps read (the rows
+# that decode where the step is ``kda.kda_step_rows``, every slot where
+# it is XLA's), and slots x KDA layers.  A prefill call adds to neither:
+# a chunk program works on its own rows' state.  A model with KDA layers
+# and no GQA layer returns them after ``moe.COUNTERS``.
+STATE_COUNTERS = ("read_state", "dense_state")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -205,8 +211,11 @@ class HybridConfig:
     def row_counters(self) -> tuple[str, ...]:
         """Names of the counters of rows read that ``forward`` returns
         after ``moe.COUNTERS``: a model with GQA layers ``ATTN_COUNTERS``,
-        a ``LatentConfig`` model ``LATENT_COUNTERS``, Ling's none."""
-        return ATTN_COUNTERS if self.has_attn_counters else ()
+        a ``LatentConfig`` model ``LATENT_COUNTERS``, one with KDA layers
+        (Ling) ``STATE_COUNTERS``."""
+        if self.has_attn_counters:
+            return ATTN_COUNTERS
+        return STATE_COUNTERS if self.layers_of("kda") else ()
 
     @property
     def rows_only(self) -> bool:
@@ -896,7 +905,10 @@ def _attend(attend, n_valid, apart: bool, *rows):
     return jax.lax.map(one, (n_valid, *rows))
 
 
-def _kda_mixer(h, lp, st, valid, n_valid, cfg: HybridConfig):
+def _kda_mixer(h, lp, st, valid, n_valid, cfg: HybridConfig, mesh=None):
+    """A ``kda`` layer.  Returns (output, state, the slots whose state a
+    decode step read and the slots there are, in the order of
+    ``STATE_COUNTERS``; zeros from a prefill call)."""
     b, s, _ = h.shape
     H, K = cfg.n_heads, cfg.kda_head_dim
     with jax.named_scope("layer/kda/proj"):
@@ -917,15 +929,26 @@ def _kda_mixer(h, lp, st, valid, n_valid, cfg: HybridConfig):
         ) * on[..., None]
         beta = jax.nn.sigmoid(write) * on
     if s == 1:
-        o, S = kda.kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], st["S"])
+        step = (q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0], st["S"])
+        if record(
+            f"kda_step b={b} h={H}",
+            kda.use_step_kernel(state_dtype=st["S"].dtype, k_dim=K, v_dim=K, heads=H, mesh=mesh),
+        ):
+            o, S = kda.kda_step_rows(*step, valid[:, 0])
+            read = (jnp.sum(n_valid), b)
+        else:
+            o, S = kda.kda_step(*step)
+            read = (b, b)
         o = o[:, None]
     else:
         o, S = kda.kda_chunked(q, k, v, g, beta, st["S"])
+        read = (0, 0)
     with jax.named_scope("layer/kda/out"):
         o = rms_norm(o, lp["o_norm"].astype(F32), cfg.norm_eps)
         o = o * jax.nn.sigmoid(out_gate.astype(F32)).reshape(b, s, H, K)
         out = jnp.dot(o.reshape(b, s, H * K).astype(h.dtype), lp["w_o"])
-    return out, {"S": S, "conv": tail.astype(st["conv"].dtype)}
+    state = {"S": S, "conv": tail.astype(st["conv"].dtype)}
+    return out, state, jnp.stack(read).astype(jnp.int32)
 
 
 def _mla_mixer(h, lp, st, pos, valid, n_valid, cfg: HybridConfig, window: int, apart: bool):
@@ -1132,10 +1155,12 @@ def _mix(
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     read = 0
     if mixer == "kda":
-        y, st = _kda_mixer(h, lp, st, valid, n_valid, cfg)
+        y, st, slots = _kda_mixer(h, lp, st, valid, n_valid, cfg, mesh)
+        if cfg.row_counters == STATE_COUNTERS:
+            read = slots
     elif mixer == "mla":
         y, st, rows = _mla_mixer(h, lp, st, pos, valid, n_valid, cfg, window, apart)
-        if cfg.row_counters:
+        if cfg.row_counters == LATENT_COUNTERS:
             read = rows
     else:
         y, st, read = _gqa_mixer(

@@ -8,9 +8,14 @@ strength ``beta`` in (0, 1), and L2-normalised ``q`` (scaled) and ``k``:
     S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
     o_t = S_t^T q_t
 
-Two forms, both plain XLA (no Pallas kernel yet: PERF.md section 7):
+Three forms:
 
-* :func:`kda_step` — one token a row, the decode step;
+* :func:`kda_step` — one token a row, the decode step, in plain XLA: two
+  fusions that between them pass three times over every slot's state;
+* :func:`kda_step_rows` — the same step as a Pallas TPU kernel that reads
+  and writes the state of the rows that decode, once, in place, and
+  leaves the other slots' state in HBM (:func:`use_step_kernel` is the
+  gate, ``kda_step`` the twin it falls back to; docs/kernels.md);
 * :func:`kda_chunked` — a ``lax.scan`` over sub-chunks of ``SUB`` tokens,
   each solved in closed form (the WY form of the delta rule).  Inside a
   sub-chunk keys are scaled by ``exp(-G)`` and queries by ``exp(+G)``,
@@ -30,8 +35,16 @@ projections) and the state is read again by every later token.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from generativeaiexamples_tpu.ops.decode_attention import _interpret_mode
+from generativeaiexamples_tpu.ops.dispatch import one_device, platform_of
+from generativeaiexamples_tpu.ops.qmm import _VMEM_BUDGET_BYTES
 
 SUB = 16
 F32 = jnp.float32
@@ -88,6 +101,132 @@ def kda_step(q, k, v, g, beta, state):
     state = decayed + k[..., None] * u[..., None, :]
     o = jnp.einsum("bhkv,bhk->bhv", state, q, precision=HI)
     return o, state
+
+
+def _heads_a_step(h: int, k: int, v: int) -> int:
+    """Heads of a row whose state rides in one grid step: the most that
+    divide ``h`` with the block's four buffers (in and out, each double)
+    inside half the VMEM budget.  At H 32, K = V 128 that is the whole
+    row, 2 MB in and 2 MB out a step: a grid step costs ~0.35 us whether
+    it copies or not, and the 20 slots of 32 that do not decode each cost
+    one."""
+    fits = [d for d in range(1, h + 1) if h % d == 0 and 4 * d * k * v * 4 <= _VMEM_BUDGET_BYTES // 2]
+    return max(fits, default=0)
+
+
+def use_step_kernel(*, state_dtype, k_dim: int, v_dim: int, heads: int, mesh=None) -> bool:
+    """The gate of :func:`kda_step_rows`, from what a traced step can
+    observe: a float32 state whose heads are whole lane tiles, on one TPU
+    device.  Everything else is :func:`kda_step`'s."""
+    if not _interpret_mode() and (platform_of(mesh) != "tpu" or not one_device(mesh)):
+        return False
+    return (
+        jnp.dtype(state_dtype) == F32
+        and k_dim % 128 == 0
+        and v_dim % 128 == 0
+        and _heads_a_step(heads, k_dim, v_dim) > 0
+    )
+
+
+def live_slots(live):
+    """(b,) bool -> (the slots that are live, in order, then zeros; how
+    many): the list the step kernel walks."""
+    b = live.shape[0]
+    slots = jnp.arange(b, dtype=jnp.int32)
+    rank = jnp.cumsum(live.astype(jnp.int32)) - 1
+    at = live[None, :] & (rank[None, :] == slots[:, None])  # (place, slot)
+    return jnp.sum(jnp.where(at, slots[None, :], 0), axis=1), jnp.sum(live.astype(jnp.int32))
+
+
+def _step_kernel(idx_ref, n_ref, cols_ref, v_ref, s_in, o_ref, s_out, *, hb: int):
+    """One listed row's ``hb`` heads.  cols_ref (1, 1, K, 4 * hb): ``q``,
+    ``k``, ``exp(g)`` and ``beta k`` of each head as columns, so that a
+    head's is a lane slice that broadcasts along a (K, V) tile's lanes;
+    v_ref, o_ref (1, 1, hb, V); s_in, s_out (1, hb, K, V), one buffer in
+    HBM.  A listed row past the count has the block indices of the step
+    before it: nothing is copied for it and it computes nothing."""
+    del idx_ref
+    i, n = pl.program_id(0), n_ref[0]
+
+    @pl.when(i < n)
+    def _live():
+        for h in range(hb):
+            q, k, a, kb = (cols_ref[0, 0, :, c * hb + h : c * hb + h + 1] for c in range(4))
+            decayed = s_in[0, h] * a
+            read = jnp.sum(decayed * k, axis=0, keepdims=True)  # (1, V)
+            new = decayed + kb * (v_ref[0, 0, h : h + 1, :] - read)
+            s_out[0, h] = new
+            o_ref[0, 0, h : h + 1, :] = jnp.sum(new * q, axis=0, keepdims=True)
+
+    # No live row: the one block the index maps name is written back as
+    # it was read.
+    @pl.when((n == 0) & (i == 0) & (pl.program_id(1) == 0))
+    def _none():
+        s_out[...] = s_in[...]
+
+
+@functools.partial(jax.jit, static_argnames=("hb", "interpret"))
+def _step_rows(idx, n, cols, v, state, *, hb: int, interpret: bool):
+    b, H, K, V = state.shape
+    groups = H // hb
+
+    def at(i, j, idx, n):
+        """Block indices of grid step (listed row i, head group j): past
+        the count, those of the last live row's last group."""
+        row = idx[jnp.minimum(i, jnp.maximum(n[0] - 1, 0))]
+        return row, jnp.where(i < n[0], j, groups - 1)
+
+    vec = pl.BlockSpec((1, 1, hb, V), lambda i, j, idx, n: (*at(i, j, idx, n), 0, 0))
+    tile = pl.BlockSpec((1, hb, K, V), lambda i, j, idx, n: (*at(i, j, idx, n), 0, 0))
+    o, state = pl.pallas_call(
+        functools.partial(_step_kernel, hb=hb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, groups),
+            in_specs=[
+                pl.BlockSpec((1, 1, K, 4 * hb), lambda i, j, idx, n: (*at(i, j, idx, n), 0, 0)),
+                vec,
+                tile,
+            ],
+            out_specs=[vec, tile],
+        ),
+        out_shape=[
+            jax.ShapeDtypeStruct((b, groups, hb, V), F32),
+            jax.ShapeDtypeStruct(state.shape, F32),
+        ],
+        # The state leaf in place (operands count the two prefetched).
+        input_output_aliases={4: 1},
+        compiler_params=pltpu.CompilerParams(
+            # In order: a row past the count leans on the blocks of the
+            # step before it.
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_BUDGET_BYTES,
+        ),
+        interpret=interpret,
+        name="kda_step_rows",
+    )(idx, n.reshape(1), cols, v.reshape(b, groups, hb, V), state)
+    return o.reshape(b, H, V), state
+
+
+@jax.named_scope("layer/kda/scan")
+def kda_step_rows(q, k, v, g, beta, state, live, *, interpret=None):
+    """:func:`kda_step` for the rows of ``live`` (b,) bool, as a Pallas
+    kernel: each live row's state is read once, updated in VMEM and
+    written once, into the buffer it came from; a row that is not live
+    is neither read nor written, and its output is exact zeros.  Float32
+    throughout, the reductions on the VPU.  Returns (o (b, H, V) f32,
+    state)."""
+    if interpret is None:
+        interpret = _interpret_mode()
+    b, H, K, V = state.shape
+    hb = _heads_a_step(H, K, V)
+    q, k, v, g, beta = (a.astype(F32) for a in (q, k, v, g, beta))
+    # (4, b, H, K) -> (b, H / hb, K, 4 * hb): 64 KB a row at H 32, K 128.
+    cols = jnp.stack([q, k, jnp.exp(g), beta[..., None] * k])
+    cols = cols.reshape(4, b, H // hb, hb, K).transpose(1, 2, 4, 0, 3).reshape(b, H // hb, K, 4 * hb)
+    idx, n = live_slots(live)
+    o, state = _step_rows(idx, n, cols, v, state, hb=hb, interpret=interpret)
+    return jnp.where(live[:, None, None], o, 0.0), state
 
 
 def _sub_chunk(state, q, k, v, g, beta):

@@ -534,3 +534,90 @@ def test_the_latent_models_decode_chunk_keeps_its_state_in_place(one_chip, monke
     print("latent decode chunk temporaries", compiled.memory_analysis().temp_size_in_bytes)
     assert compiled.memory_analysis().temp_size_in_bytes < 400_000_000
 
+
+
+# Ling's KDA layers (``ops/kda.py::kda_step_rows``): 32 slots of 32 heads,
+# K = V 128, a float32 state of 67.1 MB a layer.
+KDA_STATE = (32, 32, 128, 128)
+
+
+def test_kda_step_kernel_compiles_at_the_cells_widths(one_chip, monkeypatch):
+    from generativeaiexamples_tpu.ops import kda
+
+    S = _spec(one_chip)
+    b, H, K, V = KDA_STATE
+    monkeypatch.setattr(kda, "platform_of", lambda mesh: "tpu")
+    assert kda.use_step_kernel(state_dtype=jnp.float32, k_dim=K, v_dim=V, heads=H)
+    # A whole row a grid step: in and out, each double, a quarter of the
+    # scoped limit handed to Mosaic.
+    hb = kda._heads_a_step(H, K, V)
+    assert hb == H and 4 * hb * K * V * 4 <= qmm._VMEM_BUDGET_BYTES // 4
+
+    def step(q, k, v, g, beta, state, live):
+        return kda.kda_step_rows(q, k, v, g, beta, state, live, interpret=False)
+
+    vec = S((b, H, K), jnp.float32)
+    compiled = jax.jit(step, donate_argnums=(5,)).lower(
+        vec, vec, vec, vec, S((b, H), jnp.float32), S(KDA_STATE, jnp.float32), S((b,), jnp.bool_)
+    ).compile()
+    _state_is_the_kernels_alone(compiled.as_text(), calls=1)
+    # The leaf goes in and comes out as one buffer.
+    assert compiled.memory_analysis().alias_size_in_bytes == 4 * b * H * K * V
+    assert compiled.memory_analysis().temp_size_in_bytes < 4_000_000
+
+
+def _state_is_the_kernels_alone(text: str, *, calls: int) -> None:
+    """Every operation of a compiled program that makes an array of the
+    KDA state's shape is the step kernel, a parameter or a renaming of one:
+    no copy, and no XLA fusion that walks the leaf."""
+    shape = ",".join(map(str, KDA_STATE))
+    made = re.findall(rf"= (?:\([^=]*)?f32\[{shape}\]\S*(?:, [^=]*\))? ([\w-]+)\(", text)
+    assert made.count("custom-call") >= calls, made
+    assert set(made) <= {"custom-call", "parameter", "get-tuple-element", "bitcast", "tuple", "while"}, made
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*kda_step_rows", text)) >= calls
+
+
+def test_lings_decode_chunk_touches_the_kda_state_by_the_kernel_alone(one_chip, monkeypatch):
+    """The decode chunk of ling-3.0-flash-vl-l7e128 (8 steps over 32
+    slots) at the widest decode window: each of the six KDA layers' state
+    leaves ``f32[32,32,128,128]`` is read and written by the step kernel
+    in place; XLA's twin made two fusions over it and three passes, every
+    slot's (PERF.md, PR 39)."""
+    import json
+    from pathlib import Path
+
+    from generativeaiexamples_tpu.engine.serving_models import HybridServing
+    from generativeaiexamples_tpu.models import hybrid
+    from generativeaiexamples_tpu.ops import kda, moe
+
+    monkeypatch.setattr(moe, "platform_of", lambda mesh: "tpu")
+    monkeypatch.setattr(kda, "platform_of", lambda mesh: "tpu")
+    configs = Path(__file__).resolve().parents[1] / "benchmarks" / "configs"
+    model = json.loads((configs / "ling-3.0-flash-vl-l7e128.json").read_text())
+    engine = model["engine"]
+    max_len, b, steps = int(engine["max_len"]), int(engine["max_batch"]), int(engine["decode_chunk_size"])
+    cfg = hybrid.from_hf_config(model, max_len=max_len, kv_dtype=engine["kv_dtype"])
+    layers = len(cfg.layers_of("kda"))
+    assert (b, cfg.n_heads, cfg.kda_head_dim, cfg.kda_head_dim) == KDA_STATE and layers == 6
+    serving = HybridServing(cfg, None, max_len)
+
+    def described(make):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            jax.eval_shape(make),
+        )
+
+    spec = _spec(one_chip)
+    ints, floats = spec((b,), jnp.int32), spec((b,), jnp.float32)
+    compiled = serving.make_decode_chunk().lower(
+        described(lambda: hybrid.init_params(cfg, jax.random.PRNGKey(0))),
+        described(lambda: hybrid.init_state(cfg, b, max_len)),
+        ints, ints, spec((2,), jnp.uint32), floats, floats, ints, steps, max_len,
+        spec((b,), jnp.bool_),
+    ).compile()
+    text = compiled.as_text()
+    _state_is_the_kernels_alone(text, calls=layers)
+    _, toks, aux = compiled.out_info
+    assert toks.shape == (steps, b) and aux.shape == (len(serving.counter_names),)
+    print("ling decode chunk temporaries", compiled.memory_analysis().temp_size_in_bytes)
+    assert compiled.memory_analysis().temp_size_in_bytes < 600_000_000

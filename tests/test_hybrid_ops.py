@@ -313,7 +313,7 @@ def test_lings_latent_layer_is_bit_for_bit_what_it_was(s, apart):
     layer = cfg.layers_of("mla")[0]
     lp = hybrid.init_params(cfg, jax.random.PRNGKey(0))["layers"][layer]
     assert list(lp)[:8] == ["attn_norm", "mlp_norm", "w_q", "w_kva", "kv_norm", "w_kvb", "w_gate", "w_o"]
-    assert cfg.row_counters == () and cfg.n_counters == len(moe.COUNTERS) and not cfg.rows_only
+    assert cfg.row_counters == hybrid.STATE_COUNTERS and cfg.n_counters == len(moe.COUNTERS) + 2 and not cfg.rows_only
     r = np.random.RandomState(5)
     b, T = 3, 64
     h = jnp.asarray(r.randn(b, s, cfg.d_model), F32)
