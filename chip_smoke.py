@@ -3,7 +3,7 @@
 
     python chip_smoke.py [--seed N]          # one chip (what the driver runs)
     python chip_smoke.py --four-chips        # the two multi-chip paths only
-    python chip_smoke.py --hybrid [--model ling|mellum|exaone_moe|mistral4] [--control NAME]   # phase 5 only
+    python chip_smoke.py --hybrid [--model ling|mellum|exaone_moe|mistral4|zaya] [--control NAME]   # phase 5 only
 
 Drives the system through the entry points a user calls, at llama3-8b's
 published widths with random int8 weights from ``--seed``:
@@ -56,7 +56,21 @@ published widths with random int8 weights from ``--seed``:
    comparison (``benchmarks/arch/mistral4.py``) against the
    configuration's limits; controls ``no_attn_scale`` (``a(p)`` = 1),
    ``plain_rope`` (YaRN off), ``no_mscale`` (``m`` = 1), ``no_q_norm``
-   (the query's latent not normed) and ``w8a8_mlp``.
+   (the query's latent not normed) and ``w8a8_mlp``.  ``--model zaya``:
+   zaya1-8b-l20, compressed convolutional attention in every layer and
+   one expert a token through the ZAYA router: a prompt of 800 tokens in
+   chunks of 256 through the chunk program (``prefill_rows`` on a state of
+   8,192 rows a slot: every chunk after the first takes its convolutions'
+   history and its late values from the slot's tails), its last 16
+   positions through the decode step, by the benchmark's own comparison
+   (``benchmarks/arch/zaya.py``) against the configuration's limits;
+   controls, each the reference without one mechanism: ``no_value_shift``,
+   ``no_qk_mean``, ``no_conv``, ``no_router_average``, ``renormed_top1``
+   (the chosen expert weighted 1, not by its probability) and ``w8a8``
+   (the nearest precision below in every projection of a layer: the
+   experts' products alone, the other families' ``w8a8_mlp``, read inside
+   the sound runs' spread here, since an expert's output enters the stream
+   times its probability).
 
 The parent imports no JAX: the chip belongs to one process at a time, so
 each phase is a child (or the pair engine + chain server) that has
@@ -137,6 +151,13 @@ class Sizes:
     mistral4_prompt: int = 8448
     mistral4_chunk: int = 256
     mistral4_decode: int = 16
+    # ``--model zaya``: a prompt of the cell's reference length in chunks
+    # of ``zaya_chunk``, its last ``zaya_decode`` positions through the
+    # decode step.
+    zaya_model: str = "zaya1-8b-l20"
+    zaya_prompt: int = 800
+    zaya_chunk: int = 256
+    zaya_decode: int = 16
     kv_heads: int = 8  # the preset's own; the tiny rehearsal needs 4 to split
     start_timeout_s: float = 600.0
     request_timeout_s: float = 600.0
@@ -172,6 +193,10 @@ TINY = Sizes(
     mistral4_prompt=75,
     mistral4_chunk=16,
     mistral4_decode=8,
+    zaya_model="zaya-tiny",
+    zaya_prompt=45,
+    zaya_chunk=16,
+    zaya_decode=8,
     kv_heads=4,
     start_timeout_s=240.0,
     request_timeout_s=240.0,
@@ -1297,6 +1322,10 @@ HYBRID_CONTROLS = {
     "mellum": ("w8a8_mlp", "no_window", "no_yarn"),
     "exaone_moe": ("w8a8_mlp", "no_qk_norm", "rope_on_full", "no_window", "stale_reject"),
     "mistral4": ("w8a8_mlp", "no_attn_scale", "plain_rope", "no_mscale", "no_q_norm"),
+    "zaya": (
+        "w8a8", "no_value_shift", "no_qk_mean", "no_conv", "no_router_average",
+        "renormed_top1",
+    ),
 }
 # ``--model exaone_moe`` is held to the limits of its benchmark
 # configuration (``reference.logit_share_limits``; PERF.md section 6,
@@ -1306,6 +1335,9 @@ EXAONE_CONFIG = "benchmarks/configs/k-exaone-236b-a23b-l5e16.json"
 # ``--model mistral4`` likewise (``benchmarks/arch/mistral4.py``; PERF.md
 # section 6, PR 38).
 MISTRAL4_CONFIG = "benchmarks/configs/mistral-small-4-119b-l6e32.json"
+# ``--model zaya`` likewise (``benchmarks/arch/zaya.py``; PERF.md section
+# 6, PR 40).
+ZAYA_CONFIG = "benchmarks/configs/zaya1-8b-l20.json"
 
 
 def hybrid_limits(model: str) -> dict:
@@ -1315,6 +1347,22 @@ def hybrid_limits(model: str) -> dict:
     }
 
 
+def _int8(a, axis):
+    """``a`` rounded to int8's 255 levels, one scale along ``axis``."""
+    import jax.numpy as jnp
+
+    scale = jnp.max(jnp.abs(a), axis=axis, keepdims=True) / 127.0 + 1e-30
+    return jnp.round(a / scale) * scale
+
+
+def _w8a8_project(h, w):
+    """A projection as a W8A8 matmul would compute it: int8 weights, one
+    scale an output channel, and int8 activations, one scale a token."""
+    import jax.numpy as jnp
+
+    return _int8(h, -1) @ _int8(w.astype(jnp.float32), 0)
+
+
 def _w8a8_swiglu():
     """The nearest precision below the configurations': every MLP product
     (dense, shared and routed experts) as a W8A8 matmul would compute it:
@@ -1322,17 +1370,11 @@ def _w8a8_swiglu():
     scale a token (what ``benchmarks/run.py --control`` serves the
     llama-shaped models through)."""
     import jax
-    import jax.numpy as jnp
-
-    def int8(a, axis):
-        scale = jnp.max(jnp.abs(a), axis=axis, keepdims=True) / 127.0 + 1e-30
-        return jnp.round(a / scale) * scale
 
     def w8a8_swiglu(h, w_gu, w_down):
-        gu = int8(h, -1) @ int8(w_gu.astype(jnp.float32), 0)
+        gu = _w8a8_project(h, w_gu)
         half = gu.shape[-1] // 2
-        act = jax.nn.silu(gu[:, :half]) * gu[:, half:]
-        return int8(act, -1) @ int8(w_down.astype(jnp.float32), 0)
+        return _w8a8_project(jax.nn.silu(gu[:, :half]) * gu[:, half:], w_down)
 
     return w8a8_swiglu
 
@@ -1499,7 +1541,89 @@ def child_mistral4(seed: int, sizes: Sizes, control: str = "") -> None:
         raise SmokeFailure(f"logits left the reference: {failed} (limits {limits})")
 
 
+def child_zaya(seed: int, sizes: Sizes, control: str = "") -> None:
+    """``--hybrid --model zaya``: the serving model's chunk program
+    (``prefill_rows``, the chunk beside a pad row on a state of 8,192 rows
+    a slot) and its decode step over that state against the float32
+    reference, by the benchmark's own comparison.  A control changes what
+    the reference computes, one mechanism at a time: the values not
+    shifted, no q-k mean, no convolution (``z = u``), the router without
+    the previous layer's state, the chosen expert weighted 1, every
+    projection of a layer in the nearest precision below; each has to
+    leave a limit."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from generativeaiexamples_tpu.engine.serving_models import serving_model
+    from generativeaiexamples_tpu.models import hybrid
+    from generativeaiexamples_tpu.utils.jax_runtime import (
+        device_report,
+        enable_compile_cache,
+        runtime_report,
+    )
+
+    enable_compile_cache()
+    t0 = time.monotonic()
+    arch = _bench_arch("zaya")
+    with open(os.path.join(ROOT, ZAYA_CONFIG)) as f:
+        limits = json.load(f)["reference"]["logit_share_limits"]
+    arch._CHECK.update(limits=limits, decode=sizes.zaya_decode, chunk=sizes.zaya_chunk)
+    cfg = hybrid.PRESETS[sizes.zaya_model]()
+    pad_to = sizes.zaya_prompt
+    params = serving_model(cfg, None, pad_to).prepare_params(
+        None, quantize=False, matmul_kernel="xla", seed=seed)
+    tokens = np.random.RandomState(seed).randint(1, cfg.vocab_size, size=pad_to).astype(np.int32)
+    reference = arch.zaya_reference
+    patched = {
+        # The nearest precision below in EVERY projection of a layer (the
+        # mixer's two and the expert's three); the experts' alone reads
+        # inside the sound runs' spread here (``w8a8_mlp``: kept for the
+        # reading, no control of the comparison).
+        "w8a8": {"_swiglu": _w8a8_swiglu(), "_project": _w8a8_project},
+        "w8a8_mlp": {"_swiglu": _w8a8_swiglu()},
+        "no_value_shift": {"_shift_values": lambda now, late: jnp.concatenate([now, late], axis=-1)},
+        "no_qk_mean": {"_qk_mean": lambda qp, kp: (jnp.zeros_like(qp), jnp.zeros_like(kp))},
+        "no_conv": {"_conv": lambda u, lp, dims: u},
+        "no_router_average": {"_router_average": lambda rho, prev, gamma: rho},
+        "renormed_top1": {"_top1_weight": lambda p, chosen: chosen.astype(p.dtype)},
+    }.get(control, {})
+    plain = {name: getattr(reference, name) for name in patched}
+    for name, stand_in in patched.items():
+        setattr(reference, name, stand_in)
+    if patched:
+        jax.clear_caches()  # a layer traced before this would keep the plain one
+    try:
+        share, _ = arch.logit_shares(params, cfg, tokens, pad_to)
+    finally:
+        for name, fn in plain.items():  # a caller in this process gets the plain ones back
+            setattr(reference, name, fn)
+        if patched:
+            jax.clear_caches()
+    readings = arch.share_quantiles(share, sizes.zaya_decode)
+    failed = {k: v for k, v in readings.items() if not v <= limits[k]}
+    report = runtime_report()
+    emit(
+        {
+            "phase": "hybrid", "model": sizes.zaya_model, "control": control or None,
+            "positions": {"prefill": int(len(share)) - sizes.zaya_decode,
+                          "decode": sizes.zaya_decode},
+            **readings, "limits": limits, "within_limits": not failed,
+            "seconds": time.monotonic() - t0,
+            "compile": report["compile"], "peak_bytes_in_use": report["peak_bytes_in_use"],
+            "kernel_paths": {**_taken("moe_experts"), **_taken("attn_cca")},
+            "device": device_report(),
+        }
+    )
+    if control and not failed:
+        raise SmokeFailure(f"the control {control!r} stayed inside every limit: {readings}")
+    if not control and failed:
+        raise SmokeFailure(f"logits left the reference: {failed} (limits {limits})")
+
+
 def child_hybrid(seed: int, sizes: Sizes, control: str = "", model: str = "ling") -> None:
+    if model == "zaya":
+        return child_zaya(seed, sizes, control)
     if model == "exaone_moe":
         return child_exaone(seed, sizes, control)
     if model == "mistral4":

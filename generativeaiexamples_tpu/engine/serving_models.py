@@ -63,6 +63,14 @@ def _last_counted(x, n):
     return jnp.take_along_axis(x, at, axis=1)[:, 0]
 
 
+def _from_nothing(row, start):
+    """State as of a last token (a call's rows first) for a prompt that
+    starts at ``start`` (rows,): zeros where it starts at 0, whatever the
+    slot's last occupant left (rows can be stale, and a ring's are masked by
+    position; a recurrent state or a tail cannot be)."""
+    return jnp.where((start == 0).reshape((-1,) + (1,) * (row.ndim - 1)), 0, row)
+
+
 # The most chunks one program takes, whatever the rule below allows.
 MAX_CHUNKS_PER_PROGRAM = 8
 
@@ -268,11 +276,14 @@ class HybridServing:
     leaves are of two sorts (``hybrid.ROW_LEAVES``): rows, one a position
     (latent rows, a full layer's K/V), which a graft copies up to any
     token; and state as of the last token (a KDA layer's ``S`` and
-    ``conv``, a window layer's ring), which a prefix hit takes from a
-    snapshot saved at a prefill-chunk boundary.  A model whose every leaf
-    is of the first sort (``cfg.rows_only``: latent attention in every
-    layer) says ``cut_anywhere``: its prefix hits are cut at any row, as a
-    llama model's are, and no snapshot is ever saved for it.
+    ``conv``, a window layer's ring, a ``cca`` layer's tails), which a
+    prefix hit takes from a snapshot saved at a prefill-chunk boundary.
+    The sort is a leaf's, not a layer's: a ``cca`` layer has both, its
+    ``k`` and ``v`` rows grafted and its tails (5 KB a layer) restored.  A
+    model whose every leaf is of the first sort (``cfg.rows_only``: latent
+    attention in every layer) says ``cut_anywhere``: its prefix hits are
+    cut at any row, as a llama model's are, and no snapshot is ever saved
+    for it.
 
     A model that holds a prediction module (``cfg.draft`` ``"mtp"``) is
     served with it as the draft of every decode step.  The module's state
@@ -301,20 +312,32 @@ class HybridServing:
         "draft_proposed", "draft_accepted", "verify_positions",
         "decode_tokens_emitted", "draft_rows_rewritten",
     )
+    # Of ``moe.COUNTERS``, those exported a second time for decode steps
+    # alone: their ratio is the experts a layer of one step streamed.
+    DECODE_MOE = ("experts_touched", "expert_layer_steps")
 
     def __init__(self, cfg: hybrid.HybridConfig, mesh, max_len: int) -> None:
         self.cfg, self.mesh, self.max_len = cfg, mesh, max_len
         self.draft = cfg.draft
         self.cut_anywhere = cfg.rows_only
         # Latent rows alone, attended in blocks: the chunk programs read
-        # and write the slots' state in place (``_prefill_rows_in_place``).
-        self.rows_in_place = cfg.rows_only and bool(cfg.latent_block)
+        # and write the slots' state in place (``_prefill_rows_in_place``),
+        # and one window serves them all (``chunk_windows``).  The ``cca``
+        # kind's rows are written in place too, over the doubling windows.
+        self.one_window = cfg.rows_only and bool(cfg.latent_block)
+        self.rows_in_place = self.one_window or bool(cfg.layers_of("cca"))
         self.snapshot_bytes = cfg.snapshot_bytes(max_len)
-        # ``forward``'s counters; the rows its attention layers read are
-        # counted apart for decode steps and for prefill chunks.
-        self.counter_names = tuple(f"moe_{n}" for n in moe.COUNTERS) + tuple(
-            f"attn_rows_{n}_{phase}"
-            for phase in ("decode", "prefill") for n in cfg.row_counters
+        # ``forward``'s counters; the experts touched and the expert
+        # layers run are counted again for decode steps alone
+        # (``DECODE_MOE``), and the rows its attention layers read apart
+        # for decode steps and for prefill chunks.
+        self.counter_names = (
+            tuple(f"moe_{n}" for n in moe.COUNTERS)
+            + tuple(f"moe_{n}_decode" for n in self.DECODE_MOE)
+            + tuple(
+                f"attn_rows_{n}_{phase}"
+                for phase in ("decode", "prefill") for n in cfg.row_counters
+            )
         )
         if self.draft:
             self.counter_names += self.DRAFT_COUNTERS
@@ -332,6 +355,13 @@ class HybridServing:
                 "and no step keeps the state it started from (ops/kda.py has "
                 "no rollback)"
             )
+        if (drafted or self.draft) and self.cfg.layers_of("cca"):
+            raise ValueError(
+                "speculative decoding is not served over a cca layer's "
+                "tails: a rejected draft has moved the convolutions' last "
+                "inputs and the shifted value, and no step keeps the tails "
+                "it started from"
+            )
         if drafted:
             raise ValueError(
                 "a draft model and n-gram drafts are not served for a model "
@@ -345,7 +375,8 @@ class HybridServing:
                 "the paged layout is not served for this model: its pages "
                 "hold K/V rows of one shape, not latent rows beside a "
                 "fixed recurrent state, nor a window layer's ring whose "
-                "pages would be released behind the window"
+                "pages would be released behind the window, nor a cca "
+                "layer's tails beside its rows"
             )
         if self.mesh is not None and self.mesh.size > 1:
             raise ValueError(
@@ -360,7 +391,7 @@ class HybridServing:
             raise ValueError(
                 "int8 weights are not served for this model: "
                 "ops.quant.QUANT_TARGETS covers neither the experts nor the "
-                "KDA, MLA and fused GQA projections of models/hybrid.py"
+                "KDA, MLA, CCA and fused GQA projections of models/hybrid.py"
             )
         if params is None:
             key = jax.random.PRNGKey(seed)
@@ -377,15 +408,18 @@ class HybridServing:
         return hybrid.state_bytes(self.cfg, batch, self.max_len)
 
     def _aux(self, counters, decode: bool, drafted=None):
-        """``forward``'s counters under ``counter_names``: the attention
-        rows go to the decode or to the prefill entries; a drafting
-        model's ``DRAFT_COUNTERS`` (``drafted``; a prefill has none)
-        come last."""
-        if not self.cfg.row_counters:
-            return counters
+        """``forward``'s counters under ``counter_names``: ``DECODE_MOE``
+        again where the program is a decode chunk (zeros from a prefill),
+        the attention rows to the decode or to the prefill entries; a
+        drafting model's ``DRAFT_COUNTERS`` (``drafted``; a prefill has
+        none) come last."""
         n = len(moe.COUNTERS)
+        again = jnp.stack([counters[moe.COUNTERS.index(c)] for c in self.DECODE_MOE])
         rows, none = counters[n:], jnp.zeros_like(counters[n:])
-        parts = [counters[:n], *((rows, none) if decode else (none, rows))]
+        parts = [
+            counters[:n], again if decode else jnp.zeros_like(again),
+            *((rows, none) if decode else (none, rows)),
+        ]
         if self.draft:
             parts.append(
                 jnp.zeros((len(self.DRAFT_COUNTERS),), jnp.int32)
@@ -503,17 +537,11 @@ class HybridServing:
             )
         live = suffix_len > 0
 
-        def per_row(x, like):
-            return x.reshape(x.shape + (1,) * (like.ndim - 1))
-
         def take(name, leaf):
             if name in hybrid.ROW_LEAVES:
                 return leaf[slots, :window]
             row = leaf[slots]
-            if name in hybrid.RING_LEAVES:
-                return row
-            # As ``prefill_row``: a prompt that starts here starts from nothing.
-            return jnp.where(per_row(start == 0, row), 0, row)
+            return row if name in hybrid.RING_LEAVES else _from_nothing(row, start)
 
         rows = tuple({n: take(n, leaf) for n, leaf in layer.items()} for layer in cache)
         hidden, rows, counters = hybrid.forward(
@@ -540,19 +568,41 @@ class HybridServing:
         return cache, hidden, self._aux(counters, decode=False)
 
     def _prefill_rows_in_place(self, params, cache, tokens, start, suffix_len, slots, window):
-        """``prefill_rows`` for a model whose state is latent rows alone
-        and whose chunks attend in blocks: every layer is handed the
-        slots' whole state and which slot each row is, writes a chunk's
-        rows where they belong and reads a row's blocks from there, so no
-        window of the state is gathered or written back (at 8 rows of
-        32,768 that copy is 0.2 GB a layer each way).  A pad row writes
-        nothing (none of its tokens counts) and reads nothing."""
-        rows = tuple({**layer, "slot": slots} for layer in cache)
+        """``prefill_rows`` for a model whose layers work on the slots'
+        rows where they lie (latent rows attended in blocks; a ``cca``
+        layer's K/V rows): every layer is handed the slots' whole rows and
+        which slot each row of the call is, writes a chunk's rows where they
+        belong and reads from there (a latent layer a row's blocks, a
+        ``cca`` layer the call's windows, gathered for that layer alone), so
+        no window of the state is written back, and none is held for more
+        than a layer (at 8 rows of 32,768 latent rows that copy is 0.2 GB a
+        layer each way; at 8 rows of 8,192 over twenty ``cca`` layers the
+        windows gathered before the stack and their updated copies were
+        2.5 GB of temporaries beside 14.75 GB of weights and state).  What a layer keeps as of the last token (a
+        ``cca`` layer's tails: small) is taken by slot and put back, as in
+        ``prefill_rows``.  A pad row writes nothing (none of its tokens
+        counts) and reads nothing."""
+        live = suffix_len > 0
+
+        def take(name, leaf):
+            return leaf if name in hybrid.ROW_LEAVES else _from_nothing(leaf[slots], start)
+
+        rows = tuple(
+            {**{n: take(n, leaf) for n, leaf in layer.items()}, "slot": slots} for layer in cache
+        )
         hidden, rows, counters = hybrid.forward(
             params, self.cfg, tokens, start, suffix_len, rows, window=window,
             mesh=self.mesh, rows_apart=True,
         )
-        cache = tuple({"latent": layer["latent"]} for layer in rows)
+        # A pad row's slot is past the last: its tails' write is dropped.
+        dest = jnp.where(live, slots, jax.tree.leaves(cache)[0].shape[0])
+        cache = tuple(
+            {
+                n: row[n] if n in hybrid.ROW_LEAVES else leaf.at[dest].set(row[n], mode="drop")
+                for n, leaf in layer.items()
+            }
+            for layer, row in zip(cache, rows)
+        )
         return cache, hidden, self._aux(counters, decode=False)
 
     def chunk_windows(self, chunk_tokens: int) -> tuple[int, ...]:
@@ -560,7 +610,7 @@ class HybridServing:
         read a row's blocks in place up to its length (a wider window
         costs such a program nothing, and every window is a program to
         build for each size); else the doubling family."""
-        if self.rows_in_place:
+        if self.one_window:
             return (self.max_len,)
         return doubling_windows(chunk_tokens, self.max_len)
 
@@ -581,16 +631,18 @@ class HybridServing:
         return tuple(out)
 
     @staticmethod
-    def _as_of_last_token(layer) -> bool:
-        return not any(n in hybrid.ROW_LEAVES for n in layer)
+    def _as_of_last_token(layer) -> list:
+        """The leaves of a layer's state that hold no row a position."""
+        return [n for n in layer if n not in hybrid.ROW_LEAVES]
 
     def save_state(self, cache, slot):
-        """One slot's state as of its last token: a copy of every KDA
-        layer's state and every window layer's ring."""
+        """One slot's state as of its last token, leaf by leaf: a copy of
+        every leaf that holds no row a position (a KDA layer's state, a
+        window layer's ring, a ``cca`` layer's tails, ``h_last``); one
+        dict for each layer that has such a leaf."""
         return tuple(
-            {n: jax.lax.dynamic_index_in_dim(leaf, slot, 0, keepdims=False)
-             for n, leaf in layer.items()}
-            for layer in cache if self._as_of_last_token(layer)
+            {n: jax.lax.dynamic_index_in_dim(layer[n], slot, 0, keepdims=False) for n in names}
+            for layer in cache if (names := self._as_of_last_token(layer))
         )
 
     def restore_state(self, cache, slot, snap):
@@ -598,11 +650,10 @@ class HybridServing:
         out = []
         for layer in cache:
             if self._as_of_last_token(layer):
-                saved = next(snaps)
-                layer = {
-                    n: jax.lax.dynamic_update_index_in_dim(leaf, saved[n], slot, 0)
-                    for n, leaf in layer.items()
-                }
+                layer = {**layer, **{
+                    n: jax.lax.dynamic_update_index_in_dim(layer[n], leaf, slot, 0)
+                    for n, leaf in next(snaps).items()
+                }}
             out.append(layer)
         return tuple(out)
 

@@ -82,6 +82,8 @@ def resolve_model_preset(model_name: str) -> str:
         return "exaone_moe-tiny" if "tiny" in name else "k-exaone-236b-a23b-l5e16"
     if "mistral-small-4" in name or name.startswith("mistral4"):
         return "mistral4-tiny" if "tiny" in name else "mistral-small-4-119b-l6e32"
+    if "zaya" in name:
+        return "zaya-tiny" if "tiny" in name else "zaya1-8b-l20"
     if "mixtral" in name or "8x7b" in name:
         return "mixtral-8x7b"
     if "gemma" in name:

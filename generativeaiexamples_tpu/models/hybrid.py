@@ -1,9 +1,9 @@
 """A decoder made of layer kinds: each layer names its mixer (``kda``,
-``mla``, ``full`` or ``window``) and its MLP (``dense`` or ``experts``),
-owns the parameters of those kinds and keeps the state of its mixer's
-kind.
+``mla``, ``full``, ``window`` or ``cca``) and its MLP (``dense`` or
+``experts``), owns the parameters of those kinds and keeps the state of
+its mixer's kind.
 
-Four families are defined here.  ``bailing_hybrid`` (Ling-3.0-flash and
+Five families are defined here.  ``bailing_hybrid`` (Ling-3.0-flash and
 its -VL sibling's language model): KDA linear attention (``ops/kda.py``)
 beside a latent-attention layer every ``layer_group_size`` layers
 (``ops/mla.py``), a leading dense SwiGLU layer and then sigmoid-routed
@@ -28,6 +28,15 @@ frequencies, the query is scaled by a factor that grows with its position
 softmax-routed experts with a shared one, of which this process may hold
 a share.  The ``mla`` kind serves Ling's form and this one from one
 function; what differs is data on the configuration (``LatentConfig``).
+``zaya`` (ZAYA1-8B): compressed convolutional attention as the mixer of
+every layer (the ``cca`` kind, ``ops/cca.py``: q and k latents of 8 and 2
+heads through a two-step causal convolution, their un-mixed mean added
+back, a length norm, half of each head rotated, half of the value heads
+taken from the previous token, then a full layer's attention inside the
+latent), one expert a token of 16 chosen by the ZAYA router, an MLP that
+is handed the previous layer's router state (``ops/moe.py::route_mlp``:
+``forward`` carries that state from layer to layer beside ``x``), and a
+head tied to the embedding (``CcaConfig``).
 A new architecture is a new layer kind here, not another flag on
 ``LlamaConfig``; ``models/llama.py`` keeps serving the configurations it
 serves.
@@ -51,6 +60,12 @@ State of a slot, by the layer's mixer:
   ``sliding_window`` whatever the length: position ``p`` lives in row
   ``p % R``.  Like a recurrent state it exists only as of the last token
   written, so a prefix hit takes it from a snapshot.
+* ``cca``: BOTH sorts in one layer: ``k``, ``v`` (T, KH * head), rows
+  like a full layer's, and as of the last token ``conv0``, ``conv1`` (the
+  last inputs of the two convolution steps) and ``v_prev`` (the last
+  token's projection for the value heads taken a token late): 5,376 B a
+  layer at the published widths.  A prefix hit grafts the rows and takes
+  the tails from a snapshot, which holds nothing else.
 
 A prediction module adds two entries behind the stack's: its block's
 ``k``, ``v`` rows (a ``full`` layer's) and ``h_last`` (D,), the stack's
@@ -61,16 +76,28 @@ token after it; like a recurrent state it exists only as of that token.
 leaf is state as of the last token.  ``HybridConfig`` holds what every
 family has and the KDA and MLA sizes; ``GqaConfig`` adds the GQA sizes,
 the rotary parameters of each kind and the routing options;
-``LatentConfig`` adds what the ``mistral4`` family's latent layer has.
+``LatentConfig`` adds what the ``mistral4`` family's latent layer has;
+``CcaConfig`` the ``zaya`` family's sizes, its router's width and its tied
+head.
 
 What is read from a family's convention and not from a key of the
 public config is listed under ``assumed`` in
 ``benchmarks/configs/ling-3.0-flash-vl-l7e128.json``,
 ``benchmarks/configs/mellum2-12b-a2.5b-l12.json``,
-``benchmarks/configs/k-exaone-236b-a23b-l5e16.json`` and
-``benchmarks/configs/mistral-small-4-119b-l6e32.json``; the plain
-references are ``models/hybrid_reference.py``, ``models/mellum_reference.py``,
-``models/exaone_moe_reference.py`` and ``models/mistral4_reference.py``.
+``benchmarks/configs/k-exaone-236b-a23b-l5e16.json``,
+``benchmarks/configs/mistral-small-4-119b-l6e32.json`` and
+``benchmarks/configs/zaya1-8b-l20.json`` (which also lists what of ZAYA1
+is not served: residual scaling and "MoD" have no key and no equation);
+the plain references are ``models/hybrid_reference.py``,
+``models/mellum_reference.py``, ``models/exaone_moe_reference.py``,
+``models/mistral4_reference.py`` and ``models/zaya_reference.py``.
+
+What a row of ``benchmarks/README.md``'s layout table would say of the
+newest family (that file is a ``benchmark`` PR's to edit):
+``benchmarks/arch/zaya.py`` maps ``configs/zaya1-8b-l20.json`` to
+``CcaConfig`` through :func:`from_hf_config` and holds its counts,
+``benchmarks/zaya_reference.py`` is the copy of ``models/zaya_reference.py``
+that decides its cell's ``correct``.
 """
 
 from __future__ import annotations
@@ -83,15 +110,15 @@ import jax
 import jax.numpy as jnp
 
 from generativeaiexamples_tpu.models.llama import rms_norm
-from generativeaiexamples_tpu.ops import gqa, gqa_decode, kda, mla, moe
+from generativeaiexamples_tpu.ops import cca, gqa, gqa_decode, kda, mla, moe
 from generativeaiexamples_tpu.ops.dispatch import record
 from generativeaiexamples_tpu.ops.rope import (
-    NO_ROPE, RopeSpec, apply_rope_spec, rope_spec, yarn_mscale,
+    NO_ROPE, RopeSpec, apply_rope_partial, apply_rope_spec, rope_spec, yarn_mscale,
 )
 
 Params = Mapping[str, Any]
 F32 = jnp.float32
-MIXERS = ("kda", "mla", "full", "window")
+MIXERS = ("kda", "mla", "full", "window", "cca")
 MLPS = ("dense", "experts")
 # The block of a prediction module: a full GQA layer with experts.
 MTP_KIND = ("full", "experts")
@@ -101,6 +128,10 @@ MTP_KIND = ("full", "experts")
 ROW_LEAVES = ("latent", "k", "v")
 RING_LEAVES = ("ring_k", "ring_v")
 GQA_LEAVES = {"full": ("k", "v"), "window": RING_LEAVES}  # a GQA mixer's K and V
+# What a ``cca`` layer keeps beside its ``k`` and ``v`` rows, as of the
+# last token: the last inputs of its two convolution steps and the last
+# token's projection for the shifted value heads.
+CCA_TAILS = ("conv0", "conv1", "v_prev")
 # Rows of K (and as many of V) the attention layers read from the slots'
 # state, by kind; what the window layers would have read as full layers,
 # and what the full layers read where every slot's window is read whole
@@ -173,6 +204,9 @@ class HybridConfig:
     softmax_mscale: ClassVar[float] = 1.0
     mla_out_gate: ClassVar[bool] = True
     latent_block: ClassVar[int] = 0
+    # What ``CcaConfig`` makes fields of: a linear router, an untied head.
+    router_hidden: ClassVar[int] = 0
+    tie_embeddings: ClassVar[bool] = False
 
     def __post_init__(self) -> None:
         for mixer, mlp in self.layer_kinds:
@@ -184,10 +218,12 @@ class HybridConfig:
             raise ValueError("the experts held lie outside the router's outputs")
         if self.score_function not in moe.SCORE_FUNCTIONS:
             raise ValueError(f"unknown score_function {self.score_function!r}")
-        if self.layers_of("full") or self.layers_of("window"):
+        if self.has_attn_counters:
             if self.n_kv_heads < 1 or self.n_heads % self.n_kv_heads:
                 raise ValueError("n_kv_heads must divide n_heads")
-            if self.rope_full is None or self.rope_window is None:
+            if self.rope_full is None or (
+                self.rope_window is None and not self.layers_of("cca")
+            ):
                 raise ValueError("a GQA layer kind needs its rotary parameters")
             if self.layers_of("window") and self.sliding_window < 1:
                 raise ValueError("a window layer needs sliding_window")
@@ -205,7 +241,9 @@ class HybridConfig:
 
     @property
     def has_attn_counters(self) -> bool:
-        return bool(self.layers_of("full") or self.layers_of("window"))
+        """A model with K/V rows a position: the GQA kinds, and ``cca``,
+        whose rows are read as a full layer's."""
+        return any(self.layers_of(m) for m in ("full", "window", "cca"))
 
     @property
     def row_counters(self) -> tuple[str, ...]:
@@ -223,7 +261,8 @@ class HybridConfig:
         (latent rows, a full layer's K/V): the state can then be cut at
         any token, and a prefix hit needs no snapshot."""
         return not (
-            self.layers_of("kda") or self.layers_of("window") or self.mtp_layers
+            self.layers_of("kda") or self.layers_of("window")
+            or self.layers_of("cca") or self.mtp_layers
         )
 
     @property
@@ -266,17 +305,16 @@ class HybridConfig:
 
     def snapshot_bytes(self, max_len: int | None = None) -> int:
         """Bytes of what one slot keeps only as of its last token: the
-        recurrent state of the KDA layers and the rings of the window
-        layers (of a state ``max_len`` long; absent: ``max_seq_len``)."""
-        h, k = self.n_heads, self.kda_head_dim
-        item = self.state_dtype.itemsize
-        kda_layer = h * k * k * 4 + (self.conv_kernel - 1) * self.conv_channels * item
-        ring = self.ring_rows(self.max_seq_len if max_len is None else max_len)
-        window_layer = 2 * self.n_kv_heads * ring * self.attn_head_dim * item
-        return (
-            len(self.layers_of("kda")) * kda_layer
-            + len(self.layers_of("window")) * window_layer
-            + self.mtp_layers * self.d_model * jnp.dtype(self.dtype).itemsize
+        recurrent state of the KDA layers, the rings of the window layers
+        (of a state ``max_len`` long; absent: ``max_seq_len``), the tails
+        of the ``cca`` layers and a prediction module's ``h_last``: every
+        leaf of ``init_state`` that is no row a position."""
+        shapes = jax.eval_shape(
+            lambda: init_state(self, 1, self.max_seq_len if max_len is None else max_len)
+        )
+        return sum(
+            leaf.size * leaf.dtype.itemsize
+            for layer in shapes for name, leaf in layer.items() if name not in ROW_LEAVES
         )
 
 
@@ -364,6 +402,47 @@ class LatentConfig(HybridConfig):
         return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
 
 
+@dataclasses.dataclass(frozen=True)
+class CcaConfig(HybridConfig):
+    """A configuration whose every layer is the ``zaya`` family's: a
+    ``cca`` mixer (its K/V rows are a full GQA layer's, ``n_kv_heads`` of
+    ``attn_head_dim``) and experts chosen one a token by the ZAYA router."""
+
+    score_function: str = "softmax"
+    # The selection bias ``beta`` of ``argmax(p + beta)``.
+    router_bias: bool = True
+    n_kv_heads: int = 0
+    attn_head_dim: int = 128
+    # The rotation of q and k: over the first ``rotary_dim`` values of a head.
+    rope_full: RopeSpec | None = None
+    rotary_dim: int = 64
+    # Kernels of the two causal convolutions over [q ; k]: ``cca_time0``
+    # depthwise, ``cca_time1`` a head's channels mixed.
+    conv_time0: int = 2
+    conv_time1: int = 2
+    # The router's own width (``router_hidden_size``).
+    router_hidden: int = 256
+    # The head is the embedding (``tie_word_embeddings``).
+    tie_embeddings: bool = True
+
+    def __post_init__(self) -> None:
+        super().__post_init__()
+        if self.n_kv_heads % 2:
+            raise ValueError(
+                "a cca layer takes half of its value heads from the previous "
+                "token: n_kv_heads must be even"
+            )
+        if self.n_experts_per_tok != 1 or self.n_group != 1:
+            raise ValueError("the ZAYA router picks one expert a token, of one group")
+        if min(self.conv_time0, self.conv_time1) < 2:
+            raise ValueError("a causal convolution keeps a tail: cca_time0, cca_time1 >= 2")
+
+    @property
+    def cca_channels(self) -> int:
+        """The q and k latents side by side: what the convolutions mix."""
+        return (self.n_heads + self.n_kv_heads) * self.attn_head_dim
+
+
 def from_hf_config(
     model: Mapping[str, Any],
     *,
@@ -374,7 +453,8 @@ def from_hf_config(
 ) -> HybridConfig:
     """The public ``config.json`` keys -> ``HybridConfig``, by
     ``model_type``: ``mellum`` (:func:`_from_mellum`), ``exaone_moe``
-    (:func:`_from_exaone`), ``mistral4`` (:func:`_from_mistral4`), else the
+    (:func:`_from_exaone`), ``mistral4`` (:func:`_from_mistral4`), ``zaya``
+    (:func:`_from_zaya`), else the
     ``bailing_hybrid`` family, of which the rest speaks.  ``draft`` ``mtp`` holds the model's own prediction
     module and serves it as the decode step's draft; a family without one
     refuses it.
@@ -400,6 +480,10 @@ def from_hf_config(
         return _from_mellum(model, max_len=max_len, kv_dtype=kv_dtype)
     if model.get("model_type") == "mistral4":
         return _from_mistral4(
+            model, max_len=max_len, expert_offset=expert_offset, kv_dtype=kv_dtype
+        )
+    if model.get("model_type") == "zaya":
+        return _from_zaya(
             model, max_len=max_len, expert_offset=expert_offset, kv_dtype=kv_dtype
         )
     period = int(model["layer_group_size"])
@@ -658,6 +742,68 @@ def _from_mistral4(
     )
 
 
+def _from_zaya(
+    model: Mapping[str, Any], *, max_len: int, expert_offset: int, kv_dtype: str
+) -> CcaConfig:
+    """``model_type: zaya``: every layer ``hybrid`` (a ``cca`` mixer with
+    experts; a cut in depth keeps the first ``num_hidden_layers`` entries
+    of ``layer_types``), the rotation of ``rope_parameters["hybrid"]`` over
+    ``partial_rotary_factor`` of a head, one expert a token of
+    ``num_experts`` held of ``num_experts_published`` router outputs
+    (absent: the same) through a router ``router_hidden_size`` wide, and
+    the head tied to the embedding or not as the config says."""
+    n = int(model["num_hidden_layers"])
+    kinds = list(model["layer_types"])[:n]
+    if len(kinds) < n or set(kinds) != {"hybrid"}:
+        raise ValueError(
+            f"layer types {sorted(set(kinds) - {'hybrid'})} are not served for zaya: "
+            "every layer is 'hybrid' (no window: 'hybrid_sliding' has no layer of "
+            "the published model to be checked against), one for each of "
+            "num_hidden_layers"
+        )
+    if model.get("sliding_window"):
+        raise ValueError("a sliding window is not served for zaya")
+    if model.get("attention_bias") or model.get("lm_head_bias"):
+        raise ValueError("attention and head biases are not served")
+    if model.get("hidden_act", "silu") != "silu":
+        raise ValueError("activations other than silu are not served")
+    if int(model["num_experts_per_tok"]) != 1:
+        raise ValueError("the ZAYA router picks one expert a token (num_experts_per_tok 1)")
+    rope = model["rope_parameters"]["hybrid"]
+    head = int(model["head_dim"])
+    held = int(model["num_experts"])
+    return CcaConfig(
+        vocab_size=int(model["vocab_size"]),
+        d_model=int(model["hidden_size"]),
+        layer_kinds=(("cca", "experts"),) * n,
+        n_heads=int(model["num_attention_heads"]),
+        n_kv_heads=int(model["num_key_value_heads"]),
+        attn_head_dim=head,
+        rope_full=rope_spec(rope),
+        rotary_dim=int(head * float(rope.get(
+            "partial_rotary_factor", model.get("partial_rotary_factor", 1.0)))),
+        conv_time0=int(model["cca_time0"]),
+        conv_time1=int(model["cca_time1"]),
+        router_hidden=int(model["router_hidden_size"]),
+        tie_embeddings=bool(model["tie_word_embeddings"]),
+        d_ff=int(model["moe_intermediate_size"]),  # no layer is dense
+        moe_d_ff=int(model["moe_intermediate_size"]),
+        shared_d_ff=0,
+        n_experts=int(model.get("num_experts_published", held)),
+        experts_held=held,
+        expert_offset=int(expert_offset),
+        n_experts_per_tok=1,
+        n_group=1,
+        topk_group=1,
+        routed_scaling=1.0,
+        norm_topk=False,
+        norm_eps=float(model["rms_norm_eps"]),
+        max_seq_len=int(max_len),
+        dtype=str(model.get("torch_dtype", "bfloat16")),
+        kv_dtype=kv_dtype,
+    )
+
+
 # -- parameters ---------------------------------------------------------------
 
 
@@ -668,7 +814,9 @@ def _normal(key, scale, shape, dtype):
 
 def _layer_shapes(cfg: HybridConfig, mixer: str, mlp: str) -> dict:
     """name -> (shape, init) for one layer; init is a fan-in for a normal
-    draw, or a constant."""
+    draw, a constant, ``"bias"`` (a normal draw of 0.1: not zero, so that
+    tests see it) or ``"gamma"`` (the ZAYA router's weight of the layer
+    before: uniform in 0.25-0.75, so that leaving it out is seen)."""
     D, H, K = cfg.d_model, cfg.n_heads, cfg.kda_head_dim
     shapes: dict = {"attn_norm": ((D,), 1.0), "mlp_norm": ((D,), 1.0)}
     if mixer == "kda":
@@ -691,6 +839,19 @@ def _layer_shapes(cfg: HybridConfig, mixer: str, mlp: str) -> dict:
         )
         if cfg.qk_norm:
             shapes.update(q_norm=((hd,), 1.0), k_norm=((hd,), 1.0))
+    elif mixer == "cca":
+        hd, KH, C = cfg.attn_head_dim, cfg.n_kv_heads, cfg.cca_channels
+        shapes.update(
+            # W_q, W_k, then the values' two halves: W_v1 (the current
+            # token's heads) and W_v2 (the heads taken a token late).
+            w_qkv=((D, C + KH * hd), D),
+            conv0_w=((cfg.conv_time0, C), cfg.conv_time0),
+            conv0_b=((C,), "bias"),
+            conv1_w=((cfg.conv_time1, C // hd, hd, hd), cfg.conv_time1 * hd),
+            conv1_b=((C,), "bias"),
+            k_temp=((KH,), 1.0),  # tau: the learned temperature a key head
+            w_o=((H * hd, D), H * hd),
+        )
     else:
         qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
         if cfg.q_lora_rank:
@@ -716,7 +877,17 @@ def _layer_shapes(cfg: HybridConfig, mixer: str, mlp: str) -> dict:
         shapes.update(w_gu=((D, 2 * cfg.d_ff), D), w_down=((cfg.d_ff, D), cfg.d_ff))
     else:
         F, Fs, E = cfg.moe_d_ff, cfg.shared_d_ff, cfg.experts_held
-        shapes.update(router=((D, cfg.n_experts), D))
+        R = cfg.router_hidden
+        if R:
+            shapes.update(
+                router_down=((D, R), D), router_down_b=((R,), "bias"),
+                router_gamma=((), "gamma"), router_norm=((R,), 1.0),
+                router_w1=((R, R), R), router_b1=((R,), "bias"),
+                router_w2=((R, R), R), router_b2=((R,), "bias"),
+                router_w3=((R, cfg.n_experts), R), router_b3=((cfg.n_experts,), "bias"),
+            )
+        else:
+            shapes.update(router=((D, cfg.n_experts), D))
         if cfg.router_bias:
             shapes.update(router_bias=((cfg.n_experts,), 0.0))
         shapes.update(w_gu_e=((E, D, 2 * F), D), w_down_e=((E, F, D), F))
@@ -738,6 +909,10 @@ def init_params(cfg: HybridConfig, key: jax.Array) -> Params:
             return _normal(next(keys), 0.05, shape, F32)
         if name in ("a_log", "dt_bias"):
             return _normal(next(keys), 0.5, shape, F32)
+        if init == "bias":
+            return _normal(next(keys), 0.1, shape, dtype)
+        if init == "gamma":
+            return jax.random.uniform(next(keys), shape, F32, 0.25, 0.75)
         if isinstance(init, float):
             return jnp.full(shape, init, dtype)
         return _normal(next(keys), float(init) ** -0.5, shape, dtype)
@@ -749,10 +924,11 @@ def init_params(cfg: HybridConfig, key: jax.Array) -> Params:
         "layers": tuple(layer(kind) for kind in cfg.layer_kinds),
         "embed": _normal(next(keys), 1.0, (cfg.vocab_size, cfg.d_model), dtype),
         "final_norm": jnp.ones((cfg.d_model,), dtype),
-        "lm_head": _normal(
-            next(keys), cfg.d_model**-0.5, (cfg.d_model, cfg.vocab_size), dtype
-        ),
     }
+    if not cfg.tie_embeddings:
+        params["lm_head"] = _normal(
+            next(keys), cfg.d_model**-0.5, (cfg.d_model, cfg.vocab_size), dtype
+        )
     if cfg.mtp_layers:
         # A key stream of its own: the stack's parameters are the same
         # with the module held and without.
@@ -809,20 +985,28 @@ def _balanced_biases(params, cfg: HybridConfig, tokens):
     x = params["embed"][tokens]
     out = []
 
-    def through(x, lp, st, mixer, mlp):
+    def through(x, lp, st, mixer, mlp, rho=None):
         x, _, _ = _mix(x, lp, st, mixer, pos, valid, n_valid, cfg, s)
         if mlp == "experts":
             h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
+            h = h.reshape(-1, h.shape[-1])
+            if cfg.router_hidden:
+                prev = None if rho is None else rho.reshape(h.shape[0], -1)
+                scores, _ = moe.mlp_scores(h, lp, prev, eps=cfg.norm_eps)
+            else:
+                scores = moe.scores(h, lp["router"])
             bias = moe.balanced_bias(
-                h.reshape(-1, h.shape[-1]), lp["router"], k=cfg.n_experts_per_tok,
-                n_group=cfg.n_group, topk_group=cfg.topk_group,
+                scores, k=cfg.n_experts_per_tok, n_group=cfg.n_group,
+                topk_group=cfg.topk_group,
             )
             out.append(bias)
             lp = {**lp, "router_bias": bias}
-        return _mlp(x, lp, mlp, valid, cfg, None)[0]
+        x, _, rho = _mlp(x, lp, mlp, valid, cfg, None, rho)
+        return x, rho
 
+    rho = None
     for (mixer, mlp), lp, st in zip(cfg.layer_kinds, params["layers"], state):
-        x = through(x, lp, st, mixer, mlp)
+        x, rho = through(x, lp, st, mixer, mlp, rho)
     if cfg.mtp_layers:
         u = _mtp_input(params, cfg, x, jnp.roll(tokens, -1, axis=1))
         through(u, params["mtp"]["layer"], state[cfg.n_layers], *MTP_KIND)
@@ -843,6 +1027,17 @@ def init_state(cfg: HybridConfig, batch: int, max_len: int) -> tuple:
             rows = max_len if mixer == "full" else cfg.ring_rows(max_len)
             shape = (batch, rows, cfg.n_kv_heads * cfg.attn_head_dim)
             out.append({n: jnp.zeros(shape, sd) for n in GQA_LEAVES[mixer]})
+        elif mixer == "cca":
+            hd, KH, C = cfg.attn_head_dim, cfg.n_kv_heads, cfg.cca_channels
+            rows = (batch, max_len, KH * hd)
+            out.append(
+                {
+                    "k": jnp.zeros(rows, sd), "v": jnp.zeros(rows, sd),
+                    "conv0": jnp.zeros((batch, cfg.conv_time0 - 1, C), sd),
+                    "conv1": jnp.zeros((batch, cfg.conv_time1 - 1, C), sd),
+                    "v_prev": jnp.zeros((batch, 1, KH // 2 * hd), sd),
+                }
+            )
         elif mixer == "kda":
             out.append(
                 {
@@ -862,21 +1057,25 @@ def init_state(cfg: HybridConfig, batch: int, max_len: int) -> tuple:
 
 
 def state_bytes(cfg: HybridConfig, batch: int, max_len: int) -> dict[str, int]:
-    """Bytes of the slots' state by kind: ``full`` (rows that grow with
-    the tokens: latent, K/V), ``window`` (rings: the same at any
-    ``max_len`` over the window), ``recurrent`` and, where a prediction
-    module is held, ``draft`` (its rows and ``h_last``)."""
+    """Bytes of the slots' state by kind, leaf by leaf: ``full`` (rows
+    that grow with the tokens: latent, K/V), ``window`` (rings: the same at
+    any ``max_len`` over the window), ``recurrent`` (what exists only as
+    of the last token: a KDA layer's state, a ``cca`` layer's tails) and,
+    where a prediction module is held, ``draft`` (its rows and
+    ``h_last``)."""
     out = {"full": 0, "window": 0, "recurrent": 0}
     if cfg.mtp_layers:
         out["draft"] = 0
     shapes = jax.eval_shape(lambda: init_state(cfg, batch, max_len))
-    kinds = [
-        {"kda": "recurrent", "window": "window"}.get(mixer, "full")
-        for mixer, _ in cfg.layer_kinds
-    ]
-    kinds += ["draft"] * (len(shapes) - len(kinds))
-    for kind, layer in zip(kinds, shapes):
-        out[kind] += sum(leaf.size * leaf.dtype.itemsize for leaf in layer.values())
+    for i, layer in enumerate(shapes):
+        for name, leaf in layer.items():
+            if i >= cfg.n_layers:
+                kind = "draft"
+            elif name in ROW_LEAVES:
+                kind = "full"
+            else:
+                kind = "window" if name in RING_LEAVES else "recurrent"
+            out[kind] += leaf.size * leaf.dtype.itemsize
     return out
 
 
@@ -1073,51 +1272,147 @@ def _gqa_mixer(
         k, v = k.reshape(b, s, KH * hd), v.reshape(b, s, KH * hd)  # a state row
     names = GQA_LEAVES[mixer]
     old_k, old_v = (st[n] for n in names)
-    rows = old_k.shape[1]
-    span = min(window, rows)  # of a full layer's rows, those a call may see
-    walk = record(
-        f"{site}attn_{mixer} b={b} s={s} t={window if mixer == 'full' else rows}",
-        mixer == "full" and gqa_decode.use_row_walk(
-            s=s, q_dtype=q.dtype, rows_dtype=old_k.dtype, width=KH * hd, head_dim=hd, rows=rows,
-            window=span, batch=b, n_q=H, mesh=mesh, apart=apart,
-        ),
-    )
-
-    def write(at):
-        # A token that does not count is written nowhere (``at`` == rows).
-        with jax.named_scope(f"{scope}/kv_write"):
-            slots = jnp.arange(b)[:, None]
-            return (
-                old_k.at[slots, at].set(k.astype(old_k.dtype), mode="drop"),
-                old_v.at[slots, at].set(v.astype(old_v.dtype), mode="drop"),
-            )
-
     if mixer == "full":
-        new_k, new_v = write(jnp.where(valid, pos, rows))
-        read_full = b * span
-        with jax.named_scope(f"{scope}/attend"):
-            if walk:
-                lengths = gqa_decode.walk_lengths(pos, n_valid, span)
-                o = gqa_decode.attend_rows_walk(
-                    q, new_k, new_v, pos, lengths, n_kv=KH, window=span
-                )
-                read_full = gqa_decode.rows_walked(lengths, rows, span)
-            else:
-                o = _attend(
-                    functools.partial(gqa.attend_rows, n_kv=KH), n_valid, apart,
-                    q, new_k[:, :window], new_v[:, :window], pos,
-                )
-        read = (0, read_full, 0, b * span)
+        o, new_k, new_v, read = _full_rows(
+            q, k, v, old_k, old_v, pos, valid, n_valid, n_kv=KH, window=window,
+            apart=apart, scope=scope, site=f"{site}attn_full", mesh=mesh,
+        )
     else:
+        rows = old_k.shape[1]
+        record(f"{site}attn_window b={b} s={s} t={rows}", False)
         with jax.named_scope(f"{scope}/attend"):
             o = gqa.attend_ring(
                 q, k, v, old_k, old_v, pos, n_kv=KH, window=cfg.sliding_window
             )
-        new_k, new_v = write(gqa.ring_slots(pos, valid, n_valid, rows))
+        new_k, new_v = _write_rows(
+            old_k, old_v, k, v, gqa.ring_slots(pos, valid, n_valid, rows), scope
+        )
         read = (b * rows, 0, b * window, 0)
     with jax.named_scope(f"{scope}/wo"):
         out = jnp.dot(o.reshape(b, s, H * hd).astype(h.dtype), lp["w_o"])
     return out, dict(zip(names, (new_k, new_v))), jnp.stack(read).astype(jnp.int32)
+
+
+def _write_rows(old_k, old_v, k, v, at, scope: str, slot=None):
+    """The call's K and V rows (b, s, KH * D) into the slots' rows at
+    ``at`` (b, s), row ``i`` into slot ``slot[i]`` (absent: slot ``i``); a
+    token that does not count is written nowhere (its ``at`` is the number
+    of rows: dropped)."""
+    with jax.named_scope(f"{scope}/kv_write"):
+        slots = (jnp.arange(k.shape[0]) if slot is None else slot)[:, None]
+        return (
+            old_k.at[slots, at].set(k.astype(old_k.dtype), mode="drop"),
+            old_v.at[slots, at].set(v.astype(old_v.dtype), mode="drop"),
+        )
+
+
+def _full_rows(
+    q, k, v, old_k, old_v, pos, valid, n_valid, *, n_kv: int, window: int, apart: bool,
+    scope: str, site: str, mesh, slot=None,
+):
+    """Attention over rows a position, which the ``full`` and the ``cca``
+    kinds share: the call's rows are written, then a query attends over
+    the first ``window`` of its slot's rows (a decode step walks the rows
+    each slot holds, where ``gqa_decode.use_row_walk`` admits it).
+
+    q: (b, s, H, D) rotated; k, v: (b, s, KH * D).  With ``slot`` (b,) the
+    rows are a state of many slots, of which row ``i`` of the call is slot
+    ``slot[i]``: its rows are written there, in place, and the call's rows'
+    windows are gathered from there for this layer alone, so that no window
+    is written back and no more than one layer's are held (a chunk program:
+    ``HybridServing.prefill_rows``).
+    Returns (o (b, s, H, D), K rows, V rows, counters in the order of
+    ``ATTN_COUNTERS``)."""
+    b, s, H, hd = q.shape
+    rows = old_k.shape[1]
+    span = min(window, rows)  # of the slot's rows, those a call may see
+    walk = record(
+        f"{site} b={b} s={s} t={window}",
+        gqa_decode.use_row_walk(
+            s=s, q_dtype=q.dtype, rows_dtype=old_k.dtype, width=n_kv * hd, head_dim=hd,
+            rows=rows, window=span, batch=b, n_q=H, mesh=mesh, apart=apart,
+        ),
+    )
+    new_k, new_v = _write_rows(old_k, old_v, k, v, jnp.where(valid, pos, rows), scope, slot)
+    read_full = b * span
+    with jax.named_scope(f"{scope}/attend"):
+        if slot is not None:
+            # The call's rows' windows, gathered from where they were just
+            # written: one layer's at a time (67 MB for 8 rows of 8,192),
+            # not every layer's before the stack runs.  Read by a slice
+            # inside the loop over the rows, the chip's compiler re-laid the
+            # WHOLE leaf out for the products, once a layer and program
+            # (0.33 ms for 134 MB: my chip call 3, PR 40).
+            o = _attend(
+                functools.partial(gqa.attend_rows, n_kv=n_kv), n_valid, True,
+                q, new_k[slot, :window], new_v[slot, :window], pos,
+            )
+        elif walk:
+            lengths = gqa_decode.walk_lengths(pos, n_valid, span)
+            o = gqa_decode.attend_rows_walk(
+                q, new_k, new_v, pos, lengths, n_kv=n_kv, window=span
+            )
+            read_full = gqa_decode.rows_walked(lengths, rows, span)
+        else:
+            o = _attend(
+                functools.partial(gqa.attend_rows, n_kv=n_kv), n_valid, apart,
+                q, new_k[:, :window], new_v[:, :window], pos,
+            )
+    return o, new_k, new_v, (0, read_full, 0, b * span)
+
+
+def _cca_mixer(
+    h, lp, st, pos, valid, n_valid, cfg: HybridConfig, window: int, apart: bool, mesh=None,
+):
+    """A ``cca`` layer (``ops/cca.py``): q and k latents through the two
+    causal convolutions, the mean of the un-mixed q and k added back, each
+    head normed to length ``sqrt(d)`` (k times its temperature), the
+    rotation over the first ``rotary_dim`` of a head, half of the value
+    heads taken from the token before; then a full layer's attention over
+    the slot's rows.  Every history (the convolutions' last inputs, the
+    last token's late values) is continued from the slot's tails, which
+    move only past tokens that count.  ``st`` may carry ``slot`` (b,)
+    beside its leaves: ``k`` and ``v`` are then the rows of many slots,
+    written in place (``_full_rows``); the tails are the call's rows' own
+    either way.  Returns (output, state, counters in the order of
+    ``ATTN_COUNTERS``)."""
+    b, s, _ = h.shape
+    H, KH, hd, C = cfg.n_heads, cfg.n_kv_heads, cfg.attn_head_dim, cfg.cca_channels
+    scope = "layer/attn_cca"
+    with jax.named_scope(f"{scope}/qk"):  # and the values: one product
+        qkv = jnp.dot(h, lp["w_qkv"])
+        u, v_now, v_late = jnp.split(qkv, [C, C + KH // 2 * hd], axis=-1)
+    with jax.named_scope(f"{scope}/conv"):
+        a, xin0 = kda.causal_conv(u, st["conv0"], lp["conv0_w"])
+        a = (a + lp["conv0_b"].astype(F32)).astype(h.dtype)
+        z, xin1 = cca.head_conv(a, st["conv1"], lp["conv1_w"], lp["conv1_b"])
+    with jax.named_scope(f"{scope}/qk_mean"):
+        q, k = cca.add_qk_mean(z, u, H)
+    with jax.named_scope(f"{scope}/norm_rope"):
+        q = kda.l2_normalize(q) * hd**0.5
+        k = kda.l2_normalize(k) * (hd**0.5 * lp["k_temp"].astype(F32))[:, None]
+        q, k = (
+            apply_rope_partial(x.astype(h.dtype), pos, cfg.rope_full, cfg.rotary_dim)
+            for x in (q, k)
+        )
+    with jax.named_scope(f"{scope}/v_shift"):
+        before, late = cca.shift(v_late, st["v_prev"])
+        v = jnp.concatenate([v_now, before], axis=-1)
+    o, new_k, new_v, read = _full_rows(
+        q, k.reshape(b, s, KH * hd), v, st["k"], st["v"], pos, valid, n_valid,
+        n_kv=KH, window=window, apart=apart, scope=scope, site="attn_cca", mesh=mesh,
+        slot=st.get("slot"),
+    )
+    with jax.named_scope(f"{scope}/wo"):
+        out = jnp.dot(o.reshape(b, s, H * hd).astype(h.dtype), lp["w_o"])
+    sd = st["conv0"].dtype
+    state = {
+        "k": new_k, "v": new_v,
+        "conv0": kda.next_tail(xin0, n_valid, cfg.conv_time0).astype(sd),
+        "conv1": kda.next_tail(xin1, n_valid, cfg.conv_time1).astype(sd),
+        "v_prev": kda.next_tail(late, n_valid, 2).astype(sd),
+    }
+    return out, state, jnp.stack(read).astype(jnp.int32)
 
 
 def _swiglu(h, w_gu, w_down):
@@ -1127,15 +1422,24 @@ def _swiglu(h, w_gu, w_down):
     return jnp.dot(act.astype(h.dtype), w_down)
 
 
-def _expert_layer(h, lp, valid, cfg: HybridConfig, mesh):
+def _expert_layer(h, lp, valid, cfg: HybridConfig, mesh, rho=None):
+    """The experts' half of a layer.  ``rho`` is the router state the layer
+    before handed on (a ZAYA router's: ``cfg.router_hidden``; None for the
+    first layer and for every linear router).  Returns (y, counters, this
+    layer's router state or None)."""
     b, s, d = h.shape
     x = h.reshape(b * s, d)
-    idx, w = moe.route(
-        x, lp["router"], lp.get("router_bias"), k=cfg.n_experts_per_tok,
-        n_group=cfg.n_group, topk_group=cfg.topk_group,
-        norm_topk=cfg.norm_topk, scale=cfg.routed_scaling,
-        score=cfg.score_function,
-    )
+    if cfg.router_hidden:
+        prev = None if rho is None else rho.reshape(b * s, -1)
+        idx, w, rho = moe.route_mlp(x, lp, prev, eps=cfg.norm_eps)
+        rho = rho.reshape(b, s, -1)
+    else:
+        idx, w = moe.route(
+            x, lp["router"], lp.get("router_bias"), k=cfg.n_experts_per_tok,
+            n_group=cfg.n_group, topk_group=cfg.topk_group,
+            norm_topk=cfg.norm_topk, scale=cfg.routed_scaling,
+            score=cfg.score_function,
+        )
     y, counters = moe.expert_mlp(
         x, idx, w, valid.reshape(-1), lp,
         offset=cfg.expert_offset, held=cfg.experts_held, mesh=mesh,
@@ -1143,7 +1447,7 @@ def _expert_layer(h, lp, valid, cfg: HybridConfig, mesh):
     if cfg.shared_d_ff:
         with jax.named_scope("layer/moe/shared"):
             y = y + _swiglu(x, lp["w_gu_s"], lp["w_down_s"])
-    return y.reshape(b, s, d), counters
+    return y.reshape(b, s, d), counters, rho
 
 
 def _mix(
@@ -1162,6 +1466,8 @@ def _mix(
         y, st, rows = _mla_mixer(h, lp, st, pos, valid, n_valid, cfg, window, apart)
         if cfg.row_counters == LATENT_COUNTERS:
             read = rows
+    elif mixer == "cca":
+        y, st, read = _cca_mixer(h, lp, st, pos, valid, n_valid, cfg, window, apart, mesh)
     else:
         y, st, read = _gqa_mixer(
             h, lp, st, mixer, pos, valid, n_valid, cfg, window, apart, site, mesh
@@ -1169,14 +1475,15 @@ def _mix(
     return x + y, st, read
 
 
-def _mlp(x, lp, mlp, valid, cfg: HybridConfig, mesh):
-    """The MLP's half: (x + mlp(norm(x)), the expert layer's counters)."""
+def _mlp(x, lp, mlp, valid, cfg: HybridConfig, mesh, rho=None):
+    """The MLP's half: (x + mlp(norm(x)), the expert layer's counters, the
+    router state to hand the next layer: ``_expert_layer``)."""
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     if mlp == "dense":
         with jax.named_scope("layer/mlp"):
-            return x + _swiglu(h, lp["w_gu"], lp["w_down"]), 0
-    y, counters = _expert_layer(h, lp, valid, cfg, mesh)
-    return x + y, counters
+            return x + _swiglu(h, lp["w_gu"], lp["w_down"]), 0, rho
+    y, counters, rho = _expert_layer(h, lp, valid, cfg, mesh, rho)
+    return x + y, counters, rho
 
 
 def forward(
@@ -1206,12 +1513,13 @@ def forward(
     counters = jnp.zeros((len(moe.COUNTERS),), jnp.int32)
     read = jnp.zeros((len(cfg.row_counters),), jnp.int32)
     out_state = []
+    rho = None  # a ZAYA router's state, from layer to layer beside ``x``
     for (mixer, mlp), lp, st in zip(cfg.layer_kinds, params["layers"], state):
         x, st, r = _mix(
             x, lp, st, mixer, pos, valid, n_valid.astype(jnp.int32), cfg, window,
             rows_apart, mesh=mesh,
         )
-        x, c = _mlp(x, lp, mlp, valid, cfg, mesh)
+        x, c, rho = _mlp(x, lp, mlp, valid, cfg, mesh, rho)
         counters = counters + c
         read = read + r
         out_state.append(st)
@@ -1222,8 +1530,13 @@ def forward(
 
 
 def logits(params: Params, cfg: HybridConfig, hidden: jnp.ndarray) -> jnp.ndarray:
-    """Final norm and the untied head, accumulated in float32."""
+    """Final norm and the head, accumulated in float32: the untied
+    matrix, or the embedding where the model ties its head to it."""
     h = rms_norm(hidden, params["final_norm"], cfg.norm_eps)
+    if cfg.tie_embeddings:
+        return jnp.einsum(
+            "...d,vd->...v", h, params["embed"], preferred_element_type=F32
+        )
     return jnp.einsum(
         "...d,dv->...v", h, params["lm_head"], preferred_element_type=F32
     )
@@ -1272,7 +1585,7 @@ def mtp_forward(
             u, lp, rows, MTP_KIND[0], pos, valid, n_valid, cfg, window, rows_apart,
             site="mtp_", mesh=mesh,
         )
-        x, counters = _mlp(x, lp, MTP_KIND[1], valid, cfg, mesh)
+        x, counters, _ = _mlp(x, lp, MTP_KIND[1], valid, cfg, mesh)
     return x, rows, jnp.concatenate([counters, read])
 
 
@@ -1448,6 +1761,37 @@ MISTRAL4_TINY = {
 }
 
 
+# Zyphra/ZAYA1-8B's config.json: every key that gives the model its shape.
+ZAYA1_8B = {
+    "model_type": "zaya", "num_hidden_layers": 40, "hidden_size": 2048,
+    "moe_intermediate_size": 2048, "num_attention_heads": 8,
+    "num_key_value_heads": 2, "head_dim": 128, "attention_bias": False,
+    "lm_head_bias": False, "hidden_act": "silu", "cca_time0": 2, "cca_time1": 2,
+    "layer_types": ["hybrid"] * 40, "num_experts": 16, "num_experts_per_tok": 1,
+    "router_hidden_size": 256, "partial_rotary_factor": 0.5,
+    "sliding_window": None, "max_position_embeddings": 131072,
+    "rope_parameters": {
+        "hybrid": {"partial_rotary_factor": 0.5, "rope_theta": 5000000, "rope_type": "default"},
+        "hybrid_sliding": {"partial_rotary_factor": 0.5, "rope_theta": 10000, "rope_type": "default"},
+        "rope_type": "default",
+    },
+    "rms_norm_eps": 1e-05, "vocab_size": 262272, "tie_word_embeddings": True,
+}
+# The first of two pipeline stages: published layers 0-19, every expert,
+# the whole tied vocabulary.
+ZAYA1_L20_CUT = {"num_hidden_layers": 20}
+# Every ratio at sizes a CPU test runs: a query latent of half the hidden
+# size (4 heads of 16 under 128) on 2 key-value heads, so that the grouped
+# mean and the value shift have two heads each; three layers, so that the
+# router's state is handed on twice; 8 experts, one a token.
+ZAYA_TINY = {
+    **ZAYA1_8B, "num_hidden_layers": 3, "hidden_size": 128,
+    "moe_intermediate_size": 32, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "head_dim": 16, "num_experts": 8,
+    "router_hidden_size": 16, "vocab_size": 512, "torch_dtype": "float32",
+}
+
+
 def ling_flash_vl_l7e128() -> HybridConfig:
     return from_hf_config({**LING_FLASH_VL, **LING_L7E128_CUT}, max_len=2048)
 
@@ -1486,6 +1830,14 @@ def mistral4_tiny() -> HybridConfig:
     )
 
 
+def zaya1_8b_l20() -> HybridConfig:
+    return from_hf_config({**ZAYA1_8B, **ZAYA1_L20_CUT}, max_len=8192)
+
+
+def zaya_tiny() -> HybridConfig:
+    return from_hf_config(ZAYA_TINY, max_len=256, kv_dtype="float32")
+
+
 PRESETS = {
     "ling-3.0-flash-vl-l7e128": ling_flash_vl_l7e128,
     "ling-tiny": ling_tiny,
@@ -1495,4 +1847,6 @@ PRESETS = {
     "exaone_moe-tiny": exaone_tiny,
     "mistral-small-4-119b-l6e32": mistral_small_4_l6e32,
     "mistral4-tiny": mistral4_tiny,
+    "zaya1-8b-l20": zaya1_8b_l20,
+    "zaya-tiny": zaya_tiny,
 }

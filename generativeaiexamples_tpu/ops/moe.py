@@ -1,7 +1,9 @@
 """A share of a wide expert layer: routing over all the published experts
 (DeepSeek-V3's ``noaux_tc``: sigmoid scores, a selection bias, a group
-limit; or the plain softmax top-k of a router with none of the three),
-and the products of the experts held here, which may be all of them.
+limit; or the plain softmax top-k of a router with none of the three; or
+the ZAYA router, a small MLP that carries its state from layer to layer
+and picks one expert: :func:`route_mlp`), and the products of the experts
+held here, which may be all of them.
 
 The layer is told which experts it holds (``offset``, ``held``).  It
 routes every token over all ``E`` router outputs, computes what its own
@@ -43,7 +45,12 @@ F32 = jnp.float32
 ROW_TILE = 128
 # What the counters vector holds, in order (``models.hybrid`` sums it
 # over layers and steps; ``Stats`` exports each under ``moe_<name>``).
-COUNTERS = ("choices_routed", "choices_local", "experts_touched", "expert_rows_max")
+COUNTERS = (
+    "choices_routed", "choices_local", "experts_touched", "expert_rows_max",
+    # Calls of ``expert_mlp``: one a layer and step, so ``experts_touched``
+    # over it is the experts a layer's step streamed.
+    "expert_layer_steps",
+)
 
 
 def select(sel, *, k, n_group, topk_group):
@@ -93,16 +100,16 @@ def route(x, w_router, bias, *, k, n_group, topk_group, norm_topk, scale,
     return idx, w * scale
 
 
-def balanced_bias(x, w_router, *, k, n_group, topk_group, iters: int = 75):
-    """The selection bias that evens the experts' load on the tokens
-    ``x`` (n, D): DeepSeek-V3's aux-loss-free balancing run to its fixed
-    point on a sample — each round the bias of an expert over the mean
-    load goes down a step and of one under it up, the step decaying from
-    0.03 to 6e-5.  This is what ``moe_router_enable_expert_bias`` leaves
-    in a trained checkpoint; random weights with an arbitrary bias load
-    the experts, and so each chip's share, unevenly by a few percent that
-    change with the seed."""
-    s = scores(x, w_router)
+def balanced_bias(s, *, k, n_group, topk_group, iters: int = 75):
+    """The selection bias that evens the experts' load on a sample of
+    tokens with routing scores ``s`` (n, E) (:func:`scores` of a linear
+    router, :func:`mlp_scores` of the ZAYA router): DeepSeek-V3's
+    aux-loss-free balancing run to its fixed point — each round the bias
+    of an expert over the mean load goes down a step and of one under it
+    up, the step decaying from 0.03 to 6e-5.  This is what
+    ``moe_router_enable_expert_bias`` leaves in a trained checkpoint;
+    random weights with an arbitrary bias load the experts, and so each
+    chip's share, unevenly by a few percent that change with the seed."""
     E = s.shape[1]
 
     def step(i, bias):
@@ -111,6 +118,46 @@ def balanced_bias(x, w_router, *, k, n_group, topk_group, iters: int = 75):
         return bias - 0.03 * 0.92**i * jnp.sign(load - load.mean())
 
     return jax.lax.fori_loop(0, iters, step, jnp.zeros((E,), F32))
+
+
+def mlp_scores(x, lp, prev, *, eps: float):
+    """The ZAYA router's scores (arXiv:2511.17127): a down-projection of
+    the token to the router's own width, the previous layer's router state
+    added in (``router_gamma`` times it; ``prev`` None: the first layer,
+    which has none before it), an RMSNorm, three products with a GELU
+    (exact, the erf form) after the first two, and the softmax over the
+    experts.  Float32 at the highest precision throughout, as
+    :func:`scores`: one expert a token means a rounded score is another
+    expert.
+
+    x: (n, D); lp: the layer's ``router_*`` leaves; prev: (n, R) float32
+    or None.  Returns (p (n, E) float32, rho (n, R) float32: this layer's
+    router state after its average, which the next layer is handed)."""
+    hi = jax.lax.Precision.HIGHEST
+    w = lambda name: lp[name].astype(F32)
+    with jax.named_scope("layer/moe/router/down"):
+        rho = jnp.dot(x.astype(F32), w("router_down"), precision=hi) + w("router_down_b")
+    if prev is not None:
+        with jax.named_scope("layer/moe/router/average"):
+            rho = rho + w("router_gamma") * prev
+    with jax.named_scope("layer/moe/router/mlp"):
+        y = rho * jax.lax.rsqrt(jnp.mean(rho * rho, axis=-1, keepdims=True) + eps)
+        y = y * w("router_norm")
+        for i in (1, 2):
+            y = jnp.dot(y, w(f"router_w{i}"), precision=hi) + w(f"router_b{i}")
+            y = jax.nn.gelu(y, approximate=False)
+        logits = jnp.dot(y, w("router_w3"), precision=hi) + w("router_b3")
+        return jax.nn.softmax(logits, axis=-1), rho
+
+
+def route_mlp(x, lp, prev, *, eps: float):
+    """:func:`route` for the ZAYA router: the one expert with the largest
+    ``p + router_bias`` (a tie to the lower index), weighted by its
+    probability as it is (renormalised over one choice it would be 1).
+    Returns (idx (n, 1) int32, weights (n, 1) float32, rho (n, R))."""
+    p, rho = mlp_scores(x, lp, prev, eps=eps)
+    idx = select(p + lp["router_bias"].astype(F32), k=1, n_group=1, topk_group=1)
+    return idx, jnp.take_along_axis(p, idx, axis=-1), rho
 
 
 def use_gmm(mesh) -> bool:
@@ -165,8 +212,8 @@ def expert_mlp(x, idx, weights, valid, lp, *, offset: int, held: int, mesh=None)
 
     x: (n, D); idx, weights: (n, k); valid: (n,) bool (a padded position
     routes nowhere); lp: ``w_gu_e`` (held, D, 2F) gate and up side by
-    side, ``w_down_e`` (held, F, D).  Returns (y (n, D), counters (4,)
-    int32 in the order of ``COUNTERS``)."""
+    side, ``w_down_e`` (held, F, D).  Returns (y (n, D), counters int32
+    in the order of ``COUNTERS``)."""
     n, d = x.shape
     k = idx.shape[1]
     f = lp["w_down_e"].shape[1]
@@ -195,7 +242,7 @@ def expert_mlp(x, idx, weights, valid, lp, *, offset: int, held: int, mesh=None)
         w_local = jnp.where(local, weights, 0.0)
         y = jnp.where(local[..., None], per_choice * w_local[..., None], 0.0).sum(1)
     counters = jnp.stack(
-        [valid.sum().astype(jnp.int32) * k, n_local, (sizes > 0).sum(), sizes.max()]
+        [valid.sum().astype(jnp.int32) * k, n_local, (sizes > 0).sum(), sizes.max(), 1]
     ).astype(jnp.int32)
     return y.astype(x.dtype), counters
 

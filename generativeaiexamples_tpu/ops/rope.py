@@ -163,3 +163,15 @@ def apply_rope_spec(x: jnp.ndarray, positions: jnp.ndarray, spec: RopeSpec) -> j
     cos = (jnp.cos(angles) * spec.attention_factor)[:, :, None, :].astype(x.dtype)
     sin = (jnp.sin(angles) * spec.attention_factor)[:, :, None, :].astype(x.dtype)
     return _rotate_half(x, cos, sin)
+
+
+def apply_rope_partial(
+    x: jnp.ndarray, positions: jnp.ndarray, spec: RopeSpec, rotary_dim: int
+) -> jnp.ndarray:
+    """:func:`apply_rope_spec` over the first ``rotary_dim`` values of each
+    head (``partial_rotary_factor``: half-split pairs inside that part, its
+    frequencies those of a head ``rotary_dim`` wide); the rest passes."""
+    if rotary_dim >= x.shape[-1]:
+        return apply_rope_spec(x, positions, spec)
+    turned = apply_rope_spec(x[..., :rotary_dim], positions, spec)
+    return jnp.concatenate([turned, x[..., rotary_dim:]], axis=-1)

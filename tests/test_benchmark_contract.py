@@ -208,9 +208,11 @@ def test_cell_rehearses_end_to_end_on_the_cpu(cell, compile_cache, monkeypatch):
     assert proc.returncode == 0, proc.stderr[-3000:]
     lines = [json.loads(ln) for ln in proc.stdout.strip().splitlines()]
     line = lines[-1]
-    assert line["failed"] == 0 and line["attempted"] > 0
-    assert line["device"]["platform"] == "cpu" and "rehearsal" in line
     (window,) = [ln for ln in lines if ln.get("bench") == "window"]
+    # A red rehearsal says which request failed: the harness's own record
+    # of the window (finishes by client, the counters, the longest ticks).
+    assert line["failed"] == 0 and line["attempted"] > 0, json.dumps(window)[:6000]
+    assert line["device"]["platform"] == "cpu" and "rehearsal" in line
     listed = {m["name"] for m in SPEC["end_to_end"] if cell in _cells_of(m)}
     assert window["ttft_samples"] == line["attempted"]
     closed_and_none_ended = (

@@ -281,6 +281,7 @@ GROUP_PROGRAMS = {
     "ling": ("ling-3.0-flash-vl-l7e128", 8, 2048),
     "exaone": ("k-exaone-236b-a23b-l5e16", 8, 8192),
     "mistral4": ("mistral-small-4-119b-l6e32", 8, 32768),
+    "zaya": ("zaya1-8b-l20", 8, 8192),
 }
 # What Mellum's cell has to spare beside its weights, slots and snapshots
 # (peak 15.19 of the 16.91 GB the build sees, less the reference check's
@@ -293,7 +294,11 @@ SPARE_BYTES = 1_400_000_000
 # the state in place (no window is gathered), a block of 1,024 keys at a
 # time: the largest temporaries are a block's float32 scores (32 heads x
 # 256 x 1,024: 33.6 MB) and the experts' combine.
-SPARE_BY_FAMILY = {"exaone": 2_500_000_000, "mistral4": 400_000_000}
+# ZAYA1's cut holds 9.38 GB of weights and 5.37 GB of K/V rows: 2.1 GB to
+# spare.  Its group of 8 rows gathers each row's window of 256-wide rows (8
+# query heads' scores are 67 MB a row at 8,192); the compiler here counts
+# 0.26 GB for the chunks alone.
+SPARE_BY_FAMILY = {"exaone": 2_500_000_000, "mistral4": 400_000_000, "zaya": 800_000_000}
 
 
 @pytest.mark.parametrize("family", sorted(GROUP_PROGRAMS))
@@ -484,6 +489,56 @@ def test_the_verify_chunk_compiles_at_the_published_widths(one_chip, monkeypatch
     assert aux.shape == (len(serving.counter_names),)
     print("verify chunk temporaries", compiled.memory_analysis().temp_size_in_bytes)
     assert compiled.memory_analysis().temp_size_in_bytes < SPARE_BY_FAMILY["exaone"]
+
+
+def test_the_cca_models_decode_chunk_walks_its_rows_in_place(one_chip, monkeypatch):
+    """The decode chunk of zaya1-8b-l20 (8 steps over 32 slots of 8,192
+    rows, twenty ``cca`` layers): every layer's attention is the row walk
+    of ``ops/gqa_decode.py`` at 8 query heads on 2 key-value heads over
+    rows 256 wide, the scatter that writes a step's rows feeds it in place
+    (no K or V leaf is copied), and beside 14.75 GB of weights and state
+    the program's temporaries are the float32 logits of 32 rows x 262,272
+    and little else."""
+    import json
+    from pathlib import Path
+
+    from generativeaiexamples_tpu.engine.serving_models import HybridServing
+    from generativeaiexamples_tpu.models import hybrid
+    from generativeaiexamples_tpu.ops import gqa_decode, moe
+
+    monkeypatch.setattr(moe, "platform_of", lambda mesh: "tpu")
+    monkeypatch.setattr(gqa_decode, "platform_of", lambda mesh: "tpu")
+    configs = Path(__file__).resolve().parents[1] / "benchmarks" / "configs"
+    model = json.loads((configs / "zaya1-8b-l20.json").read_text())
+    engine = model["engine"]
+    max_len, b, steps = int(engine["max_len"]), int(engine["max_batch"]), int(engine["decode_chunk_size"])
+    cfg = hybrid.from_hf_config(model, max_len=max_len, kv_dtype=engine["kv_dtype"])
+    assert isinstance(cfg, hybrid.CcaConfig) and cfg.n_layers == 20
+    serving = HybridServing(cfg, None, max_len)
+
+    def described(make):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            jax.eval_shape(make),
+        )
+
+    spec = _spec(one_chip)
+    ints, floats = spec((b,), jnp.int32), spec((b,), jnp.float32)
+    compiled = serving.make_decode_chunk().lower(
+        described(lambda: hybrid.init_params(cfg, jax.random.PRNGKey(0))),
+        described(lambda: hybrid.init_state(cfg, b, max_len)),
+        ints, ints, spec((2,), jnp.uint32), floats, floats, ints, steps, max_len,
+        spec((b,), jnp.bool_),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count("gqa_rows_decode_attention") >= 20  # a walk a layer
+    assert not re.search(rf"= bf16\[{b},{max_len},256\]\S* copy\(", text)
+    _, toks, aux = compiled.out_info
+    assert toks.shape == (steps, b) and aux.shape == (len(serving.counter_names),)
+    memory = compiled.memory_analysis()
+    print("cca decode chunk temporaries", memory.temp_size_in_bytes)
+    assert memory.temp_size_in_bytes < 200_000_000
+    assert memory.alias_size_in_bytes >= 5_368_709_120  # the slots' state goes through in place
 
 
 def test_the_latent_models_decode_chunk_keeps_its_state_in_place(one_chip, monkeypatch):

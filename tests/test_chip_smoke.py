@@ -322,6 +322,42 @@ def test_hybrid_phase_rehearsal_of_the_latent_model_and_its_controls(control, ca
         assert line["p50"] > 0.05
 
 
+ZAYA_CONTROLS = ["no_value_shift", "no_qk_mean", "no_conv", "no_router_average", "renormed_top1", "w8a8"]
+
+
+@pytest.mark.parametrize("control", ["", *ZAYA_CONTROLS])
+def test_hybrid_phase_rehearsal_of_the_cca_model_and_its_controls(control, capsys, monkeypatch, tmp_path):
+    """``--hybrid --model zaya`` at the tiny size, in process: a prompt of
+    45 tokens in chunks of 16 through the chunk program beside a pad row
+    (the last chunk padded; every chunk after the first continues from the
+    slot's tails), its last 8 positions through the decode step over both
+    slots, by the benchmark's own comparison.  The limits are the
+    configuration's, set for the chip's size and precision; float32 at this
+    size reads 1e-7, so the rehearsal holds it to limits of its own, which
+    the sound run is far inside and each control leaves."""
+    config = json.loads(open(os.path.join(REPO, chip_smoke.ZAYA_CONFIG)).read())
+    config["reference"]["logit_share_limits"] = MISTRAL4_TINY_LIMITS
+    tiny = tmp_path / "config.json"
+    tiny.write_text(json.dumps(config))
+    monkeypatch.setattr(chip_smoke, "ZAYA_CONFIG", str(tiny))
+    # A sound run inside the limits and a control outside them both return.
+    chip_smoke.child_hybrid(0, chip_smoke.TINY, control=control, model="zaya")
+    line = _phases(capsys)["hybrid"]
+    assert line["model"] == "zaya-tiny" and line["control"] == (control or None)
+    assert line["positions"] == {"prefill": 37, "decode": 8}
+    # The shapes of the scheduler's programs: a chunk beside a pad row over
+    # the whole slot (256 rows here), a decode step over both slots.
+    assert "attn_cca b=2 s=16 t=256" in line["kernel_paths"]
+    assert "attn_cca b=2 s=1 t=256" in line["kernel_paths"]
+    assert line["within_limits"] == (not control)
+    if control == "w8a8":  # the precision: every position moves, by little
+        assert 1e-3 < line["p10"] < 0.2
+    elif control == "no_router_average":  # positions whose choice flips in some layer
+        assert line["p90"] > 1e-2
+    elif control:  # a mechanism of the mixer or the weighting: every position, by much
+        assert line["p10"] > 1e-2 and line["decode_p50"] > 1e-2
+
+
 def test_hybrid_phase_names_a_child_for_every_model_and_control():
     assert sorted(n for n in chip_smoke.CHILDREN if n.startswith("hybrid")) == [
         "hybrid_exaone_moe", "hybrid_exaone_moe_no_qk_norm", "hybrid_exaone_moe_no_window",
@@ -330,6 +366,7 @@ def test_hybrid_phase_names_a_child_for_every_model_and_control():
         "hybrid_mellum_no_yarn", "hybrid_mellum_w8a8_mlp",
         "hybrid_mistral4", "hybrid_mistral4_no_attn_scale", "hybrid_mistral4_no_mscale",
         "hybrid_mistral4_no_q_norm", "hybrid_mistral4_plain_rope", "hybrid_mistral4_w8a8_mlp",
+        "hybrid_zaya", *(f"hybrid_zaya_{c}" for c in sorted(ZAYA_CONTROLS)),
     ]
     with pytest.raises(chip_smoke.SmokeFailure, match="has no control 'no_yarn'"):
         chip_smoke.run(0, chip_smoke.TINY, expect="cpu", hybrid=("ling", "no_yarn"))

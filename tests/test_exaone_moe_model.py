@@ -203,7 +203,8 @@ def test_a_whole_sequence_and_the_modules_logits_match_the_reference(model, para
     np.testing.assert_allclose(state[L]["k"][0, : N - 1], rows["k"][0, : N - 1], atol=1e-5)
     np.testing.assert_allclose(state[L + 1]["h_last"][0], hidden[0, -1], atol=0)
     # Rows read: 6 window layers x their ring, (2 full layers + the module) x N.
-    assert counters.tolist()[4:12] == [0, 0, 0, 0, 6 * W, 3 * N, 6 * N, 3 * N]
+    first = len(hybrid.moe.COUNTERS) + 2  # behind the expert counters and their decode-only pair
+    assert counters.tolist()[first : first + 8] == [0, 0, 0, 0, 6 * W, 3 * N, 6 * N, 3 * N]
 
 
 # -- (b) chunks, then the verify step -------------------------------------------------
@@ -460,7 +461,7 @@ def test_the_four_shares_add_up_to_the_uncut_reference(params):
         cfg = dataclasses.replace(CFG, expert_offset=4 * share)
         mine = {**lp, "w_gu_e": lp["w_gu_e"][4 * share : 4 * share + 4],
                 "w_down_e": lp["w_down_e"][4 * share : 4 * share + 4]}
-        y, counters = hybrid._expert_layer(h, mine, valid, cfg, None)
+        y, counters, _ = hybrid._expert_layer(h, mine, valid, cfg, None)
         shared = hybrid._swiglu(h[0], lp["w_gu_s"], lp["w_down_s"])
         total = total + (y[0] - shared)  # this share's routed part alone
         # The plain reference, given the same share, leaves out the same.

@@ -148,7 +148,7 @@ def test_the_four_shares_add_up_to_the_uncut_reference(params):
         cfg = dataclasses.replace(CFG, expert_offset=8 * share)
         mine = {**lp, "w_gu_e": lp["w_gu_e"][8 * share : 8 * share + 8],
                 "w_down_e": lp["w_down_e"][8 * share : 8 * share + 8]}
-        y, counters = hybrid._expert_layer(h, mine, valid, cfg, None)
+        y, counters, _ = hybrid._expert_layer(h, mine, valid, cfg, None)
         shared = hybrid._swiglu(h[0], lp["w_gu_s"], lp["w_down_s"])
         total = total + (y[0] - shared)  # this share's routed part alone
         # The plain reference, given the same share, leaves out the same.

@@ -189,7 +189,7 @@ def test_sorted_dispatch_matches_every_expert_computed_whole(kernel, monkeypatch
                 rows[e - offset] += 1
     np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-5)
     assert not np.asarray(y)[40:].any()  # a padded position routes nowhere
-    assert counters.tolist() == [40 * 4, rows.sum(), (rows > 0).sum(), rows.max()]
+    assert counters.tolist() == [40 * 4, rows.sum(), (rows > 0).sum(), rows.max(), 1]  # one call
 
 
 def test_balanced_bias_evens_the_experts_load():
@@ -204,7 +204,7 @@ def test_balanced_bias_evens_the_experts_load():
     def tokens(seed, n):
         return jnp.asarray(np.random.RandomState(seed).randn(n, D), F32) + shift
 
-    bias = moe.balanced_bias(tokens(0, 4096), w, k=4, n_group=8, topk_group=4)
+    bias = moe.balanced_bias(moe.scores(tokens(0, 4096), w), k=4, n_group=8, topk_group=4)
 
     def worst_load(b):
         idx, _ = moe.route(tokens(1, 4096), w, b, k=4, n_group=8, topk_group=4,
@@ -327,3 +327,112 @@ def test_lings_latent_layer_is_bit_for_bit_what_it_was(s, apart):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     np.testing.assert_array_equal(np.asarray(got_st["latent"]), np.asarray(want_st["latent"]))
     assert set(got_st) == {"latent"}
+
+
+# -- compressed convolutional attention: the pieces before the attention ---------
+
+
+def _in_pieces(fn, x, tail, pieces):
+    """``fn(x_piece, tail) -> (y, xin)`` over ``x`` cut into pieces, each
+    continued from the tail the piece before left."""
+    out, at = [], 0
+    for n in pieces:
+        y, xin = fn(x[:, at : at + n], tail)
+        tail = kda.next_tail(xin, jnp.full((x.shape[0],), n, jnp.int32), tail.shape[1] + 1)
+        out.append(y)
+        at += n
+    return jnp.concatenate(out, axis=1), tail
+
+
+@pytest.mark.parametrize("width", [2, 3])
+def test_head_conv_mixes_a_heads_channels_and_continues_from_its_tail(width):
+    """``cca.head_conv`` against the sum written out: ``z_t[j] = sum_w
+    a_{t - (W-1-w)}[j] W[w, j] + b[j]`` with zeros before the first token;
+    cut into pieces of 1, 2, 3 and 5 tokens it gives the same, and a head's
+    output depends on no other head."""
+    from generativeaiexamples_tpu.ops import cca
+
+    r = np.random.RandomState(0)
+    b, s, J, d = 2, 11, 3, 4
+    x = jnp.asarray(r.randn(b, s, J * d), F32)
+    w = jnp.asarray(r.randn(width, J, d, d), F32)
+    bias = jnp.asarray(r.randn(J * d), F32)
+    zeros = jnp.zeros((b, width - 1, J * d), F32)
+    y, xin = cca.head_conv(x, zeros, w, bias)
+    assert y.shape == (b, s, J, d) and xin.shape == (b, s + width - 1, J * d)
+    xh = np.asarray(x).reshape(b, s, J, d)
+    want = np.zeros((b, s, J, d), np.float32) + np.asarray(bias).reshape(J, d)
+    for t in range(s):
+        for tap in range(width):
+            back = width - 1 - tap
+            if t - back >= 0:
+                want[:, t] += np.einsum("bjd,jde->bje", xh[:, t - back], np.asarray(w)[tap])
+    np.testing.assert_allclose(y, want, atol=1e-5)
+    pieces, _ = _in_pieces(lambda p, tail: cca.head_conv(p, tail, w, bias), x, zeros, (1, 2, 3, 5))
+    np.testing.assert_allclose(pieces, y, atol=1e-6)
+    other = x.at[:, :, d:].add(1.0)  # every head but the first
+    np.testing.assert_array_equal(np.asarray(cca.head_conv(other, zeros, w, bias)[0])[:, :, 0], np.asarray(y)[:, :, 0])
+
+
+def test_the_depthwise_step_and_the_value_shift_share_kda_s_tail():
+    """Step one of the convolution is ``kda.causal_conv`` at a kernel of 2,
+    and ``cca.shift`` is a tail of one: in pieces both give what they give
+    whole, and ``next_tail`` moves neither past tokens that do not count."""
+    from generativeaiexamples_tpu.ops import cca
+
+    r = np.random.RandomState(1)
+    x = jnp.asarray(r.randn(2, 11, 6), F32)
+    w = jnp.asarray(r.randn(2, 6), F32)
+    zeros = jnp.zeros((2, 1, 6), F32)
+    y, _ = kda.causal_conv(x, zeros, w)
+    before = np.concatenate([np.zeros((2, 1, 6), np.float32), np.asarray(x)[:, :-1]], axis=1)
+    np.testing.assert_allclose(y, np.asarray(w)[0] * before + np.asarray(w)[1] * np.asarray(x), atol=1e-6)
+    shifted, xin = cca.shift(x, zeros)
+    np.testing.assert_array_equal(np.asarray(shifted), before)
+    for fn in (lambda p, t: kda.causal_conv(p, t, w), cca.shift):
+        whole, _ = fn(x, zeros)
+        pieces, tail = _in_pieces(fn, x, zeros, (1, 2, 3, 5))
+        np.testing.assert_allclose(pieces, whole, atol=1e-6)
+        np.testing.assert_array_equal(np.asarray(tail), np.asarray(x)[:, -1:])
+    # Row 0 counts 4 of its tokens, row 1 none: its tail stays what it was.
+    tail = jnp.asarray(r.randn(2, 1, 6), F32)
+    _, xin = cca.shift(x, tail)
+    moved = kda.next_tail(xin, jnp.asarray([4, 0], jnp.int32), 2)
+    np.testing.assert_array_equal(np.asarray(moved)[0], np.asarray(x)[0, 3:4])
+    np.testing.assert_array_equal(np.asarray(moved)[1], np.asarray(tail)[1])
+
+
+def test_the_qk_mean_goes_to_a_query_head_and_to_its_groups_key_head():
+    from generativeaiexamples_tpu.ops import cca
+
+    r = np.random.RandomState(2)
+    b, s, H, G, d = 2, 3, 4, 2, 5
+    z = jnp.asarray(r.randn(b, s, H + G, d), F32)
+    u = jnp.asarray(r.randn(b, s, (H + G) * d), F32)
+    q, k = cca.add_qk_mean(z, u, H)
+    uh = np.asarray(u).reshape(b, s, H + G, d)
+    for i in range(H):
+        m = (uh[:, :, i] + uh[:, :, H + i // 2]) / 2
+        np.testing.assert_allclose(np.asarray(q)[:, :, i], np.asarray(z)[:, :, i] + m, atol=1e-6)
+    for g in range(G):
+        m = ((uh[:, :, 2 * g] + uh[:, :, 2 * g + 1]) / 2 + uh[:, :, H + g]) / 2
+        np.testing.assert_allclose(np.asarray(k)[:, :, g], np.asarray(z)[:, :, H + g] + m, atol=1e-6)
+
+
+def test_partial_rotation_turns_the_first_part_of_a_head_and_passes_the_rest():
+    from generativeaiexamples_tpu.ops import rope
+
+    spec = rope.RopeSpec(theta=5e6)
+    x = jnp.asarray(np.random.RandomState(3).randn(2, 5, 3, 16), F32)
+    pos = jnp.asarray([[0, 1, 2, 3, 4], [9, 10, 11, 12, 13]], jnp.int32)
+    got = rope.apply_rope_partial(x, pos, spec, 8)
+    np.testing.assert_array_equal(np.asarray(got)[..., 8:], np.asarray(x)[..., 8:])
+    # Half-split pairs (j, j + 4) of the first 8, frequencies of a head 8 wide.
+    inv = 5e6 ** (-np.arange(0, 8, 2) / 8)
+    ang = np.asarray(pos, np.float64)[..., None, None] * inv
+    x1, x2 = np.asarray(x)[..., :4], np.asarray(x)[..., 4:8]
+    np.testing.assert_allclose(np.asarray(got)[..., :4], x1 * np.cos(ang) - x2 * np.sin(ang), atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got)[..., 4:8], x2 * np.cos(ang) + x1 * np.sin(ang), atol=1e-5)
+    np.testing.assert_array_equal(
+        np.asarray(rope.apply_rope_partial(x, pos, spec, 16)), np.asarray(rope.apply_rope_spec(x, pos, spec)))
+    np.testing.assert_array_equal(np.asarray(got)[0, 0], np.asarray(x)[0, 0])  # position 0 turns nothing

@@ -292,15 +292,116 @@ def test_mellum_next_occupants_start_from_nothing_and_metrics_are_exported(mellu
         assert f"\n{name} " in metrics, name
 
 
+# -- the ``zaya`` family: rows and tails in every layer ---------------------------------
+
+
+@pytest.fixture(scope="module")
+def zaya():
+    # What engine.server.main() builds for --model zaya-tiny.
+    cfg = hybrid.PRESETS[resolve_model_preset("zaya-tiny")]()
+    s = Scheduler(
+        cfg, None, max_batch=4, max_len=256, decode_chunk_size=4, seed=9,
+        prefill_chunk_tokens=CHUNK, prefix_cache="shared",
+    )
+    s.start()
+    yield s
+    s.stop()
+
+
+def _zaya_gap(scheduler, prompt, out, pad_to=192):
+    """``_mellum_gap`` against ``zaya_reference``."""
+    from generativeaiexamples_tpu.models import zaya_reference
+
+    seq = list(prompt) + list(out)
+    lg = np.asarray(zaya_reference.all_logits(
+        scheduler.params, scheduler.cfg, seq + [0] * (pad_to - len(seq))))
+    rows = lg[len(prompt) - 1 : len(seq) - 1]
+    return float((rows.max(-1) - rows[np.arange(len(out)), out]).max())
+
+
+def test_zaya_cold_prompts_a_hit_restored_from_a_snapshot_of_tails_and_reused_slots(zaya):
+    """A layer with both sorts of state on the serving path: greedy tokens
+    equal the reference's (to a near-tie) for a cold batch, a chunked
+    prompt, a prefix hit whose rows are grafted and whose tails come from
+    a snapshot of tails alone, and slots whose last occupant left tails."""
+    cfg = zaya.cfg
+    tails = 3 * (2 * 96 + 16) * 4  # three layers: u, a (96 channels each) and the late values (16)
+    assert not zaya.model.cut_anywhere
+    assert zaya._snapshots.bytes_each == cfg.snapshot_bytes(256) == tails
+    snap0 = zaya.stats.snapshot()
+    assert snap0["state_bytes_full"] == 4 * 3 * 2 * 256 * 32 * 4 and snap0["state_bytes_window"] == 0
+    cold = [_prompt(61, 20), _prompt(62, 31)]
+    for p, o in zip(cold, _generate(zaya, cold)):
+        assert len(o) == 6 and _zaya_gap(zaya, p, o) <= GAP
+    first = _prompt(63, 100)  # chunks of 32: snapshots at 32, 64, 96
+    before = zaya.stats.snapshot()
+    (out,) = _generate(zaya, [first])
+    mid = zaya.stats.snapshot()
+    assert mid["prefill_chunks"] - before["prefill_chunks"] == 4
+    assert mid["state_snapshots_saved"] - before["state_snapshots_saved"] == 3
+    assert mid["state_snapshot_bytes"] == len(zaya._snapshots) * tails
+    assert _zaya_gap(zaya, first, out) <= GAP
+    again = first[:70] + _prompt(64, 25)  # rows match to 70, the tails exist at 64
+    (hit,) = _generate(zaya, [again])
+    after = zaya.stats.snapshot()
+    assert after["shared_prefix_hits"] - mid["shared_prefix_hits"] == 1
+    assert after["state_snapshots_restored"] - mid["state_snapshots_restored"] == 1
+    assert after["prefix_tokens_matched"] - mid["prefix_tokens_matched"] == 70
+    assert after["prefix_tokens_reused"] - mid["prefix_tokens_reused"] == 64
+    assert _zaya_gap(zaya, again, hit) <= GAP
+    for seed in (65, 66, 67, 68, 69):  # more prompts than slots: every slot is reused
+        p = _prompt(seed, 70)
+        (o,) = _generate(zaya, [p], n=3)
+        assert _zaya_gap(zaya, p, o) <= GAP
+    end = zaya.stats.snapshot()
+    # The mixer's rows are counted as a full layer's; one choice a token,
+    # every expert here; the decode-only pair says what a step streamed.
+    for phase in ("decode", "prefill"):
+        assert 0 < end[f"attn_rows_read_full_{phase}"] <= end[f"attn_rows_dense_full_{phase}"]
+        assert end[f"attn_rows_read_window_{phase}"] == 0
+    assert end["moe_choices_local"] == end["moe_choices_routed"] > 0
+    steps = end["moe_expert_layer_steps_decode"]
+    assert 0 < steps < end["moe_expert_layer_steps"] and steps % 3 == 0
+    assert steps <= end["moe_experts_touched_decode"] <= 4 * steps  # 1 to 4 live rows a step
+
+
+def test_zaya_metrics_are_exported(zaya):
+    from generativeaiexamples_tpu.engine.server import create_engine_app
+    from generativeaiexamples_tpu.engine.tokenizer import ByteTokenizer
+
+    (o,) = _generate(zaya, [_prompt(70, 40)], n=3)
+    app = create_engine_app(zaya, ByteTokenizer(), model_name="zaya-tiny")
+    loop = asyncio.new_event_loop()
+    client = TestClient(TestServer(app), loop=loop)
+    loop.run_until_complete(client.start_server())
+
+    async def go():
+        return await (await client.get("/metrics")).text(), await (await client.get("/health")).json()
+
+    try:
+        metrics, health = loop.run_until_complete(go())
+    finally:
+        loop.run_until_complete(client.close())
+        loop.close()
+    for name in ("engine_moe_experts_touched_decode_total", "engine_moe_expert_layer_steps_decode_total",
+                 "engine_moe_expert_layer_steps_total", "engine_attn_rows_read_full_decode_total",
+                 "engine_attn_rows_dense_full_prefill_total", "engine_state_bytes_full",
+                 "engine_state_snapshot_bytes", "engine_state_snapshots_saved_total"):
+        assert f"\n{name} " in metrics, name
+    paths = health["runtime"]["kernel_paths"]
+    assert any(site.startswith("attn_cca b=4 s=1 ") for site in paths), sorted(paths)
+    assert all(taken == "xla" for site, taken in paths.items() if site.startswith("attn_cca"))
+
+
 # -- the chunks of several slots in one program ---------------------------------------
 
 
-@pytest.mark.parametrize("family", ["ling", "mellum"])
+@pytest.mark.parametrize("family", ["ling", "mellum", "zaya"])
 def test_prompts_that_warm_side_by_side_are_held_to_the_reference(family, request):
     """Three chunked prompts admitted together: from their second chunk on
     a tick sends their chunks as one program (three rows padded to four,
     then two), and each greedy stream is the reference's."""
-    s = request.getfixturevalue("scheduler" if family == "ling" else "mellum")
+    s = request.getfixturevalue({"ling": "scheduler"}.get(family, family))
     prompts = [_prompt(50 + i, n) for i, n in enumerate((100, 120, 70))]
     before = s.stats.snapshot()
     outs = _generate(s, prompts, n=4)
@@ -309,7 +410,7 @@ def test_prompts_that_warm_side_by_side_are_held_to_the_reference(family, reques
     assert chunks == 4 + 4 + 3
     # Alone: each prompt's first chunk and the longest's fourth; together: two of three, one of two.
     assert after["prefill_chunk_programs"] - before["prefill_chunk_programs"] < chunks
-    gap = _worst_gap if family == "ling" else _mellum_gap
+    gap = {"ling": _worst_gap, "mellum": _mellum_gap, "zaya": _zaya_gap}[family]
     for p, o in zip(prompts, outs):
         assert len(o) == 4 and gap(s, p, o) <= GAP
 
@@ -324,7 +425,7 @@ SLOTS, MAX_LEN, WINDOW, S = 6, 128, 64, 8
 ROWS = ((4, 0, 8), (1, 40, 8), (3, 19, 5))
 
 
-@pytest.fixture(scope="module", params=["ling-tiny", "mellum-tiny"])
+@pytest.fixture(scope="module", params=["ling-tiny", "mellum-tiny", "zaya-tiny"])
 def rows_case(request):
     """A serving model, its parameters, and slots whose state is what
     ``prefill_row`` left of each row's prompt so far; every slot that is
@@ -396,12 +497,14 @@ def test_rows_of_a_group_get_what_each_gets_alone(rows_case, n_rows):
 @pytest.mark.parametrize("config, chunks", [
     ("mellum2-12b-a2.5b-l12", 4), ("ling-3.0-flash-vl-l7e128", 8),
     ("mistral-7b", 1), ("mixtral-8x7b-l4", 2), ("mistral-small-4-119b-l6e32", 8),
+    ("zaya1-8b-l20", 8),
 ])
 def test_how_many_chunks_share_a_program_follows_from_the_rows_an_expert_sees(config, chunks):
     """256 tokens x 8 choices over 64 experts are 32 rows an expert: 4
     chunks fill ``gmm``'s row tile of 128; over 512 experts they are 4
     rows: the cap of 8; Mixtral's 2 choices over 8 experts are 64 rows:
-    2 chunks; a dense projection sees every token: a chunk goes alone."""
+    2 chunks; ZAYA's one choice over 16 experts is 16 rows: 8 chunks; a
+    dense projection sees every token: a chunk goes alone."""
     import importlib
     from pathlib import Path
 

@@ -278,13 +278,13 @@ def test_the_four_shares_of_the_experts_add_up_to_the_uncut_layer(params):
     all_gu = jax.random.normal(rng, (32, CFG.d_model, 2 * CFG.moe_d_ff)) * CFG.d_model**-0.5
     all_down = jax.random.normal(jax.random.fold_in(rng, 1), (32, CFG.moe_d_ff, CFG.d_model)) * CFG.moe_d_ff**-0.5
     shared = hybrid._swiglu(h.reshape(-1, CFG.d_model), lp["w_gu_s"], lp["w_down_s"]).reshape(h.shape)
-    whole, _ = hybrid._expert_layer(h, {**lp, "w_gu_e": all_gu, "w_down_e": all_down}, valid, whole_cfg, None)
+    whole, _, _ = hybrid._expert_layer(h, {**lp, "w_gu_e": all_gu, "w_down_e": all_down}, valid, whole_cfg, None)
     parts, ref_parts = [], []
     dims = ref._dims(CFG, None, None)
     for rank in range(4):
         cfg = dataclasses.replace(CFG, expert_offset=8 * rank)
         share = {**lp, "w_gu_e": all_gu[8 * rank : 8 * rank + 8], "w_down_e": all_down[8 * rank : 8 * rank + 8]}
-        y, counters = hybrid._expert_layer(h, share, valid, cfg, None)
+        y, counters, _ = hybrid._expert_layer(h, share, valid, cfg, None)
         parts.append(y - shared)
         ref_parts.append(ref.routed_experts(h[0], share, {**dims, "offset": 8 * rank}))
     np.testing.assert_allclose(sum(parts) + shared, whole, atol=1e-5)
