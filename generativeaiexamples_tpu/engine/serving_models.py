@@ -322,10 +322,12 @@ class HybridServing:
         self.cut_anywhere = cfg.rows_only
         # Latent rows alone, attended in blocks: the chunk programs read
         # and write the slots' state in place (``_prefill_rows_in_place``),
-        # and one window serves them all (``chunk_windows``).  The ``cca``
-        # kind's rows are written in place too, over the doubling windows.
+        # and one window serves them all (``chunk_windows``).  The ``full``
+        # and ``cca`` kinds' rows are written and read in place too, over
+        # the doubling windows; only latent rows attended whole (Ling's)
+        # have their windows taken out and put back.
         self.one_window = cfg.rows_only and bool(cfg.latent_block)
-        self.rows_in_place = self.one_window or bool(cfg.layers_of("cca"))
+        self.rows_in_place = bool(cfg.latent_block) or not cfg.layers_of("mla")
         self.snapshot_bytes = cfg.snapshot_bytes(max_len)
         # ``forward``'s counters; the experts touched and the expert
         # layers run are counted again for decode steps alone
@@ -569,23 +571,30 @@ class HybridServing:
 
     def _prefill_rows_in_place(self, params, cache, tokens, start, suffix_len, slots, window):
         """``prefill_rows`` for a model whose layers work on the slots'
-        rows where they lie (latent rows attended in blocks; a ``cca``
-        layer's K/V rows): every layer is handed the slots' whole rows and
-        which slot each row of the call is, writes a chunk's rows where they
-        belong and reads from there (a latent layer a row's blocks, a
-        ``cca`` layer the call's windows, gathered for that layer alone), so
-        no window of the state is written back, and none is held for more
-        than a layer (at 8 rows of 32,768 latent rows that copy is 0.2 GB a
-        layer each way; at 8 rows of 8,192 over twenty ``cca`` layers the
-        windows gathered before the stack and their updated copies were
-        2.5 GB of temporaries beside 14.75 GB of weights and state).  What a layer keeps as of the last token (a
-        ``cca`` layer's tails: small) is taken by slot and put back, as in
+        rows where they lie (latent rows attended in blocks; a ``full`` or
+        ``cca`` layer's K/V rows, a prediction module's block among them):
+        every layer is handed the slots' whole rows and which slot each row
+        of the call is, writes a chunk's rows where they belong and reads
+        from there (a latent layer a row's blocks; a ``full`` or ``cca``
+        layer the rows its slot holds, by ``ops/gqa_decode.py``'s chunk
+        kernel, or, where its gate refuses, the call's windows gathered for
+        that layer alone), so no window of the state is written back, and
+        none is held for more than a layer (at 8 rows of 32,768 latent rows
+        that copy is 0.2 GB a layer each way; at 8 rows of 8,192 over twenty
+        ``cca`` layers the windows gathered before the stack and their
+        updated copies were 2.5 GB of temporaries beside 14.75 GB of weights
+        and state).  What a layer keeps whatever the length (a window
+        layer's ring) or as of the last token (a ``cca`` layer's tails,
+        ``h_last``: small) is taken by slot and put back, as in
         ``prefill_rows``.  A pad row writes nothing (none of its tokens
         counts) and reads nothing."""
         live = suffix_len > 0
 
         def take(name, leaf):
-            return leaf if name in hybrid.ROW_LEAVES else _from_nothing(leaf[slots], start)
+            if name in hybrid.ROW_LEAVES:
+                return leaf
+            row = leaf[slots]
+            return row if name in hybrid.RING_LEAVES else _from_nothing(row, start)
 
         rows = tuple(
             {**{n: take(n, leaf) for n, leaf in layer.items()}, "slot": slots} for layer in cache
@@ -594,7 +603,13 @@ class HybridServing:
             params, self.cfg, tokens, start, suffix_len, rows, window=window,
             mesh=self.mesh, rows_apart=True,
         )
-        # A pad row's slot is past the last: its tails' write is dropped.
+        if self.draft:
+            rows, c = self._module_behind(
+                params, hidden, tokens, start, suffix_len, rows, window, apart=True
+            )
+            counters = counters + c
+        # A pad row's slot is past the last: its rings' and tails' write is
+        # dropped.
         dest = jnp.where(live, slots, jax.tree.leaves(cache)[0].shape[0])
         cache = tuple(
             {

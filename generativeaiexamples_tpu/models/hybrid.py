@@ -1088,7 +1088,9 @@ def _attend(attend, n_valid, apart: bool, *rows):
     a layer's float32 scores (heads x queries x window: 268 MB a row for
     32 heads, a chunk of 256 and 8,192 rows), so the chunks of several
     slots in one program attend one after the other; a row with nothing
-    that counts (a group's padding) is passed over."""
+    that counts (a group's padding) is passed over.  For K/V rows this is
+    what ``ops/gqa_decode.py``'s chunk kernel leaves: float32 state, the
+    CPU, several devices."""
     if not apart:
         return attend(*rows)
     shape = jax.eval_shape(attend, *rows)
@@ -1276,6 +1278,7 @@ def _gqa_mixer(
         o, new_k, new_v, read = _full_rows(
             q, k, v, old_k, old_v, pos, valid, n_valid, n_kv=KH, window=window,
             apart=apart, scope=scope, site=f"{site}attn_full", mesh=mesh,
+            slot=st.get("slot"),
         )
     else:
         rows = old_k.shape[1]
@@ -1312,47 +1315,58 @@ def _full_rows(
 ):
     """Attention over rows a position, which the ``full`` and the ``cca``
     kinds share: the call's rows are written, then a query attends over
-    the first ``window`` of its slot's rows (a decode step walks the rows
-    each slot holds, where ``gqa_decode.use_row_walk`` admits it).
+    the first ``window`` of its slot's rows.  Where ``ops/gqa_decode.py``'s
+    gates admit it, a decode step walks the rows each slot holds
+    (``use_row_walk``) and a prefill chunk the rows its slot holds up to
+    its own last position, where they lie (``use_row_chunk``); what they
+    refuse is ``gqa.attend_rows`` over the whole window.
 
     q: (b, s, H, D) rotated; k, v: (b, s, KH * D).  With ``slot`` (b,) the
     rows are a state of many slots, of which row ``i`` of the call is slot
-    ``slot[i]``: its rows are written there, in place, and the call's rows'
-    windows are gathered from there for this layer alone, so that no window
-    is written back and no more than one layer's are held (a chunk program:
+    ``slot[i]``: its rows are written there, in place, and read from there
+    (the chunk kernel), or the call's rows' windows are gathered from there
+    for this layer alone (XLA), so that no window is written back and no
+    more than one layer's are held (a chunk program:
     ``HybridServing.prefill_rows``).
     Returns (o (b, s, H, D), K rows, V rows, counters in the order of
     ``ATTN_COUNTERS``)."""
     b, s, H, hd = q.shape
     rows = old_k.shape[1]
     span = min(window, rows)  # of the slot's rows, those a call may see
-    walk = record(
+    shape = dict(
+        s=s, q_dtype=q.dtype, rows_dtype=old_k.dtype, width=n_kv * hd, head_dim=hd,
+        rows=rows, window=span, n_q=H, mesh=mesh,
+    )
+    chunk = s > gqa._STEP_QUERIES and record(
+        f"{site}_chunk b={b} s={s} t={window}", gqa_decode.use_row_chunk(**shape)
+    )
+    walk = s <= gqa._STEP_QUERIES and record(
         f"{site} b={b} s={s} t={window}",
-        gqa_decode.use_row_walk(
-            s=s, q_dtype=q.dtype, rows_dtype=old_k.dtype, width=n_kv * hd, head_dim=hd,
-            rows=rows, window=span, batch=b, n_q=H, mesh=mesh, apart=apart,
-        ),
+        gqa_decode.use_row_walk(batch=b, apart=apart or slot is not None, **shape),
     )
     new_k, new_v = _write_rows(old_k, old_v, k, v, jnp.where(valid, pos, rows), scope, slot)
     read_full = b * span
     with jax.named_scope(f"{scope}/attend"):
-        if slot is not None:
-            # The call's rows' windows, gathered from where they were just
-            # written: one layer's at a time (67 MB for 8 rows of 8,192),
-            # not every layer's before the stack runs.  Read by a slice
-            # inside the loop over the rows, the chip's compiler re-laid the
-            # WHOLE leaf out for the products, once a layer and program
-            # (0.33 ms for 134 MB: my chip call 3, PR 40).
-            o = _attend(
-                functools.partial(gqa.attend_rows, n_kv=n_kv), n_valid, True,
-                q, new_k[slot, :window], new_v[slot, :window], pos,
+        if chunk:
+            lengths = gqa_decode.chunk_lengths(pos, valid, span)
+            o = gqa_decode.attend_rows_chunk(
+                q, new_k, new_v, pos, lengths, n_kv=n_kv, window=span, slot=slot
             )
+            read_full = gqa_decode.rows_walked(lengths, rows, span)
         elif walk:
             lengths = gqa_decode.walk_lengths(pos, n_valid, span)
             o = gqa_decode.attend_rows_walk(
                 q, new_k, new_v, pos, lengths, n_kv=n_kv, window=span
             )
             read_full = gqa_decode.rows_walked(lengths, rows, span)
+        elif slot is not None:
+            # The call's rows' windows, gathered from where they were just
+            # written: one layer's at a time (67 MB for 8 rows of 8,192),
+            # not every layer's before the stack runs.
+            o = _attend(
+                functools.partial(gqa.attend_rows, n_kv=n_kv), n_valid, True,
+                q, new_k[slot, :window], new_v[slot, :window], pos,
+            )
         else:
             o = _attend(
                 functools.partial(gqa.attend_rows, n_kv=n_kv), n_valid, apart,

@@ -1,9 +1,9 @@
 """Grouped-query attention over a slot's own K/V state, in two kinds
 (``models/hybrid.py``'s ``full`` and ``window`` mixers).  Plain XLA
-here; a full layer's decode step on the chip is
-``ops/gqa_decode.py``'s row walk, and :func:`attend_rows` is its twin:
-what the gate refuses (float32 state, prefill chunks, several devices,
-the CPU) and the tests' oracle.
+here; on the chip a full layer's decode step is ``ops/gqa_decode.py``'s
+row walk and its prefill chunk that file's chunk kernel, and
+:func:`attend_rows` is their twin: what the gates refuse (float32 state,
+several devices, the CPU) and the tests' oracle.
 
 * :func:`attend_rows` — a global layer: the state holds one row a
   position, written before it is read; a query at position ``i`` sees

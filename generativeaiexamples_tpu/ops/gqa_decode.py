@@ -1,6 +1,9 @@
-"""Pallas TPU decode attention over a full GQA layer's state rows
-(``models/hybrid.py``'s ``full`` mixer): a walk over the rows a slot
-holds, where :func:`ops.gqa.attend_rows` reads the rows it could hold.
+"""Pallas TPU attention over a full GQA layer's state rows
+(``models/hybrid.py``'s ``full`` and ``cca`` mixers): a walk over the rows
+a slot holds, where :func:`ops.gqa.attend_rows` reads the rows it could
+hold.  Two kernels under one layout contract: the decode step's
+(:func:`attend_rows_walk`, below) and a prefill chunk's
+(:func:`attend_rows_chunk`, at the end of the file).
 
 A decode step (one token a row, or a token and its draft) attends over
 ``(b, T, KH * D)`` rows of which a slot of length ``n`` holds ``n``.
@@ -24,6 +27,18 @@ static upper bound of the walk, not what is read.
 choice, the copy that runs ahead from row to row, the VMEM budget, the
 interpret hook); this one has no int8 scales, no append buffer and no
 layer index.
+
+A prefill chunk (16-256 queries a row at consecutive positions, 1-8 rows
+a program) had float32 scores of (heads, 256, window) written and read
+back five times or more by XLA, a row at a time (3.0 ms a layer and row
+for 32 heads at 8,192, 0.41 at 2,048; gathered first from ``leaf[slot,
+:window]``: PERF.md, PR 41).  The chunk kernel takes which slot each row
+of the call is as an operand (that IS the gather) and walks the blocks
+that slot holds up to the chunk's last position that counts, one KV head
+of one chunk a grid step: its queries head after head (heads x queries,
+D), the block's lanes of that head alone copied, the online softmax kept
+for 512 query rows a product.  Only the block or two that overlap the
+chunk's own positions take the causal compare.
 """
 
 from __future__ import annotations
@@ -75,6 +90,16 @@ def _walk_vmem_bytes(block_t: int, width: int, group: int, heads: int, d: int) -
     )
 
 
+def _bf16_rows_on_one_chip(q_dtype, rows_dtype, width: int, head_dim: int, mesh) -> bool:
+    """What both gates ask first: bf16 queries over bf16 rows, rows and
+    heads of whole lane tiles, one TPU device (or the interpret hook)."""
+    if not jnp.dtype(q_dtype) == jnp.dtype(rows_dtype) == jnp.bfloat16:
+        return False
+    if not _interpret_mode() and (platform_of(mesh) != "tpu" or not one_device(mesh)):
+        return False
+    return width % 128 == 0 and head_dim % 128 == 0
+
+
 def use_row_walk(
     *, s: int, q_dtype, rows_dtype, width: int, head_dim: int, rows: int, window: int,
     batch: int, n_q: int, mesh=None, apart: bool = False,
@@ -85,15 +110,11 @@ def use_row_walk(
     TPU device.  Everything else is :func:`ops.gqa.attend_rows`'."""
     if s > _STEP_QUERIES or apart:
         return False
-    if not jnp.dtype(q_dtype) == jnp.dtype(rows_dtype) == jnp.bfloat16:
-        return False
-    if not _interpret_mode() and (platform_of(mesh) != "tpu" or not one_device(mesh)):
+    if not _bf16_rows_on_one_chip(q_dtype, rows_dtype, width, head_dim, mesh):
         return False
     bt = _block_t(rows, window)
     return (
-        width % 128 == 0
-        and head_dim % 128 == 0
-        and rows % bt == 0
+        rows % bt == 0
         and bt % 16 == 0  # whole bf16 sublane tiles
         and _walk_vmem_bytes(bt, width, _row_group(batch), s * n_q, head_dim)
         <= _VMEM_BUDGET_BYTES
@@ -288,3 +309,285 @@ def _walk(q, k_rows, v_rows, q_pos, lengths, *, n_kv: int, block_t: int, interpr
         name="gqa_rows_decode_attention",
     )(lengths.astype(jnp.int32), q_pos.astype(jnp.int32).reshape(b * s), qh, k_rows, v_rows)
     return out.reshape(b, n_kv, s, g, d).transpose(0, 2, 1, 3, 4).reshape(b, s, h, d)
+
+
+# -- a prefill chunk ---------------------------------------------------------------
+
+# Query rows of one product and one online-softmax update: two heads' 256
+# queries, or more heads of a KV head side by side where the chunk is
+# shorter.  On the v5e (my chip call 2, PR 41: 32 heads x 256 queries over
+# 6,400 rows of 8,192) 256 rows take 0.296 ms, 512 0.262, and blocks of
+# 1,024 or 2,048 rows no less than the walk's 512.
+_CHUNK_TILE = 512
+
+
+def chunk_lengths(pos, valid, window: int):
+    """(b,) int32 rows each chunk's walk covers: up to its last position
+    that counts, at most ``window``; 0 for a row with none (a group's
+    padding)."""
+    last = jnp.max(jnp.where(valid, pos.astype(jnp.int32), -1), axis=1)
+    return jnp.minimum(last + 1, window).astype(jnp.int32)
+
+
+def _chunk_tile(rows: int) -> int:
+    """Query rows a product of a KV head's ``rows`` (heads x queries)."""
+    return next(t for t in (_CHUNK_TILE, 256, 128, 64, 32, 16, rows) if rows % t == 0)
+
+
+def _chunk_vmem_bytes(block_t: int, rows: int, d: int) -> int:
+    """VMEM the chunk kernel holds for one row's KV head: the ping-pong K
+    and V blocks of that head, its double-buffered queries and outputs,
+    the online-softmax scratch."""
+    return 2 * 2 * block_t * d * 2 + 2 * 2 * rows * d * 2 + rows * (2 * _STAT_LANES + d) * 4
+
+
+def use_row_chunk(
+    *, s: int, q_dtype, rows_dtype, width: int, head_dim: int, rows: int, window: int,
+    n_q: int, mesh=None,
+) -> bool:
+    """The chunk kernel's gate, from what a traced program can observe: a
+    prefill chunk (``s > _STEP_QUERIES`` queries a row at consecutive
+    positions) of bf16 queries over bf16 rows of whole lane tiles and
+    whole blocks, on one TPU device.  Everything else is
+    :func:`ops.gqa.attend_rows`'."""
+    if s <= _STEP_QUERIES:
+        return False
+    if not _bf16_rows_on_one_chip(q_dtype, rows_dtype, width, head_dim, mesh):
+        return False
+    bt = _block_t(rows, window)
+    per_kv = s * n_q // (width // head_dim)  # a KV head's query rows
+    return (
+        rows % bt == 0
+        and bt % 128 == 0  # scores of whole lane tiles
+        and per_kv % 16 == 0  # whole bf16 sublane tiles
+        and _chunk_vmem_bytes(bt, per_kv, head_dim) <= _VMEM_BUDGET_BYTES
+    )
+
+
+def _chunk_update(sc, mask, v, m_ref, l_ref, acc_ref):
+    """One block into a float32 online softmax of many query rows: ``sc``
+    (R, block) scaled scores, ``mask`` (R, block) which keys each row may
+    see (None: all), ``v`` (block, D) the block's values.  What a block
+    costs beside its products is the reductions across lanes, one a row
+    and statistic (0.57 ms for 32 heads x 256 queries over 6,400 rows with
+    the walk's update, 0.30 with this one: my chip call 2, PR 41), so the
+    running maximum is kept in every lane of ``m_ref`` (R, 128), where it
+    meets the scores tile by tile and the accumulator ``acc_ref`` (R, D) as
+    it lies, and ``l_ref`` (R, 128) keeps a sum a LANE (the probabilities'
+    lane tiles added up), reduced across lanes once, when the walk ends.
+    The probabilities go to the values' dtype before their product, as
+    ``gqa._pv``'s do."""
+    lanes = m_ref.shape[1]
+    tiles = sc.shape[1] // lanes
+    if mask is not None:
+        sc = jnp.where(mask, sc, _NEG)
+    m_prev = m_ref[...]
+    top = sc[:, :lanes]
+    for j in range(1, tiles):
+        top = jnp.maximum(top, sc[:, j * lanes : (j + 1) * lanes])
+    m_new = jnp.maximum(m_prev, jnp.max(top, axis=-1, keepdims=True))  # (R, 128), a value a row
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(sc - jnp.tile(m_new, (1, tiles)))
+    if mask is not None:
+        p = p * mask  # a query that sees nothing stays at exact zeros
+    part = p[:, :lanes]
+    for j in range(1, tiles):
+        part = part + p[:, j * lanes : (j + 1) * lanes]
+    pv = jax.lax.dot_general(
+        p.astype(v.dtype), v, (((1,), (0,)), ((), ())), preferred_element_type=F32
+    )
+    d = acc_ref.shape[1]
+    acc_ref[...] = acc_ref[...] * jnp.tile(alpha, (1, d // lanes)) + pv
+    l_ref[...] = l_ref[...] * alpha + part
+    m_ref[...] = m_new
+
+
+def _chunk_kernel(
+    len_ref,  # scalar prefetch: (B,) int32 rows each chunk's walk covers
+    slot_ref,  # scalar prefetch: (B,) int32 the slot whose rows a chunk reads
+    pos_ref,  # scalar prefetch: (B,) int32 the position of a chunk's first query
+    q_ref,  # (1, 1, G * s, D): one KV head's queries of one chunk, head-major
+    k_hbm,  # (slots, T, KH * D): stays in HBM (pl.ANY)
+    v_hbm,
+    o_ref,  # (1, 1, G * s, D)
+    kbuf,  # (2, block_t, D) VMEM: one KV head's lanes of a block
+    vbuf,
+    sem,  # DMA (2 slots, K and V)
+    state,  # SMEM (2,)
+    m_ref,  # (G * s, 128) float32
+    l_ref,
+    acc_ref,  # (G * s, D) float32
+    *,
+    block_t: int,
+    s: int,
+    tile: int,
+    scale: float,
+):
+    """One chunk's queries of one KV head, walked over the blocks its slot
+    holds.  The grid runs (chunk, KV head) in order on one core, so the
+    copies run ahead as the walk's do: block ``i + 1`` while block ``i``
+    computes, and during a program's last block the first block of the
+    next program that has any (the chunk's next head, or the next chunk
+    that is no padding).  A block whose every key lies at or before the
+    chunk's first position needs no causal compare; the one or two that
+    overlap the chunk's own positions take it."""
+    b, h = pl.program_id(0), pl.program_id(1)
+    n_chunks, kh = pl.num_programs(0), pl.num_programs(1)
+    gs, d = q_ref.shape[2:]
+    bt = block_t
+    length, first = len_ref[b], pos_ref[b]
+
+    def n_blocks(row):
+        return (len_ref[row] + bt - 1) // bt
+
+    def block_dma(buf, row, head, i):
+        start = pl.multiple_of(i * bt, bt)
+        lanes = pl.ds(pl.multiple_of(head * d, d), d)
+        return tuple(
+            pltpu.make_async_copy(
+                hbm.at[slot_ref[row], pl.ds(start, bt), lanes], vm.at[buf], sem.at[buf, j]
+            )
+            for j, (hbm, vm) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf)))
+        )
+
+    # state[0]: buffer slot of the next block to compute; state[1]: 1 if
+    # an earlier program already started this one's first copy.
+    @pl.when((b == 0) & (h == 0))
+    def _reset():
+        state[0] = 0
+        state[1] = 0
+
+    n = n_blocks(b)
+    buf0 = state[0]
+
+    @pl.when((n > 0) & (state[1] == 0))
+    def _first():
+        for cp in block_dma(buf0, b, h, 0):
+            cp.start()
+
+    m_ref[...] = jnp.full_like(m_ref, _NEG)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def block(i, masked: bool):
+        buf = (buf0 + i) % 2
+
+        @pl.when(i + 1 < n)
+        def _prefetch():
+            for cp in block_dma(1 - buf, b, h, i + 1):
+                cp.start()
+
+        @pl.when(i + 1 == n)
+        def _prefetch_next_program():
+            row = jax.lax.while_loop(
+                lambda j: (j < n_chunks) & (n_blocks(jnp.minimum(j, n_chunks - 1)) == 0),
+                lambda j: j + 1,
+                b + 1,
+            )
+            same = h + 1 < kh
+            more = same | (row < n_chunks)
+
+            @pl.when(more)
+            def _start():
+                nxt = jnp.where(same, b, jnp.minimum(row, n_chunks - 1))
+                for cp in block_dma(1 - buf, nxt, jnp.where(same, h + 1, 0), 0):
+                    cp.start()
+
+            state[0] = 1 - buf
+            state[1] = more.astype(jnp.int32)
+
+        for cp in block_dma(buf, b, h, i):
+            cp.wait()
+        k, v = kbuf[buf], vbuf[buf]  # (bt, D)
+
+        def product(j, _):
+            rows = pl.ds(pl.multiple_of(j * tile, tile), tile)
+            sc = jax.lax.dot_general(
+                q_ref[0, 0, rows, :], k, (((1,), (1,)), ((), ())), preferred_element_type=F32
+            ) * scale
+            mask = None
+            if masked:
+                # Row ``r`` of a KV head's queries is query ``r % s`` of
+                # the chunk, at position ``first + r % s``.
+                query = (jax.lax.broadcasted_iota(jnp.int32, (tile, bt), 0) + j * tile) % s
+                last = jnp.minimum(first + query, length - 1)
+                mask = jax.lax.broadcasted_iota(jnp.int32, (tile, bt), 1) + i * bt <= last
+            _chunk_update(sc, mask, v, m_ref.at[rows], l_ref.at[rows], acc_ref.at[rows])
+            return 0
+
+        jax.lax.fori_loop(0, gs // tile, product, 0)
+        return 0
+
+    # Blocks that end at or before the first query's position (and inside
+    # the length) are seen whole by every query.
+    whole = jnp.minimum(jnp.maximum(jnp.minimum(first, length - 1) + 1, 0) // bt, n)
+    jax.lax.fori_loop(0, whole, lambda i, _: block(i, False), 0)
+    jax.lax.fori_loop(whole, n, lambda i, _: block(i, True), 0)
+    total = jnp.sum(l_ref[...], axis=-1, keepdims=True)
+    o_ref[0, 0] = (acc_ref[...] / jnp.maximum(total, 1e-30)).astype(o_ref.dtype)
+
+
+def attend_rows_chunk(
+    q, k_rows, v_rows, q_pos, lengths, *, n_kv: int, window: int, slot=None, interpret=None
+):
+    """:func:`ops.gqa.attend_rows` over the first ``window`` rows, for
+    prefill chunks, each read from its slot's rows where they lie: q
+    (b, s, H, D) rotated, at consecutive positions ``q_pos`` (b, s);
+    k_rows, v_rows (slots, T, KH * D) whole; row ``i`` of the call is slot
+    ``slot[i]`` (absent: slot ``i``); ``lengths`` (b,) from
+    :func:`chunk_lengths`.  Returns (b, s, H, D) in q's dtype: exact zeros
+    for a row of length 0, which copies nothing."""
+    if interpret is None:
+        interpret = _interpret_mode()
+    b = q.shape[0]
+    slot = jnp.arange(b, dtype=jnp.int32) if slot is None else slot.astype(jnp.int32)
+    bt = _block_t(k_rows.shape[1], window)
+    return _chunk(
+        q, k_rows, v_rows, q_pos[:, 0].astype(jnp.int32), lengths.astype(jnp.int32), slot,
+        n_kv=n_kv, block_t=bt, interpret=interpret,
+    )
+
+
+# Under ``jit`` for the walk's reason: the layers of a program and the
+# programs of every window share one trace of the kernel.
+@functools.partial(jax.jit, static_argnames=("n_kv", "block_t", "interpret"))
+def _chunk(q, k_rows, v_rows, first, lengths, slot, *, n_kv: int, block_t: int, interpret: bool):
+    b, s, h, d = q.shape
+    g = h // n_kv
+    # A KV head's queries, head after head: (b, KH, G * s, D).
+    qh = q.reshape(b, s, n_kv, g, d).transpose(0, 2, 3, 1, 4).reshape(b, n_kv, g * s, d)
+    head = pl.BlockSpec((1, 1, g * s, d), lambda bi, hi, *_: (bi, hi, 0, 0))
+    out = pl.pallas_call(
+        functools.partial(
+            _chunk_kernel, block_t=block_t, s=s, tile=_chunk_tile(g * s), scale=d**-0.5
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(b, n_kv),
+            in_specs=[
+                head,
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=head,
+            scratch_shapes=[
+                pltpu.VMEM((2, block_t, d), k_rows.dtype),
+                pltpu.VMEM((2, block_t, d), v_rows.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((2,), jnp.int32),
+                pltpu.VMEM((g * s, _STAT_LANES), F32),
+                pltpu.VMEM((g * s, _STAT_LANES), F32),
+                pltpu.VMEM((g * s, d), F32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            # In order on one core: the buffer slot and the next program's
+            # first copy ride from one program to the next.
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_BUDGET_BYTES,
+        ),
+        interpret=interpret,
+        name="gqa_rows_chunk_attention",
+    )(lengths, slot, first, qh, k_rows, v_rows)
+    return out.reshape(b, n_kv, g, s, d).transpose(0, 3, 1, 2, 4).reshape(b, s, h, d)

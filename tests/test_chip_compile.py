@@ -301,26 +301,17 @@ SPARE_BYTES = 1_400_000_000
 SPARE_BY_FAMILY = {"exaone": 2_500_000_000, "mistral4": 400_000_000, "zaya": 800_000_000}
 
 
-@pytest.mark.parametrize("family", sorted(GROUP_PROGRAMS))
-def test_the_chunks_of_several_slots_compile_as_one_program(one_chip, family, monkeypatch):
-    """``_prefill_suffix_rows`` (the scheduler's program for the prefill
-    chunks of several slots) at the published widths, for the largest
-    group and the widest window each cell's family holds, against the
-    cell's own slot state (32 slots of 8,192 and of 2,048 rows): the
-    grouped products are in it, and its temporaries (a full layer's
-    float32 scores are 268 MB a row at 8,192, which is why the rows
-    attend one after the other) stay under what Mellum's cell has to
-    spare."""
+def _chunk_program(one_chip, config: str, rows: int, window: int):
+    """``_prefill_suffix_rows`` of a layer-kind configuration under
+    benchmarks/configs/, compiled for ``rows`` chunks under ``window``
+    against the cell's own slot state.  Returns (compiled, serving, engine)."""
     import json
     from pathlib import Path
 
     from generativeaiexamples_tpu.engine.scheduler import make_prefill_suffix_rows
     from generativeaiexamples_tpu.engine.serving_models import HybridServing
     from generativeaiexamples_tpu.models import hybrid
-    from generativeaiexamples_tpu.ops import moe
 
-    monkeypatch.setattr(moe, "platform_of", lambda mesh: "tpu")
-    config, rows, window = GROUP_PROGRAMS[family]
     configs = Path(__file__).resolve().parents[1] / "benchmarks" / "configs"
     model = json.loads((configs / f"{config}.json").read_text())
     engine = model["engine"]
@@ -329,9 +320,6 @@ def test_the_chunks_of_several_slots_compile_as_one_program(one_chip, family, mo
         model, max_len=max_len, kv_dtype=engine["kv_dtype"], draft=engine.get("draft", ""),
     )
     serving = HybridServing(cfg, None, max_len)
-    assert serving.chunks_per_program(chunk) == rows and window == max_len
-    if family == "mistral4":
-        assert serving.chunk_windows(chunk) == (max_len,)  # the one window it is built for
 
     def described(make):
         return jax.tree.map(
@@ -347,12 +335,75 @@ def test_the_chunks_of_several_slots_compile_as_one_program(one_chip, family, mo
         spec((rows, chunk), jnp.int32), ints, ints, ints,
         spec((2,), jnp.uint32), (floats, floats, ints), window,
     ).compile()
+    return compiled, serving, engine
+
+
+@pytest.mark.parametrize("family", sorted(GROUP_PROGRAMS))
+def test_the_chunks_of_several_slots_compile_as_one_program(one_chip, family, monkeypatch):
+    """``_prefill_suffix_rows`` (the scheduler's program for the prefill
+    chunks of several slots) at the published widths, for the largest
+    group and the widest window each cell's family holds, against the
+    cell's own slot state (32 slots of 8,192 and of 2,048 rows): the
+    grouped products are in it, and its temporaries (a full layer's
+    float32 scores are 268 MB a row at 8,192 where the chunk kernel's
+    gate refuses, as it does here, which is why the rows then attend one
+    after the other) stay under what Mellum's cell has to spare."""
+    from generativeaiexamples_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "platform_of", lambda mesh: "tpu")
+    config, rows, window = GROUP_PROGRAMS[family]
+    compiled, serving, engine = _chunk_program(one_chip, config, rows, window)
+    max_len, chunk = int(engine["max_len"]), int(engine["prefill_chunk_tokens"])
+    assert serving.chunks_per_program(chunk) == rows and window == max_len
+    if family == "mistral4":
+        assert serving.chunk_windows(chunk) == (max_len,)  # the one window it is built for
     text = compiled.as_text()
     assert "tpu_custom_call" in text
     spare = SPARE_BY_FAMILY.get(family, SPARE_BYTES)
     assert compiled.memory_analysis().temp_size_in_bytes < spare
     if family == "mistral4":
         _no_window_sized_temporaries(text, slots=int(engine["max_batch"]), rows=rows, window=window)
+
+
+# (layers that attend over rows a position: ``full`` or ``cca``, a
+# prediction module's block among them; the width of a K/V row)
+ROW_LAYERS = {"mellum": (3, 4 * HD), "exaone": (2, 8 * HD), "zaya": (20, 2 * HD)}
+
+
+@pytest.mark.parametrize("rows", ["one_row", "largest_group"])
+@pytest.mark.parametrize("family", sorted(ROW_LAYERS))
+def test_a_chunk_program_attends_over_its_slots_rows_where_they_lie(one_chip, family, rows, monkeypatch):
+    """The one-row and the largest chunk program of the three cells whose
+    layers hold K/V rows a position, at the widest window, with
+    ``ops/gqa_decode.py``'s gates believing they are on the chip: every
+    ``full`` / ``cca`` layer's attention (K-EXAONE's module's block too) is
+    the chunk kernel over the slots' leaves as they lie.  No leaf is
+    copied (the one-row program of the ``cca`` family re-laid every V leaf
+    out, ``copy(bf16[32,8192,256])`` twenty times: PERF.md, PR 40), no
+    window of one is gathered or written back, and no float32 scores of
+    (heads, 256, 8,192) are made."""
+    from generativeaiexamples_tpu.ops import gqa_decode, moe
+
+    monkeypatch.setattr(moe, "platform_of", lambda mesh: "tpu")
+    monkeypatch.setattr(gqa_decode, "platform_of", lambda mesh: "tpu")
+    config, largest, window = GROUP_PROGRAMS[family]
+    n = 1 if rows == "one_row" else largest
+    compiled, serving, engine = _chunk_program(one_chip, config, n, window)
+    assert serving.rows_in_place
+    layers, width = ROW_LAYERS[family]
+    slots, chunk = int(engine["max_batch"]), int(engine["prefill_chunk_tokens"])
+    text = compiled.as_text()
+    assert text.count("gqa_rows_chunk_attention") >= layers
+    assert not re.search(rf"= bf16\[{slots},{window},{width}\]\S* copy\(", text)
+    assert not re.search(rf"bf16\[{n},{window},{width}\]", text)  # no group's windows
+    assert not re.search(rf"f32\[(?:\d+,)*{chunk},{window}\]", text)  # no layer's scores
+    memory = compiled.memory_analysis()
+    print(family, rows, "chunk program temporaries", memory.temp_size_in_bytes)
+    # 0.09-0.32 GB here, 0.51 for K-EXAONE's eight rows of 6,144 (the
+    # experts' dispatch and combine): no window-sized buffer is among them.
+    assert memory.temp_size_in_bytes < 640_000_000
+    # The slots' rows go through in place.
+    assert memory.alias_size_in_bytes >= 2 * layers * slots * window * width * 2 * 0.99
 
 
 def _no_window_sized_temporaries(text: str, *, slots: int, rows: int, window: int) -> None:

@@ -347,7 +347,7 @@ def test_hybrid_phase_rehearsal_of_the_cca_model_and_its_controls(control, capsy
     assert line["positions"] == {"prefill": 37, "decode": 8}
     # The shapes of the scheduler's programs: a chunk beside a pad row over
     # the whole slot (256 rows here), a decode step over both slots.
-    assert "attn_cca b=2 s=16 t=256" in line["kernel_paths"]
+    assert "attn_cca_chunk b=2 s=16 t=256" in line["kernel_paths"]
     assert "attn_cca b=2 s=1 t=256" in line["kernel_paths"]
     assert line["within_limits"] == (not control)
     if control == "w8a8":  # the precision: every position moves, by little

@@ -217,6 +217,9 @@ def test_without_the_kernel_the_counters_read_the_whole_window_and_taken_says_xl
 
 @pytest.mark.parametrize("case", ["mellum", "exaone_draft_on"])
 def test_a_prefill_chunk_and_float32_state_stay_on_attend_rows(case, interpret):
+    """A chunk of three queries is no decode step (the walk's gate) and no
+    whole sublane tiles of queries (the chunk kernel's,
+    ``tests/test_gqa_chunk_kernel.py``); float32 state is neither's."""
     preset, draft = CASES[case]
     for cfg in (_cfg(preset, draft), dataclasses.replace(_cfg(preset, draft), kv_dtype="float32")):
         dispatch.TAKEN.clear()
@@ -230,4 +233,6 @@ def test_a_prefill_chunk_and_float32_state_stay_on_attend_rows(case, interpret):
             ),
             params, state,
         )
-        assert dispatch.TAKEN[f"attn_full b={b} s={s} t={MAX_LEN}"] == "xla"
+        site = "attn_full_chunk" if s == 3 else "attn_full"
+        assert dispatch.TAKEN[f"{site} b={b} s={s} t={MAX_LEN}"] == "xla"
+        assert {p for k, p in dispatch.TAKEN.items() if "attn_" in k} == {"xla"}

@@ -425,11 +425,14 @@ SLOTS, MAX_LEN, WINDOW, S = 6, 128, 64, 8
 ROWS = ((4, 0, 8), (1, 40, 8), (3, 19, 5))
 
 
-@pytest.fixture(scope="module", params=["ling-tiny", "mellum-tiny", "zaya-tiny"])
+@pytest.fixture(scope="module", params=["ling-tiny", "mellum-tiny", "zaya-tiny", "exaone_moe-tiny"])
 def rows_case(request):
     """A serving model, its parameters, and slots whose state is what
     ``prefill_row`` left of each row's prompt so far; every slot that is
-    no row's holds noise, which no call may touch."""
+    no row's holds noise, which no call may touch.  Ling's latent rows have
+    their windows taken out and put back; the others' rows (Mellum's full
+    layers, ZAYA's ``cca`` layers, K-EXAONE's full layer and its prediction
+    module's block, the draft on) are written and read in place."""
     import jax
     import jax.numpy as jnp
 
@@ -489,9 +492,22 @@ def test_rows_of_a_group_get_what_each_gets_alone(rows_case, n_rows):
         np.testing.assert_allclose(np.asarray(got, np.float32), np.asarray(each, np.float32), rtol=2e-5, atol=2e-5)
         others = [i for i in range(SLOTS) if i not in touched]
         np.testing.assert_array_equal(np.asarray(got)[others], np.asarray(was)[others])
-    # A pad row routes to no expert: the choices counted are the rows'.
-    assert int(counters[0]) == sum(n for _, _, n in rows) * model.cfg.n_experts_per_tok * sum(
-        mlp == "experts" for _, mlp in model.cfg.layer_kinds)
+    # A pad row routes to no expert: the choices counted are the rows'
+    # (and the module's expert layer's, one position behind them).
+    experts = sum(mlp == "experts" for _, mlp in model.cfg.layer_kinds)
+    tokens_routed = sum(n for _, _, n in rows)
+    behind = sum(n - (before == 0) for _, before, n in rows) if model.draft else 0
+    assert int(counters[0]) == (tokens_routed * experts + behind) * model.cfg.n_experts_per_tok
+    assert model.rows_in_place == (not model.cfg.layers_of("mla"))
+    named = dict(zip(model.counter_names, np.asarray(counters).tolist()))
+    if "attn_rows_dense_full_prefill" in named:
+        # XLA's twin (float32 state): every row's window read whole, the pad
+        # row's counted too (the chunk kernel reads less:
+        # tests/test_gqa_chunk_kernel.py).
+        layers = len(model.cfg.layers_of("full")) + len(model.cfg.layers_of("cca")) + bool(model.draft)
+        assert named["attn_rows_dense_full_prefill"] == layers * len(tokens) * WINDOW
+        assert named["attn_rows_read_full_prefill"] == named["attn_rows_dense_full_prefill"]
+        assert named["attn_rows_read_full_decode"] == named["attn_rows_dense_full_decode"] == 0
 
 
 @pytest.mark.parametrize("config, chunks", [
