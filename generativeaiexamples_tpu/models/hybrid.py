@@ -1256,8 +1256,10 @@ def _gqa_mixer(
     attends over the first ``window`` of them (a decode step walks the
     rows each slot holds, where ``gqa_decode.use_row_walk`` admits it);
     a window layer attends over its ring as it was and over its own new
-    rows, then writes.  ``site`` prefixes the layer's ``kernel_paths``
-    entry (a prediction module's block)."""
+    rows (a prefill chunk in ``ops/gqa_decode.py``'s ring kernel, where
+    ``use_ring_chunk`` admits it; a decode step and what it refuses in
+    ``gqa.attend_ring``), then writes.  ``site`` prefixes the layer's
+    ``kernel_paths`` entry (a prediction module's block)."""
     b, s, _ = h.shape
     H, KH, hd = cfg.n_heads, cfg.n_kv_heads, cfg.attn_head_dim
     scope = f"layer/attn_{mixer}"
@@ -1282,15 +1284,27 @@ def _gqa_mixer(
         )
     else:
         rows = old_k.shape[1]
-        record(f"{site}attn_window b={b} s={s} t={rows}", False)
-        with jax.named_scope(f"{scope}/attend"):
-            o = gqa.attend_ring(
-                q, k, v, old_k, old_v, pos, n_kv=KH, window=cfg.sliding_window
+        if s > gqa._STEP_QUERIES:
+            chunk = record(
+                f"{site}attn_window_chunk b={b} s={s} t={rows}",
+                gqa_decode.use_ring_chunk(
+                    s=s, q_dtype=q.dtype, rows_dtype=old_k.dtype, width=KH * hd, head_dim=hd,
+                    ring=rows, n_q=H, mesh=mesh,
+                ),
             )
+        else:
+            chunk = record(f"{site}attn_window b={b} s={s} t={rows}", False)
+        ring = dict(n_kv=KH, window=cfg.sliding_window)
+        with jax.named_scope(f"{scope}/attend"):
+            if chunk:
+                o = gqa_decode.attend_ring_chunk(q, k, v, old_k, old_v, pos, n_valid, **ring)
+            else:
+                o = gqa.attend_ring(q, k, v, old_k, old_v, pos, **ring)
         new_k, new_v = _write_rows(
             old_k, old_v, k, v, gqa.ring_slots(pos, valid, n_valid, rows), scope
         )
-        read = (b * rows, 0, b * window, 0)
+        # The kernel reads no ring for a row with nothing that counts.
+        read = ((jnp.sum(n_valid > 0) if chunk else b) * rows, 0, b * window, 0)
     with jax.named_scope(f"{scope}/wo"):
         out = jnp.dot(o.reshape(b, s, H * hd).astype(h.dtype), lp["w_o"])
     return out, dict(zip(names, (new_k, new_v))), jnp.stack(read).astype(jnp.int32)

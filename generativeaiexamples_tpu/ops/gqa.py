@@ -1,9 +1,11 @@
 """Grouped-query attention over a slot's own K/V state, in two kinds
 (``models/hybrid.py``'s ``full`` and ``window`` mixers).  Plain XLA
 here; on the chip a full layer's decode step is ``ops/gqa_decode.py``'s
-row walk and its prefill chunk that file's chunk kernel, and
-:func:`attend_rows` is their twin: what the gates refuse (float32 state,
-several devices, the CPU) and the tests' oracle.
+row walk, its prefill chunk that file's chunk kernel and a window
+layer's prefill chunk its ring kernel; :func:`attend_rows` and
+:func:`attend_ring` are their twins: what the gates refuse (float32
+state, several devices, the CPU) and the tests' oracle.  A window layer's
+decode step is :func:`attend_ring` on every platform.
 
 * :func:`attend_rows` — a global layer: the state holds one row a
   position, written before it is read; a query at position ``i`` sees
@@ -129,7 +131,12 @@ def attend_ring(q, k_new, v_new, ring_k, ring_v, q_pos, *, n_kv: int, window: in
     """q: (b, s, H, D) rotated, at consecutive positions ``q_pos`` (b, s);
     k_new, v_new: (b, s, KH * D) this call's rows; ring_k, ring_v:
     (b, R, KH * D) the ring before the call.  Position ``i`` sees ``j``
-    with ``i - window < j <= i``.  Returns (b, s, H, D)."""
+    with ``i - window < j <= i``.  Returns (b, s, H, D).
+
+    Every decode step (``s <= _STEP_QUERIES``) and the prefill calls that
+    ``gqa_decode.use_ring_chunk`` refuses; a chunk it admits is
+    ``gqa_decode.attend_ring_chunk``, the same numbers with no scores in
+    HBM."""
     s, ring = q.shape[1], ring_k.shape[1]
     held = ring_held(q_pos[:, 0], ring)[:, None, :]  # (b, 1, R)
     old_mask = (held >= 0) & (held > q_pos[:, :, None] - window)

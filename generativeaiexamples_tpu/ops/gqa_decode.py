@@ -1,9 +1,16 @@
-"""Pallas TPU attention over a full GQA layer's state rows
-(``models/hybrid.py``'s ``full`` and ``cca`` mixers): a walk over the rows
-a slot holds, where :func:`ops.gqa.attend_rows` reads the rows it could
-hold.  Two kernels under one layout contract: the decode step's
+"""Pallas TPU attention over a GQA layer's state rows
+(``models/hybrid.py``'s ``full``, ``cca`` and ``window`` mixers).  Over
+rows a position (``full``, ``cca``): a walk over the rows a slot holds,
+where :func:`ops.gqa.attend_rows` reads the rows it could hold; two
+kernels under one layout contract, the decode step's
 (:func:`attend_rows_walk`, below) and a prefill chunk's
-(:func:`attend_rows_chunk`, at the end of the file).
+(:func:`attend_rows_chunk`, after it).  Over a ring (``window``): a
+prefill chunk's (:func:`attend_ring_chunk`, at the end of the file), the
+flash form of :func:`ops.gqa.attend_ring`.  What still reaches XLA's
+forms: every call the gates refuse (float32 state, several devices, the
+CPU without the interpret switch, shapes off the tiles or past the VMEM
+budget) and a window layer's decode step, which keeps ``attend_ring``'s
+wide product over the ring as it lies.
 
 A decode step (one token a row, or a token and its draft) attends over
 ``(b, T, KH * D)`` rows of which a slot of length ``n`` holds ``n``.
@@ -39,6 +46,14 @@ of one chunk a grid step: its queries head after head (heads x queries,
 D), the block's lanes of that head alone copied, the online softmax kept
 for 512 query rows a product.  Only the block or two that overlap the
 chunk's own positions take the causal compare.
+
+A window layer's prefill chunk had float32 scores of (rows, heads, 256,
+R + 256) written and read back by XLA for all rows at once (1.0 ms a
+layer for four rows of 32 heads over 1,280 keys, 0.2 in the kernel:
+PERF.md, PR 42).  The ring kernel holds one KV head of one row's ring in
+VMEM whole (``R x D``), works out which position each ring row holds from
+the chunk's first position, and runs the chunk kernel's online softmax
+over the ring and then the call's own rows.
 """
 
 from __future__ import annotations
@@ -364,6 +379,21 @@ def use_row_chunk(
     )
 
 
+def _head_after_head(q, n_kv: int):
+    """q (b, s, H, D) as a KV head's queries, head after head:
+    (b, KH, G * s, D), row ``r`` of a KV head query ``r % s``."""
+    b, s, h, d = q.shape
+    g = h // n_kv
+    return q.reshape(b, s, n_kv, g, d).transpose(0, 2, 3, 1, 4).reshape(b, n_kv, g * s, d)
+
+
+def _query_after_query(out, s: int):
+    """:func:`_head_after_head`'s inverse: (b, KH, G * s, D) -> (b, s, H, D)."""
+    b, n_kv, gs, d = out.shape
+    g = gs // s
+    return out.reshape(b, n_kv, g, s, d).transpose(0, 3, 1, 2, 4).reshape(b, s, n_kv * g, d)
+
+
 def _chunk_update(sc, mask, v, m_ref, l_ref, acc_ref):
     """One block into a float32 online softmax of many query rows: ``sc``
     (R, block) scaled scores, ``mask`` (R, block) which keys each row may
@@ -554,8 +584,7 @@ def attend_rows_chunk(
 def _chunk(q, k_rows, v_rows, first, lengths, slot, *, n_kv: int, block_t: int, interpret: bool):
     b, s, h, d = q.shape
     g = h // n_kv
-    # A KV head's queries, head after head: (b, KH, G * s, D).
-    qh = q.reshape(b, s, n_kv, g, d).transpose(0, 2, 3, 1, 4).reshape(b, n_kv, g * s, d)
+    qh = _head_after_head(q, n_kv)
     head = pl.BlockSpec((1, 1, g * s, d), lambda bi, hi, *_: (bi, hi, 0, 0))
     out = pl.pallas_call(
         functools.partial(
@@ -590,4 +619,261 @@ def _chunk(q, k_rows, v_rows, first, lengths, slot, *, n_kv: int, block_t: int, 
         interpret=interpret,
         name="gqa_rows_chunk_attention",
     )(lengths, slot, first, qh, k_rows, v_rows)
-    return out.reshape(b, n_kv, g, s, d).transpose(0, 3, 1, 2, 4).reshape(b, s, h, d)
+    return _query_after_query(out, s)
+
+
+# -- a window layer's prefill chunk ------------------------------------------------
+
+
+def _ring_blocks(ring: int, s: int) -> tuple[int, int, int]:
+    """(rows of a block of the ring, the call's own rows widened to whole
+    lane tiles of keys, keys of a block of them)."""
+    keys = -(-s // _STAT_LANES) * _STAT_LANES
+    return _block_t(ring, ring), keys, next(t for t in (512, 256, 128) if keys % t == 0)
+
+
+def _ring_vmem_bytes(ring: int, s: int, rows: int, d: int) -> int:
+    """VMEM the ring kernel holds for one row's KV head: that head of the
+    ring twice (this program's and the next one's on its way), the call's
+    own rows, queries and outputs double-buffered, the online-softmax
+    scratch, and a product's float32 scores and probabilities."""
+    bt, keys, own = _ring_blocks(ring, s)
+    return (
+        2 * 2 * ring * d * 2
+        + 2 * 2 * keys * d * 2
+        + 2 * 2 * rows * d * 2
+        + rows * (2 * _STAT_LANES + d) * 4
+        + 4 * _chunk_tile(rows) * max(bt, own) * 4
+    )
+
+
+def use_ring_chunk(
+    *, s: int, q_dtype, rows_dtype, width: int, head_dim: int, ring: int, n_q: int, mesh=None,
+) -> bool:
+    """The ring kernel's gate, from what a traced program can observe: a
+    prefill chunk (``s > _STEP_QUERIES`` queries a row at consecutive
+    positions) of bf16 queries over a bf16 ring of whole lane tiles and
+    whole blocks, on one TPU device.  Everything else is
+    :func:`ops.gqa.attend_ring`."""
+    if s <= _STEP_QUERIES:
+        return False
+    if not _bf16_rows_on_one_chip(q_dtype, rows_dtype, width, head_dim, mesh):
+        return False
+    per_kv = s * n_q // (width // head_dim)  # a KV head's query rows
+    return (
+        _block_t(ring, ring) % 128 == 0  # whole blocks, scores of whole lane tiles
+        and s % 16 == 0 and per_kv % 16 == 0  # whole bf16 sublane tiles
+        and _ring_vmem_bytes(ring, s, per_kv, head_dim) <= _VMEM_BUDGET_BYTES
+    )
+
+
+def _ring_kernel(
+    live_ref,  # scalar prefetch: (B,) int32, 0 for a row with nothing that counts
+    pos_ref,  # scalar prefetch: (B,) int32 the position of a chunk's first query
+    q_ref,  # (1, 1, G * s, D): one KV head's queries of one chunk, head-major
+    kn_ref,  # (1, keys, D): that head of the call's own rows (zeros past s)
+    vn_ref,
+    k_hbm,  # (B, R, KH * D): the rings before the call, in HBM (pl.ANY)
+    v_hbm,
+    o_ref,  # (1, 1, G * s, D)
+    kbuf,  # (2, R, D) VMEM: one KV head's lanes of a ring
+    vbuf,
+    sem,  # DMA (2 slots, K and V)
+    state,  # SMEM (2,)
+    m_ref,  # (G * s, 128) float32
+    l_ref,
+    acc_ref,  # (G * s, D) float32
+    *,
+    block_t: int,
+    own_t: int,
+    s: int,
+    window: int,
+    tile: int,
+    scale: float,
+):
+    """One chunk's queries of one KV head over that head of its ring as
+    it was, then over the call's own rows, in one online softmax.  The
+    grid runs (chunk, KV head) in order on one core and a head of a ring
+    is one copy, so while a program computes, the ring of the next one
+    that has any (the chunk's next head, or the next chunk that is no
+    padding) is on its way; a pad row copies nothing.
+
+    With the last position written ``first - 1``, the ring wraps at
+    ``wrap = first mod R``: rows below it hold this lap's positions
+    ``first - wrap + r``, rows from it on the lap before's, ``R`` less
+    (negative in the first lap: another occupant's leftovers).  A query
+    at ``first + i`` sees what lies after ``first + i - window``; a block
+    that the chunk's last query sees whole needs no compare."""
+    b, h = pl.program_id(0), pl.program_id(1)
+    n_chunks, kh = pl.num_programs(0), pl.num_programs(1)
+    gs, d = q_ref.shape[2:]
+    ring, keys, bt = kbuf.shape[1], kn_ref.shape[1], block_t
+
+    def ring_dma(buf, row, head):
+        lanes = pl.ds(pl.multiple_of(head * d, d), d)
+        return tuple(
+            pltpu.make_async_copy(hbm.at[row, pl.ds(0, ring), lanes], vm.at[buf], sem.at[buf, j])
+            for j, (hbm, vm) in enumerate(((k_hbm, kbuf), (v_hbm, vbuf)))
+        )
+
+    # state[0]: buffer slot of the next ring to compute; state[1]: 1 if an
+    # earlier program already started this one's copy.
+    @pl.when((b == 0) & (h == 0))
+    def _reset():
+        state[0] = 0
+        state[1] = 0
+
+    def update(j, k, v, sees):
+        rows = pl.ds(pl.multiple_of(j * tile, tile), tile)
+        sc = jax.lax.dot_general(
+            q_ref[0, 0, rows, :], k, (((1,), (1,)), ((), ())), preferred_element_type=F32
+        ) * scale
+        mask = None
+        if sees is not None:
+            # Row ``r`` of a KV head's queries is query ``r % s`` of the
+            # chunk: a column of queries against a row of keys.
+            mask = sees((jax.lax.broadcasted_iota(jnp.int32, (tile, 1), 0) + j * tile) % s)
+        _chunk_update(sc, mask, v, m_ref.at[rows], l_ref.at[rows], acc_ref.at[rows])
+        return 0
+
+    @pl.when(live_ref[b] == 0)
+    def _padding():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    @pl.when(live_ref[b] > 0)
+    def _attend():
+        buf = state[0]
+
+        @pl.when(state[1] == 0)
+        def _first():
+            for cp in ring_dma(buf, b, h):
+                cp.start()
+
+        row = jax.lax.while_loop(
+            lambda j: (j < n_chunks) & (live_ref[jnp.minimum(j, n_chunks - 1)] == 0),
+            lambda j: j + 1,
+            b + 1,
+        )
+        same = h + 1 < kh
+        more = same | (row < n_chunks)
+
+        @pl.when(more)
+        def _next_program():
+            nxt = jnp.where(same, b, jnp.minimum(row, n_chunks - 1))
+            for cp in ring_dma(1 - buf, nxt, jnp.where(same, h + 1, 0)):
+                cp.start()
+
+        state[0] = 1 - buf
+        state[1] = more.astype(jnp.int32)
+        m_ref[...] = jnp.full_like(m_ref, _NEG)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        first = pos_ref[b]
+        wrap = first % ring
+        lap = first - wrap  # the position ring row 0 holds, where it holds this lap's
+        floor = first - window  # query ``i`` sees positions after ``floor + i``
+        for cp in ring_dma(buf, b, h):
+            cp.wait()
+
+        def products(k, v, sees):
+            jax.lax.fori_loop(0, gs // tile, lambda j, _: update(j, k, v, sees), 0)
+
+        def ring_block(lo: int):
+            k, v = kbuf[buf, lo : lo + bt], vbuf[buf, lo : lo + bt]
+            # The oldest position of the block, unless the ring wraps in it.
+            oldest = lap + lo - jnp.where(wrap <= lo, ring, 0)
+            whole = ((wrap <= lo) | (wrap >= lo + bt)) & (oldest >= 0) & (oldest > floor + s - 1)
+
+            def compared():
+                r = jax.lax.broadcasted_iota(jnp.int32, (1, bt), 1) + lo
+                held = lap + r - jnp.where(r >= wrap, ring, 0)
+                # Query ``i`` sees a row that holds a position after
+                # ``floor + i``; one that holds none is after no query's.
+                after = jnp.where(held >= 0, held - floor, 0)
+                products(k, v, lambda query: after > query)
+
+            pl.when(whole)(lambda: products(k, v, None))
+            pl.when(jnp.logical_not(whole))(compared)
+
+        def own_block(lo: int):
+            # Key ``j`` is the chunk's query ``j``'s own; the keys past
+            # ``s`` lie after every query.
+            key = jax.lax.broadcasted_iota(jnp.int32, (1, own_t), 1) + lo
+
+            def behind(query):
+                return (key <= query) & (key + window > query) if s > window else key <= query
+
+            products(kn_ref[0, lo : lo + own_t], vn_ref[0, lo : lo + own_t], behind)
+
+        for lo in range(0, ring, bt):
+            ring_block(lo)
+        for lo in range(0, keys, own_t):
+            own_block(lo)
+        total = jnp.sum(l_ref[...], axis=-1, keepdims=True)
+        o_ref[0, 0] = (acc_ref[...] / jnp.maximum(total, 1e-30)).astype(o_ref.dtype)
+
+
+def attend_ring_chunk(
+    q, k_new, v_new, ring_k, ring_v, q_pos, n_valid, *, n_kv: int, window: int, interpret=None
+):
+    """:func:`ops.gqa.attend_ring` for prefill chunks: q (b, s, H, D)
+    rotated, at consecutive positions ``q_pos`` (b, s); k_new, v_new
+    (b, s, KH * D) the call's rows; ring_k, ring_v (b, R, KH * D) the rings
+    before the call; ``n_valid`` (b,) the tokens of each row that count.
+    Returns (b, s, H, D) in q's dtype: exact zeros for a row with none,
+    whose ring is not read."""
+    if interpret is None:
+        interpret = _interpret_mode()
+    return _ring_chunk(
+        q, k_new, v_new, ring_k, ring_v, q_pos[:, 0].astype(jnp.int32),
+        (n_valid > 0).astype(jnp.int32), n_kv=n_kv, window=window, interpret=interpret,
+    )
+
+
+# Under ``jit`` for the walk's reason: the window layers of a program and
+# the programs of every window share one trace of the kernel.
+@functools.partial(jax.jit, static_argnames=("n_kv", "window", "interpret"))
+def _ring_chunk(q, k_new, v_new, ring_k, ring_v, first, live, *, n_kv: int, window: int, interpret: bool):
+    b, s, h, d = q.shape
+    g = h // n_kv
+    ring = ring_k.shape[1]
+    bt, keys, own_t = _ring_blocks(ring, s)
+    qh = _head_after_head(q, n_kv)
+    k_new, v_new = (jnp.pad(x, ((0, 0), (0, keys - s), (0, 0))) for x in (k_new, v_new))
+    head = pl.BlockSpec((1, 1, g * s, d), lambda bi, hi, *_: (bi, hi, 0, 0))
+    own = pl.BlockSpec((1, keys, d), lambda bi, hi, *_: (bi, 0, hi))
+    out = pl.pallas_call(
+        functools.partial(
+            _ring_kernel, block_t=bt, own_t=own_t, s=s, window=window,
+            tile=_chunk_tile(g * s), scale=d**-0.5,
+        ),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(b, n_kv),
+            in_specs=[
+                head, own, own,
+                pl.BlockSpec(memory_space=pl.ANY),
+                pl.BlockSpec(memory_space=pl.ANY),
+            ],
+            out_specs=head,
+            scratch_shapes=[
+                pltpu.VMEM((2, ring, d), ring_k.dtype),
+                pltpu.VMEM((2, ring, d), ring_v.dtype),
+                pltpu.SemaphoreType.DMA((2, 2)),
+                pltpu.SMEM((2,), jnp.int32),
+                pltpu.VMEM((g * s, _STAT_LANES), F32),
+                pltpu.VMEM((g * s, _STAT_LANES), F32),
+                pltpu.VMEM((g * s, d), F32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct(qh.shape, q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            # In order on one core: the buffer slot and the next program's
+            # ring ride from one program to the next.
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_BUDGET_BYTES,
+        ),
+        interpret=interpret,
+        name="gqa_ring_chunk_attention",
+    )(live, first, qh, k_new, v_new, ring_k, ring_v)
+    return _query_after_query(out, s)

@@ -301,10 +301,24 @@ SPARE_BYTES = 1_400_000_000
 SPARE_BY_FAMILY = {"exaone": 2_500_000_000, "mistral4": 400_000_000, "zaya": 800_000_000}
 
 
+_CHUNK_PROGRAMS: dict = {}  # what ``_chunk_program`` compiled, by what it was asked
+
+
 def _chunk_program(one_chip, config: str, rows: int, window: int):
     """``_prefill_suffix_rows`` of a layer-kind configuration under
     benchmarks/configs/, compiled for ``rows`` chunks under ``window``
-    against the cell's own slot state.  Returns (compiled, serving, engine)."""
+    against the cell's own slot state.  Returns (compiled, serving, engine).
+    A program is compiled once for the tests that read it (half a minute
+    each), apart by what the attention gates believe of the platform."""
+    from generativeaiexamples_tpu.ops import gqa_decode
+
+    key = (config, rows, window, gqa_decode.platform_of(None))
+    if key not in _CHUNK_PROGRAMS:
+        _CHUNK_PROGRAMS[key] = _compile_chunk_program(one_chip, config, rows, window)
+    return _CHUNK_PROGRAMS[key]
+
+
+def _compile_chunk_program(one_chip, config: str, rows: int, window: int):
     import json
     from pathlib import Path
 
@@ -404,6 +418,86 @@ def test_a_chunk_program_attends_over_its_slots_rows_where_they_lie(one_chip, fa
     assert memory.temp_size_in_bytes < 640_000_000
     # The slots' rows go through in place.
     assert memory.alias_size_in_bytes >= 2 * layers * slots * window * width * 2 * 0.99
+
+
+# (window layers, query heads, rows of a ring, a chunk's tokens)
+RING_LAYERS = {"mellum": (9, 32, 1024, 256), "exaone": (4, 64, 128, 256)}
+
+
+@pytest.mark.parametrize("rows", ["one_row", "largest_group"])
+@pytest.mark.parametrize("family", sorted(RING_LAYERS))
+def test_a_chunk_program_attends_over_its_rings_in_vmem(one_chip, family, rows, monkeypatch):
+    """The same programs (Mellum's and K-EXAONE's one-row and largest chunk
+    program at the widest window, the gates believing they are on the
+    chip): every ``window`` layer's attention is the ring kernel, and no
+    float32 scores of a chunk's queries against a ring (Mellum:
+    ``[.,32,256,1024]``; K-EXAONE ``[.,64,256,128]``), against the ring and
+    its own rows side by side (``[.,256,1280]``, ``[.,256,384]``) or
+    against its own rows (``[.,256,256]``) are left in the compiled text."""
+    from generativeaiexamples_tpu.ops import gqa_decode, moe
+
+    monkeypatch.setattr(moe, "platform_of", lambda mesh: "tpu")
+    monkeypatch.setattr(gqa_decode, "platform_of", lambda mesh: "tpu")
+    config, largest, window = GROUP_PROGRAMS[family]
+    n = 1 if rows == "one_row" else largest
+    compiled, serving, engine = _chunk_program(one_chip, config, n, window)
+    layers, heads, ring, chunk = RING_LAYERS[family]
+    assert len(serving.cfg.layers_of("window")) == layers
+    assert serving.cfg.ring_rows(window) == ring and int(engine["prefill_chunk_tokens"]) == chunk
+    text = compiled.as_text()
+    assert text.count("gqa_ring_chunk_attention") >= layers
+    kh = serving.cfg.n_kv_heads
+    for keys in (ring, ring + chunk, chunk):  # XLA's form has them by head and by KV head
+        assert not re.search(rf"f32\[(?:\d+,)*{heads},{chunk},{keys}\]", text), keys
+        assert not re.search(rf"f32\[(?:\d+,)*{kh},{heads // kh},{chunk},{keys}\]", text), keys
+
+
+@pytest.mark.parametrize("family", sorted(RING_LAYERS))
+def test_a_decode_chunk_keeps_the_rings_wide_form(one_chip, family, monkeypatch):
+    """Mellum's and K-EXAONE's decode chunk (8 steps over 32 slots, one
+    query a row or a token and its draft), lowered with the gates believing
+    they are on the chip: the full layers walk their rows, and a window
+    layer is ``gqa.attend_ring``'s wide form, as before the ring kernel:
+    its name is nowhere in a decode step."""
+    import json
+    from pathlib import Path
+
+    from generativeaiexamples_tpu.engine.serving_models import HybridServing
+    from generativeaiexamples_tpu.models import hybrid
+    from generativeaiexamples_tpu.ops import dispatch, gqa_decode, moe
+
+    monkeypatch.setattr(moe, "platform_of", lambda mesh: "tpu")
+    monkeypatch.setattr(gqa_decode, "platform_of", lambda mesh: "tpu")
+    configs = Path(__file__).resolve().parents[1] / "benchmarks" / "configs"
+    model = json.loads((configs / f"{GROUP_PROGRAMS[family][0]}.json").read_text())
+    engine = model["engine"]
+    max_len, b, steps = int(engine["max_len"]), int(engine["max_batch"]), int(engine["decode_chunk_size"])
+    cfg = hybrid.from_hf_config(
+        model, max_len=max_len, kv_dtype=engine["kv_dtype"], draft=engine.get("draft", "")
+    )
+    serving = HybridServing(cfg, None, max_len)
+
+    def described(make):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            jax.eval_shape(make),
+        )
+
+    spec = _spec(one_chip)
+    ints, floats, flags = spec((b,), jnp.int32), spec((b,), jnp.float32), spec((b,), jnp.bool_)
+    drafting = (spec((1, b), jnp.int32), flags, ints, flags) if cfg.draft else ()
+    dispatch.TAKEN.clear()
+    text = serving.make_decode_chunk().lower(
+        described(lambda: hybrid.init_params(cfg, jax.random.PRNGKey(0))),
+        described(lambda: hybrid.init_state(cfg, b, max_len)),
+        ints, ints, spec((2,), jnp.uint32), floats, floats, ints, steps, max_len, flags,
+        *drafting,
+    ).as_text()
+    assert "gqa_rows_decode_attention" in text and "gqa_ring_chunk_attention" not in text
+    ring = RING_LAYERS[family][2]
+    s = 2 if cfg.draft else 1
+    assert dispatch.TAKEN[f"attn_window b={b} s={s} t={ring}"] == "xla"
+    assert not any("attn_window_chunk" in site for site in dispatch.TAKEN)
 
 
 def _no_window_sized_temporaries(text: str, *, slots: int, rows: int, window: int) -> None:
