@@ -18,12 +18,9 @@ published widths with random int8 weights from ``--seed``:
    document and streams a ``/generate`` with the knowledge base on.
 3. ``retrieval`` — the ``tpu`` vector store over 262,144 x 1024 rows
    against a numpy top-k; ``vector_store.name=auto`` picks a TPU store.
-4. ``optin``    — ``kv_layout=paged`` and ``matmul_kernel=pallas_w8a8``
-   each decode greedy streams through the ``Scheduler`` (8 layers) and
-   match their reference streams token for token (paged against
-   contiguous inside one page, the W8A8 kernel against its XLA twin);
-   the paged kernel's walk over up to eight pages is held to its XLA
-   twin at tolerance, and streams of two to four pages run beside it.
+4. ``optin``    — ``matmul_kernel=pallas_w8a8`` decodes greedy streams
+   through the ``Scheduler`` (8 layers) and matches its XLA twin's
+   streams token for token.
 
 5. ``hybrid`` (``--hybrid``; not part of the default run) — a layer-kind
    model (``models/hybrid.py``) at its published widths, in process on
@@ -859,110 +856,6 @@ def _require_pallas(what: str, paths: dict, on_tpu: bool) -> None:
         )
 
 
-def _paged_kernel_vs_twin(cfg, seed: int, page: int, on_tpu: bool) -> dict:
-    """The paged decode kernel against its XLA twin on one layer of a
-    random pool: eight ragged rows of one to eight pages behind a
-    shuffled page table, with and without the append buffer.  The pair
-    is gated at tolerance, as in tests/test_paged_kv.py: the kernel
-    normalizes its online softmax in page order, the twin once over
-    the gathered window (docs/kernels.md)."""
-    import jax
-    import jax.numpy as jnp
-    import numpy as np
-
-    from generativeaiexamples_tpu.ops import decode_attention as da
-
-    layers, rows, slot_pages, chunk = 2, 8, 8, 8
-    kh, nq, hd = cfg.n_kv_heads, cfg.n_heads, cfg.head_dim
-    lengths = [1, page - 1, page, page + 1, 3 * page + 8, 4 * page + 1,
-               7 * page, 8 * page]
-    rng = np.random.default_rng(seed + 11)
-    # Pool page 0 is the pinned garbage page; the rows own the rest in
-    # shuffled order, so consecutive logical pages are far apart.
-    table = 1 + rng.permutation(rows * slot_pages).reshape(rows, slot_pages)
-    slots = -(-(rows * slot_pages + 1) * page // 128) * 128
-    keys = jax.random.split(jax.random.PRNGKey(seed + 11), 9)
-
-    def values(key, shape):
-        return jax.random.randint(key, shape, -127, 128, jnp.int8)
-
-    def scales(key, shape):
-        x = jnp.abs(jax.random.normal(key, shape, jnp.float32))
-        return (x * 0.02 + 0.01).astype(jnp.bfloat16)
-
-    pool = (
-        values(keys[0], (layers, kh, slots, hd)),
-        values(keys[1], (layers, kh, slots, hd)),
-        scales(keys[2], (layers, kh, slots)),
-        scales(keys[3], (layers, kh, slots)),
-    )
-    append = (
-        values(keys[4], (layers, kh, rows, chunk, hd)),
-        values(keys[5], (layers, kh, rows, chunk, hd)),
-        scales(keys[6], (layers, kh, rows, chunk)),
-        scales(keys[7], (layers, kh, rows, chunk)),
-        jnp.int32(5),
-    )
-    q = jax.random.normal(keys[8], (rows, nq, hd), jnp.bfloat16)
-    rest = (
-        jnp.int32(1),
-        jnp.asarray(lengths, jnp.int32),
-        jnp.asarray(table, jnp.int32),
-    )
-    took = da.use_paged_kernel(
-        s=1, kv_int8=True, page_tokens=page, n_q=nq, n_kv=kh,
-        head_dim=hd, append_width=chunk,
-    )
-    if on_tpu and not took:
-        raise SmokeFailure("use_paged_kernel refuses llama3-8b's geometry")
-    out = {
-        "rows_pages": [-(-n // page) for n in lengths],
-        "tolerance": "0.02 x the row's max|out|",
-        "max_abs_diff_over_row_max": {},
-    }
-    for name, ab in (("no_append", None), ("append_5_of_8", append)):
-        ref = np.asarray(
-            da.paged_decode_gqa_attention_xla(
-                q, *pool, *rest, ab,
-                window=slot_pages * page, page_tokens=page,
-            ),
-            np.float32,
-        )
-        if not np.isfinite(ref).all():
-            raise SmokeFailure(f"paged XLA twin ({name}): non-finite output")
-        if not took:  # the CPU rehearsal: the gate sends it to the twin
-            out["max_abs_diff_over_row_max"][name] = None
-            continue
-        got = np.asarray(
-            da.paged_decode_gqa_attention(
-                q, *pool, *rest, ab, page_tokens=page
-            ),
-            np.float32,
-        )
-        # Held row by row: two or three bfloat16 steps of the row's
-        # largest output, so a long row cannot hide behind a short one.
-        err = np.abs(got - ref).max(axis=(1, 2))
-        top = np.abs(ref).max(axis=(1, 2))
-        out["max_abs_diff_over_row_max"][name] = round(
-            float((err / top).max()), 5
-        )
-        if not (err <= 0.02 * top).all():
-            raise SmokeFailure(
-                f"paged kernel ({name}) differs from its XLA twin: max "
-                f"|d| per row {err.round(4).tolist()} against max |out| "
-                f"{top.round(4).tolist()} for {out['rows_pages']} pages"
-            )
-    return out
-
-
-def _agree(a: list, b: list) -> list:
-    """Per stream, how many leading tokens two runs have in common."""
-    return [
-        next((i for i, (x, y) in enumerate(zip(s, t)) if x != y), len(s))
-        for s, t in zip(a, b)
-    ]
-
-
 def child_optin(seed: int, sizes: Sizes) -> None:
     import jax
 
@@ -982,22 +875,7 @@ def child_optin(seed: int, sizes: Sizes) -> None:
     on_tpu = jax.default_backend() == "tpu"
     raw = init_random_int8_params(cfg, jax.random.PRNGKey(seed))
     packed = prepare_params(cfg, raw, None, pack=True)
-    # The kernel alone first, then through the Scheduler with two sets
-    # of streams.  Short: prompt + new tokens stay inside one 64-token
-    # page and one 64-slot window, where the paged and the contiguous
-    # layout fold the softmax over the same tiles, so their tokens must
-    # be equal.  Long: two to four pages per row, ragged, two of them
-    # crossing into a new page while they decode, so the kernel walks
-    # real page tables, alternates its buffer slots and prefetches page
-    # i+1 under page i.  Across tilings the kernels (and a kernel and
-    # its twin) agree to tolerance, not bitwise (docs/kernels.md), and
-    # greedy streams of a random model amplify the last bit: the long
-    # streams' agreement is printed, the tolerance is held above.
-    page = 64
-    t0 = time.monotonic()
-    kernel_vs_twin = _paged_kernel_vs_twin(cfg, seed, page, on_tpu)
     short = _prompts(seed, cfg.vocab_size, [40, 33, 24, 17])
-    long_ = _prompts(seed + 3, cfg.vocab_size, [185, 170, 120])
     new_tokens = 16
     kw = dict(max_batch=16, max_len=256, decode_chunk_size=8, seed=seed)
     note = (
@@ -1013,50 +891,6 @@ def child_optin(seed: int, sizes: Sizes) -> None:
             return [_greedy(sched, ps, new_tokens, 600.0) for ps in sets]
         finally:
             sched.stop()
-
-    contiguous = run(packed, (short, long_))
-    TAKEN.clear()
-    paged = run(packed, (short, long_), kv_layout="paged", kv_page_size=page)
-    paged_paths = _taken("paged_decode_attention")
-    os.environ["GAIE_DISABLE_PAGED_KERNEL"] = "1"
-    try:
-        TAKEN.clear()
-        (twin,) = run(packed, (long_,), kv_layout="paged", kv_page_size=page)
-        twin_paths = _taken("paged_decode_attention")
-    finally:
-        del os.environ["GAIE_DISABLE_PAGED_KERNEL"]
-    if paged[0] != contiguous[0]:
-        raise SmokeFailure(
-            f"kv_layout=paged stream differs from contiguous: "
-            f"{paged[0]} vs {contiguous[0]}"
-        )
-    _require_pallas("the paged decode kernel", paged_paths, on_tpu)
-    if on_tpu and set(twin_paths.values()) != {"xla"}:
-        raise SmokeFailure(f"the paged kernel's twin run took {twin_paths}")
-    report = runtime_report()
-    emit(
-        {
-            "phase": "optin.paged",
-            "config": note,
-            "page_tokens": page,
-            "kernel_vs_xla_twin": kernel_vs_twin,
-            "streams": len(short),
-            "tokens_each": new_tokens,
-            "matches_contiguous": True,
-            "long_streams_pages": [
-                [-(-len(p) // page), -(-(len(p) + new_tokens) // page)]
-                for p in long_
-            ],
-            "long_streams_tokens_equal_xla_twin": _agree(paged[1], twin),
-            "long_streams_tokens_equal_contiguous": _agree(
-                paged[1], contiguous[1]
-            ),
-            "kernel_paths": paged_paths,
-            "seconds": round(time.monotonic() - t0, 1),
-            "device": report["device"],
-            "compile": report["compile"],
-        }
-    )
 
     # W8A8: the kernel against its XLA twin on the same blocked params
     # (the twin is what GAIE_DISABLE_QMM_KERNEL selects at trace time).

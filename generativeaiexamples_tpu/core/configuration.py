@@ -197,24 +197,6 @@ class LLMConfig:
         "bit-identical XLA twin off-TPU.",
         default="xla",
     )
-    kv_layout: str = configfield(
-        "KV cache layout (config twin of the engine server's "
-        "--kv-layout flag): 'contiguous' gives each slot a dense "
-        "max_len window; 'paged' carves KV into fixed-size int8 pages "
-        "behind per-lane page tables — zero-copy prefix grafts (a "
-        "page-table row write plus refcount bumps), copy-on-write "
-        "sharing, slot-free parked segments, and per-lane attention "
-        "windows.  Requires kv_dtype='int8' and a single chip.",
-        default="contiguous",
-    )
-    kv_page_size: int = configfield(
-        "Tokens per KV page when kv_layout='paged' (power of two). "
-        "Smaller pages track ragged lengths more tightly (less read "
-        "amplification, finer parked accounting) at the cost of wider "
-        "page tables; the paged kernel engages for sizes that divide "
-        "128 (>= 32) or are multiples of it.  64 balances both.",
-        default=64,
-    )
 
 
 @configclass

@@ -190,30 +190,6 @@ def prepare_cache(cfg: llama.LlamaConfig, batch: int, max_len: int, mesh):
     return cache
 
 
-def prepare_paged_pool(
-    cfg: llama.LlamaConfig,
-    max_batch: int,
-    max_len: int,
-    page_tokens: int,
-    total_pages: Optional[int] = None,
-    mesh=None,
-):
-    """Allocate the paged KV pool (``engine.paged_kv.PagedKVPool``) —
-    the paged counterpart of :func:`prepare_cache`.  Single-chip only;
-    ``total_pages`` floors at ``max_batch * n_slot_pages + 1`` so
-    admission can never deadlock on pages (see paged_kv docstring)."""
-    from generativeaiexamples_tpu.engine.paged_kv import PagedKVPool
-
-    return PagedKVPool(
-        cfg,
-        max_batch,
-        max_len,
-        page_tokens,
-        total_pages=total_pages,
-        mesh=mesh,
-    )
-
-
 @jax.named_scope("kv_write")
 def _flush_append_buffer(cache, ab, starts, max_len: int):
     """Write the chunk's append buffer into the big cache, one scatter per
@@ -266,45 +242,6 @@ def _flush_append_buffer(cache, ab, starts, max_len: int):
         )
 
     return tuple(flush_leaf(bg, sm) for bg, sm in zip(cache, ab))
-
-
-@jax.named_scope("kv_write")
-def _flush_append_buffer_paged(
-    leaves, ab, starts, table, max_len: int, page_tokens: int
-):
-    """Paged twin of :func:`_flush_append_buffer`: write the chunk's
-    append buffer through the page table into the flat pool.
-
-    Row r's C slots land at LOGICAL positions [starts[r], starts[r] + C)
-    — the same :func:`ops.decode_attention.flush_clip_start` clip as the
-    contiguous flush, so garbage rows (parked/pinned lanes at
-    ``max_len - 1``) write the logical tail zone, whose table entries
-    for such lanes are unowned and therefore map to the pinned garbage
-    page 0: the flush can never corrupt a live or shared page.  Live
-    rows' pages were made private by the scheduler's ``make_writable``
-    before dispatch.
-    """
-    from generativeaiexamples_tpu.ops.decode_attention import (
-        flush_clip_start,
-    )
-
-    b = ab[0].shape[2]
-    c = ab[0].shape[3]
-    start = jnp.clip(starts, 0, flush_clip_start(max_len, c)).astype(
-        jnp.int32
-    )
-    pos = start[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
-    bidx = jnp.arange(b, dtype=jnp.int32)[:, None]
-    phys = (
-        table[bidx, pos // page_tokens] * page_tokens + pos % page_tokens
-    )  # (b, c)
-    # (L, KH, b, c, ...) updates scatter onto big[:, :, phys] — the
-    # advanced (b, c) index sits between the leading L/KH slices, so the
-    # update shape IS the append buffer's shape: one fused scatter per
-    # leaf, no transpose.
-    return tuple(
-        big.at[:, :, phys].set(small) for big, small in zip(leaves, ab)
-    )
 
 
 def pin_default_layout(cache):
@@ -520,153 +457,3 @@ def make_decode_chunk_fn(cfg: llama.LlamaConfig, mesh, max_len: int):
         )
 
     return decode_chunk_checked
-
-
-def make_paged_decode_chunk_fn(
-    cfg: llama.LlamaConfig, mesh, max_len: int, page_tokens: int
-):
-    """Paged twin of :func:`make_decode_chunk_fn`.
-
-    Signature: ``fn(params, leaves, table, tokens, lengths, key, temp,
-    top_p, top_k, n_steps, kv_bucket=None, carried=None, carry=None)``
-    (the last two as the contiguous chunk's) — the pool leaves are
-    donated, the device page table rides alongside (NOT donated: the
-    host owns it), and ``max_len`` is the LOGICAL per-slot capacity the
-    table maps.  Branch structure mirrors the contiguous chunk exactly
-    (append-buffer protocol when eligible, per-step paged scatter
-    otherwise), so greedy decode is bit-identical across layouts under
-    either branch — the parity matrix tests/test_paged_kv.py runs.
-    """
-    from generativeaiexamples_tpu.ops.decode_attention import (
-        use_append_buffer,
-    )
-
-    @functools.partial(
-        jax.jit, donate_argnums=(1,), static_argnums=(9, 10)
-    )
-    def paged_decode_chunk(
-        params,
-        leaves,
-        table,
-        tokens,
-        lengths,
-        key,
-        temp,
-        top_p,
-        top_k,
-        n_steps,
-        kv_bucket=None,
-        carried=None,
-        carry=None,
-    ):
-        tokens = carry_tokens(tokens, carried, carry)
-        window = min(kv_bucket, max_len) if kv_bucket else max_len
-        b = tokens.shape[0]
-        if use_append_buffer(
-            s=1,
-            kv_int8=True,
-            batch=b,
-            window=window,
-            n_q=cfg.n_heads,
-            n_kv=cfg.n_kv_heads,
-            head_dim=cfg.head_dim,
-            mesh=mesh,
-        ):
-            lengths0 = jnp.minimum(lengths, max_len - 1)
-            ab_shape = (
-                cfg.n_layers, cfg.n_kv_heads, b, n_steps, cfg.head_dim
-            )
-            ab = (
-                jnp.zeros(ab_shape, jnp.int8),
-                jnp.zeros(ab_shape, jnp.int8),
-                jnp.zeros(ab_shape[:-1], jnp.bfloat16),
-                jnp.zeros(ab_shape[:-1], jnp.bfloat16),
-            )
-
-            def body(carry, step):
-                ab, tok, key = carry
-                key, sub = jax.random.split(key)
-                positions = jnp.minimum(lengths0 + step, max_len - 1)[
-                    :, None
-                ]
-                hidden, _, ab = llama.forward(
-                    params,
-                    cfg,
-                    tok[:, None],
-                    positions,
-                    leaves,
-                    lengths0,
-                    mesh=mesh,
-                    kv_bucket=kv_bucket,
-                    append_cache=(ab, step),
-                    page_table=table,
-                    page_tokens=page_tokens,
-                    pages_len=max_len,
-                )
-                lg = llama.logits(params, hidden)[:, 0]
-                tok = sample(lg, sub, temp, top_p, top_k)
-                return (ab, tok, key), tok
-
-            (ab, tok, key), toks = jax.lax.scan(
-                body,
-                (ab, tokens, key),
-                jnp.arange(n_steps, dtype=jnp.int32),
-            )
-            out = _flush_append_buffer_paged(
-                leaves, ab, lengths0, table, max_len, page_tokens
-            )
-            return out, toks
-
-        def body(carry, _):
-            leaves, tok, lengths, key = carry
-            key, sub = jax.random.split(key)
-            positions = jnp.minimum(lengths, max_len - 1)[:, None]
-            hidden, leaves = llama.forward(
-                params,
-                cfg,
-                tok[:, None],
-                positions,
-                leaves,
-                jnp.minimum(lengths + 1, max_len),
-                mesh=mesh,
-                kv_bucket=kv_bucket,
-                page_table=table,
-                page_tokens=page_tokens,
-                pages_len=max_len,
-            )
-            lg = llama.logits(params, hidden)[:, 0]
-            tok = sample(lg, sub, temp, top_p, top_k)
-            return (leaves, tok, lengths + 1, key), tok
-
-        (leaves, tok, lengths, key), toks = jax.lax.scan(
-            body, (leaves, tokens, lengths, key), None, length=n_steps
-        )
-        return leaves, toks
-
-    if not os.environ.get("GAIE_DEBUG_CHECKS"):
-        return paged_decode_chunk
-
-    def paged_decode_chunk_checked(
-        params, leaves, table, tokens, lengths, key, temp, top_p,
-        top_k, n_steps, kv_bucket=None, carried=None, carry=None,
-    ):
-        """Same kv_bucket contract guard as the contiguous wrapper."""
-        if kv_bucket is not None:
-            import numpy as _np
-
-            arr = _np.asarray(lengths)
-            live = arr[arr < max_len - 1]
-            if live.size:
-                needed = min(int(live.max()) + int(n_steps), max_len)
-                if kv_bucket < needed:
-                    raise AssertionError(
-                        "kv_bucket contract violated: a live lane covers "
-                        f"positions up to {needed} but the attention "
-                        f"window is {kv_bucket}"
-                    )
-        return paged_decode_chunk(
-            params, leaves, table, tokens, lengths, key, temp, top_p,
-            top_k, n_steps, kv_bucket, carried, carry,
-        )
-
-    return paged_decode_chunk_checked

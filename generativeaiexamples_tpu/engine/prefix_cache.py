@@ -72,7 +72,6 @@ class PrefixCacheIndex:
         self.max_segments = max_segments
         self._root = _Node()
         self._tokens: dict[int, list[int]] = {}
-        self._pages: dict[int, list[int]] = {}
         self._pins: dict[int, int] = {}
         self._used: dict[int, int] = {}
         self._clock = 0
@@ -89,52 +88,20 @@ class PrefixCacheIndex:
     def tokens(self, seg_id: int) -> Optional[list[int]]:
         return self._tokens.get(seg_id)
 
-    def pages(self, seg_id: int) -> list[int]:
-        """The pool page ids this parked segment OWNS (references held
-        on the segment's behalf; the scheduler releases them back to the
-        pool when the segment is consumed or evicted).  Empty when the
-        owner runs the contiguous cache or registered tokens only."""
-        return self._pages.get(seg_id, [])
-
-    def total_pages(self) -> int:
-        """Pages held across registered segments — the paged pool's
-        parked footprint as this index sees it (a page shared by two
-        segments counts once per holder, mirroring its refcount)."""
-        return sum(len(p) for p in self._pages.values())
-
-    def lru_order(self) -> list[int]:
-        """Registered segment ids, least recently used first — the
-        pool-pressure eviction scan order (callers skip pinned ids)."""
-        return sorted(self._tokens, key=lambda s: self._used.get(s, 0))
-
     # -- mutation ----------------------------------------------------------
 
-    def insert(
-        self,
-        seg_id: int,
-        tokens: Sequence[int],
-        pages: Optional[Sequence[int]] = None,
-    ) -> None:
+    def insert(self, seg_id: int, tokens: Sequence[int]) -> None:
         """Register ``tokens`` as segment ``seg_id`` (replacing any prior
         registration of the same id).  Empty histories cache nothing.
         When ``max_segments`` is set, the least-recently-used unpinned
         segment is evicted to make room (the fresh segment never evicts
-        itself, so a cap of 1 keeps the newest).
-
-        ``pages`` records the pool page ids the parked segment OWNS
-        (exactly ``ceil(len / page_tokens)`` of them) — true-length
-        accounting, never the padded ``kv_bucket`` row the contiguous
-        cache would charge.  The index only bookkeeps the ids; the
-        scheduler moves the refcounts.  ``None`` (contiguous cache, or
-        a router mirror that tracks tokens only) holds no pages."""
+        itself, so a cap of 1 keeps the newest)."""
         if seg_id in self._tokens:
             self.remove(seg_id)
         toks = [int(t) for t in tokens]
         if not toks:
             return
         self._tokens[seg_id] = toks
-        if pages is not None:
-            self._pages[seg_id] = [int(p) for p in pages]
         self.touch(seg_id)
         self._insert_path(seg_id, toks)
         if self.max_segments is not None:
@@ -191,7 +158,6 @@ class PrefixCacheIndex:
     def remove(self, seg_id: int) -> None:
         """Drop a segment; edges left with no segments are pruned."""
         toks = self._tokens.pop(seg_id, None)
-        self._pages.pop(seg_id, None)
         self._pins.pop(seg_id, None)
         self._used.pop(seg_id, None)
         if toks is None:
@@ -210,9 +176,6 @@ class PrefixCacheIndex:
                 return
             node = child
             i += len(label)
-
-    def clear(self) -> None:
-        self.__init__(self.max_segments)
 
     # -- lookup ------------------------------------------------------------
 

@@ -1171,15 +1171,6 @@ class EnginePool:
         "executable_trace_s",
         "executable_lower_s",
         "executable_backend_s",
-        # Paged-KV pool gauges/counters sum across replicas: each
-        # replica owns a disjoint page pool, so pool-wide capacity and
-        # pressure are the sums (all zero under the contiguous layout).
-        "kv_pages_total",
-        "kv_pages_free",
-        "kv_pages_parked",
-        "kv_pages_shared",
-        "kv_cow_breaks",
-        "kv_page_evictions",
     )
 
     def snapshot(self) -> dict:
@@ -1240,22 +1231,6 @@ class EnginePool:
             accept_weighted / agg["spec_proposed"], 4
         ) if agg["spec_proposed"] else 0.0
         agg["spec_gamma"] = spec_gamma_max
-        # Derived page-pool views for the 429 Retry-After projection and
-        # dashboards: utilization over the summed pool, the worst
-        # replica's per-admission page need, and the pool-wide free
-        # rate (sums — any replica's frees can serve a new admission
-        # after routing).
-        agg["kv_page_utilization"] = (
-            round(1.0 - agg["kv_pages_free"] / agg["kv_pages_total"], 4)
-            if agg["kv_pages_total"]
-            else 0.0
-        )
-        agg["kv_pages_per_admit"] = max(
-            (s.get("kv_pages_per_admit", 0) for s in replicas), default=0
-        )
-        agg["kv_page_free_rate"] = round(
-            sum(s.get("kv_page_free_rate", 0.0) for s in replicas), 3
-        )
         agg["pool_size"] = sum(
             1 for _, state, _ in members if state in (HEALTHY, PROBATION)
         )

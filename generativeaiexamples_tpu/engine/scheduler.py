@@ -165,8 +165,8 @@ class _Chunk:
 
 # What the tick thread can be doing; it is in exactly one at any instant.
 #   idle         blocked on the empty queue: no work anywhere
-#   plan         host-only bookkeeping: page eviction, slot scans, prefix
-#                lookups, prompt clipping, slot claims, lane state
+#   plan         host-only bookkeeping: slot scans, prefix lookups, prompt
+#                clipping, slot claims, lane state
 #   dispatch     from the first host-to-device array of a program to the
 #                return of its jitted call
 #   wait_device  blocked fetching a result
@@ -478,23 +478,6 @@ class Stats:
         self.executable_trace_s = 0.0
         self.executable_lower_s = 0.0
         self.executable_backend_s = 0.0
-        # Paged KV pool gauges (zero when kv_layout="contiguous"): total
-        # pool pages, current free-list depth, pages held by parked
-        # radix segments, and pages shared by more than one owner
-        # (refcount > 1, COW-armed).  kv_cow_breaks counts pages
-        # privatized by a copy-on-write break; kv_page_evictions counts
-        # parked segments evicted under pool pressure.
-        # kv_page_free_rate is an EWMA of pages returned to the free
-        # list per second — the 429 Retry-After hint projects when
-        # enough pages free up for the next admission from it.
-        self.kv_pages_total = 0
-        self.kv_pages_free = 0
-        self.kv_pages_parked = 0
-        self.kv_pages_shared = 0
-        self.kv_cow_breaks = 0
-        self.kv_page_evictions = 0
-        self.kv_pages_per_admit = 0
-        self.kv_page_free_rate = 0.0
 
     def snapshot(self) -> dict:
         with self.lock:
@@ -566,24 +549,6 @@ class Stats:
                 "executable_trace_s": self.executable_trace_s,
                 "executable_lower_s": self.executable_lower_s,
                 "executable_backend_s": self.executable_backend_s,
-                "kv_pages_total": self.kv_pages_total,
-                "kv_pages_free": self.kv_pages_free,
-                "kv_pages_parked": self.kv_pages_parked,
-                "kv_pages_shared": self.kv_pages_shared,
-                "kv_cow_breaks": self.kv_cow_breaks,
-                "kv_page_evictions": self.kv_page_evictions,
-                "kv_pages_per_admit": self.kv_pages_per_admit,
-                "kv_page_free_rate": round(self.kv_page_free_rate, 3),
-                # Page utilization: fraction of the pool NOT on the free
-                # list (live + parked + garbage page).  0.0 when the
-                # contiguous layout runs (no pool).
-                "kv_page_utilization": (
-                    round(
-                        1.0 - self.kv_pages_free / self.kv_pages_total, 4
-                    )
-                    if self.kv_pages_total
-                    else 0.0
-                ),
             }
 
 
@@ -629,9 +594,6 @@ class Scheduler:
         quantize: bool = False,
         matmul_kernel: Optional[str] = None,
         kv_layout: str = "contiguous",
-        kv_page_size: int = 64,
-        kv_pool_pages: Optional[int] = None,
-        kv_page_low_water: Optional[int] = None,
     ) -> None:
         # Set-up in three stages (``setup/params``, ``setup/state``,
         # ``setup/programs``: spans, and gauges of ``runtime_report()``):
@@ -692,9 +654,7 @@ class Scheduler:
         # Positions a decode step writes a row: two where every step
         # verifies the model's own draft (``serving_models``: ``draft``).
         self._step_width = 2 if model.draft else 1
-        model.check_supported(
-            kv_layout=kv_layout, draft_cfg=draft_cfg, spec_mode=spec_mode
-        )
+        model.check_supported(draft_cfg=draft_cfg, spec_mode=spec_mode)
         # ``quantize`` is the int8-weights serving configuration: float
         # (or absent, hence random) params become int8 projections with
         # qkv and gate/up packed — what ``chip_smoke.py`` hands over pre-built.
@@ -719,71 +679,12 @@ class Scheduler:
         )
         # The slots' state, a draft's beside it, and the host's books.
         EXECUTABLES.enter_stage("state")
-        # Paged KV cache (opt-in): the target cache becomes a page pool
-        # (``engine.paged_kv``) — fixed-size int8 pages, per-slot page
-        # tables, refcounted free list.  Grafts turn into host table
-        # copies (zero device dispatch), parked segments hold exact
-        # pages, and ragged decode batches read only the pages each lane
-        # actually has.  The DRAFT cache (speculation) stays contiguous
-        # in every mode: it is small, slot-private, and never shared.
-        if kv_layout not in ("contiguous", "paged"):
+        # One KV layout; the keyword stays because benchmarks/run.py
+        # passes it (ROADMAP.md queue 3 item 15).
+        if kv_layout != "contiguous":
             raise ValueError(f"unknown kv_layout mode {kv_layout!r}")
-        if kv_page_size < 1 or (kv_page_size & (kv_page_size - 1)):
-            raise ValueError(
-                f"kv_page_size must be a power of two, got {kv_page_size}"
-            )
-        self.kv_layout = kv_layout
-        self.kv_page_size = int(kv_page_size)
-        self._pool = None
-        # Parked prefix segments (paged mode): finished histories park as
-        # page-owning SEGMENTS in the radix index instead of occupying a
-        # slot — ids allocated past max_batch so they can never collide
-        # with the slot ids the contiguous path registers.
-        self._next_seg = max_batch
-        self._session_segs: dict[str, int] = {}
-        self._seg_sessions: dict[int, str] = {}
-        if kv_layout == "paged":
-            from generativeaiexamples_tpu.engine.decode import (
-                make_paged_decode_chunk_fn,
-                prepare_paged_pool,
-            )
-
-            self._pool = prepare_paged_pool(
-                cfg, max_batch, self.max_len, kv_page_size,
-                total_pages=kv_pool_pages, mesh=mesh,
-            )
-            self._cache = self._pool.leaves
-            self._decode_chunk = make_paged_decode_chunk_fn(
-                cfg, mesh, self.max_len, kv_page_size
-            )
-            # Pool-pressure eviction low-water mark: when the free list
-            # drops below this many pages at a tick boundary, LRU parked
-            # prefix segments are evicted until it recovers (or none are
-            # left) — admission then allocates from a healthy free list
-            # instead of discovering pressure mid-claim.  Default: one
-            # slot's worth of pages.
-            self._kv_low_water = (
-                int(kv_page_low_water)
-                if kv_page_low_water is not None
-                else self._pool.n_slot_pages
-            )
-            self.stats.kv_pages_total = self._pool.total_pages
-            self.stats.kv_pages_free = self._pool.pages_free
-            self.stats.kv_pages_per_admit = self._pool.n_slot_pages
-            # Admission page-need EWMA (seeds at a full slot's worth)
-            # and free-rate tracking state for the server's 429
-            # Retry-After projection.
-            self._pages_per_admit_ewma = float(self._pool.n_slot_pages)
-            self._kv_frees_prev = 0
-            self._kv_free_rate_t = time.time()
-            # Pages promised to batch admissions whose allocation is
-            # deferred to _admit_dispatch later this tick — the gate
-            # counts them so one tick cannot over-admit a batch against
-            # the same free list.
-            self._kv_pages_reserved = 0
-        else:
-            self._cache = model.init_state(max_batch, self.max_len)
-            self._decode_chunk = model.make_decode_chunk()
+        self._cache = model.init_state(max_batch, self.max_len)
+        self._decode_chunk = model.make_decode_chunk()
         # Speculative decoding (TRT-LLM draft-model parity, SURVEY.md
         # §2.8): a draft config turns every decode chunk into speculation
         # rounds — draft proposes gamma tokens, target verifies in one
@@ -818,18 +719,9 @@ class Scheduler:
             self._dcache = prepare_cache(
                 draft_cfg, max_batch, self.max_len, mesh
             )
-            if self._pool is not None:
-                from generativeaiexamples_tpu.engine.spec_decode import (
-                    make_paged_spec_chunk_fn,
-                )
-
-                self._spec_chunk = make_paged_spec_chunk_fn(
-                    cfg, draft_cfg, mesh, self.max_len, kv_page_size
-                )
-            else:
-                self._spec_chunk = make_spec_chunk_fn(
-                    cfg, draft_cfg, mesh, self.max_len
-                )
+            self._spec_chunk = make_spec_chunk_fn(
+                cfg, draft_cfg, mesh, self.max_len
+            )
             # Spec-mode length margin: a live row must never start a
             # round with its write position inside the append-buffer
             # flush-clip zone [max_len - (gamma+1), max_len) — a clipped
@@ -867,18 +759,9 @@ class Scheduler:
             # and the chunk carries it forward (donated) — no per-tick
             # host-to-device upload of a (max_batch, max_len) buffer.
             self._dhist = jnp.zeros((max_batch, self.max_len), jnp.int32)
-            if self._pool is not None:
-                from generativeaiexamples_tpu.engine.spec_decode import (
-                    make_paged_ngram_spec_chunk_fn,
-                )
-
-                self._ngram_chunk = make_paged_ngram_spec_chunk_fn(
-                    cfg, mesh, self.max_len, kv_page_size, ngram=ngram
-                )
-            else:
-                self._ngram_chunk = make_ngram_spec_chunk_fn(
-                    cfg, mesh, self.max_len, ngram=ngram
-                )
+            self._ngram_chunk = make_ngram_spec_chunk_fn(
+                cfg, mesh, self.max_len, ngram=ngram
+            )
             self.effective_max_len = self.max_len - (gamma + 1)
             if self.effective_max_len < 2:
                 raise ValueError(
@@ -1112,66 +995,10 @@ class Scheduler:
         # inside a request.
         self._chunk_rows, self._chunk_windows = 1, ()
         self._chunk_programs: dict[tuple[int, int], Callable] = {}
-        if prefill_chunk_tokens and self._pool is None and draft_cfg is None:
+        if prefill_chunk_tokens and draft_cfg is None:
             self._compile_chunk_programs(
                 model.chunks_per_program(prefill_chunk_tokens)
             )
-
-        if self._pool is not None:
-            page_tokens_arg = self.kv_page_size
-            pages_len_arg = self.max_len
-
-            @functools.partial(jax.jit, donate_argnums=(0,))
-            @jax.named_scope("kv_write")
-            def _graft_rows_paged(big, small, rows, phys):
-                """Paged twin of ``_graft_rows``: cold-prefilled rows of
-                the small contiguous cache scatter to the PHYSICAL pool
-                positions the host computed from each slot's page table
-                (``phys`` (k, s) int32 = table[slot, t // pt] * pt +
-                t % pt).  Padded tail positions map through unowned
-                table entries to the garbage page — harmless by the
-                pool's layout invariant."""
-                out = []
-                for bg, sm in zip(big, small):
-                    gathered = jnp.take(sm, rows, axis=2)  # (L, KH, k, s, ..)
-                    out.append(bg.at[:, :, phys].set(gathered))
-                return tuple(out)
-
-            @functools.partial(
-                jax.jit, donate_argnums=(1,), static_argnums=(8,)
-            )
-            def _prefill_suffix_paged(
-                params, leaves, table_row, tokens, start, suffix_len,
-                key, sampling, kv_bucket,
-            ):
-                """Paged twin of ``_prefill_suffix``: the warm forward
-                writes/reads through the slot's (1, n_slot_pages) table
-                row — no row slice out of the big cache and no
-                dynamic_update_slice back; the pool leaves are donated
-                straight through."""
-                temp, top_p, top_k = sampling
-                s = tokens.shape[1]
-                positions = start + jnp.arange(s, dtype=jnp.int32)[None, :]
-                hidden, leaves = llama.forward(
-                    params,
-                    cfg,
-                    tokens,
-                    positions,
-                    leaves,
-                    jnp.reshape(start + suffix_len, (1,)),
-                    mesh=mesh_arg,
-                    kv_bucket=kv_bucket,
-                    page_table=table_row,
-                    page_tokens=page_tokens_arg,
-                    pages_len=pages_len_arg,
-                )
-                last = hidden[0, jnp.maximum(suffix_len - 1, 0)]
-                lg = llama.logits(params, last[None, None, :])[:, 0]
-                tok = sample(lg, key, temp, top_p, top_k)
-                return leaves, tok
-
-            self._graft_rows_paged = _graft_rows_paged
-            self._prefill_suffix_paged = _prefill_suffix_paged
 
         if draft_cfg is not None:
 
@@ -1383,20 +1210,6 @@ class Scheduler:
         with self.stats.lock:
             self.stats.state_snapshots_restored += 1
 
-    def _set_cache(self, leaves) -> None:
-        """Install updated cache buffers; in paged mode the pool owns
-        the leaves (its COW copies replace them too), so keep the two
-        references aliased."""
-        self._cache = leaves
-        if self._pool is not None:
-            self._pool.leaves = leaves
-
-    def _pool_cache(self):
-        """The cache to dispatch with: pool leaves in paged mode (they
-        may have been replaced by a COW copy since ``self._cache`` was
-        last assigned), ``self._cache`` otherwise."""
-        return self._pool.leaves if self._pool is not None else self._cache
-
     def _is_cancelled(self, request_id: str) -> bool:
         with self._cancel_lock:
             if request_id in self._cancelled:
@@ -1445,10 +1258,8 @@ class Scheduler:
         ]
 
     def _reclaim_parked(self, n: int) -> list[int]:
-        """Evict up to ``n`` slot-parked prefix segments, oldest first
-        (contiguous mode only — paged parking holds pages, not slots, so
-        the scan is empty there).  Segments pinned by an in-flight graft
-        are never taken."""
+        """Evict up to ``n`` slot-parked prefix segments, oldest first.
+        Segments pinned by an in-flight graft are never taken."""
         parked = sorted(
             (
                 i
@@ -1474,39 +1285,6 @@ class Scheduler:
         slot.parked_at = 0.0
         slot.length = 0
         slot.warm_pos = None
-
-    def _park_segment(
-        self, session_id: str, history: list[int], pages: list[int]
-    ) -> int:
-        """Register a finished history as a page-owning parked SEGMENT
-        (paged mode).  The segment id comes from a monotonic counter
-        starting at ``max_batch`` so it can never collide with the slot
-        ids the contiguous path registers.  A session's previous turn is
-        dropped first (one segment per session — the new turn's history
-        extends the old one, so the old adds no match the new cannot
-        serve)."""
-        seg = self._next_seg
-        self._next_seg += 1
-        if session_id:
-            stale = self._session_segs.pop(session_id, None)
-            if stale is not None:
-                self._drop_segment(stale)
-            self._session_segs[session_id] = seg
-            self._seg_sessions[seg] = session_id
-        self._prefix_index.insert(seg, history, pages=pages)
-        return seg
-
-    def _drop_segment(self, seg: int) -> None:
-        """Remove a parked segment and release its page references back
-        to the pool (pages shared with live slots survive via their
-        refcounts)."""
-        pages = self._prefix_index.pages(seg)
-        self._prefix_index.remove(seg)
-        sid = self._seg_sessions.pop(seg, None)
-        if sid is not None and self._session_segs.get(sid) == seg:
-            del self._session_segs[sid]
-        if pages and self._pool is not None:
-            self._pool.release(pages)
 
     def _active(self) -> list[int]:
         """Slots decoding this tick: live request, prefill complete and
@@ -1624,44 +1402,26 @@ class Scheduler:
                 history = list(slot.history)
             else:
                 history = slot.history[:-1]
-            if self._pool is not None:
-                # SEGMENT parking (paged mode): trim to the exact pages
-                # the history occupies — ceil(len / page_size), not the
-                # padded kv_bucket row the contiguous cache holds — then
-                # DETACH those pages from the slot and hand them to a
-                # parked segment in the radix index.  The slot itself is
-                # immediately free for the next admission: parking no
-                # longer consumes a slot, only pages.  Phantom KV from
-                # speculation/decode past the history is released by the
-                # trim (refcounted, so a page shared with a live grafted
-                # slot survives).
-                self._pool.trim(slot_idx, len(history))
-                pages = self._pool.detach(slot_idx)
-                self._park_segment(req.session_id, history, pages)
-                self._unpark(slot_idx)
-            else:
-                if req.session_id:
-                    for i, s in enumerate(self._slots):
-                        if (
-                            s.session_id == req.session_id
-                            and s.request is None
-                        ):
-                            # stale earlier turn of this session
-                            self._unpark(i)
-                slot.session_id = req.session_id
-                slot.cached = True
-                slot.history = history
-                slot.length = len(history)
-                slot.parked_at = time.monotonic()
-                if self.prefix_cache == "shared":
-                    # Register for cross-request content matching (session
-                    # turns included: many sessions share one system
-                    # prompt).
-                    self._prefix_index.insert(slot_idx, history)
+            if req.session_id:
+                for i, s in enumerate(self._slots):
+                    if (
+                        s.session_id == req.session_id
+                        and s.request is None
+                    ):
+                        # stale earlier turn of this session
+                        self._unpark(i)
+            slot.session_id = req.session_id
+            slot.cached = True
+            slot.history = history
+            slot.length = len(history)
+            slot.parked_at = time.monotonic()
+            if self.prefix_cache == "shared":
+                # Register for cross-request content matching (session
+                # turns included: many sessions share one system
+                # prompt).
+                self._prefix_index.insert(slot_idx, history)
         else:
             self._unpark(slot_idx)
-            if self._pool is not None:
-                self._pool.reset_slot(slot_idx)
         slot.emitted = 0
         if req is not None and req.id:
             # Late cancels (e.g. the handler's disconnect guard) must not
@@ -1738,32 +1498,10 @@ class Scheduler:
         slots_arr = np.full((kb,), slot_idxs[0], dtype=np.int32)
         rows[:k] = np.arange(k)
         slots_arr[:k] = slot_idxs
-        if self._pool is not None:
-            # Allocate each admitted slot's pages, then scatter the
-            # prefilled rows to their PHYSICAL pool positions.  Padding
-            # columns beyond a prompt's last owned page map through
-            # unowned (0) table entries to the garbage page; the kb - k
-            # duplicate rows re-scatter slot_idxs[0]'s row (idempotent,
-            # same as the contiguous path's duplicate grafts).
-            pt = self._pool.page_tokens
-            for r in range(k):
-                self._pool.reset_slot(slot_idxs[r])
-                self._pool.make_writable(slot_idxs[r], 0, plens[r])
-            tpos = np.arange(s, dtype=np.int64)
-            phys = (
-                self._pool.tables[slots_arr][:, tpos // pt] * pt + tpos % pt
-            ).astype(np.int32)  # (kb, s)
-            self._set_cache(
-                self._graft_rows_paged(
-                    self._pool.leaves, small, jnp.asarray(rows),
-                    jnp.asarray(phys),
-                )
-            )
-        else:
-            self._cache, self._carried = self._graft_rows(
-                self._cache, small, jnp.asarray(rows), jnp.asarray(slots_arr),
-                self._carried, tok,
-            )
+        self._cache, self._carried = self._graft_rows(
+            self._cache, small, jnp.asarray(rows), jnp.asarray(slots_arr),
+            self._carried, tok,
+        )
         if self._dhist is not None:
             # Scatter the admitted prompts into the device history.  The
             # kb padding lanes repeat row 0 so their duplicate writes to
@@ -1794,7 +1532,7 @@ class Scheduler:
             slot.history = list(req.token_ids)
             slot.accept_ewma = 1.0
             slot.unfetched = 1
-            slot.on_device = self._pool is None
+            slot.on_device = True
         return reqs, slot_idxs, tok, ticket
 
     def _admit_finalize(
@@ -1829,27 +1567,11 @@ class Scheduler:
     MIN_PREFIX = 32
 
     def _find_parked(self, req: Request) -> tuple[int, int]:
-        """Locate this session's parked prefix KV — a parked slot
-        (contiguous) or a page-owning segment (paged) — whose cached
-        history is a long-enough prefix of the new prompt; returns
-        (slot_or_seg, prefix_len) or (-1, 0)."""
+        """Locate this session's parked slot, whose cached history is a
+        long-enough prefix of the new prompt; returns (slot, prefix_len)
+        or (-1, 0)."""
         req.prefix_matched = 0
         if not req.session_id:
-            return -1, 0
-        if self._pool is not None:
-            seg = self._session_segs.get(req.session_id)
-            if seg is None:
-                return -1, 0
-            n = 0
-            for a, b in zip(
-                self._prefix_index.tokens(seg) or (), req.token_ids
-            ):
-                if a != b:
-                    break
-                n += 1
-            n = self._state_depth(req, n)
-            if n >= self.MIN_PREFIX:
-                return seg, n
             return -1, 0
         for i, s in enumerate(self._slots):
             if s.request is None and s.session_id == req.session_id:
@@ -1867,7 +1589,7 @@ class Scheduler:
     def _find_shared(self, req: Request) -> tuple[int, int]:
         """Locate a parked segment (any session) sharing the longest token
         prefix with the prompt via the radix index; returns
-        (slot_or_seg, prefix_len) or (-1, 0)."""
+        (slot, prefix_len) or (-1, 0)."""
         if self.prefix_cache != "shared":
             return -1, 0
         seg, common = self._prefix_index.match(req.token_ids)
@@ -1878,14 +1600,12 @@ class Scheduler:
             common = self._state_depth(req, common)
         if common < self.MIN_PREFIX:
             return -1, 0
-        if self._pool is None:
-            slot = self._slots[seg]
-            if slot.request is not None or not slot.cached:
-                # Defensive: the index and slot state are maintained
-                # together, but a stale entry must never graft live rows.
-                # (Paged segments carry no slot state to go stale.)
-                self._prefix_index.remove(seg)
-                return -1, 0
+        slot = self._slots[seg]
+        if slot.request is not None or not slot.cached:
+            # Defensive: the index and slot state are maintained
+            # together, but a stale entry must never graft live rows.
+            self._prefix_index.remove(seg)
+            return -1, 0
         return seg, common
 
     def _suffix_dispatch(self, req: Request, slot_idx: int, common: int):
@@ -1905,38 +1625,19 @@ class Scheduler:
             jnp.asarray([sp.top_p], dtype=jnp.float32),
             jnp.asarray([sp.top_k], dtype=jnp.int32),
         )
-        if self._pool is not None:
-            # Private pages for the suffix range (COW the boundary page
-            # a graft shared); the padded tail past plen lands in the
-            # last owned page's tail or the garbage page.
-            self._pool.make_writable(slot_idx, common, plen)
-            table = self._pool.device_table()
-            cache, tok = self._prefill_suffix_paged(
-                self.params,
-                self._pool.leaves,
-                table[slot_idx : slot_idx + 1],
-                jnp.asarray(tokens),
-                jnp.int32(common),
-                jnp.int32(len(suffix)),
-                self._next_key(),
-                sampling_dev,
-                kv_bucket,
-            )
-            self._set_cache(cache)
-        else:
-            cache, tok, aux = self._prefill_suffix(
-                self.params,
-                self._cache,
-                jnp.asarray(tokens),
-                jnp.int32(common),
-                jnp.int32(len(suffix)),
-                jnp.int32(slot_idx),
-                self._next_key(),
-                sampling_dev,
-                kv_bucket,
-            )
-            self._cache = cache
-            self._note_aux(aux)
+        cache, tok, aux = self._prefill_suffix(
+            self.params,
+            self._cache,
+            jnp.asarray(tokens),
+            jnp.int32(common),
+            jnp.int32(len(suffix)),
+            jnp.int32(slot_idx),
+            self._next_key(),
+            sampling_dev,
+            kv_bucket,
+        )
+        self._cache = cache
+        self._note_aux(aux)
         if self.draft_cfg is not None:
             # Draft-side twin: the draft cache row must cover the same
             # [0, plen) window as the target's before the next spec round
@@ -2040,138 +1741,10 @@ class Scheduler:
         t = self._suffix_dispatch(req, slot_idx, common)
         return lambda: self._suffix_finalize(*t)
 
-    def _admit_paged_hit(
-        self, req: Request, seg: int, common: int, *, consume: bool,
-        shared: bool,
-    ) -> tuple[bool, Optional[Callable[[], None]]]:
-        """Admit a prefix hit from a page-owning parked SEGMENT (paged
-        mode): a free slot's page-table row takes references to the
-        segment's pages — host bookkeeping plus refcount bumps, zero KV
-        traffic — and only the suffix is prefilled.
-
-        ``consume`` (session hits) drops the segment after the transfer:
-        the slot becomes the pages' sole owner, so its appends never
-        COW, and the updated history re-parks at finish.  Shared hits
-        keep the segment serving other requests; the destination's first
-        write into the shared boundary page breaks COW by copying only
-        that page.
-
-        Returns ``(admitted, finalize)`` — ``(False, None)`` when no
-        free slot or pages exist (caller backlogs the request)."""
-        plen = len(req.token_ids)
-        common = min(common, plen - 1, self._admit_limit - 2)
-        free = self._free_slots()
-        if not free:
-            return False, None
-        # Pin across the page-pressure eviction: _ensure_pages must not
-        # evict the very segment this admission is about to reference.
-        self._prefix_index.pin(seg)
-        try:
-            if not self._admit_pages_ok(plen, common):
-                return False, None
-            slot_idx = free[0]
-            if self._pool.slot_pages(slot_idx):
-                self._pool.reset_slot(slot_idx)  # defensive; free = empty
-            self._pool.share_pages(
-                self._prefix_index.pages(seg), slot_idx, common
-            )
-        finally:
-            self._prefix_index.unpin(seg)
-        if consume:
-            self._drop_segment(seg)
-        else:
-            self._prefix_index.touch(seg)
-        if self.draft_cfg is not None and common > 0:
-            # The segment holds TARGET pages only — the contiguous draft
-            # cache has no KV for this prefix in the destination slot.
-            # Rebuild it with one draft prefill over [0, common): draft
-            # FLOPs are a small fraction of the target FLOPs the page
-            # graft just saved, and a fresh draft window keeps spec
-            # acceptance high (a stale draft would lower acceptance,
-            # never correctness — verify resamples from the target).
-            s = min(
-                bucket_size(common, minimum=16, dense=True), self.max_len
-            )
-            dtok = np.zeros((1, s), dtype=np.int32)
-            dtok[0, :common] = req.token_ids[:common]
-            kv_bucket = bucket_size(s, maximum=self.max_len, dense=True)
-            self._clock.enter(
-                "dispatch", program="_prefill_draft_suffix", tokens=common,
-                bucket=s,
-            )
-            self._dcache = self._prefill_draft_suffix(
-                self.draft_params,
-                self._dcache,
-                jnp.asarray(dtok),
-                jnp.int32(0),
-                jnp.int32(common),
-                jnp.int32(slot_idx),
-                kv_bucket,
-            )
-            self._clock.dispatched()
-            self._clock.enter("plan")
-        return True, self._admit_hit(req, slot_idx, common, shared=shared)
-
-    def _admit_pages_ok(
-        self, plen: int, common: int = 0, *, reserve: bool = False
-    ) -> bool:
-        """Page-aware admission gate (paged mode): admit only when the
-        free list covers the prompt's new pages plus one flush round of
-        decode headroom (decode chunk or gamma+1 speculative round) — a
-        free SLOT alone is not capacity.  ``common`` tokens arrive via
-        shared pages and cost ``common // page_size`` fewer allocations
-        (a partially filled boundary page still COWs into a fresh one).
-        Evicts LRU parked segments to make room; False = backlog.
-
-        ``reserve`` marks admissions whose allocation is deferred to a
-        batched ``_admit_dispatch`` later this tick: their need counts
-        against subsequent gate checks until the dispatch lands."""
-        if self._pool is None:
-            return True
-        from generativeaiexamples_tpu.engine.paged_kv import num_slot_pages
-
-        pt = self._pool.page_tokens
-        horizon = min(plen + self._flush_width + 1, self.max_len)
-        need = max(num_slot_pages(horizon, pt) - common // pt, 1)
-        self._pages_per_admit_ewma += 0.2 * (
-            need - self._pages_per_admit_ewma
-        )
-        ok = self._ensure_pages(need + self._kv_pages_reserved)
-        if ok and reserve:
-            self._kv_pages_reserved += need
-        return ok
-
-    def _ensure_pages(self, need: int) -> bool:
-        """Free at least ``need`` pages, evicting LRU parked segments as
-        required; False when that many cannot be freed (pages shared
-        with live slots survive their segment's eviction)."""
-        if self._pool.pages_free >= need:
-            return True
-        self._evict_segments(need)
-        return self._pool.pages_free >= need
-
-    def _evict_segments(self, target: int) -> int:
-        """Evict least-recently-used unpinned parked segments until
-        ``target`` pages are free (or none are left); returns the
-        number evicted."""
-        evicted = 0
-        for seg in self._prefix_index.lru_order():
-            if self._pool.pages_free >= target:
-                break
-            if self._prefix_index.pinned(seg):
-                continue
-            self._drop_segment(seg)
-            evicted += 1
-        if evicted:
-            with self.stats.lock:
-                self.stats.kv_page_evictions += evicted
-        return evicted
-
     def _graft_into(self, src: int, dst: int, common: int) -> None:
         """Copy the shared segment's first ``common`` rows from slot
         ``src`` into slot ``dst`` (bucketed; over-copy is harmless, see
-        ``_graft_prefix``).  Contiguous mode only — paged hits go
-        through :meth:`_admit_paged_hit`'s page-table transfer instead.
+        ``_graft_prefix``).
         The source stays parked and indexed — serving one cached
         prefill to many requests is the point."""
         n = min(
@@ -2282,13 +1855,6 @@ class Scheduler:
             fin, n = slot.first_token, slot.ahead_tokens
             slot.first_token, slot.ahead_tokens = None, 0
             return fin, n
-        if ahead and self._pool is not None:
-            # Refused: a paged chunk takes its pages at dispatch, and the
-            # rows that finish in the fetch it would overtake have not
-            # returned theirs, nor has the next tick's low-water eviction
-            # run — it could find the free list empty where the next
-            # tick's chunk would not.
-            return None, 0
         pos = slot.warm_pos
         return _Chunk(
             slot_idx, pos, min(self.prefill_chunk_tokens, slot.length - pos)
@@ -2411,40 +1977,20 @@ class Scheduler:
             jnp.asarray([sp.top_p], dtype=jnp.float32),
             jnp.asarray([sp.top_k], dtype=jnp.int32),
         )
-        if self._pool is not None:
-            # Chunked prefill appends pages per chunk: only the pages
-            # this chunk's token range touches are allocated (or COWed
-            # off a grafted prefix) — the warming slot never holds pages
-            # for prompt text it has not prefilled yet.
-            self._pool.make_writable(slot_idx, pos, pos + n)
-            table = self._pool.device_table()
-            cache, tok = self._prefill_suffix_paged(
-                self.params,
-                self._pool.leaves,
-                table[slot_idx : slot_idx + 1],
-                jnp.asarray(tokens),
-                jnp.int32(pos),
-                jnp.int32(n),
-                self._next_key(),
-                sampling_dev,
-                kv_bucket,
-            )
-            self._set_cache(cache)
-        else:
-            cache, tok, aux = self._prefill_suffix(
-                self.params,
-                self._cache,
-                jnp.asarray(tokens),
-                jnp.int32(pos),
-                jnp.int32(n),
-                jnp.int32(slot_idx),
-                self._next_key(),
-                sampling_dev,
-                kv_bucket,
-            )
-            self._cache = cache
-            self._note_aux(aux)
-            self._save_boundary(slot, slot_idx, pos + n)
+        cache, tok, aux = self._prefill_suffix(
+            self.params,
+            self._cache,
+            jnp.asarray(tokens),
+            jnp.int32(pos),
+            jnp.int32(n),
+            jnp.int32(slot_idx),
+            self._next_key(),
+            sampling_dev,
+            kv_bucket,
+        )
+        self._cache = cache
+        self._note_aux(aux)
+        self._save_boundary(slot, slot_idx, pos + n)
         if self.draft_cfg is not None:
             # Same chunk through the draft: both caches advance their
             # warm frontier together, so whenever the slot joins decode
@@ -2615,22 +2161,9 @@ class Scheduler:
             for i, s in enumerate(self._slots):
                 if s.cached:
                     self._unpark(i)
-            if self._pool is not None:
-                # Parked page segments die with the pool: clear the
-                # index and session maps IN THE SAME recovery as the
-                # pool's full wipe (refcounts, free list, tables,
-                # fresh zero leaves — the old ones may have been
-                # donated away by the faulted dispatch), or a later
-                # hit would reference recycled pages.
-                self._prefix_index.clear()
-                self._session_segs.clear()
-                self._seg_sessions.clear()
-                self._pool.reset_all()
-                self._cache = self._pool.leaves
-            else:
-                self._cache = self.model.init_state(
-                    self.max_batch, self.max_len
-                )
+            self._cache = self.model.init_state(
+                self.max_batch, self.max_len
+            )
             self._aux_pending.clear()
             self._ahead_toks.clear()
             # A decode chunk sent ahead dies with the buffers it wrote,
@@ -2739,28 +2272,6 @@ class Scheduler:
                 if s.cached and s.request is None
             )
             db.record("engine.parked_slots", parked)
-            if self._pool is not None:
-                pool = self._pool
-                stats.kv_pages_total = pool.total_pages
-                stats.kv_pages_free = pool.pages_free
-                stats.kv_pages_parked = self._prefix_index.total_pages()
-                stats.kv_pages_shared = pool.pages_shared
-                stats.kv_cow_breaks = pool.cow_breaks
-                stats.kv_pages_per_admit = max(
-                    1, int(round(self._pages_per_admit_ewma))
-                )
-                # Page-free rate (pages/s EWMA) over the feed interval:
-                # the server's 429 Retry-After projects how long until
-                # an admission's page need is covered from this.
-                dt = now - self._kv_free_rate_t
-                if dt > 0:
-                    rate = (pool.frees_total - self._kv_frees_prev) / dt
-                    stats.kv_page_free_rate += 0.3 * (
-                        rate - stats.kv_page_free_rate
-                    )
-                self._kv_frees_prev = pool.frees_total
-                self._kv_free_rate_t = now
-                db.record("engine.kv.free_pages", pool.pages_free)
             prev = self._tsdb_prev
             for key in self._TSDB_COUNTER_KEYS:
                 value = snap.get(key, 0)
@@ -2792,15 +2303,6 @@ class Scheduler:
     # 32k tokens ~ one 64 x 512 admission batch.
     ADMIT_TOKEN_BUDGET = 32768
 
-    def _evict_for_pages(self) -> None:
-        """Pool-pressure eviction: when the free list is below the
-        low-water mark at a tick boundary, evict LRU parked prefix
-        segments until it recovers (or none are evictable) — admission
-        then allocates from a healthy free list instead of leaning on
-        the deadlock-freedom floor mid-claim.  Pinned segments are never
-        taken (same rule as slot-pressure reclaim)."""
-        self._evict_segments(self._kv_low_water)
-
     def _tick(self) -> None:
         with self.stats.lock:
             self.stats.tick_count += 1
@@ -2814,12 +2316,6 @@ class Scheduler:
         self._tick_ahead = 0
         self._tick_executables = 0
         self._tick_busy = False
-        if self._pool is not None:
-            self._kv_pages_reserved = 0
-        if self._pool is not None and (
-            self._pool.pages_free < self._kv_low_water
-        ):
-            self._evict_for_pages()
         # Every decode path runs the tick PIPELINED: admission
         # prefill+graft batches are dispatched first (async), the decode
         # chunk for the previously-active slots is dispatched behind them
@@ -2927,49 +2423,15 @@ class Scheduler:
                     budget = 0
                     break
                 if parked >= 0:
-                    if self._pool is not None:
-                        # Session hit (paged): reference the session
-                        # segment's pages from a free slot and consume
-                        # the segment (the updated turn re-parks).
-                        ok, fin = self._admit_paged_hit(
-                            req, parked, common, consume=True, shared=False
-                        )
-                        if not ok:
-                            self._backlog.appendleft(req)
-                            stalled = True
-                            break
-                        free = self._free_slots()
-                        settle(fin)
-                    else:
-                        # Session hit: take over the conversation's own
-                        # parked slot.
-                        settle(
-                            self._admit_hit(req, parked, common, shared=False)
-                        )
+                    # Session hit: take over the conversation's own
+                    # parked slot.
+                    settle(
+                        self._admit_hit(req, parked, common, shared=False)
+                    )
                     budget -= cost
                     progressed = True
                     continue
                 if shared_src >= 0:
-                    if self._pool is not None:
-                        # Shared-prefix hit (paged): page-table row write
-                        # + refcount bumps; the segment keeps serving
-                        # other requests, COW isolates divergence.
-                        ok, fin = self._admit_paged_hit(
-                            req,
-                            shared_src,
-                            shared_common,
-                            consume=False,
-                            shared=True,
-                        )
-                        if not ok:
-                            self._backlog.appendleft(req)
-                            stalled = True
-                            break
-                        free = self._free_slots()
-                        settle(fin)
-                        budget -= cost
-                        progressed = True
-                        continue
                     # Shared-prefix hit: graft the segment's rows into a
                     # spare slot so the segment keeps serving other
                     # requests.  The source is pinned so the one-slot
@@ -3004,9 +2466,7 @@ class Scheduler:
                 if not free:
                     # Evict exactly one parked prefix cache per request
                     # that actually needs a slot — never in bulk: every
-                    # eviction costs a cached prefix its KV.  (Paged
-                    # parking holds no slots, so the reclaim is empty
-                    # there — a full house is truly full.)
+                    # eviction costs a cached prefix its KV.
                     free = self._reclaim_parked(1)
                     if not free:
                         # Back to the FRONT: admission stays FIFO.
@@ -3017,20 +2477,6 @@ class Scheduler:
                     self.prefill_chunk_tokens
                     and plen > self.prefill_chunk_tokens
                 )
-                if self._pool is not None and not self._admit_pages_ok(
-                    # Chunked admissions allocate their first chunk
-                    # immediately; batch admissions allocate at the
-                    # deferred dispatch, so their need is reserved.
-                    plen, reserve=not chunked_cold
-                ):
-                    # A free slot is not capacity in paged mode: the
-                    # free list must also cover the prompt plus a flush
-                    # round of decode headroom (after LRU segment
-                    # eviction).  Shed to the backlog; page frees from
-                    # finishing lanes re-open admission.
-                    self._backlog.appendleft(req)
-                    stalled = True
-                    break
                 if chunked_cold:
                     # Cold chunked admission: claim the slot and dispatch
                     # the first chunk; the rest interleaves with decode
@@ -3049,10 +2495,6 @@ class Scheduler:
             batch_reqs = [r for r, _ in batch]
             batch_slots = [i for _, i in batch]
             t = self._admit_dispatch(batch_reqs, batch_slots)
-            if self._pool is not None:
-                # The dispatch just materialized the batch's page
-                # allocations — the gate's reservation is spent.
-                self._kv_pages_reserved = 0
             admits.append(lambda t=t: self._admit_finalize(*t))
             budget -= batch_tokens
             progressed = True
@@ -3138,29 +2580,12 @@ class Scheduler:
         self._clip_prompt(req)
         parked, common = self._find_parked(req)
         if parked >= 0:
-            if self._pool is not None:
-                ok, fin = self._admit_paged_hit(
-                    req, parked, common, consume=True, shared=False
-                )
-                if not ok:
-                    return False
-            else:
-                fin = self._admit_hit(req, parked, common, shared=False)
+            fin = self._admit_hit(req, parked, common, shared=False)
             if fin is not None:
                 fin()
             return True
         shared_src, shared_common = self._find_shared(req)
         if shared_src >= 0:
-            if self._pool is not None:
-                ok, fin = self._admit_paged_hit(
-                    req, shared_src, shared_common, consume=False,
-                    shared=True,
-                )
-                if not ok:
-                    return False
-                if fin is not None:
-                    fin()
-                return True
             self._prefix_index.pin(shared_src)
             try:
                 free = self._free_slots() or self._reclaim_parked(1)
@@ -3180,10 +2605,6 @@ class Scheduler:
         free = self._free_slots() or self._reclaim_parked(1)
         if not free:
             return False
-        if self._pool is not None and not self._admit_pages_ok(
-            len(req.token_ids)
-        ):
-            return False
         if (
             self.prefill_chunk_tokens
             and len(req.token_ids) > self.prefill_chunk_tokens
@@ -3202,15 +2623,13 @@ class Scheduler:
         a live request, decoding or warming.  While a slot is free the
         next arrival's prefill should lead the device's queue, not wait
         behind a decode chunk; with a full house nothing can be admitted
-        before a row ends anyway.  Decoding over the contiguous cache
-        without a draft model or n-gram drafts only: such a round's
-        acceptance counts and a paged chunk's pages are the host's to
-        know before the next dispatch.  (A chunk that verifies the
+        before a row ends anyway.  Decoding without a draft model or
+        n-gram drafts only: such a round's acceptance counts are the
+        host's to know before the next dispatch.  (A chunk that verifies the
         model's own draft leaves its rows' lengths on the device beside
         their tokens, ``_carried_len``, and goes ahead as a plain one.)"""
         return (
-            self._pool is None
-            and self.draft_cfg is None
+            self.draft_cfg is None
             and self.spec_mode != "ngram"
             and all(s.request is not None for s in self._slots)
         )
@@ -3368,92 +2787,39 @@ class Scheduler:
             "dispatch", program="spec_chunk", lanes=len(active),
             kv_bucket=kv_bucket, gamma=g,
         )
-        table = None
-        if self._pool is not None:
-            # Page-granular speculative accounting: each live lane gets
-            # pages covering the chunk's FULL potential write range; the
-            # finalize trims back to what the verifier actually
-            # accepted, so rejected drafts only ever RELEASE pages (a
-            # page shared with a grafted sibling survives its
-            # refcount — phantom KV can never corrupt shared history).
-            for i in active:
-                slot = self._slots[i]
-                live = slot.length + slot.emitted
-                self._pool.make_writable(
-                    i,
-                    max(live - 1, 0),
-                    min(live + rounds * (g + 1) + 1, self.max_len),
-                )
-            table = self._pool.device_table()
         if self.draft_cfg is not None:
-            if self._pool is not None:
-                tcache, dcache, outs, n_emits = self._spec_chunk(
-                    (self.params, self.draft_params),
-                    self._pool.leaves,
-                    table,
-                    self._dcache,
-                    jnp.asarray(self._cur_tok),
-                    jnp.asarray(np.minimum(lengths, self.max_len - 1)),
-                    self._next_key(),
-                    jnp.asarray(temp),
-                    jnp.asarray(top_p),
-                    jnp.asarray(top_k),
-                    rounds,
-                    g,
-                    kv_bucket,
-                )
-                self._set_cache(tcache)
-            else:
-                tcache, dcache, outs, n_emits = self._spec_chunk(
-                    (self.params, self.draft_params),
-                    self._cache,
-                    self._dcache,
-                    jnp.asarray(self._cur_tok),
-                    jnp.asarray(np.minimum(lengths, self.max_len - 1)),
-                    self._next_key(),
-                    jnp.asarray(temp),
-                    jnp.asarray(top_p),
-                    jnp.asarray(top_k),
-                    rounds,
-                    g,
-                    kv_bucket,
-                )
-                self._cache = tcache
+            tcache, dcache, outs, n_emits = self._spec_chunk(
+                (self.params, self.draft_params),
+                self._cache,
+                self._dcache,
+                jnp.asarray(self._cur_tok),
+                jnp.asarray(np.minimum(lengths, self.max_len - 1)),
+                self._next_key(),
+                jnp.asarray(temp),
+                jnp.asarray(top_p),
+                jnp.asarray(top_k),
+                rounds,
+                g,
+                kv_bucket,
+            )
+            self._cache = tcache
             self._dcache = dcache
         else:
-            if self._pool is not None:
-                tcache, self._dhist, outs, n_emits = self._ngram_chunk(
-                    self.params,
-                    self._pool.leaves,
-                    table,
-                    self._dhist,
-                    jnp.asarray(self._cur_tok),
-                    jnp.asarray(np.minimum(lengths, self.max_len - 1)),
-                    self._next_key(),
-                    jnp.asarray(temp),
-                    jnp.asarray(top_p),
-                    jnp.asarray(top_k),
-                    rounds,
-                    g,
-                    kv_bucket,
-                )
-                self._set_cache(tcache)
-            else:
-                tcache, self._dhist, outs, n_emits = self._ngram_chunk(
-                    self.params,
-                    self._cache,
-                    self._dhist,
-                    jnp.asarray(self._cur_tok),
-                    jnp.asarray(np.minimum(lengths, self.max_len - 1)),
-                    self._next_key(),
-                    jnp.asarray(temp),
-                    jnp.asarray(top_p),
-                    jnp.asarray(top_k),
-                    rounds,
-                    g,
-                    kv_bucket,
-                )
-                self._cache = tcache
+            tcache, self._dhist, outs, n_emits = self._ngram_chunk(
+                self.params,
+                self._cache,
+                self._dhist,
+                jnp.asarray(self._cur_tok),
+                jnp.asarray(np.minimum(lengths, self.max_len - 1)),
+                self._next_key(),
+                jnp.asarray(temp),
+                jnp.asarray(top_p),
+                jnp.asarray(top_k),
+                rounds,
+                g,
+                kv_bucket,
+            )
+            self._cache = tcache
         ticket = self._clock.dispatched()
         self._clock.enter("plan")
         return outs, n_emits, active, g, ticket
@@ -3475,16 +2841,6 @@ class Scheduler:
         if active:
             self._cur_tok[active] = last[active]
         self._consume_spec_outs(outs_h, n_h, active, gamma_used)
-        if self._pool is not None:
-            # Page-granular rollback for rejected drafts: each lane's
-            # accounted length already excludes them (n_h counts only
-            # accepted tokens), so trimming to it releases the phantom
-            # tail's pages.  Lanes that finished mid-chunk were trimmed
-            # (park) or reset (release) by _finish inside the consume.
-            for i in active:
-                slot = self._slots[i]
-                if slot.request is not None and slot.warm_pos is None:
-                    self._pool.trim(i, slot.length + slot.emitted)
         with self.stats.lock:
             self.stats.decode_chunks += 1
 
@@ -3645,71 +3001,41 @@ class Scheduler:
             "dispatch", program="decode_chunk", lanes=len(active),
             kv_bucket=kv_bucket,
         )
-        if self._pool is not None:
-            # Pages for the chunk's write range per live lane; inactive
-            # and pinned lanes write the garbage page through their
-            # unowned tail entries, so they need nothing here.
-            for i in active:
-                slot = self._slots[i]
-                live = slot.length + slot.emitted
-                self._pool.make_writable(
-                    i,
-                    max(live - 1, 0),
-                    min(live + self.decode_chunk_size, self.max_len),
-                )
-            table = self._pool.device_table()
-            cache, toks = self._decode_chunk(
-                self.params,
-                self._pool.leaves,
-                table,
-                jnp.asarray(self._cur_tok),
-                jnp.asarray(np.minimum(lengths, self.max_len - 1)),
-                self._next_key(),
-                jnp.asarray(temp),
-                jnp.asarray(top_p),
-                jnp.asarray(top_k),
-                self.decode_chunk_size,
-                kv_bucket,
-                self._carried,
-                jnp.asarray(carry),
+        lengths = np.minimum(lengths, self.max_len - 1)
+        cache, toks, *aux = self._decode_chunk(
+            self.params,
+            self._cache,
+            jnp.asarray(self._cur_tok),
+            jnp.asarray(lengths),
+            self._next_key(),
+            jnp.asarray(temp),
+            jnp.asarray(top_p),
+            jnp.asarray(top_k),
+            self.decode_chunk_size,
+            kv_bucket,
+            jnp.asarray(snap),
+            self._carried,
+            jnp.asarray(carry),
+            *(
+                (self._carried_len, jnp.asarray(carry_len))
+                if self.model.draft else ()
+            ),
+        )
+        self._cache = cache
+        if self.model.draft:
+            # Tokens (steps, b, 2), how many of the two count, and
+            # what the chunk leaves for the next: each row's newest
+            # token (wherever its counts put it) and its length.
+            n_emits, (self._carried, self._carried_len), *aux = aux
+            toks = toks, n_emits
+        self._note_aux(*aux)
+        with self.stats.lock:
+            self.stats.decode_kv_tokens_read += kv_tokens_read(
+                lengths[snap], self.max_len, kv_bucket
             )
-            self._set_cache(cache)
-        else:
-            lengths = np.minimum(lengths, self.max_len - 1)
-            cache, toks, *aux = self._decode_chunk(
-                self.params,
-                self._cache,
-                jnp.asarray(self._cur_tok),
-                jnp.asarray(lengths),
-                self._next_key(),
-                jnp.asarray(temp),
-                jnp.asarray(top_p),
-                jnp.asarray(top_k),
-                self.decode_chunk_size,
-                kv_bucket,
-                jnp.asarray(snap),
-                self._carried,
-                jnp.asarray(carry),
-                *(
-                    (self._carried_len, jnp.asarray(carry_len))
-                    if self.model.draft else ()
-                ),
+            self.stats.decode_kv_tokens_dense += (
+                self.max_batch * kv_bucket
             )
-            self._cache = cache
-            if self.model.draft:
-                # Tokens (steps, b, 2), how many of the two count, and
-                # what the chunk leaves for the next: each row's newest
-                # token (wherever its counts put it) and its length.
-                n_emits, (self._carried, self._carried_len), *aux = aux
-                toks = toks, n_emits
-            self._note_aux(*aux)
-            with self.stats.lock:
-                self.stats.decode_kv_tokens_read += kv_tokens_read(
-                    lengths[snap], self.max_len, kv_bucket
-                )
-                self.stats.decode_kv_tokens_dense += (
-                    self.max_batch * kv_bucket
-                )
         ticket = self._clock.dispatched()
         self._clock.enter("plan")
         # The chunk's last tokens stay where the next chunk can read them;

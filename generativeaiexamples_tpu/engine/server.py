@@ -361,20 +361,6 @@ def _overloaded_response(scheduler) -> web.Response:
             or snap.get("tick_ms_ewma", 0.0)
         )
         retry_after = 1.0 + float(snap.get("queued", 0)) * tick_ms / 1000.0
-        # Paged engines shed on PAGE pressure too: when the free list
-        # cannot cover a typical admission, project how long until it
-        # can from the smoothed page-free rate (pages returned per
-        # second by finishing/trimming lanes) and take the larger of
-        # the two drain estimates.
-        total = float(snap.get("kv_pages_total", 0))
-        if total:
-            deficit = float(snap.get("kv_pages_per_admit", 0)) - float(
-                snap.get("kv_pages_free", 0)
-            )
-            rate = float(snap.get("kv_page_free_rate", 0.0))
-            if deficit > 0:
-                page_wait = 1.0 + deficit / max(rate, 0.5)
-                retry_after = max(retry_after, page_wait)
     except Exception:
         pass
     return web.json_response(
@@ -748,26 +734,6 @@ async def handle_metrics(request: web.Request) -> web.Response:
         f"engine_spec_acceptance_ewma {snap.get('spec_acceptance_ewma', 0.0)}",
         "# TYPE engine_spec_gamma gauge",
         f"engine_spec_gamma {snap.get('spec_gamma', 0)}",
-        # Paged-KV pool pressure (from zero when the engine runs the
-        # contiguous cache, so dashboards need no existence checks):
-        # free/parked/shared describe the live pool (parked = pages held
-        # by radix prefix segments, shared = refcount > 1, COW-armed);
-        # cow_breaks counts pages privatized by a copy-on-write break;
-        # evictions counts parked segments dropped under pool pressure.
-        "# TYPE engine_kv_pages_total gauge",
-        f"engine_kv_pages_total {snap.get('kv_pages_total', 0)}",
-        "# TYPE engine_kv_pages_free gauge",
-        f"engine_kv_pages_free {snap.get('kv_pages_free', 0)}",
-        "# TYPE engine_kv_pages_parked gauge",
-        f"engine_kv_pages_parked {snap.get('kv_pages_parked', 0)}",
-        "# TYPE engine_kv_pages_shared gauge",
-        f"engine_kv_pages_shared {snap.get('kv_pages_shared', 0)}",
-        "# TYPE engine_kv_page_utilization gauge",
-        f"engine_kv_page_utilization {snap.get('kv_page_utilization', 0.0)}",
-        "# TYPE engine_kv_cow_breaks_total counter",
-        f"engine_kv_cow_breaks_total {snap.get('kv_cow_breaks', 0)}",
-        "# TYPE engine_kv_page_evictions_total counter",
-        f"engine_kv_page_evictions_total {snap.get('kv_page_evictions', 0)}",
     ]
     # Where the tick thread's time goes (exclusive phases: the six sum
     # to its wall time), how long it left the device with nothing
@@ -1357,24 +1323,6 @@ def main() -> None:
         "config (default xla).",
     )
     parser.add_argument(
-        "--kv-layout",
-        default=os.environ.get("GAIE_KV_LAYOUT", ""),
-        choices=["", "contiguous", "paged"],
-        help="KV cache layout: 'contiguous' gives each slot a dense "
-        "max_len window; 'paged' carves KV into fixed-size int8 pages "
-        "behind per-lane page tables (zero-copy prefix grafts, "
-        "copy-on-write sharing, slot-free parked segments; "
-        "requires int8 KV, single chip). Empty falls back to "
-        "[llm].kv_layout in config (default contiguous).",
-    )
-    parser.add_argument(
-        "--kv-page-size",
-        type=int,
-        default=int(os.environ.get("GAIE_KV_PAGE_SIZE", "0")),
-        help="tokens per KV page for --kv-layout paged (0 = "
-        "[llm].kv_page_size, default 64)",
-    )
-    parser.add_argument(
         "--prefix-cache",
         default=os.environ.get("GAIE_PREFIX_CACHE", "shared"),
         choices=["shared", "session", "off"],
@@ -1467,22 +1415,8 @@ def main() -> None:
     matmul_kernel = args.matmul_kernel or str(
         getattr(llm_cfg, "matmul_kernel", "") or "xla"
     )
-    kv_layout = args.kv_layout or str(
-        getattr(llm_cfg, "kv_layout", "") or "contiguous"
-    )
-    kv_page_size = args.kv_page_size or int(
-        getattr(llm_cfg, "kv_page_size", 0) or 64
-    )
     if args.kv_dtype:
         cfg = dataclasses.replace(cfg, kv_dtype=args.kv_dtype)
-    if kv_layout == "paged" and cfg.kv_dtype != "int8":
-        # The paged pool stores int8 pages + per-page scales; model
-        # presets default to bf16 KV, so selecting paged implies int8.
-        logger.info(
-            "kv_layout=paged requires int8 KV; overriding kv_dtype=%s",
-            cfg.kv_dtype,
-        )
-        cfg = dataclasses.replace(cfg, kv_dtype="int8")
     # --spec-decode with no draft model falls back to prompt-lookup
     # speculation: no extra weights, still distribution-preserving, and
     # the adaptive controller caps the cost when prompts don't repeat.
@@ -1528,8 +1462,6 @@ def main() -> None:
             prefill_chunk_tokens=args.prefill_chunk_tokens or None,
             quantize=args.weight_dtype == "int8",
             matmul_kernel=matmul_kernel,
-            kv_layout=kv_layout,
-            kv_page_size=kv_page_size,
         )
 
     autoscale_on = args.autoscale or get_config().autoscale.enabled

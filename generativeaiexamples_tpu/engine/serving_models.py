@@ -344,9 +344,7 @@ class HybridServing:
         if self.draft:
             self.counter_names += self.DRAFT_COUNTERS
 
-    def check_supported(
-        self, *, kv_layout="contiguous", draft_cfg=None, spec_mode=None, **_
-    ) -> None:
+    def check_supported(self, *, draft_cfg=None, spec_mode=None) -> None:
         """What is not served for a model whose state cannot be cut at a
         token, refused with the reason."""
         drafted = draft_cfg is not None or spec_mode is not None
@@ -371,14 +369,6 @@ class HybridServing:
                 "models/llama.py's K/V cache alone; the one draft served "
                 "here is the model's own prediction module (draft 'mtp'), "
                 "whose rejected position a ring masks by its own rule"
-            )
-        if kv_layout != "contiguous":
-            raise ValueError(
-                "the paged layout is not served for this model: its pages "
-                "hold K/V rows of one shape, not latent rows beside a "
-                "fixed recurrent state, nor a window layer's ring whose "
-                "pages would be released behind the window, nor a cca "
-                "layer's tails beside its rows"
             )
         if self.mesh is not None and self.mesh.size > 1:
             raise ValueError(

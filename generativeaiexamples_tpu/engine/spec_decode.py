@@ -139,8 +139,6 @@ def _verify_and_emit(
     ksub,
     kacc,
     kres,
-    page_table=None,
-    page_tokens=0,
 ):
     """Target verify pass + acceptance + emission — the shared back half
     of every speculation round (model drafts and n-gram drafts differ
@@ -149,26 +147,10 @@ def _verify_and_emit(
     u*q < p degenerates to u < p(x) and the residual to p minus its
     x-mass — still exactly the warped target marginal).
 
-    ``page_table`` switches the TARGET cache to the paged layout
-    (``tcache`` = the flat pool leaves): the verify forward reads/writes
-    through the table and the round flush scatters the gamma+1 fresh KV
-    page-wise.  The draft side is unaffected — its cache stays
-    contiguous (small and slot-private, nothing to share).
-
     Returns ``(tcache, out, n_emit, next_tok, new_lengths)``.
     """
-    from generativeaiexamples_tpu.engine.decode import (
-        _flush_append_buffer,
-        _flush_append_buffer_paged,
-    )
+    from generativeaiexamples_tpu.engine.decode import _flush_append_buffer
 
-    paged_kw = {}
-    if page_table is not None:
-        paged_kw = dict(
-            page_table=page_table,
-            page_tokens=page_tokens,
-            pages_len=max_len,
-        )
     b = tok.shape[0]
     bidx = jnp.arange(b)
     inputs = jnp.concatenate([tok[:, None], drafts], axis=1)
@@ -190,19 +172,13 @@ def _verify_and_emit(
         hidden, _, ab = llama.forward(
             tparams, tcfg, inputs, tpos, tcache, lengths0,
             mesh=mesh, kv_bucket=kv_bucket, append_cache=(ab0, 0),
-            **paged_kw,
         )
-        if page_table is not None:
-            tcache = _flush_append_buffer_paged(
-                tcache, ab, lengths0, page_table, max_len, page_tokens
-            )
-        else:
-            tcache = _flush_append_buffer(tcache, ab, lengths0, max_len)
+        tcache = _flush_append_buffer(tcache, ab, lengths0, max_len)
     else:
         hidden, tcache = llama.forward(
             tparams, tcfg, inputs, tpos, tcache,
             jnp.minimum(lengths0 + gamma + 1, max_len), mesh=mesh,
-            kv_bucket=kv_bucket, **paged_kw,
+            kv_bucket=kv_bucket,
         )
     tlogits = llama.logits(tparams, hidden)  # (b, gamma+1, vocab)
     targets = jnp.argmax(tlogits, axis=-1).astype(jnp.int32)
@@ -335,15 +311,9 @@ def _make_spec_round_body(
     temp,
     top_p,
     top_k,
-    page_table=None,
-    page_tokens=0,
 ):
     """One speculation round (draft gamma tokens, verify, emit) as a
-    ``lax.scan`` body — shared by the contiguous and paged spec chunks.
-    The draft side is identical in both (the draft cache is small and
-    slot-private, so it stays contiguous); only the TARGET cache's
-    verify/flush path switches on ``page_table``.
-    """
+    ``lax.scan`` body."""
     b = greedy.shape[0]
 
     def round_body(carry, _):
@@ -416,7 +386,6 @@ def _make_spec_round_body(
             tparams, tcfg, mesh, max_len, kv_bucket, use_ab, gamma,
             tcache, tok, lengths0, drafts, q_ids, q_probs, greedy,
             temp, top_p, top_k, ksub, kacc, kres,
-            page_table=page_table, page_tokens=page_tokens,
         )
         return (
             (tcache, dcache, next_tok, new_lengths, key),
@@ -503,82 +472,6 @@ def make_spec_chunk_fn(
     return spec_chunk
 
 
-def make_paged_spec_chunk_fn(
-    tcfg: llama.LlamaConfig,
-    dcfg: llama.LlamaConfig,
-    mesh,
-    max_len: int,
-    page_tokens: int,
-):
-    """Paged-target variant of :func:`make_spec_chunk_fn`.
-
-    Signature: ``fn(params_pair, tleaves, table, dcache, tok, lengths,
-    key, temp, top_p, top_k, n_rounds, gamma, kv_bucket)``.  ``tleaves``
-    is the flat pool 4-tuple (donated, like the contiguous target
-    cache) and ``table`` the (max_batch, n_slot_pages) int32 device page
-    table — NOT donated: the host owns the table and re-uploads it only
-    when allocation state changes.  The draft cache stays contiguous and
-    donated.  The scheduler must :meth:`~engine.paged_kv.PagedKVPool.
-    make_writable` the token range ``[lengths, lengths + n_rounds *
-    (gamma+1) + 1)`` per live lane before dispatch — rejected drafts are
-    then clipped afterwards with :meth:`~engine.paged_kv.PagedKVPool.
-    trim`, which only ever RELEASES pages (a shared page survives via
-    its refcount, so phantom KV can never corrupt a sibling's prefix).
-    Returns ``(tleaves, dcache, outs, n_emits)``.
-    """
-
-    @functools.partial(
-        jax.jit, donate_argnums=(1, 3), static_argnums=(10, 11, 12)
-    )
-    def paged_spec_chunk(
-        params_pair,
-        tleaves,
-        table,
-        dcache,
-        tok,
-        lengths,
-        key,
-        temp,
-        top_p,
-        top_k,
-        n_rounds,
-        gamma,
-        kv_bucket,
-    ):
-        from generativeaiexamples_tpu.ops.decode_attention import (
-            use_append_buffer,
-        )
-
-        tparams, dparams = params_pair
-        b = tok.shape[0]
-        greedy = temp <= 0.0
-        use_ab = use_append_buffer(
-            s=gamma + 1,
-            kv_int8=len(tleaves) == 4,
-            batch=b,
-            window=min(kv_bucket, max_len) if kv_bucket else max_len,
-            n_q=tcfg.n_heads,
-            n_kv=tcfg.n_kv_heads,
-            head_dim=tcfg.head_dim,
-            mesh=mesh,
-        )
-        round_body = _make_spec_round_body(
-            tparams, dparams, tcfg, dcfg, mesh, max_len, kv_bucket,
-            use_ab, gamma, greedy, temp, top_p, top_k,
-            page_table=table, page_tokens=page_tokens,
-        )
-
-        (tleaves, dcache, tok, lengths, key), (outs, n_emits) = jax.lax.scan(
-            round_body,
-            (tleaves, dcache, tok, lengths, key),
-            None,
-            length=n_rounds,
-        )
-        return tleaves, dcache, outs, n_emits
-
-    return paged_spec_chunk
-
-
 def _make_ngram_round_body(
     tparams,
     tcfg,
@@ -592,13 +485,9 @@ def _make_ngram_round_body(
     temp,
     top_p,
     top_k,
-    page_table=None,
-    page_tokens=0,
 ):
     """One prompt-lookup round (history match, verify, emit) as a
-    ``lax.scan`` body — shared by the contiguous and paged ngram chunks;
-    only the target cache's verify/flush path switches on
-    ``page_table``."""
+    ``lax.scan`` body."""
     b = greedy.shape[0]
     bidx = jnp.arange(b)
     p_idx = jnp.arange(max_len, dtype=jnp.int32)[None, :]
@@ -652,7 +541,6 @@ def _make_ngram_round_body(
             tparams, tcfg, mesh, max_len, kv_bucket, use_ab, gamma,
             tcache, tok, lengths0, drafts, q_ids, q_probs, greedy,
             temp, top_p, top_k, ksub, kacc, kres,
-            page_table=page_table, page_tokens=page_tokens,
         )
         # Record the accepted tokens so later ROUNDS in this chunk can
         # match against them (the host rebuilds its copy from emitted
@@ -750,73 +638,3 @@ def make_ngram_spec_chunk_fn(
         return tcache, hist, outs, n_emits
 
     return ngram_chunk
-
-
-def make_paged_ngram_spec_chunk_fn(
-    tcfg: llama.LlamaConfig,
-    mesh,
-    max_len: int,
-    page_tokens: int,
-    ngram: int = 2,
-):
-    """Paged-target variant of :func:`make_ngram_spec_chunk_fn`.
-
-    Signature: ``fn(tparams, tleaves, table, hist, tok, lengths, key,
-    temp, top_p, top_k, n_rounds, gamma, kv_bucket)`` — ``tleaves`` (the
-    flat pool 4-tuple) and ``hist`` are donated, the device page
-    ``table`` is not (the host owns it).  Same make_writable/trim
-    contract as :func:`make_paged_spec_chunk_fn`.  Returns ``(tleaves,
-    hist, outs, n_emits)``.
-    """
-    if ngram < 1:
-        raise ValueError(f"ngram must be >= 1, got {ngram}")
-
-    @functools.partial(
-        jax.jit, donate_argnums=(1, 3), static_argnums=(10, 11, 12)
-    )
-    def paged_ngram_chunk(
-        tparams,
-        tleaves,
-        table,
-        hist,
-        tok,
-        lengths,
-        key,
-        temp,
-        top_p,
-        top_k,
-        n_rounds,
-        gamma,
-        kv_bucket,
-    ):
-        from generativeaiexamples_tpu.ops.decode_attention import (
-            use_append_buffer,
-        )
-
-        b = tok.shape[0]
-        greedy = temp <= 0.0
-        use_ab = use_append_buffer(
-            s=gamma + 1,
-            kv_int8=len(tleaves) == 4,
-            batch=b,
-            window=min(kv_bucket, max_len) if kv_bucket else max_len,
-            n_q=tcfg.n_heads,
-            n_kv=tcfg.n_kv_heads,
-            head_dim=tcfg.head_dim,
-            mesh=mesh,
-        )
-        round_body = _make_ngram_round_body(
-            tparams, tcfg, mesh, max_len, kv_bucket, use_ab, gamma,
-            ngram, greedy, temp, top_p, top_k,
-            page_table=table, page_tokens=page_tokens,
-        )
-
-        (tleaves, hist, tok, lengths, key), (outs, n_emits) = jax.lax.scan(
-            round_body,
-            (tleaves, hist, tok, lengths, key),
-            None,
-            length=n_rounds,
-        )
-        return tleaves, hist, outs, n_emits
-
-    return paged_ngram_chunk

@@ -849,9 +849,6 @@ def forward(
     row_offset=0,
     return_aux: bool = False,
     append_cache: Optional[tuple] = None,
-    page_table: Optional[jnp.ndarray] = None,
-    page_tokens: int = 0,
-    pages_len: int = 0,
     chunk_valid: Optional[jnp.ndarray] = None,
 ):
     """Run the transformer body.
@@ -896,22 +893,6 @@ def forward(
     The caller flushes ab into the big cache once per chunk.  Returns
     ``(hidden, cache, ab)`` in this mode.
 
-    ``page_table`` switches the serving cache to the PAGED layout
-    (``engine.paged_kv.PagedKVPool``): ``cache`` is the 4-tuple of flat
-    pool leaves — values (L, KH, P, HD) int8, scales (L, KH, P) bf16 —
-    and ``page_table`` (b, n_slot_pages) int32 maps row ``r``'s logical
-    token ``t`` to pool slot ``table[r, t // page_tokens] * page_tokens
-    + t % page_tokens``.  ``page_tokens`` / ``pages_len`` are static:
-    tokens per page and the logical per-slot capacity (the contiguous
-    layout's ``max_len``, which ``kv_bucket`` windows against as
-    before).  Warm writes scatter through the table; attention reads
-    gather the logical window through the table and feed the SAME math
-    as the contiguous path, so greedy decode is bit-identical across
-    layouts (the tests/test_paged_kv.py gate).  Paged mode requires
-    int8 KV and is incompatible with ``cold_prefill`` (cold prefill
-    stages into a small contiguous cache; the scheduler grafts rows
-    into pool pages).
-
     ``chunk_valid`` (b, s) bool — the prefill chunks of several slots as
     one program (``LlamaServing.prefill_rows``): which positions count.
     Given, a model with experts dispatches them sorted by expert
@@ -931,38 +912,15 @@ def forward(
         x = _shard_activations(x, mesh)
 
     n_q, n_kv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    paged = page_table is not None
-    if paged:
-        if cache is None or len(cache) != 4:
-            raise ValueError(
-                "paged KV requires the int8 4-tuple of flat pool leaves"
-            )
-        if cold_prefill:
-            raise ValueError(
-                "cold_prefill is contiguous-only: paged callers stage "
-                "cold prefill in a small contiguous cache and graft "
-                "rows into pool pages"
-            )
-        if page_tokens < 1 or pages_len < 1:
-            raise ValueError(
-                "paged KV requires static page_tokens >= 1 and "
-                "pages_len >= 1"
-            )
-        t = pages_len
-    else:
-        t = cache[0].shape[3] if cache is not None else 0
+    t = cache[0].shape[3] if cache is not None else 0
     window = t if kv_bucket is None else min(kv_bucket, t)
     kv_int8 = cache is not None and len(cache) == 4
     if append_cache is not None:
         from generativeaiexamples_tpu.ops.decode_attention import (
             decode_gqa_attention,
             decode_gqa_attention_xla,
-            paged_decode_gqa_attention,
-            paged_decode_gqa_attention_xla,
-            paged_verify_gqa_attention_xla,
             use_append_buffer,
             use_decode_kernel,
-            use_paged_kernel,
             verify_gqa_attention_xla,
         )
 
@@ -989,22 +947,13 @@ def forward(
         # causal buffer.  In BOTH modes ``kv_lengths`` is the valid
         # big-cache prefix — fresh tokens' KV never touches the big
         # cache inside this executable; the caller flushes.
-        if paged:
-            _append_kernel = s == 1 and use_paged_kernel(
-                s=s, kv_int8=kv_int8, page_tokens=page_tokens,
-                n_q=n_q, n_kv=n_kv, head_dim=hd,
-                append_width=append_cache[0][0].shape[3], mesh=mesh,
-            )
-            _site = f"paged_decode_attention b={b} page={page_tokens}"
-        else:
-            _append_kernel = s == 1 and use_decode_kernel(
-                s=s, kv_int8=kv_int8, batch=b, window=window,
-                n_q=n_q, n_kv=n_kv, head_dim=hd, cache_len=t,
-                append_width=append_cache[0][0].shape[3], mesh=mesh,
-            )
-            _site = f"decode_attention b={b} w={window}"
+        _append_kernel = s == 1 and use_decode_kernel(
+            s=s, kv_int8=kv_int8, batch=b, window=window,
+            n_q=n_q, n_kv=n_kv, head_dim=hd, cache_len=t,
+            append_width=append_cache[0][0].shape[3], mesh=mesh,
+        )
         if s == 1:
-            record(_site, _append_kernel)
+            record(f"decode_attention b={b} w={window}", _append_kernel)
         ab_in, append_step = append_cache
         if s > 1 and ab_in[0].shape[3] != s:
             raise ValueError(
@@ -1014,26 +963,6 @@ def forward(
     else:
         ab_in = None
         append_step = None
-
-    if paged:
-        _pt = page_tokens
-        if append_cache is None:
-            # Warm scatter mode: physical write slots for each fresh
-            # token, and the flat gather index of the logical window
-            # [0, window).  Positions clamp to the logical capacity so a
-            # padded tail can never index past the table — it lands on
-            # the row's last entry (an owned page's garbage tail or the
-            # pinned garbage page 0), exactly the lanes the attention
-            # mask already zeroes.
-            _bidx_tab = jnp.arange(b, dtype=jnp.int32)[:, None]
-            _pos_c = jnp.minimum(positions, pages_len - 1)
-            _phys_pos = (
-                page_table[_bidx_tab, _pos_c // _pt] * _pt + _pos_c % _pt
-            )  # (b, s)
-            _w_idx = jnp.arange(window, dtype=jnp.int32)
-            _page_flat = (
-                page_table[:, _w_idx // _pt] * _pt + _w_idx % _pt
-            )  # (b, window)
 
     layers, experts = params["layers"], None
     if chunk_valid is not None and "router" in layers:
@@ -1158,29 +1087,7 @@ def forward(
                     write_ab(ab[2], ks),
                     write_ab(ab[3], vs),
                 )
-                if s == 1 and paged:
-                    if _append_kernel:
-                        attn = paged_decode_gqa_attention(
-                            q[:, 0],
-                            kv[0], kv[1], kv[2], kv[3],
-                            li,
-                            kv_lengths,
-                            page_table,
-                            append=(ab[0], ab[1], ab[2], ab[3], step + 1),
-                            page_tokens=page_tokens,
-                        )[:, None]
-                    else:
-                        attn = paged_decode_gqa_attention_xla(
-                            q[:, 0],
-                            kv[0], kv[1], kv[2], kv[3],
-                            li,
-                            kv_lengths,
-                            page_table,
-                            append=(ab[0], ab[1], ab[2], ab[3], step + 1),
-                            window=window,
-                            page_tokens=page_tokens,
-                        )[:, None]
-                elif s == 1:
+                if s == 1:
                     _decode_attn = (
                         decode_gqa_attention if _append_kernel
                         else decode_gqa_attention_xla
@@ -1196,17 +1103,6 @@ def forward(
                         append=(ab[0], ab[1], ab[2], ab[3], step + 1),
                         window=window,
                     )[:, None]
-                elif paged:  # paged speculative-verify block
-                    attn = paged_verify_gqa_attention_xla(
-                        q,
-                        kv[0], kv[1], kv[2], kv[3],
-                        li,
-                        kv_lengths,
-                        page_table,
-                        (ab[0], ab[1], ab[2], ab[3]),
-                        window=window,
-                        page_tokens=page_tokens,
-                    )
                 else:  # speculative-verify block over cache + causal buffer
                     attn = verify_gqa_attention_xla(
                         q,
@@ -1219,44 +1115,6 @@ def forward(
                         (ab[0], ab[1], ab[2], ab[3]),
                         window=window,
                     )
-            elif kv is not None and kv_int8 and paged:
-                # Paged warm mode: scatter fresh KV through the page table
-                # into the flat pool, then attend over the table-gathered
-                # logical window — the SAME ``attention`` call as the
-                # contiguous slice path, so greedy decode is bit-identical
-                # across layouts (masked window slots zero out exactly).
-                k8, ks = _quantize_kv(k)
-                v8, vs = _quantize_kv(v)
-                kv = (
-                    write_at(kv[0], k8, _phys_pos),
-                    write_at(kv[1], v8, _phys_pos),
-                    write_at(kv[2], ks, _phys_pos),
-                    write_at(kv[3], vs, _phys_pos),
-                )
-
-                def gather_layer(buf):
-                    """Layer ``li``'s logical KV window gathered through the
-                    page table: (KH, b, window, ...) -> the (b, window, KH,
-                    ...) shape gqa_attention expects."""
-                    sl = jax.lax.dynamic_slice(
-                        buf,
-                        (li,) + (0,) * (buf.ndim - 1),
-                        (1,) + buf.shape[1:],
-                    )[0]
-                    gat = sl[:, _page_flat]
-                    perm = (1, 2, 0) + tuple(range(3, gat.ndim))
-                    return jnp.transpose(gat, perm)
-
-                attn = attention(
-                    q,
-                    gather_layer(kv[0]),
-                    gather_layer(kv[1]),
-                    positions,
-                    kv_lengths,
-                    mesh=mesh,
-                    k_scale=gather_layer(kv[2]),
-                    v_scale=gather_layer(kv[3]),
-                )
             elif kv is not None and kv_int8:
                 k8, ks = _quantize_kv(k)
                 v8, vs = _quantize_kv(v)

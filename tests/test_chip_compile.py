@@ -189,39 +189,6 @@ def test_w8a8_kernel_compiles_where_the_gate_admits(one_chip, name, m):
     )
 
 
-@pytest.mark.parametrize("chunk", [0, 8])
-@pytest.mark.parametrize("page_tokens", [64, 128])
-def test_paged_kernel_compiles(one_chip, page_tokens, chunk):
-    S = _spec(one_chip)
-    batch, max_len = 64, 2048
-    n_slot_pages = max_len // page_tokens
-    assert da.use_paged_kernel(
-        s=1, kv_int8=True, page_tokens=page_tokens, n_q=NQ, n_kv=KH,
-        head_dim=HD, append_width=chunk, backend="tpu",
-    )
-    slots = -(-(batch * n_slot_pages + 1) * page_tokens // 128) * 128
-    pool = S((L, KH, slots, HD), jnp.int8)
-    scales = S((L, KH, slots), jnp.bfloat16)
-    args = [
-        S((batch, NQ, HD), jnp.bfloat16), pool, pool, scales, scales,
-        S((), jnp.int32), S((batch,), jnp.int32),
-        S((batch, n_slot_pages), jnp.int32),
-    ]
-    if chunk:
-        ab = S((L, KH, batch, chunk, HD), jnp.int8)
-        ab_scales = S((L, KH, batch, chunk), jnp.bfloat16)
-        args += [ab, ab, ab_scales, ab_scales, S((), jnp.int32)]
-
-    def attn(q, k, v, ks, vs, li, lens, table, *append):
-        return da.paged_decode_gqa_attention(
-            q, k, v, ks, vs, li, lens, table,
-            append=append or None,
-            page_tokens=page_tokens, interpret=False,
-        )
-
-    _compile(attn, *args)
-
-
 # (hidden, expert width, router outputs, experts held, group limit, score,
 # the tiles ``ops.moe._tiling`` must pick for gate-up and for down)
 EXPERT_WIDTHS = {

@@ -14,6 +14,7 @@ import pytest
 from generativeaiexamples_tpu.ops.attention import gqa_attention
 from generativeaiexamples_tpu.ops.decode_attention import (
     decode_gqa_attention,
+    decode_gqa_attention_xla,
     use_decode_kernel,
 )
 
@@ -410,28 +411,104 @@ def test_decode_chunk_dead_rows_change_nothing_that_is_kept(monkeypatch):
         assert (g[:, :, live, plen : plen + steps] != b0[:, :, live, plen : plen + steps]).any()
 
 
-def test_use_decode_kernel_gating():
-    # A 1-device mesh is a replica's slice of a multi-chip host.
-    mesh1 = jax.sharding.Mesh(np.asarray(jax.devices()[:1]), ("data",))
-    common = dict(
-        s=1, kv_int8=True, batch=320, window=256, n_q=32, n_kv=8,
-        head_dim=128, mesh=mesh1,
-    )
-    assert use_decode_kernel(backend="tpu", **common)
-    assert not use_decode_kernel(backend="cpu", **common)
-    assert not use_decode_kernel(backend="tpu", **{**common, "s": 2})
-    assert not use_decode_kernel(
-        backend="tpu", **{**common, "kv_int8": False}
-    )
-    assert not use_decode_kernel(backend="tpu", **{**common, "batch": 321})
+# (what differs from a llama3-8b decode step on one TPU chip, whether
+# the kernel takes it).  ``devices``: the mesh spans that many; a mesh of
+# one is a replica's slice of a multi-chip host, 0 is no mesh at all: the
+# gate reads the mesh, not the process, so no mesh means the default
+# device, whatever else the host holds (8 virtual devices here).
+GATE_CASES = {
+    "tpu": ({}, True),
+    "cpu": ({"backend": "cpu"}, False),
+    "two_queries": ({"s": 2}, False),
+    "bf16_kv": ({"kv_int8": False}, False),
+    "batch_321": ({"batch": 321}, False),
     # Small pow2 buckets run as a single window-deep tile (sublane
     # quantum 32 divides them); only sub-sublane windows fall back.
-    assert use_decode_kernel(backend="tpu", **{**common, "window": 64})
-    assert use_decode_kernel(backend="tpu", **{**common, "window": 32})
-    assert not use_decode_kernel(backend="tpu", **{**common, "window": 16})
-    # The gate reads the mesh, not the process: no mesh means the
-    # default device, whatever else the host holds (8 virtual devices
-    # here); only a mesh that spans devices falls back.
-    assert use_decode_kernel(backend="tpu", **{**common, "mesh": None})
-    mesh2 = jax.sharding.Mesh(np.asarray(jax.devices()[:2]), ("data",))
-    assert not use_decode_kernel(backend="tpu", **{**common, "mesh": mesh2})
+    "window_64": ({"window": 64}, True),
+    "window_32": ({"window": 32}, True),
+    "window_16": ({"window": 16}, False),
+    "no_mesh": ({"devices": 0}, True),
+    "mesh_of_two": ({"devices": 2}, False),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GATE_CASES))
+def test_use_decode_kernel_gating(case):
+    changed, taken = GATE_CASES[case]
+    asked = dict(
+        s=1, kv_int8=True, batch=320, window=256, n_q=32, n_kv=8,
+        head_dim=128, backend="tpu", devices=1,
+    )
+    asked.update(changed)
+    n = asked.pop("devices")
+    mesh = None
+    if n:
+        mesh = jax.sharding.Mesh(np.asarray(jax.devices()[:n]), ("data",))
+    assert use_decode_kernel(mesh=mesh, **asked) is taken
+
+
+# Every window ``bucket_size(..., dense=True)`` can produce for a context
+# of up to 2,048 tokens (``test_reachable_windows_are_these`` holds the
+# list to the function).
+REACHABLE_WINDOWS = [16, 32, 64, 128, 256, 384, 512, 768, 1024, 1536, 2048]
+
+
+def test_reachable_windows_are_these():
+    from generativeaiexamples_tpu.utils.buckets import bucket_size
+
+    assert REACHABLE_WINDOWS == sorted(
+        {bucket_size(n, minimum=16, dense=True) for n in range(1, 2049)}
+    )
+
+
+@pytest.mark.parametrize("window", REACHABLE_WINDOWS)
+def test_decode_kernel_gate_covers_every_reachable_window(monkeypatch, window):
+    """Regression for the ``window % 128 == 0`` gate bug that silently
+    sent the small pow2 kv buckets (32, 64) — reachable from any
+    short-context decode — to the scatter path.  Every window
+    ``bucket_size(..., dense=True)`` can actually produce must engage
+    the kernel, except the 16 floor (below the int8 sublane quantum's
+    single-tile minimum of 32)."""
+    monkeypatch.setenv("GAIE_DECODE_KERNEL_INTERPRET", "1")
+    assert use_decode_kernel(
+        s=1, kv_int8=True, batch=16, window=window, n_q=4, n_kv=2,
+        head_dim=128,
+    ) is (window >= 32)
+
+
+@pytest.mark.parametrize("window", [32, 64])
+def test_decode_kernel_numeric_at_small_windows(monkeypatch, window):
+    """The newly-admitted small windows actually run the kernel and
+    match the XLA twin (interpret mode) — the gate fix is not just a
+    predicate change."""
+    monkeypatch.setenv("GAIE_DECODE_KERNEL_INTERPRET", "1")
+    lcl, kh, b, hd, qh = 1, 2, 16, 128, 4
+    key = jax.random.PRNGKey(window)
+    kk = jax.random.split(key, 6)
+    k8 = jax.random.randint(kk[0], (lcl, kh, b, window, hd), -127, 128, jnp.int8)
+    v8 = jax.random.randint(kk[1], (lcl, kh, b, window, hd), -127, 128, jnp.int8)
+    ks = (
+        jnp.abs(jax.random.normal(kk[2], (lcl, kh, b, window))) * 0.02 + 0.01
+    ).astype(jnp.bfloat16)
+    vs = (
+        jnp.abs(jax.random.normal(kk[3], (lcl, kh, b, window))) * 0.02 + 0.01
+    ).astype(jnp.bfloat16)
+    lengths = jax.random.randint(kk[4], (b,), 1, window + 1, jnp.int32)
+    q = jax.random.normal(kk[5], (b, qh, hd), jnp.float32)
+    assert use_decode_kernel(
+        s=1, kv_int8=True, batch=b, window=window,
+        n_q=qh, n_kv=kh, head_dim=hd,
+    )
+    ref = decode_gqa_attention_xla(
+        q, k8, v8, ks, vs, jnp.int32(0), lengths, window=window
+    )
+    got = decode_gqa_attention(
+        q, k8, v8, ks, vs, jnp.int32(0), lengths,
+        window=window, interpret=True,
+    )
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32),
+        np.asarray(ref, np.float32),
+        rtol=1e-3,
+        atol=1e-4,
+    )

@@ -482,8 +482,6 @@ def test_what_is_not_served_is_refused_with_the_reason(model):
         model.check_supported(spec_mode="ngram")
     with pytest.raises(ValueError, match="a draft model and n-gram drafts are not served"):
         model.check_supported(draft_cfg=object())
-    with pytest.raises(ValueError, match="paged layout"):
-        model.check_supported(kv_layout="paged")
     with pytest.raises(ValueError, match="int8 weights are not served"):
         model.prepare_params(None, quantize=True, matmul_kernel="xla", seed=0)
     with pytest.raises(ValueError, match="int8 state"):

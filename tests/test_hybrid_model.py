@@ -165,8 +165,6 @@ def test_what_is_not_served_is_refused_with_the_reason():
         model.check_supported(spec_mode="ngram")
     with pytest.raises(ValueError, match="rolled back"):
         model.check_supported(draft_cfg=object())
-    with pytest.raises(ValueError, match="paged layout"):
-        model.check_supported(kv_layout="paged")
     with pytest.raises(ValueError, match="QUANT_TARGETS"):
         model.prepare_params(None, quantize=True, matmul_kernel="xla", seed=0)
     with pytest.raises(ValueError, match="QUANT_TARGETS"):

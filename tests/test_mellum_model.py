@@ -248,8 +248,6 @@ def test_what_is_not_served_is_refused_with_the_reason():
     assert isinstance(model, HybridServing) and not model.cut_anywhere
     with pytest.raises(ValueError, match="a draft model and n-gram drafts are not served"):
         model.check_supported(spec_mode="ngram")
-    with pytest.raises(ValueError, match="released behind the window"):
-        model.check_supported(kv_layout="paged")
     with pytest.raises(ValueError, match="fused GQA projections"):
         model.prepare_params(None, quantize=True, matmul_kernel="xla", seed=0)
     with pytest.raises(ValueError, match="int8 state"):

@@ -612,3 +612,26 @@ class TestPoolAggregation:
         assert all(r["requests_total"] == 2 for r in snap["replicas"])
         assert snap["ttft_avg_ms"] > 0
         assert snap["router_policy"] == "round_robin"
+
+
+# What a scheduler counts that a pool does not add up: the averages and
+# EWMAs it weighs or takes the worst replica's, the rejections it counts
+# itself, and the bytes of state, which stay a replica's own.
+NOT_SUMMED = {
+    "rejected_total", "spec_acceptance_ewma", "spec_gamma",
+    "state_bytes_draft", "state_bytes_full", "state_bytes_window",
+    "tick_ms_ewma", "tick_ms_norm_ewma", "ttft_avg_ms",
+}
+
+
+def test_the_pool_sums_what_a_scheduler_counts():
+    """A key added to ``Stats.snapshot()`` is summed over the replicas or
+    named above, and a key taken away is summed no longer."""
+    pool = _pool(2)
+    snap = pool.replicas[0].scheduler.stats.snapshot()
+    assert all(isinstance(v, (int, float)) for v in snap.values())
+    assert set(snap) - set(EnginePool._SUM_KEYS) == NOT_SUMMED
+    assert set(EnginePool._SUM_KEYS) <= set(snap)
+    agg = pool.snapshot()
+    assert set(EnginePool._SUM_KEYS) <= set(agg)
+    assert not [k for k in agg if "kv_page" in k or "kv_cow" in k]

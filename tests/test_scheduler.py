@@ -3,6 +3,7 @@
 import asyncio
 import json
 import queue
+import sys
 import threading
 import time
 
@@ -15,6 +16,8 @@ from generativeaiexamples_tpu.engine.tokenizer import ByteTokenizer
 from generativeaiexamples_tpu.models import llama
 
 CFG = llama.llama_tiny(dtype="float32", max_seq_len=128)
+INT8 = llama.llama_tiny(dtype="float32", max_seq_len=128, kv_dtype="int8")
+CFGS = {"bf16": CFG, "int8": INT8}
 
 
 def _collect(
@@ -35,12 +38,53 @@ def _collect(
     return tokens, reason
 
 
+def _collect_all(scheduler, prompts, max_tokens=6, sessions=None, start=False):
+    """Submit every prompt before waiting for any (``start``: and before
+    the scheduler's first tick, so that they wait together); returns
+    their (tokens, reason), in the prompts' order."""
+    out = [([], queue.Queue()) for _ in prompts]
+    for i, (prompt, (tokens, done)) in enumerate(zip(prompts, out)):
+        scheduler.submit(
+            Request(
+                token_ids=list(prompt),
+                sampling=SamplingParams(temperature=0.0, max_tokens=max_tokens),
+                on_token=tokens.append,
+                on_done=done.put,
+                session_id=sessions[i] if sessions else "",
+            )
+        )
+    if start:
+        scheduler.start()
+    return [(tokens, done.get(timeout=120)) for tokens, done in out]
+
+
 @pytest.fixture(scope="module")
 def scheduler():
     s = Scheduler(CFG, max_batch=4, max_len=128, decode_chunk_size=4)
     s.start()
     yield s
     s.stop()
+
+
+@pytest.fixture(scope="module")
+def alone_cold():
+    """The oracle of what a prompt streams: a scheduler a KV dtype with
+    no prefix cache and no chunked prefill, asked one prompt at a time.
+    ``alone_cold(kv, prompt, max_tokens)`` returns the tokens."""
+    built = {}
+
+    def oracle(kv, prompt, max_tokens):
+        if kv not in built:
+            built[kv] = Scheduler(
+                CFGS[kv], max_batch=2, max_len=128, decode_chunk_size=4,
+                prefix_cache="off", prefill_chunk_tokens=None,
+            )
+            built[kv].start()
+        return _collect(built[kv], prompt, max_tokens=max_tokens)[0]
+
+    yield oracle
+    for s in built.values():
+        s.stop()
 
 
 class TestScheduler:
@@ -702,65 +746,64 @@ class TestSharedPrefixCache:
     cached history), 1, and > the prefill chunk size (the warming path),
     in both bf16-KV and int8 append-buffer modes."""
 
-    # (case name, extra tokens appended to the cached history)
-    SUFFIX_CASES = [
-        ("suffix0", 0),
-        ("suffix1", 1),
-        ("suffix_gt_chunk", 9),  # > prefill_chunk_tokens=4 below
-    ]
+    # case name -> (which case, extra tokens appended to the cached history)
+    SUFFIX_CASES = {
+        "suffix0": (0, 0),
+        "suffix1": (1, 1),
+        "suffix_gt_chunk": (2, 9),  # > prefill_chunk_tokens=4 below
+    }
 
-    def _run_cases(self, cfg):
+    @pytest.fixture(scope="class", params=["bf16", "int8_append_buffer"])
+    def cold_and_warm(self, request):
+        """A scheduler that prefills every prompt whole and cold, and one
+        with the shared cache and chunks of 4: one pair a KV dtype."""
         kw = dict(max_batch=2, max_len=128, decode_chunk_size=4)
-        cold = Scheduler(
-            cfg, **kw, prefix_cache="off", prefill_chunk_tokens=None
-        )
-        warm = Scheduler(
-            cfg, **kw, prefix_cache="shared", prefill_chunk_tokens=4
-        )
-        cold.start()
-        warm.start()
-        try:
-            for case_i, (name, extra) in enumerate(self.SUFFIX_CASES):
-                # Distinct base prompt per case so segments parked by an
-                # earlier case can never match a later one.
-                base = list(range(2 + 50 * case_i, 42 + 50 * case_i))
-                out1, _ = _collect(cold, base, max_tokens=3)
-                # Parked history after a length finish drops the last
-                # sampled token (its KV was never written).
-                history = base + out1[:-1]
-                prompt2 = history + [499 - i for i in range(extra)]
-                expected, _ = _collect(cold, prompt2, max_tokens=4)
+        with pytest.MonkeyPatch.context() as patch:
+            cfg = CFG
+            if request.param == "int8_append_buffer":
+                # Read where a step program is traced: set for the pair's life.
+                patch.setenv("GAIE_FORCE_APPEND_BUFFER", "1")
+                cfg = INT8
+            cold = Scheduler(
+                cfg, **kw, prefix_cache="off", prefill_chunk_tokens=None
+            )
+            warm = Scheduler(
+                cfg, **kw, prefix_cache="shared", prefill_chunk_tokens=4
+            )
+            cold.start()
+            warm.start()
+            try:
+                yield cold, warm
+            finally:
+                cold.stop()
+                warm.stop()
 
-                before = warm.stats.snapshot()
-                out1w, _ = _collect(warm, base, max_tokens=3)
-                assert out1w == out1, name  # seed itself decodes cold
-                got, _ = _collect(warm, prompt2, max_tokens=4)
-                after = warm.stats.snapshot()
-                assert (
-                    after["shared_prefix_hits"]
-                    == before["shared_prefix_hits"] + 1
-                ), name
-                assert after["prefix_hits"] == before["prefix_hits"], name
-                # Reuse = the full common prefix (capped at plen-1 when
-                # the prompt equals the cached history).
-                reused = after["prefix_tokens_reused"] - before[
-                    "prefix_tokens_reused"
-                ]
-                assert reused == min(len(history), len(prompt2) - 1), name
-                assert got == expected, name
-        finally:
-            cold.stop()
-            warm.stop()
+    @pytest.mark.parametrize("name", sorted(SUFFIX_CASES))
+    def test_shared_hit_matches_cold(self, cold_and_warm, name):
+        cold, warm = cold_and_warm
+        case_i, extra = self.SUFFIX_CASES[name]
+        # Distinct base prompt per case so segments parked by another
+        # case can never match this one.
+        base = list(range(2 + 50 * case_i, 42 + 50 * case_i))
+        out1, _ = _collect(cold, base, max_tokens=3)
+        # Parked history after a length finish drops the last
+        # sampled token (its KV was never written).
+        history = base + out1[:-1]
+        prompt2 = history + [499 - i for i in range(extra)]
+        expected, _ = _collect(cold, prompt2, max_tokens=4)
 
-    def test_shared_hit_matches_cold_bf16(self):
-        self._run_cases(CFG)
-
-    def test_shared_hit_matches_cold_int8_append_buffer(self, monkeypatch):
-        monkeypatch.setenv("GAIE_FORCE_APPEND_BUFFER", "1")
-        cfg = llama.llama_tiny(
-            dtype="float32", max_seq_len=128, kv_dtype="int8"
-        )
-        self._run_cases(cfg)
+        before = warm.stats.snapshot()
+        out1w, _ = _collect(warm, base, max_tokens=3)
+        assert out1w == out1  # seed itself decodes cold
+        got, _ = _collect(warm, prompt2, max_tokens=4)
+        after = warm.stats.snapshot()
+        assert after["shared_prefix_hits"] == before["shared_prefix_hits"] + 1
+        assert after["prefix_hits"] == before["prefix_hits"]
+        # Reuse = the full common prefix (capped at plen-1 when
+        # the prompt equals the cached history).
+        reused = after["prefix_tokens_reused"] - before["prefix_tokens_reused"]
+        assert reused == min(len(history), len(prompt2) - 1)
+        assert got == expected
 
     def test_shared_hit_takeover_when_no_free_slot(self):
         """With a single slot the graft has no destination: the hit must
@@ -789,6 +832,164 @@ class TestSharedPrefixCache:
         finally:
             cold.stop()
             warm.stop()
+
+
+# Long enough to clear Scheduler.MIN_PREFIX (32), so that continuations
+# and cross-session hits take the graft paths, not cold admission.
+PREFIX = [(i * 13) % 256 + 1 for i in range(48)]
+
+
+class TestTwoSessionsOnePrefix:
+    """Two sessions that leave one parked prefix and append what differs
+    do not see each other's rows: each streams what its prompt streams
+    alone and cold.  Under ``shared`` both graft the seed's rows, which
+    stay parked; under ``session`` each takes over its own last turn."""
+
+    @pytest.mark.parametrize("mode", ["shared", "session"])
+    @pytest.mark.parametrize("kv", ["bf16", "int8"])
+    def test_each_streams_what_it_streams_alone(self, alone_cold, kv, mode):
+        sched = Scheduler(
+            CFGS[kv], max_batch=4, max_len=128, decode_chunk_size=4,
+            prefill_chunk_tokens=None, prefix_cache=mode,
+        )
+        sched.start()
+        try:
+            _collect(sched, PREFIX, session_id="seed")
+            turns = [PREFIX + [100], PREFIX + [200]]
+            first = _collect_all(sched, turns, sessions=["a", "b"])
+            # A second turn a session, past where the first two diverged.
+            turns2 = [PREFIX + [100, 101], PREFIX + [200, 201]]
+            second = _collect_all(sched, turns2, sessions=["a", "b"])
+            snap = sched.stats.snapshot()
+        finally:
+            sched.stop()
+        streams = [tokens for tokens, _ in first + second]
+        assert streams == [alone_cold(kv, p, 6) for p in turns + turns2]
+        assert streams[0] != streams[1]  # what differs was seen
+        # The rows were reused, not prefilled again.
+        hits = snap["shared_prefix_hits"] + snap["prefix_hits"]
+        assert hits == (4 if mode == "shared" else 2)
+
+
+class TestSlotPressure:
+    @pytest.mark.parametrize("kv", ["bf16", "int8"])
+    def test_more_sessions_than_slots_all_end_and_nothing_leaks(
+        self, alone_cold, kv
+    ):
+        """Six sessions on two slots, all waiting at once: every request
+        ends with its own stream, the prefixes parked along the way are
+        evicted for the next arrival, the index names exactly the slots
+        that are still parked, and reclaiming those leaves every slot
+        free."""
+        sched = Scheduler(
+            CFGS[kv], max_batch=2, max_len=128, decode_chunk_size=4,
+            prefill_chunk_tokens=32, prefix_cache="shared",
+        )
+        prompts = [list(range(1 + 40 * i, 41 + 40 * i)) for i in range(6)]
+        sched.start()
+        try:
+            got = _collect_all(
+                sched, prompts, max_tokens=3,
+                sessions=[f"s{i}" for i in range(6)],
+            )
+        finally:
+            sched.stop()
+        assert [reason for _, reason in got] == ["length"] * 6
+        assert [t for t, _ in got] == [alone_cold(kv, p, 3) for p in prompts]
+        slots = sched._slots
+        assert all(s.request is None and s.warm_pos is None for s in slots)
+        parked = {i for i, s in enumerate(slots) if s.cached}
+        assert parked and set(sched._prefix_index.segments()) == parked
+        assert sorted(sched._reclaim_parked(len(slots))) == sorted(parked)
+        assert len(sched._prefix_index) == 0
+        assert sched._free_slots() == list(range(len(slots)))
+
+
+class TestAStreamDoesNotDependOnItsAdmission:
+    """One prompt down every admission path over int8 KV: what it streams
+    alone and cold is what it streams in a cold batch of unequal prompts,
+    as a session hit, as a shared hit, as a takeover of the only slot and
+    through chunked prefill."""
+
+    PROMPT = PREFIX + list(range(60, 75))
+
+    @pytest.fixture(scope="class")
+    def warm(self):
+        s = Scheduler(
+            INT8, max_batch=4, max_len=128, decode_chunk_size=4,
+            prefill_chunk_tokens=16, prefix_cache="shared",
+        )
+        s.start()
+        yield s
+        s.stop()
+
+    def _through(self, path, warm):
+        """(prompt, its tokens, whether its path's counter moved)"""
+        prompt = self.PROMPT
+        if path == "cold_batch":
+            s = Scheduler(
+                INT8, max_batch=4, max_len=128, decode_chunk_size=4,
+                prefix_cache="off", prefill_chunk_tokens=None,
+            )
+            others = [[7, 8, 9], list(range(200, 222))]
+            try:
+                got = _collect_all(s, [prompt] + others, start=True)
+            finally:
+                s.stop()
+            snap = s.stats.snapshot()
+            taken = snap["prefill_rows"] == 3 and snap["prefill_chunks"] == 0
+            return prompt, got[0][0], taken
+        if path == "takeover":
+            s = Scheduler(
+                INT8, max_batch=1, max_len=128, decode_chunk_size=4,
+                prefix_cache="shared", prefill_chunk_tokens=None,
+            )
+            s.start()
+            try:
+                _collect(s, PREFIX, max_tokens=3)
+                got, _ = _collect(s, prompt)
+            finally:
+                s.stop()
+            return prompt, got, s.stats.snapshot()["shared_prefix_hits"] == 1
+        before = warm.stats.snapshot()
+        if path == "chunked":
+            # Nothing parked shares its first tokens: four chunks of 16, cold.
+            prompt = list(range(130, 193))
+            got, _ = _collect(warm, prompt)
+            key, by = "prefill_chunks", 4
+        elif path == "session_hit":
+            _collect(warm, PREFIX, max_tokens=3, session_id="mine")
+            before = warm.stats.snapshot()
+            got, _ = _collect(warm, prompt, session_id="mine")
+            key, by = "prefix_hits", 1
+        else:
+            _collect(warm, PREFIX, max_tokens=3, session_id="theirs")
+            before = warm.stats.snapshot()
+            got, _ = _collect(warm, prompt)
+            key, by = "shared_prefix_hits", 1
+        return prompt, got, warm.stats.snapshot()[key] == before[key] + by
+
+    @pytest.mark.parametrize(
+        "path",
+        ["cold_batch", "session_hit", "shared_hit", "takeover", "chunked"],
+    )
+    def test_same_stream(self, alone_cold, warm, path):
+        prompt, got, taken = self._through(path, warm)
+        assert taken, path
+        assert got == alone_cold("int8", prompt, 6)
+
+
+@pytest.mark.parametrize("flag", ["--kv-layout", "--kv-page-size"])
+def test_engine_server_parser_has_no_flag_for_a_second_kv_layout(
+    flag, monkeypatch, capsys
+):
+    from generativeaiexamples_tpu.engine import server
+
+    monkeypatch.setattr(sys, "argv", ["engine-server", flag, "paged"])
+    with pytest.raises(SystemExit) as exit_:
+        server.main()
+    assert exit_.value.code == 2
+    assert f"unrecognized arguments: {flag}" in capsys.readouterr().err
 
 
 class TestChunkedPrefill:

@@ -323,10 +323,11 @@ def test_scheduler_init_is_a_build_in_three_stages(family):
     assert EXECUTABLES.asker.who == "other" and EXECUTABLES.asker.stage is None
 
 
-def test_a_build_that_raises_still_ends():
+@pytest.mark.parametrize("layout", ["scattered", "paged"])
+def test_a_build_that_raises_still_ends(layout):
     before = EXECUTABLES.report()["setup"]["builds"]
     with pytest.raises(ValueError, match="kv_layout"):
-        Scheduler(CFG, max_batch=2, max_len=128, kv_layout="scattered")
+        Scheduler(CFG, max_batch=2, max_len=128, kv_layout=layout)
     assert EXECUTABLES.report()["setup"]["builds"] == before + 1
     assert EXECUTABLES.asker.who == "other" and EXECUTABLES.asker.stage is None
 

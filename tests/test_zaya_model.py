@@ -138,13 +138,9 @@ def test_what_the_family_does_not_serve_is_refused_with_the_reason(bad, match):
         hybrid.from_hf_config({**hybrid.ZAYA_TINY, **bad}, max_len=64)
 
 
-@pytest.mark.parametrize("asked, match", [
-    (dict(spec_mode="ngram"), "tails"),
-    (dict(kv_layout="paged"), "paged layout"),
-])
-def test_check_supported_refuses_drafts_and_pages_over_tails(asked, match):
-    with pytest.raises(ValueError, match=match):
-        serving_model(CFG, None, T).check_supported(**asked)
+def test_check_supported_refuses_drafts_over_tails():
+    with pytest.raises(ValueError, match="tails"):
+        serving_model(CFG, None, T).check_supported(spec_mode="ngram")
 
 
 @pytest.mark.parametrize("asked, match", [
