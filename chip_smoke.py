@@ -3,7 +3,7 @@
 
     python chip_smoke.py [--seed N]          # one chip (what the driver runs)
     python chip_smoke.py --four-chips        # the two multi-chip paths only
-    python chip_smoke.py --hybrid [--model ling|mellum|exaone_moe|mistral4|zaya] [--control NAME]   # phase 5 only
+    python chip_smoke.py --hybrid [--model ling|mellum|exaone_moe|mistral4|zaya|nemotron_h] [--control NAME]   # phase 5 only
 
 Drives the system through the entry points a user calls, at llama3-8b's
 published widths with random int8 weights from ``--seed``:
@@ -155,6 +155,11 @@ class Sizes:
     zaya_prompt: int = 800
     zaya_chunk: int = 256
     zaya_decode: int = 16
+    # ``--model nemotron_h``: likewise (two scan blocks of 128 a chunk).
+    nemotron_model: str = "nemotron-3-super-120b-a12b-l11e128"
+    nemotron_prompt: int = 800
+    nemotron_chunk: int = 256
+    nemotron_decode: int = 16
     kv_heads: int = 8  # the preset's own; the tiny rehearsal needs 4 to split
     start_timeout_s: float = 600.0
     request_timeout_s: float = 600.0
@@ -194,6 +199,10 @@ TINY = Sizes(
     zaya_prompt=45,
     zaya_chunk=16,
     zaya_decode=8,
+    nemotron_model="nemotron_h-tiny",
+    nemotron_prompt=45,
+    nemotron_chunk=16,
+    nemotron_decode=8,
     kv_heads=4,
     start_timeout_s=240.0,
     request_timeout_s=240.0,
@@ -1160,6 +1169,10 @@ HYBRID_CONTROLS = {
         "w8a8", "no_value_shift", "no_qk_mean", "no_conv", "no_router_average",
         "renormed_top1",
     ),
+    "nemotron_h": (
+        "w8a8_mlp", "state_bf16", "no_conv", "no_d_skip", "norm_whole", "gate_after_norm",
+        "relu_not_relu2", "no_routed_scale", "rope_on",
+    ),
 }
 # ``--model exaone_moe`` is held to the limits of its benchmark
 # configuration (``reference.logit_share_limits``; PERF.md section 6,
@@ -1172,6 +1185,9 @@ MISTRAL4_CONFIG = "benchmarks/configs/mistral-small-4-119b-l6e32.json"
 # ``--model zaya`` likewise (``benchmarks/arch/zaya.py``; PERF.md section
 # 6, PR 40).
 ZAYA_CONFIG = "benchmarks/configs/zaya1-8b-l20.json"
+# ``--model nemotron_h`` likewise (``benchmarks/arch/nemotron_h.py``;
+# PERF.md section 6, PR 44).
+NEMOTRON_CONFIG = "benchmarks/configs/nemotron-3-super-120b-a12b-l11e128.json"
 
 
 def hybrid_limits(model: str) -> dict:
@@ -1375,18 +1391,19 @@ def child_mistral4(seed: int, sizes: Sizes, control: str = "") -> None:
         raise SmokeFailure(f"logits left the reference: {failed} (limits {limits})")
 
 
-def child_zaya(seed: int, sizes: Sizes, control: str = "") -> None:
-    """``--hybrid --model zaya``: the serving model's chunk program
-    (``prefill_rows``, the chunk beside a pad row on a state of 8,192 rows
-    a slot) and its decode step over that state against the float32
-    reference, by the benchmark's own comparison.  A control changes what
-    the reference computes, one mechanism at a time: the values not
-    shifted, no q-k mean, no convolution (``z = u``), the router without
-    the previous layer's state, the chosen expert weighted 1, every
-    projection of a layer in the nearest precision below; each has to
+def _child_by_benchmark(
+    seed: int, control: str, *, family: str, config: str, preset: str, prompt: int,
+    chunk: int, decode: int, patches: dict, sites: tuple,
+) -> None:
+    """A family whose comparison is its benchmark's own
+    (``benchmarks/arch/<family>.py::logit_shares``): the serving model's
+    chunk program (``prefill_rows``, the chunk beside a pad row on a state
+    of ``max_len`` rows a slot) and its decode step over that state against
+    the float32 reference, held to the limits of ``config``.  A control
+    changes what the reference computes (``patches``: the reference module
+    -> control -> the functions of that module it replaces); each has to
     leave a limit."""
     import jax
-    import jax.numpy as jnp
     import numpy as np
 
     from generativeaiexamples_tpu.engine.serving_models import serving_model
@@ -1399,17 +1416,57 @@ def child_zaya(seed: int, sizes: Sizes, control: str = "") -> None:
 
     enable_compile_cache()
     t0 = time.monotonic()
-    arch = _bench_arch("zaya")
-    with open(os.path.join(ROOT, ZAYA_CONFIG)) as f:
+    arch = _bench_arch(family)
+    with open(os.path.join(ROOT, config)) as f:
         limits = json.load(f)["reference"]["logit_share_limits"]
-    arch._CHECK.update(limits=limits, decode=sizes.zaya_decode, chunk=sizes.zaya_chunk)
-    cfg = hybrid.PRESETS[sizes.zaya_model]()
-    pad_to = sizes.zaya_prompt
-    params = serving_model(cfg, None, pad_to).prepare_params(
+    arch._CHECK.update(limits=limits, decode=decode, chunk=chunk)
+    cfg = hybrid.PRESETS[preset]()
+    params = serving_model(cfg, None, prompt).prepare_params(
         None, quantize=False, matmul_kernel="xla", seed=seed)
-    tokens = np.random.RandomState(seed).randint(1, cfg.vocab_size, size=pad_to).astype(np.int32)
-    reference = arch.zaya_reference
-    patched = {
+    tokens = np.random.RandomState(seed).randint(1, cfg.vocab_size, size=prompt).astype(np.int32)
+    reference = getattr(arch, f"{family}_reference")
+    patched = patches(reference).get(control, {})
+    plain = {name: getattr(reference, name) for name in patched}
+    for name, stand_in in patched.items():
+        setattr(reference, name, stand_in)
+    if patched:
+        jax.clear_caches()  # a layer traced before this would keep the plain one
+    try:
+        share, _ = arch.logit_shares(params, cfg, tokens, prompt)
+    finally:
+        for name, fn in plain.items():  # a caller in this process gets the plain ones back
+            setattr(reference, name, fn)
+        if patched:
+            jax.clear_caches()
+    readings = arch.share_quantiles(share, decode)
+    failed = {k: v for k, v in readings.items() if not v <= limits[k]}
+    report = runtime_report()
+    emit(
+        {
+            "phase": "hybrid", "model": preset, "control": control or None,
+            "positions": {"prefill": int(len(share)) - decode, "decode": decode},
+            **readings, "limits": limits, "within_limits": not failed,
+            "seconds": time.monotonic() - t0,
+            "compile": report["compile"], "peak_bytes_in_use": report["peak_bytes_in_use"],
+            "kernel_paths": {k: v for site in sites for k, v in _taken(site).items()},
+            "device": device_report(),
+        }
+    )
+    if control and not failed:
+        raise SmokeFailure(f"the control {control!r} stayed inside every limit: {readings}")
+    if not control and failed:
+        raise SmokeFailure(f"logits left the reference: {failed} (limits {limits})")
+
+
+def child_zaya(seed: int, sizes: Sizes, control: str = "") -> None:
+    """``--hybrid --model zaya``: a control changes what the reference
+    computes, one mechanism at a time: the values not shifted, no q-k mean,
+    no convolution (``z = u``), the router without the previous layer's
+    state, the chosen expert weighted 1, every projection of a layer in the
+    nearest precision below."""
+    import jax.numpy as jnp
+
+    patches = lambda ref: {
         # The nearest precision below in EVERY projection of a layer (the
         # mixer's two and the expert's three); the experts' alone reads
         # inside the sound runs' spread here (``w8a8_mlp``: kept for the
@@ -1421,43 +1478,70 @@ def child_zaya(seed: int, sizes: Sizes, control: str = "") -> None:
         "no_conv": {"_conv": lambda u, lp, dims: u},
         "no_router_average": {"_router_average": lambda rho, prev, gamma: rho},
         "renormed_top1": {"_top1_weight": lambda p, chosen: chosen.astype(p.dtype)},
-    }.get(control, {})
-    plain = {name: getattr(reference, name) for name in patched}
-    for name, stand_in in patched.items():
-        setattr(reference, name, stand_in)
-    if patched:
-        jax.clear_caches()  # a layer traced before this would keep the plain one
-    try:
-        share, _ = arch.logit_shares(params, cfg, tokens, pad_to)
-    finally:
-        for name, fn in plain.items():  # a caller in this process gets the plain ones back
-            setattr(reference, name, fn)
-        if patched:
-            jax.clear_caches()
-    readings = arch.share_quantiles(share, sizes.zaya_decode)
-    failed = {k: v for k, v in readings.items() if not v <= limits[k]}
-    report = runtime_report()
-    emit(
-        {
-            "phase": "hybrid", "model": sizes.zaya_model, "control": control or None,
-            "positions": {"prefill": int(len(share)) - sizes.zaya_decode,
-                          "decode": sizes.zaya_decode},
-            **readings, "limits": limits, "within_limits": not failed,
-            "seconds": time.monotonic() - t0,
-            "compile": report["compile"], "peak_bytes_in_use": report["peak_bytes_in_use"],
-            "kernel_paths": {**_taken("moe_experts"), **_taken("attn_cca")},
-            "device": device_report(),
-        }
+    }
+    _child_by_benchmark(
+        seed, control, family="zaya", config=ZAYA_CONFIG, preset=sizes.zaya_model,
+        prompt=sizes.zaya_prompt, chunk=sizes.zaya_chunk, decode=sizes.zaya_decode,
+        patches=patches, sites=("moe_experts", "attn_cca"),
     )
-    if control and not failed:
-        raise SmokeFailure(f"the control {control!r} stayed inside every limit: {readings}")
-    if not control and failed:
-        raise SmokeFailure(f"logits left the reference: {failed} (limits {limits})")
+
+
+def child_nemotron_h(seed: int, sizes: Sizes, control: str = "") -> None:
+    """``--hybrid --model nemotron_h``: a control changes what the
+    reference computes: the experts' products in the nearest precision
+    below (``w8a8_mlp``), the state-space state kept in bfloat16, no
+    convolution, no ``D x``, the gated norm over all channels at once or
+    with the gate after it, ``relu`` for ``relu2``, the routed weights not
+    scaled, the attention layers rotated with the config's unused
+    ``rope_theta``."""
+    import jax
+    import jax.numpy as jnp
+
+    def gate_after_norm(y, z, gain, groups, eps):
+        g = y.reshape(y.shape[0], groups, -1)
+        g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True) + eps)
+        return g.reshape(y.shape) * gain.astype(jnp.float32) * jax.nn.silu(z)
+
+    def rope_on(q, k, theta=10000.0):
+        d = q.shape[-1]
+        inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+        ang = jnp.arange(q.shape[0], dtype=jnp.float32)[:, None, None] * inv
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        turn = lambda x: jnp.concatenate(
+            [x[..., : d // 2] * cos - x[..., d // 2 :] * sin,
+             x[..., d // 2 :] * cos + x[..., : d // 2] * sin], -1)
+        return turn(q), turn(k)
+
+    def patches(ref):
+        plain_norm = ref._gated_norm
+        return {
+            "w8a8_mlp": {"_mlp": lambda h, w_up, w_down: _w8a8_project(
+                ref._act(_w8a8_project(h, w_up)), w_down)},
+            # bfloat16's 8 bits of exponent and 7 of mantissa; a pair of casts
+            # is folded away on the chip (XLA allows excess precision).
+            "state_bf16": {"_keep": lambda state: jax.lax.reduce_precision(
+                state, exponent_bits=8, mantissa_bits=7)},
+            "no_conv": {"_conv": lambda u, lp: u},
+            "no_d_skip": {"_skip": lambda y, d, xs: y},
+            "norm_whole": {"_gated_norm": lambda y, z, gain, groups, eps: plain_norm(y, z, gain, 1, eps)},
+            "gate_after_norm": {"_gated_norm": gate_after_norm},
+            "relu_not_relu2": {"_act": jax.nn.relu},
+            "no_routed_scale": {"_routed_weights": lambda s, scale: s / (s.sum(-1, keepdims=True) + 1e-20)},
+            "rope_on": {"_rotate": rope_on},
+        }
+
+    _child_by_benchmark(
+        seed, control, family="nemotron_h", config=NEMOTRON_CONFIG, preset=sizes.nemotron_model,
+        prompt=sizes.nemotron_prompt, chunk=sizes.nemotron_chunk, decode=sizes.nemotron_decode,
+        patches=patches, sites=("moe_experts", "attn_full", "ssm_"),
+    )
 
 
 def child_hybrid(seed: int, sizes: Sizes, control: str = "", model: str = "ling") -> None:
     if model == "zaya":
         return child_zaya(seed, sizes, control)
+    if model == "nemotron_h":
+        return child_nemotron_h(seed, sizes, control)
     if model == "exaone_moe":
         return child_exaone(seed, sizes, control)
     if model == "mistral4":

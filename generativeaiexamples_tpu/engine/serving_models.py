@@ -314,7 +314,7 @@ class HybridServing:
     )
     # Of ``moe.COUNTERS``, those exported a second time for decode steps
     # alone: their ratio is the experts a layer of one step streamed.
-    DECODE_MOE = ("experts_touched", "expert_layer_steps")
+    DECODE_MOE = ("experts_touched", "expert_layer_steps", "choices_local")
 
     def __init__(self, cfg: hybrid.HybridConfig, mesh, max_len: int) -> None:
         self.cfg, self.mesh, self.max_len = cfg, mesh, max_len
@@ -354,6 +354,13 @@ class HybridServing:
                 "rejected draft would need the recurrent state rolled back, "
                 "and no step keeps the state it started from (ops/kda.py has "
                 "no rollback)"
+            )
+        if (drafted or self.draft) and self.cfg.layers_of("mamba"):
+            raise ValueError(
+                "speculative decoding is not served over mamba state: a "
+                "rejected draft has moved the state-space state and the "
+                "convolution's tail, and no step keeps what it started from "
+                "(ops/ssm.py has no rollback)"
             )
         if (drafted or self.draft) and self.cfg.layers_of("cca"):
             raise ValueError(

@@ -84,6 +84,8 @@ def resolve_model_preset(model_name: str) -> str:
         return "mistral4-tiny" if "tiny" in name else "mistral-small-4-119b-l6e32"
     if "zaya" in name:
         return "zaya-tiny" if "tiny" in name else "zaya1-8b-l20"
+    if "nemotron" in name:
+        return "nemotron_h-tiny" if "tiny" in name else "nemotron-3-super-120b-a12b-l11e128"
     if "mixtral" in name or "8x7b" in name:
         return "mixtral-8x7b"
     if "gemma" in name:

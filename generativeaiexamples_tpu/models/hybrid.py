@@ -1,9 +1,9 @@
 """A decoder made of layer kinds: each layer names its mixer (``kda``,
-``mla``, ``full``, ``window`` or ``cca``) and its MLP (``dense`` or
-``experts``), owns the parameters of those kinds and keeps the state of
-its mixer's kind.
+``mla``, ``full``, ``window``, ``cca`` or ``mamba``) and its MLP
+(``dense``, ``experts`` or ``none``: the layer is its mixer alone), owns
+the parameters of those kinds and keeps the state of its mixer's kind.
 
-Five families are defined here.  ``bailing_hybrid`` (Ling-3.0-flash and
+Six families are defined here.  ``bailing_hybrid`` (Ling-3.0-flash and
 its -VL sibling's language model): KDA linear attention (``ops/kda.py``)
 beside a latent-attention layer every ``layer_group_size`` layers
 (``ops/mla.py``), a leading dense SwiGLU layer and then sigmoid-routed
@@ -37,6 +37,20 @@ latent), one expert a token of 16 chosen by the ZAYA router, an MLP that
 is handed the previous layer's router state (``ops/moe.py::route_mlp``:
 ``forward`` carries that state from layer to layer beside ``x``), and a
 head tied to the embedding (``CcaConfig``).
+``nemotron_h`` (NVIDIA-Nemotron-3-Super-120B-A12B): a stack whose
+published layers are ONE function each, named by a letter of
+``hybrid_override_pattern`` and read here as pairs (:func:`pair_pattern`:
+a mixer takes the ``E`` that follows it, or nothing): ``M`` a Mamba-2
+mixer (the ``mamba`` kind, ``ops/ssm.py``: one projection to a gate, the
+convolution's inputs and the heads' steps, a depthwise causal convolution
+with a bias, the state-space scan over a float32 state of 64 x 128 a head
+in blocks of ``chunk_size`` tokens, or one update of it a decode step, a
+gated RMSNorm a group), ``*`` a ``full`` GQA layer that is not rotated,
+``E`` sigmoid-routed experts, 22 a token of 512, that work between two
+projections in a latent of ``moe_latent_size`` and have no gate
+(``W2 relu(W1 u)^2``), beside a shared expert on the hidden state itself;
+this process may hold a share of them (``MambaConfig``).  Its prediction
+module is not served: a rejected draft would need ``S`` rolled back.
 A new architecture is a new layer kind here, not another flag on
 ``LlamaConfig``; ``models/llama.py`` keeps serving the configurations it
 serves.
@@ -66,6 +80,12 @@ State of a slot, by the layer's mixer:
   token's projection for the value heads taken a token late): 5,376 B a
   layer at the published widths.  A prefix hit grafts the rows and takes
   the tails from a snapshot, which holds nothing else.
+* ``mamba``: ``ssm`` (H, P, N) float32, the state-space state of every
+  head, and ``conv``, the last ``conv_kernel - 1`` inputs of the
+  convolution over ``x``, ``B`` and ``C``: 4,194,304 B and 61,440 B a
+  layer at the published widths.  Fixed size; like a KDA layer's it
+  exists only as of the last token it has seen, and a token that does not
+  count moves neither (its step is 0: a decay of exactly 1, nothing added).
 
 A prediction module adds two entries behind the stack's: its block's
 ``k``, ``v`` rows (a ``full`` layer's) and ``h_last`` (D,), the stack's
@@ -78,26 +98,35 @@ family has and the KDA and MLA sizes; ``GqaConfig`` adds the GQA sizes,
 the rotary parameters of each kind and the routing options;
 ``LatentConfig`` adds what the ``mistral4`` family's latent layer has;
 ``CcaConfig`` the ``zaya`` family's sizes, its router's width and its tied
-head.
+head; ``MambaConfig`` the ``nemotron_h`` family's Mamba-2 sizes, its
+experts' latent and their activation.
 
 What is read from a family's convention and not from a key of the
 public config is listed under ``assumed`` in
 ``benchmarks/configs/ling-3.0-flash-vl-l7e128.json``,
 ``benchmarks/configs/mellum2-12b-a2.5b-l12.json``,
 ``benchmarks/configs/k-exaone-236b-a23b-l5e16.json``,
-``benchmarks/configs/mistral-small-4-119b-l6e32.json`` and
+``benchmarks/configs/mistral-small-4-119b-l6e32.json``,
 ``benchmarks/configs/zaya1-8b-l20.json`` (which also lists what of ZAYA1
-is not served: residual scaling and "MoD" have no key and no equation);
+is not served: residual scaling and "MoD" have no key and no equation)
+and ``benchmarks/configs/nemotron-3-super-120b-a12b-l11e128.json`` (no
+rotation in the attention layers, the order of the Mamba projection's
+outputs, the gate before the norm; not served: the prediction module);
 the plain references are ``models/hybrid_reference.py``,
 ``models/mellum_reference.py``, ``models/exaone_moe_reference.py``,
-``models/mistral4_reference.py`` and ``models/zaya_reference.py``.
+``models/mistral4_reference.py``, ``models/zaya_reference.py`` and
+``models/nemotron_h_reference.py``.
 
 What a row of ``benchmarks/README.md``'s layout table would say of the
 newest family (that file is a ``benchmark`` PR's to edit):
-``benchmarks/arch/zaya.py`` maps ``configs/zaya1-8b-l20.json`` to
-``CcaConfig`` through :func:`from_hf_config` and holds its counts,
-``benchmarks/zaya_reference.py`` is the copy of ``models/zaya_reference.py``
-that decides its cell's ``correct``.
+``benchmarks/arch/nemotron_h.py`` maps
+``configs/nemotron-3-super-120b-a12b-l11e128.json`` to ``MambaConfig``
+through :func:`from_hf_config` and holds its counts,
+``benchmarks/nemotron_h_reference.py`` is the copy of
+``models/nemotron_h_reference.py`` that decides its cell's ``correct``,
+``layer_metrics/decode_rows_per_expert.py`` and
+``layer_metrics/prefill_ssm_block_fill_pct.py`` read the counters this
+family added.
 """
 
 from __future__ import annotations
@@ -110,7 +139,7 @@ import jax
 import jax.numpy as jnp
 
 from generativeaiexamples_tpu.models.llama import rms_norm
-from generativeaiexamples_tpu.ops import cca, gqa, gqa_decode, kda, mla, moe
+from generativeaiexamples_tpu.ops import cca, gqa, gqa_decode, kda, mla, moe, ssm
 from generativeaiexamples_tpu.ops.dispatch import record
 from generativeaiexamples_tpu.ops.rope import (
     NO_ROPE, RopeSpec, apply_rope_partial, apply_rope_spec, rope_spec, yarn_mscale,
@@ -118,8 +147,11 @@ from generativeaiexamples_tpu.ops.rope import (
 
 Params = Mapping[str, Any]
 F32 = jnp.float32
-MIXERS = ("kda", "mla", "full", "window", "cca")
-MLPS = ("dense", "experts")
+MIXERS = ("kda", "mla", "full", "window", "cca", "mamba")
+# ``none``: the layer is its mixer alone (no norm, no parameters, nothing
+# added): a stack whose published layers are ONE function each reads as
+# such pairs (``_from_nemotron_h``).
+MLPS = ("dense", "experts", "none")
 # The block of a prediction module: a full GQA layer with experts.
 MTP_KIND = ("full", "experts")
 # State leaves that hold one row a position, which can be cut at any
@@ -145,9 +177,14 @@ LATENT_COUNTERS = ("read_latent", "dense_latent")
 # Slots whose recurrent state the KDA layers' decode steps read (the rows
 # that decode where the step is ``kda.kda_step_rows``, every slot where
 # it is XLA's), and slots x KDA layers.  A prefill call adds to neither:
-# a chunk program works on its own rows' state.  A model with KDA layers
-# and no GQA layer returns them after ``moe.COUNTERS``.
+# a chunk program works on its own rows' state.  The ``mamba`` layers count
+# their slots the same way (every slot: their step is XLA's).
 STATE_COUNTERS = ("read_state", "dense_state")
+# What the ``mamba`` layers' block scan worked on in prefill calls: tokens
+# that count, and blocks computed (``ops/ssm.py::blocks_of``, over every
+# row of the call, a group's pad rows among them).  A decode step adds to
+# neither.
+SSM_COUNTERS = ("ssm_tokens", "ssm_blocks")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -207,6 +244,10 @@ class HybridConfig:
     # What ``CcaConfig`` makes fields of: a linear router, an untied head.
     router_hidden: ClassVar[int] = 0
     tie_embeddings: ClassVar[bool] = False
+    # What ``MambaConfig`` makes fields of: experts on the hidden state
+    # itself, with a gate (SwiGLU).
+    moe_latent: ClassVar[int] = 0
+    expert_act: ClassVar[str] = "swiglu"
 
     def __post_init__(self) -> None:
         for mixer, mlp in self.layer_kinds:
@@ -223,6 +264,7 @@ class HybridConfig:
                 raise ValueError("n_kv_heads must divide n_heads")
             if self.rope_full is None or (
                 self.rope_window is None and not self.layers_of("cca")
+                and not self.layers_of("mamba")
             ):
                 raise ValueError("a GQA layer kind needs its rotary parameters")
             if self.layers_of("window") and self.sliding_window < 1:
@@ -248,12 +290,16 @@ class HybridConfig:
     @property
     def row_counters(self) -> tuple[str, ...]:
         """Names of the counters of rows read that ``forward`` returns
-        after ``moe.COUNTERS``: a model with GQA layers ``ATTN_COUNTERS``,
-        a ``LatentConfig`` model ``LATENT_COUNTERS``, one with KDA layers
-        (Ling) ``STATE_COUNTERS``."""
-        if self.has_attn_counters:
-            return ATTN_COUNTERS
-        return STATE_COUNTERS if self.layers_of("kda") else ()
+        after ``moe.COUNTERS``, the sets the model's kinds have in this
+        order: ``ATTN_COUNTERS`` (K/V rows a position), ``STATE_COUNTERS``
+        (recurrent state: ``mamba`` layers, and KDA layers where no layer
+        has K/V rows: Ling), ``SSM_COUNTERS`` (``mamba`` layers); a
+        ``LatentConfig`` model ``LATENT_COUNTERS``."""
+        mamba = bool(self.layers_of("mamba"))
+        names = ATTN_COUNTERS if self.has_attn_counters else ()
+        if mamba or (not names and self.layers_of("kda")):
+            names += STATE_COUNTERS
+        return names + (SSM_COUNTERS if mamba else ())
 
     @property
     def rows_only(self) -> bool:
@@ -262,7 +308,7 @@ class HybridConfig:
         any token, and a prefix hit needs no snapshot."""
         return not (
             self.layers_of("kda") or self.layers_of("window")
-            or self.layers_of("cca") or self.mtp_layers
+            or self.layers_of("cca") or self.layers_of("mamba") or self.mtp_layers
         )
 
     @property
@@ -305,9 +351,10 @@ class HybridConfig:
 
     def snapshot_bytes(self, max_len: int | None = None) -> int:
         """Bytes of what one slot keeps only as of its last token: the
-        recurrent state of the KDA layers, the rings of the window layers
-        (of a state ``max_len`` long; absent: ``max_seq_len``), the tails
-        of the ``cca`` layers and a prediction module's ``h_last``: every
+        recurrent state of the KDA and ``mamba`` layers, the rings of the
+        window layers (of a state ``max_len`` long; absent:
+        ``max_seq_len``), the tails of the ``cca`` layers and a prediction
+        module's ``h_last``: every
         leaf of ``init_state`` that is no row a position."""
         shapes = jax.eval_shape(
             lambda: init_state(self, 1, self.max_seq_len if max_len is None else max_len)
@@ -443,6 +490,52 @@ class CcaConfig(HybridConfig):
         return (self.n_heads + self.n_kv_heads) * self.attn_head_dim
 
 
+@dataclasses.dataclass(frozen=True)
+class MambaConfig(HybridConfig):
+    """A configuration of the ``nemotron_h`` family: ``mamba`` mixers
+    beside ``full`` GQA layers that are not rotated, each layer a mixer
+    alone or a mixer and experts that work in a latent."""
+
+    score_function: str = "sigmoid"
+    router_bias: bool = True
+    n_kv_heads: int = 0
+    attn_head_dim: int = 128
+    rope_full: RopeSpec | None = NO_ROPE
+    # The Mamba-2 mixer: ``mamba_heads`` of ``mamba_head_dim`` channels
+    # (their product is the mixer's inner width), ``B`` and ``C`` of
+    # ``ssm_state`` values shared by the heads of each of ``mamba_groups``
+    # groups, the scan in blocks of ``ssm_block`` tokens (``chunk_size``).
+    mamba_heads: int = 128
+    mamba_head_dim: int = 64
+    mamba_groups: int = 8
+    ssm_state: int = 128
+    ssm_block: int = 128
+    # (time_step_min, time_step_max, time_step_floor): the range that
+    # seeded weights draw a head's step bias for; no arithmetic reads it.
+    dt_init: tuple[float, float, float] = (0.001, 0.1, 0.0001)
+    # The routed experts work on ``h W_dn`` (D -> moe_latent) and their
+    # sum goes back through ``W_up``; 0: on the hidden state itself.
+    moe_latent: int = 0
+    # ``relu2``: ``W2 relu(W1 u)^2``, no gate (routed and shared alike).
+    expert_act: str = "relu2"
+
+    def __post_init__(self) -> None:
+        super().__post_init__()
+        if self.expert_act not in moe.ACTIVATIONS:
+            raise ValueError(f"unknown expert activation {self.expert_act!r}")
+        if self.mamba_heads % self.mamba_groups:
+            raise ValueError("n_groups must divide mamba_num_heads")
+
+    @property
+    def mamba_inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def mamba_conv_channels(self) -> int:
+        """``x``, ``B`` and ``C`` side by side: what the convolution mixes."""
+        return self.mamba_inner + 2 * self.mamba_groups * self.ssm_state
+
+
 def from_hf_config(
     model: Mapping[str, Any],
     *,
@@ -454,7 +547,7 @@ def from_hf_config(
     """The public ``config.json`` keys -> ``HybridConfig``, by
     ``model_type``: ``mellum`` (:func:`_from_mellum`), ``exaone_moe``
     (:func:`_from_exaone`), ``mistral4`` (:func:`_from_mistral4`), ``zaya``
-    (:func:`_from_zaya`), else the
+    (:func:`_from_zaya`), ``nemotron_h`` (:func:`_from_nemotron_h`), else the
     ``bailing_hybrid`` family, of which the rest speaks.  ``draft`` ``mtp`` holds the model's own prediction
     module and serves it as the decode step's draft; a family without one
     refuses it.
@@ -484,6 +577,10 @@ def from_hf_config(
         )
     if model.get("model_type") == "zaya":
         return _from_zaya(
+            model, max_len=max_len, expert_offset=expert_offset, kv_dtype=kv_dtype
+        )
+    if model.get("model_type") == "nemotron_h":
+        return _from_nemotron_h(
             model, max_len=max_len, expert_offset=expert_offset, kv_dtype=kv_dtype
         )
     period = int(model["layer_group_size"])
@@ -804,6 +901,103 @@ def _from_zaya(
     )
 
 
+def pair_pattern(pattern: str) -> tuple[tuple[str, str], ...]:
+    """A ``hybrid_override_pattern`` (one letter a published layer: ``M``
+    a Mamba-2 mixer, ``*`` attention, ``E`` experts; each layer is
+    ``x + f(RMSNorm(x))`` with that ONE ``f``) as this module's (mixer,
+    MLP) pairs: a mixer takes the ``E`` that follows it, or nothing.  An
+    ``E`` with no mixer before it (``EE``, a leading ``E``) has no pair
+    and is refused, as is a letter of another kind (``-``, the dense MLP
+    of the family's earlier models, which no layer of this one is)."""
+    mixers = {"M": "mamba", "*": "full"}
+    pairs: list[tuple[str, str]] = []
+    open_pair = False  # the last pair may still take an ``E``
+    for at, letter in enumerate(pattern):
+        if letter in mixers:
+            pairs.append((mixers[letter], "none"))
+            open_pair = True
+        elif letter == "E" and open_pair:
+            pairs[-1] = (pairs[-1][0], "experts")
+            open_pair = False
+        elif letter == "E":
+            raise ValueError(
+                f"hybrid_override_pattern {pattern!r} does not pair: the 'E' at "
+                f"{at} follows no mixer (a layer of experts after another, or "
+                "first in the stack, is not served)"
+            )
+        else:
+            raise ValueError(f"layer letter {letter!r} of {pattern!r} is not served")
+    return tuple(pairs)
+
+
+def _from_nemotron_h(
+    model: Mapping[str, Any], *, max_len: int, expert_offset: int, kv_dtype: str
+) -> MambaConfig:
+    """``model_type: nemotron_h``: the first ``num_hidden_layers`` letters
+    of ``hybrid_override_pattern`` paired (:func:`pair_pattern`); the
+    Mamba-2 sizes; attention without rotation (``rope_theta`` and
+    ``partial_rotary_factor`` are carried unused: the family's attention
+    layers have no positional embedding); sigmoid routing with a
+    selection bias over ``num_experts_published`` outputs (absent: the
+    same) of which ``n_routed_experts`` are held, ``relu2`` experts inside
+    a latent of ``moe_latent_size``.  The prediction module
+    (``num_nextn_predict_layers``) is not served: a rejected draft would
+    need the state-space state rolled back."""
+    n = int(model["num_hidden_layers"])
+    pattern = str(model["hybrid_override_pattern"])
+    if len(pattern) < n:
+        raise ValueError("hybrid_override_pattern names fewer layers than num_hidden_layers")
+    if model.get("mlp_hidden_act", "relu2") != "relu2" or model.get("mamba_hidden_act", "silu") != "silu":
+        raise ValueError("nemotron_h is served with relu2 experts and a silu mixer")
+    if any(model.get(k) for k in ("attention_bias", "mlp_bias", "mamba_proj_bias", "use_bias")):
+        raise ValueError("projection biases are not served")
+    if not model.get("use_conv_bias", True):
+        raise ValueError("the convolution is served with its bias (use_conv_bias)")
+    if model.get("sliding_window"):
+        raise ValueError("a sliding window is not served for nemotron_h")
+    heads, head = int(model["mamba_num_heads"]), int(model["mamba_head_dim"])
+    if heads * head != int(model.get("expand", 2)) * int(model["hidden_size"]):
+        raise ValueError("mamba_num_heads x mamba_head_dim is not expand x hidden_size")
+    held = int(model["n_routed_experts"])
+    return MambaConfig(
+        vocab_size=int(model["vocab_size"]),
+        d_model=int(model["hidden_size"]),
+        layer_kinds=pair_pattern(pattern[:n]),
+        n_heads=int(model["num_attention_heads"]),
+        n_kv_heads=int(model["num_key_value_heads"]),
+        attn_head_dim=int(model["head_dim"]),
+        rope_full=NO_ROPE,
+        conv_kernel=int(model["conv_kernel"]),
+        mamba_heads=heads,
+        mamba_head_dim=head,
+        mamba_groups=int(model["n_groups"]),
+        ssm_state=int(model["ssm_state_size"]),
+        ssm_block=int(model["chunk_size"]),
+        dt_init=(
+            float(model["time_step_min"]), float(model["time_step_max"]),
+            float(model["time_step_floor"]),
+        ),
+        moe_latent=int(model["moe_latent_size"]),
+        expert_act="relu2",
+        d_ff=int(model["intermediate_size"]),  # no layer is a dense MLP
+        moe_d_ff=int(model["moe_intermediate_size"]),
+        shared_d_ff=int(model["moe_shared_expert_intermediate_size"])
+        * int(model.get("n_shared_experts", 1)),
+        n_experts=int(model.get("num_experts_published", held)),
+        experts_held=held,
+        expert_offset=int(expert_offset),
+        n_experts_per_tok=int(model["num_experts_per_tok"]),
+        n_group=int(model["n_group"]),
+        topk_group=int(model["topk_group"]),
+        routed_scaling=float(model["routed_scaling_factor"]),
+        norm_topk=bool(model["norm_topk_prob"]),
+        norm_eps=float(model.get("layer_norm_epsilon", model.get("norm_eps", 1e-5))),
+        max_seq_len=int(max_len),
+        dtype=str(model.get("torch_dtype", "bfloat16")),
+        kv_dtype=kv_dtype,
+    )
+
+
 # -- parameters ---------------------------------------------------------------
 
 
@@ -815,10 +1009,16 @@ def _normal(key, scale, shape, dtype):
 def _layer_shapes(cfg: HybridConfig, mixer: str, mlp: str) -> dict:
     """name -> (shape, init) for one layer; init is a fan-in for a normal
     draw, a constant, ``"bias"`` (a normal draw of 0.1: not zero, so that
-    tests see it) or ``"gamma"`` (the ZAYA router's weight of the layer
-    before: uniform in 0.25-0.75, so that leaving it out is seen)."""
+    tests see it), ``"gamma"`` (the ZAYA router's weight of the layer
+    before: uniform in 0.25-0.75, so that leaving it out is seen), or a
+    ``mamba`` head's ``"a_log"`` (the log of a uniform draw in 1-16) and
+    ``"dt_bias"`` (the inverse softplus of a log-uniform draw in
+    ``cfg.dt_init``'s range, so that a head's decay a token lies in
+    0.2-0.999).  A layer that is its mixer alone has no ``mlp_norm``."""
     D, H, K = cfg.d_model, cfg.n_heads, cfg.kda_head_dim
-    shapes: dict = {"attn_norm": ((D,), 1.0), "mlp_norm": ((D,), 1.0)}
+    shapes: dict = {"attn_norm": ((D,), 1.0)}
+    if mlp != "none":
+        shapes["mlp_norm"] = ((D,), 1.0)
     if mixer == "kda":
         shapes.update(
             w_qkv=((D, 3 * H * K), D),
@@ -852,6 +1052,19 @@ def _layer_shapes(cfg: HybridConfig, mixer: str, mlp: str) -> dict:
             k_temp=((KH,), 1.0),  # tau: the learned temperature a key head
             w_o=((H * hd, D), H * hd),
         )
+    elif mixer == "mamba":
+        MH, inner, C = cfg.mamba_heads, cfg.mamba_inner, cfg.mamba_conv_channels
+        shapes.update(
+            # the gate z, then what the convolution mixes (x, B, C), then dt
+            w_in=((D, inner + C + MH), D),
+            conv_w=((cfg.conv_kernel, C), cfg.conv_kernel),
+            conv_b=((C,), "bias"),
+            ssm_a_log=((MH,), "a_log"),
+            ssm_dt_bias=((MH,), "dt_bias"),
+            ssm_d=((MH,), "ones_f32"),
+            ssm_norm=((inner,), 1.0),
+            w_out=((inner, D), inner),
+        )
     else:
         qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
         if cfg.q_lora_rank:
@@ -875,7 +1088,7 @@ def _layer_shapes(cfg: HybridConfig, mixer: str, mlp: str) -> dict:
         shapes.update(w_o=((H * cfg.v_head_dim, D), H * cfg.v_head_dim))
     if mlp == "dense":
         shapes.update(w_gu=((D, 2 * cfg.d_ff), D), w_down=((cfg.d_ff, D), cfg.d_ff))
-    else:
+    elif mlp == "experts":
         F, Fs, E = cfg.moe_d_ff, cfg.shared_d_ff, cfg.experts_held
         R = cfg.router_hidden
         if R:
@@ -890,9 +1103,20 @@ def _layer_shapes(cfg: HybridConfig, mixer: str, mlp: str) -> dict:
             shapes.update(router=((D, cfg.n_experts), D))
         if cfg.router_bias:
             shapes.update(router_bias=((cfg.n_experts,), 0.0))
-        shapes.update(w_gu_e=((E, D, 2 * F), D), w_down_e=((E, F, D), F))
+        # The width the routed experts work in: a latent, or the stream's.
+        De = cfg.moe_latent or D
+        if cfg.moe_latent:
+            shapes.update(w_lat_down=((D, De), D), w_lat_up=((De, D), De))
+        gated = cfg.expert_act == "swiglu"  # gate and up side by side, or up alone
+        shapes.update(
+            {"w_gu_e" if gated else "w_up_e": ((E, De, (1 + gated) * F), De)},
+            w_down_e=((E, F, De), F),
+        )
         if Fs:
-            shapes.update(w_gu_s=((D, 2 * Fs), D), w_down_s=((Fs, D), Fs))
+            shapes.update(
+                {"w_gu_s" if gated else "w_up_s": ((D, (1 + gated) * Fs), D)},
+                w_down_s=((Fs, D), Fs),
+            )
     return shapes
 
 
@@ -913,6 +1137,15 @@ def init_params(cfg: HybridConfig, key: jax.Array) -> Params:
             return _normal(next(keys), 0.1, shape, dtype)
         if init == "gamma":
             return jax.random.uniform(next(keys), shape, F32, 0.25, 0.75)
+        if init == "a_log":
+            return jnp.log(jax.random.uniform(next(keys), shape, F32, 1.0, 16.0))
+        if init == "dt_bias":
+            lo, hi, floor = cfg.dt_init
+            dt = jnp.exp(jax.random.uniform(next(keys), shape, F32, jnp.log(lo), jnp.log(hi)))
+            dt = jnp.maximum(dt, floor)
+            return dt + jnp.log(-jnp.expm1(-dt))  # softplus of it is dt
+        if init == "ones_f32":
+            return jnp.ones(shape, F32)
         if isinstance(init, float):
             return jnp.full(shape, init, dtype)
         return _normal(next(keys), float(init) ** -0.5, shape, dtype)
@@ -1038,6 +1271,17 @@ def init_state(cfg: HybridConfig, batch: int, max_len: int) -> tuple:
                     "v_prev": jnp.zeros((batch, 1, KH // 2 * hd), sd),
                 }
             )
+        elif mixer == "mamba":
+            out.append(
+                {
+                    "ssm": jnp.zeros(
+                        (batch, cfg.mamba_heads, cfg.mamba_head_dim, cfg.ssm_state), F32
+                    ),
+                    "conv": jnp.zeros(
+                        (batch, cfg.conv_kernel - 1, cfg.mamba_conv_channels), sd
+                    ),
+                }
+            )
         elif mixer == "kda":
             out.append(
                 {
@@ -1060,7 +1304,8 @@ def state_bytes(cfg: HybridConfig, batch: int, max_len: int) -> dict[str, int]:
     """Bytes of the slots' state by kind, leaf by leaf: ``full`` (rows
     that grow with the tokens: latent, K/V), ``window`` (rings: the same at
     any ``max_len`` over the window), ``recurrent`` (what exists only as
-    of the last token: a KDA layer's state, a ``cca`` layer's tails) and,
+    of the last token: a KDA or ``mamba`` layer's state and tail, a ``cca``
+    layer's tails) and,
     where a prediction module is held, ``draft`` (its rows and
     ``h_last``)."""
     out = {"full": 0, "window": 0, "recurrent": 0}
@@ -1443,10 +1688,57 @@ def _cca_mixer(
     return out, state, jnp.stack(read).astype(jnp.int32)
 
 
+def _mamba_mixer(h, lp, st, valid, n_valid, cfg: HybridConfig):
+    """A ``mamba`` layer (``ops/ssm.py``): one projection to the gate
+    ``z``, what the convolution mixes (``x``, ``B``, ``C``) and the heads'
+    steps ``dt``; a depthwise causal convolution with a bias, continued
+    from the slot's tail, and a SiLU; the state-space scan from the slot's
+    state (a decode step: one update of it); the gated norm a group; the
+    output projection.  A token that does not count has ``dt`` 0 and moves
+    neither state nor tail.  Returns (output, state, counters by name:
+    ``STATE_COUNTERS`` from a decode step, ``SSM_COUNTERS`` from a prefill
+    call)."""
+    b, s, _ = h.shape
+    H, P, G, N = cfg.mamba_heads, cfg.mamba_head_dim, cfg.mamba_groups, cfg.ssm_state
+    inner = H * P
+    with jax.named_scope("layer/mamba/proj"):
+        z, u, d = jnp.split(jnp.dot(h, lp["w_in"]), [inner, inner + cfg.mamba_conv_channels], axis=-1)
+    with jax.named_scope("layer/mamba/conv"):
+        c, xin = kda.causal_conv(u, st["conv"], lp["conv_w"])
+        tail = kda.next_tail(xin, n_valid, cfg.conv_kernel)
+        c = jax.nn.silu(c + lp["conv_b"].astype(F32)).astype(h.dtype)
+        xs, b_in, c_in = jnp.split(c, [inner, inner + G * N], axis=-1)
+        xs = xs.reshape(b, s, H, P)
+        b_in, c_in = b_in.reshape(b, s, G, N), c_in.reshape(b, s, G, N)
+    with jax.named_scope("layer/mamba/dt"):
+        dt = jax.nn.softplus(d.astype(F32) + lp["ssm_dt_bias"]) * valid[:, :, None].astype(F32)
+        a = -jnp.exp(lp["ssm_a_log"].astype(F32))
+    if s == 1:
+        record(f"ssm_step b={b} h={H}", False)
+        y, S = ssm.ssm_step(xs[:, 0], dt[:, 0], a, b_in[:, 0], c_in[:, 0], lp["ssm_d"], st["ssm"])
+        y = y[:, None]
+        read = {"read_state": b, "dense_state": b}
+    else:
+        record(f"ssm_scan b={b} s={s}", False)
+        y, S = ssm.ssm_scan(xs, dt, a, b_in, c_in, lp["ssm_d"], st["ssm"], block=cfg.ssm_block)
+        read = {"ssm_tokens": jnp.sum(n_valid), "ssm_blocks": b * ssm.blocks_of(s, cfg.ssm_block)[1]}
+    with jax.named_scope("layer/mamba/norm"):
+        v = ssm.gated_group_norm(y.reshape(b, s, inner), z, lp["ssm_norm"], G, cfg.norm_eps)
+    with jax.named_scope("layer/mamba/out"):
+        out = jnp.dot(v.astype(h.dtype), lp["w_out"])
+    return out, {"ssm": S, "conv": tail.astype(st["conv"].dtype)}, read
+
+
 def _swiglu(h, w_gu, w_down):
     gu = jnp.dot(h, w_gu)
     half = gu.shape[-1] // 2
     act = jax.nn.silu(gu[..., :half].astype(F32)) * gu[..., half:].astype(F32)
+    return jnp.dot(act.astype(h.dtype), w_down)
+
+
+def _relu2(h, w_up, w_down):
+    """``W2 relu(W1 h)^2``: an MLP without a gate."""
+    act = jnp.square(jax.nn.relu(jnp.dot(h, w_up).astype(F32)))
     return jnp.dot(act.astype(h.dtype), w_down)
 
 
@@ -1468,13 +1760,25 @@ def _expert_layer(h, lp, valid, cfg: HybridConfig, mesh, rho=None):
             norm_topk=cfg.norm_topk, scale=cfg.routed_scaling,
             score=cfg.score_function,
         )
+    u = x
+    if cfg.moe_latent:
+        # The routed experts work in a latent; the router saw the token itself.
+        with jax.named_scope("layer/moe/latent/down"):
+            u = jnp.dot(x, lp["w_lat_down"])
     y, counters = moe.expert_mlp(
-        x, idx, w, valid.reshape(-1), lp,
-        offset=cfg.expert_offset, held=cfg.experts_held, mesh=mesh,
+        u, idx, w, valid.reshape(-1), lp,
+        offset=cfg.expert_offset, held=cfg.experts_held, mesh=mesh, act=cfg.expert_act,
     )
+    if cfg.moe_latent:
+        # Linear: the shares' partial sums, each through its own copy, add up.
+        with jax.named_scope("layer/moe/latent/up"):
+            y = jnp.dot(y, lp["w_lat_up"])
     if cfg.shared_d_ff:
         with jax.named_scope("layer/moe/shared"):
-            y = y + _swiglu(x, lp["w_gu_s"], lp["w_down_s"])
+            if cfg.expert_act == "relu2":
+                y = y + _relu2(x, lp["w_up_s"], lp["w_down_s"])
+            else:
+                y = y + _swiglu(x, lp["w_gu_s"], lp["w_down_s"])
     return y.reshape(b, s, d), counters, rho
 
 
@@ -1482,30 +1786,40 @@ def _mix(
     x, lp, st, mixer, pos, valid, n_valid, cfg: HybridConfig, window: int,
     apart: bool = False, site: str = "", mesh=None,
 ):
-    """The mixer's half of a layer: (x + mixer(norm(x)), new state, the
-    rows the layer read in the order of ``cfg.row_counters``, or 0)."""
+    """The mixer's half of a layer: (x + mixer(norm(x)), new state, what
+    the layer read, (len(cfg.row_counters),) int32: each mixer's counters
+    go by name to the entries the model has, the others nowhere)."""
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
-    read = 0
     if mixer == "kda":
-        y, st, slots = _kda_mixer(h, lp, st, valid, n_valid, cfg, mesh)
-        if cfg.row_counters == STATE_COUNTERS:
-            read = slots
+        y, st, read = _kda_mixer(h, lp, st, valid, n_valid, cfg, mesh)
+        names = STATE_COUNTERS
     elif mixer == "mla":
-        y, st, rows = _mla_mixer(h, lp, st, pos, valid, n_valid, cfg, window, apart)
-        if cfg.row_counters == LATENT_COUNTERS:
-            read = rows
+        y, st, read = _mla_mixer(h, lp, st, pos, valid, n_valid, cfg, window, apart)
+        names = LATENT_COUNTERS
+    elif mixer == "mamba":
+        y, st, named = _mamba_mixer(h, lp, st, valid, n_valid, cfg)
+        names, read = tuple(named), tuple(named.values())
     elif mixer == "cca":
         y, st, read = _cca_mixer(h, lp, st, pos, valid, n_valid, cfg, window, apart, mesh)
+        names = ATTN_COUNTERS
     else:
         y, st, read = _gqa_mixer(
             h, lp, st, mixer, pos, valid, n_valid, cfg, window, apart, site, mesh
         )
+        names = ATTN_COUNTERS
+    by_name = dict(zip(names, read))
+    read = jnp.stack(
+        [jnp.asarray(by_name.get(n, 0), jnp.int32) for n in cfg.row_counters]
+    ) if cfg.row_counters else jnp.zeros((0,), jnp.int32)
     return x + y, st, read
 
 
 def _mlp(x, lp, mlp, valid, cfg: HybridConfig, mesh, rho=None):
     """The MLP's half: (x + mlp(norm(x)), the expert layer's counters, the
-    router state to hand the next layer: ``_expert_layer``)."""
+    router state to hand the next layer: ``_expert_layer``); ``x`` as it
+    came where the layer is its mixer alone."""
+    if mlp == "none":
+        return x, 0, rho
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
     if mlp == "dense":
         with jax.named_scope("layer/mlp"):
@@ -1820,6 +2134,56 @@ ZAYA_TINY = {
 }
 
 
+# nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-BF16's config.json: every key
+# that gives the model its shape (``intermediate_size`` is a dense MLP's,
+# which no layer is; ``rope_theta`` and ``partial_rotary_factor`` are its
+# class's defaults and turn nothing: the attention layers are not rotated).
+NEMOTRON3_SUPER = {
+    "model_type": "nemotron_h", "num_hidden_layers": 88, "hidden_size": 4096,
+    "hybrid_override_pattern": (
+        "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
+        "EMEMEMEMEM*EMEMEMEM*EMEMEMEME"
+    ),
+    "mtp_hybrid_override_pattern": "*E", "num_nextn_predict_layers": 1,
+    "mamba_num_heads": 128, "mamba_head_dim": 64, "expand": 2, "n_groups": 8,
+    "ssm_state_size": 128, "conv_kernel": 4, "chunk_size": 128,
+    "mamba_hidden_act": "silu", "mamba_proj_bias": False, "use_conv_bias": True,
+    "time_step_min": 0.001, "time_step_max": 0.1, "time_step_floor": 0.0001,
+    "num_attention_heads": 32, "num_key_value_heads": 2, "head_dim": 128,
+    "attention_bias": False, "rope_theta": 10000, "partial_rotary_factor": 1,
+    "sliding_window": None, "max_position_embeddings": 262144,
+    "intermediate_size": 2688, "moe_intermediate_size": 2688,
+    "moe_latent_size": 1024, "moe_shared_expert_intermediate_size": 5376,
+    "n_routed_experts": 512, "n_shared_experts": 1, "num_experts_per_tok": 22,
+    "n_group": 1, "topk_group": 1, "norm_topk_prob": True,
+    "routed_scaling_factor": 5, "mlp_hidden_act": "relu2", "mlp_bias": False,
+    "use_bias": False, "layer_norm_epsilon": 1e-05, "norm_eps": 1e-05,
+    "vocab_size": 131072, "tie_word_embeddings": False,
+}
+# Rank 0's share of the first of eight pipeline stages, every layer shared
+# by four chips: published layers 0-10 (``MEMEMEM*EME``: five Mamba-2
+# mixers, five expert layers, one attention layer), 128 of the 512 experts
+# (the first 128), a quarter of the vocabulary.
+NEMOTRON3_L11E128_CUT = {
+    "num_hidden_layers": 11, "n_routed_experts": 128, "num_experts_published": 512,
+    "vocab_size": 32768,
+}
+# Every ratio at sizes a CPU test runs: ``MEM*EM`` (all three kinds, a
+# layer that is a mixer alone, a state carried past attention), 8 heads of
+# 16 in 2 groups with a state of 16 and blocks of 8 tokens, 4 query heads
+# on 2 key-value heads, 4 of 8 experts held and 3 a token in a latent of
+# half the hidden size.
+NEMOTRON_H_TINY = {
+    **NEMOTRON3_SUPER, "num_hidden_layers": 6, "hybrid_override_pattern": "MEM*EM",
+    "hidden_size": 64, "mamba_num_heads": 8, "mamba_head_dim": 16, "n_groups": 2,
+    "ssm_state_size": 16, "chunk_size": 8, "num_attention_heads": 4,
+    "num_key_value_heads": 2, "head_dim": 16, "moe_intermediate_size": 32,
+    "moe_latent_size": 32, "moe_shared_expert_intermediate_size": 64,
+    "n_routed_experts": 4, "num_experts_published": 8, "num_experts_per_tok": 3,
+    "vocab_size": 512, "torch_dtype": "float32",
+}
+
+
 def ling_flash_vl_l7e128() -> HybridConfig:
     return from_hf_config({**LING_FLASH_VL, **LING_L7E128_CUT}, max_len=2048)
 
@@ -1866,6 +2230,14 @@ def zaya_tiny() -> HybridConfig:
     return from_hf_config(ZAYA_TINY, max_len=256, kv_dtype="float32")
 
 
+def nemotron3_super_l11e128() -> HybridConfig:
+    return from_hf_config({**NEMOTRON3_SUPER, **NEMOTRON3_L11E128_CUT}, max_len=8192)
+
+
+def nemotron_h_tiny() -> HybridConfig:
+    return from_hf_config(NEMOTRON_H_TINY, max_len=256, kv_dtype="float32")
+
+
 PRESETS = {
     "ling-3.0-flash-vl-l7e128": ling_flash_vl_l7e128,
     "ling-tiny": ling_tiny,
@@ -1877,4 +2249,6 @@ PRESETS = {
     "mistral4-tiny": mistral4_tiny,
     "zaya1-8b-l20": zaya1_8b_l20,
     "zaya-tiny": zaya_tiny,
+    "nemotron-3-super-120b-a12b-l11e128": nemotron3_super_l11e128,
+    "nemotron_h-tiny": nemotron_h_tiny,
 }

@@ -206,14 +206,21 @@ def _tiling(a: int, b: int) -> tuple[int, int, int]:
     )
 
 
-def expert_mlp(x, idx, weights, valid, lp, *, offset: int, held: int, mesh=None):
+# An expert's form: SwiGLU (``w_gu_e``, gate and up side by side), or
+# ``relu2``, ``W2 relu(W1 x)^2`` with no gate (``w_up_e``).
+ACTIVATIONS = ("swiglu", "relu2")
+
+
+def expert_mlp(x, idx, weights, valid, lp, *, offset: int, held: int, mesh=None,
+               act: str = "swiglu"):
     """What the experts held give: ``sum_i w_i E_i(x)`` over the choices
     ``i`` with ``offset <= idx_i < offset + held``.
 
     x: (n, D); idx, weights: (n, k); valid: (n,) bool (a padded position
     routes nowhere); lp: ``w_gu_e`` (held, D, 2F) gate and up side by
-    side, ``w_down_e`` (held, F, D).  Returns (y (n, D), counters int32
-    in the order of ``COUNTERS``)."""
+    side (``act`` ``relu2``: ``w_up_e`` (held, D, F), no gate),
+    ``w_down_e`` (held, F, D).  Returns (y (n, D), counters int32 in the
+    order of ``COUNTERS``)."""
     n, d = x.shape
     k = idx.shape[1]
     f = lp["w_down_e"].shape[1]
@@ -231,9 +238,13 @@ def expert_mlp(x, idx, weights, valid, lp, *, offset: int, held: int, mesh=None)
         n_local = starts[held]
         xs = x[jnp.minimum(order // k, n - 1)]
     with jax.named_scope("layer/moe/experts"):
-        h = _grouped(xs, lp["w_gu_e"], sizes, pallas, _tiling(d, 2 * f))
-        act = (jax.nn.silu(h[:, :f].astype(F32)) * h[:, f:].astype(F32)).astype(x.dtype)
-        ys = _grouped(act, lp["w_down_e"], sizes, pallas, _tiling(f, d))
+        if act == "relu2":
+            h = _grouped(xs, lp["w_up_e"], sizes, pallas, _tiling(d, f))
+            mid = jnp.square(jax.nn.relu(h.astype(F32))).astype(x.dtype)
+        else:
+            h = _grouped(xs, lp["w_gu_e"], sizes, pallas, _tiling(d, 2 * f))
+            mid = (jax.nn.silu(h[:, :f].astype(F32)) * h[:, f:].astype(F32)).astype(x.dtype)
+        ys = _grouped(mid, lp["w_down_e"], sizes, pallas, _tiling(f, d))
     with jax.named_scope("layer/moe/combine"):
         # Back to (token, choice) order by a gather.  Rows past the local
         # choices were never computed: zero, not whatever the buffer held.

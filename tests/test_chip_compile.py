@@ -653,6 +653,57 @@ def test_the_cca_models_decode_chunk_walks_its_rows_in_place(one_chip, monkeypat
     assert memory.alias_size_in_bytes >= 5_368_709_120  # the slots' state goes through in place
 
 
+def test_the_mamba_models_decode_chunk_updates_its_state_in_place(one_chip, monkeypatch):
+    """The decode chunk of nemotron-3-super-120b-a12b-l11e128 (8 steps over
+    32 slots: five ``mamba`` layers, five expert layers in a latent, one
+    ``full`` layer): the slots' 0.95 GB of state goes through in place (no
+    copy of a layer's ``S``, 537 MB of float32), the one attention layer is
+    the row walk of ``ops/gqa_decode.py`` at 32 query heads on 2 key-value
+    heads, the experts the grouped products at tiles that divide 1,024 and
+    2,688, and the program's temporaries stay small beside 10.25 GB of
+    weights and state."""
+    import json
+    from pathlib import Path
+
+    from generativeaiexamples_tpu.engine.serving_models import HybridServing
+    from generativeaiexamples_tpu.models import hybrid
+    from generativeaiexamples_tpu.ops import gqa_decode, moe
+
+    monkeypatch.setattr(moe, "platform_of", lambda mesh: "tpu")
+    monkeypatch.setattr(gqa_decode, "platform_of", lambda mesh: "tpu")
+    configs = Path(__file__).resolve().parents[1] / "benchmarks" / "configs"
+    model = json.loads((configs / "nemotron-3-super-120b-a12b-l11e128.json").read_text())
+    engine = model["engine"]
+    max_len, b, steps = int(engine["max_len"]), int(engine["max_batch"]), int(engine["decode_chunk_size"])
+    cfg = hybrid.from_hf_config(model, max_len=max_len, kv_dtype=engine["kv_dtype"])
+    assert isinstance(cfg, hybrid.MambaConfig) and cfg.n_layers == 6
+    serving = HybridServing(cfg, None, max_len)
+
+    def described(make):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            jax.eval_shape(make),
+        )
+
+    spec = _spec(one_chip)
+    ints, floats = spec((b,), jnp.int32), spec((b,), jnp.float32)
+    compiled = serving.make_decode_chunk().lower(
+        described(lambda: hybrid.init_params(cfg, jax.random.PRNGKey(0))),
+        described(lambda: hybrid.init_state(cfg, b, max_len)),
+        ints, ints, spec((2,), jnp.uint32), floats, floats, ints, steps, max_len,
+        spec((b,), jnp.bool_),
+    ).compile()
+    text = compiled.as_text()
+    assert "gqa_rows_decode_attention" in text and "gmm" in text
+    assert not re.search(rf"= f32\[{b},128,64,128\]\S* copy\(", text)
+    _, toks, aux = compiled.out_info
+    assert toks.shape == (steps, b) and aux.shape == (len(serving.counter_names),)
+    memory = compiled.memory_analysis()
+    print("mamba decode chunk temporaries", memory.temp_size_in_bytes)
+    assert memory.temp_size_in_bytes < 200_000_000
+    assert memory.alias_size_in_bytes >= 949_354_496  # S, the tails and the K/V rows, in place
+
+
 def test_the_latent_models_decode_chunk_keeps_its_state_in_place(one_chip, monkeypatch):
     """The decode chunk of mistral-small-4-119b-l6e32 (8 absorbed steps
     over 16 slots) at the widest decode window, 32,768: the slots' latent

@@ -349,6 +349,41 @@ def test_hybrid_phase_rehearsal_of_the_cca_model_and_its_controls(control, capsy
         assert line["p10"] > 1e-2 and line["decode_p50"] > 1e-2
 
 
+NEMOTRON_CONTROLS = list(chip_smoke.HYBRID_CONTROLS["nemotron_h"])
+
+
+# The nine against the reference alone: tests/test_nemotron_h_model.py.
+@pytest.mark.parametrize("control", ["", "w8a8_mlp", "state_bf16", "norm_whole", "rope_on"])
+def test_hybrid_phase_rehearsal_of_the_mamba_model_and_its_controls(control, capsys, monkeypatch, tmp_path):
+    """``--hybrid --model nemotron_h`` at the tiny size, in process: a prompt
+    of 45 tokens in chunks of 16 (two scan blocks of 8) through the chunk
+    program beside a pad row (every chunk after the first continues from
+    the slot's ``S`` and tail), its last 8 positions through the decode step
+    over both slots, by the benchmark's own comparison, held to limits of
+    the rehearsal's own (float32 at this size reads 1e-7) which the sound
+    run is far inside and each control leaves (four of the nine here)."""
+    config = json.loads(open(os.path.join(REPO, chip_smoke.NEMOTRON_CONFIG)).read())
+    config["reference"]["logit_share_limits"] = {"p10": 2e-4, "p50": 2e-4, "p90": 2e-4, "decode_p50": 2e-4}
+    tiny = tmp_path / "config.json"
+    tiny.write_text(json.dumps(config))
+    monkeypatch.setattr(chip_smoke, "NEMOTRON_CONFIG", str(tiny))
+    chip_smoke.child_hybrid(0, chip_smoke.TINY, control=control, model="nemotron_h")
+    line = _phases(capsys)["hybrid"]
+    assert line["model"] == "nemotron_h-tiny" and line["control"] == (control or None)
+    assert line["positions"] == {"prefill": 37, "decode": 8}
+    # The shapes of the scheduler's programs: a chunk beside a pad row, a
+    # decode step over both slots.
+    for site in ("ssm_scan b=2 s=16", "ssm_step b=2 h=8", "attn_full_chunk b=2 s=16 t=256", "attn_full b=2 s=1 t=256"):
+        assert line["kernel_paths"][site] == "xla"
+    assert line["within_limits"] == (not control)
+    if not control:
+        assert line["p90"] < 1e-5
+    elif control == "state_bf16":  # a rounding a token: little, and more the longer the state has run
+        assert line["decode_p50"] > 2e-4
+    else:
+        assert line["p50"] > 1e-3
+
+
 def test_hybrid_phase_names_a_child_for_every_model_and_control():
     assert sorted(n for n in chip_smoke.CHILDREN if n.startswith("hybrid")) == [
         "hybrid_exaone_moe", "hybrid_exaone_moe_no_qk_norm", "hybrid_exaone_moe_no_window",
@@ -357,6 +392,7 @@ def test_hybrid_phase_names_a_child_for_every_model_and_control():
         "hybrid_mellum_no_yarn", "hybrid_mellum_w8a8_mlp",
         "hybrid_mistral4", "hybrid_mistral4_no_attn_scale", "hybrid_mistral4_no_mscale",
         "hybrid_mistral4_no_q_norm", "hybrid_mistral4_plain_rope", "hybrid_mistral4_w8a8_mlp",
+        "hybrid_nemotron_h", *(f"hybrid_nemotron_h_{c}" for c in sorted(NEMOTRON_CONTROLS)),
         "hybrid_zaya", *(f"hybrid_zaya_{c}" for c in sorted(ZAYA_CONTROLS)),
     ]
     with pytest.raises(chip_smoke.SmokeFailure, match="has no control 'no_yarn'"):

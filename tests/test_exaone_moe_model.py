@@ -203,7 +203,8 @@ def test_a_whole_sequence_and_the_modules_logits_match_the_reference(model, para
     np.testing.assert_allclose(state[L]["k"][0, : N - 1], rows["k"][0, : N - 1], atol=1e-5)
     np.testing.assert_allclose(state[L + 1]["h_last"][0], hidden[0, -1], atol=0)
     # Rows read: 6 window layers x their ring, (2 full layers + the module) x N.
-    first = len(hybrid.moe.COUNTERS) + 2  # behind the expert counters and their decode-only pair
+    # behind the expert counters and those exported again for decode steps
+    first = len(hybrid.moe.COUNTERS) + len(model.DECODE_MOE)
     assert counters.tolist()[first : first + 8] == [0, 0, 0, 0, 6 * W, 3 * N, 6 * N, 3 * N]
 
 

@@ -393,10 +393,117 @@ def test_zaya_metrics_are_exported(zaya):
     assert all(taken == "xla" for site, taken in paths.items() if site.startswith("attn_cca"))
 
 
+# -- the ``nemotron_h`` family: state-space layers between decode chunks ----------------
+
+
+@pytest.fixture(scope="module")
+def nemotron():
+    # What engine.server.main() builds for --model nemotron_h-tiny.
+    cfg = hybrid.PRESETS[resolve_model_preset("nemotron_h-tiny")]()
+    s = Scheduler(
+        cfg, None, max_batch=4, max_len=256, decode_chunk_size=4, seed=9,
+        prefill_chunk_tokens=CHUNK, prefix_cache="shared",
+    )
+    s.start()
+    yield s
+    s.stop()
+
+
+def _nemotron_gap(scheduler, prompt, out, pad_to=192):
+    """``_mellum_gap`` against ``nemotron_h_reference``."""
+    from generativeaiexamples_tpu.models import nemotron_h_reference
+
+    seq = list(prompt) + list(out)
+    lg = np.asarray(nemotron_h_reference.all_logits(
+        scheduler.params, scheduler.cfg, seq + [0] * (pad_to - len(seq))))
+    rows = lg[len(prompt) - 1 : len(seq) - 1]
+    return float((rows.max(-1) - rows[np.arange(len(out)), out]).max())
+
+
+def test_nemotron_cold_prompts_a_hit_restored_from_a_snapshot_of_state_and_reused_slots(nemotron):
+    """State-space layers on the serving path with no change to the batcher:
+    greedy tokens equal the reference's (to a near-tie) for a cold batch, a
+    prompt in chunks of 32 (four scan blocks of 8 each, ``S`` and the tail
+    carried from program to program between decode chunks), a prefix hit
+    whose K/V rows are grafted and whose ``S`` and tails come from a
+    by-leaf snapshot, and slots whose last occupant left state."""
+    cfg = nemotron.cfg
+    assert cfg.layer_kinds == (("mamba", "experts"), ("mamba", "none"), ("full", "experts"), ("mamba", "none"))
+    one = 3 * (8 * 16 * 16 * 4 + 3 * 192 * 4)  # three mamba layers: S in float32 and a tail of 3 x 192
+    assert not nemotron.model.cut_anywhere and not nemotron.model.draft
+    assert nemotron._snapshots.bytes_each == cfg.snapshot_bytes(256) == one
+    snap0 = nemotron.stats.snapshot()
+    assert snap0["state_bytes_full"] == 4 * 2 * 256 * 32 * 4 and snap0["state_bytes_window"] == 0
+    cold = [_prompt(61, 20), _prompt(62, 31)]
+    for p, o in zip(cold, _generate(nemotron, cold)):
+        assert len(o) == 6 and _nemotron_gap(nemotron, p, o) <= GAP
+    first = _prompt(63, 100)  # chunks of 32: snapshots at 32, 64, 96
+    before = nemotron.stats.snapshot()
+    (out,) = _generate(nemotron, [first])
+    mid = nemotron.stats.snapshot()
+    assert mid["prefill_chunks"] - before["prefill_chunks"] == 4
+    assert mid["state_snapshots_saved"] - before["state_snapshots_saved"] == 3
+    assert mid["state_snapshot_bytes"] == len(nemotron._snapshots) * one
+    assert _nemotron_gap(nemotron, first, out) <= GAP
+    again = first[:70] + _prompt(64, 25)  # rows match to 70, the state exists at 64
+    (hit,) = _generate(nemotron, [again])
+    after = nemotron.stats.snapshot()
+    assert after["shared_prefix_hits"] - mid["shared_prefix_hits"] == 1
+    assert after["state_snapshots_restored"] - mid["state_snapshots_restored"] == 1
+    assert after["prefix_tokens_matched"] - mid["prefix_tokens_matched"] == 70
+    assert after["prefix_tokens_reused"] - mid["prefix_tokens_reused"] == 64
+    assert _nemotron_gap(nemotron, again, hit) <= GAP
+    for seed in (65, 66, 67, 68, 69):  # more prompts than slots: every slot is reused
+        p = _prompt(seed, 70)
+        (o,) = _generate(nemotron, [p], n=3)
+        assert _nemotron_gap(nemotron, p, o) <= GAP
+    end = nemotron.stats.snapshot()
+    # K/V rows in one layer and recurrent state in the next: both sets of
+    # counters, and the scan's; half of the router's outputs are held here.
+    for phase in ("decode", "prefill"):
+        assert 0 < end[f"attn_rows_read_full_{phase}"] <= end[f"attn_rows_dense_full_{phase}"]
+    assert end["attn_rows_read_state_decode"] == end["attn_rows_dense_state_decode"] > 0  # XLA's step
+    assert end["attn_rows_read_state_prefill"] == end["attn_rows_ssm_blocks_decode"] == 0
+    assert 0 < end["attn_rows_ssm_tokens_prefill"] <= 8 * end["attn_rows_ssm_blocks_prefill"]
+    assert 0 < end["moe_choices_local"] < end["moe_choices_routed"]
+    steps = end["moe_expert_layer_steps_decode"]
+    assert 0 < steps < end["moe_expert_layer_steps"] and steps % 2 == 0  # two expert layers
+    assert 0 < end["moe_experts_touched_decode"] <= end["moe_choices_local_decode"] < end["moe_choices_local"]
+
+
+def test_nemotron_metrics_are_exported(nemotron):
+    from generativeaiexamples_tpu.engine.server import create_engine_app
+    from generativeaiexamples_tpu.engine.tokenizer import ByteTokenizer
+
+    (o,) = _generate(nemotron, [_prompt(70, 40)], n=3)
+    app = create_engine_app(nemotron, ByteTokenizer(), model_name="nemotron_h-tiny")
+    loop = asyncio.new_event_loop()
+    client = TestClient(TestServer(app), loop=loop)
+    loop.run_until_complete(client.start_server())
+
+    async def go():
+        return await (await client.get("/metrics")).text(), await (await client.get("/health")).json()
+
+    try:
+        metrics, health = loop.run_until_complete(go())
+    finally:
+        loop.run_until_complete(client.close())
+        loop.close()
+    for name in ("engine_moe_choices_local_decode_total", "engine_moe_experts_touched_decode_total",
+                 "engine_attn_rows_ssm_tokens_prefill_total", "engine_attn_rows_ssm_blocks_prefill_total",
+                 "engine_attn_rows_read_state_decode_total", "engine_attn_rows_dense_state_decode_total",
+                 "engine_attn_rows_read_full_decode_total", "engine_state_bytes_full",
+                 "engine_state_snapshot_bytes", "engine_state_snapshots_saved_total"):
+        assert f"\n{name} " in metrics, name
+    paths = health["runtime"]["kernel_paths"]
+    assert paths["ssm_step b=4 h=8"] == "xla" and paths["ssm_scan b=1 s=32"] == "xla"
+    assert any(site.startswith("attn_full b=4 s=1 ") for site in paths), sorted(paths)
+
+
 # -- the chunks of several slots in one program ---------------------------------------
 
 
-@pytest.mark.parametrize("family", ["ling", "mellum", "zaya"])
+@pytest.mark.parametrize("family", ["ling", "mellum", "zaya", "nemotron"])
 def test_prompts_that_warm_side_by_side_are_held_to_the_reference(family, request):
     """Three chunked prompts admitted together: from their second chunk on
     a tick sends their chunks as one program (three rows padded to four,
@@ -410,7 +517,7 @@ def test_prompts_that_warm_side_by_side_are_held_to_the_reference(family, reques
     assert chunks == 4 + 4 + 3
     # Alone: each prompt's first chunk and the longest's fourth; together: two of three, one of two.
     assert after["prefill_chunk_programs"] - before["prefill_chunk_programs"] < chunks
-    gap = {"ling": _worst_gap, "mellum": _mellum_gap, "zaya": _zaya_gap}[family]
+    gap = {"ling": _worst_gap, "mellum": _mellum_gap, "zaya": _zaya_gap, "nemotron": _nemotron_gap}[family]
     for p, o in zip(prompts, outs):
         assert len(o) == 4 and gap(s, p, o) <= GAP
 
@@ -425,7 +532,7 @@ SLOTS, MAX_LEN, WINDOW, S = 6, 128, 64, 8
 ROWS = ((4, 0, 8), (1, 40, 8), (3, 19, 5))
 
 
-@pytest.fixture(scope="module", params=["ling-tiny", "mellum-tiny", "zaya-tiny", "exaone_moe-tiny"])
+@pytest.fixture(scope="module", params=["ling-tiny", "mellum-tiny", "zaya-tiny", "exaone_moe-tiny", "nemotron_h-tiny"])
 def rows_case(request):
     """A serving model, its parameters, and slots whose state is what
     ``prefill_row`` left of each row's prompt so far; every slot that is
@@ -513,7 +620,7 @@ def test_rows_of_a_group_get_what_each_gets_alone(rows_case, n_rows):
 @pytest.mark.parametrize("config, chunks", [
     ("mellum2-12b-a2.5b-l12", 4), ("ling-3.0-flash-vl-l7e128", 8),
     ("mistral-7b", 1), ("mixtral-8x7b-l4", 2), ("mistral-small-4-119b-l6e32", 8),
-    ("zaya1-8b-l20", 8),
+    ("zaya1-8b-l20", 8), ("nemotron-3-super-120b-a12b-l11e128", 8),
 ])
 def test_how_many_chunks_share_a_program_follows_from_the_rows_an_expert_sees(config, chunks):
     """256 tokens x 8 choices over 64 experts are 32 rows an expert: 4

@@ -188,7 +188,7 @@ def test_a_prefill_call_counts_no_state_and_takes_no_step(interpret):
     state = jax.eval_shape(lambda: hybrid.init_state(cfg, b, MAX_LEN))
     serving = HybridServing(cfg, None, MAX_LEN)
     assert serving.counter_names[len(moe.COUNTERS):] == (
-        "moe_experts_touched_decode", "moe_expert_layer_steps_decode",
+        "moe_experts_touched_decode", "moe_expert_layer_steps_decode", "moe_choices_local_decode",
         "attn_rows_read_state_decode", "attn_rows_dense_state_decode",
         "attn_rows_read_state_prefill", "attn_rows_dense_state_prefill",
     )
