@@ -24,6 +24,11 @@ rehearsal) a dense product over the experts held stands in.
 lie in a stack of several layers' (Mixtral's 8 of 4,096 x 14,336 under
 ``models/llama.py``'s scan): each expert's rows start at a row tile of
 their own.
+
+Both hand ``gmm`` the tiles of one rule, :func:`_tiles`, read from the
+product's shapes alone: a weight tile of megabytes that holds the whole
+of K wherever that fits, so that a product streams each expert it
+touches once (``expert_streams`` in ``COUNTERS`` counts it).
 """
 
 from __future__ import annotations
@@ -50,6 +55,11 @@ COUNTERS = (
     # Calls of ``expert_mlp``: one a layer and step, so ``experts_touched``
     # over it is the experts a layer's step streamed.
     "expert_layer_steps",
+    # Times the call's first product (gate-up, or ``relu2``'s up: the
+    # larger share of an expert's bytes) streams an expert's matrix, as
+    # the kernel's pipeline fetches it: ``_streams``.  Over
+    # ``experts_touched`` it is 1.0 where every expert streams once.
+    "expert_streams",
 )
 
 
@@ -185,25 +195,82 @@ def _grouped(xs, w, group_sizes, pallas: bool, tiling):
     return jnp.einsum("emb,me->mb", every, pick).astype(xs.dtype)
 
 
-def _tile_of(x: int, options) -> int:
-    """The first of ``options`` that divides ``x``, else 128."""
-    return next((t for t in options if x % t == 0), 128)
+# What a grid step of ``gmm`` may hold in VMEM by ``_vmem_bytes``' count,
+# of the 16 MiB that Mosaic gives a kernel unasked (megablox asks for no
+# more): on the v5e's compiler the refusals start at 14.5 MiB by that
+# count (PERF.md section 6, PR 45).
+VMEM_BUDGET_BYTES = 14 << 20
+# A weight tile that splits K stays under this: two buffers of it under
+# half of the scoped limit.
+SPLIT_TILE_BYTES = 4 << 20
+# A product walks its rows once for each column tile: a whole-K tile is
+# taken only where that is at most this many passes over the lhs.
+MAX_LHS_PASSES = 16
 
 
-def _tiling(a: int, b: int) -> tuple[int, int, int]:
-    """gmm tiles for an (m, a) x (a, b) product: whole rows of 128, the
-    widest column tiles that divide the sizes and fit VMEM.  A width that
-    is 7 or 9 times 128 (896, 2,304) divides by none of the powers of
-    two: it takes 896 or 1,152, since a tile of 128 or 256 costs one grid
-    step per 64-128 KB of weights and the steps, not the stream, then set
-    the time (the v5e: 1.8 ms for a product of 64 experts of 2,304 x
-    1,792 in 4,977 steps of (256, 256), against 0.65 ms to stream them;
-    PERF.md section 6, PR 31)."""
-    return (
-        ROW_TILE,
-        _tile_of(a, (1152, 896, 512, 256, 128)),
-        _tile_of(b, (896, 768, 512, 256, 128)),
-    )
+def _vmem_bytes(tm: int, tk: int, tn: int, itemsize: int) -> int:
+    """A grid step's blocks: two buffers each of the weight tile, the lhs
+    tile and the output tile, and the float32 accumulator."""
+    return 2 * itemsize * (tk * tn + tm * tk + tm * tn) + 4 * tm * tn
+
+
+def _divisors(x: int) -> list[int]:
+    """The multiples of 128 that divide ``x``, ascending; a size that none
+    divides takes 128 (``gmm`` masks the remainder)."""
+    return [t for t in range(128, x + 1, 128) if x % t == 0] or [128]
+
+
+def _tiles(a: int, b: int, tm: int, itemsize: int) -> tuple[int, int, int]:
+    """``gmm``'s tiles (tm, tk, tn) for an (m, a) x (a, b) product of
+    ``itemsize`` bytes an element under a row tile of ``tm``: multiples of
+    128 that divide the sizes, a weight tile of megabytes.
+
+    ``gmm``'s grid is (column tiles, visits of a (row tile, group) pair,
+    k tiles), and its pipeline fetches a block only when the block's
+    index changes.  With one k tile the visits of a group that spans
+    several row tiles ask for the same weight block, so the expert
+    streams once, and the lhs tile is fetched once a row tile and not
+    once a visit; with two or more, every visit streams the expert again
+    and every grid step its lhs tile (Mellum's chunk programs: 1.2-1.7
+    streams an expert with tk = a / 2; Mistral-Small-4's 47 visits of 32
+    experts).  So:
+
+    * ``tk = a`` with the widest ``tn`` whose blocks fit
+      ``VMEM_BUDGET_BYTES``, the whole matrix where that fits (one pass
+      over the rows), unless the rows would be walked more than
+      ``MAX_LHS_PASSES`` times (Mixtral's 4,096 x 14,336: 28 passes over
+      rows of 4,096 cost more than its tile-aligned groups save);
+    * else the largest weight tile under ``SPLIT_TILE_BYTES`` that fits,
+      the one nearest to square of several: (1024, 1792) and
+      (1792, 1024) for Mixtral's products, PR 37's tiles (3.7 MB: 1.99 ->
+      1.59 ms a product against tiles of 0.9 MB).
+
+    Tiles of 0.26-0.9 MB cost a grid step per tile and the lhs tile anew
+    at every step: K-EXAONE's gate-up product 1.43 -> 1.11 ms a call,
+    Ling's down 1.27 -> 0.73, Mistral-Small-4's gate-up 2.82 -> 1.90 on
+    the v5e (PERF.md section 6, PR 45: every candidate beside these)."""
+    ks, ns = _divisors(a), _divisors(b)
+    if ks[-1] >= a:  # a tile can hold the whole of K
+        wide = [tn for tn in ns if _vmem_bytes(tm, ks[-1], tn, itemsize) <= VMEM_BUDGET_BYTES]
+        if wide and -(-b // wide[-1]) <= MAX_LHS_PASSES:
+            return tm, ks[-1], wide[-1]
+    fits = [
+        (tk, tn) for tk in ks for tn in ns
+        if tk * tn * itemsize < SPLIT_TILE_BYTES
+        and _vmem_bytes(tm, tk, tn, itemsize) <= VMEM_BUDGET_BYTES
+    ]
+    tk, tn = max(fits, key=lambda t: (t[0] * t[1], -max(t) / min(t)))
+    return tm, tk, tn
+
+
+def _streams(starts, tiling: tuple[int, int, int], a: int):
+    """Times a product with ``tiling`` streams an expert's matrix, summed
+    over the groups that begin at ``starts`` (held + 1,): one k tile, once
+    each group that has a row; else once each row tile its rows touch."""
+    tm, tk, _ = tiling
+    begin, end = starts[:-1], starts[1:]
+    tiles = 1 if tk >= a else -(-end // tm) - begin // tm
+    return jnp.where(end > begin, tiles, 0).sum()
 
 
 # An expert's form: SwiGLU (``w_gu_e``, gate and up side by side), or
@@ -237,14 +304,16 @@ def expert_mlp(x, idx, weights, valid, lp, *, offset: int, held: int, mesh=None,
         sizes = starts[1:] - starts[:-1]  # rows of each expert held
         n_local = starts[held]
         xs = x[jnp.minimum(order // k, n - 1)]
+        first = _tiles(d, f if act == "relu2" else 2 * f, ROW_TILE, x.dtype.itemsize)
+        streams = _streams(starts, first, d)
     with jax.named_scope("layer/moe/experts"):
         if act == "relu2":
-            h = _grouped(xs, lp["w_up_e"], sizes, pallas, _tiling(d, f))
+            h = _grouped(xs, lp["w_up_e"], sizes, pallas, first)
             mid = jnp.square(jax.nn.relu(h.astype(F32))).astype(x.dtype)
         else:
-            h = _grouped(xs, lp["w_gu_e"], sizes, pallas, _tiling(d, 2 * f))
+            h = _grouped(xs, lp["w_gu_e"], sizes, pallas, first)
             mid = (jax.nn.silu(h[:, :f].astype(F32)) * h[:, f:].astype(F32)).astype(x.dtype)
-        ys = _grouped(mid, lp["w_down_e"], sizes, pallas, _tiling(f, d))
+        ys = _grouped(mid, lp["w_down_e"], sizes, pallas, _tiles(f, d, ROW_TILE, x.dtype.itemsize))
     with jax.named_scope("layer/moe/combine"):
         # Back to (token, choice) order by a gather.  Rows past the local
         # choices were never computed: zero, not whatever the buffer held.
@@ -253,7 +322,7 @@ def expert_mlp(x, idx, weights, valid, lp, *, offset: int, held: int, mesh=None,
         w_local = jnp.where(local, weights, 0.0)
         y = jnp.where(local[..., None], per_choice * w_local[..., None], 0.0).sum(1)
     counters = jnp.stack(
-        [valid.sum().astype(jnp.int32) * k, n_local, (sizes > 0).sum(), sizes.max(), 1]
+        [valid.sum().astype(jnp.int32) * k, n_local, (sizes > 0).sum(), sizes.max(), 1, streams]
     ).astype(jnp.int32)
     return y.astype(x.dtype), counters
 
@@ -268,20 +337,6 @@ def _row_tile(choices: int, n_experts: int) -> int:
     of Mixtral's take 4.5 / 5.0 / 9.4 ms at 128 / 256 / 512 for one chunk,
     7.6 / 5.1 / 9.6 for two)."""
     return min(2, max(1, -(-2 * choices // (n_experts * ROW_TILE)))) * ROW_TILE
-
-
-def _wide_tiling(a: int, b: int, tm: int) -> tuple[int, int, int]:
-    """gmm tiles for a few experts of thousands of columns: a grid step's
-    weight tile is 3.7 MB (two of them, the row tile and the accumulator
-    are 12 MB of VMEM at 256 rows), not ``_tiling``'s 0.9 MB, so the
-    steps' fixed cost is a smaller share of the stream: Mixtral's gate
-    product 1.99 -> 1.59 ms, its down product 2.07 -> 1.67 (PERF.md
-    section 6, PR 37)."""
-    return (
-        tm,
-        _tile_of(a, (1792, 1024, 512, 256, 128)),
-        _tile_of(b, (1792, 1024, 896, 512, 256, 128)),
-    )
 
 
 def stacked_expert_mlp(x, idx, weights, valid, stack, *, first, n_experts: int, mesh=None):
@@ -328,10 +383,11 @@ def stacked_expert_mlp(x, idx, weights, valid, stack, *, first, n_experts: int, 
             jnp.zeros((G,), jnp.int32), sizes, (first,)
         )
     with jax.named_scope("layer/moe/experts"):
-        gate = _grouped(xs, stack["w_gate_e"], group_sizes, pallas, _wide_tiling(d, f, tm))
-        up = _grouped(xs, stack["w_up_e"], group_sizes, pallas, _wide_tiling(d, f, tm))
+        wide = _tiles(d, f, tm, x.dtype.itemsize)
+        gate = _grouped(xs, stack["w_gate_e"], group_sizes, pallas, wide)
+        up = _grouped(xs, stack["w_up_e"], group_sizes, pallas, wide)
         act = (jax.nn.silu(gate.astype(F32)) * up.astype(F32)).astype(x.dtype)
-        ys = _grouped(act, stack["w_down_e"], group_sizes, pallas, _wide_tiling(f, d, tm))
+        ys = _grouped(act, stack["w_down_e"], group_sizes, pallas, _tiles(f, d, tm, x.dtype.itemsize))
     with jax.named_scope("layer/moe/combine"):
         # Back to (token, choice) order by a gather.  A row past the tiles
         # that hold a choice was never computed: zero, not what the buffer held.

@@ -189,12 +189,16 @@ def test_w8a8_kernel_compiles_where_the_gate_admits(one_chip, name, m):
     )
 
 
-# (hidden, expert width, router outputs, experts held, group limit, score,
-# the tiles ``ops.moe._tiling`` must pick for gate-up and for down)
+# (hidden, expert width, router outputs, experts held, choices a token,
+# group limit, score, the expert's form, the tiles ``ops.moe._tiles`` must
+# pick for the first product (gate-up, or ``relu2``'s up) and for down)
 EXPERT_WIDTHS = {
-    "ling": (2560, 768, 512, 128, (8, 4), "sigmoid", (128, 512, 768), (128, 256, 512)),
-    "mellum": (2304, 896, 64, 64, (1, 1), "softmax", (128, 1152, 896), (128, 896, 768)),
-    "exaone": (6144, 2048, 128, 16, (1, 1), "sigmoid", (128, 512, 512), (128, 512, 768)),
+    "ling": (2560, 768, 512, 128, 8, (8, 4), "sigmoid", "swiglu", (128, 2560, 768), (128, 768, 2560)),
+    "mellum": (2304, 896, 64, 64, 8, (1, 1), "softmax", "swiglu", (128, 2304, 896), (128, 896, 2304)),
+    "exaone": (6144, 2048, 128, 16, 8, (1, 1), "sigmoid", "swiglu", (128, 6144, 256), (128, 2048, 1024)),
+    "zaya": (2048, 2048, 16, 16, 1, (1, 1), "softmax", "swiglu", (128, 2048, 1024), (128, 2048, 1024)),
+    "mistral4": (4096, 2048, 128, 32, 4, (1, 1), "softmax", "swiglu", (128, 4096, 512), (128, 2048, 1024)),
+    "nemotron": (1024, 2688, 512, 128, 22, (1, 1), "sigmoid", "relu2", (128, 1024, 2688), (128, 2688, 1024)),
 }
 
 
@@ -205,37 +209,40 @@ def test_grouped_expert_products_compile_at_ling_widths(one_chip, tokens, family
     expert families served: ling-3.0-flash-vl-l7e128 (128 experts held of
     512, hidden 2,560, expert width 768, sigmoid scores, 4 of 8 groups),
     mellum2-12b-a2.5b-l12 (all 64, hidden 2,304, width 896 = 7 x 128,
-    softmax, no groups) and k-exaone-236b-a23b-l5e16 (16 held of 128,
+    softmax, no groups), k-exaone-236b-a23b-l5e16 (16 held of 128,
     hidden 6,144, width 2,048, sigmoid scores with a bias, one group),
-    8 a token; a decode step's 32 rows and a prefill
-    chunk's 256.  The grouped products are megablox's ``gmm``; this holds
-    the tilings ``ops.moe._tiling`` picks to what Mosaic accepts, and
-    Ling's to what they were."""
+    8 a token each; zaya1-8b-l20 (all 16, 2,048 / 2,048, softmax, one a
+    token), mistral-small-4-119b-l6e32 (32 held of 128, 4,096 / 2,048,
+    4 a token) and nemotron-3-super-120b-a12b-l11e128 (128 held of 512,
+    ``relu2`` experts of 2,688 in a latent of 1,024, 22 a token); a decode
+    step's 32 rows and a prefill chunk's 256.  The grouped products are
+    megablox's ``gmm``, which asks Mosaic for no more VMEM than its
+    default: this holds the tiles ``ops.moe._tiles`` picks (whole K, a
+    weight tile of 3-5.5 MB) to what Mosaic accepts, and to what the
+    sweep on the chip chose (PERF.md section 6, PR 45)."""
     from generativeaiexamples_tpu.ops import moe
 
     # The gate asks the default backend, which is the CPU here.
     monkeypatch.setattr(moe, "platform_of", lambda mesh: "tpu")
     spec = _spec(one_chip)
-    D, F, E, held, (n_group, topk_group), score, gate_up, down = EXPERT_WIDTHS[family]
-    k = 8
-    assert moe._tiling(D, 2 * F) == gate_up and moe._tiling(F, D) == down
+    D, F, E, held, k, (n_group, topk_group), score, act, first, down = EXPERT_WIDTHS[family]
+    wide = F if act == "relu2" else 2 * F
+    assert moe._tiles(D, wide, moe.ROW_TILE, 2) == first and moe._tiles(F, D, moe.ROW_TILE, 2) == down
 
-    def layer(x, w_router, bias, w_gu_e, w_down_e, valid):
+    def layer(x, w_router, bias, w_first, w_down_e, valid):
         idx, w = moe.route(
             x, w_router, bias if score == "sigmoid" else None, k=k, n_group=n_group,
-            topk_group=topk_group, norm_topk=True, scale=2.5, score=score,
+            topk_group=topk_group, norm_topk=k > 1, scale=2.5, score=score,
         )
-        return moe.expert_mlp(
-            x, idx, w, valid, {"w_gu_e": w_gu_e, "w_down_e": w_down_e},
-            offset=0, held=held,
-        )
+        lp = {"w_up_e" if act == "relu2" else "w_gu_e": w_first, "w_down_e": w_down_e}
+        return moe.expert_mlp(x, idx, w, valid, lp, offset=0, held=held, act=act)
 
     _compile(
         layer,
         spec((tokens, D), jnp.bfloat16),
         spec((D, E), jnp.bfloat16),
         spec((E,), jnp.float32),
-        spec((held, D, 2 * F), jnp.bfloat16),
+        spec((held, D, wide), jnp.bfloat16),
         spec((held, F, D), jnp.bfloat16),
         spec((tokens,), jnp.bool_),
     )

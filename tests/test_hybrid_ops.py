@@ -189,7 +189,8 @@ def test_sorted_dispatch_matches_every_expert_computed_whole(kernel, monkeypatch
                 rows[e - offset] += 1
     np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-5)
     assert not np.asarray(y)[40:].any()  # a padded position routes nowhere
-    assert counters.tolist() == [40 * 4, rows.sum(), (rows > 0).sum(), rows.max(), 1]  # one call
+    # one call; under a row tile of 128 rows and whole K, a stream an expert with a row
+    assert counters.tolist() == [40 * 4, rows.sum(), (rows > 0).sum(), rows.max(), 1, (rows > 0).sum()]
 
 
 def test_balanced_bias_evens_the_experts_load():
