@@ -1162,6 +1162,8 @@ class EnginePool:
         "prompt_tokens_clipped",
         "prefill_tokens_dispatched",
         "prefill_tokens_padded",
+        "admits_lone",
+        "admits_batched",
         "decode_kv_tokens_read",
         "decode_kv_tokens_dense",
         # Executables the replicas' tick threads asked JAX for.

@@ -443,6 +443,11 @@ class Stats:
         # minus real tokens, padded batch rows included).
         self.prefill_tokens_dispatched = 0
         self.prefill_tokens_padded = 0
+        # Cold prompts of at most one prefill chunk, by the program their
+        # admission went out in (``Scheduler._admit_cold``): a chunk of
+        # one row of its own, or a row of a ``_prefill_some`` batch.
+        self.admits_lone = 0
+        self.admits_batched = 0
         # KV positions a contiguous decode chunk's attention reads a
         # step, counted at the dispatch: each decoding row's length in
         # whole kernel blocks, beside what a dense walk of the window
@@ -508,6 +513,8 @@ class Stats:
                 "prompt_tokens_clipped": self.prompt_tokens_clipped,
                 "prefill_tokens_dispatched": self.prefill_tokens_dispatched,
                 "prefill_tokens_padded": self.prefill_tokens_padded,
+                "admits_lone": self.admits_lone,
+                "admits_batched": self.admits_batched,
                 "decode_kv_tokens_read": self.decode_kv_tokens_read,
                 "decode_kv_tokens_dense": self.decode_kv_tokens_dense,
                 "ttft_avg_ms": (
@@ -619,7 +626,7 @@ class Scheduler:
             if admit_cap < 1:
                 raise ValueError(f"admit_cap must be >= 1, got {admit_cap}")
             if admit_cap & (admit_cap - 1):
-                # _admit_many buckets each prefill batch to the next power
+                # _admit_dispatch buckets each prefill batch to the next power
                 # of two, so a non-pow2 cap pads every saturated admission
                 # batch (e.g. cap 96 -> 128 rows) and wastes prefill FLOPs
                 # — measured as a ~10% serving-throughput regression.
@@ -789,6 +796,11 @@ class Scheduler:
         if prefill_chunk_tokens is not None and prefill_chunk_tokens <= 0:
             prefill_chunk_tokens = None
         self.prefill_chunk_tokens = prefill_chunk_tokens
+        # The smallest batch bucket of ``_prefill_some``.  With chunked
+        # prefill a batch of at most half of it goes out as chunks of one
+        # row each (``_admit_cold``), so the buckets start at 8; without,
+        # a lone admission has no other program, and they start at 4.
+        self._admit_rows_min = min(8 if prefill_chunk_tokens else 4, max_batch)
         # A model whose state cannot be cut at a token is reused only from
         # where chunked prefill saved it: at every chunk boundary, under a
         # byte budget of its own (StateSnapshots).  Without chunking there
@@ -900,12 +912,15 @@ class Scheduler:
         def _prefill_some(params, tokens, lengths, key, temp, top_p, top_k):
             """Prefill a (bucketed) batch of sequences into a fresh cache.
 
-            Batched admission: under load, per-request prefill dispatch is
-            the scheduler's throughput ceiling (each single-row prefill
-            costs nearly as much wall-clock as a many-row one — prefill is
-            MXU-bound on total tokens, and the per-call latency floor
-            dominates at b == 1), so all waiting requests prefill together
-            and then graft row-by-row into their slots.
+            Batched admission: a burst of waiting requests prefills as one
+            weight pass and then grafts row-by-row into its slots.  The
+            time follows the token positions computed, pad rows included:
+            on one v5e, mistral-7b in int8, a row of 64 / 128 / 256 tokens
+            alone takes 12 / 14 / 25 ms (the weight stream's floor, then
+            the MXU), four rows 21 / 40 / 89 and eight 41 / 79 / 177
+            (PERF.md section 6, PR 46).  So a batch pays for its padding,
+            and ``_admit_cold`` sends a tick's one to four short prompts
+            as chunks of one row each instead.
             """
             b = tokens.shape[0]
             hidden, small, aux = model.prefill_cold(params, tokens, lengths)
@@ -1434,12 +1449,41 @@ class Scheduler:
             except Exception:
                 logger.exception("on_done callback failed")
 
-    def _admit_many(
+    def _admit_cold(
         self, reqs: Sequence[Request], slot_idxs: Sequence[int]
-    ) -> None:
-        """Prefill all waiting requests in one bucketed batch, then graft
-        each row into its slot."""
-        self._admit_finalize(*self._admit_dispatch(reqs, slot_idxs))
+    ) -> list[Callable[[], None]]:
+        """Dispatch the admission of cold prompts of at most one prefill
+        chunk, the tick's or the idle path's, without blocking; returns
+        their finalizers in the device's order.
+
+        Prefill time follows the token positions computed, pad rows
+        included (``_prefill_some``), so a few prompts are cheaper alone:
+        where chunked prefill is on, a batch of at most half the smallest
+        batch bucket goes out as a long prompt's chunks do, each a first
+        chunk that is also the last (``_advance_warm``: a program of one
+        row at the prompt's own bucket).  A burst stays one weight pass
+        through ``_admit_dispatch``.  A slot admitted alone joins decode a
+        tick later than a batch's row, whose first token the graft lands
+        on the device (``_carried``)."""
+        alone = bool(
+            self.prefill_chunk_tokens
+            and 2 * len(reqs) <= self._admit_rows_min
+        )
+        with self.stats.lock:
+            if alone:
+                self.stats.admits_lone += len(reqs)
+            else:
+                self.stats.admits_batched += len(reqs)
+        if not alone:
+            t = self._admit_dispatch(reqs, slot_idxs)
+            return [lambda: self._admit_finalize(*t)]
+        fins = []
+        for req, slot_idx in zip(reqs, slot_idxs):
+            self._claim_warm_cold(req, slot_idx)
+            fin, _ = self._advance_warm(slot_idx)
+            if fin is not None:  # None: cancelled since it was polled
+                fins.append(fin)
+        return fins
 
     def _admit_dispatch(
         self, reqs: Sequence[Request], slot_idxs: Sequence[int]
@@ -1462,7 +1506,7 @@ class Scheduler:
             self._clip_prompt(req)
             plens.append(len(req.token_ids))
         self._note_claim(reqs)
-        pb = bucket_size(len(reqs), minimum=min(4, self.max_batch))
+        pb = bucket_size(len(reqs), minimum=self._admit_rows_min)
         s = min(bucket_size(max(plens), dense=True), self.max_len)
         with self.stats.lock:
             self.stats.prefill_tokens_dispatched += sum(plens)
@@ -2287,7 +2331,7 @@ class Scheduler:
     # the largest prefill activation transient.  64 rows keeps admission
     # prefill near its MXU-efficient regime under saturation (smaller
     # batches pay the per-dispatch floor once per handful of requests).
-    # Must be a power of two: _admit_many buckets the batch to the next
+    # Must be a power of two: _admit_dispatch buckets the batch to the next
     # power of two, so a 96-cap pads 65-96 requests to 128 rows and
     # wastes a third of the prefill FLOPs (measured as a ~10% serving
     # throughput regression).
@@ -2494,8 +2538,7 @@ class Scheduler:
                 break
             batch_reqs = [r for r, _ in batch]
             batch_slots = [i for _, i in batch]
-            t = self._admit_dispatch(batch_reqs, batch_slots)
-            admits.append(lambda t=t: self._admit_finalize(*t))
+            admits.extend(self._admit_cold(batch_reqs, batch_slots))
             budget -= batch_tokens
             progressed = True
 
@@ -2575,7 +2618,7 @@ class Scheduler:
     def _admit_request_now(self, req: Request) -> bool:
         """Idle-path admission: route one request through the same
         decision tree as the busy tick (session hit, shared-prefix graft,
-        chunked warm claim, cold batch-of-one), finalizing synchronously.
+        chunked warm claim, ``_admit_cold`` of one), finalizing synchronously.
         Returns False when no slot could be claimed."""
         self._clip_prompt(req)
         parked, common = self._find_parked(req)
@@ -2614,7 +2657,8 @@ class Scheduler:
             if fin is not None:
                 fin()
             return True
-        self._admit_many([req], [free[0]])
+        for fin in self._admit_cold([req], [free[0]]):
+            fin()
         return True
 
     def _goes_ahead(self) -> bool:

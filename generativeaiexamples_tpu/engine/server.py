@@ -785,6 +785,8 @@ async def handle_metrics(request: web.Request) -> web.Response:
             "d",
         ),
         ("engine_prefill_tokens_padded_total", "prefill_tokens_padded", "d"),
+        ("engine_admits_lone_total", "admits_lone", "d"),
+        ("engine_admits_batched_total", "admits_batched", "d"),
         ("engine_decode_kv_tokens_read_total", "decode_kv_tokens_read", "d"),
         ("engine_decode_kv_tokens_dense_total", "decode_kv_tokens_dense", "d"),
         ("engine_prefix_tokens_matched_total", "prefix_tokens_matched", "d"),

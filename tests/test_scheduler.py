@@ -283,11 +283,12 @@ class TestAdmissionControl:
         # second batch).
         events: list = []
         # Patch the dispatch layer: both the pipelined tick and the
-        # synchronous idle path funnel through _admit_dispatch; tick
+        # synchronous idle path funnel through _admit_cold (which sends
+        # these batches of two as chunks of one row each); tick
         # boundaries (the budget's scope) come from patching _tick.
-        orig_admit = sched._admit_dispatch
+        orig_admit = sched._admit_cold
         orig_tick = sched._tick
-        sched._admit_dispatch = lambda reqs, slots: (
+        sched._admit_cold = lambda reqs, slots: (
             events.append(sum(len(r.token_ids) for r in reqs)),
             orig_admit(reqs, slots),
         )[1]
@@ -1059,11 +1060,13 @@ class TestChunkedPrefill:
             sched.cancel("runner")
             runner_done.get(timeout=60)
             sched.stop()
-        assert sched.stats.snapshot()["prefill_chunks"] == 5
+        # The runner's admission, alone, is a chunk too: the first.
+        assert sched.stats.snapshot()["prefill_chunks"] == 1 + 5
         chunk_idx = [i for i, e in enumerate(events) if e == "chunk"]
-        assert len(chunk_idx) == 5
-        for a, b in zip(chunk_idx, chunk_idx[1:]):
-            # The runner decodes between every pair of prefill chunks.
+        assert len(chunk_idx) == 1 + 5
+        for a, b in zip(chunk_idx[1:], chunk_idx[2:]):
+            # The runner decodes between every pair of the long prompt's
+            # prefill chunks.
             assert "decode" in events[a + 1 : b], events[a : b + 1]
 
 

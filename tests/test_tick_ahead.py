@@ -205,7 +205,8 @@ def test_next_chunks_go_out_behind_the_decode_chunk_and_before_its_fetch(fresh):
         assert before == ([("chunk", s, 0) for s in slots] if k == 0 else [])
         assert not [e for e in ev[fet + 1 :] if e[0] == "chunk"]
     d.drain()
-    chunks = [e[2:] for e in d.events if e[1] == "chunk"]
+    # (Before t0: the runner's admission, a chunk of its own.)
+    chunks = [e[2:] for e in d.events if e[1] == "chunk" and e[0] >= t0]
     assert len(chunks) == len(set(chunks)) == sum(map(_chunks, LENGTHS))
 
 
@@ -303,13 +304,13 @@ def test_a_final_chunk_ahead_is_dropped_with_its_cancelled_request(fresh):
 
 
 @pytest.mark.parametrize("lengths, ahead_expected", [
-    ((3, CHUNK, 5), 0),          # under a chunk: batched cold admission
+    ((3, CHUNK, 5), 0),          # under a chunk, three of five slots: a cold batch
     (LENGTHS, sum(map(_chunks, LENGTHS)) - 3),  # all but each prompt's first
 ])
 def test_the_counter_counts_chunks_sent_ahead(fresh, lengths, ahead_expected):
     d = fresh
     before = d.s.stats.snapshot()
-    d.start_runner()
+    d.start_runner()  # admitted alone: a chunk that is first and last
     # Prompts no other test sends: their state snapshots are new.
     subs = [d.submit(_prompt(20 + i, n), 3, f"c{i}") for i, n in enumerate(lengths)]
     for _ in range(10):
@@ -319,11 +320,14 @@ def test_the_counter_counts_chunks_sent_ahead(fresh, lengths, ahead_expected):
     chunks = after["prefill_chunks"] - before["prefill_chunks"]
     sent_ahead = after["prefill_chunks_ahead"] - before["prefill_chunks_ahead"]
     assert sent_ahead == ahead_expected <= chunks
-    assert chunks == sum(_chunks(n) for n in lengths if n > CHUNK)
+    assert chunks == 1 + sum(_chunks(n) for n in lengths if n > CHUNK)
+    lone = after["admits_lone"] - before["admits_lone"]
+    batched = after["admits_batched"] - before["admits_batched"]
+    assert (lone, batched) == ((1, 3) if max(lengths) <= CHUNK else (1, 0))
     # Counted once each, sent ahead or not: the real tokens (the runner's
     # two among them) and a state snapshot at every whole chunk's end.
     assert after["prefill_tokens_dispatched"] - before["prefill_tokens_dispatched"] == sum(lengths) + 2
-    if d.s._snapshots is not None and chunks:
+    if d.s._snapshots is not None and ahead_expected:
         saved = after["state_snapshots_saved"] - before["state_snapshots_saved"]
         assert saved == sum(n // CHUNK for n in lengths)
 
@@ -372,7 +376,7 @@ def test_one_ticks_chunks_share_a_program_where_a_chunk_is_a_weight_stream(fresh
     assert programs == 3 + 3 + 1
     assert [g[:3] for g in d.groups if g[1] > 1] == [(t0 + k, 3, 4) for k in range(3)]
     # Alone too a chunk is a program of the family, of one row.
-    assert [g[1:3] for g in d.groups if g[1] == 1] == [(1, 1)] * 4
+    assert [g[1:3] for g in d.groups if g[1] == 1 and g[0] >= t0] == [(1, 1)] * 4
     assert [r["prefill_chunk_programs"] for r in records[:4]] == [3 + 1, 1, 1, 1]
     assert [r["prefill_chunks"] for r in records[:4]] == [3 + 3, 3, 3, 1]
 
@@ -582,28 +586,47 @@ def test_a_row_that_stops_inside_n_has_n_plus_1_dropped(full, how):
         d.s.cancel(f"o{i}")
     d.drain()
     assert heir_done == ["length"] and heir == d.alone(heir_prompt, 9)
-    assert [e[2] for e in d.events if e[1] == "first"] == []  # cold batches all
+    # The four were a cold batch; the heir took the stopper's slot alone,
+    # as its replay took a slot: chunks of one row, fetched as such.
+    firsts = [e[2] for e in d.events if e[1] == "first"]
+    assert len(firsts) == 2 and firsts[0] == slot
 
 
-def test_a_cold_admission_decodes_in_the_chunk_dispatched_behind_it(full):
-    """Its first token reaches that chunk on the device: the second
-    token follows one chunk after the first, as with a free slot."""
+@pytest.mark.parametrize("late_n", [3, 1])
+def test_a_cold_admission_decodes_in_the_chunk_dispatched_behind_it(full, late_n):
+    """A batch's first tokens reach that chunk on the device: the second
+    token follows one chunk after the first, as with a free slot.  (Three
+    of four slots: ``_prefill_some``.)  One prompt goes alone, as a chunk
+    of one row whose token the host fetches: it joins the chunk after."""
     d = full
-    _, outs, dones = _fill(d, tokens=(40, 6, 40, 40))
-    late, late_done = d.submit(_prompt(81, 5), 20, "late")
-    row = d.slot_of("h1")
-    while not dones[1]:
+    tokens = (40,) + (6,) * late_n + (40,) * (3 - late_n)
+    _, outs, dones = _fill(d, tokens=tokens)
+    before = d.s.stats.snapshot()
+    lates = [d.submit(_prompt(81 + i, 5), 20, f"late{i}") for i in range(late_n)]
+    rows = {d.slot_of(f"h{i}") for i in range(1, 1 + late_n)}
+    while not dones[late_n]:
         d.run_tick()
-    assert late == []
-    d.run_tick()  # the freed slot is taken: prefill, graft, chunk ahead
-    assert d.slot_of("late") == row and len(late) == 1
-    assert row in [e for e in d.events if e[0] == d.tick and e[1] == "decode"][-1][2]
+    assert all(out == [] for out, _ in lates)
+    d.run_tick()  # the freed slots are taken: prefill, graft, chunk ahead
+    assert {d.slot_of(f"late{i}") for i in range(late_n)} == rows
+    assert all(len(out) == 1 for out, _ in lates)
+    after = d.s.stats.snapshot()
+    lone, batched = (after[k] - before[k] for k in ("admits_lone", "admits_batched"))
+    behind = set([e for e in d.events if e[0] == d.tick and e[1] == "decode"][-1][2])
+    if late_n == 1:
+        assert (lone, batched) == (1, 0) and not rows & behind
+        d.run_tick()  # its row is in the chunk this tick sends ahead
+        assert rows <= set([e for e in d.events if e[0] == d.tick and e[1] == "decode"][-1][2])
+        assert len(lates[0][0]) == 1
+    else:
+        assert (lone, batched) == (0, 3) and rows <= behind
     d.run_tick()
-    assert len(late) == 1 + STEPS
-    for i in (0, 2, 3):
+    assert all(len(out) == 1 + STEPS for out, _ in lates)
+    for i in range(4):
         d.s.cancel(f"h{i}")
     d.drain()
-    assert late_done == ["length"] and late == d.alone(_prompt(81, 5), 20)
+    for i, (out, done) in enumerate(lates):
+        assert done == ["length"] and out == d.alone(_prompt(81 + i, 5), 20)
 
 
 def test_a_tick_that_raises_with_a_chunk_in_flight_recovers(full):
