@@ -3,7 +3,7 @@
 
     python chip_smoke.py [--seed N]          # one chip (what the driver runs)
     python chip_smoke.py --four-chips        # the two multi-chip paths only
-    python chip_smoke.py --hybrid [--model ling|mellum|exaone_moe|mistral4|zaya|nemotron_h] [--control NAME]   # phase 5 only
+    python chip_smoke.py --hybrid [--model ling|mellum|exaone_moe|mistral4|zaya|nemotron_h|dots3_note] [--control NAME]   # phase 5 only
 
 Drives the system through the entry points a user calls, at llama3-8b's
 published widths with random int8 weights from ``--seed``:
@@ -67,7 +67,16 @@ published widths with random int8 weights from ``--seed``:
    (the nearest precision below in every projection of a layer: the
    experts' products alone, the other families' ``w8a8_mlp``, read inside
    the sound runs' spread here, since an expert's output enters the stream
-   times its probability).
+   times its probability).  ``--model dots3_note``: a prompt of 4,608
+   tokens (past twice ``index_topk``) in chunks of 256 through the chunk
+   program, its last 16 positions through the decode step, by the
+   benchmark's own comparison (``benchmarks/arch/dots3_note.py``: the
+   logit shares and ``index_overlap``, the share of the rows the program's
+   indexer keeps that the reference keeps); controls, each the reference
+   without one mechanism: ``no_selection`` (every row attended),
+   ``last_2048`` (the newest rows for the highest scores),
+   ``no_index_relu``, ``no_rescale``, ``no_gate``, ``full_sizes_in_window``
+   (a sliding layer given the full layers' theta) and ``w8a8_mlp``.
 
 The parent imports no JAX: the chip belongs to one process at a time, so
 each phase is a child (or the pair engine + chain server) that has
@@ -160,6 +169,12 @@ class Sizes:
     nemotron_prompt: int = 800
     nemotron_chunk: int = 256
     nemotron_decode: int = 16
+    # ``--model dots3_note``: a prompt past twice ``index_topk`` (the
+    # selection keeps under half of the rows at its end).
+    dots3_model: str = "dots3-note-prev-l6e32"
+    dots3_prompt: int = 4608
+    dots3_chunk: int = 256
+    dots3_decode: int = 16
     kv_heads: int = 8  # the preset's own; the tiny rehearsal needs 4 to split
     start_timeout_s: float = 600.0
     request_timeout_s: float = 600.0
@@ -203,6 +218,10 @@ TINY = Sizes(
     nemotron_prompt=45,
     nemotron_chunk=16,
     nemotron_decode=8,
+    dots3_model="dots3_note-tiny",
+    dots3_prompt=75,
+    dots3_chunk=16,
+    dots3_decode=8,
     kv_heads=4,
     start_timeout_s=240.0,
     request_timeout_s=240.0,
@@ -1173,6 +1192,14 @@ HYBRID_CONTROLS = {
         "w8a8_mlp", "state_bf16", "no_conv", "no_d_skip", "norm_whole", "gate_after_norm",
         "relu_not_relu2", "no_routed_scale", "rope_on",
     ),
+    # ``window_512`` (one row of the window layers' 513 fewer) is no control
+    # on the chip: at seeded weights it reads 0.0092 / 0.0097 / 0.0106 /
+    # 0.0095 beside a sound 0.0090 / 0.0094 / 0.0102 / 0.0092 (my chip call
+    # 2, PR 47), so tests/test_dots3_note_model.py holds it in float32.
+    "dots3_note": (
+        "w8a8_mlp", "no_selection", "last_2048", "no_index_relu", "no_rescale", "no_gate",
+        "full_sizes_in_window",
+    ),
 }
 # ``--model exaone_moe`` is held to the limits of its benchmark
 # configuration (``reference.logit_share_limits``; PERF.md section 6,
@@ -1188,6 +1215,9 @@ ZAYA_CONFIG = "benchmarks/configs/zaya1-8b-l20.json"
 # ``--model nemotron_h`` likewise (``benchmarks/arch/nemotron_h.py``;
 # PERF.md section 6, PR 44).
 NEMOTRON_CONFIG = "benchmarks/configs/nemotron-3-super-120b-a12b-l11e128.json"
+# ``--model dots3_note`` likewise (``benchmarks/arch/dots3_note.py``;
+# PERF.md section 6, PR 47).
+DOTS3_CONFIG = "benchmarks/configs/dots3-note-prev-l6e32.json"
 
 
 def hybrid_limits(model: str) -> dict:
@@ -1418,7 +1448,8 @@ def _child_by_benchmark(
     t0 = time.monotonic()
     arch = _bench_arch(family)
     with open(os.path.join(ROOT, config)) as f:
-        limits = json.load(f)["reference"]["logit_share_limits"]
+        reference_block = json.load(f)["reference"]
+    limits = reference_block["logit_share_limits"]
     arch._CHECK.update(limits=limits, decode=decode, chunk=chunk)
     cfg = hybrid.PRESETS[preset]()
     params = serving_model(cfg, None, prompt).prepare_params(
@@ -1432,7 +1463,7 @@ def _child_by_benchmark(
     if patched:
         jax.clear_caches()  # a layer traced before this would keep the plain one
     try:
-        share, _ = arch.logit_shares(params, cfg, tokens, prompt)
+        share, _, *more = arch.logit_shares(params, cfg, tokens, prompt)
     finally:
         for name, fn in plain.items():  # a caller in this process gets the plain ones back
             setattr(reference, name, fn)
@@ -1440,6 +1471,11 @@ def _child_by_benchmark(
             jax.clear_caches()
     readings = arch.share_quantiles(share, decode)
     failed = {k: v for k, v in readings.items() if not v <= limits[k]}
+    if more:  # a family whose comparison reads its indexer's selected sets too
+        readings["index_overlap"] = more[0]
+        limits = {**limits, "index_overlap_floor": reference_block["index_overlap_floor"]}
+        if not more[0] >= limits["index_overlap_floor"]:
+            failed["index_overlap"] = more[0]
     report = runtime_report()
     emit(
         {
@@ -1537,7 +1573,38 @@ def child_nemotron_h(seed: int, sizes: Sizes, control: str = "") -> None:
     )
 
 
+def child_dots3_note(seed: int, sizes: Sizes, control: str = "") -> None:
+    """``--hybrid --model dots3_note``: a control changes what the
+    reference computes: every row a query sees attended (``no_selection``),
+    the newest ``index_topk`` rows for the highest scores (``last_2048``),
+    the index scores without their ``relu``, the normed latents not
+    rescaled, no gate on the heads' outputs, a sliding layer rotated with
+    the full layers' theta, the MLP products in the nearest precision
+    below."""
+    import jax.numpy as jnp
+
+    def newest(scores, seen, topk):
+        return seen & (jnp.cumsum(seen[:, ::-1], axis=-1)[:, ::-1] <= topk)
+
+    patches = lambda ref: {
+        "w8a8_mlp": {"_swiglu": _w8a8_swiglu()},
+        "no_selection": {"_select": lambda scores, seen, topk: seen},
+        "last_2048": {"_select": newest},
+        "no_index_relu": {"_index_act": lambda dots: dots},
+        "no_rescale": {"_rescale": lambda c, d_model, rank: c},
+        "no_gate": {"_gate": lambda o, h, w_gate: o},
+        "full_sizes_in_window": {"_window_theta": lambda dims: dims["theta"]},
+    }
+    _child_by_benchmark(
+        seed, control, family="dots3_note", config=DOTS3_CONFIG, preset=sizes.dots3_model,
+        prompt=sizes.dots3_prompt, chunk=sizes.dots3_chunk, decode=sizes.dots3_decode,
+        patches=patches, sites=("moe_experts", "index_scores", "attn_latent"),
+    )
+
+
 def child_hybrid(seed: int, sizes: Sizes, control: str = "", model: str = "ling") -> None:
+    if model == "dots3_note":
+        return child_dots3_note(seed, sizes, control)
     if model == "zaya":
         return child_zaya(seed, sizes, control)
     if model == "nemotron_h":

@@ -274,10 +274,11 @@ class HybridServing:
     """``models/hybrid.py`` behind the same calls.  The state is a tuple
     of one dict a layer (``hybrid.init_state``), slot axis first.  Its
     leaves are of two sorts (``hybrid.ROW_LEAVES``): rows, one a position
-    (latent rows, a full layer's K/V), which a graft copies up to any
-    token; and state as of the last token (a KDA layer's ``S`` and
-    ``conv``, a window layer's ring, a ``cca`` layer's tails), which a
-    prefix hit takes from a snapshot saved at a prefill-chunk boundary.
+    (latent rows, an indexer's keys, a full layer's K/V), which a graft
+    copies up to any token; and state as of the last token (a KDA layer's
+    ``S`` and ``conv``, a window layer's ring of K/V or of latent rows, a
+    ``cca`` layer's tails), which a prefix hit takes from a snapshot saved
+    at a prefill-chunk boundary.
     The sort is a leaf's, not a layer's: a ``cca`` layer has both, its
     ``k`` and ``v`` rows grafted and its tails (5 KB a layer) restored.  A
     model whose every leaf is of the first sort (``cfg.rows_only``: latent
@@ -320,13 +321,16 @@ class HybridServing:
         self.cfg, self.mesh, self.max_len = cfg, mesh, max_len
         self.draft = cfg.draft
         self.cut_anywhere = cfg.rows_only
-        # Latent rows alone, attended in blocks: the chunk programs read
-        # and write the slots' state in place (``_prefill_rows_in_place``),
-        # and one window serves them all (``chunk_windows``).  The ``full``
+        # Latent layers alone, attended in blocks: the chunk programs read
+        # and write the slots' rows in place (``_prefill_rows_in_place``),
+        # and one window serves them all (``chunk_windows``: a window layer
+        # of latent rows reads its ring whatever the window).  The ``full``
         # and ``cca`` kinds' rows are written and read in place too, over
         # the doubling windows; only latent rows attended whole (Ling's)
         # have their windows taken out and put back.
-        self.one_window = cfg.rows_only and bool(cfg.latent_block)
+        self.one_window = bool(cfg.latent_block) and all(
+            mixer in ("mla", "mla_window") for mixer, _ in cfg.layer_kinds
+        )
         self.rows_in_place = bool(cfg.latent_block) or not cfg.layers_of("mla")
         self.snapshot_bytes = cfg.snapshot_bytes(max_len)
         # ``forward``'s counters; the experts touched and the expert

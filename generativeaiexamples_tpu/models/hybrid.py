@@ -1,9 +1,9 @@
 """A decoder made of layer kinds: each layer names its mixer (``kda``,
-``mla``, ``full``, ``window``, ``cca`` or ``mamba``) and its MLP
+``mla``, ``full``, ``window``, ``cca``, ``mamba`` or ``mla_window``) and its MLP
 (``dense``, ``experts`` or ``none``: the layer is its mixer alone), owns
 the parameters of those kinds and keeps the state of its mixer's kind.
 
-Six families are defined here.  ``bailing_hybrid`` (Ling-3.0-flash and
+Seven families are defined here.  ``bailing_hybrid`` (Ling-3.0-flash and
 its -VL sibling's language model): KDA linear attention (``ops/kda.py``)
 beside a latent-attention layer every ``layer_group_size`` layers
 (``ops/mla.py``), a leading dense SwiGLU layer and then sigmoid-routed
@@ -51,6 +51,20 @@ projections in a latent of ``moe_latent_size`` and have no gate
 (``W2 relu(W1 u)^2``), beside a shared expert on the hidden state itself;
 this process may hold a share of them (``MambaConfig``).  Its prediction
 module is not served: a rejected draft would need ``S`` rolled back.
+``dots3_note`` (dots3-note-prev's language model): latent attention in
+TWO kinds in one stack, so the sizes of a latent layer are a property of
+the kind (``LatentSizes``, ``HybridConfig.latent_sizes``) and not of the
+model.  Its ``mla`` layers (128 heads over a latent of 512) attend only
+the ``index_topk`` rows a learned indexer selects (DeepSeek-V3.2's: a few
+small heads score every earlier position against ONE cached index key a
+token; ``ops/mla.py::index_scores`` and what follows it); its
+``mla_window`` layers (64 heads over a latent of 1,024, another head
+size, another rotary base) see the last ``sliding_window`` positions from
+a ring of latent rows; both gate each head's output and rescale their
+normed latents; a leading dense layer, then sigmoid-routed experts with a
+shared one, of which this process may hold a share
+(``IndexedLatentConfig``).  Its vision tower, audio encoder and prediction
+module have no key in the public config and are not served.
 A new architecture is a new layer kind here, not another flag on
 ``LlamaConfig``; ``models/llama.py`` keeps serving the configurations it
 serves.
@@ -68,7 +82,14 @@ State of a slot, by the layer's mixer:
   ``conv_kernel - 1`` inputs of the q/k/v convolution.  Fixed size; it
   exists only as of the last token it has seen.
 * ``mla``: ``latent`` (T, kv_lora_rank + rope) — rows that grow with the
-  tokens and can be cut at any length.
+  tokens and can be cut at any length; with an indexer, ``index_k``
+  (T, index_head_dim) beside them: the index key of every position, rows
+  of the same sort (written with the latent row, cut anywhere, grafted on
+  a prefix hit).
+* ``mla_window``: ``ring_latent`` (R, the kind's kv_lora_rank + rope in
+  whole lanes), ``R`` = ``sliding_window``: a ring of latent rows, position
+  ``p`` in row ``p % R``; like a GQA ring it exists only as of the last
+  token written, so a prefix hit takes it from a snapshot.
 * ``full``: ``k``, ``v`` (T, KH * head) — rows like the latent ones.
 * ``window``: ``ring_k``, ``ring_v`` (R, KH * head), ``R`` =
   ``sliding_window`` whatever the length: position ``p`` lives in row
@@ -93,13 +114,14 @@ output at the last position it has seen, which the module needs with the
 token after it; like a recurrent state it exists only as of that token.
 
 ``ROW_LEAVES`` names the leaves that hold a row a position; every other
-leaf is state as of the last token.  ``HybridConfig`` holds what every
+leaf is state as of the last token (``RING_LEAVES`` those that are rings).  ``HybridConfig`` holds what every
 family has and the KDA and MLA sizes; ``GqaConfig`` adds the GQA sizes,
 the rotary parameters of each kind and the routing options;
 ``LatentConfig`` adds what the ``mistral4`` family's latent layer has;
 ``CcaConfig`` the ``zaya`` family's sizes, its router's width and its tied
 head; ``MambaConfig`` the ``nemotron_h`` family's Mamba-2 sizes, its
-experts' latent and their activation.
+experts' latent and their activation; ``IndexedLatentConfig`` the
+``dots3_note`` family's indexer, its rescale and its window kind's sizes.
 
 What is read from a family's convention and not from a key of the
 public config is listed under ``assumed`` in
@@ -111,22 +133,28 @@ public config is listed under ``assumed`` in
 is not served: residual scaling and "MoD" have no key and no equation)
 and ``benchmarks/configs/nemotron-3-super-120b-a12b-l11e128.json`` (no
 rotation in the attention layers, the order of the Mamba projection's
-outputs, the gate before the norm; not served: the prediction module);
+outputs, the gate before the norm; not served: the prediction module)
+and ``benchmarks/configs/dots3-note-prev-l6e32.json`` (the form of the
+rescale and of the gate, the indexer's form, norm and rotation, the
+window's count; not served: the towers and the prediction module);
 the plain references are ``models/hybrid_reference.py``,
 ``models/mellum_reference.py``, ``models/exaone_moe_reference.py``,
-``models/mistral4_reference.py``, ``models/zaya_reference.py`` and
-``models/nemotron_h_reference.py``.
+``models/mistral4_reference.py``, ``models/zaya_reference.py``,
+``models/nemotron_h_reference.py`` and ``models/dots3_note_reference.py``.
 
 What a row of ``benchmarks/README.md``'s layout table would say of the
 newest family (that file is a ``benchmark`` PR's to edit):
-``benchmarks/arch/nemotron_h.py`` maps
-``configs/nemotron-3-super-120b-a12b-l11e128.json`` to ``MambaConfig``
-through :func:`from_hf_config` and holds its counts,
-``benchmarks/nemotron_h_reference.py`` is the copy of
-``models/nemotron_h_reference.py`` that decides its cell's ``correct``,
-``layer_metrics/decode_rows_per_expert.py`` and
-``layer_metrics/prefill_ssm_block_fill_pct.py`` read the counters this
-family added.
+``benchmarks/arch/dots3_note.py`` maps
+``configs/dots3-note-prev-l6e32.json`` to ``IndexedLatentConfig`` through
+:func:`from_hf_config` and holds its counts and its comparison (the logit
+shares and ``index_overlap``, read from what the chunk programs' and
+decode steps' own selections returned),
+``benchmarks/dots3_note_reference.py`` is the copy of
+``models/dots3_note_reference.py`` that decides its cell's ``correct``,
+``traffic/doc-mid.json`` and ``traffic/doc-mid-closed.json`` are its mix,
+``layer_metrics/prefill_selected_rows_pct.py`` and
+``layer_metrics/decode_index_rows_pct.py`` read the counters this family
+added.
 """
 
 from __future__ import annotations
@@ -147,7 +175,7 @@ from generativeaiexamples_tpu.ops.rope import (
 
 Params = Mapping[str, Any]
 F32 = jnp.float32
-MIXERS = ("kda", "mla", "full", "window", "cca", "mamba")
+MIXERS = ("kda", "mla", "full", "window", "cca", "mamba", "mla_window")
 # ``none``: the layer is its mixer alone (no norm, no parameters, nothing
 # added): a stack whose published layers are ONE function each reads as
 # such pairs (``_from_nemotron_h``).
@@ -157,9 +185,9 @@ MTP_KIND = ("full", "experts")
 # State leaves that hold one row a position, which can be cut at any
 # token (the others exist only as of the last token written), and those
 # that are rings of rows.  Rows run along axis 1 (slot axis 0).
-ROW_LEAVES = ("latent", "k", "v")
-RING_LEAVES = ("ring_k", "ring_v")
-GQA_LEAVES = {"full": ("k", "v"), "window": RING_LEAVES}  # a GQA mixer's K and V
+ROW_LEAVES = ("latent", "k", "v", "index_k")
+RING_LEAVES = ("ring_k", "ring_v", "ring_latent")
+GQA_LEAVES = {"full": ("k", "v"), "window": ("ring_k", "ring_v")}  # a GQA mixer's K and V
 # What a ``cca`` layer keeps beside its ``k`` and ``v`` rows, as of the
 # last token: the last inputs of its two convolution steps and the last
 # token's projection for the shifted value heads.
@@ -174,6 +202,18 @@ ATTN_COUNTERS = ("read_window", "read_full", "dense_window", "dense_full")
 # read with every row's whole window (``kv_bucket``) read.  A
 # ``LatentConfig`` model returns them after ``moe.COUNTERS``.
 LATENT_COUNTERS = ("read_latent", "dense_latent")
+# What the indexer of an ``mla`` layer did (``IndexedLatentConfig``):
+# (query, position) pairs it scored; (query, row) pairs the attention
+# scored: in a prefill call every row of every block walked (``read_latent``)
+# for every query that counts, kept or not, since the selection is a mask
+# on the walk's softmax; in a decode step the latent rows gathered (its
+# ``read_latent`` too); index keys read; and the (query, row) pairs the
+# queries that count see, which is what they would attend unselected (for
+# a decode step: the rows the decoding slots hold).
+INDEX_COUNTERS = ("index_pairs", "read_selected", "read_index", "seen_latent")
+# Ring rows the ``mla_window`` layers read, and the rows they would have
+# read as full layers: ``ATTN_COUNTERS``' names for the GQA rings.
+RING_LATENT_COUNTERS = ("read_window", "dense_window")
 # Slots whose recurrent state the KDA layers' decode steps read (the rows
 # that decode where the step is ``kda.kda_step_rows``, every slot where
 # it is XLA's), and slots x KDA layers.  A prefill call adds to neither:
@@ -185,6 +225,21 @@ STATE_COUNTERS = ("read_state", "dense_state")
 # row of the call, a group's pad rows among them).  A decode step adds to
 # neither.
 SSM_COUNTERS = ("ssm_tokens", "ssm_blocks")
+
+
+@dataclasses.dataclass(frozen=True)
+class LatentSizes:
+    """What a latent-attention layer is made of.  A property of the layer
+    KIND: a stack may hold two kinds of different head counts, ranks, head
+    sizes and rotary base (``HybridConfig.latent_sizes``)."""
+
+    n_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float
 
 
 @dataclasses.dataclass(frozen=True)
@@ -241,6 +296,11 @@ class HybridConfig:
     softmax_mscale: ClassVar[float] = 1.0
     mla_out_gate: ClassVar[bool] = True
     latent_block: ClassVar[int] = 0
+    # What ``IndexedLatentConfig`` makes fields of: no indexer, no rescale
+    # of the normed latents, no window layer of latent rows.
+    index_topk: ClassVar[int] = 0
+    latent_rescale: ClassVar[bool] = False
+    window_latent: ClassVar[LatentSizes | None] = None
     # What ``CcaConfig`` makes fields of: a linear router, an untied head.
     router_hidden: ClassVar[int] = 0
     tie_embeddings: ClassVar[bool] = False
@@ -269,6 +329,13 @@ class HybridConfig:
                 raise ValueError("a GQA layer kind needs its rotary parameters")
             if self.layers_of("window") and self.sliding_window < 1:
                 raise ValueError("a window layer needs sliding_window")
+        if self.layers_of("mla_window") and (
+            self.window_latent is None or self.sliding_window < 1
+        ):
+            raise ValueError(
+                "a window layer of latent rows needs its sizes (window_latent) "
+                "and sliding_window"
+            )
         if self.mtp_layers not in (0, 1):
             raise ValueError(
                 "more than one prediction module is not served: the decode "
@@ -309,6 +376,7 @@ class HybridConfig:
         return not (
             self.layers_of("kda") or self.layers_of("window")
             or self.layers_of("cca") or self.layers_of("mamba") or self.mtp_layers
+            or self.layers_of("mla_window")
         )
 
     @property
@@ -332,6 +400,26 @@ class HybridConfig:
     def latent_width(self) -> int:
         return self.kv_lora_rank + self.qk_rope_head_dim
 
+    def latent_sizes(self, mixer: str = "mla") -> "LatentSizes":
+        """The sizes of a latent layer of kind ``mixer``: the model's own
+        fields for ``mla``, ``window_latent`` for ``mla_window``."""
+        if mixer == "mla_window":
+            return self.window_latent
+        return LatentSizes(
+            n_heads=self.n_heads, q_lora_rank=self.q_lora_rank,
+            kv_lora_rank=self.kv_lora_rank, qk_nope_head_dim=self.qk_nope_head_dim,
+            qk_rope_head_dim=self.qk_rope_head_dim, v_head_dim=self.v_head_dim,
+            rope_theta=self.rope_theta,
+        )
+
+    def row_width(self, mixer: str = "mla") -> int:
+        """A stored latent row of kind ``mixer``: ``latent_width``, or the
+        window kind's latent and rope key in whole lanes."""
+        if mixer == "mla_window":
+            sz = self.window_latent
+            return -(-(sz.kv_lora_rank + sz.qk_rope_head_dim) // 128) * 128
+        return self.latent_width
+
     @property
     def conv_channels(self) -> int:
         return 3 * self.n_heads * self.kda_head_dim
@@ -352,8 +440,8 @@ class HybridConfig:
     def snapshot_bytes(self, max_len: int | None = None) -> int:
         """Bytes of what one slot keeps only as of its last token: the
         recurrent state of the KDA and ``mamba`` layers, the rings of the
-        window layers (of a state ``max_len`` long; absent:
-        ``max_seq_len``), the tails of the ``cca`` layers and a prediction
+        window layers, of K/V or of latent rows (of a state ``max_len``
+        long; absent: ``max_seq_len``), the tails of the ``cca`` layers and a prediction
         module's ``h_last``: every
         leaf of ``init_state`` that is no row a position."""
         shapes = jax.eval_shape(
@@ -447,6 +535,41 @@ class LatentConfig(HybridConfig):
         state in and out to get at a row (at 16 slots of 32,768 rows of
         320: 2.5 GB of temporaries and 4 GB of traffic a program)."""
         return -(-(self.kv_lora_rank + self.qk_rope_head_dim) // 128) * 128
+
+
+@dataclasses.dataclass(frozen=True)
+class IndexedLatentConfig(LatentConfig):
+    """A configuration of the ``dots3_note`` family: ``mla`` layers whose
+    queries attend only the rows a learned indexer selects, beside
+    ``mla_window`` layers, latent attention of OTHER sizes over a ring of
+    the last ``sliding_window`` latent rows."""
+
+    # The indexer of an ``mla`` layer (DeepSeek-V3.2's): ``index_n_heads``
+    # heads of ``index_head_dim`` score every earlier position from one
+    # cached key a token; the ``index_topk`` highest are attended.
+    index_n_heads: int = 64
+    index_head_dim: int = 128
+    index_topk: int = 2048
+    # Both normed latents times ``(d_model / rank)^1/2``
+    # (``apply_mla_qkv_lora_rescale``), in either kind.
+    latent_rescale: bool = True
+    # The ``mla_window`` kind: its own sizes, over the last
+    # ``sliding_window`` positions, the query's own among them.
+    sliding_window: int = 0
+    window_latent: LatentSizes | None = None
+
+    def __post_init__(self) -> None:
+        super().__post_init__()
+        if self.index_topk < 1 or self.index_n_heads < 1:
+            raise ValueError("an indexer keeps index_topk >= 1 rows from index_n_heads >= 1 heads")
+        if self.index_head_dim < self.qk_rope_head_dim:
+            raise ValueError("the indexer rotates the first qk_rope_head_dim of index_head_dim")
+        if not self.q_lora_rank:
+            raise ValueError("the index queries are taken from the query's latent (q_lora_rank)")
+
+    @property
+    def row_counters(self) -> tuple[str, ...]:
+        return LATENT_COUNTERS + INDEX_COUNTERS + RING_LATENT_COUNTERS
 
 
 @dataclasses.dataclass(frozen=True)
@@ -547,7 +670,8 @@ def from_hf_config(
     """The public ``config.json`` keys -> ``HybridConfig``, by
     ``model_type``: ``mellum`` (:func:`_from_mellum`), ``exaone_moe``
     (:func:`_from_exaone`), ``mistral4`` (:func:`_from_mistral4`), ``zaya``
-    (:func:`_from_zaya`), ``nemotron_h`` (:func:`_from_nemotron_h`), else the
+    (:func:`_from_zaya`), ``nemotron_h`` (:func:`_from_nemotron_h`),
+    ``dots3_note`` (:func:`_from_dots3_note`), else the
     ``bailing_hybrid`` family, of which the rest speaks.  ``draft`` ``mtp`` holds the model's own prediction
     module and serves it as the decode step's draft; a family without one
     refuses it.
@@ -581,6 +705,10 @@ def from_hf_config(
         )
     if model.get("model_type") == "nemotron_h":
         return _from_nemotron_h(
+            model, max_len=max_len, expert_offset=expert_offset, kv_dtype=kv_dtype
+        )
+    if model.get("model_type") == "dots3_note":
+        return _from_dots3_note(
             model, max_len=max_len, expert_offset=expert_offset, kv_dtype=kv_dtype
         )
     period = int(model["layer_group_size"])
@@ -998,6 +1126,98 @@ def _from_nemotron_h(
     )
 
 
+def _from_dots3_note(
+    model: Mapping[str, Any], *, max_len: int, expert_offset: int, kv_dtype: str
+) -> IndexedLatentConfig:
+    """``model_type: dots3_note``: ``layer_types`` names each layer's mixer
+    (``full_attention`` -> ``mla`` with the indexer, ``sliding_attention``
+    -> ``mla_window`` with the ``swa_*`` sizes over ``sliding_window_size``
+    positions); a cut in depth keeps its first ``num_hidden_layers``
+    entries.  The first ``first_k_dense_replace`` layers are dense, the
+    rest experts with a shared one: sigmoid scores with a selection bias
+    (``noaux_tc``), one group, renormalised and scaled.
+    ``n_routed_experts`` counts the experts held of
+    ``num_experts_published`` router outputs (absent: the same).  Both
+    kinds gate each head's output (``headwise``) and rescale their normed
+    latents (``apply_mla_qkv_lora_rescale``).  The vision tower, the audio
+    encoder and the prediction module have no key here and are not
+    served."""
+    n = int(model["num_hidden_layers"])
+    mixers = {"full_attention": "mla", "sliding_attention": "mla_window"}
+    layer_types = list(model["layer_types"])
+    if len(layer_types) < n:
+        raise ValueError("layer_types names fewer layers than num_hidden_layers")
+    unknown = sorted(set(layer_types[:n]) - set(mixers))
+    if unknown:
+        raise ValueError(f"layer types {unknown} are not served")
+    if model.get("scoring_func", "sigmoid") != "sigmoid" or model.get("topk_method", "noaux_tc") != "noaux_tc":
+        raise ValueError("dots3_note is served with sigmoid scores and a selection bias (noaux_tc)")
+    if int(model.get("n_group", 1)) != 1 or int(model.get("topk_group", 1)) != 1:
+        raise ValueError("routing groups are not served for dots3_note (one group)")
+    if int(model.get("moe_layer_freq", 1)) != 1:
+        raise ValueError("moe_layer_freq other than 1 is not served")
+    if model.get("attention_bias") or model.get("hidden_act", "silu") != "silu":
+        raise ValueError("attention biases and activations other than silu are not served")
+    if model.get("rope_scaling"):
+        raise ValueError("rope_scaling is not served for dots3_note (the published value is null)")
+    for key in ("attention_gate_type", "swa_attention_gate_type"):
+        if model.get(key) != "headwise":
+            raise ValueError(f"{key} {model.get(key)!r} is not served: a sigmoid gate a head")
+    if not model.get("q_lora_rank") or not model.get("swa_q_lora_rank"):
+        raise ValueError("dots3_note is served with low-rank queries (q_lora_rank, swa_q_lora_rank)")
+    if not int(model.get("index_topk", 0)):
+        raise ValueError("dots3_note's full layers attend what an indexer selects (index_topk)")
+    dense = int(model["first_k_dense_replace"])
+    held = int(model["n_routed_experts"])
+    return IndexedLatentConfig(
+        vocab_size=int(model["vocab_size"]),
+        d_model=int(model["hidden_size"]),
+        layer_kinds=tuple(
+            (mixers[kind], "dense" if j < dense else "experts")
+            for j, kind in enumerate(layer_types[:n])
+        ),
+        n_heads=int(model["num_attention_heads"]),
+        kv_lora_rank=int(model["kv_lora_rank"]),
+        qk_nope_head_dim=int(model["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(model["qk_rope_head_dim"]),
+        v_head_dim=int(model["v_head_dim"]),
+        rope_theta=float(model["rope_theta"]),
+        q_lora_rank=int(model["q_lora_rank"]),
+        mla_out_gate=True,
+        index_n_heads=int(model["index_n_heads"]),
+        index_head_dim=int(model["index_head_dim"]),
+        index_topk=int(model["index_topk"]),
+        latent_rescale=bool(model["apply_mla_qkv_lora_rescale"]),
+        sliding_window=int(model["sliding_window_size"]),
+        window_latent=LatentSizes(
+            n_heads=int(model["swa_num_attention_heads"]),
+            q_lora_rank=int(model["swa_q_lora_rank"]),
+            kv_lora_rank=int(model["swa_kv_lora_rank"]),
+            qk_nope_head_dim=int(model["swa_qk_nope_head_dim"]),
+            qk_rope_head_dim=int(model["swa_qk_rope_head_dim"]),
+            v_head_dim=int(model["swa_v_head_dim"]),
+            rope_theta=float(model["swa_rope_theta"]),
+        ),
+        d_ff=int(model["intermediate_size"]),
+        moe_d_ff=int(model["moe_intermediate_size"]),
+        shared_d_ff=int(model["moe_intermediate_size"]) * int(model["n_shared_experts"]),
+        n_experts=int(model.get("num_experts_published", held)),
+        experts_held=held,
+        expert_offset=int(expert_offset),
+        n_experts_per_tok=int(model["num_experts_per_tok"]),
+        n_group=1,
+        topk_group=1,
+        routed_scaling=float(model["routed_scaling_factor"]),
+        norm_topk=bool(model["norm_topk_prob"]),
+        score_function="sigmoid",
+        router_bias=True,
+        norm_eps=float(model["rms_norm_eps"]),
+        max_seq_len=int(max_len),
+        dtype=str(model.get("torch_dtype", "bfloat16")),
+        kv_dtype=kv_dtype,
+    )
+
+
 # -- parameters ---------------------------------------------------------------
 
 
@@ -1065,27 +1285,42 @@ def _layer_shapes(cfg: HybridConfig, mixer: str, mlp: str) -> dict:
             ssm_norm=((inner,), 1.0),
             w_out=((inner, D), inner),
         )
-    else:
-        qk = cfg.qk_nope_head_dim + cfg.qk_rope_head_dim
-        if cfg.q_lora_rank:
+    else:  # ``mla`` or ``mla_window``: a latent layer of its kind's sizes
+        sz = cfg.latent_sizes(mixer)
+        H, q_rank, rank = sz.n_heads, sz.q_lora_rank, sz.kv_lora_rank
+        qk = sz.qk_nope_head_dim + sz.qk_rope_head_dim
+        # What multiplies a RESCALED latent (root mean square (D / rank)^1/2,
+        # not 1) draws as from a fan-in of D: rank values that count as D,
+        # so that a seeded layer's scores have the spread a layer without
+        # the rescale has (a checkpoint's matrices were trained under it).
+        fan_q, fan_kv = (D, D) if cfg.latent_rescale else (q_rank, rank)
+        if q_rank:
             shapes.update(
-                w_qa=((D, cfg.q_lora_rank), D),
-                q_norm=((cfg.q_lora_rank,), 1.0),
-                w_qb=((cfg.q_lora_rank, H * qk), cfg.q_lora_rank),
+                w_qa=((D, q_rank), D),
+                q_norm=((q_rank,), 1.0),
+                w_qb=((q_rank, H * qk), fan_q),
             )
         else:
             shapes.update(w_q=((D, H * qk), D))
         shapes.update(
-            w_kva=((D, cfg.kv_lora_rank + cfg.qk_rope_head_dim), D),
-            kv_norm=((cfg.kv_lora_rank,), 1.0),
-            w_kvb=(
-                (cfg.kv_lora_rank, H * (cfg.qk_nope_head_dim + cfg.v_head_dim)),
-                cfg.kv_lora_rank,
-            ),
+            w_kva=((D, rank + sz.qk_rope_head_dim), D),
+            kv_norm=((rank,), 1.0),
+            w_kvb=((rank, H * (sz.qk_nope_head_dim + sz.v_head_dim)), fan_kv),
         )
         if cfg.mla_out_gate:
             shapes.update(w_gate=((D, H), D))
-        shapes.update(w_o=((H * cfg.v_head_dim, D), H * cfg.v_head_dim))
+        shapes.update(w_o=((H * sz.v_head_dim, D), H * sz.v_head_dim))
+        if mixer == "mla" and cfg.index_topk:
+            # The indexer: queries from the query's latent, one key a token
+            # (a LayerNorm with a bias over it), a weight a head.
+            HI, dI = cfg.index_n_heads, cfg.index_head_dim
+            shapes.update(
+                w_qi=((q_rank, HI * dI), fan_q),
+                w_ki=((D, dI), D),
+                ki_norm=((dI,), 1.0),
+                ki_norm_b=((dI,), "bias"),
+                w_wi=((D, HI), D),
+            )
     if mlp == "dense":
         shapes.update(w_gu=((D, 2 * cfg.d_ff), D), w_down=((cfg.d_ff, D), cfg.d_ff))
     elif mlp == "experts":
@@ -1291,8 +1526,14 @@ def init_state(cfg: HybridConfig, batch: int, max_len: int) -> tuple:
                     ),
                 }
             )
+        elif mixer == "mla_window":
+            ring = (batch, cfg.ring_rows(max_len), cfg.row_width(mixer))
+            out.append({"ring_latent": jnp.zeros(ring, sd)})
         else:
-            out.append({"latent": jnp.zeros((batch, max_len, cfg.latent_width), sd)})
+            layer = {"latent": jnp.zeros((batch, max_len, cfg.latent_width), sd)}
+            if cfg.index_topk:
+                layer["index_k"] = jnp.zeros((batch, max_len, cfg.index_head_dim), sd)
+            out.append(layer)
     if cfg.mtp_layers:
         shape = (batch, max_len, cfg.n_kv_heads * cfg.attn_head_dim)
         out.append({n: jnp.zeros(shape, sd) for n in GQA_LEAVES["full"]})
@@ -1302,8 +1543,8 @@ def init_state(cfg: HybridConfig, batch: int, max_len: int) -> tuple:
 
 def state_bytes(cfg: HybridConfig, batch: int, max_len: int) -> dict[str, int]:
     """Bytes of the slots' state by kind, leaf by leaf: ``full`` (rows
-    that grow with the tokens: latent, K/V), ``window`` (rings: the same at
-    any ``max_len`` over the window), ``recurrent`` (what exists only as
+    that grow with the tokens: latent, index keys, K/V), ``window`` (rings,
+    of K/V or of latent rows: the same at any ``max_len`` over the window), ``recurrent`` (what exists only as
     of the last token: a KDA or ``mamba`` layer's state and tail, a ``cca``
     layer's tails) and,
     where a prediction module is held, ``draft`` (its rows and
@@ -1397,83 +1638,231 @@ def _kda_mixer(h, lp, st, valid, n_valid, cfg: HybridConfig, mesh=None):
     return out, state, jnp.stack(read).astype(jnp.int32)
 
 
+def _rescaled(c, cfg: HybridConfig, rank: int):
+    """A normed latent times ``(d_model / rank)^1/2`` where the
+    configuration says so (``latent_rescale``)."""
+    if not cfg.latent_rescale:
+        return c
+    return (c.astype(F32) * (cfg.d_model / rank) ** 0.5).astype(c.dtype)
+
+
+def _latent_q_kv(
+    h, lp, pos, sz: LatentSizes, cfg: HybridConfig, width: int, *, q_scope: str, kv_scope: str,
+    spec: RopeSpec | None,
+):
+    """What the two latent kinds share: the queries (a low-rank pair or
+    one matrix) and the token's row as it is stored; ``spec`` the rotation
+    of the rope parts (``None``: the plain frequencies of the kind's
+    ``rope_theta``).  Returns (q_nope,
+    q_rope rotated and scaled by position, the query's latent or None,
+    the new rows (b, s, width): normed latent, rotated rope key, zero
+    columns up to ``width``)."""
+    b, s, _ = h.shape
+    H, rank = sz.n_heads, sz.kv_lora_rank
+    nope, rope = sz.qk_nope_head_dim, sz.qk_rope_head_dim
+    c_q = None
+    with jax.named_scope(q_scope):
+        if sz.q_lora_rank:
+            c_q = rms_norm(jnp.dot(h, lp["w_qa"]), lp["q_norm"], cfg.norm_eps)
+            c_q = _rescaled(c_q, cfg, sz.q_lora_rank)
+            q = jnp.dot(c_q, lp["w_qb"]).reshape(b, s, H, nope + rope)
+        else:
+            q = jnp.dot(h, lp["w_q"]).reshape(b, s, H, nope + rope)
+        q_nope = q[..., :nope]
+        q_rope = mla.rope_interleaved(q[..., nope:], pos, sz.rope_theta, spec)
+        if cfg.attn_scale_beta:
+            a = mla.position_scale(pos, cfg.attn_scale_beta, spec.original_max)
+            a = a[:, :, None, None]
+            q_nope = (q_nope.astype(F32) * a).astype(q.dtype)
+            q_rope = (q_rope.astype(F32) * a).astype(q.dtype)
+    with jax.named_scope(kv_scope):
+        ckr = jnp.dot(h, lp["w_kva"])
+        c = _rescaled(rms_norm(ckr[..., :rank], lp["kv_norm"], cfg.norm_eps), cfg, rank)
+        k_rope = mla.rope_interleaved(ckr[..., rank:], pos, sz.rope_theta, spec)
+        spare = jnp.zeros((b, s, width - rank - rope), c.dtype)  # none in Ling's rows
+        new = jnp.concatenate([c, k_rope, spare], axis=-1)
+    return q_nope, q_rope, c_q, new
+
+
+def _gated_out(o, h, lp, cfg: HybridConfig, scope: str):
+    """The heads' outputs (b, s, H, v), each times its sigmoid gate where
+    the configuration has one, through ``W_o``."""
+    b, s, H, vd = o.shape
+    with jax.named_scope(f"{scope}/wo"):
+        if cfg.mla_out_gate:
+            gate = jax.nn.sigmoid(jnp.dot(h, lp["w_gate"], preferred_element_type=F32))
+            o = (o.astype(F32) * gate[..., None]).astype(h.dtype)
+        return jnp.dot(o.reshape(b, s, H * vd), lp["w_o"])
+
+
+# The LayerNorm of an index key (DeepSeek-V3.2's public inference code).
+INDEX_NORM_EPS = 1e-6
+
+
+def _index_q_k(h, c_q, lp, pos, cfg: HybridConfig):
+    """The indexer's projections of an ``mla`` layer: (q_I (b, s, HI, d),
+    w (b, s, HI) float32, k_I (b, s, d)), the first ``qk_rope_head_dim``
+    values of every query and key rotated as the main rope part is."""
+    b, s, _ = h.shape
+    HI, dI, rope = cfg.index_n_heads, cfg.index_head_dim, cfg.qk_rope_head_dim
+
+    def rotated(x):
+        turned = mla.rope_interleaved(x[..., :rope], pos, cfg.rope_theta, cfg.rope_latent)
+        return jnp.concatenate([turned, x[..., rope:]], axis=-1)
+
+    with jax.named_scope("layer/mla/index"):
+        q_i = rotated(jnp.dot(c_q, lp["w_qi"]).reshape(b, s, HI, dI))
+        k = jnp.dot(h, lp["w_ki"]).astype(F32)
+        k = k - jnp.mean(k, axis=-1, keepdims=True)
+        k = k * jax.lax.rsqrt(jnp.mean(k * k, axis=-1, keepdims=True) + INDEX_NORM_EPS)
+        k = (k * lp["ki_norm"].astype(F32) + lp["ki_norm_b"].astype(F32)).astype(h.dtype)
+        w = jnp.dot(h, lp["w_wi"], preferred_element_type=F32) * (HI**-0.5 * dI**-0.5)
+    return q_i, w, rotated(k)
+
+
 def _mla_mixer(h, lp, st, pos, valid, n_valid, cfg: HybridConfig, window: int, apart: bool):
-    """An ``mla`` layer, Ling's form or the mistral4 family's by what the
-    configuration says (a low-rank query, YaRN on the rotary part, a query
-    scale by position, no output gate, prefill in blocks).  Returns
-    (output, state, the rows read in the order of ``LATENT_COUNTERS``).
+    """An ``mla`` layer, Ling's form, the mistral4 family's or the
+    dots3_note family's by what the configuration says (a low-rank query,
+    YaRN on the rotary part, a query scale by position, no output gate,
+    prefill in blocks; the normed latents rescaled, attention over the
+    rows an indexer selects).  Returns (output, state, the rows read by
+    name: ``LATENT_COUNTERS``, and ``INDEX_COUNTERS`` with an indexer).
 
     ``st`` may carry ``slot`` (b,) beside ``latent``: the latent rows are
     then a state of many slots, of which row ``i`` of this call is slot
     ``slot[i]`` (absent: slot ``i``); the new rows are written there and
     the block forms read a row's blocks from there, a row at a time, so
     that no window of it is ever copied (a chunk program of a model whose
-    state is rows alone: ``HybridServing.prefill_rows``)."""
+    state is rows alone: ``HybridServing.prefill_rows``).
+
+    With an indexer (``cfg.index_topk``) a slot keeps one index key a
+    position beside its latent row (``index_k``).  A prefill call scores,
+    for each row that ends up longer than ``index_topk``, every position
+    up to its length a block at a time (``mla.index_scores_blocks``),
+    keeps each query's ``index_topk`` highest (``mla.select_mask``) and
+    hands the kept pairs to the block walk, which still expands and scores
+    every block up to the row's length; a shorter row keeps every position
+    it sees and is attended as without an indexer.  A decode step scores
+    every slot's first ``window`` index keys in one product, gathers each
+    slot's ``index_topk`` highest rows (``mla.select_rows``) and attends
+    over them alone (``mla.attend_selected``); a slot that does not decode
+    is computed beside the others and its result dropped."""
     b, s, _ = h.shape
-    H, rank = cfg.n_heads, cfg.kv_lora_rank
-    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
-    spec = cfg.rope_latent
-    with jax.named_scope("layer/mla/q_lora" if cfg.q_lora_rank else "layer/mla/q"):
-        if cfg.q_lora_rank:
-            c_q = rms_norm(jnp.dot(h, lp["w_qa"]), lp["q_norm"], cfg.norm_eps)
-            q = jnp.dot(c_q, lp["w_qb"]).reshape(b, s, H, nope + rope)
-        else:
-            q = jnp.dot(h, lp["w_q"]).reshape(b, s, H, nope + rope)
-        q_nope = q[..., :nope]
-        q_rope = mla.rope_interleaved(q[..., nope:], pos, cfg.rope_theta, spec)
-        if cfg.attn_scale_beta:
-            a = mla.position_scale(pos, cfg.attn_scale_beta, spec.original_max)
-            a = a[:, :, None, None]
-            q_nope = (q_nope.astype(F32) * a).astype(q.dtype)
-            q_rope = (q_rope.astype(F32) * a).astype(q.dtype)
+    sz = cfg.latent_sizes("mla")
+    H, rank = sz.n_heads, sz.kv_lora_rank
+    nope, rope, vd = sz.qk_nope_head_dim, sz.qk_rope_head_dim, sz.v_head_dim
+    T, width = st["latent"].shape[1:]
+    q_nope, q_rope, c_q, new = _latent_q_kv(
+        h, lp, pos, sz, cfg, width, kv_scope="layer/mla/kv", spec=cfg.rope_latent,
+        q_scope="layer/mla/q_lora" if sz.q_lora_rank else "layer/mla/q",
+    )
+    # A token that does not count is written nowhere.
+    at = jnp.where(valid, pos, T)
+    slot = st.get("slot")
+    mine = jnp.arange(b) if slot is None else slot
     with jax.named_scope("layer/mla/kv"):
-        ckr = jnp.dot(h, lp["w_kva"])
-        c = rms_norm(ckr[..., :rank], lp["kv_norm"], cfg.norm_eps)
-        k_rope = mla.rope_interleaved(ckr[..., rank:], pos, cfg.rope_theta, spec)
-        T, width = st["latent"].shape[1:]
-        spare = jnp.zeros((b, s, width - rank - rope), c.dtype)  # none in Ling's rows
-        new = jnp.concatenate([c, k_rope, spare], axis=-1).astype(st["latent"].dtype)
-        # A token that does not count is written nowhere.
-        at = jnp.where(valid, pos, T)
-        slot = st.get("slot")
-        mine = jnp.arange(b) if slot is None else slot
-        latent = st["latent"].at[mine[:, None], at].set(new, mode="drop")
+        latent = st["latent"].at[mine[:, None], at].set(
+            new.astype(st["latent"].dtype), mode="drop"
+        )
+    new_state = {"latent": latent}
+    topk = cfg.index_topk
+    if topk:
+        q_i, w_i, k_i = _index_q_k(h, c_q, lp, pos, cfg)
+        with jax.named_scope("layer/mla/index"):
+            index_k = st["index_k"].at[mine[:, None], at].set(
+                k_i.astype(st["index_k"].dtype), mode="drop"
+            )
+        new_state["index_k"] = index_k
+    if slot is not None:
+        new_state["slot"] = slot
     span = min(window, T)
     sizes = dict(w_kvb=lp["w_kvb"], rank=rank, nope=nope, v_dim=vd)
     if cfg.softmax_mscale != 1.0:
         sizes["scale"] = (nope + rope) ** -0.5 * cfg.softmax_mscale**2
-    read = b * span
+    read = {"read_latent": b * span, "dense_latent": b * span}
 
-    def in_place(attend, block, lengths):
+    def in_place(attend, block, lengths, *more):
         """A row at a time, its whole blocks up to ``lengths`` read from
         its slot of the state in place; a row that holds nothing (a
-        group's padding, a slot that does not decode) is passed over."""
+        group's padding, a slot that does not decode) is passed over.
+        ``more``: further operands a row of the batch each, handed to
+        ``attend`` after the first five."""
 
         def one(row):
-            qn, qr, sl, p, n = (x[None] for x in row)
+            qn, qr, sl, p, n, *rest = (x[None] for x in row)
             return jax.lax.cond(
                 n[0] > 0,
                 lambda: attend(
-                    qn, qr, latent, q_pos=p, lengths=n, block=block, slot=sl,
+                    qn, qr, latent, *rest, q_pos=p, lengths=n, block=block, slot=sl,
                     window=span, **sizes
                 )[0],
-                lambda: jnp.zeros((s, H, vd), q.dtype),
+                lambda: jnp.zeros((s, H, vd), q_nope.dtype),
             )
 
-        return jax.lax.map(one, (q_nope, q_rope, mine, pos, lengths))
+        return jax.lax.map(one, (q_nope, q_rope, mine, pos, lengths, *more))
 
-    if cfg.latent_block and s > 1:
+    if topk and s > 1:
+        record(f"index_scores b={b} s={s} t={span}", False)
+        record(f"attn_latent_sparse b={b} s={s} t={span} k={topk}", False)
+        lengths = jnp.where(n_valid > 0, pos[:, 0] + n_valid, 0)
+        selects = lengths > topk  # a shorter row keeps every position it sees
+        walked = mla.rows_in_blocks(lengths, span, cfg.latent_block)
+        scored = jnp.where(selects, walked, 0)
+        # Pairs a query of a row that counts sees, and those the walk
+        # scores for it: every row of every block walked, kept or not.
+        seen = jnp.where(valid, jnp.minimum(pos + 1, span), 0)
+        read.update(
+            read_latent=walked.sum(),
+            index_pairs=(scored * n_valid).sum(),
+            read_index=scored.sum(),
+            read_selected=(walked * n_valid).sum(),
+            seen_latent=seen.sum(),
+        )
+
+        def attend(qn, qr, lat, qi, wi, *, q_pos, lengths, block, slot, window, **kw):
+            walk = functools.partial(
+                mla.attend_blocks, qn, qr, lat, q_pos=q_pos, lengths=lengths, block=block,
+                slot=slot, window=window, **kw
+            )
+
+            def sparse():
+                scores = mla.index_scores_blocks(
+                    qi, wi, index_k, q_pos, lengths, block=block, slot=slot, window=window,
+                )
+                return walk(allowed=mla.select_mask(scores, topk))
+
+            return jax.lax.cond(lengths[0] > topk, sparse, walk)
+
+        o = in_place(attend, cfg.latent_block, lengths, q_i, w_i)
+    elif topk:
+        record(f"index_scores b={b} s=1 t={span}", False)
+        record(f"attn_latent_sparse_decode b={b} t={span} k={min(topk, span)}", False)
+        keys, rows = (index_k, latent) if slot is None else (index_k[mine], latent[mine])
+        with jax.named_scope("layer/mla/index"):
+            scores = mla.index_scores(q_i, w_i, jax.lax.slice_in_dim(keys, 0, span, axis=1))[:, 0]
+            seen = jnp.arange(span, dtype=jnp.int32)[None, :] <= pos[:, :1]
+            scores = jnp.where(seen & (n_valid > 0)[:, None], scores, -jnp.inf)
+        idx, keep = mla.select_rows(scores, topk)
+        # Every slot's index keys are read and every slot's rows gathered,
+        # whoever decodes: what the step read, not what it needed.
+        read.update(
+            read_latent=b * idx.shape[1], index_pairs=b * span, read_index=b * span,
+            read_selected=b * idx.shape[1],
+            seen_latent=jnp.where(n_valid > 0, jnp.minimum(pos[:, 0] + 1, span), 0).sum(),
+        )
+        o = mla.attend_selected(q_nope, q_rope, rows, idx=idx, keep=keep, **sizes)
+    elif cfg.latent_block and s > 1:
         record(f"attn_latent b={b} s={s} t={span}", False)
         # Rows each row holds once its tokens are written; a row with
         # nothing that counts reads nothing.
         lengths = jnp.where(n_valid > 0, pos[:, 0] + n_valid, 0)
-        read = mla.rows_in_blocks(lengths, span, cfg.latent_block).sum()
+        read["read_latent"] = mla.rows_in_blocks(lengths, span, cfg.latent_block).sum()
         o = in_place(mla.attend_blocks, cfg.latent_block, lengths)
     elif cfg.latent_block:
         record(f"attn_latent_decode b={b} t={span}", False)
         # A decode step: a row that does not decode reads nothing.
         lengths = jnp.where(n_valid > 0, pos[:, 0] + 1, 0)
-        read = mla.rows_in_blocks(lengths, span, cfg.latent_decode_block).sum()
+        read["read_latent"] = mla.rows_in_blocks(lengths, span, cfg.latent_decode_block).sum()
         o = in_place(mla.attend_absorbed_blocks, cfg.latent_decode_block, lengths)
     else:
         attend = functools.partial(
@@ -1483,13 +1872,44 @@ def _mla_mixer(h, lp, st, pos, valid, n_valid, cfg: HybridConfig, window: int, a
             lambda qn, qr, lat, p: attend(qn, qr, lat, q_pos=p),
             n_valid, apart, q_nope, q_rope, latent[:, :window], pos,
         )
-    with jax.named_scope("layer/mla/wo"):
-        if cfg.mla_out_gate:
-            gate = jax.nn.sigmoid(jnp.dot(h, lp["w_gate"], preferred_element_type=F32))
-            o = (o.astype(F32) * gate[..., None]).astype(h.dtype)
-        out = jnp.dot(o.reshape(b, s, H * vd), lp["w_o"])
-    new_state = {"latent": latent} if slot is None else {"latent": latent, "slot": slot}
-    return out, new_state, jnp.stack([read, b * span]).astype(jnp.int32)
+    return _gated_out(o, h, lp, cfg, "layer/mla"), new_state, read
+
+
+def _mla_window_mixer(h, lp, st, pos, valid, n_valid, cfg: HybridConfig, window: int, apart: bool):
+    """An ``mla_window`` layer: latent attention of the kind's own sizes
+    (``cfg.window_latent``) over the last ``sliding_window`` positions.
+    The state is a ring of latent rows (``ring_latent``: position ``p`` in
+    row ``p % R``); a call attends over the ring as it was and over its
+    own rows, in one softmax (``mla.attend_latent_ring``), then writes.
+    Returns (output, state, counters by name: ``RING_LATENT_COUNTERS``,
+    the ring's rows against the ``window`` rows a full layer beside it
+    may see)."""
+    b, s, _ = h.shape
+    sz = cfg.latent_sizes("mla_window")
+    ring = st["ring_latent"]
+    rows = ring.shape[1]
+    q_nope, q_rope, _, new = _latent_q_kv(
+        h, lp, pos, sz, cfg, ring.shape[2], q_scope="layer/mla_window/q",
+        kv_scope="layer/mla_window/kv", spec=None,
+    )
+    record(f"attn_latent_ring b={b} s={s} t={rows}", False)
+    with jax.named_scope("layer/mla_window/attn"):
+        attend = functools.partial(
+            mla.attend_latent_ring, w_kvb=lp["w_kvb"], rank=sz.kv_lora_rank,
+            nope=sz.qk_nope_head_dim, v_dim=sz.v_head_dim, window=cfg.sliding_window,
+        )
+        # A chunk's float32 scores a row at a time; a decode step all slots at once.
+        o = _attend(
+            lambda qn, qr, nw, rg, p: attend(qn, qr, nw, rg, q_pos=p),
+            n_valid, apart and s > gqa._STEP_QUERIES, q_nope, q_rope, new, ring, pos,
+        )
+    with jax.named_scope("layer/mla_window/kv"):
+        at = gqa.ring_slots(pos, valid, n_valid, rows)
+        ring = ring.at[jnp.arange(b)[:, None], at].set(new.astype(ring.dtype), mode="drop")
+    # A chunk's row with nothing that counts is passed over (``_attend``).
+    live = jnp.sum(n_valid > 0) if apart and s > gqa._STEP_QUERIES else b
+    read = {"read_window": live * rows, "dense_window": b * window}
+    return _gated_out(o, h, lp, cfg, "layer/mla_window"), {"ring_latent": ring}, read
 
 
 def _gqa_mixer(
@@ -1794,8 +2214,11 @@ def _mix(
         y, st, read = _kda_mixer(h, lp, st, valid, n_valid, cfg, mesh)
         names = STATE_COUNTERS
     elif mixer == "mla":
-        y, st, read = _mla_mixer(h, lp, st, pos, valid, n_valid, cfg, window, apart)
-        names = LATENT_COUNTERS
+        y, st, named = _mla_mixer(h, lp, st, pos, valid, n_valid, cfg, window, apart)
+        names, read = tuple(named), tuple(named.values())
+    elif mixer == "mla_window":
+        y, st, named = _mla_window_mixer(h, lp, st, pos, valid, n_valid, cfg, window, apart)
+        names, read = tuple(named), tuple(named.values())
     elif mixer == "mamba":
         y, st, named = _mamba_mixer(h, lp, st, valid, n_valid, cfg)
         names, read = tuple(named), tuple(named.values())
@@ -2184,6 +2607,58 @@ NEMOTRON_H_TINY = {
 }
 
 
+# dots-studio/dots3-note-prev's config.json: every key that gives the
+# language model its shape (the vision tower, the audio encoder and the
+# prediction module have no key there and are not modelled).
+_DOTS3_PERIOD = ["sliding_attention"] * 3 + ["full_attention"]
+DOTS3_NOTE_PREV = {
+    "model_type": "dots3_note", "num_hidden_layers": 46, "hidden_size": 5120,
+    "intermediate_size": 13824, "moe_intermediate_size": 1536,
+    "first_k_dense_replace": 1, "moe_layer_freq": 1,
+    "layer_types": ["full_attention"] * 2 + _DOTS3_PERIOD * 11,
+    "num_attention_heads": 128, "num_key_value_heads": 128, "q_lora_rank": 1024,
+    "kv_lora_rank": 512, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+    "v_head_dim": 128, "rope_theta": 80000000, "rope_scaling": None,
+    "attention_gate_type": "headwise", "apply_mla_qkv_lora_rescale": True,
+    "index_n_heads": 64, "index_head_dim": 128, "index_topk": 2048,
+    "sliding_window_size": 513, "swa_num_attention_heads": 64,
+    "swa_num_key_value_heads": 64, "swa_q_lora_rank": 1024,
+    "swa_kv_lora_rank": 1024, "swa_qk_nope_head_dim": 192,
+    "swa_qk_rope_head_dim": 64, "swa_v_head_dim": 128, "swa_rope_theta": 50000,
+    "swa_attention_gate_type": "headwise", "attention_bias": False,
+    "hidden_act": "silu", "n_routed_experts": 256, "n_shared_experts": 1,
+    "num_experts_per_tok": 8, "scoring_func": "sigmoid", "topk_method": "noaux_tc",
+    "norm_topk_prob": True, "routed_scaling_factor": 1,
+    "max_position_embeddings": 524288, "rms_norm_eps": 1e-05,
+    "vocab_size": 152064, "tie_word_embeddings": False,
+}
+# Rank 0's share of the first of eight pipeline stages, every layer shared
+# by eight chips: published layers 0-5 (full + dense, full, sliding,
+# sliding, sliding, full), 32 of the 256 experts (the first 32), an eighth
+# of the vocabulary.
+DOTS3_L6E32_CUT = {
+    "num_hidden_layers": 6, "n_routed_experts": 32, "num_experts_published": 256,
+    "vocab_size": 19008,
+}
+# Every ratio at sizes a CPU test runs: the same six layers, the two kinds
+# with heads, ranks, head sizes and thetas that all differ, an indexer of 2
+# heads that keeps 24 rows (prompts of 80 cross it), a window of 13 (shorter
+# than a chunk of 16; the ring turns over six times), 2 of 16 experts held
+# (an eighth, as in the cut) and 2 a token.
+DOTS3_NOTE_TINY = {
+    **DOTS3_NOTE_PREV, **DOTS3_L6E32_CUT, "hidden_size": 64,
+    "intermediate_size": 128, "moe_intermediate_size": 32,
+    "num_attention_heads": 4, "num_key_value_heads": 4, "q_lora_rank": 24,
+    "kv_lora_rank": 16, "qk_nope_head_dim": 8, "qk_rope_head_dim": 8,
+    "v_head_dim": 16, "index_n_heads": 2, "index_head_dim": 16, "index_topk": 24,
+    "sliding_window_size": 13, "swa_num_attention_heads": 2,
+    "swa_num_key_value_heads": 2, "swa_q_lora_rank": 16, "swa_kv_lora_rank": 24,
+    "swa_qk_nope_head_dim": 16, "swa_qk_rope_head_dim": 4, "swa_v_head_dim": 8,
+    "n_routed_experts": 2, "num_experts_published": 16, "num_experts_per_tok": 2,
+    "vocab_size": 512, "torch_dtype": "float32",
+}
+
+
 def ling_flash_vl_l7e128() -> HybridConfig:
     return from_hf_config({**LING_FLASH_VL, **LING_L7E128_CUT}, max_len=2048)
 
@@ -2238,6 +2713,18 @@ def nemotron_h_tiny() -> HybridConfig:
     return from_hf_config(NEMOTRON_H_TINY, max_len=256, kv_dtype="float32")
 
 
+def dots3_note_prev_l6e32() -> HybridConfig:
+    return from_hf_config({**DOTS3_NOTE_PREV, **DOTS3_L6E32_CUT}, max_len=16384)
+
+
+def dots3_note_tiny() -> HybridConfig:
+    # Blocks of 16 rows: shorter than the windows the tests use.
+    return dataclasses.replace(
+        from_hf_config(DOTS3_NOTE_TINY, max_len=256, kv_dtype="float32"),
+        latent_block=16, latent_decode_block=16,
+    )
+
+
 PRESETS = {
     "ling-3.0-flash-vl-l7e128": ling_flash_vl_l7e128,
     "ling-tiny": ling_tiny,
@@ -2251,4 +2738,6 @@ PRESETS = {
     "zaya-tiny": zaya_tiny,
     "nemotron-3-super-120b-a12b-l11e128": nemotron3_super_l11e128,
     "nemotron_h-tiny": nemotron_h_tiny,
+    "dots3-note-prev-l6e32": dots3_note_prev_l6e32,
+    "dots3_note-tiny": dots3_note_tiny,
 }

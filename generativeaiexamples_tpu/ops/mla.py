@@ -18,6 +18,20 @@ a token (576 values at Ling's published widths, 320 at Mistral-Small-4's).
   weighted sum over the whole row too, its rope columns dropped
   afterwards): a decode step that reads what a slot holds.
 
+* :func:`index_scores`, :func:`index_scores_blocks`, :func:`select_mask`,
+  :func:`select_rows` — DeepSeek-V3.2's lightning indexer: every earlier
+  position scored by a few small heads against one cached index key a
+  token, and the ``k`` highest kept (a tie to the lower position);
+  :func:`attend_blocks` then takes the kept (query, row) pairs as
+  ``allowed`` (a prefill chunk: every block up to the row's length is
+  still expanded and scored, the softmax keeps the selected pairs), and
+  :func:`attend_selected` gathers the kept rows and attends over them
+  alone in the absorbed form (a decode step);
+* :func:`attend_latent_ring` — a window layer of latent rows: the ring
+  as it was beside the call's own rows in one softmax
+  (``ops/gqa.py::attend_ring``'s rule), expanded for a chunk, absorbed
+  for a decode step.
+
 All are plain XLA and give the same numbers up to rounding:
 ``tests/test_hybrid_ops.py`` holds one against the other.
 
@@ -34,6 +48,7 @@ import math
 import jax
 import jax.numpy as jnp
 
+from generativeaiexamples_tpu.ops.gqa import _STEP_QUERIES, ring_held
 from generativeaiexamples_tpu.ops.rope import RopeSpec, spec_frequencies
 
 F32 = jnp.float32
@@ -127,14 +142,18 @@ def rows_in_blocks(lengths, window: int, block: int):
     return jnp.minimum(n, window // block) * block
 
 
-def _walk_blocks(score_and_weigh, latent, q_pos, lengths, *, heads, width, block, slot, window):
+def _walk_blocks(
+    score_and_weigh, latent, q_pos, lengths, *, heads, width, block, slot, window, allowed=None,
+):
     """An online softmax over the whole blocks of ``block`` latent rows
     up to the rows' ``lengths``.  ``score_and_weigh(rows)`` gives a
     block's scaled scores (b, H, s, block) float32 and the function that
     weighs its values by probabilities of that shape -> (b, H, s, width)
     float32.  With ``slot`` (1,) the one row of the batch is row ``slot``
     of ``latent`` and its blocks are read from there; ``window`` (absent:
-    all of them) bounds the rows seen.  Returns (b, H, s, width) float32:
+    all of them) bounds the rows seen; ``allowed`` (b, s, T) bool, where
+    given, the (query, row) pairs the softmax keeps of those the causal
+    mask lets through.  Returns (b, H, s, width) float32:
     zeros, not 0 / 0, for a row that holds nothing and reads no block."""
     b, s = q_pos.shape
     T = min(window or latent.shape[1], latent.shape[1])
@@ -152,9 +171,15 @@ def _walk_blocks(score_and_weigh, latent, q_pos, lengths, *, heads, width, block
         scores, weigh = score_and_weigh(rows)
         key_pos = j * block + jnp.arange(block, dtype=jnp.int32)
         seen = key_pos[None, None, None, :] <= q_pos[:, None, :, None]
+        if allowed is not None:
+            kept = jax.lax.dynamic_slice(allowed, (0, 0, j * block), (b, s, block))
+            seen = seen & kept[:, None]
         scores = jnp.where(seen, scores, -1e30)
         m_new = jnp.maximum(m, scores.max(-1))
         p = jnp.exp(scores - m_new[..., None])
+        if allowed is not None:
+            # A block of which a query keeps nothing, before any it keeps.
+            p = jnp.where(seen, p, 0.0)
         alpha = jnp.exp(m - m_new)
         return m_new, l * alpha + p.sum(-1), acc * alpha[..., None] + weigh(p)
 
@@ -170,7 +195,7 @@ def _walk_blocks(score_and_weigh, latent, q_pos, lengths, *, heads, width, block
 @jax.named_scope("layer/mla/attn")
 def attend_blocks(
     q_nope, q_rope, latent, w_kvb, q_pos, lengths, *, rank, nope, v_dim, block, scale=None,
-    slot=None, window=None,
+    slot=None, window=None, allowed=None,
 ):
     """:func:`attend_expanded` a block of ``block`` latent rows at a time
     (the window's length where it divides by no more), with an online
@@ -182,8 +207,9 @@ def attend_blocks(
     longest are not read.  With ``slot`` (1,) the one row of the batch is
     row ``slot`` of ``latent``, a state of many slots, and its blocks are
     read from there: no copy of the row's window is made.  ``window``
-    (absent: all of them) bounds the rows a call may see.  Arguments and
-    result otherwise as :func:`attend_expanded`."""
+    (absent: all of them) bounds the rows a call may see; ``allowed``
+    (b, s, T) bool the pairs an indexer kept (:func:`select_mask`).
+    Arguments and result otherwise as :func:`attend_expanded`."""
     b, H, rope = q_nope.shape[0], q_nope.shape[2], q_rope.shape[-1]
     if scale is None:
         scale = (nope + rope) ** -0.5
@@ -204,7 +230,7 @@ def attend_blocks(
 
     out = _walk_blocks(
         score_and_weigh, latent, q_pos, lengths, heads=H, width=v_dim, block=block,
-        slot=slot, window=window,
+        slot=slot, window=window, allowed=allowed,
     )
     return jnp.transpose(out.astype(q_nope.dtype), (0, 2, 1, 3))
 
@@ -242,3 +268,152 @@ def attend_absorbed_blocks(
         slot=slot, window=window,
     ).astype(q_nope.dtype)
     return jnp.einsum("bhsr,rhd->bshd", o_row[..., :rank], w[..., nope:])
+
+
+# -- the indexer: which rows a query attends --------------------------------------
+
+
+def index_scores(q_i, w, keys):
+    """``I[t, j] = sum_i w[t, i] relu(q_I[t, i] . k_I[j])``: q_i (b, s, HI,
+    d) the index queries, w (b, s, HI) float32 their weights, keys (b, t,
+    d) index keys.  Returns (b, s, t) float32, unmasked."""
+    dots = jnp.einsum("bshd,btd->bsht", q_i, keys, preferred_element_type=F32)
+    return jnp.einsum("bsht,bsh->bst", jax.nn.relu(dots), w.astype(F32))
+
+
+@jax.named_scope("layer/mla/index")
+def index_scores_blocks(q_i, w, index_k, q_pos, lengths, *, block, slot=None, window=None):
+    """:func:`index_scores` against the index keys a slot holds, a block
+    of ``block`` rows at a time up to the rows' ``lengths`` (the blocks
+    past the longest are not read), ``-inf`` at every position a query
+    does not see (a later one, or one in a block not read).  ``slot`` and
+    ``window`` as :func:`attend_blocks`.  Returns (b, s, T) float32."""
+    b, s = q_pos.shape
+    T = min(window or index_k.shape[1], index_k.shape[1])
+    if slot is not None and b != 1:
+        raise ValueError("a slot names the state row of a batch of one")
+    first = jnp.zeros((), jnp.int32) if slot is None else slot[0].astype(jnp.int32)
+    block = math.gcd(T, block)
+    n_blocks = jnp.max(rows_in_blocks(lengths, T, block)) // block
+
+    def fold(j, out):
+        keys = jax.lax.dynamic_slice(index_k, (first, j * block, 0), (b, block, index_k.shape[2]))
+        return jax.lax.dynamic_update_slice(out, index_scores(q_i, w, keys), (0, 0, j * block))
+
+    scores = jax.lax.fori_loop(0, n_blocks, fold, jnp.full((b, s, T), -jnp.inf, F32))
+    seen = jnp.arange(T, dtype=jnp.int32)[None, None, :] <= q_pos[:, :, None]
+    return jnp.where(seen, scores, -jnp.inf)
+
+
+def _ordered_bits(x):
+    """float32 -> uint32 whose unsigned order is the floats' (``-inf``
+    lowest; no NaN is expected)."""
+    bits = jax.lax.bitcast_convert_type(x.astype(F32), jnp.uint32)
+    negative = (bits >> 31).astype(bool)
+    return jnp.where(negative, ~bits, bits | jnp.uint32(0x80000000))
+
+
+@jax.named_scope("layer/mla/select")
+def select_mask(scores, k: int):
+    """The ``k`` largest of each row of ``scores`` (..., T) float32, as a
+    mask: among equal scores the lower positions first.  A position that
+    is not seen carries ``-inf`` and is never kept, so a query that sees
+    ``k`` positions or fewer keeps them all.  No sort: the ``k``-th
+    largest value is built bit by bit (32 counts over the row), which a
+    v5e does in 0.46 ms for (256, 16384) scores where ``lax.top_k`` and a
+    threshold at its last value take 2.6 ms and ``top_k`` and a scatter
+    4.8 (a chunk program of 8 rows: 245, 315 and 377 ms).  A decode step
+    needs the positions, for its gather, and has one query a slot:
+    :func:`select_rows`, the same rule."""
+    T = scores.shape[-1]
+    if k >= T:
+        return scores > -jnp.inf
+    key = _ordered_bits(scores)
+
+    def bit(i, kth):
+        cand = kth | (jnp.uint32(1) << (jnp.uint32(31) - i.astype(jnp.uint32)))
+        enough = jnp.sum(key >= cand[..., None], axis=-1) >= k
+        return jnp.where(enough, cand, kth)
+
+    kth = jax.lax.fori_loop(0, 32, bit, jnp.zeros(scores.shape[:-1], jnp.uint32))[..., None]
+    above, equal = key > kth, key == kth
+    room = k - jnp.sum(above, axis=-1, keepdims=True)
+    ties = equal & (jnp.cumsum(equal, axis=-1) <= room)
+    return (above | ties) & (scores > -jnp.inf)
+
+
+@jax.named_scope("layer/mla/select")
+def select_rows(scores, k: int):
+    """The positions of the ``k`` largest of each row of ``scores`` (b, T)
+    (a tie to the lower position: ``lax.top_k``'s rule) and which of them
+    are positions the query sees (not ``-inf``): ((b, k) int32, (b, k)
+    bool).  ``k`` is cut to ``T``."""
+    vals, idx = jax.lax.top_k(scores, min(k, scores.shape[-1]))
+    return idx.astype(jnp.int32), vals > -jnp.inf
+
+
+def _attend_whole_rows(q_nope, q_rope, rows, w, mask, nope: int, scale):
+    """The absorbed form over stored rows read as they lie (normed latent,
+    rope key, zero columns): one product of the queries, ``W_kvb``'s key
+    part folded in, with the whole rows; rows (b, t, width), w (rank, H,
+    nope + v), mask broadcast to (b, H, s, t).  Returns (b, s, H, v)."""
+    rank = w.shape[0]
+    q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w[..., :nope])
+    spare = jnp.zeros(q_lat.shape[:-1] + (rows.shape[2] - rank - q_rope.shape[-1],), q_lat.dtype)
+    q = jnp.concatenate([q_lat, q_rope.astype(q_lat.dtype), spare], axis=-1)
+    scores = jnp.einsum("bshr,btr->bhst", q, rows, preferred_element_type=F32) * scale
+    probs = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1).astype(rows.dtype)
+    o_row = jnp.einsum("bhst,btr->bhsr", probs, rows)
+    return jnp.einsum("bhsr,rhd->bshd", o_row[..., :rank], w[..., nope:])
+
+
+@jax.named_scope("layer/mla/attn")
+def attend_selected(q_nope, q_rope, latent, w_kvb, idx, keep, *, rank, nope, v_dim, scale=None):
+    """:func:`attend_absorbed` over the rows ``idx`` (b, k) of each slot's
+    ``latent`` (b, T, width) alone, of which ``keep`` (b, k) count: the
+    rows are gathered whole (zero columns after the rope key and all) and
+    read once for all heads.  q_nope, q_rope: (b, 1, H, .), one query a
+    slot.  Returns (b, 1, H, v_dim)."""
+    if scale is None:
+        scale = (nope + q_rope.shape[-1]) ** -0.5
+    w = w_kvb.reshape(rank, q_nope.shape[2], nope + v_dim)
+    rows = jnp.take_along_axis(latent, idx[:, :, None], axis=1)  # (b, k, width)
+    return _attend_whole_rows(q_nope, q_rope, rows, w, keep[:, None, None, :], nope, scale)
+
+
+def attend_latent_ring(
+    q_nope, q_rope, new_rows, ring, w_kvb, q_pos, *, rank, nope, v_dim, window: int, scale=None,
+):
+    """A window layer of latent rows: q_nope, q_rope (b, s, H, .) at
+    consecutive positions ``q_pos`` (b, s); new_rows (b, s, width) this
+    call's rows as stored (normed latent, rotated rope key, zero columns);
+    ring (b, R, width) the ring BEFORE the call, position ``p`` in row
+    ``p % R``.  Position ``i`` sees ``j`` with ``i - window < j <= i``: of
+    the ring what ``ops/gqa.py::ring_held`` says it holds, and the call's
+    own rows, in one softmax.  A chunk expands keys and values through
+    ``W_kvb``; a decode step (one or two queries a row) folds ``W_kvb``
+    into the query and reads the rows as they lie.  Returns
+    (b, s, H, v_dim)."""
+    b, s, H, rope = q_rope.shape
+    R = ring.shape[1]
+    if scale is None:
+        scale = (nope + rope) ** -0.5
+    held = ring_held(q_pos[:, 0], R)[:, None, :]  # (b, 1, R)
+    old = (held >= 0) & (held > q_pos[:, :, None] - window)
+    steps = jnp.arange(s, dtype=jnp.int32)
+    back = steps[:, None] - steps[None, :]
+    new = jnp.broadcast_to((back >= 0) & (back < window), (b, s, s))
+    mask = jnp.concatenate([old, new], axis=-1)[:, None]  # (b, 1, s, R + s)
+    rows = jnp.concatenate([ring, new_rows.astype(ring.dtype)], axis=1)
+    w = w_kvb.reshape(rank, H, nope + v_dim)
+    if s <= _STEP_QUERIES:
+        return _attend_whole_rows(q_nope, q_rope, rows, w, mask, nope, scale)
+    n = rows.shape[1]
+    kv = jnp.dot(rows[..., :rank], w_kvb).reshape(b, n, H, nope + v_dim)
+    k_rope = jnp.broadcast_to(rows[:, :, None, rank : rank + rope], (b, n, H, rope))
+    q = jnp.concatenate([q_nope, q_rope], axis=-1)
+    k = jnp.concatenate([kv[..., :nope], k_rope.astype(kv.dtype)], axis=-1)
+    scores = jnp.einsum("bshd,bthd->bhst", q, k, preferred_element_type=F32) * scale
+    probs = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1)
+    v = kv[..., nope:]
+    return jnp.einsum("bhst,bthd->bshd", probs.astype(v.dtype), v)

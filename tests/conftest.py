@@ -39,6 +39,91 @@ def pytest_configure(config):
     )
 
 
+# Seconds a file's cases take on one worker, for the files over 40 s (the
+# junit of tier-1's command on PR 47's tree: 6,660 s in all, six workers).
+# ``--dist loadfile`` hands out whole files, those with the MOST TESTS
+# first, so the long files started wherever their count put them and the
+# run ended on a few slow ones with workers idle.  Here the slowest go
+# first.  A file not listed keeps xdist's order behind these; a stale
+# number costs balance, nothing else.
+FILE_SECONDS = {
+    "tests/test_chip_compile.py": 660,
+    "tests/test_chip_smoke.py": 520,
+    "tests/test_speech.py": 400,
+    "tests/test_hybrid_serving.py": 350,
+    "tests/test_exaone_moe_model.py": 290,
+    "tests/test_benchmark_contract.py": 280,
+    "tests/test_gqa_ring_chunk_kernel.py": 280,
+    "tests/test_notebooks.py": 230,
+    "tests/test_gqa_chunk_kernel.py": 230,
+    "tests/test_scheduler.py": 210,
+    "tests/test_zaya_model.py": 170,
+    "tests/test_tick_ahead.py": 170,
+    "tests/test_gqa_decode_kernel.py": 160,
+    "tests/test_hybrid_ops.py": 140,
+    "tests/test_speculative.py": 140,
+    "tests/test_hybrid_model.py": 130,
+    "tests/test_engine.py": 120,
+    "tests/test_dots3_note_model.py": 110,
+    "tests/test_qmm.py": 100,
+    "tests/test_kda_step_kernel.py": 90,
+    "tests/test_admit_alone.py": 90,
+    "tests/test_nemotron_h_model.py": 70,
+    "tests/test_decode_attention.py": 60,
+    "tests/test_mellum_model.py": 60,
+    "tests/test_retrieval.py": 60,
+    "tests/test_llama.py": 60,
+    "tests/test_spec_serving.py": 60,
+    "tests/test_ring_attention.py": 50,
+    "tests/test_router.py": 50,
+    "tests/test_tick_tracing.py": 50,
+    "tests/test_llama_serving_rows.py": 50,
+    "tests/test_mistral4_model.py": 50,
+    "tests/test_weights.py": 50,
+    "tests/test_setup_tracing.py": 40,
+}
+
+
+# The layer-kind cells' rehearsals are units of their own, handed out
+# LAST: each holds its requests to the load generator's 20 s for a first
+# token and misses it beside five busy workers (three to five of the seven
+# red in three runs), but not at the run's end, where only rehearsals are
+# left (all green in 66-102 s each).  It costs about two minutes: xdist
+# gives a worker its next unit while it has two tests or fewer to go, so
+# some of them wait behind another on one worker.
+REHEARSALS = "tests/test_benchmark_contract_layer_kinds.py"
+
+
+@pytest.hookimpl(optionalhook=True)
+def pytest_xdist_make_scheduler(config, log):
+    """``--dist loadfile`` with the slowest files first (``FILE_SECONDS``)
+    and the rehearsals last; every other mode is xdist's own."""
+    if config.getvalue("dist") != "loadfile":
+        return None
+    from xdist.scheduler import LoadFileScheduling
+
+    def seconds(unit: str) -> int:
+        return -1 if unit.startswith(REHEARSALS) else FILE_SECONDS.get(unit, 0)
+
+    class SlowestFilesFirst(LoadFileScheduling):
+        queue_is_ordered = False
+
+        def _split_scope(self, nodeid):
+            file = super()._split_scope(nodeid)
+            return nodeid if file == REHEARSALS else file
+
+        def _assign_work_unit(self, node):
+            # The first unit handed out: the queue is whole, in xdist's order.
+            if not self.queue_is_ordered:
+                self.queue_is_ordered = True
+                units = sorted(self.workqueue.items(), key=lambda unit: -seconds(unit[0]))
+                self.workqueue.clear()
+                self.workqueue.update(units)
+            super()._assign_work_unit(node)
+
+    return SlowestFilesFirst(config, log)
+
+
 @pytest.fixture
 def clean_app_env(monkeypatch):
     """Remove APP_* env vars and reset the config cache around a test."""

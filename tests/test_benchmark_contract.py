@@ -188,8 +188,16 @@ def compile_cache(tmp_path_factory):
     return str(tmp_path_factory.mktemp("jax_cache"))
 
 
-@pytest.mark.parametrize("cell", list(CELLS))
-def test_cell_rehearses_end_to_end_on_the_cpu(cell, compile_cache, monkeypatch):
+# A rehearsal takes one to two minutes, so the layer-kind families' cells
+# rehearse in a second file, ``tests/test_benchmark_contract_layer_kinds.py``
+# (the same body over their cases), on another worker than this file's.
+def cells_of(layer_kinds: bool) -> list:
+    """The cells whose configuration names an ``arch`` (a layer-kind
+    family's), or the llama-shaped ones, which name none."""
+    return [cell for cell in CELLS if ("arch" in _config(cell)) == layer_kinds]
+
+
+def rehearse(cell, compile_cache, monkeypatch):
     """Tiny sizes, three seconds: the harness and the program still meet,
     and the last line holds every end-to-end metric the cell lists.  One
     exception, decided by the run's own record: in a closed loop in which
@@ -222,3 +230,8 @@ def test_cell_rehearses_end_to_end_on_the_cpu(cell, compile_cache, monkeypatch):
         listed.discard("itl_p95_ms")
     assert set(line["metrics"]) == listed
     assert all(isinstance(m["value"], Number) for m in line["metrics"].values())
+
+
+@pytest.mark.parametrize("cell", cells_of(False))
+def test_cell_rehearses_end_to_end_on_the_cpu(cell, compile_cache, monkeypatch):
+    rehearse(cell, compile_cache, monkeypatch)

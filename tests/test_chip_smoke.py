@@ -384,8 +384,47 @@ def test_hybrid_phase_rehearsal_of_the_mamba_model_and_its_controls(control, cap
         assert line["p50"] > 1e-3
 
 
+DOTS3_CONTROLS = list(chip_smoke.HYBRID_CONTROLS["dots3_note"])
+
+
+# The seven mechanisms against the reference alone: tests/test_dots3_note_model.py.
+@pytest.mark.parametrize("control", ["", "w8a8_mlp", "last_2048"])
+def test_hybrid_phase_rehearsal_of_the_indexed_latent_model_and_its_controls(control, capsys, monkeypatch, tmp_path):
+    """``--hybrid --model dots3_note`` at the tiny size, in process: a
+    prompt of 75 tokens (three times ``index_topk`` 24, five windows of 13)
+    in chunks of 16 through the chunk program beside a pad row, its last 8
+    positions through the decode step over both slots, by the benchmark's
+    own comparison (the logit shares and ``index_overlap``), held to limits
+    of the rehearsal's own (float32 at this size reads 1e-6 and an overlap
+    of 1) which the sound run is far inside and each control leaves (the
+    precision and a selection of other rows here)."""
+    config = json.loads(open(os.path.join(REPO, chip_smoke.DOTS3_CONFIG)).read())
+    config["reference"]["logit_share_limits"] = {"p10": 2e-4, "p50": 2e-4, "p90": 2e-4, "decode_p50": 2e-4}
+    config["reference"]["index_overlap_floor"] = 0.999
+    tiny = tmp_path / "config.json"
+    tiny.write_text(json.dumps(config))
+    monkeypatch.setattr(chip_smoke, "DOTS3_CONFIG", str(tiny))
+    chip_smoke.child_hybrid(0, chip_smoke.TINY, control=control, model="dots3_note")
+    line = _phases(capsys)["hybrid"]
+    assert line["model"] == "dots3_note-tiny" and line["control"] == (control or None)
+    assert line["positions"] == {"prefill": 67, "decode": 8}
+    # The shapes of the scheduler's programs: a chunk beside a pad row, a
+    # decode step over both slots.
+    for site in ("index_scores b=2 s=16 t=256", "attn_latent_sparse b=2 s=16 t=256 k=24",
+                 "index_scores b=2 s=1 t=256", "attn_latent_sparse_decode b=2 t=256 k=24"):
+        assert line["kernel_paths"][site] == "xla"
+    assert line["within_limits"] == (not control)
+    if not control:
+        assert line["p90"] < 1e-5 and line["index_overlap"] == 1.0
+    elif control == "w8a8_mlp":  # the precision: every position moves, by little; the sets hardly
+        assert 1e-3 < line["p10"] < 0.2 and line["index_overlap"] > 0.8
+    else:  # other rows kept: the first 24 positions agree, the rest do not
+        assert line["p90"] > 1e-2 and line["decode_p50"] > 1e-2 and line["index_overlap"] < 0.8
+
+
 def test_hybrid_phase_names_a_child_for_every_model_and_control():
     assert sorted(n for n in chip_smoke.CHILDREN if n.startswith("hybrid")) == [
+        "hybrid_dots3_note", *(f"hybrid_dots3_note_{c}" for c in sorted(DOTS3_CONTROLS)),
         "hybrid_exaone_moe", "hybrid_exaone_moe_no_qk_norm", "hybrid_exaone_moe_no_window",
         "hybrid_exaone_moe_rope_on_full", "hybrid_exaone_moe_stale_reject", "hybrid_exaone_moe_w8a8_mlp",
         "hybrid_ling", "hybrid_ling_w8a8_mlp", "hybrid_mellum", "hybrid_mellum_no_window",

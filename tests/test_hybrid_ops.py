@@ -437,3 +437,130 @@ def test_partial_rotation_turns_the_first_part_of_a_head_and_passes_the_rest():
     np.testing.assert_array_equal(
         np.asarray(rope.apply_rope_partial(x, pos, spec, 16)), np.asarray(rope.apply_rope_spec(x, pos, spec)))
     np.testing.assert_array_equal(np.asarray(got)[0, 0], np.asarray(x)[0, 0])  # position 0 turns nothing
+
+
+# -- an indexer's scores, the selection, attention over what it keeps, a ring of latent rows --
+
+
+def _latent_case(seed, b=2, s=16, T=64, H=4, rank=16, nope=8, rope=8, vd=16, HI=2, dI=16):
+    keys = jax.random.split(jax.random.PRNGKey(seed), 8)
+    return {
+        "q_nope": jax.random.normal(keys[0], (b, s, H, nope)),
+        "q_rope": jax.random.normal(keys[1], (b, s, H, rope)),
+        "latent": jax.random.normal(keys[2], (b, T, rank + rope)),
+        "w_kvb": jax.random.normal(keys[3], (rank, H * (nope + vd))) * rank**-0.5,
+        "q_i": jax.random.normal(keys[4], (b, s, HI, dI)),
+        "w_i": jax.random.normal(keys[5], (b, s, HI)),
+        "index_k": jax.random.normal(keys[6], (b, T, dI)),
+        "sizes": dict(rank=rank, nope=nope, v_dim=vd, scale=0.3),
+    }
+
+
+def _masked_attention(c, q_pos, mask):
+    """Attention over the whole window under an explicit (b, s, T) mask,
+    keys and values expanded: the oracle of the selected forms."""
+    z, (rank, nope, vd) = c["sizes"], (c["sizes"][k] for k in ("rank", "nope", "v_dim"))
+    b, T, _ = c["latent"].shape
+    H = c["q_nope"].shape[2]
+    kv = jnp.dot(c["latent"][..., :rank], c["w_kvb"]).reshape(b, T, H, nope + vd)
+    scores = (
+        jnp.einsum("bshd,bthd->bhst", c["q_nope"], kv[..., :nope])
+        + jnp.einsum("bshd,btd->bhst", c["q_rope"], c["latent"][..., rank:])
+    ) * z["scale"]
+    probs = jax.nn.softmax(jnp.where(mask[:, None], scores, -jnp.inf), axis=-1)
+    return jnp.einsum("bhst,bthd->bshd", probs, kv[..., nope:])
+
+
+@pytest.mark.parametrize("block", [8, 16, 64])
+def test_index_scores_in_blocks_and_the_selected_block_walk_match_the_whole_window(block):
+    """The indexer's scores a block of keys at a time up to the rows'
+    lengths (one row of a state of many slots read in place, too), the
+    ``k`` largest as a mask, and ``attend_blocks`` over the kept pairs,
+    against the scores of the whole window, a stable sort and a masked
+    softmax.  A query that sees fewer than ``k`` rows keeps them all; a
+    block of which a query keeps nothing adds nothing."""
+    c = _latent_case(21)
+    starts = jnp.asarray([40, 3])
+    q_pos = starts[:, None] + jnp.arange(16)[None, :]
+    lengths, k = starts + 16, 12
+    seen = jnp.arange(64)[None, None, :] <= q_pos[:, :, None]
+    whole = jnp.where(seen, mla.index_scores(c["q_i"], c["w_i"], c["index_k"]), -jnp.inf)
+    want_i = jnp.einsum(
+        "bsht,bsh->bst", jax.nn.relu(jnp.einsum("bshd,btd->bsht", c["q_i"], c["index_k"])), c["w_i"])
+    np.testing.assert_allclose(np.where(seen, whole, 0), np.where(seen, want_i, 0), atol=1e-5)
+    got_i = mla.index_scores_blocks(c["q_i"], c["w_i"], c["index_k"], q_pos, lengths, block=block)
+    np.testing.assert_allclose(np.where(seen, got_i, 0), np.where(seen, whole, 0), atol=1e-5)
+    assert np.isneginf(np.asarray(got_i)[~np.asarray(seen)]).all()
+    one = mla.index_scores_blocks(
+        c["q_i"][1:], c["w_i"][1:], c["index_k"], q_pos[1:], lengths[1:], block=block,
+        slot=jnp.asarray([1]), window=32)
+    np.testing.assert_allclose(np.where(seen[1:, :, :32], one, 0), np.where(seen[1:, :, :32], whole[1:, :, :32], 0), atol=1e-5)
+    rank_of = jnp.argsort(jnp.argsort(-whole, axis=-1, stable=True), axis=-1)
+    want_mask = seen & (rank_of < k)
+    mask = mla.select_mask(got_i, k)
+    np.testing.assert_array_equal(np.asarray(mask), np.asarray(want_mask))
+    assert (np.asarray(mask).sum(-1) == np.minimum(np.asarray(q_pos) + 1, k)).all()
+    want = _masked_attention(c, q_pos, want_mask)
+    got = mla.attend_blocks(
+        c["q_nope"], c["q_rope"], c["latent"], c["w_kvb"], q_pos, lengths, block=block,
+        allowed=mask, **c["sizes"])
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    # Every pair allowed: the walk without a mask, to the last bit.
+    plain = mla.attend_blocks(
+        c["q_nope"], c["q_rope"], c["latent"], c["w_kvb"], q_pos, lengths, block=block, **c["sizes"])
+    every = mla.attend_blocks(
+        c["q_nope"], c["q_rope"], c["latent"], c["w_kvb"], q_pos, lengths, block=block,
+        allowed=jnp.ones((2, 16, 64), bool), **c["sizes"])
+    np.testing.assert_array_equal(np.asarray(every), np.asarray(plain))
+
+
+@pytest.mark.parametrize("k", [8, 24, 64, 100])
+def test_a_decode_step_over_the_gathered_rows_matches_the_masked_window(k):
+    """``select_rows`` + ``attend_selected`` (the rows gathered whole, zero
+    columns and all, one query a slot) against the masked softmax over the
+    whole window; ``k`` past what a slot holds keeps every row it sees and
+    is ``attend_absorbed``."""
+    c = _latent_case(22, s=1)
+    wide = jnp.concatenate([c["latent"], jnp.zeros((2, 64, 104))], axis=-1)  # rows in whole lanes
+    q_pos = jnp.asarray([[50], [9]])
+    seen = jnp.arange(64)[None, :] <= q_pos
+    scores = jnp.where(seen, mla.index_scores(c["q_i"], c["w_i"], c["index_k"])[:, 0], -jnp.inf)
+    idx, keep = mla.select_rows(scores, k)
+    assert idx.shape == (2, min(k, 64)) and (np.asarray(keep).sum(-1) == np.minimum([51, 10], k)).all()
+    rank_of = jnp.argsort(jnp.argsort(-scores, axis=-1, stable=True), axis=-1)
+    want = _masked_attention(c, q_pos, (seen & (rank_of < k))[:, None])
+    got = mla.attend_selected(c["q_nope"], c["q_rope"], wide, c["w_kvb"], idx, keep, **c["sizes"])
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    if k >= 64:
+        dense = mla.attend_absorbed(c["q_nope"], c["q_rope"], c["latent"], c["w_kvb"], q_pos, **c["sizes"])
+        np.testing.assert_allclose(got, dense, atol=1e-5)
+
+
+@pytest.mark.parametrize("s, start", [(16, 0), (16, 37), (5, 11), (1, 40), (2, 3), (1, 0)],
+                         ids=["first_chunk", "turned_over", "short_chunk", "decode", "two_queries", "empty_ring"])
+def test_a_ring_of_latent_rows_beside_the_call_s_own_matches_the_windowed_whole(s, start):
+    """``attend_latent_ring``: a ring of 13 rows (shorter than a chunk of
+    16), filled position by position as the serving path fills it
+    (``p % 13``; stale rows of another occupant where nothing was written),
+    against attention over every position under the mask ``i - 13 < j <=
+    i``; a chunk takes the expanded form, a decode step the absorbed."""
+    from generativeaiexamples_tpu.ops import gqa
+
+    R = window = 13
+    c = _latent_case(23, b=2, s=s, T=64)
+    rows = jnp.concatenate([c["latent"], jnp.zeros((2, 64, 104))], axis=-1)
+    ring = jnp.full((2, R, rows.shape[2]), 9.0)  # the last occupant's leftovers
+    for p in range(start):
+        ring = ring.at[:, p % R].set(rows[:, p])
+    q_pos = jnp.full((2, 1), start) + jnp.arange(s)[None, :]
+    j = jnp.arange(64)[None, None, :]
+    mask = (j <= q_pos[:, :, None]) & (j > q_pos[:, :, None] - window)
+    want = _masked_attention(c, q_pos, mask)
+    got = mla.attend_latent_ring(
+        c["q_nope"], c["q_rope"], rows[:, start : start + s], ring, c["w_kvb"], q_pos,
+        window=window, **c["sizes"])
+    np.testing.assert_allclose(got, want, atol=1e-5)
+    # What the call then writes: its last 13 rows, each at ``p % 13``.
+    at = gqa.ring_slots(q_pos, jnp.ones((2, s), bool), jnp.full((2,), s), R)
+    assert (np.asarray(at)[:, -min(s, R):] == np.asarray(q_pos)[:, -min(s, R):] % R).all()
+    assert (np.asarray(at)[:, : max(s - R, 0)] == R).all()

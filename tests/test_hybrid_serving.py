@@ -500,10 +500,127 @@ def test_nemotron_metrics_are_exported(nemotron):
     assert any(site.startswith("attn_full b=4 s=1 ") for site in paths), sorted(paths)
 
 
+# -- the ``dots3_note`` family: an indexer's keys beside latent rows, rings of latent rows ------
+
+
+@pytest.fixture(scope="module")
+def dots3():
+    # What engine.server.main() builds for --model dots3_note-tiny.
+    cfg = hybrid.PRESETS[resolve_model_preset("dots3_note-tiny")]()
+    s = Scheduler(
+        cfg, None, max_batch=4, max_len=256, decode_chunk_size=4, seed=11,
+        prefill_chunk_tokens=CHUNK, prefix_cache="shared",
+    )
+    s.start()
+    yield s
+    s.stop()
+
+
+def _dots3_gap(scheduler, prompt, out, pad_to=192):
+    """``_mellum_gap`` against ``dots3_note_reference``."""
+    from generativeaiexamples_tpu.models import dots3_note_reference
+
+    seq = list(prompt) + list(out)
+    lg = np.asarray(dots3_note_reference.all_logits(
+        scheduler.params, scheduler.cfg, seq + [0] * (pad_to - len(seq))))
+    rows = lg[len(prompt) - 1 : len(seq) - 1]
+    return float((rows.max(-1) - rows[np.arange(len(out)), out]).max())
+
+
+def test_dots3_cold_prompts_a_hit_with_index_rows_grafted_and_rings_restored_and_reused_slots(dots3):
+    """Selected latent attention and rings of latent rows on the serving
+    path with no change to the batcher: greedy tokens equal the
+    reference's (to a near-tie) for a cold batch, a prompt in chunks of 32
+    (past ``index_topk`` 24 from its first chunk on, the rings of 13 turned
+    over seven times), a prefix hit whose latent AND index-key rows are
+    grafted and whose three rings come from a by-leaf snapshot, and slots
+    whose last occupant left rows, index keys and rings."""
+    cfg = dots3.cfg
+    assert cfg.layers_of("mla") == [0, 1, 5] and cfg.layers_of("mla_window") == [2, 3, 4]
+    one = 3 * 13 * 128 * 4  # three rings of 13 rows of 128 float32 values
+    assert not dots3.model.cut_anywhere and not dots3.model.draft
+    assert dots3._snapshots.bytes_each == cfg.snapshot_bytes(256) == one
+    assert dots3._chunk_windows == (256,)  # rows read in place, rings whatever the window
+    snap0 = dots3.stats.snapshot()
+    assert snap0["state_bytes_full"] == 4 * 3 * 256 * (128 + 16) * 4
+    assert snap0["state_bytes_window"] == 4 * one
+    cold = [_prompt(81, 20), _prompt(82, 31)]
+    for p, o in zip(cold, _generate(dots3, cold)):
+        assert len(o) == 6 and _dots3_gap(dots3, p, o) <= GAP
+    first = _prompt(83, 100)  # chunks of 32: snapshots at 32, 64, 96
+    before = dots3.stats.snapshot()
+    (out,) = _generate(dots3, [first])
+    mid = dots3.stats.snapshot()
+    assert mid["prefill_chunks"] - before["prefill_chunks"] == 4
+    assert mid["state_snapshots_saved"] - before["state_snapshots_saved"] == 3
+    assert mid["state_snapshot_bytes"] == len(dots3._snapshots) * one
+    assert _dots3_gap(dots3, first, out) <= GAP
+    again = first[:70] + _prompt(84, 25)  # rows match to 70, the rings exist at 64
+    (hit,) = _generate(dots3, [again])
+    after = dots3.stats.snapshot()
+    assert after["shared_prefix_hits"] - mid["shared_prefix_hits"] == 1
+    assert after["state_snapshots_restored"] - mid["state_snapshots_restored"] == 1
+    assert after["prefix_tokens_matched"] - mid["prefix_tokens_matched"] == 70
+    assert after["prefix_tokens_reused"] - mid["prefix_tokens_reused"] == 64
+    assert _dots3_gap(dots3, again, hit) <= GAP
+    for seed in (85, 86, 87, 88, 89):  # more prompts than slots: every slot is reused
+        p = _prompt(seed, 70)
+        (o,) = _generate(dots3, [p], n=3)
+        assert _dots3_gap(dots3, p, o) <= GAP
+    end = dots3.stats.snapshot()
+    # Prefill: the walk scored every row of its whole blocks for every
+    # query, which is no fewer pairs than the queries saw, the indexer every
+    # pair of the rows that select; the block walk read less than the
+    # windows.  Decode: 24 rows a slot gathered.
+    assert 0 < end["attn_rows_seen_latent_prefill"] <= end["attn_rows_read_selected_prefill"]
+    assert 0 < end["attn_rows_index_pairs_prefill"] and 0 < end["attn_rows_read_index_prefill"]
+    assert 0 < end["attn_rows_read_latent_prefill"] < end["attn_rows_dense_latent_prefill"]
+    assert end["attn_rows_read_selected_decode"] == end["attn_rows_read_latent_decode"] > 0
+    assert end["attn_rows_read_selected_decode"] % (3 * 4 * 24) == 0  # three layers, four slots
+    assert 0 < end["attn_rows_seen_latent_decode"] and 0 < end["attn_rows_read_index_decode"]
+    for phase in ("decode", "prefill"):
+        assert 0 < end[f"attn_rows_read_window_{phase}"] < end[f"attn_rows_dense_window_{phase}"]
+    assert 0 < end["moe_choices_local"] < end["moe_choices_routed"]  # 2 of 16 experts
+
+
+def test_dots3_metrics_and_kernel_paths_are_exported(dots3):
+    from generativeaiexamples_tpu.engine.server import create_engine_app
+    from generativeaiexamples_tpu.engine.tokenizer import ByteTokenizer
+
+    (o,) = _generate(dots3, [_prompt(90, 40)], n=3)
+    app = create_engine_app(dots3, ByteTokenizer(), model_name="dots3_note-tiny")
+    loop = asyncio.new_event_loop()
+    client = TestClient(TestServer(app), loop=loop)
+    loop.run_until_complete(client.start_server())
+
+    async def go():
+        return await (await client.get("/metrics")).text(), await (await client.get("/health")).json()
+
+    try:
+        metrics, health = loop.run_until_complete(go())
+    finally:
+        loop.run_until_complete(client.close())
+        loop.close()
+    for name in ("engine_attn_rows_index_pairs_prefill_total", "engine_attn_rows_read_selected_prefill_total",
+                 "engine_attn_rows_read_index_decode_total", "engine_attn_rows_seen_latent_decode_total",
+                 "engine_attn_rows_read_latent_decode_total", "engine_attn_rows_dense_latent_prefill_total",
+                 "engine_attn_rows_read_window_prefill_total", "engine_attn_rows_dense_window_decode_total",
+                 "engine_state_bytes_full", "engine_state_bytes_window",
+                 "engine_state_snapshot_bytes", "engine_state_snapshots_saved_total"):
+        assert f"\n{name} " in metrics, name
+    assert "engine_attn_rows_read_full_decode_total" not in metrics  # the GQA kinds' are theirs
+    paths = health["runtime"]["kernel_paths"]
+    for site in ("index_scores b=1 s=32 t=256", "attn_latent_sparse b=1 s=32 t=256 k=24",
+                 "attn_latent_ring b=1 s=32 t=13", "attn_latent_ring b=4 s=1 t=13"):
+        assert paths[site] == "xla", sorted(paths)
+    assert any(site.startswith("attn_latent_sparse_decode b=4 t=") and site.endswith(" k=24") for site in paths)
+    assert any(site.startswith("index_scores b=4 s=1 t=") for site in paths)
+
+
 # -- the chunks of several slots in one program ---------------------------------------
 
 
-@pytest.mark.parametrize("family", ["ling", "mellum", "zaya", "nemotron"])
+@pytest.mark.parametrize("family", ["ling", "mellum", "zaya", "nemotron", "dots3"])
 def test_prompts_that_warm_side_by_side_are_held_to_the_reference(family, request):
     """Three chunked prompts admitted together: from their second chunk on
     a tick sends their chunks as one program (three rows padded to four,
@@ -517,7 +634,8 @@ def test_prompts_that_warm_side_by_side_are_held_to_the_reference(family, reques
     assert chunks == 4 + 4 + 3
     # Alone: each prompt's first chunk and the longest's fourth; together: two of three, one of two.
     assert after["prefill_chunk_programs"] - before["prefill_chunk_programs"] < chunks
-    gap = {"ling": _worst_gap, "mellum": _mellum_gap, "zaya": _zaya_gap, "nemotron": _nemotron_gap}[family]
+    gap = {"ling": _worst_gap, "mellum": _mellum_gap, "zaya": _zaya_gap, "nemotron": _nemotron_gap,
+           "dots3": _dots3_gap}[family]
     for p, o in zip(prompts, outs):
         assert len(o) == 4 and gap(s, p, o) <= GAP
 
@@ -532,7 +650,8 @@ SLOTS, MAX_LEN, WINDOW, S = 6, 128, 64, 8
 ROWS = ((4, 0, 8), (1, 40, 8), (3, 19, 5))
 
 
-@pytest.fixture(scope="module", params=["ling-tiny", "mellum-tiny", "zaya-tiny", "exaone_moe-tiny", "nemotron_h-tiny"])
+@pytest.fixture(scope="module", params=[
+    "ling-tiny", "mellum-tiny", "zaya-tiny", "exaone_moe-tiny", "nemotron_h-tiny", "dots3_note-tiny"])
 def rows_case(request):
     """A serving model, its parameters, and slots whose state is what
     ``prefill_row`` left of each row's prompt so far; every slot that is
@@ -605,7 +724,8 @@ def test_rows_of_a_group_get_what_each_gets_alone(rows_case, n_rows):
     tokens_routed = sum(n for _, _, n in rows)
     behind = sum(n - (before == 0) for _, before, n in rows) if model.draft else 0
     assert int(counters[0]) == (tokens_routed * experts + behind) * model.cfg.n_experts_per_tok
-    assert model.rows_in_place == (not model.cfg.layers_of("mla"))
+    # Latent rows attended whole (Ling's) are the ones taken out and put back.
+    assert model.rows_in_place == (bool(model.cfg.latent_block) or not model.cfg.layers_of("mla"))
     named = dict(zip(model.counter_names, np.asarray(counters).tolist()))
     if "attn_rows_dense_full_prefill" in named:
         # XLA's twin (float32 state): every row's window read whole, the pad
