@@ -167,7 +167,7 @@ import jax
 import jax.numpy as jnp
 
 from generativeaiexamples_tpu.models.llama import rms_norm
-from generativeaiexamples_tpu.ops import cca, gqa, gqa_decode, kda, mla, moe, ssm
+from generativeaiexamples_tpu.ops import cca, gqa, gqa_decode, kda, mla, mla_chunk, moe, ssm
 from generativeaiexamples_tpu.ops.dispatch import record
 from generativeaiexamples_tpu.ops.rope import (
     NO_ROPE, RopeSpec, apply_rope_partial, apply_rope_spec, rope_spec, yarn_mscale,
@@ -199,9 +199,12 @@ CCA_TAILS = ("conv0", "conv1", "v_prev")
 # with such layers returns them after ``moe.COUNTERS``.
 ATTN_COUNTERS = ("read_window", "read_full", "dense_window", "dense_full")
 # Rows of the latent cache the MLA layers read, and what they would have
-# read with every row's whole window (``kv_bucket``) read.  A
-# ``LatentConfig`` model returns them after ``moe.COUNTERS``.
-LATENT_COUNTERS = ("read_latent", "dense_latent")
+# read with every row's whole window (``kv_bucket``) read; of the rows
+# read, those a prefill chunk's Pallas kernel walked (``ops/mla_chunk.py``:
+# ``read_latent``'s own count where its gate admits the call, 0 where it
+# does not and in every decode step).  A ``LatentConfig`` model returns
+# them after ``moe.COUNTERS``.
+LATENT_COUNTERS = ("read_latent", "dense_latent", "kernel_latent")
 # What the indexer of an ``mla`` layer did (``IndexedLatentConfig``):
 # (query, position) pairs it scored; (query, row) pairs the attention
 # scored: in a prefill call every row of every block walked (``read_latent``)
@@ -496,12 +499,19 @@ class LatentConfig(HybridConfig):
     softmax_mscale: float = 1.0
     # A sigmoid gate a head on the attention's output (Ling's).
     mla_out_gate: bool = True
-    # Prefill attends a block of this many latent rows at a time
-    # (``mla.attend_blocks``).  At the mistral4 family's sizes a block's
-    # float32 scores are 32 heads x 256 queries x 1,024 keys = 33.6 MB,
-    # its expansion 12.6 MB; a chunk program of 8 rows at position 12,288
-    # takes 11.8 ms a layer on a v5e, 12.6 with blocks of 512, 13.8 with
-    # blocks of 2,048.
+    # Prefill attends a block of this many latent rows at a time: in
+    # ``ops/mla_chunk.py``'s kernel where its gate admits the chunk (bf16
+    # state on one TPU device: a block's expansion, scores and
+    # probabilities stay in VMEM; 0.37 ms a row-block of 1,024 for 128
+    # heads x 256 queries on a v5e, 0.36-0.38 with blocks of 512, and 0.064
+    # for the mistral4 family's 32 heads: PERF.md, PR 48), in
+    # ``mla.attend_blocks`` where it does not (float32 state, the CPU,
+    # several devices).  There a block's float32 scores reach HBM: at the
+    # mistral4 family's sizes 32 heads x 256 queries x 1,024 keys = 33.6
+    # MB, its expansion 12.6 MB (a chunk program of 8 rows at position
+    # 12,288 took 11.8 ms a layer, 12.6 with blocks of 512, 13.8 with
+    # blocks of 2,048: PR 38), at the dots3_note family's 134 MB (0.97 ms
+    # a row-block, 0.46-0.56 with blocks of 256).
     latent_block: int = 1024
     # A decode step walks each decoding row's blocks of this many latent
     # rows up to its length (``mla.attend_absorbed_blocks``), one row
@@ -1720,7 +1730,9 @@ def _index_q_k(h, c_q, lp, pos, cfg: HybridConfig):
     return q_i, w, rotated(k)
 
 
-def _mla_mixer(h, lp, st, pos, valid, n_valid, cfg: HybridConfig, window: int, apart: bool):
+def _mla_mixer(
+    h, lp, st, pos, valid, n_valid, cfg: HybridConfig, window: int, apart: bool, mesh=None
+):
     """An ``mla`` layer, Ling's form, the mistral4 family's or the
     dots3_note family's by what the configuration says (a low-rank query,
     YaRN on the rotary part, a query scale by position, no output gate,
@@ -1742,7 +1754,11 @@ def _mla_mixer(h, lp, st, pos, valid, n_valid, cfg: HybridConfig, window: int, a
     keeps each query's ``index_topk`` highest (``mla.select_mask``) and
     hands the kept pairs to the block walk, which still expands and scores
     every block up to the row's length; a shorter row keeps every position
-    it sees and is attended as without an indexer.  A decode step scores
+    it sees and is attended as without an indexer.  The walk of a prefill
+    chunk, selected or not, is ``ops/mla_chunk.py``'s kernel for all rows
+    of the call at once where ``use_latent_chunk`` admits it (the counter
+    ``kernel_latent``: the rows it walked), and ``mla.attend_blocks`` a row
+    at a time where it does not.  A decode step scores
     every slot's first ``window`` index keys in one product, gathers each
     slot's ``index_topk`` highest rows (``mla.select_rows``) and attends
     over them alone (``mla.attend_selected``); a slot that does not decode
@@ -1801,9 +1817,22 @@ def _mla_mixer(h, lp, st, pos, valid, n_valid, cfg: HybridConfig, window: int, a
 
         return jax.lax.map(one, (q_nope, q_rope, mine, pos, lengths, *more))
 
+    def chunk_kernel(site: str, masked: bool) -> bool:
+        return record(site, mla_chunk.use_latent_chunk(
+            s=s, q_dtype=q_nope.dtype, rows_dtype=latent.dtype, width=width, rank=rank,
+            nope=nope, v_dim=vd, heads=H, rows=T, window=span, block=cfg.latent_block,
+            masked=masked, mesh=mesh,
+        ))
+
+    def chunk(lengths, **selection):
+        return mla_chunk.attend_latent_chunk(
+            q_nope, q_rope, latent, q_pos=pos, lengths=lengths, slot=mine, window=span,
+            block=cfg.latent_block, **selection, **sizes
+        )
+
     if topk and s > 1:
         record(f"index_scores b={b} s={s} t={span}", False)
-        record(f"attn_latent_sparse b={b} s={s} t={span} k={topk}", False)
+        kernel = chunk_kernel(f"attn_latent_chunk b={b} s={s} t={span} k={topk}", True)
         lengths = jnp.where(n_valid > 0, pos[:, 0] + n_valid, 0)
         selects = lengths > topk  # a shorter row keeps every position it sees
         walked = mla.rows_in_blocks(lengths, span, cfg.latent_block)
@@ -1819,21 +1848,39 @@ def _mla_mixer(h, lp, st, pos, valid, n_valid, cfg: HybridConfig, window: int, a
             seen_latent=seen.sum(),
         )
 
-        def attend(qn, qr, lat, qi, wi, *, q_pos, lengths, block, slot, window, **kw):
+        def kept(qi, wi, q_pos, lengths, slot):
+            """The pairs the indexer keeps of one row, (1, s, span) bool."""
+            scores = mla.index_scores_blocks(
+                qi, wi, index_k, q_pos, lengths, block=cfg.latent_block, slot=slot, window=span
+            )
+            return mla.select_mask(scores, topk)
+
+        def attend(qn, qr, lat, qi, wi, *, q_pos, lengths, slot, **kw):
             walk = functools.partial(
-                mla.attend_blocks, qn, qr, lat, q_pos=q_pos, lengths=lengths, block=block,
-                slot=slot, window=window, **kw
+                mla.attend_blocks, qn, qr, lat, q_pos=q_pos, lengths=lengths, slot=slot, **kw
+            )
+            return jax.lax.cond(
+                lengths[0] > topk,
+                lambda: walk(allowed=kept(qi, wi, q_pos, lengths, slot)),
+                walk,
             )
 
-            def sparse():
-                scores = mla.index_scores_blocks(
-                    qi, wi, index_k, q_pos, lengths, block=block, slot=slot, window=window,
-                )
-                return walk(allowed=mla.select_mask(scores, topk))
+        def mask(row):
+            """A row's selection as the kernel's mask; that of a row that
+            does not select is not read."""
+            qi, wi, p, n, sl = (x[None] for x in row)
+            return jax.lax.cond(
+                n[0] > topk,
+                lambda: kept(qi, wi, p, n, sl)[0].astype(jnp.int8),
+                lambda: jnp.zeros((s, span), jnp.int8),
+            )
 
-            return jax.lax.cond(lengths[0] > topk, sparse, walk)
-
-        o = in_place(attend, cfg.latent_block, lengths, q_i, w_i)
+        if kernel:
+            read["kernel_latent"] = walked.sum()
+            allowed = jax.lax.map(mask, (q_i, w_i, pos, lengths, mine))
+            o = chunk(lengths, allowed=allowed, selects=selects)
+        else:
+            o = in_place(attend, cfg.latent_block, lengths, q_i, w_i)
     elif topk:
         record(f"index_scores b={b} s=1 t={span}", False)
         record(f"attn_latent_sparse_decode b={b} t={span} k={min(topk, span)}", False)
@@ -1852,12 +1899,16 @@ def _mla_mixer(h, lp, st, pos, valid, n_valid, cfg: HybridConfig, window: int, a
         )
         o = mla.attend_selected(q_nope, q_rope, rows, idx=idx, keep=keep, **sizes)
     elif cfg.latent_block and s > 1:
-        record(f"attn_latent b={b} s={s} t={span}", False)
+        kernel = chunk_kernel(f"attn_latent_chunk b={b} s={s} t={span}", False)
         # Rows each row holds once its tokens are written; a row with
         # nothing that counts reads nothing.
         lengths = jnp.where(n_valid > 0, pos[:, 0] + n_valid, 0)
         read["read_latent"] = mla.rows_in_blocks(lengths, span, cfg.latent_block).sum()
-        o = in_place(mla.attend_blocks, cfg.latent_block, lengths)
+        if kernel:
+            read["kernel_latent"] = read["read_latent"]
+            o = chunk(lengths)
+        else:
+            o = in_place(mla.attend_blocks, cfg.latent_block, lengths)
     elif cfg.latent_block:
         record(f"attn_latent_decode b={b} t={span}", False)
         # A decode step: a row that does not decode reads nothing.
@@ -2214,7 +2265,7 @@ def _mix(
         y, st, read = _kda_mixer(h, lp, st, valid, n_valid, cfg, mesh)
         names = STATE_COUNTERS
     elif mixer == "mla":
-        y, st, named = _mla_mixer(h, lp, st, pos, valid, n_valid, cfg, window, apart)
+        y, st, named = _mla_mixer(h, lp, st, pos, valid, n_valid, cfg, window, apart, mesh)
         names, read = tuple(named), tuple(named.values())
     elif mixer == "mla_window":
         y, st, named = _mla_window_mixer(h, lp, st, pos, valid, n_valid, cfg, window, apart)

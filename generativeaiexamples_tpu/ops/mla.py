@@ -33,7 +33,15 @@ a token (576 values at Ling's published widths, 320 at Mistral-Small-4's).
   for a decode step.
 
 All are plain XLA and give the same numbers up to rounding:
-``tests/test_hybrid_ops.py`` holds one against the other.
+``tests/test_hybrid_ops.py`` holds one against the other.  A prefill
+chunk's walk (:func:`attend_blocks` as ``models/hybrid.py``'s ``mla``
+mixer calls it, with ``allowed`` or without) runs in
+``ops/mla_chunk.py``'s Pallas kernel where its gate admits the call (bf16
+rows of whole lane tiles on one TPU device); :func:`attend_blocks` stays
+its XLA twin, the tests' oracle (``tests/test_latent_chunk.py``) and what
+serves everything the gate refuses: float32 state (the rehearsals, the
+reference check's own forms), the CPU, several devices, a decode step and
+its draft (two queries a row).
 
 The rotation is over adjacent pairs (:func:`rope_interleaved`), with the
 plain frequencies of a ``theta`` or those of a ``RopeSpec``

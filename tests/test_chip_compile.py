@@ -404,6 +404,45 @@ def test_a_chunk_program_attends_over_its_slots_rows_where_they_lie(one_chip, fa
     assert memory.alias_size_in_bytes >= 2 * layers * slots * window * width * 2 * 0.99
 
 
+# (``mla`` layers whose chunk walks blocks of latent rows, query heads)
+LATENT_LAYERS = {"mistral4": (6, 32), "dots3_note": (3, 128)}
+
+
+@pytest.mark.parametrize("family", sorted(LATENT_LAYERS))
+def test_a_latent_chunk_program_keeps_a_blocks_scores_on_the_chip(one_chip, family, monkeypatch):
+    """The largest chunk program of the two latent families (8 rows; H 32
+    over slots of 32,768, H 128 over slots of 16,384 with the indexer's
+    selection as the mask), with ``ops/mla_chunk.py``'s gate believing it
+    is on the chip: every ``mla`` layer's walk is the kernel over the
+    slots' leaf as it lies, and nothing of heads x queries x block (a
+    block's float32 scores, 33.6 and 134 MB, which XLA's form wrote and
+    read back: PERF.md, PR 48) nor a block's expansion is made outside it."""
+    from generativeaiexamples_tpu.ops import dispatch, gqa_decode, moe
+
+    monkeypatch.setattr(moe, "platform_of", lambda mesh: "tpu")
+    monkeypatch.setattr(gqa_decode, "platform_of", lambda mesh: "tpu")
+    config, rows, window = GROUP_PROGRAMS[family]
+    dispatch.TAKEN.clear()
+    compiled, serving, engine = _chunk_program(one_chip, config, rows, window)
+    layers, heads = LATENT_LAYERS[family]
+    slots, chunk = int(engine["max_batch"]), int(engine["prefill_chunk_tokens"])
+    sites = {s: p for s, p in dispatch.TAKEN.items() if s.startswith("attn_latent_chunk")}
+    assert sites and set(sites.values()) == {"pallas"}, sites
+    text = compiled.as_text()
+    assert text.count("latent_chunk_attention") >= layers
+    block = serving.cfg.latent_block
+    assert not re.search(rf"(?:f32|bf16)\[(?:\d+,)?{heads},{chunk},{block}\]", text)  # a block's scores
+    sz = serving.cfg.latent_sizes("mla")
+    kv = sz.qk_nope_head_dim + sz.v_head_dim  # a block's expansion
+    assert not re.search(rf"bf16\[(?:\d+,)?{block},(?:{heads},{kv}|{heads * kv})\]", text)
+    _no_window_sized_temporaries(
+        text, slots=slots, rows=rows, window=window, H=heads, width=serving.cfg.latent_width
+    )
+    memory = compiled.memory_analysis()
+    print(family, "latent chunk program temporaries", memory.temp_size_in_bytes)
+    assert memory.temp_size_in_bytes < SPARE_BY_FAMILY[family]
+
+
 # (window layers, query heads, rows of a ring, a chunk's tokens)
 RING_LAYERS = {"mellum": (9, 32, 1024, 256), "exaone": (4, 64, 128, 256)}
 
@@ -484,13 +523,15 @@ def test_a_decode_chunk_keeps_the_rings_wide_form(one_chip, family, monkeypatch)
     assert not any("attn_window_chunk" in site for site in dispatch.TAKEN)
 
 
-def _no_window_sized_temporaries(text: str, *, slots: int, rows: int, window: int) -> None:
+def _no_window_sized_temporaries(
+    text: str, *, slots: int, rows: int, window: int, H: int = 32, width: int = 384
+) -> None:
     """Nothing of a window's size is made in a program of the latent
     family: no scores of heads x queries x window, no expansion of a
     window through ``W_kvb`` (window x heads x 192), and the slots' state
     (slots, window, 384) is a parameter, scattered into and handed on, but
     never copied, nor is a group's window of it gathered."""
-    H, s, width = 32, 256, 384
+    s = 256
     assert not re.search(rf"(?:f32|bf16)\[(?:\d+,)?{H},{s},{window}\]", text)
     assert not re.search(rf"bf16\[(?:\d+,)?{window},{H},(?:192|64|128)\]", text)
     assert not re.search(rf"bf16\[(?:\d+,)?{window},{H * 192}\]", text)

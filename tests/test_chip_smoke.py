@@ -302,7 +302,7 @@ def test_hybrid_phase_rehearsal_of_the_latent_model_and_its_controls(control, ca
     assert line["positions"] == {"prefill": 67, "decode": 8}
     # The shapes of the scheduler's programs: a chunk beside a pad row over
     # the whole slot (256 rows here), a decode step over both slots.
-    assert "attn_latent b=2 s=16 t=256" in line["kernel_paths"]
+    assert "attn_latent_chunk b=2 s=16 t=256" in line["kernel_paths"]
     assert "attn_latent_decode b=2 t=256" in line["kernel_paths"]
     assert line["within_limits"] == (not control)
     if control == "no_attn_scale":  # a(p) is 1 below the original context: the lowest tenth is sound
@@ -410,7 +410,7 @@ def test_hybrid_phase_rehearsal_of_the_indexed_latent_model_and_its_controls(con
     assert line["positions"] == {"prefill": 67, "decode": 8}
     # The shapes of the scheduler's programs: a chunk beside a pad row, a
     # decode step over both slots.
-    for site in ("index_scores b=2 s=16 t=256", "attn_latent_sparse b=2 s=16 t=256 k=24",
+    for site in ("index_scores b=2 s=16 t=256", "attn_latent_chunk b=2 s=16 t=256 k=24",
                  "index_scores b=2 s=1 t=256", "attn_latent_sparse_decode b=2 t=256 k=24"):
         assert line["kernel_paths"][site] == "xla"
     assert line["within_limits"] == (not control)

@@ -90,7 +90,7 @@ def test_the_published_keys_give_the_published_model():
     # Rings beside rows: a hit needs a snapshot, and no draft is served.
     assert not cut.rows_only and cut.draft == "" and not cut.has_attn_counters
     assert cut.row_counters == (
-        "read_latent", "dense_latent", "index_pairs", "read_selected", "read_index",
+        "read_latent", "dense_latent", "kernel_latent", "index_pairs", "read_selected", "read_index",
         "seen_latent", "read_window", "dense_window")
     # 576 and 1,088 values a row, stored in whole lanes.
     assert (cut.latent_width, cut.row_width("mla_window"), cut.ring_rows(16384)) == (640, 1152, 513)
@@ -145,7 +145,7 @@ def test_the_tiny_size_by_hand(params):
     model = serving_model(CFG, None, T)
     assert not model.cut_anywhere and model.rows_in_place and model.chunk_windows(16) == (T,)
     assert model.snapshot_bytes == 3 * 13 * 128 * 4
-    assert model.counter_names[-16:-8] == tuple(f"attn_rows_{n}_decode" for n in CFG.row_counters)
+    assert model.counter_names[-18:-9] == tuple(f"attn_rows_{n}_decode" for n in CFG.row_counters)
 
 
 @pytest.mark.parametrize("bad, match", [

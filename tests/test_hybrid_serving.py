@@ -605,12 +605,13 @@ def test_dots3_metrics_and_kernel_paths_are_exported(dots3):
                  "engine_attn_rows_read_index_decode_total", "engine_attn_rows_seen_latent_decode_total",
                  "engine_attn_rows_read_latent_decode_total", "engine_attn_rows_dense_latent_prefill_total",
                  "engine_attn_rows_read_window_prefill_total", "engine_attn_rows_dense_window_decode_total",
+                 "engine_attn_rows_kernel_latent_prefill_total",
                  "engine_state_bytes_full", "engine_state_bytes_window",
                  "engine_state_snapshot_bytes", "engine_state_snapshots_saved_total"):
         assert f"\n{name} " in metrics, name
     assert "engine_attn_rows_read_full_decode_total" not in metrics  # the GQA kinds' are theirs
     paths = health["runtime"]["kernel_paths"]
-    for site in ("index_scores b=1 s=32 t=256", "attn_latent_sparse b=1 s=32 t=256 k=24",
+    for site in ("index_scores b=1 s=32 t=256", "attn_latent_chunk b=1 s=32 t=256 k=24",
                  "attn_latent_ring b=1 s=32 t=13", "attn_latent_ring b=4 s=1 t=13"):
         assert paths[site] == "xla", sorted(paths)
     assert any(site.startswith("attn_latent_sparse_decode b=4 t=") and site.endswith(" k=24") for site in paths)
@@ -986,11 +987,12 @@ def test_mistral4_prompts_warm_side_by_side_and_metrics_are_exported(mistral4):
         loop.close()
     for name in ("engine_attn_rows_read_latent_decode_total", "engine_attn_rows_dense_latent_decode_total",
                  "engine_attn_rows_read_latent_prefill_total", "engine_attn_rows_dense_latent_prefill_total",
+                 "engine_attn_rows_kernel_latent_prefill_total",
                  "engine_state_bytes_full", "engine_moe_experts_touched_total"):
         assert f"\n{name} " in metrics, name
     assert "engine_attn_rows_read_full_decode_total" not in metrics  # the GQA kinds' four are theirs
     paths = runtime_report()["kernel_paths"]
-    assert any(site.startswith("attn_latent b=") for site in paths)
+    assert any(site.startswith("attn_latent_chunk b=") for site in paths)
     assert any(site.startswith("attn_latent_decode b=4") for site in paths)
     ticks = mistral4.tick_records(64)
     assert ticks and all("kv_bucket" in r for r in ticks)

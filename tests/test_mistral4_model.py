@@ -87,7 +87,7 @@ def test_the_published_keys_give_the_published_model():
     assert (cut.latent_block, cut.latent_decode_block) == (1024, 2048)
     # A state of rows alone: a hit is cut at any row, and a snapshot holds nothing.
     assert cut.rows_only and cut.snapshot_bytes() == 0 and cut.draft == ""
-    assert cut.row_counters == hybrid.LATENT_COUNTERS and cut.n_counters == len(hybrid.moe.COUNTERS) + 2
+    assert cut.row_counters == hybrid.LATENT_COUNTERS and cut.n_counters == len(hybrid.moe.COUNTERS) + 3
     # 320 values a token a layer, stored in rows of whole lanes.
     assert cut.kv_lora_rank + cut.qk_rope_head_dim == 320 and cut.latent_width == 384
     assert CFG.layer_kinds == whole.layer_kinds[:3]  # the tiny size keeps the pattern
@@ -152,7 +152,7 @@ def test_cold_forward_matches_the_reference_on_both_sides_of_the_original_contex
     # Rows read: each row's own whole blocks of 16 up to its length (a row
     # attends alone, in a cold batch as in a chunk program), in each of
     # three layers; dense: three windows a layer.
-    assert list(np.asarray(counters)[-2:]) == [3 * (80 + 64 + 48), 3 * 3 * T]
+    assert list(np.asarray(counters)[-3:]) == [3 * (80 + 64 + 48), 3 * 3 * T, 0]
     # A padded position wrote nothing: the rows past a row's length are zero.
     for layer in state:
         lat = np.asarray(layer["latent"])
@@ -191,7 +191,7 @@ def test_chunked_prefill_then_decode_through_the_cache_matches_the_reference(par
         np.testing.assert_allclose(np.asarray(logits)[1], want[0][pos], atol=ATOL)
     # The decode step walked the one decoding row's whole blocks of 16 up
     # to its 80 rows in each layer; the other slot read nothing.
-    assert list(np.asarray(counters)[-2:]) == [3 * 80, 3 * 2 * T]
+    assert list(np.asarray(counters)[-3:]) == [3 * 80, 3 * 2 * T, 0]
 
 
 def test_the_chunks_of_several_slots_read_their_rows_in_place(params, tokens, want):
@@ -218,10 +218,10 @@ def test_the_chunks_of_several_slots_read_their_rows_in_place(params, tokens, wa
         got = np.asarray(model.logits(params, hidden))
         for r in rows:
             np.testing.assert_allclose(got[r], want[r][at : at + 16], atol=ATOL)
-        read.append(list(np.asarray(counters)[-2:]))
+        read.append(list(np.asarray(counters)[-3:]))
     # Two live rows, whole blocks of 16 up to their length, three layers;
     # the dense count takes all three rows of the program's window.
-    assert read == [[3 * 2 * (at + 16), 3 * 3 * T] for at in range(0, 48, 16)]
+    assert read == [[3 * 2 * (at + 16), 3 * 3 * T, 0] for at in range(0, 48, 16)]  # float32: no kernel
     for layer in state:
         lat = np.asarray(layer["latent"])
         assert (lat[3] == 7.0).all() and not lat[1].any()  # the pad row's slot, a slot not named
