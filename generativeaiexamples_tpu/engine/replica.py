@@ -74,6 +74,7 @@ from generativeaiexamples_tpu.engine.health import (
 )
 from generativeaiexamples_tpu.engine.router import ReplicaView, Router
 from generativeaiexamples_tpu.engine.scheduler import (
+    DISPATCH_STAGES,
     STARVED_PHASES,
     TICK_PHASES,
     Request,
@@ -1153,6 +1154,8 @@ class EnginePool:
         *(f"tick_phase_{p}_s" for p in TICK_PHASES),
         "device_starved_s",
         *(f"device_starved_{p}_s" for p in STARVED_PHASES),
+        "dispatch_sites",
+        *(f"dispatch_{stage}_s" for stage in DISPATCH_STAGES),
         "queue_wait_s_sum",
         "queue_wait_count",
         "warm_s_sum",

@@ -174,8 +174,15 @@ class _Chunk:
 #   telemetry    _note_tick and the tick record
 TICK_PHASES = ("idle", "plan", "dispatch", "wait_device", "emit", "telemetry")
 # The phases starved-device time is split by: there is no work to give
-# in ``idle`` and the device is by definition busy in ``wait_device``.
+# in ``idle``, and in ``wait_device`` the device is busy but for the copy
+# to the host behind a ready output (``_TickClock``).
 STARVED_PHASES = ("plan", "dispatch", "emit", "telemetry")
+# What a dispatch is split into, in order; the counters a site books under
+# ``Stats.lock`` behind its jitted calls are the rest of the phase.
+#   h2d   numpy staging, the host-to-device arrays, ``_next_key``
+#   call  the site's jitted calls: the step program and what is enqueued
+#         behind it (a boundary's snapshot, a graft, the draft's prefill)
+DISPATCH_STAGES = ("h2d", "call")
 # Device bytes the snapshots of recurrent state may hold (StateSnapshots),
 # beside the slots: a tenth of a 16 GB chip, about 120 snapshots of six
 # KDA layers at the published widths.
@@ -197,41 +204,42 @@ class _TickClock:
     phase that ends and starts the next, so ``Stats.tick_phase_s``
     partitions the thread's wall time.  Each phase is also a
     ``jax.profiler.TraceAnnotation("tick/<phase>")`` on the thread's line
-    of the profiler's host plane — the same clock as the device plane —
-    which costs a check of an atomic while no trace runs.
+    of the profiler's host plane (the device plane's clock), which costs
+    a check of an atomic while no trace runs.  ``dispatch`` opens in
+    stage ``h2d``, the site marks ``stage("call")`` in front of its
+    jitted calls and ``dispatched`` ends it (``DISPATCH_STAGES``): sums
+    in ``Stats.dispatch_stage_s``, annotations ``tick/dispatch/<stage>``.
 
-    Starved-device time: once the fetch of the last-enqueued program has
-    returned (``fetched``), nothing is queued on the device until the
-    next jitted call returns (``dispatched``); the elapsed time of every
-    phase in between goes to ``Stats.device_starved_s`` too (``idle``
-    excepted).  It is a lower bound of the device's idle time: a tick
-    whose last program is never fetched (a warming chunk with no decode
-    chunk behind it) opens no interval, a gap between two programs of one
-    tick is not seen at all, and a fetch that returns a first token while
-    the graft dispatched behind its prefill still runs opens the interval
-    that much early.  Since the next tick's warming chunks are dispatched
-    behind the decode chunk it is blind wherever that happens: the chunks
-    are the last programs enqueued and are never fetched, so the fetch of
-    the decode chunk's tokens opens nothing, and a device that runs out of
-    those chunks before the next tick's first dispatch idles unseen.  What
-    it still times is a tick with no continuing warming slot (the host's
-    whole gap, as before); the device's idle share is the trace's to say.
-    A decode chunk sent ahead (a full house) is another matter: it IS
-    fetched, a tick later, so the fetch of the chunk before it opens no
-    interval because work is queued behind it, and that is true.
+    Starved-device time, the drain rule: ``dispatched(sentinel)`` keeps
+    an output of the site's LAST program, and the device is starved from
+    the moment the newest sentinel is ready until the next
+    ``dispatched``; each phase's time in between goes to
+    ``Stats.device_starved_s`` too (``STARVED_PHASES``).  The moment is
+    learnt by ``is_ready()`` at every phase change, stage mark and
+    ``poll()`` (in front of each host-to-device array, once a lane while
+    emitting), so an interval opens late by at most one poll interval:
+    a lower bound of the device's idle time.  A fetch of that output is
+    one more way to learn it (the ``enter`` behind it); its tail, the
+    copy to the host behind a ready sentinel, is booked nowhere
+    (0.4 ms a fetch on a v5e's host, in the one tick of five that
+    fetches its newest program: under 0.1 ms a tick, so ``wait_device``
+    is no starved phase).  Unseen: a gap between two programs of one site.
     """
 
     def __init__(self, stats: "Stats") -> None:
         self._stats = stats
         self._span: Optional[jax.profiler.TraceAnnotation] = None
-        self._seq = 0  # dispatch sites returned from so far
+        self._stage: Optional[str] = None
+        self._stage_span: Optional[jax.profiler.TraceAnnotation] = None
+        # The newest program's output, until a poll finds it ready.
+        self._sentinel = None
         # What the running phase was entered with: an executable that JAX
         # makes inside it is recorded with them (``Scheduler._asked``).
         self.facts: dict = {}
 
     def _lap(self) -> None:
-        """Book the time since the last lap to the running phase (under
-        the lock ``Stats.snapshot`` reads it with)."""
+        """Book the time since the last lap to the running phase and
+        stage (under the lock ``Stats.snapshot`` reads them with)."""
         st = self._stats
         now = time.perf_counter()
         with st.lock:
@@ -240,21 +248,69 @@ class _TickClock:
                 st.tick_phase_s[st.tick_phase] += dt
                 if st.device_starved and st.tick_phase in st.device_starved_s:
                     st.device_starved_s[st.tick_phase] += dt
+                if self._stage is not None:
+                    st.dispatch_stage_s[self._stage] += dt
             st.tick_phase_since = now
+
+    def poll(self) -> None:
+        """Has the device run out of work?  The time up to the poll that
+        first finds the sentinel ready is booked as before, what follows
+        as starved; nothing is asked again until the next ``dispatched``."""
+        sentinel = self._sentinel
+        if sentinel is None:
+            return
+        try:
+            if not sentinel.is_ready():
+                return
+        except RuntimeError:
+            # Donated since (a state restored in ``plan``, a fault's
+            # recovery): the program that took it is queued behind it.
+            self._sentinel = None
+            return
+        self._sentinel = None
+        self._lap()
+        self._stats.device_starved = True
+
+    def _mark(self) -> None:
+        """A phase or stage boundary: a poll point, then the lap."""
+        self.poll()
+        self._lap()
 
     def enter(self, phase: str, **facts) -> None:
         """End the current phase and start ``phase``; ``facts`` (a
         dispatch's program and shapes) ride on the trace annotation."""
-        self._lap()
+        self._mark()
         self._stats.tick_phase = phase
         self.facts = facts
         self.end_span()
         self._span = jax.profiler.TraceAnnotation(_SPAN_NAMES[phase], **facts)
         self._span.__enter__()
+        if phase == "dispatch":
+            self._open_stage("h2d")
+
+    def stage(self, name: str) -> None:
+        """Inside ``dispatch``: the stage before ends, ``name`` starts."""
+        self._mark()
+        self._close_stage()
+        self._open_stage(name)
+
+    def _open_stage(self, name: str) -> None:
+        self._stage = name
+        self._stage_span = jax.profiler.TraceAnnotation(
+            _STAGE_SPAN_NAMES[name], **self.facts
+        )
+        self._stage_span.__enter__()
+
+    def _close_stage(self) -> None:
+        self._stage = None
+        if self._stage_span is not None:
+            self._stage_span.__exit__(None, None, None)
+            self._stage_span = None
 
     def end_span(self) -> None:
-        """Close the open annotation (the phase itself runs on), so that
-        it nests inside the loop's step annotation."""
+        """Close the open annotations (the phase itself runs on), so that
+        they nest inside the loop's step annotation."""
+        self._close_stage()
         if self._span is not None:
             self._span.__exit__(None, None, None)
             self._span = None
@@ -264,6 +320,7 @@ class _TickClock:
         belongs to no phase."""
         self._stats.tick_phase = None
         self._stats.device_starved = False
+        self._sentinel = None
         self.enter(phase)
 
     def stop(self) -> None:
@@ -272,24 +329,19 @@ class _TickClock:
         self._stats.tick_phase = None
         self.end_span()
 
-    def dispatched(self) -> int:
+    def dispatched(self, sentinel) -> None:
         """A dispatch site's jitted calls have returned: the device has
-        work.  Returns the ticket its finalizer hands to ``fetched``."""
+        work until ``sentinel``, an output of the last of them that
+        answers ``is_ready()``, is ready."""
         self._lap()
+        self._close_stage()
+        self._sentinel = sentinel
         self._stats.device_starved = False
-        self._seq += 1
-        return self._seq
-
-    def fetched(self, ticket: int) -> None:
-        """A result was fetched; if nothing was dispatched behind it the
-        device is starved from here on."""
-        if ticket == self._seq:
-            self._lap()
-            self._stats.device_starved = True
+        self._stats.dispatch_sites += 1
 
     def sums(self) -> tuple:
         """(phase sums..., starved) up to now, for a tick's record."""
-        self._lap()
+        self._mark()
         st = self._stats
         return tuple(st.tick_phase_s.values()) + (
             sum(st.device_starved_s.values()),
@@ -297,6 +349,7 @@ class _TickClock:
 
 
 _SPAN_NAMES = {p: f"tick/{p}" for p in TICK_PHASES}
+_STAGE_SPAN_NAMES = {s: f"tick/dispatch/{s}" for s in DISPATCH_STAGES}
 
 
 def make_prefill_suffix_rows(model):
@@ -426,6 +479,11 @@ class Stats:
         # snapshot() sums the parts into device_starved_s.
         self.device_starved_s = dict.fromkeys(STARVED_PHASES, 0.0)
         self.device_starved = False
+        # Dispatch sites returned from (``_TickClock.dispatched``), and
+        # the ``dispatch`` phase's seconds by stage; their rest is the
+        # sites' counters.
+        self.dispatch_sites = 0
+        self.dispatch_stage_s = dict.fromkeys(DISPATCH_STAGES, 0.0)
         # Request lifecycle: submit -> slot claim (queue wait, counted at
         # the claim) and claim -> first token fetched (counted at the
         # first token); the two add up to ttft_sum request by request.
@@ -504,6 +562,8 @@ class Stats:
                 **{f"tick_phase_{p}_s": v for p, v in phase_s.items()},
                 "device_starved_s": sum(starved_s.values()),
                 **{f"device_starved_{p}_s": v for p, v in starved_s.items()},
+                "dispatch_sites": self.dispatch_sites,
+                **{f"dispatch_{k}_s": v for k, v in self.dispatch_stage_s.items()},
                 "queue_wait_s_sum": self.queue_wait_s_sum,
                 "queue_wait_count": self.queue_wait_count,
                 "warm_s_sum": self.warm_s_sum,
@@ -1162,8 +1222,22 @@ class Scheduler:
         return jax.device_put(np.zeros((self.max_batch,), np.int32))
 
     def _next_key(self) -> jax.Array:
+        self._clock.poll()
         self._key, sub = jax.random.split(self._key)
         return sub
+
+    def _h2d(self, *host) -> list:
+        """A dispatch's host arrays (or scalars) on the device, the
+        clock's poll point in front of each: a transfer is a third of a
+        millisecond of host time and more beside the server's other
+        threads (PERF.md section 5), and a device that runs out of work
+        while its next program's arrays go over should not wait for the
+        last of them to be seen."""
+        out = []
+        for a in host:
+            self._clock.poll()
+            out.append(jnp.asarray(a))
+        return out
 
     def _note_aux(self, aux=None) -> None:
         """Keep a step program's counters until they can be fetched
@@ -1198,21 +1272,26 @@ class Scheduler:
             return common
         return self._snapshots.deepest(req.token_ids, common)
 
-    def _save_boundary(self, slot: _Slot, slot_idx: int, depth: int) -> None:
+    def _save_boundary(
+        self, slot: _Slot, slot_idx: int, depth: int
+    ) -> Optional[jax.Array]:
         """After a prefill chunk that ended at ``depth``: keep the slot's
-        recurrent state if ``depth`` is a snapshot boundary."""
+        recurrent state if ``depth`` is a snapshot boundary.  Returns an
+        array of the snapshot it enqueued (the clock's sentinel), or None."""
         snaps = self._snapshots
         if snaps is None or depth % snaps.every or not snaps.capacity:
-            return
+            return None
         key = snaps.key(slot.history, depth)
         if key in snaps:
             snaps.get(key)  # fresh again
-            return
-        evicted = snaps.put(key, self._save_state(self._cache, jnp.int32(slot_idx)))
+            return None
+        snapshot = self._save_state(self._cache, jnp.int32(slot_idx))
+        evicted = snaps.put(key, snapshot)
         with self.stats.lock:
             self.stats.state_snapshots_saved += 1
             self.stats.state_snapshots_evicted += evicted
             self.stats.state_snapshot_bytes = snaps.bytes
+        return jax.tree_util.tree_leaves(snapshot)[0]
 
     def _restore_boundary(self, req: Request, slot_idx: int, depth: int) -> None:
         """Before a prefix hit's suffix runs: put the state saved at
@@ -1526,47 +1605,48 @@ class Scheduler:
             "dispatch", program="_prefill_some", tokens=sum(plens),
             rows=pb, bucket=s,
         )
-        small, tok, aux = self._prefill_some(
-            self.params,
-            jnp.asarray(tokens),
-            jnp.asarray(lengths),
-            self._next_key(),
-            jnp.asarray(temp),
-            jnp.asarray(top_p),
-            jnp.asarray(top_k),
-        )
-        self._note_aux(aux)
         k = len(reqs)
         kb = bucket_size(k, minimum=min(4, pb))
         rows = np.zeros((kb,), dtype=np.int32)
         slots_arr = np.full((kb,), slot_idxs[0], dtype=np.int32)
         rows[:k] = np.arange(k)
         slots_arr[:k] = slot_idxs
-        self._cache, self._carried = self._graft_rows(
-            self._cache, small, jnp.asarray(rows), jnp.asarray(slots_arr),
-            self._carried, tok,
+        tokens_dev, lengths_dev, rows_dev, slots_dev, *sampling_dev = self._h2d(
+            tokens, lengths, rows, slots_arr, temp, top_p, top_k
         )
+        key = self._next_key()
+        hrows_dev = None
         if self._dhist is not None:
-            # Scatter the admitted prompts into the device history.  The
-            # kb padding lanes repeat row 0 so their duplicate writes to
+            # The admitted prompts, for the device history.  The kb
+            # padding lanes repeat row 0 so their duplicate writes to
             # slots_arr[0] are idempotent (zero-padding would wipe it).
             hrows = np.zeros((kb, self.max_len), np.int32)
             for r, req in enumerate(reqs):
                 hrows[r, : plens[r]] = req.token_ids
             hrows[len(reqs) :] = hrows[0]
-            self._dhist = self._dhist.at[jnp.asarray(slots_arr)].set(
-                jnp.asarray(hrows)
-            )
+            (hrows_dev,) = self._h2d(hrows)
+        self._clock.stage("call")
+        small, tok, aux = self._prefill_some(
+            self.params, tokens_dev, lengths_dev, key, *sampling_dev
+        )
+        self._cache, self._carried = self._graft_rows(
+            self._cache, small, rows_dev, slots_dev, self._carried, tok
+        )
+        last = self._carried
+        if hrows_dev is not None:
+            self._dhist = self._dhist.at[slots_dev].set(hrows_dev)
         if self.draft_cfg is not None:
             # The draft's slot cache mirrors the target's: same prompt,
             # same slot — _graft_rows is leaf-generic over cache tuples.
             dsmall = self._prefill_draft(
-                self.draft_params, jnp.asarray(tokens), jnp.asarray(lengths)
+                self.draft_params, tokens_dev, lengths_dev
             )
             self._dcache = self._graft_rows(
-                self._dcache, dsmall, jnp.asarray(rows), jnp.asarray(slots_arr)
+                self._dcache, dsmall, rows_dev, slots_dev
             )
-        ticket = self._clock.dispatched()
+            last = self._dcache[0]
+        self._clock.dispatched(last)
+        self._note_aux(aux)
         self._clock.enter("plan")
         for r, (req, slot_idx) in enumerate(zip(reqs, slot_idxs)):
             slot = self._slots[slot_idx]
@@ -1577,23 +1657,22 @@ class Scheduler:
             slot.accept_ewma = 1.0
             slot.unfetched = 1
             slot.on_device = True
-        return reqs, slot_idxs, tok, ticket
+        return reqs, slot_idxs, tok
 
     def _admit_finalize(
         self,
         reqs: Sequence[Request],
         slot_idxs: Sequence[int],
         tok,
-        ticket: int,
     ) -> None:
         """Fetch a dispatched admission batch's first tokens and emit them."""
         self._clock.enter("wait_device")
         tok_host = np.asarray(tok)
-        self._clock.fetched(ticket)
         self._clock.enter("emit")
         self._drain_aux()
         now = time.perf_counter()
         for r, (req, slot_idx) in enumerate(zip(reqs, slot_idxs)):
+            self._clock.poll()
             req.first_token_at = now
             with self.stats.lock:
                 self.stats.queued -= 1
@@ -1663,39 +1742,6 @@ class Scheduler:
         tokens[0, : len(suffix)] = suffix
         kv_bucket = bucket_size(common + s, maximum=self.max_len, dense=True)
         sp = req.sampling
-        self._prefill_suffix_begin(len(suffix), s, kv_bucket)
-        sampling_dev = (
-            jnp.asarray([sp.temperature], dtype=jnp.float32),
-            jnp.asarray([sp.top_p], dtype=jnp.float32),
-            jnp.asarray([sp.top_k], dtype=jnp.int32),
-        )
-        cache, tok, aux = self._prefill_suffix(
-            self.params,
-            self._cache,
-            jnp.asarray(tokens),
-            jnp.int32(common),
-            jnp.int32(len(suffix)),
-            jnp.int32(slot_idx),
-            self._next_key(),
-            sampling_dev,
-            kv_bucket,
-        )
-        self._cache = cache
-        self._note_aux(aux)
-        if self.draft_cfg is not None:
-            # Draft-side twin: the draft cache row must cover the same
-            # [0, plen) window as the target's before the next spec round
-            # reads it — its cached prefix rows came from the same park
-            # or graft that produced the target's.
-            self._dcache = self._prefill_draft_suffix(
-                self.draft_params,
-                self._dcache,
-                jnp.asarray(tokens),
-                jnp.int32(common),
-                jnp.int32(len(suffix)),
-                jnp.int32(slot_idx),
-                kv_bucket,
-            )
         if self._dhist is not None:
             # Rebuild the n-gram matcher's history row for the whole
             # prompt (cached prefix included): hist[p] holds the token
@@ -1704,7 +1750,10 @@ class Scheduler:
             row = np.zeros((self.max_len,), np.int32)
             row[:plen] = req.token_ids
             self._dhist = self._dhist.at[slot_idx].set(jnp.asarray(row))
-        ticket = self._clock.dispatched()
+        self._prefill_suffix_begin(len(suffix), s, kv_bucket)
+        tok = self._call_prefill_suffix(
+            tokens, common, len(suffix), slot_idx, sp, kv_bucket
+        )
         self._clock.enter("plan")
         slot = self._slots[slot_idx]
         slot.request = req
@@ -1714,7 +1763,7 @@ class Scheduler:
         slot.warm_pos = None
         slot.accept_ewma = 1.0
         slot.unfetched = 1
-        return req, slot_idx, tok, ticket
+        return req, slot_idx, tok
 
     def _prefill_suffix_begin(
         self, n: int, s: int, kv_bucket: int, rows: int = 1,
@@ -1731,14 +1780,14 @@ class Scheduler:
             kv_bucket=kv_bucket, rows=rows,
         )
 
-    def _suffix_finalize(self, req, slot_idx, tok, ticket, row=0) -> None:
+    def _suffix_finalize(self, req, slot_idx, tok, row=0) -> None:
         """Fetch a suffix prefill's first token (``row`` of its program's)
         and emit it."""
         self._clock.enter("wait_device")
         tok_host = int(np.asarray(tok)[row])
-        self._clock.fetched(ticket)
         self._clock.enter("emit")
         self._drain_aux()
+        self._clock.poll()
         req.first_token_at = time.perf_counter()
         with self.stats.lock:
             self._note_first_token(req)
@@ -1795,19 +1844,21 @@ class Scheduler:
             bucket_size(common, minimum=16, dense=True), self.max_len
         )
         self._clock.enter("dispatch", program="_graft_prefix", rows=n)
-        self._cache = self._graft_prefix(
-            self._cache, jnp.int32(src), jnp.int32(dst), n
-        )
+        src_dev, dst_dev = self._h2d(np.int32(src), np.int32(dst))
+        self._clock.stage("call")
+        self._cache = self._graft_prefix(self._cache, src_dev, dst_dev, n)
+        last = self._cache
         if self.draft_cfg is not None:
             # Drafts graft cached prefixes too: the parked segment's
             # draft rows were written in lockstep with its target rows,
             # so the same row copy keeps both caches covering [0, common)
             # in the destination slot (_graft_prefix is leaf-generic —
             # this call compiles a second trace for the draft tuple).
-            self._dcache = self._graft_prefix(
-                self._dcache, jnp.int32(src), jnp.int32(dst), n
-            )
-        self._clock.dispatched()
+            self._dcache = self._graft_prefix(self._dcache, src_dev, dst_dev, n)
+            last = self._dcache
+        # The graft returns the state alone, which the next program takes
+        # (donated): the clock drops a sentinel that has gone that way.
+        self._clock.dispatched(jax.tree_util.tree_leaves(last)[0])
         self._clock.enter("plan")
         self._prefix_index.touch(src)
 
@@ -1973,7 +2024,6 @@ class Scheduler:
         else:
             (lone,) = group
             tok = self._dispatch_chunk(lone)
-        ticket = self._clock.dispatched()
         self._clock.enter("plan")
         self._tick_chunks += len(group)
         self._tick_chunk_programs += 1
@@ -1995,8 +2045,7 @@ class Scheduler:
                 slot.warm_pos = None
                 slot.unfetched = 1
                 fin = functools.partial(
-                    self._suffix_finalize, slot.request, c.slot_idx, tok,
-                    ticket, row,
+                    self._suffix_finalize, slot.request, c.slot_idx, tok, row
                 )
             if ahead:
                 slot.ahead_tokens, slot.first_token = c.n, fin
@@ -2014,40 +2063,51 @@ class Scheduler:
         tokens = np.zeros((1, s), dtype=np.int32)
         tokens[0, :n] = slot.history[pos : pos + n]
         kv_bucket = bucket_size(pos + s, maximum=self.max_len, dense=True)
-        sp = slot.request.sampling
         self._prefill_suffix_begin(n, s, kv_bucket)
-        sampling_dev = (
-            jnp.asarray([sp.temperature], dtype=jnp.float32),
-            jnp.asarray([sp.top_p], dtype=jnp.float32),
-            jnp.asarray([sp.top_k], dtype=jnp.int32),
+        return self._call_prefill_suffix(
+            tokens, pos, n, slot_idx, slot.request.sampling, kv_bucket,
+            boundary=True,
         )
-        cache, tok, aux = self._prefill_suffix(
-            self.params,
-            self._cache,
-            jnp.asarray(tokens),
-            jnp.int32(pos),
-            jnp.int32(n),
-            jnp.int32(slot_idx),
-            self._next_key(),
-            sampling_dev,
+
+    def _call_prefill_suffix(
+        self, tokens: np.ndarray, start: int, n: int, slot_idx: int,
+        sp: SamplingParams, kv_bucket: int, boundary: bool = False,
+    ):
+        """The rest of a ``_prefill_suffix`` dispatch, from its arrays to
+        ``dispatched``: ``n`` of ``tokens`` (1, s) into slot ``slot_idx``
+        from position ``start``, the same through a draft model's state,
+        and with ``boundary`` the slot's snapshot where the chunk ends on
+        one.  Returns the token future (1,)."""
+        tokens_dev, *where = self._h2d(
+            tokens, np.int32(start), np.int32(n), np.int32(slot_idx)
+        )
+        sampling_dev = tuple(self._h2d(
+            np.float32([sp.temperature]), np.float32([sp.top_p]),
+            np.int32([sp.top_k]),
+        ))
+        key = self._next_key()
+        self._clock.stage("call")
+        self._cache, tok, aux = self._prefill_suffix(
+            self.params, self._cache, tokens_dev, *where, key, sampling_dev,
             kv_bucket,
         )
-        self._cache = cache
-        self._note_aux(aux)
-        self._save_boundary(slot, slot_idx, pos + n)
-        if self.draft_cfg is not None:
-            # Same chunk through the draft: both caches advance their
-            # warm frontier together, so whenever the slot joins decode
-            # the draft can speculate from a complete prefix.
-            self._dcache = self._prefill_draft_suffix(
-                self.draft_params,
-                self._dcache,
-                jnp.asarray(tokens),
-                jnp.int32(pos),
-                jnp.int32(n),
-                jnp.int32(slot_idx),
-                kv_bucket,
+        last = tok
+        if boundary:
+            snapshot = self._save_boundary(
+                self._slots[slot_idx], slot_idx, start + n
             )
+            last = tok if snapshot is None else snapshot
+        if self.draft_cfg is not None:
+            # The draft's twin: both states cover the same [0, start + n)
+            # of the slot (a parked or grafted prefix came to both), so
+            # whenever the slot joins decode the draft can speculate from
+            # a complete prefix.
+            self._dcache = self._prefill_draft_suffix(
+                self.draft_params, self._dcache, tokens_dev, *where, kv_bucket
+            )
+            last = self._dcache[0]
+        self._clock.dispatched(last)
+        self._note_aux(aux)
         return tok
 
     def _dispatch_chunk_rows(self, group: list[_Chunk]):
@@ -2072,14 +2132,23 @@ class Scheduler:
         self._prefill_suffix_begin(
             int(lens.sum()), s, window, rows=rows, program="_prefill_suffix_rows"
         )
-        cache, tok, aux = self._chunk_programs[rows, window](
-            self.params, self._cache, *map(jnp.asarray, (tokens, start, lens, slots)),
-            self._next_key(), tuple(map(jnp.asarray, (temp, top_p, top_k))),
+        *rows_dev, temp_dev, top_p_dev, top_k_dev = self._h2d(
+            tokens, start, lens, slots, temp, top_p, top_k
         )
-        self._cache = cache
-        self._note_aux(aux)
+        key = self._next_key()
+        self._clock.stage("call")
+        self._cache, tok, aux = self._chunk_programs[rows, window](
+            self.params, self._cache, *rows_dev, key,
+            (temp_dev, top_p_dev, top_k_dev),
+        )
+        last = tok
         for c in group:
-            self._save_boundary(self._slots[c.slot_idx], c.slot_idx, c.pos + c.n)
+            snapshot = self._save_boundary(
+                self._slots[c.slot_idx], c.slot_idx, c.pos + c.n
+            )
+            last = last if snapshot is None else snapshot
+        self._clock.dispatched(last)
+        self._note_aux(aux)
         return tok
 
     def _first_token(self, slot_idx: int, req: Request, tid: int) -> None:
@@ -2090,6 +2159,13 @@ class Scheduler:
         if slot.request is req:
             slot.unfetched -= 1
             self._handle_token(slot_idx, tid)
+
+    def _poll_lane(self, step: int, lane: int, steps: int) -> None:
+        """The clock's poll point of an emit loop over ``steps`` rows of
+        tokens, lanes inside: asked once a row and once a lane (not once
+        a token), spread evenly over the loop."""
+        if lane == 0 or lane % steps == step:
+            self._clock.poll()
 
     def _handle_token(self, slot_idx: int, tid: int) -> None:
         """Process one sampled token for a slot; may finish the slot."""
@@ -2831,44 +2907,27 @@ class Scheduler:
             "dispatch", program="spec_chunk", lanes=len(active),
             kv_bucket=kv_bucket, gamma=g,
         )
+        cur_dev, lengths_dev, *sampling_dev = self._h2d(
+            self._cur_tok, np.minimum(lengths, self.max_len - 1),
+            temp, top_p, top_k,
+        )
+        rows_dev = (cur_dev, lengths_dev, self._next_key(), *sampling_dev)
+        self._clock.stage("call")
         if self.draft_cfg is not None:
-            tcache, dcache, outs, n_emits = self._spec_chunk(
-                (self.params, self.draft_params),
-                self._cache,
-                self._dcache,
-                jnp.asarray(self._cur_tok),
-                jnp.asarray(np.minimum(lengths, self.max_len - 1)),
-                self._next_key(),
-                jnp.asarray(temp),
-                jnp.asarray(top_p),
-                jnp.asarray(top_k),
-                rounds,
-                g,
-                kv_bucket,
+            self._cache, self._dcache, outs, n_emits = self._spec_chunk(
+                (self.params, self.draft_params), self._cache, self._dcache,
+                *rows_dev, rounds, g, kv_bucket,
             )
-            self._cache = tcache
-            self._dcache = dcache
         else:
-            tcache, self._dhist, outs, n_emits = self._ngram_chunk(
-                self.params,
-                self._cache,
-                self._dhist,
-                jnp.asarray(self._cur_tok),
-                jnp.asarray(np.minimum(lengths, self.max_len - 1)),
-                self._next_key(),
-                jnp.asarray(temp),
-                jnp.asarray(top_p),
-                jnp.asarray(top_k),
-                rounds,
-                g,
-                kv_bucket,
+            self._cache, self._dhist, outs, n_emits = self._ngram_chunk(
+                self.params, self._cache, self._dhist,
+                *rows_dev, rounds, g, kv_bucket,
             )
-            self._cache = tcache
-        ticket = self._clock.dispatched()
+        self._clock.dispatched(outs)
         self._clock.enter("plan")
-        return outs, n_emits, active, g, ticket
+        return outs, n_emits, active, g
 
-    def _spec_finalize(self, outs, n_emits, active, gamma_used, ticket):
+    def _spec_finalize(self, outs, n_emits, active, gamma_used):
         """Fetch a dispatched speculative chunk and emit its tokens.
 
         Only lanes in the dispatch snapshot update ``_cur_tok`` — lanes
@@ -2877,7 +2936,6 @@ class Scheduler:
         self._clock.enter("wait_device")
         outs_h = np.asarray(outs)
         n_h = np.asarray(n_emits)
-        self._clock.fetched(ticket)
         self._clock.enter("emit")
         last = outs_h[
             -1, np.arange(self.max_batch), np.maximum(n_h[-1] - 1, 0)
@@ -2914,7 +2972,8 @@ class Scheduler:
         spec_proposed = 0
         spec_accepted = 0
         for r in range(outs_h.shape[0]):
-            for i in active:
+            for k, i in enumerate(active):
+                self._poll_lane(r, k, outs_h.shape[0])
                 slot = self._slots[i]
                 req = slot.request
                 if req is None:
@@ -2963,8 +3022,9 @@ class Scheduler:
         stats as a speculative round does: one draft a greedy row a
         step."""
         rounds = accepted = tokens = 0
-        for row, counts in zip(toks, n_emits):
-            for i, req in mine:
+        for step, (row, counts) in enumerate(zip(toks, n_emits)):
+            for k, (i, req) in enumerate(mine):
+                self._poll_lane(step, k, len(toks))
                 if self._slots[i].request is not req:
                     continue
                 n = int(counts[i])
@@ -3046,26 +3106,21 @@ class Scheduler:
             kv_bucket=kv_bucket,
         )
         lengths = np.minimum(lengths, self.max_len - 1)
-        cache, toks, *aux = self._decode_chunk(
-            self.params,
-            self._cache,
-            jnp.asarray(self._cur_tok),
-            jnp.asarray(lengths),
-            self._next_key(),
-            jnp.asarray(temp),
-            jnp.asarray(top_p),
-            jnp.asarray(top_k),
-            self.decode_chunk_size,
-            kv_bucket,
-            jnp.asarray(snap),
-            self._carried,
-            jnp.asarray(carry),
-            *(
-                (self._carried_len, jnp.asarray(carry_len))
-                if self.model.draft else ()
-            ),
+        cur_dev, lengths_dev, live_dev, carry_dev, *sampling_dev = self._h2d(
+            self._cur_tok, lengths, snap, carry, temp, top_p, top_k
         )
-        self._cache = cache
+        carried_len = (
+            (self._carried_len, *self._h2d(carry_len))
+            if self.model.draft else ()
+        )
+        key = self._next_key()
+        self._clock.stage("call")
+        self._cache, toks, *aux = self._decode_chunk(
+            self.params, self._cache, cur_dev, lengths_dev, key,
+            *sampling_dev, self.decode_chunk_size, kv_bucket, live_dev,
+            self._carried, carry_dev, *carried_len,
+        )
+        self._clock.dispatched(toks)
         if self.model.draft:
             # Tokens (steps, b, 2), how many of the two count, and
             # what the chunk leaves for the next: each row's newest
@@ -3080,7 +3135,6 @@ class Scheduler:
             self.stats.decode_kv_tokens_dense += (
                 self.max_batch * kv_bucket
             )
-        ticket = self._clock.dispatched()
         self._clock.enter("plan")
         # The chunk's last tokens stay where the next chunk can read them;
         # a row that sat this chunk out has nothing there any more.
@@ -3092,11 +3146,9 @@ class Scheduler:
                 s.unfetched += self.decode_chunk_size
                 s.chunks_out += 1
         lanes = [(i, self._slots[i].request) for i in active]
-        return toks, lanes, ticket
+        return toks, lanes
 
-    def _decode_finalize(
-        self, toks, lanes: list, ticket: int, ahead: bool = False
-    ) -> None:
+    def _decode_finalize(self, toks, lanes: list, ahead: bool = False) -> None:
         """Fetch a dispatched decode chunk's tokens and emit them.
 
         ``lanes`` is the snapshot taken at dispatch, each slot with the
@@ -3116,7 +3168,6 @@ class Scheduler:
             toks, n_emits = toks
             n_host = np.asarray(n_emits)  # (chunk, b): 1 or 2 a live row
         toks_host = np.asarray(toks)  # (chunk, b), or (chunk, b, 2)
-        self._clock.fetched(ticket)
         self._clock.enter("emit")
         self._drain_aux()
         mine = []
@@ -3127,8 +3178,9 @@ class Scheduler:
                 slot.chunks_out -= 1
                 mine.append((i, req))
         if n_host is None:
-            for row in toks_host:
-                for i, req in mine:
+            for step, row in enumerate(toks_host):
+                for k, (i, req) in enumerate(mine):
+                    self._poll_lane(step, k, len(toks_host))
                     if self._slots[i].request is req:
                         self._handle_token(i, int(row[i]))
         else:

@@ -36,6 +36,7 @@ from aiohttp import web
 from generativeaiexamples_tpu.core.logging import get_logger
 from generativeaiexamples_tpu.engine.sampler import SamplingParams
 from generativeaiexamples_tpu.engine.scheduler import (
+    DISPATCH_STAGES,
     STARVED_PHASES,
     TICK_PHASES,
     Request,
@@ -750,6 +751,16 @@ async def handle_metrics(request: web.Request) -> web.Response:
         f'engine_device_starved_seconds_total{{phase="{p}"}} '
         f"{snap.get(f'device_starved_{p}_s', 0.0):.6f}"
         for p in STARVED_PHASES
+    ]
+    # Dispatch sites returned from, and the dispatch phase's seconds by
+    # stage (its rest: the sites' counters behind their jitted calls).
+    lines.append("# TYPE engine_dispatch_sites_total counter")
+    lines.append(f"engine_dispatch_sites_total {snap.get('dispatch_sites', 0)}")
+    lines.append("# TYPE engine_dispatch_stage_seconds_total counter")
+    lines += [
+        f'engine_dispatch_stage_seconds_total{{stage="{stage}"}} '
+        f"{snap.get(f'dispatch_{stage}_s', 0.0):.6f}"
+        for stage in DISPATCH_STAGES
     ]
     # Executables the tick thread asked JAX for, by what the persistent
     # cache said, and their seconds by stage: after warm-up each is a

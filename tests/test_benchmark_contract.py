@@ -62,9 +62,12 @@ def _modules(metric: dict) -> tuple:
 # set-up" read ``runtime_report()`` instead, which a snapshot set to ones
 # does not feed: ``tests/test_setup_tracing.py`` holds them to the
 # program the same way, in a process that built a scheduler.
+# ``idle_unseen_ms`` holds the clock's starved seconds to the trace: a
+# device-trace reader that reads the snapshot too.
 COUNTER_METRICS = [
     m for m in SPEC["per_layer"]
-    if m["source"] == "program_counter" and m["layer"] != "engine set-up"
+    if (m["source"] == "program_counter" and m["layer"] != "engine set-up")
+    or m["name"] == "idle_unseen_ms"
 ]
 MODULE_CASES = sorted(
     {
