@@ -219,9 +219,12 @@ INDEX_COUNTERS = ("index_pairs", "read_selected", "read_index", "seen_latent")
 RING_LATENT_COUNTERS = ("read_window", "dense_window")
 # Slots whose recurrent state the KDA layers' decode steps read (the rows
 # that decode where the step is ``kda.kda_step_rows``, every slot where
-# it is XLA's), and slots x KDA layers.  A prefill call adds to neither:
-# a chunk program works on its own rows' state.  The ``mamba`` layers count
-# their slots the same way (every slot: their step is XLA's).
+# it is XLA's), and slots x KDA layers.  A prefill call works on its own
+# rows' state and counts tokens under the same two names: the tokens that
+# count whose scan ``kda.kda_chunk_rows`` did (none where it is XLA's
+# ``kda_chunked``), and tokens that count x KDA layers.  The ``mamba``
+# layers count their slots the same way (every slot: their step is XLA's)
+# and add nothing from a prefill call.
 STATE_COUNTERS = ("read_state", "dense_state")
 # What the ``mamba`` layers' block scan worked on in prefill calls: tokens
 # that count, and blocks computed (``ops/ssm.py::blocks_of``, over every
@@ -1603,9 +1606,10 @@ def _attend(attend, n_valid, apart: bool, *rows):
 
 
 def _kda_mixer(h, lp, st, valid, n_valid, cfg: HybridConfig, mesh=None):
-    """A ``kda`` layer.  Returns (output, state, the slots whose state a
-    decode step read and the slots there are, in the order of
-    ``STATE_COUNTERS``; zeros from a prefill call)."""
+    """A ``kda`` layer.  Returns (output, state, ``STATE_COUNTERS``: from a
+    decode step the slots whose state it read and the slots there are;
+    from a prefill call the tokens that count whose scan was the chunk
+    kernel's (``kda.kda_chunk_rows``) and the tokens that count)."""
     b, s, _ = h.shape
     H, K = cfg.n_heads, cfg.kda_head_dim
     with jax.named_scope("layer/kda/proj"):
@@ -1638,8 +1642,16 @@ def _kda_mixer(h, lp, st, valid, n_valid, cfg: HybridConfig, mesh=None):
             read = (b, b)
         o = o[:, None]
     else:
-        o, S = kda.kda_chunked(q, k, v, g, beta, st["S"])
-        read = (0, 0)
+        kernel = record(
+            f"kda_chunk b={b} s={s} h={H}",
+            kda.use_chunk_kernel(state_dtype=st["S"].dtype, k_dim=K, v_dim=K, heads=H, s=s, mesh=mesh),
+        )
+        if kernel:
+            o, S = kda.kda_chunk_rows(q, k, v, g, beta, st["S"], n_valid)
+        else:
+            o, S = kda.kda_chunked(q, k, v, g, beta, st["S"])
+        counted = jnp.sum(n_valid)
+        read = (counted if kernel else 0, counted)
     with jax.named_scope("layer/kda/out"):
         o = rms_norm(o, lp["o_norm"].astype(F32), cfg.norm_eps)
         o = o * jax.nn.sigmoid(out_gate.astype(F32)).reshape(b, s, H, K)

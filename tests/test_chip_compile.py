@@ -294,9 +294,9 @@ def _chunk_program(one_chip, config: str, rows: int, window: int):
     against the cell's own slot state.  Returns (compiled, serving, engine).
     A program is compiled once for the tests that read it (half a minute
     each), apart by what the attention gates believe of the platform."""
-    from generativeaiexamples_tpu.ops import gqa_decode
+    from generativeaiexamples_tpu.ops import gqa_decode, kda
 
-    key = (config, rows, window, gqa_decode.platform_of(None))
+    key = (config, rows, window, gqa_decode.platform_of(None), kda.platform_of(None))
     if key not in _CHUNK_PROGRAMS:
         _CHUNK_PROGRAMS[key] = _compile_chunk_program(one_chip, config, rows, window)
     return _CHUNK_PROGRAMS[key]
@@ -346,9 +346,10 @@ def test_the_chunks_of_several_slots_compile_as_one_program(one_chip, family, mo
     float32 scores are 268 MB a row at 8,192 where the chunk kernel's
     gate refuses, as it does here, which is why the rows then attend one
     after the other) stay under what Mellum's cell has to spare."""
-    from generativeaiexamples_tpu.ops import moe
+    from generativeaiexamples_tpu.ops import kda, moe
 
     monkeypatch.setattr(moe, "platform_of", lambda mesh: "tpu")
+    monkeypatch.setattr(kda, "platform_of", lambda mesh: "tpu")  # Ling's scan: the chunk kernel
     config, rows, window = GROUP_PROGRAMS[family]
     compiled, serving, engine = _chunk_program(one_chip, config, rows, window)
     max_len, chunk = int(engine["max_len"]), int(engine["prefill_chunk_tokens"])
@@ -897,3 +898,58 @@ def test_lings_decode_chunk_touches_the_kda_state_by_the_kernel_alone(one_chip, 
     assert toks.shape == (steps, b) and aux.shape == (len(serving.counter_names),)
     print("ling decode chunk temporaries", compiled.memory_analysis().temp_size_in_bytes)
     assert compiled.memory_analysis().temp_size_in_bytes < 600_000_000
+
+
+@pytest.mark.parametrize("rows", [1, 2, 8])
+def test_kda_chunk_kernel_compiles_at_the_cells_widths(one_chip, rows, monkeypatch):
+    """``ops/kda.py::kda_chunk_rows`` for a chunk program's 1, 2 and 8 rows
+    of 256 tokens: a sublane tile of heads a grid step, cut from q, k, v
+    and o where they lie, inside half the VMEM budget, and the rows' state
+    in and out of the call as one buffer."""
+    from generativeaiexamples_tpu.ops import kda
+
+    S = _spec(one_chip)
+    _, H, K, V = KDA_STATE
+    s = 256
+    monkeypatch.setattr(kda, "platform_of", lambda mesh: "tpu")
+    assert kda.use_chunk_kernel(state_dtype=jnp.float32, k_dim=K, v_dim=V, heads=H, s=s)
+    hb = kda._heads_a_chunk(H)
+    assert hb == 8 and kda._chunk_vmem_bytes(hb, s, K, V, H) <= qmm._VMEM_BUDGET_BYTES // 2
+
+    def chunk(q, k, v, g, beta, state, n_valid):
+        return kda.kda_chunk_rows(q, k, v, g, beta, state, n_valid, interpret=False)
+
+    wide = S((rows, s, H, K), jnp.float32)
+    compiled = jax.jit(chunk, donate_argnums=(5,)).lower(
+        wide, wide, wide, wide, S((rows, s, H), jnp.float32), S((rows, H, K, V), jnp.float32),
+        S((rows,), jnp.int32),
+    ).compile()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*kda_chunk_rows", compiled.as_text())) == 1
+    assert compiled.memory_analysis().alias_size_in_bytes == 4 * rows * H * K * V
+
+
+@pytest.mark.parametrize("rows", [2, 8])
+def test_lings_chunk_program_scans_its_kda_layers_by_the_kernel_alone(one_chip, rows, monkeypatch):
+    """The chunk program of ling-3.0-flash-vl-l7e128 for 2 and for 8 rows
+    at the widest window: each of the six KDA layers' scans is one
+    ``kda_chunk_rows`` call that takes the rows' state and returns it in
+    the same buffer; what XLA made of ``kda_chunked`` (a ``while`` of 16
+    trips with a triangular inverse in its body, and q, k, v, g copied to
+    ``f32[16, rows, 32, 16, 128]`` around it: PERF.md, PR 50) is gone."""
+    from generativeaiexamples_tpu.ops import kda, moe
+
+    monkeypatch.setattr(moe, "platform_of", lambda mesh: "tpu")
+    monkeypatch.setattr(kda, "platform_of", lambda mesh: "tpu")
+    config, _, window = GROUP_PROGRAMS["ling"]
+    compiled, serving, _ = _chunk_program(one_chip, config, rows, window)
+    layers = len(serving.cfg.layers_of("kda"))
+    text = compiled.as_text()
+    lines = text.splitlines()
+    calls = [ln for ln in lines if 'custom_call_target="tpu_custom_call"' in ln and "kda_chunk_rows" in ln]
+    assert len(calls) == layers == 6
+    assert "InvertDiagBlocksLowerTriangular" not in text
+    assert not [ln for ln in lines if " while(" in ln and "layer/kda/scan" in ln]
+    assert f"f32[16,{rows},32,16,128]" not in text
+    # The rows' state (operand 7, behind the two prefetched and q, k, v,
+    # g, beta) is the call's second output.
+    assert all("output_to_operand_aliasing={{1}: (7, {})}" in call for call in calls), calls[0][-600:]
