@@ -196,7 +196,7 @@ def _flush_append_buffer(cache, ab, starts, max_len: int):
     leaf.
 
     Each row r's C slots land at cache positions [starts[r],
-    starts[r] + C) of every layer/head — the scatter windows span
+    starts[r] + C) of every plane/head — the scatter windows span
     (L, KH, C, HD) with contiguous (C, HD) runs under the default layout,
     so XLA neither re-layouts the cache (the per-token scatter's
     KH-windowed form prefers a KH-minor layout that conflicts with the
@@ -355,15 +355,7 @@ def make_decode_chunk_fn(cfg: llama.LlamaConfig, mesh, max_len: int):
             attended = (
                 lengths0 if live is None else jnp.where(live, lengths0, 0)
             )
-            ab_shape = (
-                cfg.n_layers, cfg.n_kv_heads, b, n_steps, cfg.head_dim
-            )
-            ab = (
-                jnp.zeros(ab_shape, jnp.int8),
-                jnp.zeros(ab_shape, jnp.int8),
-                jnp.zeros(ab_shape[:-1], jnp.bfloat16),
-                jnp.zeros(ab_shape[:-1], jnp.bfloat16),
-            )
+            ab = llama.init_append_buffer(cfg, b, n_steps)
 
             def body(carry, step):
                 ab, tok, key = carry

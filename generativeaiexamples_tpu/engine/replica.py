@@ -1169,6 +1169,8 @@ class EnginePool:
         "admits_batched",
         "decode_kv_tokens_read",
         "decode_kv_tokens_dense",
+        "decode_stack_passes",
+        "prefill_stack_passes",
         # Executables the replicas' tick threads asked JAX for.
         "executables_requested",
         "executables_hit",

@@ -352,6 +352,11 @@ _SPAN_NAMES = {p: f"tick/{p}" for p in TICK_PHASES}
 _STAGE_SPAN_NAMES = {s: f"tick/dispatch/{s}" for s in DISPATCH_STAGES}
 
 
+# The most a cold admission batch's own state may take
+# (``Scheduler._cold_pieces``).
+COLD_BATCH_STATE_BYTES = 1 << 30
+
+
 def make_prefill_suffix_rows(model):
     """The step program for the prefill chunks of several slots at once,
     for a serving model that has ``prefill_rows`` (``Scheduler`` compiles
@@ -512,6 +517,16 @@ class Stats:
         # would read for every slot (max_batch x kv_bucket).
         self.decode_kv_tokens_read = 0
         self.decode_kv_tokens_dense = 0
+        # Passes of the stack dispatched: a decode step or a prefill
+        # program is one, and ``ut_steps`` of a looped stack
+        # (``llama.LlamaConfig``), so decode time over ``decode_stack_passes``
+        # is what one pass of the layers costs whatever the model loops.
+        self.decode_stack_passes = 0
+        self.prefill_stack_passes = 0
+        # K/V planes a token holds and their bytes (gauges, fixed when the
+        # state is made; zero for a model whose state is not K/V planes).
+        self.cache_planes = 0
+        self.kv_bytes_per_token = 0
         # EWMA of tick wall time, updated lock-free from the tick loop
         # (single-writer; readers tolerate a torn-in-time value).  The
         # 429 Retry-After hint derives queue-drain time from it without
@@ -577,6 +592,10 @@ class Stats:
                 "admits_batched": self.admits_batched,
                 "decode_kv_tokens_read": self.decode_kv_tokens_read,
                 "decode_kv_tokens_dense": self.decode_kv_tokens_dense,
+                "decode_stack_passes": self.decode_stack_passes,
+                "prefill_stack_passes": self.prefill_stack_passes,
+                "cache_planes": self.cache_planes,
+                "kv_bytes_per_token": self.kv_bytes_per_token,
                 "ttft_avg_ms": (
                     self.ttft_sum / self.ttft_count * 1000 if self.ttft_count else 0.0
                 ),
@@ -751,6 +770,20 @@ class Scheduler:
         if kv_layout != "contiguous":
             raise ValueError(f"unknown kv_layout mode {kv_layout!r}")
         self._cache = model.init_state(max_batch, self.max_len)
+        # Passes of the stack a step or a prefill program runs.
+        self._stack_passes = getattr(cfg, "ut_steps", 1)
+        # Bytes of fresh state a cold batch's prompt token costs; zero for
+        # a model that does not say (``_cold_batch_rows`` then cuts nothing).
+        self._kv_bytes_per_token = 0
+        if hasattr(model, "kv_planes"):
+            planes, per_token = model.kv_planes(self._cache)
+            self.stats.cache_planes = planes
+            self.stats.kv_bytes_per_token = self._kv_bytes_per_token = per_token
+            logger.info(
+                "slot state: %d K/V planes (%d passes of the stack), %d B a "
+                "token, %d slots of %d rows",
+                planes, self._stack_passes, per_token, max_batch, self.max_len,
+            )
         self._decode_chunk = model.make_decode_chunk()
         # Speculative decoding (TRT-LLM draft-model parity, SURVEY.md
         # §2.8): a draft config turns every decode chunk into speculation
@@ -1541,13 +1574,23 @@ class Scheduler:
         batch bucket goes out as a long prompt's chunks do, each a first
         chunk that is also the last (``_advance_warm``: a program of one
         row at the prompt's own bucket).  A burst stays one weight pass
-        through ``_admit_dispatch``.  A slot admitted alone joins decode a
+        through ``_admit_dispatch``, cut into as many as its own state has
+        room for (``_cold_batch_rows``).  A slot admitted alone joins decode a
         tick later than a batch's row, whose first token the graft lands
         on the device (``_carried``)."""
+        rows = self._cold_batch_rows(reqs)
         alone = bool(
             self.prefill_chunk_tokens
-            and 2 * len(reqs) <= self._admit_rows_min
+            and (not rows or 2 * len(reqs) <= self._admit_rows_min)
         )
+        if not alone and rows < len(reqs):
+            return [
+                fin
+                for at in range(0, len(reqs), rows)
+                for fin in self._admit_cold(
+                    reqs[at : at + rows], slot_idxs[at : at + rows]
+                )
+            ]
         with self.stats.lock:
             if alone:
                 self.stats.admits_lone += len(reqs)
@@ -1563,6 +1606,36 @@ class Scheduler:
             if fin is not None:  # None: cancelled since it was polled
                 fins.append(fin)
         return fins
+
+    def _cold_batch_rows(self, reqs: Sequence[Request]) -> int:
+        """How many of ``reqs`` (clipped) may prefill as one batch, so that
+        the batch's own state stays within ``COLD_BATCH_STATE_BYTES``.
+
+        A batch prefills into fresh state beside the slots and the weights
+        before its rows are grafted (``_prefill_some``): batch bucket x
+        prompt bucket x the bytes a token.  At 66,560 B a token (Mistral,
+        llama3-8b in int8) 32 rows of 256 tokens are 0.5 GB and nothing is
+        cut; at a looped stack's 798,720 B, 16 rows of 256 were 3.3 GB that
+        the chip did not have (my chip call 1, PR 51): there 16 rows of 64
+        tokens or 8 of 128 stay one batch, and prompts of bucket 256 go out
+        alone, as chunks of one row written in place.
+
+        A power of two (pieces fill their batch bucket).  0 where not even
+        the smallest batch bucket fits and chunked prefill has the program
+        of one row; without it that bucket is the smallest program there
+        is, and is returned."""
+        if not self._kv_bytes_per_token:
+            return len(reqs)
+        s = min(
+            bucket_size(max(len(r.token_ids) for r in reqs), dense=True),
+            self.max_len,
+        )
+        rows = COLD_BATCH_STATE_BYTES // (s * self._kv_bytes_per_token)
+        if rows >= bucket_size(len(reqs), minimum=self._admit_rows_min):
+            return len(reqs)
+        if rows < self._admit_rows_min:
+            return 0 if self.prefill_chunk_tokens else self._admit_rows_min
+        return 1 << (int(rows).bit_length() - 1)
 
     def _admit_dispatch(
         self, reqs: Sequence[Request], slot_idxs: Sequence[int]
@@ -1590,6 +1663,7 @@ class Scheduler:
         with self.stats.lock:
             self.stats.prefill_tokens_dispatched += sum(plens)
             self.stats.prefill_tokens_padded += pb * s - sum(plens)
+            self.stats.prefill_stack_passes += self._stack_passes
         tokens = np.zeros((pb, s), dtype=np.int32)
         lengths = np.zeros((pb,), dtype=np.int32)
         temp = np.zeros((pb,), dtype=np.float32)
@@ -1775,6 +1849,7 @@ class Scheduler:
         with self.stats.lock:
             self.stats.prefill_tokens_dispatched += n
             self.stats.prefill_tokens_padded += rows * s - n
+            self.stats.prefill_stack_passes += self._stack_passes
         self._clock.enter(
             "dispatch", program=program, tokens=n, bucket=s,
             kv_bucket=kv_bucket, rows=rows,
@@ -2924,6 +2999,9 @@ class Scheduler:
                 *rows_dev, rounds, g, kv_bucket,
             )
         self._clock.dispatched(outs)
+        with self.stats.lock:
+            # A round is one verify step of the target's stack.
+            self.stats.decode_stack_passes += rounds * self._stack_passes
         self._clock.enter("plan")
         return outs, n_emits, active, g
 
@@ -3134,6 +3212,9 @@ class Scheduler:
             )
             self.stats.decode_kv_tokens_dense += (
                 self.max_batch * kv_bucket
+            )
+            self.stats.decode_stack_passes += (
+                self.decode_chunk_size * self._stack_passes
             )
         self._clock.enter("plan")
         # The chunk's last tokens stay where the next chunk can read them;

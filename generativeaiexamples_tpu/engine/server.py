@@ -817,8 +817,16 @@ async def handle_metrics(request: web.Request) -> web.Response:
     ):
         lines.append(f"# TYPE {name} counter")
         lines.append(f"{name} {format(snap.get(key, 0), fmt)}")
+    # Passes of the stack dispatched (a looped stack: ``ut_steps`` a step).
+    lines.append("# TYPE engine_stack_passes_total counter")
+    lines += [
+        f'engine_stack_passes_total{{phase="{phase}"}} '
+        f"{snap.get(f'{phase}_stack_passes', 0)}"
+        for phase in ("decode", "prefill")
+    ]
     for key in (
         "state_snapshot_bytes", "state_bytes_full", "state_bytes_window", "state_bytes_draft",
+        "cache_planes", "kv_bytes_per_token",
     ):
         lines.append(f"# TYPE engine_{key} gauge")
         lines.append(f"engine_{key} {snap.get(key, 0)}")

@@ -44,6 +44,7 @@ serves ``models/hybrid.py``'s layer kinds.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -112,8 +113,35 @@ class LlamaServing:
     def __init__(self, cfg: llama.LlamaConfig, mesh, max_len: int) -> None:
         self.cfg, self.mesh, self.max_len = cfg, mesh, max_len
 
-    def check_supported(self, **_) -> None:
-        """Every option of the scheduler serves this model."""
+    def check_supported(self, *, draft_cfg=None, spec_mode=None) -> None:
+        """Every option of the scheduler serves a stack that a token
+        passes once.  A looped one (``cfg.ut_steps`` > 1) is served with a
+        draft model or n-gram drafts too (a verify step is ``forward``'s
+        append-buffer shape, which the loop carries), and refuses, with
+        the reason, what its loop does not carry."""
+        cfg = self.cfg
+        if cfg.ut_steps == 1:
+            return
+        if cfg.early_exit_threshold < 1.0:
+            raise ValueError(
+                f"early_exit_threshold {cfg.early_exit_threshold} is not "
+                "served: below 1 the rows of one step would take their "
+                "logits from different passes (and, in any program that "
+                "saved work by it, run different numbers of passes), where "
+                "the scheduler's lanes yield one token a step and "
+                "llama.forward has one trip count; it is never read as 1"
+            )
+        # What ``spec_decode.self_draft`` would hand over: this stack, cut.
+        if draft_cfg is not None and draft_cfg.n_layers < cfg.n_layers and (
+            dataclasses.replace(draft_cfg, n_layers=cfg.n_layers) == cfg
+        ):
+            from generativeaiexamples_tpu.engine.spec_decode import LOOPED_SELF_DRAFT
+
+            raise ValueError(LOOPED_SELF_DRAFT)
+        if self.mesh is not None and self.mesh.shape.get("pipe", 1) > 1:
+            from generativeaiexamples_tpu.parallel.pipeline import LOOPED_PIPELINE
+
+            raise ValueError(LOOPED_PIPELINE)
 
     def chunks_per_program(self, chunk_tokens: int) -> int:
         """A dense model's every weight matrix multiplies every token of a
@@ -145,6 +173,13 @@ class LlamaServing:
 
         return prepare_cache(self.cfg, batch, max_len, self.mesh)
 
+    def kv_planes(self, state) -> tuple[int, int]:
+        """(K/V planes a token holds, the bytes of a token's rows in all of
+        them), read off ``state``: what a cold batch's fresh state costs a
+        prompt token, and the scheduler's two gauges."""
+        batch, rows = state[0].shape[2:4]
+        return self.cfg.cache_planes, sum(leaf.nbytes for leaf in state) // (batch * rows)
+
     def prefill_cold(self, params, tokens, lengths):
         b, s = tokens.shape
         small = llama.init_kv_cache(self.cfg, b, s)
@@ -156,14 +191,23 @@ class LlamaServing:
         return hidden, small, None
 
     def graft_rows(self, big, small, rows, slots):
-        """One scatter per leaf for the whole admission batch, leaf-wise
-        over the head-major (L, KH, B, T, ...) cache tuple (2 leaves for
-        bf16 KV, 4 for int8 KV): rows/slots index axis 2, the slot axis."""
+        """Rows ``rows`` of a cold batch's state into slots ``slots``,
+        leaf-wise over the head-major (planes, KH, B, T, ...) cache tuple
+        (2 leaves for bf16 KV, 4 for int8 KV), a row at a time: each is one
+        contiguous (planes, KH, 1, s, ...) block written where it lies.
+        One scatter a leaf for the whole batch made XLA re-lay the leaf out
+        for it and back, a copy of the leaf each way: 4.8 GB of
+        temporaries at Ouro's 192 planes, more than the chip had left (my
+        chip call 1, PR 51).  A row named twice (the caller's padding) is
+        written twice with the same values."""
         out = []
         for bg, sm in zip(big, small):
-            s = sm.shape[3]
-            gathered = jnp.take(sm, rows, axis=2)  # (L, KH, k, s, ...)
-            out.append(bg.at[:, :, slots, :s].set(gathered))
+            tail = (0,) * (bg.ndim - 3)
+            row_shape = sm.shape[:2] + (1,) + sm.shape[3:]
+            for r in range(rows.shape[0]):
+                row = jax.lax.dynamic_slice(sm, (0, 0, rows[r]) + tail, row_shape)
+                bg = jax.lax.dynamic_update_slice(bg, row, (0, 0, slots[r]) + tail)
+            out.append(bg)
         return tuple(out)
 
     def prefill_row(self, params, cache, tokens, start, suffix_len, slot, kv_bucket):

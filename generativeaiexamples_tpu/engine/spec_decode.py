@@ -90,6 +90,16 @@ def gamma_bucket(desired: int, gamma_max: int) -> int:
     return min(b, int(gamma_max))
 
 
+# Why a looped stack has no self-draft (``self_draft`` and
+# ``LlamaServing.check_supported`` both say it).
+LOOPED_SELF_DRAFT = (
+    "a looped stack has no early-exit self-draft: the draft is the target's "
+    "first layers as a shallower model, and the first layers of a stack "
+    "that every token passes ut_steps times are no model: their output "
+    "goes back into the same layers, not to the final norm and the head"
+)
+
+
 def self_draft(
     cfg: llama.LlamaConfig, params, n_layers: int
 ) -> tuple[llama.LlamaConfig, dict]:
@@ -103,6 +113,8 @@ def self_draft(
     on quantized/packed params too — every layer leaf keeps its leading
     layer axis through ``pack_for_serving`` and quantization.
     """
+    if cfg.ut_steps > 1:
+        raise ValueError(LOOPED_SELF_DRAFT)
     if not 1 <= n_layers < cfg.n_layers:
         raise ValueError(
             f"self-draft depth must be in [1, {cfg.n_layers}), got {n_layers}"
@@ -157,15 +169,7 @@ def _verify_and_emit(
     offs = jnp.arange(gamma + 1, dtype=jnp.int32)[None, :]
     tpos = jnp.minimum(lengths0[:, None] + offs, max_len - 1)
     if use_ab:
-        ab_shape = (
-            tcfg.n_layers, tcfg.n_kv_heads, b, gamma + 1, tcfg.head_dim,
-        )
-        ab0 = (
-            jnp.zeros(ab_shape, jnp.int8),
-            jnp.zeros(ab_shape, jnp.int8),
-            jnp.zeros(ab_shape[:-1], jnp.bfloat16),
-            jnp.zeros(ab_shape[:-1], jnp.bfloat16),
-        )
+        ab0 = llama.init_append_buffer(tcfg, b, gamma + 1)
         # kv_lengths = the valid BIG-CACHE prefix; the fresh block
         # attends via the buffer, then one windowed flush per round
         # lands it at [lengths0, lengths0 + gamma + 1).

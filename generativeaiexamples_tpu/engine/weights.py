@@ -191,14 +191,25 @@ def llama_config_from_hf(ckpt_dir: str, **overrides) -> "llama.LlamaConfig":
     # llama mapping would serve confident garbage with no diagnostic.
     mtype = hf.get("model_type", "llama")
     archs = hf.get("architectures") or []
-    if mtype not in ("llama", "mistral") or any(
-        "Llama" not in a and "Mistral" not in a for a in archs
+    if mtype not in ("llama", "mistral", "ouro") or any(
+        "Llama" not in a and "Mistral" not in a and "Ouro" not in a
+        for a in archs
     ):
         raise ValueError(
             f"checkpoint is model_type={mtype!r} architectures={archs!r}; "
-            "llama_config_from_hf only maps the llama/mistral family — "
-            "use the matching preset + converter for other families"
+            "llama_config_from_hf only maps the llama/mistral family and "
+            "the looped ouro family — use the matching preset + converter "
+            "for other families"
         )
+    looped = {}
+    if mtype == "ouro":
+        # The looped stack (modeling_ouro.py): llama-shaped layers with a
+        # norm on each sub-layer's output too, applied total_ut_steps times.
+        looped = {
+            "ut_steps": int(hf["total_ut_steps"]),
+            "sandwich_norm": True,
+            "early_exit_threshold": float(hf.get("early_exit_threshold", 1.0)),
+        }
     n_heads = hf["num_attention_heads"]
     cfg = llama.LlamaConfig(
         vocab_size=hf["vocab_size"],
@@ -211,6 +222,7 @@ def llama_config_from_hf(ckpt_dir: str, **overrides) -> "llama.LlamaConfig":
         rope_theta=float(hf.get("rope_theta", 10000.0)),
         norm_eps=float(hf.get("rms_norm_eps", 1e-5)),
         max_seq_len=min(int(hf.get("max_position_embeddings", 8192)), 8192),
+        **looped,
     )
     return dataclasses.replace(cfg, **overrides)
 
@@ -320,6 +332,20 @@ def load_hf_llama(cfg: llama.LlamaConfig, ckpt_dir: str) -> llama.Params:
         },
         "final_norm": jax.numpy.asarray(t("model.norm.weight"), dtype=dt),
     }
+    if cfg.sandwich_norm:
+        # modeling_ouro.py's names for the norms on a sub-layer's output.
+        params["layers"]["attn_post_norm"] = stack_layers(
+            "model.layers.{}.input_layernorm_2.weight", transpose=False
+        )
+        params["layers"]["mlp_post_norm"] = stack_layers(
+            "model.layers.{}.post_attention_layernorm_2.weight", transpose=False
+        )
+    if cfg.ut_steps > 1:
+        # The exit gate, nn.Linear(hidden_size, 1): weight (1, D), bias (1,).
+        params["exit_gate"] = {
+            "w": jax.numpy.asarray(t("model.early_exit_gate.weight")[0], dtype=dt),
+            "b": jax.numpy.asarray(t("model.early_exit_gate.bias")[0], dtype=dt),
+        }
     if "lm_head.weight" in tensors:
         params["lm_head"] = jax.numpy.asarray(t("lm_head.weight").T, dtype=dt)
     else:  # tied embeddings

@@ -65,9 +65,14 @@ normed latents; a leading dense layer, then sigmoid-routed experts with a
 shared one, of which this process may hold a share
 (``IndexedLatentConfig``).  Its vision tower, audio encoder and prediction
 module have no key in the public config and are not served.
-A new architecture is a new layer kind here, not another flag on
-``LlamaConfig``; ``models/llama.py`` keeps serving the configurations it
-serves.
+Where a family goes: a stack of DIFFERING kinds is a ``HybridConfig``, and
+a new mixer or MLP is a new layer kind here, not another flag on
+``LlamaConfig``.  A stack of IDENTICAL llama-shaped layers is a
+``LlamaConfig`` (``models/llama.py``), whatever is done with the stack:
+Ouro applies its 48 layers four times to a token, which is one more loop
+around that file's scan over stacked layers, where ``forward`` here is a
+Python loop over the layers with a state entry a layer (192 unrolled
+applications in every program, a state tuple of 192).
 
 One ``forward`` serves the three ways the engine calls a model: a cold
 batch into fresh state, a chunk of one slot's prompt, and one decode

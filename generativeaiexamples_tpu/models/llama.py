@@ -9,6 +9,9 @@ SURVEY.md §2.8).  Design points:
 * **scan over stacked layers** — per-layer weights carry a leading
   ``n_layers`` axis and the transformer body is one ``lax.scan``, which
   keeps compile time flat in depth and lets XLA pipeline the layer loop.
+  A stack of IDENTICAL llama-shaped layers belongs here whatever is done
+  with it (Ouro applies it ``ut_steps`` times to a token: one more loop
+  around the scan); a stack of differing kinds is ``models/hybrid.py``'s.
 * **Declarative sharding** — every param leaf declares logical axes
   (``embed``, ``heads``, ``mlp``, ...) which ``parallel.mesh`` maps to mesh
   axes (tensor parallelism over ICI, fsdp for training).
@@ -84,10 +87,28 @@ class LlamaConfig:
     # Gated (SwiGLU-style) MLP vs plain up->act->down (starcoder2 c_fc/
     # c_proj).
     mlp_gated: bool = True
+    # Looped-stack knobs (Ouro, arXiv:2510.25741): the whole stack of
+    # ``n_layers`` is applied ``ut_steps`` times to every token with the
+    # same weights, the final norm after each pass and its output carried
+    # into the next.  Every (pass, layer) keeps K/V of its own.
+    ut_steps: int = 1
+    # Each sub-layer's OUTPUT is normed before the residual add
+    # (``attn_post_norm`` / ``mlp_post_norm``), beside the norm on its input.
+    sandwich_norm: bool = False
+    # The exit gate's threshold: the first pass at which the exit
+    # distribution's cumulative mass reaches it gives the logits.  At 1
+    # (Ouro's published value) that is the last pass for every token, and
+    # the only value served (``LlamaServing.check_supported``).
+    early_exit_threshold: float = 1.0
 
     @property
     def compute_dtype(self):
         return jnp.dtype(self.dtype)
+
+    @property
+    def cache_planes(self) -> int:
+        """K/V planes a token holds: one a (pass, layer)."""
+        return self.n_layers * self.ut_steps
 
     @property
     def act_fn(self):
@@ -309,6 +330,36 @@ def starcoder2_tiny(**overrides) -> LlamaConfig:
     )
 
 
+def ouro_2_6b(**overrides) -> LlamaConfig:
+    """ByteDance/Ouro-2.6B geometry: 48 llama-shaped layers (16 heads of
+    128, no grouping, SwiGLU of 5,632) that every token passes four times
+    over the same weights, sandwich norms, an untied head."""
+    return dataclasses.replace(
+        LlamaConfig(
+            vocab_size=49152,
+            d_model=2048,
+            n_layers=48,
+            n_heads=16,
+            n_kv_heads=16,
+            head_dim=128,
+            d_ff=5632,
+            rope_theta=1e6,
+            norm_eps=1e-6,
+            max_seq_len=65536,
+            ut_steps=4,
+            sandwich_norm=True,
+        ),
+        **overrides,
+    )
+
+
+def ouro_tiny(**overrides) -> LlamaConfig:
+    """Tiny looped geometry (two layers, four passes) for hermetic CPU
+    tests and byte-level serving."""
+    defaults = {"ut_steps": 4, "sandwich_norm": True, "norm_eps": 1e-6}
+    return dataclasses.replace(llama_tiny(), **{**defaults, **overrides})
+
+
 PRESETS = {
     "llama3-8b": llama3_8b,
     "llama3-70b": llama3_70b,
@@ -321,6 +372,8 @@ PRESETS = {
     "gemma-tiny": gemma_tiny,
     "starcoder2-3b": starcoder2_3b,
     "starcoder2-tiny": starcoder2_tiny,
+    "ouro-2.6b": ouro_2_6b,
+    "ouro-tiny": ouro_tiny,
 }
 
 
@@ -379,6 +432,11 @@ def param_axes(cfg: LlamaConfig) -> dict:
     if cfg.norm_type == "layernorm":
         layers["attn_norm_b"] = ((L, D), ("layers", "embed"))
         layers["mlp_norm_b"] = ((L, D), ("layers", "embed"))
+    if cfg.sandwich_norm:
+        if cfg.norm_type != "rmsnorm":
+            raise ValueError("sandwich norms are RMSNorms (norm_type 'rmsnorm')")
+        layers["attn_post_norm"] = ((L, D), ("layers", "embed"))
+        layers["mlp_post_norm"] = ((L, D), ("layers", "embed"))
     out = {
         "embed": ((V, D), ("vocab", "embed")),
         "layers": layers,
@@ -387,6 +445,10 @@ def param_axes(cfg: LlamaConfig) -> dict:
     }
     if cfg.norm_type == "layernorm":
         out["final_norm_b"] = ((D,), ("embed",))
+    if cfg.ut_steps > 1:
+        # The exit gate after each pass: a checkpoint has it; unused at
+        # ``early_exit_threshold`` 1, the only value served.
+        out["exit_gate"] = {"w": ((D,), ("embed",)), "b": ((), ())}
     return out
 
 
@@ -424,8 +486,9 @@ def init_params(cfg: LlamaConfig, key: jax.Array) -> Params:
     ]
     params = jax.tree.unflatten(treedef, leaves)
     # Norm gains start at one; biases (norm + projection) at zero.
-    params["layers"]["attn_norm"] = jnp.ones_like(params["layers"]["attn_norm"])
-    params["layers"]["mlp_norm"] = jnp.ones_like(params["layers"]["mlp_norm"])
+    for name in ("attn_norm", "mlp_norm", "attn_post_norm", "mlp_post_norm"):
+        if name in params["layers"]:
+            params["layers"][name] = jnp.ones_like(params["layers"][name])
     params["final_norm"] = jnp.ones_like(params["final_norm"])
     for name in ("bq", "bk", "bv", "bo", "b_gate", "b_up", "b_down",
                  "attn_norm_b", "mlp_norm_b"):
@@ -528,11 +591,21 @@ def _badd(x: jnp.ndarray, lp: Mapping, name: str) -> jnp.ndarray:
     return x + lp[name] if name in lp else x
 
 
+def _post_norm(out: jnp.ndarray, cfg: LlamaConfig, lp: Mapping, name: str):
+    """A sub-layer's output on its way to the residual add: normed where
+    the stack has sandwich norms (the leaf exists), as it is elsewhere."""
+    if name not in lp:
+        return out
+    with jax.named_scope("layer/post_norm"):
+        return rms_norm(out, lp[name], cfg.norm_eps, cfg.norm_unit_offset)
+
+
 def init_kv_cache(
     cfg: LlamaConfig, batch: int, max_len: Optional[int] = None
 ) -> tuple[jnp.ndarray, ...]:
-    """KV cache as a tuple of (n_layers, n_kv_heads, batch, max_len, ...)
-    buffers.
+    """KV cache as a tuple of (planes, n_kv_heads, batch, max_len, ...)
+    buffers, a plane a layer (a looped stack: a plane a (pass, layer),
+    ``cfg.cache_planes``).
 
     Head-major layout: the Pallas decode kernel
     (``ops.decode_attention``) DMAs per-(head, row-block, kv-block) tiles
@@ -547,7 +620,7 @@ def init_kv_cache(
     traffic.
     """
     max_len = max_len or cfg.max_seq_len
-    shape = (cfg.n_layers, cfg.n_kv_heads, batch, max_len, cfg.head_dim)
+    shape = (cfg.cache_planes, cfg.n_kv_heads, batch, max_len, cfg.head_dim)
     # Distinct buffers: the generator donates the cache to each step, and
     # XLA rejects donating one buffer twice.
     if cfg.kv_dtype == "int8":
@@ -558,6 +631,19 @@ def init_kv_cache(
             jnp.zeros(shape[:-1], jnp.bfloat16),
         )
     return jnp.zeros(shape, cfg.compute_dtype), jnp.zeros(shape, cfg.compute_dtype)
+
+
+def init_append_buffer(cfg: LlamaConfig, batch: int, width: int) -> tuple:
+    """A decode chunk's (or a verify block's) append buffer, empty: the int8
+    cache's four leaves with ``width`` slots a row (``forward``:
+    ``append_cache``), a plane for every plane of the cache."""
+    shape = (cfg.cache_planes, cfg.n_kv_heads, batch, width, cfg.head_dim)
+    return (
+        jnp.zeros(shape, jnp.int8),
+        jnp.zeros(shape, jnp.int8),
+        jnp.zeros(shape[:-1], jnp.bfloat16),
+        jnp.zeros(shape[:-1], jnp.bfloat16),
+    )
 
 
 def kv_cache_specs(cfg: LlamaConfig, rules=None) -> tuple[P, ...]:
@@ -804,6 +890,7 @@ def dense_layer(
         )
         if tp_axis is not None:
             attn_out = jax.lax.psum(attn_out, tp_axis)
+        attn_out = _post_norm(attn_out, cfg, lp, "attn_post_norm")
         x = _shard_activations(x + attn_out, mesh)
     with jax.named_scope("layer/norm"):
         h = block_norm(x, cfg, lp, "mlp_norm")
@@ -819,6 +906,7 @@ def dense_layer(
         mlp_out = _badd(q_dot(gated, lp["w_down"], "w_down"), lp, "b_down")
         if tp_axis is not None:
             mlp_out = jax.lax.psum(mlp_out, tp_axis)
+        mlp_out = _post_norm(mlp_out, cfg, lp, "mlp_post_norm")
         return _shard_activations(x + mlp_out, mesh)
 
 
@@ -898,6 +986,14 @@ def forward(
     Given, a model with experts dispatches them sorted by expert
     (:func:`_moe_mlp_sorted`) and not one-hot, and a position that does
     not count routes to no expert; a dense model takes no notice.
+
+    A looped stack (``cfg.ut_steps`` > 1) runs the scan over the layers
+    that many times in every mode, as one loop around it: the final norm
+    follows each pass and its output is the next pass's input, and the
+    plane index beside the residual stream goes on counting, so pass ``u``,
+    layer ``l`` writes and reads plane ``u * n_layers + l`` of ``cache``
+    and of the append buffer and no other pass's.  The hidden states
+    returned are the last pass's (``early_exit_threshold`` 1).
     """
     b, s = tokens.shape
     with jax.named_scope("embed"):
@@ -1185,6 +1281,7 @@ def forward(
             attn_out = _badd(
                 q_dot(attn.reshape(b, s, n_q * hd), lp["wo"], "wo"), lp, "bo"
             )
+            attn_out = _post_norm(attn_out, cfg, lp, "attn_post_norm")
             carry_x = _shard_activations(carry_x + attn_out, mesh)
 
         with jax.named_scope("layer/norm"):
@@ -1197,6 +1294,7 @@ def forward(
             else:
                 mlp_out, layer_aux = _moe_mlp(h, lp, cfg, mesh)
             with jax.named_scope("layer/moe/experts"):
+                mlp_out = _post_norm(mlp_out, cfg, lp, "mlp_post_norm")
                 carry_x = _shard_activations(carry_x + mlp_out, mesh)
             return (carry_x, kv, ab, li + 1, aux + layer_aux), None
         with jax.named_scope("layer/mlp"):
@@ -1218,6 +1316,7 @@ def forward(
                 mlp_out = _badd(
                     q_dot(gated, lp["w_down"], "w_down"), lp, "b_down"
                 )
+            mlp_out = _post_norm(mlp_out, cfg, lp, "mlp_post_norm")
             carry_x = _shard_activations(carry_x + mlp_out, mesh)
         return (carry_x, kv, ab, li + 1, aux), None
 
@@ -1235,18 +1334,37 @@ def forward(
             "dense (n_experts <= 1) — use the matching MoE config"
         )
 
-    (x, cache_out, ab_out, _, aux_total), _ = jax.lax.scan(
-        layer_fn,
-        (x, cache, ab_in, jnp.int32(0), jnp.float32(0.0)),
-        layers,
-    )
+    carry = (x, cache, ab_in, jnp.int32(0), jnp.float32(0.0))
+    if cfg.ut_steps > 1:
+        if experts is not None:
+            raise NotImplementedError(
+                "a looped stack with sorted experts: the expert stacks are "
+                "indexed by the plane count ``li``, which a second pass "
+                "carries past the layers"
+            )
 
-    with jax.named_scope("final_norm"):
-        x = apply_final_norm(x, cfg, params)
+        def one_pass(carry, _):
+            # ``li`` goes on counting: pass u, layer l reads and writes
+            # plane u * n_layers + l of the cache and of the append buffer.
+            with jax.named_scope("loop/pass"):
+                (x, *rest), _ = jax.lax.scan(layer_fn, carry, layers)
+            with jax.named_scope("loop/renorm"):
+                x = apply_final_norm(x, cfg, params)
+            return (x, *rest), None
+
+        # One body, ``ut_steps`` trips; the last trip's norm is the final one.
+        carry, _ = jax.lax.scan(one_pass, carry, None, length=cfg.ut_steps)
+        x, cache_out, ab_out, _, aux_total = carry
+    else:
+        (x, cache_out, ab_out, _, aux_total), _ = jax.lax.scan(
+            layer_fn, carry, layers
+        )
+        with jax.named_scope("final_norm"):
+            x = apply_final_norm(x, cfg, params)
     if append_cache is not None:
         return x, cache_out, ab_out
     if return_aux:
-        return x, cache_out, aux_total / max(cfg.n_layers, 1)
+        return x, cache_out, aux_total / max(cfg.cache_planes, 1)
     return x, cache_out
 
 

@@ -82,6 +82,15 @@ BLOCK_T = 512
 # block: the second-to-minor tile of the bf16 scale planes.
 _ROW_GROUP = 16
 
+# The least query heads a KV head's block of queries holds.  Plain
+# multi-head attention (Ouro: 16 query heads on 16 KV heads) brings a
+# group of ONE, which the v5e's compiler refuses: the group axis is the
+# second-minor dim of the scores, and at size 1 its length mask no longer
+# lowers to a vector compare (``LLO_CHECK ... lhs->ProducesVreg()``, the
+# described chip, PR 51).  A lone query head rides with a zero one beside
+# it, whose output is dropped; both fit the sublane tile one took.
+_MIN_GROUP = 2
+
 
 def _block_t(cache_len: int, window: int) -> int:
     """Slots per block for a cache of ``cache_len`` slots under a window
@@ -398,7 +407,7 @@ def use_decode_kernel(
         if (backend or platform_of(mesh)) != "tpu" or not one_device(mesh):
             return False
     t = cache_len or window
-    g = n_q // max(n_kv, 1)
+    g = max(n_q // max(n_kv, 1), _MIN_GROUP)
     return (
         batch % _ROW_GROUP == 0
         # Exact-tiling gate, mirroring ``_block_t``: a cache of whole
@@ -710,7 +719,10 @@ def decode_gqa_attention(
         interpret = _interpret_mode()
     b, n_q, hd = q.shape
     n_kv = k8.shape[1]
-    g = n_q // n_kv
+    q = q.reshape(b, n_kv, n_q // n_kv, hd)
+    if q.shape[2] < _MIN_GROUP:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, _MIN_GROUP - q.shape[2]), (0, 0)))
+    g = q.shape[2]
     cache_len = k8.shape[3]
     bt = _block_t(cache_len, window)
     width = _scale_width(window, cache_len)
@@ -730,7 +742,7 @@ def decode_gqa_attention(
         pl.BlockSpec((1, n_kv, rows, width), layer_group_map(0)),
         pl.BlockSpec((1, n_kv, rows, width), layer_group_map(0)),
     ]
-    operands = [q.reshape(b, n_kv, g, hd), k8, v8, ks, vs]
+    operands = [q, k8, v8, ks, vs]
     if has_ab:
         k_ab, v_ab, ks_ab, vs_ab, count = append
         c = k_ab.shape[3]
@@ -784,4 +796,4 @@ def decode_gqa_attention(
         kv_lengths.astype(jnp.int32),
         *operands,
     )
-    return out.reshape(b, n_q, hd)
+    return out[:, :, : n_q // n_kv].reshape(b, n_q, hd)

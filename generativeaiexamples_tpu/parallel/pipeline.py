@@ -62,6 +62,15 @@ from generativeaiexamples_tpu.models import llama
 from generativeaiexamples_tpu.parallel.mesh import default_rules
 
 
+# Why a looped stack has no pipeline (``_pipeline_run`` and
+# ``LlamaServing.check_supported`` both say it).
+LOOPED_PIPELINE = (
+    "a looped stack is not served as a pipeline: the last stage's output "
+    "would go back to the first stage ut_steps - 1 times a token, and the "
+    "GPipe schedule here hands a micro-batch down the stages once"
+)
+
+
 def pipeline_rules(tensor: bool = False) -> dict:
     """Sharding rules for the pipelined train/forward path: layer stacks
     shard over ``pipe``; with ``tensor=True`` the head/MLP axes
@@ -101,6 +110,8 @@ def _pipeline_run(
     """
     if cfg.n_experts > 1:
         raise NotImplementedError("pipeline supports dense configs")
+    if cfg.ut_steps > 1:
+        raise NotImplementedError(LOOPED_PIPELINE)
     S = mesh.shape["pipe"]
     if cfg.n_layers % S:
         raise ValueError(f"{cfg.n_layers} layers not divisible by pipe={S}")

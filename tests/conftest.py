@@ -17,6 +17,10 @@ if "xla_force_host_platform_device_count" not in flags:
 os.environ.setdefault("TOKENIZERS_PARALLELISM", "false")
 os.environ.setdefault("HF_HUB_OFFLINE", "1")
 os.environ.setdefault("TRANSFORMERS_OFFLINE", "1")
+# ``tests/test_chip_compile*.py`` are five files, so under ``-n`` several
+# workers describe the chip at once, each loading the TPU's library; without
+# this the files behind the first find its lock held and skip.
+os.environ.setdefault("ALLOW_MULTIPLE_LIBTPU_LOAD", "1")
 
 import jax  # noqa: E402
 import pytest  # noqa: E402
@@ -40,47 +44,55 @@ def pytest_configure(config):
 
 
 # Seconds a file's cases take on one worker, for the files over 40 s (the
-# junit of tier-1's command on PR 47's tree: 6,660 s in all, six workers).
+# junit of tier-1's command on PR 51's tree, the builder's machine: 6,640 s
+# in all on six workers, 1,346 s by the clock; renewed by hand from a
+# junit).
 # ``--dist loadfile`` hands out whole files, those with the MOST TESTS
 # first, so the long files started wherever their count put them and the
 # run ended on a few slow ones with workers idle.  Here the slowest go
 # first.  A file not listed keeps xdist's order behind these; a stale
 # number costs balance, nothing else.
 FILE_SECONDS = {
-    "tests/test_chip_compile.py": 660,
-    "tests/test_chip_smoke.py": 520,
-    "tests/test_speech.py": 400,
-    "tests/test_hybrid_serving.py": 350,
-    "tests/test_exaone_moe_model.py": 290,
-    "tests/test_benchmark_contract.py": 280,
-    "tests/test_gqa_ring_chunk_kernel.py": 280,
+    "tests/test_chip_smoke.py": 460,
+    "tests/test_hybrid_serving.py": 380,
+    "tests/test_exaone_moe_model.py": 270,
+    "tests/test_gqa_ring_chunk_kernel.py": 260,
+    "tests/test_benchmark_contract.py": 260,
+    "tests/test_chip_compile_group_programs.py": 240,
     "tests/test_notebooks.py": 230,
-    "tests/test_gqa_chunk_kernel.py": 230,
-    "tests/test_scheduler.py": 210,
-    "tests/test_zaya_model.py": 170,
-    "tests/test_tick_ahead.py": 170,
-    "tests/test_gqa_decode_kernel.py": 160,
-    "tests/test_hybrid_ops.py": 140,
-    "tests/test_speculative.py": 140,
-    "tests/test_hybrid_model.py": 130,
-    "tests/test_engine.py": 120,
-    "tests/test_dots3_note_model.py": 110,
+    "tests/test_chip_compile_chunk_attention.py": 230,
+    "tests/test_scheduler.py": 220,
+    "tests/test_speculative.py": 220,
+    "tests/test_latent_chunk.py": 200,
+    "tests/test_tick_ahead.py": 180,
+    "tests/test_gqa_chunk_kernel.py": 160,
+    "tests/test_zaya_model.py": 140,
+    "tests/test_hybrid_ops.py": 120,
+    "tests/test_dots3_note_model.py": 120,
+    "tests/test_chip_compile_decode_chunks.py": 120,
     "tests/test_qmm.py": 100,
+    "tests/test_kda_chunk_kernel.py": 100,
+    "tests/test_gqa_decode_kernel.py": 100,
+    "tests/test_engine.py": 100,
+    "tests/test_tick_tracing.py": 90,
+    "tests/test_nemotron_h_model.py": 90,
     "tests/test_kda_step_kernel.py": 90,
-    "tests/test_admit_alone.py": 90,
-    "tests/test_nemotron_h_model.py": 70,
-    "tests/test_decode_attention.py": 60,
-    "tests/test_mellum_model.py": 60,
-    "tests/test_retrieval.py": 60,
-    "tests/test_llama.py": 60,
-    "tests/test_spec_serving.py": 60,
-    "tests/test_ring_attention.py": 50,
-    "tests/test_router.py": 50,
-    "tests/test_tick_tracing.py": 50,
-    "tests/test_llama_serving_rows.py": 50,
-    "tests/test_mistral4_model.py": 50,
-    "tests/test_weights.py": 50,
-    "tests/test_setup_tracing.py": 40,
+    "tests/test_mistral4_model.py": 90,
+    "tests/test_speech.py": 80,
+    "tests/test_chip_compile.py": 80,
+    "tests/test_decode_attention.py": 80,
+    "tests/test_ouro_model.py": 125,
+    "tests/test_hybrid_model.py": 80,
+    "tests/test_weights.py": 80,
+    "tests/test_spec_serving.py": 70,
+    "tests/test_llama_serving_rows.py": 70,
+    "tests/test_retrieval.py": 70,
+    "tests/test_llama.py": 70,
+    "tests/test_router.py": 60,
+    "tests/test_chip_compile_llama.py": 60,
+    "tests/test_mellum_model.py": 50,
+    "tests/test_pipeline.py": 40,
+    "tests/test_admit_alone.py": 40,
 }
 
 

@@ -429,6 +429,13 @@ GATE_CASES = {
     "window_16": ({"window": 16}, False),
     "no_mesh": ({"devices": 0}, True),
     "mesh_of_two": ({"devices": 2}, False),
+    # Ouro-2.6B's step: 16 slots of 768 rows, 16 KV heads with ONE query
+    # head each (the kernel's block pairs it with a zero one), a chunk's
+    # append buffer of 8, at the narrowest and the widest decode window.
+    "lone_query_heads": (
+        {"batch": 16, "window": 768, "cache_len": 768, "n_q": 16, "n_kv": 16, "append_width": 8}, True),
+    "lone_query_heads_window_64": (
+        {"batch": 16, "window": 64, "cache_len": 768, "n_q": 16, "n_kv": 16, "append_width": 8}, True),
 }
 
 
@@ -512,3 +519,41 @@ def test_decode_kernel_numeric_at_small_windows(monkeypatch, window):
         rtol=1e-3,
         atol=1e-4,
     )
+
+
+def test_a_lone_query_head_a_kv_head_walks_as_its_twin():
+    """Plain multi-head attention at Ouro-2.6B's step shape (16 slots of
+    768 rows, 16 KV heads of 128 with one query head each, an append buffer
+    of 8): the group of one rides the kernel's block beside a zero query
+    head (``_MIN_GROUP``), whose output is dropped, and equals the XLA twin
+    over ragged rows; the block's VMEM stays a sixth of the budget."""
+    from generativeaiexamples_tpu.ops import decode_attention as da
+
+    b, kh, t, hd, c = 16, 16, 768, 128, 8
+    assert da._block_t(t, t) == 256
+    for window in (64, t):
+        held = da._decode_kernel_vmem_bytes(
+            da._block_t(t, window), da._scale_width(window, t), kh, da._MIN_GROUP, hd, c)
+        assert held <= da._VMEM_BUDGET_BYTES // 5, (window, held)
+    kk = jax.random.split(jax.random.PRNGKey(51), 9)
+
+    def scales(key, n):
+        return (jnp.abs(jax.random.normal(key, (1, kh, b, n))) * 0.02 + 0.01).astype(jnp.bfloat16)
+
+    cache = (
+        jax.random.randint(kk[0], (1, kh, b, t, hd), -127, 128, jnp.int8),
+        jax.random.randint(kk[1], (1, kh, b, t, hd), -127, 128, jnp.int8),
+        scales(kk[2], t), scales(kk[3], t),
+    )
+    append = (
+        jax.random.randint(kk[4], (1, kh, b, c, hd), -127, 128, jnp.int8),
+        jax.random.randint(kk[5], (1, kh, b, c, hd), -127, 128, jnp.int8),
+        scales(kk[6], c), scales(kk[7], c), jnp.int32(3),
+    )
+    q = jax.random.normal(kk[8], (b, kh, hd), jnp.float32)
+    lengths = jnp.asarray([768, 0, 1, 255, 256, 257, 40, 511, 512, 513, 700, 0, 64, 350, 767, 128], jnp.int32)
+    kw = dict(append=append, window=t)
+    want = decode_gqa_attention_xla(q, *cache, jnp.int32(0), lengths, **kw)
+    got = decode_gqa_attention(q, *cache, jnp.int32(0), lengths, interpret=True, **kw)
+    assert got.shape == (b, kh, hd)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-3, atol=1e-4)

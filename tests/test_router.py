@@ -616,8 +616,9 @@ class TestPoolAggregation:
 
 # What a scheduler counts that a pool does not add up: the averages and
 # EWMAs it weighs or takes the worst replica's, the rejections it counts
-# itself, and the bytes of state, which stay a replica's own.
+# itself, and the bytes and planes of state, which stay a replica's own.
 NOT_SUMMED = {
+    "cache_planes", "kv_bytes_per_token",
     "rejected_total", "spec_acceptance_ewma", "spec_gamma",
     "state_bytes_draft", "state_bytes_full", "state_bytes_window",
     "tick_ms_ewma", "tick_ms_norm_ewma", "ttft_avg_ms",

@@ -309,6 +309,7 @@ class TestWav2Vec2:
             speech.W2V2_VOCAB.index("|" if ch == " " else ch) for ch in text
         ]
 
+    @pytest.mark.slow  # trains a model first: a full sweep's, not tier-1's
     def test_ctc_training_yields_real_transcription(self):
         import optax
 
@@ -370,6 +371,9 @@ class TestWav2Vec2:
             assert got == text, f"{text!r} -> {got!r}"
 
 
+# Trains a model first (330 of the file's 400 worker-seconds, for a model
+# outside every cell of the benchmark): a full sweep's, not tier-1's.
+@pytest.mark.slow
 class TestTrainedSpeechLoop:
     """Trained weights BOTH ways through the real service surfaces.
     Two trained recognizers cover the two ASR
@@ -657,6 +661,7 @@ class TestTrainedSpeechLoop:
         assert got.strip() == text.lower()
 
 
+@pytest.mark.slow  # as TestTrainedSpeechLoop: every case takes ``trained_asr``
 class TestTrainedW2V2Streaming:
     """Trained wav2vec2-CTC behind the streaming session and the
     websocket service — the HF-checkpoint-compatible recognizer serving

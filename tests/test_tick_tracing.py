@@ -630,6 +630,23 @@ def test_metrics_export_the_new_counters(engine_client):
     assert exp.value("engine_decode_kv_tokens_dense_total") > 0
 
 
+def test_metrics_export_the_stack_passes_and_the_planes(engine_client):
+    """A stack that a token passes once counts one pass a decode step and
+    a prefill program; its planes are its layers (float32 K/V here)."""
+    client, loop = engine_client
+    _complete(client, loop, max_tokens=6)
+    exp = _metrics(client, loop)
+    assert exp.types["engine_stack_passes_total"] == "counter"
+    assert exp.value("engine_stack_passes_total", phase="prefill") == 1
+    decode = exp.value("engine_stack_passes_total", phase="decode")
+    assert decode >= 4 and decode % 4 == 0  # chunks of four steps
+    assert exp.types["engine_cache_planes"] == exp.types["engine_kv_bytes_per_token"] == "gauge"
+    assert exp.value("engine_cache_planes") == CFG.n_layers
+    assert exp.value("engine_kv_bytes_per_token") == (
+        2 * CFG.n_layers * CFG.n_kv_heads * CFG.head_dim * CFG.compute_dtype.itemsize
+    )
+
+
 def test_metrics_export_the_dispatch_sites_and_stages(engine_client):
     client, loop = engine_client
     _complete(client, loop, max_tokens=6)
