@@ -173,14 +173,20 @@ def test_traced_module_is_a_step_program_of_the_scheduler(
 
 
 def _run(*argv):
-    """The benchmark's command on the CPU, as the driver starts it.  The
-    Ling cell takes a minute alone, Mellum's and the drafting model's 70-80 s;
-    beside five other workers four to five times that (the driver's run of
-    PR 33 lost the last two to a limit of 300 s here)."""
+    """The benchmark's command on the CPU, as the driver starts it.  A
+    rehearsal takes one to two minutes alone and little more at the run's
+    end, three at once (``tests/conftest.py``: 59-134 s each in a whole
+    run of PR 52's tree); beside five other workers it took four to five
+    times that, and the requests missed their deadline long before this
+    limit, which only turns a child that hangs into one red case."""
     env = {**os.environ, "JAX_PLATFORMS": "cpu"}
+    # The tests' own programs are compiled without optimisation
+    # (``tests/conftest.py``); a rehearsal's requests wait 20 s for a first
+    # token, and code that slow misses it.
+    env["XLA_FLAGS"] = env.get("XLA_FLAGS", "").replace("--xla_backend_optimization_level=0", "")
     return subprocess.run(
         [sys.executable, *COMMAND, *argv],
-        cwd=REPO, env=env, capture_output=True, text=True, timeout=600,
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=300,
     )
 
 

@@ -182,9 +182,10 @@ CHUNK = 16
 
 def _cfg(preset: dict, draft: str = "") -> hybrid.HybridConfig:
     """A tiny preset with heads of one lane tile and bf16 all through, so
-    that the gate admits its chunks."""
+    that the gate admits its chunks; one period deep (three window layers
+    and a full one)."""
     return hybrid.from_hf_config(
-        {**preset, "head_dim": D, "torch_dtype": "bfloat16"},
+        {**preset, "num_hidden_layers": 4, "head_dim": D, "torch_dtype": "bfloat16"},
         max_len=MAX_LEN, kv_dtype="bfloat16", draft=draft,
     )
 

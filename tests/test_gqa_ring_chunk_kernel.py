@@ -58,15 +58,30 @@ def _firsts(ring):
     return [0, ring // 2 - 3, ring, 2 * ring + 77, 37 * ring + ring // 2 + 5, 3 * ring + ring // 2, 9]
 
 
-# Every bucket, the smallest and the largest at a ring of one block
-# (K-EXAONE's) and of two (Mellum's), the ones between at one of them.
-@pytest.mark.parametrize("h,kh", HEADS)
-@pytest.mark.parametrize(
-    "s,ring", [(16, 128), (16, 1024), (32, 1024), (64, 128), (128, 1024), (256, 128), (256, 1024)]
+# Interpret mode pays for every program of the grid (rows x KV heads), so
+# the sweep is over what the gate tells apart and no wider.  Every bucket,
+# the smallest and the largest at a ring of one block (K-EXAONE's) and of
+# two (Mellum's), the ones between at one of them, every kind of first
+# position: at 16 query heads over 2, which is both cells' 8 queries a KV
+# head and more than one KV head.  One KV head alone at the smaller
+# buckets.  The cells' own head counts once each, at their largest chunk
+# and their own ring, over a wrap, a row whose last tokens do not count
+# and the padding.
+RINGS = [(16, 128), (16, 1024), (32, 1024), (64, 128), (128, 1024), (256, 128), (256, 1024)]
+EVERY_KIND, WRAP_PARTLY_COUNTED_PAD = (0, 1, 2, 3, 4, 5, 6), (3, 4, 6)
+SWEEP = (
+    [(s, ring, 16, 2, EVERY_KIND) for s, ring in RINGS]
+    + [(s, ring, 8, 1, EVERY_KIND) for s, ring in RINGS[:5]]
+    + [(256, 1024, *HEADS[0], WRAP_PARTLY_COUNTED_PAD), (256, 128, *HEADS[1], WRAP_PARTLY_COUNTED_PAD)]
 )
-def test_a_chunk_gets_attend_rings_numbers(s, ring, h, kh):
-    firsts = _firsts(ring)
-    counts = [s, s, s, s, s - 5, s, 0]
+
+
+@pytest.mark.parametrize(
+    "s,ring,h,kh,kinds", SWEEP, ids=[f"{s}-{ring}-{h}-{kh}-{len(kinds)}rows" for s, ring, h, kh, kinds in SWEEP]
+)
+def test_a_chunk_gets_attend_rings_numbers(s, ring, h, kh, kinds):
+    firsts, counts = _firsts(ring), [s, s, s, s, s - 5, s, 0]
+    firsts, counts = [firsts[i] for i in kinds], [counts[i] for i in kinds]
     got, want = _both(_operands(len(firsts), s, h, kh, ring, seed=s + h), firsts, counts, kh, ring)
     live = np.asarray(counts) > 0
     # Every query of a live row, those that do not count too: the masks do
@@ -156,9 +171,10 @@ RING = 128  # the window of the variants below: one block, shorter than two of t
 
 def _cfg(preset: dict, draft: str = "") -> hybrid.HybridConfig:
     """A tiny preset with heads of one lane tile, a window of one lane tile
-    of keys and bf16 all through, so that the gate admits its chunks."""
+    of keys and bf16 all through, so that the gate admits its chunks; one
+    period deep (three window layers and a full one)."""
     return hybrid.from_hf_config(
-        {**preset, "head_dim": D, "sliding_window": RING, "torch_dtype": "bfloat16"},
+        {**preset, "num_hidden_layers": 4, "head_dim": D, "sliding_window": RING, "torch_dtype": "bfloat16"},
         max_len=MAX_LEN, kv_dtype="bfloat16", draft=draft,
     )
 

@@ -111,9 +111,12 @@ def test_the_gate_asks_for_one_tpu_device(monkeypatch):
 # -- through the model: which path a traced step took, the tokens, the counters ---------------
 
 MAX_LEN = 64
-# ``ling-tiny`` with heads of one lane tile, so that the gate admits its
-# decode steps; float32 as the preset is.
-WIDE = hybrid.from_hf_config({**hybrid.LING_TINY, "head_dim": 128}, max_len=MAX_LEN, kv_dtype="float32")
+# The first three layers of ``ling-tiny`` (KDA mixers; a dense MLP, then
+# experts) with heads of one lane tile, so that the gate admits its decode
+# steps; float32 as the preset is.
+WIDE = hybrid.from_hf_config(
+    {**hybrid.LING_TINY, "head_dim": 128, "num_hidden_layers": 3}, max_len=MAX_LEN, kv_dtype="float32"
+)
 LENGTHS, ALIVE, STEPS = [5, 0, 17, 9, 0, 30], [True, False, True, True, False, True], 8
 
 

@@ -305,7 +305,7 @@ def test_microbatcher_carries_traces_across_worker_thread():
     barrier = threading.Barrier(3)
 
     def worker(i):
-        barrier.wait()
+        barrier.wait(10)
         with trace_scope(traces[i]):  # captured by submit(), not passed
             results[i] = batcher.call(i)
 
@@ -313,7 +313,7 @@ def test_microbatcher_carries_traces_across_worker_thread():
     for t in threads:
         t.start()
     for t in threads:
-        t.join()
+        t.join(10)
     batcher.close()
     assert results == [0, 2, 4]
     batch_ids = set()

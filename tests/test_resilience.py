@@ -111,7 +111,7 @@ def test_deadline_contextvar_scope():
 
         t = threading.Thread(target=other_thread)
         t.start()
-        t.join()
+        t.join(10)
         # contextvars do NOT cross threads — that's why the micro-batcher
         # carries deadlines per queue entry.
         assert seen["dl"] is None

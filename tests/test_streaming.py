@@ -133,7 +133,7 @@ class TestAccumulator:
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(30)
         acc.flush("s")
         total = sum(len(c) for c in chunks)
         # Every character is preserved modulo the per-chunk overlap re-emits.
@@ -169,7 +169,7 @@ class TestAccumulator:
         for t in threads:
             t.start()
         for t in threads:
-            t.join()
+            t.join(30)
         for source in marks:
             acc.flush(source)
         for source, mark in marks.items():
