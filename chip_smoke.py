@@ -3,7 +3,7 @@
 
     python chip_smoke.py [--seed N]          # one chip (what the driver runs)
     python chip_smoke.py --four-chips        # the two multi-chip paths only
-    python chip_smoke.py --hybrid [--model ling|mellum|exaone_moe|mistral4|zaya|nemotron_h|dots3_note] [--control NAME]   # phase 5 only
+    python chip_smoke.py --hybrid [--model ling|mellum|exaone_moe|mistral4|zaya|nemotron_h|dots3_note|deepseek_v32] [--control NAME]   # phase 5 only
 
 Drives the system through the entry points a user calls, at llama3-8b's
 published widths with random int8 weights from ``--seed``:
@@ -77,6 +77,16 @@ published widths with random int8 weights from ``--seed``:
    ``last_2048`` (the newest rows for the highest scores),
    ``no_index_relu``, ``no_rescale``, ``no_gate``, ``full_sizes_in_window``
    (a sliding layer given the full layers' theta) and ``w8a8_mlp``.
+   ``--model deepseek_v32``: a prompt of 4,608 tokens in chunks of 256
+   through the chunk program, its last 32 positions through the VERIFY
+   step on true and on wrong drafts (both of a step's positions select
+   for themselves), and the prediction module's logits, by the
+   benchmark's own comparison (``benchmarks/arch/deepseek_v32.py``: the
+   logit shares and the index overlaps of the stack and of the module's
+   block); controls: ``no_selection``, ``last_2048``, ``no_index_relu``,
+   ``no_groups`` (the 8 best of all 256 router outputs), ``no_mscale``,
+   ``draft_shares_set`` (every second position attends the set of the one
+   before it), ``stale_reject`` and ``w8a8_mlp``.
 
 The parent imports no JAX: the chip belongs to one process at a time, so
 each phase is a child (or the pair engine + chain server) that has
@@ -88,6 +98,7 @@ last line is ``{"ok": true, "device": {...}}`` and nothing else.
 from __future__ import annotations
 
 import argparse
+import contextlib
 import functools
 import dataclasses
 import json
@@ -175,6 +186,16 @@ class Sizes:
     dots3_prompt: int = 4608
     dots3_chunk: int = 256
     dots3_decode: int = 16
+    # ``--model deepseek_v32``: a prompt past twice ``index_topk``, its
+    # last ``deepseek_verify`` positions through the verify step on true
+    # and on wrong drafts.
+    deepseek_model: str = "deepseek-v3.2-l5e16"
+    deepseek_prompt: int = 4608
+    deepseek_chunk: int = 256
+    deepseek_verify: int = 32
+    # Not 0: program AND reference keep this many rows (16,384: every row a
+    # query sees, which tells what of a reading the selection's flips make).
+    deepseek_topk: int = 0
     kv_heads: int = 8  # the preset's own; the tiny rehearsal needs 4 to split
     start_timeout_s: float = 600.0
     request_timeout_s: float = 600.0
@@ -222,6 +243,10 @@ TINY = Sizes(
     dots3_prompt=75,
     dots3_chunk=16,
     dots3_decode=8,
+    deepseek_model="deepseek_v32-tiny",
+    deepseek_prompt=76,
+    deepseek_chunk=16,
+    deepseek_verify=12,
     kv_heads=4,
     start_timeout_s=240.0,
     request_timeout_s=240.0,
@@ -1200,6 +1225,10 @@ HYBRID_CONTROLS = {
         "w8a8_mlp", "no_selection", "last_2048", "no_index_relu", "no_rescale", "no_gate",
         "full_sizes_in_window",
     ),
+    "deepseek_v32": (
+        "w8a8_mlp", "no_selection", "last_2048", "no_index_relu", "stale_reject", "no_groups",
+        "no_mscale", "draft_shares_set",
+    ),
 }
 # ``--model exaone_moe`` is held to the limits of its benchmark
 # configuration (``reference.logit_share_limits``; PERF.md section 6,
@@ -1218,6 +1247,9 @@ NEMOTRON_CONFIG = "benchmarks/configs/nemotron-3-super-120b-a12b-l11e128.json"
 # ``--model dots3_note`` likewise (``benchmarks/arch/dots3_note.py``;
 # PERF.md section 6, PR 47).
 DOTS3_CONFIG = "benchmarks/configs/dots3-note-prev-l6e32.json"
+# ``--model deepseek_v32`` likewise (``benchmarks/arch/deepseek_v32.py``;
+# PERF.md section 6, PR 53).
+DEEPSEEK_CONFIG = "benchmarks/configs/deepseek-v3.2-l5e16.json"
 
 
 def hybrid_limits(model: str) -> dict:
@@ -1421,6 +1453,28 @@ def child_mistral4(seed: int, sizes: Sizes, control: str = "") -> None:
         raise SmokeFailure(f"logits left the reference: {failed} (limits {limits})")
 
 
+@contextlib.contextmanager
+def _reference_patched(reference, patched: dict):
+    """``reference`` with the functions ``patched`` names replaced (a
+    control of a comparison); a caller in this process gets the plain ones
+    back.  A layer traced before or under the patch would keep what it was
+    traced with: the caches are cleared on both sides."""
+    import jax
+
+    plain = {name: getattr(reference, name) for name in patched}
+    for name, stand_in in patched.items():
+        setattr(reference, name, stand_in)
+    if patched:
+        jax.clear_caches()
+    try:
+        yield
+    finally:
+        for name, fn in plain.items():
+            setattr(reference, name, fn)
+        if patched:
+            jax.clear_caches()
+
+
 def _child_by_benchmark(
     seed: int, control: str, *, family: str, config: str, preset: str, prompt: int,
     chunk: int, decode: int, patches: dict, sites: tuple,
@@ -1433,7 +1487,6 @@ def _child_by_benchmark(
     changes what the reference computes (``patches``: the reference module
     -> control -> the functions of that module it replaces); each has to
     leave a limit."""
-    import jax
     import numpy as np
 
     from generativeaiexamples_tpu.engine.serving_models import serving_model
@@ -1456,19 +1509,8 @@ def _child_by_benchmark(
         None, quantize=False, matmul_kernel="xla", seed=seed)
     tokens = np.random.RandomState(seed).randint(1, cfg.vocab_size, size=prompt).astype(np.int32)
     reference = getattr(arch, f"{family}_reference")
-    patched = patches(reference).get(control, {})
-    plain = {name: getattr(reference, name) for name in patched}
-    for name, stand_in in patched.items():
-        setattr(reference, name, stand_in)
-    if patched:
-        jax.clear_caches()  # a layer traced before this would keep the plain one
-    try:
+    with _reference_patched(reference, patches(reference).get(control, {})):
         share, _, *more = arch.logit_shares(params, cfg, tokens, prompt)
-    finally:
-        for name, fn in plain.items():  # a caller in this process gets the plain ones back
-            setattr(reference, name, fn)
-        if patched:
-            jax.clear_caches()
     readings = arch.share_quantiles(share, decode)
     failed = {k: v for k, v in readings.items() if not v <= limits[k]}
     if more:  # a family whose comparison reads its indexer's selected sets too
@@ -1581,15 +1623,10 @@ def child_dots3_note(seed: int, sizes: Sizes, control: str = "") -> None:
     rescaled, no gate on the heads' outputs, a sliding layer rotated with
     the full layers' theta, the MLP products in the nearest precision
     below."""
-    import jax.numpy as jnp
-
-    def newest(scores, seen, topk):
-        return seen & (jnp.cumsum(seen[:, ::-1], axis=-1)[:, ::-1] <= topk)
-
     patches = lambda ref: {
         "w8a8_mlp": {"_swiglu": _w8a8_swiglu()},
         "no_selection": {"_select": lambda scores, seen, topk: seen},
-        "last_2048": {"_select": newest},
+        "last_2048": {"_select": _newest_rows},
         "no_index_relu": {"_index_act": lambda dots: dots},
         "no_rescale": {"_rescale": lambda c, d_model, rank: c},
         "no_gate": {"_gate": lambda o, h, w_gate: o},
@@ -1602,7 +1639,102 @@ def child_dots3_note(seed: int, sizes: Sizes, control: str = "") -> None:
     )
 
 
+def _newest_rows(scores, seen, topk):
+    """``last_2048``: the newest ``topk`` rows a query sees, for the highest scores."""
+    import jax.numpy as jnp
+
+    return seen & (jnp.cumsum(seen[:, ::-1], axis=-1)[:, ::-1] <= topk)
+
+
+def deepseek_v32_patches(ref) -> dict:
+    """The controls of ``--model deepseek_v32`` that change what the
+    REFERENCE computes (control -> the functions of ``ref`` it replaces):
+    every row a query sees attended, the newest ``index_topk`` rows for the
+    highest scores, the index scores without their ``relu``, the 8 best of
+    all 256 router outputs (no groups), the softmax scale without YaRN's
+    ``m^2``, every second position attending the set of the position
+    before it (the shortcut a verify step's shared gather must not take),
+    the MLP products in the nearest precision below.  ``stale_reject`` is
+    the check's own: the step after a rejection reads the stale row."""
+    import jax.numpy as jnp
+
+    return {
+        "w8a8_mlp": {"_swiglu": _w8a8_swiglu()},
+        "no_selection": {"_select": lambda scores, seen, topk: seen},
+        "last_2048": {"_select": _newest_rows},
+        "no_index_relu": {"_index_act": lambda dots: dots},
+        "no_groups": {"_group_limit": lambda ranked, n_group, topk_group: jnp.ones(ranked.shape, bool)},
+        "no_mscale": {"_softmax_scale": lambda nope, rope, mscale: jnp.float32((nope + rope) ** -0.5)},
+        "draft_shares_set": {"_own_set": lambda kept: kept.at[1::2].set(kept[:-1:2])},
+    }
+
+
+def child_deepseek_v32(seed: int, sizes: Sizes, control: str = "") -> None:
+    """``--hybrid --model deepseek_v32``: the serving model's chunk program
+    (``prefill_rows`` in place on a state of ``max_len`` rows a slot) and
+    its verify step (on true drafts, on wrong drafts, and the prediction
+    module's logits) against the float32 reference, with the sets the
+    stack's and the module's indexers keep, by the benchmark's own
+    comparison (``benchmarks/arch/deepseek_v32.py``).  A control changes
+    what the reference computes (``deepseek_v32_patches``) or how the check
+    steps after a rejected draft (``stale_reject``); each has to leave a
+    limit."""
+    import numpy as np
+
+    from generativeaiexamples_tpu.engine.serving_models import serving_model
+    from generativeaiexamples_tpu.models import hybrid
+    from generativeaiexamples_tpu.utils.jax_runtime import (
+        device_report,
+        enable_compile_cache,
+        runtime_report,
+    )
+
+    enable_compile_cache()
+    t0 = time.monotonic()
+    arch = _bench_arch("deepseek_v32")
+    with open(os.path.join(ROOT, DEEPSEEK_CONFIG)) as f:
+        block = json.load(f)["reference"]
+    limits, floors = block["logit_share_limits"], block["index_overlap_floors"]
+    arch._CHECK.update(
+        limits=limits, floors=floors, verify=sizes.deepseek_verify, chunk=sizes.deepseek_chunk)
+    cfg = hybrid.PRESETS[sizes.deepseek_model]()
+    if sizes.deepseek_topk:
+        cfg = dataclasses.replace(cfg, index_topk=sizes.deepseek_topk)
+    prompt = sizes.deepseek_prompt
+    params = serving_model(cfg, None, prompt).prepare_params(
+        None, quantize=False, matmul_kernel="xla", seed=seed)
+    tokens = np.random.RandomState(seed).randint(1, cfg.vocab_size, size=prompt).astype(np.int32)
+    reference = arch.deepseek_v32_reference
+    with _reference_patched(reference, deepseek_v32_patches(reference).get(control, {})):
+        shares, _, overlaps = arch.logit_shares(
+            params, cfg, tokens, prompt, stale_reject=control == "stale_reject")
+    readings = arch.share_quantiles(shares)
+    failed = arch.outside_limits(readings, overlaps)
+    report = runtime_report()
+    emit(
+        {
+            "phase": "hybrid", "model": sizes.deepseek_model, "control": control or None,
+            "positions": {k: int(len(v)) for k, v in shares.items()},
+            **readings, **{f"index_overlap_{k}": v for k, v in overlaps.items()},
+            "limits": {**limits, "index_overlap_floors": floors}, "within_limits": not failed,
+            "outside": failed, "seconds": time.monotonic() - t0,
+            "compile": report["compile"], "peak_bytes_in_use": report["peak_bytes_in_use"],
+            "kernel_paths": {
+                k: v for site in ("moe_experts", "index_scores", "attn_latent", "mtp_")
+                for k, v in _taken(site).items()
+            },
+            "device": device_report(),
+        }
+    )
+    if control and not failed:
+        raise SmokeFailure(f"the control {control!r} stayed inside every limit: {readings} {overlaps}")
+    if not control and failed:
+        raise SmokeFailure(f"the program left the reference: {failed} of {readings} {overlaps}")
+
+
 def child_hybrid(seed: int, sizes: Sizes, control: str = "", model: str = "ling") -> None:
+    if model == "deepseek_v32":
+        return child_deepseek_v32(seed, sizes, control)
     if model == "dots3_note":
         return child_dots3_note(seed, sizes, control)
     if model == "zaya":

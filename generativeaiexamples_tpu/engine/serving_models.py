@@ -332,8 +332,10 @@ class HybridServing:
 
     A model that holds a prediction module (``cfg.draft`` ``"mtp"``) is
     served with it as the draft of every decode step.  The module's state
-    lies behind the stack's: its block's K/V rows, and ``h_last``, the
-    stack's output at the row's last position.  Between programs the
+    lies behind the stack's: its block's rows (K/V rows of a ``full``
+    block; a latent row and an index key a position of an ``mla`` block:
+    ``cfg.mtp_kind``), and ``h_last``, the stack's output at the row's
+    last position.  Between programs the
     module's rows are filled up to the position BEFORE the last (that one
     needs the token after it): a prefill call runs the module one
     position behind the stack, a decode chunk first catches it up with
@@ -342,17 +344,25 @@ class HybridServing:
 
     The rule for a rejected draft's rows: the step has written the draft's
     position ``p + 1`` in every layer of the stack; the row's length
-    advances by one only, so a full layer's row there lies past the
-    length (masked, as a pad row is) until the next step writes the true
-    token over it, and a window layer's ring row ``(p + 1) % R`` is taken
-    by the ring's own rule to hold position ``p + 1 - R``, which no
-    query from ``p + 1`` on may see; the module writes accepted
-    positions only."""
+    advances by one only, so what a layer keeps a position lies past the
+    length until the next step writes the true token over it: a full
+    layer's K/V row is masked as a pad row is; a latent row and its index
+    key likewise, the index key by the indexer's ``seen`` (no query at
+    ``q <= p`` scores position ``p + 1``, and the next step's queries at
+    ``p + 1`` and ``p + 2`` score the keys that very step wrote), the
+    latent row because only selected positions are gathered (the gather's
+    ``keep``); and a window layer's ring row ``(p + 1) % R`` is taken by
+    the ring's own rule to hold position ``p + 1 - R``, which no query
+    from ``p + 1`` on may see.  The module writes accepted positions
+    only."""
 
     # Counters of a drafting model's decode chunk, after ``forward``'s:
     # drafts a greedy row offered and the stack agreed with, positions the
-    # stack computed in decode steps, tokens emitted, and rows of full
-    # layers that a rejection left to be written again.
+    # stack computed in decode steps, tokens emitted, and rows that a
+    # rejection left to be written again: one for every layer of the stack
+    # that keeps a row a position (``full``, ``mla``: its latent row and
+    # index key count as one), none for the module's block, which writes
+    # accepted positions only.
     DRAFT_COUNTERS = (
         "draft_proposed", "draft_accepted", "verify_positions",
         "decode_tokens_emitted", "draft_rows_rewritten",
@@ -372,8 +382,9 @@ class HybridServing:
         # and ``cca`` kinds' rows are written and read in place too, over
         # the doubling windows; only latent rows attended whole (Ling's)
         # have their windows taken out and put back.
+        blocks = [m for m, _ in cfg.layer_kinds] + ([cfg.mtp_kind[0]] if self.draft else [])
         self.one_window = bool(cfg.latent_block) and all(
-            mixer in ("mla", "mla_window") for mixer, _ in cfg.layer_kinds
+            mixer in ("mla", "mla_window") for mixer in blocks
         )
         self.rows_in_place = bool(cfg.latent_block) or not cfg.layers_of("mla")
         self.snapshot_bytes = cfg.snapshot_bytes(max_len)
@@ -388,6 +399,8 @@ class HybridServing:
                 f"attn_rows_{n}_{phase}"
                 for phase in ("decode", "prefill") for n in cfg.row_counters
             )
+            # What only a step's form counts: no phase to tell apart.
+            + tuple(f"attn_rows_{n}" for n in cfg.step_counters)
         )
         if self.draft:
             self.counter_names += self.DRAFT_COUNTERS
@@ -457,15 +470,17 @@ class HybridServing:
     def _aux(self, counters, decode: bool, drafted=None):
         """``forward``'s counters under ``counter_names``: ``DECODE_MOE``
         again where the program is a decode chunk (zeros from a prefill),
-        the attention rows to the decode or to the prefill entries; a
+        the attention rows to the decode or to the prefill entries, a
+        step form's own (``cfg.step_counters``) as they are; a
         drafting model's ``DRAFT_COUNTERS`` (``drafted``; a prefill has
         none) come last."""
         n = len(moe.COUNTERS)
+        m = n + len(self.cfg.row_counters)
         again = jnp.stack([counters[moe.COUNTERS.index(c)] for c in self.DECODE_MOE])
-        rows, none = counters[n:], jnp.zeros_like(counters[n:])
+        rows, none = counters[n:m], jnp.zeros_like(counters[n:m])
         parts = [
             counters[:n], again if decode else jnp.zeros_like(again),
-            *((rows, none) if decode else (none, rows)),
+            *((rows, none) if decode else (none, rows)), counters[m:],
         ]
         if self.draft:
             parts.append(
@@ -833,7 +848,8 @@ class HybridServing:
 
     def _make_verify_chunk(self):
         cfg, max_len = self.cfg, self.max_len
-        n_full = len(cfg.layers_of("full"))
+        # Layers of the stack whose row at a rejected position is written again.
+        n_rewritten = len(cfg.layers_of("full")) + len(cfg.layers_of("mla"))
 
         @functools.partial(jax.jit, donate_argnums=(1,), static_argnums=(8, 9))
         def decode_chunk(
@@ -894,7 +910,7 @@ class HybridServing:
                 accepted = accept.sum().astype(jnp.int32)
                 drafted = drafted + jnp.stack([
                     proposed, accepted, 2 * counts.sum(), n_emit.sum(),
-                    n_full * (counts.sum() - accepted),
+                    n_rewritten * (counts.sum() - accepted),
                 ]).astype(jnp.int32)
                 draft = jnp.argmax(mlg, axis=-1).astype(jnp.int32)
                 carry = (cache, nxt, draft, lens + n_emit, key, aux + c + cm, drafted)

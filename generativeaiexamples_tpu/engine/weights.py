@@ -88,6 +88,8 @@ def resolve_model_preset(model_name: str) -> str:
         return "nemotron_h-tiny" if "tiny" in name else "nemotron-3-super-120b-a12b-l11e128"
     if "dots3" in name:
         return "dots3_note-tiny" if "tiny" in name else "dots3-note-prev-l6e32"
+    if "deepseek" in name:
+        return "deepseek_v32-tiny" if "tiny" in name else "deepseek-v3.2-l5e16"
     if "mixtral" in name or "8x7b" in name:
         return "mixtral-8x7b"
     if "gemma" in name:

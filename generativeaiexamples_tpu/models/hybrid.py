@@ -3,7 +3,7 @@
 (``dense``, ``experts`` or ``none``: the layer is its mixer alone), owns
 the parameters of those kinds and keeps the state of its mixer's kind.
 
-Seven families are defined here.  ``bailing_hybrid`` (Ling-3.0-flash and
+Eight families are defined here.  ``bailing_hybrid`` (Ling-3.0-flash and
 its -VL sibling's language model): KDA linear attention (``ops/kda.py``)
 beside a latent-attention layer every ``layer_group_size`` layers
 (``ops/mla.py``), a leading dense SwiGLU layer and then sigmoid-routed
@@ -65,6 +65,17 @@ normed latents; a leading dense layer, then sigmoid-routed experts with a
 shared one, of which this process may hold a share
 (``IndexedLatentConfig``).  Its vision tower, audio encoder and prediction
 module have no key in the public config and are not served.
+``deepseek_v32`` (DeepSeek-V3.2): the indexed ``mla`` kind in EVERY layer
+(no window kind, no gate, no rescale), with ``mistral4``'s YaRN rotation
+and ``mscale`` under the latent's rotary part and the indexer's alike;
+leading dense layers, then sigmoid-routed experts chosen inside the best
+``topk_group`` of ``n_group`` groups, with a shared one, of which this
+process may hold a share that is a PART of a group; and one prediction
+module whose block is itself such a latent layer, with an indexer, latent
+rows and index keys of its own, which the engine serves as the draft of
+its decode step: every step verifies two positions a row, each over the
+rows it selects for itself (``PredictingLatentConfig``; the step form of
+``_mla_mixer``).
 Where a family goes: a stack of DIFFERING kinds is a ``HybridConfig``, and
 a new mixer or MLP is a new layer kind here, not another flag on
 ``LlamaConfig``.  A stack of IDENTICAL llama-shaped layers is a
@@ -113,10 +124,12 @@ State of a slot, by the layer's mixer:
   exists only as of the last token it has seen, and a token that does not
   count moves neither (its step is 0: a decay of exactly 1, nothing added).
 
-A prediction module adds two entries behind the stack's: its block's
-``k``, ``v`` rows (a ``full`` layer's) and ``h_last`` (D,), the stack's
-output at the last position it has seen, which the module needs with the
-token after it; like a recurrent state it exists only as of that token.
+A prediction module adds two entries behind the stack's: its block's rows,
+of the kind the configuration names (``mtp_kind``: a ``full`` layer's
+``k``, ``v``, or an ``mla`` layer's ``latent`` and ``index_k``), and
+``h_last`` (D,), the stack's output at the last position it has seen,
+which the module needs with the token after it; like a recurrent state it
+exists only as of that token.
 
 ``ROW_LEAVES`` names the leaves that hold a row a position; every other
 leaf is state as of the last token (``RING_LEAVES`` those that are rings).  ``HybridConfig`` holds what every
@@ -126,7 +139,9 @@ the rotary parameters of each kind and the routing options;
 ``CcaConfig`` the ``zaya`` family's sizes, its router's width and its tied
 head; ``MambaConfig`` the ``nemotron_h`` family's Mamba-2 sizes, its
 experts' latent and their activation; ``IndexedLatentConfig`` the
-``dots3_note`` family's indexer, its rescale and its window kind's sizes.
+``dots3_note`` family's indexer, its rescale and its window kind's sizes;
+``PredictingLatentConfig`` the ``deepseek_v32`` family's prediction module
+over latent rows.
 
 What is read from a family's convention and not from a key of the
 public config is listed under ``assumed`` in
@@ -141,11 +156,15 @@ rotation in the attention layers, the order of the Mamba projection's
 outputs, the gate before the norm; not served: the prediction module)
 and ``benchmarks/configs/dots3-note-prev-l6e32.json`` (the form of the
 rescale and of the gate, the indexer's form, norm and rotation, the
-window's count; not served: the towers and the prediction module);
+window's count; not served: the towers and the prediction module)
+and ``benchmarks/configs/deepseek-v3.2-l5e16.json`` (the indexer's rotary
+convention, the module's halves, the seeded weights; not served: FP8, more
+than one drafted position);
 the plain references are ``models/hybrid_reference.py``,
 ``models/mellum_reference.py``, ``models/exaone_moe_reference.py``,
 ``models/mistral4_reference.py``, ``models/zaya_reference.py``,
-``models/nemotron_h_reference.py`` and ``models/dots3_note_reference.py``.
+``models/nemotron_h_reference.py``, ``models/dots3_note_reference.py`` and
+``models/deepseek_v32_reference.py``.
 
 What a row of ``benchmarks/README.md``'s layout table would say of the
 newest family (that file is a ``benchmark`` PR's to edit):
@@ -185,8 +204,11 @@ MIXERS = ("kda", "mla", "full", "window", "cca", "mamba", "mla_window")
 # added): a stack whose published layers are ONE function each reads as
 # such pairs (``_from_nemotron_h``).
 MLPS = ("dense", "experts", "none")
-# The block of a prediction module: a full GQA layer with experts.
-MTP_KIND = ("full", "experts")
+# The mixers a prediction module's block may have: kinds whose state is
+# rows a position, so that a rejected draft's row is masked by the length
+# and written over (a ring or a recurrent state would have to be rolled
+# back).  ``HybridConfig.mtp_kind`` says which one a configuration has.
+MTP_MIXERS = ("full", "mla")
 # State leaves that hold one row a position, which can be cut at any
 # token (the others exist only as of the last token written), and those
 # that are rings of rows.  Rows run along axis 1 (slot axis 0).
@@ -236,6 +258,14 @@ STATE_COUNTERS = ("read_state", "dense_state")
 # row of the call, a group's pad rows among them).  A decode step adds to
 # neither.
 SSM_COUNTERS = ("ssm_tokens", "ssm_blocks")
+# What the step form of an indexed ``mla`` layer gathered (one or two
+# queries a slot: a decode step, a verify step, a prediction module's
+# block beside them): latent rows gathered, every slot and every position
+# of the step, decoding or not; and the rows the decoding slots' positions
+# that count need between them (the union of a slot's kept sets: what
+# one shared gather would fetch).  A prefill call adds to
+# neither, so they are not counted by phase (``HybridConfig.step_counters``).
+VERIFY_COUNTERS = ("gathered_verify", "needed_verify")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -300,6 +330,10 @@ class HybridConfig:
     rope_window: ClassVar[RopeSpec | None] = None
     qk_norm: ClassVar[bool] = False
     mtp_layers: ClassVar[int] = 0
+    # The (mixer, mlp) of a prediction module's block: a full GQA layer
+    # with experts, unless the configuration says otherwise
+    # (``PredictingLatentConfig``: a latent layer with its own indexer).
+    mtp_kind: ClassVar[tuple[str, str]] = ("full", "experts")
     # What ``LatentConfig`` makes fields of: this is Ling's latent layer.
     q_lora_rank: ClassVar[int] = 0
     rope_latent: ClassVar[RopeSpec | None] = None
@@ -352,8 +386,18 @@ class HybridConfig:
                 "more than one prediction module is not served: the decode "
                 "step verifies one draft a row"
             )
-        if self.mtp_layers and self.n_kv_heads < 1:
-            raise ValueError("a prediction module's block is a full GQA layer")
+        if self.mtp_layers and self.mtp_kind[0] not in MTP_MIXERS:
+            raise ValueError(
+                f"a prediction module's block is a layer whose state is rows a "
+                f"position ({', '.join(MTP_MIXERS)}), not {self.mtp_kind[0]!r}: a "
+                "rejected draft's row is masked by the length, a ring or a "
+                "recurrent state would have to be rolled back"
+            )
+        if self.mtp_layers and self.mtp_kind[0] == "full" and self.n_kv_heads < 1:
+            raise ValueError(
+                "a prediction module whose block is a full GQA layer needs the "
+                "GQA sizes (n_kv_heads)"
+            )
 
     @property
     def n_layers(self) -> int:
@@ -397,10 +441,16 @@ class HybridConfig:
         return "mtp" if self.mtp_layers else ""
 
     @property
+    def step_counters(self) -> tuple[str, ...]:
+        """Names of the counters that only a step's form adds to, behind
+        ``row_counters``: none, or ``VERIFY_COUNTERS``."""
+        return ()
+
+    @property
     def n_counters(self) -> int:
-        """Entries of ``forward``'s counters: ``moe.COUNTERS`` and
-        ``row_counters`` after them."""
-        return len(moe.COUNTERS) + len(self.row_counters)
+        """Entries of ``forward``'s counters: ``moe.COUNTERS``, then
+        ``row_counters``, then ``step_counters``."""
+        return len(moe.COUNTERS) + len(self.row_counters) + len(self.step_counters)
 
     def ring_rows(self, max_len: int) -> int:
         """Rows of a window layer's ring: the window, whatever the length
@@ -591,6 +641,29 @@ class IndexedLatentConfig(LatentConfig):
 
 
 @dataclasses.dataclass(frozen=True)
+class PredictingLatentConfig(IndexedLatentConfig):
+    """A configuration of the ``deepseek_v32`` family: EVERY layer an
+    ``mla`` layer whose queries attend the rows its indexer selects (no
+    window kind, no output gate, no rescale; YaRN on the rotary parts and
+    its ``mscale`` in the softmax scale, as ``LatentConfig`` has them),
+    routing in groups, and one prediction module whose block is itself
+    such a layer, with an indexer and rows of its own: held, it drafts
+    every decode step, and the step verifies two positions a row, each
+    over the rows it selects for itself."""
+
+    latent_rescale: bool = False
+    mla_out_gate: bool = False
+    # Prediction modules held behind the stack (0 or 1) and the kind of
+    # the module's block.
+    mtp_layers: int = 0
+    mtp_kind: tuple[str, str] = ("mla", "experts")
+
+    @property
+    def step_counters(self) -> tuple[str, ...]:
+        return VERIFY_COUNTERS
+
+
+@dataclasses.dataclass(frozen=True)
 class CcaConfig(HybridConfig):
     """A configuration whose every layer is the ``zaya`` family's: a
     ``cca`` mixer (its K/V rows are a full GQA layer's, ``n_kv_heads`` of
@@ -689,7 +762,8 @@ def from_hf_config(
     ``model_type``: ``mellum`` (:func:`_from_mellum`), ``exaone_moe``
     (:func:`_from_exaone`), ``mistral4`` (:func:`_from_mistral4`), ``zaya``
     (:func:`_from_zaya`), ``nemotron_h`` (:func:`_from_nemotron_h`),
-    ``dots3_note`` (:func:`_from_dots3_note`), else the
+    ``dots3_note`` (:func:`_from_dots3_note`), ``deepseek_v32``
+    (:func:`_from_deepseek_v32`), else the
     ``bailing_hybrid`` family, of which the rest speaks.  ``draft`` ``mtp`` holds the model's own prediction
     module and serves it as the decode step's draft; a family without one
     refuses it.
@@ -703,6 +777,11 @@ def from_hf_config(
     """
     if model.get("model_type") == "exaone_moe":
         return _from_exaone(
+            model, max_len=max_len, expert_offset=expert_offset,
+            kv_dtype=kv_dtype, draft=draft,
+        )
+    if model.get("model_type") == "deepseek_v32":
+        return _from_deepseek_v32(
             model, max_len=max_len, expert_offset=expert_offset,
             kv_dtype=kv_dtype, draft=draft,
         )
@@ -1171,7 +1250,11 @@ def _from_dots3_note(
     if model.get("scoring_func", "sigmoid") != "sigmoid" or model.get("topk_method", "noaux_tc") != "noaux_tc":
         raise ValueError("dots3_note is served with sigmoid scores and a selection bias (noaux_tc)")
     if int(model.get("n_group", 1)) != 1 or int(model.get("topk_group", 1)) != 1:
-        raise ValueError("routing groups are not served for dots3_note (one group)")
+        raise ValueError(
+            "routing groups are not served for dots3_note: its public config has "
+            "no n_group / topk_group (one group); the deepseek_v32 family routes "
+            "in groups"
+        )
     if int(model.get("moe_layer_freq", 1)) != 1:
         raise ValueError("moe_layer_freq other than 1 is not served")
     if model.get("attention_bias") or model.get("hidden_act", "silu") != "silu":
@@ -1225,6 +1308,94 @@ def _from_dots3_note(
         n_experts_per_tok=int(model["num_experts_per_tok"]),
         n_group=1,
         topk_group=1,
+        routed_scaling=float(model["routed_scaling_factor"]),
+        norm_topk=bool(model["norm_topk_prob"]),
+        score_function="sigmoid",
+        router_bias=True,
+        norm_eps=float(model["rms_norm_eps"]),
+        max_seq_len=int(max_len),
+        dtype=str(model.get("torch_dtype", "bfloat16")),
+        kv_dtype=kv_dtype,
+    )
+
+
+def _from_deepseek_v32(
+    model: Mapping[str, Any], *, max_len: int, expert_offset: int, kv_dtype: str,
+    draft: str,
+) -> PredictingLatentConfig:
+    """``model_type: deepseek_v32``: every layer an ``mla`` mixer with a
+    low-rank query and the indexer (``index_n_heads`` heads of
+    ``index_head_dim`` keep ``index_topk`` rows a query); ``rope_scaling``
+    is YaRN over the rotary parts (the latent's and the indexer's) with
+    ``mscale_all_dim``'s term squared in the softmax scale, as
+    :func:`_from_mistral4` builds both; no output gate, no rescale, no
+    window.  The first ``first_k_dense_replace`` layers kept are dense,
+    the rest experts with a shared one: sigmoid scores with a selection
+    bias (``noaux_tc``) in ``n_group`` groups of which ``topk_group`` are
+    kept, renormalised and scaled.  ``n_routed_experts`` counts the
+    experts held of ``num_experts_published`` router outputs (absent: the
+    same); a share may be a part of a group.  With ``draft`` ``mtp`` the
+    one prediction module is held, its block a layer of the same kind with
+    its own indexer; without, it is left out."""
+    if model.get("quantization_config") or str(model.get("torch_dtype", "bfloat16")).startswith("float8"):
+        raise ValueError(
+            "FP8 weights, rows or index keys are not served for deepseek_v32: a "
+            "precision, not an equation; bf16 here"
+        )
+    if model.get("scoring_func", "sigmoid") != "sigmoid" or model.get("topk_method", "noaux_tc") != "noaux_tc":
+        raise ValueError("deepseek_v32 is served with sigmoid scores and a selection bias (noaux_tc)")
+    if int(model.get("moe_layer_freq", 1)) != 1:
+        raise ValueError("moe_layer_freq other than 1 is not served")
+    if model.get("attention_bias") or model.get("hidden_act", "silu") != "silu":
+        raise ValueError("attention biases and activations other than silu are not served")
+    if not model.get("q_lora_rank"):
+        raise ValueError("deepseek_v32 is served with a low-rank query (q_lora_rank)")
+    if not int(model.get("index_topk", 0)):
+        raise ValueError("deepseek_v32's layers attend what an indexer selects (index_topk)")
+    scaling = dict(model.get("rope_scaling") or {})
+    kind = str(scaling.get("rope_type", scaling.get("type", "default")))
+    if kind != "yarn":
+        raise ValueError("deepseek_v32 is served with YaRN frequencies on the rotary parts (rope_scaling)")
+    spec = rope_spec({**scaling, "rope_type": kind, "rope_theta": model["rope_theta"]})
+    modules = int(model.get("num_nextn_predict_layers", 0))
+    if draft not in ("", "mtp"):
+        raise ValueError(f"draft {draft!r} is not served: only the model's own module ('mtp')")
+    if draft and modules != 1:
+        raise ValueError(
+            f"num_nextn_predict_layers {modules} is not served: the decode "
+            "step verifies the draft of exactly one prediction module"
+        )
+    dense = int(model["first_k_dense_replace"])
+    held = int(model["n_routed_experts"])
+    return PredictingLatentConfig(
+        vocab_size=int(model["vocab_size"]),
+        d_model=int(model["hidden_size"]),
+        layer_kinds=tuple(
+            ("mla", "dense" if j < dense else "experts")
+            for j in range(int(model["num_hidden_layers"]))
+        ),
+        n_heads=int(model["num_attention_heads"]),
+        kv_lora_rank=int(model["kv_lora_rank"]),
+        qk_nope_head_dim=int(model["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(model["qk_rope_head_dim"]),
+        v_head_dim=int(model["v_head_dim"]),
+        rope_theta=float(model["rope_theta"]),
+        q_lora_rank=int(model["q_lora_rank"]),
+        rope_latent=spec,
+        softmax_mscale=yarn_mscale(spec.factor, float(scaling.get("mscale_all_dim", 0.0))),
+        index_n_heads=int(model["index_n_heads"]),
+        index_head_dim=int(model["index_head_dim"]),
+        index_topk=int(model["index_topk"]),
+        mtp_layers=1 if draft else 0,
+        d_ff=int(model["intermediate_size"]),
+        moe_d_ff=int(model["moe_intermediate_size"]),
+        shared_d_ff=int(model["moe_intermediate_size"]) * int(model["n_shared_experts"]),
+        n_experts=int(model.get("num_experts_published", held)),
+        experts_held=held,
+        expert_offset=int(expert_offset),
+        n_experts_per_tok=int(model["num_experts_per_tok"]),
+        n_group=int(model["n_group"]),
+        topk_group=int(model["topk_group"]),
         routed_scaling=float(model["routed_scaling_factor"]),
         norm_topk=bool(model["norm_topk_prob"]),
         score_function="sigmoid",
@@ -1425,7 +1596,7 @@ def init_params(cfg: HybridConfig, key: jax.Array) -> Params:
             "hnorm": jnp.ones((D,), dtype),
             # [embedding of the next token ; the stack's output] -> D
             "eh_proj": _normal(next(keys), (2 * D) ** -0.5, (2 * D, D), dtype),
-            "layer": layer(MTP_KIND),
+            "layer": layer(cfg.mtp_kind),
             "final_norm": jnp.ones((D,), dtype),
         }
     return params
@@ -1495,7 +1666,7 @@ def _balanced_biases(params, cfg: HybridConfig, tokens):
         x, rho = through(x, lp, st, mixer, mlp, rho)
     if cfg.mtp_layers:
         u = _mtp_input(params, cfg, x, jnp.roll(tokens, -1, axis=1))
-        through(u, params["mtp"]["layer"], state[cfg.n_layers], *MTP_KIND)
+        through(u, params["mtp"]["layer"], state[cfg.n_layers], *cfg.mtp_kind)
     return out
 
 
@@ -1504,11 +1675,14 @@ def _balanced_biases(params, cfg: HybridConfig, tokens):
 
 def init_state(cfg: HybridConfig, batch: int, max_len: int) -> tuple:
     """Zero state for ``batch`` rows: one dict a layer, of its mixer's
-    kind; behind them a prediction module's rows and ``h_last``."""
+    kind; behind them a prediction module's rows (its block's kind: K/V
+    rows, or a latent row and an index key a position) and ``h_last``."""
     H, K = cfg.n_heads, cfg.kda_head_dim
     sd = cfg.state_dtype
     out = []
-    for mixer, _ in cfg.layer_kinds:
+    # A prediction module's block keeps the rows of its mixer's kind.
+    module = ((cfg.mtp_kind[0],) if cfg.mtp_layers else ())
+    for mixer in tuple(m for m, _ in cfg.layer_kinds) + module:
         if mixer in ("full", "window"):
             rows = max_len if mixer == "full" else cfg.ring_rows(max_len)
             shape = (batch, rows, cfg.n_kv_heads * cfg.attn_head_dim)
@@ -1553,8 +1727,6 @@ def init_state(cfg: HybridConfig, batch: int, max_len: int) -> tuple:
                 layer["index_k"] = jnp.zeros((batch, max_len, cfg.index_head_dim), sd)
             out.append(layer)
     if cfg.mtp_layers:
-        shape = (batch, max_len, cfg.n_kv_heads * cfg.attn_head_dim)
-        out.append({n: jnp.zeros(shape, sd) for n in GQA_LEAVES["full"]})
         out.append({"h_last": jnp.zeros((batch, cfg.d_model), jnp.dtype(cfg.dtype))})
     return tuple(out)
 
@@ -1748,7 +1920,8 @@ def _index_q_k(h, c_q, lp, pos, cfg: HybridConfig):
 
 
 def _mla_mixer(
-    h, lp, st, pos, valid, n_valid, cfg: HybridConfig, window: int, apart: bool, mesh=None
+    h, lp, st, pos, valid, n_valid, cfg: HybridConfig, window: int, apart: bool,
+    site: str = "", mesh=None,
 ):
     """An ``mla`` layer, Ling's form, the mistral4 family's or the
     dots3_note family's by what the configuration says (a low-rank query,
@@ -1775,11 +1948,17 @@ def _mla_mixer(
     chunk, selected or not, is ``ops/mla_chunk.py``'s kernel for all rows
     of the call at once where ``use_latent_chunk`` admits it (the counter
     ``kernel_latent``: the rows it walked), and ``mla.attend_blocks`` a row
-    at a time where it does not.  A decode step scores
-    every slot's first ``window`` index keys in one product, gathers each
-    slot's ``index_topk`` highest rows (``mla.select_rows``) and attends
-    over them alone (``mla.attend_selected``); a slot that does not decode
-    is computed beside the others and its result dropped."""
+    at a time where it does not.  A step (``gqa._STEP_QUERIES`` queries a
+    slot or fewer: a decode step's one, a verify step's ``[token, draft]``,
+    a prediction module's block beside either) never takes the chunk's
+    form: it scores every slot's first ``window`` index keys against the
+    step's queries in one product, gathers for EACH query the
+    ``index_topk`` highest rows of what it sees (``mla.select_rows``:
+    position ``p + 1`` selects among ``j <= p + 1``, its own new row among
+    them, and its set is not position ``p``'s) and attends over them alone
+    (``mla.attend_selected``); a slot that does not decode is computed
+    beside the others and its result dropped.  ``site`` prefixes the
+    layer's ``kernel_paths`` entries (a prediction module's block)."""
     b, s, _ = h.shape
     sz = cfg.latent_sizes("mla")
     H, rank = sz.n_heads, sz.kv_lora_rank
@@ -1834,8 +2013,8 @@ def _mla_mixer(
 
         return jax.lax.map(one, (q_nope, q_rope, mine, pos, lengths, *more))
 
-    def chunk_kernel(site: str, masked: bool) -> bool:
-        return record(site, mla_chunk.use_latent_chunk(
+    def chunk_kernel(name: str, masked: bool) -> bool:
+        return record(site + name, mla_chunk.use_latent_chunk(
             s=s, q_dtype=q_nope.dtype, rows_dtype=latent.dtype, width=width, rank=rank,
             nope=nope, v_dim=vd, heads=H, rows=T, window=span, block=cfg.latent_block,
             masked=masked, mesh=mesh,
@@ -1847,10 +2026,13 @@ def _mla_mixer(
             block=cfg.latent_block, **selection, **sizes
         )
 
-    if topk and s > 1:
-        record(f"index_scores b={b} s={s} t={span}", False)
+    if topk and s > gqa._STEP_QUERIES:
+        record(f"{site}index_scores b={b} s={s} t={span}", False)
         kernel = chunk_kernel(f"attn_latent_chunk b={b} s={s} t={span} k={topk}", True)
-        lengths = jnp.where(n_valid > 0, pos[:, 0] + n_valid, 0)
+        # Rows each row holds once its tokens are written: one past its
+        # last position that counts (a prediction module's block runs one
+        # position behind, and a prompt's position -1 does not count).
+        lengths = jnp.max(jnp.where(valid, pos + 1, 0), axis=1)
         selects = lengths > topk  # a shorter row keeps every position it sees
         walked = mla.rows_in_blocks(lengths, span, cfg.latent_block)
         scored = jnp.where(selects, walked, 0)
@@ -1899,20 +2081,27 @@ def _mla_mixer(
         else:
             o = in_place(attend, cfg.latent_block, lengths, q_i, w_i)
     elif topk:
-        record(f"index_scores b={b} s=1 t={span}", False)
-        record(f"attn_latent_sparse_decode b={b} t={span} k={min(topk, span)}", False)
+        form = "decode" if s == 1 else "verify"
+        record(f"{site}index_scores b={b} s={s} t={span}", False)
+        record(f"{site}attn_latent_sparse_{form} b={b} t={span} k={min(topk, span)}", False)
         keys, rows = (index_k, latent) if slot is None else (index_k[mine], latent[mine])
         with jax.named_scope("layer/mla/index"):
-            scores = mla.index_scores(q_i, w_i, jax.lax.slice_in_dim(keys, 0, span, axis=1))[:, 0]
-            seen = jnp.arange(span, dtype=jnp.int32)[None, :] <= pos[:, :1]
-            scores = jnp.where(seen & (n_valid > 0)[:, None], scores, -jnp.inf)
-        idx, keep = mla.select_rows(scores, topk)
-        # Every slot's index keys are read and every slot's rows gathered,
-        # whoever decodes: what the step read, not what it needed.
+            scores = mla.index_scores(q_i, w_i, jax.lax.slice_in_dim(keys, 0, span, axis=1))
+            seen = jnp.arange(span, dtype=jnp.int32)[None, None, :] <= pos[:, :, None]
+            scores = jnp.where(seen & valid[:, :, None], scores, -jnp.inf)
+        # One row of scores a slot and position: each position selects for itself.
+        idx, keep = mla.select_rows(scores.reshape(b * s, span), topk)
+        idx, keep = idx.reshape(b, s, -1), keep.reshape(b, s, -1)
+        # Every slot's index keys are read and every slot's rows gathered
+        # for every position, whoever decodes: what the step read, not what
+        # it needed (``needed_verify``: the decoding slots' sets, united).
+        gathered = b * s * idx.shape[-1]
         read.update(
-            read_latent=b * idx.shape[1], index_pairs=b * span, read_index=b * span,
-            read_selected=b * idx.shape[1],
-            seen_latent=jnp.where(n_valid > 0, jnp.minimum(pos[:, 0] + 1, span), 0).sum(),
+            read_latent=gathered, index_pairs=b * s * span, read_index=b * span,
+            read_selected=gathered,
+            seen_latent=jnp.where(valid, jnp.minimum(pos + 1, span), 0).sum(),
+            gathered_verify=gathered,
+            needed_verify=mla.rows_needed(scores, idx, keep, valid).sum(),
         )
         o = mla.attend_selected(q_nope, q_rope, rows, idx=idx, keep=keep, **sizes)
     elif cfg.latent_block and s > 1:
@@ -2275,14 +2464,17 @@ def _mix(
     apart: bool = False, site: str = "", mesh=None,
 ):
     """The mixer's half of a layer: (x + mixer(norm(x)), new state, what
-    the layer read, (len(cfg.row_counters),) int32: each mixer's counters
-    go by name to the entries the model has, the others nowhere)."""
+    the layer read, int32, ``cfg.row_counters`` and then
+    ``cfg.step_counters``: each mixer's counters go by name to the entries
+    the model has, the others nowhere)."""
     h = rms_norm(x, lp["attn_norm"], cfg.norm_eps)
     if mixer == "kda":
         y, st, read = _kda_mixer(h, lp, st, valid, n_valid, cfg, mesh)
         names = STATE_COUNTERS
     elif mixer == "mla":
-        y, st, named = _mla_mixer(h, lp, st, pos, valid, n_valid, cfg, window, apart, mesh)
+        y, st, named = _mla_mixer(
+            h, lp, st, pos, valid, n_valid, cfg, window, apart, site, mesh
+        )
         names, read = tuple(named), tuple(named.values())
     elif mixer == "mla_window":
         y, st, named = _mla_window_mixer(h, lp, st, pos, valid, n_valid, cfg, window, apart)
@@ -2299,9 +2491,10 @@ def _mix(
         )
         names = ATTN_COUNTERS
     by_name = dict(zip(names, read))
+    wanted = cfg.row_counters + cfg.step_counters
     read = jnp.stack(
-        [jnp.asarray(by_name.get(n, 0), jnp.int32) for n in cfg.row_counters]
-    ) if cfg.row_counters else jnp.zeros((0,), jnp.int32)
+        [jnp.asarray(by_name.get(n, 0), jnp.int32) for n in wanted]
+    ) if wanted else jnp.zeros((0,), jnp.int32)
     return x + y, st, read
 
 
@@ -2344,7 +2537,7 @@ def forward(
     pos = start[:, None].astype(jnp.int32) + steps
     valid = steps < n_valid[:, None]
     counters = jnp.zeros((len(moe.COUNTERS),), jnp.int32)
-    read = jnp.zeros((len(cfg.row_counters),), jnp.int32)
+    read = jnp.zeros((len(cfg.row_counters) + len(cfg.step_counters),), jnp.int32)
     out_state = []
     rho = None  # a ZAYA router's state, from layer to layer beside ``x``
     for (mixer, mlp), lp, st in zip(cfg.layer_kinds, params["layers"], state):
@@ -2407,7 +2600,8 @@ def mtp_forward(
     stack's output there (``forward``'s), ``next_tokens`` the token that
     follows each, ``valid`` (b, s) which of them count (a position that
     does not writes no row and routes nowhere; ``pos`` may be -1 there),
-    ``rows`` its block's K/V rows.  Returns (the module's hidden (b, s, D),
+    ``rows`` its block's rows (K/V, or latent rows and index keys: the
+    kind ``cfg.mtp_kind`` names).  Returns (the module's hidden (b, s, D),
     whose :func:`mtp_logits` at ``t`` predict token ``t + 2``; rows;
     counters as ``forward``'s)."""
     lp = params["mtp"]["layer"]
@@ -2415,10 +2609,10 @@ def mtp_forward(
     u = _mtp_input(params, cfg, hidden, next_tokens)
     with jax.named_scope("mtp/block"):
         x, rows, read = _mix(
-            u, lp, rows, MTP_KIND[0], pos, valid, n_valid, cfg, window, rows_apart,
+            u, lp, rows, cfg.mtp_kind[0], pos, valid, n_valid, cfg, window, rows_apart,
             site="mtp_", mesh=mesh,
         )
-        x, counters, _ = _mlp(x, lp, MTP_KIND[1], valid, cfg, mesh)
+        x, counters, _ = _mlp(x, lp, cfg.mtp_kind[1], valid, cfg, mesh)
     return x, rows, jnp.concatenate([counters, read])
 
 
@@ -2727,6 +2921,54 @@ DOTS3_NOTE_TINY = {
 }
 
 
+# deepseek-ai/DeepSeek-V3.2's config.json: every key that gives the model
+# its shape (``ep_size`` and ``max_position_embeddings`` shape nothing here).
+DEEPSEEK_V32 = {
+    "model_type": "deepseek_v32", "num_hidden_layers": 61, "hidden_size": 7168,
+    "intermediate_size": 18432, "moe_intermediate_size": 2048,
+    "first_k_dense_replace": 3, "moe_layer_freq": 1, "num_attention_heads": 128,
+    "num_key_value_heads": 128, "q_lora_rank": 1536, "kv_lora_rank": 512,
+    "qk_nope_head_dim": 128, "qk_rope_head_dim": 64, "v_head_dim": 128,
+    "index_n_heads": 64, "index_head_dim": 128, "index_topk": 2048,
+    "attention_bias": False, "hidden_act": "silu", "n_routed_experts": 256,
+    "n_shared_experts": 1, "num_experts_per_tok": 8, "n_group": 8, "topk_group": 4,
+    "scoring_func": "sigmoid", "topk_method": "noaux_tc", "norm_topk_prob": True,
+    "routed_scaling_factor": 2.5, "num_nextn_predict_layers": 1,
+    "max_position_embeddings": 163840, "rope_theta": 10000,
+    "rope_scaling": {
+        "type": "yarn", "factor": 40, "original_max_position_embeddings": 4096,
+        "beta_fast": 32, "beta_slow": 1, "mscale": 1, "mscale_all_dim": 1,
+    },
+    "rms_norm_eps": 1e-06, "vocab_size": 129280, "tie_word_embeddings": False,
+}
+# Rank 0's share of the first pipeline stage, every layer shared by sixteen
+# chips: published layers 0-4 with ONE leading dense layer standing for the
+# three (they count once), 16 of the 256 experts (the first 16: half of
+# routing group 0), an eighth of the vocabulary; the module is held here.
+DEEPSEEK_V32_L5E16_CUT = {
+    "num_hidden_layers": 5, "first_k_dense_replace": 1, "n_routed_experts": 16,
+    "num_experts_published": 256, "vocab_size": 16160,
+}
+# Every ratio at sizes a CPU test runs: a dense first layer and two expert
+# layers, an indexer of 2 heads that keeps 24 rows (prompts of 80 cross
+# it), YaRN past an original context of 32, 16 router outputs in 2 groups
+# of which 1 is kept, 4 held (HALF a group, as in the cut) and 2 a token,
+# one prediction module.
+DEEPSEEK_V32_TINY = {
+    **DEEPSEEK_V32, "num_hidden_layers": 3, "first_k_dense_replace": 1,
+    "hidden_size": 64, "intermediate_size": 128, "moe_intermediate_size": 32,
+    "num_attention_heads": 4, "num_key_value_heads": 4, "q_lora_rank": 24,
+    "kv_lora_rank": 16, "qk_nope_head_dim": 8, "qk_rope_head_dim": 8,
+    "v_head_dim": 16, "index_n_heads": 2, "index_head_dim": 16, "index_topk": 24,
+    "n_routed_experts": 4, "num_experts_published": 16, "num_experts_per_tok": 2,
+    "n_group": 2, "topk_group": 1, "vocab_size": 512, "torch_dtype": "float32",
+    "rope_scaling": {
+        **DEEPSEEK_V32["rope_scaling"], "factor": 8,
+        "original_max_position_embeddings": 32,
+    },
+}
+
+
 def ling_flash_vl_l7e128() -> HybridConfig:
     return from_hf_config({**LING_FLASH_VL, **LING_L7E128_CUT}, max_len=2048)
 
@@ -2793,6 +3035,20 @@ def dots3_note_tiny() -> HybridConfig:
     )
 
 
+def deepseek_v32_l5e16() -> HybridConfig:
+    return from_hf_config(
+        {**DEEPSEEK_V32, **DEEPSEEK_V32_L5E16_CUT}, max_len=16384, draft="mtp"
+    )
+
+
+def deepseek_v32_tiny() -> HybridConfig:
+    # Blocks of 16 rows: shorter than the windows the tests use.
+    return dataclasses.replace(
+        from_hf_config(DEEPSEEK_V32_TINY, max_len=256, kv_dtype="float32", draft="mtp"),
+        latent_block=16, latent_decode_block=16,
+    )
+
+
 PRESETS = {
     "ling-3.0-flash-vl-l7e128": ling_flash_vl_l7e128,
     "ling-tiny": ling_tiny,
@@ -2808,4 +3064,6 @@ PRESETS = {
     "nemotron_h-tiny": nemotron_h_tiny,
     "dots3-note-prev-l6e32": dots3_note_prev_l6e32,
     "dots3_note-tiny": dots3_note_tiny,
+    "deepseek-v3.2-l5e16": deepseek_v32_l5e16,
+    "deepseek_v32-tiny": deepseek_v32_tiny,
 }

@@ -26,7 +26,8 @@ a token (576 values at Ling's published widths, 320 at Mistral-Small-4's).
   ``allowed`` (a prefill chunk: every block up to the row's length is
   still expanded and scored, the softmax keeps the selected pairs), and
   :func:`attend_selected` gathers the kept rows and attends over them
-  alone in the absorbed form (a decode step);
+  alone in the absorbed form (a decode step, and a verify step's two
+  positions, each over the rows it selects for itself);
 * :func:`attend_latent_ring` — a window layer of latent rows: the ring
   as it was beside the call's own rows in one softmax
   (``ops/gqa.py::attend_ring``'s rule), expanded for a chunk, absorbed
@@ -352,12 +353,36 @@ def select_mask(scores, k: int):
 
 @jax.named_scope("layer/mla/select")
 def select_rows(scores, k: int):
-    """The positions of the ``k`` largest of each row of ``scores`` (b, T)
+    """The positions of the ``k`` largest of each row of ``scores`` (n, T)
     (a tie to the lower position: ``lax.top_k``'s rule) and which of them
-    are positions the query sees (not ``-inf``): ((b, k) int32, (b, k)
-    bool).  ``k`` is cut to ``T``."""
+    are positions the query sees (not ``-inf``): ((n, k) int32, (n, k)
+    bool).  ``k`` is cut to ``T``.  A decode step hands it one row a slot,
+    a verify step one row a slot and position (slot-major): each position
+    selects for itself."""
     vals, idx = jax.lax.top_k(scores, min(k, scores.shape[-1]))
     return idx.astype(jnp.int32), vals > -jnp.inf
+
+
+@jax.named_scope("layer/mla/select")
+def rows_needed(scores, idx, keep, counts):
+    """How many rows the queries of each slot need between them: the size
+    of the union of the sets :func:`select_rows` kept (``idx``, ``keep``
+    (b, s, k) of ``scores`` (b, s, T)) for the slot's queries that count
+    (``counts`` (b, s) bool).  Each set is rebuilt as a mask from its LAST
+    entry (``lax.top_k`` orders by score, equal scores by position, so the
+    last position kept holds the lowest kept score and, of the scores
+    equal to it, the highest position kept): what lies above that score,
+    and of the scores equal to it the positions up to that one.  One score
+    a query is looked up; nothing is scattered, cumulated or gathered by
+    the set (a gather of 65,536 scores a block took 0.81 ms of a verify
+    step on a v5e: PERF.md, PR 53).  Returns (b,) int32."""
+    n_kept = jnp.sum(keep, axis=-1, keepdims=True)
+    last = jnp.take_along_axis(idx, jnp.maximum(n_kept - 1, 0), axis=-1)  # (b, s, 1)
+    key = _ordered_bits(scores)  # ``top_k``'s order: -0.0 under 0.0
+    lowest = jnp.take_along_axis(key, last, axis=-1)
+    at = jnp.arange(scores.shape[-1], dtype=jnp.int32)
+    kept = (n_kept > 0) & ((key > lowest) | ((key == lowest) & (at <= last)))
+    return jnp.sum(jnp.any(kept & counts[..., None], axis=1), axis=-1).astype(jnp.int32)
 
 
 def _attend_whole_rows(q_nope, q_rope, rows, w, mask, nope: int, scale):
@@ -377,16 +402,26 @@ def _attend_whole_rows(q_nope, q_rope, rows, w, mask, nope: int, scale):
 
 @jax.named_scope("layer/mla/attn")
 def attend_selected(q_nope, q_rope, latent, w_kvb, idx, keep, *, rank, nope, v_dim, scale=None):
-    """:func:`attend_absorbed` over the rows ``idx`` (b, k) of each slot's
-    ``latent`` (b, T, width) alone, of which ``keep`` (b, k) count: the
-    rows are gathered whole (zero columns after the rope key and all) and
-    read once for all heads.  q_nope, q_rope: (b, 1, H, .), one query a
-    slot.  Returns (b, 1, H, v_dim)."""
+    """:func:`attend_absorbed` over the rows ``idx`` (b, s, k) of each
+    slot's ``latent`` (b, T, width) alone, of which ``keep`` (b, s, k)
+    count: every query has its OWN rows (a verify step's second position
+    keeps another set than its first), gathered whole (zero columns after
+    the rope key and all) and read once for all heads.  q_nope, q_rope:
+    (b, s, H, .), a decode step's one query a slot or a verify step's two.
+    Returns (b, s, H, v_dim)."""
     if scale is None:
         scale = (nope + q_rope.shape[-1]) ** -0.5
-    w = w_kvb.reshape(rank, q_nope.shape[2], nope + v_dim)
-    rows = jnp.take_along_axis(latent, idx[:, :, None], axis=1)  # (b, k, width)
-    return _attend_whole_rows(q_nope, q_rope, rows, w, keep[:, None, None, :], nope, scale)
+    b, s, H = q_nope.shape[:3]
+    k = idx.shape[-1]
+    w = w_kvb.reshape(rank, H, nope + v_dim)
+    rows = jnp.take_along_axis(latent[:, None], idx[..., None], axis=2)  # (b, s, k, width)
+    # A query a row of the batch: each against the rows gathered for it.
+    alone = lambda x: x.reshape((b * s, 1) + x.shape[2:])
+    o = _attend_whole_rows(
+        alone(q_nope), alone(q_rope), rows.reshape(b * s, k, -1), w,
+        keep.reshape(b * s, 1, 1, k), nope, scale,
+    )
+    return o.reshape(b, s, H, v_dim)
 
 
 def attend_latent_ring(

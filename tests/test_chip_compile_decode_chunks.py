@@ -130,6 +130,65 @@ def test_the_verify_chunk_compiles_at_the_published_widths(one_chip, monkeypatch
     assert compiled.memory_analysis().temp_size_in_bytes < SPARE_BY_FAMILY["exaone"]
 
 
+def test_the_verify_chunk_over_selected_latent_rows_compiles_and_never_takes_the_chunk_form(
+        one_chip, monkeypatch):
+    """The decode chunk of deepseek-v3.2-l5e16 (the module's catch-up, then
+    8 verify steps over 16 slots of 16,384 at the widest decode window:
+    five indexed latent layers and the module's block, two positions a
+    row): with an indexer and two queries a slot every block scores,
+    selects and gathers a set a position (``attn_latent_sparse_verify``)
+    and no block walks a row's blocks as a prefill chunk does; the grouped
+    expert products are in it; beside 11.38 GB of weights and 2.42 GB of
+    state its temporaries (the gathered rows, 84 MB a block, and a
+    block's float32 index scores, 134 MB) stay under half a GB."""
+    import json
+    from pathlib import Path
+
+    from generativeaiexamples_tpu.engine.serving_models import HybridServing
+    from generativeaiexamples_tpu.models import hybrid
+    from generativeaiexamples_tpu.ops import dispatch, moe
+
+    monkeypatch.setattr(moe, "platform_of", lambda mesh: "tpu")
+    monkeypatch.setattr(dispatch, "TAKEN", {})
+    configs = Path(__file__).resolve().parents[1] / "benchmarks" / "configs"
+    model = json.loads((configs / "deepseek-v3.2-l5e16.json").read_text())
+    engine = model["engine"]
+    max_len, b, steps = int(engine["max_len"]), int(engine["max_batch"]), int(engine["decode_chunk_size"])
+    cfg = hybrid.from_hf_config(model, max_len=max_len, kv_dtype=engine["kv_dtype"], draft=engine["draft"])
+    assert cfg.draft == "mtp" and cfg.mtp_kind == ("mla", "experts") and len(cfg.layers_of("mla")) == 5
+    serving = HybridServing(cfg, None, max_len)
+
+    def described(make):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            jax.eval_shape(make),
+        )
+
+    spec = _spec(one_chip)
+    ints, floats = spec((b,), jnp.int32), spec((b,), jnp.float32)
+    compiled = serving.make_decode_chunk().lower(
+        described(lambda: hybrid.init_params(cfg, jax.random.PRNGKey(0))),
+        described(lambda: hybrid.init_state(cfg, b, max_len)),
+        ints, ints, spec((2,), jnp.uint32), floats, floats, ints, steps, max_len,
+        spec((b,), jnp.bool_), spec((1, b), jnp.int32), spec((b,), jnp.bool_),
+        ints, spec((b,), jnp.bool_),
+    ).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    taken = dict(dispatch.TAKEN)
+    for site in (f"index_scores b={b} s=2 t={max_len}", f"attn_latent_sparse_verify b={b} t={max_len} k=2048",
+                 f"mtp_index_scores b={b} s=2 t={max_len}", f"mtp_attn_latent_sparse_verify b={b} t={max_len} k=2048",
+                 f"mtp_attn_latent_sparse_decode b={b} t={max_len} k=2048"):
+        assert taken[site] == "xla", (site, taken)
+    assert not [site for site in taken if "chunk" in site], taken
+    assert {taken[site] for site in taken if site.startswith("moe_experts")} == {"pallas"}
+    _, toks, counts, (newest, lengths), aux = compiled.out_info
+    assert toks.shape == (steps, b, 2) and counts.shape == (steps, b)
+    assert newest.shape == (1, b) and lengths.shape == (b,)
+    assert aux.shape == (len(serving.counter_names),)
+    print("verify chunk temporaries", compiled.memory_analysis().temp_size_in_bytes)
+    assert compiled.memory_analysis().temp_size_in_bytes < 500_000_000
+
+
 def test_the_cca_models_decode_chunk_walks_its_rows_in_place(one_chip, monkeypatch):
     """The decode chunk of zaya1-8b-l20 (8 steps over 32 slots of 8,192
     rows, twenty ``cca`` layers): every layer's attention is the row walk

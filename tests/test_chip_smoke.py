@@ -422,8 +422,52 @@ def test_hybrid_phase_rehearsal_of_the_indexed_latent_model_and_its_controls(con
         assert line["p90"] > 1e-2 and line["decode_p50"] > 1e-2 and line["index_overlap"] < 0.8
 
 
+DEEPSEEK_CONTROLS = list(chip_smoke.HYBRID_CONTROLS["deepseek_v32"])
+
+
+# The mechanisms against the reference alone: tests/test_deepseek_v32_model.py.
+@pytest.mark.parametrize("control", ["", "stale_reject", "draft_shares_set"])
+def test_hybrid_phase_rehearsal_of_the_drafting_indexed_model_and_its_controls(control, capsys, monkeypatch, tmp_path):
+    """``--hybrid --model deepseek_v32`` at the tiny size, in process: a
+    prompt of 76 tokens (three times ``index_topk`` 24) in chunks of 16
+    through the chunk program beside a pad row, its last 12 positions
+    through the verify step over both slots on true and on wrong drafts,
+    by the benchmark's own comparison (the logit shares of the stack and
+    of the module, and both index overlaps), held to limits of the
+    rehearsal's own which the sound run is far inside and each control
+    leaves: a rejected draft's stale row read as a token's, and a verify
+    step's second position given the first's set."""
+    config = json.loads(open(os.path.join(REPO, chip_smoke.DEEPSEEK_CONFIG)).read())
+    config["reference"]["logit_share_limits"] = {
+        k: 2e-4 for k in ("p10", "p50", "p90", "accept_p50", "reject_p50", "module_p50")}
+    config["reference"]["index_overlap_floors"] = {"stack": 0.999, "module": 0.999}
+    tiny = tmp_path / "config.json"
+    tiny.write_text(json.dumps(config))
+    monkeypatch.setattr(chip_smoke, "DEEPSEEK_CONFIG", str(tiny))
+    chip_smoke.child_hybrid(0, chip_smoke.TINY, control=control, model="deepseek_v32")
+    line = _phases(capsys)["hybrid"]
+    assert line["model"] == "deepseek_v32-tiny" and line["control"] == (control or None)
+    assert line["positions"] == {"prefill": 64, "accept": 12, "reject": 11, "module": 18}
+    # The shapes of the scheduler's programs: a chunk beside a pad row, a
+    # verify step over both slots, in the stack and in the module's block.
+    for site in ("index_scores b=2 s=16 t=256", "attn_latent_chunk b=2 s=16 t=256 k=24",
+                 "index_scores b=2 s=2 t=256", "attn_latent_sparse_verify b=2 t=256 k=24",
+                 "mtp_attn_latent_chunk b=2 s=16 t=256 k=24", "mtp_attn_latent_sparse_verify b=2 t=256 k=24",
+                 "mtp_attn_latent_sparse_decode b=2 t=256 k=24"):
+        assert line["kernel_paths"][site] == "xla"
+    assert line["within_limits"] == (not control)
+    if not control:
+        assert max(line[k] for k in config["reference"]["logit_share_limits"]) < 1e-5
+        assert line["index_overlap_stack"] == line["index_overlap_module"] == 1.0
+    elif control == "stale_reject":  # the prefill and the accept pass are sound
+        assert line["p90"] < 1e-5 and line["accept_p50"] < 1e-5 and line["reject_p50"] > 1e-2
+    else:  # every second position attends another set, prefilled or verified
+        assert line["p50"] > 1e-2 and line["accept_p50"] > 1e-2 and line["index_overlap_stack"] < 0.9
+
+
 def test_hybrid_phase_names_a_child_for_every_model_and_control():
     assert sorted(n for n in chip_smoke.CHILDREN if n.startswith("hybrid")) == [
+        "hybrid_deepseek_v32", *(f"hybrid_deepseek_v32_{c}" for c in sorted(DEEPSEEK_CONTROLS)),
         "hybrid_dots3_note", *(f"hybrid_dots3_note_{c}" for c in sorted(DOTS3_CONTROLS)),
         "hybrid_exaone_moe", "hybrid_exaone_moe_no_qk_norm", "hybrid_exaone_moe_no_window",
         "hybrid_exaone_moe_rope_on_full", "hybrid_exaone_moe_stale_reject", "hybrid_exaone_moe_w8a8_mlp",

@@ -529,7 +529,8 @@ def test_a_decode_step_over_the_gathered_rows_matches_the_masked_window(k):
     assert idx.shape == (2, min(k, 64)) and (np.asarray(keep).sum(-1) == np.minimum([51, 10], k)).all()
     rank_of = jnp.argsort(jnp.argsort(-scores, axis=-1, stable=True), axis=-1)
     want = _masked_attention(c, q_pos, (seen & (rank_of < k))[:, None])
-    got = mla.attend_selected(c["q_nope"], c["q_rope"], wide, c["w_kvb"], idx, keep, **c["sizes"])
+    got = mla.attend_selected(  # one query a slot: a set a slot and position
+        c["q_nope"], c["q_rope"], wide, c["w_kvb"], idx[:, None], keep[:, None], **c["sizes"])
     np.testing.assert_allclose(got, want, atol=1e-5)
     if k >= 64:
         dense = mla.attend_absorbed(c["q_nope"], c["q_rope"], c["latent"], c["w_kvb"], q_pos, **c["sizes"])
