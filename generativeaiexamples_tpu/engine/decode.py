@@ -295,20 +295,28 @@ def make_decode_chunk_fn(cfg: llama.LlamaConfig, mesh, max_len: int):
     ``kv_lengths`` 0: nothing of its cache is read, it folds the append
     buffer alone, so its tokens are finite and — as before — never
     emitted.  Its write positions, append-buffer slots and the flush are
-    those of ``lengths``, exactly as without ``live``.  ``None`` attends
-    every row over its length.
+    those of ``lengths``, exactly as without ``live``: its fresh K/V is
+    written into the buffer like any row's, because the flush reads
+    every row.  ``None`` attends every row over its length.
 
     Two equivalent implementations, chosen at trace time:
 
     * **Append-buffer** (TPU, int8 KV): per-step KV goes to a small
-      (L, KH, B, n_steps, HD) append buffer via contiguous writes;
-      attention streams the big-cache window plus the buffer through
-      ``ops.decode_attention`` — the Pallas kernel when shapes align and
-      it is enabled, else its XLA einsum twin
-      (``decode_gqa_attention_xla``), so disabling the kernel never
-      falls back to big-cache scatters (which OOM at serving batch);
-      one windowed scatter flushes the buffer at chunk end.  The big
-      cache is read-only inside the step, which is what keeps its layout
+      (L, KH, B, n_steps, HD) append buffer, and attention streams the
+      big-cache window plus the buffer through ``ops.decode_attention``
+      — the Pallas kernel when shapes align and it is enabled, else its
+      XLA einsum twin (``decode_gqa_attention_xla``), so disabling the
+      kernel never falls back to big-cache scatters (which OOM at
+      serving batch).  Whichever attends also writes: the kernel takes
+      the step's quantised rows beside the buffer's four leaves, puts
+      them into its own block in VMEM and hands the leaves back aliased
+      onto its operands, so inside the layer loop the scan's carry is
+      updated by the Mosaic call and by no XLA operation (an XLA write
+      into a buffer laid out for the kernel cost 22-25 us a layer call,
+      4.3-4.9 ms of Ouro's 30.4 ms step: PERF.md section 6, PR 54); the
+      twin writes them as contiguous ``dynamic_update_slice``.  One
+      windowed scatter flushes the buffer at chunk end.  The big cache
+      is read-only inside the step, which is what keeps its layout
       kernel-compatible.
     * **XLA reference** (CPU tests, bf16 KV, multi-chip): per-step scatter
       into the big cache + slice/einsum attention — the semantics oracle.

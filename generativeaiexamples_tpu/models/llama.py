@@ -970,10 +970,13 @@ def forward(
     Neva/DePlot-class VLM bridge in ``models.vision``).
 
     ``append_cache`` — the serving decode chunk's append-buffer protocol
-    (int8 KV + Pallas decode kernel only): ``(ab, step)`` where ``ab`` is
+    (int8 KV, one chip): ``(ab, step)`` where ``ab`` is
     a 4-tuple of (L, KH, B, C, HD) int8 values / (L, KH, B, C) bf16
     scales and ``step`` the chunk-step index.  The fresh token's KV is
-    written to ab slot ``step`` (contiguous dynamic_update_slice) and
+    written to ab slot ``step`` of every row, by the decode kernel itself
+    where it runs (the leaves go through the Mosaic call aliased, and no
+    XLA operation of the layer loop touches them), by a contiguous
+    dynamic_update_slice in its XLA twin and for a verify block, and
     attention runs over the big cache's [0, kv_lengths) prefix PLUS ab
     slots [0, step] — the big cache is never written, which keeps the
     decode executable free of the per-token scatter whose preferred
@@ -1018,6 +1021,7 @@ def forward(
             use_append_buffer,
             use_decode_kernel,
             verify_gqa_attention_xla,
+            write_append_rows,
         )
 
         if not (
@@ -1048,9 +1052,15 @@ def forward(
             n_q=n_q, n_kv=n_kv, head_dim=hd, cache_len=t,
             append_width=append_cache[0][0].shape[3], mesh=mesh,
         )
+        ab_in, append_step = append_cache
         if s == 1:
             record(f"decode_attention b={b} w={window}", _append_kernel)
-        ab_in, append_step = append_cache
+            # Who writes the step's fresh rows into the append buffer: the
+            # kernel itself, or the twin's dynamic_update_slice.
+            record(
+                f"decode_append_write b={b} c={ab_in[0].shape[3]}",
+                _append_kernel,
+            )
         if s > 1 and ab_in[0].shape[3] != s:
             raise ValueError(
                 f"verify append buffer has {ab_in[0].shape[3]} slots for "
@@ -1159,57 +1169,35 @@ def forward(
         with jax.named_scope("layer/attn"):
             if kv is not None and kv_int8 and ab is not None:
                 # Append-buffer decode: fresh KV goes to ab slot
-                # ``append_step`` (a contiguous dynamic_update_slice — no
-                # scatter touches the big cache in this executable), and the
-                # kernel attends over cache[0:kv_lengths) + ab[0:step].
+                # ``append_step`` and attention runs over
+                # cache[0:kv_lengths) + ab[0:step]; no scatter touches the
+                # big cache in this executable.  A decode step hands the
+                # fresh rows to the attention, which writes them itself
+                # (the kernel into its own block of the buffer, so that no
+                # XLA operation touches a leaf inside the layer loop; the
+                # twin as ``dynamic_update_slice``); a verify block writes
+                # its S rows here, once.
                 k8, ks = _quantize_kv(k)
                 v8, vs = _quantize_kv(v)
                 step = jnp.asarray(append_step, jnp.int32)
-
-                def write_ab(buf, fresh):
-                    with jax.named_scope("kv_write"):
-                        fresh_t = jnp.transpose(
-                            fresh, (2, 0, 1) + tuple(range(3, fresh.ndim))
-                        )[None]
-                        return jax.lax.dynamic_update_slice(
-                            buf,
-                            fresh_t,
-                            (li, 0, 0, step) + (0,) * (buf.ndim - 4),
-                        )
-
-                ab = (
-                    write_ab(ab[0], k8),
-                    write_ab(ab[1], v8),
-                    write_ab(ab[2], ks),
-                    write_ab(ab[3], vs),
-                )
                 if s == 1:
                     _decode_attn = (
                         decode_gqa_attention if _append_kernel
                         else decode_gqa_attention_xla
                     )
-                    attn = _decode_attn(
-                        q[:, 0],
-                        kv[0],
-                        kv[1],
-                        kv[2],
-                        kv[3],
-                        li,
-                        kv_lengths,
-                        append=(ab[0], ab[1], ab[2], ab[3], step + 1),
-                        window=window,
-                    )[:, None]
+                    fresh = (k8[:, 0], v8[:, 0], ks[:, 0], vs[:, 0])
+                    attn, ab = _decode_attn(
+                        q[:, 0], *kv, li, kv_lengths,
+                        append=(ab, fresh, step), window=window,
+                    )
+                    attn = attn[:, None]
                 else:  # speculative-verify block over cache + causal buffer
+                    ab = tuple(
+                        write_append_rows(leaf, fresh, li, step)
+                        for leaf, fresh in zip(ab, (k8, v8, ks, vs))
+                    )
                     attn = verify_gqa_attention_xla(
-                        q,
-                        kv[0],
-                        kv[1],
-                        kv[2],
-                        kv[3],
-                        li,
-                        kv_lengths,
-                        (ab[0], ab[1], ab[2], ab[3]),
-                        window=window,
+                        q, *kv, li, kv_lengths, ab, window=window
                     )
             elif kv is not None and kv_int8:
                 k8, ks = _quantize_kv(k)

@@ -23,6 +23,14 @@ to the query dtype inside the dot (HBM streams int8 bytes only), and the
 per-(token, head) dequant scales fold into scores / softmax weights.
 Rows with ``kv_length == 0`` produce exact zeros.
 
+Inside a decode chunk the kernel also WRITES: it takes the step's fresh
+quantised K/V beside the chunk's append buffer, puts every row's into
+the buffer's slot of this step in VMEM, folds the buffer, and hands the
+buffer's four leaves back aliased onto its operands.  An XLA write into
+a buffer laid out for the kernel costs per call, not per byte (22-25 us
+a layer call on the v5e: PERF.md section 6, PR 54), so inside the layer
+loop nothing but the Mosaic call touches the leaves.
+
 Cache layout contract (``models.llama.init_kv_cache``): values
 ``(L, KH, B, T, HD)``, scales ``(L, KH, B, T)`` — head-major so the
 kernel's KV blocks tile the minor-most ``(T, HD)`` dims legally.
@@ -162,6 +170,35 @@ def _online_update(
     l_ref[:] = jnp.broadcast_to(l_new, l_ref.shape)
 
 
+def _put_fresh_rows(ab_refs, fresh_refs, out_refs, slot):
+    """The group's blocks of the append buffer with the step's fresh rows
+    at slot ``slot``, written to ``out_refs`` (which alias the leaves).
+
+    ``ab_refs`` are the blocks as the call found them (values
+    (1, KH, 16, C, HD) int8, scales (1, KH, 16, C) bf16) and
+    ``fresh_refs`` the 16 rows' quantised K/V of this step ((16, KH, HD)
+    int8, (16, KH) bf16): every row's goes in, whether it decodes or
+    not.  A head at a time, in a loop that is traced once (an unrolled
+    one doubled the kernel's trace, 0.85 s a decode-chunk program of
+    Ouro's set-up on the chip's host: PERF.md section 6, PR 54).  The
+    head's rows come out of the fresh block as a masked sum and go into
+    the slot as a select, both in float32, because a packed sublane
+    takes no dynamic index; an int8 and a bfloat16 both survive the
+    round trip bit for bit."""
+    fresh = [ref[...].astype(jnp.float32) for ref in fresh_refs]
+
+    def put_head(h, _):
+        for new, ab_ref, out_ref in zip(fresh, ab_refs, out_refs):
+            of_head = jax.lax.broadcasted_iota(jnp.int32, new.shape, 1) == h
+            row = jnp.sum(jnp.where(of_head, new, 0.0), axis=1, keepdims=True)
+            block = ab_ref[0, h].astype(jnp.float32)  # (16, C[, HD])
+            at_slot = jax.lax.broadcasted_iota(jnp.int32, block.shape, 1) == slot
+            out_ref[0, h] = jnp.where(at_slot, row, block).astype(out_ref.dtype)
+        return 0
+
+    jax.lax.fori_loop(0, ab_refs[0].shape[1], put_head, 0)
+
+
 def _fold_append_group(
     q_ref, kab_ref, vab_ref, ksab_ref, vsab_ref, count,
     m_ref, l_ref, acc_ref, scale,
@@ -206,15 +243,18 @@ def _fold_append_group(
 
 def _decode_kernel(
     li_ref,  # scalar prefetch: (1,) int32 layer index
-    abn_ref,  # scalar prefetch: (1,) int32 valid append-buffer slots
+    slot_ref,  # scalar prefetch: (1,) int32 append slot of this step
     len_ref,  # scalar prefetch: (B,) int32 valid kv prefix per row
     q_ref,  # (16, KH, G, HD) — the program's group of 16 rows
     k_hbm,  # (L, KH, B, T, HD) int8 — stays in HBM (pl.ANY)
     v_hbm,  # (L, KH, B, T, HD) int8 — stays in HBM
     ks_ref,  # (1, KH, 16, W) bf16 — the group's scale planes
     vs_ref,  # (1, KH, 16, W) bf16
-    # with has_ab: kab, vab (1, KH, 16, C, HD) int8; ksab, vsab
-    # (1, KH, 16, C) bf16 — the group's rows of the append buffer.
+    # with has_ab: kab, vab (1, KH, 16, C, HD) int8 and ksab, vsab
+    # (1, KH, 16, C) bf16, the group's rows of the append buffer; then
+    # the group's fresh rows, k8, v8 (16, KH, HD) int8 and ks, vs
+    # (16, KH) bf16.  Outputs: o (16, KH, G, HD) and, with has_ab, the
+    # four append blocks again, which alias the leaves.
     *rest,
     block_t: int,
     scale: float,
@@ -238,15 +278,18 @@ def _decode_kernel(
     What a one-row copy cannot bring (the bf16 scale planes tile
     ``(B, T)`` by (16, 128), so one row is not a whole tile) comes in
     through BlockSpecs for the group: scales, and with them queries,
-    append slots and the output block.  The append buffer folds first,
-    for all 16 rows at once (:func:`_fold_append_group`), and the
-    outputs are normalized and stored once at the end, so a row that
-    reads nothing costs a few scalar operations.
+    append slots and the output block.  The append buffer takes the
+    step's fresh rows (:func:`_put_fresh_rows`) and folds first, for all
+    16 rows at once (:func:`_fold_append_group`), and the outputs are
+    normalized and stored once at the end, so a row that reads nothing
+    costs a few scalar operations.
     """
     if has_ab:
-        kab_ref, vab_ref, ksab_ref, vsab_ref = rest[:4]
-        rest = rest[4:]
-    o_ref, kbuf, vbuf, sem, state, m_ref, l_ref, acc_ref = rest
+        ab_refs, fresh_refs, rest = rest[:4], rest[4:8], rest[8:]
+        o_ref, out_refs, rest = rest[0], rest[1:5], rest[5:]
+    else:
+        o_ref, rest = rest[0], rest[1:]
+    kbuf, vbuf, sem, state, m_ref, l_ref, acc_ref = rest
     rows, kh, g = q_ref.shape[0], q_ref.shape[1], q_ref.shape[2]
     first_row = pl.program_id(0) * rows
     n_rows = pl.num_programs(0) * rows
@@ -276,8 +319,11 @@ def _decode_kernel(
         state[1] = 0
 
     if has_ab:
+        # The step's rows go into the blocks that are handed back, and
+        # the fold reads those.
+        _put_fresh_rows(ab_refs, fresh_refs, out_refs, slot_ref[0])
         _fold_append_group(
-            q_ref, kab_ref, vab_ref, ksab_ref, vsab_ref, abn_ref[0],
+            q_ref, *out_refs, slot_ref[0] + 1,
             m_ref, l_ref, acc_ref, scale,
         )
     else:
@@ -365,12 +411,15 @@ def _decode_kernel_vmem_bytes(
 ) -> int:
     """VMEM the contiguous kernel holds: the ping-pong k/v blocks, and
     for the row's group of 16 the double-buffered scale planes, append
-    slots, queries and outputs, plus the online-softmax scratch."""
+    slots as they come in and as they go out again, fresh rows, queries
+    and outputs, plus the online-softmax scratch."""
     rows = _ROW_GROUP
     return n_kv * (
         2 * 2 * block_t * hd  # k/v block double buffers (int8)
         + 2 * 2 * rows * width * 2  # k/v scale planes (bf16) x 2 buffers
-        + 2 * 2 * rows * c * (hd + 2)  # append values + scales x 2 buffers
+        # append values + scales, in and out, x 2 buffers each
+        + 2 * 2 * 2 * rows * c * (hd + 2)
+        + 2 * 2 * rows * (hd + 2)  # the step's fresh rows x 2 buffers
         + 2 * 2 * rows * g * hd * 4  # q + output blocks (f32 worst case)
         + rows * (2 * 128 + hd) * g * 4  # m/l/acc f32 scratch
     )
@@ -471,6 +520,21 @@ def use_append_buffer(
     if os.environ.get("GAIE_FORCE_APPEND_BUFFER"):
         return True
     return (backend or platform_of(mesh)) == "tpu" and one_device(mesh)
+
+
+@jax.named_scope("kv_write")
+def write_append_rows(leaf, fresh, layer, slot):
+    """An append leaf (L, KH, B, C, ...) with ``fresh`` (B, S, KH, ...)
+    at slots [slot, slot + S) of layer ``layer``, every row: XLA's form
+    of the write (a contiguous ``dynamic_update_slice``), the decode
+    twin's with S 1 and the verify block's with S the block."""
+    fresh_t = jnp.transpose(fresh, (2, 0, 1) + tuple(range(3, fresh.ndim)))
+    return jax.lax.dynamic_update_slice(
+        leaf,
+        fresh_t[None],
+        (jnp.asarray(layer, jnp.int32), 0, 0, jnp.asarray(slot, jnp.int32))
+        + (0,) * (leaf.ndim - 4),
+    )
 
 
 def _slice_layer_window(buf, li, w):
@@ -615,7 +679,7 @@ def decode_gqa_attention_xla(
     append=None,
     *,
     window: int,
-) -> jnp.ndarray:
+):
     """XLA twin of :func:`decode_gqa_attention` — identical contract,
     einsum math, no shape-alignment requirements.
 
@@ -626,18 +690,25 @@ def decode_gqa_attention_xla(
     executable shares the kernel path's memory/layout profile instead of
     the scatter path's (which OOMs at serving batch).  Cost vs the
     kernel: the per-layer KV window materializes as an XLA slice (the
-    round-2 4.3 ms/step item the kernel exists to kill).
+    round-2 4.3 ms/step item the kernel exists to kill), and the step's
+    fresh rows go into the append leaves as four
+    ``dynamic_update_slice`` (:func:`write_append_rows`), which on the
+    chip cost what PERF.md section 6, PR 54 says.
     """
     if append is not None:
-        k_ab, v_ab, ks_ab, vs_ab, count = append
-        buf = (k_ab, v_ab, ks_ab, vs_ab)
-        buf_base = jnp.asarray(count, jnp.int32) - 1
+        leaves, fresh, slot = append
+        buf = tuple(
+            write_append_rows(leaf, new[:, None], layer, slot)
+            for leaf, new in zip(leaves, fresh)
+        )
+        buf_base = jnp.asarray(slot, jnp.int32)
     else:
         buf, buf_base = None, jnp.int32(0)
-    return _cache_buffer_attention_xla(
+    out = _cache_buffer_attention_xla(
         q[:, None], k8, v8, ks, vs, layer, kv_lengths, buf, buf_base,
         window=window,
     )[:, 0]
+    return out if buf is None else (out, buf)
 
 
 @functools.partial(jax.jit, static_argnames=("window",))
@@ -686,7 +757,7 @@ def decode_gqa_attention(
     *,
     window: int,
     interpret=None,
-) -> jnp.ndarray:
+):
     """Decode attention for one layer of the stacked cache.
 
     Args:
@@ -702,18 +773,27 @@ def decode_gqa_attention(
         reads nothing from the cache: it attends the append buffer
         alone (exact zeros without one), which is what the decode chunk
         asks for rows that do not decode.
-      append: optional ``(k_ab, v_ab, ks_ab, vs_ab, count)`` — the decode
-        chunk's append buffer holding this chunk's fresh KV: values
-        (L, KH, B, C, HD) int8, scales (L, KH, B, C) bf16, ``count`` an
-        int32 scalar of valid slots (all rows share the count).  Folded
-        before the rows' walks, 16 rows at a time.
+      append: optional ``(leaves, fresh, slot)`` — the decode chunk's
+        append buffer and this step's part in it.  ``leaves`` are the
+        buffer's four (``models.llama.init_append_buffer``): values
+        (L, KH, B, C, HD) int8, scales (L, KH, B, C) bf16.  ``fresh``
+        is the step's quantised K/V as ``_quantize_kv`` makes it, ``k8,
+        v8`` (B, KH, HD) int8 and ``ks, vs`` (B, KH) bf16, and ``slot``
+        an int32 scalar (all rows share it).  The kernel itself puts
+        every row's fresh K/V into slot ``slot`` of layer ``layer``,
+        whether the row decodes or not (the chunk's flush reads every
+        row), then folds slots [0, slot] before the rows' walks, 16 rows
+        at a time.  The leaves come back as outputs aliased onto their
+        inputs: the call writes a group's blocks of the one layer and
+        nothing else touches the buffers.
       window: static; the caller guarantees ``kv_lengths <= window``.
         It no longer bounds the kernel's reads of k/v (the lengths do);
         it sizes the scale planes a program holds for its 16 rows, and
         a length beyond it is clipped to it.
 
     Returns:
-      (B, n_q_heads, HD) in q's dtype.
+      (B, n_q_heads, HD) in q's dtype; with ``append``, a pair of that
+      and the four leaves with the fresh rows in.
     """
     if interpret is None:
         interpret = _interpret_mode()
@@ -730,10 +810,10 @@ def decode_gqa_attention(
     rows = _ROW_GROUP
 
     def group_map(*tail):
-        return lambda gi, li, abn, lens: (gi,) + tail
+        return lambda gi, li, slot, lens: (gi,) + tail
 
     def layer_group_map(*tail):
-        return lambda gi, li, abn, lens: (li[0], 0, gi) + tail
+        return lambda gi, li, slot, lens: (li[0], 0, gi) + tail
 
     in_specs = [
         pl.BlockSpec((rows, n_kv, g, hd), group_map(0, 0, 0)),
@@ -743,21 +823,34 @@ def decode_gqa_attention(
         pl.BlockSpec((1, n_kv, rows, width), layer_group_map(0)),
     ]
     operands = [q, k8, v8, ks, vs]
+    out_specs = [pl.BlockSpec((rows, n_kv, g, hd), group_map(0, 0, 0))]
+    out_shape = [jax.ShapeDtypeStruct((b, n_kv, g, hd), q.dtype)]
+    aliases = {}
     if has_ab:
-        k_ab, v_ab, ks_ab, vs_ab, count = append
-        c = k_ab.shape[3]
-        in_specs += [
-            pl.BlockSpec((1, n_kv, rows, c, hd), layer_group_map(0, 0)),
-            pl.BlockSpec((1, n_kv, rows, c, hd), layer_group_map(0, 0)),
-            pl.BlockSpec((1, n_kv, rows, c), layer_group_map(0)),
-            pl.BlockSpec((1, n_kv, rows, c), layer_group_map(0)),
-        ]
-        operands += [k_ab, v_ab, ks_ab, vs_ab]
-        abn = jnp.asarray(count, jnp.int32).reshape(1)
+        leaves, fresh, slot = append
+        c = leaves[0].shape[3]
+        ab_specs = 2 * [
+            pl.BlockSpec((1, n_kv, rows, c, hd), layer_group_map(0, 0))
+        ] + 2 * [pl.BlockSpec((1, n_kv, rows, c), layer_group_map(0))]
+        # The leaves go out as they came in, a group's blocks of one
+        # layer rewritten: operands count from the three prefetched
+        # scalars, outputs from the attention's.
+        aliases = {3 + len(operands) + i: 1 + i for i in range(4)}
+        in_specs += ab_specs
+        in_specs += 2 * [pl.BlockSpec((rows, n_kv, hd), group_map(0, 0))]
+        in_specs += 2 * [pl.BlockSpec((rows, n_kv), group_map(0))]
+        operands += [*leaves, *fresh]
+        out_specs += ab_specs
+        # Held to HBM, and through the aliases the operands with them:
+        # left to its own choice XLA brings a whole scale leaf into VMEM
+        # before every call and takes it out again after (two copies of
+        # 12.6 MB a layer call at Ouro's widths, PERF.md section 6, PR 54).
+        out_shape += [pltpu.HBM(x.shape, x.dtype) for x in leaves]
+        slot = jnp.asarray(slot, jnp.int32).reshape(1)
     else:
-        abn = jnp.zeros((1,), jnp.int32)
+        slot = jnp.zeros((1,), jnp.int32)
 
-    out = pl.pallas_call(
+    out, *leaves = pl.pallas_call(
         functools.partial(
             _decode_kernel,
             block_t=bt,
@@ -768,9 +861,7 @@ def decode_gqa_attention(
             num_scalar_prefetch=3,
             grid=(b // rows,),
             in_specs=in_specs,
-            out_specs=pl.BlockSpec(
-                (rows, n_kv, g, hd), group_map(0, 0, 0)
-            ),
+            out_specs=out_specs,
             scratch_shapes=[
                 pltpu.VMEM((2, n_kv, bt, hd), jnp.int8),
                 pltpu.VMEM((2, n_kv, bt, hd), jnp.int8),
@@ -781,7 +872,8 @@ def decode_gqa_attention(
                 pltpu.VMEM((rows, n_kv * g, hd), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((b, n_kv, g, hd), q.dtype),
+        out_shape=out_shape,
+        input_output_aliases=aliases,
         compiler_params=pltpu.CompilerParams(
             # In order on one core: the buffer slot and the next row's
             # first copy ride from one program to the next.
@@ -792,8 +884,9 @@ def decode_gqa_attention(
         name="decode_gqa_attention",
     )(
         jnp.asarray(layer, jnp.int32).reshape(1),
-        abn,
+        slot,
         kv_lengths.astype(jnp.int32),
         *operands,
     )
-    return out[:, :, : n_q // n_kv].reshape(b, n_q, hd)
+    out = out[:, :, : n_q // n_kv].reshape(b, n_q, hd)
+    return (out, tuple(leaves)) if has_ab else out

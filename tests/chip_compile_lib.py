@@ -193,3 +193,26 @@ def _state_is_the_kernels_alone(text: str, *, calls: int) -> None:
     assert made.count("custom-call") >= calls, made
     assert set(made) <= {"custom-call", "parameter", "get-tuple-element", "bitcast", "tuple", "while"}, made
     assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*kda_step_rows", text)) >= calls
+
+
+def _append_leaves_are_the_kernels_alone(text: str, shape: tuple) -> None:
+    """Every operation of a compiled program's layer body (the computation
+    that holds the decode kernel) that makes or takes an array of an append
+    leaf's shape, values ``shape`` int8 or scales ``shape[:-1]`` bfloat16,
+    is the kernel, the body's parameter or root, or a renaming between
+    them: no ``dynamic-update-slice``, no ``copy`` or ``copy-start`` into
+    another memory and back, no ``slice-start`` and no XLA fusion."""
+    values = "s8[" + ",".join(map(str, shape)) + "]"
+    scales = "bf16[" + ",".join(map(str, shape[:-1])) + "]"
+    bodies = [
+        c for c in re.split(r"\n(?=(?:ENTRY )?%?[\w.\-]+ \(.*\) -> .* \{\n)", text)
+        if re.search(r"custom_call_target=\"tpu_custom_call\"[^\n]*decode_gqa_attention", c)
+    ]
+    assert len(bodies) == 1, len(bodies)
+    touching = [
+        re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = .*? ([\w\-]+)\(", line).group(1)
+        for line in bodies[0].splitlines()[1:]
+        if (values in line or scales in line) and " = " in line
+    ]
+    assert touching.count("custom-call") == 1, touching
+    assert set(touching) <= {"custom-call", "parameter", "get-tuple-element", "bitcast", "tuple"}, touching

@@ -53,29 +53,45 @@ PROJECTIONS = {
         # slots of 768 rows, the widest and the narrowest decode window.
         (16, 768, 768, 8, (192, 16, 16)),
         (16, 64, 768, 8, (192, 16, 16)),
+        # Mistral-7B's step (llama3-8b's heads): 32 slots of 2,048 rows in
+        # two groups of 16, 8 KV heads, 8 slots a row in the append buffer.
+        (32, 2048, 2048, 8, LLAMA3_8B),
     ],
 )
 def test_decode_kernel_compiles(one_chip, batch, window, cache_len, chunk, heads):
+    """The kernel with the step's fresh rows beside the append buffer: it
+    writes them into the buffer itself and hands the four leaves back
+    aliased onto their operands, so a caller that gives the leaves away
+    gets them back in place (what a whole decode chunk makes of that:
+    ``test_chip_compile_llama.py``)."""
     L, KH, NQ = heads
     S = _spec(one_chip)
     cache = S((L, KH, batch, cache_len, HD), jnp.int8)
     scales = S((L, KH, batch, cache_len), jnp.bfloat16)
     ab = S((L, KH, batch, chunk, HD), jnp.int8)
     ab_scales = S((L, KH, batch, chunk), jnp.bfloat16)
+    row, row_scales = S((batch, KH, HD), jnp.int8), S((batch, KH), jnp.bfloat16)
 
-    def attn(q, k, v, ks, vs, li, lens, kab, vab, ksab, vsab, count):
+    def attn(q, k, v, ks, vs, li, lens, leaves, fresh, slot):
         return da.decode_gqa_attention(
             q, k, v, ks, vs, li, lens,
-            append=(kab, vab, ksab, vsab, count),
+            append=(leaves, fresh, slot),
             window=window, interpret=False,
         )
 
-    _compile(
-        attn,
+    compiled = jax.jit(attn, donate_argnums=(7,)).lower(
         S((batch, NQ, HD), jnp.bfloat16), cache, cache, scales, scales,
         S((), jnp.int32), S((batch,), jnp.int32),
-        ab, ab, ab_scales, ab_scales, S((), jnp.int32),
-    )
+        (ab, ab, ab_scales, ab_scales), (row, row, row_scales, row_scales),
+        S((), jnp.int32),
+    ).compile()
+    call = re.search(r"[^\n]*custom_call_target=\"tpu_custom_call\"[^\n]*", compiled.as_text())[0]
+    # Outputs 1-4 are operands 8-11 (after three scalars, q and the cache).
+    aliased = re.findall(r"\{(\d)\}: \((\d+), \{\}\)", call.split("output_to_operand_aliasing=")[1])
+    assert aliased == [("1", "8"), ("2", "9"), ("3", "10"), ("4", "11")], aliased
+    # As tiled the scales' slots are padded to a lane tile: at least this.
+    leaf_bytes = 2 * L * KH * batch * chunk * (HD + 2)
+    assert compiled.memory_analysis().alias_size_in_bytes >= leaf_bytes
 
 
 # The layer-kind models' full GQA layers (``ops/gqa_decode.py``): 32 slots
