@@ -170,24 +170,6 @@ class LLMConfig:
         "for tests).",
         default="tpu",
     )
-    spec_decode: bool = configfield(
-        "Enable speculative decoding in the serving scheduler (config "
-        "twin of the engine server's --spec-decode flag): draft-model "
-        "draft/verify when draft_model is set, prompt-lookup (n-gram) "
-        "speculation otherwise. Always distribution-preserving.",
-        default=False,
-    )
-    draft_model: str = configfield(
-        "Draft model preset/HF id for speculative decoding; empty means "
-        "no draft model (spec_decode falls back to prompt-lookup).",
-        default="",
-    )
-    spec_gamma: int = configfield(
-        "Maximum speculation lookahead (draft tokens per round); 0 means "
-        "use the engine server's --gamma default. The per-request "
-        "acceptance-adaptive controller only ever shrinks below this.",
-        default=0,
-    )
     matmul_kernel: str = configfield(
         "Serving matmul path (config twin of the engine server's "
         "--matmul-kernel flag): 'xla' streams weight-only int8 through "

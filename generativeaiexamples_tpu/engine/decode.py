@@ -4,14 +4,10 @@ One implementation of the device-side chunked decode scan and the
 params/cache preparation, so the two serving frontends (offline
 ``LlamaGenerator`` and continuous-batching ``Scheduler``) cannot drift.
 
-The scheduler's speculative tick (``engine/spec_decode.py``) replaces
-the plain decode chunk built here with draft+verify rounds but shares
-the same cache preparation and append-buffer flush geometry
-(``ops.decode_attention.flush_clip_start``): a speculative round
-writes up to ``gamma + 1`` KV positions per lane, so the clip start is
-computed from the widest per-round flush —
-``max(decode_chunk_size, gamma + 1)`` — keeping parked histories clear
-of the tail scratch zone in both modes.
+The append-buffer flush geometry lives beside it
+(``ops.decode_attention.flush_clip_start``): a chunk's flush is
+``decode_chunk_size`` wide, and the scheduler keeps parked histories and
+admitted prompts clear of the tail scratch zone that width defines.
 """
 
 from __future__ import annotations

@@ -21,14 +21,12 @@ scheduler's LRU slot reclaim can never evict the segment an in-flight
 graft is copying from, and recency-tracked (:meth:`touch`) so matches
 prefer the most recently used candidate at equal depth.
 
-Under speculative decoding the registration invariant tightens in one
-way that matters to correctness: a parked slot's history — and hence
-its registered segment — contains only *verified* tokens (accepted by
-the target's batched verify, or emitted by the target itself).
-Rejected draft proposals exist solely as phantom KV rows past the
-slot's accounted length and are never registered here, so a graft from
-a segment can never replay a token the target would not have produced
-(``tests/test_spec_serving.py`` pins this).
+Where a model drafts (every decode step verifies its own prediction
+module's token), a parked slot's history — and hence its registered
+segment — contains only *verified* tokens.  A rejected draft exists
+solely as a row past the slot's accounted length and is never registered
+here, so a graft from a segment can never replay a token the stack would
+not have produced (``tests/test_own_draft_serving.py`` pins this).
 
 Two owners use this index with different bounds: the scheduler's own
 index is implicitly bounded by its slot count (a segment per parked

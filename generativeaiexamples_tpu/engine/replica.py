@@ -377,7 +377,7 @@ class EnginePool:
             # tick_ms_norm_ewma is single-writer (the tick thread); a
             # torn read is impossible for a Python float.  The
             # token-NORMALIZED value feeds the straggler scorer so a
-            # speculating replica's multi-token ticks don't read as
+            # drafting replica's two-token steps don't read as
             # latency (falls back to the raw EWMA for duck-typed stats).
             db.record(
                 f"engine.replica.{idx}.tick_ms",
@@ -1146,7 +1146,6 @@ class EnginePool:
         "spec_tokens",
         "spec_proposed",
         "spec_accepted",
-        "spec_fallbacks",
         "ttft_count",
         # Tick-phase seconds, starved-device seconds and the request
         # lifecycle sums: each replica has its own tick thread, so the
@@ -1197,7 +1196,6 @@ class EnginePool:
         tick_ewma_max = 0.0
         tick_norm_max = 0.0
         accept_weighted = 0.0
-        spec_gamma_max = 0
         replicas = []
         for replica, state, score in members:
             snap = replica.scheduler.stats.snapshot()
@@ -1221,23 +1219,19 @@ class EnginePool:
                 tick_norm_max = max(
                     tick_norm_max, snap.get("tick_ms_norm_ewma", 0.0)
                 )
-                spec_gamma_max = max(
-                    spec_gamma_max, snap.get("spec_gamma", 0)
-                )
         agg["ttft_avg_ms"] = (
             ttft_weighted / agg["ttft_count"] if agg["ttft_count"] else 0.0
         )
         # Worst live replica's tick EWMA: the conservative basis for the
         # Retry-After drain estimate on the 429 path (norm twin for
-        # consumers calibrated against per-token cost under speculation).
+        # consumers calibrated against per-token cost under drafts).
         agg["tick_ms_ewma"] = tick_ewma_max
         agg["tick_ms_norm_ewma"] = tick_norm_max
-        # Proposal-weighted acceptance: replicas that speculated more
-        # weigh more; idle/non-spec replicas contribute nothing.
+        # Proposal-weighted acceptance: replicas that verified more
+        # drafts weigh more; idle ones contribute nothing.
         agg["spec_acceptance_ewma"] = round(
             accept_weighted / agg["spec_proposed"], 4
         ) if agg["spec_proposed"] else 0.0
-        agg["spec_gamma"] = spec_gamma_max
         agg["pool_size"] = sum(
             1 for _, state, _ in members if state in (HEALTHY, PROBATION)
         )

@@ -50,46 +50,18 @@ class SamplingParams:
     stop_on_eos: bool = True
 
 
-def warped_candidates(
-    logits: jnp.ndarray,
-    temperature: jnp.ndarray,
-    top_p: jnp.ndarray,
-    top_k: jnp.ndarray,
-    *,
-    approx: Optional[bool] = None,
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """The warped (temperature → top-k → top-p) sampling distribution,
-    sparse over the candidate pool.
-
-    Returns ``(cand_ids, cand_probs)``, each ``(b, K)`` with
-    ``K = min(CANDIDATES, vocab)``: the candidate token ids and the exact
-    probabilities :func:`sample` draws them with (filtered-out candidates
-    hold probability 0).  This sparse form is what speculative rejection
-    sampling needs — both the target ``p`` and draft ``q`` distributions
-    stay ``(b, K)`` instead of ``(b, vocab)``.
-    """
-    cand_idx, cand_logits, keep, _ = _warp(
-        logits, temperature, top_p, top_k, approx
-    )
-    probs = jax.nn.softmax(cand_logits, axis=-1)
-    # exp(_NEG_INF - max) underflows to exactly 0 in f32, so filtered
-    # candidates carry no mass; re-zero anyway for belt-and-braces.
-    probs = jnp.where(keep, probs, 0.0)
-    return cand_idx, probs
-
-
 def _warp(
     logits: jnp.ndarray,
     temperature: jnp.ndarray,
     top_p: jnp.ndarray,
     top_k: jnp.ndarray,
     approx: Optional[bool],
-) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Shared temperature→candidates→top-k/top-p pipeline.
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """The temperature→candidates→top-k/top-p pipeline.
 
-    Returns ``(cand_idx, cand_logits, keep, scaled)``: candidate ids, the
-    masked tempered logits over them (filtered = _NEG_INF), the keep mask,
-    and the full tempered logits (for the unfiltered-row special case).
+    Returns ``(cand_idx, cand_logits, scaled)``: candidate ids, the
+    masked tempered logits over them (filtered = _NEG_INF), and the full
+    tempered logits (for the unfiltered-row special case).
     """
     if approx is None:
         approx = not exact_sampling_enabled()
@@ -123,32 +95,7 @@ def _warp(
 
     keep = topk_mask & topp_mask
     cand_logits = jnp.where(keep, sorted_scaled, _NEG_INF)
-    return cand_idx, cand_logits, keep, scaled
-
-
-def sample_from_candidates(
-    cand_ids: jnp.ndarray,
-    cand_probs: jnp.ndarray,
-    key: jax.Array,
-) -> jnp.ndarray:
-    """Draw one token per row from a sparse candidate distribution."""
-    choice = jax.random.categorical(
-        key, jnp.log(cand_probs + 1e-30), axis=-1
-    )
-    return jnp.take_along_axis(cand_ids, choice[:, None], axis=-1)[
-        :, 0
-    ].astype(jnp.int32)
-
-
-def prob_of(
-    cand_ids: jnp.ndarray,
-    cand_probs: jnp.ndarray,
-    tokens: jnp.ndarray,
-) -> jnp.ndarray:
-    """Probability each row's sparse distribution assigns to ``tokens``
-    ((b,) int32) — 0 for tokens outside the candidate pool."""
-    match = cand_ids == tokens[:, None]
-    return jnp.sum(jnp.where(match, cand_probs, 0.0), axis=-1)
+    return cand_idx, cand_logits, scaled
 
 
 @jax.named_scope("sample")
@@ -187,7 +134,7 @@ def sample(
 
     # Temperature first, then nucleus/top-k on the tempered distribution —
     # the OpenAI/HF semantics the reference's clients expect.
-    cand_idx, cand_logits, _, scaled = _warp(
+    cand_idx, cand_logits, scaled = _warp(
         logits, temperature, top_p, top_k, approx
     )
     # Sample within the candidate pool, then map back to vocab ids — no

@@ -62,11 +62,7 @@ from generativeaiexamples_tpu.ops.decode_attention import (
     flush_clip_start,
     kv_tokens_read,
 )
-from generativeaiexamples_tpu.resilience.faults import (
-    FaultInjected,
-    inject,
-    inject_replica,
-)
+from generativeaiexamples_tpu.resilience.faults import inject_replica
 from generativeaiexamples_tpu.utils.buckets import bucket_size
 from generativeaiexamples_tpu.utils.jax_runtime import EXECUTABLES
 
@@ -144,13 +140,6 @@ class _Slot:
     # step: until its fetch the row's length is the device's to know
     # (``Scheduler._carried_len``) and ``unfetched`` counts the fewest.
     chunks_out: int = 0
-    # Speculative decoding: EWMA of this request's observed per-round
-    # acceptance rate (accepted drafts / gamma).  Drives the adaptive
-    # lookahead — a request whose drafts keep getting rejected decays
-    # toward gamma=1 (≈ non-spec cost) instead of paying gamma wasted
-    # draft+verify tokens every round.  Reset to 1.0 (optimistic) at
-    # every claim so a fresh request starts at full lookahead.
-    accept_ewma: float = 1.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -181,7 +170,7 @@ STARVED_PHASES = ("plan", "dispatch", "emit", "telemetry")
 # ``Stats.lock`` behind its jitted calls are the rest of the phase.
 #   h2d   numpy staging, the host-to-device arrays, ``_next_key``
 #   call  the site's jitted calls: the step program and what is enqueued
-#         behind it (a boundary's snapshot, a graft, the draft's prefill)
+#         behind it (a boundary's snapshot, a graft)
 DISPATCH_STAGES = ("h2d", "call")
 # Device bytes the snapshots of recurrent state may hold (StateSnapshots),
 # beside the slots: a tenth of a 16 GB chip, about 120 snapshots of six
@@ -438,30 +427,19 @@ class Stats:
         # fetch (it stopped on EOS or was cancelled in the chunk before).
         self.decode_chunks_ahead = 0
         self.decode_tokens_dropped = 0
-        # Speculative decoding: rounds = live speculating (slot, round)
-        # pairs run, tokens = tokens emitted by those rounds.  Acceptance
-        # rate is derivable as (tokens/rounds - 1) / gamma.  Greedy slots
-        # speculate via prefix agreement; sampled slots via rejection
-        # sampling — both count.  Only UNFILTERED sampled slots (top_p >=
-        # 1 and top_k == 0) are excluded: they always emit exactly one
-        # token per round by design and would bias the derived acceptance
-        # toward zero without saying anything about draft quality.
+        # Steps that verified the model's own draft (``_emit_verified``):
+        # rounds = live (greedy row, step) pairs, one draft each, and
+        # tokens = what those steps emitted (one or two).  A sampled row
+        # takes one token a step and counts in none of them.  proposed
+        # counts the drafts put in front of the stack, accepted the ones
+        # it kept (the stack's own next token behind an accepted draft is
+        # NOT an accepted draft: accepted / proposed stays in [0, 1]);
+        # spec_acceptance_ewma smooths the per-chunk rate.
         self.spec_rounds = 0
         self.spec_tokens = 0
-        # Raw acceptance telemetry for the serving integration: proposed
-        # counts every draft token put in front of the verifier by a
-        # counted row-round; accepted counts the ones the verifier kept
-        # (the bonus token a fully-accepted round emits is NOT an
-        # accepted draft — acceptance = accepted/proposed stays in
-        # [0, 1]).  spec_acceptance_ewma smooths the per-chunk rate;
-        # spec_gamma is the lookahead the adaptive controller picked for
-        # the most recent speculative chunk; spec_fallbacks counts ticks
-        # degraded to plain decode by a draft fault (spec_draft site).
         self.spec_proposed = 0
         self.spec_accepted = 0
         self.spec_acceptance_ewma = 0.0
-        self.spec_gamma = 0
-        self.spec_fallbacks = 0
         # tick_count counts every pass of the tick loop, idle polls
         # included; busy_ticks only those that dispatched or fetched a
         # program.
@@ -534,15 +512,15 @@ class Stats:
         self.tick_ms_ewma = 0.0
         # Token-normalized tick time: raw tick wall time scaled down by
         # emitted-tokens / baseline-chunk-tokens when a tick emits MORE
-        # than one decode chunk's worth (speculation: up to gamma+1
-        # tokens per slot per round).  Every latency signal derived from
-        # tick time — autoscaler tick_high_ms, replica brownout scoring,
-        # the 429 Retry-After drain estimate — compares against a
-        # one-token-per-slot-per-chunk-step cost model; feeding it the
-        # raw wall time of a tick that emitted 3x the tokens would read
-        # "3x slower" when the engine is actually 3x FASTER per token.
-        # Non-speculative ticks emit at most the baseline, so there
-        # norm == raw and nothing changes.
+        # than one decode chunk's worth (a step that verifies the model's
+        # own draft emits up to two tokens a row).  Every latency signal
+        # derived from tick time — autoscaler tick_high_ms, replica
+        # brownout scoring, the 429 Retry-After drain estimate — compares
+        # against a one-token-per-slot-per-chunk-step cost model; feeding
+        # it the raw wall time of a tick that emitted 2x the tokens would
+        # read "2x slower" when the engine is actually 2x FASTER per
+        # token.  A tick without drafts emits at most the baseline, so
+        # there norm == raw and nothing changes.
         self.tick_ms_norm_ewma = 0.0
         # Executables this scheduler's tick thread asked JAX for (a step
         # program's first call at a new shape; ``utils.jax_runtime``'s
@@ -625,8 +603,6 @@ class Stats:
                 "spec_proposed": self.spec_proposed,
                 "spec_accepted": self.spec_accepted,
                 "spec_acceptance_ewma": round(self.spec_acceptance_ewma, 4),
-                "spec_gamma": self.spec_gamma,
-                "spec_fallbacks": self.spec_fallbacks,
                 "tick_ms_ewma": round(self.tick_ms_ewma, 3),
                 "tick_ms_norm_ewma": round(self.tick_ms_norm_ewma, 3),
                 "executables_requested": self.executables_requested,
@@ -668,13 +644,6 @@ class Scheduler:
         max_queue: Optional[int] = None,
         admit_cap: Optional[int] = None,
         admit_token_budget: Optional[int] = None,
-        draft_cfg: Optional[llama.LlamaConfig] = None,
-        draft_params=None,
-        gamma: int = 4,
-        draft_quantize: bool = False,
-        adaptive_gamma: bool = True,
-        spec_mode: Optional[str] = None,
-        ngram: int = 2,
         prefill_chunk_tokens: Optional[int] = 256,
         prefix_cache: str = "shared",
         quantize: bool = False,
@@ -692,8 +661,6 @@ class Scheduler:
         self.replica_index: Optional[int] = None
         self.max_batch = max_batch
         self.max_len = max_len or cfg.max_seq_len
-        # Overridden by the speculative branch below (flush margin).
-        self.effective_max_len = self.max_len
         self.decode_chunk_size = decode_chunk_size
         # Admission control: with a FIFO queue and sustained overload the
         # queue (and therefore TTFT) grows without bound — a
@@ -725,10 +692,6 @@ class Scheduler:
             self.ADMIT_TOKEN_BUDGET = admit_token_budget
         self.stats = Stats()
         self._key = jax.random.PRNGKey(seed)
-        from generativeaiexamples_tpu.engine.decode import (
-            prepare_cache,
-            prepare_params,
-        )
         from generativeaiexamples_tpu.engine.serving_models import (
             serving_model,
         )
@@ -740,7 +703,7 @@ class Scheduler:
         # Positions a decode step writes a row: two where every step
         # verifies the model's own draft (``serving_models``: ``draft``).
         self._step_width = 2 if model.draft else 1
-        model.check_supported(draft_cfg=draft_cfg, spec_mode=spec_mode)
+        model.check_supported()
         # ``quantize`` is the int8-weights serving configuration: float
         # (or absent, hence random) params become int8 projections with
         # qkv and gate/up packed — what ``chip_smoke.py`` hands over pre-built.
@@ -763,7 +726,7 @@ class Scheduler:
             )
             else "xla"
         )
-        # The slots' state, a draft's beside it, and the host's books.
+        # The slots' state and the host's books.
         EXECUTABLES.enter_stage("state")
         # One KV layout; the keyword stays because benchmarks/run.py
         # passes it (ROADMAP.md queue 3 item 15).
@@ -785,98 +748,9 @@ class Scheduler:
                 planes, self._stack_passes, per_token, max_batch, self.max_len,
             )
         self._decode_chunk = model.make_decode_chunk()
-        # Speculative decoding (TRT-LLM draft-model parity, SURVEY.md
-        # §2.8): a draft config turns every decode chunk into speculation
-        # rounds — draft proposes gamma tokens, target verifies in one
-        # pass.  The draft keeps its own slot cache, prefilled alongside
-        # the target's at admission AND along every other KV-building
-        # path (suffix prefill, chunked-prefill warming, shared-prefix
-        # grafts), so the two caches cover the same [0, length) window at
-        # all times and parking/prefix reuse stay available under
-        # speculation.  ``gamma`` is the MAXIMUM lookahead; with
-        # ``adaptive_gamma`` each chunk runs at the pow2 bucket of the
-        # highest per-request acceptance-EWMA-derived desire (bounded
-        # compile set {1, 2, 4, ...} ∪ {gamma}).
-        self.draft_cfg = draft_cfg
-        self.gamma = gamma
-        self.adaptive_gamma = adaptive_gamma
-        if draft_cfg is not None:
-            from generativeaiexamples_tpu.engine.spec_decode import (
-                gamma_bucket,
-                make_spec_chunk_fn,
-            )
-
-            self._gamma_bucket = gamma_bucket
-
-            if draft_cfg.vocab_size != cfg.vocab_size:
-                raise ValueError("draft and target must share a vocabulary")
-            if gamma < 1:
-                raise ValueError(f"gamma must be >= 1, got {gamma}")
-            self.draft_params = prepare_params(
-                draft_cfg, draft_params, mesh, quantize=draft_quantize,
-                pack=True, matmul_kernel=matmul_kernel,
-            )
-            self._dcache = prepare_cache(
-                draft_cfg, max_batch, self.max_len, mesh
-            )
-            self._spec_chunk = make_spec_chunk_fn(
-                cfg, draft_cfg, mesh, self.max_len
-            )
-            # Spec-mode length margin: a live row must never start a
-            # round with its write position inside the append-buffer
-            # flush-clip zone [max_len - (gamma+1), max_len) — a clipped
-            # flush would overwrite real history that the NEXT round's
-            # verify re-reads (the plain chunk never re-reads its own
-            # flush, so it tolerates the clip; spec rounds do not).
-            # Costs gamma+1 tokens of per-sequence capacity.
-            self.effective_max_len = self.max_len - (gamma + 1)
-            if self.effective_max_len < 2:
-                raise ValueError(
-                    f"max_len {self.max_len} too small for gamma {gamma}"
-                )
-        # Prompt-lookup (n-gram) speculation: no draft model — proposals
-        # come from the sequence's own token history (vLLM prompt-lookup;
-        # made for RAG answers that quote retrieved context).  Shares the
-        # spec path's verify/emit machinery and its append-buffer flush
-        # margin.
-        if spec_mode not in (None, "ngram"):
-            raise ValueError(f"unknown spec_mode {spec_mode!r}")
-        if spec_mode == "ngram" and draft_cfg is not None:
-            raise ValueError("spec_mode='ngram' excludes a draft model")
-        self.spec_mode = spec_mode
-        self.ngram = ngram
-        if spec_mode == "ngram":
-            from generativeaiexamples_tpu.engine.spec_decode import (
-                gamma_bucket,
-                make_ngram_spec_chunk_fn,
-            )
-
-            self._gamma_bucket = gamma_bucket
-
-            if gamma < 1:
-                raise ValueError(f"gamma must be >= 1, got {gamma}")
-            # Token history lives ON DEVICE: rows scatter in at admission
-            # and the chunk carries it forward (donated) — no per-tick
-            # host-to-device upload of a (max_batch, max_len) buffer.
-            self._dhist = jnp.zeros((max_batch, self.max_len), jnp.int32)
-            self._ngram_chunk = make_ngram_spec_chunk_fn(
-                cfg, mesh, self.max_len, ngram=ngram
-            )
-            self.effective_max_len = self.max_len - (gamma + 1)
-            if self.effective_max_len < 2:
-                raise ValueError(
-                    f"max_len {self.max_len} too small for gamma {gamma}"
-                )
-        else:
-            self._dhist = None
         # Prefix cache mode: "shared" (cross-request content matching via
         # the radix index + per-session parking), "session" (conversation
-        # parking only — the pre-shared behavior), "off".  Speculative
-        # modes compose: the suffix-prefill and graft paths rebuild the
-        # DRAFT cache (and the n-gram history row) alongside the target's,
-        # so a parked segment is reusable by a speculating admission, and
-        # the parking margin accounts for the wider speculative flush
-        # (see _flush_width below and the rollback note in _finish).
+        # parking only — the pre-shared behavior), "off".
         if prefix_cache not in ("shared", "session", "off"):
             raise ValueError(f"unknown prefix_cache mode {prefix_cache!r}")
         self.prefix_cache = prefix_cache
@@ -884,8 +758,7 @@ class Scheduler:
         # Chunked prefill: cold prompts (and cache-hit suffixes) longer
         # than this claim a slot and prefill one chunk per tick,
         # interleaved with decode.  None/0 disables (monolithic batched
-        # admission for everything).  Composes with speculation: warming
-        # chunks rebuild the draft cache row alongside the target's.
+        # admission for everything).
         if prefill_chunk_tokens is not None and prefill_chunk_tokens <= 0:
             prefill_chunk_tokens = None
         self.prefill_chunk_tokens = prefill_chunk_tokens
@@ -935,22 +808,13 @@ class Scheduler:
         # Pipelined ticks dispatch the decode chunk in the same tick as
         # admissions, pinning not-yet-decoding lanes to max_len - 1 —
         # whose append-buffer flush garbage-writes [max_len - w, max_len)
-        # where w is the per-round flush width: decode_chunk_size for the
-        # plain chunk, gamma + 1 for a speculative round (the adaptive
-        # controller only ever shrinks gamma, so max(chunk, gamma + 1)
-        # covers every chunk this scheduler can dispatch, including the
-        # plain-decode fallback a spec_draft fault degrades to).
-        # Admitted prompt KV must stay strictly below flush_clip_start of
-        # that widest flush, so admissions truncate to one less (ADVICE
-        # r5: longer same-tick prompts had their tail KV overwritten and
-        # decoded garbage from then on).
-        if draft_cfg is not None or spec_mode == "ngram":
-            self._flush_width = max(self.decode_chunk_size, gamma + 1)
-        else:
-            self._flush_width = self.decode_chunk_size
-        self._admit_limit = min(
-            self.effective_max_len,
-            flush_clip_start(self.max_len, self._flush_width),
+        # where w is the chunk's flush width, decode_chunk_size.  Admitted
+        # prompt KV must stay strictly below flush_clip_start of that
+        # flush, so admissions truncate to one less (ADVICE r5: longer
+        # same-tick prompts had their tail KV overwritten and decoded
+        # garbage from then on).
+        self._admit_limit = flush_clip_start(
+            self.max_len, self.decode_chunk_size
         )
         if self._admit_limit < 2:
             raise ValueError(
@@ -998,8 +862,6 @@ class Scheduler:
         # The step programs: closures that compile at their first call,
         # and the family compiled here (``_compile_chunk_programs``).
         EXECUTABLES.enter_stage("programs")
-        mesh_arg = mesh
-        max_len = self.max_len
 
         @jax.jit
         def _prefill_some(params, tokens, lengths, key, temp, top_p, top_k):
@@ -1103,71 +965,10 @@ class Scheduler:
         # inside a request.
         self._chunk_rows, self._chunk_windows = 1, ()
         self._chunk_programs: dict[tuple[int, int], Callable] = {}
-        if prefill_chunk_tokens and draft_cfg is None:
+        if prefill_chunk_tokens:
             self._compile_chunk_programs(
                 model.chunks_per_program(prefill_chunk_tokens)
             )
-
-        if draft_cfg is not None:
-
-            @jax.jit
-            def _prefill_draft(dparams, tokens, lengths):
-                """Prefill the admission batch into a fresh DRAFT cache
-                (no sampling — the draft only ever needs KV)."""
-                b, s = tokens.shape
-                small = llama.init_kv_cache(draft_cfg, b, s)
-                positions = jnp.broadcast_to(
-                    jnp.arange(s, dtype=jnp.int32), (b, s)
-                )
-                _, small = llama.forward(
-                    dparams, draft_cfg, tokens, positions, small, lengths,
-                    mesh=mesh_arg, cold_prefill=True,
-                )
-                return small
-
-            self._prefill_draft = _prefill_draft
-
-            @functools.partial(
-                jax.jit, donate_argnums=(1,), static_argnums=(6,)
-            )
-            def _prefill_draft_suffix(
-                dparams, cache, tokens, start, suffix_len, slot, kv_bucket
-            ):
-                """Warm-prefill a prompt suffix into one DRAFT cache row —
-                the draft-side twin of ``_prefill_suffix`` (no sampling;
-                the draft only ever needs KV).  Keeps the draft cache
-                covering the same [0, length) window as the target's on
-                the prefix-hit and chunked-warming paths, which is what
-                makes KV parking legal under speculation."""
-                s = tokens.shape[1]
-                row = tuple(
-                    jax.lax.dynamic_slice(
-                        bg,
-                        (0, 0, slot) + (0,) * (bg.ndim - 3),
-                        bg.shape[:2] + (1,) + bg.shape[3:],
-                    )
-                    for bg in cache
-                )
-                positions = start + jnp.arange(s, dtype=jnp.int32)[None, :]
-                _, row = llama.forward(
-                    dparams,
-                    draft_cfg,
-                    tokens,
-                    positions,
-                    row,
-                    jnp.reshape(start + suffix_len, (1,)),
-                    mesh=mesh_arg,
-                    kv_bucket=kv_bucket,
-                )
-                with jax.named_scope("kv_write"):
-                    return tuple(
-                        jax.lax.dynamic_update_slice(
-                            bg, r, (0, 0, slot) + (0,) * (bg.ndim - 3)
-                        )
-                        for bg, r in zip(cache, row)
-                    )
-
-            self._prefill_draft_suffix = _prefill_draft_suffix
 
     # -- public API --------------------------------------------------------
 
@@ -1508,14 +1309,11 @@ class Scheduler:
             # Parked history must stay clear of the cache tail: inactive
             # lanes' garbage lands at [max_len - 1] (scatter path) or in
             # the append-buffer flush zone [flush_clip_start, max_len)
-            # (kernel path).  _flush_width is the widest per-round flush
-            # this scheduler dispatches (decode chunk or gamma+1
-            # speculative round), so the margin also covers speculative
-            # rounds a lane's neighbours keep running after this finish.
+            # (kernel path), decode_chunk_size wide.
             and slot.length + slot.emitted
             < min(
-                flush_clip_start(self.max_len, self._flush_width),
-                self.max_len - max(16, self._flush_width + 1),
+                flush_clip_start(self.max_len, self.decode_chunk_size),
+                self.max_len - max(16, self.decode_chunk_size + 1),
             )
         ):
             # Park the slot: its cache rows hold KV for the prompt plus
@@ -1689,16 +1487,6 @@ class Scheduler:
             tokens, lengths, rows, slots_arr, temp, top_p, top_k
         )
         key = self._next_key()
-        hrows_dev = None
-        if self._dhist is not None:
-            # The admitted prompts, for the device history.  The kb
-            # padding lanes repeat row 0 so their duplicate writes to
-            # slots_arr[0] are idempotent (zero-padding would wipe it).
-            hrows = np.zeros((kb, self.max_len), np.int32)
-            for r, req in enumerate(reqs):
-                hrows[r, : plens[r]] = req.token_ids
-            hrows[len(reqs) :] = hrows[0]
-            (hrows_dev,) = self._h2d(hrows)
         self._clock.stage("call")
         small, tok, aux = self._prefill_some(
             self.params, tokens_dev, lengths_dev, key, *sampling_dev
@@ -1706,20 +1494,7 @@ class Scheduler:
         self._cache, self._carried = self._graft_rows(
             self._cache, small, rows_dev, slots_dev, self._carried, tok
         )
-        last = self._carried
-        if hrows_dev is not None:
-            self._dhist = self._dhist.at[slots_dev].set(hrows_dev)
-        if self.draft_cfg is not None:
-            # The draft's slot cache mirrors the target's: same prompt,
-            # same slot — _graft_rows is leaf-generic over cache tuples.
-            dsmall = self._prefill_draft(
-                self.draft_params, tokens_dev, lengths_dev
-            )
-            self._dcache = self._graft_rows(
-                self._dcache, dsmall, rows_dev, slots_dev
-            )
-            last = self._dcache[0]
-        self._clock.dispatched(last)
+        self._clock.dispatched(self._carried)
         self._note_aux(aux)
         self._clock.enter("plan")
         for r, (req, slot_idx) in enumerate(zip(reqs, slot_idxs)):
@@ -1728,7 +1503,6 @@ class Scheduler:
             slot.length = plens[r]
             slot.emitted = 0
             slot.history = list(req.token_ids)
-            slot.accept_ewma = 1.0
             slot.unfetched = 1
             slot.on_device = True
         return reqs, slot_idxs, tok
@@ -1816,14 +1590,6 @@ class Scheduler:
         tokens[0, : len(suffix)] = suffix
         kv_bucket = bucket_size(common + s, maximum=self.max_len, dense=True)
         sp = req.sampling
-        if self._dhist is not None:
-            # Rebuild the n-gram matcher's history row for the whole
-            # prompt (cached prefix included): hist[p] holds the token
-            # whose KV sits at position p.  Zero padding clears stale
-            # tokens from the row's previous occupant.
-            row = np.zeros((self.max_len,), np.int32)
-            row[:plen] = req.token_ids
-            self._dhist = self._dhist.at[slot_idx].set(jnp.asarray(row))
         self._prefill_suffix_begin(len(suffix), s, kv_bucket)
         tok = self._call_prefill_suffix(
             tokens, common, len(suffix), slot_idx, sp, kv_bucket
@@ -1835,7 +1601,6 @@ class Scheduler:
         slot.emitted = 0
         slot.history = list(req.token_ids)
         slot.warm_pos = None
-        slot.accept_ewma = 1.0
         slot.unfetched = 1
         return req, slot_idx, tok
 
@@ -1922,18 +1687,9 @@ class Scheduler:
         src_dev, dst_dev = self._h2d(np.int32(src), np.int32(dst))
         self._clock.stage("call")
         self._cache = self._graft_prefix(self._cache, src_dev, dst_dev, n)
-        last = self._cache
-        if self.draft_cfg is not None:
-            # Drafts graft cached prefixes too: the parked segment's
-            # draft rows were written in lockstep with its target rows,
-            # so the same row copy keeps both caches covering [0, common)
-            # in the destination slot (_graft_prefix is leaf-generic —
-            # this call compiles a second trace for the draft tuple).
-            self._dcache = self._graft_prefix(self._dcache, src_dev, dst_dev, n)
-            last = self._dcache
         # The graft returns the state alone, which the next program takes
         # (donated): the clock drops a sentinel that has gone that way.
-        self._clock.dispatched(jax.tree_util.tree_leaves(last)[0])
+        self._clock.dispatched(jax.tree_util.tree_leaves(self._cache)[0])
         self._clock.enter("plan")
         self._prefix_index.touch(src)
 
@@ -1950,14 +1706,6 @@ class Scheduler:
         slot.cached = False
         slot.parked_at = 0.0
         slot.warm_pos = start
-        slot.accept_ewma = 1.0
-        if self._dhist is not None:
-            # The whole prompt's history row can be written up front —
-            # the matcher only reads positions below the live length, and
-            # warming chunks build KV toward exactly these tokens.
-            row = np.zeros((self.max_len,), np.int32)
-            row[: slot.length] = req.token_ids
-            self._dhist = self._dhist.at[slot_idx].set(jnp.asarray(row))
 
     def _claim_warm_cold(self, req: Request, slot_idx: int) -> None:
         """Cold chunked admission: claim + account (no cached prefix)."""
@@ -2150,9 +1898,8 @@ class Scheduler:
     ):
         """The rest of a ``_prefill_suffix`` dispatch, from its arrays to
         ``dispatched``: ``n`` of ``tokens`` (1, s) into slot ``slot_idx``
-        from position ``start``, the same through a draft model's state,
-        and with ``boundary`` the slot's snapshot where the chunk ends on
-        one.  Returns the token future (1,)."""
+        from position ``start``, and with ``boundary`` the slot's snapshot
+        where the chunk ends on one.  Returns the token future (1,)."""
         tokens_dev, *where = self._h2d(
             tokens, np.int32(start), np.int32(n), np.int32(slot_idx)
         )
@@ -2172,15 +1919,6 @@ class Scheduler:
                 self._slots[slot_idx], slot_idx, start + n
             )
             last = tok if snapshot is None else snapshot
-        if self.draft_cfg is not None:
-            # The draft's twin: both states cover the same [0, start + n)
-            # of the slot (a parked or grafted prefix came to both), so
-            # whenever the slot joins decode the draft can speculate from
-            # a complete prefix.
-            self._dcache = self._prefill_draft_suffix(
-                self.draft_params, self._dcache, tokens_dev, *where, kv_bucket
-            )
-            last = self._dcache[0]
         self._clock.dispatched(last)
         self._note_aux(aux)
         return tok
@@ -2272,7 +2010,7 @@ class Scheduler:
         self._tick_tokens += 1
         if slot.emitted >= req.sampling.max_tokens:
             self._finish(slot_idx, "length")
-        elif slot.length + slot.emitted >= self.effective_max_len:
+        elif slot.length + slot.emitted >= self.max_len:
             self._finish(slot_idx, "length")
 
     def _loop(self) -> None:
@@ -2370,22 +2108,6 @@ class Scheduler:
                 self._snapshots.clear()
                 with self.stats.lock:
                     self.stats.state_snapshot_bytes = 0
-            if self.draft_cfg is not None:
-                from generativeaiexamples_tpu.engine.decode import (
-                    prepare_cache,
-                )
-
-                self._dcache = prepare_cache(
-                    self.draft_cfg, self.max_batch, self.max_len,
-                    self.mesh,
-                )
-            if self._dhist is not None:
-                # The n-gram history is donated through the chunk the
-                # same way the caches are — a fault mid-step can
-                # leave it deleted too.
-                self._dhist = jnp.zeros(
-                    (self.max_batch, self.max_len), jnp.int32
-                )
         clock.enter("telemetry")
         self._note_tick((time.perf_counter() - tick_t0) * 1000.0)
         if self._tick_busy:
@@ -2413,7 +2135,6 @@ class Scheduler:
         "shared_prefix_hits",
         "prefill_chunks",
         "spec_accepted",
-        "spec_fallbacks",
     )
     # Snapshot keys whose TSDB series name predates the generic
     # ``engine.<key>`` convention (dashboards already reference it).
@@ -2435,14 +2156,14 @@ class Scheduler:
             stats = self.stats
             stats.tick_ms_ewma += 0.1 * (dt_ms - stats.tick_ms_ewma)
             # Token-normalized tick time: scale the wall time back to a
-            # one-chunk-per-lane cost model when speculation emitted more
-            # than the baseline chunk would have.  Every downstream
+            # one-chunk-per-lane cost model when accepted drafts emitted
+            # more than the baseline chunk would have.  Every downstream
             # consumer of "tick latency" (autoscaler tick_high_ms, the
             # pool's brownout scorer, 429 Retry-After) was calibrated
             # against that model; feeding them the raw wall time of a
-            # tick that emitted 3x the tokens reads as congestion when
-            # the engine is 3x FASTER per token.  Non-speculative ticks
-            # emit at most the baseline, so norm == raw there.
+            # tick that emitted 2x the tokens reads as congestion when
+            # the engine is 2x FASTER per token.  A tick without drafts
+            # emits at most the baseline, so norm == raw there.
             emitted = self._tick_tokens
             baseline = self._tick_decoded * self.decode_chunk_size
             norm_ms = dt_ms
@@ -2532,11 +2253,9 @@ class Scheduler:
         # harmless BECAUSE admissions are length-bounded: non-snapshot
         # lanes pin to max_len - 1, whose append-buffer flush clips into
         # [flush_clip_start, max_len) — _clip_prompt keeps every
-        # admitted prompt's KV strictly below that zone for the WIDEST
-        # flush this scheduler dispatches (_flush_width covers the plain
-        # chunk and a gamma+1 speculative round; on the XLA scatter path
-        # the garbage lands at max_len - 1 only, which the row's own
-        # decode rewrites before its mask exposes it).
+        # admitted prompt's KV strictly below that zone (on the XLA
+        # scatter path the garbage lands at max_len - 1 only, which the
+        # row's own decode rewrites before its mask exposes it).
         decode_active: list[int] = self._active()
         admits: list[Callable[[], None]] = []
 
@@ -2713,7 +2432,9 @@ class Scheduler:
         else:
             lanes = self._decode_lanes() if ahead else decode_active
             if lanes:
-                decode_pending = self._dispatch_decode_phase(lanes)
+                decode_pending = (
+                    self._decode_finalize, self._decode_dispatch(lanes)
+                )
         if decode_pending is not None:
             progressed = True
             # The tick's record describes the chunk it fetches: its
@@ -2818,16 +2539,10 @@ class Scheduler:
         a live request, decoding or warming.  While a slot is free the
         next arrival's prefill should lead the device's queue, not wait
         behind a decode chunk; with a full house nothing can be admitted
-        before a row ends anyway.  Decoding without a draft model or
-        n-gram drafts only: such a round's acceptance counts are the
-        host's to know before the next dispatch.  (A chunk that verifies the
-        model's own draft leaves its rows' lengths on the device beside
-        their tokens, ``_carried_len``, and goes ahead as a plain one.)"""
-        return (
-            self.draft_cfg is None
-            and self.spec_mode != "ngram"
-            and all(s.request is not None for s in self._slots)
-        )
+        before a row ends anyway.  (A chunk that verifies the model's own
+        draft leaves its rows' lengths on the device beside their tokens,
+        ``_carried_len``, and goes ahead as a plain one.)"""
+        return all(s.request is not None for s in self._slots)
 
     def _decode_lanes(self) -> list[int]:
         """A full house's rows for the next decode chunk: every row whose
@@ -2848,14 +2563,14 @@ class Scheduler:
             done = s.emitted + s.unfetched
             if (
                 done < s.request.sampling.max_tokens
-                and s.length + done < self.effective_max_len
+                and s.length + done < self.max_len
             ):
                 lanes.append(i)
         return lanes
 
     def _lane_state(self) -> tuple[np.ndarray, np.ndarray, np.ndarray, np.ndarray, int]:
-        """Per-slot decode-chunk inputs shared by the plain and speculative
-        paths: (lengths, temp, top_p, top_k, max_active_length).
+        """Per-slot decode-chunk inputs: (lengths, temp, top_p, top_k,
+        max_active_length).
 
         Next write position per slot: the prompt plus all emitted tokens
         except the latest one, which is the decode input and gets written
@@ -2903,202 +2618,15 @@ class Scheduler:
             max(active_lengths) if active_lengths else 0,
         )
 
-    def _dispatch_decode_phase(self, active: list[int]):
-        """Dispatch this tick's decode work for the pre-admission active
-        snapshot and return ``(finalize_fn, args)`` for the tick to run
-        after the admission finalizes.  Speculative schedulers route
-        through :meth:`_spec_dispatch`; a ``spec_draft`` fault degrades
-        the WHOLE tick to the plain decode chunk (requests never fail —
-        acceptance just drops to the non-spec baseline; the stale draft
-        KV this leaves behind cannot break exactness because rejection
-        sampling corrects ANY proposal distribution the draft actually
-        sampled from, and greedy rows only keep drafts that match the
-        target argmax)."""
-        if self.draft_cfg is not None or self.spec_mode == "ngram":
-            try:
-                inject("spec_draft")
-            except FaultInjected:
-                from generativeaiexamples_tpu.resilience.degrade import (
-                    mark_degraded,
-                )
-
-                mark_degraded("spec_draft")
-                with self.stats.lock:
-                    self.stats.spec_fallbacks += 1
-                return self._decode_finalize, self._decode_dispatch(active)
-            return self._spec_finalize, self._spec_dispatch(active)
-        return self._decode_finalize, self._decode_dispatch(active)
-
-    def _pick_gamma(self, active: list[int]) -> int:
-        """Lookahead for this chunk: the pow2 bucket of the highest
-        per-slot desire, clamped to [1, gamma].
-
-        Per-slot desire rounds ``accept_ewma * gamma`` — a request whose
-        drafts keep being rejected wants gamma=1 (≈ plain decode cost:
-        one draft + one verify token per round), while a quoting RAG
-        answer at 0.9+ acceptance wants the full lookahead.  The chunk
-        runs ONE gamma for every lane (gamma is a static jit arg), so the
-        max desire wins: over-speculating a low-acceptance lane wastes
-        its rejected tail, but under-speculating a high-acceptance lane
-        caps the whole batch's tokens/tick.  Bucketing to powers of two
-        bounds the compile set to {1, 2, 4, ...} ∪ {gamma}."""
-        g = self.gamma
-        if self.adaptive_gamma and active:
-            desired = 1
-            for i in active:
-                slot = self._slots[i]
-                if slot.request is None:
-                    continue
-                want = int(round(slot.accept_ewma * self.gamma))
-                desired = max(desired, min(self.gamma, max(1, want)))
-            g = self._gamma_bucket(desired, self.gamma)
-        with self.stats.lock:
-            self.stats.spec_gamma = g
-        return g
-
-    def _spec_dispatch(self, active: list[int]) -> tuple:
-        """Dispatch one speculative chunk (draft-model or n-gram rounds)
-        asynchronously; :meth:`_spec_finalize` fetches and emits.
-
-        Lanes outside the ``active`` snapshot (admitted this tick) pin to
-        max_len - 1 exactly like the plain chunk's: the room clamp inside
-        ``_verify_and_emit`` holds them to one garbage token per round
-        whose writes land only in the tail flush zone that
-        ``_admit_limit`` keeps clear of live KV."""
-        lengths, temp, top_p, top_k, max_active = self._lane_state()
-        snap = np.zeros((self.max_batch,), dtype=bool)
-        snap[active] = True
-        lengths = np.where(snap, lengths, self.max_len - 1)
-        g = self._pick_gamma(active)
-        # Rounds per chunk: keep the per-tick emission ceiling near the
-        # plain chunk's so streaming cadence and admission latency stay
-        # comparable at any adaptive gamma.
-        rounds = max(1, -(-self.decode_chunk_size // (g + 1)))
-        kv_bucket = bucket_size(
-            max_active + rounds * (g + 1) + 1, maximum=self.max_len
-        )
-        self._tick_kv_bucket = kv_bucket
-        self._clock.enter(
-            "dispatch", program="spec_chunk", lanes=len(active),
-            kv_bucket=kv_bucket, gamma=g,
-        )
-        cur_dev, lengths_dev, *sampling_dev = self._h2d(
-            self._cur_tok, np.minimum(lengths, self.max_len - 1),
-            temp, top_p, top_k,
-        )
-        rows_dev = (cur_dev, lengths_dev, self._next_key(), *sampling_dev)
-        self._clock.stage("call")
-        if self.draft_cfg is not None:
-            self._cache, self._dcache, outs, n_emits = self._spec_chunk(
-                (self.params, self.draft_params), self._cache, self._dcache,
-                *rows_dev, rounds, g, kv_bucket,
-            )
-        else:
-            self._cache, self._dhist, outs, n_emits = self._ngram_chunk(
-                self.params, self._cache, self._dhist,
-                *rows_dev, rounds, g, kv_bucket,
-            )
-        self._clock.dispatched(outs)
-        with self.stats.lock:
-            # A round is one verify step of the target's stack.
-            self.stats.decode_stack_passes += rounds * self._stack_passes
-        self._clock.enter("plan")
-        return outs, n_emits, active, g
-
-    def _spec_finalize(self, outs, n_emits, active, gamma_used):
-        """Fetch a dispatched speculative chunk and emit its tokens.
-
-        Only lanes in the dispatch snapshot update ``_cur_tok`` — lanes
-        admitted behind the dispatch keep the first token their prefill
-        wrote (same masked-update contract as ``_decode_finalize``)."""
-        self._clock.enter("wait_device")
-        outs_h = np.asarray(outs)
-        n_h = np.asarray(n_emits)
-        self._clock.enter("emit")
-        last = outs_h[
-            -1, np.arange(self.max_batch), np.maximum(n_h[-1] - 1, 0)
-        ]
-        if active:
-            self._cur_tok[active] = last[active]
-        self._consume_spec_outs(outs_h, n_h, active, gamma_used)
-        with self.stats.lock:
-            self.stats.decode_chunks += 1
-
-    def _consume_spec_outs(
-        self,
-        outs_h: np.ndarray,
-        n_h: np.ndarray,
-        active: list[int],
-        gamma_used: int,
-    ) -> None:
-        """Host back half of every speculation chunk: emit each round's
-        accepted tokens per snapshot lane and account acceptance.
-
-        Rollback is IMPLICIT here — the correctness crux of the serving
-        integration: ``n_h[r, i]`` already counts only verifier-accepted
-        tokens (plus the bonus token), so rejected drafts never reach
-        ``_handle_token`` and therefore never enter ``slot.history``,
-        ``slot.emitted``, the parked-segment length, or the radix index.
-        The phantom KV those rejected tokens wrote on device sits at
-        positions >= the slot's accounted length and is overwritten by
-        the lane's own future writes before any attention mask or graft
-        can expose it.  A mid-chunk finish breaks the lane's emission
-        loop; later rounds' tokens for that lane are dropped the same
-        way (device-side they only wrote phantom positions)."""
-        spec_rounds = 0
-        spec_tokens = 0
-        spec_proposed = 0
-        spec_accepted = 0
-        for r in range(outs_h.shape[0]):
-            for k, i in enumerate(active):
-                self._poll_lane(r, k, outs_h.shape[0])
-                slot = self._slots[i]
-                req = slot.request
-                if req is None:
-                    continue
-                s = req.sampling
-                count_spec = s.temperature <= 0.0 or (
-                    s.top_p < 1.0 or s.top_k > 0
-                )
-                n = int(n_h[r, i])
-                accepted = min(max(n - 1, 0), gamma_used)
-                if count_spec:
-                    spec_rounds += 1
-                    spec_proposed += gamma_used
-                    spec_accepted += accepted
-                    rate = accepted / gamma_used
-                else:
-                    # Unfiltered sampled rows emit exactly one token per
-                    # round by design — speculation buys them nothing, so
-                    # their desire decays to gamma=1.
-                    rate = 0.0
-                slot.accept_ewma += 0.3 * (rate - slot.accept_ewma)
-                for j in range(n):
-                    self._handle_token(i, int(outs_h[r, i, j]))
-                    if count_spec:
-                        spec_tokens += 1
-                    if slot.request is None:
-                        break
-        with self.stats.lock:
-            self.stats.spec_rounds += spec_rounds
-            self.stats.spec_tokens += spec_tokens
-            self.stats.spec_proposed += spec_proposed
-            self.stats.spec_accepted += spec_accepted
-            if spec_proposed:
-                chunk_rate = spec_accepted / spec_proposed
-                self.stats.spec_acceptance_ewma += 0.2 * (
-                    chunk_rate - self.stats.spec_acceptance_ewma
-                )
-        self._flush_tokens()
-
     def _emit_verified(self, toks: np.ndarray, n_emits: np.ndarray, mine: list) -> None:
         """Emit a chunk whose steps verified the model's own draft: step
         ``r`` gave row ``i`` its first ``n_emits[r, i]`` of ``toks[r, i]``
         (two where the stack agreed with the draft).  A row may end on
         the first of two (``max_tokens``, ``max_len``, EOS): its second,
-        and its later steps, are dropped with it.  Feeds the ``spec_*``
-        stats as a speculative round does: one draft a greedy row a
-        step."""
+        and its later steps, are dropped with it: a rejected draft never
+        reaches ``_handle_token``, so it enters neither ``slot.history``
+        nor what a finish parks.  Feeds the ``spec_*`` stats: one draft a
+        greedy row a step."""
         rounds = accepted = tokens = 0
         for step, (row, counts) in enumerate(zip(toks, n_emits)):
             for k, (i, req) in enumerate(mine):

@@ -354,9 +354,9 @@ def _overloaded_response(scheduler) -> web.Response:
     retry_after = 1.0
     try:
         snap = scheduler.stats.snapshot()
-        # Token-normalized tick latency when available: a speculative
-        # tick emits several tokens' worth of work, so its raw wall time
-        # over-estimates drain time by the acceptance multiple.
+        # Token-normalized tick latency when available: a tick whose
+        # drafts were accepted emits up to two tokens a step, so its raw
+        # wall time over-estimates drain time by the acceptance multiple.
         tick_ms = float(
             snap.get("tick_ms_norm_ewma", 0.0)
             or snap.get("tick_ms_ewma", 0.0)
@@ -721,20 +721,14 @@ async def handle_metrics(request: web.Request) -> web.Response:
         f"engine_spec_rounds_total {snap['spec_rounds']}",
         "# TYPE engine_spec_tokens_total counter",
         f"engine_spec_tokens_total {snap['spec_tokens']}",
-        # Serving-path speculation telemetry (from zero whether or not a
-        # draft is configured, so dashboards need no existence checks):
-        # acceptance = accepted/proposed; the gauge pair mirrors the
-        # adaptive controller's state.
+        # From zero whether or not the model drafts, so dashboards need
+        # no existence checks: acceptance = accepted/proposed.
         "# TYPE engine_spec_proposed_total counter",
         f"engine_spec_proposed_total {snap.get('spec_proposed', 0)}",
         "# TYPE engine_spec_accepted_total counter",
         f"engine_spec_accepted_total {snap.get('spec_accepted', 0)}",
-        "# TYPE engine_spec_fallbacks_total counter",
-        f"engine_spec_fallbacks_total {snap.get('spec_fallbacks', 0)}",
         "# TYPE engine_spec_acceptance_ewma gauge",
         f"engine_spec_acceptance_ewma {snap.get('spec_acceptance_ewma', 0.0)}",
-        "# TYPE engine_spec_gamma gauge",
-        f"engine_spec_gamma {snap.get('spec_gamma', 0)}",
     ]
     # Where the tick thread's time goes (exclusive phases: the six sum
     # to its wall time), how long it left the device with nothing
@@ -1258,58 +1252,6 @@ def main() -> None:
         "visible devices rather than re-partitioning live mesh slices.",
     )
     parser.add_argument(
-        "--draft-model",
-        default=os.environ.get("GAIE_DRAFT_MODEL", ""),
-        help="draft model preset/HF id for speculative decoding (empty = "
-        "off; TRT-LLM draft-model parity, SURVEY.md §2.8). Greedy "
-        "requests verify by prefix agreement; filtered sampled requests "
-        "by rejection sampling.",
-    )
-    parser.add_argument(
-        "--spec-ngram",
-        action="store_true",
-        default=os.environ.get("GAIE_SPEC_NGRAM", "") == "1",
-        help="prompt-lookup speculation: draft tokens mined from the "
-        "request's own prompt+output history (no draft model — the RAG "
-        "quote-the-context accelerator). Mutually exclusive with "
-        "--draft-model.",
-    )
-    parser.add_argument(
-        "--gamma",
-        type=int,
-        default=int(os.environ.get("GAIE_SPEC_GAMMA", "4")),
-        help="draft tokens proposed per speculation round",
-    )
-    parser.add_argument(
-        "--spec-decode",
-        action="store_true",
-        default=os.environ.get("GAIE_SPEC_DECODE", "") == "1",
-        help="enable speculative decoding in the serving scheduler: with "
-        "--draft-model (or [llm].draft_model in config) the draft "
-        "proposes and the target verifies; without one, falls back to "
-        "prompt-lookup (n-gram) speculation — always "
-        "distribution-preserving, with per-request acceptance-adaptive "
-        "lookahead",
-    )
-    parser.add_argument(
-        "--spec-gamma",
-        type=int,
-        default=(
-            int(os.environ["GAIE_SPEC_GAMMA_MAX"])
-            if os.environ.get("GAIE_SPEC_GAMMA_MAX")
-            else None
-        ),
-        help="maximum speculation lookahead (overrides --gamma; the "
-        "acceptance-adaptive controller shrinks per-chunk gamma below "
-        "this, never above)",
-    )
-    parser.add_argument(
-        "--draft-checkpoint",
-        default=os.environ.get("GAIE_DRAFT_CHECKPOINT", ""),
-        help="explicit weights directory for the draft model (overrides "
-        "the $GAIE_WEIGHTS_DIR lookup for --draft-model)",
-    )
-    parser.add_argument(
         "--seed",
         type=int,
         default=0,
@@ -1422,42 +1364,11 @@ def main() -> None:
     # Config-file fallbacks ([llm] section) for deployments that prefer
     # config over flags; explicit flags win.
     llm_cfg = get_config().llm
-    spec_decode = args.spec_decode or bool(
-        getattr(llm_cfg, "spec_decode", False)
-    )
-    draft_model = args.draft_model or str(
-        getattr(llm_cfg, "draft_model", "") or ""
-    )
-    gamma = (
-        args.spec_gamma
-        if args.spec_gamma is not None
-        else (int(getattr(llm_cfg, "spec_gamma", 0) or 0) or args.gamma)
-    )
     matmul_kernel = args.matmul_kernel or str(
         getattr(llm_cfg, "matmul_kernel", "") or "xla"
     )
     if args.kv_dtype:
         cfg = dataclasses.replace(cfg, kv_dtype=args.kv_dtype)
-    # --spec-decode with no draft model falls back to prompt-lookup
-    # speculation: no extra weights, still distribution-preserving, and
-    # the adaptive controller caps the cost when prompts don't repeat.
-    spec_ngram = args.spec_ngram or (spec_decode and not draft_model)
-    draft_cfg = None
-    draft_params = None
-    if draft_model:
-        draft_preset = resolve_model_preset(draft_model)
-        draft_cfg = llama.PRESETS[draft_preset]()
-        draft_ckpt = args.draft_checkpoint or weights_dir_for(draft_model)
-        if draft_ckpt:
-            logger.info("loading draft weights from %s", draft_ckpt)
-            draft_params = load_hf_causal_lm(draft_cfg, draft_ckpt)
-        else:
-            logger.warning(
-                "no checkpoint for draft %s under $GAIE_WEIGHTS_DIR; "
-                "speculating with random-initialized draft weights "
-                "(acceptance will be near zero)",
-                draft_model,
-            )
     from generativeaiexamples_tpu.parallel.mesh import (
         MeshSpec,
         make_mesh,
@@ -1466,8 +1377,7 @@ def main() -> None:
 
     def make_scheduler(mesh):
         # The pool's scheduler_factory closes over this too, so replicas
-        # the autoscaler grows later speculate with the same draft
-        # params and gamma ceiling as the initial set.
+        # the autoscaler grows later are built as the initial set.
         return Scheduler(
             cfg,
             params,
@@ -1475,10 +1385,6 @@ def main() -> None:
             max_batch=args.max_batch,
             max_len=args.max_len,
             seed=args.seed,
-            draft_cfg=draft_cfg,
-            draft_params=draft_params,
-            gamma=gamma,
-            spec_mode="ngram" if spec_ngram else None,
             prefix_cache=args.prefix_cache,
             prefill_chunk_tokens=args.prefill_chunk_tokens or None,
             quantize=args.weight_dtype == "int8",

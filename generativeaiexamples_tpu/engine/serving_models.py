@@ -44,7 +44,6 @@ serves ``models/hybrid.py``'s layer kinds.
 
 from __future__ import annotations
 
-import dataclasses
 import functools
 
 import jax
@@ -108,16 +107,14 @@ class LlamaServing:
     cut_anywhere = True
     counter_names: tuple = ()
     snapshot_bytes = 0
-    draft = ""  # draft models and n-gram drafts are the scheduler's own
+    draft = ""  # no prediction module: a decode step yields one token
 
     def __init__(self, cfg: llama.LlamaConfig, mesh, max_len: int) -> None:
         self.cfg, self.mesh, self.max_len = cfg, mesh, max_len
 
-    def check_supported(self, *, draft_cfg=None, spec_mode=None) -> None:
+    def check_supported(self) -> None:
         """Every option of the scheduler serves a stack that a token
-        passes once.  A looped one (``cfg.ut_steps`` > 1) is served with a
-        draft model or n-gram drafts too (a verify step is ``forward``'s
-        append-buffer shape, which the loop carries), and refuses, with
+        passes once.  A looped one (``cfg.ut_steps`` > 1) refuses, with
         the reason, what its loop does not carry."""
         cfg = self.cfg
         if cfg.ut_steps == 1:
@@ -131,13 +128,6 @@ class LlamaServing:
                 "the scheduler's lanes yield one token a step and "
                 "llama.forward has one trip count; it is never read as 1"
             )
-        # What ``spec_decode.self_draft`` would hand over: this stack, cut.
-        if draft_cfg is not None and draft_cfg.n_layers < cfg.n_layers and (
-            dataclasses.replace(draft_cfg, n_layers=cfg.n_layers) == cfg
-        ):
-            from generativeaiexamples_tpu.engine.spec_decode import LOOPED_SELF_DRAFT
-
-            raise ValueError(LOOPED_SELF_DRAFT)
         if self.mesh is not None and self.mesh.shape.get("pipe", 1) > 1:
             from generativeaiexamples_tpu.parallel.pipeline import LOOPED_PIPELINE
 
@@ -405,38 +395,29 @@ class HybridServing:
         if self.draft:
             self.counter_names += self.DRAFT_COUNTERS
 
-    def check_supported(self, *, draft_cfg=None, spec_mode=None) -> None:
+    def check_supported(self) -> None:
         """What is not served for a model whose state cannot be cut at a
         token, refused with the reason."""
-        drafted = draft_cfg is not None or spec_mode is not None
-        if (drafted or self.draft) and self.cfg.layers_of("kda"):
+        if self.draft and self.cfg.layers_of("kda"):
             raise ValueError(
                 "speculative decoding is not served over KDA state: a "
                 "rejected draft would need the recurrent state rolled back, "
                 "and no step keeps the state it started from (ops/kda.py has "
                 "no rollback)"
             )
-        if (drafted or self.draft) and self.cfg.layers_of("mamba"):
+        if self.draft and self.cfg.layers_of("mamba"):
             raise ValueError(
                 "speculative decoding is not served over mamba state: a "
                 "rejected draft has moved the state-space state and the "
                 "convolution's tail, and no step keeps what it started from "
                 "(ops/ssm.py has no rollback)"
             )
-        if (drafted or self.draft) and self.cfg.layers_of("cca"):
+        if self.draft and self.cfg.layers_of("cca"):
             raise ValueError(
                 "speculative decoding is not served over a cca layer's "
                 "tails: a rejected draft has moved the convolutions' last "
                 "inputs and the shifted value, and no step keeps the tails "
                 "it started from"
-            )
-        if drafted:
-            raise ValueError(
-                "a draft model and n-gram drafts are not served for a model "
-                "of layer kinds: engine/spec_decode.py verifies over "
-                "models/llama.py's K/V cache alone; the one draft served "
-                "here is the model's own prediction module (draft 'mtp'), "
-                "whose rejected position a ring masks by its own rule"
             )
         if self.mesh is not None and self.mesh.size > 1:
             raise ValueError(

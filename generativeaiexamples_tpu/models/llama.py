@@ -140,13 +140,8 @@ def llama3_70b(**overrides) -> LlamaConfig:
 
 
 def llama32_1b(**overrides) -> LlamaConfig:
-    """meta-llama/Llama-3.2-1B(-Instruct) geometry.
-
-    Shares the llama3 vocabulary (128256), which is what makes it the
-    natural DRAFT model for speculative decoding against llama3-8b/70b
-    targets (``engine/spec_decode.py``; drafts and targets must agree on
-    token ids).
-    """
+    """meta-llama/Llama-3.2-1B(-Instruct) geometry (the llama3
+    vocabulary, 128256)."""
     return dataclasses.replace(
         LlamaConfig(
             d_model=2048,
@@ -530,8 +525,7 @@ def pack_for_serving(params: Params) -> Params:
         # packed branches in forward() don't add biases.
         return params
     if "wqkv" in layers:
-        # Already packed (e.g. a self-speculation draft sliced from
-        # packed serving params): idempotent no-op.
+        # Already packed: idempotent no-op.
         return params
     layers["wqkv"] = cat(layers.pop("wq"), layers.pop("wk"), layers.pop("wv"))
     if "w_gate" in layers:  # dense MLP only; MoE experts stay unpacked
@@ -634,7 +628,7 @@ def init_kv_cache(
 
 
 def init_append_buffer(cfg: LlamaConfig, batch: int, width: int) -> tuple:
-    """A decode chunk's (or a verify block's) append buffer, empty: the int8
+    """A decode chunk's append buffer, empty: the int8
     cache's four leaves with ``width`` slots a row (``forward``:
     ``append_cache``), a plane for every plane of the cache."""
     shape = (cfg.cache_planes, cfg.n_kv_heads, batch, width, cfg.head_dim)
@@ -976,11 +970,11 @@ def forward(
     written to ab slot ``step`` of every row, by the decode kernel itself
     where it runs (the leaves go through the Mosaic call aliased, and no
     XLA operation of the layer loop touches them), by a contiguous
-    dynamic_update_slice in its XLA twin and for a verify block, and
-    attention runs over the big cache's [0, kv_lengths) prefix PLUS ab
-    slots [0, step] — the big cache is never written, which keeps the
-    decode executable free of the per-token scatter whose preferred
-    layout conflicts with the kernel's (measured: 5 GB of entry copies).
+    dynamic_update_slice in its XLA twin, and attention runs over the big
+    cache's [0, kv_lengths) prefix PLUS ab slots [0, step] — the big
+    cache is never written, which keeps the decode executable free of
+    the per-token scatter whose preferred layout conflicts with the
+    kernel's (measured: 5 GB of entry copies).
     The caller flushes ab into the big cache once per chunk.  Returns
     ``(hidden, cache, ab)`` in this mode.
 
@@ -1020,8 +1014,6 @@ def forward(
             decode_gqa_attention_xla,
             use_append_buffer,
             use_decode_kernel,
-            verify_gqa_attention_xla,
-            write_append_rows,
         )
 
         if not (
@@ -1039,33 +1031,24 @@ def forward(
         ):
             raise ValueError(
                 "append_cache requires the append-buffer protocol "
-                "(int8 KV, single chip; s == 1 decode or s > 1 verify)"
+                "(int8 KV, single chip, one token a row)"
             )
-        # s == 1 decode: Pallas kernel when eligible, else the XLA twin.
-        # s > 1 (speculative verify): the whole fresh block rides the
-        # buffer and verify_gqa_attention_xla attends cache-prefix +
-        # causal buffer.  In BOTH modes ``kv_lengths`` is the valid
-        # big-cache prefix — fresh tokens' KV never touches the big
-        # cache inside this executable; the caller flushes.
-        _append_kernel = s == 1 and use_decode_kernel(
+        # Pallas kernel when eligible, else the XLA twin.  ``kv_lengths``
+        # is the valid big-cache prefix — fresh tokens' KV never touches
+        # the big cache inside this executable; the caller flushes.
+        _append_kernel = use_decode_kernel(
             s=s, kv_int8=kv_int8, batch=b, window=window,
             n_q=n_q, n_kv=n_kv, head_dim=hd, cache_len=t,
             append_width=append_cache[0][0].shape[3], mesh=mesh,
         )
         ab_in, append_step = append_cache
-        if s == 1:
-            record(f"decode_attention b={b} w={window}", _append_kernel)
-            # Who writes the step's fresh rows into the append buffer: the
-            # kernel itself, or the twin's dynamic_update_slice.
-            record(
-                f"decode_append_write b={b} c={ab_in[0].shape[3]}",
-                _append_kernel,
-            )
-        if s > 1 and ab_in[0].shape[3] != s:
-            raise ValueError(
-                f"verify append buffer has {ab_in[0].shape[3]} slots for "
-                f"{s} fresh tokens"
-            )
+        record(f"decode_attention b={b} w={window}", _append_kernel)
+        # Who writes the step's fresh rows into the append buffer: the
+        # kernel itself, or the twin's dynamic_update_slice.
+        record(
+            f"decode_append_write b={b} c={ab_in[0].shape[3]}",
+            _append_kernel,
+        )
     else:
         ab_in = None
         append_step = None
@@ -1125,8 +1108,7 @@ def forward(
             (b, window, KH, ...) shape gqa_attention expects.  XLA
             materializes this slice — the Pallas decode kernel below is
             the hot path that avoids it; this is the fallback for warm
-            multi-token calls (suffix prefill, speculative verify) and
-            non-TPU backends."""
+            multi-token calls (suffix prefill) and non-TPU backends."""
             sl = jax.lax.dynamic_slice(
                 buf,
                 (li,) + (0,) * (buf.ndim - 1),
@@ -1171,34 +1153,24 @@ def forward(
                 # Append-buffer decode: fresh KV goes to ab slot
                 # ``append_step`` and attention runs over
                 # cache[0:kv_lengths) + ab[0:step]; no scatter touches the
-                # big cache in this executable.  A decode step hands the
-                # fresh rows to the attention, which writes them itself
-                # (the kernel into its own block of the buffer, so that no
-                # XLA operation touches a leaf inside the layer loop; the
-                # twin as ``dynamic_update_slice``); a verify block writes
-                # its S rows here, once.
+                # big cache in this executable.  The step hands the fresh
+                # rows to the attention, which writes them itself (the
+                # kernel into its own block of the buffer, so that no XLA
+                # operation touches a leaf inside the layer loop; the twin
+                # as ``dynamic_update_slice``).
                 k8, ks = _quantize_kv(k)
                 v8, vs = _quantize_kv(v)
                 step = jnp.asarray(append_step, jnp.int32)
-                if s == 1:
-                    _decode_attn = (
-                        decode_gqa_attention if _append_kernel
-                        else decode_gqa_attention_xla
-                    )
-                    fresh = (k8[:, 0], v8[:, 0], ks[:, 0], vs[:, 0])
-                    attn, ab = _decode_attn(
-                        q[:, 0], *kv, li, kv_lengths,
-                        append=(ab, fresh, step), window=window,
-                    )
-                    attn = attn[:, None]
-                else:  # speculative-verify block over cache + causal buffer
-                    ab = tuple(
-                        write_append_rows(leaf, fresh, li, step)
-                        for leaf, fresh in zip(ab, (k8, v8, ks, vs))
-                    )
-                    attn = verify_gqa_attention_xla(
-                        q, *kv, li, kv_lengths, ab, window=window
-                    )
+                _decode_attn = (
+                    decode_gqa_attention if _append_kernel
+                    else decode_gqa_attention_xla
+                )
+                fresh = (k8[:, 0], v8[:, 0], ks[:, 0], vs[:, 0])
+                attn, ab = _decode_attn(
+                    q[:, 0], *kv, li, kv_lengths,
+                    append=(ab, fresh, step), window=window,
+                )
+                attn = attn[:, None]
             elif kv is not None and kv_int8:
                 k8, ks = _quantize_kv(k)
                 v8, vs = _quantize_kv(v)
@@ -1226,8 +1198,8 @@ def forward(
                     # Cold prefill: attend over the fresh bf16 k/v (exact — no
                     # quantization error on the prompt pass).  Only valid when
                     # the caller guarantees the cache holds nothing visible to
-                    # these queries; warm multi-token calls (chunked prefill,
-                    # speculative verify) must read the cache below.
+                    # these queries; warm multi-token calls (chunked prefill)
+                    # must read the cache below.
                     attn = attention(q, k, v, positions, kv_lengths, mesh=mesh)
                 else:
                     # NOTE: the Pallas kernel is deliberately NOT used here

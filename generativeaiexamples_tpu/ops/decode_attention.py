@@ -506,15 +506,13 @@ def use_append_buffer(
     window) but correct at full batch.  Off-TPU the scatter path stays
     the default test oracle; ``GAIE_FORCE_APPEND_BUFFER=1`` opts in.
     """
-    if s < 1 or not kv_int8:
+    if s != 1 or not kv_int8:
         return False
-    if s == 1 and use_decode_kernel(
+    if use_decode_kernel(
         s=s, kv_int8=kv_int8, batch=batch, window=window,
         n_q=n_q, n_kv=n_kv, head_dim=head_dim, mesh=mesh, backend=backend,
     ):
         return True
-    # s > 1 is the speculative-verify block (verify_gqa_attention_xla):
-    # same protocol, no kernel yet, same platform gating.
     if n_q % n_kv != 0:
         return False
     if os.environ.get("GAIE_FORCE_APPEND_BUFFER"):
@@ -527,7 +525,7 @@ def write_append_rows(leaf, fresh, layer, slot):
     """An append leaf (L, KH, B, C, ...) with ``fresh`` (B, S, KH, ...)
     at slots [slot, slot + S) of layer ``layer``, every row: XLA's form
     of the write (a contiguous ``dynamic_update_slice``), the decode
-    twin's with S 1 and the verify block's with S the block."""
+    twin's with S 1."""
     fresh_t = jnp.transpose(fresh, (2, 0, 1) + tuple(range(3, fresh.ndim)))
     return jax.lax.dynamic_update_slice(
         leaf,
@@ -547,30 +545,35 @@ def _slice_layer_window(buf, li, w):
     )[0]
 
 
-def _window_buffer_attention_core(
-    q, k_w, v_w, ks_w, vs_w, kv_lengths, append_w, buf_base
+def _cache_buffer_attention_xla(
+    q, k8, v8, ks, vs, layer, kv_lengths, append, buf_base, *, window
 ):
-    """Shared XLA math for the append-buffer attention family, over
-    PRE-SLICED per-layer windows.
+    """The XLA math of the append-buffer decode: slice layer ``layer``'s
+    first ``window`` slots out of the stacked (L, KH, B, T, ...) cache
+    (and the whole of the append buffer's layer), then attend both.
 
-    ``q`` is (B, S, n_q, HD) fresh-token queries; ``k_w``/``v_w`` are
-    (KH, B, W, HD) int8 window values with (KH, B, W) scales.  Window
-    slot ``t`` contributes iff ``t < kv_lengths[b]`` and the (optional,
-    pre-sliced) append buffer contributes slot ``j`` to query ``i`` iff
-    ``j <= buf_base + i`` — decode passes ``buf_base = count - 1`` with
-    S=1 (all written slots visible), verify passes ``buf_base = 0``
-    (causal within the block).  Masked window slots contribute EXACT
-    zeros (``where`` before the max + multiplicative mask), so two
-    callers whose windows agree on the unmasked slots produce
-    bit-identical outputs regardless of what garbage fills the rest.  One
-    implementation keeps the numerics (mask constants, softmax clamp,
-    dequant-scale folding) of the decode and verify twins identical.
+    ``q`` is (B, 1, n_q, HD), the fresh token's queries; the cache's
+    window slot ``t`` contributes iff ``t < kv_lengths[b]`` and the
+    (optional) append buffer contributes slots ``j <= buf_base``, the
+    ones written so far.  Masked window slots contribute EXACT zeros
+    (``where`` before the max + multiplicative mask), so two callers
+    whose windows agree on the unmasked slots produce bit-identical
+    outputs regardless of what garbage fills the rest.
     """
+    li = jnp.asarray(layer, jnp.int32)
+    append_w = None
+    if append is not None:
+        append_w = tuple(
+            _slice_layer_window(leaf, li, leaf.shape[3]) for leaf in append
+        )
+    k_w = _slice_layer_window(k8, li, window)
+    v_w = _slice_layer_window(v8, li, window)
+    ks_w = _slice_layer_window(ks, li, window)
+    vs_w = _slice_layer_window(vs, li, window)
     b, s, n_q, hd = q.shape
     n_kv = k_w.shape[0]
     g = n_q // n_kv
     scale = hd**-0.5
-    window = k_w.shape[2]
 
     qg = q.reshape(b, s, n_kv, g, hd)
 
@@ -597,8 +600,7 @@ def _window_buffer_attention_core(
     vals = [(v_w, vs_w)]
     if append_w is not None:
         k_ab, v_ab, ks_ab, vs_ab = append_w
-        c = k_ab.shape[2]
-        j_idx = jnp.arange(c, dtype=jnp.int32)
+        j_idx = jnp.arange(k_ab.shape[2], dtype=jnp.int32)
         visible = (
             j_idx[None, :]
             <= buf_base + jnp.arange(s, dtype=jnp.int32)[:, None]
@@ -635,35 +637,6 @@ def _window_buffer_attention_core(
         jnp.transpose(out, (0, 3, 1, 2, 4))
         .reshape(b, s, n_q, hd)
         .astype(q.dtype)
-    )
-
-
-def _cache_buffer_attention_xla(
-    q, k8, v8, ks, vs, layer, kv_lengths, append, buf_base, *, window
-):
-    """Contiguous-cache front half of the append-buffer family: slice
-    layer ``li``'s first ``window`` slots out of the stacked
-    (L, KH, B, T, ...) cache, then run the shared window core."""
-    li = jnp.asarray(layer, jnp.int32)
-    append_w = None
-    if append is not None:
-        k_ab, v_ab, ks_ab, vs_ab = append
-        c = k_ab.shape[3]
-        append_w = (
-            _slice_layer_window(k_ab, li, c),
-            _slice_layer_window(v_ab, li, c),
-            _slice_layer_window(ks_ab, li, c),
-            _slice_layer_window(vs_ab, li, c),
-        )
-    return _window_buffer_attention_core(
-        q,
-        _slice_layer_window(k8, li, window),
-        _slice_layer_window(v8, li, window),
-        _slice_layer_window(ks, li, window),
-        _slice_layer_window(vs, li, window),
-        kv_lengths,
-        append_w,
-        buf_base,
     )
 
 
@@ -709,37 +682,6 @@ def decode_gqa_attention_xla(
         window=window,
     )[:, 0]
     return out if buf is None else (out, buf)
-
-
-@functools.partial(jax.jit, static_argnames=("window",))
-def verify_gqa_attention_xla(
-    q: jnp.ndarray,
-    k8: jnp.ndarray,
-    v8: jnp.ndarray,
-    ks: jnp.ndarray,
-    vs: jnp.ndarray,
-    layer: jnp.ndarray,
-    kv_lengths: jnp.ndarray,
-    append,
-    *,
-    window: int,
-) -> jnp.ndarray:
-    """Multi-token verify attention over [big-cache prefix ; fresh block].
-
-    The speculative-decode verify pass's append-buffer attention
-    (``engine/spec_decode.py``): ``q`` is (B, S, n_q, HD) — row r's S
-    fresh tokens sit at absolute positions ``kv_lengths[r] + i`` — the
-    big cache contributes slots ``t < kv_lengths[r]`` (every fresh query
-    sees the whole valid prefix), and the append buffer (all S slots
-    fresh this call) contributes causally: slot j visible to query i iff
-    ``j <= i``.  The big cache is only SLICED — no scatter shares this
-    executable, so the layout-copy failure mode of warm multi-token
-    scatters at serving batch cannot occur.
-    """
-    return _cache_buffer_attention_xla(
-        q, k8, v8, ks, vs, layer, kv_lengths, append, jnp.int32(0),
-        window=window,
-    )
 
 
 @functools.partial(

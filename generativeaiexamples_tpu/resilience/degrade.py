@@ -15,9 +15,6 @@ Stage names are free-form but the ladder uses a fixed vocabulary:
   ``index_fallback``  approximate/quantized index bypassed for the
                       exact host-side scan
   ``retrieval``       retrieval abandoned entirely; answer is LLM-only
-  ``spec_draft``      scheduler tick fell back from speculative to plain
-                      decoding (draft model faulted); requests keep
-                      streaming, throughput drops to the non-spec rate
 
 Like the request deadline, the log rides a ``contextvars`` scope so it
 crosses the server's generator-pump thread via ``Context.run`` without

@@ -17,11 +17,6 @@ Standard sites (the names the tests and the docs use):
   ``microbatch`` inside the MicroBatcher worker's batch dispatch
   ``replica``    one pass of a scheduler replica's tick loop (gray-
                  failure drills: ``index`` selects a single straggler)
-  ``spec_draft`` the scheduler's speculative-decode dispatch (draft
-                 model or n-gram proposer); an injected error degrades
-                 that TICK to the plain decode chunk — requests never
-                 fail, acceptance just drops to the non-spec baseline
-                 (``spec_draft:error=1`` kills speculation entirely)
   =============  =====================================================
 
 Configuration: programmatic (``install``), or a spec string from the
@@ -59,7 +54,6 @@ SITES = (
     "llm",
     "microbatch",
     "replica",
-    "spec_draft",
 )
 
 

@@ -104,8 +104,8 @@ FILE_SECONDS = {
     "tests/test_exaone_moe_model.py": 324,
     "tests/test_notebooks.py": 258,
     "tests/test_gqa_ring_chunk_kernel.py": 207,
-    "tests/test_speculative.py": 197,
     "tests/test_gqa_chunk_kernel.py": 187,
+    "tests/test_own_draft_serving.py": 154,
     "tests/test_chip_compile_decode_chunks.py": 140,
     "tests/test_tick_ahead.py": 139,
     "tests/test_latent_chunk.py": 138,
@@ -131,7 +131,6 @@ FILE_SECONDS = {
     "tests/test_llama_serving_rows.py": 57,
     "tests/test_admit_alone.py": 55,
     "tests/test_mistral4_model.py": 49,
-    "tests/test_spec_serving.py": 49,
     "tests/test_setup_tracing.py": 47,
     "tests/test_weights.py": 47,
     "tests/test_engine.py": 45,

@@ -176,60 +176,29 @@ def test_append_buffer_path_matches_scatter_path(monkeypatch, mode):
         np.testing.assert_allclose(f, r, atol=3.0)
 
 
-def test_multi_token_append_verify_matches_warm_scatter(monkeypatch):
-    """forward(append_cache=..., s=gamma+1) + flush == the warm-scatter
-    multi-token verify pass — the speculative chunk's two target-cache
-    protocols must agree on hidden states AND the resulting cache."""
-    from generativeaiexamples_tpu.engine.decode import _flush_append_buffer
+def test_a_block_of_several_tokens_over_the_append_buffer_is_refused(monkeypatch):
+    """The append buffer takes a decode step's one token a row: the gate
+    says no to a block, and ``forward`` raises rather than write one (its
+    verify block went with the engine of a second model's drafts, PR 55;
+    a model's own draft is verified by ``HybridServing``, two positions
+    through ``hybrid.forward``)."""
     from generativeaiexamples_tpu.models import llama
+    from generativeaiexamples_tpu.ops.decode_attention import use_append_buffer
 
     monkeypatch.setenv("GAIE_FORCE_APPEND_BUFFER", "1")
     cfg = _append_cfg()
-    b, plen, s_v = 8, 8, 4  # verify block of gamma+1 = 4 tokens
-    key = jax.random.PRNGKey(5)
-    params = llama.init_params(cfg, key)
-    tokens = jax.random.randint(key, (b, plen), 0, cfg.vocab_size)
-    lengths = jnp.full((b,), plen, jnp.int32)
-    positions = jnp.broadcast_to(jnp.arange(plen), (b, plen))
-
-    cache = llama.init_kv_cache(cfg, b, 128)
-    _, cache = llama.forward(
-        params, cfg, tokens, positions, cache, lengths, cold_prefill=True
-    )
-    cache_ref = jax.tree.map(jnp.copy, cache)
-
-    fresh = jax.random.randint(key, (b, s_v), 0, cfg.vocab_size)
-    vpos = lengths[:, None] + jnp.arange(s_v)[None, :]
-
-    # Reference: warm multi-token scatter path.
-    h_ref, cache_ref = llama.forward(
-        params, cfg, fresh, vpos, cache_ref, lengths + s_v, kv_bucket=128
-    )
-
-    # Append-buffer verify path + flush.
-    ab_shape = (cfg.n_layers, cfg.n_kv_heads, b, s_v, cfg.head_dim)
-    ab0 = (
-        jnp.zeros(ab_shape, jnp.int8),
-        jnp.zeros(ab_shape, jnp.int8),
-        jnp.zeros(ab_shape[:-1], jnp.bfloat16),
-        jnp.zeros(ab_shape[:-1], jnp.bfloat16),
-    )
-    h_ab, _, ab = llama.forward(
-        params, cfg, fresh, vpos, cache, lengths, kv_bucket=128,
-        append_cache=(ab0, 0),
-    )
-    cache_ab = _flush_append_buffer(cache, ab, lengths, 128)
-
-    np.testing.assert_allclose(
-        np.asarray(h_ab, np.float32),
-        np.asarray(h_ref, np.float32),
-        rtol=0.08, atol=0.08,
-    )
-    for leaf_f, leaf_r in zip(cache_ab, cache_ref):
-        f = np.asarray(leaf_f).astype(np.float32)
-        r = np.asarray(leaf_r).astype(np.float32)
-        np.testing.assert_array_equal(f[0], r[0])  # layer 0 bit-exact
-        np.testing.assert_allclose(f, r, atol=3.0)
+    gate = dict(kv_int8=True, batch=8, window=128, n_q=cfg.n_heads, n_kv=cfg.n_kv_heads,
+                head_dim=cfg.head_dim)
+    assert use_append_buffer(s=1, **gate) and not use_append_buffer(s=4, **gate)
+    params = llama.init_params(cfg, jax.random.PRNGKey(5))
+    cache = llama.init_kv_cache(cfg, 8, 128)
+    lengths = jnp.full((8,), 8, jnp.int32)
+    fresh = jnp.zeros((8, 4), jnp.int32)
+    with pytest.raises(ValueError, match="one token a row"):
+        llama.forward(
+            params, cfg, fresh, lengths[:, None] + jnp.arange(4)[None, :], cache, lengths,
+            kv_bucket=128, append_cache=(llama.init_append_buffer(cfg, 8, 4), 0),
+        )
 
 
 def test_flush_clip_boundary_confines_damage_to_tail_zone():

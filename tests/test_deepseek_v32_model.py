@@ -268,13 +268,10 @@ def test_what_the_family_does_not_serve_is_refused_with_the_reason(bad, kwargs, 
 
 def test_a_draft_over_latent_rows_is_supported_and_the_other_refusals_stand():
     """``check_supported`` passes ``draft="mtp"`` over latent rows and
-    index keys, and still refuses a draft model beside it, a mesh of
-    several devices, and a module whose block keeps a ring."""
+    index keys, and still refuses a module whose block keeps a ring."""
     model = _serving(CFG)
     model.check_supported()
     assert model.draft == "mtp" and model.one_window and model.rows_in_place and not model.cut_anywhere
-    with pytest.raises(ValueError, match="a draft model and n-gram drafts are not served"):
-        model.check_supported(spec_mode="ngram")
     with pytest.raises(ValueError, match="rows a position"):
         dataclasses.replace(CFG, mtp_kind=("mla_window", "experts"))
     names = model.counter_names

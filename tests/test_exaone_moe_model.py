@@ -479,10 +479,6 @@ def test_the_four_shares_add_up_to_the_uncut_reference(params):
 def test_what_is_not_served_is_refused_with_the_reason(model):
     assert isinstance(serving_model(CFG, None, T), HybridServing) and model.draft == "mtp"
     model.check_supported()  # the model's own draft over rings and rows is served
-    with pytest.raises(ValueError, match="a draft model and n-gram drafts are not served"):
-        model.check_supported(spec_mode="ngram")
-    with pytest.raises(ValueError, match="a draft model and n-gram drafts are not served"):
-        model.check_supported(draft_cfg=object())
     with pytest.raises(ValueError, match="int8 weights are not served"):
         model.prepare_params(None, quantize=True, matmul_kernel="xla", seed=0)
     with pytest.raises(ValueError, match="int8 state"):
@@ -495,7 +491,8 @@ def test_what_is_not_served_is_refused_with_the_reason(model):
     assert serving_model(PLAIN, None, T).counter_names[-1] == "attn_rows_dense_full_prefill"
 
 
-# -- (c) through the scheduler: rows that end inside a step ------------------------------------------
+# -- (c) through the scheduler: a full house (rows that end inside a step: ------------------------
+# tests/test_own_draft_serving.py) -----------------------------------------------------------------
 
 
 def _generate(scheduler, prompts, ns, temperature=0.0):
@@ -512,46 +509,6 @@ def _generate(scheduler, prompts, ns, temperature=0.0):
     scheduler.start()
     assert all(ev.wait(300) for ev in done)
     return outs
-
-
-def test_streams_with_the_draft_on_and_off_are_equal_also_where_a_row_ends_inside_a_step(tokens):
-    """(c) Through ``Scheduler``: greedy streams with the draft on (drafts
-    right at two positions in three, so rows end on ``max_tokens`` and on
-    ``max_len`` on the first and on the second token of a step) equal the
-    streams with it off."""
-    # Lengths whose streams lie at positions of their own (9-17, 20-27,
-    # 31-39, 45-58, 100-128), so that one table of drafts by position
-    # serves whatever slot a request lands in.
-    prompts = [tokens[2, :9].tolist(), tokens[0, :20].tolist(), tokens[1, :31].tolist(),
-               tokens[0, :45].tolist(), np.random.RandomState(7).randint(0, 512, size=100).tolist()]
-    ns = [8, 7, 8, 13, 40]  # the last ends on max_len 128: 100 + 28
-    streams = {}
-    for name, cfg in (("off", PLAIN), ("on", CFG)):
-        s = Scheduler(cfg, None, max_batch=4, max_len=T, decode_chunk_size=4, seed=3,
-                      prefill_chunk_tokens=32, prefix_cache="shared")
-        if name == "on":
-            # Drafts from the draft-off streams: right, but at every third position.
-            table = np.zeros((4, T + 2), np.int32)
-            for p, o in zip(prompts, streams["off"]):
-                table[:, len(p) : len(p) + len(o)] = o
-            table = np.where(np.arange(T + 2)[None, :] % 3 == 0, (table + 1) % 512, table)
-            s._decode_chunk = _Oracle(cfg, None, T, table).make_decode_chunk()
-        s.start()
-        try:
-            slots = []
-            for p, n in zip(prompts, ns):  # one at a time: each alone in the house
-                slots.append(_generate(s, [p], [n])[0])
-            streams[name] = slots
-            snap = s.stats.snapshot()
-        finally:
-            s.stop()
-    assert streams["on"] == streams["off"]
-    assert [len(o) for o in streams["on"]] == [8, 7, 8, 13, 28]
-    assert snap["spec_accepted"] > 0 and snap["draft_accepted"] >= snap["spec_accepted"]
-    assert snap["spec_proposed"] == snap["spec_rounds"] > 0
-    # Fewer tokens than rounds + kept drafts: a row ended on the first of a
-    # step's two tokens, and its second was dropped with it.
-    assert snap["spec_rounds"] < snap["spec_tokens"] < snap["spec_rounds"] + snap["spec_accepted"]
 
 
 def test_a_full_house_goes_ahead_under_the_draft_with_the_lengths_on_the_device(tokens):

@@ -161,10 +161,11 @@ def test_the_four_shares_add_up_to_the_uncut_reference(params):
 def test_what_is_not_served_is_refused_with_the_reason():
     model = serving_model(CFG, None, T)
     assert isinstance(model, HybridServing) and not model.cut_anywhere
+    model.check_supported()
+    drafting = serving_model(CFG, None, T)
+    drafting.draft = "mtp"  # a prediction module over KDA state, were one held
     with pytest.raises(ValueError, match="rolled back"):
-        model.check_supported(spec_mode="ngram")
-    with pytest.raises(ValueError, match="rolled back"):
-        model.check_supported(draft_cfg=object())
+        drafting.check_supported()
     with pytest.raises(ValueError, match="QUANT_TARGETS"):
         model.prepare_params(None, quantize=True, matmul_kernel="xla", seed=0)
     with pytest.raises(ValueError, match="QUANT_TARGETS"):

@@ -1003,8 +1003,6 @@ def test_check_supported_keeps_refusing_what_it_refuses_for_a_rows_only_model():
 
     model = serving_model(hybrid.PRESETS["mistral4-tiny"](), None, 128)
     model.check_supported()
-    with pytest.raises(ValueError, match="draft model and n-gram"):
-        model.check_supported(spec_mode="ngram")
     with pytest.raises(ValueError, match="int8 weights"):
         model.prepare_params(None, quantize=True, matmul_kernel="xla", seed=0)
     with pytest.raises(ValueError, match="int8 state"):

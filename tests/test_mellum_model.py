@@ -246,8 +246,7 @@ def test_a_window_layers_state_does_not_grow_with_max_len(max_len):
 def test_what_is_not_served_is_refused_with_the_reason():
     model = serving_model(CFG, None, T)
     assert isinstance(model, HybridServing) and not model.cut_anywhere
-    with pytest.raises(ValueError, match="a draft model and n-gram drafts are not served"):
-        model.check_supported(spec_mode="ngram")
+    model.check_supported()
     with pytest.raises(ValueError, match="fused GQA projections"):
         model.prepare_params(None, quantize=True, matmul_kernel="xla", seed=0)
     with pytest.raises(ValueError, match="int8 state"):

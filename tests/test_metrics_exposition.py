@@ -166,8 +166,6 @@ class _StubStats:
             "spec_proposed": 0,
             "spec_accepted": 0,
             "spec_acceptance_ewma": 0.0,
-            "spec_gamma": 0,
-            "spec_fallbacks": 0,
             "tick_ms_ewma": 0.0,
             "tick_ms_norm_ewma": 0.0,
         }
@@ -219,16 +217,13 @@ def test_engine_server_metrics_is_valid_exposition():
     assert (
         exp.value("rag_stage_latency_ms_bucket", stage="llm_ttft", le="25") == 1
     )
-    # Speculative-serving telemetry exports from zero (no draft model
-    # configured on the stub) in valid exposition format.
+    # The draft telemetry exports from zero (the stub's model does not
+    # draft) in valid exposition format.
     assert exp.types["engine_spec_proposed_total"] == "counter"
     assert exp.value("engine_spec_proposed_total") == 0
     assert exp.value("engine_spec_accepted_total") == 0
-    assert exp.value("engine_spec_fallbacks_total") == 0
     assert exp.types["engine_spec_acceptance_ewma"] == "gauge"
     assert exp.value("engine_spec_acceptance_ewma") == 0
-    assert exp.types["engine_spec_gamma"] == "gauge"
-    assert exp.value("engine_spec_gamma") == 0
     # One KV layout: no series speaks of pages.
     assert not [name for name in exp.types if "kv_page" in name or "kv_cow" in name]
     # Matmul-path info gauge exports from zero: the stub predates the

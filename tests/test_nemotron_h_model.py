@@ -156,8 +156,11 @@ def test_the_other_families_count_the_rows_they_counted():
 
 
 def test_check_supported_refuses_drafts_over_mamba_state():
+    model = serving_model(CFG, None, T)
+    model.check_supported()
+    model.draft = "mtp"  # a prediction module over mamba state, were one held
     with pytest.raises(ValueError, match="mamba state"):
-        serving_model(CFG, None, T).check_supported(spec_mode="ngram")
+        model.check_supported()
     with pytest.raises(ValueError, match="no prediction module"):
         hybrid.from_hf_config(hybrid.NEMOTRON_H_TINY, max_len=64, draft="mtp")
 

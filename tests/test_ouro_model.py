@@ -409,17 +409,9 @@ def test_the_exit_rule_at_the_published_threshold_and_below(params, tokens):
 def test_what_a_looped_stack_refuses_is_refused_with_its_sentence(params):
     from jax.sharding import Mesh
 
-    from generativeaiexamples_tpu.engine.spec_decode import self_draft
     from generativeaiexamples_tpu.parallel import pipeline
 
-    serving = LlamaServing(CFG, None, ROWS)
-    serving.check_supported(draft_cfg=None, spec_mode=None)
-    serving.check_supported(draft_cfg=llama.llama_tiny(), spec_mode=None)  # a draft MODEL
-    serving.check_supported(draft_cfg=None, spec_mode="ngram")
-    with pytest.raises(ValueError, match="no early-exit self-draft"):
-        self_draft(CFG, params, 1)
-    with pytest.raises(ValueError, match="no early-exit self-draft"):
-        serving.check_supported(draft_cfg=dataclasses.replace(CFG, n_layers=1), spec_mode=None)
+    LlamaServing(CFG, None, ROWS).check_supported()
     with pytest.raises(ValueError, match="early_exit_threshold 0.9 is not served.*never read as 1"):
         LlamaServing(dataclasses.replace(CFG, early_exit_threshold=0.9), None, ROWS).check_supported()
     mesh = Mesh(np.array(jax.devices()[:2]).reshape(2, 1), ("pipe", "data"))
@@ -428,30 +420,8 @@ def test_what_a_looped_stack_refuses_is_refused_with_its_sentence(params):
     with pytest.raises(NotImplementedError, match="not served as a pipeline"):
         pipeline.pipeline_forward(
             params, CFG, jnp.zeros((2, 8), jnp.int32), jnp.zeros((2, 8), jnp.int32), mesh)
-    # A stack passed once is served with all of them, as before.
-    LlamaServing(llama.llama_tiny(), mesh, ROWS).check_supported(
-        draft_cfg=dataclasses.replace(llama.llama_tiny(), n_layers=1), spec_mode=None)
-
-
-def test_a_verify_step_is_the_steps_it_stands_for(params, tokens, monkeypatch):
-    """``forward``'s verify shape (``s`` > 1 over the append buffer: a
-    draft model's and the n-gram draft's verify pass) through the loop:
-    three positions at once are those positions a step at a time."""
-    monkeypatch.setenv("GAIE_FORCE_APPEND_BUFFER", "1")
-    cfg, serving, cold, graft, _, steps, _ = _serving("int8")
-    toks = jnp.asarray(tokens)
-    state = serving.init_state(SLOTS, ROWS)
-    _, small, _ = cold(params, toks[:, :COLD], jnp.full((2,), COLD, jnp.int32))
-    state = graft(state, small, jnp.arange(2, dtype=jnp.int32), jnp.asarray([0, 1], jnp.int32))
-    lengths = jnp.asarray([COLD, COLD, 0, 0], jnp.int32)
-    fed = jnp.zeros((3, SLOTS), jnp.int32).at[:, :2].set(toks[:, COLD : COLD + 3].T)
-    _, stepped = steps(params, state, fed, lengths)
-    ab = llama.init_append_buffer(cfg, SLOTS, 3)
-    hidden, _, _ = llama.forward(
-        params, cfg, fed.T, lengths[:, None] + jnp.arange(3)[None], state, lengths,
-        append_cache=(ab, 0))
-    verified = llama.logits(params, hidden)  # (SLOTS, 3, V)
-    _held(np.asarray(verified)[:2], np.swapaxes(np.asarray(stepped), 0, 1)[:2], 1e-5)
+    # A stack passed once is served over that mesh, as before.
+    LlamaServing(llama.llama_tiny(), mesh, ROWS).check_supported()
 
 
 @pytest.fixture(scope="module")
@@ -485,23 +455,6 @@ def test_the_scheduler_serves_the_reference_and_counts_its_passes(params, served
     # names: tests/test_tick_tracing.py).
     assert snap["decode_stack_passes"] >= CFG.ut_steps * 4 * snap["decode_chunks"] > 0
     assert snap["decode_stack_passes"] % (CFG.ut_steps * 4) == 0
-
-
-def test_an_ngram_drafts_verify_steps_stream_what_plain_steps_stream(params, served):
-    from generativeaiexamples_tpu.engine.scheduler import Scheduler
-    from tests.test_scheduler import _collect
-
-    _, prompt, stream = served
-    sched = Scheduler(CFG, params, max_batch=2, max_len=128, decode_chunk_size=4,
-                      spec_mode="ngram", gamma=3)
-    sched.start()
-    try:
-        got, _ = _collect(sched, prompt, max_tokens=9)
-    finally:
-        sched.stop()
-    assert got == stream
-    snap = sched.stats.snapshot()
-    assert snap["spec_rounds"] > 0 and snap["decode_stack_passes"] % CFG.ut_steps == 0
 
 
 def test_a_plain_stack_counts_one_pass_a_step():

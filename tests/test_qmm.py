@@ -4,11 +4,10 @@ The fused decode path's whole contract is bit-exactness: the Pallas
 kernel (interpret mode on CPU) and its XLA twin consume identical
 quantized operands and must agree to the bit, all the way up through
 greedy decode in the serving scheduler on every admission path (cold,
-chunked prefill, shared-prefix graft, speculative).  Tile blocking
+chunked prefill, shared-prefix graft).  Tile blocking
 happens ONCE at load — ``BLOCK_EVENTS`` proves no decode step re-tiles.
 """
 
-import dataclasses
 import queue
 
 import jax
@@ -290,20 +289,6 @@ def test_greedy_parity_fused_vs_xla_all_paths(monkeypatch, int8_packed_params):
     fused = _run_paths(int8_packed_params, sched_kw)
     assert fused == ref
     assert ref["cold"][0] and ref["chunked"][0]  # non-degenerate streams
-
-
-def test_greedy_parity_spec_decode(monkeypatch, int8_packed_params):
-    """Fused kernel under the speculative scheduler (draft + verify)."""
-    draft_cfg = dataclasses.replace(CFG, n_layers=1)
-    sched_kw = dict(
-        draft_cfg=draft_cfg, draft_quantize=True, gamma=2, seed=3
-    )
-    monkeypatch.setenv("GAIE_DISABLE_QMM_KERNEL", "1")
-    ref = _run_paths(int8_packed_params, sched_kw)
-    monkeypatch.delenv("GAIE_DISABLE_QMM_KERNEL")
-    monkeypatch.setenv("GAIE_QMM_INTERPRET", "1")
-    fused = _run_paths(int8_packed_params, sched_kw)
-    assert fused == ref
 
 
 def test_no_per_step_retiling_through_scheduler(int8_packed_params):

@@ -619,7 +619,7 @@ class TestPoolAggregation:
 # itself, and the bytes and planes of state, which stay a replica's own.
 NOT_SUMMED = {
     "cache_planes", "kv_bytes_per_token",
-    "rejected_total", "spec_acceptance_ewma", "spec_gamma",
+    "rejected_total", "spec_acceptance_ewma",
     "state_bytes_draft", "state_bytes_full", "state_bytes_window",
     "tick_ms_ewma", "tick_ms_norm_ewma", "ttft_avg_ms",
 }

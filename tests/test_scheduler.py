@@ -650,28 +650,22 @@ class Test70BTensorParallelServing:
 
 
 class TestSchedulerStress:
-    @pytest.mark.parametrize("spec", [False, True], ids=["plain", "spec"])
-    def test_many_requests_random_cancels(self, spec):
-        """Churn: 24 requests over 3 slots with mid-flight cancels — every
-        request must finish exactly once with a sane reason (SURVEY §5.2:
-        stress the batching scheduler in lieu of sanitizers).  Runs both
-        decode paths: the speculative chunk shares the slot/cancel
-        bookkeeping and must survive the same churn."""
+    def test_many_requests_random_cancels(self):
+        self.churn(
+            Scheduler(CFG, max_batch=3, max_len=128, decode_chunk_size=4)
+        )
+
+    @staticmethod
+    def churn(sched):
+        """Churn: 24 requests over ``sched``'s 3 slots with mid-flight
+        cancels — every request must finish exactly once with a sane
+        reason (SURVEY §5.2: stress the batching scheduler in lieu of
+        sanitizers).  ``tests/test_own_draft_serving.py`` runs the same
+        over a model whose every decode step verifies its own draft."""
         import random
         import threading
 
         rng = random.Random(0)
-        kwargs = {}
-        if spec:
-            kwargs = dict(
-                draft_cfg=llama.llama_tiny(
-                    dtype="float32", max_seq_len=128, n_layers=1
-                ),
-                gamma=3,
-            )
-        sched = Scheduler(
-            CFG, max_batch=3, max_len=128, decode_chunk_size=4, **kwargs
-        )
         sched.start()
         done: dict[int, list[str]] = {i: [] for i in range(24)}
         tokens: dict[int, int] = {i: 0 for i in range(24)}
@@ -1147,48 +1141,35 @@ class TestPipelinedTickBounds:
         assert sched.stats.snapshot()["active_slots"] == 2
 
 
-class TestEngineServerNgram:
-    def test_completions_over_ngram_scheduler(self):
-        """The HTTP serving front over a prompt-lookup scheduler: valid
-        completions + spec counters at /metrics (the --spec-ngram path)."""
-        from generativeaiexamples_tpu.engine.server import create_engine_app
+class TestTickNormalization:
+    KW = dict(max_batch=2, max_len=128, decode_chunk_size=4)
 
-        scheduler = Scheduler(
-            CFG, max_batch=2, max_len=128, decode_chunk_size=4,
-            spec_mode="ngram", gamma=3,
+    def test_multi_token_ticks_normalize_tick_ms(self):
+        """A tick emitting N tokens a lane-step is not N times slower — the
+        ``engine.tick_ms`` signal (autoscaler, replica scorer, 429
+        Retry-After) must be normalized to per-decode-chunk cost while
+        the raw EWMA keeps wall-clock truth."""
+        sched = Scheduler(CFG, **self.KW)  # never started
+        for _ in range(60):
+            # Synthetic tick: 1 decode dispatch, 24 tokens emitted
+            # (chunk budget 4) in 60 ms -> normalized cost 10 ms.
+            sched._tick_tokens = 24
+            sched._tick_decoded = 1
+            sched._note_tick(60.0)
+        snap = sched.stats.snapshot()
+        assert snap["tick_ms_ewma"] == pytest.approx(60.0, rel=0.05)
+        assert snap["tick_ms_norm_ewma"] == pytest.approx(10.0, rel=0.05)
+
+    def test_plain_ticks_unchanged(self):
+        sched = Scheduler(CFG, **self.KW)
+        for _ in range(60):
+            sched._tick_tokens = 4  # == decode_chunk_size: no speedup
+            sched._tick_decoded = 1
+            sched._note_tick(20.0)
+        snap = sched.stats.snapshot()
+        assert snap["tick_ms_norm_ewma"] == pytest.approx(
+            snap["tick_ms_ewma"], rel=0.01
         )
-        scheduler.start()
-        app = create_engine_app(
-            scheduler, ByteTokenizer(), model_name="llama-tiny"
-        )
-        loop = asyncio.new_event_loop()
-        client = TestClient(TestServer(app), loop=loop)
-        try:
-            loop.run_until_complete(client.start_server())
-
-            async def go():
-                resp = await client.post(
-                    "/v1/completions",
-                    json={
-                        "model": "llama-tiny",
-                        "prompt": "ab ab ab ab",
-                        "max_tokens": 8,
-                        "temperature": 0,
-                    },
-                )
-                assert resp.status == 200
-                body = await resp.json()
-                assert body["usage"]["completion_tokens"] == 8
-                resp = await client.get("/metrics")
-                text = await resp.text()
-                assert "engine_spec_rounds_total" in text
-
-            loop.run_until_complete(go())
-        finally:
-            loop.run_until_complete(client.close())
-            loop.close()
-            scheduler.stop()
-        assert scheduler.stats.snapshot()["spec_rounds"] > 0
 
 
 def test_engine_metrics_export_embed_batcher_series():

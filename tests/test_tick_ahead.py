@@ -675,23 +675,3 @@ def test_an_ahead_tick_runs_no_program_a_plain_tick_has_not(full):
     serve(5)
     assert _counters(d)[1] > before[1]
     assert {k: f._cache_size() for k, f in programs.items()} == sizes
-
-
-def test_a_speculative_scheduler_sends_nothing_ahead():
-    d = Driven("llama", max_batch=4, spec_mode="ngram", gamma=2)
-    lengths, tokens = SHORT, (9, 14, 21, 30, 12, 17)
-    subs = [
-        d.submit(_prompt(60 + i, n), t, f"s{i}")
-        for i, (n, t) in enumerate(zip(lengths, tokens))
-    ]
-    full_house = 0
-    for _ in range(200):
-        d.run_tick()
-        assert d.s._flight is None
-        full_house += all(sl.request is not None for sl in d.s._slots)
-        if all(done for _, done in subs):
-            break
-    assert full_house >= 3
-    chunks, ahead, dropped = _counters(d)
-    assert ahead == 0 and dropped == 0
-    assert [len(out) for out, _ in subs] == list(tokens)

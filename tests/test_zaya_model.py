@@ -139,8 +139,11 @@ def test_what_the_family_does_not_serve_is_refused_with_the_reason(bad, match):
 
 
 def test_check_supported_refuses_drafts_over_tails():
+    model = serving_model(CFG, None, T)
+    model.check_supported()
+    model.draft = "mtp"  # a prediction module over a cca layer's tails, were one held
     with pytest.raises(ValueError, match="tails"):
-        serving_model(CFG, None, T).check_supported(spec_mode="ngram")
+        model.check_supported()
 
 
 @pytest.mark.parametrize("asked, match", [
