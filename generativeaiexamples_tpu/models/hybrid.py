@@ -191,7 +191,7 @@ import jax
 import jax.numpy as jnp
 
 from generativeaiexamples_tpu.models.llama import rms_norm
-from generativeaiexamples_tpu.ops import cca, gqa, gqa_decode, kda, mla, mla_chunk, moe, ssm
+from generativeaiexamples_tpu.ops import cca, gqa, gqa_decode, kda, mla, mla_chunk, mla_decode, moe, ssm
 from generativeaiexamples_tpu.ops.dispatch import record
 from generativeaiexamples_tpu.ops.rope import (
     NO_ROPE, RopeSpec, apply_rope_partial, apply_rope_spec, rope_spec, yarn_mscale,
@@ -227,9 +227,10 @@ CCA_TAILS = ("conv0", "conv1", "v_prev")
 ATTN_COUNTERS = ("read_window", "read_full", "dense_window", "dense_full")
 # Rows of the latent cache the MLA layers read, and what they would have
 # read with every row's whole window (``kv_bucket``) read; of the rows
-# read, those a prefill chunk's Pallas kernel walked (``ops/mla_chunk.py``:
-# ``read_latent``'s own count where its gate admits the call, 0 where it
-# does not and in every decode step).  A ``LatentConfig`` model returns
+# read, those a Pallas kernel walked (a prefill chunk's,
+# ``ops/mla_chunk.py``, a decode step's, ``ops/mla_decode.py``:
+# ``read_latent``'s own count where the gate admits the call, 0 where it
+# does not and in every indexed step).  A ``LatentConfig`` model returns
 # them after ``moe.COUNTERS``.
 LATENT_COUNTERS = ("read_latent", "dense_latent", "kernel_latent")
 # What the indexer of an ``mla`` layer did (``IndexedLatentConfig``):
@@ -572,13 +573,19 @@ class LatentConfig(HybridConfig):
     # a row-block, 0.46-0.56 with blocks of 256).
     latent_block: int = 1024
     # A decode step walks each decoding row's blocks of this many latent
-    # rows up to its length (``mla.attend_absorbed_blocks``), one row
-    # after the other, and reads nothing of a slot that does not decode.
-    # One layer's attention of a step over 16 slots of 32,768 rows on a
-    # v5e: 0.31 ms with 4 rows of 14,000 decoding (what the family's cell
-    # holds) and 1.04 ms with all 16, where one product over every slot's
-    # whole window takes 1.13 ms whoever decodes; blocks of 1,024 read
-    # 0.38 and 1.29 ms, of 4,096 0.30 and 1.02.
+    # rows up to its length and reads nothing of a slot that does not
+    # decode: in ``ops/mla_decode.py``'s kernel, all rows of the step in
+    # one call, where its gate admits the step (bf16 state on one TPU
+    # device), in ``mla.attend_absorbed_blocks``, one row after the other,
+    # where it does not.  The twin's figures (PR 38), one layer's attention
+    # of a step over 16 slots of 32,768 rows on a v5e: 0.31 ms with 4 rows
+    # of 14,000 decoding (what the family's cell holds) and 1.04 ms with
+    # all 16, where one product over every slot's whole window takes 1.13
+    # ms whoever decodes; blocks of 1,024 read 0.38 and 1.29 ms, of 4,096
+    # 0.30 and 1.02.  The kernel's (PR 56): 0.097 ms at the 4 rows (the
+    # twin beside it 0.308), 0.275 with all 16 (0.935), 0.506 with 16 rows
+    # of 28,000 (1.725); blocks of 512 read 0.116 / 0.361 / 0.663, of 1,024
+    # 0.102 / 0.287 / 0.529, of 4,096 0.108 / 0.306 / 0.510.
     latent_decode_block: int = 2048
 
     def __post_init__(self) -> None:
@@ -1948,7 +1955,12 @@ def _mla_mixer(
     chunk, selected or not, is ``ops/mla_chunk.py``'s kernel for all rows
     of the call at once where ``use_latent_chunk`` admits it (the counter
     ``kernel_latent``: the rows it walked), and ``mla.attend_blocks`` a row
-    at a time where it does not.  A step (``gqa._STEP_QUERIES`` queries a
+    at a time where it does not.  A decode step without an indexer walks
+    each decoding row's blocks up to its length in ``ops/mla_decode.py``'s
+    kernel, every row of the step in one call, where ``use_latent_decode``
+    admits it (``kernel_latent`` again), and ``mla.attend_absorbed_blocks``
+    a row at a time where it does not.  With an indexer a step
+    (``gqa._STEP_QUERIES`` queries a
     slot or fewer: a decode step's one, a verify step's ``[token, draft]``,
     a prediction module's block beside either) never takes the chunk's
     form: it scores every slot's first ``window`` index keys against the
@@ -2116,11 +2128,21 @@ def _mla_mixer(
         else:
             o = in_place(mla.attend_blocks, cfg.latent_block, lengths)
     elif cfg.latent_block:
-        record(f"attn_latent_decode b={b} t={span}", False)
+        kernel = record(f"attn_latent_decode b={b} t={span}", mla_decode.use_latent_decode(
+            s=s, q_dtype=q_nope.dtype, rows_dtype=latent.dtype, width=width, rank=rank, heads=H,
+            rows=T, window=span, block=cfg.latent_decode_block, mesh=mesh,
+        ))
         # A decode step: a row that does not decode reads nothing.
         lengths = jnp.where(n_valid > 0, pos[:, 0] + 1, 0)
         read["read_latent"] = mla.rows_in_blocks(lengths, span, cfg.latent_decode_block).sum()
-        o = in_place(mla.attend_absorbed_blocks, cfg.latent_decode_block, lengths)
+        if kernel:
+            read["kernel_latent"] = read["read_latent"]
+            o = mla_decode.attend_latent_decode(
+                q_nope, q_rope, latent, q_pos=pos, lengths=lengths, slot=mine, window=span,
+                block=cfg.latent_decode_block, **sizes
+            )
+        else:
+            o = in_place(mla.attend_absorbed_blocks, cfg.latent_decode_block, lengths)
     else:
         attend = functools.partial(
             mla.attend_absorbed if s == 1 else mla.attend_expanded, **sizes

@@ -42,7 +42,10 @@ rows of whole lane tiles on one TPU device); :func:`attend_blocks` stays
 its XLA twin, the tests' oracle (``tests/test_latent_chunk.py``) and what
 serves everything the gate refuses: float32 state (the rehearsals, the
 reference check's own forms), the CPU, several devices, a decode step and
-its draft (two queries a row).
+its draft (two queries a row).  A decode step's walk
+(:func:`attend_absorbed_blocks`) runs in ``ops/mla_decode.py``'s kernel
+under the same conditions, every row of the step in one call, and stays
+that kernel's twin and oracle (``tests/test_latent_decode.py``).
 
 The rotation is over adjacent pairs (:func:`rope_interleaved`), with the
 plain frequencies of a ``theta`` or those of a ``RopeSpec``
@@ -244,6 +247,16 @@ def attend_blocks(
     return jnp.transpose(out.astype(q_nope.dtype), (0, 2, 1, 3))
 
 
+def whole_row_queries(q_nope, q_rope, w, nope: int, width: int):
+    """The absorbed form's queries against stored rows read as they lie:
+    ``W_kvb``'s key part (w (rank, H, nope + v)) folded into q_nope
+    (b, s, H, nope), the rope query beside it where a row keeps its rope
+    key, zeros against the row's zero columns: (b, s, H, width)."""
+    q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w[..., :nope])
+    spare = jnp.zeros(q_lat.shape[:-1] + (width - w.shape[0] - q_rope.shape[-1],), q_lat.dtype)
+    return jnp.concatenate([q_lat, q_rope.astype(q_lat.dtype), spare], axis=-1)
+
+
 @jax.named_scope("layer/mla/attn")
 def attend_absorbed_blocks(
     q_nope, q_rope, latent, w_kvb, q_pos, lengths, *, rank, nope, v_dim, block, scale=None,
@@ -262,9 +275,7 @@ def attend_absorbed_blocks(
     if scale is None:
         scale = (nope + q_rope.shape[-1]) ** -0.5
     w = w_kvb.reshape(rank, H, nope + v_dim)
-    q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w[..., :nope])
-    spare = jnp.zeros(q_lat.shape[:-1] + (width - rank - q_rope.shape[-1],), q_lat.dtype)
-    q = jnp.concatenate([q_lat, q_rope.astype(q_lat.dtype), spare], axis=-1)
+    q = whole_row_queries(q_nope, q_rope, w, nope, width)
 
     def score_and_weigh(rows):
         scores = jnp.einsum("bshr,btr->bhst", q, rows, preferred_element_type=F32) * scale
@@ -391,9 +402,7 @@ def _attend_whole_rows(q_nope, q_rope, rows, w, mask, nope: int, scale):
     part folded in, with the whole rows; rows (b, t, width), w (rank, H,
     nope + v), mask broadcast to (b, H, s, t).  Returns (b, s, H, v)."""
     rank = w.shape[0]
-    q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w[..., :nope])
-    spare = jnp.zeros(q_lat.shape[:-1] + (rows.shape[2] - rank - q_rope.shape[-1],), q_lat.dtype)
-    q = jnp.concatenate([q_lat, q_rope.astype(q_lat.dtype), spare], axis=-1)
+    q = whole_row_queries(q_nope, q_rope, w, nope, rows.shape[2])
     scores = jnp.einsum("bshr,btr->bhst", q, rows, preferred_element_type=F32) * scale
     probs = jax.nn.softmax(jnp.where(mask, scores, -1e30), axis=-1).astype(rows.dtype)
     o_row = jnp.einsum("bhst,btr->bhsr", probs, rows)

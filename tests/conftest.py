@@ -128,6 +128,7 @@ FILE_SECONDS = {
     "tests/test_retrieval.py": 58,
     "tests/test_chip_compile_llama.py": 58,
     "tests/test_nemotron_h_model.py": 57,
+    "tests/test_latent_decode.py": 46,
     "tests/test_llama_serving_rows.py": 57,
     "tests/test_admit_alone.py": 55,
     "tests/test_mistral4_model.py": 49,

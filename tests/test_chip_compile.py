@@ -128,6 +128,49 @@ def test_row_walk_kernel_compiles_at_both_cells_widths(one_chip, s, n_q, n_kv, w
     )
 
 
+# A decode step over latent rows (``ops/mla_decode.py``), 16 slots:
+# Mistral-Small-4's rows of 384 columns over a latent of 256 under one
+# query a row, dots3-note-prev's of 640 over 512 under a token and its
+# draft (what a verify step would hand it), at the narrowest and the widest
+# decode window (blocks of 2,048 either way).
+@pytest.mark.parametrize("window", [2048, 32768])
+@pytest.mark.parametrize(
+    "s,heads,rank,nope,width,rows",
+    [(1, 32, 256, 64, 384, 32768), (2, 128, 512, 128, 640, 16384)],
+    ids=["mistral-small-4", "dots3"],
+)
+def test_latent_decode_kernel_compiles_at_both_families_widths(
+    one_chip, s, heads, rank, nope, width, rows, window, monkeypatch
+):
+    from generativeaiexamples_tpu.ops import gqa_decode, mla_decode
+
+    S = _spec(one_chip)
+    batch, rope, v_dim, window = 16, 64, 128, min(window, rows)
+    leaf = S((batch, rows, width), jnp.bfloat16)
+    monkeypatch.setattr(gqa_decode, "platform_of", lambda mesh: "tpu")
+    assert mla_decode.use_latent_decode(
+        s=s, q_dtype=jnp.bfloat16, rows_dtype=leaf.dtype, width=width, rank=rank, heads=heads,
+        rows=rows, window=window, block=2048,
+    )
+    assert mla_decode._vmem_bytes(s * heads, 2048, width, rank) <= qmm._VMEM_BUDGET_BYTES // 2
+
+    def attn(q_nope, q_rope, latent, w_kvb, pos, lens, slot):
+        return mla_decode.attend_latent_decode(
+            q_nope, q_rope, latent, w_kvb, pos, lens, slot, rank=rank, nope=nope, v_dim=v_dim,
+            window=window, block=2048, interpret=False,
+        )
+
+    compiled = _compile(
+        attn, S((batch, s, heads, nope), jnp.bfloat16), S((batch, s, heads, rope), jnp.bfloat16),
+        leaf, S((rank, heads * (nope + v_dim)), jnp.bfloat16), S((batch, s), jnp.int32),
+        S((batch,), jnp.int32), S((batch,), jnp.int32),
+    )
+    text = compiled.as_text()
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*latent_decode_attention", text)) == 1
+    # The leaf stays where it lies: nothing of its size is made.
+    assert compiled.memory_analysis().temp_size_in_bytes < 4_000_000
+
+
 @pytest.mark.parametrize("batch,s,t", [(8, 1536, 1536), (16, 256, 2048)])
 def test_flash_kernel_compiles(one_chip, batch, s, t):
     S = _spec(one_chip)

@@ -298,15 +298,22 @@ def test_the_latent_models_decode_chunk_keeps_its_state_in_place(one_chip, monke
     such a leaf puts the POSITIONS minor, and the program then copied every
     layer's 0.34 GB in and out (2.5 GB of temporaries); rows of 384
     columns keep the layout the steps work in, and the whole-row
-    contraction never cuts a row into its latent and its rope key."""
+    contraction never cuts a row into its latent and its rope key.  Each
+    layer's ``layer/mla/attn`` is ``ops/mla_decode.py``'s Mosaic call over
+    all 16 rows: no ``dynamic-slice`` of a block of 2,048 rows and no
+    ``conditional`` a slot is left of ``attend_absorbed_blocks``' loop, and
+    the leaf goes into the call and past it where it lies in HBM (no
+    ``copy`` or ``copy-start`` of it: memory-space assignment prefetches
+    nothing of a 403 MB operand)."""
     import json
     from pathlib import Path
 
     from generativeaiexamples_tpu.engine.serving_models import HybridServing
     from generativeaiexamples_tpu.models import hybrid
-    from generativeaiexamples_tpu.ops import moe
+    from generativeaiexamples_tpu.ops import gqa_decode, moe
 
     monkeypatch.setattr(moe, "platform_of", lambda mesh: "tpu")
+    monkeypatch.setattr(gqa_decode, "platform_of", lambda mesh: "tpu")
     configs = Path(__file__).resolve().parents[1] / "benchmarks" / "configs"
     model = json.loads((configs / "mistral-small-4-119b-l6e32.json").read_text())
     engine = model["engine"]
@@ -333,6 +340,13 @@ def test_the_latent_models_decode_chunk_keeps_its_state_in_place(one_chip, monke
     assert "tpu_custom_call" in text  # the grouped expert products
     assert not re.search(rf"= bf16\[{b},{max_len},384\]\S* copy\(", text)
     assert not re.search(rf"= bf16\[{b},{max_len},(?:256|64|320)\]", text)  # no row cut in two
+    calls = re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*latent_decode_attention", text)
+    assert len(calls) >= cfg.n_layers  # a walk a layer, every row of the step in it
+    assert all("layer/mla/attn" in call for call in calls)
+    leaf = rf"bf16\[{b},{max_len},384\]"
+    assert not re.search(rf"= (?:\([^=]*)?{leaf}\S*(?:, [^=]*\))? copy-start\(", text)
+    assert not re.search(r"bf16\[1,2048,384\]\S* dynamic-slice\(", text)
+    assert not re.search(r"\(bf16\[1,1,32,128\]\S*\) conditional\(", text)  # no branch a slot
     _, toks, aux = compiled.out_info
     assert toks.shape == (steps, b) and aux.shape == (len(serving.counter_names),)
     print("latent decode chunk temporaries", compiled.memory_analysis().temp_size_in_bytes)

@@ -987,7 +987,7 @@ def test_mistral4_prompts_warm_side_by_side_and_metrics_are_exported(mistral4):
         loop.close()
     for name in ("engine_attn_rows_read_latent_decode_total", "engine_attn_rows_dense_latent_decode_total",
                  "engine_attn_rows_read_latent_prefill_total", "engine_attn_rows_dense_latent_prefill_total",
-                 "engine_attn_rows_kernel_latent_prefill_total",
+                 "engine_attn_rows_kernel_latent_prefill_total", "engine_attn_rows_kernel_latent_decode_total",
                  "engine_state_bytes_full", "engine_moe_experts_touched_total"):
         assert f"\n{name} " in metrics, name
     assert "engine_attn_rows_read_full_decode_total" not in metrics  # the GQA kinds' four are theirs
