@@ -3,7 +3,7 @@
 
     python chip_smoke.py [--seed N]          # one chip (what the driver runs)
     python chip_smoke.py --four-chips        # the two multi-chip paths only
-    python chip_smoke.py --hybrid [--model ling|mellum|exaone_moe|mistral4|zaya|nemotron_h|dots3_note|deepseek_v32] [--control NAME]   # phase 5 only
+    python chip_smoke.py --hybrid [--model ling|mellum|exaone_moe|mistral4|zaya|nemotron_h|dots3_note|deepseek_v32|longcat_flash] [--control NAME]   # phase 5 only
 
 Drives the system through the entry points a user calls, at llama3-8b's
 published widths with random int8 weights from ``--seed``:
@@ -87,6 +87,20 @@ published widths with random int8 weights from ``--seed``:
    ``no_groups`` (the 8 best of all 256 router outputs), ``no_mscale``,
    ``draft_shares_set`` (every second position attends the set of the one
    before it), ``stale_reject`` and ``w8a8_mlp``.
+   ``--model longcat_flash``: longcat-flash-chat-l4e16, four published
+   layers of two latent sublayers, two dense MLPs and one expert layer
+   whose sum is added after the second sublayer's dense MLP, under a
+   router of 768 outputs of which 256 are identity experts: a prompt of
+   4,864 tokens (the cell's reference length) in chunks of 256 through
+   the chunk program (``prefill_rows``, the slots' 16,384 rows in place),
+   its last 16 positions through the decode step, by the benchmark's own
+   comparison (``benchmarks/arch/longcat_flash.py``) against the
+   configuration's limits; controls, each the reference with one step
+   changed: ``no_latent_rescale`` (the normed latents not scaled),
+   ``no_zero_identity`` (identity choices dropped), ``shortcut_early`` (the
+   experts' sum added before the second sublayer), ``renormed_weights``
+   (the 12 weights renormalised to sum to one before the scaling factor)
+   and ``w8a8_mlp``.
 
 The parent imports no JAX: the chip belongs to one process at a time, so
 each phase is a child (or the pair engine + chain server) that has
@@ -196,6 +210,13 @@ class Sizes:
     # Not 0: program AND reference keep this many rows (16,384: every row a
     # query sees, which tells what of a reading the selection's flips make).
     deepseek_topk: int = 0
+    # ``--model longcat_flash``: a prompt of the cell's reference length
+    # in chunks of ``longcat_chunk``, its last ``longcat_decode`` positions
+    # through the decode step.
+    longcat_model: str = "longcat-flash-chat-l4e16"
+    longcat_prompt: int = 4864
+    longcat_chunk: int = 256
+    longcat_decode: int = 16
     kv_heads: int = 8  # the preset's own; the tiny rehearsal needs 4 to split
     start_timeout_s: float = 600.0
     request_timeout_s: float = 600.0
@@ -247,6 +268,10 @@ TINY = Sizes(
     deepseek_prompt=76,
     deepseek_chunk=16,
     deepseek_verify=12,
+    longcat_model="longcat_flash-tiny",
+    longcat_prompt=75,
+    longcat_chunk=16,
+    longcat_decode=8,
     kv_heads=4,
     start_timeout_s=240.0,
     request_timeout_s=240.0,
@@ -1229,6 +1254,9 @@ HYBRID_CONTROLS = {
         "w8a8_mlp", "no_selection", "last_2048", "no_index_relu", "stale_reject", "no_groups",
         "no_mscale", "draft_shares_set",
     ),
+    "longcat_flash": (
+        "w8a8_mlp", "no_latent_rescale", "no_zero_identity", "shortcut_early", "renormed_weights",
+    ),
 }
 # ``--model exaone_moe`` is held to the limits of its benchmark
 # configuration (``reference.logit_share_limits``; PERF.md section 6,
@@ -1250,6 +1278,9 @@ DOTS3_CONFIG = "benchmarks/configs/dots3-note-prev-l6e32.json"
 # ``--model deepseek_v32`` likewise (``benchmarks/arch/deepseek_v32.py``;
 # PERF.md section 6, PR 53).
 DEEPSEEK_CONFIG = "benchmarks/configs/deepseek-v3.2-l5e16.json"
+# ``--model longcat_flash`` likewise (``benchmarks/arch/longcat_flash.py``;
+# PERF.md section 6, PR 57).
+LONGCAT_CONFIG = "benchmarks/configs/longcat-flash-chat-l4e16.json"
 
 
 def hybrid_limits(model: str) -> dict:
@@ -1564,6 +1595,42 @@ def child_zaya(seed: int, sizes: Sizes, control: str = "") -> None:
     )
 
 
+def child_longcat_flash(seed: int, sizes: Sizes, control: str = "") -> None:
+    """``--hybrid --model longcat_flash``: a control changes what the
+    reference computes, one step at a time: its MLP products (dense and
+    experts) in the nearest precision below, the normed latents not
+    rescaled, the identity experts' term left out, the experts' sum added
+    where a plain expert layer adds it (before the second sublayer), the
+    chosen outputs' weights renormalised to sum to one."""
+    import jax.numpy as jnp
+
+    def early(ref):
+        def layer(x, first, second, dims_t):
+            eps = dict(dims_t)["eps"]
+            x = ref._attend(x, first, dims_t)
+            x = ref._dense(x, first, eps) + ref._experts(x, first, dims_t)
+            return ref._dense(ref._attend(x, second, dims_t), second, eps)
+
+        return layer
+
+    def renormed(g, chosen, dims):
+        w = jnp.where(chosen, g, 0.0)
+        return w / w.sum(-1, keepdims=True) * dims["scale"]
+
+    patches = lambda ref: {
+        "w8a8_mlp": {"_swiglu": _w8a8_swiglu()},
+        "no_latent_rescale": {"_rescale": lambda c, d_model, rank: c},
+        "no_zero_identity": {"_identity": lambda u, w_zero: jnp.zeros_like(u)},
+        "shortcut_early": {"layer": early(ref)},
+        "renormed_weights": {"_weights": renormed},
+    }
+    _child_by_benchmark(
+        seed, control, family="longcat_flash", config=LONGCAT_CONFIG, preset=sizes.longcat_model,
+        prompt=sizes.longcat_prompt, chunk=sizes.longcat_chunk, decode=sizes.longcat_decode,
+        patches=patches, sites=("moe_experts", "attn_latent"),
+    )
+
+
 def child_nemotron_h(seed: int, sizes: Sizes, control: str = "") -> None:
     """``--hybrid --model nemotron_h``: a control changes what the
     reference computes: the experts' products in the nearest precision
@@ -1735,6 +1802,8 @@ def child_deepseek_v32(seed: int, sizes: Sizes, control: str = "") -> None:
 def child_hybrid(seed: int, sizes: Sizes, control: str = "", model: str = "ling") -> None:
     if model == "deepseek_v32":
         return child_deepseek_v32(seed, sizes, control)
+    if model == "longcat_flash":
+        return child_longcat_flash(seed, sizes, control)
     if model == "dots3_note":
         return child_dots3_note(seed, sizes, control)
     if model == "zaya":

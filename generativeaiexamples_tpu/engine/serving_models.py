@@ -559,10 +559,10 @@ class HybridServing:
         cut, 8 (the cap) for Ling's.  A model with no expert layer is a
         dense one: 1."""
         cfg = self.cfg
-        if not any(mlp == "experts" for _, mlp in cfg.layer_kinds):
+        if not any(mlp in hybrid.EXPERT_MLPS for _, mlp in cfg.layer_kinds):
             return 1
         return chunks_sharing_experts(
-            chunk_tokens, cfg.n_experts, cfg.n_experts_per_tok
+            chunk_tokens, cfg.router_outputs, cfg.n_experts_per_tok
         )
 
     def prefill_rows(self, params, cache, tokens, start, suffix_len, slots, window):

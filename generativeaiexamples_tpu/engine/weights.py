@@ -90,6 +90,8 @@ def resolve_model_preset(model_name: str) -> str:
         return "dots3_note-tiny" if "tiny" in name else "dots3-note-prev-l6e32"
     if "deepseek" in name:
         return "deepseek_v32-tiny" if "tiny" in name else "deepseek-v3.2-l5e16"
+    if "longcat" in name:
+        return "longcat_flash-tiny" if "tiny" in name else "longcat-flash-chat-l4e16"
     if "mixtral" in name or "8x7b" in name:
         return "mixtral-8x7b"
     if "gemma" in name:

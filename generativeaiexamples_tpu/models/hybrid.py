@@ -1,9 +1,11 @@
 """A decoder made of layer kinds: each layer names its mixer (``kda``,
 ``mla``, ``full``, ``window``, ``cca``, ``mamba`` or ``mla_window``) and its MLP
-(``dense``, ``experts`` or ``none``: the layer is its mixer alone), owns
-the parameters of those kinds and keeps the state of its mixer's kind.
+(``dense``, ``experts``, ``none``: the layer is its mixer alone, or the
+pair ``shortcut`` / ``dense_add``: a dense MLP and experts on one normed
+input, the experts' sum added a layer later), owns the parameters of those
+kinds and keeps the state of its mixer's kind.
 
-Eight families are defined here.  ``bailing_hybrid`` (Ling-3.0-flash and
+Nine families are defined here.  ``bailing_hybrid`` (Ling-3.0-flash and
 its -VL sibling's language model): KDA linear attention (``ops/kda.py``)
 beside a latent-attention layer every ``layer_group_size`` layers
 (``ops/mla.py``), a leading dense SwiGLU layer and then sigmoid-routed
@@ -76,6 +78,23 @@ rows and index keys of its own, which the engine serves as the draft of
 its decode step: every step verifies two positions a row, each over the
 rows it selects for itself (``PredictingLatentConfig``; the step form of
 ``_mla_mixer``).
+``longcat_flash`` (LongCat-Flash-Chat): a published layer is TWO entries
+of ``layer_kinds`` and two state entries, ``("mla", "shortcut")`` and
+``("mla", "dense_add")``: two latent sublayers (``mistral4``'s low-rank
+query and no gate, ``dots3_note``'s rescale of both normed latents, the
+plain frequencies), each followed by a dense SwiGLU, and ONE expert layer
+that reads the first sublayer's normed post-attention stream beside the
+first dense MLP and is added after the second sublayer's dense MLP
+(shortcut-connected experts: ``forward`` carries the experts' sum from the
+one entry to the next beside ``x``, as it carries a ZAYA router's state;
+in a deployment that is the window in which the experts' exchange hides).
+Its router has ``zero_experts`` outputs past the ``n_experts`` real ones
+(768 = 512 + 256): softmax over them all, a selection bias, 12 a token,
+not renormalised; a choice past the real experts is an identity expert
+that adds ``w x`` and computes nothing (``ops/moe.py::expert_mlp``,
+``zero_from``), so the real experts a token computes vary from 0 to 12
+(``ShortcutLatentConfig``).  Its prediction module has no key in the
+public config and is not served.
 Where a family goes: a stack of DIFFERING kinds is a ``HybridConfig``, and
 a new mixer or MLP is a new layer kind here, not another flag on
 ``LlamaConfig``.  A stack of IDENTICAL llama-shaped layers is a
@@ -98,7 +117,10 @@ State of a slot, by the layer's mixer:
   ``conv_kernel - 1`` inputs of the q/k/v convolution.  Fixed size; it
   exists only as of the last token it has seen.
 * ``mla``: ``latent`` (T, kv_lora_rank + rope) — rows that grow with the
-  tokens and can be cut at any length; with an indexer, ``index_k``
+  tokens and can be cut at any length (a ``longcat_flash`` layer keeps two
+  of them, one a sublayer: 2 x 1,280 B a token at the published widths;
+  the experts' sum that crosses the second sublayer lives inside one call
+  and is no state); with an indexer, ``index_k``
   (T, index_head_dim) beside them: the index key of every position, rows
   of the same sort (written with the latent row, cut anywhere, grafted on
   a prefix hit).
@@ -141,7 +163,8 @@ head; ``MambaConfig`` the ``nemotron_h`` family's Mamba-2 sizes, its
 experts' latent and their activation; ``IndexedLatentConfig`` the
 ``dots3_note`` family's indexer, its rescale and its window kind's sizes;
 ``PredictingLatentConfig`` the ``deepseek_v32`` family's prediction module
-over latent rows.
+over latent rows; ``ShortcutLatentConfig`` the ``longcat_flash`` family's
+rescale and its identity experts.
 
 What is read from a family's convention and not from a key of the
 public config is listed under ``assumed`` in
@@ -159,26 +182,30 @@ rescale and of the gate, the indexer's form, norm and rotation, the
 window's count; not served: the towers and the prediction module)
 and ``benchmarks/configs/deepseek-v3.2-l5e16.json`` (the indexer's rotary
 convention, the module's halves, the seeded weights; not served: FP8, more
-than one drafted position);
+than one drafted position)
+and ``benchmarks/configs/longcat-flash-chat-l4e16.json`` (the layer's
+wiring, where the two scales apply, no renormalisation, the rotation over
+adjacent pairs, the untied head; not served: the prediction module);
 the plain references are ``models/hybrid_reference.py``,
 ``models/mellum_reference.py``, ``models/exaone_moe_reference.py``,
 ``models/mistral4_reference.py``, ``models/zaya_reference.py``,
-``models/nemotron_h_reference.py``, ``models/dots3_note_reference.py`` and
-``models/deepseek_v32_reference.py``.
+``models/nemotron_h_reference.py``, ``models/dots3_note_reference.py``,
+``models/deepseek_v32_reference.py`` and
+``models/longcat_flash_reference.py``.
 
 What a row of ``benchmarks/README.md``'s layout table would say of the
 newest family (that file is a ``benchmark`` PR's to edit):
-``benchmarks/arch/dots3_note.py`` maps
-``configs/dots3-note-prev-l6e32.json`` to ``IndexedLatentConfig`` through
-:func:`from_hf_config` and holds its counts and its comparison (the logit
-shares and ``index_overlap``, read from what the chunk programs' and
-decode steps' own selections returned),
-``benchmarks/dots3_note_reference.py`` is the copy of
-``models/dots3_note_reference.py`` that decides its cell's ``correct``,
-``traffic/doc-mid.json`` and ``traffic/doc-mid-closed.json`` are its mix,
-``layer_metrics/prefill_selected_rows_pct.py`` and
-``layer_metrics/decode_index_rows_pct.py`` read the counters this family
-added.
+``benchmarks/arch/longcat_flash.py`` maps
+``configs/longcat-flash-chat-l4e16.json`` to ``ShortcutLatentConfig``
+through :func:`from_hf_config` (``num_layers`` 4 -> 8 entries of
+``layer_kinds``) and holds its counts (an identity choice costs no bytes
+and no operations) and its comparison (``arch/mistral4.py``'s: the prompt
+through ``prefill_rows`` in place, its last positions through the decode
+step), ``benchmarks/longcat_flash_reference.py`` is the copy of
+``models/longcat_flash_reference.py`` that decides its cell's ``correct``,
+``traffic/doc-reason.json`` and ``traffic/doc-reason-closed.json``
+(DeepSeek-V3.2's) are its mix, ``layer_metrics/decode_zero_choice_pct.py``
+reads the counter this family added (``moe_choices_zero``).
 """
 
 from __future__ import annotations
@@ -202,8 +229,15 @@ F32 = jnp.float32
 MIXERS = ("kda", "mla", "full", "window", "cca", "mamba", "mla_window")
 # ``none``: the layer is its mixer alone (no norm, no parameters, nothing
 # added): a stack whose published layers are ONE function each reads as
-# such pairs (``_from_nemotron_h``).
-MLPS = ("dense", "experts", "none")
+# such pairs (``_from_nemotron_h``).  ``shortcut``: a dense MLP AND experts
+# on the same normed input, the dense MLP added here and the experts'
+# sum handed on as a pending branch; ``dense_add``: a dense MLP, and then
+# the pending branch of the layer before is added (LongCat-Flash's
+# shortcut-connected experts: the two halves of one published layer).
+MLPS = ("dense", "experts", "none", "shortcut", "dense_add")
+# The MLP kinds that own experts, and those that own a dense MLP.
+EXPERT_MLPS = ("experts", "shortcut")
+DENSE_MLPS = ("dense", "shortcut", "dense_add")
 # The mixers a prediction module's block may have: kinds whose state is
 # rows a position, so that a rejected draft's row is masked by the length
 # and written over (a ring or a recurrent state would have to be rolled
@@ -347,6 +381,9 @@ class HybridConfig:
     index_topk: ClassVar[int] = 0
     latent_rescale: ClassVar[bool] = False
     window_latent: ClassVar[LatentSizes | None] = None
+    # What ``ShortcutLatentConfig`` makes a field of: router outputs past
+    # ``n_experts`` that are identity experts (none).
+    zero_experts: ClassVar[int] = 0
     # What ``CcaConfig`` makes fields of: a linear router, an untied head.
     router_hidden: ClassVar[int] = 0
     tie_embeddings: ClassVar[bool] = False
@@ -359,6 +396,13 @@ class HybridConfig:
         for mixer, mlp in self.layer_kinds:
             if mixer not in MIXERS or mlp not in MLPS:
                 raise ValueError(f"unknown layer kind ({mixer!r}, {mlp!r})")
+        mlps = [mlp for _, mlp in self.layer_kinds]
+        # Each layer's MLP beside the one before it, the ends beside None.
+        if any((m == "dense_add") != (before == "shortcut") for m, before in zip(mlps + [None], [None] + mlps)):
+            raise ValueError(
+                "a 'shortcut' layer hands its experts' sum to the 'dense_add' "
+                "layer that follows it, and to no other"
+            )
         if self.n_experts % self.n_group:
             raise ValueError("n_group must divide n_experts")
         if not 0 <= self.expert_offset <= self.n_experts - self.experts_held:
@@ -403,6 +447,12 @@ class HybridConfig:
     @property
     def n_layers(self) -> int:
         return len(self.layer_kinds)
+
+    @property
+    def router_outputs(self) -> int:
+        """The router's width: the published experts, then the identity
+        experts, which no chip holds."""
+        return self.n_experts + self.zero_experts
 
     @property
     def has_attn_counters(self) -> bool:
@@ -613,6 +663,24 @@ class LatentConfig(HybridConfig):
 
 
 @dataclasses.dataclass(frozen=True)
+class ShortcutLatentConfig(LatentConfig):
+    """A configuration of the ``longcat_flash`` family: a published layer
+    is TWO entries of ``layer_kinds``, ``("mla", "shortcut")`` and
+    ``("mla", "dense_add")``: two latent sublayers (the ``mistral4``
+    family's low-rank query, no gate, plain frequencies, both normed
+    latents rescaled as ``dots3_note``'s are) with a dense MLP each, and
+    one expert layer that reads the first sublayer's normed post-attention
+    stream and is added after the second sublayer's dense MLP
+    (``forward`` carries it between the two entries beside ``x``).  Its
+    router has ``zero_experts`` outputs past the ``n_experts`` published
+    ones; a choice there is an identity expert (``ops/moe.py::expert_mlp``,
+    ``zero_from``)."""
+
+    latent_rescale: bool = True
+    zero_experts: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
 class IndexedLatentConfig(LatentConfig):
     """A configuration of the ``dots3_note`` family: ``mla`` layers whose
     queries attend only the rows a learned indexer selects, beside
@@ -770,7 +838,8 @@ def from_hf_config(
     (:func:`_from_exaone`), ``mistral4`` (:func:`_from_mistral4`), ``zaya``
     (:func:`_from_zaya`), ``nemotron_h`` (:func:`_from_nemotron_h`),
     ``dots3_note`` (:func:`_from_dots3_note`), ``deepseek_v32``
-    (:func:`_from_deepseek_v32`), else the
+    (:func:`_from_deepseek_v32`), ``longcat_flash``
+    (:func:`_from_longcat_flash`), else the
     ``bailing_hybrid`` family, of which the rest speaks.  ``draft`` ``mtp`` holds the model's own prediction
     module and serves it as the decode step's draft; a family without one
     refuses it.
@@ -813,6 +882,10 @@ def from_hf_config(
         )
     if model.get("model_type") == "dots3_note":
         return _from_dots3_note(
+            model, max_len=max_len, expert_offset=expert_offset, kv_dtype=kv_dtype
+        )
+    if model.get("model_type") == "longcat_flash":
+        return _from_longcat_flash(
             model, max_len=max_len, expert_offset=expert_offset, kv_dtype=kv_dtype
         )
     period = int(model["layer_group_size"])
@@ -1414,6 +1487,72 @@ def _from_deepseek_v32(
     )
 
 
+def _from_longcat_flash(
+    model: Mapping[str, Any], *, max_len: int, expert_offset: int, kv_dtype: str
+) -> ShortcutLatentConfig:
+    """``model_type: longcat_flash``: ``num_layers`` counts published
+    layers, each two ``mla`` sublayers (a low-rank query, both normed
+    latents times ``(hidden_size / rank)^1/2``: ``mla_scale_q_lora``,
+    ``mla_scale_kv_lora``; the plain frequencies of ``rope_theta``; no
+    gate) with a dense SwiGLU of ``ffn_hidden_size`` each, and one expert
+    layer of ``expert_ffn_hidden_size`` between them: two entries of
+    ``layer_kinds`` and two state entries a published layer.  The router
+    has ``num_experts_published + zero_expert_num`` outputs (softmax over
+    them all, a selection bias, ``moe_topk`` a token, not renormalised,
+    times ``routed_scaling_factor``), of which ``n_routed_experts`` real
+    ones are held from ``expert_offset`` on and the last
+    ``zero_expert_num`` are identity experts.  The prediction module has
+    no key here and is not served."""
+    if str(model.get("attention_method", "MLA")) != "MLA":
+        raise ValueError("longcat_flash is served with latent attention (attention_method MLA)")
+    if int(model.get("zero_expert_num", 0)) and model.get("zero_expert_type") != "identity":
+        raise ValueError(
+            f"zero_expert_type {model.get('zero_expert_type')!r} is not served: an "
+            "identity expert adds its weight times the token"
+        )
+    if bool(model.get("mla_scale_q_lora")) != bool(model.get("mla_scale_kv_lora")):
+        raise ValueError("mla_scale_q_lora and mla_scale_kv_lora are served both on or both off")
+    if model.get("attention_bias") or model.get("hidden_act", "silu") != "silu":
+        raise ValueError("attention biases and activations other than silu are not served")
+    if not model.get("q_lora_rank"):
+        raise ValueError("longcat_flash is served with a low-rank query (q_lora_rank)")
+    if model.get("rope_scaling"):
+        raise ValueError("rope_scaling is not served for longcat_flash (the plain frequencies)")
+    held = int(model["n_routed_experts"])
+    return ShortcutLatentConfig(
+        vocab_size=int(model["vocab_size"]),
+        d_model=int(model["hidden_size"]),
+        layer_kinds=(("mla", "shortcut"), ("mla", "dense_add")) * int(model["num_layers"]),
+        n_heads=int(model["num_attention_heads"]),
+        kv_lora_rank=int(model["kv_lora_rank"]),
+        qk_nope_head_dim=int(model["qk_nope_head_dim"]),
+        qk_rope_head_dim=int(model["qk_rope_head_dim"]),
+        v_head_dim=int(model["v_head_dim"]),
+        rope_theta=float(model["rope_theta"]),
+        q_lora_rank=int(model["q_lora_rank"]),
+        mla_out_gate=False,
+        latent_rescale=bool(model.get("mla_scale_kv_lora")),
+        zero_experts=int(model.get("zero_expert_num", 0)),
+        d_ff=int(model["ffn_hidden_size"]),
+        moe_d_ff=int(model["expert_ffn_hidden_size"]),
+        shared_d_ff=0,
+        n_experts=int(model.get("num_experts_published", held)),
+        experts_held=held,
+        expert_offset=int(expert_offset),
+        n_experts_per_tok=int(model["moe_topk"]),
+        n_group=1,
+        topk_group=1,
+        routed_scaling=float(model["routed_scaling_factor"]),
+        norm_topk=False,
+        score_function="softmax",
+        router_bias=True,
+        norm_eps=float(model["rms_norm_eps"]),
+        max_seq_len=int(max_len),
+        dtype=str(model.get("torch_dtype", "bfloat16")),
+        kv_dtype=kv_dtype,
+    )
+
+
 # -- parameters ---------------------------------------------------------------
 
 
@@ -1517,9 +1656,9 @@ def _layer_shapes(cfg: HybridConfig, mixer: str, mlp: str) -> dict:
                 ki_norm_b=((dI,), "bias"),
                 w_wi=((D, HI), D),
             )
-    if mlp == "dense":
+    if mlp in DENSE_MLPS:
         shapes.update(w_gu=((D, 2 * cfg.d_ff), D), w_down=((cfg.d_ff, D), cfg.d_ff))
-    elif mlp == "experts":
+    if mlp in EXPERT_MLPS:
         F, Fs, E = cfg.moe_d_ff, cfg.shared_d_ff, cfg.experts_held
         R = cfg.router_hidden
         if R:
@@ -1531,9 +1670,9 @@ def _layer_shapes(cfg: HybridConfig, mixer: str, mlp: str) -> dict:
                 router_w3=((R, cfg.n_experts), R), router_b3=((cfg.n_experts,), "bias"),
             )
         else:
-            shapes.update(router=((D, cfg.n_experts), D))
+            shapes.update(router=((D, cfg.router_outputs), D))
         if cfg.router_bias:
-            shapes.update(router_bias=((cfg.n_experts,), 0.0))
+            shapes.update(router_bias=((cfg.router_outputs,), 0.0))
         # The width the routed experts work in: a latent, or the stream's.
         De = cfg.moe_latent or D
         if cfg.moe_latent:
@@ -1617,12 +1756,25 @@ def balance_router_biases(params: Params, cfg: HybridConfig, key: jax.Array) -> 
     expert layer's bias is balanced on its own inputs
     (``ops.moe.balanced_bias``) before they go on through it.  Random
     weights stand in for a checkpoint whose
-    ``moe_router_enable_expert_bias`` training has done this."""
-    if not cfg.router_bias or not any(mlp == "experts" for _, mlp in cfg.layer_kinds):
+    ``moe_router_enable_expert_bias`` training has done this.
+
+    The sample goes through a layer whole where the expert layer's combine
+    of it, (tokens, choices, width) in float32, stays under
+    ``BALANCE_WHOLE_BYTES`` (every family before ``longcat_flash``: 0.8-1.9
+    GB), and else in equal groups of rows of at most a quarter of that
+    (LongCat-Flash's 96 rows of 12 choices of 6,144 would be 7.2 GB beside
+    10.35 GB of weights: 16 groups of 6 rows); a layer's scores are those
+    of the whole sample either way."""
+    if not cfg.router_bias or not any(mlp in EXPERT_MLPS for _, mlp in cfg.layer_kinds):
         return params
-    rows = max(4, cfg.n_experts // 8)
+    rows = max(4, cfg.router_outputs // 8)
+    a_row = 256 * cfg.n_experts_per_tok * (cfg.moe_latent or cfg.d_model) * 4
+    groups = 1
+    if rows * a_row > BALANCE_WHOLE_BYTES:
+        fit = max(1, BALANCE_WHOLE_BYTES // 4 // a_row)
+        groups = next(g for g in range(1, rows + 1) if rows % g == 0 and rows // g <= fit)
     biases = iter(_balanced_biases(
-        params, cfg, jax.random.randint(key, (rows, 256), 0, cfg.vocab_size, jnp.int32)
+        params, cfg, jax.random.randint(key, (rows, 256), 0, cfg.vocab_size, jnp.int32), groups
     ))
     layers = tuple(
         {**lp, "router_bias": next(biases)} if "router_bias" in lp else lp
@@ -1635,13 +1787,31 @@ def balance_router_biases(params: Params, cfg: HybridConfig, key: jax.Array) -> 
     return params
 
 
-@functools.partial(jax.jit, static_argnames=("cfg",))
-def _balanced_biases(params, cfg: HybridConfig, tokens):
+# ``balance_router_biases``: the largest float32 combine a sample goes
+# through an expert layer whole with.
+BALANCE_WHOLE_BYTES = 2 << 30
+
+
+def _in_groups(fn, groups: int, *rows):
+    """``fn(*rows)`` over the leading (row) axis of every operand (arrays,
+    dicts of them, or None): all rows at once, or, ``groups`` > 1, in that
+    many equal parts one after the other (a function of each row alone:
+    the same numbers, one part's temporaries in memory)."""
+    if groups == 1:
+        return fn(*rows)
+    part = lambda a: a.reshape((groups, a.shape[0] // groups) + a.shape[1:])
+    out = jax.lax.map(lambda parts: fn(*parts), jax.tree.map(part, rows))
+    return jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), out)
+
+
+@functools.partial(jax.jit, static_argnames=("cfg", "groups"))
+def _balanced_biases(params, cfg: HybridConfig, tokens, groups: int = 1):
     """``forward`` over whole rows from nothing, with each expert layer's
     bias balanced on that layer's inputs before they pass through it; a
     prediction module's last, on what the stack hands it (the token that
     follows each position is the row's next; the last position's wraps,
-    which random tokens do not notice)."""
+    which random tokens do not notice).  ``groups``: the rows go through a
+    layer's halves in that many parts (``balance_router_biases``)."""
     b, s = tokens.shape
     pos = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32), (b, s))
     valid, n_valid = jnp.ones((b, s), bool), jnp.full((b,), s, jnp.int32)
@@ -1649,28 +1819,35 @@ def _balanced_biases(params, cfg: HybridConfig, tokens):
     x = params["embed"][tokens]
     out = []
 
-    def through(x, lp, st, mixer, mlp, rho=None):
-        x, _, _ = _mix(x, lp, st, mixer, pos, valid, n_valid, cfg, s)
-        if mlp == "experts":
+    def through(x, lp, st, mixer, mlp, rho=None, pending=None):
+        x = _in_groups(
+            lambda x, st, pos, valid, n_valid: _mix(x, lp, st, mixer, pos, valid, n_valid, cfg, s)[0],
+            groups, x, st, pos, valid, n_valid,
+        )
+        if mlp in EXPERT_MLPS:
             h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
             h = h.reshape(-1, h.shape[-1])
             if cfg.router_hidden:
                 prev = None if rho is None else rho.reshape(h.shape[0], -1)
                 scores, _ = moe.mlp_scores(h, lp, prev, eps=cfg.norm_eps)
             else:
-                scores = moe.scores(h, lp["router"])
+                scores = moe.scores(h, lp["router"], cfg.score_function)
             bias = moe.balanced_bias(
                 scores, k=cfg.n_experts_per_tok, n_group=cfg.n_group,
                 topk_group=cfg.topk_group,
             )
             out.append(bias)
             lp = {**lp, "router_bias": bias}
-        x, _, rho = _mlp(x, lp, mlp, valid, cfg, None, rho)
-        return x, rho
 
-    rho = None
+        def mlp_half(x, valid, rho, pending):
+            x, _, rho, pending = _mlp(x, lp, mlp, valid, cfg, None, rho, pending)
+            return x, rho, pending
+
+        return _in_groups(mlp_half, groups, x, valid, rho, pending)
+
+    rho = pending = None
     for (mixer, mlp), lp, st in zip(cfg.layer_kinds, params["layers"], state):
-        x, rho = through(x, lp, st, mixer, mlp, rho)
+        x, rho, pending = through(x, lp, st, mixer, mlp, rho, pending)
     if cfg.mtp_layers:
         u = _mtp_input(params, cfg, x, jnp.roll(tokens, -1, axis=1))
         through(u, params["mtp"]["layer"], state[cfg.n_layers], *cfg.mtp_kind)
@@ -2467,6 +2644,7 @@ def _expert_layer(h, lp, valid, cfg: HybridConfig, mesh, rho=None):
     y, counters = moe.expert_mlp(
         u, idx, w, valid.reshape(-1), lp,
         offset=cfg.expert_offset, held=cfg.experts_held, mesh=mesh, act=cfg.expert_act,
+        zero_from=cfg.n_experts if cfg.zero_experts else None,
     )
     if cfg.moe_latent:
         # Linear: the shares' partial sums, each through its own copy, add up.
@@ -2520,18 +2698,30 @@ def _mix(
     return x + y, st, read
 
 
-def _mlp(x, lp, mlp, valid, cfg: HybridConfig, mesh, rho=None):
+def _mlp(x, lp, mlp, valid, cfg: HybridConfig, mesh, rho=None, pending=None):
     """The MLP's half: (x + mlp(norm(x)), the expert layer's counters, the
-    router state to hand the next layer: ``_expert_layer``); ``x`` as it
-    came where the layer is its mixer alone."""
+    router state to hand the next layer: ``_expert_layer``, the pending
+    branch to hand it); ``x`` as it came where the layer is its mixer
+    alone.  A ``shortcut`` layer adds its dense MLP and hands its experts'
+    sum on as ``pending`` (both read the same normed ``x``); the
+    ``dense_add`` layer after it adds its dense MLP and then that sum.
+    ``rho`` and ``pending`` are None wherever no layer has either: what a
+    program carries is decided from the configuration at trace time."""
     if mlp == "none":
-        return x, 0, rho
+        return x, 0, rho, pending
     h = rms_norm(x, lp["mlp_norm"], cfg.norm_eps)
-    if mlp == "dense":
-        with jax.named_scope("layer/mlp"):
-            return x + _swiglu(h, lp["w_gu"], lp["w_down"]), 0, rho
-    y, counters, rho = _expert_layer(h, lp, valid, cfg, mesh, rho)
-    return x + y, counters, rho
+    counters = 0
+    if mlp in EXPERT_MLPS:
+        y, counters, rho = _expert_layer(h, lp, valid, cfg, mesh, rho)
+        if mlp == "experts":
+            return x + y, counters, rho, pending
+        pending = y
+    with jax.named_scope("layer/mlp"):
+        x = x + _swiglu(h, lp["w_gu"], lp["w_down"])
+    if mlp == "dense_add":
+        with jax.named_scope("layer/moe/shortcut_add"):
+            x, pending = x + pending, None
+    return x, counters, rho, pending
 
 
 def forward(
@@ -2561,13 +2751,15 @@ def forward(
     counters = jnp.zeros((len(moe.COUNTERS),), jnp.int32)
     read = jnp.zeros((len(cfg.row_counters) + len(cfg.step_counters),), jnp.int32)
     out_state = []
-    rho = None  # a ZAYA router's state, from layer to layer beside ``x``
+    # Beside ``x`` from layer to layer: a ZAYA router's state, and the
+    # experts' sum a ``shortcut`` layer started, until its ``dense_add``.
+    rho = pending = None
     for (mixer, mlp), lp, st in zip(cfg.layer_kinds, params["layers"], state):
         x, st, r = _mix(
             x, lp, st, mixer, pos, valid, n_valid.astype(jnp.int32), cfg, window,
             rows_apart, mesh=mesh,
         )
-        x, c, rho = _mlp(x, lp, mlp, valid, cfg, mesh, rho)
+        x, c, rho, pending = _mlp(x, lp, mlp, valid, cfg, mesh, rho, pending)
         counters = counters + c
         read = read + r
         out_state.append(st)
@@ -2634,7 +2826,7 @@ def mtp_forward(
             u, lp, rows, cfg.mtp_kind[0], pos, valid, n_valid, cfg, window, rows_apart,
             site="mtp_", mesh=mesh,
         )
-        x, counters, _ = _mlp(x, lp, cfg.mtp_kind[1], valid, cfg, mesh)
+        x, counters, _, _ = _mlp(x, lp, cfg.mtp_kind[1], valid, cfg, mesh)
     return x, rows, jnp.concatenate([counters, read])
 
 
@@ -2991,6 +3183,42 @@ DEEPSEEK_V32_TINY = {
 }
 
 
+# meituan-longcat/LongCat-Flash-Chat's config.json: every key that gives
+# the model its shape (the prediction module has no key there and is not
+# modelled).
+LONGCAT_FLASH_CHAT = {
+    "model_type": "longcat_flash", "num_layers": 28, "hidden_size": 6144,
+    "ffn_hidden_size": 12288, "expert_ffn_hidden_size": 2048,
+    "num_attention_heads": 64, "attention_method": "MLA", "q_lora_rank": 1536,
+    "kv_lora_rank": 512, "qk_nope_head_dim": 128, "qk_rope_head_dim": 64,
+    "v_head_dim": 128, "mla_scale_q_lora": True, "mla_scale_kv_lora": True,
+    "attention_bias": False, "n_routed_experts": 512, "zero_expert_num": 256,
+    "zero_expert_type": "identity", "moe_topk": 12, "routed_scaling_factor": 6,
+    "max_position_embeddings": 131072, "rope_theta": 10000000,
+    "rms_norm_eps": 1e-05, "vocab_size": 131072,
+}
+# Rank 0's share of the first of seven pipeline stages, every layer shared
+# by 32 chips: published layers 0-3 (eight latent sublayers), 16 of the 512
+# real experts (the first 16), all 256 identity outputs of the router, an
+# eighth of the vocabulary.
+LONGCAT_L4E16_CUT = {
+    "num_layers": 4, "n_routed_experts": 16, "num_experts_published": 512,
+    "vocab_size": 16384,
+}
+# Every ratio at sizes a CPU test runs: two published layers (four
+# sublayers: a branch crosses a sublayer twice), 4 of 16 real experts held
+# beside 8 identity outputs (a third of the router, as published) and 3 a
+# token, a query rank under the hidden size.
+LONGCAT_FLASH_TINY = {
+    **LONGCAT_FLASH_CHAT, "num_layers": 2, "hidden_size": 64,
+    "ffn_hidden_size": 128, "expert_ffn_hidden_size": 32,
+    "num_attention_heads": 4, "q_lora_rank": 24, "kv_lora_rank": 16,
+    "qk_nope_head_dim": 8, "qk_rope_head_dim": 8, "v_head_dim": 16,
+    "n_routed_experts": 4, "num_experts_published": 16, "zero_expert_num": 8,
+    "moe_topk": 3, "vocab_size": 512, "torch_dtype": "float32",
+}
+
+
 def ling_flash_vl_l7e128() -> HybridConfig:
     return from_hf_config({**LING_FLASH_VL, **LING_L7E128_CUT}, max_len=2048)
 
@@ -3071,6 +3299,18 @@ def deepseek_v32_tiny() -> HybridConfig:
     )
 
 
+def longcat_flash_chat_l4e16() -> HybridConfig:
+    return from_hf_config({**LONGCAT_FLASH_CHAT, **LONGCAT_L4E16_CUT}, max_len=16384)
+
+
+def longcat_flash_tiny() -> HybridConfig:
+    # Blocks of 16 latent rows: shorter than the windows the tests use.
+    return dataclasses.replace(
+        from_hf_config(LONGCAT_FLASH_TINY, max_len=256, kv_dtype="float32"),
+        latent_block=16, latent_decode_block=16,
+    )
+
+
 PRESETS = {
     "ling-3.0-flash-vl-l7e128": ling_flash_vl_l7e128,
     "ling-tiny": ling_tiny,
@@ -3088,4 +3328,6 @@ PRESETS = {
     "dots3_note-tiny": dots3_note_tiny,
     "deepseek-v3.2-l5e16": deepseek_v32_l5e16,
     "deepseek_v32-tiny": deepseek_v32_tiny,
+    "longcat-flash-chat-l4e16": longcat_flash_chat_l4e16,
+    "longcat_flash-tiny": longcat_flash_tiny,
 }

@@ -60,6 +60,10 @@ COUNTERS = (
     # the kernel's pipeline fetches it: ``_streams``.  Over
     # ``experts_touched`` it is 1.0 where every expert streams once.
     "expert_streams",
+    # Choices that fell on identity experts (router outputs from
+    # ``zero_from`` on: they add ``w x`` and compute nothing); they count
+    # under ``choices_routed`` too, never under ``choices_local``.
+    "choices_zero",
 )
 
 
@@ -279,9 +283,14 @@ ACTIVATIONS = ("swiglu", "relu2")
 
 
 def expert_mlp(x, idx, weights, valid, lp, *, offset: int, held: int, mesh=None,
-               act: str = "swiglu"):
+               act: str = "swiglu", zero_from: int | None = None):
     """What the experts held give: ``sum_i w_i E_i(x)`` over the choices
-    ``i`` with ``offset <= idx_i < offset + held``.
+    ``i`` with ``offset <= idx_i < offset + held``; with ``zero_from``, the
+    router's outputs from that one on are identity experts (LongCat-Flash's
+    zero-computation experts: no weights, no rows in the grouped products),
+    and every choice ``idx_i >= zero_from`` adds ``w_i x``, whichever share
+    of the real experts is held (a token's own chip adds them: they need
+    no exchange).
 
     x: (n, D); idx, weights: (n, k); valid: (n,) bool (a padded position
     routes nowhere); lp: ``w_gu_e`` (held, D, 2F) gate and up side by
@@ -321,8 +330,15 @@ def expert_mlp(x, idx, weights, valid, lp, *, offset: int, held: int, mesh=None,
         per_choice = ys[back].reshape(n, k, d).astype(F32)
         w_local = jnp.where(local, weights, 0.0)
         y = jnp.where(local[..., None], per_choice * w_local[..., None], 0.0).sum(1)
+    n_zero = 0
+    if zero_from is not None:
+        with jax.named_scope("layer/moe/zero"):
+            zero = (idx >= zero_from) & valid[:, None]
+            y = y + jnp.where(zero, weights, 0.0).sum(1, keepdims=True) * x.astype(F32)
+            n_zero = zero.sum()
     counters = jnp.stack(
-        [valid.sum().astype(jnp.int32) * k, n_local, (sizes > 0).sum(), sizes.max(), 1, streams]
+        [valid.sum().astype(jnp.int32) * k, n_local, (sizes > 0).sum(), sizes.max(), 1, streams,
+         n_zero]
     ).astype(jnp.int32)
     return y.astype(x.dtype), counters
 

@@ -118,6 +118,7 @@ FILE_SECONDS = {
     "tests/test_gqa_decode_kernel.py": 92,
     "tests/test_qmm.py": 84,
     "tests/test_deepseek_v32_model.py": 74,
+    "tests/test_longcat_flash_model.py": 45,
     "tests/test_ouro_model.py": 84,
     "tests/test_speech.py": 77,
     "tests/test_chip_compile.py": 76,
@@ -192,6 +193,7 @@ REHEARSAL_SECONDS = {
     "zaya1-8b-l20.reason-closed": 74,
     "dots3-note-prev-l6e32.doc-mid-closed": 74,
     "deepseek-v3.2-l5e16.doc-reason-closed": 70,
+    "longcat-flash-chat-l4e16.doc-reason-closed": 70,
     "ouro-2.6b.chat-short-closed": 71,
     "mistral-small-4-119b-l6e32.doc-long-closed": 59,
 }

@@ -131,13 +131,15 @@ def test_row_walk_kernel_compiles_at_both_cells_widths(one_chip, s, n_q, n_kv, w
 # A decode step over latent rows (``ops/mla_decode.py``), 16 slots:
 # Mistral-Small-4's rows of 384 columns over a latent of 256 under one
 # query a row, dots3-note-prev's of 640 over 512 under a token and its
-# draft (what a verify step would hand it), at the narrowest and the widest
-# decode window (blocks of 2,048 either way).
+# draft (what a verify step would hand it), LongCat-Flash's of 640 over 512
+# under one query of 64 heads a row (no indexer in front: the step walks a
+# row's every block), at the narrowest and the widest decode window (blocks
+# of 2,048 either way).
 @pytest.mark.parametrize("window", [2048, 32768])
 @pytest.mark.parametrize(
     "s,heads,rank,nope,width,rows",
-    [(1, 32, 256, 64, 384, 32768), (2, 128, 512, 128, 640, 16384)],
-    ids=["mistral-small-4", "dots3"],
+    [(1, 32, 256, 64, 384, 32768), (2, 128, 512, 128, 640, 16384), (1, 64, 512, 128, 640, 16384)],
+    ids=["mistral-small-4", "dots3", "longcat-flash"],
 )
 def test_latent_decode_kernel_compiles_at_both_families_widths(
     one_chip, s, heads, rank, nope, width, rows, window, monkeypatch
@@ -169,6 +171,38 @@ def test_latent_decode_kernel_compiles_at_both_families_widths(
     assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*latent_decode_attention", text)) == 1
     # The leaf stays where it lies: nothing of its size is made.
     assert compiled.memory_analysis().temp_size_in_bytes < 4_000_000
+
+
+def test_latent_chunk_kernel_compiles_at_longcat_flashs_widths(one_chip, monkeypatch):
+    """A prefill chunk's walk over latent rows (``ops/mla_chunk.py``) at the
+    one set of widths no chunk PROGRAM of these files holds: LongCat-Flash's
+    64 heads over rows of 640 and a latent of 512, 8 rows of 256 queries on
+    16 slots of 16,384, no indexer's mask, 8 heads a grid step (the whole
+    8-row program, eight such walks beside two dense MLPs a layer, compiled
+    here with 1.19 GB of temporaries: PERF.md, PR 57)."""
+    from generativeaiexamples_tpu.ops import gqa_decode, mla_chunk
+
+    S = _spec(one_chip)
+    b, s, heads, rank, nope, rope, v_dim, width, rows = 8, 256, 64, 512, 128, 64, 128, 640, 16384
+    leaf = S((16, rows, width), jnp.bfloat16)
+    monkeypatch.setattr(gqa_decode, "platform_of", lambda mesh: "tpu")
+    assert mla_chunk.use_latent_chunk(
+        s=s, q_dtype=jnp.bfloat16, rows_dtype=leaf.dtype, width=width, rank=rank, nope=nope,
+        v_dim=v_dim, heads=heads, rows=rows, window=rows, block=1024, masked=False,
+    )
+
+    def attn(q_nope, q_rope, latent, w_kvb, pos, lens, slot):
+        return mla_chunk.attend_latent_chunk(
+            q_nope, q_rope, latent, w_kvb, pos, lens, slot, rank=rank, nope=nope, v_dim=v_dim,
+            window=rows, block=1024, interpret=False,
+        )
+
+    compiled = _compile(
+        attn, S((b, s, heads, nope), jnp.bfloat16), S((b, s, heads, rope), jnp.bfloat16), leaf,
+        S((rank, heads * (nope + v_dim)), jnp.bfloat16), S((b, s), jnp.int32), S((b,), jnp.int32),
+        S((b,), jnp.int32),
+    )
+    assert len(re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*latent_chunk_attention", compiled.as_text())) == 1
 
 
 @pytest.mark.parametrize("batch,s,t", [(8, 1536, 1536), (16, 256, 2048)])

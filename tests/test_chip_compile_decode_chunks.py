@@ -353,6 +353,64 @@ def test_the_latent_models_decode_chunk_keeps_its_state_in_place(one_chip, monke
     assert compiled.memory_analysis().temp_size_in_bytes < 400_000_000
 
 
+def test_the_shortcut_models_decode_chunk_walks_eight_sublayers_in_place(one_chip, monkeypatch):
+    """The decode chunk of longcat-flash-chat-l4e16 (4 steps over 16 slots
+    of 16,384, four published layers: eight latent sublayers, eight dense
+    MLPs, four expert layers whose sum crosses a sublayer) at the widest
+    decode window: every sublayer's ``layer/mla/attn`` is
+    ``ops/mla_decode.py``'s Mosaic call over all 16 rows (64 query rows a
+    slot over a latent of 512: the width PR 56's kernel had not met without
+    an indexer in front), the grouped expert products are in it, no leaf of
+    the slots' state is copied, and beside 10.35 GB of weights and 2.68 GB
+    of state its temporaries stay near half a GB (0.46 here)."""
+    import json
+    from pathlib import Path
+
+    from generativeaiexamples_tpu.engine.serving_models import HybridServing
+    from generativeaiexamples_tpu.models import hybrid
+    from generativeaiexamples_tpu.ops import dispatch, gqa_decode, moe
+
+    monkeypatch.setattr(moe, "platform_of", lambda mesh: "tpu")
+    monkeypatch.setattr(gqa_decode, "platform_of", lambda mesh: "tpu")
+    monkeypatch.setattr(dispatch, "TAKEN", {})
+    configs = Path(__file__).resolve().parents[1] / "benchmarks" / "configs"
+    model = json.loads((configs / "longcat-flash-chat-l4e16.json").read_text())
+    engine = model["engine"]
+    max_len, b, steps = int(engine["max_len"]), int(engine["max_batch"]), int(engine["decode_chunk_size"])
+    cfg = hybrid.from_hf_config(model, max_len=max_len, kv_dtype=engine["kv_dtype"])
+    assert cfg.latent_width == 640 and len(cfg.layers_of("mla")) == 8 and cfg.router_outputs == 768
+    serving = HybridServing(cfg, None, max_len)
+
+    def described(make):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+            jax.eval_shape(make),
+        )
+
+    spec = _spec(one_chip)
+    ints, floats = spec((b,), jnp.int32), spec((b,), jnp.float32)
+    compiled = serving.make_decode_chunk().lower(
+        described(lambda: hybrid.init_params(cfg, jax.random.PRNGKey(0))),
+        described(lambda: hybrid.init_state(cfg, b, max_len)),
+        ints, ints, spec((2,), jnp.uint32), floats, floats, ints, steps, max_len,
+        spec((b,), jnp.bool_),
+    ).compile()
+    text = compiled.as_text()
+    taken = dict(dispatch.TAKEN)
+    assert taken[f"attn_latent_decode b={b} t={max_len}"] == "pallas", taken
+    assert {taken[site] for site in taken if site.startswith("moe_experts")} == {"pallas"}
+    calls = re.findall(r"custom_call_target=\"tpu_custom_call\"[^\n]*latent_decode_attention", text)
+    assert len(calls) >= cfg.n_layers and all("layer/mla/attn" in call for call in calls)
+    leaf = rf"bf16\[{b},{max_len},640\]"
+    assert not re.search(rf"= {leaf}\S* copy\(", text)
+    assert not re.search(rf"= (?:\([^=]*)?{leaf}\S*(?:, [^=]*\))? copy-start\(", text)
+    _, toks, aux = compiled.out_info
+    assert toks.shape == (steps, b) and aux.shape == (len(serving.counter_names),)
+    assert "moe_choices_zero" in serving.counter_names
+    print("shortcut decode chunk temporaries", compiled.memory_analysis().temp_size_in_bytes)
+    assert compiled.memory_analysis().temp_size_in_bytes < 600_000_000
+
+
 def test_lings_decode_chunk_touches_the_kda_state_by_the_kernel_alone(one_chip, monkeypatch):
     """The decode chunk of ling-3.0-flash-vl-l7e128 (8 steps over 32
     slots) at the widest decode window: each of the six KDA layers' state

@@ -313,6 +313,44 @@ def test_hybrid_phase_rehearsal_of_the_latent_model_and_its_controls(control, ca
         assert line["p50"] > 0.05
 
 
+LONGCAT_CONTROLS = ["no_latent_rescale", "no_zero_identity", "shortcut_early", "renormed_weights", "w8a8_mlp"]
+
+
+# The sound run and the controls that no other tier-1 test makes of the
+# reference (the branch moved, the identity term dropped and the rescale
+# left out are tests/test_longcat_flash_model.py's and the benchmark's own).
+@pytest.mark.parametrize("control", ["", "w8a8_mlp", "renormed_weights"])
+def test_hybrid_phase_rehearsal_of_the_shortcut_model_and_its_controls(control, capsys, monkeypatch, tmp_path):
+    """``--hybrid --model longcat_flash`` at the tiny size, in process: a
+    prompt of 75 tokens in chunks of 16 through the chunk program beside a
+    pad row (two published layers: the expert branch crosses a sublayer
+    twice), its last 8 positions through the decode step over both slots,
+    by the benchmark's own comparison.  The limits are the configuration's,
+    set for the chip's size and precision; float32 at this size reads 1e-6,
+    so the rehearsal holds it to limits of its own, which the sound run is
+    far inside and each control leaves."""
+    config = json.loads(open(os.path.join(REPO, chip_smoke.LONGCAT_CONFIG)).read())
+    config["reference"]["logit_share_limits"] = MISTRAL4_TINY_LIMITS
+    tiny = tmp_path / "config.json"
+    tiny.write_text(json.dumps(config))
+    monkeypatch.setattr(chip_smoke, "LONGCAT_CONFIG", str(tiny))
+    # A sound run inside the limits and a control outside them both return.
+    chip_smoke.child_hybrid(0, chip_smoke.TINY, control=control, model="longcat_flash")
+    line = _phases(capsys)["hybrid"]
+    assert line["model"] == "longcat_flash-tiny" and line["control"] == (control or None)
+    assert line["positions"] == {"prefill": 67, "decode": 8}
+    assert "attn_latent_chunk b=2 s=16 t=256" in line["kernel_paths"]
+    assert "attn_latent_decode b=2 t=256" in line["kernel_paths"]
+    assert any(site.startswith("moe_experts") for site in line["kernel_paths"])
+    assert line["within_limits"] == (not control)
+    if control == "w8a8_mlp":  # the precision: every position moves, by little
+        assert 1e-3 < line["p10"] < 0.2
+    elif control:
+        assert line["p50"] > 1e-2 and line["decode_p50"] > 1e-2
+    else:
+        assert max(line[k] for k in MISTRAL4_TINY_LIMITS) < 1e-5
+
+
 ZAYA_CONTROLS = ["no_value_shift", "no_qk_mean", "no_conv", "no_router_average", "renormed_top1", "w8a8"]
 
 
@@ -471,7 +509,9 @@ def test_hybrid_phase_names_a_child_for_every_model_and_control():
         "hybrid_dots3_note", *(f"hybrid_dots3_note_{c}" for c in sorted(DOTS3_CONTROLS)),
         "hybrid_exaone_moe", "hybrid_exaone_moe_no_qk_norm", "hybrid_exaone_moe_no_window",
         "hybrid_exaone_moe_rope_on_full", "hybrid_exaone_moe_stale_reject", "hybrid_exaone_moe_w8a8_mlp",
-        "hybrid_ling", "hybrid_ling_w8a8_mlp", "hybrid_mellum", "hybrid_mellum_no_window",
+        "hybrid_ling", "hybrid_ling_w8a8_mlp",
+        "hybrid_longcat_flash", *(f"hybrid_longcat_flash_{c}" for c in sorted(LONGCAT_CONTROLS)),
+        "hybrid_mellum", "hybrid_mellum_no_window",
         "hybrid_mellum_no_yarn", "hybrid_mellum_w8a8_mlp",
         "hybrid_mistral4", "hybrid_mistral4_no_attn_scale", "hybrid_mistral4_no_mscale",
         "hybrid_mistral4_no_q_norm", "hybrid_mistral4_plain_rope", "hybrid_mistral4_w8a8_mlp",

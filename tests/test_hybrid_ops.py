@@ -149,6 +149,28 @@ def test_group_limited_choice_matches_brute_force(router, ties):
     np.testing.assert_allclose(weights.sum(-1), kind["total"], rtol=1e-5)
 
 
+def test_softmax_scores_under_a_selection_bias_keep_their_own_weights():
+    """LongCat-Flash's router: softmax over all outputs (real experts, then
+    identity experts), the bias only selects, the weights are the chosen
+    scores times the scaling factor, not renormalised; the reference's
+    stable sort and ``lax.top_k`` agree on a tie (the lower index)."""
+    from generativeaiexamples_tpu.models import longcat_flash_reference
+
+    r = np.random.RandomState(9)
+    x, w = jnp.asarray(r.randn(64, 16), F32), jnp.asarray(r.randn(16, 48), F32)
+    bias = jnp.asarray(r.randn(48) * 0.05, F32)
+    for router, b in ((w, bias), (jnp.zeros_like(w), jnp.zeros_like(bias))):
+        idx, weights = moe.route(x, router, b, k=5, n_group=1, topk_group=1, norm_topk=False,
+                                 scale=6.0, score="softmax")
+        dense = longcat_flash_reference.routing(
+            x, {"router": router, "router_bias": b}, {"k": 5, "scale": 6.0})
+        got = np.zeros((64, 48), np.float32)
+        np.put_along_axis(got, np.asarray(idx), np.asarray(weights), axis=1)
+        np.testing.assert_allclose(got, dense, rtol=1e-5, atol=1e-7)
+    assert np.asarray(idx)[0].tolist() == [0, 1, 2, 3, 4]  # every score ties
+    assert float(weights.sum(-1).max()) == pytest.approx(6 * 5 / 48)
+
+
 def test_softmax_weights_without_renormalising_are_the_probabilities():
     r = np.random.RandomState(8)
     x, w = jnp.asarray(r.randn(16, 8), F32), jnp.asarray(r.randn(8, 32), F32)
@@ -167,8 +189,12 @@ def _experts(seed, held, D, F):
     }
 
 
+@pytest.mark.parametrize("zero_from", [None, 24], ids=["all_real", "identity_from_24"])
 @pytest.mark.parametrize("kernel", ["dense_stand_in", "gmm_interpret"])
-def test_sorted_dispatch_matches_every_expert_computed_whole(kernel, monkeypatch):
+def test_sorted_dispatch_matches_every_expert_computed_whole(kernel, zero_from, monkeypatch):
+    """``zero_from`` 24: the router's outputs 24-31 are identity experts
+    (LongCat-Flash's zero-computation experts): a choice there adds ``w x``,
+    has no row in the grouped products and is counted apart."""
     if kernel == "gmm_interpret":
         monkeypatch.setenv("GAIE_MOE_KERNEL_INTERPRET", "1")
     r = np.random.RandomState(11)
@@ -178,11 +204,15 @@ def test_sorted_dispatch_matches_every_expert_computed_whole(kernel, monkeypatch
     idx = jnp.asarray(np.stack([r.choice(32, 4, replace=False) for _ in range(n)]), jnp.int32)
     w = jnp.asarray(r.uniform(0.1, 1.0, size=(n, 4)), F32)
     valid = jnp.asarray(np.arange(n) < 40)
-    y, counters = moe.expert_mlp(x, idx, w, valid, lp, offset=offset, held=held)
+    y, counters = moe.expert_mlp(x, idx, w, valid, lp, offset=offset, held=held, zero_from=zero_from)
     want = np.zeros((n, D), np.float32)
     rows = np.zeros(held, int)
+    zero = 0
     for t in range(40):
         for e, wt in zip(np.asarray(idx)[t], np.asarray(w)[t]):
+            if zero_from is not None and e >= zero_from:
+                want[t] += wt * np.asarray(x[t])
+                zero += 1
             if offset <= e < offset + held:
                 gu = x[t] @ lp["w_gu_e"][e - offset]
                 want[t] += wt * np.asarray((jax.nn.silu(gu[:F]) * gu[F:]) @ lp["w_down_e"][e - offset])
@@ -190,7 +220,8 @@ def test_sorted_dispatch_matches_every_expert_computed_whole(kernel, monkeypatch
     np.testing.assert_allclose(y, want, rtol=1e-4, atol=1e-5)
     assert not np.asarray(y)[40:].any()  # a padded position routes nowhere
     # one call; under a row tile of 128 rows and whole K, a stream an expert with a row
-    assert counters.tolist() == [40 * 4, rows.sum(), (rows > 0).sum(), rows.max(), 1, (rows > 0).sum()]
+    assert counters.tolist() == [40 * 4, rows.sum(), (rows > 0).sum(), rows.max(), 1, (rows > 0).sum(), zero]
+    assert (zero > 0) == (zero_from is not None)
 
 
 def test_balanced_bias_evens_the_experts_load():

@@ -197,6 +197,8 @@ ADMITTED = dict(
     rows=16384, window=16384, block=1024, masked=True,
 )
 MISTRAL4 = dict(ADMITTED, width=384, rank=256, nope=64, heads=32, rows=32768, window=32768, masked=False)
+# LongCat-Flash's: dots3's rows under 64 heads and no indexer's mask.
+LONGCAT = dict(ADMITTED, heads=64, masked=False)
 
 
 @pytest.fixture
@@ -205,7 +207,7 @@ def interpret(monkeypatch):
 
 
 @pytest.mark.parametrize("s", [16, 32, 64, 128, 256])
-@pytest.mark.parametrize("shape", [ADMITTED, MISTRAL4], ids=["dots3", "mistral4"])
+@pytest.mark.parametrize("shape", [ADMITTED, MISTRAL4, LONGCAT], ids=["dots3", "mistral4", "longcat_flash"])
 def test_the_gate_admits_the_chunk_buckets_of_both_families(shape, s, interpret):
     assert mla_chunk.use_latent_chunk(**{**shape, "s": s})
     sizes = dict(s=s, bt=1024, width=shape["width"], rank=shape["rank"],
@@ -268,7 +270,10 @@ def test_a_mask_tile_of_sixteen_queries_is_filled_up_to_a_whole_int8_tile():
 # through, so that the gate admits their chunks (rows of 256: the rope key
 # and 120 zero columns after the latent).
 WIDE = {"kv_lora_rank": 128, "v_head_dim": 128, "torch_dtype": "bfloat16"}
-MIXERS = {"dots3_note": (hybrid.DOTS3_NOTE_TINY, " k=24"), "mistral4": (hybrid.MISTRAL4_TINY, "")}
+MIXERS = {
+    "dots3_note": (hybrid.DOTS3_NOTE_TINY, " k=24"), "mistral4": (hybrid.MISTRAL4_TINY, ""),
+    "longcat_flash": (hybrid.LONGCAT_FLASH_TINY, ""),  # rescaled latents and no mask
+}
 
 
 @pytest.mark.parametrize("family", sorted(MIXERS))

@@ -20,11 +20,13 @@ from tests.test_latent_chunk import BF16, BLOCK, SLOTS, TOL, T, V, _poisoned
 
 # The families' widths as their cells hold them (Mistral-Small-4: 32 heads,
 # rows of 384 = a latent of 256, a rope key of 64 and 64 zero columns;
-# dots3-note-prev: 128 heads, rows of 640 = 512 + 64 + 64) and at an eighth
-# of them, the interpreter's size.
+# dots3-note-prev: 128 heads, rows of 640 = 512 + 64 + 64; LongCat-Flash:
+# 64 heads over rows of 640, with no indexer in front of its step) and at
+# an eighth of them, the interpreter's size.
 FAMILIES = {
     "mistral4": dict(H=32, rank=256, nope=64, rope=64, width=384),
     "dots3": dict(H=128, rank=512, nope=128, rope=64, width=640),
+    "longcat_flash": dict(H=64, rank=512, nope=128, rope=64, width=640),
     "mistral4_eighth": dict(H=4, rank=128, nope=16, rope=16, width=256),
 }
 
@@ -72,7 +74,7 @@ SLOT = [3, 0, 8, 5, 1, 7, 2]
 # each family's widths once in the step's own form (bf16, one query a row),
 # the small widths in float32 and with a draft beside the token.
 CASES = [
-    ("mistral4", 1, BF16), ("dots3", 1, BF16), ("mistral4", 2, BF16),
+    ("mistral4", 1, BF16), ("dots3", 1, BF16), ("longcat_flash", 1, BF16), ("mistral4", 2, BF16),
     ("mistral4_eighth", 1, jnp.float32), ("mistral4_eighth", 2, jnp.float32),
     ("mistral4_eighth", 2, BF16),
 ]
@@ -143,6 +145,7 @@ ADMITTED = dict(
     window=32768, block=2048,
 )
 DOTS3 = dict(ADMITTED, width=640, rank=512, heads=128, rows=16384, window=16384)
+LONGCAT = dict(DOTS3, heads=64)
 
 
 @pytest.fixture
@@ -152,7 +155,7 @@ def interpret(monkeypatch):
 
 @pytest.mark.parametrize("window", [2048, 4096, 8192, 16384, 32768])
 @pytest.mark.parametrize("s", [1, 2])
-@pytest.mark.parametrize("shape", [ADMITTED, DOTS3], ids=["mistral4", "dots3"])
+@pytest.mark.parametrize("shape", [ADMITTED, DOTS3, LONGCAT], ids=["mistral4", "dots3", "longcat_flash"])
 def test_the_gate_admits_the_decode_windows_of_both_families(shape, s, window, interpret):
     window = min(window, shape["rows"])
     assert mla_decode.use_latent_decode(**{**shape, "s": s, "window": window})
@@ -202,13 +205,15 @@ def test_two_devices_and_the_cpu_take_attend_absorbed_blocks(monkeypatch):
 WIDE = {"kv_lora_rank": 128, "v_head_dim": 128, "num_attention_heads": 16, "torch_dtype": "bfloat16"}
 
 
-def test_the_mixer_takes_the_kernel_where_the_gate_admits_and_counts_its_rows(monkeypatch):
-    """``_mla_mixer``'s decode step over four rows of a state of six slots
+@pytest.mark.parametrize("preset", ["MISTRAL4_TINY", "LONGCAT_FLASH_TINY"])
+def test_the_mixer_takes_the_kernel_where_the_gate_admits_and_counts_its_rows(preset, monkeypatch):
+    """(Mistral-Small-4's form, and LongCat-Flash's: rescaled latents, the
+    plain frequencies.)  ``_mla_mixer``'s decode step over four rows of a state of six slots
     (one on a block's edge, one of a single token, one that does not
     decode) on the kernel and on its twin: the same output where a token
     counts, the same rows written, ``kernel_latent`` the rows read on the
     one and absent on the other."""
-    cfg = hybrid.from_hf_config({**hybrid.MISTRAL4_TINY, **WIDE}, max_len=T, kv_dtype="bfloat16")
+    cfg = hybrid.from_hf_config({**getattr(hybrid, preset), **WIDE}, max_len=T, kv_dtype="bfloat16")
     cfg = dataclasses.replace(cfg, latent_decode_block=BLOCK)
     layer = cfg.layers_of("mla")[0]
     lp = hybrid.init_params(cfg, jax.random.PRNGKey(0))["layers"][layer]

@@ -400,7 +400,7 @@ def test_balancing_evens_the_top1_load_through_the_mlp_router():
             idx, _, _ = moe.route_mlp(h, lp, None if rho is None else rho.reshape(2048, -1), eps=CFG.norm_eps)
             load = np.bincount(np.asarray(idx).ravel(), minlength=CFG.n_experts)
             worst = max(worst, load.max() / load.mean())
-            x, _, rho = hybrid._mlp(x, lp, "experts", valid, CFG, None, rho)
+            x, _, rho, _ = hybrid._mlp(x, lp, "experts", valid, CFG, None, rho)
         return worst
 
     without, with_bias = worst_load({**raw, "layers": flat}), worst_load(balanced)
